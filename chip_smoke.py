@@ -1,0 +1,552 @@
+"""chip_smoke.py — the quickest proof that the system still starts on
+the chip: BERT-base pretraining through the normal entry points
+(fluid.Program -> fluid.Executor(fluid.XLAPlace(0)).run) on one TPU.
+
+    python chip_smoke.py            # one chip, every phase below
+    python chip_smoke.py --chips 4  # the data-parallel / sharded path
+                                    # across four chips and the single-
+                                    # device run it is compared with
+
+One process, no children.  It fails (non-zero, no result line) unless
+jax.devices()[0].platform == 'tpu'; nothing here falls back to the CPU.
+Weights and batches are random, from fixed seeds; every check is the
+repo's own means (loss falls, dispatch counters, eval determinism,
+save/load round trip, fused-vs-dense agreement).  Earlier lines carry
+what is worth keeping (step times, compile seconds, peak HBM, the
+dispatch table, versions) — smoke readings, not a benchmark.  The LAST
+stdout line is the contract's:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+"""
+
+import argparse
+import contextlib
+import json
+import shutil
+import sys
+import tempfile
+import time
+
+import numpy as np
+
+# first-step losses of two lowerings of one program on the same seeded
+# weights: both run bf16 matmuls (8 mantissa bits, eps 2^-8 = 3.9e-3)
+# and average ~8k token losses, so they agree far inside 1e-2; a wrong
+# gather row or a dropped gradient moves the loss by whole units
+BF16_LOSS_RTOL = 1e-2
+# kernel-vs-dense elementwise on bf16 attention outputs / f32 grads
+BF16_ELEM_TOL = 3e-2
+# the kernels a BERT + Adam train step reaches
+KERNELS = ('flash_attention', 'embedding_lookup', 'fused_optimizer')
+
+_T0 = time.time()
+
+
+class SmokeFailure(Exception):
+    pass
+
+
+def check(ok, what):
+    if not ok:
+        raise SmokeFailure(what)
+    say('ok: %s' % what)
+
+
+def say(msg):
+    print('[%7.1fs] %s' % (time.time() - _T0, msg), flush=True)
+
+
+# what JAX itself reports while the phases run: every backend compile
+# (the executor's own segment_cache_miss cannot see a jit that quietly
+# re-specialises) and the persistent cache's hits and misses
+_JAX_EVENTS = {'compiles': 0, 'cache_hits': 0, 'cache_misses': 0}
+_listening = []
+
+
+def _listen():
+    if _listening:
+        return
+    import jax
+
+    def on_duration(event, duration, **_):
+        if event == '/jax/core/compile/backend_compile_duration':
+            _JAX_EVENTS['compiles'] += 1
+
+    def on_event(event, **_):
+        for k in ('cache_hits', 'cache_misses'):
+            if event == '/jax/compilation_cache/' + k:
+                _JAX_EVENTS[k] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+    _listening.append(True)
+
+
+def _ints32(batch):
+    return {k: v.astype('int32') if v.dtype == np.int64 else v
+            for k, v in batch.items()}
+
+
+def build_bert(cfg, seq):
+    """bench_bert_long's program: BERT pretrain, bf16 AMP with dynamic
+    loss scaling around Adam.  Also returns the for_test clone taken
+    before minimize (forward only, dropout off)."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu import models
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 42
+    with fluid.program_guard(main, startup):
+        _, _, loss = models.bert.build_pretrain(cfg, seq)
+        test = main.clone(for_test=True)
+        opt = fluid.contrib.mixed_precision.decorate(
+            fluid.optimizer.Adam(1e-4), use_dynamic_loss_scaling=True)
+        opt.minimize(loss)
+    return main, startup, test, loss
+
+
+def host_batch(cfg, batch, seq):
+    """The one fixed synthetic batch (seed 0), ints already int32."""
+    from paddle_tpu import models
+    return _ints32(models.bert.synthetic_batch(
+        cfg, batch, seq, np.random.RandomState(0)))
+
+
+def train_fresh(cfg, batch, seq, steps):
+    """Build the program, run startup in a fresh scope and train
+    `steps` steps on the device-resident fixed batch (uncommitted, as
+    bench._timed_steps feeds).  Returns train_steps()'s triple."""
+    import jax
+    import paddle_tpu.fluid as fluid
+    main, startup, _, loss = build_bert(cfg, seq)
+    feed = {k: jax.device_put(v)
+            for k, v in host_batch(cfg, batch, seq).items()}
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        return train_steps(exe, main, feed, loss, steps)
+
+
+@contextlib.contextmanager
+def flags_set(values):
+    from paddle_tpu.fluid.flags import get_flag, set_flags
+    was = {n: get_flag(n) for n in values}
+    set_flags(values)
+    try:
+        yield
+    finally:
+        set_flags(was)
+
+
+def _scalar(fetched):
+    return float(np.asarray(fetched[0]).ravel()[0])
+
+
+def train_steps(exe, target, feed, loss, steps):
+    """`steps` runs of one fixed batch; each fetch is a host numpy
+    value, so every step has finished on the device when its time is
+    taken.  Returns (losses, seconds per step, and per step the
+    compiles it caused: (executor/segment_cache_miss, JAX backend
+    compiles))."""
+    from paddle_tpu.fluid import monitor
+    _listen()
+
+    def compiles():
+        return (int(monitor.counter_value('executor/segment_cache_miss')),
+                _JAX_EVENTS['compiles'])
+
+    losses, secs, compiled = [], [], []
+    for _ in range(steps):
+        t, c0 = time.time(), compiles()
+        losses.append(_scalar(exe.run(target, feed=feed,
+                                      fetch_list=[loss])))
+        secs.append(time.time() - t)
+        compiled.append(tuple(b - a for a, b in zip(c0, compiles())))
+    return losses, secs, compiled
+
+
+def check_trained(tag, losses, secs, compiled, warm=1):
+    say('%s losses %s' % (tag, ' '.join('%.4f' % v for v in losses)))
+    say('%s first step (compile + run) %.1f s; later steps ms: %s'
+        % (tag, secs[0], ' '.join('%.1f' % (s * 1e3) for s in secs[1:])))
+    check(all(np.isfinite(losses)), '%s: losses finite' % tag)
+    check(losses[-1] < losses[0],
+          '%s: loss falls (%.4f -> %.4f)' % (tag, losses[0], losses[-1]))
+    check(not any(n for c in compiled[warm:] for n in c),
+          '%s: no compile after warm-up; per step (segment_cache_miss, '
+          'jax backend compiles) = %s' % (tag, compiled))
+
+
+def check_dispatch(kernels):
+    """From the counters, not the flags: each kernel dispatched fused,
+    its last decision was not for the interpreter (the platform is one
+    per process, so then none was), and nothing fell back for want of
+    a TPU."""
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.ops.pallas import common
+    table = common.report().get('kernels', {})
+    say('dispatch table: %s' % json.dumps(table, sort_keys=True))
+    for k in kernels:
+        n = monitor.counter_value('pallas/%s/dispatch_fused' % k)
+        check(n > 0, 'pallas/%s/dispatch_fused = %d > 0' % (k, n))
+        check(table[k]['last']['interpret'] is False,
+              '%s: interpret False (last decision %s)'
+              % (k, table[k]['last']))
+    off = {k: v for k, v in monitor.flat().items()
+           if k.startswith('pallas/') and k.endswith('/fallback/off_tpu')
+           and v}
+    check(not off, 'no pallas/*/fallback/off_tpu counted %s' % (off or ''))
+
+
+def phase_train_eval_roundtrip(cfg, batch, seq, steps):
+    """Train on one fixed batch, then flows 2 and 3 of the verify
+    skill: for_test clone evaluated twice is the same loss, and
+    save_persistables -> fresh scope -> load_persistables reproduces
+    it.  Returns the training losses."""
+    import jax
+    import paddle_tpu.fluid as fluid
+    main, startup, test, loss = build_bert(cfg, seq)
+    feed = {k: jax.device_put(v)
+            for k, v in host_batch(cfg, batch, seq).items()}
+    tag = 'bert b%d s%d L%d' % (batch, seq, cfg.layers)
+    exe = fluid.Executor(fluid.XLAPlace(0))
+    ckpt = tempfile.mkdtemp(prefix='chip_smoke_ckpt_')
+    try:
+        with fluid.scope_guard(fluid.Scope()):
+            exe.run(startup)
+            losses, secs, compiled_at = train_steps(exe, main, feed,
+                                                    loss, steps)
+            check_trained(tag, losses, secs, compiled_at)
+            check_dispatch(KERNELS)
+            e1 = _scalar(exe.run(test, feed=feed, fetch_list=[loss]))
+            e2 = _scalar(exe.run(test, feed=feed, fetch_list=[loss]))
+            check(np.isfinite(e1) and e1 == e2,
+                  'for_test clone evaluated twice: %.6f == %.6f'
+                  % (e1, e2))
+            fluid.io.save_persistables(exe, ckpt, main)
+        with fluid.scope_guard(fluid.Scope()):
+            fluid.io.load_persistables(exe, ckpt, main)
+            e3 = _scalar(exe.run(test, feed=feed, fetch_list=[loss]))
+            check(e3 == e1, 'save_persistables -> fresh scope -> '
+                  'load_persistables reproduces the eval loss: %.6f == '
+                  '%.6f' % (e3, e1))
+    finally:
+        shutil.rmtree(ckpt, ignore_errors=True)
+    return losses
+
+
+def phase_attn_dropout(cfg, batch, seq):
+    """The reference-default attn_dropout=0.1: the in-kernel
+    counter-hash mask path, two steps."""
+    losses, secs, _ = train_fresh(cfg, batch, seq, 2)
+    say('attn_dropout=%.1f losses %s; first step %.1f s, second %.1f ms'
+        % (cfg.attn_dropout, ' '.join('%.4f' % v for v in losses),
+           secs[0], secs[1] * 1e3))
+    check(all(np.isfinite(losses)),
+          'attn_dropout=%.1f: two steps, losses finite' % cfg.attn_dropout)
+
+
+def phase_dense_lowerings(cfg, batch, seq, fused_losses):
+    """Something independent, where it is cheap: the same seeded
+    program with the three kernel flags off runs the dense XLA
+    lowerings (jnp.take gather + XLA scatter-add, per-tensor Adam
+    chains).  Its first loss must agree with the fused run's; its
+    second has been through one backward and one Adam update of each
+    kind, so it holds the backward and optimizer kernels to the same
+    tolerance."""
+    with flags_set({'FLAGS_pallas_embedding': False,
+                    'FLAGS_pallas_opt_fuse': False,
+                    'FLAGS_pallas_quant_collective': False}):
+        dense, secs, _ = train_fresh(cfg, batch, seq, 2)
+    say('dense lowerings losses %s (fused %s); first step %.1f s, '
+        'second %.1f ms'
+        % (' '.join('%.4f' % v for v in dense),
+           ' '.join('%.4f' % v for v in fused_losses[:2]),
+           secs[0], secs[1] * 1e3))
+    for i in range(2):
+        check(abs(dense[i] - fused_losses[i]) <=
+              BF16_LOSS_RTOL * abs(dense[i]),
+              'step %d loss fused %.4f vs dense %.4f within rtol %g'
+              % (i + 1, fused_losses[i], dense[i], BF16_LOSS_RTOL))
+
+
+def phase_flash_vs_dense(b=2, t=1024, h=12, d=64, rate=0.1):
+    """Flash attention has no flag to turn off, so hold the kernels to
+    the dense chain directly: forward and all four gradients, with a
+    key bias and in-kernel dropout (both arms draw the same counter-
+    hash mask)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v = (jax.random.normal(kk, (b, t, h, d), jnp.bfloat16)
+               for kk in ks[:3])
+    bias = jax.random.normal(ks[3], (b, t), jnp.float32)
+
+    def run(min_seq):
+        def loss(q, k, v, bias):
+            o = fa.flash_attention(
+                q, k, v, key_bias=bias, min_seq=min_seq,
+                dropout_rate=rate, dropout_seed=jnp.uint32(11))
+            return jnp.sum(o.astype(jnp.float32) ** 2), o
+        (_, o), g = jax.jit(jax.value_and_grad(
+            loss, (0, 1, 2, 3), has_aux=True))(q, k, v, bias)
+        return (o,) + g
+
+    fused, dense = run(0), run(10 ** 9)
+    for name, a, r in zip(('out', 'dq', 'dk', 'dv', 'dbias'), fused,
+                          dense):
+        a, r = np.asarray(a, np.float32), np.asarray(r, np.float32)
+        err = float(np.max(np.abs(a - r)) / (np.max(np.abs(r)) + 1e-9))
+        check(np.isfinite(a).all() and err <= BF16_ELEM_TOL,
+              'flash %s vs dense chain: max err / max |ref| = %.2e <= %g'
+              % (name, err, BF16_ELEM_TOL))
+
+
+def phase_kernels_bert_does_not_reach():
+    """lamb (two launches, per-tensor trust ratio from per-block
+    partials) and the fused adagrad row update compile for the chip but
+    no BERT + Adam step runs them: hold each to its dense lowering on
+    small seeded inputs."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import registry
+    from paddle_tpu.ops.pallas import embedding, fused_optimizer
+    rng = np.random.RandomState(3)
+
+    def f32(*shape):
+        return jnp.asarray(rng.randn(*shape).astype('float32'))
+
+    shapes = [(768, 768), (768,), (768, 2), (3, 5, 7)]
+    ins = {'Param': [f32(*s) for s in shapes],
+           'Grad': [f32(*s) for s in shapes],
+           'Moment1': [f32(*s) for s in shapes],
+           'Moment2': [jnp.abs(f32(*s)) for s in shapes],
+           'LearningRate': [jnp.float32(1e-3 * (i + 1))
+                            for i in range(len(shapes))],
+           'Beta1Pow': [jnp.float32(0.9 ** (i + 1))
+                        for i in range(len(shapes))],
+           'Beta2Pow': [jnp.float32(0.999 ** (i + 1))
+                        for i in range(len(shapes))]}
+    ctx = registry.LowerCtx(0)
+    fused = fused_optimizer.apply('lamb', ctx, ins, {})
+    dense = fused_optimizer._dense('lamb', ctx, ins, {})
+    err = max(float(jnp.max(jnp.abs(a - b)))
+              for slot in ('ParamOut', 'Moment1Out', 'Moment2Out')
+              for a, b in zip(fused[slot], dense[slot]))
+    check(err <= 1e-5, 'fused lamb vs per-tensor dense lamb: max abs '
+          'err %.2e <= 1e-5 over %d tensors' % (err, len(shapes)))
+
+    rows, width, n = 2048, 256, 512
+    ids = jnp.asarray(rng.randint(0, 64, (n,)).astype('int32'))  # dups
+    upd = {'Param': [f32(rows, width)],
+           'Moment': [jnp.abs(f32(rows, width))], 'Ids': [ids],
+           'Grad': [f32(n, width)],
+           'LearningRate': [jnp.float32(0.05)]}
+    fused = embedding.apply_update(ctx, upd, {'epsilon': 1e-6})
+    with flags_set({'FLAGS_pallas_embedding': False}):
+        dense = embedding.apply_update(ctx, upd, {'epsilon': 1e-6})
+    err = max(float(jnp.max(jnp.abs(fused[k][0] - dense[k][0])))
+              for k in ('ParamOut', 'MomentOut'))
+    check(err <= 1e-4, 'fused adagrad row update vs dense scatter + '
+          'adagrad: max abs err %.2e <= 1e-4 (%d ids over 64 rows)'
+          % (err, n))
+
+
+def phase_lenet(batch=512):
+    """One line of record: does the LeNet b512 f32 conv weight-gradient
+    at FLAGS_conv_precision='highest' compile on this chip's compiler,
+    and how long does it take."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu import models
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 42
+    with fluid.program_guard(main, startup):
+        _, _, loss, _ = models.lenet.build()
+        fluid.optimizer.Adam(1e-3).minimize(loss)
+    rng = np.random.RandomState(0)
+    feed = {'img': rng.rand(batch, 1, 28, 28).astype('float32'),
+            'label': rng.randint(0, 10, (batch, 1)).astype('int32')}
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        losses, secs, _ = train_steps(exe, main, feed, loss, 3)
+    check(all(np.isfinite(losses)) and losses[-1] < losses[0],
+          'lenet b%d conv_precision=%s compiled and trains: first step '
+          '(compile + run) %.1f s, loss %.4f -> %.4f'
+          % (batch, fluid.flags.get_flag('FLAGS_conv_precision'),
+             secs[0], losses[0], losses[-1]))
+
+
+def _peak_bytes(devs):
+    return [d.memory_stats()['peak_bytes_in_use'] for d in devs]
+
+
+def phase_four_chips(cfg, global_batch, seq, steps, n=4):
+    """The data-parallel and the sharded path, in this one process over
+    `n` devices, against the single-device run of the same seeded
+    program: (a) with_data_parallel on a dp mesh, (b) dp x mp=2 with
+    __graft_entry__'s column-parallel rule.  First-step losses agree
+    and losses fall; parameters and the batch really lie on every
+    device.  The three kernels of the step dispatch fused in the
+    single-device run and answer dense under either mesh, every time
+    for the counted reason `auto_partitioned` (XLA cannot partition a
+    Mosaic kernel; ops/pallas/common.py dispatch())."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    devs = jax.devices()[:n]
+    host = host_batch(cfg, global_batch, seq)
+
+    def column_parallel(name, shape):
+        # fc weight matrices [in, out] -> column-parallel on 'mp'
+        if len(shape) == 2 and min(shape) >= 8 and '.w' in name:
+            return P(None, 'mp')
+        return None
+
+    def kernel_counts():
+        return np.array([[int(monitor.counter_value(
+            'pallas/%s/%s' % (k, c))) for k in KERNELS] for c in
+            ('dispatch_fused', 'fallback/auto_partitioned')])
+
+    def run(tag, mesh, rule=None):
+        main, startup, _, loss = build_bert(cfg, seq)
+        target, feed = main, host
+        if mesh is not None:
+            target = fluid.CompiledProgram(main).with_data_parallel(
+                loss_name=loss.name).with_mesh(mesh)
+            if rule is not None:
+                target = target.with_param_shardings(rule)
+            feed = {k: jax.device_put(v, NamedSharding(mesh, P('dp')))
+                    for k, v in host.items()}
+            spans = {k: len(v.sharding.device_set)
+                     for k, v in feed.items()}
+            check(set(spans.values()) == {n},
+                  '%s: every feed of the batch lies on %d devices'
+                  % (tag, n))
+        else:
+            feed = {k: jax.device_put(v) for k, v in host.items()}
+        scope = fluid.Scope()
+        # taken after the build, which infers shapes through dispatch()
+        before = kernel_counts()
+        with fluid.scope_guard(scope):
+            exe = fluid.Executor(fluid.XLAPlace(0))
+            exe.run(startup)
+            losses, secs, compiled_at = train_steps(exe, target, feed,
+                                                    loss, steps)
+            check_trained(tag, losses, secs, compiled_at)
+            fused, dense = kernel_counts() - before
+            counted = 'dispatch_fused %s, fallback/auto_partitioned %s ' \
+                'for %s' % (fused, dense, ' / '.join(KERNELS))
+            if mesh is None:
+                check((fused > 0).all() and not dense.any(),
+                      '%s: every kernel dispatched fused: %s'
+                      % (tag, counted))
+            else:
+                check(not fused.any() and (dense > 0).all(),
+                      '%s: every kernel answered dense, counted as '
+                      'auto_partitioned: %s' % (tag, counted))
+            if mesh is not None:
+                params = {
+                    p.name: fluid.core.as_array(scope.find_var(p.name))
+                    for p in main.all_parameters()}
+                on = {k: len(v.sharding.device_set)
+                      for k, v in params.items()}
+                check(set(on.values()) == {n},
+                      '%s: all %d parameters lie on %d devices'
+                      % (tag, len(on), n))
+                if rule is not None:
+                    split = [k for k, v in params.items()
+                             if not v.sharding.is_fully_replicated]
+                    check(split, '%s: %d parameters are split over '
+                          "'mp' (e.g. %s %s)"
+                          % (tag, len(split), split[0],
+                             params[split[0]].sharding.spec))
+        return losses
+
+    single = run('single device b%d s%d' % (global_batch, seq), None)
+    check_dispatch(KERNELS)     # while the last decisions are its own
+    base = _peak_bytes(devs)
+    mp = 2
+    for tag, mesh, rule in (
+            ('dp%d' % n, Mesh(np.array(devs), ('dp',)), None),
+            ('dp%dxmp%d' % (n // mp, mp),
+             Mesh(np.array(devs).reshape(n // mp, mp), ('dp', 'mp')),
+             column_parallel)):
+        losses = run(tag, mesh, rule)
+        check(abs(losses[0] - single[0]) <=
+              BF16_LOSS_RTOL * abs(single[0]),
+              '%s first-step loss %.4f vs single device %.4f within '
+              'rtol %g' % (tag, losses[0], single[0], BF16_LOSS_RTOL))
+    peaks = _peak_bytes(devs)
+    say('peak HBM per device, GB: after single-device run %s; after '
+        'mesh runs %s'
+        % (' '.join('%.2f' % (p / 1e9) for p in base),
+           ' '.join('%.2f' % (p / 1e9) for p in peaks)))
+    # the single-device run leaves devices 1.. untouched; a mesh run
+    # that quietly put everything on device 0 would leave them so
+    check(min(peaks[1:]) > 0.25 * max(peaks),
+          'every device held a real share of a mesh run')
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
+    ap.add_argument('--chips', type=int, choices=(1, 4), default=1)
+    args = ap.parse_args()
+
+    import jax
+    import jaxlib
+    devs = jax.devices()
+    if devs[0].platform != 'tpu':
+        sys.exit('chip_smoke.py needs a TPU chip and found none: '
+                 "jax.devices()[0].platform is %r" % devs[0].platform)
+    if len(devs) < args.chips:
+        sys.exit('chip_smoke.py --chips %d: only %d TPU chip(s) attached'
+                 % (args.chips, len(devs)))
+
+    from paddle_tpu import models
+    from paddle_tpu.fluid import compile_cache
+    _listen()
+    cache_dir = compile_cache.place_jax_cache()
+    try:
+        import libtpu
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = 'unknown'
+    say('jax %s jaxlib %s libtpu %s; %d x %s; jax cache %s'
+        % (jax.__version__, jaxlib.__version__, libtpu_version,
+           len(devs), devs[0].device_kind, cache_dir))
+
+    try:
+        if args.chips == 4:
+            phase_four_chips(
+                models.bert.BertConfig(dropout=0.0, attn_dropout=0.0),
+                global_batch=16, seq=512, steps=4)
+        else:
+            seq, batch = 2048, 4
+            fused = phase_train_eval_roundtrip(
+                models.bert.BertConfig(max_pos=seq, attn_dropout=0.0),
+                batch, seq, steps=8)
+            phase_attn_dropout(
+                models.bert.BertConfig(max_pos=seq, attn_dropout=0.1),
+                batch, seq)
+            phase_dense_lowerings(
+                models.bert.BertConfig(max_pos=seq, attn_dropout=0.0),
+                batch, seq, fused)
+            phase_flash_vs_dense()
+            phase_kernels_bert_does_not_reach()
+            check_dispatch(('embedding_update',))
+            phase_lenet()
+            say('peak HBM %.2f GB of %.2f GB'
+                % (devs[0].memory_stats()['peak_bytes_in_use'] / 1e9,
+                   devs[0].memory_stats()['bytes_limit'] / 1e9))
+    except SmokeFailure as e:
+        sys.exit('chip_smoke FAILED: %s' % e)
+    say('jax compiled %(compiles)d programs; its persistent cache: '
+        '%(cache_hits)d hits, %(cache_misses)d misses' % _JAX_EVENTS)
+    print(json.dumps({'ok': True, 'device': {
+        'platform': devs[0].platform, 'kind': devs[0].device_kind,
+        'count': args.chips}}))
+
+
+if __name__ == '__main__':
+    main()

@@ -1,41 +1,10 @@
-"""jax API compatibility shims shared by fluid/ and ops/.
-
-The runtime targets more than one jax release: ``shard_map`` moved
-from ``jax.experimental.shard_map`` (where replication checking is the
-``check_rep`` kwarg) to ``jax.shard_map`` (``check_vma``).  Callers go
-through :func:`shard_map` here so the collective/ring-attention/MoE
-paths run on either — an AttributeError at shard-map construction
-used to kill every collective program on older jaxlibs before the
-executor's incident capture could even see a step.
-"""
+"""``shard_map`` as fluid/ and ops/ call it: the installed
+``jax.shard_map`` with replication checking off (the fluid runners
+bind their own out_specs; the check only costs trace time)."""
 
 import jax
 
 
 def shard_map(fn, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map(fn)`` with replication checking
-    off (the fluid runners bind their own out_specs; the check only
-    costs trace time)."""
-    sm = getattr(jax, 'shard_map', None)
-    if sm is not None:
-        try:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_vma=False)
-        except TypeError:
-            pass
-        try:
-            # top-level shard_map from the transition window still
-            # spelling the kwarg check_rep: keep checking OFF there
-            # too, not just on the experimental API
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-        except TypeError:
-            return sm(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as esm
-    try:
-        return esm(fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
-    except TypeError:  # pragma: no cover - very old experimental API
-        return esm(fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

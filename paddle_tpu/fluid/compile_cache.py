@@ -26,10 +26,10 @@ Tensor-Processing-Primitives argument, arXiv:2104.05755):
   (jax.experimental.serialize_executable) written atomically
   (tmpfile + os.replace) and read corrupt-tolerantly — a truncated or
   stale entry recompiles, never crashes.  JAX's own persistent
-  compilation cache (``jax_compilation_cache_dir``) is wired to
-  ``<dir>/xla`` underneath, so compiles that bypass the segment store
-  (CompiledStep jits, parallel/collective runners, bucket counters)
-  still dedupe their XLA compile across processes.
+  compilation cache is switched on beside it, so compiles that bypass
+  the segment store (CompiledStep jits, parallel/collective runners,
+  bucket counters) still dedupe their XLA compile across processes;
+  WHERE it lives is ``place_jax_cache()``'s rule, not this flag's.
 
 - a background ``ThreadPoolExecutor`` (``FLAGS_compile_threads``) that
   compiles segments concurrently; results are delivered via futures so
@@ -57,6 +57,59 @@ FORMAT_VERSION = 1
 
 _PICKLE_MAGIC = b'ptcc1\n'
 
+
+
+# JAX's persistent cache announces a hit with this monitoring event, on
+# the compiling thread; compile_lowered() counts them around ONE
+# compile (per thread: the warmup pool compiles concurrently).
+_JAX_CACHE_HIT = '/jax/compilation_cache/cache_hits'
+_hits = threading.local()
+_listener_lock = threading.Lock()
+_listening = False
+
+
+def _on_jax_event(event, **_):
+    if event == _JAX_CACHE_HIT:
+        _hits.n = getattr(_hits, 'n', 0) + 1
+
+
+def compile_lowered(lowered):
+    """``lowered.compile()`` for an executable bound for the segment
+    store -> ``(compiled, from_jax_cache)``.  The bit travels WITH the
+    executable (build() -> obtain()/submit() -> disk_store()): one JAX
+    re-loaded from its own persistent cache re-serializes to a payload
+    that loads cleanly and then fails at its first dispatch
+    ("Function ... not found", CPU backend, jaxlib 0.9.0), so the
+    store must not publish it."""
+    global _listening
+    with _listener_lock:
+        if not _listening:
+            import jax
+            jax.monitoring.register_event_listener(_on_jax_event)
+            _listening = True
+    before = getattr(_hits, 'n', 0)
+    compiled = lowered.compile()
+    return compiled, getattr(_hits, 'n', 0) != before
+
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def place_jax_cache():
+    """The one rule for where JAX's persistent compilation cache lives
+    (chip_smoke.py, bench.py and the segment store all call this and
+    nothing else sets the directory): where JAX_COMPILATION_CACHE_DIR
+    is set, JAX has already read it and no code moves it; otherwise
+    ``<checkout>/.jax_cache``, computed from this file's location — the
+    path is part of every cache key, so it never derives from a
+    temporary name, a pid or the clock.  Returns the directory."""
+    d = os.environ.get('JAX_COMPILATION_CACHE_DIR')
+    if not d:
+        import jax
+        d = os.path.join(_CHECKOUT, '.jax_cache')
+        jax.config.update('jax_compilation_cache_dir', d)
+    return d
 
 class LRUCache(object):
     """Dict-shaped LRU used for the plan cache, per-segment executable
@@ -291,7 +344,7 @@ class CompilePlane(object):
                 or 256))
         self._pool = None
         self._warmed = False
-        self._jax_cache_dir = None
+        self._wired_dir = None
         self._dir_memo = None   # (raw flag value, normalized path)
 
     def note_out_specs(self, fp, out_specs):
@@ -309,8 +362,9 @@ class CompilePlane(object):
     def cache_dir(self):
         """The persistent store directory, or None.  Read per call so
         set_flags({'FLAGS_compile_cache_dir': ...}) takes effect
-        immediately; wires jax's own persistent cache on first sight
-        of a directory.  The normalization is memoized on the raw flag
+        immediately; switches jax's own persistent cache on at first
+        sight of a directory.  The normalization is memoized on the
+        raw flag
         value — this runs on the (plane-active) step path."""
         raw = get_flag('FLAGS_compile_cache_dir') or None
         if not raw:
@@ -319,36 +373,32 @@ class CompilePlane(object):
         if memo is not None and memo[0] == raw:
             return memo[1]
         d = os.path.abspath(os.path.expanduser(str(raw)))
-        if d != self._jax_cache_dir:
+        if d != self._wired_dir:
             self._wire_jax_cache(d)
         self._dir_memo = (raw, d)
         return d
 
     def _wire_jax_cache(self, d):
         with self._lock:
-            if d == self._jax_cache_dir:
+            if d == self._wired_dir:
                 return
             try:
                 os.makedirs(os.path.join(d, 'segments'), exist_ok=True)
-                xla_dir = os.path.join(d, 'xla')
-                os.makedirs(xla_dir, exist_ok=True)
-                import jax
-                jax.config.update('jax_compilation_cache_dir', xla_dir)
-                # small programs compile in ms; cache them anyway — the
-                # point is process-restart latency, not compile CPU
-                jax.config.update(
-                    'jax_persistent_cache_min_compile_time_secs', 0.0)
-                try:
-                    jax.config.update(
-                        'jax_persistent_cache_min_entry_size_bytes', -1)
-                except Exception:
-                    pass  # older jaxlib: size gate absent
-                self._jax_cache_dir = d
-            except Exception as e:  # unwritable dir etc: run uncached
+            except OSError as e:  # unwritable dir: run uncached
                 monitor.add('executor/compile_cache_errors')
                 import warnings
                 warnings.warn('compile cache dir %r unusable: %s'
                               % (d, e))
+                return
+            import jax
+            place_jax_cache()
+            # small programs compile in ms; cache them anyway — the
+            # point is process-restart latency, not compile CPU
+            jax.config.update(
+                'jax_persistent_cache_min_compile_time_secs', 0.0)
+            jax.config.update(
+                'jax_persistent_cache_min_entry_size_bytes', -1)
+            self._wired_dir = d
 
     @property
     def active(self):
@@ -377,12 +427,18 @@ class CompilePlane(object):
         d = self.cache_dir()
         return os.path.join(d, 'segments', fp + '.pkl') if d else None
 
-    def disk_store(self, fp, compiled, out_specs=None):
+    def disk_store(self, fp, compiled, out_specs=None,
+                   from_jax_cache=False):
         """Serialize one AOT executable atomically; failures (backend
         without serialization support, read-only dir) degrade to the
         jax-level cache, never to an error."""
         path = self._entry_path(fp)
         if path is None:
+            return False
+        if from_jax_cache:
+            # compile_lowered()'s word on `compiled`; JAX's own cache
+            # holds it, so a restart still skips the compile
+            monitor.add('executor/compile_cache_skipped_jax_hit')
             return False
         try:
             from jax.experimental.serialize_executable import (
@@ -393,11 +449,18 @@ class CompilePlane(object):
             # .compile() itself re-loaded from the XLA-level persistent
             # cache serializes to a payload whose symbols cannot be
             # re-loaded (observed on the CPU backend) — writing it
-            # would poison the store for every future process
-            deserialize_and_load(payload, in_tree, out_tree)
+            # would poison the store for every future process.  The
+            # reload is over the devices the executable was built for:
+            # by default deserialize_and_load spreads it over EVERY
+            # device of the backend and a one-device executable is
+            # refused ("8 shards, got: [1]")
+            devices = compiled.runtime_executable().local_devices()
+            deserialize_and_load(payload, in_tree, out_tree,
+                                 execution_devices=devices)
             blob = _PICKLE_MAGIC + pickle.dumps(
                 {'fp': fp, 'payload': payload, 'in_tree': in_tree,
-                 'out_tree': out_tree, 'out_specs': out_specs},
+                 'out_tree': out_tree, 'out_specs': out_specs,
+                 'device_ids': [d.id for d in devices]},
                 protocol=pickle.HIGHEST_PROTOCOL)
             fd, tmp = tempfile.mkstemp(dir=os.path.dirname(path),
                                        prefix='.tmp_' + fp[:8])
@@ -434,8 +497,12 @@ class CompilePlane(object):
                     raise ValueError('fingerprint mismatch')
                 from jax.experimental.serialize_executable import \
                     deserialize_and_load
+                import jax
+                by_id = {d.id: d for d in jax.devices()}
                 compiled = deserialize_and_load(
-                    rec['payload'], rec['in_tree'], rec['out_tree'])
+                    rec['payload'], rec['in_tree'], rec['out_tree'],
+                    execution_devices=[by_id[i]
+                                       for i in rec['device_ids']])
             if with_specs:
                 return compiled, rec.get('out_specs')
             return compiled
@@ -462,7 +529,8 @@ class CompilePlane(object):
         """The run-path resolution order: memory (hit), in-flight
         future (block on THIS segment only), disk (deserialize), else
         `build()` (trace+compile) and publish both layers.  `build`
-        returns (compiled, out_specs_or_None)."""
+        returns (compiled, out_specs_or_None, from_jax_cache) — the
+        last as compile_lowered() reported it."""
         from concurrent.futures import Future
         v = self.lookup(fp)
         if v is not None and not isinstance(v, Future):
@@ -497,11 +565,11 @@ class CompilePlane(object):
                 comms.record_memory('fp:%s' % fp[:12], ex)
                 return ex
             monitor.add('executor/compile_cache_disk_miss')
-        ex, out_specs = build()
+        ex, out_specs, from_jax_cache = build()
         self.store(fp, ex)
         self.note_out_specs(fp, out_specs)
         if disk:
-            self.disk_store(fp, ex, out_specs)
+            self.disk_store(fp, ex, out_specs, from_jax_cache)
         return ex
 
     def submit(self, fp, build, disk=True):
@@ -532,12 +600,12 @@ class CompilePlane(object):
                         comms.record_memory('fp:%s' % fp[:12], ex)
                         return
                     monitor.add('executor/compile_cache_disk_miss')
-                ex, out_specs = build()
+                ex, out_specs, from_jax_cache = build()
                 fut.set_result(ex)
                 self.store(fp, ex)
                 self.note_out_specs(fp, out_specs)
                 if disk:
-                    self.disk_store(fp, ex, out_specs)
+                    self.disk_store(fp, ex, out_specs, from_jax_cache)
             except BaseException as e:
                 fut.set_exception(e)
 

@@ -62,9 +62,15 @@ class XLAPlace(Place):
         # PROCESS-LOCAL device index, matching the reference semantics
         # where CUDAPlace(i) is trainer-local GPU i (each NCCL2-mode
         # trainer process owns its own device numbering).  On a
-        # single-process runtime local == global.
+        # single-process runtime local == global.  An index past the
+        # local devices is an error: wrapping it would put a program
+        # that asked for a second chip on the first.
         devs = jax.local_devices()
-        return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                'XLAPlace(%d): this process has %d local %s device(s)'
+                % (self.device_id, len(devs), devs[0].platform))
+        return devs[self.device_id]
 
 
 # Compatibility alias: existing fluid scripts use CUDAPlace.

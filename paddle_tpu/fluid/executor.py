@@ -136,10 +136,7 @@ def _uninitialized(name):
 # _ArrayImpl` costs ~60ns where `isinstance(v, jax.Array)` pays the
 # ABC __instancecheck__ (~1us) — per name per step, that dominates the
 # bind at a few hundred parameters
-try:
-    from jax._src.array import ArrayImpl as _ArrayImpl
-except Exception:  # pragma: no cover - jax internals moved
-    _ArrayImpl = jax.Array
+from jax._src.array import ArrayImpl as _ArrayImpl
 
 _process_default_device = None
 
@@ -854,7 +851,7 @@ def _wpg_partition(segment):
     see identical masks), but XLA schedules the backward as one graph
     — the hand-written-JAX shape.  Measured motivation: BERT-s2048 at
     byte/FLOP parity with its hand-JAX ceiling still ran ~10% slower
-    on a diffuse small-fusion tail (BENCHMARKS.md round 4).
+    on a diffuse small-fusion tail (pre-round reading).
 
     Eligible programs may contain control flow (while/conditional_block
     lower to differentiable masked scans / lax.cond when they carry
@@ -1140,8 +1137,8 @@ def _aot_build(seg, wpg, state_specs, data_specs, device=None):
     `device` pins the executable to the executor's place (the lazily-
     jitted path compiles inside jax.default_device(device); the AOT
     build must match or a non-default-place executor would get a
-    device-0 executable).  Returns (compiled, out_specs) for the
-    plane's disk entry."""
+    device-0 executable).  Returns (compiled, out_specs,
+    from_jax_cache) for the plane's disk entry."""
     import contextlib
     import numpy as _np
     t0 = _time_mod.perf_counter()
@@ -1153,7 +1150,7 @@ def _aot_build(seg, wpg, state_specs, data_specs, device=None):
         lowered = jax.jit(fn, donate_argnums=(1,)).lower(
             _step_spec(), state_specs, data_specs)
         out_info = lowered.out_info
-        compiled = lowered.compile()
+        compiled, from_jax_cache = compile_cache.compile_lowered(lowered)
     t1 = _time_mod.perf_counter()
     monitor.add('executor/aot_compiles')
     monitor.add('executor/segments_lowered')
@@ -1177,7 +1174,7 @@ def _aot_build(seg, wpg, state_specs, data_specs, device=None):
     out_specs = {n: (tuple(int(s) for s in v.shape),
                      _np.dtype(v.dtype).str)
                  for n, v in out_info.items()}
-    return compiled, out_specs
+    return compiled, out_specs, from_jax_cache
 
 
 def _specs_from_args(state, data):
@@ -1194,10 +1191,7 @@ def _specs_from_args(state, data):
             {n: spec(v) for n, v in data.items()})
 
 
-try:
-    from jax.core import Tracer as _Tracer
-except Exception:  # pragma: no cover - jax internals moved
-    _Tracer = ()
+from jax.core import Tracer as _Tracer
 
 
 def _any_tracer(step, state, data):
@@ -1650,7 +1644,8 @@ class Executor(object):
                           _ctx=_dev_ctx):
                     t0 = _time_mod.perf_counter()
                     with _ctx():
-                        compiled = _lowered.compile()
+                        compiled, from_jax_cache = \
+                            compile_cache.compile_lowered(_lowered)
                     t1 = _time_mod.perf_counter()
                     monitor.add('executor/aot_compiles')
                     monitor.observe(
@@ -1658,7 +1653,7 @@ class Executor(object):
                     # background-pool span: thread-aware, shows the
                     # warmup futures overlapping the first steps
                     _trace.record('warmup_compile', t0, t1)
-                    return compiled, _specs
+                    return compiled, _specs, from_jax_cache
 
                 fut = plane.submit(fp, build)
                 from concurrent.futures import Future
@@ -2141,7 +2136,13 @@ class Executor(object):
         if host_part:
             with _trace.span('feed_h2d', nbytes=nbytes,
                              vars=len(host_part)):
-                put = jax.device_put(host_part, device)
+                # on the default device, stage UNCOMMITTED (like the
+                # state startup leaves in the scope): a committed feed
+                # makes step 1's outputs committed, step 2 then binds
+                # committed state and jit compiles the segment again
+                put = jax.device_put(
+                    host_part,
+                    None if _is_default_device(device) else device)
             monitor.add('executor/h2d_bytes_async', nbytes)
             for k, a in put.items():
                 # pointer-donation claim only where the plan proves a

@@ -28,8 +28,11 @@ _DEFAULTS = {
     # layouts), so persistent state lives in the layout the compute
     # wants.  Off by default: measured ~0 gain on the ResNet headline
     # (the boundary casts are layout-forced for any f32-master-weight
-    # program) and AUTO-layout executables break when reloaded from the
-    # persistent XLA compile cache on this backend (see BENCHMARKS.md)
+    # program; pre-round reading, not measured on current code).  The
+    # installed jax takes Layout.AUTO only for arguments that carry no
+    # layout of their own: host feeds and uncommitted arrays work (the
+    # executor stages feeds uncommitted on the default device), a
+    # committed device array fed by the caller is refused by jit.
     'FLAGS_segment_auto_layout': False,
     # Lower eligible train segments as forward ops + ONE jax.vjp over
     # the whole forward region instead of per-op synthesized grad
@@ -37,7 +40,7 @@ _DEFAULTS = {
     # grads are vjp of the same lowerings and stochastic lowerings key
     # RNG on (op_seed, step) — but XLA schedules the backward as one
     # graph, the hand-written-JAX shape (BERT-long 144.7 -> 119.8
-    # ms/step, BENCHMARKS.md round 4).  DEFAULT ON since round 5;
+    # ms/step, pre-round reading).  DEFAULT ON since round 5;
     # ineligible segments (recompute programs, consumed intermediate
     # grads, split forwards) automatically keep the per-op path.
     'FLAGS_whole_program_grad': True,
@@ -294,11 +297,9 @@ _DEFAULTS = {
     'FLAGS_rpc_backoff_max_ms': 2000,
     # f32 conv MXU precision: 'highest' (6-pass bf16 emulation,
     # reference-accurate fp32 — the default), 'high' (3-pass), or
-    # 'default' (single-pass bf16 inputs).  Escape hatch for an XLA
-    # backend pathology: multi-pass weight-gradient convs at certain
-    # shapes (e.g. LeNet b512/b256/b128 dW with a fused cotangent
-    # producer) hang this service's compiler — see BENCHMARKS.md
-    # round-4 and tools/repro_conv_wedge.py.
+    # 'default' (single-pass bf16 inputs): accuracy against speed.
+    # (LeNet b512 at 'highest' compiles on the v5e: chip_smoke.py
+    # prints the line.)
     'FLAGS_conv_precision': 'highest',
     # windowed history plane (fluid/timeseries.py): on, the executor's
     # step boundary and the rank-0 aggregator's heartbeat each append
@@ -394,7 +395,10 @@ _DEFAULTS = {
     # adam/adamw/lamb ops in a segment collapse into one fused_<type>
     # launch over flattened parameter slabs (lamb's per-param
     # trust-ratio reduction included).  Off restores the per-param
-    # elementwise chains bit for bit.
+    # elementwise chains bit for bit.  First chip reading (PR 21,
+    # smoke, one run): with this and FLAGS_pallas_embedding on,
+    # BERT-base b4 s2048 steps in 158 ms against 109 ms with both off
+    # — repair from a trace or delete is ROADMAP S1's next issue.
     'FLAGS_pallas_opt_fuse': True,
     # minimum run length before the optimizer grouping pays for
     # itself (packing/unpacking a single tensor buys nothing)
@@ -403,7 +407,8 @@ _DEFAULTS = {
     # the Pallas row-gather kernel (scatter-add custom-vjp backward),
     # and AdagradOptimizer rewrites eligible embedding updates into
     # one fused_emb_update over only the touched rows, replacing the
-    # dense scatter + full-table update lowering.
+    # dense scatter + full-table update lowering.  Slower than the
+    # dense lowering on the chip today: see FLAGS_pallas_opt_fuse.
     'FLAGS_pallas_embedding': True,
     # vocab-rows floor for the embedding kernel: small tables stay on
     # the dense gather (bit-exact) where XLA already wins

@@ -222,7 +222,8 @@ def analysis_fields(compiled):
               'generated_code_bytes'):
         out[k] = out[k] or 0.0
     if out['peak_bytes'] is None:
-        # CPU XLA reports no peak; arg+out+temp is the live-set bound
+        # a backend that reports no peak: arg+out+temp is the
+        # live-set bound
         out['peak_bytes'] = (out['argument_bytes'] +
                              out['output_bytes'] + out['temp_bytes'])
     return out

@@ -31,7 +31,8 @@ class BertConfig(object):
         self.attn_dropout = dropout if attn_dropout is None \
             else attn_dropout
         self.use_flash = use_flash
-        # measured on one v5e-class chip (BENCHMARKS.md): the batched
+        # pre-round readings on one v5e-class chip (not measured on
+        # current code): the batched
         # round-3 tuned kernels (bf16 MXU dots, 512/1024 blocks —
         # tools/bench_flash.py): flash beats the naive XLA chain from
         # seq 512 up (512: 6.3 vs 8.2 ms; 1024: 11.3 vs 21.5;

@@ -15,8 +15,10 @@ from .registry import register
 @register('fused_multihead_attention', stochastic=True)
 def fused_multihead_attention(ctx, ins, attrs):
     """Q,K,V: [B, T, H, D] (+ optional KeyBias [B, T] additive score
-    bias, e.g. a padding mask) -> Out [B, T, H, D] via the Pallas flash
-    attention kernels, forward and backward (interpret mode off-TPU).
+    bias, e.g. a padding mask) -> Out [B, T, H, D] via
+    flash_attention(): the Pallas kernels forward and backward on a
+    TPU, the dense chain elsewhere and under the GSPMD runner's mesh
+    (ops/pallas/common.py dispatch()).
 
     attrs['dropout_rate'] > 0 applies attention-probability dropout
     INSIDE the kernels (reference default: dropout around softmax,
@@ -33,10 +35,10 @@ def fused_multihead_attention(ctx, ins, attrs):
     seed = ctx.dropout_seed(attrs) if rate else None
     if seed is None:
         rate = 0.0
-    return {'Out': [flash_attention(q, k, v,
-                                    causal=attrs.get('causal', False),
-                                    key_bias=bias, dropout_rate=rate,
-                                    dropout_seed=seed)]}
+    return {'Out': [flash_attention(
+        q, k, v, causal=attrs.get('causal', False), key_bias=bias,
+        dropout_rate=rate, dropout_seed=seed,
+        auto_partitioned=ctx.auto_partitioned)]}
 
 
 @register('fused_elemwise_activation')

@@ -19,9 +19,8 @@ from .registry import register
 def _f32_conv_precision():
     """MXU algorithm for f32 convs, from FLAGS_conv_precision:
     'highest' matches reference fp32 accuracy (6-pass bf16 emulation);
-    'default'/'high' are the escape hatch for the backend's multi-pass
-    dW-conv compile hang (BENCHMARKS.md round-4,
-    tools/repro_conv_wedge.py)."""
+    'high' (3-pass) and 'default' (single-pass bf16) trade accuracy
+    for speed."""
     try:
         from ..fluid.flags import get_flag
         name = str(get_flag('FLAGS_conv_precision', 'highest')).lower()
@@ -373,7 +372,7 @@ def _ln_fwd_rule(x2, scale, bias, eps):
     # rstd — the lean saved set the analytic backward needs.  Letting
     # jax.vjp differentiate mean/var instead keeps several full f32
     # activation tensors alive per LN: on BERT-large-context that was
-    # +2.7 GB/layer of HBM traffic (BENCHMARKS.md round 4).
+    # +2.7 GB/layer of HBM traffic (pre-round reading).
     rstd = jax.lax.rsqrt(v + eps)
     return y, (xhat.astype(x2.dtype), rstd, scale, bias)
 

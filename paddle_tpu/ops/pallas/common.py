@@ -41,7 +41,8 @@ KERNELS = {}
 _LAST = {}
 
 _FALLBACK_REASONS = ('flag_off', 'off_tpu', 'below_floor',
-                     'vmem_over_budget', 'dtype', 'layout')
+                     'vmem_over_budget', 'dtype', 'layout',
+                     'auto_partitioned')
 
 
 def register_kernel(name, dense_fallback, has_vjp=False, doc='',
@@ -83,11 +84,10 @@ def covering_kernel(op_types):
 
 
 def on_tpu():
-    try:
-        return jax.devices()[0].platform.startswith('tpu') or \
-            'TPU' in str(jax.devices()[0])
-    except Exception:
-        return False
+    """True iff JAX's default backend is a TPU.  Raises what JAX
+    raises when no backend comes up: a program that cannot reach its
+    chip must say so, not be handed the dense path."""
+    return jax.devices()[0].platform == 'tpu'
 
 
 def force_fused():
@@ -138,10 +138,8 @@ def block_sizes(t, block_q, block_k, d=64, itemsize=2):
 
 
 def record_dispatch(kernel, fused, reason, interpret=False):
-    """Account one dispatch decision: counters + last-decision entry.
-    Used directly by kernels with a bespoke gate (flash attention's
-    historical always-pallas-even-off-TPU contract); everything else
-    goes through dispatch()."""
+    """Account one dispatch decision: counters + last-decision entry
+    (dispatch()'s bookkeeping half)."""
     try:
         from ...fluid import monitor
         monitor.add('pallas/%s/dispatch_%s'
@@ -154,7 +152,8 @@ def record_dispatch(kernel, fused, reason, interpret=False):
                      'reason': reason, 'interpret': bool(interpret)}
 
 
-def dispatch(kernel, enabled, checks=(), force=None):
+def dispatch(kernel, enabled, checks=(), force=None,
+             auto_partitioned=False):
     """The auto-dispatch gate.  ``checks`` is a sequence of
     ``(reason, ok)`` pairs evaluated in order (reasons from
     _FALLBACK_REASONS: 'below_floor', 'vmem_over_budget', 'dtype',
@@ -163,10 +162,20 @@ def dispatch(kernel, enabled, checks=(), force=None):
     runs under the Pallas interpreter (off-TPU force mode).
 
     Gate order: flag first (an off flag falls back even on TPU), then
-    the kernel's own checks, then the platform.  ``force`` (default
-    FLAGS_pallas_force) only overrides the PLATFORM gate — a kernel
-    whose shape/dtype gates fail stays dense even under force, so
-    forced parity runs still exercise the real gates."""
+    the kernel's own checks, then ``auto_partitioned``, then the
+    platform.  ``force`` (default FLAGS_pallas_force) only overrides
+    the PLATFORM gate — a kernel whose shape/dtype gates fail stays
+    dense even under force, so forced parity runs still exercise the
+    real gates.
+
+    ``auto_partitioned`` is the caller's word (an op lowering passes
+    ``ctx.auto_partitioned``) that this trace is ONE program XLA will
+    partition over a multi-device mesh — the GSPMD runner of
+    with_data_parallel / with_mesh.  XLA cannot partition a Mosaic
+    kernel ("Mosaic kernels cannot be automatically partitioned.
+    Please wrap the call in a shard_map"), so the dense lowering —
+    which it can — is the only one that compiles there.  Code inside a
+    shard_map is per-device and passes nothing."""
     if not enabled:
         record_dispatch(kernel, False, 'flag_off')
         return False, False
@@ -176,6 +185,9 @@ def dispatch(kernel, enabled, checks=(), force=None):
         if not ok:
             record_dispatch(kernel, False, reason)
             return False, False
+    if auto_partitioned:
+        record_dispatch(kernel, False, 'auto_partitioned')
+        return False, False
     if on_tpu():
         record_dispatch(kernel, True, 'tpu')
         return True, False

@@ -1,8 +1,9 @@
 """Fused sparse embedding lookup + update kernels (PS/recsys path).
 
 Forward: one grid step per looked-up id; the scalar-prefetch index map
-DMAs exactly the touched row of the [V, D] table into VMEM
-(``lambda i, ids: (ids[i], 0)``) — XLA's gather is fine, but the
+DMAs exactly the touched row of the table into VMEM (``_row_spec``:
+a (1, 1, D) block of the [V, 1, D] view, row ``ids[i]``) — XLA's
+gather is fine, but the
 backward's dense lowering is not: ``jnp.zeros_like(w).at[ids].add(g)``
 materializes a full [V, D] scatter the size of the table per step.
 
@@ -80,6 +81,16 @@ def _lookup(w, ids, interpret):
     return _gather(w, ids, interpret)
 
 
+def _row_spec(d, index):
+    """One [1, 1, D] row of a table viewed as [N, 1, D].  Mosaic
+    refuses a (1, D) block of a 2-D [N, D] array (second-to-last block
+    dim neither a multiple of 8 nor the array's); with the unit middle
+    axis the block's last two dims EQUAL the array's, which it admits.
+    ``index(i, *prefetch_refs)`` names the row."""
+    return pl.BlockSpec((1, 1, d),
+                        lambda i, *refs: (index(i, *refs), 0, 0))
+
+
 def _gather(w, ids, interpret):
     n, (v, d) = ids.shape[0], w.shape
     return pl.pallas_call(
@@ -87,11 +98,10 @@ def _gather(w, ids, interpret):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),
-            in_specs=[pl.BlockSpec((1, d), lambda i, ids_ref:
-                                   (ids_ref[i], 0))],
-            out_specs=pl.BlockSpec((1, d), lambda i, ids_ref: (i, 0))),
-        out_shape=jax.ShapeDtypeStruct((n, d), w.dtype),
-        interpret=interpret)(ids, w)
+            in_specs=[_row_spec(d, lambda i, ids_ref: ids_ref[i])],
+            out_specs=_row_spec(d, lambda i, ids_ref: i)),
+        out_shape=jax.ShapeDtypeStruct((n, 1, d), w.dtype),
+        interpret=interpret)(ids, w[:, None, :])[:, 0, :]
 
 
 def scatter_add(nrows, ids, g, interpret):
@@ -101,19 +111,18 @@ def scatter_add(nrows, ids, g, interpret):
     order = jnp.argsort(ids)
     sids = jnp.take(ids, order)
     sg = jnp.take(g, order, axis=0)
-    row = pl.BlockSpec((1, d), lambda i, sids_ref: (sids_ref[i], 0))
+    row = _row_spec(d, lambda i, sids_ref: sids_ref[i])
     return pl.pallas_call(
         _scatter_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n,),
-            in_specs=[pl.BlockSpec((1, d), lambda i, sids_ref:
-                                   (i, 0)), row],
+            in_specs=[_row_spec(d, lambda i, sids_ref: i), row],
             out_specs=row),
-        out_shape=jax.ShapeDtypeStruct((nrows, d), g.dtype),
+        out_shape=jax.ShapeDtypeStruct((nrows, 1, d), g.dtype),
         input_output_aliases={2: 0},
-        interpret=interpret)(sids, sg,
-                             jnp.zeros((nrows, d), g.dtype))
+        interpret=interpret)(sids, sg[:, None, :],
+                             jnp.zeros((nrows, 1, d), g.dtype))[:, 0, :]
 
 
 def _lookup_fwd(w, ids, interpret):
@@ -129,11 +138,12 @@ def _lookup_bwd(interpret, res, g):
 _lookup.defvjp(_lookup_fwd, _lookup_bwd)
 
 
-def embedding_lookup(w, ids, padding_idx=-1):
+def embedding_lookup(w, ids, padding_idx=-1, auto_partitioned=False):
     """Auto-dispatched [V, D] row gather for arbitrary-rank ids ->
     ids.shape + (D,).  Padding masking stays an XLA epilogue on both
     paths (bit-identical; its vjp zeroes padding cotangents before
-    they reach the scatter)."""
+    they reach the scatter).  ``auto_partitioned``: see
+    common.dispatch()."""
     from ...fluid.flags import get_flag
     v, d = w.shape
     n = int(np.prod(ids.shape)) if ids.shape else 1
@@ -148,7 +158,7 @@ def embedding_lookup(w, ids, padding_idx=-1):
             # on real TPUs keep the lane dim aligned; the interpreter
             # has no layout constraint
             ('layout', d % 128 == 0 or not common.on_tpu()),
-        ))
+        ), auto_partitioned=auto_partitioned)
     if not fused:
         return _dense_lookup(w, ids, padding_idx)
     # jnp.take clips out-of-range ids; mirror it so the paths agree
@@ -162,7 +172,7 @@ def embedding_lookup(w, ids, padding_idx=-1):
 
 # ------------------------------------------------- fused row update
 
-def _update_kernel(sids_ref, g_ref, lr_ref, w_ref, m_ref,
+def _update_kernel(sids_ref, lr_ref, g_ref, w_ref, m_ref,
                    wo_ref, mo_ref, acc_ref, *, epsilon):
     i = pl.program_id(0)
     n = pl.num_programs(0)
@@ -178,8 +188,8 @@ def _update_kernel(sids_ref, g_ref, lr_ref, w_ref, m_ref,
     # visit; intermediate visits pass the original row through (the
     # out block is only flushed to HBM when the id changes)
     m_new = m_ref[...] + acc * acc
-    w_new = w_ref[...] - lr_ref[0, 0] * acc / (jnp.sqrt(m_new) +
-                                               epsilon)
+    w_new = w_ref[...] - lr_ref[0] * acc / (jnp.sqrt(m_new) +
+                                            epsilon)
     wo_ref[...] = jnp.where(last, w_new, w_ref[...])
     mo_ref[...] = jnp.where(last, m_new, m_ref[...])
 
@@ -188,27 +198,30 @@ def _fused_rows_update(w, mom, ids, g, lr, epsilon, interpret):
     """Apply adagrad to only the rows named by ids (duplicates merged
     by summing their grads first — the dense scatter-add semantics).
     Untouched rows ride through via input/output aliasing."""
-    n, d = g.shape
+    (n, d), v = g.shape, w.shape[0]
     order = jnp.argsort(ids)
     sids = jnp.take(ids, order)
     sg = jnp.take(g, order, axis=0)
-    lr2 = lr.reshape(()).astype(jnp.float32).reshape(1, 1)
-    row = pl.BlockSpec((1, d), lambda i, sids_ref: (sids_ref[i], 0))
-    return pl.pallas_call(
+    # the learning rate rides in SMEM beside the sorted ids (a second
+    # scalar-prefetch operand): the kernel reads it as a scalar
+    lr1 = lr.reshape((1,)).astype(jnp.float32)
+    row = _row_spec(d, lambda i, sids_ref, lr_ref: sids_ref[i])
+    w_out, m_out = pl.pallas_call(
         functools.partial(_update_kernel, epsilon=epsilon),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(n,),
             in_specs=[
-                pl.BlockSpec((1, d), lambda i, sids_ref: (i, 0)),
-                pl.BlockSpec((1, 1), lambda i, sids_ref: (0, 0)),
+                _row_spec(d, lambda i, sids_ref, lr_ref: i),
                 row, row],
             out_specs=[row, row],
-            scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct(w.shape, w.dtype),
-                   jax.ShapeDtypeStruct(mom.shape, mom.dtype)],
+            scratch_shapes=[pltpu.VMEM((1, 1, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((v, 1, d), w.dtype),
+                   jax.ShapeDtypeStruct((v, 1, d), mom.dtype)],
         input_output_aliases={3: 0, 4: 1},
-        interpret=interpret)(sids, sg, lr2, w, mom)
+        interpret=interpret)(sids, lr1, sg[:, None, :],
+                             w[:, None, :], mom[:, None, :])
+    return w_out[:, 0, :], m_out[:, 0, :]
 
 
 def apply_update(ctx, ins, attrs):
@@ -245,7 +258,7 @@ def apply_update(ctx, ins, attrs):
             ('dtype', w.dtype == jnp.float32 and
              mom.dtype == jnp.float32),
             ('layout', d % 128 == 0 or not common.on_tpu()),
-        ))
+        ), auto_partitioned=ctx.auto_partitioned)
     if fused:
         w_out, m_out = _fused_rows_update(
             w, mom, flat_ids, flat_g, ins['LearningRate'][0],

@@ -16,8 +16,9 @@ An optional additive key bias [B, T] (padding masks, per-key biases)
 is applied to the scores inside the kernels — the BERT input-mask path
 (models/bert.py) — and receives a real gradient so learned biases work.
 
-On non-TPU platforms the kernels run in interpreter mode so tests cover
-them everywhere.
+Both public entries go through common.dispatch(): compiled on a TPU,
+the dense XLA chain anywhere else, and the Pallas interpreter only
+under FLAGS_pallas_force (tests).
 """
 
 import functools
@@ -35,8 +36,8 @@ from jax.experimental import pallas as pl
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
 
-# Measured flash-vs-naive crossover (fwd+bwd, BENCHMARKS.md round-3/4
-# tables): below this sequence length XLA's fused dense chain fits
+# Measured flash-vs-naive crossover (fwd+bwd; pre-round reading, not
+# measured on current code): below this sequence length XLA's fused dense chain fits
 # VMEM outright and beats the kernel, so flash_attention() auto-selects
 # the dense path — the public entry never ships the regression pocket.
 FLASH_MIN_SEQ = 512
@@ -47,7 +48,6 @@ FLASH_MIN_SEQ = 512
 from . import common as _common  # noqa: E402
 
 VMEM_BUDGET_BYTES = _common.VMEM_BUDGET_BYTES
-_on_tpu = _common.on_tpu
 _vmem_estimate = _common.vmem_estimate
 _block_sizes = _common.block_sizes
 
@@ -734,28 +734,28 @@ def _dense_reference(q, k, v, causal):
         q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_lse(q, k, v, bias, seed, h, causal, rate):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash_lse(q, k, v, bias, seed, h, causal, rate, interpret):
     """(o, lse): lse is a first-class differentiable output so ring
     attention can merge per-block flash results (parallel/
-    ring_attention.py ring_flash_attention)."""
-    interpret = not _on_tpu()
+    ring_attention.py ring_flash_attention).  ``interpret`` is the one
+    common.dispatch() decision the public entry made; forward and
+    backward kernels all read it."""
     return _flash_fwd(q, k, v, bias, seed, h, causal, DEFAULT_BLOCK_Q,
                       DEFAULT_BLOCK_K, interpret, rate)
 
 
-def _flash_lse_fwd_rule(q, k, v, bias, seed, h, causal, rate):
-    interpret = not _on_tpu()
+def _flash_lse_fwd_rule(q, k, v, bias, seed, h, causal, rate,
+                        interpret):
     o, lse = _flash_fwd(q, k, v, bias, seed, h, causal,
                         DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret,
                         rate)
     return (o, lse), (q, k, v, bias, seed, o, lse)
 
 
-def _flash_lse_bwd_rule(h, causal, rate, res, gs):
+def _flash_lse_bwd_rule(h, causal, rate, interpret, res, gs):
     q, k, v, bias, seed, o, lse = res
     g, g_lse = gs
-    interpret = not _on_tpu()
     dq, dk, dv, dbias = _flash_bwd(q, k, v, bias, seed, o, lse, g,
                                    g_lse, h, causal, DEFAULT_BLOCK_Q,
                                    DEFAULT_BLOCK_K, interpret, rate)
@@ -766,27 +766,24 @@ def _flash_lse_bwd_rule(h, causal, rate, res, gs):
 _flash_lse.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash(q, k, v, bias, seed, h, causal, rate):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _flash(q, k, v, bias, seed, h, causal, rate, interpret):
     # o-only primitive with its OWN vjp so the common (non-ring) path
     # never ships a zeros g_lse operand into the backward kernels
-    interpret = not _on_tpu()
     o, _ = _flash_fwd(q, k, v, bias, seed, h, causal, DEFAULT_BLOCK_Q,
                       DEFAULT_BLOCK_K, interpret, rate)
     return o
 
 
-def _flash_fwd_rule(q, k, v, bias, seed, h, causal, rate):
-    interpret = not _on_tpu()
+def _flash_fwd_rule(q, k, v, bias, seed, h, causal, rate, interpret):
     o, lse = _flash_fwd(q, k, v, bias, seed, h, causal,
                         DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret,
                         rate)
     return o, (q, k, v, bias, seed, o, lse)
 
 
-def _flash_bwd_rule(h, causal, rate, res, g):
+def _flash_bwd_rule(h, causal, rate, interpret, res, g):
     q, k, v, bias, seed, o, lse = res
-    interpret = not _on_tpu()
     dq, dk, dv, dbias = _flash_bwd(q, k, v, bias, seed, o, lse, g,
                                    None, h, causal, DEFAULT_BLOCK_Q,
                                    DEFAULT_BLOCK_K, interpret, rate)
@@ -799,13 +796,15 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
                 dropout_seed=None, dropout_offsets=None,
-                dropout_g_offset=0):
+                dropout_g_offset=0, with_lse=False):
     """Fused-by-XLA dense chain on [B, T, H, D] (bf16 dots, f32
     softmax) — the measured winner below FLASH_MIN_SEQ, where the
     whole chain fits VMEM outright.  Differentiable via XLA autodiff.
     Dropout draws the SAME counter-hash mask as the Pallas kernels, so
     the two dispatch arms are bit-identical stochastic functions of
-    (seed, element position)."""
+    (seed, element position).  ``with_lse`` also returns the per-row
+    log-sum-exp [B, H, T] of the undropped scores (the
+    flash_attention_with_lse contract)."""
     b, t, h, d = q.shape
     s = jnp.einsum('bthd,bshd->bhts', q, k,
                    preferred_element_type=jnp.float32) / (d ** 0.5)
@@ -824,12 +823,16 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
                                   dropout_g_offset, dropout_rate)
         p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
     p = p.astype(q.dtype)
-    return jnp.einsum('bhts,bshd->bthd', p, v)
+    o = jnp.einsum('bhts,bshd->bthd', p, v)
+    if with_lse:
+        return o, jax.nn.logsumexp(s, axis=-1)
+    return o
 
 
 def flash_attention(q, k, v, causal=False, key_bias=None,
                     min_seq=None, dropout_rate=0.0, dropout_seed=None,
-                    dropout_offsets=None, dropout_g_offset=0):
+                    dropout_offsets=None, dropout_g_offset=0,
+                    auto_partitioned=False):
     """q,k,v: [B, T, H, D]; key_bias: optional [B, T] additive score
     bias (e.g. padding mask as 0 / -10000) -> [B, T, H, D].
 
@@ -842,27 +845,26 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     whole-program vjp see the same network.  dropout_seed must be a
     uint32 scalar (fold the op seed with the step).
 
-    Auto-dispatch: sequences shorter than `min_seq` (default
-    FLASH_MIN_SEQ, the measured crossover) run the dense XLA chain —
-    the entry point never loses to naive.  Pass min_seq=0 to force the
-    Pallas kernels (benchmark sweeps)."""
+    Auto-dispatch through common.dispatch(): sequences shorter than
+    `min_seq` (default FLASH_MIN_SEQ, the measured crossover) run the
+    dense XLA chain, and so does every call off a TPU unless
+    FLAGS_pallas_force asks for the interpreter (tests), and every
+    call an op lowering marks ``auto_partitioned`` (the GSPMD runner's
+    trace; see common.dispatch()).  Pass min_seq=0 to drop the floor
+    (benchmark sweeps)."""
     b, t, h, d = q.shape
     if min_seq is None:
         min_seq = FLASH_MIN_SEQ
     rate = float(dropout_rate or 0.0)
     if rate and dropout_seed is None:
         raise ValueError('dropout_rate > 0 needs a dropout_seed')
-    if t < min_seq:
-        _common.record_dispatch('flash_attention', False, 'below_floor')
+    fused, interpret = _common.dispatch(
+        'flash_attention', True, checks=(('below_floor', t >= min_seq),),
+        auto_partitioned=auto_partitioned)
+    if not fused:
         return _dense_path(q, k, v, causal, key_bias, rate,
                            dropout_seed, dropout_offsets,
                            dropout_g_offset)
-    # historical contract: off-TPU the kernels run under the
-    # interpreter rather than falling back dense, so tests cover the
-    # kernel bodies everywhere — record which mode actually ran
-    _common.record_dispatch('flash_attention', True,
-                            'tpu' if _on_tpu() else 'forced_interpret',
-                            interpret=not _on_tpu())
 
     def to_bh(x):
         return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
@@ -872,7 +874,7 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     seed = _pack_seed(dropout_seed, dropout_offsets,
                       dropout_g_offset) if rate else None
     out = _flash(to_bh(q), to_bh(k), to_bh(v), key_bias, seed, h,
-                 causal, rate)
+                 causal, rate, interpret)
     return jnp.transpose(out.reshape(b, h, t, d), (0, 2, 1, 3))
 
 
@@ -889,6 +891,11 @@ def flash_attention_with_lse(q, k, v, causal=False, key_bias=None,
     rate = float(dropout_rate or 0.0)
     if rate and dropout_seed is None:
         raise ValueError('dropout_rate > 0 needs a dropout_seed')
+    fused, interpret = _common.dispatch('flash_attention', True)
+    if not fused:
+        return _dense_path(q, k, v, causal, key_bias, rate,
+                           dropout_seed, dropout_offsets,
+                           dropout_g_offset, with_lse=True)
 
     def to_bh(x):
         return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
@@ -898,6 +905,6 @@ def flash_attention_with_lse(q, k, v, causal=False, key_bias=None,
     seed = _pack_seed(dropout_seed, dropout_offsets,
                       dropout_g_offset) if rate else None
     o, lse = _flash_lse(to_bh(q), to_bh(k), to_bh(v), key_bias, seed,
-                        h, causal, rate)
+                        h, causal, rate, interpret)
     o = jnp.transpose(o.reshape(b, h, t, d), (0, 2, 1, 3))
     return o, lse.reshape(b, h, t)

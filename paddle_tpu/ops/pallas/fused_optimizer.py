@@ -10,13 +10,17 @@ multiple, and concatenated into parameter/grad/moment slabs; a
 per-block scalar table carries each tensor's learning rate and beta
 powers, so tensors with different lr schedules still fuse.  The grid
 walks blocks; hyperparameters shared by the run (beta1/beta2/epsilon/
-weight-decay — the grouping key) are compile-time constants.
+weight-decay — the grouping key) are compile-time constants.  The
+scalar table and the block->tensor map ride in SMEM as scalar-prefetch
+operands: Mosaic refuses a (1, 8) VMEM block of a [nblk, 8] table, and
+per-tensor scalars are what SMEM is for.
 
 lamb needs a per-TENSOR trust ratio ``||p|| / ||r||``, a reduction the
 elementwise pass can't see whole: pass 1 updates moments and emits
-per-block partial sums of ``p**2`` and ``r**2`` (one (1, 8) row per
-block), a segment-sum over the block->tensor map builds the trust
-ratios, and pass 2 applies them — the [T, nblk] one-hot matmul a dense
+per-block partial sums of ``p**2`` and ``r**2``, a segment-sum over the block->tensor map builds the trust
+ratios, and pass 2 applies them (the partial rows are one (1, 128)
+lane row per block of a [nblk, 1, 128] output — the unit middle axis
+makes the block's last two dims equal the array's) — the [T, nblk] one-hot matmul a dense
 multi-tensor lamb would need never materializes.
 
 Dense fallback: the per-tensor registered lowerings looped in run
@@ -34,6 +38,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import common
 
@@ -41,8 +46,8 @@ BLOCK_ROWS = 32
 BLOCK_LANES = 128
 BLOCK = BLOCK_ROWS * BLOCK_LANES
 
-# per-block scalar row: [lr, beta1_pow, beta2_pow, trust, 0...]
-SCAL_COLS = 8
+# per-tensor scalar row: [lr, beta1_pow, beta2_pow, trust]
+SCAL_COLS = 4
 
 common.register_kernel(
     'fused_optimizer',
@@ -58,7 +63,7 @@ def _pack(tensors):
     """Flatten+pad each tensor to a BLOCK multiple and concatenate ->
     (slab [nblk, BLOCK_ROWS, BLOCK_LANES] f32,
      tid  [nblk] numpy int32 block->tensor map,
-     spans [(flat_offset, numel, shape)]).
+     spans [(first_block, nblocks, numel, shape)]).
 
     Per-tensor padding (not one tail pad) keeps every block owned by
     exactly one tensor — the lamb partial-norm rows need that."""
@@ -73,35 +78,58 @@ def _pack(tensors):
                 [f, jnp.zeros((nb * BLOCK - n,), jnp.float32)])
         flats.append(f)
         tids.append(np.full((nb,), i, np.int32))
-        spans.append((off, n, t.shape))
-        off += nb * BLOCK
+        spans.append((off, nb, n, t.shape))
+        off += nb
     slab = jnp.concatenate(flats).reshape(-1, BLOCK_ROWS, BLOCK_LANES)
     return slab, np.concatenate(tids), spans
 
 
 def _unpack(slab, spans):
-    flat = slab.reshape(-1)
-    return [flat[off:off + n].reshape(shape)
-            for off, n, shape in spans]
+    # slice each tensor's BLOCKS out first: a reshape of a slice of the
+    # whole flat slab is hoisted above the slice by XLA's simplifier,
+    # and a [numel/2, 2] view of the full slab (an fc with 2 outputs)
+    # pads 64x under the (8, 128) tiling — 28 GB at BERT-base
+    return [slab[off:off + nb].reshape(-1)[:n].reshape(shape)
+            for off, nb, n, shape in spans]
 
 
 def _slab_spec():
     return pl.BlockSpec((1, BLOCK_ROWS, BLOCK_LANES),
-                        lambda i: (i, 0, 0))
+                        lambda i, tid_ref, scal_ref: (i, 0, 0))
 
 
-def _scal_spec():
-    return pl.BlockSpec((1, SCAL_COLS), lambda i: (i, 0))
+def _part_spec():
+    return pl.BlockSpec((1, 1, BLOCK_LANES),
+                        lambda i, tid_ref, scal_ref: (i, 0, 0))
 
 
-def _adam_kernel(scal_ref, p_ref, g_ref, m1_ref, m2_ref,
+def _scalars(tid_ref, scal_ref):
+    """This block's tensor's scalar row, read from the flat SMEM
+    table [n * SCAL_COLS]."""
+    base = tid_ref[pl.program_id(0)] * SCAL_COLS
+    return [scal_ref[base + c] for c in range(SCAL_COLS)]
+
+
+def _launch(kernel, nblk, n_in, out_specs, out_shape, interpret):
+    """pallas_call over the block grid with (tid, scal) prefetched
+    into SMEM and ``n_in`` slab operands."""
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(nblk,),
+            in_specs=[_slab_spec()] * n_in,
+            out_specs=out_specs),
+        out_shape=out_shape,
+        interpret=interpret)
+
+
+def _adam_kernel(tid_ref, scal_ref, p_ref, g_ref, m1_ref, m2_ref,
                  po_ref, m1o_ref, m2o_ref, *, beta1, beta2, epsilon,
                  coeff):
     # same expression order as ops.optimizer_ops.adam/adamw — the
     # interpret-mode fused path is bitwise the dense reference
-    lr = scal_ref[0, 0]
-    b1p = scal_ref[0, 1]
-    b2p = scal_ref[0, 2]
+    lr, b1p, b2p, _ = _scalars(tid_ref, scal_ref)
     p = p_ref[...]
     g = g_ref[...]
     m1n = beta1 * m1_ref[...] + (1 - beta1) * g
@@ -115,11 +143,10 @@ def _adam_kernel(scal_ref, p_ref, g_ref, m1_ref, m2_ref,
     m2o_ref[...] = m2n
 
 
-def _lamb1_kernel(scal_ref, p_ref, g_ref, m1_ref, m2_ref,
+def _lamb1_kernel(tid_ref, scal_ref, p_ref, g_ref, m1_ref, m2_ref,
                   m1o_ref, m2o_ref, part_ref, *, beta1, beta2,
                   epsilon, wd):
-    b1p = scal_ref[0, 1]
-    b2p = scal_ref[0, 2]
+    _, b1p, b2p, _ = _scalars(tid_ref, scal_ref)
     p = p_ref[...]
     g = g_ref[...]
     m1n = beta1 * m1_ref[...] + (1 - beta1) * g
@@ -129,19 +156,17 @@ def _lamb1_kernel(scal_ref, p_ref, g_ref, m1_ref, m2_ref,
     r = mhat / (jnp.sqrt(vhat) + epsilon) + wd * p
     m1o_ref[...] = m1n
     m2o_ref[...] = m2n
-    # per-block partial norms; padded blocks contribute exact zeros
-    # (p and every moment term are zero there)
-    part_ref[...] = (jnp.zeros((1, SCAL_COLS), jnp.float32)
-                     .at[0, 0].set(jnp.sum(p * p))
-                     .at[0, 1].set(jnp.sum(r * r)))
+    # per-block partial norms in lanes 0 and 1; padded blocks
+    # contribute exact zeros (p and every moment term are zero there)
+    lane = jax.lax.broadcasted_iota(jnp.int32, part_ref.shape, 2)
+    part_ref[...] = jnp.where(
+        lane == 0, jnp.sum(p * p),
+        jnp.where(lane == 1, jnp.sum(r * r), 0.0))
 
 
-def _lamb2_kernel(scal_ref, p_ref, m1o_ref, m2o_ref, po_ref, *,
-                  beta1, beta2, epsilon, wd):
-    lr = scal_ref[0, 0]
-    b1p = scal_ref[0, 1]
-    b2p = scal_ref[0, 2]
-    trust = scal_ref[0, 3]
+def _lamb2_kernel(tid_ref, scal_ref, p_ref, m1o_ref, m2o_ref, po_ref,
+                  *, beta1, beta2, epsilon, wd):
+    lr, b1p, b2p, trust = _scalars(tid_ref, scal_ref)
     p = p_ref[...]
     mhat = m1o_ref[...] / (1 - b1p * beta1)
     vhat = m2o_ref[...] / (1 - b2p * beta2)
@@ -179,7 +204,8 @@ def apply(kind, ctx, ins, attrs):
     fused, interpret = common.dispatch(
         'fused_optimizer',
         bool(get_flag('FLAGS_pallas_opt_fuse', True)),
-        checks=(('below_floor', n >= min_n), ('dtype', dtype_ok)))
+        checks=(('below_floor', n >= min_n), ('dtype', dtype_ok)),
+        auto_partitioned=ctx.auto_partitioned)
     if not fused:
         return _dense(kind, ctx, ins, attrs)
 
@@ -205,41 +231,32 @@ def apply(kind, ctx, ins, attrs):
 
     if kind in ('adam', 'adamw'):
         coeff = attrs.get('coeff', 0.01) if kind == 'adamw' else 0.0
-        po, m1o, m2o = pl.pallas_call(
+        po, m1o, m2o = _launch(
             functools.partial(_adam_kernel, beta1=beta1, beta2=beta2,
                               epsilon=epsilon, coeff=coeff),
-            grid=(nblk,),
-            in_specs=[_scal_spec()] + [_slab_spec()] * 4,
-            out_specs=[_slab_spec()] * 3,
-            out_shape=[slab_shape] * 3,
-            interpret=interpret,
-        )(scal_t[tid_j], slab_p, slab_g, slab_m1, slab_m2)
+            nblk, 4, [_slab_spec()] * 3, [slab_shape] * 3, interpret,
+        )(tid_j, scal_t.reshape(-1), slab_p, slab_g, slab_m1, slab_m2)
     else:
         wd = attrs.get('weight_decay', 0.01)
-        m1o, m2o, part = pl.pallas_call(
+        m1o, m2o, part = _launch(
             functools.partial(_lamb1_kernel, beta1=beta1, beta2=beta2,
                               epsilon=epsilon, wd=wd),
-            grid=(nblk,),
-            in_specs=[_scal_spec()] + [_slab_spec()] * 4,
-            out_specs=[_slab_spec()] * 2 + [_scal_spec()],
-            out_shape=[slab_shape] * 2 +
-            [jax.ShapeDtypeStruct((nblk, SCAL_COLS), jnp.float32)],
-            interpret=interpret,
-        )(scal_t[tid_j], slab_p, slab_g, slab_m1, slab_m2)
+            nblk, 4, [_slab_spec()] * 2 + [_part_spec()],
+            [slab_shape] * 2 +
+            [jax.ShapeDtypeStruct((nblk, 1, BLOCK_LANES), jnp.float32)],
+            interpret,
+        )(tid_j, scal_t.reshape(-1), slab_p, slab_g, slab_m1, slab_m2)
         pn = jnp.sqrt(jnp.zeros((n,), jnp.float32)
-                      .at[tid_j].add(part[:, 0]))
+                      .at[tid_j].add(part[:, 0, 0]))
         rn = jnp.sqrt(jnp.zeros((n,), jnp.float32)
-                      .at[tid_j].add(part[:, 1]))
+                      .at[tid_j].add(part[:, 0, 1]))
         trust = jnp.where((pn > 0) & (rn > 0), pn / rn, 1.0)
-        po = pl.pallas_call(
+        po = _launch(
             functools.partial(_lamb2_kernel, beta1=beta1, beta2=beta2,
                               epsilon=epsilon, wd=wd),
-            grid=(nblk,),
-            in_specs=[_scal_spec()] + [_slab_spec()] * 3,
-            out_specs=_slab_spec(),
-            out_shape=slab_shape,
-            interpret=interpret,
-        )(scal_t.at[:, 3].set(trust)[tid_j], slab_p, m1o, m2o)
+            nblk, 3, _slab_spec(), slab_shape, interpret,
+        )(tid_j, scal_t.at[:, 3].set(trust).reshape(-1), slab_p, m1o,
+          m2o)
 
     return {
         'ParamOut': _unpack(po, spans),

@@ -142,9 +142,9 @@ def ring_attention_op(ctx, ins, attrs):
         return {'Out': [f(q, k, v)]}
     if use_flash:
         from .pallas.flash_attention import flash_attention
-        return {'Out': [flash_attention(q, k, v, causal=causal,
-                                        dropout_rate=rate,
-                                        dropout_seed=seed)]}
+        return {'Out': [flash_attention(
+            q, k, v, causal=causal, dropout_rate=rate, dropout_seed=seed,
+            auto_partitioned=ctx.auto_partitioned)]}
     if rate:
         # dense fallback with the SAME global-position hash mask the
         # ring draws (flash _dense_path implements it)

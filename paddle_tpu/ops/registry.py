@@ -20,6 +20,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..parallel import mesh as pmesh
+
 
 class LowerCtx(object):
     """Per-op lowering context: deterministic per-(op, step) RNG.
@@ -34,6 +36,14 @@ class LowerCtx(object):
         self.step = step
         self.op_seed = int(op_seed)
         self.prefer_test = prefer_test
+        # the GSPMD runner (with_data_parallel / with_mesh) publishes
+        # its mesh while a segment traces: the op is then lowered ONCE
+        # for all devices and XLA partitions it, which it cannot do to
+        # a Mosaic kernel.  A lowering hands this to the kernel's
+        # dispatch(); code it wraps in a shard_map is per-device and
+        # passes nothing.
+        mesh = pmesh.trace_mesh()
+        self.auto_partitioned = mesh is not None and mesh.devices.size > 1
 
     def rng(self, salt=0):
         key = jax.random.PRNGKey(self.op_seed + 7919 * salt)

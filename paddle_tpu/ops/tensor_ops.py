@@ -350,7 +350,8 @@ def lookup_table_v2(ctx, ins, attrs):
     w = ins['W'][0]
     ids = ins['Ids'][0]
     padding_idx = attrs.get('padding_idx', -1)
-    return {'Out': [pallas_emb.embedding_lookup(w, ids, padding_idx)]}
+    return {'Out': [pallas_emb.embedding_lookup(
+        w, ids, padding_idx, auto_partitioned=ctx.auto_partitioned)]}
 
 
 @register('lookup_table')

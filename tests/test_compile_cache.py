@@ -14,10 +14,19 @@ from paddle_tpu.fluid import executor as executor_mod
 
 
 @pytest.fixture
-def plane_dir(tmp_path):
+def plane_dir(tmp_path, monkeypatch):
     """A fresh cache dir + a fresh plane, restored afterwards so the
-    rest of the suite keeps the plane-off fast path."""
+    rest of the suite keeps the plane-off fast path.  JAX's own cache
+    is placed under the same tmp dir the way a deployment would place
+    it (JAX_COMPILATION_CACHE_DIR, inherited by child processes; jax
+    read the variable at import, so this process is told directly):
+    the checkout's shared .jax_cache would make "cold" depend on what
+    earlier suite runs compiled."""
+    import jax
     d = str(tmp_path / 'ccache')
+    xla = str(tmp_path / 'xla')
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', xla)
+    jax.config.update('jax_compilation_cache_dir', xla)
     compile_cache.reset_plane()
     fluid.set_flags({'FLAGS_compile_cache_dir': d})
     try:
@@ -25,11 +34,7 @@ def plane_dir(tmp_path):
     finally:
         fluid.set_flags({'FLAGS_compile_cache_dir': ''})
         compile_cache.reset_plane()
-        import jax
-        try:
-            jax.config.update('jax_compilation_cache_dir', None)
-        except Exception:
-            pass
+        jax.config.update('jax_compilation_cache_dir', None)
 
 
 def _prog(seed, width=4):

@@ -9,6 +9,8 @@ import jax.numpy as jnp
 from paddle_tpu.ops.pallas.flash_attention import flash_attention
 from paddle_tpu.parallel.ring_attention import reference_attention
 
+pytestmark = pytest.mark.usefixtures('pallas_interpret')
+
 
 @pytest.mark.parametrize('causal', [False, True])
 def test_flash_matches_dense(causal):
@@ -206,10 +208,8 @@ def test_auto_dispatch_and_vmem_clamp():
 
 
 def test_conv_precision_flag():
-    """FLAGS_conv_precision selects the f32 MXU algorithm (escape
-    hatch for the multi-pass dW-conv compile hang,
-    tools/repro_conv_wedge.py) without changing results beyond
-    algorithm tolerance."""
+    """FLAGS_conv_precision selects the f32 MXU algorithm without
+    changing results beyond algorithm tolerance."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid import layers
     from paddle_tpu.ops.nn_ops import _f32_conv_precision
@@ -431,3 +431,56 @@ def test_bert_trains_with_attn_dropout_on_flash_path():
     wpg, per_op = run(True), run(False)
     assert all(np.isfinite(wpg))
     np.testing.assert_allclose(wpg, per_op, rtol=2e-5)
+
+
+@pytest.mark.parametrize('axes', [{'dp': 4}, {'dp': 2, 'mp': 2}])
+def test_kernels_answer_dense_under_a_gspmd_mesh(axes):
+    """with_data_parallel / with_mesh trace ONE program for GSPMD,
+    which cannot partition a Mosaic kernel: every kernel the step would
+    dispatch on one device (here forced, under the interpreter) answers
+    dense there, counted as `auto_partitioned` — never silently — and
+    the sharded losses are the single-device losses, dropout mask
+    included (the dense chain hashes GLOBAL positions)."""
+    import paddle_tpu.fluid as fluid
+    from jax.sharding import Mesh
+    from paddle_tpu import models
+    from paddle_tpu.fluid import monitor
+    seq = 512      # flash_attention()'s own floor: kernels, not dense
+    cfg = models.bert.BertConfig(
+        vocab_size=512, hidden=128, layers=1, heads=2, intermediate=128,
+        max_pos=seq, dropout=0.0, attn_dropout=0.1)
+    batch = models.bert.synthetic_batch(cfg, 4, seq,
+                                        np.random.RandomState(0))
+    kernels = ('flash_attention', 'embedding_lookup', 'fused_optimizer')
+
+    def counts():
+        return np.array([[monitor.counter_value('pallas/%s/%s' % (k, c))
+                          for k in kernels] for c in
+                         ('dispatch_fused', 'fallback/auto_partitioned')])
+
+    def run(mesh):
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 5
+        with fluid.program_guard(main, startup):
+            _, _, loss = models.bert.build_pretrain(cfg, seq)
+            fluid.optimizer.Adam(1e-3).minimize(loss)
+        target = main if mesh is None else fluid.CompiledProgram(
+            main).with_data_parallel(loss_name=loss.name).with_mesh(mesh)
+        before = counts()   # building infers shapes through dispatch()
+        with fluid.scope_guard(fluid.Scope()):
+            exe = fluid.Executor(fluid.XLAPlace(0))
+            exe.run(startup)
+            losses = [float(np.asarray(exe.run(
+                target, feed=batch, fetch_list=[loss])[0]).ravel()[0])
+                for _ in range(2)]
+        fused, auto_partitioned = counts() - before
+        return losses, fused, auto_partitioned
+
+    single, fused, auto_partitioned = run(None)
+    assert (fused > 0).all() and not auto_partitioned.any()
+    n = int(np.prod(list(axes.values())))
+    mesh = Mesh(np.array(jax.devices()[:n]).reshape(*axes.values()),
+                tuple(axes))
+    sharded, fused, auto_partitioned = run(mesh)
+    assert not fused.any() and (auto_partitioned > 0).all()
+    np.testing.assert_allclose(sharded, single, rtol=2e-4)

@@ -539,6 +539,7 @@ def test_flash_attention_grads_match_dense(shape, causal, with_bias):
                                    err_msg='d%s %s' % (name, shape))
 
 
+@pytest.mark.usefixtures('pallas_interpret')
 def test_flash_attention_lse_grads():
     """The lse-output variant (ring-attention merge state): both o and
     lse cotangents flow; compare against the jax-native computation of

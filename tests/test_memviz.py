@@ -87,9 +87,11 @@ def test_peak_decomposition_sums_to_analysis_totals():
     named = sum(r['classes'].values())
     assert named + r['arg_overhead_bytes'] == \
         pytest.approx(r['argument_bytes'])
-    # CPU XLA reports no peak: the live-set bound must be used
-    assert r['peak_bytes'] == pytest.approx(
-        r['argument_bytes'] + r['output_bytes'] + r['temp_bytes'])
+    # the compiler's own peak where it reports one (the installed CPU
+    # XLA does), else the live-set bound arg+out+temp — which bounds
+    # the reported peak from above either way
+    assert 0 < r['peak_bytes'] <= \
+        r['argument_bytes'] + r['output_bytes'] + r['temp_bytes']
     assert r['classes']['param'] > 0      # fc weights are attributed
     assert r['classes']['feed'] > 0       # the x feed is attributed
     # largest buffers are named and sorted descending

@@ -119,6 +119,29 @@ def test_fused_optimizer_below_floor_reason():
         'path': 'dense', 'reason': 'below_floor', 'interpret': False}
 
 
+def test_auto_partitioned_is_the_callers_word_and_beats_force():
+    """dispatch() probes nothing: a lowering hands it
+    ctx.auto_partitioned, which LowerCtx takes from the mesh the GSPMD
+    runner publishes while it traces (one device: nothing to
+    partition).  Dense then, counted, even under force."""
+    from jax.sharding import Mesh
+    from paddle_tpu.parallel import mesh as pmesh
+    assert not registry.LowerCtx(0).auto_partitioned
+    with pmesh.use_trace_mesh(Mesh(np.array(jax.devices()[:1]), ('dp',))):
+        assert not registry.LowerCtx(0).auto_partitioned
+    _force(True)
+    with pmesh.use_trace_mesh(Mesh(np.array(jax.devices()[:2]), ('dp',))):
+        ctx = registry.LowerCtx(0)
+    assert ctx.auto_partitioned
+    before = monitor.counter_value(
+        'pallas/fused_optimizer/fallback/auto_partitioned')
+    fused_optimizer.apply('adam', ctx, _opt_ins(2), {})
+    assert common._LAST['fused_optimizer'] == {
+        'path': 'dense', 'reason': 'auto_partitioned', 'interpret': False}
+    assert monitor.counter_value(
+        'pallas/fused_optimizer/fallback/auto_partitioned') == before + 1
+
+
 def test_executor_groups_optimizer_run():
     """An Adam program with several params runs the fused op at the
     executor level and matches the ungrouped lowering bitwise (dense

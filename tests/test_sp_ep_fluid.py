@@ -7,6 +7,7 @@ plus the 3D dp x pp x mp composition from ONE fluid Program
 (program_pipeline.build_train_step data_axis/param_specs)."""
 
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -311,6 +312,7 @@ def test_ring_attention_dropout_sharded_matches_dense():
     np.testing.assert_allclose(sharded, single, rtol=5e-3, atol=5e-4)
 
 
+@pytest.mark.usefixtures('pallas_interpret')
 def test_ring_flash_attention_dropout_sharded_matches_dense():
     """Same contract with the Pallas flash per-block engine (interpret
     mode on CPU): dropout offsets ride the packed seed operand into

@@ -61,9 +61,13 @@ def child():
 
 
 def run_child(cache_dir):
+    # JAX's own cache starts as empty as the segment store (an
+    # executable it served from a warm one is not re-published there),
+    # placed from outside through the variable place_jax_cache() honours
     env = dict(os.environ,
                JAX_PLATFORMS=os.environ.get('JAX_PLATFORMS', 'cpu'),
-               FLAGS_compile_cache_dir=cache_dir)
+               FLAGS_compile_cache_dir=cache_dir,
+               JAX_COMPILATION_CACHE_DIR=os.path.join(cache_dir, 'xla'))
     p = subprocess.run(
         [sys.executable, os.path.abspath(__file__), '--child'],
         capture_output=True, text=True, timeout=600, env=env,
