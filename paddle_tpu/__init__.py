@@ -9,11 +9,22 @@ root for the reference layer map this mirrors.
 
 __version__ = '0.1.0'
 
-from . import ops  # registers all operators
-from . import fluid  # noqa: F401
+import time as _time
+
+_import_t0 = _time.perf_counter()
+
+from . import ops  # noqa: E402  registers all operators
+from . import fluid  # noqa: E402,F401
 
 # paddle.* compatibility aliases
-from .fluid import layers  # noqa: F401
+from .fluid import layers  # noqa: E402,F401
+
+# what the process paid to have the package, once ('compile/*' beside
+# it says what it paid to have its programs: from here on JAX's own
+# compile events are folded into fluid.monitor)
+fluid.monitor.set_gauge('import/paddle_tpu_seconds',
+                        _time.perf_counter() - _import_t0)
+fluid.compile_cache.listen()
 
 
 def enable_static():

@@ -59,10 +59,27 @@ _PICKLE_MAGIC = b'ptcc1\n'
 
 
 
-# JAX's persistent cache announces a hit with this monitoring event, on
-# the compiling thread; compile_lowered() counts them around ONE
-# compile (per thread: the warmup pool compiles concurrently).
+# What JAX itself reports about obtaining programs, folded into
+# fluid.monitor under 'compile/*' as it happens (only ever at compile
+# time, never per step):
+#
+#   compile/trace_seconds, _count          tracing python to jaxprs
+#   compile/lower_seconds, _count          lowering jaxprs to MLIR
+#   compile/backend_built_seconds, _count  programs the compiler built
+#   compile/backend_loaded_seconds, _count programs JAX's persistent
+#                                          cache served (load time)
+#
+# JAX announces a persistent-cache hit with an event on the compiling
+# thread just before the backend_compile_duration of that program
+# ends, so a per-thread flag splits built from loaded (the warmup pool
+# compiles concurrently); compile_lowered() reads the same flag's
+# count around ONE compile.
 _JAX_CACHE_HIT = '/jax/compilation_cache/cache_hits'
+_JAX_DURATIONS = {
+    '/jax/core/compile/jaxpr_trace_duration': 'compile/trace',
+    '/jax/core/compile/jaxpr_to_mlir_module_duration': 'compile/lower',
+}
+_JAX_BACKEND = '/jax/core/compile/backend_compile_duration'
 _hits = threading.local()
 _listener_lock = threading.Lock()
 _listening = False
@@ -71,6 +88,35 @@ _listening = False
 def _on_jax_event(event, **_):
     if event == _JAX_CACHE_HIT:
         _hits.n = getattr(_hits, 'n', 0) + 1
+        _hits.pending = True
+
+
+def _on_jax_duration(event, seconds, **_):
+    if event == _JAX_BACKEND:
+        served = getattr(_hits, 'pending', False)
+        _hits.pending = False
+        name = 'compile/backend_loaded' if served \
+            else 'compile/backend_built'
+    else:
+        name = _JAX_DURATIONS.get(event)
+        if name is None:
+            return
+    monitor.add(name + '_seconds', seconds)
+    monitor.add(name + '_count')
+
+
+def listen():
+    """Register the listeners above with JAX, once per process.  The
+    package calls this as it is imported, so the 'compile/*' totals
+    cover every program the process obtained."""
+    global _listening
+    with _listener_lock:
+        if not _listening:
+            import jax
+            jax.monitoring.register_event_listener(_on_jax_event)
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration)
+            _listening = True
 
 
 def compile_lowered(lowered):
@@ -81,12 +127,7 @@ def compile_lowered(lowered):
     that loads cleanly and then fails at its first dispatch
     ("Function ... not found", CPU backend, jaxlib 0.9.0), so the
     store must not publish it."""
-    global _listening
-    with _listener_lock:
-        if not _listening:
-            import jax
-            jax.monitoring.register_event_listener(_on_jax_event)
-            _listening = True
+    listen()        # the hit counter below is the listener's
     before = getattr(_hits, 'n', 0)
     compiled = lowered.compile()
     return compiled, getattr(_hits, 'n', 0) != before
@@ -340,6 +381,13 @@ class CompilePlane(object):
         # fp -> {name: (shape, dtype_str)}; LRU like the executable
         # map — a long-running service cycling programs must not leak
         self._outspecs = LRUCache(
+            int(get_flag('FLAGS_compile_cache_memory_capacity', 256)
+                or 256))
+        # lazily jitted callables the runners hold, with what their
+        # first call was given: key -> (weak reference to the callable,
+        # lowering args).  Weak, so a dead segment's program is not
+        # kept alive from here; read by held_hlo() only
+        self._lazy = LRUCache(
             int(get_flag('FLAGS_compile_cache_memory_capacity', 256)
                 or 256))
         self._pool = None
@@ -634,6 +682,46 @@ class CompilePlane(object):
             'aot_compiles': monitor.counter_value(
                 'executor/aot_compiles'),
         }
+
+    def note_lazy(self, key, jitted, lowering_args):
+        """A runner's word, at the first call of a lazily jitted
+        segment, on how to find the compiled program again without a
+        second trace: ``jitted.lower(*lowering_args)`` repeats the
+        call's signature, so jit's own caches serve the lowering and
+        the compile."""
+        import weakref
+        with self._lock:
+            self._lazy[key] = (weakref.ref(jitted), lowering_args)
+
+    def held_hlo(self):
+        """[(key, optimised HLO text)] of every executable this process
+        holds: the AOT executables of the map and the lazily jitted
+        callables the runners noted.  On demand only (fluid.profiler's
+        scope table), never on the step path: it prints whole modules,
+        and where jit's caches were dropped a lazily jitted callable
+        lowers and compiles again.  A program that cannot be lowered
+        again, or an executable that keeps no HLO, raises: a table
+        that silently lacked a program would move all of its device
+        time to 'unattributed'."""
+        from concurrent.futures import Future
+        with self._lock:
+            held = [(fp, ex) for fp, ex in self._mem.items()
+                    if not isinstance(ex, Future) and
+                    hasattr(ex, 'as_text')]
+            lazy = [(key, ref(), args)
+                    for key, (ref, args) in self._lazy.items()]
+        for key, jitted, args in lazy:
+            if jitted is not None:      # else its segment is gone
+                held.append((key, jitted.lower(*args).compile()))
+        out = []
+        for key, ex in held:
+            text = ex.as_text()
+            if not text:
+                raise RuntimeError(
+                    'the executable %r gives no HLO text, so no scope '
+                    'table can be built for it' % (key,))
+            out.append((key, text))
+        return out
 
     def shared_jit(self, fp, make_fn):
         """One process-wide jit callable per fingerprint, for the

@@ -1191,6 +1191,23 @@ def _specs_from_args(state, data):
             {n: spec(v) for n, v in data.items()})
 
 
+def _lowering_args(step, state, data):
+    """What ``jitted.lower()`` must be given to name the program a lazy
+    jit compiled for this call (``CompilePlane.note_lazy``): the step
+    as passed and, for every array, its shape, dtype, weak type and,
+    where it is committed, its sharding."""
+    def spec(v):
+        if not isinstance(v, jax.Array):
+            return v if isinstance(v, (int, float)) else \
+                jax.ShapeDtypeStruct(np.shape(v), np.asarray(v).dtype)
+        return jax.ShapeDtypeStruct(
+            v.shape, v.dtype, weak_type=v.weak_type,
+            sharding=v.sharding if v.committed else None)
+
+    return (spec(step), {n: spec(v) for n, v in state.items()},
+            {n: spec(v) for n, v in data.items()})
+
+
 from jax.core import Tracer as _Tracer
 
 
@@ -2437,6 +2454,10 @@ class Executor(object):
                 monitor.add('executor/segments_lowered')
                 compiled = seg.compiled[key] = _jit_segment(
                     seg, auto, whole_program_grad=wpg)
+                if _is_default_device(device):
+                    plane.note_lazy((id(seg), key), compiled,
+                                    _lowering_args(self._step, state,
+                                                   data))
             else:
                 monitor.add('executor/segment_cache_hit')
 

@@ -26,7 +26,7 @@ from . import supervisor as _sup
 from . import timeseries as _tseries
 from . import trace as _trace
 from .executor import (_Segment, _SegmentBinder, FetchHandle,
-                       _make_segment_fn, _add_note,
+                       _make_segment_fn, _add_note, _lowering_args,
                        _lowering_flag_items, _release_donated_state)
 
 
@@ -50,6 +50,29 @@ def _resolve_fetch(val, return_numpy):
     if return_numpy == 'async':
         return FetchHandle(val, resolver=_fetch_to_host)
     return _fetch_to_host(val) if return_numpy else val
+
+
+def _fetch_values(fetch_names, fetched, scope, return_numpy):
+    results = []
+    for name in fetch_names:
+        val = fetched.get(name)
+        if val is None:
+            val = core.as_array(scope.find_var(name))
+        results.append(_resolve_fetch(val, return_numpy))
+    return results
+
+
+def _resolve_fetches(fetch_names, fetched, scope, return_numpy):
+    """The step's fetch list resolved, inside the step span: a blocking
+    D2H here is step time, recorded as the executor's 'fetch_d2h'
+    phase (an async handle records its own when it resolves)."""
+    if not fetch_names:
+        return []
+    if return_numpy and return_numpy != 'async':
+        with _trace.span('fetch_d2h'):
+            return _fetch_values(fetch_names, fetched, scope,
+                                 return_numpy)
+    return _fetch_values(fetch_names, fetched, scope, return_numpy)
 
 
 def _dispatch_span(name, key, records):
@@ -444,12 +467,8 @@ def run_parallel(executor, compiled, feed, fetch_list, scope, return_numpy):
                 op = item[1]
                 with _trace.span('host_op', op=op.type):
                     registry.get(op.type).fn(executor, scope, op)
-        results = []
-        for name in fetch_names:
-            val = fetched.get(name)
-            if val is None:
-                val = core.as_array(scope.find_var(name))
-            results.append(_resolve_fetch(val, return_numpy))
+        results = _resolve_fetches(fetch_names, fetched, scope,
+                                   return_numpy)
     _memviz.maybe_sample(executor._step, scope)
     # dispatch-side wall time: this runner is an Executor.run entry
     # point too (CompiledProgram path), so it records the same counters
@@ -518,13 +537,15 @@ def _run_segment_parallel(executor, seg, feed, scope, mesh, ndev, fetched,
     # pin state shardings by resharding the inputs (device_put is a
     # no-op when the array already matches); outputs inherit XLA's
     # propagated shardings and flow back here next step
-    state = {n: _to_global(v, state_shard(n, v))
-             for n, v in state.items()}
+    with _trace.span('place_state'):
+        state = {n: _to_global(v, state_shard(n, v))
+                 for n, v in state.items()}
 
     def _convert_data(n, v):
         sh = data_shard(n, v)
         return _to_global(v, sh, per_process=sh.spec != P())
-    data = {n: _convert_data(n, v) for n, v in data.items()}
+    with _trace.span('place_data'):
+        data = {n: _convert_data(n, v) for n, v in data.items()}
     compiled = seg.compiled.get('parallel')
     first_run = compiled is None
     monitor.add('parallel/segment_cache_miss' if first_run
@@ -575,6 +596,8 @@ def _run_segment_parallel(executor, seg, feed, scope, mesh, ndev, fetched,
                                 donate_argnums=(1,)))
         seg.compiled['parallel'] = compiled
         seg.comms_key = fp
+        compile_cache.plane().note_lazy(
+            fp, compiled, _lowering_args(executor._step, state, data))
     recs = comms.records_for(seg.comms_key)
     try:
         if first_run and _finject.armed():
@@ -690,12 +713,8 @@ def run_collective(executor, program, feed, fetch_list, scope,
                              batch_feeds, fetched)
         # fetch resolution inside the step span, same as run_parallel:
         # a blocking D2H here is step time the report must attribute
-        results = []
-        for name in fetch_names:
-            val = fetched.get(name)
-            if val is None:
-                val = _core.as_array(scope.find_var(name))
-            results.append(_resolve_fetch(val, return_numpy))
+        results = _resolve_fetches(fetch_names, fetched, scope,
+                                   return_numpy)
     _memviz.maybe_sample(executor._step, scope)
     monitor.add('executor/run_calls')
     monitor.observe('executor/run_seconds',
@@ -728,11 +747,14 @@ def _run_collective_plan(executor, plan, feed, scope, mesh, ndev,
         if jax.process_count() > 1:
             # multi-trainer mode: feeds are process-local shards, params
             # replicated global arrays (reference NCCL2 multi-process DP)
-            state = {n: _to_global(v, NamedSharding(mesh, P()))
-                     for n, v in state.items()}
-            data = {n: _to_global(v, NamedSharding(mesh, data_specs[n]),
-                                  per_process=data_specs[n] != P())
-                    for n, v in data.items()}
+            with _trace.span('place_state'):
+                state = {n: _to_global(v, NamedSharding(mesh, P()))
+                         for n, v in state.items()}
+            with _trace.span('place_data'):
+                data = {n: _to_global(
+                            v, NamedSharding(mesh, data_specs[n]),
+                            per_process=data_specs[n] != P())
+                        for n, v in data.items()}
         compiled = seg.compiled.get('collective')
         first_run = compiled is None
         monitor.add('parallel/segment_cache_miss' if first_run
@@ -774,6 +796,9 @@ def _run_collective_plan(executor, plan, feed, scope, mesh, ndev,
                               NamedSharding(mesh, P()))
         else:
             step = jnp.asarray(executor._step)
+        if first_run:
+            compile_cache.plane().note_lazy(
+                seg.comms_key, compiled, _lowering_args(step, state, data))
         recs = comms.records_for(seg.comms_key)
         try:
             if first_run and _finject.armed():

@@ -18,18 +18,22 @@ TPU-native split, mirroring the reference's two profilers:
   untouched under a jax.profiler device-trace capture; on exit the
   trace's per-kernel events are attributed back to fluid op types
   through the named_scope metadata every lowering runs under
-  (executor._lower_ops -> XLA op_metadata -> trace `tf_op` args) and
+  (executor._lower_ops -> XLA op_metadata of the compiled HLO) and
   summed into the same sorted table.  This is the reference's
   DeviceTracer leg (platform/device_tracer.h: CUPTI kernels correlated
   back to op RecordEvents) — per-op attribution of the REAL fused run.
-  Device-kernel metadata is only emitted by the TPU backend; on CPU
-  hosts the table falls back to unattributed HLO thunk names.
+  The trace is the ``.xplane.pb`` jax.profiler writes; its op events
+  name HLO instructions only, so the fluid op of each comes from the
+  executables this process holds (``hlo_scopes`` / ``scope_tables``).
 
 stop_profiler prints the sorted table; summary_records() /
 summary_string() expose it programmatically.  start_trace()/
-stop_trace() + tools/timeline.py remain the raw Perfetto capture.
+stop_trace() capture without printing: the same table, plus the
+events for tools/timeline.py's merged Perfetto file.
 """
 
+import bisect
+import collections
 import contextlib
 import os
 import re
@@ -113,7 +117,7 @@ def _resolve_component(comp, op_types, per_instance):
     while '(' in base and base.endswith(')'):
         base = base[base.index('(') + 1:-1]
     for cand in (comp, base):
-        if cand in op_types:
+        if _is_op_type(cand, op_types):
             return cand
         if per_instance and '#' in cand:
             typ = cand.rsplit('#', 1)[0]
@@ -126,11 +130,13 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
                            with_stats=False):
     """Map device-trace kernel events back to fluid op types.
 
-    `events` are chrome-trace events (trace.json 'traceEvents').  Each
-    TPU kernel event carries args['tf_op'] — the XLA op_metadata
-    op_name, i.e. the jax.named_scope path the executor wrapped the
-    lowering in ('jit_segment_x/relu/max' or, under whole-program
-    autodiff, 'jit_.../transpose(jvp(...))/relu/...').  Attribution:
+    `events` are chrome-trace events (``load_trace_events``, or a
+    trace.json's 'traceEvents').  Each kernel event carries
+    args['tf_op'] — a fluid scope from ``scope_tables()``, or a raw
+    XLA op_metadata op_name, i.e. the jax.named_scope path the
+    executor wrapped the lowering in ('jit_segment_x/relu/max' or,
+    under whole-program autodiff,
+    'jit_.../transpose(jvp(...))/relu/...').  Attribution:
     the first path component that names a registered op type; kernels
     with no such component (copies, infeed, grad-only glue) land under
     'unattributed/<hlo name>'.  Returns {name: [calls, total_s, max_s,
@@ -183,7 +189,8 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
             dropped += 1
             continue
         try:
-            sec = float(e.get('dur') or 0) * 1e-6
+            # an event other events nest in counts its own part only
+            sec = float(e.get('self_dur', e.get('dur')) or 0) * 1e-6
         except (TypeError, ValueError):
             sec = 0.0
         hit = cache.get(tf_op)
@@ -226,16 +233,329 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
     return recs
 
 
-def _load_trace_events(logdir):
+# ------------------------------------------------ HLO instruction scopes
+# A device trace names HLO instructions (``fusion.933``), and XLA renames
+# them whenever a lowering changes.  The executor lowers every fluid op
+# inside ``jax.named_scope(op.type)``, so each instruction of the
+# OPTIMISED HLO still says where it came from, in the ``op_name`` of its
+# metadata: ``jit(segment_x)/mul/dot_general``, or
+# ``jit(segment_wpg_x)/transpose(jvp(mul))/dot_general`` for backward
+# code jax derived inside the scope.  The rule, written once:
+#
+# - an instruction counts to the first component of its ``op_name``,
+#   the primitive's own name at the end left out, that is a registered
+#   fluid op type; jax's transform wrappers are looked through, and a
+#   ``transpose`` among them makes it that type's backward
+#   (``mul_grad``, the name the explicit grad op lowers under);
+# - a plain named scope the lowering itself opened right under the op's
+#   is kept as ``<type>/<scope>`` (``fused_adam/pack``);
+# - a fusion counts to the ``dot`` / ``convolution`` / custom call it
+#   holds, else to its root; a root that carries no scope (the tuple of
+#   a multi-output fusion, a bitcast or copy XLA put there) stands for
+#   the nearest of its operands inside the fusion that does, and only
+#   if none does, the fusion's own ``op_name`` decides;
+# - an instruction with no fluid scope counts to none.
+_TRANSFORMS = re.compile(
+    r'^(jvp|transpose|vmap|checkpoint|remat|custom_jvp|custom_vjp)'
+    r'\((.*)\)$')
+_HLO_MODULE = re.compile(r'^HloModule\s+([^\s,]+)')
+_HLO_COMPUTATION = re.compile(r'^(?:ENTRY\s+)?%?([^\s(]+)\s+\(.*->.*\{\s*$')
+_HLO_INSTRUCTION = re.compile(r'^\s+(ROOT\s+)?%?(\S+)\s+=\s+')
+_HLO_OPERAND = re.compile(r'%([^\s,()]+)')
+_HLO_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_HLO_CALLS = re.compile(r'\bcalls=%?([^\s,}]+)')
+_HELD_BY_FUSION = ('dot', 'convolution', 'custom-call')
+
+_Instruction = collections.namedtuple(
+    '_Instruction', 'name opcode operands op_name calls root')
+
+
+def _is_op_type(name, op_types):
+    """Registered, or the generic gradient of a registered type (grad
+    ops have no registry entry of their own)."""
+    return name in op_types or (name.endswith('_grad') and
+                                name[:-5] in op_types)
+
+
+def fluid_scope(op_name, op_types=None):
+    """The fluid op an HLO instruction was lowered from, by the rule
+    above, from the ``op_name`` of its metadata: ``'mul'``,
+    ``'mul_grad'``, ``'fused_adam/pack'``, or None."""
+    if not op_name:
+        return None
+    op_types = op_types or _registered_op_types()
+    parts = op_name.split('/')
+    for i, comp in enumerate(parts[:-1]):
+        backward = False
+        m = _TRANSFORMS.match(comp)
+        while m:
+            backward = backward or m.group(1) == 'transpose'
+            comp = m.group(2)
+            m = _TRANSFORMS.match(comp)
+        comp = comp.split('#', 1)[0]    # FLAGS_opprof's instance suffix
+        if not _is_op_type(comp, op_types):
+            continue
+        if backward and not comp.endswith('_grad'):
+            comp += '_grad'
+        inner = parts[i + 1] if i + 2 < len(parts) else ''
+        return comp + '/' + inner if inner and '(' not in inner else comp
+    return None
+
+
+def _closing(text, start):
+    """Index just past the parenthesis that closes the one at
+    ``text[start]``."""
+    depth = 0
+    for i in range(start, len(text)):
+        depth += (text[i] == '(') - (text[i] == ')')
+        if depth == 0:
+            return i + 1
+    return len(text)
+
+
+def _parse_hlo(text):
+    """An HLO module's text -> (module name, {computation name:
+    [_Instruction]}).  ``operands`` stays the text between the
+    opcode's parentheses: only a fusion's root needs it split."""
+    module, computations, body = '', {}, None
+    for line in text.splitlines():
+        if body is not None:
+            m = _HLO_INSTRUCTION.match(line)
+            if m:
+                i = m.end()
+                # the shape: one word, or a tuple that holds spaces
+                i = _closing(line, i) if line[i] == '(' else \
+                    line.find(' ', i)
+                paren = line.find('(', i)
+                if paren < 0:
+                    continue
+                end = _closing(line, paren)
+                op_name = _HLO_OP_NAME.search(line, end)
+                calls = _HLO_CALLS.search(line, end)
+                body.append(_Instruction(
+                    m.group(2), line[i:paren].strip(),
+                    line[paren + 1:end - 1],
+                    op_name.group(1) if op_name else '',
+                    calls.group(1) if calls else None,
+                    bool(m.group(1))))
+            elif line.startswith('}'):
+                body = None
+            continue
+        m = _HLO_COMPUTATION.match(line)
+        if m:
+            body = computations[m.group(1)] = []
+        elif not module:
+            m = _HLO_MODULE.match(line)
+            if m:
+                module = m.group(1)
+    return module, computations
+
+
+def _fusion_scope(fusion, body, op_types):
+    scopes = {ins.name: fluid_scope(ins.op_name, op_types)
+              for ins in body}
+    for ins in body:
+        if ins.opcode in _HELD_BY_FUSION and scopes[ins.name]:
+            return scopes[ins.name]
+    by_name = {ins.name: ins for ins in body}
+    roots = [ins for ins in body if ins.root] or body[-1:]
+    queue, seen = collections.deque(roots), set()
+    while queue:                # breadth first: the nearest operand
+        ins = queue.popleft()
+        if scopes[ins.name]:
+            return scopes[ins.name]
+        for name in _HLO_OPERAND.findall(ins.operands):
+            if name in by_name and name not in seen:
+                seen.add(name)
+                queue.append(by_name[name])
+    return fluid_scope(fusion.op_name, op_types)
+
+
+def hlo_scopes(hlo_text, op_types=None):
+    """One compiled module's optimised HLO text (``Compiled.as_text()``)
+    -> (module name, {instruction name: fluid scope or None}) for every
+    instruction a trace can name: those of fused computations are left
+    out, their fusion stands for them."""
+    op_types = op_types or _registered_op_types()
+    module, computations = _parse_hlo(hlo_text)
+    fused = {ins.calls for body in computations.values() for ins in body
+             if ins.opcode == 'fusion'}
+    table = {}
+    for name, body in computations.items():
+        if name in fused:
+            continue
+        for ins in body:
+            if ins.opcode == 'fusion' and ins.calls in computations:
+                table[ins.name] = _fusion_scope(
+                    ins, computations[ins.calls], op_types)
+            else:
+                table[ins.name] = fluid_scope(ins.op_name, op_types)
+    return module, table
+
+
+def scope_tables():
+    """{HLO module name: [table, ...]} (tables as ``hlo_scopes`` gives
+    them) of every executable this process holds
+    (``CompilePlane.held_hlo``).  Built when asked for and at no other
+    time: it prints and parses whole modules, seconds at BERT-base.  Two
+    programs of one name (a segment planned for two fetch lists) keep a
+    table each; ``pick_table`` tells them apart."""
+    from . import compile_cache
+    tables = {}
+    for _key, text in compile_cache.plane().held_hlo():
+        module, table = hlo_scopes(text)
+        tables.setdefault(module, []).append(table)
+    return tables
+
+
+def pick_table(candidates, instruction_names):
+    """Of the tables of same-named modules, the one that knows most of
+    the instructions one run of the module executed and, among equals,
+    holds the fewest others ({} for none)."""
+    if not candidates:
+        return {}
+    if len(candidates) == 1:
+        return candidates[0]
+    names = set(instruction_names)
+    return max(candidates,
+               key=lambda t: (len(names.intersection(t)), -len(t)))
+
+
+# a module run is named "<module name>(<program id>)"
+_PROGRAM_ID = re.compile(r'\(\d+\)$')
+_MODULE_LINE = 'XLA Modules'
+
+
+def module_runs(plane):
+    """Sorted [(start ns, end ns, name)] of the module runs of one
+    device plane of a trace (its 'XLA Modules' line; the name is the
+    module's with the program id, ``jit_segment_x(12)``); [] where the
+    plane has no such line."""
+    return sorted(
+        (float(ev.start_ns), float(ev.start_ns + ev.duration_ns), ev.name)
+        for line in plane.lines if line.name == _MODULE_LINE
+        for ev in line.events)
+
+
+def program_at(runs, t):
+    """The name of the module run (``module_runs``) that holds the
+    instant ``t``; '' for none."""
+    i = bisect.bisect_right(runs, (t, float('inf'), '')) - 1
+    return runs[i][2] if i >= 0 and t <= runs[i][1] else ''
+
+
+def instruction_scopes(ops, tables):
+    """[(program, instruction name)] of executed instructions -> their
+    fluid scopes (None for none), in order.  ``program`` is the name of
+    the module run the instruction ran in (``program_at``) or a
+    module's bare name: two programs of one module name, the quiet step
+    and the one that fetches, hold a ``fusion.933`` each, so the
+    instructions of each program are looked up in the one table
+    (``pick_table``) of that module name that knows most of them.
+    Where the program is not known ('': a trace without module runs)
+    every table of ``tables`` is a candidate."""
+    by_program = {}
+    for i, (program, _name) in enumerate(ops):
+        by_program.setdefault(program, []).append(i)
+    scopes = [None] * len(ops)
+    for program, indices in by_program.items():
+        candidates = tables.get(_PROGRAM_ID.sub('', program))
+        if candidates is None and not program:
+            candidates = [t for ts in tables.values() for t in ts]
+        table = pick_table(candidates, {ops[i][1] for i in indices})
+        for i in indices:
+            scopes[i] = table.get(ops[i][1])
+    return scopes
+
+
+# ---------------------------------------------------- reading the trace
+_DEVICE_PLANE = re.compile(r'^/device:[A-Za-z]+:\d+$')
+_TRACED_INSTRUCTION = re.compile(r'^%?(\S+) = ')
+
+
+def _newest_xplane(logdir):
     import glob
-    import gzip
-    import json
-    paths = glob.glob(os.path.join(logdir, '**', '*.trace.json.gz'),
+    paths = glob.glob(os.path.join(logdir, '**', '*.xplane.pb'),
                       recursive=True)
-    if not paths:
+    return max(paths, key=os.path.getmtime) if paths else None
+
+
+def _self_durations(events):
+    """[(start, duration)] of one trace line -> the part of each
+    duration no later-starting event of the line covers (a ``while``
+    covers its body's ops; durations must not be summed)."""
+    order = sorted(range(len(events)),
+                   key=lambda i: (events[i][0], -events[i][1]))
+    own = [d for _s, d in events]
+    stack = []                      # indices of the open nest
+    for i in order:
+        start, dur = events[i]
+        while stack and events[stack[-1]][0] + events[stack[-1]][1] \
+                <= start:
+            stack.pop()
+        if stack:
+            own[stack[-1]] -= dur
+        stack.append(i)
+    return [max(0.0, d) for d in own]
+
+
+def load_trace_events(logdir):
+    """The newest ``.xplane.pb`` under ``logdir`` (what jax.profiler
+    writes on this runtime) as chrome-trace 'X' events, times in us:
+    one pid per plane, one tid per line.  An event that is an executed
+    HLO instruction (a TPU plane's 'XLA Ops' line, or a CPU thunk with
+    an ``hlo_op`` stat) carries ``args['tf_op']``: the fluid scope
+    ``scope_tables()`` gives it, else the module's name, and
+    ``self_dur`` where other events of its line nest inside it."""
+    path = _newest_xplane(logdir)
+    if path is None:
         return []
-    with gzip.open(sorted(paths)[-1], 'rt') as f:
-        return json.load(f).get('traceEvents', [])
+    from jax.profiler import ProfileData
+    tables = scope_tables()
+    out = []
+    for pid, plane in enumerate(ProfileData.from_file(path).planes):
+        out.append({'ph': 'M', 'pid': pid, 'name': 'process_name',
+                    'args': {'name': plane.name}})
+        device = bool(_DEVICE_PLANE.match(plane.name))
+        lines = list(plane.lines)
+        runs = module_runs(plane) if device else []
+        for tid, line in enumerate(lines):
+            op_line = device and line.name == 'XLA Ops'
+            rows = []
+            for ev in line.events:
+                row = {'ph': 'X', 'pid': pid, 'tid': tid,
+                       'name': ev.name, 'ts': ev.start_ns / 1e3,
+                       'dur': ev.duration_ns / 1e3}
+                if op_line:
+                    m = _TRACED_INSTRUCTION.match(ev.name)
+                    row['name'] = m.group(1) if m else ev.name
+                    row['module'] = program_at(runs, ev.start_ns)
+                elif not device:
+                    stats = dict(ev.stats)
+                    if 'hlo_op' in stats:
+                        row['name'] = str(stats['hlo_op'])
+                        row['module'] = str(stats.get('hlo_module', ''))
+                rows.append(row)
+            _attach_scopes([r for r in rows if 'module' in r], tables)
+            out.extend(rows)
+    return out
+
+
+def _attach_scopes(rows, tables):
+    """Give the instruction events of one line their ``tf_op`` and
+    ``self_dur``."""
+    if not rows:
+        return
+    programs = [row.pop('module') for row in rows]
+    scopes = instruction_scopes(
+        [(p, row['name']) for p, row in zip(programs, rows)], tables)
+    for row, program, scope in zip(rows, programs, scopes):
+        row['args'] = {'tf_op': scope or _PROGRAM_ID.sub('', program) or
+                       'unknown_module'}
+    for row, own in zip(rows, _self_durations(
+            [(r['ts'], r['dur']) for r in rows])):
+        if own != row['dur']:
+            row['self_dur'] = own
 
 
 def _attach_span_tracer():
@@ -335,9 +655,9 @@ def stop_profiler(sorted_key='total', profile_path=None):
             # detach even when the jax stop raises, or the attached
             # capture keeps recording (and buffering) forever
             host_cap = trace_mod.detach_capture()
-        device_events = _load_trace_events(_prof_trace_dir)
-        recs, stats = attribute_trace_events(device_events,
-                                             with_stats=True)
+        device_events = load_trace_events(_prof_trace_dir)
+        recs, stats = attribute_trace_events(
+            [e for e in device_events if 'args' in e], with_stats=True)
         _records.update(recs)
         if stats['dropped']:
             # malformed capture rows are counted, not silently eaten
@@ -419,9 +739,13 @@ def start_trace(logdir='/tmp/profile'):
 
 
 def stop_trace():
-    """Stop the device capture; returns the logdir.  The attached span
-    tracer's host events persist as '<logdir>/host_trace.json' for the
-    timeline merger."""
+    """Stop the device capture; returns the logdir.  The capture's
+    ``.xplane.pb`` is read back (``load_trace_events``): its per-op
+    device time becomes the profiler's table (``summary_records()`` /
+    ``summary_string()``), and its events persist as
+    '<logdir>/device.trace.json' beside the attached span tracer's
+    host events, '<logdir>/host_trace.json', for the timeline merger
+    (tools/timeline.py)."""
     global _trace_path
     from . import trace as trace_mod
     try:
@@ -432,12 +756,20 @@ def stop_trace():
         # force-enabled and its capture buffer grows unboundedly
         host_cap = trace_mod.detach_capture()
     path, _trace_path = _trace_path, None
-    if path is not None and host_cap is not None:
-        try:
+    if path is None:
+        return path
+    device_events = load_trace_events(path)
+    reset_profiler()
+    _records.update(attribute_trace_events(
+        [e for e in device_events if 'args' in e]))
+    try:
+        trace_mod.write_chrome(os.path.join(path, 'device.trace.json'),
+                               device_events)
+        if host_cap is not None:
             trace_mod.write_host_trace(
                 os.path.join(path, 'host_trace.json'), host_cap)
-        except OSError:
-            pass  # read-only logdir: device trace still usable
+    except OSError:
+        pass  # read-only logdir: the .xplane.pb is still usable
     return path
 
 

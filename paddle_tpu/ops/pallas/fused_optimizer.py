@@ -66,21 +66,28 @@ def _pack(tensors):
      spans [(first_block, nblocks, numel, shape)]).
 
     Per-tensor padding (not one tail pad) keeps every block owned by
-    exactly one tensor — the lamb partial-norm rows need that."""
+    exactly one tensor — the lamb partial-norm rows need that.
+
+    The ``pack`` scope (and ``unpack`` below) names this work in the
+    compiled program, under the fluid op's own scope, so a device
+    trace tells the copies around the kernel from the kernel
+    (fluid.profiler.hlo_scopes)."""
     flats, tids, spans = [], [], []
     off = 0
-    for i, t in enumerate(tensors):
-        n = int(np.prod(t.shape)) if t.shape else 1
-        nb = -(-n // BLOCK)
-        f = t.reshape(-1).astype(jnp.float32)
-        if nb * BLOCK - n:
-            f = jnp.concatenate(
-                [f, jnp.zeros((nb * BLOCK - n,), jnp.float32)])
-        flats.append(f)
-        tids.append(np.full((nb,), i, np.int32))
-        spans.append((off, nb, n, t.shape))
-        off += nb
-    slab = jnp.concatenate(flats).reshape(-1, BLOCK_ROWS, BLOCK_LANES)
+    with jax.named_scope('pack'):
+        for i, t in enumerate(tensors):
+            n = int(np.prod(t.shape)) if t.shape else 1
+            nb = -(-n // BLOCK)
+            f = t.reshape(-1).astype(jnp.float32)
+            if nb * BLOCK - n:
+                f = jnp.concatenate(
+                    [f, jnp.zeros((nb * BLOCK - n,), jnp.float32)])
+            flats.append(f)
+            tids.append(np.full((nb,), i, np.int32))
+            spans.append((off, nb, n, t.shape))
+            off += nb
+        slab = jnp.concatenate(flats).reshape(-1, BLOCK_ROWS,
+                                              BLOCK_LANES)
     return slab, np.concatenate(tids), spans
 
 
@@ -89,8 +96,9 @@ def _unpack(slab, spans):
     # whole flat slab is hoisted above the slice by XLA's simplifier,
     # and a [numel/2, 2] view of the full slab (an fc with 2 outputs)
     # pads 64x under the (8, 128) tiling — 28 GB at BERT-base
-    return [slab[off:off + nb].reshape(-1)[:n].reshape(shape)
-            for off, nb, n, shape in spans]
+    with jax.named_scope('unpack'):
+        return [slab[off:off + nb].reshape(-1)[:n].reshape(shape)
+                for off, nb, n, shape in spans]
 
 
 def _slab_spec():

@@ -498,6 +498,23 @@ def _wait_ready(proc, url, deadline):
     raise RuntimeError('worker at %s never became ready' % url)
 
 
+def _wait_steps(proc, url, deadline, steps):
+    """``/healthz/local`` answers as soon as the worker's status server
+    is up, before its first train step has compiled: wait until its own
+    counters say it has stepped ``steps`` times (a fixed sleep is too
+    short on a loaded machine)."""
+    while time.time() < deadline:
+        if proc.poll() is not None:
+            raise RuntimeError('worker died: rc=%d' % proc.returncode)
+        _code, body = _get(url + '/metrics.json')
+        counters = json.loads(body)['state']['counters']
+        # run_calls counts the startup program's run too
+        if counters.get('executor/run_calls', 0.0) > steps:
+            return
+        time.sleep(0.25)
+    raise RuntimeError('worker at %s never ran %d steps' % (url, steps))
+
+
 def test_two_process_collect_job_merged_timeline():
     """Acceptance: a real two-worker collective job collects into ONE
     schema-valid merged trace with both ranks' spans on a shared
@@ -510,6 +527,13 @@ def test_two_process_collect_job_merged_timeline():
     base_env.update({'JAX_PLATFORMS': 'cpu',
                      'PADDLE_TPU_STATUS_WORKERS': spec,
                      'FLAGS_health_heartbeat_seconds': '0.5',
+                     # each rank's dump is the window its flight
+                     # recorder retains, and the two are pulled one
+                     # after the other: keep every step since READY,
+                     # or on a loaded machine the default 16 steps
+                     # (~0.4 s) of one rank end before the other's
+                     # begin and "stepping concurrently" cannot show
+                     'FLAGS_trace_buffer_steps': '4096',
                      'FLAGS_trace': '1'})
     env0 = dict(base_env, PADDLE_TRAINER_ID='0',
                 PADDLE_TPU_STATUS_AGGREGATE='1')
@@ -528,7 +552,8 @@ def test_two_process_collect_job_merged_timeline():
         wrk = 'http://127.0.0.1:%d' % p1
         _wait_ready(procs[0], wrk, deadline)
         _wait_ready(procs[1], agg, deadline)
-        time.sleep(1.5)     # a few steps on both ranks
+        _wait_steps(procs[0], wrk, deadline, 3)
+        _wait_steps(procs[1], agg, deadline, 3)
 
         doc = trace.collect_job(workers=spec)
         assert not doc['ptJob']['skipped']

@@ -501,3 +501,54 @@ def test_fetch_set_keys_executable_identity(plane_dir):
         attrs={}, out_slot='Out')
     # and the distinct executables both landed in the store
     assert len(_seg_entries(plane_dir)) >= 2
+
+
+def test_listener_splits_built_from_loaded_and_times_the_stages(
+        tmp_path):
+    """JAX's own duration events, folded into fluid.monitor: one
+    program the compiler builds, then the same program served from the
+    persistent cache after jit's in-memory caches are dropped."""
+    import jax
+    import jax.numpy as jnp
+    names = ['compile/%s_%s' % (stage, kind)
+             for stage in ('trace', 'lower', 'backend_built',
+                           'backend_loaded')
+             for kind in ('seconds', 'count')]
+
+    def delta(before):
+        return {n: monitor.counter_value(n) - before[n] for n in names}
+
+    compile_cache.listen()      # the package does this at import
+    settings = {'jax_compilation_cache_dir': str(tmp_path / 'xla'),
+                'jax_persistent_cache_min_compile_time_secs': 0.0,
+                'jax_persistent_cache_min_entry_size_bytes': -1}
+    was = {k: getattr(jax.config, k) for k in settings}
+    for k, v in settings.items():
+        jax.config.update(k, v)
+    try:
+        def f(x):
+            return jnp.tanh(x) @ x + 23.0
+
+        x = jnp.ones((16, 16))
+        before = {n: monitor.counter_value(n) for n in names}
+        jax.jit(f)(x).block_until_ready()
+        built = delta(before)
+        assert built['compile/backend_built_count'] == 1
+        assert built['compile/backend_loaded_count'] == 0
+        assert built['compile/backend_built_seconds'] > 0
+        assert built['compile/trace_count'] >= 1
+        assert built['compile/lower_count'] == 1
+        assert built['compile/trace_seconds'] > 0
+        assert built['compile/lower_seconds'] > 0
+
+        jax.clear_caches()
+        before = {n: monitor.counter_value(n) for n in names}
+        jax.jit(f)(x).block_until_ready()
+        loaded = delta(before)
+        assert loaded['compile/backend_built_count'] == 0
+        assert loaded['compile/backend_loaded_count'] == 1
+        assert loaded['compile/backend_loaded_seconds'] > 0
+        assert loaded['compile/lower_count'] == 1
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)

@@ -301,6 +301,11 @@ def test_step_tags_attribution(exe):
     sc = fluid.Scope()
     with fluid.scope_guard(sc):
         exe.run(startup)
+        # the tracer is process-global and this worker ran other files
+        # before this one: start from an empty ring (enable() keeps
+        # what an earlier test's tagged steps left behind)
+        pt_trace.disable()
+        pt_trace.reset()
         pt_trace.enable(buffer_steps=8)
         try:
             with pt_trace.step_tags(tenant='t1', bucket=4):

@@ -330,3 +330,33 @@ def test_profiler_capture_attaches_tracer(tmp_path):
                 if e.get('ph') == 'X')
     assert {'bind', 'dispatch'} <= names
     assert host['ptSync'] is not None
+
+
+def test_parallel_runner_records_the_executor_phases():
+    """The data-parallel runner's step record holds the phases the
+    one-chip executor records, under the same names, plus the two
+    ``_to_global`` loops; the fetch is 'fetch_d2h' there too."""
+    import jax
+    main, startup, loss = _build()
+    x = np.random.RandomState(0).randn(8, 16).astype('float32')
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:4]), ('dp',))
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        target = fluid.CompiledProgram(main).with_data_parallel(
+            loss_name=loss.name).with_mesh(mesh)
+        exe.run(target, feed={'x': x}, fetch_list=[loss])   # compiles
+        exe.run(target, feed={'x': x}, fetch_list=[])
+        trace.enable(buffer_steps=8)
+        exe.run(target, feed={'x': x}, fetch_list=[])
+        exe.run(target, feed={'x': x}, fetch_list=[loss])
+        trace.disable()
+    quiet, fetching = trace.steps()
+    names = [s[0] for s in quiet['spans']]
+    assert names == ['bind', 'place_state', 'place_data', 'dispatch',
+                     'state_release']
+    assert [s[0] for s in fetching['spans']] == names + ['fetch_d2h']
+    # phases follow each other inside the step: none nests in another
+    spans = sorted(fetching['spans'], key=lambda s: s[1])
+    assert all(a[2] <= b[1] for a, b in zip(spans, spans[1:]))
+    assert fetching['t0'] <= spans[0][1] and spans[-1][2] <= fetching['t1']
