@@ -99,8 +99,7 @@ bench:
 # when no fleet exists; and the op-cost attribution plane must replay
 # a warmed LeNet into per-instance rows whose segment sums agree with
 # the step report's dispatch wall within 10%, emit a schema-valid
-# op_worklist.json naming >= 3 ranked candidates with the warmed adam
-# run cross-referenced to pallas/fused_optimizer, serve /statusz
+# op_worklist.json naming >= 3 ranked candidates, serve /statusz
 # op_costs + /opprof live, and cost one flag read per step when off
 check:
 	python tools/check_stat_coverage.py

@@ -1798,22 +1798,9 @@ def bench_kernels(rounds=6, per_round=4, warmup=3):
 
     rng = np.random.RandomState(0)
 
-    def opt_net():
+    def emb_net():
         # feed from a PINNED seed: both arms of an entry must see the
         # same batch or the cross-arm loss comparison is noise
-        feed_rng = np.random.RandomState(1)
-        main_p, startup = fluid.Program(), fluid.Program()
-        main_p.random_seed = startup.random_seed = 7
-        with fluid.program_guard(main_p, startup):
-            x = layers.data('x', shape=[128], dtype='float32')
-            h = layers.fc(x, 128, act='relu')
-            h = layers.fc(h, 128, act='relu')
-            loss = layers.reduce_mean(layers.square(layers.fc(h, 8)))
-            fluid.optimizer.Adam(1e-3).minimize(loss)
-        return main_p, startup, loss, \
-            {'x': feed_rng.rand(64, 128).astype('float32')}
-
-    def emb_net():
         feed_rng = np.random.RandomState(2)
         main_p, startup = fluid.Program(), fluid.Program()
         main_p.random_seed = startup.random_seed = 7
@@ -1829,8 +1816,7 @@ def bench_kernels(rounds=6, per_round=4, warmup=3):
 
     out = {}
     for kernel, build, flag in (
-            ('fused_optimizer', opt_net, 'FLAGS_pallas_opt_fuse'),
-            ('embedding_update', emb_net, 'FLAGS_pallas_embedding')):
+            ('embedding_update', emb_net, 'FLAGS_pallas_embedding'),):
         prev = fluid.get_flags([flag])
         disp0 = {k: monitor.counter_value('pallas/%s/dispatch_%s'
                                           % (kernel, k))

@@ -36,8 +36,9 @@ import numpy as np
 BF16_LOSS_RTOL = 1e-2
 # kernel-vs-dense elementwise on bf16 attention outputs / f32 grads
 BF16_ELEM_TOL = 3e-2
-# the kernels a BERT + Adam train step reaches
-KERNELS = ('flash_attention', 'embedding_lookup', 'fused_optimizer')
+# the kernels a BERT + Adam train step reaches (Adam itself is no
+# kernel: each parameter's own lowering, fused by XLA)
+KERNELS = ('flash_attention', 'embedding_lookup')
 
 _T0 = time.time()
 
@@ -247,14 +248,12 @@ def phase_attn_dropout(cfg, batch, seq):
 
 def phase_dense_lowerings(cfg, batch, seq, fused_losses):
     """Something independent, where it is cheap: the same seeded
-    program with the three kernel flags off runs the dense XLA
-    lowerings (jnp.take gather + XLA scatter-add, per-tensor Adam
-    chains).  Its first loss must agree with the fused run's; its
-    second has been through one backward and one Adam update of each
-    kind, so it holds the backward and optimizer kernels to the same
+    program with the kernel flags off runs the dense XLA lowerings
+    (jnp.take gather + XLA scatter-add).  Its first loss must agree
+    with the fused run's; its second has been through one backward and
+    one Adam update, so it holds the backward kernels to the same
     tolerance."""
     with flags_set({'FLAGS_pallas_embedding': False,
-                    'FLAGS_pallas_opt_fuse': False,
                     'FLAGS_pallas_quant_collective': False}):
         dense, secs, _ = train_fresh(cfg, batch, seq, 2)
     say('dense lowerings losses %s (fused %s); first step %.1f s, '
@@ -303,38 +302,18 @@ def phase_flash_vs_dense(b=2, t=1024, h=12, d=64, rate=0.1):
 
 
 def phase_kernels_bert_does_not_reach():
-    """lamb (two launches, per-tensor trust ratio from per-block
-    partials) and the fused adagrad row update compile for the chip but
-    no BERT + Adam step runs them: hold each to its dense lowering on
-    small seeded inputs."""
+    """The fused adagrad row update compiles for the chip but no BERT +
+    Adam step runs it: hold it to its dense lowering on small seeded
+    inputs."""
     import jax.numpy as jnp
     from paddle_tpu.ops import registry
-    from paddle_tpu.ops.pallas import embedding, fused_optimizer
+    from paddle_tpu.ops.pallas import embedding
     rng = np.random.RandomState(3)
 
     def f32(*shape):
         return jnp.asarray(rng.randn(*shape).astype('float32'))
 
-    shapes = [(768, 768), (768,), (768, 2), (3, 5, 7)]
-    ins = {'Param': [f32(*s) for s in shapes],
-           'Grad': [f32(*s) for s in shapes],
-           'Moment1': [f32(*s) for s in shapes],
-           'Moment2': [jnp.abs(f32(*s)) for s in shapes],
-           'LearningRate': [jnp.float32(1e-3 * (i + 1))
-                            for i in range(len(shapes))],
-           'Beta1Pow': [jnp.float32(0.9 ** (i + 1))
-                        for i in range(len(shapes))],
-           'Beta2Pow': [jnp.float32(0.999 ** (i + 1))
-                        for i in range(len(shapes))]}
     ctx = registry.LowerCtx(0)
-    fused = fused_optimizer.apply('lamb', ctx, ins, {})
-    dense = fused_optimizer._dense('lamb', ctx, ins, {})
-    err = max(float(jnp.max(jnp.abs(a - b)))
-              for slot in ('ParamOut', 'Moment1Out', 'Moment2Out')
-              for a, b in zip(fused[slot], dense[slot]))
-    check(err <= 1e-5, 'fused lamb vs per-tensor dense lamb: max abs '
-          'err %.2e <= 1e-5 over %d tensors' % (err, len(shapes)))
-
     rows, width, n = 2048, 256, 512
     ids = jnp.asarray(rng.randint(0, 64, (n,)).astype('int32'))  # dups
     upd = {'Param': [f32(rows, width)],
@@ -386,7 +365,7 @@ def phase_four_chips(cfg, global_batch, seq, steps, n=4):
     program: (a) with_data_parallel on a dp mesh, (b) dp x mp=2 with
     __graft_entry__'s column-parallel rule.  First-step losses agree
     and losses fall; parameters and the batch really lie on every
-    device.  The three kernels of the step dispatch fused in the
+    device.  The two kernels of the step dispatch fused in the
     single-device run and answer dense under either mesh, every time
     for the counted reason `auto_partitioned` (XLA cannot partition a
     Mosaic kernel; ops/pallas/common.py dispatch())."""

@@ -379,96 +379,6 @@ def _op_dep_reads(op):
     return names
 
 
-# optimizer types the pallas multi-tensor kernel can batch -> their
-# registered fused op type (ops/optimizer_ops.py)
-_FUSABLE_OPT = {'adam': 'fused_adam', 'adamw': 'fused_adamw',
-                'lamb': 'fused_lamb'}
-
-
-def _opt_group_key(op):
-    """Hyperparameters a fused run must share (they become compile-time
-    kernel constants); per-tensor lr / beta-pow stay per-op inputs."""
-    a = op.attrs
-    key = (op.type, a.get('beta1', 0.9), a.get('beta2', 0.999),
-           a.get('epsilon', 1e-6 if op.type == 'lamb' else 1e-8))
-    if op.type == 'adamw':
-        key += (a.get('coeff', 0.01),)
-    elif op.type == 'lamb':
-        key += (a.get('weight_decay', 0.01),)
-    return key
-
-
-def _fused_opt_run(ops, i):
-    """Maximal contiguous run of same-type/same-hyper optimizer ops
-    starting at ops[i] with no read-after-write hazard inside the run
-    (op j must not read anything an earlier run member wrote).
-    Returns the run list, or None when grouping is off / too short."""
-    from .flags import get_flag
-    if not get_flag('FLAGS_pallas_opt_fuse', True):
-        return None
-    key = _opt_group_key(ops[i])
-    run = [ops[i]]
-    written = set(_op_writes(ops[i]))
-    j = i + 1
-    while j < len(ops) and ops[j].type == ops[i].type and \
-            _opt_group_key(ops[j]) == key:
-        reads = {n for ns in ops[j].inputs.values() for n in ns}
-        if reads & written:
-            break
-        run.append(ops[j])
-        written.update(_op_writes(ops[j]))
-        j += 1
-    min_n = max(2, int(get_flag('FLAGS_pallas_opt_min_tensors', 2)))
-    return run if len(run) >= min_n else None
-
-
-def _lower_fused_opt_run(run, env, step, prefer_test):
-    """Lower a grouped optimizer run through its fused_<type> op: each
-    input slot carries the whole run's tensors aligned by run order,
-    and the fused outputs scatter back to each member op's outputs."""
-    fused_type = _FUSABLE_OPT[run[0].type]
-    opdef = registry.get(fused_type)
-    ins = {}
-    for op in run:
-        for slot, names in op.inputs.items():
-            if not names:
-                continue
-            try:
-                ins.setdefault(slot, []).extend(env[n] for n in names)
-            except KeyError as e:
-                err = RuntimeError(
-                    'op %s reads undefined var %s' % (op.type, e))
-                _add_note(err, _op_error_context(op, {}))
-                raise err from e
-    ctx = registry.LowerCtx(step, run[0].attrs.get('__op_seed__', 0),
-                            prefer_test)
-    # instance provenance (FLAGS_opprof): the fused run anchors its
-    # scope at the first member's block index, so a device capture
-    # still resolves the launch to a specific op desc.  Trace-time
-    # only, and never part of the segment fingerprint.
-    scope_name = (_opprof.op_scope(run[0], fused_type)
-                  if _opprof.instancing() else fused_type)
-    try:
-        with jax.named_scope(scope_name):
-            outs = opdef.run(ctx, ins, dict(run[0].attrs))
-    except Exception as e:
-        _add_note(e, 'while lowering a fused run of %d %s ops (%s)'
-                  % (len(run), run[0].type,
-                     ', '.join(op.outputs.get('ParamOut', ['?'])[0]
-                               for op in run)))
-        raise
-    cursor = {}
-    for op in run:
-        for slot, names in op.outputs.items():
-            vals = outs.get(slot)
-            if not vals:
-                continue
-            k = cursor.get(slot, 0)
-            for n, v in zip(names, vals[k:k + len(names)]):
-                env[n] = v
-            cursor[slot] = k + len(names)
-
-
 def _lower_ops(ops, env, step, prefer_test):
     """Run a list of ops' lowering rules over a functional env."""
     CF_LOWERINGS = {'while': _lower_while,
@@ -481,22 +391,13 @@ def _lower_ops(ops, env, step, prefer_test):
     # op descs + specs + lowering flags), so this flag is
     # fingerprint-neutral: flipping it causes zero retraces.
     inst = _opprof.instancing()
-    i = 0
-    while i < len(ops):
-        op = ops[i]
+    for op in ops:
         cf = CF_LOWERINGS.get(op.type)
         if cf is not None:
             with jax.named_scope(_opprof.op_scope(op) if inst
                                  else op.type):
                 cf(op, env, step, prefer_test)
-            i += 1
             continue
-        if op.type in _FUSABLE_OPT:
-            run = _fused_opt_run(ops, i)
-            if run is not None:
-                _lower_fused_opt_run(run, env, step, prefer_test)
-                i += len(run)
-                continue
         opdef = registry.get(op.type)
         ins = {}
         for slot, names in op.inputs.items():
@@ -530,7 +431,6 @@ def _lower_ops(ops, env, step, prefer_test):
             vals = outs.get(slot, [])
             for n, v in zip(names, vals):
                 env[n] = v
-        i += 1
 
 
 def _subblock_carry(sub_ops, env):
@@ -1106,8 +1006,6 @@ def _pallas_flag_items():
     persistent fingerprint and the per-step in-memory cache key."""
     from .flags import get_flag
     return (bool(get_flag('FLAGS_pallas_force', False)),
-            bool(get_flag('FLAGS_pallas_opt_fuse', True)),
-            int(get_flag('FLAGS_pallas_opt_min_tensors', 2)),
             bool(get_flag('FLAGS_pallas_embedding', True)),
             int(get_flag('FLAGS_pallas_embedding_min_rows', 512)),
             bool(get_flag('FLAGS_pallas_quant_collective', True)))

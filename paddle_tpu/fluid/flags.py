@@ -298,8 +298,10 @@ _DEFAULTS = {
     # f32 conv MXU precision: 'highest' (6-pass bf16 emulation,
     # reference-accurate fp32 — the default), 'high' (3-pass), or
     # 'default' (single-pass bf16 inputs): accuracy against speed.
-    # (LeNet b512 at 'highest' compiles on the v5e: chip_smoke.py
-    # prints the line.)
+    # The multi-pass convolutions are kept out of XLA's fusions
+    # (ops/nn_ops.py conv2d): fused with a neighbour, LeNet b512 at
+    # 'highest' does not compile on the v5e; chip_smoke.py prints the
+    # line.
     'FLAGS_conv_precision': 'highest',
     # windowed history plane (fluid/timeseries.py): on, the executor's
     # step boundary and the rank-0 aggregator's heartbeat each append
@@ -391,24 +393,14 @@ _DEFAULTS = {
     # exercise the kernels on the CPU mesh; never set it in
     # production.
     'FLAGS_pallas_force': False,
-    # fused multi-tensor optimizer updates: consecutive same-hyper
-    # adam/adamw/lamb ops in a segment collapse into one fused_<type>
-    # launch over flattened parameter slabs (lamb's per-param
-    # trust-ratio reduction included).  Off restores the per-param
-    # elementwise chains bit for bit.  First chip reading (PR 21,
-    # smoke, one run): with this and FLAGS_pallas_embedding on,
-    # BERT-base b4 s2048 steps in 158 ms against 109 ms with both off
-    # — repair from a trace or delete is ROADMAP S1's next issue.
-    'FLAGS_pallas_opt_fuse': True,
-    # minimum run length before the optimizer grouping pays for
-    # itself (packing/unpacking a single tensor buys nothing)
-    'FLAGS_pallas_opt_min_tensors': 2,
     # fused sparse embedding path: lookup_table(_v2) gathers through
     # the Pallas row-gather kernel (scatter-add custom-vjp backward),
     # and AdagradOptimizer rewrites eligible embedding updates into
     # one fused_emb_update over only the touched rows, replacing the
     # dense scatter + full-table update lowering.  Slower than the
-    # dense lowering on the chip today: see FLAGS_pallas_opt_fuse.
+    # dense lowering on the chip today (BERT-base s128 b192, PR 23's
+    # trace: lookup_table_v2* 15.7 ms a step against 2.5 ms dense) —
+    # repair from a trace or delete is ROADMAP S1's next issue.
     'FLAGS_pallas_embedding': True,
     # vocab-rows floor for the embedding kernel: small tables stay on
     # the dense gather (bit-exact) where XLA already wins

@@ -48,9 +48,8 @@ still get a cost table whose segment sums agree with
 are kept; nothing is vibes).
 
 **The worklist.**  ``kernel_worklist()`` ranks contiguous
-same-segment op runs (maximal same-type runs — the shape the existing
-fused multi-tensor kernels consume) by attributable ms/step and bytes
-moved, cross-references the ``ops/pallas/common.py`` dispatch
+same-segment op runs (maximal same-type runs) by attributable ms/step
+and bytes moved, cross-references the ``ops/pallas/common.py`` dispatch
 registry's declared ``op_types`` coverage to mark runs a fused kernel
 already serves, and ``write_worklist()`` emits ``op_worklist.json`` —
 the artifact ROADMAP item 5's next kernels are chosen from.
@@ -144,15 +143,12 @@ def _block_index(op):
     return idx.get(id(op), -1)
 
 
-def op_scope(op, type_name=None):
+def op_scope(op):
     """The instance scope name for an op desc: ``<type>#<block-index>``.
     Stable across retraces of the same Program (the block's op list is
     the identity), and what a device capture's ``tf_op`` path carries
-    back when ``FLAGS_opprof`` was on at trace time.  ``type_name``
-    overrides the leading component (the fused-optimizer runs lower
-    under their ``fused_<type>`` name, anchored at the run's first
-    member)."""
-    return '%s#%d' % (type_name or op.type, _block_index(op))
+    back when ``FLAGS_opprof`` was on at trace time."""
+    return '%s#%d' % (op.type, _block_index(op))
 
 
 def split_instance(name):
@@ -517,10 +513,8 @@ def kernel_worklist(limit=TOP_K):
     """Rank contiguous same-segment op runs by attributable ms/step
     (tie: bytes moved, then name — deterministic).  A run is a maximal
     sequence of same-type instances adjacent in their segment's op
-    order — the shape the existing fused multi-tensor kernels consume
-    (a run of ``sgd`` ops -> one fused launch).  Each run
-    cross-references the pallas dispatch registry's declared
-    ``op_types`` coverage: ``covered_by`` names the kernel that
+    order.  Each run cross-references the pallas dispatch registry's
+    declared ``op_types`` coverage: ``covered_by`` names the kernel that
     already serves it (worklist readers skip those, or read them as
     validation that the ranking finds the kernels we already built)."""
     try:

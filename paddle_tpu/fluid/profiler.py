@@ -248,7 +248,7 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
 #   ``transpose`` among them makes it that type's backward
 #   (``mul_grad``, the name the explicit grad op lowers under);
 # - a plain named scope the lowering itself opened right under the op's
-#   is kept as ``<type>/<scope>`` (``fused_adam/pack``);
+#   is kept as ``<type>/<scope>``;
 # - a fusion counts to the ``dot`` / ``convolution`` / custom call it
 #   holds, else to its root; a root that carries no scope (the tuple of
 #   a multi-output fusion, a bitcast or copy XLA put there) stands for
@@ -280,7 +280,7 @@ def _is_op_type(name, op_types):
 def fluid_scope(op_name, op_types=None):
     """The fluid op an HLO instruction was lowered from, by the rule
     above, from the ``op_name`` of its metadata: ``'mul'``,
-    ``'mul_grad'``, ``'fused_adam/pack'``, or None."""
+    ``'mul_grad'``, ``'<type>/<scope>'``, or None."""
     if not op_name:
         return None
     op_types = op_types or _registered_op_types()

@@ -77,14 +77,26 @@ def conv2d(ctx, ins, attrs):
         # stay bf16 in HBM end-to-end — black-list ops cast up to f32
         # themselves
         x, w = x.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
+    precision = _f32_conv_precision() if x.dtype == jnp.float32 else None
+    # the multi-pass (6- / 3-pass bf16) f32 convolution stands alone:
+    # once XLA fuses an elementwise neighbour into it (relu-grad and
+    # bias-grad producers of the cotangent, an optimizer update
+    # consuming the weight gradient) the v5e compiler does not finish
+    # LeNet b512 (host memory past 40 GB; any dense optimizer).  The
+    # barriers transpose onto the cotangents, so they hold the
+    # derived backward convolutions too.
+    alone = precision in (jax.lax.Precision.HIGHEST,
+                          jax.lax.Precision.HIGH)
+    if alone:
+        x, w = jax.lax.optimization_barrier((x, w))
     out = jax.lax.conv_general_dilated(
         x, w, window_strides=strides, padding=pad,
         rhs_dilation=dilations, feature_group_count=groups,
-        dimension_numbers=dn,
-        precision=(_f32_conv_precision()
-                   if x.dtype == jnp.float32 else None),
+        dimension_numbers=dn, precision=precision,
         preferred_element_type=None if amp else (
             jnp.float32 if x.dtype != jnp.float64 else None))
+    if alone:
+        out = jax.lax.optimization_barrier(out)
     if not amp:
         out = out.astype(ins['Input'][0].dtype)
     return {'Output': [out]}

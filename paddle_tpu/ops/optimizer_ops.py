@@ -213,32 +213,6 @@ def lamb(ctx, ins, attrs):
             'Beta2PowOut': [(b2p * b2).reshape(ins['Beta2Pow'][0].shape)]}
 
 
-# ---- fused multi-tensor updates (ops/pallas/fused_optimizer.py) ----
-# Registered real op types: the executor's run grouping lowers a
-# contiguous run of same-hyper adam/adamw/lamb ops through one of
-# these (every input slot carries the whole run's tensors, aligned by
-# index), profiler trace attribution picks the name up, and progcheck
-# walks them like any op.  Off-TPU / gate failure they fall back to
-# the per-tensor lowerings above, bit for bit.
-
-@register('fused_adam')
-def fused_adam(ctx, ins, attrs):
-    from .pallas import fused_optimizer
-    return fused_optimizer.apply('adam', ctx, ins, attrs)
-
-
-@register('fused_adamw')
-def fused_adamw(ctx, ins, attrs):
-    from .pallas import fused_optimizer
-    return fused_optimizer.apply('adamw', ctx, ins, attrs)
-
-
-@register('fused_lamb')
-def fused_lamb(ctx, ins, attrs):
-    from .pallas import fused_optimizer
-    return fused_optimizer.apply('lamb', ctx, ins, attrs)
-
-
 @register('fused_emb_update')
 def fused_emb_update(ctx, ins, attrs):
     """Sparse embedding-table adagrad over only the touched rows:

@@ -313,3 +313,35 @@ class TestAccuracyOp(OpTest):
                            'Indices': idx, 'Label': label},
                           out_slots=('Accuracy',))
         np.testing.assert_allclose(got['Accuracy'], 2.0 / 3.0, rtol=1e-6)
+
+
+@pytest.mark.parametrize('conv_precision,amp,barriers', [
+    ('highest', False, True), ('high', False, True),
+    ('default', False, False), ('highest', True, False)])
+def test_multi_pass_f32_conv_stands_alone(conv_precision, amp, barriers):
+    """The 6- / 3-pass f32 convolution and the backward convolutions jax
+    derives from it sit between optimization barriers (the v5e compiler
+    does not finish LeNet b512 once a neighbour fuses into one); a
+    single-pass or bf16 convolution stays free to fuse."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fluid.flags import get_flag, set_flags
+    from paddle_tpu.ops import registry
+    x = jnp.asarray(rng.randn(2, 3, 8, 8).astype('float32'))
+    w = jnp.asarray(rng.randn(4, 3, 3, 3).astype('float32'))
+    attrs = {'strides': [1, 1], 'paddings': [1, 1], '__amp__': amp}
+
+    def loss(x, w):
+        out = registry.get('conv2d').fn(
+            registry.LowerCtx(0), {'Input': [x], 'Filter': [w]}, attrs)
+        return jnp.sum(jax.nn.relu(out['Output'][0]).astype(jnp.float32))
+
+    was = get_flag('FLAGS_conv_precision')
+    set_flags({'FLAGS_conv_precision': conv_precision})
+    try:
+        text = jax.jit(jax.grad(loss, (0, 1))).lower(x, w).as_text()
+    finally:
+        set_flags({'FLAGS_conv_precision': was})
+    assert text.count('stablehlo.convolution') == 3
+    # forward inputs and output, and their two cotangent transposes
+    assert text.count('optimization_barrier') == (4 if barriers else 0)

@@ -207,3 +207,236 @@ def test_beta_pow_advances_once_per_step_adam_and_lamb():
         assert pows, opt_cls
         for pw in pows:
             assert abs(pw - 0.9) < 1e-6, (opt_cls.__name__, pows)
+
+
+# ----- adam / adamw / lamb: one registered lowering per parameter -----
+# The only way these ops are lowered, on every platform and under every
+# runner: each through its own lowering in its own named scope, XLA
+# fusing the elementwise chain per parameter and updating the donated
+# state in place.
+
+def _adam_family_ins(n_tensors, seed, zero_idx=None):
+    """One {slot: array} per tensor: distinct shapes, learning rates
+    and beta powers; tensor ``zero_idx`` has zero gradient and
+    moments."""
+    r = np.random.RandomState(seed)
+    shapes = [(33, 47), (128,), (5, 8, 13), (257,)][:n_tensors]
+    out = []
+    for i, s in enumerate(shapes):
+        live = 0.0 if zero_idx == i else 1.0
+        out.append({
+            'Param': r.randn(*s).astype('float32'),
+            'Grad': live * r.randn(*s).astype('float32'),
+            'Moment1': live * r.randn(*s).astype('float32'),
+            'Moment2': live * np.abs(r.randn(*s)).astype('float32'),
+            'LearningRate': np.array([0.001 * (i + 1)], 'float32'),
+            'Beta1Pow': np.array([0.9 ** (i + 1)], 'float32'),
+            'Beta2Pow': np.array([0.999 ** (i + 1)], 'float32')})
+    return out
+
+
+def _adam_family_reference(kind, ins, attrs):
+    """The update in float64 NumPy, from the reference operators'
+    formulas (adam_op.h, lamb_op.h)."""
+    p, g, m1, m2 = (ins[k].astype('float64') for k in
+                    ('Param', 'Grad', 'Moment1', 'Moment2'))
+    lr, b1p, b2p = (float(ins[k][0]) for k in
+                    ('LearningRate', 'Beta1Pow', 'Beta2Pow'))
+    b1, b2 = attrs['beta1'], attrs['beta2']
+    m1n = b1 * m1 + (1 - b1) * g
+    m2n = b2 * m2 + (1 - b2) * g * g
+    if kind == 'lamb':
+        eps, wd = attrs.get('epsilon', 1e-6), attrs['weight_decay']
+        r = m1n / (1 - b1p * b1) / \
+            (np.sqrt(m2n / (1 - b2p * b2)) + eps) + wd * p
+        pn, rn = np.sqrt((p * p).sum()), np.sqrt((r * r).sum())
+        trust = pn / rn if pn > 0 and rn > 0 else 1.0
+        pout = p - lr * trust * r
+    else:
+        eps = attrs.get('epsilon', 1e-8)
+        lr_t = lr * np.sqrt(1 - b2p * b2) / (1 - b1p * b1)
+        pout = p - lr_t * m1n / (np.sqrt(m2n) + eps)
+        if kind == 'adamw':
+            pout = pout - lr * attrs['coeff'] * p
+    return {'ParamOut': pout, 'Moment1Out': m1n, 'Moment2Out': m2n,
+            'Beta1PowOut': np.array([b1p * b1]),
+            'Beta2PowOut': np.array([b2p * b2])}
+
+
+@pytest.mark.parametrize('kind,attrs', [
+    ('adam', {'beta1': 0.9, 'beta2': 0.999}),
+    ('adamw', {'beta1': 0.9, 'beta2': 0.999, 'coeff': 0.02}),
+    ('lamb', {'beta1': 0.9, 'beta2': 0.999, 'weight_decay': 0.01}),
+])
+def test_adam_family_per_tensor_lowering_vs_numpy(kind, attrs):
+    for ins in _adam_family_ins(4, seed=3):
+        got = run_lowering(kind, ins, attrs)
+        want = _adam_family_reference(kind, ins, attrs)
+        assert set(got) == set(want)
+        for slot in want:
+            np.testing.assert_allclose(
+                np.asarray(got[slot][0]), want[slot], rtol=3e-6,
+                atol=3e-7, err_msg='%s %s %s' % (
+                    kind, slot, ins['Param'].shape))
+            assert got[slot][0].dtype == np.float32
+
+
+def test_lamb_zero_r_norm_keeps_trust_one():
+    """A tensor whose r-norm is zero (zero gradient, moments and weight
+    decay) takes the trust = 1 branch and stays where it was; its
+    neighbours get ||p|| / ||r||, each its own."""
+    attrs = {'beta1': 0.9, 'beta2': 0.999, 'weight_decay': 0.0}
+    tensors = _adam_family_ins(3, seed=7, zero_idx=1)
+    for i, ins in enumerate(tensors):
+        got = np.asarray(run_lowering('lamb', ins, attrs)['ParamOut'][0])
+        assert np.isfinite(got).all()
+        if i == 1:
+            assert np.array_equal(got, ins['Param'])
+            continue
+        want = _adam_family_reference('lamb', ins, attrs)['ParamOut']
+        np.testing.assert_allclose(got, want, rtol=3e-6, atol=3e-7)
+        # the step's length is lr * ||p||: the trust ratio was applied
+        np.testing.assert_allclose(
+            np.linalg.norm(got - ins['Param']),
+            float(ins['LearningRate'][0]) * np.linalg.norm(ins['Param']),
+            rtol=1e-4)
+
+
+_ADAM_FAMILY = {
+    'adam': lambda: fluid.optimizer.Adam(1e-2),
+    'adamw': lambda: fluid.optimizer.AdamW(1e-2, weight_decay=0.01),
+    'lamb': lambda: fluid.optimizer.Lamb(1e-2),
+}
+
+
+def _mlp_with(kind, width=256):
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 11
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data('x', shape=[64], dtype='float32')
+        h = fluid.layers.fc(x, width, act='relu')
+        h = fluid.layers.fc(h, width, act='relu')
+        loss = fluid.layers.reduce_mean(fluid.layers.fc(h, 4))
+        _ADAM_FAMILY[kind]().minimize(loss)
+    return main, startup, loss
+
+
+@pytest.mark.parametrize('kind', sorted(_ADAM_FAMILY))
+def test_adam_family_program_lowers_one_scope_per_parameter(kind):
+    """What Executor.run traces for a six-parameter program: six ops of
+    the type, each under its own scope (FLAGS_opprof's instance suffix
+    tells them apart), no fused_* op, no Mosaic call, and no
+    concatenate (the packed path built parameter-sized ones)."""
+    import re
+    import jax
+    main, startup, loss = _mlp_with(kind)
+    block = main.global_block()
+    updates = [i for i, op in enumerate(block.ops) if op.type == kind]
+    assert len(updates) == 6
+    assert not [op.type for op in block.ops
+                if op.type.startswith('fused_')]
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        step = exe.compile(main, feed_names=['x'], fetch_names=[loss])
+        scope = fluid.global_scope()
+
+        def spec(name):
+            a = np.asarray(fluid.core.as_array(scope.find_var(name)))
+            return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+        state = {n: spec(n) for n in step.state_names}
+        data = {n: spec(n) for n in step.input_names if n != 'x'}
+    data['x'] = jax.ShapeDtypeStruct((4, 64), np.float32)
+    fluid.set_flags({'FLAGS_opprof': True})
+    try:
+        text = jax.jit(step.fn, donate_argnums=(1,)).lower(
+            np.int32(0), state, data).as_text(debug_info=True)
+    finally:
+        fluid.set_flags({'FLAGS_opprof': False})
+    scopes = set(re.findall(r'/(%s#\d+)/' % kind, text))
+    assert scopes == {'%s#%d' % (kind, i) for i in updates}, scopes
+    assert 'fused_' + kind not in text
+    assert 'tpu_custom_call' not in text
+    assert 'concatenate' not in text
+
+
+def test_adam_segment_temporaries_stay_under_one_parameter_copy():
+    """The optimizer ops of a program, compiled alone with their state
+    donated: the updates alias their inputs and XLA's temporaries stay
+    far under one copy of the parameters (packing four operand sets
+    into slabs and slicing three back needed seven)."""
+    import jax
+    from paddle_tpu.fluid import executor
+    main, _, _ = _mlp_with('adam', width=512)
+    block = main.global_block()
+    ops = [op for op in block.ops if op.type == 'adam']
+    state_names = sorted({n for op in ops for ns in op.outputs.values()
+                          for n in ns})
+    read_names = sorted({n for op in ops for ns in op.inputs.values()
+                         for n in ns} - set(state_names))
+
+    def spec(name):
+        v = block.var(name)
+        return jax.ShapeDtypeStruct(tuple(v.shape), np.dtype(v.dtype))
+
+    def update(state, reads):
+        env = dict(reads, **state)
+        executor._lower_ops(ops, env, np.int32(0), False)
+        return {n: env[n] for n in state_names}
+
+    compiled = jax.jit(update, donate_argnums=(0,)).lower(
+        {n: spec(n) for n in state_names},
+        {n: spec(n) for n in read_names}).compile()
+    param_bytes = sum(
+        4 * int(np.prod(block.var(op.inputs['Param'][0]).shape))
+        for op in ops)
+    mem = compiled.memory_analysis()
+    assert param_bytes > 1 << 20
+    assert mem.temp_size_in_bytes < param_bytes / 4, mem
+    # params and both moments are updated in place
+    assert mem.alias_size_in_bytes >= 3 * param_bytes, mem
+
+
+@pytest.mark.parametrize('suffix,value', [('fuse', False),
+                                          ('min_tensors', 8)])
+def test_removed_optimizer_grouping_flags_are_unknown_flags(suffix,
+                                                            value):
+    """The two knobs of the removed packed path are no flags any more:
+    no default, no environment pick-up, and setting one neither
+    re-keys a compiled program nor changes what it computes."""
+    from paddle_tpu.fluid import executor, flags, monitor
+    name = 'FLAGS_pallas_opt_' + suffix
+    assert name not in flags._DEFAULTS
+    assert fluid.get_flags(name) == {name: None}
+    main, startup, loss = _mlp_with('adam', width=16)
+    feed = {'x': np.random.RandomState(0).randn(4, 64).astype('float32')}
+
+    def train(flip_after):
+        """Three steps on one live executor; the flag is set after
+        step ``flip_after``.  Returns the losses and the segments
+        lowered since the flag was set."""
+        with fluid.scope_guard(fluid.Scope()):
+            exe = fluid.Executor(fluid.XLAPlace(0))
+            exe.run(startup)
+            losses, lowered = [], None
+            for i in range(3):
+                if i == flip_after:
+                    assert executor._pallas_flag_items() == key
+                    fluid.set_flags({name: value})
+                    assert executor._pallas_flag_items() == key
+                    lowered = monitor.counter_value(
+                        'executor/segments_lowered')
+                losses.append(np.asarray(
+                    exe.run(main, feed=feed, fetch_list=[loss])[0]))
+            return losses, lowered
+
+    key = executor._pallas_flag_items()
+    base, _ = train(None)
+    try:
+        again, lowered = train(1)
+    finally:
+        flags._flags.pop(name, None)
+    assert monitor.counter_value('executor/segments_lowered') == lowered
+    for a, b in zip(base, again):
+        assert np.array_equal(a, b)

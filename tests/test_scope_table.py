@@ -190,17 +190,15 @@ def _assert_table_covers(program_types, scopes, must_have, absorbed):
     op into a neighbour's (a residual add into its convolution), or
     because nothing read the op's result."""
     forward = {t for t in program_types if not t.endswith('_grad')}
-    # every other op type of the program that computes appears, its
-    # fused run standing for an optimizer op
-    missing = {t for t in forward - _LAYOUT_ONLY
-               if not (scopes[t] or scopes['fused_' + t])}
+    # every other op type of the program that computes appears
+    missing = {t for t in forward - _LAYOUT_ONLY if not scopes[t]}
     assert missing == absorbed, (sorted(missing), sorted(scopes))
     assert must_have <= set(scopes), sorted(scopes)
-    # and the table invents none: a scope is an op of the program, the
-    # backward jax derived inside one, or an optimizer's fused run
+    # and the table invents none: a scope is an op of the program or
+    # the backward jax derived inside one
     for t in scopes:
         base = t[:-5] if t.endswith('_grad') else t
-        assert base in forward or base[len('fused_'):] in forward, t
+        assert base in forward, t
 
 
 def test_scope_table_of_a_tiny_bert_program():
@@ -229,7 +227,7 @@ def test_scope_table_of_a_tiny_bert_program():
         program_types, scopes,
         {'lookup_table_v2', 'lookup_table_v2_grad', 'mul', 'mul_grad',
          'matmul', 'matmul_grad', 'softmax', 'layer_norm_grad',
-         'fused_adam', 'check_finite_and_unscale'},
+         'adam', 'check_finite_and_unscale'},
         absorbed={'elementwise_mul'})
 
 

@@ -10,8 +10,8 @@ auto-dispatch + dense-fallback contract:
   2. parity: each kernel's forced-fused (interpret) path against its
      dense reference on CPU — bitwise where the reference is exact
      (embedding gather/scatter, blockwise quantize), tolerance-bounded
-     where the compiled kernel body may contract FMAs (optimizer
-     updates);
+     where the compiled kernel body may contract FMAs (the adagrad
+     row update);
   3. observability: every dispatch lands a pallas/<kernel>/dispatch_*
      counter and a last-decision record with a reason, and the
      /statusz pallas section renders them — a silent dense fallback
@@ -26,8 +26,8 @@ Run from `make check` (CPU: JAX_PLATFORMS=cpu).
 import os
 import sys
 
-EXPECTED = ('flash_attention', 'fused_optimizer', 'embedding_lookup',
-            'embedding_update', 'quant_collective')
+EXPECTED = ('flash_attention', 'embedding_lookup', 'embedding_update',
+            'quant_collective')
 
 
 def main():
@@ -41,8 +41,7 @@ def main():
     from paddle_tpu.fluid import health, monitor
     from paddle_tpu.fluid.flags import _DEFAULTS
     from paddle_tpu.ops import registry
-    from paddle_tpu.ops.pallas import (common, embedding,
-                                       fused_optimizer, quant_collective)
+    from paddle_tpu.ops.pallas import common, embedding, quant_collective
 
     failures = []
 
@@ -58,40 +57,6 @@ def main():
 
     # -- 2. parity, forced-fused vs dense ----------------------------
     rng = np.random.RandomState(0)
-
-    def opt_ins():
-        ins = {k: [] for k in ('Param', 'Grad', 'Moment1', 'Moment2',
-                               'LearningRate', 'Beta1Pow', 'Beta2Pow')}
-        for i, s in enumerate([(17, 9), (70,)]):
-            ins['Param'].append(jnp.asarray(
-                rng.randn(*s).astype('float32')))
-            ins['Grad'].append(jnp.asarray(
-                rng.randn(*s).astype('float32')))
-            ins['Moment1'].append(jnp.asarray(
-                rng.randn(*s).astype('float32')))
-            ins['Moment2'].append(jnp.asarray(
-                np.abs(rng.randn(*s)).astype('float32')))
-            ins['LearningRate'].append(
-                jnp.asarray(np.float32(0.01 * (i + 1))))
-            ins['Beta1Pow'].append(jnp.asarray(np.float32(0.9)))
-            ins['Beta2Pow'].append(jnp.asarray(np.float32(0.999)))
-        return ins
-
-    for kind in ('adam', 'adamw', 'lamb'):
-        ins = opt_ins()
-        fluid.set_flags({'FLAGS_pallas_force': True})
-        fused = fused_optimizer.apply(kind, registry.LowerCtx(0), ins,
-                                      {})
-        fluid.set_flags({'FLAGS_pallas_force': False})
-        dense = fused_optimizer._dense(kind, registry.LowerCtx(0), ins,
-                                       {})
-        for slot in dense:
-            for a, b in zip(fused[slot], dense[slot]):
-                if not np.allclose(np.asarray(a), np.asarray(b),
-                                   rtol=2e-6, atol=3e-7):
-                    failures.append('fused_optimizer %s %s parity'
-                                    % (kind, slot))
-
     w = jnp.asarray(rng.randn(600, 8).astype('float32'))
     ids = jnp.asarray(np.array([3, 3, 0, 599, 3], np.int64))
     fluid.set_flags({'FLAGS_pallas_force': True})
@@ -137,12 +102,10 @@ def main():
     if not (np.array_equal(np.asarray(qv), np.asarray(qref)) and
             np.array_equal(np.asarray(s), np.asarray(sref))):
         failures.append('quantize_blocks not bitwise vs dense q()')
-    print('parity: optimizer x3, embedding lookup/grad/update, '
-          'quantize_blocks ok')
+    print('parity: embedding lookup/grad/update, quantize_blocks ok')
 
     # -- 3. dispatch observability -----------------------------------
-    for name in ('fused_optimizer', 'embedding_lookup',
-                 'embedding_update'):
+    for name in ('embedding_lookup', 'embedding_update'):
         got = monitor.counter_value(
             'pallas/%s/dispatch_fused' % name) + \
             monitor.counter_value('pallas/%s/dispatch_dense' % name)
@@ -158,7 +121,7 @@ def main():
     if not rep or not rep.get('kernels'):
         failures.append('/statusz pallas section missing or empty')
     else:
-        for name in ('fused_optimizer', 'embedding_lookup'):
+        for name in ('embedding_lookup', 'embedding_update'):
             if name not in rep['kernels']:
                 failures.append('/statusz pallas section lacks %r'
                                 % name)
