@@ -33,6 +33,9 @@ def _mark_amp_ops(program, amp_lists):
     # rule is theirs for free without degrading the parameters
     no_harmonize = {'batch_norm', 'layer_norm', 'instance_norm',
                     'group_norm', 'sync_batch_norm',
+                    'rms_norm', 'rotary_embedding',
+                    # f32 gates beside bf16 rows: cast neither way
+                    'moe_route', 'moe_dispatch', 'moe_combine',
                     # compute in f32 internally; black-casting their
                     # bf16 inputs up would only double the buffer
                     # (SWCE's analytic-vjp residual is the logits AS
@@ -85,6 +88,12 @@ class OptimizerWithMixedPrecision(object):
         self._incr_ratio = incr_ratio
         self._decr_ratio = decr_ratio
         self._loss_scaling = None
+        # a fixed scale of 1 is no loss scaling (bfloat16 training as
+        # published: f32's exponent range): no scaled loss, no
+        # check_finite_and_unscale, so no op joins all the gradients
+        # and each dies at its own parameter's update
+        self._scales = bool(use_dynamic_loss_scaling) or \
+            float(init_loss_scaling) != 1.0
 
     def get_loss_scaling(self):
         return self._loss_scaling
@@ -93,6 +102,10 @@ class OptimizerWithMixedPrecision(object):
                  no_grad_set=None, callbacks=None):
         program = loss.block.program
         _mark_amp_ops(program, self._amp_lists)
+        if not self._scales:
+            return self._optimizer.backward(
+                loss, startup_program, parameter_list, no_grad_set,
+                callbacks)
         self._loss_scaling = _make_scalar(
             unique_name.generate('loss_scaling'), 'float32',
             self._init_loss_scaling)
@@ -114,6 +127,8 @@ class OptimizerWithMixedPrecision(object):
             return self._apply_gradients_impl(params_grads)
 
     def _apply_gradients_impl(self, params_grads):
+        if not self._scales:
+            return self._optimizer.apply_gradients(params_grads)
         block = default_main_program().global_block()
         grads = [g for _, g in params_grads if g is not None]
         unscaled = []
@@ -171,7 +186,9 @@ def decorate(optimizer, amp_lists=None, init_loss_scaling=2**15,
              incr_every_n_steps=1000, decr_every_n_nan_or_inf=2,
              incr_ratio=2.0, decr_ratio=0.5,
              use_dynamic_loss_scaling=True):
-    """Reference: decorator.py decorate()."""
+    """Reference: decorator.py decorate().  ``init_loss_scaling=1.0``
+    with ``use_dynamic_loss_scaling=False`` is bfloat16 training with
+    no loss scaling at all: the loss-scaling ops are left out."""
     return OptimizerWithMixedPrecision(
         optimizer, amp_lists, init_loss_scaling, use_dynamic_loss_scaling,
         incr_every_n_steps, decr_every_n_nan_or_inf, incr_ratio,

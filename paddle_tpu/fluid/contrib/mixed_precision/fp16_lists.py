@@ -8,12 +8,17 @@ marks MXU ops; loss-scaling still applies when float16 is forced.
 white_list = {
     'conv2d', 'depthwise_conv2d', 'conv2d_transpose', 'matmul',
     'matmul_v2', 'mul', 'bmm',
+    # the grouped expert matmuls of a dropless MoE layer
+    'moe_experts',
 }
 
 black_list = {
     'exp', 'square', 'log', 'mean', 'sum', 'cos_sim',
     'softmax', 'softmax_with_cross_entropy', 'sigmoid_cross_entropy_'
     'with_logits', 'cross_entropy', 'cross_entropy2',
+    # the router: f32 logits, softmax over all experts, top-k and the
+    # two auxiliary losses (its lowering computes in f32 by itself)
+    'moe_route',
 }
 
 gray_list = {
@@ -21,6 +26,8 @@ gray_list = {
     'elementwise_div', 'relu', 'gelu', 'tanh', 'sigmoid', 'pool2d',
     'batch_norm', 'layer_norm', 'dropout', 'reshape2', 'transpose2',
     'concat', 'split', 'slice', 'scale',
+    # f32 inside, output in the input's dtype, like layer_norm
+    'rms_norm', 'rotary_embedding', 'moe_dispatch', 'moe_combine',
 }
 
 

@@ -1612,7 +1612,32 @@ class Executor(object):
         resolution blocks only at as_numpy()).  use_program_cache=False
         bypasses the program's plan cache: the plan (and its segment
         executables) is rebuilt for this call — the reference's
-        uncached Executor.run semantics, paid in recompiles."""
+        uncached Executor.run semantics, paid in recompiles.
+
+        What the program watches (``Program.watch``) rides along on a
+        run that already fetches and blocks, whichever runner takes
+        it; a quiet run reads nothing."""
+        from .compiler import CompiledProgram
+        base = program.program if isinstance(program, CompiledProgram) \
+            else program or framework.default_main_program()
+        watched = base._watched if fetch_list and return_numpy is True \
+            else {}
+        if not watched:
+            return self._run(program, feed, fetch_list, scope,
+                             return_numpy, use_program_cache)
+        n_user = len(fetch_list)
+        out = self._run(
+            program, feed,
+            list(fetch_list) + sum(watched.values(), []), scope,
+            return_numpy, use_program_cache)
+        rest = out[n_user:]
+        for record, names in watched.items():
+            record(rest[:len(names)])
+            rest = rest[len(names):]
+        return out[:n_user]
+
+    def _run(self, program, feed, fetch_list, scope, return_numpy,
+             use_program_cache):
         from .compiler import CompiledProgram
         from .parallel_executor import run_parallel, run_collective
         if _sup.active():

@@ -414,6 +414,20 @@ class Program(object):
         self._current_role = 'forward'
         _all_programs.add(self)
 
+    # {record: [variable names]}, see watch(); a clone starts empty
+    _watched = {}
+
+    def watch(self, names, record):
+        """Have the variables ``names`` read on every ``Executor.run``
+        of this program that fetches something and blocks for it
+        (``return_numpy=True``), beside the user's fetches, and handed
+        to ``record(values)``: all the names given for one ``record``,
+        in the order given.  A run that fetches nothing reads nothing,
+        so a layer can report through ``fluid.monitor`` without adding
+        a device-to-host copy to the quiet steps."""
+        watched = self.__dict__.setdefault('_watched', {})
+        watched.setdefault(record, []).extend(names)
+
     @contextlib.contextmanager
     def _role_guard(self, role):
         """Context manager stamping appended ops with `role`

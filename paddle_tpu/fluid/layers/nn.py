@@ -233,6 +233,34 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
     return helper.append_activation(out, act)
 
 
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """Root-mean-square norm over the last axis with a learned gain
+    (initialised to 1) and no bias: the norm of the 2023+ decoder
+    blocks.  Statistics in float32 whatever the input's dtype."""
+    helper = LayerHelper('rms_norm', name=name)
+    gain = helper.create_parameter(
+        param_attr, shape=[int(input.shape[-1])], dtype=input.dtype,
+        default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op('rms_norm', inputs={'X': input, 'Scale': gain},
+                     outputs={'Y': out}, attrs={'epsilon': epsilon})
+    return out
+
+
+def rotary_embedding(q, k, positions, theta=10000.0, name=None):
+    """Rotary position embedding of q and k [B, T, H, D] at integer
+    ``positions`` [B, T], rotate-half pairing over the whole head ->
+    (q, k) rotated."""
+    helper = LayerHelper('rotary_embedding', name=name)
+    q_out = helper.create_variable_for_type_inference(q.dtype)
+    k_out = helper.create_variable_for_type_inference(k.dtype)
+    helper.append_op('rotary_embedding',
+                     inputs={'Q': q, 'K': k, 'Positions': positions},
+                     outputs={'QOut': q_out, 'KOut': k_out},
+                     attrs={'theta': float(theta)})
+    return q_out, k_out
+
+
 def dropout(x, dropout_prob, is_test=False, seed=None, name=None,
             dropout_implementation='downgrade_in_infer'):
     helper = LayerHelper('dropout', name=name)

@@ -14,6 +14,8 @@ executor path picks up so expert weights land sharded over 'ep'
 without the user writing a with_param_shardings rule.
 """
 
+import numpy as np
+
 from ..layer_helper import LayerHelper
 from ..initializer import Normal
 
@@ -67,35 +69,65 @@ def context_parallel_attention(q, k, v, causal=False, use_flash=False,
 
 def moe(x, num_experts, hidden_size, capacity_factor=2.0,
         aux_weight=0.01, axis='ep', top_k=1, param_attr=None,
-        name=None):
-    """GShard-style Mixture-of-Experts FFN layer (top_k=1 Switch
-    routing; top_k=2 adds GShard second-choice routing with
-    renormalized gates and drop-second-first capacity overflow).
+        name=None, renormalize=True, z_loss_weight=0.0):
+    """Mixture-of-Experts feed-forward layer, in two forms.
 
-    x: [B, T, D].  Creates gate [D, E] and per-expert FFN weights
-    W1 [E, D, hidden_size], W2 [E, hidden_size, D]; under a mesh with
-    an `axis` ('ep') dimension the experts shard across it and tokens
-    route via all_to_all over ICI.
+    **Capacity-based** (``capacity_factor`` a number, the default):
+    ``top_k=1`` is Switch routing, ``top_k=2`` GShard's (second choice
+    with the pair's gates renormalized, dropped first on overflow);
+    experts are ``relu(x W1) W2`` with W1 [E, D, hidden_size] and W2
+    [E, hidden_size, D]; tokens over an expert's capacity are dropped.
+    Under a mesh with an ``axis`` ('ep') dimension the experts shard
+    across it and tokens route via all_to_all over ICI.
 
-    Returns (out [B, T, D], aux_loss []): add `aux_loss` (already
-    scaled by aux_weight) to the training loss — the Switch
-    load-balance term that keeps routing spread across experts.
+    **Dropless** (``capacity_factor=None``): any ``top_k`` up to
+    ``num_experts``, no token dropped, gates taken from the softmax
+    over all experts and divided by their sum only under
+    ``renormalize`` (OLMoE: False); experts are gated,
+    ``down(silu(gate x) * up x)`` with gate and up [E, D, hidden_size],
+    down [E, hidden_size, D].  Routing sorts the (token, expert) pairs
+    by expert and runs one grouped matmul per weight set
+    (``moe_route`` / ``moe_dispatch`` / ``moe_experts`` /
+    ``moe_combine`` ops); it raises NotImplementedError under an
+    ``axis`` mesh dimension.
+
+    x: [B, T, D].  Returns (out [B, T, D], aux []): ``aux`` is the
+    load-balance loss times ``aux_weight`` plus, dropless only, the
+    router z-loss times ``z_loss_weight``; add it to the training loss.
     """
-    if int(top_k) not in (1, 2):
-        raise ValueError('moe: top_k must be 1 (Switch) or 2 (GShard), '
-                         'got %r' % (top_k,))
+    top_k, e, h = int(top_k), int(num_experts), int(hidden_size)
+    dropless = capacity_factor is None
+    if dropless and not 1 <= top_k <= e:
+        raise ValueError('moe: dropless top_k must be in 1..num_experts '
+                         '(%d), got %r' % (e, top_k))
+    if not dropless and top_k not in (1, 2):
+        raise ValueError(
+            'moe: the capacity-based path routes top_k 1 (Switch) or 2 '
+            '(GShard), got %r; any top_k needs capacity_factor=None '
+            '(dropless)' % (top_k,))
     helper = LayerHelper(name or 'moe', param_attr=param_attr)
     d = int(x.shape[-1])
-    e, h = int(num_experts), int(hidden_size)
-    wg = helper.create_parameter(param_attr, shape=[d, e],
-                                 dtype=x.dtype,
-                                 default_initializer=Normal(0., 0.02))
-    w1 = helper.create_parameter(param_attr, shape=[e, d, h],
-                                 dtype=x.dtype,
-                                 default_initializer=Normal(0., 0.02))
-    w2 = helper.create_parameter(param_attr, shape=[e, h, d],
-                                 dtype=x.dtype,
-                                 default_initializer=Normal(0., 0.02))
+
+    def weight(shape):
+        return helper.create_parameter(
+            param_attr, shape=shape, dtype=x.dtype,
+            default_initializer=Normal(0., 0.02))
+
+    def scaled(var, by):
+        # always scale (aux_weight=0.0 must yield a ZEROED term,
+        # honoring the "already scaled" contract, not the raw loss)
+        out = helper.create_variable_for_type_inference('float32')
+        helper.append_op('scale', inputs={'X': var},
+                         outputs={'Out': out},
+                         attrs={'scale': float(by)})
+        return out
+
+    wg = weight([d, e])
+    if dropless:
+        return _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k,
+                             axis, renormalize, aux_weight,
+                             z_loss_weight)
+    w1, w2 = weight([e, d, h]), weight([e, h, d])
     out = helper.create_variable_for_type_inference(x.dtype)
     aux = helper.create_variable_for_type_inference('float32')
     helper.append_op('moe_ffn',
@@ -103,16 +135,83 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
                      outputs={'Out': out, 'AuxLoss': aux},
                      attrs={'axis': axis,
                             'capacity_factor': float(capacity_factor),
-                            'top_k': int(top_k)})
+                            'top_k': top_k})
     prog = helper.main_program
     _add_hint(prog, w1.name, (axis, None, None))
     _add_hint(prog, w2.name, (axis, None, None))
     _add_hint(prog, x.name, ('dp', ('sp', axis), None))
     _add_hint(prog, out.name, ('dp', ('sp', axis), None))
-    # always scale (aux_weight=0.0 must yield a ZEROED term, honoring
-    # the "already scaled" contract — not the raw Switch loss)
-    scaled = helper.create_variable_for_type_inference('float32')
-    helper.append_op('scale', inputs={'X': aux},
-                     outputs={'Out': scaled},
-                     attrs={'scale': float(aux_weight)})
-    return out, scaled
+    return out, scaled(aux, aux_weight)
+
+
+def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
+                  renormalize, aux_weight, z_loss_weight):
+    d = int(x.shape[-1])
+    w_gate, w_up, w_down = weight([e, d, h]), weight([e, d, h]), \
+        weight([e, h, d])
+
+    def var(dtype, stop_gradient=False):
+        return helper.create_variable_for_type_inference(
+            dtype, stop_gradient=stop_gradient)
+
+    idx, load, dropped = (var('int32', True) for _ in range(3))
+    gates, balance, z = var('float32'), var('float32'), var('float32')
+    helper.append_op('moe_route', inputs={'X': x, 'Gate': wg},
+                     outputs={'TopKIdx': idx, 'TopKWeight': gates,
+                              'AuxLoss': balance, 'ZLoss': z,
+                              'Load': load},
+                     attrs={'top_k': top_k, 'axis': axis,
+                            'renormalize': bool(renormalize)})
+    # rows = tokens x top_k: with a dynamic batch, shape inference's
+    # stand-in for it overflows int32 index arithmetic at that size, so
+    # the shapes of the permuted tensors are stated, not inferred
+    rows, order, inverse = var(x.dtype), var('int32', True), \
+        var('int32', True)
+    helper.append_op('moe_dispatch',
+                     inputs={'X': x, 'TopKIdx': idx, 'GroupSizes': load},
+                     outputs={'Rows': rows, 'Order': order,
+                              'Inverse': inverse, 'Dropped': dropped},
+                     infer_shape=False)
+    expert_out = var(x.dtype)
+    helper.append_op('moe_experts',
+                     inputs={'Rows': rows, 'GroupSizes': load,
+                             'WGate': w_gate, 'WUp': w_up,
+                             'WDown': w_down},
+                     outputs={'Out': expert_out}, infer_shape=False)
+    flat = var(x.dtype)
+    helper.append_op('moe_combine',
+                     inputs={'Rows': expert_out, 'TopKWeight': gates,
+                             'Order': order, 'Inverse': inverse},
+                     outputs={'Out': flat}, infer_shape=False)
+    from ...ops.registry import _DYN_SENTINEL
+    # a dynamic batch counts as inference's stand-in, products literal
+    # (registry.infer_shapes does the same for layer_norm's row count)
+    tokens = int(np.prod([_DYN_SENTINEL if n < 0 else int(n)
+                          for n in x.shape[:-1]]))
+    n_rows = tokens * top_k
+    rows.shape = expert_out.shape = (n_rows, d)
+    order.shape = inverse.shape = (n_rows,)
+    dropped.shape = (1,)
+    flat.shape = (tokens, d)
+    out = flat
+    if len(x.shape) != 2:
+        inner = [int(n) for n in x.shape[1:-1]]
+        if min(inner) < 0:
+            raise ValueError('moe: only the first dim of x may be '
+                             'dynamic, got shape %r' % (x.shape,))
+        from . import nn
+        out = nn.reshape(flat, [-1] + inner + [d])
+    # read on the runs that fetch and block (Program.watch):
+    # moe/tokens_routed, moe/dropped_tokens, moe/load_max_over_mean
+    from .. import moe_stats
+    helper.main_program.watch([load.name, dropped.name],
+                              moe_stats.record)
+    aux = scaled(balance, aux_weight)
+    if z_loss_weight:
+        total = var('float32')
+        helper.append_op('elementwise_add',
+                         inputs={'X': aux,
+                                 'Y': scaled(z, z_loss_weight)},
+                         outputs={'Out': total}, attrs={'axis': -1})
+        aux = total
+    return out, aux

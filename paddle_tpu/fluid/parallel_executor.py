@@ -304,7 +304,8 @@ def get_mesh(compiled, program=None, feed=None):
 def run_parallel(executor, compiled, feed, fetch_list, scope, return_numpy):
     program = compiled.program
     if not compiled._is_data_parallel:
-        return executor.run(program, feed, fetch_list, scope, return_numpy)
+        return executor._run(program, feed, fetch_list, scope,
+                             return_numpy, True)
     scope = scope or core.global_scope()
     feed = feed or {}
     fetch_list = fetch_list or []

@@ -254,6 +254,11 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
 #   a multi-output fusion, a bitcast or copy XLA put there) stands for
 #   the nearest of its operands inside the fusion that does, and only
 #   if none does, the fusion's own ``op_name`` decides;
+# - an instruction the TPU compiler expands and renames itself keeps no
+#   ``op_name`` of the program's (a grouped matmul comes out as Mosaic
+#   calls named ``ragged-dot-none``): it counts to the op whose
+#   registration declares that name (``ops.registry.COMPILER_NAMED``);
+#   forward and backward cannot be told apart there;
 # - an instruction with no fluid scope counts to none.
 _TRANSFORMS = re.compile(
     r'^(jvp|transpose|vmap|checkpoint|remat|custom_jvp|custom_vjp)'
@@ -299,6 +304,10 @@ def fluid_scope(op_name, op_types=None):
             comp += '_grad'
         inner = parts[i + 1] if i + 2 < len(parts) else ''
         return comp + '/' + inner if inner and '(' not in inner else comp
+    from ..ops import registry
+    for prefix, op_type in registry.COMPILER_NAMED.items():
+        if op_name.startswith(prefix):
+            return op_type
     return None
 
 

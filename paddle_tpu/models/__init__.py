@@ -1,6 +1,6 @@
 """Model zoo matching BASELINE.json configs:
 LeNet (MNIST), ResNet-50 (ImageNet), BERT-base, Transformer NMT,
-Wide&Deep CTR, word2vec — all built on the fluid layers API so they run
+Wide&Deep CTR, word2vec, plus GPT-2 and OLMoE decoders — all built on the fluid layers API so they run
 unchanged on the reference framework.
 """
 
@@ -9,6 +9,7 @@ from . import resnet
 from . import se_resnext
 from . import bert
 from . import gpt
+from . import olmoe
 from . import transformer
 from . import wide_deep
 from . import word2vec

@@ -90,6 +90,27 @@ def multi_head_attention(x, attn_bias, cfg, is_test, key_bias=None,
         ctx = layers.reshape(out, [0, 0, h])
         return layers.fc(ctx, size=h, num_flatten_dims=2)
 
+    ctx = scaled_dot_product_attention(q, k, v, x, cfg, is_test,
+                                       attn_bias=attn_bias,
+                                       key_bias=key_bias, causal=causal)
+    return layers.fc(ctx, size=h, num_flatten_dims=2)
+
+
+def scaled_dot_product_attention(q, k, v, x, cfg, is_test,
+                                 attn_bias=None, key_bias=None,
+                                 causal=False):
+    """softmax(q k^T / sqrt(d)) v for every head -> context [B, T, h],
+    by the zoo's one flash / dense choice: the
+    ``fused_multihead_attention`` op (Pallas flash kernels on a chip)
+    from ``cfg.flash_min_len`` up, the ``matmul`` + ``softmax`` chain
+    under it.  q, k, v are the projections, [B, T, h] or already split
+    into heads [B, T, heads, d] (a decoder that norms and rotates q and
+    k per head); ``x`` is the block's input, for its sequence length.
+    Shared by the encoder (``multi_head_attention``) and the decoder
+    zoo (gpt.py through it, olmoe.py directly)."""
+    h, heads = cfg.hidden, cfg.heads
+    d = h // heads
+    split = len(q.shape) == 4
     seq_len = x.shape[1] if len(x.shape) >= 2 else 0
     use_flash = getattr(cfg, 'use_flash', False) and \
         (seq_len is None or seq_len < 0 or
@@ -104,7 +125,7 @@ def multi_head_attention(x, attn_bias, cfg, is_test, key_bias=None,
         from ..fluid.layer_helper import LayerHelper
 
         def to_bthd(t):
-            return layers.reshape(t, [0, 0, heads, d])
+            return t if split else layers.reshape(t, [0, 0, heads, d])
 
         q3, k3, v3 = to_bthd(q), to_bthd(k), to_bthd(v)
         helper = LayerHelper('fused_multihead_attention')
@@ -120,11 +141,11 @@ def multi_head_attention(x, attn_bias, cfg, is_test, key_bias=None,
                                 'dropout_rate': adrop},
                          infer_shape=False)
         out.shape = tuple(q3.shape)
-        ctx = layers.reshape(out, [0, 0, h])
-        return layers.fc(ctx, size=h, num_flatten_dims=2)
+        return layers.reshape(out, [0, 0, h])
 
     def to_heads(t):
-        t = layers.reshape(t, [0, 0, heads, d])
+        if not split:
+            t = layers.reshape(t, [0, 0, heads, d])
         return layers.transpose(t, [0, 2, 1, 3])
 
     q, k, v = to_heads(q), to_heads(k), to_heads(v)
@@ -143,8 +164,7 @@ def multi_head_attention(x, attn_bias, cfg, is_test, key_bias=None,
                                dropout_implementation='upscale_in_train')
     ctx = layers.matmul(probs, v)
     ctx = layers.transpose(ctx, [0, 2, 1, 3])
-    ctx = layers.reshape(ctx, [0, 0, h])
-    return layers.fc(ctx, size=h, num_flatten_dims=2)
+    return layers.reshape(ctx, [0, 0, h])
 
 
 def encoder_layer(x, attn_bias, cfg, is_test, key_bias=None):

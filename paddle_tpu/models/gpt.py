@@ -37,7 +37,8 @@ class GptConfig(object):
             attn_dropout
         self.use_flash = use_flash
         self.flash_min_len = 512
-        # MoE FFN blocks (GShard top-1, layers.moe): moe_experts > 0
+        # MoE FFN blocks (layers.moe's capacity-based path: Switch
+        # top-1 by default, GShard top-2 with moe_top_k=2): moe_experts > 0
         # swaps the dense MLP for an expert-parallel MoE that shards
         # over an 'ep' mesh axis under CompiledProgram.with_mesh
         self.moe_experts = moe_experts
@@ -57,8 +58,9 @@ TINY = GptConfig(vocab_size=97, hidden=64, layers=2, heads=4,
 
 
 def decoder_block(x, cfg, is_test, aux_losses=None):
-    """Pre-LN GPT-2 block; with cfg.moe_experts the MLP is a GShard
-    MoE FFN and its load-balance loss is appended to aux_losses."""
+    """Pre-LN GPT-2 block; with cfg.moe_experts the MLP is a
+    capacity-based MoE FFN (Switch top-1 / GShard top-2) and its
+    load-balance loss is appended to aux_losses."""
     a = layers.layer_norm(x, begin_norm_axis=2)
     a = _bert.multi_head_attention(a, None, cfg, is_test, causal=True)
     if not is_test and cfg.dropout:

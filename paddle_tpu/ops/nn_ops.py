@@ -425,6 +425,44 @@ def layer_norm(ctx, ins, attrs):
             'Mean': [m.reshape(lead)], 'Variance': [v.reshape(lead)]}
 
 
+@register('rms_norm')
+def rms_norm(ctx, ins, attrs):
+    """X [..., D], Scale [D] -> Y = x * rsqrt(mean(x^2) + epsilon) *
+    scale over the last axis (Zhang & Sennrich 2019; no mean, no bias).
+    Statistics and the product in float32, Y in X's dtype: the
+    layer_norm policy, so a bf16 stream stays bf16 past the f32 gain."""
+    x = ins['X'][0]
+    xf = x if x.dtype == jnp.float64 else x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(
+        jnp.mean(jnp.square(xf), axis=-1, keepdims=True) +
+        attrs.get('epsilon', 1e-5))
+    return {'Y': [(y * ins['Scale'][0].astype(xf.dtype)).astype(x.dtype)]}
+
+
+@register('rotary_embedding')
+def rotary_embedding(ctx, ins, attrs):
+    """Q, K [B, T, H, D], Positions [B, T] int -> QOut, KOut: rotary
+    position embedding (Su et al. 2021) over the whole head, with the
+    ROTATE-HALF pairing of HF ``apply_rotary_pos_emb``: feature i pairs
+    with i + D/2, both turned by the angle pos * theta^(-2i/D).
+    Angles and the rotation in float32, outputs in the input dtype."""
+    q, k = ins['Q'][0], ins['K'][0]
+    pos = ins['Positions'][0].astype(jnp.float32)
+    half = q.shape[-1] // 2
+    inv_freq = 1.0 / (float(attrs.get('theta', 10000.0)) ** (
+        jnp.arange(half, dtype=jnp.float32) / half))
+    angle = pos[:, :, None, None] * inv_freq            # [B, T, 1, D/2]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+
+    def rotate(x):
+        xf = x.astype(jnp.float32)
+        x1, x2 = xf[..., :half], xf[..., half:]
+        return jnp.concatenate([x1 * cos - x2 * sin,
+                                x2 * cos + x1 * sin], -1).astype(x.dtype)
+
+    return {'QOut': [rotate(q)], 'KOut': [rotate(k)]}
+
+
 @register('instance_norm', no_grad_out_slots=('SavedMean', 'SavedVariance'))
 def instance_norm(ctx, ins, attrs):
     # stats in f32, output in the input dtype (the layer_norm /

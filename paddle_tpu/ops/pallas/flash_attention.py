@@ -118,6 +118,21 @@ def _pack_seed(seed, offsets=None, g_off=0):
                       jnp.asarray(g_off, jnp.uint32)]).reshape(1, 4)
 
 
+def _precision(dtype):
+    """float32 operands multiply at full precision, as every matmul op
+    of an f32 program does (ops/math_ops.py); bfloat16 ones (AMP) in
+    the MXU's one native pass."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def _dot(a, b, contract):
+    """a . b over ``contract`` (one dim of each), accumulated in f32."""
+    return jax.lax.dot_general(
+        a, b, ((contract[:1], contract[1:]), ((), ())),
+        precision=_precision(a.dtype),
+        preferred_element_type=jnp.float32)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                       block_k, has_bias, rate):
     rest = list(rest)
@@ -128,9 +143,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     # o_ref: [1, bq, d]; lse_ref: [1, 1, bq]  (the singleton middle dim
     # satisfies the TPU block-shape rule for 1-D-per-row operands)
     # dots consume the native (usually bf16) dtype and accumulate in
-    # f32 (preferred_element_type): the MXU runs bf16 at 2x f32
-    # throughput and VMEM traffic halves — the pre-cast-to-f32 variant
-    # measured ~25% slower at seq 512
+    # f32 (_dot): the MXU runs bf16 at 2x f32 throughput and VMEM
+    # traffic halves — the pre-cast-to-f32 variant measured ~25%
+    # slower at seq 512; f32 operands multiply at full precision
     q = q_ref[0]
     bq, d = q.shape
     t = k_ref.shape[1]
@@ -143,8 +158,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         m, l, acc = carry
         k = k_ref[0, pl.dslice(i * block_k, block_k), :]
         v = v_ref[0, pl.dslice(i * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        s = _dot(q, k, (1, 1))
         s = s * scale
         if has_bias:
             bias = bias_ref[0, 0, pl.dslice(i * block_k,
@@ -175,9 +189,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                                  g_id + _seed_off(seed_ref, 3),
                                  qpos_d, kpos_d, _keep_threshold(rate))
             p = jnp.where(keep, p * (1.0 / (1.0 - rate)), 0.0)
-        acc_new = acc * corr[:, None] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_new = acc * corr[:, None] + _dot(p.astype(v.dtype), v,
+                                             (1, 0))
         return m_new, l_new, acc_new
 
     m0 = jnp.full((bq,), -jnp.inf, jnp.float32)
@@ -223,8 +236,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     def body(i, dq):
         k = k_ref[0, pl.dslice(i * block_k, block_k), :]
         v = v_ref[0, pl.dslice(i * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        s = _dot(q, k, (1, 1))
         s = s * scale
         if has_bias:
             bias = bias_ref[0, 0, pl.dslice(i * block_k,
@@ -238,8 +250,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             s = jnp.where(qpos >= kpos, s, -jnp.inf)
         p = jnp.where(jnp.isfinite(s),
                       jnp.exp(s - lse[:, None]), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dp = _dot(do, v, (1, 1))
         if rate:
             # softmax vjp with post-softmax dropout u: dS = p*(u*dp -
             # delta); delta = rowsum(dO*O) already sees the dropout
@@ -256,9 +267,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         if has_glse:
             dd = dd + glse[:, None]
         ds = p * dd * scale
-        return dq + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        return dq + _dot(ds.astype(k.dtype), k, (1, 0))
 
     if causal:
         last = (q_off + bq + block_k - 1) // block_k
@@ -301,8 +310,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             jnp.float32)
         glse = glse_ref[0, 0, pl.dslice(j * block_q, block_q)].astype(
             jnp.float32) if has_glse else None
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        s = _dot(q, k, (1, 1))
         s = s * scale
         if has_bias:
             s = s + bias[None, :]
@@ -325,20 +333,15 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             pu = jnp.where(keep, p * (1.0 / (1.0 - rate)), 0.0)
         else:
             keep, pu = None, p
-        dv = dv + jax.lax.dot_general(
-            pu.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
+        dv = dv + _dot(pu.astype(do.dtype), do, (0, 0))
+        dp = _dot(do, v, (1, 1))
         if rate:
             dp = jnp.where(keep, dp * (1.0 / (1.0 - rate)), 0.0)
         dd = dp - delta[:, None]
         if has_glse:
             dd = dd + glse[:, None]
         ds_raw = p * dd
-        dk = dk + jax.lax.dot_general(
-            ds_raw.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        dk = dk + _dot(ds_raw.astype(q.dtype), q, (0, 0)) * scale
         if has_bias:
             dbias = dbias + jnp.sum(ds_raw, axis=0)
         return dk, dv, dbias
@@ -404,8 +407,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             delta = delta_ref[0, 0, pl.dslice(j * block_q,
                                               block_q)].astype(
                 jnp.float32)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+            s = _dot(q, k, (1, 1))
             s = s * scale
             if has_bias:
                 s = s + bias[None, :]
@@ -417,8 +419,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                 s = jnp.where(qpos >= kpos, s, -jnp.inf)
             p = jnp.where(jnp.isfinite(s),
                           jnp.exp(s - lse[:, None]), 0.0)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
+            dp = _dot(do, v, (1, 1))
             if rate:
                 qpos_d = j * block_q + _seed_off(seed_ref, 1) + \
                     jax.lax.broadcasted_iota(
@@ -433,9 +434,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                 dp = jnp.where(keep, dp * (1.0 / (1.0 - rate)), 0.0)
             else:
                 pu = p
-            dv = dv + jax.lax.dot_general(
-                pu.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            dv = dv + _dot(pu.astype(do.dtype), do, (0, 0))
             dd = dp - delta[:, None]
             if has_glse:
                 glse = glse_ref[0, 0, pl.dslice(j * block_q,
@@ -443,14 +442,10 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                     jnp.float32)
                 dd = dd + glse[:, None]
             ds_raw = p * dd
-            dk = dk + jax.lax.dot_general(
-                ds_raw.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+            dk = dk + _dot(ds_raw.astype(q.dtype), q, (0, 0)) * scale
             if has_bias:
                 dbias = dbias + jnp.sum(ds_raw, axis=0)
-            dq_blk = jax.lax.dot_general(
-                ds_raw.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
+            dq_blk = _dot(ds_raw.astype(k.dtype), k, (1, 0)) * scale
             # dq accumulates across k-blocks in the f32 VMEM scratch
             # (read-modify-write through the ref: Mosaic supports
             # dynamic slicing on refs, not on carried values)
@@ -807,6 +802,7 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
     flash_attention_with_lse contract)."""
     b, t, h, d = q.shape
     s = jnp.einsum('bthd,bshd->bhts', q, k,
+                   precision=_precision(q.dtype),
                    preferred_element_type=jnp.float32) / (d ** 0.5)
     if key_bias is not None:
         s = s + key_bias.astype(jnp.float32)[:, None, None, :]
@@ -823,7 +819,8 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
                                   dropout_g_offset, dropout_rate)
         p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
     p = p.astype(q.dtype)
-    o = jnp.einsum('bhts,bshd->bthd', p, v)
+    o = jnp.einsum('bhts,bshd->bthd', p, v,
+                   precision=_precision(q.dtype))
     if with_lse:
         return o, jax.nn.logsumexp(s, axis=-1)
     return o

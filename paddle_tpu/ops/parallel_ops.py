@@ -4,7 +4,8 @@ These make ring attention (context parallelism over 'sp') and GShard
 MoE (expert parallelism over 'ep') FIRST-CLASS Program ops: a fluid
 layer appends them like any other op, and the SAME program runs
 
-- single-device: dense fallbacks (reference attention / dense MoE);
+- single-device: the one-chip lowerings (reference attention; the
+  capacity-based one-hot MoE on all experts);
 - under CompiledProgram.with_mesh on a mesh with 'sp'/'ep' axes: the
   lowering opens a jax.shard_map over the trace-time mesh
   (parallel.mesh.trace_mesh, published by the executor's GSPMD path)
@@ -156,7 +157,7 @@ def ring_attention_op(ctx, ins, attrs):
 
 @register('moe_ffn', no_grad_out_slots=())
 def moe_ffn_op(ctx, ins, attrs):
-    """GShard top-1 MoE FFN.
+    """Capacity-based MoE FFN (Switch top-1 / GShard top-2).
 
     X [B, T, D] tokens; Gate [D, E]; W1 [E, D, H]; W2 [E, H, D].
     Outs: Out [B, T, D], AuxLoss [] (Switch load-balance loss — add it
@@ -167,7 +168,11 @@ def moe_ffn_op(ctx, ins, attrs):
     Under a trace mesh with an 'ep' axis (attrs['axis']), experts shard
     over 'ep' (leading dim of W1/W2) and tokens route via all_to_all
     (parallel/moe.py); tokens additionally shard over dp/sp/ep when
-    divisible so no compute duplicates.  Dense fallback otherwise.
+    divisible so no compute duplicates.  On one chip (no such axis) the
+    same one-hot dispatch / combine einsums run over all experts:
+    that is the only path a one-chip user of this op gets, not a
+    fallback.  Dropless routing with any top_k and gated experts is
+    the moe_route / moe_dispatch / moe_experts / moe_combine ops below.
 
     Capacity semantics match parallel.moe: per-shard capacity =
     capacity_factor * local_tokens / n_experts, so the sharded and
@@ -225,3 +230,91 @@ def moe_ffn_op(ctx, ins, attrs):
     out, aux = reference_moe_ffn(x, wg, w1, w2, capacity_factor=cf,
                                  top_k=top_k)
     return {'Out': [out], 'AuxLoss': [jnp.asarray(aux, jnp.float32)]}
+
+
+# ---------------------------------------------------------------------------
+# Dropless MoE (any top_k, gated experts): four ops, so that a device
+# trace tells routing, the permutation and the expert matmuls apart by
+# their named scopes.  The math is parallel/moe.py's second half.
+# ---------------------------------------------------------------------------
+
+
+def _no_expert_axis(attrs):
+    """Dropless routing is one-chip (or data-parallel) only so far."""
+    from ..parallel import mesh as pmesh
+    axis = attrs.get('axis', 'ep')
+    if pmesh.axis_size(pmesh.trace_mesh(), axis) > 1:
+        raise NotImplementedError(
+            'dropless MoE routing (capacity_factor=None) over an %r '
+            'mesh axis is not implemented: experts sharded over chips '
+            'need a ragged all_to_all, which the olmoe_1b7b_s4096_ep4 '
+            'benchmark cell will force; use the capacity-based path '
+            '(capacity_factor=2.0, top_k <= 2) under expert parallelism'
+            % (axis,))
+
+
+@register('moe_route', no_grad_out_slots=('TopKIdx', 'Load'))
+def moe_route_op(ctx, ins, attrs):
+    """X [..., D], Gate [D, E] -> TopKIdx [S, k] int32, TopKWeight
+    [S, k] f32, AuxLoss [] (load-balance), ZLoss [] (router z-loss),
+    Load [E] int32 ((token, expert) pairs per expert: the group sizes
+    the grouped matmuls are handed).  All float32 whatever X is: the
+    router is the part of a routed model that does not survive
+    bfloat16.  attrs: top_k, renormalize."""
+    from ..parallel.moe import route_topk
+    _no_expert_axis(attrs)
+    x, wg = ins['X'][0], ins['Gate'][0]
+    idx, weight, balance, z, load = route_topk(
+        x.reshape(-1, x.shape[-1]), wg, int(attrs['top_k']),
+        bool(attrs.get('renormalize', False)))
+    return {'TopKIdx': [idx], 'TopKWeight': [weight],
+            'AuxLoss': [balance], 'ZLoss': [z], 'Load': [load]}
+
+
+@register('moe_dispatch',
+          no_grad_out_slots=('Order', 'Inverse', 'Dropped'))
+def moe_dispatch_op(ctx, ins, attrs):
+    """X [..., D], TopKIdx [S, k], GroupSizes [E] (moe_route's Load)
+    -> Rows [S*k, D] grouped by expert, Order / Inverse [S*k] int32
+    (the permutation and its inverse), Dropped [1] int32: the rows
+    that sit outside the group of the expert their token picked, given
+    the sizes moe_experts is handed (0 unless the grouping is broken;
+    computed only where something reads it)."""
+    from ..parallel.moe import (dispatch_rows, rows_outside_their_group,
+                                sort_by_expert)
+    x, idx = ins['X'][0], ins['TopKIdx'][0]
+    order, inverse = sort_by_expert(idx)
+    rows = dispatch_rows(x.reshape(-1, x.shape[-1]), order, inverse,
+                         int(idx.shape[-1]))
+    dropped = rows_outside_their_group(idx, order, ins['GroupSizes'][0])
+    return {'Rows': [rows], 'Order': [order], 'Inverse': [inverse],
+            'Dropped': [dropped.reshape(1)]}
+
+
+@register('moe_experts', compiler_named=('ragged-dot',))
+def moe_experts_op(ctx, ins, attrs):
+    """Rows [M, D] grouped by expert, GroupSizes [E] int32, WGate /
+    WUp [E, D, H], WDown [E, H, D] -> Out [M, D]:
+    down(silu(gate x) * up x), one grouped matmul per weight set; in
+    bfloat16 under AMP (white-listed).  The TPU compiler turns each
+    ``lax.ragged_dot`` into Mosaic calls whose whole op_name is its own
+    (``ragged-dot-none``, ``ragged-dot-metadata``), forward and
+    backward alike."""
+    from ..parallel.moe import grouped_gated_mlp
+    rows = ins['Rows'][0]
+    low = bool(attrs.get('__amp__')) and \
+        rows.dtype in (jnp.float32, jnp.bfloat16)
+    return {'Out': [grouped_gated_mlp(
+        rows, ins['GroupSizes'][0], ins['WGate'][0], ins['WUp'][0],
+        ins['WDown'][0], low_precision=low)]}
+
+
+@register('moe_combine')
+def moe_combine_op(ctx, ins, attrs):
+    """Rows [S*k, D] expert outputs, TopKWeight [S, k], Order /
+    Inverse -> Out [S, D]: each token's k outputs weighted and summed
+    in float32, emitted in Rows' dtype."""
+    from ..parallel.moe import combine_rows
+    return {'Out': [combine_rows(
+        ins['Rows'][0], ins['TopKWeight'][0].astype(jnp.float32),
+        ins['Order'][0], ins['Inverse'][0])]}

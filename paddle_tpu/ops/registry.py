@@ -129,14 +129,25 @@ _REGISTRY = {}
 HOST_OPS = set()
 
 
+# op_name prefix -> op type, for instructions the TPU compiler expands
+# and renames itself, which keep no named scope of the program's: the
+# lowering that emits them says so (register(compiler_named=...)) and
+# fluid.profiler's scope table reads it here
+COMPILER_NAMED = {}
+
+
 def register(type, in_slots=None, out_slots=None, no_grad_out_slots=(),
-             stochastic=False):
-    """Decorator: register `fn(ctx, ins, attrs) -> outs` as op `type`."""
+             stochastic=False, compiler_named=()):
+    """Decorator: register `fn(ctx, ins, attrs) -> outs` as op `type`.
+    ``compiler_named``: op_name prefixes of the instructions the chip's
+    compiler emits for this lowering under a name of its own."""
 
     def deco(fn):
         _REGISTRY[type] = OpDef(type, fn, in_slots, out_slots,
                                 no_grad_out_slots,
                                 stochastic=stochastic)
+        for prefix in compiler_named:
+            COMPILER_NAMED[prefix] = type
         return fn
 
     return deco

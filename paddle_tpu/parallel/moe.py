@@ -10,7 +10,18 @@ static-shaped MXU work — no scatter with data-dependent shapes.
 
 Differentiable end-to-end: all_to_all and the one-hot einsums are linear,
 so jax.vjp routes token grads back through the same ring.
+
+The second half of the file is the DROPLESS path (OLMoE-style, any
+top_k): sort-and-group.  The one-hot maps above are [S, E, C]; at
+S = 8192, E = 64, k = 8 that is 2 GB a map, so routing there is a sort
+of the S*k (token, expert) pairs by expert, a row gather, one grouped
+(ragged) matmul per expert matrix and a weighted sum back.  Shapes stay
+static (S*k rows whatever the routing), no token is dropped, and every
+backward is again a gather: the sort is a permutation, so its inverse
+replaces the scatter-add.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -160,3 +171,142 @@ def reference_moe_ffn(x, wg, w1_full, w2_full, capacity_factor=2.0,
     expert_out = jnp.einsum('ech,ehd->ecd', h, w2_full)
     out = jnp.einsum('sec,ecd->sd', combine, expert_out)
     return out.reshape(b, t, d).astype(x.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless routing: sort, gather, grouped matmuls, weighted sum back
+# ---------------------------------------------------------------------------
+
+
+def route_topk(x, wg, top_k, renormalize=False):
+    """The router, in float32 whatever ``x`` is.  x [S, D], wg [D, E] ->
+    (idx [S, k] int32, weight [S, k] f32, balance loss, z-loss,
+    load [E] int32).
+
+    ``weight`` are the top-k of the softmax over ALL experts, divided
+    by their sum only under ``renormalize`` (OLMoE publishes
+    ``norm_topk_prob: false``).  Balance loss: E * sum_e f_e * P_e with
+    f_e the share of tokens that picked e among their k (sums to k)
+    and P_e the mean router probability.  z-loss: mean over tokens of
+    logsumexp(logits)^2.  ``load`` counts the (token, expert) pairs of
+    each expert and sums to S*k."""
+    n_experts = wg.shape[-1]
+    logits = jnp.dot(x.astype(jnp.float32), wg.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    probs = jnp.exp(logits - lse[:, None])
+    weight, idx = jax.lax.top_k(probs, top_k)
+    if renormalize:
+        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    picked = jnp.sum(jax.nn.one_hot(idx, n_experts, dtype=jnp.int32),
+                     axis=1)                                # [S, E]
+    load = jnp.sum(picked, axis=0)
+    share = load.astype(jnp.float32) / x.shape[0]
+    balance = n_experts * jnp.sum(share * jnp.mean(probs, axis=0))
+    return (idx.astype(jnp.int32), weight, balance,
+            jnp.mean(jnp.square(lse)), load.astype(jnp.int32))
+
+
+def sort_by_expert(idx):
+    """idx [S, k] -> (order [S*k], inverse [S*k]) int32: ``order`` lists
+    the flat (token, choice) pairs grouped by expert (stable, so a
+    group keeps token order), ``inverse`` undoes it."""
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    # lax.iota, not jnp.arange: shape inference runs this with a huge
+    # stand-in for a dynamic batch, past what arange's bound check takes
+    inverse = jnp.zeros_like(order).at[order].set(
+        jax.lax.iota(jnp.int32, order.shape[0]), unique_indices=True)
+    return order, inverse
+
+
+def rows_outside_their_group(idx, order, group_sizes):
+    """How many of the S*k sorted rows the grouped matmuls hand to
+    another expert than the router picked, or to none: 0 when the sort
+    and ``group_sizes`` agree.  Row j holds pair ``order[j]``, whose
+    expert is ``idx.flat[order[j]]``; ``ragged_dot`` gives row j to the
+    group whose running total of ``group_sizes`` first passes j, and to
+    no group (a row of zeros) past their sum."""
+    picked = idx.reshape(-1)[order]
+    row = jax.lax.iota(jnp.int32, order.shape[0])
+    # compare_all: one [S*k, E] comparison; the default's binary search
+    # is a gather per step, which a TPU does slowly
+    given = jnp.searchsorted(jnp.cumsum(group_sizes), row, side='right',
+                             method='compare_all')
+    return jnp.sum((given != picked).astype(jnp.int32))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def dispatch_rows(x, order, inverse, top_k):
+    """x [S, D] -> rows [S*k, D] in expert order (row j is token
+    order[j] // k)."""
+    return x[order // top_k]
+
+
+def _dispatch_fwd(x, order, inverse, top_k):
+    return dispatch_rows(x, order, inverse, top_k), (order, inverse)
+
+
+def _dispatch_bwd(top_k, res, g):
+    order, inverse = res
+    s = g.shape[0] // top_k
+    dx = jnp.sum(g[inverse].reshape(s, top_k, -1).astype(jnp.float32),
+                 axis=1)
+    return dx.astype(g.dtype), None, None
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(y, weight, order, inverse):
+    """y [S*k, D] expert-ordered outputs, weight [S, k] f32 ->
+    [S, D]: each token's k outputs, weighted, summed in f32."""
+    s, k = weight.shape
+    picked = y[inverse].reshape(s, k, -1).astype(jnp.float32)
+    return jnp.sum(picked * weight[:, :, None], axis=1).astype(y.dtype)
+
+
+def _combine_fwd(y, weight, order, inverse):
+    return combine_rows(y, weight, order, inverse), \
+        (y, weight, order, inverse)
+
+
+def _combine_bwd(res, g):
+    y, weight, order, inverse = res
+    s, k = weight.shape
+    gf = g.astype(jnp.float32)
+    dy = (gf[:, None, :] * weight[:, :, None]).astype(y.dtype)
+    picked = y[inverse].reshape(s, k, -1).astype(jnp.float32)
+    dweight = jnp.sum(picked * gf[:, None, :], axis=-1)
+    return dy.reshape(s * k, -1)[order], dweight, None, None
+
+
+combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+def grouped_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
+                      low_precision=False):
+    """down(silu(gate x) * up x) for rows grouped by expert.
+
+    rows [M, D] (group e is the next group_sizes[e] rows), w_gate and
+    w_up [E, D, H], w_down [E, H, D] -> [M, D].  One ragged matmul per
+    weight set (``jax.lax.ragged_dot``: the TPU compiler's own grouped
+    matmul, 2*M*D*H FLOPs whatever the grouping).  ``low_precision``
+    (AMP) multiplies in bfloat16 and keeps the [M, H] intermediates in
+    bfloat16; otherwise float32 operands multiply at full precision."""
+    if low_precision:
+        rows = rows.astype(jnp.bfloat16)
+        w_gate, w_up, w_down = (w.astype(jnp.bfloat16)
+                                for w in (w_gate, w_up, w_down))
+        precision = None
+    else:
+        precision = jax.lax.Precision.HIGHEST \
+            if rows.dtype == jnp.float32 else None
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
+                            precision=precision)
+    gate = dot(rows, w_gate)
+    up = dot(rows, w_up)
+    hidden = (jax.nn.silu(gate.astype(jnp.float32)) *
+              up.astype(jnp.float32)).astype(rows.dtype)
+    return dot(hidden, w_down)

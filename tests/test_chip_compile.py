@@ -104,6 +104,68 @@ def test_flash_attention_fwd_bwd(one_chip, as_on_tpu, b, t, h, d, rate):
     assert n >= 2, n    # forward + fused (or dq, dkv) backward
 
 
+@pytest.mark.parametrize('b,t', [(2, 512), (1, 2048)])
+def test_flash_forward_in_float32_at_the_reference_check_shapes(
+        one_chip, as_on_tpu, b, t):
+    """The benchmark's reference check runs BERT's f32 for_test clone:
+    the forward kernel with a key bias, its products at full
+    precision (Mosaic's fp32 contract precision)."""
+    qkv = _spec((b, t, 12, 64), jnp.float32)
+    n = _compile(lambda q, k, v, bias: flash_attention.flash_attention(
+        q, k, v, key_bias=bias), one_chip, qkv, qkv, qkv, _spec((b, t)))
+    assert n == 1, n
+
+
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+def test_causal_flash_at_the_olmoe_cell_shape(one_chip, as_on_tpu, dtype):
+    """olmoe_1b7b_s4096: b3 t4096 h16 d128, causal, no key bias, no
+    dropout.  At d128 the one-pass backward's residency is over the
+    VMEM budget, so _flash_bwd picks the two-pass kernels: forward,
+    dq, dkv.  bfloat16 is the timed train step; float32 (products at
+    full precision) is the cell's reference check and
+    ``chip_smoke.py --phase olmoe``."""
+    b, t, h, d = 3, 4096, 16, 128
+    assert flash_attention._fused_bwd_vmem(
+        t, d, flash_attention.FUSED_BLOCK_Q, flash_attention.FUSED_BLOCK_K,
+        2) > flash_attention.VMEM_BUDGET_BYTES
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            o = flash_attention.flash_attention(q, k, v, causal=True)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    qkv = _spec((b, t, h, d), dtype)
+    n = _compile(step, one_chip, qkv, qkv, qkv)
+    _compiled_on_chip('flash_attention')
+    assert n == 3, n
+
+
+def test_grouped_expert_matmuls_at_the_olmoe_cell_shape(one_chip):
+    """The dropless MoE layer's grouped gate / up / down matmuls at
+    olmoe_1b7b_s4096's 3 * 4096 * 8 routed rows over 64 experts of
+    2048 x 1024, bf16, forward and backward: jax.lax.ragged_dot has to
+    stay the chip compiler's own grouped matmul (temporaries of a few
+    [rows, H] intermediates), not its dense expansion over the groups
+    (64 x the rows)."""
+    from paddle_tpu.parallel import moe
+    rows, d, hidden, experts = 3 * 4096 * 8, 2048, 1024, 64
+
+    def step(x, sizes, gate, up, down):
+        def loss(x, gate, up, down):
+            return jnp.sum(moe.grouped_gated_mlp(
+                x, sizes, gate, up, down,
+                low_precision=True).astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2, 3))(x, gate, up, down)
+
+    wide = _spec((experts, d, hidden), jnp.bfloat16)
+    compiled = _compiled(step, one_chip, _spec((rows, d), jnp.bfloat16),
+                         _spec((experts,), jnp.int32), wide, wide,
+                         _spec((experts, hidden, d), jnp.bfloat16))
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        16 * rows * d * 2
+
+
 @pytest.mark.parametrize('rows', [VOCAB, MAX_POS])
 def test_embedding_gather_and_scatter_add(one_chip, as_on_tpu, rows):
     def step(w, ids):

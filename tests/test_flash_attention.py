@@ -97,6 +97,24 @@ def test_flash_grad_bf16():
         assert np.isfinite(np.asarray(a, np.float32)).all()
 
 
+@pytest.mark.parametrize('dtype,full', [('float32', True),
+                                        ('bfloat16', False)])
+def test_kernel_products_follow_the_operand_dtype(dtype, full):
+    """An f32 program gets f32 answers from every matmul op
+    (ops/math_ops.py: HIGHEST); the kernels' products, forward and
+    backward, ask the same of Mosaic for f32 operands and stay one
+    native pass for bf16 ones (AMP: the timed programs)."""
+    q = jnp.zeros((1, 32, 2, 8), dtype)
+    text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, causal=True, min_seq=0).astype(
+            jnp.float32)), (0, 1, 2)))(q, q, q))
+    assert 'pallas_call' in text
+    n_dots = text.count('dot_general[')
+    assert n_dots >= 7, text        # 2 forward + 5 backward products
+    assert text.count('HIGHEST') >= n_dots if full \
+        else 'HIGHEST' not in text, text
+
+
 def test_bert_flash_path_parity():
     """BERT encoder with the fused flash op == naive attention chain
     (same weights/seeds), forward loss and parameter gradients."""
