@@ -1778,106 +1778,22 @@ def _plan_ab_fields(batch=256, width=256, rounds=6, per_round=4,
     return out
 
 
-def bench_kernels(rounds=6, per_round=4, warmup=3):
-    """Pallas kernel-library interleaved A/B (BENCH_kernels.json):
-    per kernel, the shipped auto-dispatch arm vs the kernel flag
-    forced off (dense reference), same program and feed, timed in
-    interleaved bursts so OS noise hits both arms equally.
-
-    Honest-A/B bookkeeping rides in the artifact: each entry records
-    the pallas/<kernel>/dispatch_{fused,dense} deltas (so a silent
-    dense fallback — the CPU posture, where both arms lower the same
-    dense reference and must tie — can never masquerade as a fused
-    win), the post-warmup retrace count (executor segments_lowered
-    delta across the timed rounds, which must be zero: dispatch is a
-    trace-time decision keyed into the lowering fingerprint), and the
-    final losses for the parity claim."""
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import layers, monitor
-    from paddle_tpu.ops.pallas import common as pallas_common
-
-    rng = np.random.RandomState(0)
-
-    def emb_net():
-        # feed from a PINNED seed: both arms of an entry must see the
-        # same batch or the cross-arm loss comparison is noise
-        feed_rng = np.random.RandomState(2)
-        main_p, startup = fluid.Program(), fluid.Program()
-        main_p.random_seed = startup.random_seed = 7
-        with fluid.program_guard(main_p, startup):
-            ids = layers.data('ids', shape=[1], dtype='int64')
-            emb = layers.embedding(ids, size=[4096, 64])
-            loss = layers.reduce_mean(
-                layers.square(layers.fc(emb, 8)))
-            fluid.optimizer.Adagrad(0.05).minimize(loss)
-        return main_p, startup, loss, \
-            {'ids': feed_rng.randint(0, 4096,
-                                     size=(64, 1)).astype('int64')}
-
-    out = {}
-    for kernel, build, flag in (
-            ('embedding_update', emb_net, 'FLAGS_pallas_embedding'),):
-        prev = fluid.get_flags([flag])
-        disp0 = {k: monitor.counter_value('pallas/%s/dispatch_%s'
-                                          % (kernel, k))
-                 for k in ('fused', 'dense')}
-        arms = {}
-        try:
-            for arm, on in (('auto', True), ('dense', False)):
-                fluid.set_flags({flag: on})
-                main_p, startup, loss, feed = build()
-                scope = fluid.Scope()
-                exe = fluid.Executor(fluid.XLAPlace(0))
-                with fluid.scope_guard(scope):
-                    exe.run(startup)
-                    for _ in range(warmup):
-                        exe.run(main_p, feed=feed, fetch_list=[loss])
-                arms[arm] = {'on': on, 'program': main_p,
-                             'loss': loss, 'feed': feed,
-                             'scope': scope, 'exe': exe, 'walls': [],
-                             'final_loss': None}
-            lowered0 = monitor.counter_value(
-                'executor/segments_lowered')
-            for _ in range(rounds):
-                for arm in ('auto', 'dense'):
-                    s = arms[arm]
-                    fluid.set_flags({flag: s['on']})
-                    with fluid.scope_guard(s['scope']):
-                        t0 = time.perf_counter()
-                        for _ in range(per_round):
-                            lv, = s['exe'].run(s['program'],
-                                               feed=s['feed'],
-                                               fetch_list=[s['loss']])
-                        s['walls'].append(time.perf_counter() - t0)
-                        s['final_loss'] = float(np.asarray(lv))
-            rec = {
-                'post_warmup_retraces': int(
-                    monitor.counter_value('executor/segments_lowered')
-                    - lowered0),
-            }
-            for arm in ('auto', 'dense'):
-                s = arms[arm]
-                rec[arm] = {
-                    'steps_per_sec': round(
-                        per_round / min(s['walls']), 2),
-                    'best_step_ms': round(
-                        min(s['walls']) / per_round * 1e3, 3),
-                    'final_loss': s['final_loss'],
-                }
-            for k in ('fused', 'dense'):
-                rec['dispatch_%s_count' % k] = monitor.counter_value(
-                    'pallas/%s/dispatch_%s' % (kernel, k)) - disp0[k]
-            out[kernel] = rec
-        finally:
-            fluid.set_flags(prev)
-
-    # quantized-collective element phases, kernel level: the wire
-    # collectives are identical in both arms, so the A/B times the
-    # quantize + dequant/reduce/requant chain itself (jitted); off-TPU
-    # the fused arm runs the Pallas interpreter and is labeled so
+def bench_kernels(rounds=6, per_round=4):
+    """Pallas kernel-library interleaved A/B (BENCH_kernels.json): the
+    quantized collective's fused element phases against their dense
+    chain, same input, timed in interleaved bursts so OS noise hits
+    both arms equally.  The artifact records the post-warmup retrace
+    count (must be zero), bitwise parity, and which path the fused arm
+    ran (off a TPU: the Pallas interpreter, labeled so)."""
     import jax
     import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import common as pallas_common
     from paddle_tpu.ops.pallas import quant_collective as qc
+
+    rng = np.random.RandomState(0)
+    out = {}
+    # the wire collectives are identical in both arms, so the A/B times
+    # the quantize + dequant/reduce/requant chain itself (jitted)
     n_ranks, cb, block = 8, 16, 256
     xq = jnp.asarray(
         rng.randn(n_ranks * cb, block).astype('float32'))

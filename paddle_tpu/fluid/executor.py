@@ -1006,8 +1006,6 @@ def _pallas_flag_items():
     persistent fingerprint and the per-step in-memory cache key."""
     from .flags import get_flag
     return (bool(get_flag('FLAGS_pallas_force', False)),
-            bool(get_flag('FLAGS_pallas_embedding', True)),
-            int(get_flag('FLAGS_pallas_embedding_min_rows', 512)),
             bool(get_flag('FLAGS_pallas_quant_collective', True)))
 
 

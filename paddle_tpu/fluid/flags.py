@@ -393,18 +393,6 @@ _DEFAULTS = {
     # exercise the kernels on the CPU mesh; never set it in
     # production.
     'FLAGS_pallas_force': False,
-    # fused sparse embedding path: lookup_table(_v2) gathers through
-    # the Pallas row-gather kernel (scatter-add custom-vjp backward),
-    # and AdagradOptimizer rewrites eligible embedding updates into
-    # one fused_emb_update over only the touched rows, replacing the
-    # dense scatter + full-table update lowering.  Slower than the
-    # dense lowering on the chip today (BERT-base s128 b192, PR 23's
-    # trace: lookup_table_v2* 15.7 ms a step against 2.5 ms dense) —
-    # repair from a trace or delete is ROADMAP S1's next issue.
-    'FLAGS_pallas_embedding': True,
-    # vocab-rows floor for the embedding kernel: small tables stay on
-    # the dense gather (bit-exact) where XLA already wins
-    'FLAGS_pallas_embedding_min_rows': 512,
     # fused block-scaled quantize->reduce-scatter for the quantized
     # collective arm: the int8 copy + fp32 dequant temporaries of the
     # dense arm never materialize in HBM, and comms_plan prices the

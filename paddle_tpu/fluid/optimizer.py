@@ -370,60 +370,12 @@ class AdagradOptimizer(Optimizer):
     def _append_optimize_op(self, block, param_and_grad):
         p, g = param_and_grad
         moment = self._get_accumulator('moment', p)
-        fused = self._try_fused_emb_update(block, p, g, moment,
-                                           param_and_grad)
-        if fused is not None:
-            return fused
         return block.append_op(
             'adagrad',
             inputs={'Param': p, 'Grad': g, 'Moment': moment,
                     'LearningRate': self._create_param_lr(param_and_grad)},
             outputs={'ParamOut': p, 'MomentOut': moment},
             attrs={'epsilon': self._epsilon}, infer_shape=False)
-
-    def _try_fused_emb_update(self, block, p, g, moment,
-                              param_and_grad):
-        """Sparse embedding-table path (ops/pallas/embedding.py): when
-        this param's grad comes STRAIGHT from a lookup_table(_v2)_grad
-        op and nothing else consumes it, replace that dense
-        [V, D]-scatter op + full-table adagrad with one
-        fused_emb_update over the looked-up rows.  Any other grad
-        topology — clipping, regularization, a param fed by several
-        lookups (the grad is then a sum op's output) — fails the
-        producer/consumer check and keeps the dense pair."""
-        from .flags import get_flag
-        if not get_flag('FLAGS_pallas_embedding', True) or g is None:
-            return None
-        producer_idx = None
-        for i, op in enumerate(block.ops):
-            if g.name in op.output_arg_names:
-                producer_idx = i
-        if producer_idx is None:
-            return None
-        prod = block.ops[producer_idx]
-        if prod.type not in ('lookup_table_grad',
-                             'lookup_table_v2_grad'):
-            return None
-        if any(g.name in op.input_arg_names for op in block.ops):
-            return None
-        ids_name = prod.inputs['Ids'][0]
-        out_grad_name = prod.inputs['GRAD::Out'][0]
-        if prod.inputs['W'][0] != p.name:
-            return None
-        op = block.append_op(
-            'fused_emb_update',
-            inputs={'Param': p, 'Grad': out_grad_name,
-                    'Ids': ids_name, 'Moment': moment,
-                    'LearningRate':
-                        self._create_param_lr(param_and_grad)},
-            outputs={'ParamOut': p, 'MomentOut': moment},
-            attrs={'epsilon': self._epsilon,
-                   'padding_idx': prod.attrs.get('padding_idx', -1)},
-            infer_shape=False)
-        # the dense scatter is now dead — drop it so the executor
-        # never lowers it (its W@GRAD output has no readers)
-        block._remove_op(producer_idx)
-        return op
 
 
 class AdamaxOptimizer(Optimizer):

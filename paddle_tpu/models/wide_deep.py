@@ -1,6 +1,6 @@
 """Wide&Deep CTR model (BASELINE.json config 3).
 
-Reference workload: embedding_lookup_sparse + SelectedRows sparse
+Reference workload: sparse embedding lookups + SelectedRows sparse
 gradients (operators/lookup_table_op with is_sparse=True).  TPU-native:
 the embedding gradient is a dense scatter-add that XLA keeps on-chip;
 the host-sharded embedding-table path for beyond-HBM vocabularies lives

@@ -213,17 +213,6 @@ def lamb(ctx, ins, attrs):
             'Beta2PowOut': [(b2p * b2).reshape(ins['Beta2Pow'][0].shape)]}
 
 
-@register('fused_emb_update')
-def fused_emb_update(ctx, ins, attrs):
-    """Sparse embedding-table adagrad over only the touched rows:
-    Param/Moment [V, D], Ids [...], Grad ids.shape+[D] (the lookup's
-    OUT-grad — no dense [V, D] scatter ever built), LearningRate.
-    AdagradOptimizer emits this in place of lookup_table_v2_grad +
-    adagrad when the grad path is eligible (fluid/optimizer.py)."""
-    from .pallas import embedding
-    return embedding.apply_update(ctx, ins, attrs)
-
-
 @register('dpsgd')
 def dpsgd(ctx, ins, attrs):
     p = ins['Param'][0]

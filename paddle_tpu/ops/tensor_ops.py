@@ -342,16 +342,18 @@ def one_hot_v2(ctx, ins, attrs):
 
 @register('lookup_table_v2')
 def lookup_table_v2(ctx, ins, attrs):
-    # auto-dispatched: the pallas row-gather kernel (with its sorted
-    # scatter-add custom-vjp backward) above the vocab floor on TPU,
-    # the historical jnp.take + padding mask everywhere else —
-    # ops/pallas/embedding.py holds both paths
-    from .pallas import embedding as pallas_emb
+    # XLA's gather; the gradient is jnp.take's own vjp (scatter-add
+    # into zeros), the same on one chip and under a mesh.  An id
+    # outside [-V, V) reads NaN and trains nothing (take's 'fill':
+    # the reference op raises there, a compiled program cannot)
     w = ins['W'][0]
     ids = ins['Ids'][0]
     padding_idx = attrs.get('padding_idx', -1)
-    return {'Out': [pallas_emb.embedding_lookup(
-        w, ids, padding_idx, auto_partitioned=ctx.auto_partitioned)]}
+    out = jnp.take(w, ids, axis=0)
+    if padding_idx is not None and padding_idx >= 0:
+        mask = (ids == padding_idx)[..., None]
+        out = jnp.where(mask, jnp.zeros_like(out), out)
+    return {'Out': [out]}
 
 
 @register('lookup_table')

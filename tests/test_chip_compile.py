@@ -21,12 +21,11 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops import registry
-from paddle_tpu.ops.pallas import (common, embedding, flash_attention,
+from paddle_tpu.ops.pallas import (common, flash_attention,
                                    quant_collective)
 
 # BERT-base (models.bert.BASE) at the chip_smoke.py width
 VOCAB, MAX_POS, HIDDEN, FFN, LAYERS = 30522, 512, 768, 3072, 12
-N_IDS = 4 * 2048
 
 
 @pytest.fixture(scope='module')
@@ -166,32 +165,58 @@ def test_grouped_expert_matmuls_at_the_olmoe_cell_shape(one_chip):
         16 * rows * d * 2
 
 
-@pytest.mark.parametrize('rows', [VOCAB, MAX_POS])
-def test_embedding_gather_and_scatter_add(one_chip, as_on_tpu, rows):
+def test_lookup_table_and_its_gradient_hold_no_kernel(one_chip):
+    """lookup_table_v2 and its gradient at the largest table a cell
+    has (olmoe_1b7b_s4096: 12,288 tokens into 50304 x 2048) compile
+    for the described chip with no kernel of ours."""
+    lower = registry.get('lookup_table_v2').fn
+
     def step(w, ids):
         def loss(w):
-            return jnp.sum(embedding.embedding_lookup(w, ids) ** 2)
+            out = lower(registry.LowerCtx(0), {'W': [w], 'Ids': [ids]},
+                        {'padding_idx': -1})['Out'][0]
+            return jnp.sum(out ** 2)
         return jax.value_and_grad(loss)(w)
 
-    n = _compile(step, one_chip, _spec((rows, HIDDEN)),
-                 _spec((4, 2048), jnp.int32))
-    _compiled_on_chip('embedding_lookup')
-    assert n == 2, n    # row gather + sorted scatter-add
+    text = _compiled(step, one_chip, _spec((50304, 2048)),
+                     _spec((3, 4096), jnp.int32)).as_text()
+    assert 'tpu_custom_call' not in text
 
 
-def test_embedding_fused_row_update(one_chip, as_on_tpu):
-    def step(w, mom, ids, g, lr):
-        return embedding.apply_update(
-            registry.LowerCtx(0),
-            {'Param': [w], 'Moment': [mom], 'Ids': [ids], 'Grad': [g],
-             'LearningRate': [lr]}, {'epsilon': 1e-6})
+def test_a_program_with_an_embedding_holds_no_mosaic_call(one_chip,
+                                                          as_on_tpu):
+    """layers.embedding over BERT's word table under Adagrad, the
+    whole train step as Executor.run would jit it, compiled for the
+    described chip: no kernel and no dispatch decision."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        ids = fluid.layers.data('ids', shape=[128], dtype='int64')
+        emb = fluid.layers.embedding(ids, size=[VOCAB, HIDDEN],
+                                     padding_idx=0)
+        loss = fluid.layers.reduce_mean(fluid.layers.square(emb))
+        fluid.optimizer.Adagrad(0.1).minimize(loss)
+    before = monitor.flat()
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        step = exe.compile(main, feed_names=['ids'],
+                           fetch_names=[loss.name])
+        scope = fluid.global_scope()
 
-    table = _spec((VOCAB, HIDDEN))
-    n = _compile(step, one_chip, table, table,
-                 _spec((N_IDS,), jnp.int32), _spec((N_IDS, HIDDEN)),
-                 _spec((1,)))
-    _compiled_on_chip('embedding_update')
-    assert n == 1, n
+        def held(n):
+            v = fluid.core.as_array(scope.find_var(n))
+            return _spec(v.shape, v.dtype)
+
+        state = {n: held(n) for n in step.state_names}
+        data = {n: _spec((8, 128), jnp.int32) if n == 'ids' else held(n)
+                for n in step.input_names}
+    text = _compiled(step.fn, one_chip, _spec((), jnp.int32), state,
+                     data, donate=(1,)).as_text()
+    assert 'tpu_custom_call' not in text
+    assert {k: v for k, v in monitor.flat().items()
+            if k.startswith('pallas/') and v != before.get(k, 0)} == {}
 
 
 def _bert_base_param_shapes():
@@ -260,5 +285,4 @@ def test_quant_collective_tiles(one_chip):
 def test_every_dispatchable_kernel_is_compiled_here():
     """A kernel registered later must bring its compile with it."""
     assert set(common.kernels()) == {
-        'flash_attention', 'embedding_lookup', 'embedding_update',
-        'quant_collective'}
+        'flash_attention', 'quant_collective'}

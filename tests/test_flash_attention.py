@@ -469,7 +469,7 @@ def test_kernels_answer_dense_under_a_gspmd_mesh(axes):
         max_pos=seq, dropout=0.0, attn_dropout=0.1)
     batch = models.bert.synthetic_batch(cfg, 4, seq,
                                         np.random.RandomState(0))
-    kernels = ('flash_attention', 'embedding_lookup')
+    kernels = ('flash_attention',)
 
     def counts():
         return np.array([[monitor.counter_value('pallas/%s/%s' % (k, c))

@@ -207,7 +207,7 @@ def _adam_run_capture():
         {'ph': 'X', 'name': 'f.2', 'dur': 30,
          'args': {'tf_op': 'jit_s/adam#7'}},
         {'ph': 'X', 'name': 'f.3', 'dur': 20,
-         'args': {'tf_op': 'jit_s/lookup_table_v2#0'}},
+         'args': {'tf_op': 'jit_s/softmax#0'}},
         # same type but NOT block-contiguous: its own run
         {'ph': 'X', 'name': 'f.4', 'dur': 10,
          'args': {'tf_op': 'jit_s/adam#9'}},
@@ -231,10 +231,10 @@ def test_worklist_ranks_contiguous_runs_deterministically(tmp_path):
     assert ['adam#9'] in [r['ops'] for r in wl1]
     # coverage cross-reference: no kernel serves adam runs (XLA's own
     # per-parameter fusions run at the memory system's pace); the
-    # registry does declare one for the lookup
+    # registry does declare one for the softmax
     assert top['covered_by'] is None
     assert {r['op_type']: r['covered_by'] for r in wl1}[
-        'lookup_table_v2'] == 'embedding_lookup'
+        'softmax'] == 'flash_attention'
     assert monitor.gauge_value('opprof/worklist_candidates') == \
         float(len(wl1))
     # the artifact round-trips as schema-stable JSON

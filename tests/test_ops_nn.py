@@ -252,6 +252,75 @@ class TestLookupTable(OpTest):
                         {'W': w, 'Ids': ids}, grad_slots=['W'])
 
 
+def _lookup_vjp(op, w, ids, padding_idx, cotangent):
+    """(Out, dW) of one of the three lookup ops through its registered
+    lowering; lookup_table takes its ids with a trailing unit axis."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import registry
+    fed = jnp.asarray(ids[..., None] if op == 'lookup_table' else ids)
+
+    def lookup(w):
+        return registry.get(op).fn(
+            registry.LowerCtx(0), {'W': [w], 'Ids': [fed]},
+            {'padding_idx': padding_idx})['Out'][0]
+
+    out, vjp = jax.vjp(lookup, w)
+    return out, vjp(jnp.asarray(cotangent, w.dtype))[0]
+
+
+_LOOKUP_OPS = ['lookup_table', 'lookup_table_v2', 'embedding']
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('rank', [1, 2, 3])
+@pytest.mark.parametrize('op', _LOOKUP_OPS)
+def test_lookup_forward_and_gradient_against_numpy(op, rank, dtype):
+    """The three lookup ops share one lowering: rows gathered in the
+    table's dtype, padding rows zero, and a gradient that sums the
+    cotangents of duplicate ids into their row and gives the padding
+    row none."""
+    import jax.numpy as jnp
+    vocab, width, pad = 11, 4, 3
+    r = np.random.RandomState(rank)
+    ids = r.randint(0, vocab, (2, 3, 5)[:rank]).astype('int32')
+    ids.flat[:3] = (7, 7, pad)      # a duplicate and a padding id
+    # small integers: every sum below is exact in bfloat16 too
+    w = jnp.asarray(r.randint(-4, 5, (vocab, width)), dtype)
+    cot = r.randint(-2, 3, ids.shape + (width,)).astype('float32')
+    out, grad = _lookup_vjp(op, w, ids, pad, cot)
+    assert out.dtype == w.dtype and grad.dtype == w.dtype
+    keep = (ids != pad)[..., None]
+    want = np.asarray(w, 'float32')[ids] * keep
+    assert np.array_equal(np.asarray(out, 'float32'), want)
+    want_grad = np.zeros((vocab, width), 'float32')
+    np.add.at(want_grad, ids, cot * keep)
+    assert np.array_equal(np.asarray(grad, 'float32'), want_grad)
+
+
+@pytest.mark.parametrize('op', _LOOKUP_OPS)
+def test_lookup_out_of_range_ids_are_jnp_takes_own(op):
+    """No clip of the lowering's own: an id outside [-V, V) reads a
+    row of NaN and trains nothing (jnp.take's default, where the
+    reference operator raises), an id in [-V, 0) counts from the
+    end."""
+    import jax.numpy as jnp
+    vocab, width = 6, 4
+    ids = np.array([-vocab - 1, -1, 0, vocab - 1, vocab, vocab + 7],
+                   'int32')
+    valid = np.array([False, True, True, True, False, False])
+    w = jnp.asarray(np.arange(1.0, 1 + vocab * width, dtype='float32')
+                    .reshape(vocab, width))
+    out, grad = _lookup_vjp(op, w, ids, -1,
+                            np.ones((ids.size, width), 'float32'))
+    out = np.asarray(out)
+    assert np.isnan(out[~valid]).all()
+    assert np.array_equal(out[valid], np.asarray(w)[ids[valid]])
+    want_grad = np.zeros((vocab, width), 'float32')
+    np.add.at(want_grad, ids[valid], 1.0)
+    assert np.array_equal(np.asarray(grad), want_grad)
+
+
 class TestTensorManip(OpTest):
     def test_reshape_transpose_concat(self):
         x = rng.randn(2, 6).astype('float32')

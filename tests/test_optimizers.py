@@ -398,15 +398,17 @@ def test_adam_segment_temporaries_stay_under_one_parameter_copy():
     assert mem.alias_size_in_bytes >= 3 * param_bytes, mem
 
 
-@pytest.mark.parametrize('suffix,value', [('fuse', False),
-                                          ('min_tensors', 8)])
-def test_removed_optimizer_grouping_flags_are_unknown_flags(suffix,
-                                                            value):
-    """The two knobs of the removed packed path are no flags any more:
-    no default, no environment pick-up, and setting one neither
-    re-keys a compiled program nor changes what it computes."""
+@pytest.mark.parametrize('suffix,value', [('opt_fuse', False),
+                                          ('opt_min_tensors', 8),
+                                          ('embedding', False),
+                                          ('embedding_min_rows', 8)])
+def test_removed_kernel_flags_are_unknown_flags(suffix, value):
+    """The knobs of the removed packed optimizer path and of the
+    removed embedding row kernels are no flags any more: no default,
+    no environment pick-up, and setting one neither re-keys a compiled
+    program nor changes what it computes."""
     from paddle_tpu.fluid import executor, flags, monitor
-    name = 'FLAGS_pallas_opt_' + suffix
+    name = 'FLAGS_pallas_' + suffix
     assert name not in flags._DEFAULTS
     assert fluid.get_flags(name) == {name: None}
     main, startup, loss = _mlp_with('adam', width=16)
@@ -440,3 +442,43 @@ def test_removed_optimizer_grouping_flags_are_unknown_flags(suffix,
     assert monitor.counter_value('executor/segments_lowered') == lowered
     for a, b in zip(base, again):
         assert np.array_equal(a, b)
+
+
+@pytest.mark.parametrize('whole_program_grad', [True, False])
+def test_adagrad_on_an_embedding_with_repeated_ids(whole_program_grad):
+    """An embedding parameter under Adagrad is lookup_table_v2_grad (a
+    scatter-add of the repeated ids' cotangents) + adagrad over the
+    table, nothing fused, and trains as numpy's Adagrad does."""
+    vocab, width, lr, eps = 20, 4, 0.1, 1e-6
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 3
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        ids = fluid.layers.data('ids', shape=[6], dtype='int64')
+        emb = fluid.layers.embedding(
+            ids, size=[vocab, width],
+            param_attr=fluid.ParamAttr(name='table'))
+        loss = fluid.layers.reduce_mean(fluid.layers.square(emb))
+        fluid.optimizer.Adagrad(lr, epsilon=eps).minimize(loss)
+    types = [op.type for op in main.global_block().ops]
+    assert types[-2:] == ['lookup_table_v2_grad', 'adagrad'], types
+    assert not [t for t in types if t.startswith('fused_')]
+    fed = np.array([[1, 1, 1, 7, 19, 7], [0, 1, 7, 7, 2, 2]], 'int64')
+    old = fluid.get_flags('FLAGS_whole_program_grad')
+    fluid.set_flags({'FLAGS_whole_program_grad': whole_program_grad})
+    try:
+        with fluid.scope_guard(fluid.Scope()):
+            exe = fluid.Executor(fluid.XLAPlace(0))
+            exe.run(startup)
+            table = np.array(fluid.global_scope().find_var('table'))
+            moment = np.zeros_like(table)
+            for _ in range(3):
+                exe.run(main, feed={'ids': fed}, fetch_list=[loss])
+                grad = np.zeros_like(table)
+                np.add.at(grad, fed, 2 * table[fed] / (fed.size * width))
+                moment += grad * grad
+                table -= lr * grad / (np.sqrt(moment) + eps)
+            got = np.array(fluid.global_scope().find_var('table'))
+    finally:
+        fluid.set_flags(old)
+    assert np.abs(table - got).max() < 1e-6
+    assert np.array_equal(table[3], got[3])     # an untouched row

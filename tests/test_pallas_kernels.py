@@ -13,7 +13,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers, monitor, progcheck
 from paddle_tpu.fluid.flags import _DEFAULTS, set_flags
 from paddle_tpu.ops import registry
-from paddle_tpu.ops.pallas import common, embedding
+from paddle_tpu.ops.pallas import common, flash_attention, quant_collective
 
 
 _PALLAS_FLAGS = [k for k in _DEFAULTS if k.startswith('FLAGS_pallas_')]
@@ -34,11 +34,10 @@ def _force(on=True):
 
 # ------------------------------------------------ dispatch contract
 
-def _emb_args():
+def _qkv(t=8):
     rng = np.random.RandomState(0)
-    w = jnp.asarray(rng.randn(600, 16).astype('float32'))
-    ids = jnp.asarray(rng.randint(0, 600, size=(7, 5)).astype('int64'))
-    return w, ids
+    return [jnp.asarray(rng.randn(1, t, 1, 8).astype('float32'))
+            for _ in range(3)]
 
 
 def test_auto_partitioned_is_the_callers_word_and_beats_force():
@@ -56,36 +55,39 @@ def test_auto_partitioned_is_the_callers_word_and_beats_force():
         ctx = registry.LowerCtx(0)
     assert ctx.auto_partitioned
     before = monitor.counter_value(
-        'pallas/embedding_lookup/fallback/auto_partitioned')
-    embedding.embedding_lookup(*_emb_args(),
-                               auto_partitioned=ctx.auto_partitioned)
-    assert common._LAST['embedding_lookup'] == {
+        'pallas/flash_attention/fallback/auto_partitioned')
+    flash_attention.flash_attention(
+        *_qkv(), min_seq=0, auto_partitioned=ctx.auto_partitioned)
+    assert common._LAST['flash_attention'] == {
         'path': 'dense', 'reason': 'auto_partitioned', 'interpret': False}
     assert monitor.counter_value(
-        'pallas/embedding_lookup/fallback/auto_partitioned') == before + 1
+        'pallas/flash_attention/fallback/auto_partitioned') == before + 1
 
 
 def test_pallas_flag_flip_rekeys_live_executor():
     """Flipping a FLAGS_pallas_* knob on an ALREADY-COMPILED executor
     must re-dispatch (the per-step executable cache keys on the pallas
     flag tuple); flipping back must be a cache hit, not a retrace."""
+    from paddle_tpu.fluid.layer_helper import LayerHelper
+    seq = flash_attention.FLASH_MIN_SEQ
     main, startup = fluid.Program(), fluid.Program()
-    main.random_seed = startup.random_seed = 13
     with fluid.program_guard(main, startup):
-        ids = layers.data('ids', shape=[1], dtype='int64')
-        emb = layers.embedding(ids, size=[600, 16])
-        loss = layers.reduce_mean(layers.fc(emb, 4))
-        fluid.optimizer.Adam(1e-2).minimize(loss)
-    feed = {'ids': np.random.RandomState(3).randint(
-        0, 600, size=(6, 1)).astype('int64')}
+        q, k, v = (layers.data(n, shape=[seq, 1, 8], dtype='float32')
+                   for n in 'qkv')
+        helper = LayerHelper('fused_multihead_attention')
+        out = helper.create_variable_for_type_inference('float32')
+        helper.append_op('fused_multihead_attention',
+                         inputs={'Q': q, 'K': k, 'V': v},
+                         outputs={'Out': out}, attrs={})
+        loss = layers.reduce_mean(out)
+    feed = dict(zip('qkv', (np.asarray(x) for x in _qkv(seq))))
     with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.XLAPlace(0))
-        exe.run(startup)
         exe.run(main, feed=feed, fetch_list=[loss])
-        assert common._LAST['embedding_lookup']['path'] == 'dense'
+        assert common._LAST['flash_attention']['path'] == 'dense'
         set_flags({'FLAGS_pallas_force': True})
         exe.run(main, feed=feed, fetch_list=[loss])
-        assert common._LAST['embedding_lookup'] == {
+        assert common._LAST['flash_attention'] == {
             'path': 'fused', 'reason': 'forced_interpret',
             'interpret': True}
         set_flags({'FLAGS_pallas_force': False})
@@ -93,107 +95,6 @@ def test_pallas_flag_flip_rekeys_live_executor():
         exe.run(main, feed=feed, fetch_list=[loss])
         assert monitor.counter_value(
             'executor/segments_lowered') == lowered
-
-
-# ------------------------------------------ fused embedding kernels
-
-def test_embedding_lookup_parity_bitwise():
-    w, ids = _emb_args()
-    set_flags({'FLAGS_pallas_embedding': True})
-    _force(True)
-    fused = embedding.embedding_lookup(w, ids, padding_idx=3)
-    _force(False)
-    dense = embedding._dense_lookup(w, ids, 3)
-    assert np.array_equal(np.asarray(fused), np.asarray(dense))
-
-
-def test_embedding_lookup_grad_collisions_bitwise():
-    """Cotangent scatter with heavily repeated ids: sorted runs
-    accumulate in-VMEM; result is bitwise the dense .at[].add."""
-    rng = np.random.RandomState(1)
-    w = jnp.asarray(rng.randn(520, 8).astype('float32'))
-    ids = jnp.asarray(
-        np.array([0, 5, 5, 5, 2, 519, 2, 5, 0, 0], np.int64))
-
-    def loss(fn, w):
-        return jnp.sum(fn(w, ids, -1) ** 2)
-
-    _force(True)
-    gf = jax.grad(lambda w: loss(embedding.embedding_lookup, w))(w)
-    _force(False)
-    gd = jax.grad(lambda w: loss(embedding._dense_lookup, w))(w)
-    assert np.array_equal(np.asarray(gf), np.asarray(gd))
-
-
-def test_embedding_update_collisions_and_padding():
-    rng = np.random.RandomState(2)
-    v, d = 530, 8
-    w = jnp.asarray(rng.randn(v, d).astype('float32'))
-    mom = jnp.asarray(np.abs(rng.randn(v, d)).astype('float32'))
-    ids = jnp.asarray(
-        np.array([7, 7, 7, 1, 0, 529, 1, 7], np.int64))
-    g = jnp.asarray(rng.randn(8, d).astype('float32'))
-    ins = {'Param': [w], 'Moment': [mom], 'Ids': [ids], 'Grad': [g],
-           'LearningRate': [jnp.asarray(np.float32(0.1))]}
-    attrs = {'epsilon': 1e-6, 'padding_idx': 1}
-    set_flags({'FLAGS_pallas_embedding': True})
-    _force(True)
-    fused = embedding.apply_update(registry.LowerCtx(0), ins, attrs)
-    _force(False)
-    dense = embedding.apply_update(registry.LowerCtx(0), ins, attrs)
-    for slot in ('ParamOut', 'MomentOut'):
-        np.testing.assert_allclose(
-            np.asarray(fused[slot][0]), np.asarray(dense[slot][0]),
-            rtol=2e-6, atol=2e-6, err_msg=slot)
-    # padding rows and untouched rows are bit-identical to the input
-    for row in (1, 2, 100):
-        assert np.array_equal(np.asarray(fused['ParamOut'][0][row]),
-                              np.asarray(w[row]))
-
-
-def test_adagrad_embedding_rewrite_end_to_end():
-    """Embedding + Adagrad: the graph rewrite replaces the dense
-    lookup_table_v2_grad scatter + full-table adagrad pair with one
-    fused_emb_update op, and training matches the unrewritten program
-    bitwise under dense dispatch."""
-    def build(rewrite):
-        set_flags({'FLAGS_pallas_embedding': rewrite})
-        main, startup = fluid.Program(), fluid.Program()
-        main.random_seed = startup.random_seed = 13
-        with fluid.program_guard(main, startup):
-            ids = layers.data('ids', shape=[1], dtype='int64')
-            emb = layers.embedding(ids, size=[600, 16])
-            pred = layers.fc(emb, 4)
-            loss = layers.reduce_mean(pred)
-            fluid.optimizer.Adagrad(0.05).minimize(loss)
-        return main, startup, loss
-
-    main, _, _ = build(True)
-    types = [op.type for op in main.global_block().ops]
-    assert 'fused_emb_update' in types
-    assert 'lookup_table_v2_grad' not in types
-    main, _, _ = build(False)
-    types = [op.type for op in main.global_block().ops]
-    assert 'fused_emb_update' not in types
-
-    feed = {'ids': np.random.RandomState(3).randint(
-        0, 600, size=(6, 1)).astype('int64')}
-
-    def run(rewrite, force):
-        main, startup, loss = build(rewrite)
-        set_flags({'FLAGS_pallas_force': force})
-        with fluid.scope_guard(fluid.Scope()):
-            exe = fluid.Executor(fluid.XLAPlace(0))
-            exe.run(startup)
-            return np.asarray(
-                [exe.run(main, feed=feed, fetch_list=[loss])[0]
-                 for _ in range(4)])
-
-    base = run(False, False)
-    rewritten = run(True, False)
-    forced = run(True, True)
-    assert np.array_equal(base, rewritten)
-    np.testing.assert_allclose(forced, base, rtol=2e-5, atol=1e-6)
 
 
 # --------------------------------------- fused quantized collective
@@ -272,22 +173,21 @@ def test_comms_plan_fused_quant_admissibility():
 
 def test_kernel_registry_contract():
     ks = common.kernels()
-    assert set(ks) == {'flash_attention', 'embedding_lookup',
-                       'embedding_update', 'quant_collective'}
+    assert set(ks) == {'flash_attention', 'quant_collective'}
     for name in ks:
         assert ks[name]['dense_fallback'], name
 
 
 def test_dispatch_reasons_and_statusz():
-    set_flags({'FLAGS_pallas_embedding': False})
-    embedding.embedding_lookup(*_emb_args())
-    assert common._LAST['embedding_lookup']['reason'] == 'flag_off'
+    set_flags({'FLAGS_pallas_quant_collective': False})
+    assert quant_collective.dispatch() == (False, False)
+    assert common._LAST['quant_collective']['reason'] == 'flag_off'
     assert monitor.counter_value(
-        'pallas/embedding_lookup/fallback/flag_off') > 0
+        'pallas/quant_collective/fallback/flag_off') > 0
     from paddle_tpu.fluid import health
     rep = health.statusz()['pallas']
-    assert rep and 'embedding_lookup' in rep['kernels']
-    k = rep['kernels']['embedding_lookup']
+    assert rep and 'quant_collective' in rep['kernels']
+    k = rep['kernels']['quant_collective']
     assert k['last']['reason'] == 'flag_off'
     assert k['dense_fallback']
 
@@ -297,18 +197,19 @@ def test_dispatch_reasons_and_statusz():
 def test_progcheck_programs_with_fused_ops():
     """The static verifier walks a program containing the fused op
     (shape inference runs the real lowering via eval_shape)."""
-    # fused_emb_update via the Adagrad rewrite
-    set_flags({'FLAGS_pallas_embedding': True})
+    from paddle_tpu import models
+    seq = flash_attention.FLASH_MIN_SEQ
+    cfg = models.bert.BertConfig(
+        vocab_size=64, hidden=32, layers=1, heads=2, intermediate=32,
+        max_pos=seq, dropout=0.0)
     main, startup = fluid.Program(), fluid.Program()
     main.random_seed = startup.random_seed = 17
     with fluid.program_guard(main, startup):
-        ids = layers.data('ids', shape=[1], dtype='int64')
-        emb = layers.embedding(ids, size=[600, 16])
-        loss = layers.reduce_mean(layers.fc(emb, 4))
+        feeds, _, loss = models.bert.build_pretrain(cfg, seq)
         fluid.optimizer.Adagrad(0.05).minimize(loss)
-    assert 'fused_emb_update' in [op.type for op in
-                                  main.global_block().ops]
+    assert 'fused_multihead_attention' in [
+        op.type for op in main.global_block().ops]
     rep = progcheck.verify_program(
-        main, feed_names=('ids',), fetch_names=(loss.name,),
+        main, feed_names=tuple(feeds), fetch_names=(loss.name,),
         startup_program=startup, level='full', raise_on_error=False)
     assert rep.ok(), rep.format()

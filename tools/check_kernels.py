@@ -9,9 +9,8 @@ auto-dispatch + dense-fallback contract:
      from the package import would otherwise vanish without a gate);
   2. parity: each kernel's forced-fused (interpret) path against its
      dense reference on CPU — bitwise where the reference is exact
-     (embedding gather/scatter, blockwise quantize), tolerance-bounded
-     where the compiled kernel body may contract FMAs (the adagrad
-     row update);
+     (blockwise quantize), tolerance-bounded where the kernel body
+     sums in another order (flash attention's online softmax);
   3. observability: every dispatch lands a pallas/<kernel>/dispatch_*
      counter and a last-decision record with a reason, and the
      /statusz pallas section renders them — a silent dense fallback
@@ -26,8 +25,7 @@ Run from `make check` (CPU: JAX_PLATFORMS=cpu).
 import os
 import sys
 
-EXPECTED = ('flash_attention', 'embedding_lookup', 'embedding_update',
-            'quant_collective')
+EXPECTED = ('flash_attention', 'quant_collective')
 
 
 def main():
@@ -40,8 +38,8 @@ def main():
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid import health, monitor
     from paddle_tpu.fluid.flags import _DEFAULTS
-    from paddle_tpu.ops import registry
-    from paddle_tpu.ops.pallas import common, embedding, quant_collective
+    from paddle_tpu.ops.pallas import (common, flash_attention,
+                                       quant_collective)
 
     failures = []
 
@@ -57,35 +55,23 @@ def main():
 
     # -- 2. parity, forced-fused vs dense ----------------------------
     rng = np.random.RandomState(0)
-    w = jnp.asarray(rng.randn(600, 8).astype('float32'))
-    ids = jnp.asarray(np.array([3, 3, 0, 599, 3], np.int64))
-    fluid.set_flags({'FLAGS_pallas_force': True})
-    lf = embedding.embedding_lookup(w, ids, -1)
-    gf = jax.grad(lambda w: jnp.sum(
-        embedding.embedding_lookup(w, ids, -1) ** 2))(w)
-    fluid.set_flags({'FLAGS_pallas_force': False})
-    ld = embedding._dense_lookup(w, ids, -1)
-    gd = jax.grad(lambda w: jnp.sum(
-        embedding._dense_lookup(w, ids, -1) ** 2))(w)
-    if not np.array_equal(np.asarray(lf), np.asarray(ld)):
-        failures.append('embedding_lookup forward not bitwise')
-    if not np.array_equal(np.asarray(gf), np.asarray(gd)):
-        failures.append('embedding_lookup scatter-add grad not bitwise')
+    qkv = [jnp.asarray(rng.randn(1, 32, 2, 8).astype('float32'))
+           for _ in range(3)]
 
-    mom = jnp.asarray(np.abs(rng.randn(600, 8)).astype('float32'))
-    g = jnp.asarray(rng.randn(5, 8).astype('float32'))
-    upd_ins = {'Param': [w], 'Moment': [mom], 'Ids': [ids],
-               'Grad': [g],
-               'LearningRate': [jnp.asarray(np.float32(0.1))]}
+    def attend(q, k, v):
+        return jnp.sum(flash_attention.flash_attention(
+            q, k, v, causal=True, min_seq=0) ** 2)
+
     fluid.set_flags({'FLAGS_pallas_force': True})
-    uf = embedding.apply_update(registry.LowerCtx(0), upd_ins, {})
+    fused = jax.value_and_grad(attend, (0, 1, 2))(*qkv)
     fluid.set_flags({'FLAGS_pallas_force': False})
-    ud = embedding.apply_update(registry.LowerCtx(0), upd_ins, {})
-    for slot in ('ParamOut', 'MomentOut'):
-        if not np.allclose(np.asarray(uf[slot][0]),
-                           np.asarray(ud[slot][0]),
-                           rtol=2e-6, atol=2e-6):
-            failures.append('embedding_update %s parity' % slot)
+    dense = jax.value_and_grad(attend, (0, 1, 2))(*qkv)
+    for a, b in zip(jax.tree_util.tree_leaves(fused),
+                    jax.tree_util.tree_leaves(dense)):
+        if not np.allclose(np.asarray(a), np.asarray(b),
+                           rtol=5e-5, atol=5e-5):
+            failures.append('flash_attention forward/grad parity')
+            break
 
     flat = jnp.asarray(rng.randn(16, 256).astype('float32'))
     qv, s = quant_collective.quantize_blocks(flat, True)
@@ -102,10 +88,11 @@ def main():
     if not (np.array_equal(np.asarray(qv), np.asarray(qref)) and
             np.array_equal(np.asarray(s), np.asarray(sref))):
         failures.append('quantize_blocks not bitwise vs dense q()')
-    print('parity: embedding lookup/grad/update, quantize_blocks ok')
+    print('parity: flash_attention fwd/grad, quantize_blocks ok')
 
     # -- 3. dispatch observability -----------------------------------
-    for name in ('embedding_lookup', 'embedding_update'):
+    quant_collective.dispatch()
+    for name in EXPECTED:
         got = monitor.counter_value(
             'pallas/%s/dispatch_fused' % name) + \
             monitor.counter_value('pallas/%s/dispatch_dense' % name)
@@ -121,7 +108,7 @@ def main():
     if not rep or not rep.get('kernels'):
         failures.append('/statusz pallas section missing or empty')
     else:
-        for name in ('embedding_lookup', 'embedding_update'):
+        for name in EXPECTED:
             if name not in rep['kernels']:
                 failures.append('/statusz pallas section lacks %r'
                                 % name)
