@@ -17,12 +17,14 @@ analog of the reference's nGraph engine-op precedent
 Host ops (feed/fetch/save/load/print) cut segments and run on the host.
 """
 
+import contextlib
 import time as _time_mod
 import weakref
 
 import numpy as np
 import jax
 
+from . import comms
 from . import compile_cache
 from . import core
 from . import faultinject as _finject
@@ -33,6 +35,7 @@ from . import opprof as _opprof
 from . import supervisor as _sup
 from . import timeseries as _tseries
 from . import trace as _trace
+from .flags import get_flag
 from ..ops import registry
 
 
@@ -68,7 +71,6 @@ class _Segment(object):
         # sizes, + per-shape AOT spec keys when the compile plane is
         # on) — bucketing/re-tracing would otherwise grow this without
         # bound in a long-running service
-        from .flags import get_flag
         self.compiled = compile_cache.LRUCache(
             lambda: get_flag('FLAGS_segment_cache_capacity', 32),
             'executor/segment_cache_evictions')
@@ -348,6 +350,30 @@ def _survivable_copy(v):
         except Exception:
             return np.asarray(v)
     return v
+
+
+def _segment_label(seg, comms_key=None):
+    """A segment's name in watchdog dumps, op-cost snapshots and
+    estimated memory rows."""
+    if comms_key is not None:
+        return '%dops@%s' % (len(seg.ops), str(comms_key)[:8])
+    return '%dops:%s' % (len(seg.ops),
+                         ','.join(sorted(seg.output_names)[:3]))
+
+
+def _dispatch_span(comms_key, records):
+    """The segment-dispatch trace span, annotated with the segment's
+    collective profile (payload/wire bytes, per-kind call counts, mesh
+    axes, participants) when it has one.  No span kwargs otherwise:
+    disabled-mode cost must stay one truth test, one call and one
+    global load, allocation free (the merged timeline names the segment
+    anyway via the jit scope); the profile itself is the memoized
+    summary of the frozen records."""
+    if records and _trace.is_active():
+        annot = comms.summary_for(comms_key)
+        if annot:
+            return _trace.span('dispatch', **annot)
+    return _trace.span('dispatch')
 
 
 def _segment_health_names(seg):
@@ -681,6 +707,16 @@ def _add_note(e, note):
         pass
 
 
+def _flight_dump_note(tag, extra=None):
+    """Dump the flight recorder for an incident (a failed dispatch, a
+    NaN trip): the line that names the dump, None while the tracer is
+    off."""
+    dump = _trace.dump_on_error(tag, extra=extra)
+    if dump:
+        return ('trace flight recorder (last %d steps) dumped to %s'
+                % (len(_trace.steps()), dump))
+
+
 def _op_error_context(op, ins):
     """One text block describing the failing op: type, input
     shapes/dtypes, and the user callstack recorded at op creation."""
@@ -1004,7 +1040,6 @@ def _pallas_flag_items():
     """Pallas kernel dispatch happens at trace time, so every knob that
     flips a fused/dense decision must key the executable — both the
     persistent fingerprint and the per-step in-memory cache key."""
-    from .flags import get_flag
     return (bool(get_flag('FLAGS_pallas_force', False)),
             bool(get_flag('FLAGS_pallas_quant_collective', True)))
 
@@ -1013,7 +1048,6 @@ def _lowering_flag_items(prefer_test, wpg, auto=False):
     """The flag values that change a segment's lowering — exactly the
     set the in-memory executable key already guards — as a fingerprint
     component."""
-    from .flags import get_flag
     return (bool(prefer_test), bool(wpg), bool(auto),
             str(get_flag('FLAGS_conv_precision', 'highest'))) + \
         _pallas_flag_items()
@@ -1210,23 +1244,9 @@ class CompiledPipeline(object):
     def __call__(self, feed=None, scope=None, return_numpy=True):
         scope = scope or core.global_scope()
         exe = self._exe
-        exe._step += 1
-        t0 = _time_mod.perf_counter()
-        with _trace.step_span(exe._step):
-            out = exe._run_plan(self._program, self._plan, feed or {},
-                                self.fetch_names, scope, return_numpy)
-            exe._post_step(self._program, scope)
-        # same instrumentation as Executor.run: this is the other
-        # per-step entry point, monitor dumps must cover both
-        monitor.add('executor/run_calls')
-        monitor.observe('executor/run_seconds',
-                        _time_mod.perf_counter() - t0)
-        monitor.set_gauge('executor/last_step_unix_ts',
-                          _time_mod.time())
-        # windowed-history sample at the step boundary (one flag read
-        # when FLAGS_timeseries is off — the memviz.maybe_sample deal)
-        _tseries.maybe_sample(exe._step)
-        return out
+        with exe._step_scope(self._program, scope):
+            return exe._run_plan(self._program, self._plan, feed or {},
+                                 self.fetch_names, scope, return_numpy)
 
 
 class Executor(object):
@@ -1236,6 +1256,7 @@ class Executor(object):
         self.place = place or core.XLAPlace(0)
         self._step = 0
         self._opprof_step = False
+        self._posture = (False, False, 0.0)
         # FLAGS_status_port: the status/metrics HTTP plane starts with
         # the first executor (no-op when the flag is 0 or a server is
         # already up)
@@ -1340,7 +1361,6 @@ class Executor(object):
             raise ValueError(
                 'feed names %r are not read by the program (inputs: '
                 '%r)' % (bogus, sorted(known)))
-        from .flags import get_flag
         wpg = bool(get_flag('FLAGS_whole_program_grad'))
         fn = _make_segment_fn(seg, prefer_test, whole_program_grad=wpg)
         # the compile plane keys the jit on the segment's content
@@ -1388,7 +1408,6 @@ class Executor(object):
         even without a cache dir (memory-only)."""
         import threading as _threading
         import numpy as _np
-        from .flags import get_flag
         # warmup is an explicit re-plan point: promote any pending
         # autopilot comms refit BEFORE fingerprinting, so this rebuild
         # traces exactly once onto the refit coefficients and the plan
@@ -1636,43 +1655,71 @@ class Executor(object):
 
     def _run(self, program, feed, fetch_list, scope, return_numpy,
              use_program_cache):
+        """Pick the runner.  All three (and CompiledPipeline) run their
+        plan inside `_step_scope` and dispatch every segment through
+        `_dispatch_segment`; what a runner owns is how arguments are
+        placed, how the executable is built and what its fingerprint
+        holds."""
         from .compiler import CompiledProgram
         from .parallel_executor import run_parallel, run_collective
+        compiled = None
+        if isinstance(program, CompiledProgram):
+            if program._is_data_parallel:
+                compiled = program
+            program = program.program
+        program = program or framework.default_main_program()
+        scope = scope or core.global_scope()
+        feed = feed or {}
+        fetch_names = [v.name if isinstance(v, framework.Variable) else v
+                       for v in fetch_list or []]
+        if compiled is not None:
+            return run_parallel(self, compiled, feed, fetch_names, scope,
+                                return_numpy)
+        if getattr(program, '_collective_dp', False):
+            return run_collective(self, program, feed, fetch_names,
+                                  scope, return_numpy)
+        plan = self._get_plan(program, tuple(sorted(feed.keys())),
+                              tuple(fetch_names),
+                              use_cache=use_program_cache)
+        with self._step_scope(program, scope):
+            return self._run_plan(program, plan, feed, fetch_names,
+                                  scope, return_numpy)
+
+    @contextlib.contextmanager
+    def _step_scope(self, program, scope):
+        """What a step boundary is, for every runner (one chip,
+        `with_data_parallel`, the collective runner, CompiledPipeline):
+        the only place that counts the step and calls each plane's
+        step hook.  The body is the runner's plan walk and fetch
+        resolution, inside the step span and the ambient memviz
+        program label (per-(program, segment) HBM attribution and the
+        collective planner's per-program headroom resolve through
+        it).  A body that raises closes the span and skips everything
+        after it: a failed step is not a completed one.  Every hook
+        costs one flag or module-global read while its plane is off."""
         if _sup.active():
             # self-healing controller: a pending recovery executes at
             # this step boundary (and raises supervisor.Recovered so
             # the train loop re-reads the rewound step counter)
             _sup.on_step_begin(self)
-        if isinstance(program, CompiledProgram):
-            out = run_parallel(self, program, feed, fetch_list, scope,
-                               return_numpy)
-            if _sup.active():
-                _sup.on_step_end(self)
-            return out
-        program = program or framework.default_main_program()
-        if getattr(program, '_collective_dp', False):
-            out = run_collective(self, program, feed, fetch_list,
-                                 scope, return_numpy)
-            if _sup.active():
-                _sup.on_step_end(self)
-            return out
-        scope = scope or core.global_scope()
-        feed = feed or {}
-        fetch_list = fetch_list or []
-        fetch_names = [v.name if isinstance(v, framework.Variable) else v
-                       for v in fetch_list]
-
-        plan = self._get_plan(program, tuple(sorted(feed.keys())),
-                              tuple(fetch_names),
-                              use_cache=use_program_cache)
         self._step += 1
         if _finject.armed():
             # chaos hook: 'executor.step:die@N' is worker death mid-run
             _finject.check('executor.step', step=self._step)
         t0 = _time_mod.perf_counter()
+        # the step's debugging posture, read once here and not per
+        # segment: the op-cost snapshot decision (fluid.opprof), the
+        # NaN sweep, the tensor-health summaries, the hung-step
+        # watchdog's deadline
+        self._opprof_step = _opprof.want_snapshot(self._step)
+        self._posture = (
+            bool(get_flag('FLAGS_check_nan_inf')),
+            bool(get_flag('FLAGS_health_summaries')),
+            float(get_flag('FLAGS_step_timeout_s', 0.0) or 0.0))
         with _trace.step_span(self._step):
-            out = self._run_plan(program, plan, feed, fetch_names,
-                                 scope, return_numpy)
+            with _memviz.program_scope(_memviz.program_label(program)):
+                yield
+            _memviz.maybe_sample(self._step, scope)
             self._post_step(program, scope)
         # dispatch-side wall time: jit dispatch is async, so this is the
         # host cost of one step (compiles land here on cold caches)
@@ -1683,14 +1730,12 @@ class Executor(object):
         # complete a step (one clock read + dict store)
         monitor.set_gauge('executor/last_step_unix_ts',
                           _time_mod.time())
-        # windowed-history sample at the step boundary (one flag read
-        # when FLAGS_timeseries is off — the memviz.maybe_sample deal)
+        # windowed-history sample at the step boundary
         _tseries.maybe_sample(self._step)
         if _sup.active():
             # checkpoint cadence runs at the step boundary, on this
             # thread: a snapshot here can never mix two steps' params
             _sup.on_step_end(self)
-        return out
 
     def program_cost(self, program, feed, fetch_list=None, scope=None):
         """XLA cost analysis summed over the program's device segments
@@ -1700,7 +1745,6 @@ class Executor(object):
         Segments are lowered/compiled AOT here; the XLA compile caches
         (service + persistent) dedupe against the run-path executables.
         """
-        from .flags import get_flag
         scope = scope or core.global_scope()
         feed = feed or {}
         fetch_list = fetch_list or []
@@ -1740,8 +1784,8 @@ class Executor(object):
         return total
 
     def _post_step(self, program, scope):
-        """Per-step hooks shared by run() and CompiledPipeline: k-step
-        LocalSGD sync and the async-PS grad push/param pull."""
+        """k-step LocalSGD sync and the async-PS grad push / param
+        pull, at the end of every runner's step."""
         lsgd = getattr(program, '_local_sgd', None)
         if lsgd:
             lsgd['count'] = lsgd.get('count', 0) + 1
@@ -1807,7 +1851,6 @@ class Executor(object):
         'progcheck.mutate' chaos site, then run the fluid.progcheck
         pass when FLAGS_program_verify is on.  Error-class findings
         raise ProgramVerifyError before anything traces."""
-        from .flags import get_flag
         if _finject.armed():
             c = _finject.check('progcheck.mutate')
             if c is not None and c['action'] == 'mutate':
@@ -1906,7 +1949,6 @@ class Executor(object):
         # extra outputs: vars consumed outside the program by host
         # protocols (e.g. async-PS grad push), exempt from DCE
         extra_outputs = set(getattr(program, '_extra_output_names', ()))
-        from .flags import get_flag
         if get_flag('FLAGS_health_summaries'):
             # tensor-health grad norms need the PARAM gradients
             # observable at the segment boundary (activation grads stay
@@ -2090,64 +2132,20 @@ class Executor(object):
 
     def _run_plan(self, program, plan, feed, fetch_names, scope,
                   return_numpy):
-        """Program-scoped wrapper over the plan interpreter: the
-        ambient memviz program label (per-(program, segment) HBM
-        attribution + the collective planner's per-program headroom
-        gate) and the flag-gated live-memory sampler ride here, so
-        BOTH per-step entry points (Executor.run, CompiledPipeline)
-        are covered.  Disabled memviz cost: one flag read per step."""
-        # op-cost snapshot decision for this step (fluid.opprof): one
-        # flag read when FLAGS_opprof is off — the memviz deal; both
-        # per-step entry points (Executor.run, CompiledPipeline) pass
-        # through here
-        self._opprof_step = _opprof.want_snapshot(self._step)
-        with _memviz.program_scope(_memviz.program_label(program)):
-            out = self._run_plan_inner(program, plan, feed,
-                                       fetch_names, scope,
-                                       return_numpy)
-        _memviz.maybe_sample(self._step, scope)
-        return out
-
-    def _run_plan_inner(self, program, plan, feed, fetch_names, scope,
-                        return_numpy):
+        """The one-chip runner's step body (Executor.run and
+        CompiledPipeline, inside `_step_scope`): stage the feeds, walk
+        the plan, resolve the fetches."""
         device = self.place.jax_device()
         feed = self._stage_feeds(program, plan, feed, device)
         fetched = {}
-        has_host = any(not isinstance(it, _Segment) for it in plan)
-        if has_host:
-            # host ops read vars through the scope; make feeds visible
-            for k, v in feed.items():
-                scope.set_var(k, v)
         prefer_test = any(isinstance(it, _Segment) and it.prefer_test
                           for it in plan)
-        from . import profiler as _profiler
-        prof = _profiler.is_enabled()
-        for item in plan:
-            if prof:
-                import time as _time
-                t0 = _time.perf_counter()
-            if isinstance(item, _Segment):
-                self._run_segment(item, feed, scope, device, fetched)
-            elif item[0] == 'bucket':
-                with _trace.span('bucket_count', op=item[1].type):
-                    self._run_bucket_count(item[1], feed, scope,
-                                           device, prefer_test)
-            else:
-                op = item[1]
-                monitor.add('executor/host_ops_run')
-                with _trace.span('host_op', op=op.type):
-                    registry.get(op.type).fn(self, scope, op)
-            if prof:
-                if isinstance(item, _Segment):
-                    # host-time to COMPLETION, not dispatch
-                    for n in item.output_names:
-                        if n in fetched:
-                            jax.block_until_ready(fetched[n])
-                    name = item.ops[0].type if len(item.ops) == 1 \
-                        else 'segment[%d ops]' % len(item.ops)
-                else:
-                    name = item[1].type
-                _profiler.record_op(name, _time.perf_counter() - t0)
+        self._walk_plan(
+            plan, feed, scope, fetched,
+            lambda seg: self._run_segment(seg, feed, scope, device,
+                                          fetched),
+            lambda op: self._run_bucket_count(op, feed, scope, device,
+                                              prefer_test))
         results = []
         for name in fetch_names:
             if name in fetched:
@@ -2176,6 +2174,49 @@ class Executor(object):
         if fetch_names:
             monitor.add('executor/fetch_vars', float(len(fetch_names)))
         return results
+
+    def _walk_plan(self, plan, feed, scope, fetched, run_segment,
+                   run_bucket=None):
+        """The plan walk of every runner: a segment goes to the
+        runner's `run_segment`, a host op runs here through the scope.
+        `run_bucket` (the auto-bucketed while's trip count) is the
+        one-chip runner's alone."""
+        if any(not isinstance(it, _Segment) for it in plan):
+            # host ops read vars through the scope; make feeds visible
+            for k, v in feed.items():
+                scope.set_var(k, v.data if isinstance(v, core.LoDTensor)
+                              else v)
+        from . import profiler as _profiler
+        prof = _profiler.is_enabled()
+        for item in plan:
+            if prof:
+                t0 = _time_mod.perf_counter()
+            if isinstance(item, _Segment):
+                run_segment(item)
+            elif item[0] == 'bucket':
+                if run_bucket is None:
+                    raise NotImplementedError(
+                        'a while loop with an unbounded gradient '
+                        '(auto-bucketed trip count) runs on one chip '
+                        'only, not under a mesh')
+                with _trace.span('bucket_count', op=item[1].type):
+                    run_bucket(item[1])
+            else:
+                op = item[1]
+                monitor.add('executor/host_ops_run')
+                with _trace.span('host_op', op=op.type):
+                    registry.get(op.type).fn(self, scope, op)
+            if prof:
+                if isinstance(item, _Segment):
+                    # host-time to COMPLETION, not dispatch
+                    for n in item.output_names:
+                        if n in fetched:
+                            jax.block_until_ready(fetched[n])
+                    name = item.ops[0].type if len(item.ops) == 1 \
+                        else 'segment[%d ops]' % len(item.ops)
+                else:
+                    name = item[1].type
+                _profiler.record_op(name, _time_mod.perf_counter() - t0)
 
     def _lookup_input(self, name, feed, scope):
         """One-off argument lookup for the cold paths (program_cost,
@@ -2249,11 +2290,13 @@ class Executor(object):
                 o.attrs['max_trip_count'] = bucket
 
     def _run_segment(self, seg, feed, scope, device, fetched):
+        """The one-chip runner's part of a segment: bind the arguments
+        and resolve the executable (AOT compile plane, or a lazy jit);
+        `_dispatch_segment` runs it."""
         # segments holding auto-bucketed while ops compile one
         # executable PER BUCKET (the masked-scan length is baked into
         # the trace); the cache also keys on the auto-layout flag so
         # toggling it takes effect on already-compiled programs
-        from .flags import get_flag
         auto = bool(get_flag('FLAGS_segment_auto_layout'))
         # flags that change the LOWERING must key the executable cache,
         # or toggling them after first compile is silently ignored
@@ -2266,45 +2309,6 @@ class Executor(object):
         if binder is None:
             binder = seg.binder = _SegmentBinder(seg)
         state, data = binder.bind(feed, scope)
-        check_nan = bool(get_flag('FLAGS_check_nan_inf'))
-        health_on = bool(get_flag('FLAGS_health_summaries'))
-        replay = None
-        if check_nan and get_flag('FLAGS_nan_replay', True):
-            # the op-by-op provenance replay needs the segment inputs
-            # AS FED; state buffers are donated (deleted by the step),
-            # so snapshot them now — async device copies, debug-mode
-            # only (data args are not donated: pointers suffice)
-            with _trace.span('nan_snapshot'):
-                replay = ({n: _survivable_copy(v)
-                           for n, v in state.items()}, dict(data))
-        opprof_snap = None
-        opprof_wall = None
-        if self._opprof_step:
-            # op-cost replay snapshot (fluid.opprof): same survivable-
-            # copy rule as the nan path — the donated state buffers
-            # are gone after the step; reuse a live nan snapshot
-            # instead of copying twice
-            if replay is not None:
-                opprof_snap = (dict(replay[0]), dict(data))
-            else:
-                opprof_snap = ({n: _survivable_copy(v)
-                                for n, v in state.items()}, dict(data))
-        prev_params = None
-        hp = None
-        if health_on:
-            hp = seg.health_params
-            if hp is None:
-                hp = seg.health_params = _segment_health_names(seg)
-            if hp[0]:
-                # update ratios compare against the pre-step weights,
-                # which the donated step deletes — same snapshot rule;
-                # a live nan-replay snapshot already paid for these
-                # copies, reuse it instead of copying params twice
-                src = replay[0] if replay is not None else None
-                prev_params = {
-                    n: (src[n] if src is not None and n in src
-                        else _survivable_copy(state[n]))
-                    for n in hp[0] if n in state}
         plane = compile_cache.plane()
         first_run = False
         if plane.active and not auto:
@@ -2375,14 +2379,10 @@ class Executor(object):
                 monitor.add('executor/segments_lowered')
                 compiled = seg.compiled[key] = _jit_segment(
                     seg, auto, whole_program_grad=wpg)
-                if _is_default_device(device):
-                    plane.note_lazy((id(seg), key), compiled,
-                                    _lowering_args(self._step, state,
-                                                   data))
             else:
                 monitor.add('executor/segment_cache_hit')
 
-        def _call(c):
+        def call(c=compiled):
             if _is_default_device(device):
                 # `device` IS where jax would place this anyway, so the
                 # default_device context is a no-op — and it must be
@@ -2395,53 +2395,148 @@ class Executor(object):
             with jax.default_device(device):
                 return c(self._step, state, data)
 
-        # hung-step watchdog (FLAGS_step_timeout_s): steady-state
-        # dispatches run under supervisor.guard_dispatch — a dispatch
-        # blocked past the deadline dumps the flight recorder with
-        # this segment named and raises StepTimeoutError instead of
-        # hanging the process.  First runs (compiles) are exempt: a
-        # legitimate cold compile can exceed any step deadline.
-        # Disabled (the default) this is one flag read per segment.
-        step_timeout = float(get_flag('FLAGS_step_timeout_s', 0.0)
-                             or 0.0)
+        def shape_polymorphic():
+            # an AOT executable is shape/tree-exact; an argument kind
+            # it cannot absorb (exotic array subclass, odd scalar)
+            # falls back to the shape-polymorphic jit — correctness
+            # over the cached-compile win
+            monitor.add('executor/compile_cache_fallbacks')
+            jitted = seg.compiled[skey] = _jit_segment(
+                seg, auto, whole_program_grad=wpg)
+            return lambda: call(jitted)
 
-        def _guarded_dispatch():
-            if _finject.armed():
-                # chaos hook: 'executor.dispatch:stall:<s>' is a hung
-                # device call — the watchdog's test vehicle on the
-                # single-device executor
-                _finject.check('executor.dispatch', step=self._step)
-            res = _call(compiled)
+        # the donated state is gone after the call: take its specs now
+        noted = _lowering_args(self._step, state, data) \
+            if first_run and _is_default_device(device) else None
+        self._dispatch_segment(
+            seg, call, state, data, feed, scope, fetched, first_run,
+            # one chip lowers every op the plain way, so a segment can
+            # be replayed op by op outside its executable
+            replayable=True,
+            aot_fallback=shape_polymorphic
+            if plane.active and not auto else None)
+        if noted is not None:
+            # only a program that ran: one whose first call raised (a
+            # feed of the wrong shape) cannot be lowered again, and
+            # would fail every later scope table of the process
+            plane.note_lazy((id(seg), key), compiled, noted)
+
+    def _dispatch_segment(self, seg, call, state, data, feed, scope,
+                          fetched, first_run, comms_key=None,
+                          replayable=False, aot_fallback=None,
+                          describe_args=False):
+        """What dispatching one compiled segment is, for every runner:
+        `call()` runs the executable the runner resolved on the
+        arguments it placed; everything around the call lives here, the
+        error path included.
+
+        `first_run`: the call traces and compiles (the 'compile' span
+        and the compile-seconds histogram; exempt from the watchdog: a
+        legitimate cold compile can exceed any step deadline).
+        `comms_key`: the fingerprint a mesh runner's shared jit is
+        registered under; its lowerings file collective records there
+        at trace time, and the executable exposes no memory analysis,
+        so its memory row is estimated from the arguments.  None on
+        one chip: no collective to account, and the compile plane
+        recorded the exact row when it built the executable.
+        `replayable`: the segment's ops can be run again one by one,
+        eagerly, on copies of these arguments (NaN provenance, op-cost
+        attribution).  Not under a mesh: c_* lowerings need shard_map's
+        bound axis names, mesh-aware lowerings need the trace mesh.
+        `aot_fallback`: builds the call to retry with when an AOT
+        executable refuses an argument's kind (the compile plane's
+        executables are shape- and tree-exact).
+        `describe_args`: on failure, spell out every argument's shape,
+        dtype and sharding: shard_map's own errors name the positions
+        of spec leaves, not variables."""
+        step = self._step
+        check_nan, health_on, step_timeout = self._posture
+        replay = opprof_snap = opprof_wall = prev_params = hp = None
+        if check_nan and replayable and get_flag('FLAGS_nan_replay',
+                                                 True):
+            # the op-by-op provenance replay needs the segment inputs
+            # AS FED; state buffers are donated (deleted by the step),
+            # so snapshot them now — async device copies, debug-mode
+            # only (data args are not donated: pointers suffice)
+            with _trace.span('nan_snapshot'):
+                replay = ({n: _survivable_copy(v)
+                           for n, v in state.items()}, dict(data))
+        if self._opprof_step and replayable:
+            # op-cost replay snapshot (fluid.opprof): same survivable-
+            # copy rule as the nan path — the donated state buffers
+            # are gone after the step; reuse a live nan snapshot
+            # instead of copying twice
+            if replay is not None:
+                opprof_snap = (dict(replay[0]), dict(data))
+            else:
+                opprof_snap = ({n: _survivable_copy(v)
+                                for n, v in state.items()}, dict(data))
+        if health_on:
+            hp = seg.health_params
+            if hp is None:
+                hp = seg.health_params = _segment_health_names(seg)
+            if hp[0]:
+                # update ratios compare against the pre-step weights,
+                # which the donated step deletes — same snapshot rule;
+                # a live nan-replay snapshot already paid for these
+                # copies, reuse it instead of copying params twice
+                src = replay[0] if replay is not None else None
+                prev_params = {
+                    n: (src[n] if src is not None and n in src
+                        else _survivable_copy(state[n]))
+                    for n in hp[0] if n in state}
+        mesh = comms_key is not None
+        recs = comms.records_for(comms_key) if mesh else ()
+        chaos = _finject.armed()
+
+        def run():
+            if chaos:
+                # 'executor.dispatch:stall:<s>' is a hung device call,
+                # 'collective.dispatch:stall:<s>' a straggling
+                # collective, ':fail' a fabric fault
+                _finject.check('collective.dispatch' if mesh
+                               else 'executor.dispatch', step=step)
+            return call()
+
+        def watched():
+            res = run()
             # the execution sync must park INSIDE the guarded region:
-            # jit dispatch is async, so a wedged device call would
-            # otherwise hang later at fetch — outside the watchdog.
-            # Armed-mode cost: the step loses dispatch/compute overlap
-            # (the watchdog is an opt-in debugging/resilience posture).
+            # jit dispatch is async, so a wedged device call (or a
+            # collective blocked on a dead peer) would otherwise hang
+            # later, at fetch or at the donated-state release, outside
+            # the watchdog.  Armed-mode cost: the step loses
+            # dispatch/compute overlap (an opt-in resilience posture).
             jax.block_until_ready(res)
             return res
 
         try:
-            if first_run:
-                # the first call of a jitted segment traces + compiles
-                # synchronously (only execution is async), so timing it
-                # is the per-segment compile-latency histogram — and the
-                # step's 'compile' phase span; steady-state calls are
-                # the async 'dispatch' phase
+            if first_run or recs:
                 t0 = _time_mod.perf_counter()
             try:
-                # no span kwargs on this per-step site: disabled-mode
-                # cost must stay one call + one global load, allocation
-                # free (the merged timeline names the segment anyway
-                # via the jit scope)
-                if step_timeout > 0 and not first_run:
-                    with _trace.span('dispatch'):
+                if first_run:
+                    # the first call of a jitted segment traces +
+                    # compiles synchronously (only execution is
+                    # async), so timing it is the per-segment
+                    # compile-latency histogram — and the step's
+                    # 'compile' phase span; steady-state calls are the
+                    # async 'dispatch' phase
+                    with comms.collecting(comms_key) if mesh \
+                            else contextlib.nullcontext(), \
+                            _trace.span('compile'):
+                        out = run()
+                    if mesh:
+                        recs = comms.records_for(comms_key)
+                elif step_timeout > 0:
+                    # hung-step watchdog (FLAGS_step_timeout_s): a
+                    # dispatch blocked past the deadline dumps the
+                    # flight recorder with this segment named and
+                    # raises StepTimeoutError instead of hanging the
+                    # process
+                    with _dispatch_span(comms_key, recs):
                         out = _sup.guard_dispatch(
-                            _guarded_dispatch,
-                            '%dops:%s' % (
-                                len(seg.ops),
-                                ','.join(sorted(seg.output_names)[:3])),
-                            step_timeout, step=self._step)
-                elif opprof_snap is not None and not first_run:
+                            watched, _segment_label(seg, comms_key),
+                            step_timeout, step=step)
+                elif opprof_snap is not None:
                     # opprof snapshot step: park the sync INSIDE the
                     # dispatch span so the measured wall — the eager-
                     # replay normalization target — is this segment's
@@ -2450,42 +2545,61 @@ class Executor(object):
                     # attribution sums are checked against.  Costs the
                     # dispatch/compute overlap on snapshot steps only
                     # (an opt-in profiling posture).
-                    with _trace.span('dispatch'):
+                    with _dispatch_span(comms_key, recs):
                         t_sync0 = _time_mod.perf_counter()
-                        out = _call(compiled)
+                        out = run()
                         jax.block_until_ready(out)
                         opprof_wall = (_time_mod.perf_counter() -
                                        t_sync0)
                 else:
-                    with _trace.span('compile' if first_run
-                                     else 'dispatch'):
-                        out = _call(compiled)
+                    with _dispatch_span(comms_key, recs):
+                        out = run()
             except TypeError:
-                if first_run or not (plane.active and not auto):
+                if first_run or aot_fallback is None:
                     raise
-                # an AOT executable is shape/tree-exact; an argument
-                # kind it cannot absorb (exotic array subclass, odd
-                # scalar) falls back to the shape-polymorphic jit —
-                # correctness over the cached-compile win
-                monitor.add('executor/compile_cache_fallbacks')
-                compiled = seg.compiled[skey] = _jit_segment(
-                    seg, auto, whole_program_grad=wpg)
+                call = aot_fallback()
                 with _trace.span('compile', ops=len(seg.ops)):
-                    out = _call(compiled)
+                    out = call()
             if first_run:
-                monitor.observe('executor/segment_compile_seconds',
-                                _time_mod.perf_counter() - t0)
+                monitor.observe(
+                    'parallel/segment_compile_seconds' if mesh
+                    else 'executor/segment_compile_seconds',
+                    _time_mod.perf_counter() - t0)
+                if mesh:
+                    # keeps the per-program HBM headroom gate live for
+                    # programs a mesh runner compiled
+                    _memviz.record_segment_estimate(
+                        None, _segment_label(seg, comms_key), state,
+                        data, outputs=out, seg=seg)
+            if recs:
+                # achieved bandwidth needs the EXECUTION wall, not the
+                # async dispatch: block here — the donated-state
+                # release below would block on the in-flight execution
+                # anyway, so this only moves that sync earlier and
+                # attributes it to comms
+                jax.block_until_ready(out)
+                comms.account_dispatch(recs,
+                                       _time_mod.perf_counter() - t0,
+                                       compile_run=first_run)
         except Exception as e:
             note = _feed_mismatch_note(seg.ops[0].block.program, feed)
             if note:
                 _add_note(e, note)
+            if describe_args:
+                _add_note(e, 'segment inputs:\n  ' + '\n  '.join(
+                    '%s[%s]: %s %s %s' % (
+                        group, n, getattr(v, 'shape', '?'),
+                        getattr(v, 'dtype', '?'),
+                        getattr(v, 'sharding', type(v).__name__))
+                    for group, d in (('state', state), ('data', data))
+                    for n, v in d.items()))
             oom_note = None
             if _memviz.is_oom_error(e):
                 # OOM forensics (the memory analog of the NaN
                 # provenance path): embed the live census + per-segment
                 # peaks + largest buffers in the flight dump and name
                 # the top contributors in the error itself
-                oom_note = _memviz.oom_incident(e, step=self._step,
+                oom_note = _memviz.oom_incident(e, step=step,
                                                 scope=scope)
                 if oom_note:
                     _add_note(e, oom_note)
@@ -2493,25 +2607,20 @@ class Executor(object):
             # full flight recorder + snapshot, so the generic segfail
             # dump runs only when the OOM path didn't write one
             if not (oom_note and 'flight dump' in oom_note):
-                dump = _trace.dump_on_error(
-                    'segfail_step%d' % self._step)
-                if dump:
-                    _add_note(e, 'trace flight recorder (last %d '
-                              'steps) dumped to %s'
-                              % (len(_trace.steps()), dump))
+                note = _flight_dump_note('segfail_step%d' % step)
+                if note:
+                    _add_note(e, note)
             raise
-        if opprof_snap is not None and opprof_wall is not None:
+        if opprof_wall is not None:
             _opprof.note_segment(
-                _memviz.current_program(),
-                '%dops:%s' % (len(seg.ops),
-                              ','.join(sorted(seg.output_names)[:3])),
-                seg.ops, opprof_snap[0], opprof_snap[1], self._step,
+                _memviz.current_program(), _segment_label(seg),
+                seg.ops, opprof_snap[0], opprof_snap[1], step,
                 seg.prefer_test, opprof_wall)
         if check_nan:
             self._check_nan_inf(out, seg=seg, replay=replay)
-        if health_on and hp is not None and hp[0]:
+        if health_on and hp[0]:
             from . import health as _health
-            _health.summarize_step(self._step, out, prev_params or {},
+            _health.summarize_step(step, out, prev_params or {},
                                    hp[0], hp[1])
         for n, v in out.items():
             scope.set_var(n, v)
@@ -2564,11 +2673,9 @@ class Executor(object):
                  'bad_vars': bad}
         if report is not None:
             extra['provenance'] = report
-        dump = _trace.dump_on_error('nan_step%d' % self._step,
-                                    extra=extra)
-        if dump:
-            parts.append('trace flight recorder (last %d steps) '
-                         'dumped to %s' % (len(_trace.steps()), dump))
+        note = _flight_dump_note('nan_step%d' % self._step, extra)
+        if note:
+            parts.append(note)
         # the provenance/dump notes go INTO the message (this
         # interpreter may predate PEP 678 add_note) and as notes for
         # 3.11+ tooling that renders them separately

@@ -56,7 +56,7 @@ the artifact ROADMAP item 5's next kernels are chosen from.
 
 Hot-path discipline mirrors memviz: no jax import at module level,
 ``FLAGS_opprof`` off costs ONE flag read per step (the
-``want_snapshot`` gate in ``Executor._run_plan``), instance naming is
+``want_snapshot`` gate in ``Executor._step_scope``), instance naming is
 trace-time only, and all registries are bounded and lock-disciplined
 (tools/staticcheck.py LOCK_MODULES).
 """
@@ -201,7 +201,7 @@ def layer_of(op):
 
 # ------------------------------------------------------- replay snapshots
 def want_snapshot(step):
-    """The per-step gate ``Executor._run_plan`` reads ONCE per step:
+    """The per-step gate ``Executor._step_scope`` reads ONCE per step:
     False immediately when ``FLAGS_opprof`` is off (one flag read —
     the whole disabled-path cost), else the snapshot cadence."""
     if not get_flag('FLAGS_opprof'):

@@ -10,24 +10,18 @@ all-reduces for the replicated parameter updates.  Parameter "broadcast"
 is jit auto-replication of the scope's single-device arrays.
 """
 
-import time as _time_mod
-
 import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import comms
 from . import compile_cache
 from . import core
-from . import faultinject as _finject
 from . import memviz as _memviz
 from . import monitor
-from . import supervisor as _sup
-from . import timeseries as _tseries
 from . import trace as _trace
-from .executor import (_Segment, _SegmentBinder, FetchHandle,
-                       _make_segment_fn, _add_note, _lowering_args,
-                       _lowering_flag_items, _release_donated_state)
+from .executor import (_SegmentBinder, FetchHandle, _make_segment_fn,
+                       _lowering_args, _lowering_flag_items)
+from .flags import get_flag
 
 
 def _mesh_fingerprint_key(mesh):
@@ -73,56 +67,6 @@ def _resolve_fetches(fetch_names, fetched, scope, return_numpy):
             return _fetch_values(fetch_names, fetched, scope,
                                  return_numpy)
     return _fetch_values(fetch_names, fetched, scope, return_numpy)
-
-
-def _dispatch_span(name, key, records):
-    """The segment-dispatch trace span, annotated with the segment's
-    collective profile (payload/wire bytes, per-kind call counts, mesh
-    axes, participants) when it has one — comms-free segments pay one
-    truth test, and the profile itself is the memoized summary of the
-    frozen records (one dict lookup per step, not an O(records)
-    rebuild)."""
-    if records and _trace.is_active():
-        annot = comms.summary_for(key)
-        if annot:
-            return _trace.span(name, **annot)
-    return _trace.span(name)
-
-
-def _collective_dispatch(executor, compiled, args, seg, recs):
-    """Steady-state dispatch of a parallel/collective segment, under
-    the hung-step watchdog when FLAGS_step_timeout_s arms it: the
-    faultinject 'collective.dispatch' site, the jit call AND the
-    execution sync run inside the guarded region — a collective
-    blocked on a dead peer hangs at block_until_ready, which is
-    exactly what the watchdog must convert into a named timeout.
-    Disarmed (the default) this is one flag read per dispatch."""
-    from .flags import get_flag
-    timeout = float(get_flag('FLAGS_step_timeout_s', 0.0) or 0.0)
-
-    def _do():
-        if _finject.armed():
-            # chaos hook: 'collective.dispatch:stall:<s>' is a
-            # straggling collective, 'fail' a fabric fault
-            _finject.check('collective.dispatch',
-                           step=executor._step)
-        out = compiled(*args)
-        if timeout > 0:
-            # the execution sync must sit INSIDE the guarded region
-            # (the caller's later block_until_ready is then a no-op):
-            # a dead peer parks the dispatch here.  Unconditional —
-            # a segment whose comms records were evicted still hangs
-            # on a dead peer, and an async dispatch that returns
-            # immediately would dodge the watchdog entirely.
-            jax.block_until_ready(out)
-        return out
-
-    if timeout > 0:
-        return _sup.guard_dispatch(
-            _do,
-            '%dops@%s' % (len(seg.ops), str(seg.comms_key)[:8]),
-            timeout, step=executor._step)
-    return _do()
 
 
 def _default_mesh(places=None):
@@ -301,42 +245,42 @@ def get_mesh(compiled, program=None, feed=None):
     return _check_mesh_spans_processes(compiled._mesh)
 
 
-def run_parallel(executor, compiled, feed, fetch_list, scope, return_numpy):
-    program = compiled.program
-    if not compiled._is_data_parallel:
-        return executor._run(program, feed, fetch_list, scope,
-                             return_numpy, True)
-    scope = scope or core.global_scope()
-    feed = feed or {}
-    fetch_list = fetch_list or []
-    from . import framework
-    fetch_names = [v.name if isinstance(v, framework.Variable) else v
-                   for v in fetch_list]
-    mesh = get_mesh(compiled, program, feed)
-    ndev = mesh.devices.size
-    monitor.set_gauge('parallel/device_count', ndev)
-    monitor.set_gauge('parallel/process_count', jax.process_count())
-    t_run0 = _time_mod.perf_counter()
-
-    key = ('pplan', tuple(sorted(feed.keys())), tuple(fetch_names))
-    plan = compiled._exec_cache.get(key)
+def _runner_plan(executor, cache, key, program, feed, fetch_names,
+                 origin):
+    """The mesh runners' plan lookup; the plan-BUILD verification hook
+    is the single-device executor's discipline: cache misses only, one
+    flag read."""
+    plan = cache.get(key)
     monitor.add('parallel/plan_cache_hit' if plan is not None
                 else 'parallel/plan_cache_miss')
     if plan is None:
         plan = executor._build_plan(program, tuple(sorted(feed.keys())),
                                     tuple(fetch_names))
-        # plan-BUILD verification hook (same discipline as the
-        # single-device executor): cache misses only, one flag read
-        from .flags import get_flag as _gf
-        if _gf('FLAGS_program_verify'):
+        if get_flag('FLAGS_program_verify'):
             from . import progcheck
             progcheck.verify_program(
                 program, feed_names=tuple(sorted(feed.keys())),
                 fetch_names=tuple(fetch_names), plan=plan,
-                origin='parallel')
-        compiled._exec_cache[key] = plan
+                origin=origin)
+        cache[key] = plan
+    return plan
 
-    executor._step += 1
+
+def run_parallel(executor, compiled, feed, fetch_names, scope,
+                 return_numpy):
+    """`with_data_parallel` / `with_mesh`: one jit over the mesh per
+    segment, GSPMD partitions it.  Owns the mesh, the sharding rule
+    and the placement; the step is `Executor._step_scope`'s, the
+    dispatch `Executor._dispatch_segment`'s."""
+    program = compiled.program
+    mesh = get_mesh(compiled, program, feed)
+    ndev = mesh.devices.size
+    monitor.set_gauge('parallel/device_count', ndev)
+    monitor.set_gauge('parallel/process_count', jax.process_count())
+    plan = _runner_plan(
+        executor, compiled._exec_cache,
+        ('pplan', tuple(sorted(feed.keys())), tuple(fetch_names)),
+        program, feed, fetch_names, 'parallel')
     fetched = {}
     param_rule = getattr(compiled, '_param_sharding_rule', None)
     batch_axes = (mesh.axis_names[0],)
@@ -434,8 +378,7 @@ def run_parallel(executor, compiled, feed, fetch_list, scope, return_numpy):
                     shape[0] > 1:
                 return P(zero_axis)
             return None
-    from .flags import get_flag as _gf2
-    if _gf2('FLAGS_program_verify') and param_rule is not None and \
+    if get_flag('FLAGS_program_verify') and param_rule is not None and \
             not getattr(compiled, '_progcheck_shard_ok', False):
         # static sharding legality of the RESOLVED rule (user
         # with_param_shardings specs are otherwise unvalidated until
@@ -452,47 +395,28 @@ def run_parallel(executor, compiled, feed, fetch_list, scope, return_numpy):
             origin='with_param_shardings')
         compiled._progcheck_shard_ok = True
     batch_feeds = _batch_feed_names(program, feed)
-    # ambient program label: per-(program, segment) memory attribution
-    # and the collective planner's per-program HBM headroom resolve
-    # through it at trace time
-    with _memviz.program_scope(_memviz.program_label(program)), \
-            _trace.step_span(executor._step):
-        for item in plan:
-            if isinstance(item, _Segment):
-                _run_segment_parallel(executor, item, feed, scope, mesh,
-                                      ndev, fetched, param_rule,
-                                      batch_feeds, hints, batch_axes,
-                                      auto_plan)
-            else:
-                from ..ops import registry
-                op = item[1]
-                with _trace.span('host_op', op=op.type):
-                    registry.get(op.type).fn(executor, scope, op)
-        results = _resolve_fetches(fetch_names, fetched, scope,
-                                   return_numpy)
-    _memviz.maybe_sample(executor._step, scope)
-    # dispatch-side wall time: this runner is an Executor.run entry
-    # point too (CompiledProgram path), so it records the same counters
-    monitor.add('executor/run_calls')
-    monitor.observe('executor/run_seconds',
-                    _time_mod.perf_counter() - t_run0)
-    monitor.set_gauge('executor/last_step_unix_ts', _time_mod.time())
-    _tseries.maybe_sample(executor._step)
-    return results
+    with executor._step_scope(program, scope):
+        executor._walk_plan(
+            plan, feed, scope, fetched,
+            lambda seg: _run_segment_parallel(
+                executor, seg, feed, scope, fetched, mesh, param_rule,
+                batch_feeds, hints, batch_axes, auto_plan))
+        return _resolve_fetches(fetch_names, fetched, scope,
+                                return_numpy)
 
 
-def _run_segment_parallel(executor, seg, feed, scope, mesh, ndev, fetched,
-                          param_rule=None, batch_feeds=None, hints=None,
-                          batch_axes=None, auto_plan=None):
+def _run_segment_parallel(executor, seg, feed, scope, fetched, mesh,
+                          param_rule, batch_feeds, hints, batch_axes,
+                          auto_plan):
+    """One segment under GSPMD: place state and data by the resolved
+    rule, build (or share) the jit with those `in_shardings`, hand it
+    to the executor's dispatch."""
     repl = NamedSharding(mesh, P())
-    if batch_axes is None:
-        batch_axes = (mesh.axis_names[0],)
     dp_size = 1
     for a in batch_axes:
         dp_size *= mesh.shape[a]
     batch_spec = P(batch_axes if len(batch_axes) > 1
                    else batch_axes[0]) if batch_axes else P()
-    batch_feeds = feed if batch_feeds is None else batch_feeds
 
     def data_shard(name, val):
         if hints and name in hints and jax.process_count() == 1:
@@ -597,274 +521,126 @@ def _run_segment_parallel(executor, seg, feed, scope, mesh, ndev, fetched,
                                 donate_argnums=(1,)))
         seg.compiled['parallel'] = compiled
         seg.comms_key = fp
-        compile_cache.plane().note_lazy(
-            fp, compiled, _lowering_args(executor._step, state, data))
-    recs = comms.records_for(seg.comms_key)
-    try:
-        if first_run and _finject.armed():
-            # chaos hook: 'collective.dispatch:stall:<s>' is a
-            # straggling collective, 'fail' a fabric fault (the
-            # steady-state branch consults the site inside the
-            # watchdog-guarded dispatch below)
-            _finject.check('collective.dispatch', step=executor._step)
-        t0 = _time_mod.perf_counter()
-        if first_run:
-            # the first call runs the deferred jit trace: collect the
-            # collective records the lowerings file, keyed by the
-            # shared-jit fingerprint so reused jits keep their profile
-            with comms.collecting(seg.comms_key):
-                with _trace.span('compile'):
-                    out = compiled(executor._step, state, data)
-            recs = comms.records_for(seg.comms_key)
-            monitor.observe('parallel/segment_compile_seconds',
-                            _time_mod.perf_counter() - t0)
-            # estimated attribution (args + outputs; shared jits
-            # expose no memory_analysis): keeps the per-program HBM
-            # headroom gate live for runner-compiled programs
-            _memviz.record_segment_estimate(
-                None, '%dops@%s' % (len(seg.ops),
-                                    str(seg.comms_key)[:8]),
-                state, data, outputs=out, seg=seg)
-        else:
-            with _dispatch_span('dispatch', seg.comms_key, recs):
-                out = _collective_dispatch(
-                    executor, compiled, (executor._step, state, data),
-                    seg, recs)
-        if recs:
-            # achieved bandwidth needs the EXECUTION wall, not the
-            # async dispatch: block here — the donated-state release
-            # below would block on the in-flight execution anyway
-            # (PR 4's state_release discovery), so this only moves
-            # that sync earlier and attributes it to comms
-            jax.block_until_ready(out)
-            comms.account_dispatch(recs,
-                                   _time_mod.perf_counter() - t0,
-                                   compile_run=first_run)
-    except Exception as e:
-        # same incident contract as the single-device executor: the
-        # flight recorder holds the steps that led here — dump it
-        # (ONE dump: the OOM path's dump already embeds everything)
-        oom_note = None
-        if _memviz.is_oom_error(e):
-            oom_note = _memviz.oom_incident(e, step=executor._step,
-                                            scope=scope)
-            if oom_note:
-                _add_note(e, oom_note)
-        if not (oom_note and 'flight dump' in oom_note):
-            dump = _trace.dump_on_error(
-                'segfail_step%d' % executor._step)
-            if dump:
-                _add_note(e, 'trace flight recorder (last %d steps) '
-                          'dumped to %s' % (len(_trace.steps()), dump))
-        raise
-    for n, v in out.items():
-        scope.set_var(n, v)
-        fetched[n] = v
-    _release_donated_state(state)
+    _dispatch_noting(executor, seg, compiled, executor._step, state,
+                     data, feed, scope, fetched, first_run)
 
 
-def run_collective(executor, program, feed, fetch_list, scope,
+def _dispatch_noting(executor, seg, compiled, step, state, data, feed,
+                     scope, fetched, first_run, describe_args=False):
+    """Hand a mesh runner's shared jit to the executor's dispatch, and
+    after a first call that ran, tell the compile plane how to find
+    the compiled program again (a program whose first call raised
+    cannot be lowered again, and is not noted)."""
+    # the donated state is gone after the call: take its specs now
+    noted = _lowering_args(step, state, data) if first_run else None
+    executor._dispatch_segment(
+        seg, lambda: compiled(step, state, data), state, data, feed,
+        scope, fetched, first_run, comms_key=seg.comms_key,
+        describe_args=describe_args)
+    if first_run:
+        compile_cache.plane().note_lazy(seg.comms_key, compiled, noted)
+
+
+def run_collective(executor, program, feed, fetch_names, scope,
                    return_numpy):
     """Shard-map execution of a collective-rewritten program (fleet
     GradAllReduce mode): the program's c_allreduce_* ops lower to
     jax.lax collectives over the 'dp' mesh axis; each mesh device runs
     the trainer-local program on its batch shard."""
-    from . import core as _core
-    from . import framework
-    scope = scope or _core.global_scope()
-    feed = feed or {}
-    fetch_names = [v.name if isinstance(v, framework.Variable) else v
-                   for v in (fetch_list or [])]
     if getattr(program, '_mesh', None) is None:
         program._mesh = _default_mesh()
     mesh = _check_mesh_spans_processes(program._mesh)
-    ndev = mesh.devices.size
-    monitor.set_gauge('parallel/device_count', ndev)
-
-    key = ('cplan', tuple(sorted(feed.keys())), tuple(fetch_names),
-           id(executor))
-    plan = program._exec_cache.get(key)
-    monitor.add('parallel/plan_cache_hit' if plan is not None
-                else 'parallel/plan_cache_miss')
-    if plan is None:
-        plan = executor._build_plan(program, tuple(sorted(feed.keys())),
-                                    tuple(fetch_names))
-        from .flags import get_flag as _gf
-        if _gf('FLAGS_program_verify'):
-            from . import progcheck
-            progcheck.verify_program(
-                program, feed_names=tuple(sorted(feed.keys())),
-                fetch_names=tuple(fetch_names), plan=plan,
-                origin='collective')
-        program._exec_cache[key] = plan
-
-    executor._step += 1
-    t_run0 = _time_mod.perf_counter()
+    monitor.set_gauge('parallel/device_count', mesh.devices.size)
+    plan = _runner_plan(
+        executor, program._exec_cache,
+        ('cplan', tuple(sorted(feed.keys())), tuple(fetch_names),
+         id(executor)),
+        program, feed, fetch_names, 'collective')
     fetched = {}
     batch_feeds = _batch_feed_names(program, feed)
-    if any(not isinstance(it, _Segment) for it in plan):
-        # host ops read their inputs through the scope (same contract
-        # as Executor._run_plan): make feeds visible
-        for k, v in feed.items():
-            scope.set_var(k, v.data if isinstance(v, _core.LoDTensor)
-                          else v)
-    with _memviz.program_scope(_memviz.program_label(program)), \
-            _trace.step_span(executor._step):
-        _run_collective_plan(executor, plan, feed, scope, mesh, ndev,
-                             batch_feeds, fetched)
+    with executor._step_scope(program, scope):
+        executor._walk_plan(
+            plan, feed, scope, fetched,
+            lambda seg: _run_segment_collective(
+                executor, seg, feed, scope, fetched, mesh, batch_feeds))
         # fetch resolution inside the step span, same as run_parallel:
         # a blocking D2H here is step time the report must attribute
-        results = _resolve_fetches(fetch_names, fetched, scope,
-                                   return_numpy)
-    _memviz.maybe_sample(executor._step, scope)
-    monitor.add('executor/run_calls')
-    monitor.observe('executor/run_seconds',
-                    _time_mod.perf_counter() - t_run0)
-    monitor.set_gauge('executor/last_step_unix_ts', _time_mod.time())
-    _tseries.maybe_sample(executor._step)
-    return results
+        return _resolve_fetches(fetch_names, fetched, scope,
+                                return_numpy)
 
 
-def _run_collective_plan(executor, plan, feed, scope, mesh, ndev,
-                         batch_feeds, fetched):
-    """run_collective's per-item plan walk, under the step's trace
-    span: segment binds/dispatches and host ops record as phases."""
+def _run_segment_collective(executor, seg, feed, scope, fetched, mesh,
+                            batch_feeds):
+    """One segment under shard_map: batch feeds split over 'dp',
+    everything else replicated; build (or share) the jitted shard_map,
+    hand it to the executor's dispatch."""
     import jax.numpy as jnp
-    for item in plan:
-        if not isinstance(item, _Segment):
-            from ..ops import registry
-            with _trace.span('host_op', op=item[1].type):
-                registry.get(item[1].type).fn(executor, scope, item[1])
-            continue
-        seg = item
-        state, data = _bind_segment_args(seg, feed, scope)
-        data_specs = {n: (P('dp') if (n in feed and n in batch_feeds and
-                                      getattr(data[n], 'ndim', 0) >= 1 and
-                                      (jax.process_count() == 1 or
-                                       _guard_local_batch(n, data[n], mesh,
-                                                          ndev)))
-                          else P())
-                      for n in seg.input_names}
-        if jax.process_count() > 1:
-            # multi-trainer mode: feeds are process-local shards, params
-            # replicated global arrays (reference NCCL2 multi-process DP)
-            with _trace.span('place_state'):
-                state = {n: _to_global(v, NamedSharding(mesh, P()))
-                         for n, v in state.items()}
-            with _trace.span('place_data'):
-                data = {n: _to_global(
-                            v, NamedSharding(mesh, data_specs[n]),
-                            per_process=data_specs[n] != P())
-                        for n, v in data.items()}
-        compiled = seg.compiled.get('collective')
-        first_run = compiled is None
-        monitor.add('parallel/segment_cache_miss' if first_run
-                    else 'parallel/segment_cache_hit')
-        if compiled is None:
-            fn = _make_segment_fn(seg)
-            in_specs = (P(),
-                        {n: P() for n in seg.state_names},
-                        data_specs)
-            out_specs = {n: P() for n in seg.output_names}
-            # shared through the compile plane, same contract as the
-            # data-parallel runner above
-            # planner decisions resolve at trace time against this
-            # mesh; folding the digest in keys the executable (and its
-            # comms records) by the plan that produced it
-            from . import comms_plan
-            from ..parallel import plan as _ashard
-            fp = compile_cache.fingerprint(
-                seg.ops,
-                (_mesh_fingerprint_key(mesh), repr(in_specs),
-                 repr(out_specs), comms_plan.digest(),
-                 _ashard.digest()),
-                _lowering_flag_items(False, False),
-                donate=True, purpose='collective')
+    ndev = mesh.devices.size
+    state, data = _bind_segment_args(seg, feed, scope)
+    data_specs = {n: (P('dp') if (n in feed and n in batch_feeds and
+                                  getattr(data[n], 'ndim', 0) >= 1 and
+                                  (jax.process_count() == 1 or
+                                   _guard_local_batch(n, data[n], mesh,
+                                                      ndev)))
+                      else P())
+                  for n in seg.input_names}
+    if jax.process_count() > 1:
+        # multi-trainer mode: feeds are process-local shards, params
+        # replicated global arrays (reference NCCL2 multi-process DP)
+        with _trace.span('place_state'):
+            state = {n: _to_global(v, NamedSharding(mesh, P()))
+                     for n, v in state.items()}
+        with _trace.span('place_data'):
+            data = {n: _to_global(
+                        v, NamedSharding(mesh, data_specs[n]),
+                        per_process=data_specs[n] != P())
+                    for n, v in data.items()}
+    compiled = seg.compiled.get('collective')
+    first_run = compiled is None
+    monitor.add('parallel/segment_cache_miss' if first_run
+                else 'parallel/segment_cache_hit')
+    if compiled is None:
+        fn = _make_segment_fn(seg)
+        in_specs = (P(),
+                    {n: P() for n in seg.state_names},
+                    data_specs)
+        out_specs = {n: P() for n in seg.output_names}
+        # shared through the compile plane, same contract as the
+        # data-parallel runner above
+        # planner decisions resolve at trace time against this
+        # mesh; folding the digest in keys the executable (and its
+        # comms records) by the plan that produced it
+        from . import comms_plan
+        from ..parallel import plan as _ashard
+        fp = compile_cache.fingerprint(
+            seg.ops,
+            (_mesh_fingerprint_key(mesh), repr(in_specs),
+             repr(out_specs), comms_plan.digest(),
+             _ashard.digest()),
+            _lowering_flag_items(False, False),
+            donate=True, purpose='collective')
 
-            def _build(_fn=fn, _in=in_specs, _out=out_specs):
-                from ..compat import shard_map
-                sm = shard_map(_fn, mesh=mesh, in_specs=_in,
-                               out_specs=_out)
-                return jax.jit(sm, donate_argnums=(1,))
+        def _build(_fn=fn, _in=in_specs, _out=out_specs):
+            from ..compat import shard_map
+            sm = shard_map(_fn, mesh=mesh, in_specs=_in,
+                           out_specs=_out)
+            return jax.jit(sm, donate_argnums=(1,))
 
-            compiled = compile_cache.plane().shared_jit(fp, _build)
-            seg.compiled['collective'] = compiled
-            seg.comms_key = fp
-        if jax.process_count() > 1:
-            # a process-local scalar would carry an inconsistent
-            # single-device sharding across processes; replicate it
-            step = _to_global(np.int64(executor._step),
-                              NamedSharding(mesh, P()))
-        else:
-            step = jnp.asarray(executor._step)
-        if first_run:
-            compile_cache.plane().note_lazy(
-                seg.comms_key, compiled, _lowering_args(step, state, data))
-        recs = comms.records_for(seg.comms_key)
-        try:
-            if first_run and _finject.armed():
-                # steady-state dispatches consult the site inside the
-                # watchdog-guarded _collective_dispatch below
-                _finject.check('collective.dispatch',
-                               step=executor._step)
-            t0 = _time_mod.perf_counter()
-            if first_run:
-                # first call runs the deferred jit trace: collect the
-                # collective records the c_* lowerings file, keyed by
-                # the shared-jit fingerprint
-                with comms.collecting(seg.comms_key):
-                    with _trace.span('compile'):
-                        out = compiled(step, state, data)
-                recs = comms.records_for(seg.comms_key)
-                monitor.observe('parallel/segment_compile_seconds',
-                                _time_mod.perf_counter() - t0)
-                # same estimated attribution as the data-parallel
-                # runner: per-program headroom needs a per-program row
-                _memviz.record_segment_estimate(
-                    None, '%dops@%s' % (len(seg.ops),
-                                        str(seg.comms_key)[:8]),
-                    state, data, outputs=out, seg=seg)
-            else:
-                with _dispatch_span('dispatch', seg.comms_key, recs):
-                    out = _collective_dispatch(
-                        executor, compiled, (step, state, data),
-                        seg, recs)
-            if recs:
-                # bandwidth needs the execution wall, not the async
-                # dispatch; the donated-state release below blocks on
-                # the in-flight execution anyway — this moves that
-                # sync earlier and attributes it to comms
-                jax.block_until_ready(out)
-                comms.account_dispatch(
-                    recs, _time_mod.perf_counter() - t0,
-                    compile_run=first_run)
-        except Exception as e:
-            detail = []
-            for group, d in (('state', state), ('data', data)):
-                for n, v in d.items():
-                    detail.append('%s[%s]: %s %s %s' % (
-                        group, n, getattr(v, 'shape', '?'),
-                        getattr(v, 'dtype', '?'),
-                        getattr(v, 'sharding', type(v).__name__)))
-            _add_note(e, 'segment inputs:\n  ' + '\n  '.join(detail))
-            oom_note = None
-            if _memviz.is_oom_error(e):
-                oom_note = _memviz.oom_incident(
-                    e, step=executor._step, scope=scope)
-                if oom_note:
-                    _add_note(e, oom_note)
-            if not (oom_note and 'flight dump' in oom_note):
-                dump = _trace.dump_on_error(
-                    'segfail_step%d' % executor._step)
-                if dump:
-                    _add_note(e, 'trace flight recorder (last %d '
-                              'steps) dumped to %s'
-                              % (len(_trace.steps()), dump))
-            raise
-        for n, v in out.items():
-            scope.set_var(n, v)
-            fetched[n] = v
-        _release_donated_state(state)
+        compiled = compile_cache.plane().shared_jit(fp, _build)
+        seg.compiled['collective'] = compiled
+        seg.comms_key = fp
+    if jax.process_count() > 1:
+        # a process-local scalar would carry an inconsistent
+        # single-device sharding across processes; replicate it
+        step = _to_global(np.int64(executor._step),
+                          NamedSharding(mesh, P()))
+    else:
+        step = jnp.asarray(executor._step)
+    _dispatch_noting(executor, seg, compiled, step, state, data, feed,
+                     scope, fetched, first_run,
+                     # shard_map's own errors name the positions of
+                     # spec leaves, not variables: spell them out
+                     describe_args=True)
 
 
 class ParallelExecutor(object):

@@ -487,14 +487,23 @@ def test_two_process_aggregated_metrics_and_failover():
         assert health.prom_lint(text) == []
         assert 'paddle_tpu_health_test_marker_rank0 1' in text
         assert 'paddle_tpu_health_test_marker_rank1 1' in text
-        # run_calls merged = sum of both workers (> either alone)
+        # run_calls merged = sum of both workers (> either alone).
+        # Both keep stepping and the aggregator scrapes on its
+        # heartbeat, so read worker 1's own count FIRST and give the
+        # merged view a few heartbeats to hold a scrape newer than
+        # that reading: from then on it is that count plus rank 0's.
         code, body = _get(wrk + '/metrics.json')
         w1_calls = json.loads(body)['state']['counters'][
             'executor/run_calls']
-        merged = dict(
-            line.rsplit(' ', 1)
-            for line in text.splitlines()
-            if line and not line.startswith('#') and '{' not in line)
+        for _ in range(40):
+            code, text = _get(agg + '/metrics')
+            merged = dict(
+                line.rsplit(' ', 1)
+                for line in text.splitlines()
+                if line and not line.startswith('#') and '{' not in line)
+            if float(merged['paddle_tpu_executor_run_calls']) > w1_calls:
+                break
+            time.sleep(0.25)
         assert float(merged['paddle_tpu_executor_run_calls']) > \
             w1_calls
         assert 'paddle_tpu_health_agg_worker_up{worker="1"' in text

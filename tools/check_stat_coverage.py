@@ -57,7 +57,7 @@ REQUIRED = [
     ('paddle_tpu/fluid/parallel_executor.py', 'parallel/device_count'),
     ('paddle_tpu/fluid/parallel_executor.py',
      'parallel/segment_cache_miss'),
-    ('paddle_tpu/fluid/parallel_executor.py',
+    ('paddle_tpu/fluid/executor.py',
      'parallel/segment_compile_seconds'),
     ('paddle_tpu/fluid/compiler.py',
      'compiler/data_parallel_programs_built'),
@@ -95,11 +95,11 @@ REQUIRED = [
     ('paddle_tpu/fluid/trace.py', 'trace/dumps_written'),
     ('paddle_tpu/fluid/executor.py', "_trace.span('feed_h2d'"),
     ('paddle_tpu/fluid/executor.py', "_trace.record('bind'"),
-    ('paddle_tpu/fluid/executor.py', "else 'dispatch'"),
+    ('paddle_tpu/fluid/executor.py', "_trace.span('dispatch'"),
     ('paddle_tpu/fluid/executor.py', "_trace.record('fetch_d2h'"),
     ('paddle_tpu/fluid/executor.py', 'executor/state_release_seconds'),
     ('paddle_tpu/fluid/reader.py', "_trace.record('reader_wait'"),
-    ('paddle_tpu/fluid/parallel_executor.py', "_trace.step_span"),
+    ('paddle_tpu/fluid/parallel_executor.py', "_step_scope("),
     ('paddle_tpu/fluid/compile_cache.py', "'cache_deserialize'"),
     ('bench.py', '_step_phase_fields'),
     # health plane (fluid/health.py): the HTTP status surface, the
@@ -151,9 +151,8 @@ REQUIRED = [
     ('paddle_tpu/fluid/comms.py', 'executor/segment_temp_bytes'),
     ('paddle_tpu/ops/collective_ops.py', 'comms.record_trace'),
     ('paddle_tpu/ops/parallel_ops.py', 'comms.record_trace'),
-    ('paddle_tpu/fluid/parallel_executor.py',
-     'comms.account_dispatch'),
-    ('paddle_tpu/fluid/parallel_executor.py', 'comms.collecting'),
+    ('paddle_tpu/fluid/executor.py', 'comms.account_dispatch'),
+    ('paddle_tpu/fluid/executor.py', 'comms.collecting'),
     # collective planner (fluid/comms_plan.py + the planned lowerings
     # in ops/collective_ops.py + the GradAllReduce bucket rewrite):
     # which arm ran, actual vs dense-equivalent wire bytes, the cost
@@ -208,7 +207,6 @@ REQUIRED = [
     ('paddle_tpu/fluid/executor.py', '_memviz.record_segment'),
     ('paddle_tpu/fluid/executor.py', '_memviz.maybe_sample'),
     ('paddle_tpu/fluid/executor.py', '_memviz.oom_incident'),
-    ('paddle_tpu/fluid/parallel_executor.py', '_memviz.oom_incident'),
     ('paddle_tpu/fluid/trace.py', 'trace/counter_samples'),
     ('paddle_tpu/fluid/comms_plan.py', 'memviz.peak_bytes'),
     ('paddle_tpu/fluid/health.py', 'memviz.memory_pressure'),
@@ -273,7 +271,7 @@ REQUIRED = [
     ('paddle_tpu/distributed/rpc_ps.py', 'rpc/backoff_seconds'),
     ('paddle_tpu/distributed/rpc_ps.py', 'rpc_exhausted'),
     ('paddle_tpu/fluid/executor.py', '_finject.check'),
-    ('paddle_tpu/fluid/parallel_executor.py', '_finject.check'),
+    ('paddle_tpu/fluid/executor.py', "'collective.dispatch'"),
     ('paddle_tpu/fluid/health.py', 'elastic.report'),
     ('bench.py', '_elastic_fields'),
     # self-healing supervisor (fluid/supervisor.py + the hung-step
@@ -302,7 +300,6 @@ REQUIRED = [
     ('paddle_tpu/fluid/supervisor.py', 'executor/step_timeouts'),
     ('paddle_tpu/fluid/executor.py', '_sup.guard_dispatch'),
     ('paddle_tpu/fluid/executor.py', '_sup.on_step_begin'),
-    ('paddle_tpu/fluid/parallel_executor.py', '_sup.guard_dispatch'),
     ('paddle_tpu/fluid/serving.py', 'serving/shed_expired'),
     ('paddle_tpu/fluid/serving.py', 'serving/shed_degraded'),
     ('paddle_tpu/fluid/serving.py', 'serving/degraded'),
@@ -352,7 +349,6 @@ REQUIRED = [
     ('paddle_tpu/fluid/slo.py', 'slo/firing'),
     ('paddle_tpu/fluid/slo.py', 'supervisor.record_slo_breach'),
     ('paddle_tpu/fluid/executor.py', '_tseries.maybe_sample'),
-    ('paddle_tpu/fluid/parallel_executor.py', '_tseries.maybe_sample'),
     ('paddle_tpu/fluid/health.py', 'timeseries.job_sample'),
     ('paddle_tpu/fluid/health.py', 'timeseries.job_gap'),
     ('paddle_tpu/fluid/health.py', 'timeseries.http_query'),
