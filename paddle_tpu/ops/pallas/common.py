@@ -28,6 +28,9 @@ import jax
 # scratch/compiler overhead; 10 MB keeps every swept config compiling
 # with headroom.
 VMEM_BUDGET_BYTES = 10 * 1024 * 1024
+# What Mosaic gives one kernel instance on a v5e (its scoped VMEM): a
+# kernel that asks for more is refused by the compiler.
+SCOPED_VMEM_BYTES = 16 * 1024 * 1024
 
 # kernel-library registry: name -> descriptor.  Populated by each
 # kernel module at import via register_kernel(); tools/check_kernels.py
@@ -95,15 +98,38 @@ def force_fused():
     return bool(get_flag('FLAGS_pallas_force', False))
 
 
+def score_tile_bytes(block_q, block_k):
+    """The f32 p/s score block of one [block_q, block_k] tile plus its
+    exp/corr temporaries (-> x3)."""
+    return 3 * block_q * block_k * 4
+
+
 def vmem_estimate(t, d, block_q, block_k, itemsize):
     """Bytes a kernel instance keeps resident in VMEM.  Dominant terms
     across the three kernels: the full K and V rows (streamed via
-    dslice but block-spec'd whole), the q/o/do row blocks, and the f32
-    p/s score blocks (plus their exp/corr temporaries -> x3)."""
+    dslice but block-spec'd whole), the q/o/do row blocks, and one
+    score tile."""
     kv = 2 * t * d * itemsize
     rows = 3 * block_q * d * itemsize
-    scores = 3 * block_q * block_k * 4
-    return kv + rows + scores + (1 << 18)  # fixed slack
+    return kv + rows + score_tile_bytes(block_q, block_k) + (1 << 18)
+
+
+def room_for_second_tile(resident, block_q, block_k, itemsize):
+    """May a kernel instance hold two score tiles alive at once?
+    Inside one tile the products and the vector chain depend on each
+    other, so an instance that holds one tile runs MXU and VPU in
+    turn; with a second tile alive the scheduler puts one's chain
+    beside the other's products (PERF.md section 6, PR 29).  Each of
+    the two is counted as vmem_estimate() counts the one, and f32
+    operands twice (their full-precision products split each operand
+    into bf16 parts that sit beside it); ``resident`` is the caller's
+    estimate of everything else the instance holds, counted twice
+    (the pipeline keeps two buffers of every row and block, which the
+    one-tile estimates leave to their budget's headroom); the sum has
+    to stay under what the compiler allows.
+    tests/test_chip_compile.py compiles the shapes that decide."""
+    tile = score_tile_bytes(block_q, block_k) * itemsize // 2
+    return 2 * resident + 2 * tile <= SCOPED_VMEM_BYTES
 
 
 def block_sizes(t, block_q, block_k, d=64, itemsize=2):

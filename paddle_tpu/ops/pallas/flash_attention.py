@@ -6,11 +6,20 @@ Replaces the reference's fused attention chain
 MXU against K/V blocks streamed through VMEM; no [T, T] score matrix
 ever materializes in HBM.
 
-Backward is the standard two-pass flash scheme wired through custom_vjp:
-the forward additionally emits the per-row log-sum-exp (lse); backward
-precomputes delta = rowsum(dO * O), then one kernel recomputes p blocks
-to accumulate dQ (grid over Q blocks) and a second accumulates dK/dV
-(+ the key-bias gradient) with a grid over K blocks.
+Backward is wired through custom_vjp: the forward additionally emits
+the per-row log-sum-exp (lse); backward precomputes delta =
+rowsum(dO * O), then either ONE kernel per head walks k-blocks x
+q-blocks and accumulates dQ, dK, dV (+ the key-bias gradient) while
+the head's rows fit VMEM (_flash_bwd_fused_kernel: every BERT shape),
+or the standard two-pass scheme: one kernel recomputes p blocks to
+accumulate dQ (grid over Q blocks) and a second accumulates dK/dV with
+a grid over K blocks (d128, long sequences).
+
+The four kernel bodies share the per-tile chain (_score_tile: scores,
+scale, bias, causal mask, dropout multiplier) and the tile loop
+(_loop, one or two tiles a trip: _second_tile); what differs
+between callers — bias, rate, causal, whether 1/sqrt(d) is a power of
+two, operand width — is static at trace time.
 
 An optional additive key bias [B, T] (padding masks, per-key biases)
 is applied to the scores inside the kernels — the BERT input-mask path
@@ -22,24 +31,24 @@ under FLAGS_pallas_force (tests).
 """
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# Round-3 sweep on the v5-lite chip (tools/bench_flash.py): large
-# blocks dominate for d=64 — underfilled MXU passes cost more than the
-# extra VMEM residency.  512/1024 is the best compiling config at seq
-# 2048 (39.1 ms vs 69.1 ms at 256/256 and 77.7 ms naive XLA) and
-# clamps to 512/512 at seq 512 (5.6 ms vs 7.2 ms naive); 2048-wide
-# blocks exceed VMEM — _block_sizes clamps them (see VMEM model there).
+# Block sizes: 512/1024 is the largest pair that compiles at seq 2048
+# (2048-wide blocks exceed VMEM: _block_sizes clamps them, see the
+# VMEM model in common.py) and clamps to 512/512 at seq 512; smaller
+# blocks underfill the MXU at d=64 and lost every sweep (rounds 3, 5).
+# What the calls cost on a v5e, [B*H, T, 64] bf16 with a key bias and
+# rate 0.1, is in PERF.md section 6 (PR 29) with the ablation.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
 
-# Measured flash-vs-naive crossover (fwd+bwd; pre-round reading, not
-# measured on current code): below this sequence length XLA's fused dense chain fits
-# VMEM outright and beats the kernel, so flash_attention() auto-selects
-# the dense path — the public entry never ships the regression pocket.
+# Below this sequence length flash_attention() runs the dense XLA
+# chain (a pre-round reading, not re-measured on this code: ROADMAP
+# S3 / S4 ask for the crossover at s128-s256 again).
 FLASH_MIN_SEQ = 512
 
 # platform probe / VMEM model / block clamp live in common.py now
@@ -59,22 +68,42 @@ _common.register_kernel(
     op_types=('matmul', 'scale', 'softmax', 'dropout'))
 
 
-def _dropout_keep(seed, g, qpos, kpos, keep_threshold):
+def _keep_rows(seed, g, qpos):
+    """The part of the keep hash's pre-mix that depends on the head
+    and the query position only (uint32, shaped like ``g`` x
+    ``qpos``)."""
+    return (qpos.astype(jnp.uint32) * jnp.uint32(0x9E3779B1)) ^ \
+        (jnp.asarray(g, jnp.uint32) * jnp.uint32(0xC2B2AE3D)) ^ \
+        jnp.asarray(seed, jnp.uint32)
+
+
+def _keep_cols(kpos):
+    """The part that depends on the key position only."""
+    return kpos.astype(jnp.uint32) * jnp.uint32(0x85EBCA77)
+
+
+def _dropout_keep(rows, cols, keep_threshold):
     """Deterministic per-(head, q, k) keep mask from a counter hash
     (murmur3-finalizer mix): the same element draws the same bit in the
     forward kernel, both backward kernels, the dense path, and any
     replay (per-op grad or whole-program vjp) — the (op_seed, step)
     keying discipline the dropout op uses, in-kernel.  Integer ops
-    only, so Mosaic and interpret mode agree bit-for-bit."""
-    h = (qpos.astype(jnp.uint32) * jnp.uint32(0x9E3779B1)) ^ \
-        (kpos.astype(jnp.uint32) * jnp.uint32(0x85EBCA77)) ^ \
-        (jnp.asarray(g, jnp.uint32) * jnp.uint32(0xC2B2AE3D)) ^ seed
+    only, so Mosaic and interpret mode agree bit-for-bit.
+
+    The pre-mix is (qpos * A) ^ (kpos * B) ^ (g * C) ^ seed: a xor of
+    a term of the row (_keep_rows) and a term of the column
+    (_keep_cols), so callers build a [rows, 1] and a [1, cols] vector
+    and ONE broadcast xor makes the tile; only the finalizer below is
+    per-element work."""
+    h = rows ^ cols
     h = h ^ (h >> jnp.uint32(16))
     h = h * jnp.uint32(0x7FEB352D)
     h = h ^ (h >> jnp.uint32(15))
     h = h * jnp.uint32(0x846CA68B)
     h = h ^ (h >> jnp.uint32(16))
-    return (h >> jnp.uint32(8)) < jnp.uint32(keep_threshold)
+    # 24 bits against the threshold, compared as signed: the same bit
+    return (h >> jnp.uint32(8)).astype(jnp.int32) < \
+        jnp.int32(keep_threshold)
 
 
 def _keep_threshold(rate):
@@ -90,18 +119,39 @@ def _seed_off(seed_ref, idx):
     return jnp.asarray(seed_ref[0, idx], jnp.int32)
 
 
+def _draw_rows(seed_ref, g, q0, n):
+    """[n, 1] row term of the draw for local rows q0 .. q0+n-1 of the
+    grid's head ``g`` (the seed operand shifts both to global); None
+    without a seed operand (rate 0)."""
+    if seed_ref is None:
+        return None
+    qpos = q0 + _seed_off(seed_ref, 1) + \
+        jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+    return _keep_rows(seed_ref[0, 0], g + _seed_off(seed_ref, 3), qpos)
+
+
+def _draw_cols(seed_ref, k0, n):
+    """[1, n] column term of the draw for local columns k0 .. k0+n-1."""
+    if seed_ref is None:
+        return None
+    return _keep_cols(k0 + _seed_off(seed_ref, 2) +
+                      jax.lax.broadcasted_iota(jnp.int32, (1, n), 1))
+
+
 def dropout_keep_dense(seed, b, h, tq, tk, q_off=0, k_off=0, g_off=0,
                        rate=0.0):
     """[b, h, tq, tk] keep mask at GLOBAL positions — the dense-form
     twin of the in-kernel draw, shared by the XLA dense dispatch arm
     and the einsum ring (_block_attend) so every path stays
     bit-identical to the Pallas kernels."""
-    g = (jax.lax.broadcasted_iota(jnp.int32, (b, h, tq, tk), 0) * h +
-         jax.lax.broadcasted_iota(jnp.int32, (b, h, tq, tk), 1) +
+    g = (jax.lax.broadcasted_iota(jnp.int32, (b, h, 1, 1), 0) * h +
+         jax.lax.broadcasted_iota(jnp.int32, (b, h, 1, 1), 1) +
          jnp.asarray(g_off, jnp.int32))
-    qpos = jnp.asarray(q_off, jnp.int32) +         jax.lax.broadcasted_iota(jnp.int32, (b, h, tq, tk), 2)
-    kpos = jnp.asarray(k_off, jnp.int32) +         jax.lax.broadcasted_iota(jnp.int32, (b, h, tq, tk), 3)
-    return _dropout_keep(jnp.asarray(seed, jnp.uint32), g, qpos, kpos,
+    qpos = jnp.asarray(q_off, jnp.int32) + \
+        jax.lax.broadcasted_iota(jnp.int32, (1, 1, tq, 1), 2)
+    kpos = jnp.asarray(k_off, jnp.int32) + \
+        jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, tk), 3)
+    return _dropout_keep(_keep_rows(seed, g, qpos), _keep_cols(kpos),
                          _keep_threshold(rate))
 
 
@@ -133,8 +183,60 @@ def _dot(a, b, contract):
         preferred_element_type=jnp.float32)
 
 
+def _scale_is_exact(scale):
+    """True where multiplying by ``scale`` only moves the exponent
+    (1/sqrt(d) for d = 16, 64, 256): then (x * scale) . y and
+    (x . y) * scale are the same bits, in bf16 and in f32, and the
+    kernels scale the [block, d] operand their loop holds fixed
+    instead of every [block_q, block_k] score tile."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _score_tile(q, k, bias, rows, cols, *, scale, causal, q0, k0,
+                rate):
+    """What the four kernel bodies share for one [bq, bk] tile:
+    s = q k^T (* scale, unless an operand already carries it:
+    scale=None) (+ bias[None, :]) (-inf above the diagonal), and the
+    dropout multiplier u = 1/(1-rate) where the element is kept, 0
+    where it is dropped (None at rate 0), drawn from the tile's
+    [bq, 1] ``rows`` and [1, bk] ``cols`` terms.  Callers turn s into
+    probabilities with ONE exp(s - stat[:, None]), stat finite (the
+    running max or the saved lse): a masked s = -inf gives exactly 0
+    there, so no isfinite guard follows."""
+    s = _dot(q, k, (1, 1))
+    if scale is not None:
+        s = s * scale
+    if bias is not None:
+        s = s + bias[None, :]
+    if causal:
+        bq, bk = s.shape
+        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        s = jnp.where(qpos >= kpos, s, -jnp.inf)
+    u = None
+    if rate:
+        u = jnp.where(_dropout_keep(rows, cols, _keep_threshold(rate)),
+                      1.0 / (1.0 - rate), 0.0)
+    return s, u
+
+
+def _loop(lo, hi, step, init, tiles):
+    """fori_loop over the tiles lo .. hi-1 of one kernel instance,
+    ``tiles`` of them a trip (_second_tile() says how many and why;
+    it divides the trip count, and is 1 wherever lo or hi is only
+    known on the chip)."""
+    if tiles == 1:
+        return jax.lax.fori_loop(lo, hi, step, init)
+
+    def trip(t, carry):
+        for r in range(tiles):
+            carry = step(lo + tiles * t + r, carry)
+        return carry
+    return jax.lax.fori_loop(0, (hi - lo) // tiles, trip, init)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
-                      block_k, has_bias, rate):
+                      block_k, tiles, has_bias, rate):
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
     seed_ref = rest.pop(0) if rate else None
@@ -150,7 +252,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     bq, d = q.shape
     t = k_ref.shape[1]
     q_off = pl.program_id(1) * bq
-    g_id = pl.program_id(0)
+    exact = _scale_is_exact(scale)
+    if exact:
+        q = q * scale
+    rows = _draw_rows(seed_ref, pl.program_id(0), q_off, bq)
 
     nk = t // block_k
 
@@ -158,37 +263,24 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         m, l, acc = carry
         k = k_ref[0, pl.dslice(i * block_k, block_k), :]
         v = v_ref[0, pl.dslice(i * block_k, block_k), :]
-        s = _dot(q, k, (1, 1))
-        s = s * scale
-        if has_bias:
-            bias = bias_ref[0, 0, pl.dslice(i * block_k,
-                                            block_k)].astype(jnp.float32)
-            s = s + bias[None, :]
-        if causal:
-            qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (bq,
-                                                                block_k),
-                                                    0)
-            kpos = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, -jnp.inf)
+        bias = bias_ref[0, 0, pl.dslice(i * block_k, block_k)].astype(
+            jnp.float32) if has_bias else None
+        s, u = _score_tile(
+            q, k, bias, rows,
+            _draw_cols(seed_ref, i * block_k, block_k),
+            scale=None if exact else scale, causal=causal, q0=q_off,
+            k0=i * block_k, rate=rate)
         m_new = jnp.maximum(m, jnp.max(s, axis=1))
+        # a row with every key masked so far: m_new = -inf, p = 0
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
         p = jnp.exp(s - m_safe[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+        corr = jnp.exp(m - m_safe)
         # dropout applies AFTER softmax (reference: dropout around the
         # probs, python/paddle/fluid/layers/nn.py): the normalizer l
         # accumulates the UNDROPPED p, only the V-weighting is masked
         l_new = l * corr + jnp.sum(p, axis=1)
         if rate:
-            qpos_d = q_off + _seed_off(seed_ref, 1) + \
-                jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            kpos_d = i * block_k + _seed_off(seed_ref, 2) + \
-                jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            keep = _dropout_keep(seed_ref[0, 0],
-                                 g_id + _seed_off(seed_ref, 3),
-                                 qpos_d, kpos_d, _keep_threshold(rate))
-            p = jnp.where(keep, p * (1.0 / (1.0 - rate)), 0.0)
+            p = p * u
         acc_new = acc * corr[:, None] + _dot(p.astype(v.dtype), v,
                                              (1, 0))
         return m_new, l_new, acc_new
@@ -202,7 +294,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         nk_eff = jnp.minimum(nk, last)
     else:
         nk_eff = nk
-    m, l, acc = jax.lax.fori_loop(0, nk_eff, body, (m0, l0, acc0))
+    m, l, acc = _loop(0, nk_eff, body, (m0, l0, acc0), tiles)
     l_safe = jnp.maximum(l, 1e-20)
     out = acc / l_safe[:, None]
     o_ref[0] = out.astype(o_ref.dtype)
@@ -211,15 +303,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
-                         block_k, has_bias, has_glse, rate):
+                         block_k, tiles, has_bias, has_glse, rate):
+    """Grid (BH, T/bq): recompute p row-blocks from q and lse, then
+    dq = sum_k (p * (dO V^T - delta)) K * scale."""
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
     seed_ref = rest.pop(0) if rate else None
     do_ref, lse_ref, delta_ref = rest[0], rest[1], rest[2]
     glse_ref = rest[3] if has_glse else None
     dq_ref = rest[-1]
-    """Grid (BH, T/bq): recompute p row-blocks from q and lse, then
-    dq = sum_k (p * (dO V^T - delta)) K * scale."""
     q = q_ref[0]
     do = do_ref[0]
     lse = lse_ref[0, 0].astype(jnp.float32)
@@ -230,43 +322,34 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     bq, d = q.shape
     t = k_ref.shape[1]
     q_off = pl.program_id(1) * bq
-    g_id = pl.program_id(0)
+    exact = _scale_is_exact(scale)
+    q_s = q * scale if exact else q
+    rows = _draw_rows(seed_ref, pl.program_id(0), q_off, bq)
     nk = t // block_k
 
     def body(i, dq):
         k = k_ref[0, pl.dslice(i * block_k, block_k), :]
         v = v_ref[0, pl.dslice(i * block_k, block_k), :]
-        s = _dot(q, k, (1, 1))
-        s = s * scale
-        if has_bias:
-            bias = bias_ref[0, 0, pl.dslice(i * block_k,
-                                            block_k)].astype(jnp.float32)
-            s = s + bias[None, :]
-        if causal:
-            qpos = q_off + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 0)
-            kpos = i * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (bq, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, -jnp.inf)
-        p = jnp.where(jnp.isfinite(s),
-                      jnp.exp(s - lse[:, None]), 0.0)
+        bias = bias_ref[0, 0, pl.dslice(i * block_k, block_k)].astype(
+            jnp.float32) if has_bias else None
+        s, u = _score_tile(
+            q_s, k, bias, rows,
+            _draw_cols(seed_ref, i * block_k, block_k),
+            scale=None if exact else scale, causal=causal, q0=q_off,
+            k0=i * block_k, rate=rate)
+        p = jnp.exp(s - lse[:, None])
         dp = _dot(do, v, (1, 1))
         if rate:
             # softmax vjp with post-softmax dropout u: dS = p*(u*dp -
             # delta); delta = rowsum(dO*O) already sees the dropout
             # because O was computed WITH it
-            qpos_d = q_off + _seed_off(seed_ref, 1) + \
-                jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
-            kpos_d = i * block_k + _seed_off(seed_ref, 2) + \
-                jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
-            keep = _dropout_keep(seed_ref[0, 0],
-                                 g_id + _seed_off(seed_ref, 3),
-                                 qpos_d, kpos_d, _keep_threshold(rate))
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - rate)), 0.0)
+            dp = dp * u
         dd = dp - delta[:, None]
         if has_glse:
             dd = dd + glse[:, None]
-        ds = p * dd * scale
+        ds = p * dd
+        if not exact:
+            ds = ds * scale
         return dq + _dot(ds.astype(k.dtype), k, (1, 0))
 
     if causal:
@@ -274,13 +357,18 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         nk_eff = jnp.minimum(nk, last)
     else:
         nk_eff = nk
-    dq = jax.lax.fori_loop(0, nk_eff, body,
-                           jnp.zeros((bq, d), jnp.float32))
+    dq = _loop(0, nk_eff, body, jnp.zeros((bq, d), jnp.float32), tiles)
+    if exact:
+        dq = dq * scale
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
-                          block_q, has_bias, has_glse, rate):
+                          block_q, tiles, dp_early, has_bias, has_glse,
+                          rate):
+    """Grid (BH, T/bk): for one K/V block, stream Q row-blocks:
+    dv = sum_q p^T dO;  ds_raw = p * (dO V^T - delta);
+    dk = sum_q ds_raw^T Q * scale;  dbias = sum_q ds_raw (per key)."""
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
     seed_ref = rest.pop(0) if rate else None
@@ -288,9 +376,6 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     glse_ref = rest[3] if has_glse else None
     dk_ref, dv_ref = rest[-3:-1] if has_bias else rest[-2:]
     dbias_ref = rest[-1] if has_bias else None
-    """Grid (BH, T/bk): for one K/V block, stream Q row-blocks:
-    dv = sum_q p^T dO;  ds_raw = p * (dO V^T - delta);
-    dk = sum_q ds_raw^T Q * scale;  dbias = sum_q ds_raw (per key)."""
     k = k_ref[0]
     v = v_ref[0]
     bias = bias_ref[0, 0].astype(jnp.float32) if has_bias else None
@@ -298,6 +383,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     t = q_ref.shape[1]
     k_off = pl.program_id(1) * bk
     g_id = pl.program_id(0)
+    exact = _scale_is_exact(scale)
+    k_s = k * scale if exact else k
+    cols = _draw_cols(seed_ref, k_off, bk)
     nq = t // block_q
 
     def body(j, carry):
@@ -310,38 +398,34 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             jnp.float32)
         glse = glse_ref[0, 0, pl.dslice(j * block_q, block_q)].astype(
             jnp.float32) if has_glse else None
-        s = _dot(q, k, (1, 1))
-        s = s * scale
-        if has_bias:
-            s = s + bias[None, :]
-        if causal:
-            qpos = j * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 0)
-            kpos = k_off + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, bk), 1)
-            s = jnp.where(qpos >= kpos, s, -jnp.inf)
-        p = jnp.where(jnp.isfinite(s),
-                      jnp.exp(s - lse[:, None]), 0.0)
-        if rate:
-            qpos_d = j * block_q + _seed_off(seed_ref, 1) + \
-                jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
-            kpos_d = k_off + _seed_off(seed_ref, 2) + \
-                jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
-            keep = _dropout_keep(seed_ref[0, 0],
-                                 g_id + _seed_off(seed_ref, 3),
-                                 qpos_d, kpos_d, _keep_threshold(rate))
-            pu = jnp.where(keep, p * (1.0 / (1.0 - rate)), 0.0)
-        else:
-            keep, pu = None, p
-        dv = dv + _dot(pu.astype(do.dtype), do, (0, 0))
-        dp = _dot(do, v, (1, 1))
-        if rate:
-            dp = jnp.where(keep, dp * (1.0 / (1.0 - rate)), 0.0)
+        s, u = _score_tile(
+            q, k_s, bias,
+            _draw_rows(seed_ref, g_id, j * block_q, block_q), cols,
+            scale=None if exact else scale, causal=causal,
+            q0=j * block_q, k0=k_off, rate=rate)
+        p = jnp.exp(s - lse[:, None])
+
+        def dp_tile():
+            dp = _dot(do, v, (1, 1))
+            return dp * u if rate else dp
+
+        # dO V^T does not wait for the chain: where a second tile may
+        # be alive (_second_tile) it is issued before p^T dO, so the
+        # MXU has work while the VPU makes p.  Else after it, as a dp
+        # alive across that product put f32 d128 calls over the
+        # scoped VMEM (17.47M of 16M at [6, 2048, 16, 128])
+        if dp_early:
+            dp = dp_tile()
+        dv = dv + _dot((p * u if rate else p).astype(do.dtype), do,
+                       (0, 0))
+        if not dp_early:
+            dp = dp_tile()
         dd = dp - delta[:, None]
         if has_glse:
             dd = dd + glse[:, None]
         ds_raw = p * dd
-        dk = dk + _dot(ds_raw.astype(q.dtype), q, (0, 0)) * scale
+        dk_blk = _dot(ds_raw.astype(q.dtype), q, (0, 0))
+        dk = dk + (dk_blk if exact else dk_blk * scale)
         if has_bias:
             dbias = dbias + jnp.sum(ds_raw, axis=0)
         return dk, dv, dbias
@@ -354,7 +438,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     dk0 = jnp.zeros((bk, d), jnp.float32)
     dv0 = jnp.zeros((bk, d), jnp.float32)
     db0 = jnp.zeros((bk,), jnp.float32)
-    dk, dv, dbias = jax.lax.fori_loop(j0, nq, body, (dk0, dv0, db0))
+    dk, dv, dbias = _loop(j0, nq, body, (dk0, dv0, db0), tiles)
+    if exact:
+        dk = dk * scale
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
     if has_bias:
@@ -362,20 +448,17 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
-                            block_q, block_k, has_bias, has_glse,
-                            rate):
+                            block_q, block_k, tiles, dp_early, has_bias,
+                            has_glse, rate):
     """Single-pass backward: grid (BH,) only.  The two-pass scheme
     (dq grid over Q blocks, dk/dv grid over K blocks) recomputes the
     score block s AND the prob-cotangent dp = dO V^T in BOTH kernels —
-    9 MXU dots per (q,k) tile-pair step instead of 7.  When the whole
+    7 MXU dots per (q,k) tile-pair step instead of 5.  When the whole
     per-head working set fits VMEM (q/k/v/do rows + an f32 dq
     accumulator — true for the long-context shapes this kernel
     exists for), one kernel can walk k-blocks x q-blocks computing s
     and dp ONCE and accumulating all three gradients: dk/dv stream out
-    per k-block, dq rides a VMEM carry.  Measured motivation: the
-    round-5 traced per-op table put the flash kernels at 41% of the
-    BERT-s2048 step with 2/9 of their dot FLOPs being these
-    recomputes."""
+    per k-block, dq rides a VMEM carry."""
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
     seed_ref = rest.pop(0) if rate else None
@@ -390,6 +473,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         dbias_ref = None
     t, d = q_ref.shape[1], q_ref.shape[2]
     g_id = pl.program_id(0)
+    exact = _scale_is_exact(scale)
     nq, nk = t // block_q, t // block_k
 
     def k_step(i, _):
@@ -397,6 +481,9 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         v = v_ref[0, pl.dslice(i * block_k, block_k), :]
         bias = bias_ref[0, 0, pl.dslice(i * block_k, block_k)].astype(
             jnp.float32) if has_bias else None
+        # with an exact scale k carries it into s AND into dq
+        k_s = k * scale if exact else k
+        cols = _draw_cols(seed_ref, i * block_k, block_k)
 
         def q_step(j, carry):
             dk, dv, dbias = carry
@@ -407,34 +494,24 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             delta = delta_ref[0, 0, pl.dslice(j * block_q,
                                               block_q)].astype(
                 jnp.float32)
-            s = _dot(q, k, (1, 1))
-            s = s * scale
-            if has_bias:
-                s = s + bias[None, :]
-            if causal:
-                qpos = j * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                kpos = i * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1)
-                s = jnp.where(qpos >= kpos, s, -jnp.inf)
-            p = jnp.where(jnp.isfinite(s),
-                          jnp.exp(s - lse[:, None]), 0.0)
-            dp = _dot(do, v, (1, 1))
-            if rate:
-                qpos_d = j * block_q + _seed_off(seed_ref, 1) + \
-                    jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 0)
-                kpos_d = i * block_k + _seed_off(seed_ref, 2) + \
-                    jax.lax.broadcasted_iota(
-                        jnp.int32, (block_q, block_k), 1)
-                keep = _dropout_keep(
-                    seed_ref[0, 0], g_id + _seed_off(seed_ref, 3),
-                    qpos_d, kpos_d, _keep_threshold(rate))
-                pu = jnp.where(keep, p * (1.0 / (1.0 - rate)), 0.0)
-                dp = jnp.where(keep, dp * (1.0 / (1.0 - rate)), 0.0)
-            else:
-                pu = p
-            dv = dv + _dot(pu.astype(do.dtype), do, (0, 0))
+            s, u = _score_tile(
+                q, k_s, bias,
+                _draw_rows(seed_ref, g_id, j * block_q, block_q), cols,
+                scale=None if exact else scale, causal=causal,
+                q0=j * block_q, k0=i * block_k, rate=rate)
+            p = jnp.exp(s - lse[:, None])
+
+            def dp_tile():
+                dp = _dot(do, v, (1, 1))
+                return dp * u if rate else dp
+
+            # dO V^T before or after p^T dO: _flash_bwd_dkv_kernel
+            if dp_early:
+                dp = dp_tile()
+            dv = dv + _dot((p * u if rate else p).astype(do.dtype), do,
+                           (0, 0))
+            if not dp_early:
+                dp = dp_tile()
             dd = dp - delta[:, None]
             if has_glse:
                 glse = glse_ref[0, 0, pl.dslice(j * block_q,
@@ -442,10 +519,13 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                     jnp.float32)
                 dd = dd + glse[:, None]
             ds_raw = p * dd
-            dk = dk + _dot(ds_raw.astype(q.dtype), q, (0, 0)) * scale
+            dk_blk = _dot(ds_raw.astype(q.dtype), q, (0, 0))
+            dq_blk = _dot(ds_raw.astype(k.dtype), k_s, (1, 0))
+            if not exact:
+                dk_blk, dq_blk = dk_blk * scale, dq_blk * scale
+            dk = dk + dk_blk
             if has_bias:
                 dbias = dbias + jnp.sum(ds_raw, axis=0)
-            dq_blk = _dot(ds_raw.astype(k.dtype), k, (1, 0)) * scale
             # dq accumulates across k-blocks in the f32 VMEM scratch
             # (read-modify-write through the ref: Mosaic supports
             # dynamic slicing on refs, not on carried values)
@@ -460,8 +540,9 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         dk0 = jnp.zeros((block_k, d), jnp.float32)
         dv0 = jnp.zeros((block_k, d), jnp.float32)
         db0 = jnp.zeros((block_k,), jnp.float32)
-        dk, dv, dbias = jax.lax.fori_loop(
-            j0, nq, q_step, (dk0, dv0, db0))
+        dk, dv, dbias = _loop(j0, nq, q_step, (dk0, dv0, db0), tiles)
+        if exact:
+            dk = dk * scale
         dk_ref[0, pl.dslice(i * block_k, block_k), :] = \
             dk.astype(dk_ref.dtype)
         dv_ref[0, pl.dslice(i * block_k, block_k), :] = \
@@ -483,10 +564,15 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
     has_glse = glse3 is not None
+    tiles, dp_early = _second_tile(
+        None if causal else t // block_q,
+        _fused_bwd_resident(t, d, block_k, q.dtype.itemsize),
+        block_q, block_k, q.dtype.itemsize)
     kernel = functools.partial(
         _flash_bwd_fused_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, has_bias=has_bias,
-        has_glse=has_glse, rate=rate)
+        block_q=block_q, block_k=block_k, tiles=tiles,
+        dp_early=dp_early, has_bias=has_bias, has_glse=has_glse,
+        rate=rate)
     row = pl.BlockSpec((1, t, d), lambda i: (i, 0, 0))
     vec = pl.BlockSpec((1, 1, t), lambda i: (i, 0, 0))
     in_specs = [row, row, row]
@@ -540,14 +626,44 @@ FUSED_BLOCK_Q = 512
 FUSED_BLOCK_K = 512
 
 
-def _fused_bwd_vmem(t, d, block_q, block_k, itemsize):
-    """Resident bytes for the fused backward: q/k/v/do full rows, the
-    f32 dq accumulator + dk/dv/score f32 blocks (x2 slack for compiler
-    temporaries)."""
+def _fused_bwd_resident(t, d, block_k, itemsize):
+    """What a fused-backward instance holds beside its score tiles:
+    q/k/v/do full rows, the f32 dq accumulator and the dk/dv f32
+    blocks (x2 slack for compiler temporaries)."""
     rows = 4 * t * d * itemsize
     dq_acc = t * d * 4
-    blocks = 2 * block_k * d * 4 + 3 * block_q * block_k * 4
-    return rows + dq_acc + 2 * blocks + (1 << 19)
+    return rows + dq_acc + 2 * 2 * block_k * d * 4 + (1 << 19)
+
+
+def _fused_bwd_vmem(t, d, block_q, block_k, itemsize):
+    """Resident bytes for the fused backward, one tile a trip: each
+    tile has two chains (s -> p and dp -> ds), so two of
+    common.score_tile_bytes()."""
+    return _fused_bwd_resident(t, d, block_k, itemsize) + \
+        2 * _common.score_tile_bytes(block_q, block_k)
+
+
+def _second_tile(trips, resident, block_q, block_k, itemsize):
+    """(tiles a loop trip, dp_early): how a kernel instance uses the
+    room for a second score tile, where the VMEM model finds it
+    (common.room_for_second_tile).  The tile loop takes two tiles a
+    trip where its trip count is even and known at trace time
+    (``trips``; None for causal calls, which bound their loops by the
+    diagonal).  Where it is not, the backward bodies issue their
+    second independent product (dO V^T) before the first tile's
+    chain is through, which keeps a second tile alive just the
+    same."""
+    room = _common.room_for_second_tile(resident, block_q, block_k,
+                                        itemsize)
+    tiles = 2 if room and trips is not None and trips % 2 == 0 else 1
+    return tiles, room and tiles == 1
+
+
+def _rows_resident(t, d, block_q, block_k, itemsize):
+    """What a forward, dq or dkv instance holds beside its score
+    tile, as vmem_estimate() counts it."""
+    return _vmem_estimate(t, d, block_q, block_k, itemsize) - \
+        _common.score_tile_bytes(block_q, block_k)
 
 
 def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
@@ -555,14 +671,36 @@ def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
     """q,k,v: [BH, T, D], bias: [B, T] or None, seed: packed (1,4)
     uint32 [seed, q_off, k_off, g_off] (_pack_seed, required when
     rate>0) -> (o [BH,T,D], lse [BH,T])."""
+    _, t, d = q.shape
+    return _fwd_call(
+        q, k, v, bias, seed, h=h, causal=causal,
+        blocks=_block_sizes(t, block_q, block_k, d, q.dtype.itemsize),
+        interpret=interpret, rate=rate)
+
+
+# The calls are jitted on their static arguments: the layers of a model
+# call them with the same shapes, so the kernel bodies are traced once
+# a process, not once a layer in each of its programs (a BERT-base
+# step holds 24 calls: PERF.md section 6, PR 29, on `setup_s`).
+# ``inline``: the cached trace is spliced into the caller's, with no
+# call of its own in the program, so a kernel's instruction keeps the
+# name of the scope the caller lowered it in (the executor's, the
+# fluid op's type), which is how a device trace is read.
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    'h', 'causal', 'blocks', 'interpret', 'rate'))
+def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
+              rate):
     bh, t, d = q.shape
-    block_q, block_k = _block_sizes(t, block_q, block_k, d,
-                                    q.dtype.itemsize)
+    block_q, block_k = blocks
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
-    kernel = functools.partial(_flash_fwd_kernel, scale=scale,
-                               causal=causal, block_k=block_k,
-                               has_bias=has_bias, rate=rate)
+    tiles, _ = _second_tile(
+        None if causal else t // block_k,
+        _rows_resident(t, d, block_q, block_k, q.dtype.itemsize),
+        block_q, block_k, q.dtype.itemsize)
+    kernel = functools.partial(
+        _flash_fwd_kernel, scale=scale, causal=causal, block_k=block_k,
+        tiles=tiles, has_bias=has_bias, rate=rate)
     grid = (bh, t // block_q)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -596,9 +734,28 @@ def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
 
 def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
                block_q, block_k, interpret, rate=0.0):
-    bh, t, d = q.shape
+    _, t, d = q.shape
     block_q, block_k = _block_sizes(t, block_q, block_k, d,
                                     q.dtype.itemsize)
+    fq, fk = min(block_q, FUSED_BLOCK_Q), min(block_k, FUSED_BLOCK_K)
+    while t % fq:
+        fq //= 2
+    while t % fk:
+        fk //= 2
+    fused = FUSED_BWD and _fused_bwd_vmem(
+        t, d, fq, fk, q.dtype.itemsize) <= VMEM_BUDGET_BYTES
+    return _bwd_call(
+        q, k, v, bias, seed, o, lse, do, g_lse, h=h, causal=causal,
+        blocks=(fq, fk) if fused else (block_q, block_k), fused=fused,
+        interpret=interpret, rate=rate)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    'h', 'causal', 'blocks', 'fused', 'interpret', 'rate'))
+def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
+              blocks, fused, interpret, rate):
+    bh, t, d = q.shape
+    block_q, block_k = blocks
     scale = 1.0 / (d ** 0.5)
     # delta = rowsum(dO * O): one fused elementwise+reduce in XLA
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -611,21 +768,18 @@ def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
     seed2 = jnp.asarray(seed, jnp.uint32).reshape(1, 4) if rate else None
     seed_spec = pl.BlockSpec((1, 4), lambda i, j: (0, 0))
 
-    fq, fk = min(block_q, FUSED_BLOCK_Q), min(block_k, FUSED_BLOCK_K)
-    while t % fq:
-        fq //= 2
-    while t % fk:
-        fk //= 2
-    if FUSED_BWD and _fused_bwd_vmem(t, d, fq, fk, q.dtype.itemsize) \
-            <= VMEM_BUDGET_BYTES:
+    if fused:
         return _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3,
-                                glse3, h, causal, fq, fk, interpret,
-                                rate)
+                                glse3, h, causal, block_q, block_k,
+                                interpret, rate)
 
-    dq_kernel = functools.partial(_flash_bwd_dq_kernel, scale=scale,
-                                  causal=causal, block_k=block_k,
-                                  has_bias=has_bias, has_glse=has_glse,
-                                  rate=rate)
+    resident = _rows_resident(t, d, block_q, block_k, q.dtype.itemsize)
+    tiles, _ = _second_tile(None if causal else t // block_k, resident,
+                            block_q, block_k, q.dtype.itemsize)
+    dq_kernel = functools.partial(
+        _flash_bwd_dq_kernel, scale=scale, causal=causal,
+        block_k=block_k, tiles=tiles, has_bias=has_bias,
+        has_glse=has_glse, rate=rate)
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
@@ -658,10 +812,13 @@ def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
         interpret=interpret,
     )(*dq_operands)
 
-    dkv_kernel = functools.partial(_flash_bwd_dkv_kernel, scale=scale,
-                                   causal=causal, block_q=block_q,
-                                   has_bias=has_bias,
-                                   has_glse=has_glse, rate=rate)
+    tiles, dp_early = _second_tile(
+        None if causal else t // block_q, resident, block_q, block_k,
+        q.dtype.itemsize)
+    dkv_kernel = functools.partial(
+        _flash_bwd_dkv_kernel, scale=scale, causal=causal,
+        block_q=block_q, tiles=tiles, dp_early=dp_early,
+        has_bias=has_bias, has_glse=has_glse, rate=rate)
     dkv_specs = [
         pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
         pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),

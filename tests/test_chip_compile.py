@@ -86,6 +86,7 @@ def _compiled_on_chip(kernel):
     (4, 2048, 12, 64, 0.0),     # chip_smoke.py / bench_bert_long
     (4, 2048, 12, 64, 0.1),     # in-kernel dropout mask
     (16, 512, 12, 64, 0.0),     # the dispatch floor (FLASH_MIN_SEQ)
+    (48, 512, 12, 64, 0.1),     # bert_base_s512_b48's calls
     (4, 2048, 16, 128, 0.0),    # d128: the two-pass backward
 ])
 def test_flash_attention_fwd_bwd(one_chip, as_on_tpu, b, t, h, d, rate):
@@ -138,6 +139,82 @@ def test_causal_flash_at_the_olmoe_cell_shape(one_chip, as_on_tpu, dtype):
     n = _compile(step, one_chip, qkv, qkv, qkv)
     _compiled_on_chip('flash_attention')
     assert n == 3, n
+
+
+@pytest.mark.parametrize('dtype,b,t,h,d,fused', [
+    # what the compiler asks for moves with the grid, so the cells'
+    # own: bert_base_s2048's fused backward, two [512, 512] tiles a trip
+    ('bfloat16', 12, 2048, 12, 64, True),
+    # dq + dkv, two [512, 1024] tiles a trip (four: 17.39M of 16M)
+    ('bfloat16', 12, 2048, 12, 64, False),
+    ('bfloat16', 6, 4096, 12, 64, False),
+    # the rows alone fill the VMEM: one tile a trip (two: 16.29M)
+    ('bfloat16', 3, 8192, 12, 64, False),
+    # d128 with the draw's extra tile: fused, then dq + dkv
+    ('bfloat16', 12, 1024, 16, 128, True),
+    ('bfloat16', 6, 2048, 16, 128, True),
+    # f32 keeps one tile a trip and the blocks it had (two tiles of
+    # the fused backward at d128: 17.88M)
+    ('float32', 12, 1024, 16, 128, True),
+    ('float32', 2, 1024, 4, 128, False),
+    # one tile an instance, dO V^T issued early: f32 at the floor
+    ('float32', 48, 512, 12, 64, True),
+    # f32 at t2048 compiles on a small grid only: at [12, 2048, 12, 64]
+    # and [6, 2048, 16, 128] the backward is refused, before PR 29 and
+    # since (ROADMAP S3 (6))
+    ('float32', 2, 2048, 4, 128, True),
+    ('float32', 2, 2048, 4, 64, True),
+])
+def test_flash_backward_fits_the_scoped_vmem(one_chip, as_on_tpu,
+                                             monkeypatch, dtype, b, t, h,
+                                             d, fused):
+    """The backward kernels with a key bias and the in-kernel draw at
+    the shapes that decide whether an instance may hold a second
+    score tile alive (common.room_for_second_tile; flash_attention.
+    _second_tile says what it is used for)."""
+    monkeypatch.setattr(flash_attention, 'FUSED_BWD', fused)
+
+    def step(q, k, v, bias):
+        def loss(q, k, v, bias):
+            o = flash_attention.flash_attention(
+                q, k, v, key_bias=bias, dropout_rate=0.1,
+                dropout_seed=jnp.uint32(7))
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2, 3))(q, k, v, bias)
+
+    qkv = _spec((b, t, h, d), jnp.dtype(dtype))
+    n = _compile(step, one_chip, qkv, qkv, qkv, _spec((b, t)))
+    _compiled_on_chip('flash_attention')
+    assert n >= 2, n
+
+
+def test_flash_kernels_are_named_after_the_scope_they_are_lowered_in(
+        one_chip, as_on_tpu):
+    """A device trace is read by instruction names (the benchmark's
+    flash_roofline looks for the fluid op's type among the Mosaic
+    calls), and the compiler names a kernel's instruction after the
+    innermost scope around it: the executor's jax.named_scope(op.type)
+    has to stay that scope, whatever the kernel file wraps its calls
+    in (the jitted _fwd_call / _bwd_call are inlined)."""
+    import re
+
+    def step(q, k, v, bias):
+        def loss(q, k, v, bias):
+            with jax.named_scope('fused_multihead_attention'):
+                o = flash_attention.flash_attention(
+                    q, k, v, key_bias=bias, dropout_rate=0.1,
+                    dropout_seed=jnp.uint32(7))
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2, 3))(q, k, v, bias)
+
+    qkv = _spec((12, 2048, 12, 64), jnp.bfloat16)
+    text = _compiled(step, one_chip, qkv, qkv, qkv,
+                     _spec((12, 2048))).as_text()
+    names = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert sorted(re.sub(r'\.\d+$', '', n) for n in names) == [
+        'jvp_fused_multihead_attention_',
+        'transpose_jvp_fused_multihead_attention__'], names
 
 
 def test_grouped_expert_matmuls_at_the_olmoe_cell_shape(one_chip):

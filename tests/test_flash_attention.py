@@ -12,6 +12,31 @@ from paddle_tpu.parallel.ring_attention import reference_attention
 pytestmark = pytest.mark.usefixtures('pallas_interpret')
 
 
+@pytest.fixture(params=[True, False], ids=['fused_bwd', 'two_pass_bwd'])
+def fused_bwd(request, monkeypatch):
+    """Both backward schemes: the one-pass kernel every BERT cell runs
+    and the dq + dkv pair (long sequences, d128), which only the chip
+    ran before PR 29."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.setattr(fa, 'FUSED_BWD', request.param)
+    return request.param
+
+
+def _parent_dropout_keep(seed, g, qpos, kpos, keep_threshold):
+    """The keep hash as the kernels computed it before PR 29, every
+    term on the full tile: a literal copy, kept here so that the row /
+    column form (and every arm that calls it) is held to these bits."""
+    h = (qpos.astype(jnp.uint32) * jnp.uint32(0x9E3779B1)) ^ \
+        (kpos.astype(jnp.uint32) * jnp.uint32(0x85EBCA77)) ^ \
+        (jnp.asarray(g, jnp.uint32) * jnp.uint32(0xC2B2AE3D)) ^ seed
+    h = h ^ (h >> jnp.uint32(16))
+    h = h * jnp.uint32(0x7FEB352D)
+    h = h ^ (h >> jnp.uint32(15))
+    h = h * jnp.uint32(0x846CA68B)
+    h = h ^ (h >> jnp.uint32(16))
+    return (h >> jnp.uint32(8)) < jnp.uint32(keep_threshold)
+
+
 @pytest.mark.parametrize('causal', [False, True])
 def test_flash_matches_dense(causal):
     rng = np.random.RandomState(0)
@@ -26,7 +51,7 @@ def test_flash_matches_dense(causal):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_flash_grad():
+def test_flash_grad(fused_bwd):
     rng = np.random.RandomState(1)
     q = rng.randn(1, 32, 2, 8).astype('float32')
     k = rng.randn(1, 32, 2, 8).astype('float32')
@@ -58,7 +83,7 @@ def test_fused_op_registered():
 
 
 @pytest.mark.parametrize('causal', [False, True])
-def test_flash_grad_noncausal_and_odd_t(causal):
+def test_flash_grad_noncausal_and_odd_t(causal, fused_bwd):
     """Backward Pallas kernels (dq + dkv) against the dense vjp at a
     sequence length that forces block-size shrinkage (t=48)."""
     rng = np.random.RandomState(3)
@@ -84,7 +109,7 @@ def test_flash_grad_noncausal_and_odd_t(causal):
                                    atol=5e-5, rtol=5e-5)
 
 
-def test_flash_grad_bf16():
+def test_flash_grad_bf16(fused_bwd):
     rng = np.random.RandomState(4)
     q = jnp.asarray(rng.randn(1, 32, 1, 8), jnp.bfloat16)
     k = jnp.asarray(rng.randn(1, 32, 1, 8), jnp.bfloat16)
@@ -319,7 +344,7 @@ def test_flash_dropout_matches_dense_same_mask():
 
 
 @pytest.mark.parametrize('causal', [False, True])
-def test_flash_dropout_grads_match_dense_same_mask(causal):
+def test_flash_dropout_grads_match_dense_same_mask(causal, fused_bwd):
     from paddle_tpu.ops.pallas import flash_attention as fa
     rng = np.random.RandomState(4)
     q = jnp.asarray(rng.randn(1, 32, 2, 8).astype('float32'))
@@ -343,7 +368,7 @@ def test_flash_dropout_grads_match_dense_same_mask(causal):
                                    atol=1e-4, rtol=1e-4)
 
 
-def test_flash_dropout_key_bias_grad_matches_dense():
+def test_flash_dropout_key_bias_grad_matches_dense(fused_bwd):
     """dbias under dropout: the key-bias gradient rides ds_raw, which
     now carries the dropout-masked dp term."""
     from paddle_tpu.ops.pallas import flash_attention as fa
@@ -371,7 +396,8 @@ def test_flash_dropout_key_bias_grad_matches_dense():
              jax.lax.broadcasted_iota(jnp.int32, (b, h, t, t), 1))
         qp = jax.lax.broadcasted_iota(jnp.int32, (b, h, t, t), 2)
         kp = jax.lax.broadcasted_iota(jnp.int32, (b, h, t, t), 3)
-        keep = fa._dropout_keep(seed, g, qp, kp, fa._keep_threshold(0.2))
+        keep = _parent_dropout_keep(seed, g, qp, kp,
+                                    fa._keep_threshold(0.2))
         p = jnp.where(keep, p / 0.8, 0.0)
         o = jnp.einsum('bhts,bshd->bthd', p, v)
         return jnp.sum(o ** 2)
@@ -380,6 +406,241 @@ def test_flash_dropout_key_bias_grad_matches_dense():
     gr = jax.grad(r_loss)(bias)
     np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize('case', range(6))
+def test_dropout_keep_row_column_form_draws_the_parent_bits(case):
+    """PR 29 draws the mask from a [rows, 1] term and a [1, cols] term
+    and one broadcast xor; every bit must be the one the per-element
+    formula drew, for any seed, head, position and offset (ring
+    attention and dp meshes pass non-zero ones)."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    rng = np.random.RandomState(100 + case)
+    seed = jnp.uint32(rng.randint(0, 2 ** 32, dtype=np.uint64))
+    b, h = (int(x) for x in rng.randint(1, 5, 2))
+    tq, tk = (int(x) for x in rng.randint(8, 70, 2))
+    q_off, k_off, g_off = (int(x) for x in rng.randint(0, 2 ** 20, 3)) \
+        if case else (0, 0, 0)
+    rate = float(rng.choice([0.1, 0.25, 0.5]))
+    shape = (b, h, tq, tk)
+    g = jax.lax.broadcasted_iota(jnp.int32, shape, 0) * h + \
+        jax.lax.broadcasted_iota(jnp.int32, shape, 1) + g_off
+    qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+    kpos = k_off + jax.lax.broadcasted_iota(jnp.int32, shape, 3)
+    want = _parent_dropout_keep(seed, g, qpos, kpos,
+                                fa._keep_threshold(rate))
+    got = fa.dropout_keep_dense(seed, b, h, tq, tk, q_off, k_off, g_off,
+                                rate)
+    assert got.shape == shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # and the kernels' own spelling: one head, 1-D positions
+    rows = fa._keep_rows(seed, g_off + 1, qpos[0, 0, :, :1])
+    cols = fa._keep_cols(kpos[0, 0, :1, :])
+    np.testing.assert_array_equal(
+        np.asarray(fa._dropout_keep(rows, cols,
+                                    fa._keep_threshold(rate))),
+        np.asarray(_parent_dropout_keep(
+            seed, g_off + 1, qpos[0, 0], kpos[0, 0],
+            fa._keep_threshold(rate))))
+    assert 0.0 < float(jnp.mean(got)) < 1.0
+
+
+@pytest.mark.parametrize('rate', [0.0, 0.2])
+@pytest.mark.parametrize('causal', [False, True])
+def test_fully_masked_rows_give_zeros_not_nans(causal, rate, fused_bwd):
+    """A key bias of -inf on EVERY key of one batch element: every
+    score of its rows is -inf.  The kernels carry no isfinite guard
+    around exp since PR 29 (exp(-inf - finite) is 0 and the running
+    max / the saved lse are kept finite), so this holds what the
+    guarded kernels gave: a zero output row, lse = log(1e-20), and
+    zero, finite gradients — in the forward, the fused and the
+    two-pass backward.  (The dense chain gives NaN here; the values
+    are the parent kernels', written out.)"""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    rng = np.random.RandomState(11)
+    q, k, v, cot = (jnp.asarray(rng.randn(2, 32, 2, 8), jnp.float32)
+                    for _ in range(4))
+    bias = jnp.asarray(np.stack([np.full(32, -np.inf),
+                                 rng.randn(32)]), jnp.float32)
+    kw = dict(causal=causal, dropout_rate=rate,
+              dropout_seed=jnp.uint32(5) if rate else None)
+
+    def loss(q, k, v, bias):
+        return jnp.vdot(fa.flash_attention(q, k, v, key_bias=bias,
+                                           min_seq=0, **kw), cot)
+
+    o, lse = fa.flash_attention_with_lse(q, k, v, key_bias=bias, **kw)
+    grads = jax.grad(loss, (0, 1, 2, 3))(q, k, v, bias)
+    assert np.isfinite(np.asarray(o)).all()
+    np.testing.assert_array_equal(np.asarray(o[0]), 0.0)
+    np.testing.assert_allclose(np.asarray(lse[0]), np.log(1e-20),
+                               rtol=1e-6)
+    for g in grads:
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_array_equal(np.asarray(g[0]), 0.0)
+    # the other batch element is ordinary attention
+    ref = fa._dense_path(q[1:], k[1:], v[1:], causal, bias[1:], rate,
+                         kw['dropout_seed'], dropout_g_offset=2)
+    np.testing.assert_allclose(np.asarray(o[1:]), np.asarray(ref),
+                               atol=3e-5, rtol=3e-5)
+    assert np.abs(np.asarray(grads[0][1])).max() > 0
+
+
+@pytest.mark.parametrize('d', [64, 128])
+def test_flash_parity_on_both_sides_of_the_exact_scale(d, fused_bwd):
+    """1/sqrt(64) only moves the exponent, so the kernels fold it into
+    the q or k tile their loop holds fixed; 1/sqrt(128) does not, and
+    multiplies the score tile as before.  Output and every gradient
+    against the dense chain on the same mask, both ways."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    assert fa._scale_is_exact(1.0 / d ** 0.5) == (d == 64)
+    rng = np.random.RandomState(d)
+    q, k, v, cot = (jnp.asarray(rng.randn(1, 64, 2, d), jnp.float32)
+                    for _ in range(4))
+    bias = jnp.asarray(rng.randn(1, 64), jnp.float32)
+    seed = jnp.uint32(21)
+
+    def f(q, k, v, bias):
+        return jnp.vdot(fa.flash_attention(
+            q, k, v, key_bias=bias, min_seq=0, dropout_rate=0.1,
+            dropout_seed=seed), cot)
+
+    def r(q, k, v, bias):
+        return jnp.vdot(fa._dense_path(q, k, v, False, bias, 0.1, seed),
+                        cot)
+
+    np.testing.assert_allclose(float(f(q, k, v, bias)),
+                               float(r(q, k, v, bias)), rtol=1e-4)
+    for a, b in zip(jax.grad(f, (0, 1, 2, 3))(q, k, v, bias),
+                    jax.grad(r, (0, 1, 2, 3))(q, k, v, bias)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize('lo,hi,tiles', [
+    (0, 8, 2),
+    (0, 6, 2),
+    (2, 6, 2),
+    (0, 2, 2),
+    (1, 4, 1),
+    (0, 8, 1),
+    (0, 1, 1),
+])
+def test_loop_runs_every_tile_once_and_in_order(lo, hi, tiles):
+    """_loop puts ``tiles`` tiles in one loop trip (so that the chip
+    overlaps one tile's vector chain with another's products): the
+    same steps in the same order as one tile a trip."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    def step(i, c):
+        return jax.lax.rem(c * 3 + i, 1000003)      # order-sensitive
+
+    def run(hi_):
+        return fa._loop(lo, hi_, step, jnp.int32(1), tiles)
+
+    want = jax.lax.fori_loop(lo, hi, step, jnp.int32(1))
+    assert int(run(hi)) == int(want)
+    if tiles == 1:
+        assert int(jax.jit(run)(hi)) == int(want)   # traced bound
+    text = str(jax.make_jaxpr(lambda: run(hi))())
+    assert text.count(' rem ') == tiles, text
+
+
+@pytest.mark.parametrize('t,d,dtype,causal,fwd,dkv,fused', [
+    # (tiles a trip, dO V^T issued early) of the forward / dq loop,
+    # the dkv loop and the fused backward's
+    (2048, 64, 'bfloat16', False, (2, False), (2, False), (2, False)),
+    (512, 64, 'bfloat16', False, (1, True), (1, True), (1, True)),
+    (1024, 64, 'bfloat16', False, (1, True), (2, False), (2, False)),
+    # olmoe: a dynamic bound, and no room beside 4096 rows of 128
+    (4096, 128, 'bfloat16', True, (1, False), (1, False), (1, False)),
+    (2048, 128, 'bfloat16', True, (1, True), (1, True), (1, True)),
+    (2048, 128, 'bfloat16', False, (2, False), (2, False), (2, False)),
+    (8192, 64, 'bfloat16', False, (1, False), (1, False), (1, False)),
+    # f32 tiles count twice
+    (2048, 64, 'float32', False, (1, False), (1, False), (1, False)),
+    (1024, 128, 'float32', False, (1, False), (1, False), (1, False)),
+    (512, 64, 'float32', False, (1, True), (1, True), (1, True)),
+])
+def test_the_second_tile_follows_the_vmem_model(t, d, dtype, causal, fwd,
+                                                dkv, fused):
+    """What each kernel does with the room for a second score tile at
+    the shapes that decide (_second_tile over
+    common.room_for_second_tile): two tiles a loop trip where the
+    trip count is even and known at trace time, else the backward's
+    dO V^T issued early, and neither where two tiles do not fit
+    beside the instance's rows."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    isz = jnp.dtype(dtype).itemsize
+    bq, bk = fa._block_sizes(t, fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K,
+                             d, isz)
+    rows = fa._rows_resident(t, d, bq, bk, isz)
+    assert fa._second_tile(None if causal else t // bk, rows, bq, bk,
+                           isz) == fwd
+    assert fa._second_tile(None if causal else t // bq, rows, bq, bk,
+                           isz) == dkv
+    fq, fk = min(bq, fa.FUSED_BLOCK_Q), min(bk, fa.FUSED_BLOCK_K)
+    assert fa._second_tile(
+        None if causal else t // fq,
+        fa._fused_bwd_resident(t, d, fk, isz), fq, fk, isz) == fused
+
+
+@pytest.fixture
+def fresh_calls():
+    """_fwd_call / _bwd_call keep their trace by their static
+    arguments; the VMEM model's limit, which a test patches under
+    them, is not among those."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    fa._fwd_call.clear_cache()
+    fa._bwd_call.clear_cache()
+    yield
+    fa._fwd_call.clear_cache()
+    fa._bwd_call.clear_cache()
+
+
+@pytest.mark.parametrize('tokens,room', [
+    (128, True),     # eight tiles an instance: four trips of two
+    (48, True),      # three: one a trip, dO V^T issued early
+    (48, False),     # no room: one a trip, the products in turn
+])
+def test_flash_parity_with_and_without_a_second_tile(
+        fused_bwd, fresh_calls, monkeypatch, tokens, room):
+    """bf16 operands with several [16, 16] tiles a kernel instance
+    against the dense chain on the same mask, in each of the three
+    ways _second_tile() can answer: a tile visited twice, skipped or
+    out of place would be far outside bf16's tolerance."""
+    from paddle_tpu.ops.pallas import common
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    for name in ('DEFAULT_BLOCK_Q', 'DEFAULT_BLOCK_K', 'FUSED_BLOCK_Q',
+                 'FUSED_BLOCK_K'):
+        monkeypatch.setattr(fa, name, 16)
+    if not room:
+        monkeypatch.setattr(common, 'SCOPED_VMEM_BYTES', 0)
+    assert fa._second_tile(tokens // 16, 0, 16, 16, 2) == {
+        (128, True): (2, False), (48, True): (1, True),
+        (48, False): (1, False)}[tokens, room]
+    rng = np.random.RandomState(8)
+    q, k, v, cot = (jnp.asarray(rng.randn(1, tokens, 2, 16), jnp.bfloat16)
+                    for _ in range(4))
+    bias = jnp.asarray(rng.randn(1, tokens), jnp.float32)
+    seed = jnp.uint32(3)
+
+    def f(q, k, v, bias):
+        o = fa.flash_attention(q, k, v, key_bias=bias, min_seq=0,
+                               dropout_rate=0.1, dropout_seed=seed)
+        return jnp.vdot(o.astype(jnp.float32), cot.astype(jnp.float32))
+
+    def r(q, k, v, bias):
+        o = fa._dense_path(q, k, v, False, bias, 0.1, seed)
+        return jnp.vdot(o.astype(jnp.float32), cot.astype(jnp.float32))
+
+    np.testing.assert_allclose(float(f(q, k, v, bias)),
+                               float(r(q, k, v, bias)), rtol=2e-2)
+    for a, b in zip(jax.grad(f, (0, 1, 2, 3))(q, k, v, bias),
+                    jax.grad(r, (0, 1, 2, 3))(q, k, v, bias)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= 0.05 * np.abs(b).max() + 0.02, \
+            np.abs(a - b).max()
 
 
 def test_flash_dropout_deterministic_and_seed_sensitive():
