@@ -616,11 +616,219 @@ def phase_olmoe_gradients(seq=4096, seed=0, rows=64):
               % (kind, OLMOE_ENTRY_RTOL, entry))
 
 
+# Laguna-S-2.1's cut (5 layers, experts 0-7 of 256, 12544 vocabulary
+# rows) at published widths: the f32 train program against jax.grad of
+# the plain reference, per sampled gradient tensor, and the loss over
+# many batches in f32 and with the reference in bfloat16 throughout
+# (my chip runs, PR 30: PERF.md section 6 has the readings).  Every
+# product on both sides is full f32 (the flash kernels, the grouped
+# matmuls, the reference), so what is left is summation order through
+# five layers, and the tokens whose 10th and 11th router probabilities
+# nearly tie: such a token may pick the other expert in the program
+# than in the reference (the loads of two experts of a layer then
+# differ by one, which this phase counts), its own loss and gradient
+# move by their own size, and the mean loss by up to 3.9e-6 (5 of 24
+# batches read over 3e-7; the limit on the loss is the benchmark
+# family's, 1e-5, which the reference in bfloat16 throughout misses on
+# 20 of the 24: 2.9e-6 to 1.6e-4).  Gradients: relative L2 distance
+# 2.8e-5 to 3.8e-4 (largest where every token's error adds up: the
+# shared expert, the projections of the early layers), single entries
+# up to 2.2e-3 of their tensor's largest where one flipped token's
+# rows land; the bound is on the L2 distance, 5x the worst reading.  A
+# wrong K/V group, band edge, rotated width, gate or held range, or an
+# unmasked row past the held groups (the first chip run of this phase:
+# 1e5) moves a gradient by its own size or more.
+LAGUNA_LOSS_RTOL = 1e-5
+LAGUNA_LOSS_BATCHES = 24
+LAGUNA_L2_RTOL = 2e-3
+# creation order: embedding 0; layer 0 (full, dense) g_in 1 Wq Wk Wv
+# Wg Wo g_post gate up down 10; layer 1 (sliding, sparse) g_in 11 Wq
+# Wk Wv Wg Wo g_post router 18 gate up down 21 shared 22-24; layers 2,
+# 3 the same from 25 and 39; layer 4 (full, sparse) from 53; g_final
+# 67; head 68
+LAGUNA_SAMPLED = {'embedding': 0, 'full Wk (layer 0)': 3,
+                  'full head gate (layer 0)': 5,
+                  'sliding Wk (layer 1)': 13,
+                  'sliding head gate (layer 1)': 15,
+                  'router (layer 1)': 18, 'gate': 19, 'up': 20,
+                  'down': 21, 'shared down (layer 1)': 24,
+                  'full Wk (layer 4)': 55, 'router (layer 4)': 60}
+
+
+def _laguna_cut():
+    from paddle_tpu.models import laguna
+    return laguna.LagunaConfig(vocab_size=12544, layers=5,
+                               experts_held=(0, 8))
+
+
+def _laguna_programs(seq, seed):
+    """(main with SGD at lr 0, startup, f32 for_test clone, loss,
+    params in creation order, {param: its gradient's name})."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import laguna
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        _, _, loss = laguna.build_pretrain(_laguna_cut(), seq)
+        params = [p.name for p in main.all_parameters()]
+        test = main.clone(for_test=True)
+        pairs = dict((p.name, g.name) for p, g in
+                     fluid.optimizer.SGD(0.0).minimize(loss)[1])
+    return main, startup, test, loss, params, pairs
+
+
+def phase_laguna_gradients(seq=4096, seed=0, rows=64):
+    """models.laguna.BASE cut as the benchmark cuts it: loss and
+    sampled gradients of the f32 TRAIN program (flash kernels, banded
+    and full, grouped K/V; the held experts' grouped matmuls) against
+    the reference's, on one seeded sequence; then the f32 for_test
+    program's loss against the reference in float32 and in bfloat16
+    throughout over LAGUNA_LOSS_BATCHES batches."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.models import laguna
+    from paddle_tpu.models.reference import laguna as reference
+    cfg = _laguna_cut()
+    sizes = reference.sizes_of(cfg)
+    feeds = [_ints32(laguna.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(s)))
+        for s in range(seed, seed + LAGUNA_LOSS_BATCHES)]
+    feed = feeds[0]
+    picked_rows = np.unique(feed['ids'])[:rows]
+    experts = {}
+
+    def sample(name, array):
+        if name == 'embedding':
+            return {'embedding rows': array[picked_rows]}
+        if name in ('gate', 'up', 'down'):
+            return {'%s, %s loaded held expert (layer 1)' % (name, which):
+                    array[e] for which, e in experts.items()}
+        return {name: array}
+
+    main, startup, test, loss, params, pairs = _laguna_programs(seq, seed)
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights = [np.asarray(fluid.core.as_array(scope.find_var(p)))
+                   for p in params]
+        program_losses = [_scalar(exe.run(test, feed=f, fetch_list=[loss]))
+                          for f in feeds]
+        say('laguna f32 for_test program: %d batches of 1 x %d tokens'
+            % (len(feeds), seq))
+        t0 = time.time()
+        got = exe.run(main, feed=feed, return_numpy=False, fetch_list=[
+            loss] + [pairs[params[i]] for i in LAGUNA_SAMPLED.values()])
+        got_loss = _scalar(got[:1])
+        # a run that fetches and blocks reads the routers' loads
+        program_loads = exe.run(main, feed=feed, fetch_list=[loss] + [
+            op.output('Load')[0] for op in main.global_block().ops
+            if op.type == 'moe_route'])[1:]
+        say('laguna f32 train program, 1 x %d tokens: loss %.6f in %.1f s '
+            '(with compile); moe/held_share %.4f, moe/rows_held %d of '
+            '%d routed in four layers'
+            % (seq, got_loss, time.time() - t0,
+               monitor.gauge_value('moe/held_share'),
+               monitor.counter_value('moe/rows_held'),
+               monitor.counter_value('moe/tokens_routed')))
+        check(monitor.counter_value('moe/dropped_tokens') == 0 and
+              monitor.counter_value('moe/rows_held') > 0,
+              'rows were held and moe/dropped_tokens stayed 0')
+        # the held experts' loads of layer 1, from the reference below
+        held_grads = got[1:]
+        del got
+        ids, pos, labels = (jnp.asarray(feed[k])
+                            for k in ('ids', 'pos_ids', 'labels'))
+        device_weights = [jnp.asarray(w) for w in weights]
+        loads = [np.asarray(x) for x in jax.jit(
+            lambda w: reference.forward(w, ids, pos, sizes=sizes)[1])(
+                device_weights)]
+        say('routed layers whose experts\' loads differ between program '
+            'and reference (a near-tie token changes two by one): %s'
+            % [int(np.sum(np.asarray(a) != b))
+               for a, b in zip(program_loads, loads)])
+        load = loads[0][:8]
+        experts.update(most=int(load.argmax()), least=int(load.argmin()))
+        grads = {what: np.asarray(x)
+                 for name, g in zip(LAGUNA_SAMPLED, held_grads)
+                 for what, x in sample(name, g).items()}
+        del held_grads
+        for name in scope.local_var_names():
+            scope.erase(name)
+
+    # the weights go in as an argument: closed over, they would be
+    # constants of the program (3.2 GB a compile on the host)
+    def ref_loss(some, full, dtype=jnp.float32, remat=True, batch=None):
+        full = list(full)
+        for name, w in some.items():
+            full[LAGUNA_SAMPLED[name]] = w
+        i, p, l = batch or (ids, pos, labels)
+        return reference.loss(full, i, p, l, sizes=sizes, dtype=dtype,
+                              remat=remat)
+
+    some = {name: device_weights[i] for name, i in LAGUNA_SAMPLED.items()}
+    want_loss, want_grads = jax.jit(jax.value_and_grad(ref_loss))(
+        some, device_weights)
+    want_loss = float(want_loss)
+    say('reference: loss %.6f; held experts\' loads in layer 1: max %d '
+        '(expert %d) min %d (expert %d), %d of %d rows'
+        % (want_loss, load.max(), experts['most'], load.min(),
+           experts['least'], load.sum(), seq * cfg.top_k))
+    rel = abs(got_loss - want_loss) / want_loss
+    say('f32 train program: loss %.6f, relative difference %.2e'
+        % (got_loss, rel))
+    check(rel <= LAGUNA_LOSS_RTOL, 'laguna f32 train loss within %g of '
+          'the reference' % LAGUNA_LOSS_RTOL)
+    worst = 0.0
+    for name in LAGUNA_SAMPLED:
+        for what, y in sample(name, np.asarray(want_grads[name])).items():
+            x = grads[what]
+            e = float(np.abs(x - y).max() / np.abs(y).max())
+            d = float(np.linalg.norm(x - y) / np.linalg.norm(y))
+            worst = max(worst, d)
+            say('gradient of %s %s: largest entry difference %.3e of the '
+                'largest entry (%.3e), relative L2 distance %.3e'
+                % (what, x.shape, e, np.abs(y).max(), d))
+    del want_grads
+    both = jax.jit(lambda full, i, p, l: [
+        ref_loss({}, full, dtype=dt, remat=False, batch=(i, p, l))
+        for dt in (jnp.float32, jnp.bfloat16)])
+    off, low = [], []
+    for n, (other, got) in enumerate(zip(feeds, program_losses)):
+        full, half = (float(x) for x in both(device_weights, *(
+            jnp.asarray(other[k]) for k in ('ids', 'pos_ids', 'labels'))))
+        off.append(abs(got - full) / full)
+        low.append(abs(half - full) / full)
+        if off[-1] > 3e-7 or low[-1] <= LAGUNA_LOSS_RTOL:
+            say('batch seed %d: program %.6f, reference %.6f (relative '
+                'difference %.2e), reference in bfloat16 throughout '
+                '%.6f (%.2e)' % (seed + n, got, full, off[-1], half,
+                                 low[-1]))
+    say('over %d batches: f32 for_test program against the reference, '
+        'relative: median %.2e, %d above 3e-7, largest %.2e; reference '
+        'in bfloat16 throughout: smallest %.2e, quartiles %.2e %.2e '
+        '%.2e, largest %.2e, %d within %g'
+        % ((len(off), np.median(off), sum(x > 3e-7 for x in off), max(off),
+            min(low)) + tuple(np.percentile(low, (25, 50, 75))) +
+           (max(low), sum(x <= LAGUNA_LOSS_RTOL for x in low),
+            LAGUNA_LOSS_RTOL)))
+    check(max(off) <= LAGUNA_LOSS_RTOL, 'laguna f32 for_test loss within '
+          '%g of the reference on every batch' % LAGUNA_LOSS_RTOL)
+    check(worst <= LAGUNA_L2_RTOL,
+          'laguna gradients: every sampled tensor within %g of the '
+          'reference\'s, relative L2 distance (worst %.3e)'
+          % (LAGUNA_L2_RTOL, worst))
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
     ap.add_argument('--chips', type=int, choices=(1, 4), default=1)
-    ap.add_argument('--phase', choices=('bert', 'olmoe'), default='bert',
-                    help="'olmoe': only the OLMoE gradient check")
+    ap.add_argument('--phase', choices=('bert', 'olmoe', 'laguna'),
+                    default='bert',
+                    help="'olmoe' / 'laguna': only that model's "
+                    "gradient check")
     args = ap.parse_args()
 
     import jax
@@ -649,6 +857,8 @@ def main():
     try:
         if args.phase == 'olmoe':
             phase_olmoe_gradients()
+        elif args.phase == 'laguna':
+            phase_laguna_gradients()
         elif args.chips == 4:
             phase_four_chips(
                 models.bert.BertConfig(dropout=0.0, attn_dropout=0.0),

@@ -2350,9 +2350,18 @@ class Executor(object):
                     tuple(sorted(seg.output_names)),
                     donate=True)
                 state_specs, data_specs = _specs_from_args(state, data)
-                compiled = plane.obtain(
-                    fp, lambda: _aot_build(seg, wpg, state_specs,
-                                           data_specs, device))
+                try:
+                    compiled = plane.obtain(
+                        fp, lambda: _aot_build(seg, wpg, state_specs,
+                                               data_specs, device))
+                except Exception as e:
+                    # the plane lowers here, before any dispatch: name
+                    # a diverging feed as _dispatch_segment does
+                    note = _feed_mismatch_note(
+                        seg.ops[0].block.program, feed)
+                    if note:
+                        _add_note(e, note)
+                    raise
                 seg.compiled[skey] = compiled
                 # memory-plane attribution: once per NEW executable
                 # entry — compile, memory hit or disk hit all land

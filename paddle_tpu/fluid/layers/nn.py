@@ -247,17 +247,31 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(q, k, positions, theta=10000.0, name=None):
-    """Rotary position embedding of q and k [B, T, H, D] at integer
-    ``positions`` [B, T], rotate-half pairing over the whole head ->
-    (q, k) rotated."""
+def rotary_embedding(q, k, positions, theta=10000.0, name=None,
+                     rotary_dim=None, inv_freq=None,
+                     attention_factor=1.0):
+    """Rotary position embedding of q [B, T, H, D] and k [B, T, Hk, D]
+    at integer ``positions`` [B, T], rotate-half pairing -> (q, k)
+    rotated.  By default over the whole head with the frequencies
+    theta^(-2i/D).  ``rotary_dim`` rotates the first ``rotary_dim``
+    features of each head only (a partial rotary factor) and passes
+    the rest through; ``inv_freq`` is a variable [rotary_dim / 2] of
+    inverse frequencies to use instead of theta's (a YaRN or NTK
+    table, e.g. ``layers.assign`` of a numpy array);
+    ``attention_factor`` multiplies cos and sin (YaRN's)."""
     helper = LayerHelper('rotary_embedding', name=name)
     q_out = helper.create_variable_for_type_inference(q.dtype)
     k_out = helper.create_variable_for_type_inference(k.dtype)
-    helper.append_op('rotary_embedding',
-                     inputs={'Q': q, 'K': k, 'Positions': positions},
-                     outputs={'QOut': q_out, 'KOut': k_out},
-                     attrs={'theta': float(theta)})
+    inputs = {'Q': q, 'K': k, 'Positions': positions}
+    attrs = {'theta': float(theta)}
+    if rotary_dim is not None:
+        attrs['rotary_dim'] = int(rotary_dim)
+    if inv_freq is not None:
+        inputs['InvFreq'] = inv_freq
+    if attention_factor != 1.0:
+        attrs['attention_factor'] = float(attention_factor)
+    helper.append_op('rotary_embedding', inputs=inputs,
+                     outputs={'QOut': q_out, 'KOut': k_out}, attrs=attrs)
     return q_out, k_out
 
 

@@ -69,7 +69,8 @@ def context_parallel_attention(q, k, v, causal=False, use_flash=False,
 
 def moe(x, num_experts, hidden_size, capacity_factor=2.0,
         aux_weight=0.01, axis='ep', top_k=1, param_attr=None,
-        name=None, renormalize=True, z_loss_weight=0.0):
+        name=None, renormalize=True, z_loss_weight=0.0,
+        experts_held=None, gate_scale=1.0):
     """Mixture-of-Experts feed-forward layer, in two forms.
 
     **Capacity-based** (``capacity_factor`` a number, the default):
@@ -89,7 +90,20 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
     by expert and runs one grouped matmul per weight set
     (``moe_route`` / ``moe_dispatch`` / ``moe_experts`` /
     ``moe_combine`` ops); it raises NotImplementedError under an
-    ``axis`` mesh dimension.
+    ``axis`` mesh dimension.  ``gate_scale`` multiplies the gates (a
+    routed scaling factor).  ``experts_held=(first, count)`` makes
+    this layer one chip's share of an expert-parallel layer, without
+    the exchange: the router stays ``num_experts`` wide and picks
+    ``top_k`` of all of them, the expert weights are [count, D,
+    hidden_size] (experts first .. first + count - 1), and the output
+    is the part of the layer's result those experts give: the sum over
+    each token's chosen experts HELD HERE of gate x expert(x).  The
+    (token, expert) pairs are sorted with the held experts' rows
+    first; the grouped matmuls get those groups' sizes only, over a
+    buffer of tokens x min(top_k, count) rows (the most that can be
+    held), and what lies past the last group is neither computed nor
+    counted as dropped.  What every chip computes alike (a shared
+    expert) is the model's to add, once.
 
     x: [B, T, D].  Returns (out [B, T, D], aux []): ``aux`` is the
     load-balance loss times ``aux_weight`` plus, dropless only, the
@@ -97,6 +111,19 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
     """
     top_k, e, h = int(top_k), int(num_experts), int(hidden_size)
     dropless = capacity_factor is None
+    if experts_held is not None:
+        first, count = (int(n) for n in experts_held)
+        if not dropless or not (0 <= first and 1 <= count and
+                                first + count <= e):
+            raise ValueError(
+                'moe: experts_held=(first, count) names a range of the '
+                '%d experts of a dropless layer (capacity_factor=None); '
+                'got %r with capacity_factor=%r'
+                % (e, experts_held, capacity_factor))
+        experts_held = (first, count)
+    if gate_scale != 1.0 and not dropless:
+        raise ValueError('moe: gate_scale needs the dropless path '
+                         '(capacity_factor=None)')
     if dropless and not 1 <= top_k <= e:
         raise ValueError('moe: dropless top_k must be in 1..num_experts '
                          '(%d), got %r' % (e, top_k))
@@ -126,7 +153,8 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
     if dropless:
         return _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k,
                              axis, renormalize, aux_weight,
-                             z_loss_weight)
+                             z_loss_weight, experts_held,
+                             float(gate_scale))
     w1, w2 = weight([e, d, h]), weight([e, h, d])
     out = helper.create_variable_for_type_inference(x.dtype)
     aux = helper.create_variable_for_type_inference('float32')
@@ -145,10 +173,12 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
 
 
 def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
-                  renormalize, aux_weight, z_loss_weight):
+                  renormalize, aux_weight, z_loss_weight, held=None,
+                  gate_scale=1.0):
     d = int(x.shape[-1])
-    w_gate, w_up, w_down = weight([e, d, h]), weight([e, d, h]), \
-        weight([e, h, d])
+    here = e if held is None else held[1]      # experts with weights
+    w_gate, w_up, w_down = weight([here, d, h]), weight([here, d, h]), \
+        weight([here, h, d])
 
     def var(dtype, stop_gradient=False):
         return helper.create_variable_for_type_inference(
@@ -156,42 +186,57 @@ def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
 
     idx, load, dropped = (var('int32', True) for _ in range(3))
     gates, balance, z = var('float32'), var('float32'), var('float32')
+    route_outs = {'TopKIdx': idx, 'TopKWeight': gates,
+                  'AuxLoss': balance, 'ZLoss': z, 'Load': load}
+    route_attrs = {'top_k': top_k, 'axis': axis,
+                   'renormalize': bool(renormalize)}
+    sizes, held_attrs = load, {}
+    if gate_scale != 1.0:
+        route_attrs['scale'] = gate_scale
+    if held is not None:
+        # the groups the matmuls are handed: the held experts' loads
+        sizes = route_outs['HeldLoad'] = var('int32', True)
+        held_attrs = {'experts_held': list(held)}
+        route_attrs.update(held_attrs)
     helper.append_op('moe_route', inputs={'X': x, 'Gate': wg},
-                     outputs={'TopKIdx': idx, 'TopKWeight': gates,
-                              'AuxLoss': balance, 'ZLoss': z,
-                              'Load': load},
-                     attrs={'top_k': top_k, 'axis': axis,
-                            'renormalize': bool(renormalize)})
+                     outputs=route_outs, attrs=route_attrs)
     # rows = tokens x top_k: with a dynamic batch, shape inference's
     # stand-in for it overflows int32 index arithmetic at that size, so
     # the shapes of the permuted tensors are stated, not inferred
     rows, order, inverse = var(x.dtype), var('int32', True), \
         var('int32', True)
     helper.append_op('moe_dispatch',
-                     inputs={'X': x, 'TopKIdx': idx, 'GroupSizes': load},
+                     inputs={'X': x, 'TopKIdx': idx, 'GroupSizes': sizes},
                      outputs={'Rows': rows, 'Order': order,
                               'Inverse': inverse, 'Dropped': dropped},
-                     infer_shape=False)
+                     attrs=held_attrs, infer_shape=False)
     expert_out = var(x.dtype)
     helper.append_op('moe_experts',
-                     inputs={'Rows': rows, 'GroupSizes': load,
+                     inputs={'Rows': rows, 'GroupSizes': sizes,
                              'WGate': w_gate, 'WUp': w_up,
                              'WDown': w_down},
-                     outputs={'Out': expert_out}, infer_shape=False)
+                     outputs={'Out': expert_out}, attrs=held_attrs,
+                     infer_shape=False)
     flat = var(x.dtype)
-    helper.append_op('moe_combine',
-                     inputs={'Rows': expert_out, 'TopKWeight': gates,
-                             'Order': order, 'Inverse': inverse},
+    combine_ins = {'Rows': expert_out, 'TopKWeight': gates,
+                   'Order': order, 'Inverse': inverse}
+    if held is not None:
+        combine_ins['GroupSizes'] = sizes
+    helper.append_op('moe_combine', inputs=combine_ins,
                      outputs={'Out': flat}, infer_shape=False)
     from ...ops.registry import _DYN_SENTINEL
     # a dynamic batch counts as inference's stand-in, products literal
     # (registry.infer_shapes does the same for layer_norm's row count)
     tokens = int(np.prod([_DYN_SENTINEL if n < 0 else int(n)
                           for n in x.shape[:-1]]))
-    n_rows = tokens * top_k
+    from ...parallel.moe import held_rows_bound
+    n_rows = held_rows_bound(tokens, top_k, held)
     rows.shape = expert_out.shape = (n_rows, d)
-    order.shape = inverse.shape = (n_rows,)
+    order.shape = (n_rows,)
+    inverse.shape = (tokens * top_k,)
     dropped.shape = (1,)
+    if held is not None:
+        sizes.shape = (held[1],)
     flat.shape = (tokens, d)
     out = flat
     if len(x.shape) != 2:
@@ -206,6 +251,10 @@ def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
     from .. import moe_stats
     helper.main_program.watch([load.name, dropped.name],
                               moe_stats.record)
+    if held is not None:
+        # moe/rows_held, moe/held_share
+        helper.main_program.watch([load.name, sizes.name],
+                                  moe_stats.record_held)
     aux = scaled(balance, aux_weight)
     if z_loss_weight:
         total = var('float32')

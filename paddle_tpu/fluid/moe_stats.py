@@ -17,6 +17,16 @@ fetches and calls ``record``; a quiet run reads nothing.
   mean load, the worst layer of the last run read (1.0 is perfectly
   even; E is everything on one expert).
 
+A layer that holds a range of the experts (``experts_held``) also
+reports, on the same runs:
+
+- counter ``moe/rows_held``: the pairs routed to an expert held here,
+  i.e. the rows the grouped matmuls compute.  A pair routed to an
+  absent expert is no drop: ``moe/dropped_tokens`` stays 0;
+- gauge ``moe/held_share``: rows held over rows routed, all such
+  layers of the last run read together (held / all experts where the
+  routing is even).
+
 Under ``with_data_parallel`` the values are the whole batch's; under
 the collective (shard_map) runner they are the first device's share.
 """
@@ -37,3 +47,14 @@ def record(values):
                     float(np.asarray(dropped, np.int64).sum()))
         worst = max(worst, float(load.max()) / max(load.mean(), 1e-9))
     monitor.set_gauge('moe/load_max_over_mean', worst)
+
+
+def record_held(values):
+    """``values``: load [E], held load [count], ... one pair a layer
+    that holds a range of its experts, as fetched."""
+    routed = held = 0.0
+    for load, mine in zip(values[0::2], values[1::2]):
+        routed += float(np.asarray(load, np.int64).sum())
+        held += float(np.asarray(mine, np.int64).sum())
+    monitor.add('moe/rows_held', held)
+    monitor.set_gauge('moe/held_share', held / max(routed, 1.0))

@@ -7,6 +7,9 @@ automatic fusions; the ones kept here either use a Pallas kernel
 (attention) or encode a pattern XLA cannot see (none yet).
 """
 
+import contextlib
+
+import jax
 import jax.numpy as jnp
 
 from .registry import register
@@ -14,11 +17,22 @@ from .registry import register
 
 @register('fused_multihead_attention', stochastic=True)
 def fused_multihead_attention(ctx, ins, attrs):
-    """Q,K,V: [B, T, H, D] (+ optional KeyBias [B, T] additive score
-    bias, e.g. a padding mask) -> Out [B, T, H, D] via
+    """Q: [B, T, H, D], K,V: [B, T, Hkv, D] (+ optional KeyBias [B, T]
+    additive score bias, e.g. a padding mask) -> Out [B, T, H, D] via
     flash_attention(): the Pallas kernels forward and backward on a
     TPU, the dense chain elsewhere and under the GSPMD runner's mesh
     (ops/pallas/common.py dispatch()).
+
+    Two attributes of the shape, read by every arm alike: K/V of
+    fewer heads than Q (Hkv divides H: query head i attends K/V head
+    i // (H / Hkv); the kernels read the shared head through their
+    index maps and sum its gradient over the group, nothing is
+    repeated in HBM), and attrs['window'] (0 = none; with 'causal',
+    query i sees keys j with 0 <= i - j < window, and the kernels skip
+    the blocks outside the band).  A windowed call is lowered inside a
+    scope of its own, ``window<n>``, so a device trace tells it from a
+    full one (the compiler names a Mosaic call after the innermost
+    scope).
 
     attrs['dropout_rate'] > 0 applies attention-probability dropout
     INSIDE the kernels (reference default: dropout around softmax,
@@ -35,10 +49,13 @@ def fused_multihead_attention(ctx, ins, attrs):
     seed = ctx.dropout_seed(attrs) if rate else None
     if seed is None:
         rate = 0.0
-    return {'Out': [flash_attention(
-        q, k, v, causal=attrs.get('causal', False), key_bias=bias,
-        dropout_rate=rate, dropout_seed=seed,
-        auto_partitioned=ctx.auto_partitioned)]}
+    window = int(attrs.get('window', 0) or 0)
+    with jax.named_scope('window%d' % window) if window \
+            else contextlib.nullcontext():
+        return {'Out': [flash_attention(
+            q, k, v, causal=attrs.get('causal', False), key_bias=bias,
+            dropout_rate=rate, dropout_seed=seed,
+            auto_partitioned=ctx.auto_partitioned, window=window)]}
 
 
 @register('fused_elemwise_activation')

@@ -441,24 +441,40 @@ def rms_norm(ctx, ins, attrs):
 
 @register('rotary_embedding')
 def rotary_embedding(ctx, ins, attrs):
-    """Q, K [B, T, H, D], Positions [B, T] int -> QOut, KOut: rotary
-    position embedding (Su et al. 2021) over the whole head, with the
-    ROTATE-HALF pairing of HF ``apply_rotary_pos_emb``: feature i pairs
-    with i + D/2, both turned by the angle pos * theta^(-2i/D).
-    Angles and the rotation in float32, outputs in the input dtype."""
+    """Q [B, T, H, D], K [B, T, Hk, D], Positions [B, T] int -> QOut,
+    KOut: rotary position embedding (Su et al. 2021) with the
+    ROTATE-HALF pairing of HF ``apply_rotary_pos_emb``: over the first
+    ``rotary_dim`` features of each head (attrs; default D, the whole
+    head), feature i pairs with i + rotary_dim/2, both turned by the
+    angle pos * inv_freq[i]; the features past ``rotary_dim`` pass
+    through.  The inverse frequencies are the input InvFreq
+    [rotary_dim/2] where given (a scaled table: YaRN, NTK), else
+    theta^(-2i/rotary_dim); cos and sin are multiplied by
+    attrs['attention_factor'] (default 1.0: nothing).  Angles and the
+    rotation in float32, outputs in the input dtype."""
     q, k = ins['Q'][0], ins['K'][0]
     pos = ins['Positions'][0].astype(jnp.float32)
-    half = q.shape[-1] // 2
-    inv_freq = 1.0 / (float(attrs.get('theta', 10000.0)) ** (
-        jnp.arange(half, dtype=jnp.float32) / half))
-    angle = pos[:, :, None, None] * inv_freq            # [B, T, 1, D/2]
+    width = q.shape[-1]
+    rotary = int(attrs.get('rotary_dim', 0) or width)
+    half = rotary // 2
+    if ins.get('InvFreq'):
+        inv_freq = ins['InvFreq'][0].astype(jnp.float32)
+    else:
+        inv_freq = 1.0 / (float(attrs.get('theta', 10000.0)) ** (
+            jnp.arange(half, dtype=jnp.float32) / half))
+    angle = pos[:, :, None, None] * inv_freq            # [B, T, 1, R/2]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    factor = float(attrs.get('attention_factor', 1.0))
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
 
     def rotate(x):
         xf = x.astype(jnp.float32)
-        x1, x2 = xf[..., :half], xf[..., half:]
-        return jnp.concatenate([x1 * cos - x2 * sin,
-                                x2 * cos + x1 * sin], -1).astype(x.dtype)
+        x1, x2 = xf[..., :half], xf[..., half:rotary]
+        parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+        if rotary < width:
+            parts.append(xf[..., rotary:])
+        return jnp.concatenate(parts, -1).astype(x.dtype)
 
     return {'QOut': [rotate(q)], 'KOut': [rotate(k)]}
 

@@ -193,10 +193,11 @@ def _scale_is_exact(scale):
 
 
 def _score_tile(q, k, bias, rows, cols, *, scale, causal, q0, k0,
-                rate):
+                rate, window=0):
     """What the four kernel bodies share for one [bq, bk] tile:
     s = q k^T (* scale, unless an operand already carries it:
-    scale=None) (+ bias[None, :]) (-inf above the diagonal), and the
+    scale=None) (+ bias[None, :]) (-inf above the diagonal and, with
+    a ``window``, ``window`` or more keys below it), and the
     dropout multiplier u = 1/(1-rate) where the element is kept, 0
     where it is dropped (None at rate 0), drawn from the tile's
     [bq, 1] ``rows`` and [1, bk] ``cols`` terms.  Callers turn s into
@@ -212,7 +213,10 @@ def _score_tile(q, k, bias, rows, cols, *, scale, causal, q0, k0,
         bq, bk = s.shape
         qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        s = jnp.where(qpos >= kpos, s, -jnp.inf)
+        visible = qpos >= kpos
+        if window:
+            visible = visible & (qpos - kpos < window)
+        s = jnp.where(visible, s, -jnp.inf)
     u = None
     if rate:
         u = jnp.where(_dropout_keep(rows, cols, _keep_threshold(rate)),
@@ -235,8 +239,51 @@ def _loop(lo, hi, step, init, tiles):
     return jax.lax.fori_loop(0, (hi - lo) // tiles, trip, init)
 
 
+def _key_blocks(q0, bq, block_k, nk, causal, window):
+    """[lo, hi) of the key blocks that hold a key some query of the
+    block q0 .. q0+bq-1 sees: all of them without a mask, up to the
+    diagonal's under a causal one, and from the block of key
+    q0 - window + 1 on under a banded one.  The blocks outside are
+    not visited; the ones on the band's two edges are masked per
+    element (_score_tile)."""
+    if not causal:
+        return 0, nk
+    hi = jnp.minimum(nk, (q0 + bq + block_k - 1) // block_k)
+    lo = jnp.maximum(q0 - window + 1, 0) // block_k if window else 0
+    return lo, hi
+
+
+def _query_blocks(k0, bk, block_q, nq, causal, window):
+    """[lo, hi) of the query blocks that hold a query which sees some
+    key of the block k0 .. k0+bk-1: _key_blocks() from the other
+    side (the last such query is k0 + bk - 1 + window - 1)."""
+    if not causal:
+        return 0, nq
+    hi = jnp.minimum(nq, (k0 + bk + window - 2) // block_q + 1) \
+        if window else nq
+    return k0 // block_q, hi
+
+
+def _zero_at_first(member, accs):
+    """Where a grid axis walks the ``group`` query heads that share a
+    K/V head, dk and dv add up over it in f32 VMEM scratch: zeroed at
+    the group's first head ..."""
+    @pl.when(member == 0)
+    def _():
+        for acc in accs:
+            acc[...] = jnp.zeros_like(acc)
+
+
+def _flush_at_last(member, group, accs, outs):
+    """... and written out, in the outputs' dtype, at its last."""
+    @pl.when(member == group - 1)
+    def _():
+        for acc, out in zip(accs, outs):
+            out[0] = acc[...].astype(out.dtype)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
-                      block_k, tiles, has_bias, rate):
+                      block_k, tiles, has_bias, rate, window=0):
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
     seed_ref = rest.pop(0) if rate else None
@@ -269,7 +316,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             q, k, bias, rows,
             _draw_cols(seed_ref, i * block_k, block_k),
             scale=None if exact else scale, causal=causal, q0=q_off,
-            k0=i * block_k, rate=rate)
+            k0=i * block_k, rate=rate, window=window)
         m_new = jnp.maximum(m, jnp.max(s, axis=1))
         # a row with every key masked so far: m_new = -inf, p = 0
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -288,13 +335,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     m0 = jnp.full((bq,), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
     acc0 = jnp.zeros((bq, d), jnp.float32)
-    if causal:
-        # skip fully-masked K blocks beyond the diagonal
-        last = (q_off + bq + block_k - 1) // block_k
-        nk_eff = jnp.minimum(nk, last)
-    else:
-        nk_eff = nk
-    m, l, acc = _loop(0, nk_eff, body, (m0, l0, acc0), tiles)
+    # skip the K blocks no query of this block sees
+    lo, hi = _key_blocks(q_off, bq, block_k, nk, causal, window)
+    m, l, acc = _loop(lo, hi, body, (m0, l0, acc0), tiles)
     l_safe = jnp.maximum(l, 1e-20)
     out = acc / l_safe[:, None]
     o_ref[0] = out.astype(o_ref.dtype)
@@ -303,7 +346,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
-                         block_k, tiles, has_bias, has_glse, rate):
+                         block_k, tiles, has_bias, has_glse, rate,
+                         window=0):
     """Grid (BH, T/bq): recompute p row-blocks from q and lse, then
     dq = sum_k (p * (dO V^T - delta)) K * scale."""
     rest = list(rest)
@@ -336,7 +380,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             q_s, k, bias, rows,
             _draw_cols(seed_ref, i * block_k, block_k),
             scale=None if exact else scale, causal=causal, q0=q_off,
-            k0=i * block_k, rate=rate)
+            k0=i * block_k, rate=rate, window=window)
         p = jnp.exp(s - lse[:, None])
         dp = _dot(do, v, (1, 1))
         if rate:
@@ -352,12 +396,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             ds = ds * scale
         return dq + _dot(ds.astype(k.dtype), k, (1, 0))
 
-    if causal:
-        last = (q_off + bq + block_k - 1) // block_k
-        nk_eff = jnp.minimum(nk, last)
-    else:
-        nk_eff = nk
-    dq = _loop(0, nk_eff, body, jnp.zeros((bq, d), jnp.float32), tiles)
+    lo, hi = _key_blocks(q_off, bq, block_k, nk, causal, window)
+    dq = _loop(lo, hi, body, jnp.zeros((bq, d), jnp.float32), tiles)
     if exact:
         dq = dq * scale
     dq_ref[0] = dq.astype(dq_ref.dtype)
@@ -365,11 +405,18 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                           block_q, tiles, dp_early, has_bias, has_glse,
-                          rate):
+                          rate, window=0, group=1):
     """Grid (BH, T/bk): for one K/V block, stream Q row-blocks:
     dv = sum_q p^T dO;  ds_raw = p * (dO V^T - delta);
-    dk = sum_q ds_raw^T Q * scale;  dbias = sum_q ds_raw (per key)."""
+    dk = sum_q ds_raw^T Q * scale;  dbias = sum_q ds_raw (per key).
+
+    With ``group`` > 1 query heads to a K/V head the grid is
+    (B*Hkv, T/bk, group): the last axis walks the group's query heads
+    over one resident K/V block, and dk, dv add up over it in two f32
+    VMEM scratch blocks, written out at the group's last head."""
     rest = list(rest)
+    dk_acc, dv_acc = (rest.pop(-2), rest.pop(-1)) if group > 1 \
+        else (None, None)
     bias_ref = rest.pop(0) if has_bias else None
     seed_ref = rest.pop(0) if rate else None
     do_ref, lse_ref, delta_ref = rest[0], rest[1], rest[2]
@@ -383,6 +430,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     t = q_ref.shape[1]
     k_off = pl.program_id(1) * bk
     g_id = pl.program_id(0)
+    if group > 1:       # the query head this step holds
+        g_id = g_id * group + pl.program_id(2)
     exact = _scale_is_exact(scale)
     k_s = k * scale if exact else k
     cols = _draw_cols(seed_ref, k_off, bk)
@@ -402,7 +451,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             q, k_s, bias,
             _draw_rows(seed_ref, g_id, j * block_q, block_q), cols,
             scale=None if exact else scale, causal=causal,
-            q0=j * block_q, k0=k_off, rate=rate)
+            q0=j * block_q, k0=k_off, rate=rate, window=window)
         p = jnp.exp(s - lse[:, None])
 
         def dp_tile():
@@ -430,26 +479,31 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             dbias = dbias + jnp.sum(ds_raw, axis=0)
         return dk, dv, dbias
 
-    if causal:
-        # q blocks strictly above the diagonal contribute nothing
-        j0 = k_off // block_q
-    else:
-        j0 = 0
+    # q blocks whose queries see no key of this block contribute
+    # nothing
+    j0, j1 = _query_blocks(k_off, bk, block_q, nq, causal, window)
     dk0 = jnp.zeros((bk, d), jnp.float32)
     dv0 = jnp.zeros((bk, d), jnp.float32)
     db0 = jnp.zeros((bk,), jnp.float32)
-    dk, dv, dbias = _loop(j0, nq, body, (dk0, dv0, db0), tiles)
+    dk, dv, dbias = _loop(j0, j1, body, (dk0, dv0, db0), tiles)
     if exact:
         dk = dk * scale
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    if group == 1:
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+    else:
+        member = pl.program_id(2)
+        _zero_at_first(member, (dk_acc, dv_acc))
+        dk_acc[...] += dk
+        dv_acc[...] += dv
+        _flush_at_last(member, group, (dk_acc, dv_acc), (dk_ref, dv_ref))
     if has_bias:
         dbias_ref[0, 0] = dbias.astype(dbias_ref.dtype)
 
 
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                             block_q, block_k, tiles, dp_early, has_bias,
-                            has_glse, rate):
+                            has_glse, rate, window=0, group=1):
     """Single-pass backward: grid (BH,) only.  The two-pass scheme
     (dq grid over Q blocks, dk/dv grid over K blocks) recomputes the
     score block s AND the prob-cotangent dp = dO V^T in BOTH kernels —
@@ -458,8 +512,15 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     accumulator — true for the long-context shapes this kernel
     exists for), one kernel can walk k-blocks x q-blocks computing s
     and dp ONCE and accumulating all three gradients: dk/dv stream out
-    per k-block, dq rides a VMEM carry."""
+    per k-block, dq rides a VMEM carry.
+
+    With ``group`` > 1 query heads to a K/V head the grid is
+    (B*Hkv, group): the second axis walks the group's query heads over
+    one resident K/V head, and dk, dv add up over it in two more f32
+    [T, d] scratch buffers, written out at the group's last head."""
     rest = list(rest)
+    dk_acc, dv_acc = (rest.pop(-2), rest.pop(-1)) if group > 1 \
+        else (None, None)
     bias_ref = rest.pop(0) if has_bias else None
     seed_ref = rest.pop(0) if rate else None
     do_ref, lse_ref, delta_ref = rest[0], rest[1], rest[2]
@@ -473,6 +534,8 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         dbias_ref = None
     t, d = q_ref.shape[1], q_ref.shape[2]
     g_id = pl.program_id(0)
+    if group > 1:       # the query head this step holds
+        g_id = g_id * group + pl.program_id(1)
     exact = _scale_is_exact(scale)
     nq, nk = t // block_q, t // block_k
 
@@ -498,7 +561,8 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                 q, k_s, bias,
                 _draw_rows(seed_ref, g_id, j * block_q, block_q), cols,
                 scale=None if exact else scale, causal=causal,
-                q0=j * block_q, k0=i * block_k, rate=rate)
+                q0=j * block_q, k0=i * block_k, rate=rate,
+                window=window)
             p = jnp.exp(s - lse[:, None])
 
             def dp_tile():
@@ -533,63 +597,77 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             acc_ref[pl.dslice(j * block_q, block_q), :] = cur + dq_blk
             return dk, dv, dbias
 
-        if causal:
-            j0 = (i * block_k) // block_q
-        else:
-            j0 = 0
+        j0, j1 = _query_blocks(i * block_k, block_k, block_q, nq,
+                               causal, window)
         dk0 = jnp.zeros((block_k, d), jnp.float32)
         dv0 = jnp.zeros((block_k, d), jnp.float32)
         db0 = jnp.zeros((block_k,), jnp.float32)
-        dk, dv, dbias = _loop(j0, nq, q_step, (dk0, dv0, db0), tiles)
+        dk, dv, dbias = _loop(j0, j1, q_step, (dk0, dv0, db0), tiles)
         if exact:
             dk = dk * scale
-        dk_ref[0, pl.dslice(i * block_k, block_k), :] = \
-            dk.astype(dk_ref.dtype)
-        dv_ref[0, pl.dslice(i * block_k, block_k), :] = \
-            dv.astype(dv_ref.dtype)
+        here = pl.dslice(i * block_k, block_k)
+        if group == 1:
+            dk_ref[0, here, :] = dk.astype(dk_ref.dtype)
+            dv_ref[0, here, :] = dv.astype(dv_ref.dtype)
+        else:
+            dk_acc[here, :] += dk
+            dv_acc[here, :] += dv
         if has_bias:
             dbias_ref[0, 0, pl.dslice(i * block_k, block_k)] = \
                 dbias.astype(dbias_ref.dtype)
         return 0
 
     acc_ref[...] = jnp.zeros((t, d), jnp.float32)
+    if group > 1:
+        _zero_at_first(pl.program_id(1), (dk_acc, dv_acc))
     jax.lax.fori_loop(0, nk, k_step, 0)
     dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+    if group > 1:
+        _flush_at_last(pl.program_id(1), group, (dk_acc, dv_acc),
+                       (dk_ref, dv_ref))
 
 
 def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
-                     causal, block_q, block_k, interpret, rate):
-    """pallas_call plumbing for the one-pass backward (grid (BH,))."""
+                     causal, block_q, block_k, interpret, rate,
+                     window=0):
+    """pallas_call plumbing for the one-pass backward: grid (BH,), or
+    (B*Hkv, group) where ``group`` query heads share a K/V head."""
     bh, t, d = q.shape
+    group = bh // k.shape[0]
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
     has_glse = glse3 is not None
     tiles, dp_early = _second_tile(
         None if causal else t // block_q,
-        _fused_bwd_resident(t, d, block_k, q.dtype.itemsize),
+        _fused_bwd_resident(t, d, block_k, q.dtype.itemsize, group),
         block_q, block_k, q.dtype.itemsize)
     kernel = functools.partial(
         _flash_bwd_fused_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, tiles=tiles,
         dp_early=dp_early, has_bias=has_bias, has_glse=has_glse,
-        rate=rate)
-    row = pl.BlockSpec((1, t, d), lambda i: (i, 0, 0))
-    vec = pl.BlockSpec((1, 1, t), lambda i: (i, 0, 0))
-    in_specs = [row, row, row]
+        rate=rate, window=window, group=group)
+
+    def head(*ids):     # the query head of a grid step
+        return ids[0] if group == 1 else ids[0] * group + ids[1]
+
+    row = pl.BlockSpec((1, t, d), lambda *ids: (head(*ids), 0, 0))
+    vec = pl.BlockSpec((1, 1, t), lambda *ids: (head(*ids), 0, 0))
+    kv_row = pl.BlockSpec((1, t, d), lambda *ids: (ids[0], 0, 0))
+    in_specs = [row, kv_row, kv_row]
     operands = [q, k, v]
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, 1, t),
-                                     lambda i: (i // h, 0, 0)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, t), lambda *ids: (head(*ids) // h, 0, 0)))
         operands.append(bias[:, None, :])
     if rate:
-        in_specs.append(pl.BlockSpec((1, 4), lambda i: (0, 0)))
+        in_specs.append(pl.BlockSpec((1, 4), lambda *ids: (0, 0)))
         operands.append(seed2)
     in_specs += [row, vec, vec]
     operands += [do, lse3, delta3]
     if has_glse:
         in_specs.append(vec)
         operands.append(glse3)
-    out_specs = [row, row, row]
+    out_specs = [row, kv_row, kv_row]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype),
                  jax.ShapeDtypeStruct(k.shape, k.dtype),
                  jax.ShapeDtypeStruct(v.shape, v.dtype)]
@@ -597,13 +675,14 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
         out_specs.append(vec)
         out_shape.append(jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
     from jax.experimental.pallas import tpu as pltpu
+    scratch = pltpu.VMEM((t, d), jnp.float32)
     res = pl.pallas_call(
         kernel,
-        grid=(bh,),
+        grid=(bh,) if group == 1 else (bh // group, group),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32)],
+        scratch_shapes=[scratch] * (1 if group == 1 else 3),
         interpret=interpret,
     )(*operands)
     if has_bias:
@@ -626,20 +705,21 @@ FUSED_BLOCK_Q = 512
 FUSED_BLOCK_K = 512
 
 
-def _fused_bwd_resident(t, d, block_k, itemsize):
+def _fused_bwd_resident(t, d, block_k, itemsize, group=1):
     """What a fused-backward instance holds beside its score tiles:
-    q/k/v/do full rows, the f32 dq accumulator and the dk/dv f32
-    blocks (x2 slack for compiler temporaries)."""
+    q/k/v/do full rows, the f32 dq accumulator (and, where ``group``
+    query heads share a K/V head, the dk and dv ones) and the dk/dv
+    f32 blocks (x2 slack for compiler temporaries)."""
     rows = 4 * t * d * itemsize
-    dq_acc = t * d * 4
-    return rows + dq_acc + 2 * 2 * block_k * d * 4 + (1 << 19)
+    accs = (1 if group == 1 else 3) * t * d * 4
+    return rows + accs + 2 * 2 * block_k * d * 4 + (1 << 19)
 
 
-def _fused_bwd_vmem(t, d, block_q, block_k, itemsize):
+def _fused_bwd_vmem(t, d, block_q, block_k, itemsize, group=1):
     """Resident bytes for the fused backward, one tile a trip: each
     tile has two chains (s -> p and dp -> ds), so two of
     common.score_tile_bytes()."""
-    return _fused_bwd_resident(t, d, block_k, itemsize) + \
+    return _fused_bwd_resident(t, d, block_k, itemsize, group) + \
         2 * _common.score_tile_bytes(block_q, block_k)
 
 
@@ -666,16 +746,29 @@ def _rows_resident(t, d, block_q, block_k, itemsize):
         _common.score_tile_bytes(block_q, block_k)
 
 
+def _window_blocks(blocks, window):
+    """A banded call's blocks: the key block no wider than the band
+    (and not under 128), since every key block a query block touches
+    is computed whole and the band is ``window`` keys of it."""
+    block_q, block_k = blocks
+    while window and block_k // 2 >= max(window, 128):
+        block_k //= 2
+    return block_q, block_k
+
+
 def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
-               interpret, rate=0.0):
-    """q,k,v: [BH, T, D], bias: [B, T] or None, seed: packed (1,4)
+               interpret, rate=0.0, window=0):
+    """q: [BH, T, D], k,v: [B*Hkv, T, D] (query head i reads K/V head
+    i // (H / Hkv)), bias: [B, T] or None, seed: packed (1,4)
     uint32 [seed, q_off, k_off, g_off] (_pack_seed, required when
     rate>0) -> (o [BH,T,D], lse [BH,T])."""
     _, t, d = q.shape
     return _fwd_call(
         q, k, v, bias, seed, h=h, causal=causal,
-        blocks=_block_sizes(t, block_q, block_k, d, q.dtype.itemsize),
-        interpret=interpret, rate=rate)
+        blocks=_window_blocks(
+            _block_sizes(t, block_q, block_k, d, q.dtype.itemsize),
+            window),
+        interpret=interpret, rate=rate, window=window)
 
 
 # The calls are jitted on their static arguments: the layers of a model
@@ -687,10 +780,11 @@ def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
 # name of the scope the caller lowered it in (the executor's, the
 # fluid op's type), which is how a device trace is read.
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    'h', 'causal', 'blocks', 'interpret', 'rate'))
+    'h', 'causal', 'blocks', 'interpret', 'rate', 'window'))
 def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
-              rate):
+              rate, window=0):
     bh, t, d = q.shape
+    group = bh // k.shape[0]
     block_q, block_k = blocks
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
@@ -700,12 +794,12 @@ def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
         block_q, block_k, q.dtype.itemsize)
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal, block_k=block_k,
-        tiles=tiles, has_bias=has_bias, rate=rate)
+        tiles=tiles, has_bias=has_bias, rate=rate, window=window)
     grid = (bh, t // block_q)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((1, t, d), lambda i, j: (i // group, 0, 0)),
+        pl.BlockSpec((1, t, d), lambda i, j: (i // group, 0, 0)),
     ]
     operands = [q, k, v]
     if has_bias:
@@ -733,28 +827,30 @@ def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
 
 
 def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
-               block_q, block_k, interpret, rate=0.0):
-    _, t, d = q.shape
-    block_q, block_k = _block_sizes(t, block_q, block_k, d,
-                                    q.dtype.itemsize)
+               block_q, block_k, interpret, rate=0.0, window=0):
+    bh, t, d = q.shape
+    block_q, block_k = _window_blocks(
+        _block_sizes(t, block_q, block_k, d, q.dtype.itemsize), window)
     fq, fk = min(block_q, FUSED_BLOCK_Q), min(block_k, FUSED_BLOCK_K)
     while t % fq:
         fq //= 2
     while t % fk:
         fk //= 2
     fused = FUSED_BWD and _fused_bwd_vmem(
-        t, d, fq, fk, q.dtype.itemsize) <= VMEM_BUDGET_BYTES
+        t, d, fq, fk, q.dtype.itemsize,
+        bh // k.shape[0]) <= VMEM_BUDGET_BYTES
     return _bwd_call(
         q, k, v, bias, seed, o, lse, do, g_lse, h=h, causal=causal,
         blocks=(fq, fk) if fused else (block_q, block_k), fused=fused,
-        interpret=interpret, rate=rate)
+        interpret=interpret, rate=rate, window=window)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    'h', 'causal', 'blocks', 'fused', 'interpret', 'rate'))
+    'h', 'causal', 'blocks', 'fused', 'interpret', 'rate', 'window'))
 def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
-              blocks, fused, interpret, rate):
+              blocks, fused, interpret, rate, window=0):
     bh, t, d = q.shape
+    group = bh // k.shape[0]
     block_q, block_k = blocks
     scale = 1.0 / (d ** 0.5)
     # delta = rowsum(dO * O): one fused elementwise+reduce in XLA
@@ -771,7 +867,7 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
     if fused:
         return _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3,
                                 glse3, h, causal, block_q, block_k,
-                                interpret, rate)
+                                interpret, rate, window)
 
     resident = _rows_resident(t, d, block_q, block_k, q.dtype.itemsize)
     tiles, _ = _second_tile(None if causal else t // block_k, resident,
@@ -779,11 +875,11 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, scale=scale, causal=causal,
         block_k=block_k, tiles=tiles, has_bias=has_bias,
-        has_glse=has_glse, rate=rate)
+        has_glse=has_glse, rate=rate, window=window)
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
+        pl.BlockSpec((1, t, d), lambda i, j: (i // group, 0, 0)),
+        pl.BlockSpec((1, t, d), lambda i, j: (i // group, 0, 0)),
     ]
     dq_operands = [q, k, v]
     if has_bias:
@@ -818,48 +914,53 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, scale=scale, causal=causal,
         block_q=block_q, tiles=tiles, dp_early=dp_early,
-        has_bias=has_bias, has_glse=has_glse, rate=rate)
-    dkv_specs = [
-        pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-    ]
+        has_bias=has_bias, has_glse=has_glse, rate=rate, window=window,
+        group=group)
+
+    # grid (BH, T/bk), or (B*Hkv, T/bk, group): ids[0] is the K/V head
+    def head(*ids):     # the query head of a grid step
+        return ids[0] if group == 1 else ids[0] * group + ids[2]
+
+    q_rows = pl.BlockSpec((1, t, d), lambda *ids: (head(*ids), 0, 0))
+    q_vec = pl.BlockSpec((1, 1, t), lambda *ids: (head(*ids), 0, 0))
+    kv_block = pl.BlockSpec((1, block_k, d),
+                            lambda *ids: (ids[0], ids[1], 0))
+    dkv_specs = [q_rows, kv_block, kv_block]
     dkv_operands = [q, k, v]
     if has_bias:
-        dkv_specs.append(pl.BlockSpec((1, 1, block_k),
-                                      lambda i, j: (i // h, 0, j)))
+        dkv_specs.append(pl.BlockSpec(
+            (1, 1, block_k),
+            lambda *ids: (head(*ids) // h, 0, ids[1])))
         dkv_operands.append(bias[:, None, :])
     if rate:
-        dkv_specs.append(seed_spec)
+        dkv_specs.append(pl.BlockSpec((1, 4), lambda *ids: (0, 0)))
         dkv_operands.append(seed2)
-    dkv_specs += [
-        pl.BlockSpec((1, t, d), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, 1, t), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, 1, t), lambda i, j: (i, 0, 0)),
-    ]
+    dkv_specs += [q_rows, q_vec, q_vec]
     dkv_operands += [do, lse3, delta3]
     if has_glse:
-        dkv_specs.append(pl.BlockSpec((1, 1, t),
-                                      lambda i, j: (i, 0, 0)))
+        dkv_specs.append(q_vec)
         dkv_operands.append(glse3)
-    out_specs = [
-        pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-    ]
+    out_specs = [kv_block, kv_block]
     out_shape = [
         jax.ShapeDtypeStruct(k.shape, k.dtype),
         jax.ShapeDtypeStruct(v.shape, v.dtype),
     ]
     if has_bias:
-        out_specs.append(pl.BlockSpec((1, 1, block_k),
-                                      lambda i, j: (i, 0, j)))
+        out_specs.append(pl.BlockSpec(
+            (1, 1, block_k), lambda *ids: (head(*ids), 0, ids[1])))
         out_shape.append(jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
+    scratch = []
+    if group > 1:
+        from jax.experimental.pallas import tpu as pltpu
+        scratch = [pltpu.VMEM((block_k, d), jnp.float32)] * 2
     res = pl.pallas_call(
         dkv_kernel,
-        grid=(bh, t // block_k),
+        grid=(bh, t // block_k) if group == 1
+        else (bh // group, t // block_k, group),
         in_specs=dkv_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
     )(*dkv_operands)
     if has_bias:
@@ -918,27 +1019,29 @@ def _flash_lse_bwd_rule(h, causal, rate, interpret, res, gs):
 _flash_lse.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash(q, k, v, bias, seed, h, causal, rate, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash(q, k, v, bias, seed, h, causal, rate, interpret, window=0):
     # o-only primitive with its OWN vjp so the common (non-ring) path
     # never ships a zeros g_lse operand into the backward kernels
     o, _ = _flash_fwd(q, k, v, bias, seed, h, causal, DEFAULT_BLOCK_Q,
-                      DEFAULT_BLOCK_K, interpret, rate)
+                      DEFAULT_BLOCK_K, interpret, rate, window)
     return o
 
 
-def _flash_fwd_rule(q, k, v, bias, seed, h, causal, rate, interpret):
+def _flash_fwd_rule(q, k, v, bias, seed, h, causal, rate, interpret,
+                    window):
     o, lse = _flash_fwd(q, k, v, bias, seed, h, causal,
                         DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret,
-                        rate)
+                        rate, window)
     return o, (q, k, v, bias, seed, o, lse)
 
 
-def _flash_bwd_rule(h, causal, rate, interpret, res, g):
+def _flash_bwd_rule(h, causal, rate, interpret, window, res, g):
     q, k, v, bias, seed, o, lse = res
     dq, dk, dv, dbias = _flash_bwd(q, k, v, bias, seed, o, lse, g,
                                    None, h, causal, DEFAULT_BLOCK_Q,
-                                   DEFAULT_BLOCK_K, interpret, rate)
+                                   DEFAULT_BLOCK_K, interpret, rate,
+                                   window)
     return dq, dk, dv, (None if bias is None
                         else dbias.astype(bias.dtype)), None
 
@@ -948,7 +1051,7 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
                 dropout_seed=None, dropout_offsets=None,
-                dropout_g_offset=0, with_lse=False):
+                dropout_g_offset=0, with_lse=False, window=0):
     """Fused-by-XLA dense chain on [B, T, H, D] (bf16 dots, f32
     softmax) — the measured winner below FLASH_MIN_SEQ, where the
     whole chain fits VMEM outright.  Differentiable via XLA autodiff.
@@ -956,8 +1059,12 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
     the two dispatch arms are bit-identical stochastic functions of
     (seed, element position).  ``with_lse`` also returns the per-row
     log-sum-exp [B, H, T] of the undropped scores (the
-    flash_attention_with_lse contract)."""
+    flash_attention_with_lse contract).  K/V of fewer heads than q
+    are repeated over their group here (the kernels read them through
+    their index maps instead); ``window`` bands the causal mask."""
     b, t, h, d = q.shape
+    if k.shape[2] != h:
+        k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
     s = jnp.einsum('bthd,bshd->bhts', q, k,
                    precision=_precision(q.dtype),
                    preferred_element_type=jnp.float32) / (d ** 0.5)
@@ -965,6 +1072,8 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
         s = s + key_bias.astype(jnp.float32)[:, None, None, :]
     if causal:
         mask = jnp.tril(jnp.ones((t, t), bool))
+        if window:
+            mask = mask & ~jnp.tril(jnp.ones((t, t), bool), -window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     if dropout_rate:
@@ -983,12 +1092,30 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
     return o
 
 
+def _check_mask_and_heads(q, k, v, causal, window):
+    h, hkv = q.shape[2], k.shape[2]
+    if k.shape != v.shape or hkv < 1 or h % hkv:
+        raise ValueError(
+            'attention: K and V must have one shape and a head count '
+            'that divides Q\'s; got Q %r, K %r, V %r'
+            % (q.shape, k.shape, v.shape))
+    if window and not causal:
+        raise ValueError('attention: a window (%r) bands the causal '
+                         'mask; causal is False' % (window,))
+    return int(window or 0)
+
+
 def flash_attention(q, k, v, causal=False, key_bias=None,
                     min_seq=None, dropout_rate=0.0, dropout_seed=None,
                     dropout_offsets=None, dropout_g_offset=0,
-                    auto_partitioned=False):
-    """q,k,v: [B, T, H, D]; key_bias: optional [B, T] additive score
-    bias (e.g. padding mask as 0 / -10000) -> [B, T, H, D].
+                    auto_partitioned=False, window=0):
+    """q: [B, T, H, D]; k,v: [B, T, Hkv, D] with Hkv a divisor of H
+    (grouped K/V: query head i attends K/V head i // (H / Hkv));
+    key_bias: optional [B, T] additive score bias (e.g. padding mask
+    as 0 / -10000) -> [B, T, H, D].  ``window`` > 0 (with ``causal``)
+    bands the mask: query i sees the keys j with 0 <= i - j < window,
+    and the kernels skip the blocks wholly outside the band as they
+    skip those above the diagonal.
 
     dropout_rate > 0 applies dropout to the attention probabilities
     INSIDE the kernels (reference default: dropout around softmax,
@@ -1007,6 +1134,7 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     trace; see common.dispatch()).  Pass min_seq=0 to drop the floor
     (benchmark sweeps)."""
     b, t, h, d = q.shape
+    window = _check_mask_and_heads(q, k, v, causal, window)
     if min_seq is None:
         min_seq = FLASH_MIN_SEQ
     rate = float(dropout_rate or 0.0)
@@ -1018,17 +1146,17 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     if not fused:
         return _dense_path(q, k, v, causal, key_bias, rate,
                            dropout_seed, dropout_offsets,
-                           dropout_g_offset)
+                           dropout_g_offset, window=window)
 
     def to_bh(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(-1, t, d)
 
     if key_bias is not None:
         key_bias = key_bias.astype(jnp.float32)
     seed = _pack_seed(dropout_seed, dropout_offsets,
                       dropout_g_offset) if rate else None
     out = _flash(to_bh(q), to_bh(k), to_bh(v), key_bias, seed, h,
-                 causal, rate, interpret)
+                 causal, rate, interpret, window)
     return jnp.transpose(out.reshape(b, h, t, d), (0, 2, 1, 3))
 
 

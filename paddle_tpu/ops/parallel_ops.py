@@ -248,46 +248,77 @@ def _no_expert_axis(attrs):
             'dropless MoE routing (capacity_factor=None) over an %r '
             'mesh axis is not implemented: experts sharded over chips '
             'need a ragged all_to_all, which the olmoe_1b7b_s4096_ep4 '
-            'benchmark cell will force; use the capacity-based path '
+            'benchmark cell will force (experts_held gives one chip '
+            'its share of a layer, without the exchange); use the '
+            'capacity-based path '
             '(capacity_factor=2.0, top_k <= 2) under expert parallelism'
             % (axis,))
 
 
-@register('moe_route', no_grad_out_slots=('TopKIdx', 'Load'))
+def _held(attrs):
+    """(first, count) of the experts this layer holds, or None for
+    all of them."""
+    held = attrs.get('experts_held')
+    return None if not held else (int(held[0]), int(held[1]))
+
+
+@register('moe_route',
+          no_grad_out_slots=('TopKIdx', 'Load', 'HeldLoad'))
 def moe_route_op(ctx, ins, attrs):
     """X [..., D], Gate [D, E] -> TopKIdx [S, k] int32, TopKWeight
     [S, k] f32, AuxLoss [] (load-balance), ZLoss [] (router z-loss),
     Load [E] int32 ((token, expert) pairs per expert: the group sizes
     the grouped matmuls are handed).  All float32 whatever X is: the
     router is the part of a routed model that does not survive
-    bfloat16.  attrs: top_k, renormalize."""
+    bfloat16.  attrs: top_k, renormalize, scale (a factor on the
+    gates), experts_held ((first, count): the router stays E wide, and
+    HeldLoad [count] is Load's slice of the held experts, the group
+    sizes of a layer that holds only those)."""
     from ..parallel.moe import route_topk
     _no_expert_axis(attrs)
     x, wg = ins['X'][0], ins['Gate'][0]
     idx, weight, balance, z, load = route_topk(
         x.reshape(-1, x.shape[-1]), wg, int(attrs['top_k']),
-        bool(attrs.get('renormalize', False)))
-    return {'TopKIdx': [idx], 'TopKWeight': [weight],
+        bool(attrs.get('renormalize', False)),
+        float(attrs.get('scale', 1.0)))
+    outs = {'TopKIdx': [idx], 'TopKWeight': [weight],
             'AuxLoss': [balance], 'ZLoss': [z], 'Load': [load]}
+    held = _held(attrs)
+    if held is not None:
+        outs['HeldLoad'] = [load[held[0]:held[0] + held[1]]]
+    return outs
 
 
 @register('moe_dispatch',
           no_grad_out_slots=('Order', 'Inverse', 'Dropped'))
 def moe_dispatch_op(ctx, ins, attrs):
-    """X [..., D], TopKIdx [S, k], GroupSizes [E] (moe_route's Load)
-    -> Rows [S*k, D] grouped by expert, Order / Inverse [S*k] int32
-    (the permutation and its inverse), Dropped [1] int32: the rows
-    that sit outside the group of the expert their token picked, given
-    the sizes moe_experts is handed (0 unless the grouping is broken;
-    computed only where something reads it)."""
-    from ..parallel.moe import (dispatch_rows, rows_outside_their_group,
+    """X [..., D], TopKIdx [S, k], GroupSizes (moe_route's Load, or
+    its HeldLoad under attrs['experts_held']) -> Rows [R, D] grouped
+    by expert, Order [R] / Inverse [S*k] int32 (the permutation and
+    its inverse), Dropped [1] int32: the rows that sit outside the
+    group of the expert their token picked, given the sizes
+    moe_experts is handed (0 unless the grouping is broken; computed
+    only where something reads it).  R is S*k, or with a held range
+    S * min(k, count) (parallel.moe.held_rows_bound): the held
+    experts' rows come first and fill it at most; the pairs routed to
+    absent experts lie past the last group, are not computed and are
+    no drops."""
+    from ..parallel.moe import (dispatch_rows, held_rows_bound,
+                                rows_outside_their_group,
                                 sort_by_expert)
     x, idx = ins['X'][0], ins['TopKIdx'][0]
-    order, inverse = sort_by_expert(idx)
-    rows = dispatch_rows(x.reshape(-1, x.shape[-1]), order, inverse,
-                         int(idx.shape[-1]))
-    dropped = rows_outside_their_group(idx, order, ins['GroupSizes'][0])
-    return {'Rows': [rows], 'Order': [order], 'Inverse': [inverse],
+    held = _held(attrs)
+    order, inverse = sort_by_expert(idx, held)
+    top_k = int(idx.shape[-1])
+    sizes = ins['GroupSizes'][0]
+    kept, held_rows = order, None
+    if held is not None:
+        kept = order[:held_rows_bound(idx.shape[0], top_k, held)]
+        held_rows = jnp.sum(sizes)
+    rows = dispatch_rows(x.reshape(-1, x.shape[-1]), kept, inverse,
+                         top_k, held_rows)
+    dropped = rows_outside_their_group(idx, order, sizes, held)
+    return {'Rows': [rows], 'Order': [kept], 'Inverse': [inverse],
             'Dropped': [dropped.reshape(1)]}
 
 
@@ -299,22 +330,33 @@ def moe_experts_op(ctx, ins, attrs):
     bfloat16 under AMP (white-listed).  The TPU compiler turns each
     ``lax.ragged_dot`` into Mosaic calls whose whole op_name is its own
     (``ragged-dot-none``, ``ragged-dot-metadata``), forward and
-    backward alike."""
+    backward alike.  In a layer that holds a range of the experts
+    (attrs['experts_held']) most of the buffer lies past the last
+    group: the three [M, H] intermediates are not kept for the
+    backward pass but computed again there (the grouped matmuls skip
+    the rows past the groups; keeping them costs M, not the rows
+    held)."""
     from ..parallel.moe import grouped_gated_mlp
     rows = ins['Rows'][0]
     low = bool(attrs.get('__amp__')) and \
         rows.dtype in (jnp.float32, jnp.bfloat16)
-    return {'Out': [grouped_gated_mlp(
-        rows, ins['GroupSizes'][0], ins['WGate'][0], ins['WUp'][0],
-        ins['WDown'][0], low_precision=low)]}
+    mlp = functools.partial(grouped_gated_mlp, low_precision=low)
+    if _held(attrs) is not None:
+        mlp = jax.checkpoint(mlp)
+    return {'Out': [mlp(rows, ins['GroupSizes'][0], ins['WGate'][0],
+                        ins['WUp'][0], ins['WDown'][0])]}
 
 
 @register('moe_combine')
 def moe_combine_op(ctx, ins, attrs):
-    """Rows [S*k, D] expert outputs, TopKWeight [S, k], Order /
+    """Rows [R, D] expert outputs, TopKWeight [S, k], Order /
     Inverse -> Out [S, D]: each token's k outputs weighted and summed
-    in float32, emitted in Rows' dtype."""
+    in float32, emitted in Rows' dtype.  With GroupSizes (a layer that
+    holds a range of the experts) only the rows inside the groups
+    count: a pair routed to an absent expert adds nothing."""
     from ..parallel.moe import combine_rows
+    held_rows = jnp.sum(ins['GroupSizes'][0]) \
+        if ins.get('GroupSizes') else None
     return {'Out': [combine_rows(
         ins['Rows'][0], ins['TopKWeight'][0].astype(jnp.float32),
-        ins['Order'][0], ins['Inverse'][0])]}
+        ins['Order'][0], ins['Inverse'][0], held_rows)]}

@@ -178,15 +178,17 @@ def reference_moe_ffn(x, wg, w1_full, w2_full, capacity_factor=2.0,
 # ---------------------------------------------------------------------------
 
 
-def route_topk(x, wg, top_k, renormalize=False):
+def route_topk(x, wg, top_k, renormalize=False, scale=1.0):
     """The router, in float32 whatever ``x`` is.  x [S, D], wg [D, E] ->
     (idx [S, k] int32, weight [S, k] f32, balance loss, z-loss,
     load [E] int32).
 
     ``weight`` are the top-k of the softmax over ALL experts, divided
     by their sum only under ``renormalize`` (OLMoE publishes
-    ``norm_topk_prob: false``).  Balance loss: E * sum_e f_e * P_e with
-    f_e the share of tokens that picked e among their k (sums to k)
+    ``norm_topk_prob: false``), times ``scale`` (a routed scaling
+    factor; 1.0 multiplies nothing).  Balance loss: E * sum_e f_e *
+    P_e with f_e the share of tokens that picked e among their k (sums
+    to k)
     and P_e the mean router probability.  z-loss: mean over tokens of
     logsumexp(logits)^2.  ``load`` counts the (token, expert) pairs of
     each expert and sums to S*k."""
@@ -198,6 +200,8 @@ def route_topk(x, wg, top_k, renormalize=False):
     weight, idx = jax.lax.top_k(probs, top_k)
     if renormalize:
         weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weight = weight * scale
     picked = jnp.sum(jax.nn.one_hot(idx, n_experts, dtype=jnp.int32),
                      axis=1)                                # [S, E]
     load = jnp.sum(picked, axis=0)
@@ -207,12 +211,36 @@ def route_topk(x, wg, top_k, renormalize=False):
             jnp.mean(jnp.square(lse)), load.astype(jnp.int32))
 
 
-def sort_by_expert(idx):
+def held_rows_bound(tokens, top_k, held=None):
+    """The static row count of the sorted buffer the grouped matmuls
+    are handed: every (token, expert) pair where all experts are held;
+    with a held range (first, count) the most pairs that can name a
+    held expert, ``tokens * min(top_k, count)`` (a token picks an
+    expert at most once), so that nothing held is ever cut."""
+    return tokens * (top_k if held is None else min(top_k, held[1]))
+
+
+def sort_keys(idx, held=None):
+    """idx [S, k] expert ids -> [S*k] int32 keys to sort the pairs by:
+    the id itself, or with a held range (first, count) the expert's
+    index among the held ones and ``count`` for every absent expert,
+    so that the held experts' rows come first, grouped, and what the
+    absent ones would compute lies past the last group."""
+    flat = idx.reshape(-1)
+    if held is None:
+        return flat
+    first, count = held
+    local = flat - first
+    return jnp.where((local >= 0) & (local < count), local, count)
+
+
+def sort_by_expert(idx, held=None):
     """idx [S, k] -> (order [S*k], inverse [S*k]) int32: ``order`` lists
     the flat (token, choice) pairs grouped by expert (stable, so a
-    group keeps token order), ``inverse`` undoes it."""
-    flat = idx.reshape(-1)
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    group keeps token order; sort_keys() says where a held range puts
+    the absent experts' pairs), ``inverse`` undoes it."""
+    order = jnp.argsort(sort_keys(idx, held), stable=True).astype(
+        jnp.int32)
     # lax.iota, not jnp.arange: shape inference runs this with a huge
     # stand-in for a dynamic batch, past what arange's bound check takes
     inverse = jnp.zeros_like(order).at[order].set(
@@ -220,14 +248,15 @@ def sort_by_expert(idx):
     return order, inverse
 
 
-def rows_outside_their_group(idx, order, group_sizes):
+def rows_outside_their_group(idx, order, group_sizes, held=None):
     """How many of the S*k sorted rows the grouped matmuls hand to
     another expert than the router picked, or to none: 0 when the sort
     and ``group_sizes`` agree.  Row j holds pair ``order[j]``, whose
     expert is ``idx.flat[order[j]]``; ``ragged_dot`` gives row j to the
     group whose running total of ``group_sizes`` first passes j, and to
-    no group (a row of zeros) past their sum."""
-    picked = idx.reshape(-1)[order]
+    no group (a row of zeros) past their sum.  With a held range a pair
+    routed to an absent expert belongs to no group and is no drop."""
+    picked = sort_keys(idx, held)[order]
     row = jax.lax.iota(jnp.int32, order.shape[0])
     # compare_all: one [S*k, E] comparison; the default's binary search
     # is a gather per step, which a TPU does slowly
@@ -237,49 +266,79 @@ def rows_outside_their_group(idx, order, group_sizes):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def dispatch_rows(x, order, inverse, top_k):
-    """x [S, D] -> rows [S*k, D] in expert order (row j is token
-    order[j] // k)."""
+def dispatch_rows(x, order, inverse, top_k, held_rows=None):
+    """x [S, D] -> rows [len(order), D] in expert order (row j is token
+    order[j] // k).  ``order`` may be the first R entries of the sort
+    only (held_rows_bound); ``inverse`` is always the whole one.  With
+    ``held_rows`` (an int32 scalar: the held experts' rows, the first
+    of the buffer) the rows past them take no gradient back to x,
+    whatever their cotangent holds: on the chip the grouped matmuls
+    leave the rows past their last group UNWRITTEN, in the forward
+    pass and in the gradient they hand back alike."""
     return x[order // top_k]
 
 
-def _dispatch_fwd(x, order, inverse, top_k):
-    return dispatch_rows(x, order, inverse, top_k), (order, inverse)
+def _dispatch_fwd(x, order, inverse, top_k, held_rows=None):
+    return dispatch_rows(x, order, inverse, top_k, held_rows), \
+        (inverse, held_rows)
 
 
 def _dispatch_bwd(top_k, res, g):
-    order, inverse = res
-    s = g.shape[0] // top_k
-    dx = jnp.sum(g[inverse].reshape(s, top_k, -1).astype(jnp.float32),
+    inverse, held_rows = res
+    s = inverse.shape[0] // top_k
+    picked = _rows_of_pairs(g, inverse, held_rows)
+    dx = jnp.sum(picked.reshape(s, top_k, -1).astype(jnp.float32),
                  axis=1)
-    return dx.astype(g.dtype), None, None
+    return dx.astype(g.dtype), None, None, None
 
 
 dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
+def _rows_of_pairs(y, inverse, held_rows=None):
+    """y [R, D] sorted rows -> [S*k, D], pair p's row y[inverse[p]].
+    A pair whose row lies past the buffer (R < S*k) or, given
+    ``held_rows``, past the rows of the held experts reads zeros."""
+    n = y.shape[0]
+    if n == inverse.shape[0] and held_rows is None:
+        return y[inverse]
+    limit = n if held_rows is None else jnp.minimum(held_rows, n)
+    return jnp.where((inverse < limit)[:, None],
+                     y[jnp.minimum(inverse, n - 1)], 0)
+
+
 @jax.custom_vjp
-def combine_rows(y, weight, order, inverse):
-    """y [S*k, D] expert-ordered outputs, weight [S, k] f32 ->
-    [S, D]: each token's k outputs, weighted, summed in f32."""
+def combine_rows(y, weight, order, inverse, held_rows=None):
+    """y [R, D] expert-ordered outputs, weight [S, k] f32 ->
+    [S, D]: each token's k outputs, weighted, summed in f32.  With
+    ``held_rows`` (an int32 scalar: the held experts' rows, the first
+    of the buffer) only those rows count: what lies past them, and the
+    pairs whose row is not in the buffer at all, add nothing and get
+    no gradient, whatever the buffer holds there."""
     s, k = weight.shape
-    picked = y[inverse].reshape(s, k, -1).astype(jnp.float32)
+    picked = _rows_of_pairs(y, inverse, held_rows).reshape(
+        s, k, -1).astype(jnp.float32)
     return jnp.sum(picked * weight[:, :, None], axis=1).astype(y.dtype)
 
 
-def _combine_fwd(y, weight, order, inverse):
-    return combine_rows(y, weight, order, inverse), \
-        (y, weight, order, inverse)
+def _combine_fwd(y, weight, order, inverse, held_rows=None):
+    return combine_rows(y, weight, order, inverse, held_rows), \
+        (y, weight, order, inverse, held_rows)
 
 
 def _combine_bwd(res, g):
-    y, weight, order, inverse = res
+    y, weight, order, inverse, held_rows = res
     s, k = weight.shape
     gf = g.astype(jnp.float32)
     dy = (gf[:, None, :] * weight[:, :, None]).astype(y.dtype)
-    picked = y[inverse].reshape(s, k, -1).astype(jnp.float32)
+    dy = dy.reshape(s * k, -1)[order[:y.shape[0]]]
+    if held_rows is not None:
+        row = jax.lax.iota(jnp.int32, y.shape[0])
+        dy = jnp.where((row < held_rows)[:, None], dy, 0)
+    picked = _rows_of_pairs(y, inverse, held_rows).reshape(
+        s, k, -1).astype(jnp.float32)
     dweight = jnp.sum(picked * gf[:, None, :], axis=-1)
-    return dy.reshape(s * k, -1)[order], dweight, None, None
+    return dy, dweight, None, None, None
 
 
 combine_rows.defvjp(_combine_fwd, _combine_bwd)

@@ -141,6 +141,44 @@ def test_causal_flash_at_the_olmoe_cell_shape(one_chip, as_on_tpu, dtype):
     assert n == 3, n
 
 
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize('heads,window', [(72, 512), (48, 0)],
+                         ids=['sliding_72', 'full_48'])
+def test_banded_grouped_flash_at_the_laguna_cell_shapes(
+        one_chip, as_on_tpu, heads, window, dtype):
+    """laguna_s21_s4096: b1 t4096 d128, 72 (banded, window 512) or 48
+    (full causal) query heads over 8 K/V heads.  The two-pass backward
+    at d128: the dK/dV kernel walks a K/V head's group of query heads
+    on a third grid axis and sums into two f32 VMEM blocks; the banded
+    calls take 512-wide key blocks.  bfloat16 is the timed step,
+    float32 the reference check and ``chip_smoke.py --phase laguna``.
+    The windowed calls carry the scope the op lowers them in."""
+    import contextlib
+    import re
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            with jax.named_scope('fused_multihead_attention'):
+                with jax.named_scope('window%d' % window) if window \
+                        else contextlib.nullcontext():
+                    o = flash_attention.flash_attention(
+                        q, k, v, causal=True, window=window)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    assert flash_attention._window_blocks((512, 1024), window) == \
+        ((512, 512) if window else (512, 1024))
+    text = _compiled(step, one_chip, _spec((1, 4096, heads, 128), dtype),
+                     _spec((1, 4096, 8, 128), dtype),
+                     _spec((1, 4096, 8, 128), dtype)).as_text()
+    _compiled_on_chip('flash_attention')
+    names = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert len(names) == 3, names
+    assert all(n.startswith('window512') == bool(window)
+               for n in names), names
+
+
 @pytest.mark.parametrize('dtype,b,t,h,d,fused', [
     # what the compiler asks for moves with the grid, so the cells'
     # own: bert_base_s2048's fused backward, two [512, 512] tiles a trip
