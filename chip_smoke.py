@@ -728,14 +728,26 @@ def phase_laguna_gradients(seq=4096, seed=0, rows=64):
             if op.type == 'moe_route'])[1:]
         say('laguna f32 train program, 1 x %d tokens: loss %.6f in %.1f s '
             '(with compile); moe/held_share %.4f, moe/rows_held %d of '
-            '%d routed in four layers'
+            '%d routed in four layers, moe/held_rows_max %d, '
+            'moe/prefix_overflows %d, moe/dropped_tokens %d'
             % (seq, got_loss, time.time() - t0,
                monitor.gauge_value('moe/held_share'),
                monitor.counter_value('moe/rows_held'),
-               monitor.counter_value('moe/tokens_routed')))
+               monitor.counter_value('moe/tokens_routed'),
+               monitor.gauge_value('moe/held_rows_max'),
+               monitor.counter_value('moe/prefix_overflows'),
+               monitor.counter_value('moe/dropped_tokens')))
         check(monitor.counter_value('moe/dropped_tokens') == 0 and
               monitor.counter_value('moe/rows_held') > 0,
               'rows were held and moe/dropped_tokens stayed 0')
+        # the gradients below are the prefix arm's: no layer overflowed
+        from paddle_tpu.parallel.moe import held_rows_prefix
+        prefix = held_rows_prefix(seq, cfg.top_k, cfg.experts_held,
+                                  cfg.experts)
+        check(monitor.counter_value('moe/prefix_overflows') == 0 and
+              2 * monitor.gauge_value('moe/held_rows_max') <= prefix,
+              'no layer held more than half its prefix of %d rows'
+              % prefix)
         # the held experts' loads of layer 1, from the reference below
         held_grads = got[1:]
         del got

@@ -196,8 +196,10 @@ def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
     if held is not None:
         # the groups the matmuls are handed: the held experts' loads
         sizes = route_outs['HeldLoad'] = var('int32', True)
-        held_attrs = {'experts_held': list(held)}
-        route_attrs.update(held_attrs)
+        route_attrs['experts_held'] = list(held)
+        # the router's width tells the permutation how small a share
+        # of its buffer is held (parallel.moe.held_rows_prefix)
+        held_attrs = {'experts_held': list(held), 'num_experts': e}
     helper.append_op('moe_route', inputs={'X': x, 'Gate': wg},
                      outputs=route_outs, attrs=route_attrs)
     # rows = tokens x top_k: with a dynamic batch, shape inference's
@@ -223,7 +225,8 @@ def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
     if held is not None:
         combine_ins['GroupSizes'] = sizes
     helper.append_op('moe_combine', inputs=combine_ins,
-                     outputs={'Out': flat}, infer_shape=False)
+                     outputs={'Out': flat}, attrs=held_attrs,
+                     infer_shape=False)
     from ...ops.registry import _DYN_SENTINEL
     # a dynamic batch counts as inference's stand-in, products literal
     # (registry.infer_shapes does the same for layer_norm's row count)
@@ -252,9 +255,11 @@ def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
     helper.main_program.watch([load.name, dropped.name],
                               moe_stats.record)
     if held is not None:
-        # moe/rows_held, moe/held_share
-        helper.main_program.watch([load.name, sizes.name],
-                                  moe_stats.record_held)
+        # moe/rows_held, moe/held_share, moe/held_rows_max,
+        # moe/prefix_overflows
+        held_layers = moe_stats.HeldLayers.of(helper.main_program)
+        held_layers.top_k.append(top_k)
+        helper.main_program.watch([load.name, sizes.name], held_layers)
     aux = scaled(balance, aux_weight)
     if z_loss_weight:
         total = var('float32')

@@ -25,7 +25,14 @@ reports, on the same runs:
   absent expert is no drop: ``moe/dropped_tokens`` stays 0;
 - gauge ``moe/held_share``: rows held over rows routed, all such
   layers of the last run read together (held / all experts where the
-  routing is even).
+  routing is even);
+- gauge ``moe/held_rows_max``: the most rows any such layer held on
+  the last run read;
+- counter ``moe/prefix_overflows``: the layers, on the runs read, that
+  held more rows than the prefix of their buffer the permutation walks
+  (``parallel.moe.held_rows_prefix``), so that its whole-buffer arm
+  ran: slower, never different.  0 as long as the routing stays
+  within a few times an even one.
 
 Under ``with_data_parallel`` the values are the whole batch's; under
 the collective (shard_map) runner they are the first device's share.
@@ -49,12 +56,40 @@ def record(values):
     monitor.set_gauge('moe/load_max_over_mean', worst)
 
 
-def record_held(values):
+class HeldLayers(object):
+    """The record of a program's layers that hold a range of their
+    experts.  ``Program.watch`` hands a record the fetched values and
+    nothing else, and a layer's prefix also takes its ``top_k``: each
+    layer leaves it here as it asks to be watched."""
+
+    def __init__(self):
+        self.top_k = []
+
+    @classmethod
+    def of(cls, program):
+        """The program's one, or a new one."""
+        return next((r for r in program._watched if isinstance(r, cls)),
+                    None) or cls()
+
+    def __call__(self, values):
+        record_held(values, self.top_k)
+
+
+def record_held(values, top_k):
     """``values``: load [E], held load [count], ... one pair a layer
-    that holds a range of its experts, as fetched."""
-    routed = held = 0.0
-    for load, mine in zip(values[0::2], values[1::2]):
-        routed += float(np.asarray(load, np.int64).sum())
-        held += float(np.asarray(mine, np.int64).sum())
-    monitor.add('moe/rows_held', held)
+    that holds a range of its experts, as fetched; ``top_k``: one a
+    layer."""
+    from ..parallel.moe import held_rows_prefix
+    routed = held = most = over = 0
+    for load, mine, k in zip(values[0::2], values[1::2], top_k):
+        pairs = int(np.asarray(load, np.int64).sum())
+        rows = int(np.asarray(mine, np.int64).sum())
+        routed += pairs
+        held += rows
+        most = max(most, rows)
+        over += rows > held_rows_prefix(pairs // k, k, (0, len(mine)),
+                                        len(load))
+    monitor.add('moe/rows_held', float(held))
+    monitor.add('moe/prefix_overflows', float(over))
     monitor.set_gauge('moe/held_share', held / max(routed, 1.0))
+    monitor.set_gauge('moe/held_rows_max', float(most))

@@ -18,7 +18,11 @@ of the S*k (token, expert) pairs by expert, a row gather, one grouped
 (ragged) matmul per expert matrix and a weighted sum back.  Shapes stay
 static (S*k rows whatever the routing), no token is dropped, and every
 backward is again a gather: the sort is a permutation, so its inverse
-replaces the scatter-add.
+replaces the scatter-add.  A layer that holds a RANGE of its experts
+(one chip's share of an expert-parallel layer) fills a small part of
+that buffer; there the weighted sum back and both backward bodies walk
+a static prefix of the buffer's rows instead of every pair, and the
+per-token sums are scatter-adds of those few rows (held_rows_prefix).
 """
 
 import functools
@@ -265,8 +269,63 @@ def rows_outside_their_group(idx, order, group_sizes, held=None):
     return jnp.sum((given != picked).astype(jnp.int32))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def dispatch_rows(x, order, inverse, top_k, held_rows=None):
+# A layer that holds a range of its experts fills a small part of its
+# worst-case buffer (held_rows_bound): 8 of 256 experts, top-10, hold
+# about 1/26 of it.  held_rows_prefix() is how many of its first rows
+# the permutation walks there, PREFIX_OVER_EVEN times what an even
+# routing holds.  Set from the loads of the laguna_s21_s4096 cell so
+# that no layer on any seed held more than half its prefix: its router
+# learns to pick the held experts (only their outputs reach the loss),
+# and one layer came to hold 5.5 times an even share within a run
+# (PERF.md section 6, PR 31).
+PREFIX_OVER_EVEN = 12
+_PREFIX_STEP = 512
+
+
+def held_rows_prefix(tokens, top_k, held, n_experts):
+    """How many of the buffer's first rows dispatch_rows / combine_rows
+    walk where a layer holds the range ``held`` = (first, count) of its
+    ``n_experts``: PREFIX_OVER_EVEN times the rows an even routing
+    holds, in whole steps of 512, and the buffer's bound at most.  A
+    function of static shapes only, so the step's time does not follow
+    the routing; a routing that holds more takes the whole-buffer arm
+    (slower, never different)."""
+    rows = PREFIX_OVER_EVEN * tokens * top_k * held[1]
+    steps = -(-rows // (n_experts * _PREFIX_STEP))
+    return min(held_rows_bound(tokens, top_k, held), steps * _PREFIX_STEP)
+
+
+def _walk(row_side, n_rows, held_rows, prefix):
+    """``row_side(n)``, a body over the buffer's first n rows, for a
+    layer that holds a range of its experts: n is the static
+    ``prefix`` while the held rows (a device scalar) fit it, else the
+    whole buffer: slower, never different, and no row is ever cut.
+    One body and no conditional where the prefix is the buffer."""
+    if prefix is None or prefix >= n_rows:
+        return row_side(n_rows)
+    return jax.lax.cond(held_rows <= prefix, lambda: row_side(prefix),
+                        lambda: row_side(n_rows))
+
+
+def _live_rows(order, top_k, held_rows, n):
+    """The first ``n`` rows of the buffer: (pair, token, live) with
+    row j pair ``order[j]`` of token ``order[j] // top_k``, live while
+    it is a held expert's."""
+    pair = order[:n]
+    return pair, pair // top_k, \
+        jax.lax.iota(jnp.int32, n) < held_rows
+
+
+def _sum_per_token(rows, token, live, tokens):
+    """rows [n, D] f32 -> [tokens, D] f32: each token's live rows
+    summed.  XLA's scatter-add into zeros, one row at a time in the
+    buffer's order: the same bits every run."""
+    return jax.ops.segment_sum(jnp.where(live[:, None], rows, 0), token,
+                               num_segments=tokens)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 5))
+def dispatch_rows(x, order, inverse, top_k, held_rows=None, prefix=None):
     """x [S, D] -> rows [len(order), D] in expert order (row j is token
     order[j] // k).  ``order`` may be the first R entries of the sort
     only (held_rows_bound); ``inverse`` is always the whole one.  With
@@ -274,70 +333,97 @@ def dispatch_rows(x, order, inverse, top_k, held_rows=None):
     of the buffer) the rows past them take no gradient back to x,
     whatever their cotangent holds: on the chip the grouped matmuls
     leave the rows past their last group UNWRITTEN, in the forward
-    pass and in the gradient they hand back alike."""
+    pass and in the gradient they hand back alike.  That gradient is
+    then summed row-side, over the first ``prefix`` rows (static,
+    held_rows_prefix) while the held rows fit them.  The forward
+    gather is the same either way: it is bound by writing the buffer,
+    which a fill costs too (on the chip 0.34 ms for 32,768 rows of
+    3072 against 0.39 for 7,680 and zeros: PERF.md section 6,
+    PR 31)."""
     return x[order // top_k]
 
 
-def _dispatch_fwd(x, order, inverse, top_k, held_rows=None):
-    return dispatch_rows(x, order, inverse, top_k, held_rows), \
-        (inverse, held_rows)
+def _dispatch_fwd(x, order, inverse, top_k, held_rows=None, prefix=None):
+    return dispatch_rows(x, order, inverse, top_k, held_rows, prefix), \
+        (order, inverse, held_rows)
 
 
-def _dispatch_bwd(top_k, res, g):
-    inverse, held_rows = res
+def _dispatch_bwd(top_k, prefix, res, g):
+    order, inverse, held_rows = res
     s = inverse.shape[0] // top_k
-    picked = _rows_of_pairs(g, inverse, held_rows)
-    dx = jnp.sum(picked.reshape(s, top_k, -1).astype(jnp.float32),
-                 axis=1)
+    if held_rows is None:
+        dx = jnp.sum(g[inverse].reshape(s, top_k, -1).astype(
+            jnp.float32), axis=1)
+        return dx.astype(g.dtype), None, None, None
+
+    def row_side(n):
+        _, token, live = _live_rows(order, top_k, held_rows, n)
+        return _sum_per_token(g[:n].astype(jnp.float32), token, live, s)
+
+    dx = _walk(row_side, g.shape[0], held_rows, prefix)
     return dx.astype(g.dtype), None, None, None
 
 
 dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def _rows_of_pairs(y, inverse, held_rows=None):
-    """y [R, D] sorted rows -> [S*k, D], pair p's row y[inverse[p]].
-    A pair whose row lies past the buffer (R < S*k) or, given
-    ``held_rows``, past the rows of the held experts reads zeros."""
-    n = y.shape[0]
-    if n == inverse.shape[0] and held_rows is None:
-        return y[inverse]
-    limit = n if held_rows is None else jnp.minimum(held_rows, n)
-    return jnp.where((inverse < limit)[:, None],
-                     y[jnp.minimum(inverse, n - 1)], 0)
-
-
-@jax.custom_vjp
-def combine_rows(y, weight, order, inverse, held_rows=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def combine_rows(y, weight, order, inverse, held_rows=None, prefix=None):
     """y [R, D] expert-ordered outputs, weight [S, k] f32 ->
     [S, D]: each token's k outputs, weighted, summed in f32.  With
     ``held_rows`` (an int32 scalar: the held experts' rows, the first
     of the buffer) only those rows count: what lies past them, and the
     pairs whose row is not in the buffer at all, add nothing and get
-    no gradient, whatever the buffer holds there."""
+    no gradient, whatever the buffer holds there; the sum and both
+    gradients then walk the buffer's rows, not the S*k pairs: the
+    first ``prefix`` (static, held_rows_prefix) while the held rows
+    fit them."""
     s, k = weight.shape
-    picked = _rows_of_pairs(y, inverse, held_rows).reshape(
-        s, k, -1).astype(jnp.float32)
-    return jnp.sum(picked * weight[:, :, None], axis=1).astype(y.dtype)
+    if held_rows is None:
+        picked = y[inverse].reshape(s, k, -1).astype(jnp.float32)
+        return jnp.sum(picked * weight[:, :, None], axis=1).astype(
+            y.dtype)
+
+    def row_side(n):
+        pair, token, live = _live_rows(order, k, held_rows, n)
+        gate = weight.reshape(-1)[pair]
+        return _sum_per_token(y[:n].astype(jnp.float32) * gate[:, None],
+                              token, live, s)
+
+    return _walk(row_side, y.shape[0], held_rows, prefix).astype(y.dtype)
 
 
-def _combine_fwd(y, weight, order, inverse, held_rows=None):
-    return combine_rows(y, weight, order, inverse, held_rows), \
+def _combine_fwd(y, weight, order, inverse, held_rows=None, prefix=None):
+    return combine_rows(y, weight, order, inverse, held_rows, prefix), \
         (y, weight, order, inverse, held_rows)
 
 
-def _combine_bwd(res, g):
+def _combine_bwd(prefix, res, g):
     y, weight, order, inverse, held_rows = res
     s, k = weight.shape
-    gf = g.astype(jnp.float32)
-    dy = (gf[:, None, :] * weight[:, :, None]).astype(y.dtype)
-    dy = dy.reshape(s * k, -1)[order[:y.shape[0]]]
-    if held_rows is not None:
-        row = jax.lax.iota(jnp.int32, y.shape[0])
-        dy = jnp.where((row < held_rows)[:, None], dy, 0)
-    picked = _rows_of_pairs(y, inverse, held_rows).reshape(
-        s, k, -1).astype(jnp.float32)
-    dweight = jnp.sum(picked * gf[:, None, :], axis=-1)
+    if held_rows is None:
+        gf = g.astype(jnp.float32)
+        dy = (gf[:, None, :] * weight[:, :, None]).astype(y.dtype)
+        picked = y[inverse].reshape(s, k, -1).astype(jnp.float32)
+        dweight = jnp.sum(picked * gf[:, None, :], axis=-1)
+        return dy.reshape(s * k, -1)[order], dweight, None, None, None
+
+    def row_side(n):
+        # y and g are each read once: row j's gradient is its token's
+        # g times its gate, its gate's gradient their product summed
+        pair, token, live = _live_rows(order, k, held_rows, n)
+        gate = weight.reshape(-1)[pair]
+        g_rows = g[token].astype(jnp.float32)
+        dy = jnp.where(live[:, None], g_rows * gate[:, None], 0)
+        dgate = jnp.where(live, jnp.sum(
+            y[:n].astype(jnp.float32) * g_rows, axis=-1), 0)
+        dweight = jnp.zeros((s * k,), jnp.float32).at[pair].set(
+            dgate, unique_indices=True)
+        # zeros past the rows walked: a fill, nothing reads them
+        return jnp.pad(dy.astype(y.dtype), ((0, y.shape[0] - n), (0, 0))), \
+            dweight.reshape(s, k)
+
+    dy, dweight = _walk(row_side, y.shape[0], held_rows, prefix)
     return dy, dweight, None, None, None
 
 

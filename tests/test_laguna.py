@@ -239,47 +239,179 @@ def test_the_sorted_buffer_is_bounded_by_what_can_be_held():
     assert inverse[order].tolist() == list(range(6))
 
 
-def test_rows_past_the_held_groups_carry_nothing_whatever_they_hold():
+def _pair_side(x, w, y, g_rows, g_out, order, inverse, k, n_held):
+    """The plain pair-side formulas (what the whole-buffer body
+    computes), in numpy at float64, reading only the rows of the held
+    experts -> (rows, dx, out, dy, dweight)."""
+    x, w, y, g_rows, g_out = (np.asarray(a, np.float64)
+                              for a in (x, w, y, g_rows, g_out))
+    order, inverse = np.asarray(order), np.asarray(inverse)
+    s, bound = w.shape[0], y.shape[0]
+    live = inverse < n_held                       # by pair
+    at = np.minimum(inverse, bound - 1)
+    rows = x[order[:bound] // k]
+    dx = np.where(live[:, None], g_rows[at], 0).reshape(s, k, -1).sum(1)
+    picked = np.where(live[:, None], y[at], 0).reshape(s, k, -1)
+    out = (picked * w[:, :, None]).sum(1)
+    dy = (g_out[:, None, :] * w[:, :, None]).reshape(s * k, -1)[
+        order[:bound]] * (np.arange(bound) < n_held)[:, None]
+    dweight = (picked * g_out[:, None, :]).sum(-1)
+    return rows, dx, out, dy, dweight
+
+
+# a prefix of 8 rows in a buffer of 24: under it, the prefix filled
+# exactly, one row over it (the whole-buffer arm), and no prefix
+@pytest.mark.parametrize('n_held,prefix', [
+    (0, 8), (5, 8), (8, 8), (9, 8), (24, 8), (9, 24), (5, None)])
+def test_rows_past_the_held_groups_carry_nothing_whatever_they_hold(
+        n_held, prefix):
     """On the chip the grouped matmuls leave the rows past their last
     group unwritten, in the forward pass and in the gradient they hand
     back (found by ``chip_smoke.py --phase laguna``: gradients 1e5
     times too large upstream of a routed layer; the CPU's ragged_dot
     writes zeros there, so no test on the tiny model can see it).
-    Poison those rows: neither the combine, nor its gradients, nor the
-    gradient the dispatch hands to the tokens may move."""
-    rng = np.random.RandomState(0)
-    s, k, d, held = 6, 3, 4, (2, 2)
-    idx = jnp.asarray(np.stack([rng.permutation(6)[:k]
-                                for _ in range(s)]), jnp.int32)
+    Poison those rows of the experts' output and of the dispatch's
+    cotangent with NaN: dispatch_rows / combine_rows and their two
+    backward bodies equal the pair-side formulas written out above
+    within float32 rounding, whether the held rows fit the static
+    prefix (the row-side arm), overflow it by one row or wholly (the
+    whole-buffer arm), the prefix is the buffer, or there is none."""
+    rng = np.random.RandomState(n_held)
+    s, k, d, held = 12, 3, 5, (0, 2)
+    # n_held pairs on the two held experts, at most one of each a token
+    idx = np.stack([2 + rng.permutation(6)[:k] for _ in range(s)])
+    for j in rng.permutation(s * 2)[:n_held]:
+        idx[j // 2, j % 2] = j % 2
+    idx = jnp.asarray(idx, jnp.int32)
     order, inverse = pmoe.sort_by_expert(idx, held)
     bound = pmoe.held_rows_bound(s, k, held)
     kept = order[:bound]
-    n_held = int(jnp.sum(pmoe.sort_keys(idx, held) < held[1]))
-    assert 0 < n_held < bound
+    assert int(jnp.sum(pmoe.sort_keys(idx, held) < held[1])) == n_held
     x = jnp.asarray(rng.randn(s, d), jnp.float32)
     w = jnp.asarray(rng.rand(s, k), jnp.float32)
-    y = jnp.asarray(rng.randn(bound, d), jnp.float32)
+    g_out = jnp.asarray(rng.randn(s, d), jnp.float32)
     poison = jnp.where((jnp.arange(bound) >= n_held)[:, None],
                        jnp.nan, 0.0)
+    y = jnp.asarray(rng.randn(bound, d), jnp.float32) + poison
+    g_rows = jnp.asarray(rng.randn(bound, d), jnp.float32) + poison
     held_rows = jnp.int32(n_held)
 
-    def through(y_rows, g_rows):
-        """combine of given expert outputs, and the dispatch's
-        gradient for a given cotangent of the buffer."""
+    @jax.jit
+    def through(x, w, y, g_rows, g_out, held_rows):
+        rows, back = jax.vjp(lambda x: pmoe.dispatch_rows(
+            x, kept, inverse, k, held_rows, prefix), x)
         out, vjp = jax.vjp(lambda y, w: pmoe.combine_rows(
-            y, w, kept, inverse, held_rows), y_rows, w)
-        dy, dw = vjp(jnp.ones_like(out))
-        _, back = jax.vjp(lambda x: pmoe.dispatch_rows(
-            x, kept, inverse, k, held_rows), x)
-        return out, dy, dw, back(g_rows)[0]
+            y, w, kept, inverse, held_rows, prefix), y, w)
+        return (rows, back(g_rows)[0], out) + vjp(g_out)
 
-    clean = through(jnp.where(jnp.isnan(poison), 0.0, y), y * 0 + 1.0)
-    dirty = through(y + poison, y * 0 + 1.0 + poison)
-    for a, b in zip(clean, dirty):
-        assert np.isfinite(np.asarray(b)).all()
-        assert (np.asarray(a) == np.asarray(b)).all()
-    # the rows past the held ones get no gradient either
-    assert (np.asarray(dirty[1])[n_held:] == 0).all()
+    got = through(x, w, y, g_rows, g_out, held_rows)
+    want = _pair_side(x, w, jnp.nan_to_num(y), jnp.nan_to_num(g_rows),
+                      g_out, order, inverse, k, n_held)
+    for name, a, b in zip(('rows', 'dx', 'out', 'dy', 'dweight'), got,
+                          want):
+        a = np.asarray(a)
+        assert np.isfinite(a).all(), name
+        if name == 'dy':          # past the held rows: no gradient
+            assert (a[n_held:] == 0).all()
+        assert np.abs(a - b).max(initial=0) <= 1e-6 * max(
+            np.abs(b).max(initial=0), 1), name
+    # a conditional in each body but the forward gather where the
+    # prefix is shorter than the buffer, none where it is the buffer
+    # or there is none
+    text = str(jax.make_jaxpr(through)(x, w, y, g_rows, g_out,
+                                       held_rows))
+    assert text.count("cond[") == (3 if prefix == 8 else 0)
+
+
+def test_the_prefix_is_a_few_times_an_even_share_and_the_bound_at_most():
+    """The cell's layer (4096 tokens, top-10, 8 of 256 experts): an
+    even routing holds 1,280 rows, the buffer 32,768, and the
+    permutation walks PREFIX_OVER_EVEN times the first in whole steps
+    of 512.  A layer that holds a large share walks its whole buffer,
+    with no second arm in its program."""
+    even = 4096 * 10 * 8 // 256
+    p = pmoe.held_rows_prefix(4096, 10, (0, 8), 256)
+    assert p == pmoe.PREFIX_OVER_EVEN * even == 15360 and p % 512 == 0
+    assert pmoe.held_rows_prefix(4096, 10, (8, 8), 256) == p
+    assert pmoe.held_rows_prefix(4096, 10, (0, 8), 250) == 15872
+    bound = pmoe.held_rows_bound(4096, 10, (0, 64))
+    assert pmoe.held_rows_prefix(4096, 10, (0, 64), 256) == bound
+    assert pmoe.held_rows_prefix(6, 3, (2, 2), 6) == \
+        pmoe.held_rows_bound(6, 3, (2, 2))
+
+
+def _conditionals_of_a_layer(held, experts, tokens=512):
+    """How many conditionals the permutation of a ``layers.moe``
+    (top-2, forward and backward) traces to: the layer's own ops with
+    the attributes it gave them, route -> dispatch -> combine."""
+    from paddle_tpu.ops import registry
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()), \
+            fluid.unique_name.guard():
+        xv = layers.data('x', shape=[tokens, 8], dtype='float32')
+        layers.moe(xv, num_experts=experts, hidden_size=4,
+                   capacity_factor=None, top_k=2, experts_held=held)
+    ops = {op.type: op for op in main.global_block().ops}
+
+    def run(kind, ins):
+        return registry.get(kind).fn(_Ctx(), ins, ops[kind].attrs)
+
+    def permuted(x, wg):
+        route = run('moe_route', {'X': [x], 'Gate': [wg]})
+        sizes = route.get('HeldLoad', route['Load'])
+        rows = run('moe_dispatch', {'X': [x], 'TopKIdx': route['TopKIdx'],
+                                    'GroupSizes': sizes})
+        ins = {'Rows': rows['Rows'], 'TopKWeight': route['TopKWeight'],
+               'Order': rows['Order'], 'Inverse': rows['Inverse']}
+        if held is not None:
+            ins['GroupSizes'] = sizes
+        return jnp.sum(run('moe_combine', ins)['Out'][0])
+
+    x, wg = jnp.ones((tokens, 8)), jnp.ones((8, experts))
+    return str(jax.make_jaxpr(jax.grad(permuted, (0, 1)))(x, wg)).count(
+        'cond[')
+
+
+def test_only_a_layer_with_a_short_prefix_traces_a_conditional():
+    """512 tokens top-2: a layer that holds 2 of 16 experts (an even
+    share is 128 rows of its buffer's 1,024) and one that holds 1 of
+    64 (16 of 512; the prefix comes in steps of 512) walk their whole
+    buffer, one body and no second arm; one that holds 2 of 64 walks
+    512 of 1,024 behind a conditional in the combine and in both
+    backward bodies; all experts: the pair-side program, none."""
+    assert _conditionals_of_a_layer(None, 16) == 0
+    assert _conditionals_of_a_layer((0, 2), 16) == 0
+    assert _conditionals_of_a_layer((3, 1), 64) == 0
+    assert _conditionals_of_a_layer((0, 2), 64) == 3
+
+
+@pytest.mark.parametrize('rows,overflows', [
+    ([100, 412], 0), ([100, 413], 1), ([0, 0], 0)],
+    ids=['at_the_prefix', 'one_over', 'none_held'])
+def test_held_layers_report_their_largest_load_and_the_overflows(
+        rows, overflows):
+    """Two layers of 512 tokens top-2 that hold 2 of 64 experts (prefix
+    512 of a 1,024-row buffer), the first always under its prefix:
+    ``moe/held_rows_max`` is the larger layer's rows, a layer over its
+    prefix counts once in ``moe/prefix_overflows``, one at it not at
+    all."""
+    from paddle_tpu.fluid import moe_stats
+    assert pmoe.held_rows_prefix(512, 2, (0, 2), 64) == 512
+    values = []
+    for n in (40, sum(rows)):
+        load = np.zeros(64, np.int32)
+        load[4:6] = [n - n // 2, n // 2] if n == 40 else rows
+        load[63] = 1024 - load.sum()
+        values += [load, load[4:6]]
+    record = moe_stats.HeldLayers()
+    record.top_k += [2, 2]
+    monitor.reset()
+    record(values)
+    assert monitor.counter_value('moe/prefix_overflows') == overflows
+    assert monitor.gauge_value('moe/held_rows_max') == max(sum(rows), 40)
+    assert monitor.counter_value('moe/rows_held') == 40 + sum(rows)
+    assert monitor.gauge_value('moe/held_share') == pytest.approx(
+        (40 + sum(rows)) / 2048.)
 
 
 def test_moe_rejects_a_held_range_it_cannot_hold():
