@@ -10,6 +10,9 @@ the chip: BERT-base pretraining through the normal entry points
                                     # at published widths (one layer,
                                     # one 4096-token sequence, f32)
                                     # against jax.grad of its reference
+    python chip_smoke.py --phase moonlight   # Moonlight-16B-A3B's, one
+                                    # 8192-token sequence, before and
+                                    # after its routers' bias has moved
 
 One process, no children.  It fails (non-zero, no result line) unless
 jax.devices()[0].platform == 'tpu'; nothing here falls back to the CPU.
@@ -834,13 +837,340 @@ def phase_laguna_gradients(seq=4096, seed=0, rows=64):
           % (LAGUNA_L2_RTOL, worst))
 
 
+# Moonlight-16B-A3B at published widths, one 8192-token sequence (the
+# cell's), the dense layer and MOONLIGHT_LAYERS - 1 sparse ones with
+# experts 0-7 of 64 held and 20480 vocabulary rows: the f32 train
+# program (the flash kernels at 192-wide queries and keys over 128-wide
+# values, whose f32 rows ask Mosaic for more scoped VMEM than its
+# default; the held experts' grouped matmuls; the sigmoid router and
+# its choice bias) against jax.grad of the plain reference, per sampled
+# gradient tensor: once on the startup weights and once after
+# MOONLIGHT_STEPS train steps have moved the bias (SGD at lr 0: the
+# bias is the only state that moves, so what the second loss differs
+# by is the routing following it), weights and bias read back from the
+# scope.  Fewer layers than the cell's six: six layers' f32
+# activations at 8192 tokens do not fit beside the weights.
+#
+# Of 8192 tokens a layer, three to seven have a 6th and 7th BIASED
+# score so near a tie (64 sigmoid scores lie 6e-3 apart on average, and
+# a float32 logit is good to 1e-6) that the program picks the other
+# expert than the reference; each moves its own rows' gradients by
+# their own size, which a router's or a lightly loaded expert's
+# gradient (a sum over a few hundred rows) shows as 0.7-1.4e-2 of its
+# L2 norm (my chip run, PR 32: PERF.md section 6).  So the gradients
+# are compared with the reference routed by the PROGRAM'S OWN CHOICE
+# (``chosen=``, the op's TopKIdx), which leaves summation order alone,
+# and the choice itself is held to the reference's through the loads
+# (the experts whose load differs are printed and bounded).  The loss
+# is compared with both.  The limit on it lies between the program's
+# reading against the free reference (1.8e-7 and 4.6e-7 on the two
+# states) and the bfloat16-throughout reference's (1.48e-5 and
+# 5.07e-6), which has to miss it; the family's 1e-5 for the cell's six
+# layers is a limit on any seed's batch, this one is for seed 0.
+MOONLIGHT_LAYERS = 3
+MOONLIGHT_STEPS = 5
+MOONLIGHT_LOSS_RTOL = 2e-6
+MOONLIGHT_L2_RTOL = 2e-3
+# experts of a layer whose load may differ from the reference's (two
+# a near-tie token)
+MOONLIGHT_LOADS_OFF = 24
+# the cell's own cut, forward only: benchmark/families/moonlight.py's
+# REFERENCE_RTOL and the readings it lies between
+MOONLIGHT_CELL_LAYERS = 6
+MOONLIGHT_CELL_RTOL = 2e-5
+MOONLIGHT_LOSS_BATCHES = 12
+# creation order, trainable parameters only: embedding 0; layer 0
+# (dense) g_in 1 Wq 2 Wkva 3 g_latent 4 Wkvb 5 Wo 6 g_post 7 gate up
+# down 10; layer 1 (sparse) g_in 11 Wq Wkva 13 g_latent 14 Wkvb 15 Wo
+# g_post 17 router 18 gate 19 up 20 down 21 shared gate up down 24;
+# layer 2 the same from 25
+MOONLIGHT_SAMPLED = {'embedding': 0, 'Wq (layer 0)': 2,
+                     'Wkva (layer 0)': 3,
+                     'latent norm gain (layer 0)': 4, 'Wkvb (layer 0)': 5,
+                     'Wkva (layer 1)': 13, 'Wkvb (layer 1)': 15,
+                     'router Wg (layer 1)': 18, 'gate': 19, 'up': 20,
+                     'down': 21, 'shared gate (layer 1)': 22,
+                     'shared down (layer 1)': 24,
+                     'router Wg (layer 2)': 32}
+
+
+def _moonlight_cut(layers=None):
+    from paddle_tpu.models import moonlight
+    return moonlight.MoonlightConfig(
+        vocab_size=20480, layers=layers or MOONLIGHT_LAYERS,
+        experts_held=(0, 8), bias_init_std=0.005)
+
+
+def _moonlight_cell_losses(seq, seed):
+    """The benchmark cell's own cut (MOONLIGHT_CELL_LAYERS layers),
+    forward only: the f32 for_test program's loss on
+    MOONLIGHT_LOSS_BATCHES batches, on the state the seeded startup
+    program gives, beside the reference's in float32 and in bfloat16
+    throughout: the two readings the family's REFERENCE_RTOL lies
+    between."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import moonlight
+    from paddle_tpu.models.reference import moonlight as reference
+    cfg = _moonlight_cut(MOONLIGHT_CELL_LAYERS)
+    sizes = reference.sizes_of(cfg)
+    feeds = [_ints32(moonlight.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(s)))
+        for s in range(seed, seed + MOONLIGHT_LOSS_BATCHES)]
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.scope_guard(fluid.Scope()):
+        with fluid.program_guard(main, startup), \
+                fluid.unique_name.guard():
+            _, _, loss = moonlight.build_pretrain(cfg, seq)
+            test = main.clone(for_test=True)
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        # host copies first: a run donates the state it may write
+        weights, biases = ([np.asarray(fluid.core.as_array(
+            scope.find_var(p.name))) for p in main.all_parameters()
+            if p.trainable == kind] for kind in (True, False))
+        program = [_scalar(exe.run(test, feed=f, fetch_list=[loss]))
+                   for f in feeds]
+        weights, biases = ([jnp.asarray(x) for x in part]
+                           for part in (weights, biases))
+        both = jax.jit(lambda w, b, i, p, l: [reference.loss(
+            w, b, i, p, l, sizes=sizes, dtype=dt)
+            for dt in (jnp.float32, jnp.bfloat16)])
+        off, low = [], []
+        for n, (feed, got) in enumerate(zip(feeds, program)):
+            full, half = (float(x) for x in both(weights, biases, *(
+                jnp.asarray(feed[k])
+                for k in ('ids', 'pos_ids', 'labels'))))
+            off.append(abs(got - full) / full)
+            low.append(abs(half - full) / full)
+            say('%d layers, batch seed %d: program %.6f, reference %.6f '
+                '(relative difference %.2e), reference in bfloat16 '
+                'throughout %.6f (%.2e)'
+                % (cfg.layers, seed + n, got, full, off[-1], half,
+                   low[-1]))
+        for name in scope.local_var_names():
+            scope.erase(name)
+    say('over %d batches at %d layers: f32 for_test program against the '
+        'reference, relative: median %.2e, largest %.2e; reference in '
+        'bfloat16 throughout: smallest %.2e, quartiles %.2e %.2e %.2e, '
+        'largest %.2e, %d within %g'
+        % ((len(off), cfg.layers, np.median(off), max(off), min(low)) +
+           tuple(np.percentile(low, (25, 50, 75))) +
+           (max(low), sum(x <= MOONLIGHT_CELL_RTOL for x in low),
+            MOONLIGHT_CELL_RTOL)))
+    check(max(off) <= MOONLIGHT_CELL_RTOL, 'moonlight f32 for_test loss '
+          'at the cell\'s cut within %g of the reference on every batch'
+          % MOONLIGHT_CELL_RTOL)
+
+
+def _moonlight_programs(seq, seed):
+    """(main with SGD at lr 0, startup, loss, trainable parameters in
+    creation order, the choice biases in layer order, {param: its
+    gradient's name})."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import moonlight
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        _, _, loss = moonlight.build_pretrain(_moonlight_cut(), seq)
+        every = main.all_parameters()
+        params = [p.name for p in every if p.trainable]
+        biases = [p.name for p in every if not p.trainable]
+        pairs = dict((p.name, g.name) for p, g in
+                     fluid.optimizer.SGD(0.0).minimize(loss)[1])
+    return main, startup, loss, params, biases, pairs
+
+
+def phase_moonlight_gradients(seq=8192, seed=0, rows=64):
+    """models.moonlight.BASE cut as above: loss and sampled gradients
+    of the f32 TRAIN program against the reference's on one seeded
+    sequence, on the startup state and again after the bias has moved;
+    beside each the reference in bfloat16 throughout."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.models import moonlight
+    from paddle_tpu.models.reference import moonlight as reference
+    cfg = _moonlight_cut()
+    sizes = reference.sizes_of(cfg)
+    feed = _ints32(moonlight.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(seed)))
+    ids, pos, labels = (jnp.asarray(feed[k])
+                        for k in ('ids', 'pos_ids', 'labels'))
+    picked_rows = np.unique(feed['ids'])[:rows]
+    experts = {}
+
+    def sample(name, array):
+        if name == 'embedding':
+            return {'embedding rows': array[picked_rows]}
+        if name in ('gate', 'up', 'down'):
+            return {'%s, %s loaded held expert (layer 1)' % (name, which):
+                    array[e] for which, e in experts.items()}
+        return {name: array}
+
+    # the weights go in as arguments: closed over, they would be
+    # constants of the program
+    def ref_loss(some, full, biases, chosen=None, dtype=jnp.float32):
+        full = list(full)
+        for name, w in some.items():
+            full[MOONLIGHT_SAMPLED[name]] = w
+        return reference.loss(full, biases, ids, pos, labels, sizes=sizes,
+                              dtype=dtype, remat=True, chosen=chosen)
+
+    ref_grads = jax.jit(jax.value_and_grad(ref_loss))
+    ref_free = jax.jit(lambda full, biases: [
+        ref_loss({}, full, biases, dtype=dt)
+        for dt in (jnp.float32, jnp.bfloat16)])
+    ref_loads = jax.jit(lambda full, biases: reference.forward(
+        full, biases, ids, pos, sizes=sizes)[1])
+
+    main, startup, loss, params, biases, pairs = _moonlight_programs(
+        seq, seed)
+    fetches = [loss] + [pairs[params[i]]
+                        for i in MOONLIGHT_SAMPLED.values()]
+    routers = [op for op in main.global_block().ops
+               if op.type == 'moe_route']
+    load_names = [op.output('Load')[0] for op in routers]
+    choice_names = [op.output('TopKIdx')[0] for op in routers]
+    worst = {}
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+
+        def state():
+            """Host copies: the run below donates the scope's own."""
+            return tuple([np.asarray(fluid.core.as_array(
+                scope.find_var(n))) for n in names]
+                for names in (params, biases))
+
+        def compare(tag):
+            """One fetching run of the train program on the state the
+            scope holds now, against the reference on that state."""
+            weights, bias_values = state()
+            t0 = time.time()
+            # a run that fetches and blocks also reads what the layers
+            # have the program watch (the loads, the biases)
+            got = exe.run(main, feed=feed, fetch_list=fetches +
+                          load_names + choice_names)
+            got_loss = _scalar(got[:1])
+            chosen = [jnp.asarray(x) for x in got[-len(routers):]]
+            weights, bias_values = ([jnp.asarray(x) for x in part]
+                                    for part in (weights, bias_values))
+            program_loads = [np.asarray(x) for x in got[
+                len(fetches):len(fetches) + len(routers)]]
+            say('moonlight f32 train program, %s, 1 x %d tokens: loss '
+                '%.6f in %.1f s; largest |bias| %.4f; '
+                'moe/held_share %.4f, moe/held_rows_max %d, '
+                'moe/prefix_overflows %d, moe/dropped_tokens %d, '
+                'moe/bias_updates %d, moe/score_bias_abs_max %.4f'
+                % (tag, seq, got_loss, time.time() - t0,
+                   max(float(jnp.abs(b).max()) for b in bias_values),
+                   monitor.gauge_value('moe/held_share'),
+                   monitor.gauge_value('moe/held_rows_max'),
+                   monitor.counter_value('moe/prefix_overflows'),
+                   monitor.counter_value('moe/dropped_tokens'),
+                   monitor.counter_value('moe/bias_updates'),
+                   monitor.gauge_value('moe/score_bias_abs_max')))
+            loads = [np.asarray(x)
+                     for x in ref_loads(weights, bias_values)]
+            loads_off = [int(np.sum(a != b))
+                         for a, b in zip(program_loads, loads)]
+            say('%s: experts a routed layer whose load differs between '
+                'program and reference (a near-tie token changes two by '
+                'one): %s; held rows a layer %s'
+                % (tag, loads_off, [int(x[:8].sum()) for x in loads]))
+            check(max(loads_off) <= MOONLIGHT_LOADS_OFF,
+                  'the program\'s choice, %s, is the reference\'s but '
+                  'for near-ties (at most %d experts\' loads a layer '
+                  'differ)' % (tag, MOONLIGHT_LOADS_OFF))
+            load = loads[0][:8]
+            experts.update(most=int(load.argmax()),
+                           least=int(load.argmin()))
+            grads = {what: np.asarray(x)
+                     for name, g in zip(MOONLIGHT_SAMPLED,
+                                        got[1:len(fetches)])
+                     for what, x in sample(name, g).items()}
+            del got
+            some = {name: weights[i]
+                    for name, i in MOONLIGHT_SAMPLED.items()}
+            pinned, want_grads = ref_grads(some, weights, bias_values,
+                                           chosen)
+            want_loss, low = (float(x)
+                              for x in ref_free(weights, bias_values))
+            rel = abs(got_loss - want_loss) / want_loss
+            low_rel = abs(low - want_loss) / want_loss
+            say('%s: reference loss %.6f, program %.6f (relative '
+                'difference %.2e; %.2e from the reference routed by '
+                'the program\'s choice); reference in bfloat16 '
+                'throughout %.6f (%.2e)'
+                % (tag, want_loss, got_loss, rel,
+                   abs(got_loss - float(pinned)) / float(pinned), low,
+                   low_rel))
+            check(rel <= MOONLIGHT_LOSS_RTOL, 'moonlight f32 train loss, '
+                  '%s, within %g of the reference'
+                  % (tag, MOONLIGHT_LOSS_RTOL))
+            check(low_rel > MOONLIGHT_LOSS_RTOL, 'the reference in '
+                  'bfloat16 throughout, %s, misses that tolerance' % tag)
+            far = 0.0
+            for name in MOONLIGHT_SAMPLED:
+                for what, y in sample(
+                        name, np.asarray(want_grads[name])).items():
+                    x = grads[what]
+                    e = float(np.abs(x - y).max() / np.abs(y).max())
+                    d = float(np.linalg.norm(x - y) / np.linalg.norm(y))
+                    far = max(far, d)
+                    say('%s, gradient of %s %s: largest entry '
+                        'difference %.3e of the largest entry (%.3e), '
+                        'relative L2 distance %.3e'
+                        % (tag, what, x.shape, e, np.abs(y).max(), d))
+            worst[tag] = far
+            return got_loss, bias_values
+
+        first_loss, before = compare('startup state')
+        check(monitor.counter_value('moe/dropped_tokens') == 0 and
+              monitor.counter_value('moe/rows_held') > 0,
+              'rows were held and moe/dropped_tokens stayed 0')
+        for _ in range(MOONLIGHT_STEPS - 1):
+            exe.run(main, feed=feed, fetch_list=[])
+        moved_loss, after = compare(
+            'after %d train steps' % MOONLIGHT_STEPS)
+        moved = max(float(jnp.abs(a - b).max())
+                    for a, b in zip(after, before))
+        say('the bias moved by at most %.4f an expert in %d steps of '
+            'gamma %g; the loss on the same weights %.6f -> %.6f'
+            % (moved, MOONLIGHT_STEPS, cfg.bias_update_rate, first_loss,
+               moved_loss))
+        check(abs(moved - MOONLIGHT_STEPS * cfg.bias_update_rate) <= 1e-6,
+              'some expert\'s bias moved by gamma on every step')
+        check(moved_loss != first_loss,
+              'the routing followed the bias (the loss moved)')
+        check(monitor.counter_value('moe/dropped_tokens') == 0 and
+              monitor.counter_value('moe/prefix_overflows') == 0,
+              'moe/dropped_tokens and moe/prefix_overflows stayed 0')
+        for name in scope.local_var_names():
+            scope.erase(name)
+    _moonlight_cell_losses(seq, seed)
+    for tag, far in worst.items():
+        check(far <= MOONLIGHT_L2_RTOL,
+              'moonlight gradients, %s: every sampled tensor within %g '
+              'of the reference\'s routed by the program\'s choice, '
+              'relative L2 distance (worst %.3e)'
+              % (tag, MOONLIGHT_L2_RTOL, far))
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
     ap.add_argument('--chips', type=int, choices=(1, 4), default=1)
-    ap.add_argument('--phase', choices=('bert', 'olmoe', 'laguna'),
+    ap.add_argument('--phase',
+                    choices=('bert', 'olmoe', 'laguna', 'moonlight'),
                     default='bert',
-                    help="'olmoe' / 'laguna': only that model's "
-                    "gradient check")
+                    help="'olmoe' / 'laguna' / 'moonlight': only that "
+                    "model's gradient check")
     args = ap.parse_args()
 
     import jax
@@ -871,6 +1201,8 @@ def main():
             phase_olmoe_gradients()
         elif args.phase == 'laguna':
             phase_laguna_gradients()
+        elif args.phase == 'moonlight':
+            phase_moonlight_gradients()
         elif args.chips == 4:
             phase_four_chips(
                 models.bert.BertConfig(dropout=0.0, attn_dropout=0.0),

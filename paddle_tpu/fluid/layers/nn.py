@@ -249,7 +249,7 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
 
 def rotary_embedding(q, k, positions, theta=10000.0, name=None,
                      rotary_dim=None, inv_freq=None,
-                     attention_factor=1.0):
+                     attention_factor=1.0, interleaved=False):
     """Rotary position embedding of q [B, T, H, D] and k [B, T, Hk, D]
     at integer ``positions`` [B, T], rotate-half pairing -> (q, k)
     rotated.  By default over the whole head with the frequencies
@@ -258,7 +258,11 @@ def rotary_embedding(q, k, positions, theta=10000.0, name=None,
     the rest through; ``inv_freq`` is a variable [rotary_dim / 2] of
     inverse frequencies to use instead of theta's (a YaRN or NTK
     table, e.g. ``layers.assign`` of a numpy array);
-    ``attention_factor`` multiplies cos and sin (YaRN's)."""
+    ``attention_factor`` multiplies cos and sin (YaRN's).
+    ``interleaved`` pairs the input's features (2i, 2i + 1) instead
+    of (i, i + rotary_dim / 2) and leaves the output in [evens | odds]
+    order (HF ``deepseek_v3``'s ``rope_interleave``); k may have one
+    head for all of q's."""
     helper = LayerHelper('rotary_embedding', name=name)
     q_out = helper.create_variable_for_type_inference(q.dtype)
     k_out = helper.create_variable_for_type_inference(k.dtype)
@@ -270,6 +274,8 @@ def rotary_embedding(q, k, positions, theta=10000.0, name=None,
         inputs['InvFreq'] = inv_freq
     if attention_factor != 1.0:
         attrs['attention_factor'] = float(attention_factor)
+    if interleaved:
+        attrs['interleaved'] = True
     helper.append_op('rotary_embedding', inputs=inputs,
                      outputs={'QOut': q_out, 'KOut': k_out}, attrs=attrs)
     return q_out, k_out

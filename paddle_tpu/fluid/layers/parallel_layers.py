@@ -70,7 +70,8 @@ def context_parallel_attention(q, k, v, causal=False, use_flash=False,
 def moe(x, num_experts, hidden_size, capacity_factor=2.0,
         aux_weight=0.01, axis='ep', top_k=1, param_attr=None,
         name=None, renormalize=True, z_loss_weight=0.0,
-        experts_held=None, gate_scale=1.0):
+        experts_held=None, gate_scale=1.0, score_func='softmax',
+        score_bias=None, bias_update_rate=0.0):
     """Mixture-of-Experts feed-forward layer, in two forms.
 
     **Capacity-based** (``capacity_factor`` a number, the default):
@@ -105,6 +106,22 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
     counted as dropped.  What every chip computes alike (a shared
     expert) is the model's to add, once.
 
+    ``score_func='sigmoid'`` (dropless only) scores each expert by the
+    sigmoid of its own logit instead of the softmax over all;
+    ``renormalize`` then divides the chosen scores by (their sum +
+    1e-20).  ``score_bias`` (sigmoid only: True, or a ``ParamAttr``
+    whose initializer draws its startup values; default zeros) adds a
+    persistable, NON-trainable [num_experts] float32 bias to the
+    scores for the CHOICE of the ``top_k`` experts only: the gates are
+    the plain scores, the bias takes no gradient and is no parameter
+    of the optimizer.  With ``bias_update_rate`` gamma > 0 every run
+    of the train program moves it, ``b += gamma * sign(mean load -
+    load)`` from that run's expert loads (DeepSeek-V3's
+    auxiliary-loss-free balancing), as an output of the ``moe_route``
+    op; a ``clone(for_test=True)`` leaves it as it is.  Gauge
+    ``moe/score_bias_abs_max`` and counter ``moe/bias_updates``
+    (``fluid/moe_stats.py``) read it on the runs that fetch.
+
     x: [B, T, D].  Returns (out [B, T, D], aux []): ``aux`` is the
     load-balance loss times ``aux_weight`` plus, dropless only, the
     router z-loss times ``z_loss_weight``; add it to the training loss.
@@ -124,6 +141,26 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
     if gate_scale != 1.0 and not dropless:
         raise ValueError('moe: gate_scale needs the dropless path '
                          '(capacity_factor=None)')
+    if score_func not in ('softmax', 'sigmoid'):
+        raise ValueError("moe: score_func is 'softmax' or 'sigmoid', "
+                         'got %r' % (score_func,))
+    if score_func != 'softmax' and not dropless:
+        raise ValueError(
+            'moe: score_func=%r needs the dropless path '
+            '(capacity_factor=None): the capacity-based maps are built '
+            'from a softmax; got capacity_factor=%r'
+            % (score_func, capacity_factor))
+    if score_bias and score_func != 'sigmoid':
+        raise ValueError(
+            'moe: score_bias corrects the CHOICE among sigmoid scores '
+            "and never weighs (score_func='sigmoid', "
+            "capacity_factor=None); added to a softmax's probabilities "
+            "or to the capacity-based path's it would have no such "
+            'reading; got score_func=%r, capacity_factor=%r'
+            % (score_func, capacity_factor))
+    if bias_update_rate and not score_bias:
+        raise ValueError('moe: bias_update_rate=%r moves a score_bias, '
+                         'and there is none' % (bias_update_rate,))
     if dropless and not 1 <= top_k <= e:
         raise ValueError('moe: dropless top_k must be in 1..num_experts '
                          '(%d), got %r' % (e, top_k))
@@ -154,7 +191,8 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
         return _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k,
                              axis, renormalize, aux_weight,
                              z_loss_weight, experts_held,
-                             float(gate_scale))
+                             float(gate_scale), score_func, score_bias,
+                             float(bias_update_rate))
     w1, w2 = weight([e, d, h]), weight([e, h, d])
     out = helper.create_variable_for_type_inference(x.dtype)
     aux = helper.create_variable_for_type_inference('float32')
@@ -174,7 +212,8 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
 
 def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
                   renormalize, aux_weight, z_loss_weight, held=None,
-                  gate_scale=1.0):
+                  gate_scale=1.0, score_func='softmax', score_bias=None,
+                  bias_update_rate=0.0):
     d = int(x.shape[-1])
     here = e if held is None else held[1]      # experts with weights
     w_gate, w_up, w_down = weight([here, d, h]), weight([here, d, h]), \
@@ -200,7 +239,30 @@ def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
         # the router's width tells the permutation how small a share
         # of its buffer is held (parallel.moe.held_rows_prefix)
         held_attrs = {'experts_held': list(held), 'num_experts': e}
-    helper.append_op('moe_route', inputs={'X': x, 'Gate': wg},
+    route_ins = {'X': x, 'Gate': wg}
+    if score_func != 'softmax':
+        route_attrs['score_func'] = score_func
+    bias = None
+    if score_bias:
+        from ..initializer import Constant
+        from ..param_attr import ParamAttr
+        attr = score_bias if isinstance(score_bias, ParamAttr) \
+            else ParamAttr()
+        attr.trainable = False
+        bias = helper.create_parameter(
+            attr, shape=[e], dtype='float32',
+            default_initializer=Constant(0.0))
+        bias.stop_gradient = True
+        # the op reads a copy: its gradient op runs the router again
+        # from the op's inputs, after the bias itself has moved
+        from . import tensor
+        route_ins['ScoreBias'] = read = tensor.assign(bias)
+        read.stop_gradient = True
+        if bias_update_rate:
+            route_outs['ScoreBiasOut'] = bias
+            route_attrs['bias_update_rate'] = bias_update_rate
+            route_attrs['is_test'] = False
+    helper.append_op('moe_route', inputs=route_ins,
                      outputs=route_outs, attrs=route_attrs)
     # rows = tokens x top_k: with a dynamic batch, shape inference's
     # stand-in for it overflows int32 index arithmetic at that size, so
@@ -260,6 +322,9 @@ def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
         held_layers = moe_stats.HeldLayers.of(helper.main_program)
         held_layers.top_k.append(top_k)
         helper.main_program.watch([load.name, sizes.name], held_layers)
+    if bias_update_rate:
+        # moe/score_bias_abs_max, moe/bias_updates
+        helper.main_program.watch([bias.name], moe_stats.record_bias)
     aux = scaled(balance, aux_weight)
     if z_loss_weight:
         total = var('float32')

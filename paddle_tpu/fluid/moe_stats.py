@@ -34,6 +34,15 @@ reports, on the same runs:
   ran: slower, never different.  0 as long as the routing stays
   within a few times an even one.
 
+A layer whose router carries a choice bias that its train program
+moves (``score_bias`` with a ``bias_update_rate``) reports:
+
+- gauge ``moe/score_bias_abs_max``: the largest ``|b|`` over the
+  experts of all such layers, as the last run read left it;
+- counter ``moe/bias_updates``: the biases the runs read have moved,
+  one a layer and run read (a ``for_test`` clone moves none and
+  watches nothing).
+
 Under ``with_data_parallel`` the values are the whole batch's; under
 the collective (shard_map) runner they are the first device's share.
 """
@@ -93,3 +102,11 @@ def record_held(values, top_k):
     monitor.add('moe/prefix_overflows', float(over))
     monitor.set_gauge('moe/held_share', held / max(routed, 1.0))
     monitor.set_gauge('moe/held_rows_max', float(most))
+
+
+def record_bias(values):
+    """``values``: one [E] choice bias a layer, as fetched from the
+    train program (read after the run's update)."""
+    monitor.add('moe/bias_updates', float(len(values)))
+    monitor.set_gauge('moe/score_bias_abs_max', max(
+        float(np.abs(np.asarray(b, np.float64)).max()) for b in values))

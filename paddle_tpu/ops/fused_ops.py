@@ -17,8 +17,9 @@ from .registry import register
 
 @register('fused_multihead_attention', stochastic=True)
 def fused_multihead_attention(ctx, ins, attrs):
-    """Q: [B, T, H, D], K,V: [B, T, Hkv, D] (+ optional KeyBias [B, T]
-    additive score bias, e.g. a padding mask) -> Out [B, T, H, D] via
+    """Q: [B, T, H, D], K: [B, T, Hkv, D], V: [B, T, Hkv, Dv] (+
+    optional KeyBias [B, T] additive score bias, e.g. a padding mask)
+    -> Out [B, T, H, Dv] via
     flash_attention(): the Pallas kernels forward and backward on a
     TPU, the dense chain elsewhere and under the GSPMD runner's mesh
     (ops/pallas/common.py dispatch()).
@@ -32,7 +33,11 @@ def fused_multihead_attention(ctx, ins, attrs):
     the blocks outside the band).  A windowed call is lowered inside a
     scope of its own, ``window<n>``, so a device trace tells it from a
     full one (the compiler names a Mosaic call after the innermost
-    scope).
+    scope).  V may be narrower or wider than Q and K (Dv != D: a
+    latent-attention head's 192-wide keys over 128-wide values; the
+    scores are scaled by 1/sqrt(D), every product runs at its own
+    width and nothing is padded); such a call is lowered inside the
+    scope ``qk<D>v<Dv>``.
 
     attrs['dropout_rate'] > 0 applies attention-probability dropout
     INSIDE the kernels (reference default: dropout around softmax,
@@ -50,8 +55,12 @@ def fused_multihead_attention(ctx, ins, attrs):
     if seed is None:
         rate = 0.0
     window = int(attrs.get('window', 0) or 0)
-    with jax.named_scope('window%d' % window) if window \
-            else contextlib.nullcontext():
+    scopes = ['window%d' % window] if window else []
+    if v.shape[-1] != q.shape[-1]:
+        scopes.append('qk%dv%d' % (q.shape[-1], v.shape[-1]))
+    with contextlib.ExitStack() as stack:
+        for name in scopes:
+            stack.enter_context(jax.named_scope(name))
         return {'Out': [flash_attention(
             q, k, v, causal=attrs.get('causal', False), key_bias=bias,
             dropout_rate=rate, dropout_seed=seed,

@@ -451,7 +451,14 @@ def rotary_embedding(ctx, ins, attrs):
     [rotary_dim/2] where given (a scaled table: YaRN, NTK), else
     theta^(-2i/rotary_dim); cos and sin are multiplied by
     attrs['attention_factor'] (default 1.0: nothing).  Angles and the
-    rotation in float32, outputs in the input dtype."""
+    rotation in float32, outputs in the input dtype.  Hk need not be H
+    (one rotary key for all heads: Hk = 1).
+
+    attrs['interleaved'] reads the INPUT's pairs as (2i, 2i + 1)
+    instead, as HF ``deepseek_v3``'s ``apply_rotary_pos_emb_interleave``
+    does: the rotated features are first brought to [evens | odds] and
+    then turned as above, and the output STAYS in that order (q and k
+    are permuted alike, so their products do not change)."""
     q, k = ins['Q'][0], ins['K'][0]
     pos = ins['Positions'][0].astype(jnp.float32)
     width = q.shape[-1]
@@ -468,9 +475,14 @@ def rotary_embedding(ctx, ins, attrs):
     if factor != 1.0:
         cos, sin = cos * factor, sin * factor
 
+    interleaved = bool(attrs.get('interleaved', False))
+
     def rotate(x):
         xf = x.astype(jnp.float32)
-        x1, x2 = xf[..., :half], xf[..., half:rotary]
+        if interleaved:
+            x1, x2 = xf[..., 0:rotary:2], xf[..., 1:rotary:2]
+        else:
+            x1, x2 = xf[..., :half], xf[..., half:rotary]
         parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
         if rotary < width:
             parts.append(xf[..., rotary:])

@@ -104,13 +104,20 @@ def score_tile_bytes(block_q, block_k):
     return 3 * block_q * block_k * 4
 
 
-def vmem_estimate(t, d, block_q, block_k, itemsize):
+def resident_row_bytes(t, d, itemsize, dv=None):
+    """The full rows a forward, dq or dkv instance keeps resident: K
+    and V (or Q and dO), ``d`` and ``dv`` wide (one width: dv=None)."""
+    return t * (d + (d if dv is None else dv)) * itemsize
+
+
+def vmem_estimate(t, d, block_q, block_k, itemsize, dv=None):
     """Bytes a kernel instance keeps resident in VMEM.  Dominant terms
     across the three kernels: the full K and V rows (streamed via
     dslice but block-spec'd whole), the q/o/do row blocks, and one
-    score tile."""
-    kv = 2 * t * d * itemsize
-    rows = 3 * block_q * d * itemsize
+    score tile.  ``d`` is the width of q and k, ``dv`` that of v, o
+    and do where it differs."""
+    kv = resident_row_bytes(t, d, itemsize, dv)
+    rows = block_q * (d + 2 * (d if dv is None else dv)) * itemsize
     return kv + rows + score_tile_bytes(block_q, block_k) + (1 << 18)
 
 
@@ -132,35 +139,55 @@ def room_for_second_tile(resident, block_q, block_k, itemsize):
     return 2 * resident + 2 * tile <= SCOPED_VMEM_BYTES
 
 
-def block_sizes(t, block_q, block_k, d=64, itemsize=2):
+def block_sizes(t, block_q, block_k, d=64, itemsize=2, dv=None):
     """Clamp requested blocks to divide t AND fit the VMEM budget —
     an oversized config degrades to the largest fitting one instead of
-    failing to compile (round-3's 2048-wide failure mode)."""
+    failing to compile (round-3's 2048-wide failure mode).  ``dv``:
+    vmem_estimate()'s.
+
+    Where the resident K/V rows alone pass the budget at the smallest
+    blocks (float32 rows of an 8k sequence at 192 + 128: 10.5 MB), no
+    block size helps: the blocks are clamped as if those rows were
+    free, and the calls ask Mosaic for the scoped VMEM they need
+    (scoped_vmem) instead of its default."""
     block_q = min(block_q, t)
     block_k = min(block_k, t)
     while t % block_q:
         block_q //= 2
     while t % block_k:
         block_k //= 2
-    while vmem_estimate(t, d, block_q, block_k, itemsize) > \
-            VMEM_BUDGET_BYTES and max(block_q, block_k) > 128:
+
+    def over(bq, bk, free=0):
+        return vmem_estimate(t, d, bq, bk, itemsize, dv) - free > \
+            VMEM_BUDGET_BYTES
+
+    free = 0
+    if over(min(block_q, 128), min(block_k, 128)):
+        free = resident_row_bytes(t, d, itemsize, dv)
+    while over(block_q, block_k, free) and max(block_q, block_k) > 128:
         if block_k >= block_q and block_k > 128:
             block_k //= 2
         else:
             block_q //= 2
-    if vmem_estimate(t, d, block_q, block_k, itemsize) > \
-            VMEM_BUDGET_BYTES:
-        # the resident K/V rows alone exceed the budget (huge t*d):
-        # block shrinking cannot help — surface it so a compile
-        # failure is attributable; sequences this long belong on the
-        # ring-attention path (T sharded over 'sp'), not one kernel
-        import logging
-        logging.getLogger(__name__).warning(
-            'pallas kernel t=%d d=%d: K/V residency exceeds the '
-            'VMEM budget at the smallest blocks (%d/%d); compile may '
-            'fail — use ring attention / sequence parallelism for '
-            'this length', t, d, block_q, block_k)
     return block_q, block_k
+
+
+def scoped_vmem(t, d, block_q, block_k, itemsize, dv=None):
+    """The ``vmem_limit_bytes`` a forward, dq or dkv instance asks
+    Mosaic for: None (the compiler's default, SCOPED_VMEM_BYTES) while
+    the full rows it keeps resident (K and V, or Q and dO: t x (d +
+    dv)), which the pipeline holds in two buffers, take less than the
+    whole budget the block clamp works to; every call did before an
+    8k sequence at 192 + 128.  From there on the default leaves the
+    tiles under 6 MB (the cell's bfloat16 dkv call asked for 16.56 of
+    16 MB inside its train step), and the call asks for twice its
+    estimate and 16 MB for the tiles' temporaries (a float32 dkv call
+    with 512 x 1024 tiles takes 42.4 MB: its full-precision products
+    split every operand), of the 128 MB a v5e core has."""
+    if 2 * resident_row_bytes(t, d, itemsize, dv) < VMEM_BUDGET_BYTES:
+        return None
+    estimate = vmem_estimate(t, d, block_q, block_k, itemsize, dv)
+    return min(2 * estimate + (16 << 20), 100 << 20)
 
 
 def record_dispatch(kernel, fused, reason, interpret=False):

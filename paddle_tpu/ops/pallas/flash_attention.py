@@ -21,6 +21,12 @@ scale, bias, causal mask, dropout multiplier) and the tile loop
 between callers — bias, rate, causal, whether 1/sqrt(d) is a power of
 two, operand width — is static at trace time.
 
+q and k share one width d (the contraction of q k^T, and the softmax
+scale 1/sqrt(d)); v, and with it o and do, may have another, dv (a
+latent-attention head: 192-wide keys over 128-wide values).  Every
+product then runs at its own width (q k^T, dq, dk over d; p v, dv,
+dO v^T over dv) and nothing is padded to the wider one.
+
 An optional additive key bias [B, T] (padding masks, per-key biases)
 is applied to the scores inside the kernels — the BERT input-mask path
 (models/bert.py) — and receives a real gradient so learned biases work.
@@ -288,9 +294,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     bias_ref = rest.pop(0) if has_bias else None
     seed_ref = rest.pop(0) if rate else None
     o_ref, lse_ref = rest
-    # q_ref: [1, bq, d]; k/v_ref: [1, T, d]; bias_ref: [1, 1, T];
-    # o_ref: [1, bq, d]; lse_ref: [1, 1, bq]  (the singleton middle dim
-    # satisfies the TPU block-shape rule for 1-D-per-row operands)
+    # q_ref: [1, bq, d]; k_ref: [1, T, d]; v_ref: [1, T, dv]; bias_ref:
+    # [1, 1, T]; o_ref: [1, bq, dv]; lse_ref: [1, 1, bq]  (the singleton
+    # middle dim satisfies the TPU block-shape rule for 1-D-per-row
+    # operands)
     # dots consume the native (usually bf16) dtype and accumulate in
     # f32 (_dot): the MXU runs bf16 at 2x f32 throughput and VMEM
     # traffic halves — the pre-cast-to-f32 variant measured ~25%
@@ -334,7 +341,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
     m0 = jnp.full((bq,), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
+    acc0 = jnp.zeros((bq, v_ref.shape[2]), jnp.float32)
     # skip the K blocks no query of this block sees
     lo, hi = _key_blocks(q_off, bq, block_k, nk, causal, window)
     m, l, acc = _loop(lo, hi, body, (m0, l0, acc0), tiles)
@@ -483,7 +490,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     # nothing
     j0, j1 = _query_blocks(k_off, bk, block_q, nq, causal, window)
     dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
+    dv0 = jnp.zeros(v.shape, jnp.float32)
     db0 = jnp.zeros((bk,), jnp.float32)
     dk, dv, dbias = _loop(j0, j1, body, (dk0, dv0, db0), tiles)
     if exact:
@@ -600,7 +607,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         j0, j1 = _query_blocks(i * block_k, block_k, block_q, nq,
                                causal, window)
         dk0 = jnp.zeros((block_k, d), jnp.float32)
-        dv0 = jnp.zeros((block_k, d), jnp.float32)
+        dv0 = jnp.zeros((block_k, v_ref.shape[2]), jnp.float32)
         db0 = jnp.zeros((block_k,), jnp.float32)
         dk, dv, dbias = _loop(j0, j1, q_step, (dk0, dv0, db0), tiles)
         if exact:
@@ -633,13 +640,14 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
     """pallas_call plumbing for the one-pass backward: grid (BH,), or
     (B*Hkv, group) where ``group`` query heads share a K/V head."""
     bh, t, d = q.shape
+    dv = v.shape[2]
     group = bh // k.shape[0]
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
     has_glse = glse3 is not None
     tiles, dp_early = _second_tile(
         None if causal else t // block_q,
-        _fused_bwd_resident(t, d, block_k, q.dtype.itemsize, group),
+        _fused_bwd_resident(t, d, block_k, q.dtype.itemsize, group, dv),
         block_q, block_k, q.dtype.itemsize)
     kernel = functools.partial(
         _flash_bwd_fused_kernel, scale=scale, causal=causal,
@@ -650,10 +658,13 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
     def head(*ids):     # the query head of a grid step
         return ids[0] if group == 1 else ids[0] * group + ids[1]
 
-    row = pl.BlockSpec((1, t, d), lambda *ids: (head(*ids), 0, 0))
+    def rows(width, kv=False):      # one head's [t, width] rows
+        return pl.BlockSpec(
+            (1, t, width),
+            lambda *ids: (ids[0] if kv else head(*ids), 0, 0))
+
     vec = pl.BlockSpec((1, 1, t), lambda *ids: (head(*ids), 0, 0))
-    kv_row = pl.BlockSpec((1, t, d), lambda *ids: (ids[0], 0, 0))
-    in_specs = [row, kv_row, kv_row]
+    in_specs = [rows(d), rows(d, True), rows(dv, True)]
     operands = [q, k, v]
     if has_bias:
         in_specs.append(pl.BlockSpec(
@@ -662,12 +673,12 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
     if rate:
         in_specs.append(pl.BlockSpec((1, 4), lambda *ids: (0, 0)))
         operands.append(seed2)
-    in_specs += [row, vec, vec]
+    in_specs += [rows(dv), vec, vec]
     operands += [do, lse3, delta3]
     if has_glse:
         in_specs.append(vec)
         operands.append(glse3)
-    out_specs = [row, kv_row, kv_row]
+    out_specs = [rows(d), rows(d, True), rows(dv, True)]
     out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype),
                  jax.ShapeDtypeStruct(k.shape, k.dtype),
                  jax.ShapeDtypeStruct(v.shape, v.dtype)]
@@ -675,14 +686,17 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
         out_specs.append(vec)
         out_shape.append(jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
     from jax.experimental.pallas import tpu as pltpu
-    scratch = pltpu.VMEM((t, d), jnp.float32)
+    scratch = [pltpu.VMEM((t, d), jnp.float32)]         # dq
+    if group > 1:                                       # dk, dv
+        scratch += [pltpu.VMEM((t, d), jnp.float32),
+                    pltpu.VMEM((t, dv), jnp.float32)]
     res = pl.pallas_call(
         kernel,
         grid=(bh,) if group == 1 else (bh // group, group),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[scratch] * (1 if group == 1 else 3),
+        scratch_shapes=scratch,
         interpret=interpret,
     )(*operands)
     if has_bias:
@@ -705,21 +719,23 @@ FUSED_BLOCK_Q = 512
 FUSED_BLOCK_K = 512
 
 
-def _fused_bwd_resident(t, d, block_k, itemsize, group=1):
+def _fused_bwd_resident(t, d, block_k, itemsize, group=1, dv=None):
     """What a fused-backward instance holds beside its score tiles:
-    q/k/v/do full rows, the f32 dq accumulator (and, where ``group``
-    query heads share a K/V head, the dk and dv ones) and the dk/dv
-    f32 blocks (x2 slack for compiler temporaries)."""
-    rows = 4 * t * d * itemsize
-    accs = (1 if group == 1 else 3) * t * d * 4
-    return rows + accs + 2 * 2 * block_k * d * 4 + (1 << 19)
+    q/k (``d`` wide) and v/do (``dv`` wide) full rows, the f32 dq
+    accumulator (and, where ``group`` query heads share a K/V head,
+    the dk and dv ones) and the dk/dv f32 blocks (x2 slack for
+    compiler temporaries)."""
+    dv = d if dv is None else dv
+    rows = 2 * t * (d + dv) * itemsize
+    accs = t * d * 4 + (0 if group == 1 else t * (d + dv) * 4)
+    return rows + accs + 2 * block_k * (d + dv) * 4 + (1 << 19)
 
 
-def _fused_bwd_vmem(t, d, block_q, block_k, itemsize, group=1):
+def _fused_bwd_vmem(t, d, block_q, block_k, itemsize, group=1, dv=None):
     """Resident bytes for the fused backward, one tile a trip: each
     tile has two chains (s -> p and dp -> ds), so two of
     common.score_tile_bytes()."""
-    return _fused_bwd_resident(t, d, block_k, itemsize, group) + \
+    return _fused_bwd_resident(t, d, block_k, itemsize, group, dv) + \
         2 * _common.score_tile_bytes(block_q, block_k)
 
 
@@ -739,10 +755,22 @@ def _second_tile(trips, resident, block_q, block_k, itemsize):
     return tiles, room and tiles == 1
 
 
-def _rows_resident(t, d, block_q, block_k, itemsize):
+def _mosaic_params(t, d, block_q, block_k, itemsize, dv):
+    """pallas_call's ``compiler_params`` for a forward, dq or dkv
+    call: none but where its resident rows ask for more scoped VMEM
+    than the compiler's default (common.scoped_vmem)."""
+    limit = _common.scoped_vmem(t, d, block_q, block_k, itemsize, dv)
+    if limit is None:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {'compiler_params': pltpu.CompilerParams(
+        vmem_limit_bytes=limit)}
+
+
+def _rows_resident(t, d, block_q, block_k, itemsize, dv=None):
     """What a forward, dq or dkv instance holds beside its score
     tile, as vmem_estimate() counts it."""
-    return _vmem_estimate(t, d, block_q, block_k, itemsize) - \
+    return _vmem_estimate(t, d, block_q, block_k, itemsize, dv) - \
         _common.score_tile_bytes(block_q, block_k)
 
 
@@ -758,15 +786,16 @@ def _window_blocks(blocks, window):
 
 def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
                interpret, rate=0.0, window=0):
-    """q: [BH, T, D], k,v: [B*Hkv, T, D] (query head i reads K/V head
-    i // (H / Hkv)), bias: [B, T] or None, seed: packed (1,4)
-    uint32 [seed, q_off, k_off, g_off] (_pack_seed, required when
-    rate>0) -> (o [BH,T,D], lse [BH,T])."""
+    """q: [BH, T, D], k: [B*Hkv, T, D], v: [B*Hkv, T, Dv] (query head
+    i reads K/V head i // (H / Hkv)), bias: [B, T] or None, seed:
+    packed (1,4) uint32 [seed, q_off, k_off, g_off] (_pack_seed,
+    required when rate>0) -> (o [BH,T,Dv], lse [BH,T])."""
     _, t, d = q.shape
     return _fwd_call(
         q, k, v, bias, seed, h=h, causal=causal,
         blocks=_window_blocks(
-            _block_sizes(t, block_q, block_k, d, q.dtype.itemsize),
+            _block_sizes(t, block_q, block_k, d, q.dtype.itemsize,
+                         v.shape[2]),
             window),
         interpret=interpret, rate=rate, window=window)
 
@@ -784,13 +813,14 @@ def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
 def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
               rate, window=0):
     bh, t, d = q.shape
+    dv = v.shape[2]
     group = bh // k.shape[0]
     block_q, block_k = blocks
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
     tiles, _ = _second_tile(
         None if causal else t // block_k,
-        _rows_resident(t, d, block_q, block_k, q.dtype.itemsize),
+        _rows_resident(t, d, block_q, block_k, q.dtype.itemsize, dv),
         block_q, block_k, q.dtype.itemsize)
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal, block_k=block_k,
@@ -799,7 +829,7 @@ def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, t, d), lambda i, j: (i // group, 0, 0)),
-        pl.BlockSpec((1, t, d), lambda i, j: (i // group, 0, 0)),
+        pl.BlockSpec((1, t, dv), lambda i, j: (i // group, 0, 0)),
     ]
     operands = [q, k, v]
     if has_bias:
@@ -814,14 +844,15 @@ def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         interpret=interpret,
+        **_mosaic_params(t, d, block_q, block_k, q.dtype.itemsize, dv),
     )(*operands)
     return o, lse3[:, 0, :]
 
@@ -829,8 +860,10 @@ def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
 def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
                block_q, block_k, interpret, rate=0.0, window=0):
     bh, t, d = q.shape
+    dv = v.shape[2]
     block_q, block_k = _window_blocks(
-        _block_sizes(t, block_q, block_k, d, q.dtype.itemsize), window)
+        _block_sizes(t, block_q, block_k, d, q.dtype.itemsize, dv),
+        window)
     fq, fk = min(block_q, FUSED_BLOCK_Q), min(block_k, FUSED_BLOCK_K)
     while t % fq:
         fq //= 2
@@ -838,7 +871,7 @@ def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
         fk //= 2
     fused = FUSED_BWD and _fused_bwd_vmem(
         t, d, fq, fk, q.dtype.itemsize,
-        bh // k.shape[0]) <= VMEM_BUDGET_BYTES
+        bh // k.shape[0], dv) <= VMEM_BUDGET_BYTES
     return _bwd_call(
         q, k, v, bias, seed, o, lse, do, g_lse, h=h, causal=causal,
         blocks=(fq, fk) if fused else (block_q, block_k), fused=fused,
@@ -850,6 +883,7 @@ def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
 def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
               blocks, fused, interpret, rate, window=0):
     bh, t, d = q.shape
+    dv = v.shape[2]
     group = bh // k.shape[0]
     block_q, block_k = blocks
     scale = 1.0 / (d ** 0.5)
@@ -869,9 +903,12 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
                                 glse3, h, causal, block_q, block_k,
                                 interpret, rate, window)
 
-    resident = _rows_resident(t, d, block_q, block_k, q.dtype.itemsize)
+    resident = _rows_resident(t, d, block_q, block_k, q.dtype.itemsize,
+                              dv)
     tiles, _ = _second_tile(None if causal else t // block_k, resident,
                             block_q, block_k, q.dtype.itemsize)
+    more_vmem = _mosaic_params(t, d, block_q, block_k,
+                               q.dtype.itemsize, dv)
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, scale=scale, causal=causal,
         block_k=block_k, tiles=tiles, has_bias=has_bias,
@@ -879,7 +916,7 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, t, d), lambda i, j: (i // group, 0, 0)),
-        pl.BlockSpec((1, t, d), lambda i, j: (i // group, 0, 0)),
+        pl.BlockSpec((1, t, dv), lambda i, j: (i // group, 0, 0)),
     ]
     dq_operands = [q, k, v]
     if has_bias:
@@ -890,7 +927,7 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
         dq_specs.append(seed_spec)
         dq_operands.append(seed2)
     dq_specs += [
-        pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
+        pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
         pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
     ]
@@ -906,6 +943,7 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        **more_vmem,
     )(*dq_operands)
 
     tiles, dp_early = _second_tile(
@@ -921,11 +959,16 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
     def head(*ids):     # the query head of a grid step
         return ids[0] if group == 1 else ids[0] * group + ids[2]
 
-    q_rows = pl.BlockSpec((1, t, d), lambda *ids: (head(*ids), 0, 0))
-    q_vec = pl.BlockSpec((1, 1, t), lambda *ids: (head(*ids), 0, 0))
-    kv_block = pl.BlockSpec((1, block_k, d),
+    def q_rows(width):      # the query head's [t, width] rows
+        return pl.BlockSpec((1, t, width),
+                            lambda *ids: (head(*ids), 0, 0))
+
+    def kv_block(width):
+        return pl.BlockSpec((1, block_k, width),
                             lambda *ids: (ids[0], ids[1], 0))
-    dkv_specs = [q_rows, kv_block, kv_block]
+
+    q_vec = pl.BlockSpec((1, 1, t), lambda *ids: (head(*ids), 0, 0))
+    dkv_specs = [q_rows(d), kv_block(d), kv_block(dv)]
     dkv_operands = [q, k, v]
     if has_bias:
         dkv_specs.append(pl.BlockSpec(
@@ -935,12 +978,12 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
     if rate:
         dkv_specs.append(pl.BlockSpec((1, 4), lambda *ids: (0, 0)))
         dkv_operands.append(seed2)
-    dkv_specs += [q_rows, q_vec, q_vec]
+    dkv_specs += [q_rows(dv), q_vec, q_vec]
     dkv_operands += [do, lse3, delta3]
     if has_glse:
         dkv_specs.append(q_vec)
         dkv_operands.append(glse3)
-    out_specs = [kv_block, kv_block]
+    out_specs = [kv_block(d), kv_block(dv)]
     out_shape = [
         jax.ShapeDtypeStruct(k.shape, k.dtype),
         jax.ShapeDtypeStruct(v.shape, v.dtype),
@@ -952,7 +995,8 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
     scratch = []
     if group > 1:
         from jax.experimental.pallas import tpu as pltpu
-        scratch = [pltpu.VMEM((block_k, d), jnp.float32)] * 2
+        scratch = [pltpu.VMEM((block_k, d), jnp.float32),
+                   pltpu.VMEM((block_k, dv), jnp.float32)]
     res = pl.pallas_call(
         dkv_kernel,
         grid=(bh, t // block_k) if group == 1
@@ -962,6 +1006,7 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        **more_vmem,
     )(*dkv_operands)
     if has_bias:
         dk, dv, dbias_bh = res
@@ -1061,7 +1106,8 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
     log-sum-exp [B, H, T] of the undropped scores (the
     flash_attention_with_lse contract).  K/V of fewer heads than q
     are repeated over their group here (the kernels read them through
-    their index maps instead); ``window`` bands the causal mask."""
+    their index maps instead); ``window`` bands the causal mask.  v
+    may be of another width than q and k: the scale is q's."""
     b, t, h, d = q.shape
     if k.shape[2] != h:
         k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
@@ -1094,10 +1140,12 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
 
 def _check_mask_and_heads(q, k, v, causal, window):
     h, hkv = q.shape[2], k.shape[2]
-    if k.shape != v.shape or hkv < 1 or h % hkv:
+    if k.shape[:3] != v.shape[:3] or k.shape[3] != q.shape[3] or \
+            hkv < 1 or h % hkv:
         raise ValueError(
-            'attention: K and V must have one shape and a head count '
-            'that divides Q\'s; got Q %r, K %r, V %r'
+            'attention: K and V must have one batch, length and head '
+            'count, which divides Q\'s, and K the width of Q (V may '
+            'have its own); got Q %r, K %r, V %r'
             % (q.shape, k.shape, v.shape))
     if window and not causal:
         raise ValueError('attention: a window (%r) bands the causal '
@@ -1109,10 +1157,12 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
                     min_seq=None, dropout_rate=0.0, dropout_seed=None,
                     dropout_offsets=None, dropout_g_offset=0,
                     auto_partitioned=False, window=0):
-    """q: [B, T, H, D]; k,v: [B, T, Hkv, D] with Hkv a divisor of H
-    (grouped K/V: query head i attends K/V head i // (H / Hkv));
+    """q: [B, T, H, D]; k: [B, T, Hkv, D], v: [B, T, Hkv, Dv] with Hkv
+    a divisor of H (grouped K/V: query head i attends K/V head
+    i // (H / Hkv)) and Dv = D unless the values are narrower or wider
+    than the keys (scores are scaled by 1/sqrt(D); nothing is padded);
     key_bias: optional [B, T] additive score bias (e.g. padding mask
-    as 0 / -10000) -> [B, T, H, D].  ``window`` > 0 (with ``causal``)
+    as 0 / -10000) -> [B, T, H, Dv].  ``window`` > 0 (with ``causal``)
     bands the mask: query i sees the keys j with 0 <= i - j < window,
     and the kernels skip the blocks wholly outside the band as they
     skip those above the diagonal.
@@ -1149,7 +1199,7 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
                            dropout_g_offset, window=window)
 
     def to_bh(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(-1, t, d)
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(-1, t, x.shape[3])
 
     if key_bias is not None:
         key_bias = key_bias.astype(jnp.float32)
@@ -1157,7 +1207,7 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
                       dropout_g_offset) if rate else None
     out = _flash(to_bh(q), to_bh(k), to_bh(v), key_bias, seed, h,
                  causal, rate, interpret, window)
-    return jnp.transpose(out.reshape(b, h, t, d), (0, 2, 1, 3))
+    return jnp.transpose(out.reshape(b, h, t, v.shape[3]), (0, 2, 1, 3))
 
 
 def flash_attention_with_lse(q, k, v, causal=False, key_bias=None,

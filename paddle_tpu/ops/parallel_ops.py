@@ -276,7 +276,8 @@ def _prefix(attrs, tokens, top_k):
 
 
 @register('moe_route',
-          no_grad_out_slots=('TopKIdx', 'Load', 'HeldLoad'))
+          no_grad_out_slots=('TopKIdx', 'Load', 'HeldLoad',
+                             'ScoreBiasOut'))
 def moe_route_op(ctx, ins, attrs):
     """X [..., D], Gate [D, E] -> TopKIdx [S, k] int32, TopKWeight
     [S, k] f32, AuxLoss [] (load-balance), ZLoss [] (router z-loss),
@@ -286,19 +287,34 @@ def moe_route_op(ctx, ins, attrs):
     bfloat16.  attrs: top_k, renormalize, scale (a factor on the
     gates), experts_held ((first, count): the router stays E wide, and
     HeldLoad [count] is Load's slice of the held experts, the group
-    sizes of a layer that holds only those)."""
-    from ..parallel.moe import route_topk
+    sizes of a layer that holds only those), score_func ('softmax',
+    the default, or 'sigmoid').
+
+    ScoreBias [E] f32 (sigmoid scores only) is added to the scores for
+    the choice of the k experts and for nothing else
+    (parallel.moe.route_topk); it takes no gradient.  With
+    attrs['bias_update_rate'] > 0 the op also emits ScoreBiasOut, the
+    bias after this step's loads (parallel.moe.bias_update), which the
+    layer writes to the persistable bias as ``batch_norm`` does its
+    MeanOut; under attrs['is_test'] (a ``for_test`` clone) it is not
+    emitted and the bias stays as it is."""
+    from ..parallel.moe import bias_update, route_topk
     _no_expert_axis(attrs)
     x, wg = ins['X'][0], ins['Gate'][0]
+    bias = ins['ScoreBias'][0] if ins.get('ScoreBias') else None
     idx, weight, balance, z, load = route_topk(
         x.reshape(-1, x.shape[-1]), wg, int(attrs['top_k']),
         bool(attrs.get('renormalize', False)),
-        float(attrs.get('scale', 1.0)))
+        float(attrs.get('scale', 1.0)),
+        attrs.get('score_func', 'softmax'), bias)
     outs = {'TopKIdx': [idx], 'TopKWeight': [weight],
             'AuxLoss': [balance], 'ZLoss': [z], 'Load': [load]}
     held = _held(attrs)
     if held is not None:
         outs['HeldLoad'] = [load[held[0]:held[0] + held[1]]]
+    rate = float(attrs.get('bias_update_rate', 0.0) or 0.0)
+    if bias is not None and rate and not attrs.get('is_test', False):
+        outs['ScoreBiasOut'] = [bias_update(bias, load, rate)]
     return outs
 
 

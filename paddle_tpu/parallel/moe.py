@@ -182,28 +182,52 @@ def reference_moe_ffn(x, wg, w1_full, w2_full, capacity_factor=2.0,
 # ---------------------------------------------------------------------------
 
 
-def route_topk(x, wg, top_k, renormalize=False, scale=1.0):
+def route_topk(x, wg, top_k, renormalize=False, scale=1.0,
+               score_func='softmax', choice_bias=None):
     """The router, in float32 whatever ``x`` is.  x [S, D], wg [D, E] ->
     (idx [S, k] int32, weight [S, k] f32, balance loss, z-loss,
     load [E] int32).
 
-    ``weight`` are the top-k of the softmax over ALL experts, divided
-    by their sum only under ``renormalize`` (OLMoE publishes
-    ``norm_topk_prob: false``), times ``scale`` (a routed scaling
-    factor; 1.0 multiplies nothing).  Balance loss: E * sum_e f_e *
-    P_e with f_e the share of tokens that picked e among their k (sums
-    to k)
-    and P_e the mean router probability.  z-loss: mean over tokens of
-    logsumexp(logits)^2.  ``load`` counts the (token, expert) pairs of
-    each expert and sums to S*k."""
+    ``score_func`` 'softmax' (the default): ``weight`` are the top-k of
+    the softmax over ALL experts, divided by their sum only under
+    ``renormalize`` (OLMoE publishes ``norm_topk_prob: false``), times
+    ``scale`` (a routed scaling factor; 1.0 multiplies nothing).
+
+    ``score_func`` 'sigmoid': each expert's score is the sigmoid of its
+    own logit.  ``choice_bias`` [E] (sigmoid only) enters the CHOICE
+    and nothing else: the k experts are the largest of score + bias,
+    and ``weight`` are their plain scores, under ``renormalize``
+    divided by (their sum + 1e-20), times ``scale``: the bias picks and
+    never weighs, and takes no gradient (the auxiliary-loss-free
+    balancing of DeepSeek-V3, ``topk_method: noaux_tc`` with one
+    group; bias_update() moves it).
+
+    Balance loss: E * sum_e f_e * P_e with f_e the share of tokens that
+    picked e among their k (sums to k) and P_e the mean router
+    probability (sigmoid: the scores divided by their sum over the
+    experts).  z-loss: mean over tokens of logsumexp(logits)^2.
+    ``load`` counts the (token, expert) pairs of each expert and sums
+    to S*k."""
     n_experts = wg.shape[-1]
     logits = jnp.dot(x.astype(jnp.float32), wg.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     lse = jax.nn.logsumexp(logits, axis=-1)
-    probs = jnp.exp(logits - lse[:, None])
-    weight, idx = jax.lax.top_k(probs, top_k)
-    if renormalize:
-        weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    if score_func == 'softmax':
+        probs = jnp.exp(logits - lse[:, None])
+        weight, idx = jax.lax.top_k(probs, top_k)
+        if renormalize:
+            weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        biased = scores if choice_bias is None else \
+            scores + jax.lax.stop_gradient(
+                choice_bias.astype(jnp.float32))[None, :]
+        _, idx = jax.lax.top_k(biased, top_k)
+        weight = jnp.take_along_axis(scores, idx, axis=-1)
+        if renormalize:
+            weight = weight / (jnp.sum(weight, axis=-1, keepdims=True)
+                               + 1e-20)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
     if scale != 1.0:
         weight = weight * scale
     picked = jnp.sum(jax.nn.one_hot(idx, n_experts, dtype=jnp.int32),
@@ -213,6 +237,16 @@ def route_topk(x, wg, top_k, renormalize=False, scale=1.0):
     balance = n_experts * jnp.sum(share * jnp.mean(probs, axis=0))
     return (idx.astype(jnp.int32), weight, balance,
             jnp.mean(jnp.square(lse)), load.astype(jnp.int32))
+
+
+def bias_update(choice_bias, load, rate):
+    """The choice bias after one train step: each expert's moves by
+    ``rate`` towards the mean load, up where it was picked less than
+    the mean and down where more (b += rate * sign(mean - load), the
+    DeepSeek-V3 report's rule)."""
+    load = load.astype(jnp.float32)
+    return choice_bias + rate * jnp.sign(jnp.mean(load) - load).astype(
+        choice_bias.dtype)
 
 
 def held_rows_bound(tokens, top_k, held=None):

@@ -179,6 +179,49 @@ def test_banded_grouped_flash_at_the_laguna_cell_shapes(
                for n in names), names
 
 
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+def test_latent_flash_at_the_moonlight_cell_shape(one_chip, as_on_tpu,
+                                                  dtype):
+    """moonlight_16b_s8192: b1 t8192 h16, queries and keys 192 wide
+    over values 128 wide, causal.  The fused backward's residency is
+    over the budget (two-pass: forward, dq, dkv), and the resident
+    rows of an 8k sequence at 192 + 128, which the pipeline keeps
+    twice, are the block clamp's whole budget in bfloat16 and twice it
+    in float32: these calls ask Mosaic for more scoped VMEM than its
+    default (common.scoped_vmem; inside the cell's train step the
+    bfloat16 dkv call was refused at 16.56M of 16M without).  bfloat16
+    is the timed step, float32 ``chip_smoke.py --phase moonlight`` and
+    the cell's reference check.  The calls carry the scope the op
+    lowers them in."""
+    import re
+    b, t, h, d, dv = 1, 8192, 16, 192, 128
+    item = jnp.dtype(dtype).itemsize
+    assert flash_attention._fused_bwd_vmem(
+        t, d, flash_attention.FUSED_BLOCK_Q, flash_attention.FUSED_BLOCK_K,
+        item, 1, dv) > flash_attention.VMEM_BUDGET_BYTES
+    blocks = common.block_sizes(t, 512, 1024, d, item, dv)
+    assert common.scoped_vmem(t, d, *blocks, item, dv) > \
+        common.SCOPED_VMEM_BYTES
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            with jax.named_scope('fused_multihead_attention'):
+                with jax.named_scope('qk%dv%d' % (d, dv)):
+                    o = flash_attention.flash_attention(q, k, v,
+                                                        causal=True)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    text = _compiled(step, one_chip, _spec((b, t, h, d), dtype),
+                     _spec((b, t, h, d), dtype),
+                     _spec((b, t, h, dv), dtype)).as_text()
+    _compiled_on_chip('flash_attention')
+    names = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert len(names) == 3, names
+    assert all(n.startswith('qk192v128') for n in names), names
+
+
 @pytest.mark.parametrize('dtype,b,t,h,d,fused', [
     # what the compiler asks for moves with the grid, so the cells'
     # own: bert_base_s2048's fused backward, two [512, 512] tiles a trip
