@@ -103,8 +103,11 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
     first; the grouped matmuls get those groups' sizes only, over a
     buffer of tokens x min(top_k, count) rows (the most that can be
     held), and what lies past the last group is neither computed nor
-    counted as dropped.  What every chip computes alike (a shared
-    expert) is the model's to add, once.
+    counted as dropped; the weighted sum back and the gradients walk
+    that buffer only as far as its rows are held, so the permutation
+    costs what the layer holds and its time follows the routing.  What
+    every chip computes alike (a shared expert) is the model's to add,
+    once.
 
     ``score_func='sigmoid'`` (dropless only) scores each expert by the
     sigmoid of its own logit instead of the softmax over all;
@@ -236,9 +239,7 @@ def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
         # the groups the matmuls are handed: the held experts' loads
         sizes = route_outs['HeldLoad'] = var('int32', True)
         route_attrs['experts_held'] = list(held)
-        # the router's width tells the permutation how small a share
-        # of its buffer is held (parallel.moe.held_rows_prefix)
-        held_attrs = {'experts_held': list(held), 'num_experts': e}
+        held_attrs = {'experts_held': list(held)}
     route_ins = {'X': x, 'Gate': wg}
     if score_func != 'softmax':
         route_attrs['score_func'] = score_func
@@ -318,7 +319,7 @@ def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
                               moe_stats.record)
     if held is not None:
         # moe/rows_held, moe/held_share, moe/held_rows_max,
-        # moe/prefix_overflows
+        # moe/walked_share
         held_layers = moe_stats.HeldLayers.of(helper.main_program)
         held_layers.top_k.append(top_k)
         helper.main_program.watch([load.name, sizes.name], held_layers)

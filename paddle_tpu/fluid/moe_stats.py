@@ -28,11 +28,11 @@ reports, on the same runs:
   routing is even);
 - gauge ``moe/held_rows_max``: the most rows any such layer held on
   the last run read;
-- counter ``moe/prefix_overflows``: the layers, on the runs read, that
-  held more rows than the prefix of their buffer the permutation walks
-  (``parallel.moe.held_rows_prefix``), so that its whole-buffer arm
-  ran: slower, never different.  0 as long as the routing stays
-  within a few times an even one.
+- gauge ``moe/walked_share``: the rows such layers' permutation
+  walked on the last run read over the rows of their buffers: it
+  walks a buffer in chunks (``parallel.moe.held_rows_chunk``) up to
+  the one that holds the last held row, so this is what the
+  permutation's time follows.
 
 A layer whose router carries a choice bias that its train program
 moves (``score_bias`` with a ``bias_update_rate``) reports:
@@ -68,8 +68,8 @@ def record(values):
 class HeldLayers(object):
     """The record of a program's layers that hold a range of their
     experts.  ``Program.watch`` hands a record the fetched values and
-    nothing else, and a layer's prefix also takes its ``top_k``: each
-    layer leaves it here as it asks to be watched."""
+    nothing else, and a layer's buffer is as long as its ``top_k``
+    says: each layer leaves it here as it asks to be watched."""
 
     def __init__(self):
         self.top_k = []
@@ -88,20 +88,22 @@ def record_held(values, top_k):
     """``values``: load [E], held load [count], ... one pair a layer
     that holds a range of its experts, as fetched; ``top_k``: one a
     layer."""
-    from ..parallel.moe import held_rows_prefix
-    routed = held = most = over = 0
+    from ..parallel.moe import held_rows_bound, held_rows_chunk
+    routed = held = most = walked = buffers = 0
     for load, mine, k in zip(values[0::2], values[1::2], top_k):
         pairs = int(np.asarray(load, np.int64).sum())
         rows = int(np.asarray(mine, np.int64).sum())
         routed += pairs
         held += rows
         most = max(most, rows)
-        over += rows > held_rows_prefix(pairs // k, k, (0, len(mine)),
-                                        len(load))
+        n_rows = held_rows_bound(pairs // k, k, (0, len(mine)))
+        chunk = held_rows_chunk(n_rows)
+        walked += min(-(-rows // chunk) * chunk, n_rows)
+        buffers += n_rows
     monitor.add('moe/rows_held', float(held))
-    monitor.add('moe/prefix_overflows', float(over))
     monitor.set_gauge('moe/held_share', held / max(routed, 1.0))
     monitor.set_gauge('moe/held_rows_max', float(most))
+    monitor.set_gauge('moe/walked_share', walked / max(buffers, 1.0))
 
 
 def record_bias(values):

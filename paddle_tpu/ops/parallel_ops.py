@@ -262,19 +262,6 @@ def _held(attrs):
     return None if not held else (int(held[0]), int(held[1]))
 
 
-def _prefix(attrs, tokens, top_k):
-    """How many of its buffer's first rows the permutation of a layer
-    that holds a range of its experts walks
-    (parallel.moe.held_rows_prefix; attrs['num_experts'] is the
-    router's width, which ``layers.moe`` leaves beside experts_held);
-    None where all experts are held."""
-    from ..parallel.moe import held_rows_prefix
-    held = _held(attrs)
-    if held is None:
-        return None
-    return held_rows_prefix(tokens, top_k, held, int(attrs['num_experts']))
-
-
 @register('moe_route',
           no_grad_out_slots=('TopKIdx', 'Load', 'HeldLoad',
                              'ScoreBiasOut'))
@@ -331,9 +318,8 @@ def moe_dispatch_op(ctx, ins, attrs):
     S * min(k, count) (parallel.moe.held_rows_bound): the held
     experts' rows come first and fill it at most; the pairs routed to
     absent experts lie past the last group, are not computed and are
-    no drops.  attrs['num_experts'] (the router's width, with
-    experts_held) lets the permutation walk a static prefix of that
-    buffer instead of all of it (_prefix)."""
+    no drops; the gradient walks that buffer's rows only as far as
+    GroupSizes says they are held (parallel.moe._sum_per_token)."""
     from ..parallel.moe import (dispatch_rows, held_rows_bound,
                                 rows_outside_their_group,
                                 sort_by_expert)
@@ -347,8 +333,7 @@ def moe_dispatch_op(ctx, ins, attrs):
         kept = order[:held_rows_bound(idx.shape[0], top_k, held)]
         held_rows = jnp.sum(sizes)
     rows = dispatch_rows(x.reshape(-1, x.shape[-1]), kept, inverse,
-                         top_k, held_rows,
-                         _prefix(attrs, idx.shape[0], top_k))
+                         top_k, held_rows)
     dropped = rows_outside_their_group(idx, order, sizes, held)
     return {'Rows': [rows], 'Order': [kept], 'Inverse': [inverse],
             'Dropped': [dropped.reshape(1)]}
@@ -385,12 +370,13 @@ def moe_combine_op(ctx, ins, attrs):
     Inverse -> Out [S, D]: each token's k outputs weighted and summed
     in float32, emitted in Rows' dtype.  With GroupSizes (a layer that
     holds a range of the experts) only the rows inside the groups
-    count: a pair routed to an absent expert adds nothing (attrs
-    experts_held, num_experts: as moe_dispatch's)."""
+    count: a pair routed to an absent expert adds nothing, and the
+    sum and its gradients walk the buffer only as far as the groups
+    reach (parallel.moe.combine_rows)."""
     from ..parallel.moe import combine_rows
     held_rows = jnp.sum(ins['GroupSizes'][0]) \
         if ins.get('GroupSizes') else None
     weight = ins['TopKWeight'][0].astype(jnp.float32)
     return {'Out': [combine_rows(
         ins['Rows'][0], weight, ins['Order'][0], ins['Inverse'][0],
-        held_rows, _prefix(attrs, *weight.shape))]}
+        held_rows)]}

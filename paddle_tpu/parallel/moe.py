@@ -21,8 +21,9 @@ backward is again a gather: the sort is a permutation, so its inverse
 replaces the scatter-add.  A layer that holds a RANGE of its experts
 (one chip's share of an expert-parallel layer) fills a small part of
 that buffer; there the weighted sum back and both backward bodies walk
-a static prefix of the buffer's rows instead of every pair, and the
-per-token sums are scatter-adds of those few rows (held_rows_prefix).
+the buffer's rows instead of every pair, in chunks up to the last row
+held, and the per-token sums are scatter-adds of those few rows
+(held_rows_chunk).
 """
 
 import functools
@@ -305,61 +306,84 @@ def rows_outside_their_group(idx, order, group_sizes, held=None):
 
 # A layer that holds a range of its experts fills a small part of its
 # worst-case buffer (held_rows_bound): 8 of 256 experts, top-10, hold
-# about 1/26 of it.  held_rows_prefix() is how many of its first rows
-# the permutation walks there, PREFIX_OVER_EVEN times what an even
-# routing holds.  Set from the loads of the laguna_s21_s4096 cell so
-# that no layer on any seed held more than half its prefix: its router
-# learns to pick the held experts (only their outputs reach the loss),
-# and one layer came to hold 5.5 times an even share within a run
-# (PERF.md section 6, PR 31).
-PREFIX_OVER_EVEN = 12
-_PREFIX_STEP = 512
+# about 1/26 of it, 8 of 64, top-6, a seventh; and its router learns to
+# pick the held experts (only their outputs reach the loss), so no
+# static share of the buffer is safe for long.  The weighted sum back
+# and both backward bodies therefore walk the buffer in chunks of
+# held_rows_chunk() rows and stop after the chunk that holds the last
+# held row: ``held_rows`` (a device scalar) bounds one loop on the
+# device, and the bodies cost what the rows held cost.
 
 
-def held_rows_prefix(tokens, top_k, held, n_experts):
-    """How many of the buffer's first rows dispatch_rows / combine_rows
-    walk where a layer holds the range ``held`` = (first, count) of its
-    ``n_experts``: PREFIX_OVER_EVEN times the rows an even routing
-    holds, in whole steps of 512, and the buffer's bound at most.  A
-    function of static shapes only, so the step's time does not follow
-    the routing; a routing that holds more takes the whole-buffer arm
-    (slower, never different)."""
-    rows = PREFIX_OVER_EVEN * tokens * top_k * held[1]
-    steps = -(-rows // (n_experts * _PREFIX_STEP))
-    return min(held_rows_bound(tokens, top_k, held), steps * _PREFIX_STEP)
+def held_rows_chunk(n_rows):
+    """How many of the buffer's rows one trip of a held layer's loops
+    walks: a function of the buffer's static length only.  On the chip
+    a trip costs what its rows cost plus a few microseconds of its own
+    (PERF.md section 6, PR 33), and the last chunk's dead rows cost
+    like live ones."""
+    return min(n_rows, 512)
 
 
-def _walk(row_side, n_rows, held_rows, prefix):
-    """``row_side(n)``, a body over the buffer's first n rows, for a
-    layer that holds a range of its experts: n is the static
-    ``prefix`` while the held rows (a device scalar) fit it, else the
-    whole buffer: slower, never different, and no row is ever cut.
-    One body and no conditional where the prefix is the buffer."""
-    if prefix is None or prefix >= n_rows:
-        return row_side(n_rows)
-    return jax.lax.cond(held_rows <= prefix, lambda: row_side(prefix),
-                        lambda: row_side(n_rows))
+def _walk_held(n_rows, held_rows, trip, carry):
+    """``carry`` after ``trip(rows, fresh, carry)`` over every chunk of
+    the buffer up to the one that holds row ``held_rows - 1``, in
+    order: ``rows`` are the chunk's row numbers (held_rows_chunk of
+    them, consecutive) and ``fresh`` marks those no earlier trip has
+    been handed.  The last chunk of a buffer its length does not
+    divide starts early instead of running past the end, so every
+    slice a trip takes at ``rows[0]`` (_chunk) is in range."""
+    chunk = held_rows_chunk(n_rows)
+
+    def body(i, carry):
+        first = i * chunk
+        rows = jnp.minimum(first, n_rows - chunk) + \
+            jax.lax.iota(jnp.int32, chunk)
+        return trip(rows, rows >= first, carry)
+
+    return jax.lax.fori_loop(0, (held_rows + chunk - 1) // chunk, body,
+                             carry)
 
 
-def _live_rows(order, top_k, held_rows, n):
-    """The first ``n`` rows of the buffer: (pair, token, live) with
-    row j pair ``order[j]`` of token ``order[j] // top_k``, live while
-    it is a held expert's."""
-    pair = order[:n]
-    return pair, pair // top_k, \
-        jax.lax.iota(jnp.int32, n) < held_rows
+def _chunk(a, rows):
+    """The chunk of ``a``'s leading axis a trip was handed ``rows``
+    of."""
+    return jax.lax.dynamic_slice_in_dim(a, rows[0], rows.shape[0])
 
 
-def _sum_per_token(rows, token, live, tokens):
-    """rows [n, D] f32 -> [tokens, D] f32: each token's live rows
-    summed.  XLA's scatter-add into zeros, one row at a time in the
-    buffer's order: the same bits every run."""
-    return jax.ops.segment_sum(jnp.where(live[:, None], rows, 0), token,
-                               num_segments=tokens)
+def _zeros(shape, dtype, held_rows):
+    """Zeros for a loop over the held rows to write into, broadcast
+    from a scalar the compiler cannot fold (``held_rows`` is never
+    negative): it merges the constant fills of one shape across layers
+    into instructions that carry no op's name, and a device trace then
+    cannot say whose time they are."""
+    return jnp.broadcast_to(jnp.minimum(held_rows, 0).astype(dtype), shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 5))
-def dispatch_rows(x, order, inverse, top_k, held_rows=None, prefix=None):
+def _sum_per_token(rows, order, top_k, held_rows, tokens, weight=None):
+    """rows [R, D], row j that of pair ``order[j]`` -> [tokens, D] f32:
+    each token's held rows (the first ``held_rows`` of the buffer)
+    summed, times their pair's gate in ``weight`` [S, k] if given.
+    XLA's scatter-add into the loop's carry, a chunk a trip and one row
+    at a time in the buffer's order: each token's sum is the same chain
+    of f32 adds whatever the chunk, the same bits every run.  Rows past
+    the held ones are never multiplied, only masked: on the chip the
+    grouped matmuls leave them unwritten."""
+    def trip(at, fresh, acc):
+        pair = _chunk(order, at)
+        part = _chunk(rows, at).astype(jnp.float32)
+        if weight is not None:
+            part = part * weight.reshape(-1)[pair][:, None]
+        live = fresh & (at < held_rows)
+        return acc.at[pair // top_k].add(
+            jnp.where(live[:, None], part, 0))
+
+    return _walk_held(
+        rows.shape[0], held_rows, trip,
+        _zeros((tokens, rows.shape[1]), jnp.float32, held_rows))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def dispatch_rows(x, order, inverse, top_k, held_rows=None):
     """x [S, D] -> rows [len(order), D] in expert order (row j is token
     order[j] // k).  ``order`` may be the first R entries of the sort
     only (held_rows_bound); ``inverse`` is always the whole one.  With
@@ -368,71 +392,58 @@ def dispatch_rows(x, order, inverse, top_k, held_rows=None, prefix=None):
     whatever their cotangent holds: on the chip the grouped matmuls
     leave the rows past their last group UNWRITTEN, in the forward
     pass and in the gradient they hand back alike.  That gradient is
-    then summed row-side, over the first ``prefix`` rows (static,
-    held_rows_prefix) while the held rows fit them.  The forward
-    gather is the same either way: it is bound by writing the buffer,
-    which a fill costs too (on the chip 0.34 ms for 32,768 rows of
-    3072 against 0.39 for 7,680 and zeros: PERF.md section 6,
-    PR 31)."""
+    then summed row-side, over the chunks of the buffer that hold a
+    held row (_sum_per_token).  The forward gather is the same either
+    way: it is bound by writing the buffer, which a fill costs too (on
+    the chip 0.34 ms for 32,768 rows of 3072 against 0.39 for 7,680
+    and zeros: PERF.md section 6, PR 31)."""
     return x[order // top_k]
 
 
-def _dispatch_fwd(x, order, inverse, top_k, held_rows=None, prefix=None):
-    return dispatch_rows(x, order, inverse, top_k, held_rows, prefix), \
+def _dispatch_fwd(x, order, inverse, top_k, held_rows=None):
+    return dispatch_rows(x, order, inverse, top_k, held_rows), \
         (order, inverse, held_rows)
 
 
-def _dispatch_bwd(top_k, prefix, res, g):
+def _dispatch_bwd(top_k, res, g):
     order, inverse, held_rows = res
     s = inverse.shape[0] // top_k
     if held_rows is None:
         dx = jnp.sum(g[inverse].reshape(s, top_k, -1).astype(
             jnp.float32), axis=1)
         return dx.astype(g.dtype), None, None, None
-
-    def row_side(n):
-        _, token, live = _live_rows(order, top_k, held_rows, n)
-        return _sum_per_token(g[:n].astype(jnp.float32), token, live, s)
-
-    dx = _walk(row_side, g.shape[0], held_rows, prefix)
+    dx = _sum_per_token(g, order, top_k, held_rows, s)
     return dx.astype(g.dtype), None, None, None
 
 
 dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def combine_rows(y, weight, order, inverse, held_rows=None, prefix=None):
+@jax.custom_vjp
+def combine_rows(y, weight, order, inverse, held_rows=None):
     """y [R, D] expert-ordered outputs, weight [S, k] f32 ->
     [S, D]: each token's k outputs, weighted, summed in f32.  With
     ``held_rows`` (an int32 scalar: the held experts' rows, the first
     of the buffer) only those rows count: what lies past them, and the
     pairs whose row is not in the buffer at all, add nothing and get
     no gradient, whatever the buffer holds there; the sum and both
-    gradients then walk the buffer's rows, not the S*k pairs: the
-    first ``prefix`` (static, held_rows_prefix) while the held rows
-    fit them."""
+    gradients then walk the buffer's rows, not the S*k pairs, and of
+    those the chunks that hold a held row (_walk_held)."""
     s, k = weight.shape
     if held_rows is None:
         picked = y[inverse].reshape(s, k, -1).astype(jnp.float32)
         return jnp.sum(picked * weight[:, :, None], axis=1).astype(
             y.dtype)
-
-    def row_side(n):
-        pair, token, live = _live_rows(order, k, held_rows, n)
-        gate = weight.reshape(-1)[pair]
-        return _sum_per_token(y[:n].astype(jnp.float32) * gate[:, None],
-                              token, live, s)
-
-    return _walk(row_side, y.shape[0], held_rows, prefix).astype(y.dtype)
+    return _sum_per_token(y, order, k, held_rows, s, weight).astype(
+        y.dtype)
 
 
-def _combine_fwd(y, weight, order, inverse, held_rows=None, prefix=None):
-    return combine_rows(y, weight, order, inverse, held_rows, prefix), \
+def _combine_fwd(y, weight, order, inverse, held_rows=None):
+    return combine_rows(y, weight, order, inverse, held_rows), \
         (y, weight, order, inverse, held_rows)
 
 
-def _combine_bwd(prefix, res, g):
+def _combine_bwd(res, g):
     y, weight, order, inverse, held_rows = res
     s, k = weight.shape
     if held_rows is None:
@@ -442,23 +453,28 @@ def _combine_bwd(prefix, res, g):
         dweight = jnp.sum(picked * gf[:, None, :], axis=-1)
         return dy.reshape(s * k, -1)[order], dweight, None, None, None
 
-    def row_side(n):
+    def trip(at, fresh, carry):
         # y and g are each read once: row j's gradient is its token's
-        # g times its gate, its gate's gradient their product summed
-        pair, token, live = _live_rows(order, k, held_rows, n)
-        gate = weight.reshape(-1)[pair]
-        g_rows = g[token].astype(jnp.float32)
-        dy = jnp.where(live[:, None], g_rows * gate[:, None], 0)
+        # g times its gate, its gate's gradient their product summed.
+        # A row handed to two trips is written the same twice.
+        dy, dweight = carry
+        pair = _chunk(order, at)
+        live = at < held_rows
+        g_rows = g[pair // k].astype(jnp.float32)
+        part = jnp.where(
+            live[:, None], g_rows * weight.reshape(-1)[pair][:, None], 0)
         dgate = jnp.where(live, jnp.sum(
-            y[:n].astype(jnp.float32) * g_rows, axis=-1), 0)
-        dweight = jnp.zeros((s * k,), jnp.float32).at[pair].set(
-            dgate, unique_indices=True)
-        # zeros past the rows walked: a fill, nothing reads them
-        return jnp.pad(dy.astype(y.dtype), ((0, y.shape[0] - n), (0, 0))), \
-            dweight.reshape(s, k)
+            _chunk(y, at).astype(jnp.float32) * g_rows, axis=-1), 0)
+        return (jax.lax.dynamic_update_slice_in_dim(
+                    dy, part.astype(y.dtype), at[0], 0),
+                dweight.at[pair].set(dgate, unique_indices=True))
 
-    dy, dweight = _walk(row_side, y.shape[0], held_rows, prefix)
-    return dy, dweight, None, None, None
+    # zeros past the chunks walked: a fill, nothing reads them
+    dy, dweight = _walk_held(
+        y.shape[0], held_rows, trip,
+        (_zeros(y.shape, y.dtype, held_rows),
+         _zeros((s * k,), jnp.float32, held_rows)))
+    return dy, dweight.reshape(s, k), None, None, None
 
 
 combine_rows.defvjp(_combine_fwd, _combine_bwd)

@@ -259,12 +259,26 @@ def _pair_side(x, w, y, g_rows, g_out, order, inverse, k, n_held):
     return rows, dx, out, dy, dweight
 
 
-# a prefix of 8 rows in a buffer of 24: under it, the prefix filled
-# exactly, one row over it (the whole-buffer arm), and no prefix
-@pytest.mark.parametrize('n_held,prefix', [
-    (0, 8), (5, 8), (8, 8), (9, 8), (24, 8), (9, 24), (5, None)])
+def _routing(rng, s, k, n_held):
+    """idx [s, k] over 8 experts of which 0 and 1 are held, with
+    exactly ``n_held`` pairs on them (at most one of each a token),
+    and the sort's (order, inverse) for held = (0, 2)."""
+    idx = np.stack([2 + rng.permutation(6)[:k] for _ in range(s)])
+    for j in rng.permutation(s * 2)[:n_held]:
+        idx[j // 2, j % 2] = j % 2
+    idx = jnp.asarray(idx, jnp.int32)
+    assert int(jnp.sum(pmoe.sort_keys(idx, (0, 2)) < 2)) == n_held
+    return (idx,) + pmoe.sort_by_expert(idx, (0, 2))
+
+
+# a buffer of 24 rows walked in chunks of 8 (which divide it), of 7
+# (which do not: the last chunk starts early) and of 24 (one trip);
+# nothing held, part of a chunk, a chunk exactly, a chunk and a row,
+# the whole buffer
+@pytest.mark.parametrize('chunk', [8, 7, 24])
+@pytest.mark.parametrize('n_held', [0, 5, 8, 9, 24])
 def test_rows_past_the_held_groups_carry_nothing_whatever_they_hold(
-        n_held, prefix):
+        n_held, chunk, monkeypatch):
     """On the chip the grouped matmuls leave the rows past their last
     group unwritten, in the forward pass and in the gradient they hand
     back (found by ``chip_smoke.py --phase laguna``: gradients 1e5
@@ -273,20 +287,15 @@ def test_rows_past_the_held_groups_carry_nothing_whatever_they_hold(
     Poison those rows of the experts' output and of the dispatch's
     cotangent with NaN: dispatch_rows / combine_rows and their two
     backward bodies equal the pair-side formulas written out above
-    within float32 rounding, whether the held rows fit the static
-    prefix (the row-side arm), overflow it by one row or wholly (the
-    whole-buffer arm), the prefix is the buffer, or there is none."""
+    within float32 rounding, whatever the chunk the loops walk the
+    buffer in and wherever in a chunk the held rows end."""
+    monkeypatch.setattr(pmoe, 'held_rows_chunk',
+                        lambda n_rows: min(n_rows, chunk))
     rng = np.random.RandomState(n_held)
     s, k, d, held = 12, 3, 5, (0, 2)
-    # n_held pairs on the two held experts, at most one of each a token
-    idx = np.stack([2 + rng.permutation(6)[:k] for _ in range(s)])
-    for j in rng.permutation(s * 2)[:n_held]:
-        idx[j // 2, j % 2] = j % 2
-    idx = jnp.asarray(idx, jnp.int32)
-    order, inverse = pmoe.sort_by_expert(idx, held)
+    idx, order, inverse = _routing(rng, s, k, n_held)
     bound = pmoe.held_rows_bound(s, k, held)
     kept = order[:bound]
-    assert int(jnp.sum(pmoe.sort_keys(idx, held) < held[1])) == n_held
     x = jnp.asarray(rng.randn(s, d), jnp.float32)
     w = jnp.asarray(rng.rand(s, k), jnp.float32)
     g_out = jnp.asarray(rng.randn(s, d), jnp.float32)
@@ -299,9 +308,9 @@ def test_rows_past_the_held_groups_carry_nothing_whatever_they_hold(
     @jax.jit
     def through(x, w, y, g_rows, g_out, held_rows):
         rows, back = jax.vjp(lambda x: pmoe.dispatch_rows(
-            x, kept, inverse, k, held_rows, prefix), x)
+            x, kept, inverse, k, held_rows), x)
         out, vjp = jax.vjp(lambda y, w: pmoe.combine_rows(
-            y, w, kept, inverse, held_rows, prefix), y, w)
+            y, w, kept, inverse, held_rows), y, w)
         return (rows, back(g_rows)[0], out) + vjp(g_out)
 
     got = through(x, w, y, g_rows, g_out, held_rows)
@@ -315,35 +324,69 @@ def test_rows_past_the_held_groups_carry_nothing_whatever_they_hold(
             assert (a[n_held:] == 0).all()
         assert np.abs(a - b).max(initial=0) <= 1e-6 * max(
             np.abs(b).max(initial=0), 1), name
-    # a conditional in each body but the forward gather where the
-    # prefix is shorter than the buffer, none where it is the buffer
-    # or there is none
+    # one loop in each body but the forward gather, and no conditional
     text = str(jax.make_jaxpr(through)(x, w, y, g_rows, g_out,
                                        held_rows))
-    assert text.count("cond[") == (3 if prefix == 8 else 0)
+    assert text.count('while[') == 3 and 'cond[' not in text
 
 
-def test_the_prefix_is_a_few_times_an_even_share_and_the_bound_at_most():
-    """The cell's layer (4096 tokens, top-10, 8 of 256 experts): an
-    even routing holds 1,280 rows, the buffer 32,768, and the
-    permutation walks PREFIX_OVER_EVEN times the first in whole steps
-    of 512.  A layer that holds a large share walks its whole buffer,
-    with no second arm in its program."""
-    even = 4096 * 10 * 8 // 256
-    p = pmoe.held_rows_prefix(4096, 10, (0, 8), 256)
-    assert p == pmoe.PREFIX_OVER_EVEN * even == 15360 and p % 512 == 0
-    assert pmoe.held_rows_prefix(4096, 10, (8, 8), 256) == p
-    assert pmoe.held_rows_prefix(4096, 10, (0, 8), 250) == 15872
-    bound = pmoe.held_rows_bound(4096, 10, (0, 64))
-    assert pmoe.held_rows_prefix(4096, 10, (0, 64), 256) == bound
-    assert pmoe.held_rows_prefix(6, 3, (2, 2), 6) == \
-        pmoe.held_rows_bound(6, 3, (2, 2))
+@pytest.mark.parametrize('chunk', [5, 16, 512])
+def test_both_sums_are_the_whole_length_scatter_add_bit_for_bit(
+        chunk, monkeypatch):
+    """The loops keep the buffer's row order, so each token's sum is
+    the same chain of float32 adds as one scatter-add of the masked
+    rows over the whole buffer: the dispatch's gradient and the
+    weighted sum back equal ``jax.ops.segment_sum`` to the last bit,
+    for random routings and every chunk (a seed trains the trajectory
+    it trained before the buffer was walked in chunks)."""
+    monkeypatch.setattr(pmoe, 'held_rows_chunk',
+                        lambda n_rows: min(n_rows, chunk))
+    s, k, d, held = 40, 3, 33, (0, 2)
+    bound = pmoe.held_rows_bound(s, k, held)
+    for seed, n_held in enumerate((0, 1, 17, 48, 63, 80)):
+        rng = np.random.RandomState(seed)
+        _, order, inverse = _routing(rng, s, k, n_held)
+        kept, held_rows = order[:bound], jnp.int32(n_held)
+        x = jnp.zeros((s, d), jnp.float32)
+        w = jnp.asarray(rng.rand(s, k), jnp.float32)
+        y = jnp.asarray(rng.randn(bound, d) * 10 ** rng.uniform(
+            -3, 3, (bound, 1)), jnp.float32)
+        live = (jnp.arange(bound) < n_held)[:, None]
+
+        def whole(rows):
+            return jax.ops.segment_sum(jnp.where(live, rows, 0),
+                                       kept // k, num_segments=s)
+
+        dx = jax.jit(lambda g: jax.vjp(lambda x: pmoe.dispatch_rows(
+            x, kept, inverse, k, held_rows), x)[1](g)[0])(y)
+        assert (np.asarray(dx) == np.asarray(jax.jit(whole)(y))).all()
+        out = jax.jit(lambda y, w: pmoe.combine_rows(
+            y, w, kept, inverse, held_rows))(y, w)
+        gated = jax.jit(lambda y, w: whole(
+            y * w.reshape(-1)[kept][:, None]))(y, w)
+        assert (np.asarray(out) == np.asarray(gated)).all()
 
 
-def _conditionals_of_a_layer(held, experts, tokens=512):
-    """How many conditionals the permutation of a ``layers.moe``
-    (top-2, forward and backward) traces to: the layer's own ops with
-    the attributes it gave them, route -> dispatch -> combine."""
+@pytest.mark.parametrize('tokens,top_k,held,n_rows', [
+    (4096, 10, (0, 8), 32768), (8192, 6, (0, 8), 49152),
+    (12, 3, (0, 2), 24)], ids=['laguna', 'moonlight', 'tiny'])
+def test_the_chunk_comes_from_the_buffers_length_alone(
+        tokens, top_k, held, n_rows):
+    """Both held cells' layers walk their buffer 512 rows a trip (an
+    even routing holds 1,280 of Laguna's 32,768 rows and 6,144 of
+    Moonlight's 49,152: 3 and 12 trips); a buffer shorter than that is
+    one chunk.  No constant tuned on a cell's loads is left."""
+    assert pmoe.held_rows_bound(tokens, top_k, held) == n_rows
+    chunk = pmoe.held_rows_chunk(n_rows)
+    assert chunk == min(n_rows, 512)
+    assert not hasattr(pmoe, 'held_rows_prefix')
+    assert not hasattr(pmoe, 'PREFIX_OVER_EVEN')
+
+
+def _layer_jaxpr(held, experts, tokens=512):
+    """What the permutation of a ``layers.moe`` (top-2, forward and
+    backward) traces to: the layer's own ops with the attributes it
+    gave them, route -> dispatch -> combine."""
     from paddle_tpu.ops import registry
     main = fluid.Program()
     with fluid.program_guard(main, fluid.Program()), \
@@ -368,35 +411,35 @@ def _conditionals_of_a_layer(held, experts, tokens=512):
         return jnp.sum(run('moe_combine', ins)['Out'][0])
 
     x, wg = jnp.ones((tokens, 8)), jnp.ones((8, experts))
-    return str(jax.make_jaxpr(jax.grad(permuted, (0, 1)))(x, wg)).count(
-        'cond[')
+    return str(jax.make_jaxpr(jax.grad(permuted, (0, 1)))(x, wg))
 
 
-def test_only_a_layer_with_a_short_prefix_traces_a_conditional():
-    """512 tokens top-2: a layer that holds 2 of 16 experts (an even
-    share is 128 rows of its buffer's 1,024) and one that holds 1 of
-    64 (16 of 512; the prefix comes in steps of 512) walk their whole
-    buffer, one body and no second arm; one that holds 2 of 64 walks
-    512 of 1,024 behind a conditional in the combine and in both
-    backward bodies; all experts: the pair-side program, none."""
-    assert _conditionals_of_a_layer(None, 16) == 0
-    assert _conditionals_of_a_layer((0, 2), 16) == 0
-    assert _conditionals_of_a_layer((3, 1), 64) == 0
-    assert _conditionals_of_a_layer((0, 2), 64) == 3
+@pytest.mark.parametrize('held,experts,loops', [
+    (None, 16, 0), ((0, 2), 16, 3), ((3, 1), 64, 3), ((0, 64), 64, 3)],
+    ids=['all_experts', 'an_eighth', 'one_of_64', 'a_range_that_is_all'])
+def test_a_held_layer_traces_three_loops_and_no_conditional(
+        held, experts, loops):
+    """A layer that holds a range of its experts, however large a
+    share, loops in the combine and in both backward bodies, with one
+    body a direction: no conditional, no second arm.  All experts
+    (no ``experts_held``): the pair-side program, neither."""
+    text = _layer_jaxpr(held, experts)
+    assert text.count('while[') == loops and 'cond[' not in text
 
 
-@pytest.mark.parametrize('rows,overflows', [
-    ([100, 412], 0), ([100, 413], 1), ([0, 0], 0)],
-    ids=['at_the_prefix', 'one_over', 'none_held'])
-def test_held_layers_report_their_largest_load_and_the_overflows(
-        rows, overflows):
-    """Two layers of 512 tokens top-2 that hold 2 of 64 experts (prefix
-    512 of a 1,024-row buffer), the first always under its prefix:
-    ``moe/held_rows_max`` is the larger layer's rows, a layer over its
-    prefix counts once in ``moe/prefix_overflows``, one at it not at
-    all."""
+@pytest.mark.parametrize('rows,walked', [
+    ([0, 0], 0), ([1, 0], 512), ([100, 413], 1024)],
+    ids=['none_held', 'one_row', 'a_chunk_and_a_row'])
+def test_held_layers_report_their_largest_load_and_the_share_walked(
+        rows, walked):
+    """Two layers of 512 tokens top-2 that hold 2 of 64 experts (a
+    buffer of 1,024 rows, two chunks of 512), the first always with 40
+    rows in its first chunk: ``moe/held_rows_max`` is the larger
+    layer's rows, ``moe/walked_share`` the rows both layers' loops
+    walk, whole chunks up to the last held row, over both buffers."""
     from paddle_tpu.fluid import moe_stats
-    assert pmoe.held_rows_prefix(512, 2, (0, 2), 64) == 512
+    assert pmoe.held_rows_chunk(pmoe.held_rows_bound(
+        512, 2, (0, 2))) == 512
     values = []
     for n in (40, sum(rows)):
         load = np.zeros(64, np.int32)
@@ -407,11 +450,13 @@ def test_held_layers_report_their_largest_load_and_the_overflows(
     record.top_k += [2, 2]
     monitor.reset()
     record(values)
-    assert monitor.counter_value('moe/prefix_overflows') == overflows
+    assert monitor.gauge_value('moe/walked_share') == \
+        (512 + walked) / 2048.
     assert monitor.gauge_value('moe/held_rows_max') == max(sum(rows), 40)
     assert monitor.counter_value('moe/rows_held') == 40 + sum(rows)
     assert monitor.gauge_value('moe/held_share') == pytest.approx(
         (40 + sum(rows)) / 2048.)
+    assert 'moe/prefix_overflows' not in monitor.flat()
 
 
 def test_moe_rejects_a_held_range_it_cannot_hold():
