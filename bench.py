@@ -76,10 +76,6 @@ def bench_resnet50(batch=128, steps=30, warmup=5, amp=True,
                         fetch_list=[loss])
         np.asarray(last)  # block on the last step
         dt = time.time() - t0
-        global LAST_PERF
-        LAST_PERF = _perf_fields(
-            dt / steps, _program_cost(exe, main, {'image': x, 'label': y},
-                                      loss))
     return batch * steps / dt
 
 
@@ -113,9 +109,11 @@ def _chip_peak():
     return CHIP_PEAKS[kind]
 
 
-# set by _timed_steps from XLA's own cost analysis of the program it
-# just timed; benches merge it into their JSON line so every entry
-# reports achieved TFLOP/s and MFU (round-4 VERDICT item 2)
+# achieved TFLOP/s and MFU of the last timed program, merged into the
+# benches' JSON lines.  Empty since the count it was made from went
+# (Executor.program_cost, XLA's own cost analysis after a second
+# compile): benchmark/run.py's `mfu` and the profiler's cost table
+# (fluid.profiler.cost_tables) are the numbers now
 LAST_PERF = {}
 
 # set by _timed_steps from fluid.trace's flight recorder over the timed
@@ -205,16 +203,6 @@ def append_history(entry, rec, path=None):
         return None
 
 
-def _program_cost(exe, program, feed, loss):
-    """XLA's cost analysis of the step, or None where the backend has
-    none to give (reported, not fatal: the timing stands without it)."""
-    try:
-        return exe.program_cost(program, feed, fetch_list=[loss])
-    except Exception as e:
-        sys.stderr.write('cost analysis unavailable: %s\n' % e)
-        return None
-
-
 def _perf_fields(step_s, cost):
     """Achieved TFLOP/s and HBM GB/s against the chip's published
     peaks.  Empty off a TPU: a CPU run has no device utilization to
@@ -294,9 +282,6 @@ def _timed_steps(exe, main_prog, feed, loss, steps=20, warmup=3):
         if not trace_was_on:
             pt_trace.disable()
             pt_trace.reset()
-    global LAST_PERF
-    LAST_PERF = _perf_fields(dt / steps,
-                             _program_cost(exe, main_prog, feed, loss))
     return dt / steps
 
 

@@ -390,6 +390,9 @@ class CompilePlane(object):
         self._lazy = LRUCache(
             int(get_flag('FLAGS_compile_cache_memory_capacity', 256)
                 or 256))
+        # key -> (weak reference to the program, what held_tables'
+        # builder made of its HLO text)
+        self._built = {}
         self._pool = None
         self._warmed = False
         self._wired_dir = None
@@ -693,6 +696,33 @@ class CompilePlane(object):
         with self._lock:
             self._lazy[key] = (weakref.ref(jitted), lowering_args)
 
+    def _held(self):
+        """[(key, the object that is the program while it lives, () ->
+        its executable)]: the AOT executables of the map and the lazily
+        jitted callables the runners noted."""
+        from concurrent.futures import Future
+        with self._lock:
+            held = [(fp, ex, lambda ex=ex: ex)
+                    for fp, ex in self._mem.items()
+                    if not isinstance(ex, Future) and
+                    hasattr(ex, 'as_text')]
+            lazy = [(key, ref(), args)
+                    for key, (ref, args) in self._lazy.items()]
+        for key, jitted, args in lazy:
+            if jitted is not None:      # else its segment is gone
+                held.append((key, jitted, lambda jitted=jitted, args=args:
+                             jitted.lower(*args).compile()))
+        return held
+
+    @staticmethod
+    def _hlo_text(key, executable):
+        text = executable.as_text()
+        if not text:
+            raise RuntimeError(
+                'the executable %r gives no HLO text, so no scope '
+                'table can be built for it' % (key,))
+        return text
+
     def held_hlo(self):
         """[(key, optimised HLO text)] of every executable this process
         holds: the AOT executables of the map and the lazily jitted
@@ -703,24 +733,32 @@ class CompilePlane(object):
         again, or an executable that keeps no HLO, raises: a table
         that silently lacked a program would move all of its device
         time to 'unattributed'."""
-        from concurrent.futures import Future
-        with self._lock:
-            held = [(fp, ex) for fp, ex in self._mem.items()
-                    if not isinstance(ex, Future) and
-                    hasattr(ex, 'as_text')]
-            lazy = [(key, ref(), args)
-                    for key, (ref, args) in self._lazy.items()]
-        for key, jitted, args in lazy:
-            if jitted is not None:      # else its segment is gone
-                held.append((key, jitted.lower(*args).compile()))
+        return [(key, self._hlo_text(key, executable()))
+                for key, _program, executable in self._held()]
+
+    def held_tables(self, build):
+        """[(key, build(optimised HLO text))] of the same executables,
+        each lowered, printed and built ONCE while the process holds it
+        (fluid.profiler's scope and cost tables come from one parse,
+        and a second trace of the process parses nothing again).  A
+        key can come back for another program (a lazy key holds an
+        ``id``), so an entry counts only while the object it was built
+        for is the one held.  One builder a process: the profiler's.
+        Raises what ``held_hlo`` raises."""
+        import weakref
+        kept = {}
         out = []
-        for key, ex in held:
-            text = ex.as_text()
-            if not text:
-                raise RuntimeError(
-                    'the executable %r gives no HLO text, so no scope '
-                    'table can be built for it' % (key,))
-            out.append((key, text))
+        for key, program, executable in self._held():
+            hit = self._built.get(key)
+            if hit is None or hit[0]() is not program:
+                try:
+                    ref = weakref.ref(program)
+                except TypeError:   # an executable that takes no weak
+                    ref = lambda program=program: program   # reference
+                hit = (ref, build(self._hlo_text(key, executable())))
+            kept[key] = hit
+            out.append((key, hit[1]))
+        self._built = kept      # what is gone from the plane goes here too
         return out
 
     def shared_jit(self, fp, make_fn):

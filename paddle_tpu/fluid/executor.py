@@ -1737,52 +1737,6 @@ class Executor(object):
             # thread: a snapshot here can never mix two steps' params
             _sup.on_step_end(self)
 
-    def program_cost(self, program, feed, fetch_list=None, scope=None):
-        """XLA cost analysis summed over the program's device segments
-        for the given feed: {'flops', 'bytes'} per step.  The basis for
-        the benches' achieved-TFLOP/s and MFU reporting — XLA's own
-        count of what the compiled executable does, not a hand model.
-        Segments are lowered/compiled AOT here; the XLA compile caches
-        (service + persistent) dedupe against the run-path executables.
-        """
-        scope = scope or core.global_scope()
-        feed = feed or {}
-        fetch_list = fetch_list or []
-        fetch_names = [v.name if isinstance(v, framework.Variable)
-                       else v for v in fetch_list]
-        plan = self._get_plan(program, tuple(sorted(feed.keys())),
-                              tuple(fetch_names))
-        total = {'flops': 0.0, 'bytes': 0.0}
-        device = self.place.jax_device()
-        prefer_test = any(isinstance(it, _Segment) and it.prefer_test
-                          for it in plan)
-        for item in plan:
-            if not isinstance(item, _Segment):
-                if item[0] == 'bucket':
-                    # stamp max_trip_count like the run path does, or
-                    # downstream segments cannot lower (they read the
-                    # bucketed trip bound at trace time)
-                    self._run_bucket_count(item[1], feed, scope,
-                                           device, prefer_test)
-                continue
-            fn = _make_segment_fn(
-                item, item.prefer_test,
-                whole_program_grad=bool(
-                    get_flag('FLAGS_whole_program_grad')))
-            state = {n: self._lookup_input(n, feed, scope)
-                     for n in item.state_names}
-            data = {n: self._lookup_input(n, feed, scope)
-                    for n in item.input_names}
-            compiled = jax.jit(fn, donate_argnums=(1,)).lower(
-                self._step, state, data).compile()
-            ca = compiled.cost_analysis()
-            if isinstance(ca, list):
-                ca = ca[0] if ca else {}
-            total['flops'] += float(ca.get('flops', 0.0) or 0.0)
-            total['bytes'] += float(ca.get('bytes accessed', 0.0)
-                                    or 0.0)
-        return total
-
     def _post_step(self, program, scope):
         """k-step LocalSGD sync and the async-PS grad push / param
         pull, at the end of every runner's step."""
@@ -2219,8 +2173,8 @@ class Executor(object):
                 _profiler.record_op(name, _time_mod.perf_counter() - t0)
 
     def _lookup_input(self, name, feed, scope):
-        """One-off argument lookup for the cold paths (program_cost,
-        bucket counting); the run loop binds through _SegmentBinder."""
+        """One-off argument lookup for the cold paths (bucket
+        counting); the run loop binds through _SegmentBinder."""
         if name in feed:
             return _normalize_feed_value(feed[name])
         val = scope.find_var(name)

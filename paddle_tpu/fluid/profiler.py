@@ -24,7 +24,9 @@ TPU-native split, mirroring the reference's two profilers:
   back to op RecordEvents) — per-op attribution of the REAL fused run.
   The trace is the ``.xplane.pb`` jax.profiler writes; its op events
   name HLO instructions only, so the fluid op of each comes from the
-  executables this process holds (``hlo_scopes`` / ``scope_tables``).
+  executables this process holds (``hlo_scopes`` / ``scope_tables``),
+  and so do the FLOPs and bytes of each (``hlo_costs`` /
+  ``cost_tables``; the rules of both are written out further down).
 
 stop_profiler prints the sorted table; summary_records() /
 summary_string() expose it programmatically.  start_trace()/
@@ -35,16 +37,22 @@ events for tools/timeline.py's merged Perfetto file.
 import bisect
 import collections
 import contextlib
+import functools
+import math
 import os
 import re
 
 import jax
 
 _SORT_KEYS = ('calls', 'total', 'max', 'min', 'ave')
+# a trace-derived row's cost: key in summary_records(), table heading
+_COST_COLUMNS = (('gflop', 'GFLOP'), ('mb', 'MB'), ('tflops', 'TFLOP/s'),
+                 ('gbps', 'GB/s'))
 
 _enabled = False
 _mode = 'Serial'         # 'Serial' | 'Default' (trace-derived)
 _records = {}  # op type -> [calls, total, max, min]
+_costs = {}    # op type -> [GFLOP or None (unknown), MB], trace-derived
 _folded = False          # records already added to fluid.monitor
 _trace_path = None
 _prof_trace_dir = None   # capture dir while a 'Default' profile runs
@@ -73,33 +81,53 @@ def reset_profiler():
     platform::ResetProfiler)."""
     global _folded
     _records.clear()
+    _costs.clear()
     _folded = False
 
 
 def summary_records():
-    """{op_type: {'calls', 'total', 'max', 'min', 'ave'}} (seconds)."""
-    return {t: {'calls': c, 'total': tot, 'max': mx, 'min': mn,
-                'ave': tot / c}
-            for t, (c, tot, mx, mn) in _records.items()}
+    """{op_type: {'calls', 'total', 'max', 'min', 'ave'}} (seconds).
+    After a device trace a row whose instructions the cost table
+    (``cost_tables()``) knows also holds 'mb' (bytes moved) and, unless
+    one of its instructions is a custom call, 'gflop' and the rates
+    over 'total', 'tflops' and 'gbps': what a custom call computes, and
+    how much of its operands it reads, no HLO text says."""
+    out = {}
+    for t, (c, tot, mx, mn) in _records.items():
+        row = out[t] = {'calls': c, 'total': tot, 'max': mx, 'min': mn,
+                        'ave': tot / c}
+        if t in _costs:
+            gflop, mb = _costs[t]
+            row['mb'] = mb
+            if gflop is not None:
+                row['gflop'] = gflop
+                if tot > 0:
+                    row['gbps'] = mb / 1e3 / tot
+                    row['tflops'] = gflop / 1e3 / tot
+    return out
 
 
 def summary_string(sorted_key='total'):
     """The reference's profiler table (profiler.h:166 prints Event
-    rows sorted by sorted_key)."""
+    rows sorted by sorted_key); after a device trace with four columns
+    more, a row's cost and what it achieved ('-': not known)."""
     if sorted_key not in (None,) + _SORT_KEYS:
         raise ValueError('sorted_key must be one of %s, got %r'
                          % (_SORT_KEYS, sorted_key))
     key = sorted_key or 'total'
     rows = sorted(summary_records().items(),
                   key=lambda kv: kv[1][key], reverse=True)
+    extra = _COST_COLUMNS if _costs else ()
     lines = ['%-28s %8s %12s %12s %12s %12s'
              % ('Event', 'Calls', 'Total(ms)', 'Min(ms)', 'Max(ms)',
-                'Ave(ms)')]
+                'Ave(ms)') + ''.join(' %10s' % head for _, head in extra)]
     for t, r in rows:
         lines.append('%-28s %8d %12.4f %12.4f %12.4f %12.4f'
                      % (t, r['calls'], r['total'] * 1e3,
                         r['min'] * 1e3, r['max'] * 1e3,
-                        r['ave'] * 1e3))
+                        r['ave'] * 1e3) + ''.join(
+                            ' %10.2f' % r[k] if k in r else ' %10s' % '-'
+                            for k, _ in extra))
     return '\n'.join(lines)
 
 
@@ -127,7 +155,7 @@ def _resolve_component(comp, op_types, per_instance):
 
 
 def attribute_trace_events(events, op_types=None, per_instance=False,
-                           with_stats=False):
+                           with_stats=False, costs=None):
     """Map device-trace kernel events back to fluid op types.
 
     `events` are chrome-trace events (``load_trace_events``, or a
@@ -158,6 +186,11 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
     returns (recs, {'events', 'attributed', 'dropped'}) so skipped
     rows are COUNTED, not silently eaten.
 
+    `costs`, a dict, receives {name: [GFLOP or None, MB]} summed from
+    the events that carry a cost (``load_trace_events`` gives
+    args['mb'] and, where known, args['gflop']), split as the time is;
+    None once one event of the name has bytes and no FLOPs.
+
     Both positive and negative lookups are cached per tf_op string
     (a capture repeats each unattributed scope on every step; without
     the negative cache every repeat re-splits the path)."""
@@ -166,15 +199,21 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
     cache = {}   # tf_op -> tuple(resolved names) | () for negative
     n_events = n_attr = dropped = 0
 
-    def _fold(name, sec, calls=1):
+    def _fold(name, sec, cost=None):
         rec = recs.get(name)
         if rec is None:
-            recs[name] = [calls, sec, sec, sec]
+            recs[name] = [1, sec, sec, sec]
         else:
-            rec[0] += calls
+            rec[0] += 1
             rec[1] += sec
             rec[2] = max(rec[2], sec)
             rec[3] = min(rec[3], sec)
+        if cost is not None and costs is not None:
+            gflop, mb = cost
+            held = costs.setdefault(name, [0.0, 0.0])
+            held[0] = None if gflop is None or held[0] is None \
+                else held[0] + gflop
+            held[1] += mb
 
     for e in events:
         if not isinstance(e, dict):
@@ -213,17 +252,24 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
             hit = tuple(resolved)
             cache[tf_op] = hit   # negative ((None,)*n) cached too
         matched = [n for n in hit if n is not None]
+        cost = part = None
+        if isinstance(args.get('mb'), (int, float)):
+            gflop = args.get('gflop')
+            if not isinstance(gflop, (int, float)):
+                gflop = None
+            cost = (gflop, args['mb'])
+            part = (gflop and gflop / len(hit), args['mb'] / len(hit))
         if not matched:
             # per-HLO-name bucket: distinct kernels share a scope
             # path, so the bucket keys on the event name instead
             _fold('unattributed/' +
-                  str(e.get('name', '?')).split('.')[0], sec)
+                  str(e.get('name', '?')).split('.')[0], sec, cost)
             continue
         n_attr += 1
         share = sec / len(hit)
         leftover = share * (len(hit) - len(matched))
         for name in matched:
-            _fold(name, share)
+            _fold(name, share, part)
         if leftover > 0:
             _fold('unattributed/' +
                   str(e.get('name', '?')).split('.')[0], leftover)
@@ -260,6 +306,66 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
 #   registration declares that name (``ops.registry.COMPILER_NAMED``);
 #   forward and backward cannot be told apart there;
 # - an instruction with no fluid scope counts to none.
+#
+# The same parse says what each instruction COSTS, so that the time a
+# trace gives it reads against the chip's peaks: a
+# ``Cost(kind, flops, bytes, dtype, group, shapes)``, by one rule:
+#
+# - ``dot``: 2 x (elements of the result) x (product of the lhs
+#   contracting sizes).  ``convolution``: 2 x the multiply-adds whose
+#   two factors are both elements of the operands: batch x output
+#   features x input features / ``feature_group_count`` x, for each
+#   spatial dimension by ``dim_labels``, the (output position, window
+#   tap) pairs that land on an element.  A tap on padding, or on a
+#   hole ``lhs_dilate`` opens between elements, is no multiply-add.
+#   That is what makes the rule hold for what the TPU compiler prints:
+#   it writes EVERY dot as a convolution, a batched one with its batch
+#   dimensions as spatial ones dilated so that one tap in ``size``
+#   lands (``window={size=192x12 stride=191x11 lhs_dilate=192x12}``),
+#   and an input-gradient at stride 2 with three holes in four.  The
+#   window-times-result count would be 2,304 and 4 times too high
+#   there.  The price: a model count that takes a padded window whole
+#   (``benchmark/lib/flops.py``, every published one) is higher, by
+#   3.45% for ResNet-50 at 224 x 224 (its 3 x 3 and 7 x 7 windows'
+#   taps on padding; 18% of the 3 x 3 at 7 x 7, none of a matmul).
+# - a ``fusion``: the FLOPs of the dots and convolutions of the
+#   computation it calls; the bytes of its operands and its result AT
+#   THE FUSION BOUNDARY (tuples summed), an operand the body reads only
+#   through ``slice`` / ``dynamic-slice`` / ``gather`` at the size
+#   read, one it only updates in place (operand 0 of a
+#   ``dynamic-update-slice``) at nothing, and a result written through
+#   ``dynamic-update-slice`` at the size written.  Elementwise FLOPs
+#   are not counted (under 2% at these widths, as ``flops.py``).
+# - a Mosaic or other ``custom-call``: ``flops=None`` (unknown, never
+#   0), bytes of operands and result; so has a fusion that holds one.
+#   No rate is made of either: how much of its operands a call reads
+#   is not in the text (the chip's grouped matmul skips the rows past
+#   its last group).
+# - a collective (``all-reduce``, ``all-gather``, ``reduce-scatter``,
+#   ``all-to-all``, ``collective-permute``, their ``-start`` forms and
+#   an ``async-start`` that wraps one): ``kind='collective'``, the
+#   bytes of its operands (one device's, in whatever memory space),
+#   ``group`` the devices of one of its ``replica_groups``.  The
+#   ``-done`` costs nothing more.
+# - ``while`` / ``conditional`` / ``call`` and what moves no data
+#   (``parameter``, ``tuple``, ``bitcast``, ...) have no cost: the
+#   trace names a loop's body's instructions one by one.  Nor has an
+#   asynchronous copy or slice (``copy-start`` / ``-done``): it moves
+#   its bytes beside the op line, and its time there is the wait.
+# - any other instruction: bytes of operands and result, by the same
+#   slice rules.
+# - bytes are those of the device's main memory, the one the bandwidth
+#   peak is of: an array whose layout names another space (``S(1)``,
+#   where such a copy put an operand ahead of its use) counts nothing,
+#   and the copy's own read is given to no instruction, so a scope's
+#   GB/s is a floor.
+# - ``dtype`` is the type of the dot's or convolution's lhs operand
+#   (the largest's, where a fusion holds several), else the result's:
+#   an f32 product takes the MXU several bf16 passes, and every share
+#   of a peak is against the bf16 peak.  ``kind`` is the opcode held
+#   (``dot``, ``convolution``, ``custom-call``, ``collective``) or the
+#   instruction's own; ``shapes`` the operands and result of what is
+#   held, for a reader (``fusion.933`` says nothing).
 _TRANSFORMS = re.compile(
     r'^(jvp|transpose|vmap|checkpoint|remat|custom_jvp|custom_vjp)'
     r'\((.*)\)$')
@@ -271,8 +377,13 @@ _HLO_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 _HLO_CALLS = re.compile(r'\bcalls=%?([^\s,}]+)')
 _HELD_BY_FUSION = ('dot', 'convolution', 'custom-call')
 
+# ``shape`` and ``attrs`` stay text too (the result's shape, and the
+# line after the operands): costing reads them for the few opcodes it
+# counts
 _Instruction = collections.namedtuple(
-    '_Instruction', 'name opcode operands op_name calls root')
+    '_Instruction', 'name opcode operands op_name calls root shape attrs')
+
+Cost = collections.namedtuple('Cost', 'kind flops bytes dtype group shapes')
 
 
 def _is_op_type(name, op_types):
@@ -325,7 +436,8 @@ def _closing(text, start):
 def _parse_hlo(text):
     """An HLO module's text -> (module name, {computation name:
     [_Instruction]}).  ``operands`` stays the text between the
-    opcode's parentheses: only a fusion's root needs it split."""
+    opcode's parentheses: only a fusion's root and costing need it
+    split."""
     module, computations, body = '', {}, None
     for line in text.splitlines():
         if body is not None:
@@ -346,7 +458,7 @@ def _parse_hlo(text):
                     line[paren + 1:end - 1],
                     op_name.group(1) if op_name else '',
                     calls.group(1) if calls else None,
-                    bool(m.group(1))))
+                    bool(m.group(1)), line[m.end():i], line[end:]))
             elif line.startswith('}'):
                 body = None
             continue
@@ -380,40 +492,336 @@ def _fusion_scope(fusion, body, op_types):
     return fluid_scope(fusion.op_name, op_types)
 
 
+# ------------------------------------------------- HLO instruction costs
+_HLO_ARRAY = re.compile(
+    r'\b([a-z]+\d*[a-z0-9]*)\[([^\]]*)\](?:\{[^}]*?S\(([1-9]\d*)\)[^}]*\})?')
+_HLO_WINDOW = re.compile(r'\bwindow=\{([^}]*)\}')
+_HLO_DIM_LABELS = re.compile(r'\bdim_labels=(\w+)_(\w+)->(\w+)')
+_HLO_FEATURE_GROUPS = re.compile(r'\bfeature_group_count=(\d+)')
+_HLO_CONTRACTING = re.compile(r'\blhs_contracting_dims=\{([\d,]*)\}')
+_HLO_REPLICA_GROUPS = re.compile(
+    r'\breplica_groups=(?:\{\{([\d,]*)\}|\[\d+,(\d+)\])')
+_ITEMSIZE = {'pred': 1, 's4': 0.5, 'u4': 0.5, 's8': 1, 'u8': 1,
+             's16': 2, 'u16': 2, 'f16': 2, 'bf16': 2, 's32': 4, 'u32': 4,
+             'f32': 4, 's64': 8, 'u64': 8, 'f64': 8, 'c64': 8, 'c128': 16}
+_COLLECTIVES = ('all-reduce', 'all-gather', 'reduce-scatter', 'all-to-all',
+                'collective-permute', 'collective-broadcast',
+                'ragged-all-to-all')
+_SLICED = ('slice', 'dynamic-slice', 'gather')
+_UPDATED = 'dynamic-update-slice'
+# control flow (its bodies' instructions are costed one by one) and
+# what moves no data
+_NO_COST = frozenset([
+    'while', 'conditional', 'call', 'parameter', 'tuple',
+    'get-tuple-element', 'bitcast', 'constant', 'after-all',
+    'partition-id', 'replica-id', 'opt-barrier', 'async-update'])
+
+
+@functools.lru_cache(maxsize=8192)
+def _arrays(shape):
+    """((dtype, dims, memory space), ...) of a shape's text, a tuple's
+    in order; the space is '' for the device's main memory, else the
+    ``S(n)`` of the layout."""
+    return tuple(
+        (dtype, tuple(int(d.lstrip('<=')) for d in dims.split(',') if d),
+         space)
+        for dtype, dims, space in _HLO_ARRAY.findall(shape))
+
+
+@functools.lru_cache(maxsize=8192)
+def _nbytes(shape, every_space=False):
+    """Bytes of a shape in the device's main memory: an array the
+    layout puts in another space (``S(1)``: a prefetched operand) is
+    not read from there.  ``every_space``: wherever they lie."""
+    total = 0
+    for dtype, dims, space in _arrays(shape):
+        if space and not every_space:
+            continue
+        size = 1 if dtype.startswith('f8') else _ITEMSIZE.get(dtype, 0)
+        total += size * math.prod(dims)
+    return int(total)
+
+
+def _dtype(shape):
+    """The type of a shape's first array, or None."""
+    typed = _arrays(shape)
+    return typed[0][0] if typed else None
+
+
+def _plain(shape):
+    """A shape's text without its layout."""
+    return ', '.join('%s[%s]' % (t, ','.join(map(str, dims)))
+                     for t, dims, _space in _arrays(shape))
+
+
+@functools.lru_cache(maxsize=4096)
+def _landing_taps(n, o, k, stride, lo, dilate, rhs_dilate):
+    """The (output position, window tap) pairs of one spatial dimension
+    of a convolution whose tap lands on an element of the lhs: ``n``
+    elements ``dilate`` apart behind ``lo`` of padding, ``o`` output
+    positions ``stride`` apart, ``k`` taps ``rhs_dilate`` apart.  One
+    congruence an output position, not one test a pair: a batch
+    dimension the TPU compiler writes as a spatial one has ``o == k ==``
+    its size."""
+    last = (n - 1) * dilate
+    g = math.gcd(rhs_dilate, dilate)
+    period = dilate // g
+    inverse = pow(rhs_dilate // g, -1, period) if period > 1 else 0
+    total = 0
+    for p in range(o):
+        base = p * stride - lo          # tap t lands at base + t * rhs_dilate
+        first = max(0, -(base // rhs_dilate))
+        end = min(k - 1, (last - base) // rhs_dilate)
+        if end < first or base % g:
+            continue
+        t0 = (-base // g * inverse) % period
+        total += (end - t0) // period - (first - 1 - t0) // period
+    return total
+
+
+def _window(attrs, rank):
+    """{field: [value a spatial dimension]} of ``window={...}``; ``pad``
+    is the low side's."""
+    m = _HLO_WINDOW.search(attrs)
+    given = dict(item.split('=', 1) for item in m.group(1).split()) \
+        if m else {}
+
+    def field(key, default):
+        if key not in given:
+            return [default] * rank
+        return [int(v.split('_')[0]) for v in given[key].split('x')]
+
+    return {'size': field('size', 1), 'stride': field('stride', 1),
+            'pad': field('pad', 0), 'lhs_dilate': field('lhs_dilate', 1),
+            'rhs_dilate': field('rhs_dilate', 1)}
+
+
+def _operand_names(ins):
+    return _HLO_OPERAND.findall(ins.operands)
+
+
+def _matmul_flops(ins, shapes):
+    """FLOPs of a ``dot`` or ``convolution`` by the rule above; None
+    where its text does not say (unknown, never 0)."""
+    try:
+        lhs = _arrays(shapes[_operand_names(ins)[0]])[0][1]
+        out = _arrays(ins.shape)[0][1]
+        if ins.opcode == 'dot':
+            m = _HLO_CONTRACTING.search(ins.attrs)
+            return 2 * math.prod(out) * math.prod(
+                lhs[int(i)] for i in m.group(1).split(',') if i)
+        lhs_l, _rhs_l, out_l = _HLO_DIM_LABELS.search(ins.attrs).groups()
+        spatial = sorted(c for c in out_l if c.isdigit())
+        window = _window(ins.attrs, len(spatial))
+        groups = _HLO_FEATURE_GROUPS.search(ins.attrs)
+        macs = out[out_l.index('b')] * out[out_l.index('f')] * (
+            lhs[lhs_l.index('f')] // (int(groups.group(1)) if groups else 1))
+        for j, c in enumerate(spatial):
+            macs *= _landing_taps(
+                lhs[lhs_l.index(c)], out[out_l.index(c)],
+                window['size'][j], window['stride'][j], window['pad'][j],
+                window['lhs_dilate'][j], window['rhs_dilate'][j])
+        return 2 * macs
+    except (AttributeError, IndexError, KeyError, ValueError):
+        return None
+
+
+def _held_cost(ins, shapes):
+    """(kind, flops, dtype, shapes' text) of a ``dot``, ``convolution``
+    or custom call."""
+    operands = [shapes.get(n, '') for n in _operand_names(ins)]
+    flops = None if ins.opcode == 'custom-call' else \
+        _matmul_flops(ins, shapes)
+    return (ins.opcode, flops,
+            _dtype(operands[0] if operands else '') or _dtype(ins.shape),
+            '%s -> %s' % (' x '.join(_plain(o) for o in operands),
+                          _plain(ins.shape)))
+
+
+def _moved_bytes(ins, shapes):
+    """Bytes of an instruction's operands and result, by the slice
+    rules."""
+    names = _operand_names(ins)
+    if ins.opcode in _SLICED:           # reads what it returns
+        return 2 * _nbytes(ins.shape) + sum(
+            _nbytes(shapes.get(n, '')) for n in names[1:])
+    if ins.opcode == _UPDATED:          # writes the update, in place
+        return 2 * _nbytes(shapes.get(names[1], '')) + sum(
+            _nbytes(shapes.get(n, '')) for n in names[2:])
+    return _nbytes(ins.shape) + sum(_nbytes(shapes.get(n, ''))
+                                    for n in names)
+
+
+def _boundary_bytes(body):
+    """Bytes a fusion moves at its boundary, from the computation it
+    calls: its parameters as far as the body reads them, its root as
+    far as the body writes it."""
+    by_name, users = {}, collections.defaultdict(list)
+    for ins in body:
+        by_name[ins.name] = ins
+        for position, name in enumerate(_operand_names(ins)):
+            users[name].append((ins, position))
+
+    def read(name, full):
+        total = 0
+        for user, position in users.get(name, ()):
+            if user.opcode == 'bitcast':
+                part = read(user.name, full)
+            elif user.opcode in _SLICED and position == 0:
+                part = _nbytes(user.shape)
+            elif user.opcode == _UPDATED and position == 0:
+                part = 0
+            else:
+                return full
+            total += part
+            if total >= full:
+                return full
+        return total
+
+    def written(name):
+        inner = by_name[name]
+        while inner.opcode == 'bitcast':
+            source = by_name.get((_operand_names(inner) or [None])[0])
+            if source is None:
+                break
+            inner = source
+        if inner.opcode == _UPDATED:
+            update = by_name.get((_operand_names(inner) + [None, None])[1])
+            if update is not None:
+                return _nbytes(update.shape)
+        return _nbytes(by_name[name].shape)
+
+    total = sum(read(ins.name, _nbytes(ins.shape)) for ins in body
+                if ins.opcode == 'parameter')
+    for root in [ins for ins in body if ins.root] or body[-1:]:
+        outputs = _operand_names(root) if root.opcode == 'tuple' \
+            else [root.name]
+        total += sum(written(n) for n in outputs if n in by_name)
+    return total
+
+
+def _group_size(attrs):
+    m = _HLO_REPLICA_GROUPS.search(attrs)
+    if not m:
+        return None
+    if m.group(2):
+        return int(m.group(2))
+    return len([d for d in m.group(1).split(',') if d]) or None
+
+
+def _collective_cost(ins, shapes, attrs):
+    names = _operand_names(ins)
+    nbytes = sum(_nbytes(shapes.get(n, ''), True) for n in names)
+    return Cost('collective', 0, nbytes,
+                _dtype(shapes.get(names[0], '')) if names else None,
+                _group_size(attrs),
+                ', '.join(_plain(shapes.get(n, '')) for n in names))
+
+
+def _instruction_cost(ins, shapes, called):
+    """The Cost of one instruction a trace can name, by the rule above,
+    or None; ``shapes`` are those of its computation's instructions,
+    ``called`` the body of the computation it calls, if any."""
+    opcode = ins.opcode
+    base = opcode[:-6] if opcode.endswith('-start') else \
+        opcode[:-5] if opcode.endswith('-done') else opcode
+    if base in _COLLECTIVES:
+        return None if opcode.endswith('-done') else \
+            _collective_cost(ins, shapes, ins.attrs)
+    if base == 'async':
+        wrapped = [i for i in called or () if i.opcode in _COLLECTIVES]
+        return _collective_cost(ins, shapes, wrapped[0].attrs) \
+            if wrapped and opcode == 'async-start' else None
+    if opcode in _NO_COST or base != opcode:
+        return None                     # an asynchronous copy or slice
+    if opcode == 'fusion' and called is not None:
+        inner = {i.name: i.shape for i in called}
+        held = [_held_cost(i, inner) for i in called
+                if i.opcode in _HELD_BY_FUSION]
+        nbytes = _boundary_bytes(called)
+    else:
+        held = [_held_cost(ins, shapes)] \
+            if opcode in _HELD_BY_FUSION else []
+        nbytes = _moved_bytes(ins, shapes)
+    if not held:
+        return Cost(opcode, 0, nbytes, _dtype(ins.shape), None,
+                    _plain(ins.shape))
+    unknown = any(h[1] is None for h in held)
+    kind, _, dtype, text = max(
+        held, key=lambda h: (h[0] != 'custom-call', h[1] or 0))
+    return Cost(kind, None if unknown else sum(h[1] for h in held),
+                nbytes, dtype, None, text)
+
+
+def _tables(hlo_text, op_types=None):
+    """One compiled module's optimised HLO text -> (module name, scope
+    table, cost table) from ONE parse; both tables hold every
+    instruction a trace can name (those of fused computations are left
+    out, their fusion stands for them), so ``pick_table`` picks the
+    same program in both."""
+    op_types = op_types or _registered_op_types()
+    module, computations = _parse_hlo(hlo_text)
+    fused = {ins.calls for body in computations.values() for ins in body
+             if ins.opcode == 'fusion'}
+    scopes, costs = {}, {}
+    for name, body in computations.items():
+        if name in fused:
+            continue
+        shapes = {ins.name: ins.shape for ins in body}
+        for ins in body:
+            called = computations.get(ins.calls)
+            if ins.opcode == 'fusion' and called is not None:
+                scopes[ins.name] = _fusion_scope(ins, called, op_types)
+            else:
+                scopes[ins.name] = fluid_scope(ins.op_name, op_types)
+            costs[ins.name] = _instruction_cost(ins, shapes, called)
+    return module, scopes, costs
+
+
 def hlo_scopes(hlo_text, op_types=None):
     """One compiled module's optimised HLO text (``Compiled.as_text()``)
     -> (module name, {instruction name: fluid scope or None}) for every
     instruction a trace can name: those of fused computations are left
     out, their fusion stands for them."""
-    op_types = op_types or _registered_op_types()
-    module, computations = _parse_hlo(hlo_text)
-    fused = {ins.calls for body in computations.values() for ins in body
-             if ins.opcode == 'fusion'}
-    table = {}
-    for name, body in computations.items():
-        if name in fused:
-            continue
-        for ins in body:
-            if ins.opcode == 'fusion' and ins.calls in computations:
-                table[ins.name] = _fusion_scope(
-                    ins, computations[ins.calls], op_types)
-            else:
-                table[ins.name] = fluid_scope(ins.op_name, op_types)
-    return module, table
+    return _tables(hlo_text, op_types)[:2]
+
+
+def hlo_costs(hlo_text):
+    """The same text -> (module name, {instruction name: Cost or None})
+    for the same instructions, by the cost rule above."""
+    module, _scopes, costs = _tables(hlo_text)
+    return module, costs
+
+
+def _held_tables():
+    """[(module name, scope table, cost table)] of every executable
+    this process holds.  The compile plane keeps what ``_tables`` made
+    of an executable while it holds it: each is printed and parsed
+    once, whichever table is asked for first and however often."""
+    from . import compile_cache
+    return [built for _key, built in
+            compile_cache.plane().held_tables(_tables)]
 
 
 def scope_tables():
     """{HLO module name: [table, ...]} (tables as ``hlo_scopes`` gives
     them) of every executable this process holds
-    (``CompilePlane.held_hlo``).  Built when asked for and at no other
-    time: it prints and parses whole modules, seconds at BERT-base.  Two
-    programs of one name (a segment planned for two fetch lists) keep a
-    table each; ``pick_table`` tells them apart."""
-    from . import compile_cache
+    (``CompilePlane.held_tables``).  Built when asked for and at no
+    other time: it prints and parses whole modules, seconds at
+    BERT-base.  Two programs of one name (a segment planned for two
+    fetch lists) keep a table each; ``pick_table`` tells them apart."""
     tables = {}
-    for _key, text in compile_cache.plane().held_hlo():
-        module, table = hlo_scopes(text)
-        tables.setdefault(module, []).append(table)
+    for module, scopes, _costs in _held_tables():
+        tables.setdefault(module, []).append(scopes)
+    return tables
+
+
+def cost_tables():
+    """{HLO module name: [table, ...]} (tables as ``hlo_costs`` gives
+    them), beside ``scope_tables()`` and from the same parse."""
+    tables = {}
+    for module, _scopes, costs in _held_tables():
+        tables.setdefault(module, []).append(costs)
     return tables
 
 
@@ -466,15 +874,22 @@ def instruction_scopes(ops, tables):
     by_program = {}
     for i, (program, _name) in enumerate(ops):
         by_program.setdefault(program, []).append(i)
-    scopes = [None] * len(ops)
+    found = [None] * len(ops)
     for program, indices in by_program.items():
         candidates = tables.get(_PROGRAM_ID.sub('', program))
         if candidates is None and not program:
             candidates = [t for ts in tables.values() for t in ts]
         table = pick_table(candidates, {ops[i][1] for i in indices})
         for i in indices:
-            scopes[i] = table.get(ops[i][1])
-    return scopes
+            found[i] = table.get(ops[i][1])
+    return found
+
+
+def instruction_costs(ops, tables):
+    """The same walk over ``cost_tables()``: -> the instructions' Costs
+    (None for none).  Both tables of a program hold the same
+    instructions, so both walks pick the same program."""
+    return instruction_scopes(ops, tables)
 
 
 # ---------------------------------------------------- reading the trace
@@ -514,13 +929,16 @@ def load_trace_events(logdir):
     one pid per plane, one tid per line.  An event that is an executed
     HLO instruction (a TPU plane's 'XLA Ops' line, or a CPU thunk with
     an ``hlo_op`` stat) carries ``args['tf_op']``: the fluid scope
-    ``scope_tables()`` gives it, else the module's name, and
-    ``self_dur`` where other events of its line nest inside it."""
+    ``scope_tables()`` gives it, else the module's name,
+    ``self_dur`` where other events of its line nest inside it, and
+    what ``cost_tables()`` knows of it: ``args['kind']``, ``args['mb']``
+    and, unless it is a custom call, ``args['gflop']`` and the rates
+    over its ``dur``, ``args['tflops']`` and ``args['gbps']``."""
     path = _newest_xplane(logdir)
     if path is None:
         return []
     from jax.profiler import ProfileData
-    tables = scope_tables()
+    tables = scope_tables(), cost_tables()
     out = []
     for pid, plane in enumerate(ProfileData.from_file(path).planes):
         out.append({'ph': 'M', 'pid': pid, 'name': 'process_name',
@@ -551,16 +969,28 @@ def load_trace_events(logdir):
 
 
 def _attach_scopes(rows, tables):
-    """Give the instruction events of one line their ``tf_op`` and
-    ``self_dur``."""
+    """Give the instruction events of one line their ``tf_op``, their
+    cost and their ``self_dur``; ``tables`` are (scope tables, cost
+    tables)."""
     if not rows:
         return
     programs = [row.pop('module') for row in rows]
-    scopes = instruction_scopes(
-        [(p, row['name']) for p, row in zip(programs, rows)], tables)
-    for row, program, scope in zip(rows, programs, scopes):
-        row['args'] = {'tf_op': scope or _PROGRAM_ID.sub('', program) or
-                       'unknown_module'}
+    ops = [(p, row['name']) for p, row in zip(programs, rows)]
+    scopes = instruction_scopes(ops, tables[0])
+    costs = instruction_costs(ops, tables[1])
+    for row, program, scope, cost in zip(rows, programs, scopes, costs):
+        args = row['args'] = {
+            'tf_op': scope or _PROGRAM_ID.sub('', program) or
+            'unknown_module'}
+        if cost is None:
+            continue
+        args['kind'] = cost.kind
+        args['mb'] = cost.bytes / 1e6
+        if cost.flops is not None:
+            args['gflop'] = cost.flops / 1e9
+            if row['dur'] > 0:          # us: MB / us is TB/s
+                args['gbps'] = args['mb'] / row['dur'] * 1e3
+                args['tflops'] = args['gflop'] / row['dur'] * 1e3
     for row, own in zip(rows, _self_durations(
             [(r['ts'], r['dur']) for r in rows])):
         if own != row['dur']:
@@ -666,7 +1096,8 @@ def stop_profiler(sorted_key='total', profile_path=None):
             host_cap = trace_mod.detach_capture()
         device_events = load_trace_events(_prof_trace_dir)
         recs, stats = attribute_trace_events(
-            [e for e in device_events if 'args' in e], with_stats=True)
+            [e for e in device_events if 'args' in e], with_stats=True,
+            costs=_costs)
         _records.update(recs)
         if stats['dropped']:
             # malformed capture rows are counted, not silently eaten
@@ -751,7 +1182,8 @@ def stop_trace():
     """Stop the device capture; returns the logdir.  The capture's
     ``.xplane.pb`` is read back (``load_trace_events``): its per-op
     device time becomes the profiler's table (``summary_records()`` /
-    ``summary_string()``), and its events persist as
+    ``summary_string()``, with each row's FLOPs, bytes and achieved
+    rates where ``cost_tables()`` knows them), and its events persist as
     '<logdir>/device.trace.json' beside the attached span tracer's
     host events, '<logdir>/host_trace.json', for the timeline merger
     (tools/timeline.py)."""
@@ -770,7 +1202,7 @@ def stop_trace():
     device_events = load_trace_events(path)
     reset_profiler()
     _records.update(attribute_trace_events(
-        [e for e in device_events if 'args' in e]))
+        [e for e in device_events if 'args' in e], costs=_costs))
     try:
         trace_mod.write_chrome(os.path.join(path, 'device.trace.json'),
                                device_events)

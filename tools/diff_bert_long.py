@@ -28,9 +28,6 @@ def build_framework(batch, seq):
     exe = fluid.Executor(fluid.XLAPlace(0))
     with fluid.scope_guard(scope):
         exe.run(startup)
-        cost = exe.program_cost(main, batch_data, fetch_list=[loss])
-        print('framework cost: %.1f GFLOP  %.2f GB/step'
-              % (cost['flops'] / 1e9, cost['bytes'] / 1e9))
 
     def run_steps(n):
         with fluid.scope_guard(scope):
