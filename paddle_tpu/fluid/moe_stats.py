@@ -31,8 +31,10 @@ reports, on the same runs:
 - gauge ``moe/walked_share``: the rows such layers' permutation
   walked on the last run read over the rows of their buffers: it
   walks a buffer in chunks (``parallel.moe.held_rows_chunk``) up to
-  the one that holds the last held row, so this is what the
-  permutation's time follows.
+  the one that holds the last held row, and the expert MLP's
+  element-wise work between its grouped matmuls walks the same
+  chunks, so this is what the permutation's time and that of
+  ``moe_experts`` outside its matmuls follow.
 
 A layer whose router carries a choice bias that its train program
 moves (``score_bias`` with a ``bias_update_rate``) reports:

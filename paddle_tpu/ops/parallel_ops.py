@@ -349,19 +349,20 @@ def moe_experts_op(ctx, ins, attrs):
     (``ragged-dot-none``, ``ragged-dot-metadata``), forward and
     backward alike.  In a layer that holds a range of the experts
     (attrs['experts_held']) most of the buffer lies past the last
-    group: the three [M, H] intermediates are not kept for the
-    backward pass but computed again there (the grouped matmuls skip
-    the rows past the groups; keeping them costs M, not the rows
-    held)."""
-    from ..parallel.moe import grouped_gated_mlp
+    group, and the grouped matmuls skip those rows: there the op is
+    parallel.moe.held_gated_mlp, whose SiLU product, its backward and
+    the sum of Rows' two cotangents walk the chunks of the buffer that
+    hold a held row, in place, so that what lies between the products
+    follows sum(GroupSizes) as the products do (``moe/walked_share``),
+    and whose backward computes gate and up again instead of keeping
+    the [M, H] intermediates, whose cost is M, not the rows held."""
+    from ..parallel.moe import grouped_gated_mlp, held_gated_mlp
     rows = ins['Rows'][0]
     low = bool(attrs.get('__amp__')) and \
         rows.dtype in (jnp.float32, jnp.bfloat16)
-    mlp = functools.partial(grouped_gated_mlp, low_precision=low)
-    if _held(attrs) is not None:
-        mlp = jax.checkpoint(mlp)
+    mlp = grouped_gated_mlp if _held(attrs) is None else held_gated_mlp
     return {'Out': [mlp(rows, ins['GroupSizes'][0], ins['WGate'][0],
-                        ins['WUp'][0], ins['WDown'][0])]}
+                        ins['WUp'][0], ins['WDown'][0], low)]}
 
 
 @register('moe_combine')

@@ -480,15 +480,15 @@ def _combine_bwd(res, g):
 combine_rows.defvjp(_combine_fwd, _combine_bwd)
 
 
-def grouped_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
-                      low_precision=False):
-    """down(silu(gate x) * up x) for rows grouped by expert.
+def _gated(gate, up, dtype):
+    """silu(gate) * up, multiplied in float32, in ``dtype``."""
+    return (jax.nn.silu(gate.astype(jnp.float32)) *
+            up.astype(jnp.float32)).astype(dtype)
 
-    rows [M, D] (group e is the next group_sizes[e] rows), w_gate and
-    w_up [E, D, H], w_down [E, H, D] -> [M, D].  One ragged matmul per
-    weight set (``jax.lax.ragged_dot``: the TPU compiler's own grouped
-    matmul, 2*M*D*H FLOPs whatever the grouping).  ``low_precision``
-    (AMP) multiplies in bfloat16 and keeps the [M, H] intermediates in
+
+def _operands(rows, group_sizes, w_gate, w_up, w_down, low_precision):
+    """What the grouped matmuls multiply and how -> (dot, rows, w_gate,
+    w_up, w_down): ``low_precision`` (AMP) casts everything to
     bfloat16; otherwise float32 operands multiply at full precision."""
     if low_precision:
         rows = rows.astype(jnp.bfloat16)
@@ -500,8 +500,115 @@ def grouped_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
             if rows.dtype == jnp.float32 else None
     dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
                             precision=precision)
+    return dot, rows, w_gate, w_up, w_down
+
+
+def grouped_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
+                      low_precision=False):
+    """down(silu(gate x) * up x) for rows grouped by expert.
+
+    rows [M, D] (group e is the next group_sizes[e] rows), w_gate and
+    w_up [E, D, H], w_down [E, H, D] -> [M, D].  One ragged matmul per
+    weight set (``jax.lax.ragged_dot``: the TPU compiler's own grouped
+    matmul, 2*M*D*H FLOPs whatever the grouping).  ``low_precision``
+    (AMP) multiplies in bfloat16 and keeps the [M, H] intermediates in
+    bfloat16; otherwise float32 operands multiply at full precision."""
+    dot, rows, w_gate, w_up, w_down = _operands(
+        rows, group_sizes, w_gate, w_up, w_down, low_precision)
     gate = dot(rows, w_gate)
     up = dot(rows, w_up)
-    hidden = (jax.nn.silu(gate.astype(jnp.float32)) *
-              up.astype(jnp.float32)).astype(rows.dtype)
+    return dot(_gated(gate, up, rows.dtype), w_down)
+
+
+def _rewrite_held(held_rows, per_chunk, buffers, *read):
+    """``buffers`` (a tuple of [R, .]) with every chunk of theirs up to
+    the one that holds row ``held_rows - 1`` (_walk_held) replaced by
+    ``per_chunk(*chunks of buffers, *chunks of read)``, in place: the
+    buffers are the loop's carry.  A row handed to two trips keeps what
+    the first one wrote: the second would read what was written where
+    it expects what was there before.  Rows past those chunks stay as
+    they were."""
+    def trip(at, fresh, buffers):
+        old = tuple(_chunk(a, at) for a in buffers)
+        new = per_chunk(*old, *(_chunk(a, at) for a in read))
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                a, jnp.where(fresh[:, None], n, o), at[0], 0)
+            for a, n, o in zip(buffers, new, old))
+
+    return _walk_held(buffers[0].shape[0], held_rows, trip, buffers)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def held_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
+                   low_precision=False):
+    """grouped_gated_mlp for a layer that holds a range of its
+    experts, whose groups fill the first ``sum(group_sizes)`` rows of a
+    worst-case buffer (held_rows_bound): the same products, and
+    everything between them walks the chunks of the buffer that hold a
+    held row (_rewrite_held): silu(gate) * up is written over
+    ``gate``.  Rows past those chunks stay as the grouped matmuls
+    leave them, unwritten on the chip: every consumer is a grouped
+    matmul that skips them again.
+
+    Its own backward computes gate and up again and keeps no [M, H]
+    intermediate between the passes (the buffer's cost is its static
+    length): one loop turns (gate, up, dhidden) into (dgate, dup,
+    hidden), each over the buffer it came from, and a second adds the
+    up branch's cotangent of ``rows`` to the gate branch's."""
+    dot, rows, w_gate, w_up, w_down = _operands(
+        rows, group_sizes, w_gate, w_up, w_down, low_precision)
+    hidden, = _rewrite_held(
+        jnp.sum(group_sizes),
+        lambda gate, up: (_gated(gate, up, rows.dtype),),
+        (dot(rows, w_gate),), dot(rows, w_up))
     return dot(hidden, w_down)
+
+
+def _held_fwd(rows, group_sizes, w_gate, w_up, w_down, low_precision):
+    return held_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
+                          low_precision), \
+        (rows, group_sizes, w_gate, w_up, w_down)
+
+
+def _held_bwd(low_precision, res, dout):
+    # the barrier (jax.checkpoint's own) keeps the compiler from
+    # sharing the forward pass's casts and products with the ones
+    # computed again here, which would keep them alive in between, and
+    # from computing them before ``dout`` is there
+    group_sizes = res[1]
+    rows, w_gate, w_up, w_down, dout = jax.lax.optimization_barrier(
+        (res[0],) + res[2:] + (dout,))
+    dot, rows_c, w_gate_c, w_up_c, w_down_c = _operands(
+        rows, group_sizes, w_gate, w_up, w_down, low_precision)
+    held_rows = jnp.sum(group_sizes)
+
+    def grad(product, at, cotangent):
+        # a grouped matmul is linear in either operand
+        return jax.linear_transpose(product, at)(cotangent)[0]
+
+    def gated_grad(gate, up, dhidden):
+        hidden, back = jax.vjp(
+            lambda g, u: _gated(g, u, rows_c.dtype), gate, up)
+        return back(dhidden) + (hidden,)
+
+    gate, up = dot(rows_c, w_gate_c), dot(rows_c, w_up_c)
+    dgate, dup, hidden = _rewrite_held(
+        held_rows, gated_grad,
+        (gate, up, grad(lambda h: dot(h, w_down_c), gate, dout)))
+    drows, = _rewrite_held(
+        held_rows,
+        lambda a, b: ((a.astype(jnp.float32) +
+                       b.astype(jnp.float32)).astype(a.dtype),),
+        (grad(lambda r: dot(r, w_gate_c), rows_c, dgate),),
+        grad(lambda r: dot(r, w_up_c), rows_c, dup))
+    return (drows.astype(rows.dtype), None,
+            grad(lambda w: dot(rows_c, w), w_gate_c, dgate).astype(
+                w_gate.dtype),
+            grad(lambda w: dot(rows_c, w), w_up_c, dup).astype(
+                w_up.dtype),
+            grad(lambda w: dot(hidden, w), w_down_c, dout).astype(
+                w_down.dtype))
+
+
+held_gated_mlp.defvjp(_held_fwd, _held_bwd)

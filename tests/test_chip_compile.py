@@ -323,6 +323,52 @@ def test_grouped_expert_matmuls_at_the_olmoe_cell_shape(one_chip):
         16 * rows * d * 2
 
 
+@pytest.mark.parametrize('rows,d,hidden', [
+    (49152, 2048, 1408), (32768, 3072, 1024)], ids=['moonlight', 'laguna'])
+def test_a_held_layers_experts_pass_over_no_whole_buffer(one_chip, rows,
+                                                         d, hidden):
+    """One held layer's ``moe_experts`` and its gradient op at the two
+    held cells' shapes (8 experts held, bf16 rows under AMP's casts of
+    f32 weights): whatever has a [rows, .] result, in any computation
+    of the module, is a grouped-matmul call, a loop handing on its
+    carry, or a trip's write of its chunk into the carry: no fusion
+    over the buffer, no ``add`` of two cotangents, no ``copy`` of a
+    carry at a loop's entry or in its body.  What runs between the
+    products runs inside the three loops, a chunk a trip."""
+    import re
+    lower = registry.get('moe_experts').fn
+    grad = registry.grad_op_def(registry.get('moe_experts')).fn
+    attrs = {'experts_held': (0, 8), '__amp__': True}
+
+    def step(x, sizes, gate, up, down, dout):
+        ins = {'Rows': [x], 'GroupSizes': [sizes], 'WGate': [gate],
+               'WUp': [up], 'WDown': [down]}
+        with jax.named_scope('moe_experts'):
+            out = lower(None, ins, attrs)['Out'][0]
+        with jax.named_scope('moe_experts_grad'):
+            grads = grad(None, dict(ins, **{'GRAD::Out': [dout]}), attrs)
+        return out, grads
+
+    buffer = _spec((rows, d), jnp.bfloat16)
+    wide = _spec((8, d, hidden))
+    text = _compiled(step, one_chip, buffer, _spec((8,), jnp.int32), wide,
+                     wide, _spec((8, hidden, d)), buffer).as_text()
+    # copy-start / copy-done: the compiler's own prefetch of a product's
+    # operand into its 128 MiB of fast memory, where that is free (here,
+    # alone; in no cell's step)
+    idle = {'parameter', 'tuple', 'get-tuple-element', 'bitcast', 'while',
+            'custom-call', 'opt-barrier', 'dynamic-update-slice',
+            'copy-start', 'copy-done'}
+    passes = [
+        line.strip()[:160] for line in text.split('\n')
+        if re.search(r' = \(?(\w+\[[\d,]*\]\S* )*\w+\[%d,' % rows, line)
+        and re.search(r' ([\w\-]+)\(', line.split(' = ', 1)[1]).group(1)
+        not in idle]
+    assert not passes, passes
+    assert text.count(' while(') == 3 and ' conditional(' not in text
+    assert text.count('custom_call_target="tpu_custom_call"') >= 11
+
+
 def test_lookup_table_and_its_gradient_hold_no_kernel(one_chip):
     """lookup_table_v2 and its gradient at the largest table a cell
     has (olmoe_1b7b_s4096: 12,288 tokens into 50304 x 2048) compile

@@ -8,6 +8,7 @@ published widths are checked on the chip (``chip_smoke.py --phase
 laguna``, PERF.md)."""
 
 import copy
+import functools
 
 import numpy as np
 import pytest
@@ -328,6 +329,108 @@ def test_rows_past_the_held_groups_carry_nothing_whatever_they_hold(
     text = str(jax.make_jaxpr(through)(x, w, y, g_rows, g_out,
                                        held_rows))
     assert text.count('while[') == 3 and 'cond[' not in text
+
+
+def _experts_inputs(n_held, dtype, n_rows=24, d=6, hidden=5,
+                    experts=3):
+    """A buffer of ``n_rows`` whose first ``n_held`` rows are the
+    groups of ``experts`` held experts, the rest NaN, in ``rows`` and
+    in the output's cotangent -> (sizes, rows, dout, three weights)."""
+    rng = np.random.RandomState(n_held)
+    sizes = np.bincount(rng.randint(experts, size=n_held),
+                        minlength=experts).astype(np.int32)
+    dead = (np.arange(n_rows) >= n_held)[:, None]
+    rows, dout = (jnp.asarray(np.where(dead, np.nan, rng.randn(n_rows, d)),
+                              dtype) for _ in range(2))
+    weights = [jnp.asarray(rng.randn(*shape) / 2, jnp.float32)
+               for shape in ((experts, d, hidden), (experts, d, hidden),
+                             (experts, hidden, d))]
+    return jnp.asarray(sizes), rows, dout, weights
+
+
+def _through_experts(body, sizes, low, rows, dout, *weights):
+    out, vjp = jax.vjp(
+        lambda rows, *w: body(rows, sizes, *w, low), rows, *weights)
+    return (out,) + vjp(dout)
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+@pytest.mark.parametrize('chunk', [8, 7, 24])
+@pytest.mark.parametrize('n_held', [0, 5, 8, 9, 24])
+def test_the_held_experts_body_is_the_plain_one_on_the_rows_held(
+        n_held, chunk, dtype, monkeypatch):
+    """held_gated_mlp (the SiLU product, its backward and the sum of
+    the rows' two cotangents walked in chunks, in place; its own
+    backward that computes gate and up again) against
+    ``jax.checkpoint(grouped_gated_mlp)``, which it replaced in the
+    layers that hold a range of their experts: with the rows past the
+    held ones NaN in ``rows`` and in the output's cotangent, the
+    output and the rows' gradient on the held rows and the three
+    weight gradients are equal within float32 rounding (bfloat16 under
+    AMP's casts: two units) and finite, whatever the chunk and
+    wherever in a chunk the held rows end; one loop forward, two
+    backward and no conditional."""
+    monkeypatch.setattr(pmoe, 'held_rows_chunk',
+                        lambda n_rows: min(n_rows, chunk))
+    low = dtype == 'bfloat16'
+    sizes, rows, dout, weights = _experts_inputs(n_held, dtype)
+
+    def plain(rows, sizes, w_gate, w_up, w_down, low):
+        return jax.checkpoint(functools.partial(
+            pmoe.grouped_gated_mlp, low_precision=low))(
+                rows, sizes, w_gate, w_up, w_down)
+
+    got = jax.jit(functools.partial(
+        _through_experts, pmoe.held_gated_mlp, sizes, low))(
+            rows, dout, *weights)
+    want = jax.jit(functools.partial(_through_experts, plain, sizes, low))(
+        jnp.nan_to_num(rows), jnp.nan_to_num(dout), *weights)
+    unit = 2 * 2.0 ** -8 if low else 1e-6
+    for name, a, b in zip(('out', 'drows', 'dw_gate', 'dw_up', 'dw_down'),
+                          got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = (np.asarray(v, np.float32) for v in (a, b))
+        if name in ('out', 'drows'):    # past the held rows: anything
+            a, b = a[:n_held], b[:n_held]
+        assert np.isfinite(a).all(), name
+        assert np.abs(a - b).max(initial=0) <= unit * max(
+            np.abs(b).max(initial=0), 1), name
+    text = str(jax.make_jaxpr(functools.partial(
+        _through_experts, pmoe.held_gated_mlp, sizes, low))(
+            rows, dout, *weights))
+    assert text.count('while[') == 3 and 'cond[' not in text
+
+
+@pytest.mark.parametrize('held', [None, (0, 3)], ids=['all', 'a_range'])
+def test_only_a_held_layers_experts_loop(held):
+    """``moe_experts`` without ``experts_held`` (OLMoE: every row of
+    the buffer is some expert's) traces to grouped_gated_mlp as it
+    was: three products forward, no loop, no body with a backward of
+    its own, nothing computed twice.  With a held range: the same
+    three products forward, the loops, no conditional."""
+    from paddle_tpu.ops import registry
+    sizes, rows, dout, weights = _experts_inputs(9, 'float32')
+
+    def experts(rows, *w):
+        return registry.get('moe_experts').fn(
+            _Ctx(), {'Rows': [rows], 'GroupSizes': [sizes], 'WGate': [w[0]],
+                     'WUp': [w[1]], 'WDown': [w[2]]},
+            {'experts_held': held})['Out'][0]
+
+    forward = str(jax.make_jaxpr(experts)(rows, *weights))
+    both = str(jax.make_jaxpr(
+        lambda *a: jax.vjp(experts, *a)[1](dout))(rows, *weights))
+    assert 'cond[' not in both and 'checkpoint' not in both
+    if held is None:
+        plain = str(jax.make_jaxpr(
+            lambda rows, *w: pmoe.grouped_gated_mlp(rows, sizes, *w))(
+                rows, *weights))
+        assert forward == plain and 'custom_vjp' not in forward
+        assert 'while[' not in both
+    else:
+        assert forward.count('while[') == 1 and both.count('while[') == 3
+        assert 'optimization_barrier' in both
+    assert forward.count('ragged_dot_general[') == 3
 
 
 @pytest.mark.parametrize('chunk', [5, 16, 512])
