@@ -33,7 +33,7 @@ def _mark_amp_ops(program, amp_lists):
     # rule is theirs for free without degrading the parameters
     no_harmonize = {'batch_norm', 'layer_norm', 'instance_norm',
                     'group_norm', 'sync_batch_norm',
-                    'rms_norm', 'rotary_embedding',
+                    'rms_norm', 'rotary_embedding', 'short_conv',
                     # f32 gates beside bf16 rows: cast neither way
                     'moe_route', 'moe_dispatch', 'moe_combine',
                     # compute in f32 internally; black-casting their

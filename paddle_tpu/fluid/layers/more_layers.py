@@ -182,6 +182,28 @@ def row_conv(input, future_context_size, param_attr=None, act=None,
     return helper.append_activation(out, act)
 
 
+def short_conv(input, filter_size, gate_in=None, gate_out=None,
+               param_attr=None, name=None):
+    """A causal depthwise convolution over time with one
+    ``filter_size``-tap filter a channel and no bias (``row_conv``
+    looks ahead; this looks back): input [B, T, C] -> out[b, t] =
+    sum_j w[:, j] * z[b, t - (filter_size - 1) + j], z zero before the
+    sequence starts, so the LAST tap weighs the token itself.  The
+    filter is a parameter [C, filter_size].  ``gate_in`` and
+    ``gate_out`` ([B, T, C] each) fuse the two multiplicative gates of
+    a gated short convolution in: z = input * gate_in, out = gate_out *
+    (the filter of z); without them z = input."""
+    helper = LayerHelper('short_conv', name=name)
+    w = helper.create_parameter(
+        param_attr, [int(input.shape[-1]), int(filter_size)], input.dtype)
+    ins = {'X': input, 'Filter': w}
+    if gate_in is not None:
+        ins['GateIn'] = gate_in
+    if gate_out is not None:
+        ins['GateOut'] = gate_out
+    return _simple('short_conv', ins, name=name)
+
+
 def grid_sampler(x, grid, name=None):
     return _simple('grid_sampler', {'X': x, 'Grid': grid}, name=name)
 

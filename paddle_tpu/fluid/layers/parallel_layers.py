@@ -71,7 +71,7 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
         aux_weight=0.01, axis='ep', top_k=1, param_attr=None,
         name=None, renormalize=True, z_loss_weight=0.0,
         experts_held=None, gate_scale=1.0, score_func='softmax',
-        score_bias=None, bias_update_rate=0.0):
+        score_bias=None, bias_update_rate=0.0, renorm_eps=1e-20):
     """Mixture-of-Experts feed-forward layer, in two forms.
 
     **Capacity-based** (``capacity_factor`` a number, the default):
@@ -112,7 +112,8 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
     ``score_func='sigmoid'`` (dropless only) scores each expert by the
     sigmoid of its own logit instead of the softmax over all;
     ``renormalize`` then divides the chosen scores by (their sum +
-    1e-20).  ``score_bias`` (sigmoid only: True, or a ``ParamAttr``
+    ``renorm_eps``: 1e-20 by default, DeepSeek-V3's; LFM2 publishes
+    1e-6).  ``score_bias`` (sigmoid only: True, or a ``ParamAttr``
     whose initializer draws its startup values; default zeros) adds a
     persistable, NON-trainable [num_experts] float32 bias to the
     scores for the CHOICE of the ``top_k`` experts only: the gates are
@@ -195,7 +196,7 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
                              axis, renormalize, aux_weight,
                              z_loss_weight, experts_held,
                              float(gate_scale), score_func, score_bias,
-                             float(bias_update_rate))
+                             float(bias_update_rate), float(renorm_eps))
     w1, w2 = weight([e, d, h]), weight([e, h, d])
     out = helper.create_variable_for_type_inference(x.dtype)
     aux = helper.create_variable_for_type_inference('float32')
@@ -216,7 +217,7 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
 def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
                   renormalize, aux_weight, z_loss_weight, held=None,
                   gate_scale=1.0, score_func='softmax', score_bias=None,
-                  bias_update_rate=0.0):
+                  bias_update_rate=0.0, renorm_eps=1e-20):
     d = int(x.shape[-1])
     here = e if held is None else held[1]      # experts with weights
     w_gate, w_up, w_down = weight([here, d, h]), weight([here, d, h]), \
@@ -243,6 +244,8 @@ def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
     route_ins = {'X': x, 'Gate': wg}
     if score_func != 'softmax':
         route_attrs['score_func'] = score_func
+    if renorm_eps != 1e-20:     # the default leaves the op as it was
+        route_attrs['renorm_eps'] = renorm_eps
     bias = None
     if score_bias:
         from ..initializer import Constant

@@ -1108,6 +1108,41 @@ def row_conv(ctx, ins, attrs):
     return {'Out': [out]}
 
 
+@register('short_conv')
+def short_conv(ctx, ins, attrs):
+    """A causal depthwise filter over time, row_conv's mirror image:
+    X [B, T, C], Filter [C, L] -> Out[b, t] = sum_{j<L} Filter[:, j] *
+    z[b, t - (L - 1) + j] with z zero before a sequence's start, so
+    Filter[:, L - 1] weighs the token itself, nothing later enters and
+    nothing crosses from one sequence of the batch into the next (a
+    ``Conv1d(C, C, L, groups=C, padding=L - 1)`` cut to its first T
+    outputs, no bias).  The two multiplicative gates of a gated short
+    convolution (Liquid's LFM2 ``conv`` operator) fuse in where given:
+    z = X * GateIn before the filter, Out = GateOut * (filter of z)
+    after it, each [B, T, C]; without them z = X.
+
+    L shifted multiply-adds in float32 whatever X is, Out in X's dtype
+    (the rms_norm policy: a bf16 stream stays bf16 past the f32
+    filter).  Bytes-bound: every operand is read once and Out written
+    once in one fusion; the gradient (jax.vjp of this) is the same
+    pattern shifted the other way plus the filter's sum over B and T."""
+    from ..fluid import monitor
+    monitor.add('short_conv/calls', 1)
+    x = ins['X'][0]
+    w = ins['Filter'][0]
+    taps = w.shape[1]
+    t = x.shape[1]
+    f32 = jnp.float64 if x.dtype == jnp.float64 else jnp.float32
+    z = x.astype(f32)
+    if ins.get('GateIn'):
+        z = z * ins['GateIn'][0].astype(f32)
+    zp = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
+    out = sum(zp[:, j:j + t] * w[:, j].astype(f32) for j in range(taps))
+    if ins.get('GateOut'):
+        out = out * ins['GateOut'][0].astype(f32)
+    return {'Out': [out.astype(x.dtype)]}
+
+
 @register('conv_shift')
 def conv_shift(ctx, ins, attrs):
     """Reference operators/conv_shift_op.cc: circular convolution

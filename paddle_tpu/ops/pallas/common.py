@@ -183,8 +183,22 @@ def scoped_vmem(t, d, block_q, block_k, itemsize, dv=None):
     16 MB inside its train step), and the call asks for twice its
     estimate and 16 MB for the tiles' temporaries (a float32 dkv call
     with 512 x 1024 tiles takes 42.4 MB: its full-precision products
-    split every operand), of the 128 MB a v5e core has."""
-    if 2 * resident_row_bytes(t, d, itemsize, dv) < VMEM_BUDGET_BYTES:
+    split every operand), of the 128 MB a v5e core has.
+
+    Rows narrower than the 128 lanes of a VMEM tile lie in 128 each:
+    they are counted so, and ask from where their two buffers take
+    half of Mosaic's default (an 8k sequence at width 64: 4 MB by its
+    numbers and 8 MB where it lies; inside LFM2's train step the
+    bfloat16 dkv call of 32 query heads over 8 K/V heads was refused
+    at 16.07 of 16 MB, its float32 twin alone at 18).  Every narrower
+    call that ran before (width 64 up to 2048 keys) stays at the
+    default, as it was."""
+    dv = d if dv is None else dv
+    if min(d, dv) < 128:
+        rows = resident_row_bytes(t, max(d, 128), itemsize, max(dv, 128))
+        if 2 * rows < SCOPED_VMEM_BYTES // 2:
+            return None
+    elif 2 * resident_row_bytes(t, d, itemsize, dv) < VMEM_BUDGET_BYTES:
         return None
     estimate = vmem_estimate(t, d, block_q, block_k, itemsize, dv)
     return min(2 * estimate + (16 << 20), 100 << 20)

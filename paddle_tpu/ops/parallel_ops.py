@@ -275,7 +275,8 @@ def moe_route_op(ctx, ins, attrs):
     gates), experts_held ((first, count): the router stays E wide, and
     HeldLoad [count] is Load's slice of the held experts, the group
     sizes of a layer that holds only those), score_func ('softmax',
-    the default, or 'sigmoid').
+    the default, or 'sigmoid'), renorm_eps (sigmoid under renormalize:
+    what is added to the chosen scores' sum; default 1e-20).
 
     ScoreBias [E] f32 (sigmoid scores only) is added to the scores for
     the choice of the k experts and for nothing else
@@ -293,7 +294,8 @@ def moe_route_op(ctx, ins, attrs):
         x.reshape(-1, x.shape[-1]), wg, int(attrs['top_k']),
         bool(attrs.get('renormalize', False)),
         float(attrs.get('scale', 1.0)),
-        attrs.get('score_func', 'softmax'), bias)
+        attrs.get('score_func', 'softmax'), bias,
+        float(attrs.get('renorm_eps', 1e-20)))
     outs = {'TopKIdx': [idx], 'TopKWeight': [weight],
             'AuxLoss': [balance], 'ZLoss': [z], 'Load': [load]}
     held = _held(attrs)

@@ -184,7 +184,8 @@ def reference_moe_ffn(x, wg, w1_full, w2_full, capacity_factor=2.0,
 
 
 def route_topk(x, wg, top_k, renormalize=False, scale=1.0,
-               score_func='softmax', choice_bias=None):
+               score_func='softmax', choice_bias=None,
+               renorm_eps=1e-20):
     """The router, in float32 whatever ``x`` is.  x [S, D], wg [D, E] ->
     (idx [S, k] int32, weight [S, k] f32, balance loss, z-loss,
     load [E] int32).
@@ -198,7 +199,8 @@ def route_topk(x, wg, top_k, renormalize=False, scale=1.0,
     own logit.  ``choice_bias`` [E] (sigmoid only) enters the CHOICE
     and nothing else: the k experts are the largest of score + bias,
     and ``weight`` are their plain scores, under ``renormalize``
-    divided by (their sum + 1e-20), times ``scale``: the bias picks and
+    divided by (their sum + ``renorm_eps``: 1e-20 as DeepSeek-V3's
+    code, 1e-6 as LFM2's), times ``scale``: the bias picks and
     never weighs, and takes no gradient (the auxiliary-loss-free
     balancing of DeepSeek-V3, ``topk_method: noaux_tc`` with one
     group; bias_update() moves it).
@@ -227,7 +229,7 @@ def route_topk(x, wg, top_k, renormalize=False, scale=1.0,
         weight = jnp.take_along_axis(scores, idx, axis=-1)
         if renormalize:
             weight = weight / (jnp.sum(weight, axis=-1, keepdims=True)
-                               + 1e-20)
+                               + renorm_eps)
         probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
     if scale != 1.0:
         weight = weight * scale

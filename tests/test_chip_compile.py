@@ -222,6 +222,44 @@ def test_latent_flash_at_the_moonlight_cell_shape(one_chip, as_on_tpu,
     assert all(n.startswith('qk192v128') for n in names), names
 
 
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+def test_grouped_causal_flash_d64_at_the_lfm2_cell_shape(one_chip, as_on_tpu,
+                                                         dtype):
+    """lfm2_8b_a1b_s8192: b2 t8192, 32 query heads over 8 K/V heads of
+    64, causal: grouped K/V heads had run at width 128 only (Laguna),
+    width 64 only with as many K/V heads as query heads, not causal,
+    at 2048 and under (BERT).  bfloat16 is the timed step, float32
+    ``chip_smoke.py --phase lfm2`` and the cell's reference check.
+    Every call is named after the op's own scope: no window, one
+    width."""
+    import re
+    b, t, h, hkv, d = 2, 8192, 32, 8, 64
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            with jax.named_scope('fused_multihead_attention'):
+                o = flash_attention.flash_attention(q, k, v, causal=True)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    text = _compiled(step, one_chip, _spec((b, t, h, d), dtype),
+                     _spec((b, t, hkv, d), dtype),
+                     _spec((b, t, hkv, d), dtype)).as_text()
+    _compiled_on_chip('flash_attention')
+    names = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert len(names) == 3 and all(
+        'fused_multihead_attention' in n for n in names), names
+    # rows 64 wide lie in 128 lanes: two buffers of an 8k sequence's
+    # take half of Mosaic's default and the calls ask for more (inside
+    # the cell's train step the bfloat16 dkv call was refused at 16.07
+    # of 16 MB without); BERT's, at 2048 keys and under, do not
+    item = jnp.dtype(dtype).itemsize
+    assert common.scoped_vmem(t, d, 512, 512, item) > \
+        common.SCOPED_VMEM_BYTES
+    assert common.scoped_vmem(2048, d, 512, 1024, item) is None
+
+
 @pytest.mark.parametrize('dtype,b,t,h,d,fused', [
     # what the compiler asks for moves with the grid, so the cells'
     # own: bert_base_s2048's fused backward, two [512, 512] tiles a trip
