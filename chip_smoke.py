@@ -164,13 +164,15 @@ def train_steps(exe, target, feed, loss, steps):
     return losses, secs, compiled
 
 
-def check_trained(tag, losses, secs, compiled, warm=1):
+def check_trained(tag, losses, secs, compiled, warm=1, falls=True):
     say('%s losses %s' % (tag, ' '.join('%.4f' % v for v in losses)))
     say('%s first step (compile + run) %.1f s; later steps ms: %s'
         % (tag, secs[0], ' '.join('%.1f' % (s * 1e3) for s in secs[1:])))
     check(all(np.isfinite(losses)), '%s: losses finite' % tag)
-    check(losses[-1] < losses[0],
-          '%s: loss falls (%.4f -> %.4f)' % (tag, losses[0], losses[-1]))
+    if falls:
+        check(losses[-1] < losses[0],
+              '%s: loss falls (%.4f -> %.4f)'
+              % (tag, losses[0], losses[-1]))
     check(not any(n for c in compiled[warm:] for n in c),
           '%s: no compile after warm-up; per step (segment_cache_miss, '
           'jax backend compiles) = %s' % (tag, compiled))
@@ -306,16 +308,36 @@ def _peak_bytes(devs):
     return [d.memory_stats()['peak_bytes_in_use'] for d in devs]
 
 
-def phase_four_chips(cfg, global_batch, seq, steps, n=4):
+_COUNTED_EXECUTABLES = set()
+
+
+def _mosaic_calls_a_step():
+    """The most Mosaic calls in one executable this process has come
+    to hold since the last time this was asked (the train step's;
+    every chip runs the same program, and none of the calls sits in a
+    loop): which arm of the dispatch ran, read from the optimised HLO
+    without a trace."""
+    from paddle_tpu.fluid import compile_cache
+    new = compile_cache.plane().held_hlo(skip=_COUNTED_EXECUTABLES)
+    _COUNTED_EXECUTABLES.update(key for key, _ in new)
+    return max([text.count('custom_call_target="tpu_custom_call"')
+                for _, text in new] or [0])
+
+
+def phase_four_chips(cfg, global_batch, seq, steps, n=4, mp=2, falls=True):
     """The data-parallel and the sharded path, in this one process over
     `n` devices, against the single-device run of the same seeded
-    program: (a) with_data_parallel on a dp mesh, (b) dp x mp=2 with
-    __graft_entry__'s column-parallel rule.  First-step losses agree
-    and losses fall; parameters and the batch really lie on every
-    device.  The step's kernel dispatches fused in the
-    single-device run and answers dense under either mesh, every time
-    for the counted reason `auto_partitioned` (XLA cannot partition a
-    Mosaic kernel; ops/pallas/common.py dispatch())."""
+    program: (a) with_data_parallel on a dp mesh, (b) with ``mp``,
+    dp x mp with __graft_entry__'s column-parallel rule.  First-step
+    losses agree (attention dropout included, where cfg has it: every
+    shard hashes its global batch x head index) and losses fall;
+    parameters and the batch really lie on every device.  The step's
+    kernel dispatches fused in the single-device run, and under either
+    mesh inside a shard_map over the batch axis, on each chip's share
+    of the batch (`dispatch_sharded`; ops/pallas/flash_attention.py
+    mesh_flash_attention): nothing answers dense for the mesh's sake.
+    Every run prints its dispatch counters and the Mosaic calls a
+    step.  ``falls``: whether `steps` steps have to lower the loss."""
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     import paddle_tpu.fluid as fluid
@@ -329,10 +351,13 @@ def phase_four_chips(cfg, global_batch, seq, steps, n=4):
             return P(None, 'mp')
         return None
 
+    counters = ('dispatch_fused', 'dispatch_sharded', 'dispatch_dense',
+                'fallback/auto_partitioned', 'fallback/batch_not_split')
+
     def kernel_counts():
         return np.array([[int(monitor.counter_value(
-            'pallas/%s/%s' % (k, c))) for k in KERNELS] for c in
-            ('dispatch_fused', 'fallback/auto_partitioned')])
+            'pallas/%s/%s' % (k, c)) or 0) for k in KERNELS]
+            for c in counters])
 
     def run(tag, mesh, rule=None):
         main, startup, _, loss = build_bert(cfg, seq)
@@ -359,18 +384,25 @@ def phase_four_chips(cfg, global_batch, seq, steps, n=4):
             exe.run(startup)
             losses, secs, compiled_at = train_steps(exe, target, feed,
                                                     loss, steps)
-            check_trained(tag, losses, secs, compiled_at)
-            fused, dense = kernel_counts() - before
-            counted = 'dispatch_fused %s, fallback/auto_partitioned %s ' \
-                'for %s' % (fused, dense, ' / '.join(KERNELS))
+            check_trained(tag, losses, secs, compiled_at, falls=falls)
+            fused, sharded, dense, unwrapped, unsplit = \
+                kernel_counts() - before
+            counted = '%s for %s; %d Mosaic calls a step' % (
+                ', '.join('%s %s' % (c, v) for c, v in zip(
+                    counters, (fused, sharded, dense, unwrapped,
+                               unsplit))),
+                ' / '.join(KERNELS), _mosaic_calls_a_step())
             if mesh is None:
-                check((fused > 0).all() and not dense.any(),
+                check((fused > 0).all() and not dense.any() and
+                      not sharded.any(),
                       '%s: every kernel dispatched fused: %s'
                       % (tag, counted))
             else:
-                check(not fused.any() and (dense > 0).all(),
-                      '%s: every kernel answered dense, counted as '
-                      'auto_partitioned: %s' % (tag, counted))
+                check((fused > 0).all() and (sharded > 0).all() and
+                      not dense.any(),
+                      '%s: every kernel dispatched fused inside a '
+                      'shard_map over the batch axis: %s'
+                      % (tag, counted))
             if mesh is not None:
                 params = {
                     p.name: fluid.core.as_array(scope.find_var(p.name))
@@ -392,12 +424,13 @@ def phase_four_chips(cfg, global_batch, seq, steps, n=4):
     single = run('single device b%d s%d' % (global_batch, seq), None)
     check_dispatch(KERNELS)     # while the last decisions are its own
     base = _peak_bytes(devs)
-    mp = 2
-    for tag, mesh, rule in (
-            ('dp%d' % n, Mesh(np.array(devs), ('dp',)), None),
-            ('dp%dxmp%d' % (n // mp, mp),
-             Mesh(np.array(devs).reshape(n // mp, mp), ('dp', 'mp')),
-             column_parallel)):
+    meshes = [('dp%d' % n, Mesh(np.array(devs), ('dp',)), None)]
+    if mp:
+        meshes.append((
+            'dp%dxmp%d' % (n // mp, mp),
+            Mesh(np.array(devs).reshape(n // mp, mp), ('dp', 'mp')),
+            column_parallel))
+    for tag, mesh, rule in meshes:
         losses = run(tag, mesh, rule)
         check(abs(losses[0] - single[0]) <=
               BF16_LOSS_RTOL * abs(single[0]),
@@ -1511,6 +1544,16 @@ def main():
             phase_four_chips(
                 models.bert.BertConfig(dropout=0.0, attn_dropout=0.0),
                 global_batch=16, seq=512, steps=4)
+            # bert_base_s2048_dp4's own shape and mask: 2 sequences of
+            # 2048 a chip, attention dropout on.  Three steps from this
+            # start need not lower the loss (one device read 2.7316
+            # 15.0020 5.5147: my chip run, PR 37; the cell's falls over
+            # its window): the phase is here for the dispatch
+            # counters, the Mosaic calls and the first step's loss
+            phase_four_chips(
+                models.bert.BertConfig(max_pos=2048, dropout=0.0,
+                                       attn_dropout=0.1),
+                global_batch=8, seq=2048, steps=3, mp=0, falls=False)
         else:
             seq, batch = 2048, 4
             phase_train_eval_roundtrip(

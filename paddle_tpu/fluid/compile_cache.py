@@ -723,10 +723,11 @@ class CompilePlane(object):
                 'table can be built for it' % (key,))
         return text
 
-    def held_hlo(self):
+    def held_hlo(self, skip=()):
         """[(key, optimised HLO text)] of every executable this process
-        holds: the AOT executables of the map and the lazily jitted
-        callables the runners noted.  On demand only (fluid.profiler's
+        holds (but those whose key is in ``skip``: printing a module
+        costs seconds): the AOT executables of the map and the lazily
+        jitted callables the runners noted.  On demand only (fluid.profiler's
         scope table), never on the step path: it prints whole modules,
         and where jit's caches were dropped a lazily jitted callable
         lowers and compiles again.  A program that cannot be lowered
@@ -734,7 +735,8 @@ class CompilePlane(object):
         that silently lacked a program would move all of its device
         time to 'unattributed'."""
         return [(key, self._hlo_text(key, executable()))
-                for key, _program, executable in self._held()]
+                for key, _program, executable in self._held()
+                if key not in skip]
 
     def held_tables(self, build):
         """[(key, build(optimised HLO text))] of the same executables,

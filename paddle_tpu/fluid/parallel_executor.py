@@ -478,13 +478,14 @@ def _run_segment_parallel(executor, seg, feed, scope, fetched, mesh,
     if compiled is None:
         fn0 = _make_segment_fn(seg)
 
-        # publish the mesh for the duration of TRACING so mesh-aware op
-        # lowerings (ring_attention / moe_ffn, ops/parallel_ops.py) can
-        # open shard_maps over its named axes; the context manager runs
+        # publish the mesh and the batch axes for the duration of
+        # TRACING so mesh-aware op lowerings (ring_attention / moe_ffn,
+        # ops/parallel_ops.py; the flash kernels' wrap) can open
+        # shard_maps over its named axes; the context manager runs
         # inside the traced python body, i.e. exactly at trace time
         def fn(step, state, data, _fn0=fn0, _mesh=mesh):
             from ..parallel import mesh as pmesh
-            with pmesh.use_trace_mesh(_mesh):
+            with pmesh.use_trace_mesh(_mesh, batch_axes):
                 return _fn0(step, state, data)
         fn.__name__ = fn0.__name__
         in_shardings = (None,

@@ -20,9 +20,14 @@ def fused_multihead_attention(ctx, ins, attrs):
     """Q: [B, T, H, D], K: [B, T, Hkv, D], V: [B, T, Hkv, Dv] (+
     optional KeyBias [B, T] additive score bias, e.g. a padding mask)
     -> Out [B, T, H, Dv] via
-    flash_attention(): the Pallas kernels forward and backward on a
-    TPU, the dense chain elsewhere and under the GSPMD runner's mesh
-    (ops/pallas/common.py dispatch()).
+    mesh_flash_attention(): the Pallas kernels forward and backward on
+    a TPU, the dense chain elsewhere (ops/pallas/common.py
+    dispatch()).  Under the GSPMD runner's mesh (with_data_parallel /
+    with_mesh) the kernels run inside a shard_map on each device's
+    share of the batch, split over the axes the runner split the
+    batch over; heads are not split, a mesh's further axes see the
+    call replicated, and a batch those axes do not divide answers
+    dense (``fallback/batch_not_split``).
 
     Two attributes of the shape, read by every arm alike: K/V of
     fewer heads than Q (Hkv divides H: query head i attends K/V head
@@ -45,7 +50,7 @@ def fused_multihead_attention(ctx, ins, attrs):
     mask keyed on (op seed, step) so per-op replay and whole-program
     vjp regenerate it; skipped in test-mode lowering like the dropout
     op."""
-    from .pallas.flash_attention import flash_attention
+    from .pallas.flash_attention import mesh_flash_attention
     q = ins['Q'][0]
     k = ins['K'][0]
     v = ins['V'][0]
@@ -61,10 +66,11 @@ def fused_multihead_attention(ctx, ins, attrs):
     with contextlib.ExitStack() as stack:
         for name in scopes:
             stack.enter_context(jax.named_scope(name))
-        return {'Out': [flash_attention(
-            q, k, v, causal=attrs.get('causal', False), key_bias=bias,
-            dropout_rate=rate, dropout_seed=seed,
-            auto_partitioned=ctx.auto_partitioned, window=window)]}
+        return {'Out': [mesh_flash_attention(
+            q, k, v, ctx.auto_partitioned,
+            scopes[-1] if scopes else 'fused_multihead_attention',
+            causal=attrs.get('causal', False), key_bias=bias,
+            dropout_rate=rate, dropout_seed=seed, window=window)]}
 
 
 @register('fused_elemwise_activation')

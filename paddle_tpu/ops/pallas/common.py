@@ -45,7 +45,7 @@ _LAST = {}
 
 _FALLBACK_REASONS = ('flag_off', 'off_tpu', 'below_floor',
                      'vmem_over_budget', 'dtype', 'layout',
-                     'auto_partitioned')
+                     'auto_partitioned', 'batch_not_split')
 
 
 def register_kernel(name, dense_fallback, has_vjp=False, doc='',
@@ -219,6 +219,27 @@ def record_dispatch(kernel, fused, reason, interpret=False):
                      'reason': reason, 'interpret': bool(interpret)}
 
 
+def decide(enabled, checks=(), force=None, auto_partitioned=False):
+    """dispatch()'s decision, recording nothing: ``(use_fused, reason,
+    interpret)``.  A caller that can wrap its kernel in a shard_map
+    asks it, without ``auto_partitioned``, whether every other gate
+    passes before it opens one."""
+    if not enabled:
+        return False, 'flag_off', False
+    for reason, ok in checks:
+        if reason not in _FALLBACK_REASONS:
+            raise ValueError('unknown fallback reason %r' % (reason,))
+        if not ok:
+            return False, reason, False
+    if auto_partitioned:
+        return False, 'auto_partitioned', False
+    if on_tpu():
+        return True, 'tpu', False
+    if force if force is not None else force_fused():
+        return True, 'forced_interpret', True
+    return False, 'off_tpu', False
+
+
 def dispatch(kernel, enabled, checks=(), force=None,
              auto_partitioned=False):
     """The auto-dispatch gate.  ``checks`` is a sequence of
@@ -235,34 +256,22 @@ def dispatch(kernel, enabled, checks=(), force=None,
     dense even under force, so forced parity runs still exercise the
     real gates.
 
-    ``auto_partitioned`` is the caller's word (an op lowering passes
-    ``ctx.auto_partitioned``) that this trace is ONE program XLA will
-    partition over a multi-device mesh — the GSPMD runner of
-    with_data_parallel / with_mesh.  XLA cannot partition a Mosaic
+    ``auto_partitioned`` is the caller's word that this call sits, as
+    it stands, in ONE program XLA will partition over a multi-device
+    mesh — the GSPMD runner of with_data_parallel / with_mesh — and
+    that the caller wraps nothing.  XLA cannot partition a Mosaic
     kernel ("Mosaic kernels cannot be automatically partitioned.
     Please wrap the call in a shard_map"), so the dense lowering —
     which it can — is the only one that compiles there.  Code inside a
-    shard_map is per-device and passes nothing."""
-    if not enabled:
-        record_dispatch(kernel, False, 'flag_off')
-        return False, False
-    for reason, ok in checks:
-        if reason not in _FALLBACK_REASONS:
-            raise ValueError('unknown fallback reason %r' % (reason,))
-        if not ok:
-            record_dispatch(kernel, False, reason)
-            return False, False
-    if auto_partitioned:
-        record_dispatch(kernel, False, 'auto_partitioned')
-        return False, False
-    if on_tpu():
-        record_dispatch(kernel, True, 'tpu')
-        return True, False
-    if force if force is not None else force_fused():
-        record_dispatch(kernel, True, 'forced_interpret', interpret=True)
-        return True, True
-    record_dispatch(kernel, False, 'off_tpu')
-    return False, False
+    shard_map is per-device and passes nothing: the flash op's
+    lowerings take that road (flash_attention.mesh_flash_attention
+    splits the batch over the runner's batch axes and calls the
+    kernels on each device's share; where it cannot, it answers dense
+    under 'batch_not_split')."""
+    fused, reason, interpret = decide(enabled, checks, force,
+                                      auto_partitioned)
+    record_dispatch(kernel, fused, reason, interpret)
+    return fused, interpret
 
 
 def report():
@@ -285,6 +294,11 @@ def report():
         ent = {'dense_fallback': info['dense_fallback'],
                'has_vjp': info['has_vjp'],
                'dispatch_fused': fused, 'dispatch_dense': dense}
+        sharded = counter('pallas/%s/dispatch_sharded' % name) or 0
+        if sharded:
+            # of the fused ones: lowered inside a shard_map over the
+            # GSPMD runner's batch axes
+            ent['dispatch_sharded'] = sharded
         if info.get('op_types'):
             ent['op_types'] = list(info['op_types'])
         if last:

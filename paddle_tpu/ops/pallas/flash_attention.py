@@ -1153,6 +1153,12 @@ def _check_mask_and_heads(q, k, v, causal, window):
     return int(window or 0)
 
 
+def _checks(t, min_seq=None):
+    """flash_attention()'s own gates for common.dispatch()."""
+    return (('below_floor',
+             t >= (FLASH_MIN_SEQ if min_seq is None else min_seq)),)
+
+
 def flash_attention(q, k, v, causal=False, key_bias=None,
                     min_seq=None, dropout_rate=0.0, dropout_seed=None,
                     dropout_offsets=None, dropout_g_offset=0,
@@ -1180,18 +1186,18 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     `min_seq` (default FLASH_MIN_SEQ, the measured crossover) run the
     dense XLA chain, and so does every call off a TPU unless
     FLAGS_pallas_force asks for the interpreter (tests), and every
-    call an op lowering marks ``auto_partitioned`` (the GSPMD runner's
-    trace; see common.dispatch()).  Pass min_seq=0 to drop the floor
-    (benchmark sweeps)."""
+    call that says ``auto_partitioned`` (the GSPMD runner's trace
+    with no shard_map around the call; see common.dispatch().  An op
+    lowering under that runner calls mesh_flash_attention(), which
+    opens one).  Pass min_seq=0 to drop the floor (benchmark
+    sweeps)."""
     b, t, h, d = q.shape
     window = _check_mask_and_heads(q, k, v, causal, window)
-    if min_seq is None:
-        min_seq = FLASH_MIN_SEQ
     rate = float(dropout_rate or 0.0)
     if rate and dropout_seed is None:
         raise ValueError('dropout_rate > 0 needs a dropout_seed')
     fused, interpret = _common.dispatch(
-        'flash_attention', True, checks=(('below_floor', t >= min_seq),),
+        'flash_attention', True, checks=_checks(t, min_seq),
         auto_partitioned=auto_partitioned)
     if not fused:
         return _dense_path(q, k, v, causal, key_bias, rate,
@@ -1208,6 +1214,90 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     out = _flash(to_bh(q), to_bh(k), to_bh(v), key_bias, seed, h,
                  causal, rate, interpret, window)
     return jnp.transpose(out.reshape(b, h, t, v.shape[3]), (0, 2, 1, 3))
+
+
+def mesh_flash_attention(q, k, v, auto_partitioned, scope, causal=False,
+                         key_bias=None, dropout_rate=0.0,
+                         dropout_seed=None, window=0):
+    """flash_attention() as an op lowering calls it, with
+    ``ctx.auto_partitioned``: the one place a flash call is wrapped
+    for the GSPMD runner (fused_multihead_attention, ring_attention's
+    one-device-a-sequence flash arm).
+
+    Outside that runner's trace (one device, or code already inside a
+    shard_map) it IS flash_attention().  Under it, where the kernels
+    would be chosen but for the mesh (every other gate of
+    common.dispatch() passes), the call is made inside a shard_map
+    over the trace mesh: q, k, v, the key bias and the output split
+    along dimension 0 over the axes the runner split the batch over
+    (parallel.mesh.trace_batch_axes()), nothing else split, the seed
+    replicated.  Each device then runs the unchanged kernels on its
+    share of the batch, with ``dropout_g_offset`` its first global
+    (batch x head) index, so every shard draws the mask a one-device
+    run draws; the gradient is shard_map's transpose of the same
+    call.  Heads are never split: the hash's head index is b * H + h,
+    and a split of h is no single offset.  Over the mesh's further
+    axes (a model axis) the call is replicated, and GSPMD gathers an
+    operand that was split over one.
+
+    ``scope`` is the innermost named scope the caller lowers this
+    call in (the fluid op's type, or the scope of its own the
+    lowering opened under it).  The compiler names a Mosaic call after
+    the innermost scope around it, a device trace is read by those
+    names, and shard_map opens a scope of its own: the body enters
+    ``scope`` again, so a wrapped call is named as a bare one is.  In
+    the program's scope table the wrapped calls of a scopeless
+    lowering read ``<op type>/shard_map``.
+
+    Where the runner split the batch over no axis of more than one
+    device (a tp-only plan), or this call's batch does not divide by
+    their product, there is no shard to hand the kernels: the dense
+    chain answers, counted as ``fallback/batch_not_split``.  Calls
+    lowered inside the wrap count in ``dispatch_sharded`` (and, by
+    flash_attention() inside, in ``dispatch_fused``)."""
+    if not auto_partitioned or \
+            not _common.decide(True, _checks(q.shape[1]))[0]:
+        return flash_attention(
+            q, k, v, causal=causal, key_bias=key_bias,
+            dropout_rate=dropout_rate, dropout_seed=dropout_seed,
+            auto_partitioned=auto_partitioned, window=window)
+    from jax.sharding import PartitionSpec as P
+    from ...compat import shard_map
+    from ...fluid import monitor, trace
+    from ...parallel import mesh as pmesh
+    mesh = pmesh.trace_mesh()
+    axes = tuple(a for a in pmesh.trace_batch_axes()
+                 if mesh.shape[a] > 1)
+    shards = math.prod(mesh.shape[a] for a in axes)
+    if not axes or q.shape[0] % shards:
+        window = _check_mask_and_heads(q, k, v, causal, window)
+        _common.record_dispatch('flash_attention', False,
+                                'batch_not_split')
+        return _dense_path(q, k, v, causal, key_bias,
+                           float(dropout_rate or 0.0), dropout_seed,
+                           window=window)
+    split = P(axes if len(axes) > 1 else axes[0])
+    operands = [(x, spec) for x, spec in (
+        (q, split), (k, split), (v, split), (key_bias, split),
+        (dropout_seed, P())) if x is not None]
+
+    def local(q_, k_, v_, *rest):
+        rest = list(rest)
+        seed_ = rest.pop() if dropout_seed is not None else None
+        first = jax.lax.axis_index(axes) * q_.shape[0] * q_.shape[2]
+        with jax.named_scope(scope):
+            return flash_attention(
+                q_, k_, v_, causal=causal,
+                key_bias=rest.pop() if rest else None,
+                dropout_rate=dropout_rate, dropout_seed=seed_,
+                dropout_g_offset=first, window=window)
+
+    with trace.span('pallas/flash_attention/shard_map',
+                    axes=','.join(axes), shards=shards):
+        monitor.add('pallas/flash_attention/dispatch_sharded', 1)
+        return shard_map(
+            local, mesh=mesh, in_specs=tuple(spec for _, spec in operands),
+            out_specs=split)(*(x for x, _ in operands))
 
 
 def flash_attention_with_lse(q, k, v, causal=False, key_bias=None,

@@ -142,10 +142,10 @@ def ring_attention_op(ctx, ins, attrs):
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
         return {'Out': [f(q, k, v)]}
     if use_flash:
-        from .pallas.flash_attention import flash_attention
-        return {'Out': [flash_attention(
-            q, k, v, causal=causal, dropout_rate=rate, dropout_seed=seed,
-            auto_partitioned=ctx.auto_partitioned)]}
+        from .pallas.flash_attention import mesh_flash_attention
+        return {'Out': [mesh_flash_attention(
+            q, k, v, ctx.auto_partitioned, 'ring_attention', causal=causal,
+            dropout_rate=rate, dropout_seed=seed)]}
     if rate:
         # dense fallback with the SAME global-position hash mask the
         # ring draws (flash _dense_path implements it)

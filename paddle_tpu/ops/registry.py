@@ -40,8 +40,9 @@ class LowerCtx(object):
         # its mesh while a segment traces: the op is then lowered ONCE
         # for all devices and XLA partitions it, which it cannot do to
         # a Mosaic kernel.  A lowering hands this to the kernel's
-        # dispatch(); code it wraps in a shard_map is per-device and
-        # passes nothing.
+        # dispatch(), which then answers dense, or wraps the call in a
+        # shard_map (the flash op: mesh_flash_attention); code inside
+        # one is per-device and passes nothing.
         mesh = pmesh.trace_mesh()
         self.auto_partitioned = mesh is not None and mesh.devices.size > 1
 

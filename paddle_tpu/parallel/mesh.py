@@ -43,10 +43,12 @@ def create_mesh(dp=None, mp=1, pp=1, sp=1, ep=1, fsdp=1, devices=None):
 
 # --- trace-time mesh context ------------------------------------------
 # The executor's GSPMD path (parallel_executor._run_segment_parallel)
-# publishes the active mesh here while a segment traces, so MESH-AWARE
-# op lowerings (ring_attention, moe_ffn in ops/parallel_ops.py) can
-# open a shard_map over named axes.  Thread-local: parallel test
-# runners trace independent programs concurrently.
+# publishes the active mesh here while a segment traces, and beside it
+# the axes it split the batch over, so MESH-AWARE op lowerings
+# (ring_attention, moe_ffn in ops/parallel_ops.py; the flash kernels'
+# wrap, ops/pallas/flash_attention.py) can open a shard_map over named
+# axes.  Thread-local: parallel test runners trace independent
+# programs concurrently.
 
 import contextlib
 import threading
@@ -55,19 +57,29 @@ _TRACE = threading.local()
 
 
 @contextlib.contextmanager
-def use_trace_mesh(mesh):
-    prev = getattr(_TRACE, 'mesh', None)
-    _TRACE.mesh = mesh
+def use_trace_mesh(mesh, batch_axes=()):
+    """``batch_axes``: the mesh axes the runner sharded dimension 0 of
+    the batch feeds over, in the order of its PartitionSpec; () where
+    it replicated the batch (a tp-only plan) or the publisher is not a
+    runner that shards one."""
+    prev = trace_mesh(), trace_batch_axes()
+    _TRACE.mesh, _TRACE.batch_axes = mesh, tuple(batch_axes)
     try:
         yield mesh
     finally:
-        _TRACE.mesh = prev
+        _TRACE.mesh, _TRACE.batch_axes = prev
 
 
 def trace_mesh():
     """The mesh the current segment is being traced under, or None
     (single-device executor path / inside an outer shard_map)."""
     return getattr(_TRACE, 'mesh', None)
+
+
+def trace_batch_axes():
+    """The axes of trace_mesh() the batch is split over: what
+    use_trace_mesh() was handed."""
+    return getattr(_TRACE, 'batch_axes', ())
 
 
 def axis_size(mesh, name):

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from paddle_tpu.fluid import monitor
 from paddle_tpu.ops import registry
 from paddle_tpu.ops.pallas import (common, flash_attention,
                                    quant_collective)
@@ -29,7 +30,7 @@ VOCAB, MAX_POS, HIDDEN, FFN, LAYERS = 30522, 512, 768, 3072, 12
 
 
 @pytest.fixture(scope='module')
-def one_chip():
+def topo():
     os.environ.setdefault('TPU_LOG_DIR', 'disabled')
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -44,9 +45,22 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update('jax_enable_compilation_cache', False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update('jax_enable_compilation_cache', was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope='module')
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope='module')
+def dp_mesh(topo):
+    """The described host's four chips as the benchmark's dp layout
+    takes them (benchmark/layouts/dp.py)."""
+    from jax.sharding import Mesh
+    return Mesh(np.array(topo.devices), ('dp',))
 
 
 @pytest.fixture
@@ -334,6 +348,66 @@ def test_flash_kernels_are_named_after_the_scope_they_are_lowered_in(
     assert sorted(re.sub(r'\.\d+$', '', n) for n in names) == [
         'jvp_fused_multihead_attention_',
         'transpose_jvp_fused_multihead_attention__'], names
+
+
+@pytest.mark.parametrize('shape,attrs,bias,named', [
+    # (b, t, h, hkv, d, dv): bert_base_s2048_dp4's own calls, 2 a chip
+    ((8, 2048, 12, 12, 64, 64), {'dropout_rate': 0.1}, True,
+     'fused_multihead_attention'),
+    ((4, 4096, 72, 8, 128, 128), {'causal': True, 'window': 512}, False,
+     'window512'),
+    ((4, 8192, 16, 16, 192, 128), {'causal': True}, False, 'qk192v128'),
+], ids=['bert_s2048_dp4', 'laguna_window', 'moonlight_latent'])
+def test_the_wrapped_flash_op_over_a_dp_mesh_of_four(dp_mesh, as_on_tpu,
+                                                     shape, attrs, bias,
+                                                     named):
+    """fused_multihead_attention as the GSPMD runner traces it over the
+    four described chips (the mesh and its batch axis published), the
+    forward op and then the grad op, which replays the forward inside a
+    shard_map of its own: the partitioner accepts the Mosaic calls (it
+    refuses a bare one), the replay is merged with the forward op's
+    call (one forward call, not two), no [b, h, t, t] tensor is left,
+    nothing crosses chips, and the calls keep the name of the scope the
+    op is lowered in, which the trace's readers look for."""
+    import re
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.parallel import mesh as pmesh
+    b, t, h, hkv, d, dv = shape
+    dtype = jnp.bfloat16
+
+    def op(*xs):
+        ins = dict(zip(('Q', 'K', 'V', 'KeyBias'), ([x] for x in xs)))
+        with pmesh.use_trace_mesh(dp_mesh, ('dp',)):
+            ctx = registry.LowerCtx(jnp.uint32(0), 3)
+            with jax.named_scope('fused_multihead_attention'):
+                return registry.get('fused_multihead_attention').fn(
+                    ctx, ins, attrs)['Out'][0]
+
+    def step(*xs):
+        out = op(*xs)
+        _, vjp = jax.vjp(op, *xs)
+        return out, vjp((out * 2).astype(out.dtype))
+
+    split = NamedSharding(dp_mesh, P('dp'))
+    specs = [jax.ShapeDtypeStruct((b, t, n, w), dtype, sharding=split)
+             for n, w in ((h, d), (hkv, d), (hkv, dv))]
+    if bias:
+        specs.append(jax.ShapeDtypeStruct((b, t), jnp.float32,
+                                          sharding=split))
+    before = monitor.counter_value(
+        'pallas/flash_attention/dispatch_sharded') or 0
+    text = jax.jit(step).lower(*specs).compile().as_text()
+    _compiled_on_chip('flash_attention')
+    assert monitor.counter_value(
+        'pallas/flash_attention/dispatch_sharded') == before + 2
+    names = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    fused = attrs.get('window') is None and d == 64
+    assert len(names) == (2 if fused else 3), names   # fwd + bwd (dq, dkv)
+    assert {re.sub(r'\.\d+$', '', n) for n in names} == {named}, names
+    assert not re.search(r'\[\d+,%d,%d,%d\]' % (h, t, t), text)
+    assert not re.search(r'all-reduce|all-gather|all-to-all|'
+                         r'collective-permute', text)
 
 
 def test_grouped_expert_matmuls_at_the_olmoe_cell_shape(one_chip):

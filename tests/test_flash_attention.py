@@ -712,30 +712,42 @@ def test_bert_trains_with_attn_dropout_on_flash_path():
     np.testing.assert_allclose(wpg, per_op, rtol=2e-5)
 
 
-@pytest.mark.parametrize('axes', [{'dp': 4}, {'dp': 2, 'mp': 2}])
-def test_kernels_answer_dense_under_a_gspmd_mesh(axes):
-    """with_data_parallel / with_mesh trace ONE program for GSPMD,
-    which cannot partition a Mosaic kernel: every kernel the step would
-    dispatch on one device (here forced, under the interpreter) answers
-    dense there, counted as `auto_partitioned` — never silently — and
-    the sharded losses are the single-device losses, dropout mask
-    included (the dense chain hashes GLOBAL positions)."""
-    import paddle_tpu.fluid as fluid
-    from jax.sharding import Mesh
-    from paddle_tpu import models
+_COUNTED = ('dispatch_fused', 'dispatch_sharded',
+            'fallback/auto_partitioned', 'fallback/batch_not_split')
+
+
+def _flash_counts():
     from paddle_tpu.fluid import monitor
+    return np.array([monitor.counter_value('pallas/flash_attention/' + c)
+                     or 0 for c in _COUNTED])
+
+
+def _mesh(axes):
+    from jax.sharding import Mesh
+    n = int(np.prod(list(axes.values())))
+    return Mesh(np.array(jax.devices()[:n]).reshape(*axes.values()),
+                tuple(axes))
+
+
+@pytest.mark.parametrize('axes', [{'dp': 4}, {'dp': 2, 'mp': 2}])
+def test_kernels_run_on_each_shard_under_a_gspmd_mesh(axes):
+    """with_data_parallel / with_mesh trace ONE program for GSPMD,
+    which cannot partition a Mosaic kernel: the flash op opens a
+    shard_map over the axes the runner split the batch over and runs
+    the kernels (here forced, under the interpreter) on each device's
+    share, counted as `dispatch_sharded`, and nothing answers dense
+    for the mesh's sake.  The sharded losses are the single-device
+    losses, dropout mask included (every shard hashes its GLOBAL
+    batch x head index), over a model axis too (the call is replicated
+    over it)."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu import models
     seq = 512      # flash_attention()'s own floor: kernels, not dense
     cfg = models.bert.BertConfig(
         vocab_size=512, hidden=128, layers=1, heads=2, intermediate=128,
         max_pos=seq, dropout=0.0, attn_dropout=0.1)
     batch = models.bert.synthetic_batch(cfg, 4, seq,
                                         np.random.RandomState(0))
-    kernels = ('flash_attention',)
-
-    def counts():
-        return np.array([[monitor.counter_value('pallas/%s/%s' % (k, c))
-                          for k in kernels] for c in
-                         ('dispatch_fused', 'fallback/auto_partitioned')])
 
     def run(mesh):
         main, startup = fluid.Program(), fluid.Program()
@@ -745,21 +757,163 @@ def test_kernels_answer_dense_under_a_gspmd_mesh(axes):
             fluid.optimizer.Adam(1e-3).minimize(loss)
         target = main if mesh is None else fluid.CompiledProgram(
             main).with_data_parallel(loss_name=loss.name).with_mesh(mesh)
-        before = counts()   # building infers shapes through dispatch()
+        before = _flash_counts()   # building infers shapes through dispatch()
         with fluid.scope_guard(fluid.Scope()):
             exe = fluid.Executor(fluid.XLAPlace(0))
             exe.run(startup)
             losses = [float(np.asarray(exe.run(
                 target, feed=batch, fetch_list=[loss])[0]).ravel()[0])
                 for _ in range(2)]
-        fused, auto_partitioned = counts() - before
-        return losses, fused, auto_partitioned
+        return losses, dict(zip(_COUNTED, _flash_counts() - before))
 
-    single, fused, auto_partitioned = run(None)
-    assert (fused > 0).all() and not auto_partitioned.any()
-    n = int(np.prod(list(axes.values())))
-    mesh = Mesh(np.array(jax.devices()[:n]).reshape(*axes.values()),
-                tuple(axes))
-    sharded, fused, auto_partitioned = run(mesh)
-    assert not fused.any() and (auto_partitioned > 0).all()
+    single, counted = run(None)
+    assert counted['dispatch_fused'] > 0 and \
+        not counted['dispatch_sharded'] and \
+        not counted['fallback/auto_partitioned'], counted
+    sharded, counted = run(_mesh(axes))
+    assert counted['dispatch_fused'] > 0 and \
+        counted['dispatch_sharded'] > 0 and \
+        not counted['fallback/auto_partitioned'] and \
+        not counted['fallback/batch_not_split'], counted
     np.testing.assert_allclose(sharded, single, rtol=2e-4)
+
+
+def _lowered(mesh, batch_axes, attrs, op_seed=3):
+    """fused_multihead_attention's lowering as the GSPMD runner traces
+    it: under the published mesh and batch axes.  -> (fn(q, k, v[,
+    bias]) -> Out, the op's dropout seed)."""
+    from paddle_tpu.ops import registry
+    from paddle_tpu.parallel import mesh as pmesh
+
+    def fn(q, k, v, bias=None):
+        ins = {'Q': [q], 'K': [k], 'V': [v]}
+        if bias is not None:
+            ins['KeyBias'] = [bias]
+        with pmesh.use_trace_mesh(mesh, batch_axes):
+            ctx = registry.LowerCtx(jnp.uint32(0), op_seed)
+            return registry.get('fused_multihead_attention').fn(
+                ctx, ins, attrs)['Out'][0]
+    return fn, registry.LowerCtx(jnp.uint32(0), op_seed).dropout_seed(attrs)
+
+
+def _operands(b, t, h, hkv, d, dv, bias, seed=0):
+    rng = np.random.RandomState(seed)
+    qkv = [jnp.asarray(rng.randn(b, t, n, w).astype('float32') * 0.5)
+           for n, w in ((h, d), (hkv, d), (hkv, dv))]
+    if bias:
+        qkv.append(jnp.asarray(np.where(
+            rng.rand(b, t) < 0.2, -10000.0, 0.0).astype('float32')))
+    return qkv
+
+
+@pytest.mark.parametrize('batch,axes,batch_axes', [
+    (3, {'dp': 2}, ('dp',)),          # the axes do not divide the batch
+    (2, {'mp': 2}, ()),               # a tp-only plan: no batch axis
+    (2, {'dp': 1, 'mp': 2}, ('dp',)),   # none of more than one device
+], ids=['indivisible', 'no_batch_axis', 'batch_axis_of_one'])
+def test_a_batch_the_mesh_does_not_split_answers_dense_and_is_counted(
+        batch, axes, batch_axes):
+    """No shard to hand the kernels: the dense chain answers under a
+    reason of its own, not under `auto_partitioned` (a caller that
+    wraps nothing) and not in silence."""
+    from paddle_tpu.ops.pallas import common
+    from paddle_tpu.ops.pallas.flash_attention import _dense_path
+    attrs = {'dropout_rate': 0.1}
+    fn, seed = _lowered(_mesh(axes), batch_axes, attrs)
+    qkv = _operands(batch, 512, 2, 2, 8, 8, bias=True)
+    before = _flash_counts()
+    out = jax.jit(fn)(*qkv)
+    counted = dict(zip(_COUNTED, _flash_counts() - before))
+    assert counted == {'dispatch_fused': 0, 'dispatch_sharded': 0,
+                       'fallback/auto_partitioned': 0,
+                       'fallback/batch_not_split': 1}, counted
+    assert common._LAST['flash_attention'] == {
+        'path': 'dense', 'reason': 'batch_not_split', 'interpret': False}
+    assert common.report()['kernels']['flash_attention'][
+        'fallbacks']['batch_not_split'] >= 1
+    np.testing.assert_allclose(
+        out, _dense_path(*qkv[:3], False, qkv[3], 0.1, seed), atol=1e-6)
+
+
+@pytest.mark.parametrize('shape,attrs,bias,axes', [
+    # (b, t, h, hkv, d, dv)
+    ((4, 512, 2, 2, 64, 64), {'dropout_rate': 0.1}, True, {'dp': 2}),
+    ((2, 512, 4, 2, 16, 16), {'causal': True, 'window': 128,
+                              'dropout_rate': 0.1}, False, {'dp': 2}),
+    ((2, 512, 2, 2, 24, 16), {'causal': True}, False, {'dp': 2}),
+    ((4, 512, 2, 2, 16, 16), {'dropout_rate': 0.1}, True,
+     {'dp': 2, 'fsdp': 2}),
+    ((2, 512, 2, 2, 16, 16), {'dropout_rate': 0.1}, True,
+     {'dp': 2, 'mp': 2}),
+], ids=['key_bias_dropout_d64', 'window_grouped_kv', 'dv_is_not_d',
+        'two_batch_axes', 'replicated_over_mp'])
+def test_wrapped_op_and_its_gradients_match_dense_on_a_dp_mesh(
+        shape, attrs, bias, axes, fused_bwd):
+    """The op under a dp mesh of 2 (the kernels on each device's half
+    of the batch, shard_map's transpose for the gradient) against the
+    dense chain on the whole batch with the same seed: the second
+    shard's mask is the one-device run's (its head index starts at
+    local_batch x heads), through window, grouped K/V and Dv != D, and
+    over two batch axes (an auto plan's dp x fsdp: the shard's index
+    is the row-major one of the batch's PartitionSpec), and beside a
+    model axis the call is replicated over (the gradient is the one
+    call's, not the sum of two)."""
+    from paddle_tpu.ops.pallas import common
+    from paddle_tpu.ops.pallas.flash_attention import _dense_path
+    fn, seed = _lowered(_mesh(axes), tuple(a for a in axes if a != 'mp'),
+                        attrs)
+    operands = _operands(*shape, bias=bias)
+    rate = attrs.get('dropout_rate', 0.0)
+    w = jnp.asarray(np.random.RandomState(1).randn(
+        shape[0], shape[1], shape[2], shape[5]).astype('float32'))
+
+    def dense(q, k, v, key_bias=None):
+        return _dense_path(q, k, v, attrs.get('causal', False), key_bias,
+                           rate, seed if rate else None,
+                           window=attrs.get('window', 0))
+
+    def grads(f):
+        def loss(*xs):
+            out = f(*xs)
+            return jnp.sum(out * w), out
+        return jax.jit(jax.grad(
+            loss, argnums=tuple(range(len(operands))),
+            has_aux=True))(*operands)
+
+    before = _flash_counts()
+    got_grads, got = grads(fn)
+    counted = dict(zip(_COUNTED, _flash_counts() - before))
+    assert counted['dispatch_sharded'] >= 1 and \
+        counted['dispatch_fused'] >= 1 and \
+        not counted['fallback/auto_partitioned'], counted
+    assert common.report()['kernels']['flash_attention'][
+        'dispatch_sharded'] >= 1
+    want_grads, want = grads(dense)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    for g, wg in zip(got_grads, want_grads):
+        np.testing.assert_allclose(g, wg, rtol=2e-4, atol=2e-5)
+
+
+def test_a_one_device_lowering_holds_no_shard_map():
+    """No trace mesh (the one-chip runner), or a mesh of one device:
+    the op traces to flash_attention()'s own jaxpr, kernels and all,
+    with no shard_map in it, so no one-chip program moves."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    attrs = {'dropout_rate': 0.1}
+    qkv = _operands(2, 512, 2, 2, 64, 64, bias=True)
+    plain, _ = _lowered(None, (), attrs)
+    one, _ = _lowered(_mesh({'dp': 1}), ('dp',), attrs)
+
+    def direct(q, k, v, bias):
+        from paddle_tpu.ops import registry
+        return fa.flash_attention(
+            q, k, v, key_bias=bias, dropout_rate=0.1,
+            dropout_seed=registry.LowerCtx(jnp.uint32(0), 3).dropout_seed(
+                attrs))
+
+    want = str(jax.make_jaxpr(direct)(*qkv))
+    assert 'pallas_call' in want and 'shard_map' not in want
+    assert str(jax.make_jaxpr(plain)(*qkv)) == want
+    assert str(jax.make_jaxpr(one)(*qkv)) == want
+    two, _ = _lowered(_mesh({'dp': 2}), ('dp',), attrs)
+    assert 'shard_map' in str(jax.make_jaxpr(two)(*qkv))

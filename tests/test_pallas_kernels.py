@@ -41,10 +41,13 @@ def _qkv(t=8):
 
 
 def test_auto_partitioned_is_the_callers_word_and_beats_force():
-    """dispatch() probes nothing: a lowering hands it
+    """dispatch() probes nothing: a caller hands it
     ctx.auto_partitioned, which LowerCtx takes from the mesh the GSPMD
     runner publishes while it traces (one device: nothing to
-    partition).  Dense then, counted, even under force."""
+    partition).  A bare flash_attention() call that says so wraps
+    nothing: dense then, counted, even under force (the op lowerings
+    go through mesh_flash_attention, which opens a shard_map:
+    test_flash_attention.py)."""
     from jax.sharding import Mesh
     from paddle_tpu.parallel import mesh as pmesh
     assert not registry.LowerCtx(0).auto_partitioned
