@@ -16,6 +16,10 @@ the chip: BERT-base pretraining through the normal entry points
     python chip_smoke.py --phase lfm2    # LFM2-8B-A1B's: short
                                     # convolutions, grouped causal flash
                                     # at d64, the tied table's gradient
+    python chip_smoke.py --phase evabyte # EvaByte's: one EVA attention
+                                    # call at the published 32768
+                                    # positions, then every gradient
+                                    # of one layer at 4096
 
 One process, no children.  It fails (non-zero, no result line) unless
 jax.devices()[0].platform == 'tpu'; nothing here falls back to the CPU.
@@ -1497,15 +1501,363 @@ def phase_lfm2_gradients(seq=8192, seed=0, rows=64):
               % (tag, LFM2_L2_RTOL, far))
 
 
+# EvaByte at published widths (hidden 4096, 32 heads of 128, MLP
+# 11008, 320 rows, 8 heads of prediction, windows of 2048 over chunks
+# of 16).  Tolerances from the chip runs of this phase (my chip runs,
+# PR 38; PERF.md section 6 has the readings), each with room over its
+# reading.  Every product on both sides of the f32 comparisons is full
+# float32 (the flash kernels' too) and nothing is chosen by a top-k,
+# so what is left is the order of float32 sums: two streams merged by
+# their log-sum-exps against one softmax over both kinds of keys.
+# The single op at 32768: float32 9.9e-6 to 3.4e-5 of a tensor's
+# largest entry (limit 1e-4), bfloat16 2.2e-3 to 3.1e-3 (limit 3e-2).
+# One layer's gradients at 4096, relative L2: 2.3e-5 to 1.3e-4 for
+# every matrix and gain, 2.0e-4 for phi and 5.8e-4 for mu, whose
+# gradients are sums that all but cancel (a row's dS sums to zero over
+# ALL its keys; mu's gradient is the part over the summaries alone, its
+# largest entry 3.3e-5 where Wq's is 4.1e-4): limit 2e-3.  The loss:
+# 7.8e-8 (train program) and 0 to 2.3e-7 over six batches at the
+# cell's four layers, the reference in bfloat16 throughout 4.8e-6 to
+# 1.9e-5 on the same batches: the limit 1e-6 refuses every one.
+EVA_T = 32768               # the published max_position_embeddings
+EVA_LAYER_SEQ = 4096        # the cell's: two windows
+EVA_F32_OUT_TOL = 1e-4      # f32 single op, of the tensor's largest
+EVA_BF16_OUT_TOL = 3e-2     # bf16 single op, of the tensor's largest
+EVA_LOSS_RTOL = 1e-6        # = benchmark/families/evabyte.py's
+EVA_L2_RTOL = 2e-3          # a gradient tensor's relative L2 distance
+EVA_CELL_LAYERS = 4
+EVA_LOSS_BATCHES = 6
+EVA_NAMES = ('embedding', 'g1', 'Wq', 'Wk', 'Wv', 'phi', 'mu', 'Wo',
+             'g2', 'Wg', 'Wu', 'Wd', 'g_last') + tuple(
+                 'W_%d' % i for i in range(8))
+
+
+def _timed(fn, *args, runs=3):
+    """Seconds a call of a jitted ``fn`` takes on the chip, the best of
+    ``runs`` after one that compiles."""
+    import jax
+    jax.block_until_ready(fn(*args))
+    best = float('inf')
+    for _ in range(runs):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        best = min(best, time.perf_counter() - t0)
+    return best
+
+
+def _eva_single_op(t, dtype, seed, heads=32, d=128, window=2048,
+                   chunk=16):
+    """ONE ``layers.eva_attention`` forward + backward at ``t``
+    positions through a fluid program (the normal path), against the
+    plain reference in float32 on the same (rounded) inputs: agreement
+    of the output and of the five gradients, the device's peak memory,
+    and each part's time and share of its roofline from the same
+    functions the ops lower to, timed apart."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import layers
+    from paddle_tpu.models import evabyte
+    from paddle_tpu.models.reference import evabyte as reference
+    from paddle_tpu.ops import registry
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from benchmark.lib import evabyte_flops, flops, peaks
+    name = jnp.dtype(dtype).name
+    rng = np.random.RandomState(seed)
+    host = {n: np.asarray(jnp.asarray(
+        rng.randn(1, t, heads, d), dtype).astype(jnp.float32))
+        for n in ('q', 'k', 'v', 'cot')}
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.scope_guard(fluid.Scope()):
+        with fluid.program_guard(main, startup), \
+                fluid.unique_name.guard():
+            ins = {n: layers.data(n, shape=[t, heads, d], dtype='float32')
+                   for n in host}
+            for x in ins.values():
+                x.stop_gradient = False
+            q, k, v = (layers.cast(ins[n], name) for n in ('q', 'k', 'v'))
+            phi, mu = (layers.create_parameter(
+                [heads, d], 'float32',
+                default_initializer=evabyte.ClippedNormal(d ** -0.5))
+                for _ in range(2))
+            out = layers.eva_attention(q, k, v, window, chunk, phi, mu)
+            loss = layers.reduce_sum(layers.elementwise_mul(
+                layers.cast(out, 'float32'), ins['cot']))
+            grads = fluid.backward.gradients(
+                [loss], [ins['q'], ins['k'], ins['v'], phi, mu])
+        fetch = [out] + list(grads)
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        phi_v, mu_v = (np.asarray(fluid.core.as_array(
+            scope.find_var(x.name))) for x in (phi, mu))
+        fused0 = _fused_dispatches()
+        t0 = time.time()
+        got = [np.asarray(x, np.float32)
+               for x in exe.run(main, feed=host, fetch_list=fetch)]
+        say('eva_attention %s at T = %d (%d windows, %d summaries), %d '
+            'heads of %d, forward + backward through fluid: %.1f s with '
+            'compile; %d flash dispatches fused'
+            % (name, t, t // window, t // chunk, heads, d,
+               time.time() - t0, _fused_dispatches() - fused0))
+        check(_fused_dispatches() - fused0 >= 2,
+              'both streams dispatched to the flash kernels')
+        for n in scope.local_var_names():
+            scope.erase(n)
+    stats = jax.devices()[0].memory_stats() or {}
+    peak = stats.get('peak_bytes_in_use', 0) + \
+        stats.get('peak_bytes_reserved', 0)
+    scores = 4 * heads * t * (t // chunk)
+    say('device peak so far %.2f GB (in use %.2f + reserved %.2f); one '
+        '[32, T, T / 16] float32 tensor would be %.2f GB, [32, T, 2048] '
+        '%.2f GB' % (peak / 1e9, stats.get('peak_bytes_in_use', 0) / 1e9,
+                     stats.get('peak_bytes_reserved', 0) / 1e9,
+                     scores / 1e9, 4 * heads * t * window / 1e9))
+
+    # the plain reference, float32 'highest', a block of queries at a
+    # time, on the inputs as the program rounded them
+    dev = {n: jnp.asarray(x) for n, x in host.items()}
+
+    def plain(q, k, v, phi, mu):
+        o = reference.eva_attention(q, k, v, phi, mu, window, chunk,
+                                    block=512, remat=True)
+        return jnp.vdot(o, dev['cot']), o
+
+    with jax.default_matmul_precision('highest'):
+        t0 = time.time()
+        (_, want_o), want_g = jax.jit(jax.value_and_grad(
+            plain, (0, 1, 2, 3, 4), has_aux=True))(
+            dev['q'], dev['k'], dev['v'], jnp.asarray(phi_v),
+            jnp.asarray(mu_v))
+        want = [np.asarray(want_o)] + [np.asarray(g) for g in want_g]
+        say('reference (one softmax over [T + T / 16] keys, blocks of '
+            '512 queries, float32 highest), forward + jax.grad: %.1f s'
+            % (time.time() - t0))
+    tol = EVA_F32_OUT_TOL if name == 'float32' else EVA_BF16_OUT_TOL
+    worst = 0.0
+    for what, x, y in zip(('o', 'dq', 'dk', 'dv', 'dphi', 'dmu'), got,
+                          want):
+        e = float(np.abs(x - y).max() / np.abs(y).max())
+        l2 = float(np.linalg.norm(x - y) / np.linalg.norm(y))
+        worst = max(worst, e)
+        say('%s %s %s: largest entry difference %.3e of the largest '
+            'entry (%.3e), relative L2 distance %.3e'
+            % (name, what, x.shape, e, np.abs(y).max(), l2))
+    check(worst <= tol, 'eva_attention %s at T = %d within %g of the '
+          'reference, output and five gradients (worst %.3e)'
+          % (name, t, tol, worst))
+    if name == 'float32':
+        return
+    # each part alone, forward + backward, the functions the ops lower to
+    del got, want, want_o, want_g
+    q, k, v, cot = (dev[n].astype(dtype) for n in ('q', 'k', 'v', 'cot'))
+    phi_d, mu_d = jnp.asarray(phi_v), jnp.asarray(mu_v)
+    cot_lse = jnp.ones((1, heads, t), jnp.float32)
+
+    def fold(x):
+        return x.reshape(-1, window, heads, d)
+
+    def pool(k, v, phi, mu):
+        out = registry.get('eva_chunk_summary').fn(
+            registry.LowerCtx(0), {'K': [k], 'V': [v], 'Phi': [phi],
+                                   'Mu': [mu]}, {'chunk_size': chunk})
+        return out['KS'][0], out['VS'][0]
+
+    def with_cotangents(stream):
+        def loss(*args):
+            o, lse = stream(*args)
+            return jnp.vdot(o.astype(jnp.float32), cot.reshape(o.shape)) \
+                + jnp.sum(jnp.where(jnp.isfinite(lse), lse, 0.0))
+        return jax.jit(jax.grad(loss, tuple(range(3))))
+
+    local = with_cotangents(lambda q, k, v: fa.flash_attention(
+        fold(q), fold(k), fold(v), causal=True, with_lse=True))
+    remote = with_cotangents(lambda q, ks, vs: fa.flash_attention(
+        q, ks, vs, coarse=(window, chunk), with_lse=True))
+    pooling = jax.jit(jax.grad(
+        lambda k, v, phi, mu: sum(jnp.sum(x.astype(jnp.float32))
+                                  for x in pool(k, v, phi, mu)),
+        (0, 1, 2, 3)))
+    ks, vs = jax.jit(pool)(k, v, phi_d, mu_d)
+    peak_flops, peak_bytes = peaks.chip_peak(
+        jax.devices()[0].device_kind)
+    for what, seconds, cost in (
+            ('local stream', _timed(local, q, k, v),
+             evabyte_flops.local_train_cost(1, heads, t, d, window)),
+            ('remote stream', _timed(remote, q, ks, vs),
+             evabyte_flops.remote_train_cost(1, heads, t, d, window,
+                                             chunk)),
+            ('pooling', _timed(pooling, k, v, phi_d, mu_d),
+             evabyte_flops.chunk_summary_train_cost(1, heads, t, d,
+                                                    chunk))):
+        least, bound = flops.roofline_seconds(cost[0], cost[1],
+                                              peak_flops, peak_bytes)
+        say('T = %d %s, forward + backward with its cotangents\' dot '
+            'products in the same program: %.2f ms; %.2f GFLOP, %.1f MB, '
+            '%s-bound, least %.2f ms: %.1f%% of its roofline'
+            % (t, what, seconds * 1e3, cost[0] / 1e9, cost[1] / 1e6,
+               bound, least * 1e3, 100.0 * least / seconds))
+    say('T = %d: remote pairs %.2f of local (%d / %d a head)'
+        % (t, evabyte_flops.remote_pairs(t, window, chunk) /
+           evabyte_flops.local_pairs(t, window),
+           evabyte_flops.remote_pairs(t, window, chunk),
+           evabyte_flops.local_pairs(t, window)))
+
+
+def _fused_dispatches():
+    from paddle_tpu.fluid import monitor
+    return monitor.counter_value('pallas/flash_attention/dispatch_fused') \
+        or 0
+
+
+def _evabyte_layer_gradients(seq, seed):
+    """One layer of the published model, f32 TRAIN program (SGD at lr
+    0: the step is the gradients) on one seeded sequence: the loss and
+    EVERY parameter's gradient, phi and mu by name, against jax.grad
+    of the plain reference."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import evabyte
+    from paddle_tpu.models.reference import evabyte as reference
+    cfg = evabyte.EvaByteConfig(layers=1)
+    feed = _ints32(evabyte.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(seed)))
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.scope_guard(fluid.Scope()):
+        with fluid.program_guard(main, startup), \
+                fluid.unique_name.guard():
+            _, _, loss = evabyte.build_pretrain(cfg, seq)
+            params = [p.name for p in main.all_parameters()]
+            pairs = dict((p.name, g.name) for p, g in
+                         fluid.optimizer.SGD(0.0).minimize(loss)[1])
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        # host copies first: a run donates the state it may write
+        weights = [np.asarray(fluid.core.as_array(scope.find_var(p)))
+                   for p in params]
+        fused0 = _fused_dispatches()
+        t0 = time.time()
+        got = exe.run(main, feed=feed,
+                      fetch_list=[loss] + [pairs[p] for p in params])
+        got_loss = _scalar(got[:1])
+        grads = [np.asarray(g) for g in got[1:]]
+        say('evabyte f32 train program, one layer, 1 x %d bytes: loss '
+            '%.6f in %.1f s (with compile); %d flash dispatches fused'
+            % (seq, got_loss, time.time() - t0,
+               _fused_dispatches() - fused0))
+        check(_fused_dispatches() - fused0 >= 2,
+              'both streams of the f32 train step dispatched to the '
+              'flash kernels')
+        del got
+        for n in scope.local_var_names():
+            scope.erase(n)
+    jfeed = {k: jnp.asarray(v) for k, v in feed.items()}
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda w: reference.loss(w, jfeed, cfg, remat=True)))(
+        [jnp.asarray(w) for w in weights])
+    want_loss = float(want_loss)
+    rel = abs(got_loss - want_loss) / want_loss
+    say('reference: loss %.6f; relative difference %.2e'
+        % (want_loss, rel))
+    check(rel <= EVA_LOSS_RTOL, 'evabyte f32 train loss within %g of '
+          'the reference' % EVA_LOSS_RTOL)
+    worst = 0.0
+    for what, x, y in zip(EVA_NAMES, grads, want):
+        y = np.asarray(y)
+        e = float(np.abs(x - y).max() / np.abs(y).max())
+        l2 = float(np.linalg.norm(x - y) / np.linalg.norm(y))
+        worst = max(worst, l2)
+        say('gradient of %s %s: largest entry difference %.3e of the '
+            'largest entry (%.3e), relative L2 distance %.3e'
+            % (what, x.shape, e, np.abs(y).max(), l2))
+    check(worst <= EVA_L2_RTOL, 'evabyte gradients: every parameter '
+          'within %g relative L2 of the reference\'s (worst %.3e)'
+          % (EVA_L2_RTOL, worst))
+
+
+def _evabyte_cell_losses(seq, seed):
+    """The benchmark cell's own cut (four layers), forward only: the
+    f32 for_test program's loss on EVA_LOSS_BATCHES batches beside the
+    reference's in float32 and in bfloat16 throughout: the two readings
+    the family's REFERENCE_RTOL lies between."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import evabyte
+    from paddle_tpu.models.reference import evabyte as reference
+    cfg = evabyte.EvaByteConfig(layers=EVA_CELL_LAYERS)
+    feeds = [_ints32(evabyte.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(s)))
+        for s in range(seed, seed + EVA_LOSS_BATCHES)]
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.scope_guard(fluid.Scope()):
+        with fluid.program_guard(main, startup), \
+                fluid.unique_name.guard():
+            _, _, loss = evabyte.build_pretrain(cfg, seq)
+            test = main.clone(for_test=True)
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights = [np.asarray(fluid.core.as_array(scope.find_var(p.name)))
+                   for p in main.all_parameters()]
+        program = [_scalar(exe.run(test, feed=f, fetch_list=[loss]))
+                   for f in feeds]
+        for n in scope.local_var_names():
+            scope.erase(n)
+    weights = [jnp.asarray(w) for w in weights]
+    both = jax.jit(lambda w, f: [reference.loss(w, f, cfg, dtype=dt)
+                                 for dt in (jnp.float32, jnp.bfloat16)])
+    off, low = [], []
+    for n, (feed, got) in enumerate(zip(feeds, program)):
+        full, half = (float(x) for x in both(
+            weights, {k: jnp.asarray(v) for k, v in feed.items()}))
+        off.append(abs(got - full) / full)
+        low.append(abs(half - full) / full)
+        say('%d layers, batch seed %d: program %.6f, reference %.6f '
+            '(relative difference %.2e), reference in bfloat16 '
+            'throughout %.6f (%.2e)'
+            % (cfg.layers, seed + n, got, full, off[-1], half, low[-1]))
+    say('over %d batches at %d layers: f32 for_test program against the '
+        'reference, relative: median %.2e, largest %.2e; reference in '
+        'bfloat16 throughout: smallest %.2e, median %.2e, largest %.2e, '
+        '%d within %g'
+        % (len(off), cfg.layers, np.median(off), max(off), min(low),
+           np.median(low), max(low),
+           sum(x <= EVA_LOSS_RTOL for x in low), EVA_LOSS_RTOL))
+    check(max(off) <= EVA_LOSS_RTOL, 'evabyte f32 for_test loss at the '
+          'cell\'s cut within %g of the reference on every batch'
+          % EVA_LOSS_RTOL)
+    check(min(low) > EVA_LOSS_RTOL, 'the reference in bfloat16 '
+          'throughout misses %g on every batch' % EVA_LOSS_RTOL)
+
+
+def phase_evabyte(seed=0):
+    """EvaByte at published widths: (d) one eva_attention call at the
+    published 32768 positions, where the remote stream is half of
+    attention, in bfloat16 (timed, each stream against its roofline)
+    and in float32 (tight agreement); (c) every gradient of one layer's
+    f32 train program at the cell's 4096 bytes; the cell's own forward
+    check over several batches, f32 and bfloat16."""
+    _eva_single_op(EVA_T, 'bfloat16', seed)
+    _eva_single_op(EVA_T, 'float32', seed)
+    _evabyte_layer_gradients(EVA_LAYER_SEQ, seed)
+    _evabyte_cell_losses(EVA_LAYER_SEQ, seed)
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
     ap.add_argument('--chips', type=int, choices=(1, 4), default=1)
     ap.add_argument('--phase',
                     choices=('bert', 'olmoe', 'laguna', 'moonlight',
-                             'lfm2'),
+                             'lfm2', 'evabyte'),
                     default='bert',
-                    help="'olmoe' / 'laguna' / 'moonlight' / 'lfm2': "
-                    "only that model's gradient check")
+                    help="'olmoe' / 'laguna' / 'moonlight' / 'lfm2' / "
+                    "'evabyte': only that model's gradient check")
     args = ap.parse_args()
 
     import jax
@@ -1540,6 +1892,8 @@ def main():
             phase_moonlight_gradients()
         elif args.phase == 'lfm2':
             phase_lfm2_gradients()
+        elif args.phase == 'evabyte':
+            phase_evabyte()
         elif args.chips == 4:
             phase_four_chips(
                 models.bert.BertConfig(dropout=0.0, attn_dropout=0.0),

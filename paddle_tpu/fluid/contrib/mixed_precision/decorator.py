@@ -17,6 +17,26 @@ from ...framework import default_main_program, default_startup_program
 from .fp16_lists import AutoMixedPrecisionLists
 
 
+# An op that carries this attribute is left unmarked whatever list its
+# type is on: it computes in the dtypes its inputs arrive in, by
+# jnp's promotion (a float32 residual stream plus a bfloat16 branch is
+# float32; a matmul of float32 operands is a float32 matmul).
+KEEP_FLOAT32 = '__amp_keep_float32__'
+
+
+def keep_float32(*variables):
+    """Exempt the ops that produced ``variables`` from the lists'
+    placement: placement is by op TYPE for a whole program, and a
+    model whose residual adds or output heads stay float32 beside
+    bfloat16 matmuls (EvaByte's ``fp32_skip_add``, ``fp32_logits``)
+    says so op by op.  Call it on a layer's output as the program is
+    built; ``decorate(...).minimize`` honours it.  -> ``variables``
+    (the one, or the tuple)."""
+    for var in variables:
+        var.op.attrs[KEEP_FLOAT32] = True
+    return variables[0] if len(variables) == 1 else variables
+
+
 def _mark_amp_ops(program, amp_lists):
     """White ops run their MXU dots in bf16 ('__amp__'); gray ops FOLLOW
     a low-precision input by casting their f32 inputs down
@@ -34,6 +54,7 @@ def _mark_amp_ops(program, amp_lists):
     no_harmonize = {'batch_norm', 'layer_norm', 'instance_norm',
                     'group_norm', 'sync_batch_norm',
                     'rms_norm', 'rotary_embedding', 'short_conv',
+                    'eva_chunk_summary',
                     # f32 gates beside bf16 rows: cast neither way
                     'moe_route', 'moe_dispatch', 'moe_combine',
                     # compute in f32 internally; black-casting their
@@ -46,6 +67,8 @@ def _mark_amp_ops(program, amp_lists):
     no_harmonize -= getattr(amp_lists, 'custom_placed', set())
     for block in program.blocks:
         for op in block.ops:
+            if op.attrs.get(KEEP_FLOAT32):
+                continue
             if op.type in amp_lists.white_list:
                 op.attrs['__amp__'] = True
             elif op.type in amp_lists.gray_list - no_harmonize:

@@ -28,7 +28,7 @@ gray_list = {
     'concat', 'split', 'slice', 'scale',
     # f32 inside, output in the input's dtype, like layer_norm
     'rms_norm', 'rotary_embedding', 'moe_dispatch', 'moe_combine',
-    'short_conv',
+    'short_conv', 'eva_chunk_summary',
 }
 
 

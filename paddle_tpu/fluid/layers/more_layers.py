@@ -204,6 +204,143 @@ def short_conv(input, filter_size, gate_in=None, gate_out=None,
     return _simple('short_conv', ins, name=name)
 
 
+def flash_attention(q, k, v, causal=False, window=0, coarse=None,
+                    with_lse=False, name=None):
+    """The ``fused_multihead_attention`` op on heads already split: q
+    [B, T, H, D], k [B, Tk, Hkv, D], v [B, Tk, Hkv, Dv] -> [B, T, H,
+    Dv] (the flash kernels on a chip from
+    ``flash_attention.FLASH_MIN_SEQ`` queries up, the op's dense chain
+    under it and off a chip).  ``causal``, ``window`` and ``coarse`` =
+    (window, chunk) are the op's three masks (``ops/pallas/
+    flash_attention.py`` lists them in one place); ``with_lse`` also
+    returns every row's log-sum-exp [B, T, H], differentiable, for
+    ``attention_merge``."""
+    helper = LayerHelper('fused_multihead_attention', name=name)
+    out = helper.create_variable_for_type_inference(q.dtype)
+    outputs = {'Out': out}
+    attrs = {'causal': bool(causal), 'dropout_rate': 0.0}
+    if window:
+        attrs['window'] = int(window)
+    if coarse:
+        attrs['coarse_window'], attrs['coarse_chunk'] = \
+            (int(n) for n in coarse)
+    if with_lse:
+        attrs['with_lse'] = True
+        lse = helper.create_variable_for_type_inference('float32')
+        outputs['Lse'] = lse
+    helper.append_op('fused_multihead_attention',
+                     inputs={'Q': q, 'K': k, 'V': v}, outputs=outputs,
+                     attrs=attrs, infer_shape=False)
+    out.shape = tuple(q.shape[:3]) + (v.shape[3],)
+    if not with_lse:
+        return out
+    lse.shape = tuple(q.shape[:3])
+    return out, lse
+
+
+def eva_chunk_summary(k, v, chunk_size, phi, mu, name=None):
+    """One learned-softmax summary key and value a chunk of
+    ``chunk_size`` positions: k [B, T, H, D], v [B, T, H, Dv], phi and
+    mu [H, D] (variables; a model makes them parameters) -> (ks [B, T
+    / chunk_size, H, D], vs [B, T / chunk_size, H, Dv]); the op
+    ``eva_chunk_summary`` has the equations."""
+    t = int(k.shape[1])
+    if t % int(chunk_size):
+        raise ValueError('eva_chunk_summary: %d positions are no whole '
+                         'number of %d-position chunks' % (t, chunk_size))
+    helper = LayerHelper('eva_chunk_summary', name=name)
+    ks = helper.create_variable_for_type_inference(k.dtype)
+    vs = helper.create_variable_for_type_inference(v.dtype)
+    helper.append_op('eva_chunk_summary',
+                     inputs={'K': k, 'V': v, 'Phi': phi, 'Mu': mu},
+                     outputs={'KS': ks, 'VS': vs},
+                     attrs={'chunk_size': int(chunk_size)})
+    return ks, vs
+
+
+def attention_merge(x1, lse1, x2, lse2, name=None):
+    """Two attention results over disjoint key sets joined into the
+    one softmax over both by their rows' log-sum-exps: x1, x2 [B, T,
+    H, Dv], lse1, lse2 [B, T, H] -> (out [B, T, H, Dv], the second
+    set's mean weight [1] over the rows where it is not empty, no
+    gradient); the op ``attention_merge`` has the equation."""
+    helper = LayerHelper('attention_merge', name=name)
+    out = helper.create_variable_for_type_inference(x1.dtype)
+    weight = helper.create_variable_for_type_inference(
+        'float32', stop_gradient=True)
+    helper.append_op('attention_merge',
+                     inputs={'X1': x1, 'Lse1': lse1, 'X2': x2,
+                             'Lse2': lse2},
+                     outputs={'Out': out, 'SecondWeight': weight})
+    return out, weight
+
+
+def _record_remote_weight(values):
+    """``Program.watch``'s record for ``eva_attention``: the layers'
+    mean remote weights of the last run that fetched, averaged."""
+    from .. import monitor
+    monitor.set_gauge('eva/remote_weight_mean', float(np.mean(
+        [np.asarray(v, np.float64).ravel()[0] for v in values])))
+
+
+def eva_attention(q, k, v, window_size, chunk_size, phi, mu, name=None):
+    """EVA attention as EvaByte ships it (Zheng et al. 2023, "Efficient
+    Attention via Control Variates", with a learned feature): q, k, v
+    [B, T, H, D] (rotated already; v may be Dv wide), phi and mu [H, D]
+    -> [B, T, H, Dv].  Query t sees two kinds of keys in ONE softmax:
+
+    - exactly and causally, the keys of its own window of
+      ``window_size`` positions (block-diagonal, not a sliding band);
+    - one summary a ``chunk_size``-position chunk
+      (``eva_chunk_summary`` of k, v by phi and mu) of every EARLIER
+      window, and none of its own.
+
+    Lowered as four ops, each with its gradient: the summaries; the
+    local stream, causal flash attention with the windows folded into
+    the batch ([B, T, ..] -> [B T / window, window, ..], a reshape);
+    the remote stream, the flash kernels under the coarse mask over T
+    / chunk keys; ``attention_merge`` of the two by their
+    log-sum-exps.  On a chip no [T, T] or [T, T / chunk] tensor reaches
+    HBM, forward or backward.  With T <= ``window_size`` there is no
+    earlier window: the layer IS causal attention, and phi and mu stay
+    unused.  T has to be a whole number of windows (or one shorter
+    window) and of chunks, and a window a whole number of chunks:
+    anything else is refused here, not padded into the softmax.
+
+    On the runs of the program that fetch, the gauge
+    ``eva/remote_weight_mean`` holds the summaries' mean share of the
+    softmax (``Program.watch``); ``eva/local_pairs``,
+    ``eva/remote_pairs`` and ``eva/chunks`` are set as the remote call
+    is lowered."""
+    t, heads = int(q.shape[1]), int(q.shape[2])
+    window, chunk = int(window_size), int(chunk_size)
+    if t <= window:
+        return flash_attention(q, k, v, causal=True, name=name)
+    if window % chunk or t % window:
+        raise ValueError(
+            'eva_attention: %d positions in windows of %d over chunks '
+            'of %d: the length has to be a whole number of windows and '
+            'a window of chunks' % (t, window, chunk))
+    from .nn import reshape
+
+    def fold(x):        # windows into the batch
+        return reshape(x, [-1, window, int(x.shape[2]), int(x.shape[3])])
+
+    def unfold(x):
+        return reshape(x, [-1, t] + [int(n) for n in x.shape[2:]])
+
+    ks, vs = eva_chunk_summary(k, v, chunk, phi, mu)
+    local, local_lse = flash_attention(fold(q), fold(k), fold(v),
+                                       causal=True, with_lse=True)
+    remote, remote_lse = flash_attention(q, ks, vs,
+                                         coarse=(window, chunk),
+                                         with_lse=True)
+    out, weight = attention_merge(unfold(local), unfold(local_lse),
+                                  remote, remote_lse, name=name)
+    out.block.program.watch([weight.name], _record_remote_weight)
+    return out
+
+
 def grid_sampler(x, grid, name=None):
     return _simple('grid_sampler', {'X': x, 'Grid': grid}, name=name)
 

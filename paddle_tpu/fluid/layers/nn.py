@@ -233,17 +233,25 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
     return helper.append_activation(out, act)
 
 
-def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None,
+             unit_offset=False):
     """Root-mean-square norm over the last axis with a learned gain
     (initialised to 1) and no bias: the norm of the 2023+ decoder
-    blocks.  Statistics in float32 whatever the input's dtype."""
+    blocks.  Statistics in float32 whatever the input's dtype.
+    ``unit_offset`` stores the gain as its offset from one (the
+    parameter starts at 0 and the norm multiplies by 1 + it: Gemma's
+    and EvaByte's ``norm_add_unit_offset``), so weight decay pulls
+    the gain to 1 and not to 0."""
     helper = LayerHelper('rms_norm', name=name)
     gain = helper.create_parameter(
         param_attr, shape=[int(input.shape[-1])], dtype=input.dtype,
-        default_initializer=Constant(1.0))
+        default_initializer=Constant(0.0 if unit_offset else 1.0))
     out = helper.create_variable_for_type_inference(input.dtype)
+    attrs = {'epsilon': epsilon}
+    if unit_offset:     # the default leaves the op as it was
+        attrs['unit_offset'] = True
     helper.append_op('rms_norm', inputs={'X': input, 'Scale': gain},
-                     outputs={'Y': out}, attrs={'epsilon': epsilon})
+                     outputs={'Y': out}, attrs=attrs)
     return out
 
 

@@ -25,7 +25,6 @@ hold this to.
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.initializer import Normal
-from paddle_tpu.fluid.layer_helper import LayerHelper
 
 from . import gpt as _gpt
 
@@ -103,20 +102,10 @@ def _linear(x, size, cfg):
 
 
 def _attend(q, k, v):
-    """q [B, T, H, d], k and v [B, T, Hkv, d] -> [B, T, H, d]: the
-    ``fused_multihead_attention`` op, causal, query head i over K/V
-    head i // (H / Hkv) (the flash kernels on a chip from
-    ``flash_attention.FLASH_MIN_SEQ`` keys up, the op's dense chain
-    under it and off a chip)."""
-    helper = LayerHelper('fused_multihead_attention')
-    out = helper.create_variable_for_type_inference(q.dtype)
-    helper.append_op('fused_multihead_attention',
-                     inputs={'Q': q, 'K': k, 'V': v},
-                     outputs={'Out': out},
-                     attrs={'causal': True, 'dropout_rate': 0.0},
-                     infer_shape=False)
-    out.shape = tuple(q.shape)
-    return out
+    """q [B, T, H, d], k and v [B, T, Hkv, d] -> [B, T, H, d]: causal
+    attention, query head i over K/V head i // (H / Hkv)
+    (``layers.flash_attention``)."""
+    return layers.flash_attention(q, k, v, causal=True)
 
 
 def short_conv_operator(u, cfg):

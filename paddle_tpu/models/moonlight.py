@@ -32,7 +32,6 @@ PR 32).
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
 from paddle_tpu.fluid.initializer import Normal
-from paddle_tpu.fluid.layer_helper import LayerHelper
 
 from . import gpt as _gpt
 
@@ -91,19 +90,9 @@ def _linear(x, size, cfg):
 
 
 def _attend(q, k, v):
-    """q, k [B, T, H, dqk], v [B, T, H, dv] -> [B, T, H, dv]: the
-    ``fused_multihead_attention`` op, causal, scores over 1/sqrt(dqk)
-    (the flash kernels on a chip from ``flash_attention.FLASH_MIN_SEQ``
-    keys up, the op's dense chain under it and off a chip)."""
-    helper = LayerHelper('fused_multihead_attention')
-    out = helper.create_variable_for_type_inference(q.dtype)
-    helper.append_op('fused_multihead_attention',
-                     inputs={'Q': q, 'K': k, 'V': v},
-                     outputs={'Out': out},
-                     attrs={'causal': True, 'dropout_rate': 0.0},
-                     infer_shape=False)
-    out.shape = tuple(q.shape[:3]) + (v.shape[3],)
-    return out
+    """q, k [B, T, H, dqk], v [B, T, H, dv] -> [B, T, H, dv]: causal
+    attention, scores over 1/sqrt(dqk) (``layers.flash_attention``)."""
+    return layers.flash_attention(q, k, v, causal=True)
 
 
 def attention(u, pos_ids, cfg):

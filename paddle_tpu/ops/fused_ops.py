@@ -49,7 +49,21 @@ def fused_multihead_attention(ctx, ins, attrs):
     python/paddle/fluid/layers/nn.py + operators/dropout_op.cu) with a
     mask keyed on (op seed, step) so per-op replay and whole-program
     vjp regenerate it; skipped in test-mode lowering like the dropout
-    op."""
+    op.
+
+    attrs['coarse_window'] with attrs['coarse_chunk'] is the third
+    mask (flash_attention()'s ``coarse``): K and V are summaries, one
+    a chunk of ``coarse_chunk`` positions ([B, T / chunk, Hkv, .]:
+    ``eva_chunk_summary``), and query i sees those of every window of
+    ``coarse_window`` positions before its own.  Such a call is
+    lowered inside the scope ``remote``; as it is lowered it sets the
+    gauges ``eva/remote_pairs`` (the (query, summary) pairs it scores,
+    a head and step), ``eva/chunks`` (its summaries) and
+    ``eva/local_pairs`` (the pairs of the exact causal call over each
+    window that completes it).  attrs['with_lse'] adds the output Lse
+    [B, T, H] (float32), every row's log-sum-exp with a gradient of
+    its own, -inf where a row sees no key: what ``attention_merge``
+    joins two calls by."""
     from .pallas.flash_attention import mesh_flash_attention
     q = ins['Q'][0]
     k = ins['K'][0]
@@ -63,14 +77,41 @@ def fused_multihead_attention(ctx, ins, attrs):
     scopes = ['window%d' % window] if window else []
     if v.shape[-1] != q.shape[-1]:
         scopes.append('qk%dv%d' % (q.shape[-1], v.shape[-1]))
+    more = {}
+    if attrs.get('coarse_window'):
+        more['coarse'] = (int(attrs['coarse_window']),
+                          int(attrs['coarse_chunk']))
+        scopes.append('remote')
+        _coarse_gauges(q.shape[0], q.shape[1], k.shape[1],
+                       *more['coarse'])
+    if attrs.get('with_lse'):
+        more['with_lse'] = True
     with contextlib.ExitStack() as stack:
         for name in scopes:
             stack.enter_context(jax.named_scope(name))
-        return {'Out': [mesh_flash_attention(
+        out = mesh_flash_attention(
             q, k, v, ctx.auto_partitioned,
             scopes[-1] if scopes else 'fused_multihead_attention',
             causal=attrs.get('causal', False), key_bias=bias,
-            dropout_rate=rate, dropout_seed=seed, window=window)]}
+            dropout_rate=rate, dropout_seed=seed, window=window, **more)
+    if not attrs.get('with_lse'):
+        return {'Out': [out]}
+    return {'Out': [out[0]], 'Lse': [jnp.transpose(out[1], (0, 2, 1))]}
+
+
+def _coarse_gauges(batch, t, summaries, window, chunk):
+    """Static counts of a coarse call, a head and step (the docstring
+    above)."""
+    from ..fluid import monitor
+    windows = -(-t // window)
+    per_window = window // chunk
+    monitor.set_gauge('eva/remote_pairs', float(
+        batch * sum(min(window, t - w * window) * w * per_window
+                    for w in range(windows))))
+    monitor.set_gauge('eva/local_pairs', float(batch * sum(
+        n * (n + 1) // 2 for n in
+        (min(window, t - w * window) for w in range(windows)))))
+    monitor.set_gauge('eva/chunks', float(batch * summaries))
 
 
 @register('fused_elemwise_activation')

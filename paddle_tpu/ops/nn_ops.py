@@ -436,7 +436,74 @@ def rms_norm(ctx, ins, attrs):
     y = xf * jax.lax.rsqrt(
         jnp.mean(jnp.square(xf), axis=-1, keepdims=True) +
         attrs.get('epsilon', 1e-5))
-    return {'Y': [(y * ins['Scale'][0].astype(xf.dtype)).astype(x.dtype)]}
+    gain = ins['Scale'][0].astype(xf.dtype)
+    if attrs.get('unit_offset'):    # Scale holds the gain's offset from 1
+        gain = 1.0 + gain
+    return {'Y': [(y * gain).astype(x.dtype)]}
+
+
+@register('eva_chunk_summary')
+def eva_chunk_summary(ctx, ins, attrs):
+    """K [B, T, H, D], V [B, T, H, Dv], Phi [H, D], Mu [H, D] -> KS
+    [B, T / c, H, D], VS [B, T / c, H, Dv], c = attrs['chunk_size']:
+    one summary key and value a chunk of c positions, pooled by a
+    learned softmax.  For chunk n of head h, over its positions j:
+
+        a_j  = softmax_j(K_j . Phi_h)           (no 1/sqrt(D) scale)
+        KS_n = sum_j a_j K_j + Mu_h
+        VS_n = sum_j a_j V_j
+
+    EVA's control-variate estimate of a chunk's share of the softmax
+    (Zheng et al. 2023) with the sampled random feature replaced by a
+    learned one, as EvaByte ships it.  An attention call with the
+    coarse mask (``fused_multihead_attention``'s ``coarse_window``)
+    reads them as keys and values.  Float32 inside whatever K is, KS
+    and VS in K's and V's dtype (the rms_norm policy); sums over
+    products, no matmul: the op reads K and V once and is bytes-bound.
+    The gradient is jax.vjp of this."""
+    k, v = ins['K'][0], ins['V'][0]
+    chunk = int(attrs['chunk_size'])
+    b, t, h, d = k.shape
+    if chunk < 1 or t % chunk:
+        raise ValueError('eva_chunk_summary: %d positions are no whole '
+                         'number of %d-position chunks' % (t, chunk))
+    f32 = jnp.float64 if k.dtype == jnp.float64 else jnp.float32
+    kc = k.astype(f32).reshape(b, t // chunk, chunk, h, d)
+    vc = v.astype(f32).reshape(b, t // chunk, chunk, h, v.shape[3])
+    a = jax.nn.softmax(
+        jnp.sum(kc * ins['Phi'][0].astype(f32), axis=-1), axis=2)[..., None]
+    ks = jnp.sum(a * kc, axis=2) + ins['Mu'][0].astype(f32)
+    vs = jnp.sum(a * vc, axis=2)
+    return {'KS': [ks.astype(k.dtype)], 'VS': [vs.astype(v.dtype)]}
+
+
+@register('attention_merge', no_grad_out_slots=('SecondWeight',))
+def attention_merge(ctx, ins, attrs):
+    """Two attention results over two DISJOINT key sets, each with its
+    rows' log-sum-exp, joined into the one softmax over both sets: X1,
+    X2 [B, T, H, Dv], Lse1, Lse2 [B, T, H] ->
+
+        Out = sigma(Lse1 - Lse2) X1 + sigma(Lse2 - Lse1) X2
+
+    (the two weights are each set's share of the joint normaliser).
+    A row whose second set is empty carries Lse2 = -inf: its weight is
+    exactly 0, Out = X1 there, and no cotangent reaches X2 or the
+    log-sum-exps through it (the sigmoid's slope at -inf is 0, not
+    NaN).  Float32 inside, Out in X1's dtype.  SecondWeight [1] is
+    the mean of the second set's weight over the rows where it is not
+    empty (0 where every row's is), for a monitor gauge: nothing is
+    computed for it where nothing fetches it."""
+    x1, x2 = ins['X1'][0], ins['X2'][0]
+    lse1, lse2 = ins['Lse1'][0], ins['Lse2'][0]
+    f32 = jnp.float64 if x1.dtype == jnp.float64 else jnp.float32
+    w2 = jax.nn.sigmoid(lse2.astype(f32) - lse1.astype(f32))
+    x1f = x1.astype(f32)
+    out = x1f + w2[..., None] * (x2.astype(f32) - x1f)
+    has = jnp.isfinite(lse2)
+    share = jnp.sum(jnp.where(has, w2, 0.0)) / \
+        jnp.maximum(jnp.sum(has), 1).astype(f32)
+    return {'Out': [out.astype(x1.dtype)],
+            'SecondWeight': [jax.lax.stop_gradient(share).reshape(1)]}
 
 
 @register('rotary_embedding')

@@ -139,31 +139,36 @@ def room_for_second_tile(resident, block_q, block_k, itemsize):
     return 2 * resident + 2 * tile <= SCOPED_VMEM_BYTES
 
 
-def block_sizes(t, block_q, block_k, d=64, itemsize=2, dv=None):
+def block_sizes(t, block_q, block_k, d=64, itemsize=2, dv=None, tk=None):
     """Clamp requested blocks to divide t AND fit the VMEM budget —
     an oversized config degrades to the largest fitting one instead of
     failing to compile (round-3's 2048-wide failure mode).  ``dv``:
-    vmem_estimate()'s.
+    vmem_estimate()'s.  ``tk``: the keys' length where it is not the
+    queries' (a coarse mask's summaries): block_k divides it, and the
+    resident rows are counted at the longer of the two (the dkv call
+    keeps Q and dO resident, the others K and V).
 
     Where the resident K/V rows alone pass the budget at the smallest
     blocks (float32 rows of an 8k sequence at 192 + 128: 10.5 MB), no
     block size helps: the blocks are clamped as if those rows were
     free, and the calls ask Mosaic for the scoped VMEM they need
     (scoped_vmem) instead of its default."""
+    tk = t if tk is None else tk
+    rows = max(t, tk)
     block_q = min(block_q, t)
-    block_k = min(block_k, t)
+    block_k = min(block_k, tk)
     while t % block_q:
         block_q //= 2
-    while t % block_k:
+    while tk % block_k:
         block_k //= 2
 
     def over(bq, bk, free=0):
-        return vmem_estimate(t, d, bq, bk, itemsize, dv) - free > \
+        return vmem_estimate(rows, d, bq, bk, itemsize, dv) - free > \
             VMEM_BUDGET_BYTES
 
     free = 0
     if over(min(block_q, 128), min(block_k, 128)):
-        free = resident_row_bytes(t, d, itemsize, dv)
+        free = resident_row_bytes(rows, d, itemsize, dv)
     while over(block_q, block_k, free) and max(block_q, block_k) > 128:
         if block_k >= block_q and block_k > 128:
             block_k //= 2

@@ -31,7 +31,29 @@ An optional additive key bias [B, T] (padding masks, per-key biases)
 is applied to the scores inside the kernels — the BERT input-mask path
 (models/bert.py) — and receives a real gradient so learned biases work.
 
-Both public entries go through common.dispatch(): compiled on a TPU,
+The masks, all static at trace time and all applied per tile by
+_score_tile, with the tiles wholly outside them never visited
+(_key_blocks, _query_blocks):
+
+- none: every query sees every key;
+- ``causal``: query i sees the keys j <= i;
+- ``causal`` with ``window``: the band 0 <= i - j < window;
+- ``coarse`` = (window, chunk): the keys are SUMMARIES, Tk >= T /
+  chunk of them, one a chunk of ``chunk`` positions, and query i sees
+  those of every window of ``window`` positions before its own
+  (_coarse_visible).  The one mask under which queries and keys
+  differ in length: the resident rows are then of two lengths (K and
+  V in a forward or dq call, Q and dO in a dkv call, both in the
+  fused backward), and every estimate of common.py takes the length
+  that is resident.
+
+flash_attention() is the one public entry; ``with_lse`` makes the
+rows' log-sum-exp a second, differentiable output (its cotangent
+folds into dS inside the backward kernels), by which partial results
+over disjoint key sets merge: ring attention's blocks
+(parallel/ring_attention.py), EVA's exact and summarised keys
+(layers.eva_attention).  mesh_flash_attention() wraps it for the
+GSPMD runner.  Both go through common.dispatch(): compiled on a TPU,
 the dense XLA chain anywhere else, and the Pallas interpreter only
 under FLAGS_pallas_force (tests).
 """
@@ -199,11 +221,13 @@ def _scale_is_exact(scale):
 
 
 def _score_tile(q, k, bias, rows, cols, *, scale, causal, q0, k0,
-                rate, window=0):
+                rate, window=0, coarse=None):
     """What the four kernel bodies share for one [bq, bk] tile:
     s = q k^T (* scale, unless an operand already carries it:
     scale=None) (+ bias[None, :]) (-inf above the diagonal and, with
     a ``window``, ``window`` or more keys below it), and the
+    a ``coarse`` (window, keys a window) mask instead: -inf from key
+    (q // window) * keys on, _coarse_visible), and the
     dropout multiplier u = 1/(1-rate) where the element is kept, 0
     where it is dropped (None at rate 0), drawn from the tile's
     [bq, 1] ``rows`` and [1, bk] ``cols`` terms.  Callers turn s into
@@ -223,11 +247,28 @@ def _score_tile(q, k, bias, rows, cols, *, scale, causal, q0, k0,
         if window:
             visible = visible & (qpos - kpos < window)
         s = jnp.where(visible, s, -jnp.inf)
+    elif coarse:
+        bq, bk = s.shape
+        s = jnp.where(_coarse_visible(
+            q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0),
+            k0 + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1),
+            coarse), s, -jnp.inf)
     u = None
     if rate:
         u = jnp.where(_dropout_keep(rows, cols, _keep_threshold(rate)),
                       1.0 / (1.0 - rate), 0.0)
     return s, u
+
+
+def _coarse_visible(qpos, kpos, coarse):
+    """The third mask, coarse in the keys: the keys are one summary a
+    chunk of an earlier stretch of the sequence, ``coarse`` = (window,
+    keys a window), and query t sees the summaries of every window
+    BEFORE its own, keys 0 .. (t // window) * keys - 1: none in the
+    first window, and none of its own window's (which an exact,
+    causal call over that window covers)."""
+    window, keys = coarse
+    return kpos < (qpos // window) * keys
 
 
 def _loop(lo, hi, step, init, tiles):
@@ -245,13 +286,17 @@ def _loop(lo, hi, step, init, tiles):
     return jax.lax.fori_loop(0, (hi - lo) // tiles, trip, init)
 
 
-def _key_blocks(q0, bq, block_k, nk, causal, window):
+def _key_blocks(q0, bq, block_k, nk, causal, window, coarse=None):
     """[lo, hi) of the key blocks that hold a key some query of the
     block q0 .. q0+bq-1 sees: all of them without a mask, up to the
     diagonal's under a causal one, and from the block of key
     q0 - window + 1 on under a banded one.  The blocks outside are
     not visited; the ones on the band's two edges are masked per
-    element (_score_tile)."""
+    element (_score_tile).  Under a coarse mask: up to the last
+    summary the block's last query sees."""
+    if coarse:
+        seen = ((q0 + bq - 1) // coarse[0]) * coarse[1]
+        return 0, jnp.minimum(nk, (seen + block_k - 1) // block_k)
     if not causal:
         return 0, nk
     hi = jnp.minimum(nk, (q0 + bq + block_k - 1) // block_k)
@@ -259,10 +304,15 @@ def _key_blocks(q0, bq, block_k, nk, causal, window):
     return lo, hi
 
 
-def _query_blocks(k0, bk, block_q, nq, causal, window):
+def _query_blocks(k0, bk, block_q, nq, causal, window, coarse=None):
     """[lo, hi) of the query blocks that hold a query which sees some
     key of the block k0 .. k0+bk-1: _key_blocks() from the other
-    side (the last such query is k0 + bk - 1 + window - 1)."""
+    side (the last such query is k0 + bk - 1 + window - 1).  Under a
+    coarse mask: from the first query of the window after key k0's
+    on (possibly none: lo = nq)."""
+    if coarse:
+        first = (k0 // coarse[1] + 1) * coarse[0]
+        return jnp.minimum(nq, first // block_q), nq
     if not causal:
         return 0, nq
     hi = jnp.minimum(nq, (k0 + bk + window - 2) // block_q + 1) \
@@ -289,7 +339,8 @@ def _flush_at_last(member, group, accs, outs):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
-                      block_k, tiles, has_bias, rate, window=0):
+                      block_k, tiles, has_bias, rate, window=0,
+                      coarse=None):
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
     seed_ref = rest.pop(0) if rate else None
@@ -323,7 +374,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             q, k, bias, rows,
             _draw_cols(seed_ref, i * block_k, block_k),
             scale=None if exact else scale, causal=causal, q0=q_off,
-            k0=i * block_k, rate=rate, window=window)
+            k0=i * block_k, rate=rate, window=window, coarse=coarse)
         m_new = jnp.maximum(m, jnp.max(s, axis=1))
         # a row with every key masked so far: m_new = -inf, p = 0
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -343,7 +394,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     l0 = jnp.zeros((bq,), jnp.float32)
     acc0 = jnp.zeros((bq, v_ref.shape[2]), jnp.float32)
     # skip the K blocks no query of this block sees
-    lo, hi = _key_blocks(q_off, bq, block_k, nk, causal, window)
+    lo, hi = _key_blocks(q_off, bq, block_k, nk, causal, window,
+                         coarse)
     m, l, acc = _loop(lo, hi, body, (m0, l0, acc0), tiles)
     l_safe = jnp.maximum(l, 1e-20)
     out = acc / l_safe[:, None]
@@ -354,7 +406,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                          block_k, tiles, has_bias, has_glse, rate,
-                         window=0):
+                         window=0, coarse=None):
     """Grid (BH, T/bq): recompute p row-blocks from q and lse, then
     dq = sum_k (p * (dO V^T - delta)) K * scale."""
     rest = list(rest)
@@ -387,7 +439,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             q_s, k, bias, rows,
             _draw_cols(seed_ref, i * block_k, block_k),
             scale=None if exact else scale, causal=causal, q0=q_off,
-            k0=i * block_k, rate=rate, window=window)
+            k0=i * block_k, rate=rate, window=window, coarse=coarse)
         p = jnp.exp(s - lse[:, None])
         dp = _dot(do, v, (1, 1))
         if rate:
@@ -403,7 +455,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             ds = ds * scale
         return dq + _dot(ds.astype(k.dtype), k, (1, 0))
 
-    lo, hi = _key_blocks(q_off, bq, block_k, nk, causal, window)
+    lo, hi = _key_blocks(q_off, bq, block_k, nk, causal, window,
+                         coarse)
     dq = _loop(lo, hi, body, jnp.zeros((bq, d), jnp.float32), tiles)
     if exact:
         dq = dq * scale
@@ -412,7 +465,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                           block_q, tiles, dp_early, has_bias, has_glse,
-                          rate, window=0, group=1):
+                          rate, window=0, group=1, coarse=None):
     """Grid (BH, T/bk): for one K/V block, stream Q row-blocks:
     dv = sum_q p^T dO;  ds_raw = p * (dO V^T - delta);
     dk = sum_q ds_raw^T Q * scale;  dbias = sum_q ds_raw (per key).
@@ -458,7 +511,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             q, k_s, bias,
             _draw_rows(seed_ref, g_id, j * block_q, block_q), cols,
             scale=None if exact else scale, causal=causal,
-            q0=j * block_q, k0=k_off, rate=rate, window=window)
+            q0=j * block_q, k0=k_off, rate=rate, window=window,
+            coarse=coarse)
         p = jnp.exp(s - lse[:, None])
 
         def dp_tile():
@@ -488,7 +542,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
     # q blocks whose queries see no key of this block contribute
     # nothing
-    j0, j1 = _query_blocks(k_off, bk, block_q, nq, causal, window)
+    j0, j1 = _query_blocks(k_off, bk, block_q, nq, causal, window,
+                           coarse)
     dk0 = jnp.zeros((bk, d), jnp.float32)
     dv0 = jnp.zeros(v.shape, jnp.float32)
     db0 = jnp.zeros((bk,), jnp.float32)
@@ -510,7 +565,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                             block_q, block_k, tiles, dp_early, has_bias,
-                            has_glse, rate, window=0, group=1):
+                            has_glse, rate, window=0, group=1,
+                            coarse=None):
     """Single-pass backward: grid (BH,) only.  The two-pass scheme
     (dq grid over Q blocks, dk/dv grid over K blocks) recomputes the
     score block s AND the prob-cotangent dp = dO V^T in BOTH kernels —
@@ -544,7 +600,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     if group > 1:       # the query head this step holds
         g_id = g_id * group + pl.program_id(1)
     exact = _scale_is_exact(scale)
-    nq, nk = t // block_q, t // block_k
+    nq, nk = t // block_q, k_ref.shape[1] // block_k
 
     def k_step(i, _):
         k = k_ref[0, pl.dslice(i * block_k, block_k), :]
@@ -569,7 +625,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                 _draw_rows(seed_ref, g_id, j * block_q, block_q), cols,
                 scale=None if exact else scale, causal=causal,
                 q0=j * block_q, k0=i * block_k, rate=rate,
-                window=window)
+                window=window, coarse=coarse)
             p = jnp.exp(s - lse[:, None])
 
             def dp_tile():
@@ -605,7 +661,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             return dk, dv, dbias
 
         j0, j1 = _query_blocks(i * block_k, block_k, block_q, nq,
-                               causal, window)
+                               causal, window, coarse)
         dk0 = jnp.zeros((block_k, d), jnp.float32)
         dv0 = jnp.zeros((block_k, v_ref.shape[2]), jnp.float32)
         db0 = jnp.zeros((block_k,), jnp.float32)
@@ -636,31 +692,32 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
                      causal, block_q, block_k, interpret, rate,
-                     window=0):
+                     window=0, coarse=None):
     """pallas_call plumbing for the one-pass backward: grid (BH,), or
     (B*Hkv, group) where ``group`` query heads share a K/V head."""
     bh, t, d = q.shape
-    dv = v.shape[2]
+    tk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
     has_glse = glse3 is not None
     tiles, dp_early = _second_tile(
-        None if causal else t // block_q,
-        _fused_bwd_resident(t, d, block_k, q.dtype.itemsize, group, dv),
+        None if causal or coarse else t // block_q,
+        _fused_bwd_resident(t, d, block_k, q.dtype.itemsize, group, dv,
+                            tk),
         block_q, block_k, q.dtype.itemsize)
     kernel = functools.partial(
         _flash_bwd_fused_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, tiles=tiles,
         dp_early=dp_early, has_bias=has_bias, has_glse=has_glse,
-        rate=rate, window=window, group=group)
+        rate=rate, window=window, group=group, coarse=coarse)
 
     def head(*ids):     # the query head of a grid step
         return ids[0] if group == 1 else ids[0] * group + ids[1]
 
-    def rows(width, kv=False):      # one head's [t, width] rows
+    def rows(width, kv=False):      # one head's [t | tk, width] rows
         return pl.BlockSpec(
-            (1, t, width),
+            (1, tk if kv else t, width),
             lambda *ids: (ids[0] if kv else head(*ids), 0, 0))
 
     vec = pl.BlockSpec((1, 1, t), lambda *ids: (head(*ids), 0, 0))
@@ -668,7 +725,7 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
     operands = [q, k, v]
     if has_bias:
         in_specs.append(pl.BlockSpec(
-            (1, 1, t), lambda *ids: (head(*ids) // h, 0, 0)))
+            (1, 1, tk), lambda *ids: (head(*ids) // h, 0, 0)))
         operands.append(bias[:, None, :])
     if rate:
         in_specs.append(pl.BlockSpec((1, 4), lambda *ids: (0, 0)))
@@ -683,13 +740,14 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
                  jax.ShapeDtypeStruct(k.shape, k.dtype),
                  jax.ShapeDtypeStruct(v.shape, v.dtype)]
     if has_bias:
-        out_specs.append(vec)
-        out_shape.append(jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
+        out_specs.append(pl.BlockSpec(
+            (1, 1, tk), lambda *ids: (head(*ids), 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((bh, 1, tk), jnp.float32))
     from jax.experimental.pallas import tpu as pltpu
     scratch = [pltpu.VMEM((t, d), jnp.float32)]         # dq
     if group > 1:                                       # dk, dv
-        scratch += [pltpu.VMEM((t, d), jnp.float32),
-                    pltpu.VMEM((t, dv), jnp.float32)]
+        scratch += [pltpu.VMEM((tk, d), jnp.float32),
+                    pltpu.VMEM((tk, dv), jnp.float32)]
     res = pl.pallas_call(
         kernel,
         grid=(bh,) if group == 1 else (bh // group, group),
@@ -698,11 +756,14 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        **_backward_params(
+            _fused_bwd_vmem(t, d, block_q, block_k, q.dtype.itemsize,
+                            group, dv, tk), q.dtype.itemsize),
     )(*operands)
     if has_bias:
         dq, dk, dv, dbias_bh = res
         b = bh // h
-        dbias = dbias_bh[:, 0, :].reshape(b, h, t).sum(axis=1)
+        dbias = dbias_bh[:, 0, :].reshape(b, h, tk).sum(axis=1)
     else:
         dq, dk, dv = res
         dbias = None
@@ -717,26 +778,32 @@ FUSED_BWD = True
 # over it and fall back to two-pass).
 FUSED_BLOCK_Q = 512
 FUSED_BLOCK_K = 512
+# The widest a coarse call's key block is narrowed to (_window_blocks).
+COARSE_BLOCK_K = 512
 
 
-def _fused_bwd_resident(t, d, block_k, itemsize, group=1, dv=None):
+def _fused_bwd_resident(t, d, block_k, itemsize, group=1, dv=None,
+                        tk=None):
     """What a fused-backward instance holds beside its score tiles:
-    q/k (``d`` wide) and v/do (``dv`` wide) full rows, the f32 dq
-    accumulator (and, where ``group`` query heads share a K/V head,
-    the dk and dv ones) and the dk/dv f32 blocks (x2 slack for
-    compiler temporaries)."""
+    q/k (``d`` wide) and v/do (``dv`` wide) full rows, q and do ``t``
+    long, k and v ``tk`` (t unless the keys are of another length),
+    the f32 dq accumulator (and, where ``group`` query heads share a
+    K/V head, the dk and dv ones) and the dk/dv f32 blocks (x2 slack
+    for compiler temporaries)."""
     dv = d if dv is None else dv
-    rows = 2 * t * (d + dv) * itemsize
-    accs = t * d * 4 + (0 if group == 1 else t * (d + dv) * 4)
+    tk = t if tk is None else tk
+    rows = (t + tk) * (d + dv) * itemsize
+    accs = t * d * 4 + (0 if group == 1 else tk * (d + dv) * 4)
     return rows + accs + 2 * block_k * (d + dv) * 4 + (1 << 19)
 
 
-def _fused_bwd_vmem(t, d, block_q, block_k, itemsize, group=1, dv=None):
+def _fused_bwd_vmem(t, d, block_q, block_k, itemsize, group=1, dv=None,
+                    tk=None):
     """Resident bytes for the fused backward, one tile a trip: each
     tile has two chains (s -> p and dp -> ds), so two of
     common.score_tile_bytes()."""
-    return _fused_bwd_resident(t, d, block_k, itemsize, group, dv) + \
-        2 * _common.score_tile_bytes(block_q, block_k)
+    return _fused_bwd_resident(t, d, block_k, itemsize, group, dv, tk) \
+        + 2 * _common.score_tile_bytes(block_q, block_k)
 
 
 def _second_tile(trips, resident, block_q, block_k, itemsize):
@@ -757,14 +824,36 @@ def _second_tile(trips, resident, block_q, block_k, itemsize):
 
 def _mosaic_params(t, d, block_q, block_k, itemsize, dv):
     """pallas_call's ``compiler_params`` for a forward, dq or dkv
-    call: none but where its resident rows ask for more scoped VMEM
-    than the compiler's default (common.scoped_vmem)."""
-    limit = _common.scoped_vmem(t, d, block_q, block_k, itemsize, dv)
+    call whose resident rows are ``t`` long (K and V in a forward or
+    dq call, Q and dO in a dkv one): none but where they ask for more
+    scoped VMEM than the compiler's default (common.scoped_vmem)."""
+    return _vmem_limit(
+        _common.scoped_vmem(t, d, block_q, block_k, itemsize, dv))
+
+
+def _vmem_limit(limit):
     if limit is None:
         return {}
     from jax.experimental.pallas import tpu as pltpu
     return {'compiler_params': pltpu.CompilerParams(
         vmem_limit_bytes=limit)}
+
+
+def _backward_params(estimate, itemsize, rows=None):
+    """``compiler_params`` of a backward call whose own VMEM model
+    gives ``estimate``.  bfloat16 calls ask what a forward call asks:
+    a dq or dkv call _mosaic_params(*``rows``, itemsize, dv), ``rows``
+    = (resident length, d, block_q, block_k, dv); the fused one
+    nothing.  float32 ones always ask for twice their
+    estimate and 16 MB: their full-precision products split every
+    operand into bfloat16 parts that lie beside it, which no estimate
+    here counts, and from a grid of some size on the compiler refused
+    them at its default (2048 keys x 128: 16.96 of 16 MB for a dkv
+    call, 17.99 for a fused one over 4096 queries and 256 keys;
+    ROADMAP S3 (6))."""
+    if itemsize >= 4:
+        return _vmem_limit(min(2 * estimate + (16 << 20), 100 << 20))
+    return _mosaic_params(*rows[:4], itemsize, rows[4]) if rows else {}
 
 
 def _rows_resident(t, d, block_q, block_k, itemsize, dv=None):
@@ -774,30 +863,41 @@ def _rows_resident(t, d, block_q, block_k, itemsize, dv=None):
         _common.score_tile_bytes(block_q, block_k)
 
 
-def _window_blocks(blocks, window):
+def _window_blocks(blocks, window, coarse=None, tk=0):
     """A banded call's blocks: the key block no wider than the band
     (and not under 128), since every key block a query block touches
-    is computed whole and the band is ``window`` keys of it."""
+    is computed whole and the band is ``window`` keys of it.  A coarse
+    call's likewise, a window's queries seeing whole multiples of
+    ``coarse[1]`` summaries, but not under a quarter of its ``tk``
+    keys up to COARSE_BLOCK_K: with many windows most query blocks
+    see many multiples, and [512, 128] tiles cost a long call twice
+    what [512, 512] ones do (forward + backward at 32768 queries over
+    2048 summaries: 56.2 ms against 27.2; at 4096 over 256, where a
+    query block sees 128 or none, 1.74 against 1.87: my chip run,
+    PR 38)."""
     block_q, block_k = blocks
-    while window and block_k // 2 >= max(window, 128):
+    band = coarse[1] if coarse else window
+    floor = min(COARSE_BLOCK_K, max(128, tk // 4)) if coarse else 128
+    while band and block_k // 2 >= max(band, floor):
         block_k //= 2
     return block_q, block_k
 
 
 def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
-               interpret, rate=0.0, window=0):
-    """q: [BH, T, D], k: [B*Hkv, T, D], v: [B*Hkv, T, Dv] (query head
-    i reads K/V head i // (H / Hkv)), bias: [B, T] or None, seed:
-    packed (1,4) uint32 [seed, q_off, k_off, g_off] (_pack_seed,
-    required when rate>0) -> (o [BH,T,Dv], lse [BH,T])."""
+               interpret, rate=0.0, window=0, coarse=None):
+    """q: [BH, T, D], k: [B*Hkv, Tk, D], v: [B*Hkv, Tk, Dv] (query
+    head i reads K/V head i // (H / Hkv); Tk = T but under a coarse
+    mask), bias: [B, Tk] or None, seed: packed (1,4) uint32 [seed,
+    q_off, k_off, g_off] (_pack_seed, required when rate>0) ->
+    (o [BH,T,Dv], lse [BH,T])."""
     _, t, d = q.shape
     return _fwd_call(
         q, k, v, bias, seed, h=h, causal=causal,
         blocks=_window_blocks(
             _block_sizes(t, block_q, block_k, d, q.dtype.itemsize,
-                         v.shape[2]),
-            window),
-        interpret=interpret, rate=rate, window=window)
+                         v.shape[2], k.shape[1]),
+            window, coarse, k.shape[1]),
+        interpret=interpret, rate=rate, window=window, coarse=coarse)
 
 
 # The calls are jitted on their static arguments: the layers of a model
@@ -809,31 +909,32 @@ def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
 # name of the scope the caller lowered it in (the executor's, the
 # fluid op's type), which is how a device trace is read.
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    'h', 'causal', 'blocks', 'interpret', 'rate', 'window'))
+    'h', 'causal', 'blocks', 'interpret', 'rate', 'window', 'coarse'))
 def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
-              rate, window=0):
+              rate, window=0, coarse=None):
     bh, t, d = q.shape
-    dv = v.shape[2]
+    tk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
     block_q, block_k = blocks
     scale = 1.0 / (d ** 0.5)
     has_bias = bias is not None
     tiles, _ = _second_tile(
-        None if causal else t // block_k,
-        _rows_resident(t, d, block_q, block_k, q.dtype.itemsize, dv),
+        None if causal or coarse else tk // block_k,
+        _rows_resident(tk, d, block_q, block_k, q.dtype.itemsize, dv),
         block_q, block_k, q.dtype.itemsize)
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal, block_k=block_k,
-        tiles=tiles, has_bias=has_bias, rate=rate, window=window)
+        tiles=tiles, has_bias=has_bias, rate=rate, window=window,
+        coarse=coarse)
     grid = (bh, t // block_q)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, t, d), lambda i, j: (i // group, 0, 0)),
-        pl.BlockSpec((1, t, dv), lambda i, j: (i // group, 0, 0)),
+        pl.BlockSpec((1, tk, d), lambda i, j: (i // group, 0, 0)),
+        pl.BlockSpec((1, tk, dv), lambda i, j: (i // group, 0, 0)),
     ]
     operands = [q, k, v]
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, 1, t),
+        in_specs.append(pl.BlockSpec((1, 1, tk),
                                      lambda i, j: (i // h, 0, 0)))
         operands.append(bias[:, None, :])
     if rate:
@@ -852,38 +953,40 @@ def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         interpret=interpret,
-        **_mosaic_params(t, d, block_q, block_k, q.dtype.itemsize, dv),
+        **_mosaic_params(tk, d, block_q, block_k, q.dtype.itemsize, dv),
     )(*operands)
     return o, lse3[:, 0, :]
 
 
 def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
-               block_q, block_k, interpret, rate=0.0, window=0):
+               block_q, block_k, interpret, rate=0.0, window=0,
+               coarse=None):
     bh, t, d = q.shape
-    dv = v.shape[2]
+    tk, dv = k.shape[1], v.shape[2]
     block_q, block_k = _window_blocks(
-        _block_sizes(t, block_q, block_k, d, q.dtype.itemsize, dv),
-        window)
+        _block_sizes(t, block_q, block_k, d, q.dtype.itemsize, dv, tk),
+        window, coarse, tk)
     fq, fk = min(block_q, FUSED_BLOCK_Q), min(block_k, FUSED_BLOCK_K)
     while t % fq:
         fq //= 2
-    while t % fk:
+    while tk % fk:
         fk //= 2
     fused = FUSED_BWD and _fused_bwd_vmem(
         t, d, fq, fk, q.dtype.itemsize,
-        bh // k.shape[0], dv) <= VMEM_BUDGET_BYTES
+        bh // k.shape[0], dv, tk) <= VMEM_BUDGET_BYTES
     return _bwd_call(
         q, k, v, bias, seed, o, lse, do, g_lse, h=h, causal=causal,
         blocks=(fq, fk) if fused else (block_q, block_k), fused=fused,
-        interpret=interpret, rate=rate, window=window)
+        interpret=interpret, rate=rate, window=window, coarse=coarse)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    'h', 'causal', 'blocks', 'fused', 'interpret', 'rate', 'window'))
+    'h', 'causal', 'blocks', 'fused', 'interpret', 'rate', 'window',
+    'coarse'))
 def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
-              blocks, fused, interpret, rate, window=0):
+              blocks, fused, interpret, rate, window=0, coarse=None):
     bh, t, d = q.shape
-    dv = v.shape[2]
+    tk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
     block_q, block_k = blocks
     scale = 1.0 / (d ** 0.5)
@@ -901,26 +1004,26 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
     if fused:
         return _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3,
                                 glse3, h, causal, block_q, block_k,
-                                interpret, rate, window)
+                                interpret, rate, window, coarse)
 
-    resident = _rows_resident(t, d, block_q, block_k, q.dtype.itemsize,
+    # the dq call keeps a head's K and V rows resident (tk long), the
+    # dkv call its Q and dO rows (t long)
+    resident = _rows_resident(tk, d, block_q, block_k, q.dtype.itemsize,
                               dv)
-    tiles, _ = _second_tile(None if causal else t // block_k, resident,
-                            block_q, block_k, q.dtype.itemsize)
-    more_vmem = _mosaic_params(t, d, block_q, block_k,
-                               q.dtype.itemsize, dv)
+    tiles, _ = _second_tile(None if causal or coarse else tk // block_k,
+                            resident, block_q, block_k, q.dtype.itemsize)
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, scale=scale, causal=causal,
         block_k=block_k, tiles=tiles, has_bias=has_bias,
-        has_glse=has_glse, rate=rate, window=window)
+        has_glse=has_glse, rate=rate, window=window, coarse=coarse)
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, t, d), lambda i, j: (i // group, 0, 0)),
-        pl.BlockSpec((1, t, dv), lambda i, j: (i // group, 0, 0)),
+        pl.BlockSpec((1, tk, d), lambda i, j: (i // group, 0, 0)),
+        pl.BlockSpec((1, tk, dv), lambda i, j: (i // group, 0, 0)),
     ]
     dq_operands = [q, k, v]
     if has_bias:
-        dq_specs.append(pl.BlockSpec((1, 1, t),
+        dq_specs.append(pl.BlockSpec((1, 1, tk),
                                      lambda i, j: (i // h, 0, 0)))
         dq_operands.append(bias[:, None, :])
     if rate:
@@ -943,17 +1046,23 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        **more_vmem,
+        **_backward_params(
+            _vmem_estimate(tk, d, block_q, block_k, q.dtype.itemsize,
+                           dv),
+            q.dtype.itemsize, (tk, d, block_q, block_k, dv)),
     )(*dq_operands)
 
+    if t != tk:
+        resident = _rows_resident(t, d, block_q, block_k,
+                                  q.dtype.itemsize, dv)
     tiles, dp_early = _second_tile(
-        None if causal else t // block_q, resident, block_q, block_k,
-        q.dtype.itemsize)
+        None if causal or coarse else t // block_q, resident, block_q,
+        block_k, q.dtype.itemsize)
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, scale=scale, causal=causal,
         block_q=block_q, tiles=tiles, dp_early=dp_early,
         has_bias=has_bias, has_glse=has_glse, rate=rate, window=window,
-        group=group)
+        group=group, coarse=coarse)
 
     # grid (BH, T/bk), or (B*Hkv, T/bk, group): ids[0] is the K/V head
     def head(*ids):     # the query head of a grid step
@@ -991,7 +1100,7 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
     if has_bias:
         out_specs.append(pl.BlockSpec(
             (1, 1, block_k), lambda *ids: (head(*ids), 0, ids[1])))
-        out_shape.append(jax.ShapeDtypeStruct((bh, 1, t), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((bh, 1, tk), jnp.float32))
     scratch = []
     if group > 1:
         from jax.experimental.pallas import tpu as pltpu
@@ -999,20 +1108,23 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
                    pltpu.VMEM((block_k, dv), jnp.float32)]
     res = pl.pallas_call(
         dkv_kernel,
-        grid=(bh, t // block_k) if group == 1
-        else (bh // group, t // block_k, group),
+        grid=(bh, tk // block_k) if group == 1
+        else (bh // group, tk // block_k, group),
         in_specs=dkv_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
-        **more_vmem,
+        **_backward_params(
+            _vmem_estimate(t, d, block_q, block_k, q.dtype.itemsize,
+                           dv),
+            q.dtype.itemsize, (t, d, block_q, block_k, dv)),
     )(*dkv_operands)
     if has_bias:
         dk, dv, dbias_bh = res
         # bias is per (batch, key): sum head lanes
         b = bh // h
-        dbias = dbias_bh.reshape(b, h, t).sum(axis=1)
+        dbias = dbias_bh.reshape(b, h, tk).sum(axis=1)
     else:
         dk, dv = res
         dbias = None
@@ -1032,83 +1144,75 @@ def _dense_reference(q, k, v, causal):
         q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash_lse(q, k, v, bias, seed, h, causal, rate, interpret):
-    """(o, lse): lse is a first-class differentiable output so ring
-    attention can merge per-block flash results (parallel/
-    ring_attention.py ring_flash_attention).  ``interpret`` is the one
+def _flash_primitive(with_lse):
+    """The kernels as one differentiable function of q, k, v and the
+    key bias.  Two of them share this body: ``_flash`` returns o only
+    and has its OWN vjp, so the common path never ships a zeros g_lse
+    operand into the backward kernels; ``_flash_lse`` returns (o,
+    lse) with lse a first-class differentiable output, whose cotangent
+    folds into dS inside the backward kernels, so that per-block
+    results can be merged in log-sum-exp space (ring attention's
+    blocks, EVA's two key sets).  ``interpret`` is the one
     common.dispatch() decision the public entry made; forward and
     backward kernels all read it."""
-    return _flash_fwd(q, k, v, bias, seed, h, causal, DEFAULT_BLOCK_Q,
-                      DEFAULT_BLOCK_K, interpret, rate)
+    def outputs(o, lse):
+        return (o, lse) if with_lse else o
+
+    def primitive(q, k, v, bias, seed, h, causal, rate, interpret,
+                  window=0, coarse=None):
+        return outputs(*_flash_fwd(
+            q, k, v, bias, seed, h, causal, DEFAULT_BLOCK_Q,
+            DEFAULT_BLOCK_K, interpret, rate, window, coarse))
+
+    # the name a jaxpr (and an instruction's metadata) shows
+    primitive.__name__ = '_flash_lse' if with_lse else '_flash'
+    primitive = jax.custom_vjp(primitive,
+                               nondiff_argnums=(5, 6, 7, 8, 9, 10))
+
+    def fwd_rule(q, k, v, bias, seed, h, causal, rate, interpret,
+                 window, coarse):
+        o, lse = _flash_fwd(q, k, v, bias, seed, h, causal,
+                            DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret,
+                            rate, window, coarse)
+        return outputs(o, lse), (q, k, v, bias, seed, o, lse)
+
+    def bwd_rule(h, causal, rate, interpret, window, coarse, res, g):
+        q, k, v, bias, seed, o, lse = res
+        g, g_lse = g if with_lse else (g, None)
+        dq, dk, dv, dbias = _flash_bwd(
+            q, k, v, bias, seed, o, lse, g, g_lse, h, causal,
+            DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret, rate, window,
+            coarse)
+        return dq, dk, dv, (None if bias is None
+                            else dbias.astype(bias.dtype)), None
+
+    primitive.defvjp(fwd_rule, bwd_rule)
+    return primitive
 
 
-def _flash_lse_fwd_rule(q, k, v, bias, seed, h, causal, rate,
-                        interpret):
-    o, lse = _flash_fwd(q, k, v, bias, seed, h, causal,
-                        DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret,
-                        rate)
-    return (o, lse), (q, k, v, bias, seed, o, lse)
-
-
-def _flash_lse_bwd_rule(h, causal, rate, interpret, res, gs):
-    q, k, v, bias, seed, o, lse = res
-    g, g_lse = gs
-    dq, dk, dv, dbias = _flash_bwd(q, k, v, bias, seed, o, lse, g,
-                                   g_lse, h, causal, DEFAULT_BLOCK_Q,
-                                   DEFAULT_BLOCK_K, interpret, rate)
-    return dq, dk, dv, (None if bias is None
-                        else dbias.astype(bias.dtype)), None
-
-
-_flash_lse.defvjp(_flash_lse_fwd_rule, _flash_lse_bwd_rule)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _flash(q, k, v, bias, seed, h, causal, rate, interpret, window=0):
-    # o-only primitive with its OWN vjp so the common (non-ring) path
-    # never ships a zeros g_lse operand into the backward kernels
-    o, _ = _flash_fwd(q, k, v, bias, seed, h, causal, DEFAULT_BLOCK_Q,
-                      DEFAULT_BLOCK_K, interpret, rate, window)
-    return o
-
-
-def _flash_fwd_rule(q, k, v, bias, seed, h, causal, rate, interpret,
-                    window):
-    o, lse = _flash_fwd(q, k, v, bias, seed, h, causal,
-                        DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret,
-                        rate, window)
-    return o, (q, k, v, bias, seed, o, lse)
-
-
-def _flash_bwd_rule(h, causal, rate, interpret, window, res, g):
-    q, k, v, bias, seed, o, lse = res
-    dq, dk, dv, dbias = _flash_bwd(q, k, v, bias, seed, o, lse, g,
-                                   None, h, causal, DEFAULT_BLOCK_Q,
-                                   DEFAULT_BLOCK_K, interpret, rate,
-                                   window)
-    return dq, dk, dv, (None if bias is None
-                        else dbias.astype(bias.dtype)), None
-
-
-_flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+_flash, _flash_lse = _flash_primitive(False), _flash_primitive(True)
 
 
 def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
                 dropout_seed=None, dropout_offsets=None,
-                dropout_g_offset=0, with_lse=False, window=0):
+                dropout_g_offset=0, with_lse=False, window=0,
+                coarse=None):
     """Fused-by-XLA dense chain on [B, T, H, D] (bf16 dots, f32
     softmax) — the measured winner below FLASH_MIN_SEQ, where the
     whole chain fits VMEM outright.  Differentiable via XLA autodiff.
     Dropout draws the SAME counter-hash mask as the Pallas kernels, so
     the two dispatch arms are bit-identical stochastic functions of
     (seed, element position).  ``with_lse`` also returns the per-row
-    log-sum-exp [B, H, T] of the undropped scores (the
-    flash_attention_with_lse contract).  K/V of fewer heads than q
-    are repeated over their group here (the kernels read them through
-    their index maps instead); ``window`` bands the causal mask.  v
-    may be of another width than q and k: the scale is q's."""
+    log-sum-exp [B, H, T] of the undropped scores (flash_attention's
+    ``with_lse`` contract).  K/V of fewer heads than q are repeated
+    over their group here (the kernels read them through their index
+    maps instead); ``window`` bands the causal mask, ``coarse`` is the
+    third mask (_coarse_visible) over keys of another length than the
+    queries, and this arm, unlike the kernels, builds its [T, Tk]
+    scores.  v may be of another width than q and k: the scale is
+    q's."""
     b, t, h, d = q.shape
+    tk = k.shape[1]
     if k.shape[2] != h:
         k, v = (jnp.repeat(x, h // x.shape[2], axis=2) for x in (k, v))
     s = jnp.einsum('bthd,bshd->bhts', q, k,
@@ -1121,36 +1225,72 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
         if window:
             mask = mask & ~jnp.tril(jnp.ones((t, t), bool), -window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
+    lse = None
+    if coarse:
+        s = jnp.where(_coarse_visible(
+            jnp.arange(t)[:, None], jnp.arange(tk)[None, :], coarse),
+            s, -jnp.inf)
+        # a query of the first window sees no key: p = 0 and lse =
+        # -inf there, not softmax's 0 / 0 (the row's max moves
+        # neither, so it carries no gradient)
+        m = jax.lax.stop_gradient(jnp.max(s, axis=-1, keepdims=True))
+        e = jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0))
+        total = jnp.sum(e, axis=-1, keepdims=True)
+        p = e / jnp.maximum(total, 1e-20)
+        lse = jnp.where(total > 0, m + jnp.log(jnp.maximum(total, 1e-20)),
+                        -jnp.inf)[..., 0]
+    else:
+        p = jax.nn.softmax(s, axis=-1)
     if dropout_rate:
         # SAME hash as the kernels (per-element head-index array here,
         # the grid program_id there)
         qo, ko = dropout_offsets if dropout_offsets is not None \
             else (0, 0)
-        keep = dropout_keep_dense(dropout_seed, b, h, t, t, qo, ko,
+        keep = dropout_keep_dense(dropout_seed, b, h, t, tk, qo, ko,
                                   dropout_g_offset, dropout_rate)
         p = jnp.where(keep, p * (1.0 / (1.0 - dropout_rate)), 0.0)
     p = p.astype(q.dtype)
     o = jnp.einsum('bhts,bshd->bthd', p, v,
                    precision=_precision(q.dtype))
     if with_lse:
-        return o, jax.nn.logsumexp(s, axis=-1)
+        return o, jax.nn.logsumexp(s, axis=-1) if lse is None else lse
     return o
 
 
-def _check_mask_and_heads(q, k, v, causal, window):
+def _check_mask_and_heads(q, k, v, causal, window, coarse=None):
+    """The argument checks every entry shares -> (window, coarse) as
+    the kernels' static arguments."""
     h, hkv = q.shape[2], k.shape[2]
     if k.shape[:3] != v.shape[:3] or k.shape[3] != q.shape[3] or \
-            hkv < 1 or h % hkv:
+            k.shape[0] != q.shape[0] or hkv < 1 or h % hkv:
         raise ValueError(
-            'attention: K and V must have one batch, length and head '
-            'count, which divides Q\'s, and K the width of Q (V may '
-            'have its own); got Q %r, K %r, V %r'
+            'attention: K and V must have Q\'s batch, one length and '
+            'head count, which divides Q\'s, and K the width of Q (V '
+            'may have its own); got Q %r, K %r, V %r'
             % (q.shape, k.shape, v.shape))
     if window and not causal:
         raise ValueError('attention: a window (%r) bands the causal '
                          'mask; causal is False' % (window,))
-    return int(window or 0)
+    t, tk = q.shape[1], k.shape[1]
+    if not coarse:
+        if tk != t:
+            raise ValueError(
+                'attention: %d keys for %d queries; keys of another '
+                'length than the queries need the coarse mask' % (tk, t))
+        return int(window or 0), None
+    span, chunk = (int(n) for n in coarse)
+    if causal or window:
+        raise ValueError('attention: the coarse mask is a mask of its '
+                         'own; causal and window must be off')
+    if chunk < 1 or span < chunk or span % chunk:
+        raise ValueError(
+            'attention: coarse=(window, chunk) wants a window that is '
+            'a multiple of the chunk; got %r' % (tuple(coarse),))
+    if tk * chunk < t:
+        raise ValueError(
+            'attention: %d summaries of %d-key chunks do not cover %d '
+            'queries' % (tk, chunk, t))
+    return 0, (span, span // chunk)
 
 
 def _checks(t, min_seq=None):
@@ -1162,16 +1302,37 @@ def _checks(t, min_seq=None):
 def flash_attention(q, k, v, causal=False, key_bias=None,
                     min_seq=None, dropout_rate=0.0, dropout_seed=None,
                     dropout_offsets=None, dropout_g_offset=0,
-                    auto_partitioned=False, window=0):
+                    auto_partitioned=False, window=0, with_lse=False,
+                    coarse=None):
     """q: [B, T, H, D]; k: [B, T, Hkv, D], v: [B, T, Hkv, Dv] with Hkv
     a divisor of H (grouped K/V: query head i attends K/V head
     i // (H / Hkv)) and Dv = D unless the values are narrower or wider
     than the keys (scores are scaled by 1/sqrt(D); nothing is padded);
     key_bias: optional [B, T] additive score bias (e.g. padding mask
-    as 0 / -10000) -> [B, T, H, Dv].  ``window`` > 0 (with ``causal``)
-    bands the mask: query i sees the keys j with 0 <= i - j < window,
-    and the kernels skip the blocks wholly outside the band as they
-    skip those above the diagonal.
+    as 0 / -10000) -> [B, T, H, Dv].
+
+    The three masks, and none by default (every query sees every key):
+
+    - ``causal``: query i sees the keys j <= i; the kernels skip the
+      blocks above the diagonal;
+    - ``causal`` with ``window`` > 0, a band: the keys j with
+      0 <= i - j < window; the blocks wholly outside the band are
+      skipped like those above the diagonal;
+    - ``coarse`` = (window, chunk), without ``causal``: k and v are
+      Tk >= T / chunk SUMMARIES, one a chunk of ``chunk`` positions,
+      and query i sees the summaries of every window of ``window``
+      positions before its own, keys 0 .. (i // window) * (window /
+      chunk) - 1.  A query of the first window sees none: its output
+      is 0 and its lse -inf.  The kernels walk only the key blocks a
+      query block sees; no [T, Tk] tensor reaches HBM.
+
+    ``with_lse`` also returns the per-row log-sum-exp [B, H, T]
+    (float32), the merge state for blockwise composition:
+    ring attention's blocks, an exact and a coarse call over two key
+    sets.  Both outputs are differentiable (the lse cotangent folds
+    into dS inside the backward kernels).  lse is computed from the
+    UNDROPPED probabilities (dropout scales only the V-weighting), so
+    merges stay exact under dropout.
 
     dropout_rate > 0 applies dropout to the attention probabilities
     INSIDE the kernels (reference default: dropout around softmax,
@@ -1190,9 +1351,10 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     with no shard_map around the call; see common.dispatch().  An op
     lowering under that runner calls mesh_flash_attention(), which
     opens one).  Pass min_seq=0 to drop the floor (benchmark
-    sweeps)."""
+    sweeps, a ring's blocks)."""
     b, t, h, d = q.shape
-    window = _check_mask_and_heads(q, k, v, causal, window)
+    window, coarse = _check_mask_and_heads(q, k, v, causal, window,
+                                           coarse)
     rate = float(dropout_rate or 0.0)
     if rate and dropout_seed is None:
         raise ValueError('dropout_rate > 0 needs a dropout_seed')
@@ -1202,23 +1364,36 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     if not fused:
         return _dense_path(q, k, v, causal, key_bias, rate,
                            dropout_seed, dropout_offsets,
-                           dropout_g_offset, window=window)
+                           dropout_g_offset, with_lse=with_lse,
+                           window=window, coarse=coarse)
 
     def to_bh(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(-1, t, x.shape[3])
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(
+            -1, x.shape[1], x.shape[3])
+
+    def to_bthd(x):
+        return jnp.transpose(x.reshape(b, h, t, x.shape[2]), (0, 2, 1, 3))
 
     if key_bias is not None:
         key_bias = key_bias.astype(jnp.float32)
     seed = _pack_seed(dropout_seed, dropout_offsets,
                       dropout_g_offset) if rate else None
-    out = _flash(to_bh(q), to_bh(k), to_bh(v), key_bias, seed, h,
-                 causal, rate, interpret, window)
-    return jnp.transpose(out.reshape(b, h, t, v.shape[3]), (0, 2, 1, 3))
+    if not with_lse:
+        return to_bthd(_flash(to_bh(q), to_bh(k), to_bh(v), key_bias,
+                              seed, h, causal, rate, interpret, window,
+                              coarse))
+    o, lse = _flash_lse(to_bh(q), to_bh(k), to_bh(v), key_bias, seed, h,
+                        causal, rate, interpret, window, coarse)
+    lse = lse.reshape(b, h, t)
+    if coarse:      # the rows that saw no key: the dense arm's -inf
+        lse = jnp.where(jnp.arange(t) >= coarse[0], lse, -jnp.inf)
+    return to_bthd(o), lse
 
 
 def mesh_flash_attention(q, k, v, auto_partitioned, scope, causal=False,
                          key_bias=None, dropout_rate=0.0,
-                         dropout_seed=None, window=0):
+                         dropout_seed=None, window=0, with_lse=False,
+                         coarse=None):
     """flash_attention() as an op lowering calls it, with
     ``ctx.auto_partitioned``: the one place a flash call is wrapped
     for the GSPMD runner (fused_multihead_attention, ring_attention's
@@ -1260,7 +1435,8 @@ def mesh_flash_attention(q, k, v, auto_partitioned, scope, causal=False,
         return flash_attention(
             q, k, v, causal=causal, key_bias=key_bias,
             dropout_rate=dropout_rate, dropout_seed=dropout_seed,
-            auto_partitioned=auto_partitioned, window=window)
+            auto_partitioned=auto_partitioned, window=window,
+            with_lse=with_lse, coarse=coarse)
     from jax.sharding import PartitionSpec as P
     from ...compat import shard_map
     from ...fluid import monitor, trace
@@ -1270,12 +1446,14 @@ def mesh_flash_attention(q, k, v, auto_partitioned, scope, causal=False,
                  if mesh.shape[a] > 1)
     shards = math.prod(mesh.shape[a] for a in axes)
     if not axes or q.shape[0] % shards:
-        window = _check_mask_and_heads(q, k, v, causal, window)
+        window, coarse = _check_mask_and_heads(q, k, v, causal, window,
+                                               coarse)
         _common.record_dispatch('flash_attention', False,
                                 'batch_not_split')
         return _dense_path(q, k, v, causal, key_bias,
                            float(dropout_rate or 0.0), dropout_seed,
-                           window=window)
+                           with_lse=with_lse, window=window,
+                           coarse=coarse)
     split = P(axes if len(axes) > 1 else axes[0])
     operands = [(x, spec) for x, spec in (
         (q, split), (k, split), (v, split), (key_bias, split),
@@ -1290,43 +1468,13 @@ def mesh_flash_attention(q, k, v, auto_partitioned, scope, causal=False,
                 q_, k_, v_, causal=causal,
                 key_bias=rest.pop() if rest else None,
                 dropout_rate=dropout_rate, dropout_seed=seed_,
-                dropout_g_offset=first, window=window)
+                dropout_g_offset=first, window=window,
+                with_lse=with_lse, coarse=coarse)
 
     with trace.span('pallas/flash_attention/shard_map',
                     axes=','.join(axes), shards=shards):
         monitor.add('pallas/flash_attention/dispatch_sharded', 1)
         return shard_map(
             local, mesh=mesh, in_specs=tuple(spec for _, spec in operands),
-            out_specs=split)(*(x for x, _ in operands))
-
-
-def flash_attention_with_lse(q, k, v, causal=False, key_bias=None,
-                             dropout_rate=0.0, dropout_seed=None,
-                             dropout_offsets=None, dropout_g_offset=0):
-    """Like flash_attention but also returns the per-row log-sum-exp
-    [B, H, T] — the merge state for blockwise/ring composition.  Both
-    outputs are differentiable (the lse cotangent folds into dS inside
-    the backward kernels).  lse is computed from the UNDROPPED probs
-    (dropout scales only the V-weighting), so ring merges stay exact
-    under dropout."""
-    b, t, h, d = q.shape
-    rate = float(dropout_rate or 0.0)
-    if rate and dropout_seed is None:
-        raise ValueError('dropout_rate > 0 needs a dropout_seed')
-    fused, interpret = _common.dispatch('flash_attention', True)
-    if not fused:
-        return _dense_path(q, k, v, causal, key_bias, rate,
-                           dropout_seed, dropout_offsets,
-                           dropout_g_offset, with_lse=True)
-
-    def to_bh(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
-
-    if key_bias is not None:
-        key_bias = key_bias.astype(jnp.float32)
-    seed = _pack_seed(dropout_seed, dropout_offsets,
-                      dropout_g_offset) if rate else None
-    o, lse = _flash_lse(to_bh(q), to_bh(k), to_bh(v), key_bias, seed,
-                        h, causal, rate, interpret)
-    o = jnp.transpose(o.reshape(b, h, t, d), (0, 2, 1, 3))
-    return o, lse.reshape(b, h, t)
+            out_specs=(split, split) if with_lse else split)(
+                *(x for x, _ in operands))

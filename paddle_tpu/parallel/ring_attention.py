@@ -113,11 +113,11 @@ def ring_flash_attention_inner(q, k, v, axis_name, causal=False,
         o' = o * exp(L - L') + o_blk * exp(lse_blk - L')
 
     Differentiable end-to-end: the flash kernel exposes lse as a real
-    output (ops/pallas/flash_attention.py _flash_lse) whose cotangent
+    output (ops/pallas/flash_attention.py, ``with_lse``) whose cotangent
     folds into dS inside the backward kernels, and jax.vjp reverses the
     ppermute ring.  Call INSIDE shard_map with q,k,v sequence-sharded
     [B, T_loc, H, D]."""
-    from ..ops.pallas.flash_attention import flash_attention_with_lse
+    from ..ops.pallas.flash_attention import flash_attention
     n = jax.lax.psum(1, axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, tq, h, d = q.shape
@@ -134,13 +134,14 @@ def ring_flash_attention_inner(q, k, v, axis_name, causal=False,
                 'dropout_offsets': (idx * tq, k_off),
                 'dropout_g_offset': dropout_g_offset}
 
+    # a ring's blocks are flash's at any length: no floor
     def full_block(kk, vv, k_off):
-        return flash_attention_with_lse(q, kk, vv, causal=False,
-                                        **_drop_kw(k_off))
+        return flash_attention(q, kk, vv, causal=False, min_seq=0,
+                               with_lse=True, **_drop_kw(k_off))
 
     def diag_block(kk, vv, k_off):
-        return flash_attention_with_lse(q, kk, vv, causal=True,
-                                        **_drop_kw(k_off))
+        return flash_attention(q, kk, vv, causal=True, min_seq=0,
+                               with_lse=True, **_drop_kw(k_off))
 
     def skip_block(kk, vv, k_off):
         return (jnp.zeros((b, tq, h, d), q.dtype),

@@ -274,6 +274,79 @@ def test_grouped_causal_flash_d64_at_the_lfm2_cell_shape(one_chip, as_on_tpu,
     assert common.scoped_vmem(2048, d, 512, 1024, item) is None
 
 
+@pytest.mark.parametrize('dtype,t', [
+    (jnp.bfloat16, 4096), (jnp.float32, 4096), (jnp.bfloat16, 32768)],
+    ids=['bf16-cell', 'f32-cell', 'bf16-32768'])
+def test_both_eva_streams_at_the_evabyte_shapes(one_chip, as_on_tpu,
+                                                dtype, t):
+    """evabyte_6b5_s4096: b1 t4096, 32 heads of 128, windows of 2048
+    over chunks of 16, through the op (so the calls carry its scopes):
+    the local stream is causal flash at 2048 keys with the windows
+    folded into the batch, WITH its log-sum-exp and a cotangent on it;
+    the remote stream runs the kernels at Tk = T / 16 != Tq under the
+    coarse mask, named ``remote``.  bfloat16 is the timed step;
+    float32 is ``chip_smoke.py --phase evabyte`` and the cell's
+    reference check, whose backward calls ask Mosaic for more scoped
+    VMEM than its default (flash_attention._backward_params: refused
+    at 17.99 of 16 MB without).  T = 32768 is the published context:
+    16 windows, 2048 summaries, dq + dkv for both streams, and no
+    [T, T / 16] tensor in the program."""
+    import re
+    from paddle_tpu.ops import fused_ops
+    b, h, d, window, chunk = 1, 32, 128, 2048, 16
+    item = jnp.dtype(dtype).itemsize
+    # the blocks: a coarse call's key block is narrowed towards a
+    # window's 128 summaries, but not under a quarter of the keys; at
+    # 4096 the backward is the one-pass kernel over rows of two
+    # lengths, at 32768 its q and dO rows are too long
+    block_k = 128 if t == 4096 else 512
+    assert flash_attention._window_blocks(
+        common.block_sizes(t, 512, 1024, d, item, None, t // chunk), 0,
+        (window, window // chunk), t // chunk) == (512, block_k)
+    assert (flash_attention._fused_bwd_vmem(t, d, 512, block_k, item, 1,
+                                            d, t // chunk) <=
+            flash_attention.VMEM_BUDGET_BYTES) == (t == 4096)
+
+    def run(ins, attrs):
+        return fused_ops.fused_multihead_attention(
+            registry.LowerCtx(0), {k: [x] for k, x in ins.items()},
+            dict(attrs, with_lse=True))
+
+    def step(q, k, v, ks, vs):
+        def loss(q, k, v, ks, vs):
+            def fold(x):
+                return x.reshape(-1, window, h, d)
+            with jax.named_scope('fused_multihead_attention'):
+                local = run({'Q': fold(q), 'K': fold(k), 'V': fold(v)},
+                            {'causal': True})
+                remote = run({'Q': q, 'K': ks, 'V': vs},
+                             {'coarse_window': window,
+                              'coarse_chunk': chunk})
+            lse = remote['Lse'][0]
+            return sum(jnp.sum(x.astype(jnp.float32)) for x in (
+                local['Out'][0], local['Lse'][0], remote['Out'][0],
+                jnp.where(jnp.isfinite(lse), lse, 0.0)))
+        return jax.grad(loss, (0, 1, 2, 3, 4))(q, k, v, ks, vs)
+
+    full, summaries = _spec((b, t, h, d), dtype), \
+        _spec((b, t // chunk, h, d), dtype)
+    text = _compiled(step, one_chip, full, full, full, summaries,
+                     summaries).as_text()
+    _compiled_on_chip('flash_attention')
+    names = [re.sub(r'\.\d+$', '', n) for n in re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)]
+    remote = [n for n in names if 'remote' in n]
+    local = [n for n in names if 'remote' not in n]
+    assert all('fused_multihead_attention' in n for n in local), names
+    # forward + one-pass backward, or forward + dq + dkv
+    assert len(remote) == (2 if t == 4096 else 3), names
+    assert len(local) in (2, 3), names
+    assert not re.search(r'\[(\d+,)*%d,%d\]' % (t, t // chunk), text)
+    assert not re.search(r'\[(\d+,)*%d,%d\]' % (window, window), text)
+    assert monitor.gauge_value('eva/remote_pairs') == sum(
+        window * w * (window // chunk) for w in range(t // window))
+
+
 @pytest.mark.parametrize('dtype,b,t,h,d,fused', [
     # what the compiler asks for moves with the grid, so the cells'
     # own: bert_base_s2048's fused backward, two [512, 512] tiles a trip
