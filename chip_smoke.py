@@ -203,6 +203,23 @@ def check_dispatch(kernels):
     check(not off, 'no pallas/*/fallback/off_tpu counted %s' % (off or ''))
 
 
+def check_dropout_draws(drawn, ops, elements):
+    """The dropout op draws from the flash kernels' counter hash
+    (ops/keep_hash.py): `dropout/counter_draws` rose by one a lowering
+    of each of the program's `ops` dropout ops, a whole number of
+    times (once a trace of the one-chip runner's whole-program
+    gradient), and `dropout/elements` is what the last traced program
+    draws a step."""
+    from paddle_tpu.fluid import monitor
+    seen = monitor.gauge_value('dropout/elements')
+    check(drawn > 0 and drawn % ops == 0 and seen % (ops * elements) == 0
+          and seen > 0,
+          'dropout/counter_draws rose by %d = %d x %d dropout ops; '
+          'dropout/elements %d = %d x %d ops x %d elements'
+          % (drawn, drawn // ops, ops, seen, seen // (ops * elements),
+             ops, elements))
+
+
 def phase_train_eval_roundtrip(cfg, batch, seq, steps):
     """Train on one fixed batch, then flows 2 and 3 of the verify
     skill: for_test clone evaluated twice is the same loss, and
@@ -210,10 +227,13 @@ def phase_train_eval_roundtrip(cfg, batch, seq, steps):
     it."""
     import jax
     import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
     main, startup, test, loss = build_bert(cfg, seq)
     feed = {k: jax.device_put(v)
             for k, v in host_batch(cfg, batch, seq).items()}
     tag = 'bert b%d s%d L%d' % (batch, seq, cfg.layers)
+    # taken after the build, which infers shapes through the lowerings
+    drawn = monitor.counter_value('dropout/counter_draws')
     exe = fluid.Executor(fluid.XLAPlace(0))
     ckpt = tempfile.mkdtemp(prefix='chip_smoke_ckpt_')
     try:
@@ -223,6 +243,9 @@ def phase_train_eval_roundtrip(cfg, batch, seq, steps):
                                                     loss, steps)
             check_trained(tag, losses, secs, compiled_at)
             check_dispatch(KERNELS)
+            check_dropout_draws(
+                monitor.counter_value('dropout/counter_draws') - drawn,
+                2 * cfg.layers + 1, batch * seq * cfg.hidden)
             e1 = _scalar(exe.run(test, feed=feed, fetch_list=[loss]))
             e2 = _scalar(exe.run(test, feed=feed, fetch_list=[loss]))
             check(np.isfinite(e1) and e1 == e2,
@@ -248,6 +271,58 @@ def phase_attn_dropout(cfg, batch, seq):
            secs[0], secs[1] * 1e3))
     check(all(np.isfinite(losses)),
           'attn_dropout=%.1f: two steps, losses finite' % cfg.attn_dropout)
+
+
+def phase_dropout_bits(shape=(16, 512, 768), rate=0.1, steps=2):
+    """The dropout op through the executor on the chip, two steps:
+    `Mask` is the counter hash of ops/keep_hash.py computed here in
+    numpy (the chip's integer multiplies, shifts and the signed
+    compare agree with it bit for bit), the gradient is the same
+    mask times the scale, and each step draws anew."""
+    import paddle_tpu.fluid as fluid
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data('x', shape=list(shape[1:]), dtype='float32')
+        x.stop_gradient = False
+        out = fluid.layers.dropout(
+            x, rate, dropout_implementation='upscale_in_train')
+        loss = fluid.layers.reduce_sum(out)
+        fluid.backward.append_backward(loss)
+    op = [o for o in main.global_block().ops if o.type == 'dropout'][0]
+    op_seed = op.attrs['__op_seed__'] * 2654435761 % (1 << 32)
+    rows = np.arange(int(np.prod(shape[:-1])), dtype=np.uint32)[:, None]
+    cols = np.arange(shape[-1], dtype=np.uint32)[None, :]
+    u = np.uint32
+
+    def want(step):
+        seed = u(op_seed) ^ u(step * 0x9E3779B9 % (1 << 32))
+        h = (rows * u(0x9E3779B1)) ^ seed ^ (cols * u(0x85EBCA77))
+        h ^= h >> u(16)
+        h *= u(0x7FEB352D)
+        h ^= h >> u(15)
+        h *= u(0x846CA68B)
+        h ^= h >> u(16)
+        return ((h >> u(8)) < u(round((1 - rate) * (1 << 24)))
+                ).reshape(shape)
+
+    feed = {'x': np.ones(shape, 'float32')}
+    masks = []
+    with fluid.scope_guard(fluid.Scope()), np.errstate(over='ignore'):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        for _ in range(steps):
+            got, dx = exe.run(main, feed=feed, fetch_list=[
+                out, main._grad_name_map[x.name]])
+            masks.append(np.asarray(got) != 0)
+            hits = [s for s in range(4)
+                    if np.array_equal(masks[-1], want(s))]
+            check(len(hits) == 1 and
+                  np.array_equal(np.asarray(dx), np.asarray(got)),
+                  'dropout op on the chip, run %d: Mask is the numpy '
+                  'counter hash of step %s, keep share %.4f, the '
+                  'gradient is Out' % (len(masks), hits, masks[-1].mean()))
+    check((masks[0] != masks[1]).mean() > rate,
+          'dropout op: two steps draw two masks')
 
 
 def phase_flash_vs_dense(b=2, t=1024, h=12, d=64, rate=0.1):
@@ -1916,6 +1991,7 @@ def main():
             phase_attn_dropout(
                 models.bert.BertConfig(max_pos=seq, attn_dropout=0.1),
                 batch, seq)
+            phase_dropout_bits()
             phase_flash_vs_dense()
             phase_lenet()
             say('peak HBM %.2f GB of %.2f GB'

@@ -937,6 +937,7 @@ def _make_segment_fn(segment, prefer_test=False, whole_program_grad=False):
         CF_FWD = ('while', 'conditional_block')
 
         def fn(step, state, data):
+            registry.begin_trace()
             env0 = {}
             env0.update(data)
             env0.update(state)
@@ -1005,6 +1006,7 @@ def _make_segment_fn(segment, prefer_test=False, whole_program_grad=False):
         return fn
 
     def fn(step, state, data):
+        registry.begin_trace()
         env = {}
         env.update(data)
         env.update(state)

@@ -350,7 +350,7 @@ def statusz():
     try:
         from ..ops.pallas import common as pallas_common
         rep = pallas_common.report()
-        if rep.get('kernels'):
+        if rep:
             pallas_section = rep
     except Exception:
         pass

@@ -13,6 +13,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from . import keep_hash
 from .registry import register
 
 
@@ -615,7 +616,15 @@ def dropout(ctx, ins, attrs):
         if impl == 'upscale_in_train':
             return {'Out': [x], 'Mask': [jnp.ones_like(x)]}
         return {'Out': [x * (1.0 - p)], 'Mask': [jnp.ones_like(x)]}
-    keep = jax.random.bernoulli(ctx.rng(), 1.0 - p, x.shape)
+    # the counter hash the flash kernels draw from, keyed by (op
+    # seed, step) over the element's position: no generator state, so
+    # the vjp's replay (and the grad op's, under the parallel runner)
+    # draws the same bits and XLA merges the two draws
+    from ..fluid import monitor
+    monitor.add('dropout/counter_draws', 1)
+    monitor.set_gauge('dropout/elements',
+                      monitor.gauge_value('dropout/elements') + x.size)
+    keep = keep_hash.keep_nd(ctx.draw_seed(), x.shape, p)
     mask = keep.astype(x.dtype)
     if impl == 'upscale_in_train':
         out = jnp.where(keep, x / max(1.0 - p, 1e-8), jnp.zeros_like(x))

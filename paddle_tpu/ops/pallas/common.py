@@ -281,14 +281,19 @@ def dispatch(kernel, enabled, checks=(), force=None,
 
 def report():
     """/statusz section: per-kernel registration + last decision +
-    dispatch/fallback counter values.  Empty dict when no kernel has
-    dispatched yet (health.py hides the section)."""
+    dispatch/fallback counter values, and under 'dropout' the dropout
+    op's draws from the kernels' counter hash (ops/keep_hash.py):
+    lowerings counted and the elements the last traced program draws
+    a step.  Empty dict when nothing has dispatched or drawn yet
+    (health.py hides the section)."""
     try:
         from ...fluid import monitor
         counter = monitor.counter_value
+        gauge = monitor.gauge_value
     except Exception:
         def counter(name):
             return 0
+        gauge = counter
     out = {}
     for name, info in sorted(KERNELS.items()):
         fused = counter('pallas/%s/dispatch_fused' % name) or 0
@@ -316,4 +321,9 @@ def report():
         if fb:
             ent['fallbacks'] = fb
         out[name] = ent
-    return {'kernels': out} if out else {}
+    rep = {'kernels': out} if out else {}
+    draws = counter('dropout/counter_draws') or 0
+    if draws:
+        rep['dropout'] = {'counter_draws': draws,
+                          'elements': gauge('dropout/elements') or 0}
+    return rep

@@ -96,47 +96,11 @@ _common.register_kernel(
     op_types=('matmul', 'scale', 'softmax', 'dropout'))
 
 
-def _keep_rows(seed, g, qpos):
-    """The part of the keep hash's pre-mix that depends on the head
-    and the query position only (uint32, shaped like ``g`` x
-    ``qpos``)."""
-    return (qpos.astype(jnp.uint32) * jnp.uint32(0x9E3779B1)) ^ \
-        (jnp.asarray(g, jnp.uint32) * jnp.uint32(0xC2B2AE3D)) ^ \
-        jnp.asarray(seed, jnp.uint32)
-
-
-def _keep_cols(kpos):
-    """The part that depends on the key position only."""
-    return kpos.astype(jnp.uint32) * jnp.uint32(0x85EBCA77)
-
-
-def _dropout_keep(rows, cols, keep_threshold):
-    """Deterministic per-(head, q, k) keep mask from a counter hash
-    (murmur3-finalizer mix): the same element draws the same bit in the
-    forward kernel, both backward kernels, the dense path, and any
-    replay (per-op grad or whole-program vjp) — the (op_seed, step)
-    keying discipline the dropout op uses, in-kernel.  Integer ops
-    only, so Mosaic and interpret mode agree bit-for-bit.
-
-    The pre-mix is (qpos * A) ^ (kpos * B) ^ (g * C) ^ seed: a xor of
-    a term of the row (_keep_rows) and a term of the column
-    (_keep_cols), so callers build a [rows, 1] and a [1, cols] vector
-    and ONE broadcast xor makes the tile; only the finalizer below is
-    per-element work."""
-    h = rows ^ cols
-    h = h ^ (h >> jnp.uint32(16))
-    h = h * jnp.uint32(0x7FEB352D)
-    h = h ^ (h >> jnp.uint32(15))
-    h = h * jnp.uint32(0x846CA68B)
-    h = h ^ (h >> jnp.uint32(16))
-    # 24 bits against the threshold, compared as signed: the same bit
-    return (h >> jnp.uint32(8)).astype(jnp.int32) < \
-        jnp.int32(keep_threshold)
-
-
-def _keep_threshold(rate):
-    """24-bit integer threshold for keep-probability (1 - rate)."""
-    return int(round((1.0 - float(rate)) * (1 << 24)))
+# the draw itself (row term, column term, finalizer, threshold) lives
+# in ops/keep_hash.py, shared with the dropout op; the kernels below
+# trace the same functions under their original names
+from ..keep_hash import (_keep_rows, _keep_cols, _dropout_keep,  # noqa: E402,F401
+                         _keep_threshold)
 
 
 def _seed_off(seed_ref, idx):

@@ -50,16 +50,31 @@ class LowerCtx(object):
         key = jax.random.PRNGKey(self.op_seed + 7919 * salt)
         return jax.random.fold_in(key, self.step)
 
-    def dropout_seed(self, attrs):
-        """uint32 counter-hash seed for in-kernel dropout, or None in
-        eval mode (prefer_test lowering or a clone-stamped is_test
-        attr).  Shared by every stochastic attention lowering so the
+    def draw_seed(self):
+        """uint32 counter-hash seed of this (op, step): what every
+        dropout of the tree keys ops/keep_hash.py with, so the
         (op_seed, step) keying never diverges between them."""
-        if self.prefer_test or attrs.get("is_test"):
-            return None
         return (jnp.uint32(self.op_seed * 2654435761 % (1 << 32)) ^
                 jnp.asarray(self.step, jnp.uint32) *
                 jnp.uint32(0x9E3779B9))
+
+    def dropout_seed(self, attrs):
+        """draw_seed() for in-kernel dropout, or None in eval mode
+        (prefer_test lowering or a clone-stamped is_test attr): the
+        stochastic attention lowerings then run at rate 0.  The
+        dropout op tests its own is_test and calls draw_seed()."""
+        if self.prefer_test or attrs.get("is_test"):
+            return None
+        return self.draw_seed()
+
+
+def begin_trace():
+    """The executor calls this as a trace of a segment begins:
+    `dropout/elements` is a sum the dropout lowerings add to over ONE
+    traced program, so what shape inference lowered at build time, or
+    an earlier program, is not in the reading."""
+    from ..fluid import monitor
+    monitor.set_gauge('dropout/elements', 0.0)
 
 
 class OpDef(object):

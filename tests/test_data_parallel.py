@@ -82,6 +82,48 @@ def test_gspmd_data_parallel_loss_parity():
     assert par[-1] < par[0]
 
 
+@pytest.mark.parametrize('shape', [[16, 24], [6, 40], [5]])
+def test_dropout_mask_of_a_sharded_batch_is_the_one_device_mask(shape):
+    """The dropout op's draw is a hash of the element's GLOBAL
+    position (iotas, which GSPMD shards with the tensor): the mask of
+    a batch split over `dp` is the one-device mask of the same batch,
+    and the grad op, which the parallel runner lowers apart from the
+    op, replays the same bits."""
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = 11
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data('x', shape=shape, dtype='float32')
+        x.stop_gradient = False
+        out = fluid.layers.dropout(
+            x, 0.3, dropout_implementation='upscale_in_train')
+        loss = fluid.layers.reduce_sum(out)
+        fluid.backward.append_backward(loss)
+    dx = main._grad_name_map[x.name]
+    feed = {'x': np.ones([16] + shape, 'float32')}
+
+    def masks(target):
+        kept = []
+        with fluid.scope_guard(fluid.Scope()):
+            exe = fluid.Executor(fluid.XLAPlace(0))
+            exe.run(startup)
+            for _ in range(2):
+                o, g = exe.run(target, feed=feed, fetch_list=[out, dx])
+                np.testing.assert_array_equal(np.asarray(g),
+                                              np.asarray(o))
+                kept.append(np.asarray(o) != 0)
+        return kept
+
+    single = masks(main)
+    sharded = masks(fluid.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name))
+    assert single[0].shape == tuple([16] + shape)
+    assert abs(single[0].mean() - 0.7) < \
+        4 * np.sqrt(0.21 / single[0].size)
+    assert (single[0] != single[1]).any()
+    for a, b in zip(single, sharded):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_fleet_collective_loss_parity():
     from paddle_tpu.fluid.incubate.fleet.collective import fleet, \
         DistributedStrategy

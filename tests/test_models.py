@@ -34,6 +34,57 @@ def test_bert_tiny_trains():
     assert losses[-1] < losses[0], losses
 
 
+@pytest.mark.parametrize('attn_dropout,per_layer', [(0.0, 2), (0.1, 3)])
+def test_bert_train_step_draws_dropout_from_the_counter_hash(
+        attn_dropout, per_layer):
+    """The lowered train step of a two-layer BERT holds no threefry
+    generator: every `dropout` op (two a layer and the embedding's,
+    one more a layer on the attention probabilities of a short
+    sequence) draws from ops/keep_hash.py, one
+    `dropout/counter_draws` a lowering."""
+    import jax
+    from paddle_tpu.fluid import monitor
+    layers, seq = 2, 32
+    cfg = models.bert.BertConfig(
+        vocab_size=128, hidden=32, layers=layers, heads=2,
+        intermediate=64, max_pos=seq, dropout=0.1,
+        attn_dropout=attn_dropout)
+    ops = per_layer * layers + 1
+    batch = models.bert.synthetic_batch(cfg, 4, seq,
+                                        np.random.RandomState(0))
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        _, _, loss = models.bert.build_pretrain(cfg, seq)
+        fluid.optimizer.Adam(1e-3).minimize(loss)
+    assert sum(op.type == 'dropout'
+               for op in main.global_block().ops) == ops
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        step = exe.compile(main, feed_names=sorted(batch),
+                           fetch_names=[loss.name])
+        scope = fluid.global_scope()
+        state = {n: fluid.core.as_array(scope.find_var(n))
+                 for n in step.state_names}
+        data = {n: batch[n] if n in batch
+                else fluid.core.as_array(scope.find_var(n))
+                for n in step.input_names}
+        before = monitor.counter_value('dropout/counter_draws')
+        text = jax.jit(step.fn).lower(
+            np.int32(0), state, data).as_text(debug_info=True)
+        drawn = monitor.counter_value('dropout/counter_draws') - before
+        # the one-chip runner's whole-program gradient lowers an op
+        # once; a runner that lowers the grad op's replay apart lowers
+        # it twice (XLA merges the two draws)
+        assert drawn and drawn % ops == 0, (drawn, ops)
+        assert monitor.gauge_value('dropout/elements') == \
+            drawn // ops * (
+                (2 * layers + 1) * 4 * seq * cfg.hidden +
+                (per_layer - 2) * layers * 4 * cfg.heads * seq * seq)
+    assert 'threefry' not in text and '_bernoulli' not in text
+    assert 'dropout' in text        # the scopes are there to look in
+
+
 def test_transformer_tiny_trains():
     # fixed batch (memorization): with fresh random token batches every
     # step the loss signal is below the dropout noise floor at 15 steps

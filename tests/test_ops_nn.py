@@ -7,6 +7,7 @@ test_batch_norm_op.py, test_softmax_with_cross_entropy_op.py, etc.
 import numpy as np
 import pytest
 
+import paddle_tpu.fluid as fluid
 from op_test import OpTest
 
 rng = np.random.RandomState(7)
@@ -193,6 +194,185 @@ class TestDropout(OpTest):
                                  'dropout_implementation':
                                      'upscale_in_train'},
                           expect={'Out': x})
+
+
+# -- the dropout op's draw: ops/keep_hash.py's counter hash, keyed by
+# (op seed, step) over the element's position --------------------------
+
+
+def _dropout_lowered(x, rate, op_seed=1000003 * 3 + 17, step=5,
+                     impl='upscale_in_train', prefer_test=False):
+    """The op's lowering called as the executor calls it -> outs."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import registry
+    ctx = registry.LowerCtx(jnp.int32(step), op_seed,
+                            prefer_test=prefer_test)
+    return registry.get('dropout').run(
+        ctx, {'X': [jnp.asarray(x)]},
+        {'dropout_prob': rate, 'is_test': False,
+         'dropout_implementation': impl})
+
+
+def _draw(shape, rate, **kw):
+    return np.asarray(_dropout_lowered(
+        np.ones(shape, 'float32'), rate, **kw)['Mask'][0]) != 0
+
+
+@pytest.mark.parametrize('shape', [(64, 128, 768), (4096,), ()])
+@pytest.mark.parametrize('rate', [0.1, 0.3, 0.5])
+def test_dropout_keep_rate(rate, shape):
+    keep = _draw(shape, rate)
+    assert keep.shape == shape
+    if not shape:       # one element: a bit, drawn anew a step
+        bits = [bool(_draw((), rate, step=s)) for s in range(256)]
+        assert abs(np.mean(bits) - (1 - rate)) < \
+            3 * np.sqrt(rate * (1 - rate) / 256)
+        return
+    sigma = np.sqrt(rate * (1 - rate) / keep.size)
+    assert abs(keep.mean() - (1 - rate)) < 3 * sigma
+
+
+@pytest.mark.parametrize('rate', [0.1, 0.5])
+def test_dropout_draw_has_no_stripe(rate):
+    """The pre-mix is (row term) xor (column term): every row and every
+    column keeps its share, and neighbours along either axis (and the
+    diagonal) are independent."""
+    keep = _draw((64, 128, 768), rate).reshape(-1, 768).astype('float64')
+    p, var = 1 - rate, rate * (1 - rate)
+    for axis, n in ((1, 768), (0, keep.shape[0])):
+        z = (keep.mean(axis) - p) / np.sqrt(var / n)
+        # the z of 8192 rows / 768 columns: unit variance, no outlier
+        assert abs(np.mean(z ** 2) - 1) < 4 * np.sqrt(2.0 / z.size)
+        assert np.abs(z).max() < 5
+    c = keep - p
+    for a, b in ((c[:-1], c[1:]), (c[:, :-1], c[:, 1:]),
+                 (c[:-1, :-1], c[1:, 1:]), (c[:-1, 1:], c[1:, :-1]),
+                 (c[:-2], c[2:]), (c[:, :-2], c[:, 2:])):
+        assert abs((a * b).mean() / var) < 4 / np.sqrt(a.size)
+
+
+@pytest.mark.parametrize('other', [dict(step=6), dict(step=4),
+                                   dict(op_seed=1000003 * 4 + 17),
+                                   dict(op_seed=1000003 * 3 + 18)])
+def test_dropout_masks_of_two_steps_and_two_ops_are_independent(other):
+    p, var = 0.9, 0.09
+    a = _draw((64, 128, 768), 0.1) - p
+    b = _draw((64, 128, 768), 0.1, **other) - p
+    assert abs((a * b).mean() / var) < 4 / np.sqrt(a.size)
+    # ... and shifted by one row / one column (a keying that only
+    # moved the counter would show here)
+    a, b = a.reshape(-1, 768), b.reshape(-1, 768)
+    for x, y in ((a[:-1], b[1:]), (a[1:], b[:-1]),
+                 (a[:, :-1], b[:, 1:]), (a[:, 1:], b[:, :-1])):
+        assert abs((x * y).mean() / var) < 4 / np.sqrt(x.size)
+
+
+@pytest.mark.parametrize('shape', [(3, 5, 7, 33), (6, 130), (257,), ()])
+def test_dropout_bits_are_the_shared_definition(shape):
+    """One definition of the draw in the tree: the op's mask is
+    keep_hash.keep_nd's, which is the flash kernels' element hash
+    (_dropout_keep over _keep_rows / _keep_cols) at head 0, row = the
+    flattened index over the leading axes, column = the last axis."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import keep_hash, registry
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    assert fa._dropout_keep is keep_hash._dropout_keep and \
+        fa._keep_rows is keep_hash._keep_rows and \
+        fa._keep_cols is keep_hash._keep_cols and \
+        fa._keep_threshold is keep_hash._keep_threshold
+    op_seed, step, rate = 77, 9, 0.3
+    seed = registry.LowerCtx(jnp.int32(step), op_seed).draw_seed()
+    got = _draw(shape, rate, op_seed=op_seed, step=step)
+    np.testing.assert_array_equal(
+        got, np.asarray(keep_hash.keep_nd(seed, shape, rate)))
+    cols = shape[-1] if shape else 1
+    flat = np.arange(max(int(np.prod(shape)), 1), dtype=np.int64)
+    want = keep_hash._dropout_keep(
+        keep_hash._keep_rows(seed, 0, jnp.asarray(flat // cols,
+                                                  jnp.int32)),
+        keep_hash._keep_cols(jnp.asarray(flat % cols, jnp.int32)),
+        keep_hash._keep_threshold(rate))
+    np.testing.assert_array_equal(got, np.asarray(want).reshape(shape))
+    if len(shape) == 4:     # the attention form at one head a batch
+        b, _, tq, tk = shape
+        dense = fa.dropout_keep_dense(seed, 1, 1, b * shape[1] * tq, tk,
+                                      rate=rate)
+        np.testing.assert_array_equal(
+            got, np.asarray(dense).reshape(shape))
+
+
+@pytest.mark.parametrize('impl', ['upscale_in_train',
+                                  'downgrade_in_infer'])
+class TestDropoutContract(OpTest):
+    def _attrs(self, impl, **more):
+        return dict({'dropout_prob': 0.3, 'is_test': False,
+                     'dropout_implementation': impl}, **more)
+
+    def test_mask_is_out_nonzero(self, impl):
+        x = (rng.rand(33, 65) + 0.5).astype('float32')
+        got = self.run_op('dropout', {'X': x}, attrs=self._attrs(impl),
+                          out_slots=('Out', 'Mask'))
+        out, mask = np.asarray(got['Out']), np.asarray(got['Mask'])
+        assert mask.dtype == x.dtype and out.dtype == x.dtype
+        np.testing.assert_array_equal(mask, (out != 0).astype(x.dtype))
+        scale = 1 / 0.7 if impl == 'upscale_in_train' else 1.0
+        np.testing.assert_allclose(out, x * mask * scale, rtol=1e-6)
+        assert 0.6 < mask.mean() < 0.8
+
+    def test_grad_is_the_forward_mask(self, impl):
+        """The synthesized vjp replays the forward and draws the same
+        bits: dX is 0 exactly where Out is, the scale elsewhere."""
+        x = (rng.rand(17, 40) + 0.5).astype('float32')
+        main, startup, feed, in_vars, out_vars = self._build(
+            'dropout', {'X': x}, self._attrs(impl), ('Out', 'Mask'))
+        with fluid.program_guard(main, startup):
+            loss = fluid.layers.reduce_sum(out_vars['Out'])
+            fluid.backward.append_backward(loss)
+        gname = main._grad_name_map[in_vars['X'].name]
+        with fluid.scope_guard(fluid.Scope()):
+            exe = fluid.Executor(fluid.XLAPlace(0))
+            exe.run(startup)
+            for _ in range(2):      # two steps, two masks, each its own
+                out, dx = exe.run(main, feed=feed,
+                                  fetch_list=[out_vars['Out'], gname])
+                scale = 1 / 0.7 if impl == 'upscale_in_train' else 1.0
+                np.testing.assert_allclose(
+                    np.asarray(dx),
+                    np.where(np.asarray(out) != 0, scale, 0.0),
+                    rtol=1e-6)
+
+    def test_fresh_scopes_agree_and_steps_differ(self, impl):
+        x = np.ones((64, 64), 'float32')
+        main, startup, feed, _, out_vars = self._build(
+            'dropout', {'X': x}, self._attrs(impl), ('Out', 'Mask'))
+        runs = []
+        for _ in range(2):
+            with fluid.scope_guard(fluid.Scope()):
+                exe = fluid.Executor(fluid.XLAPlace(0))
+                exe.run(startup)
+                runs.append([np.asarray(exe.run(
+                    main, feed=feed, fetch_list=[out_vars['Mask']])[0])
+                    for _ in range(2)])
+        np.testing.assert_array_equal(runs[0][0], runs[1][0])
+        np.testing.assert_array_equal(runs[0][1], runs[1][1])
+        assert (runs[0][0] != runs[0][1]).mean() > 0.2
+
+    def test_prefer_test_inference_keeps_the_train_op(self, impl):
+        """Shape inference lowers with prefer_test: a train op stays a
+        train op there (the op tests its own is_test), same shapes and
+        dtypes as the run, and the same bits."""
+        from paddle_tpu.ops import registry
+        spec = registry.infer_shapes(
+            'dropout', {'X': [((-1, 12, 40), 'float32')]},
+            self._attrs(impl))
+        assert spec['Out'] == [((-1, 12, 40), np.dtype('float32'))]
+        assert spec['Mask'] == [((-1, 12, 40), np.dtype('float32'))]
+        x = np.ones((4, 12, 40), 'float32')
+        train = _dropout_lowered(x, 0.3, impl=impl)
+        infer = _dropout_lowered(x, 0.3, impl=impl, prefer_test=True)
+        np.testing.assert_array_equal(np.asarray(train['Mask'][0]),
+                                      np.asarray(infer['Mask'][0]))
+        assert not np.asarray(infer['Mask'][0]).all()
 
 
 class TestSoftmaxWithCrossEntropy(OpTest):
