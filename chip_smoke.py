@@ -1999,6 +1999,11 @@ def main():
                    devs[0].memory_stats()['bytes_limit'] / 1e9))
     except SmokeFailure as e:
         sys.exit('chip_smoke FAILED: %s' % e)
+    # which backward each flash lowering took (one pass, or dq + dkv)
+    # and the most scoped VMEM a call asked Mosaic for
+    from paddle_tpu.ops.pallas import common
+    say('pallas kernels: %s' % json.dumps(
+        common.report().get('kernels', {}), sort_keys=True))
     say('jax compiled %(compiles)d programs; its persistent cache: '
         '%(cache_hits)d hits, %(cache_misses)d misses' % _JAX_EVENTS)
     print(json.dumps({'ok': True, 'device': {

@@ -24,13 +24,22 @@ segment), so none of this is hot-path.
 
 import jax
 
-# VMEM budget for the block-size clamp.  v5e cores have 16 MB less
-# scratch/compiler overhead; 10 MB keeps every swept config compiling
-# with headroom.
+# The budget the block-size clamp (block_sizes) and the resident-row
+# rule (scoped_vmem) work to: what a forward, dq or dkv instance may
+# count, by vmem_estimate(), and still compile at Mosaic's DEFAULT
+# scoped limit with the pipeline's second buffers and the compiler's
+# temporaries beside it.  A figure about that default, not about the
+# chip: a v5e core has 128 MiB of VMEM.
 VMEM_BUDGET_BYTES = 10 * 1024 * 1024
-# What Mosaic gives one kernel instance on a v5e (its scoped VMEM): a
-# kernel that asks for more is refused by the compiler.
+# Mosaic's default scoped-VMEM limit for one kernel instance on a v5e
+# (16 MiB of the core's 128): a kernel that needs more is refused by
+# the compiler UNLESS its call asks for more (``vmem_limit_bytes``),
+# which scoped_vmem and one_pass_backward_limit do.
 SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+# The most any call here asks Mosaic for, of the core's 128 MiB, and
+# what a call that asks adds to its count for the tiles' temporaries.
+VMEM_LIMIT_CAP_BYTES = 100 << 20
+VMEM_HEADROOM_BYTES = 16 << 20
 
 # kernel-library registry: name -> descriptor.  Populated by each
 # kernel module at import via register_kernel(); tools/check_kernels.py
@@ -121,7 +130,8 @@ def vmem_estimate(t, d, block_q, block_k, itemsize, dv=None):
     return kv + rows + score_tile_bytes(block_q, block_k) + (1 << 18)
 
 
-def room_for_second_tile(resident, block_q, block_k, itemsize):
+def room_for_second_tile(resident, block_q, block_k, itemsize,
+                         limit=None):
     """May a kernel instance hold two score tiles alive at once?
     Inside one tile the products and the vector chain depend on each
     other, so an instance that holds one tile runs MXU and VPU in
@@ -133,10 +143,12 @@ def room_for_second_tile(resident, block_q, block_k, itemsize):
     estimate of everything else the instance holds, counted twice
     (the pipeline keeps two buffers of every row and block, which the
     one-tile estimates leave to their budget's headroom); the sum has
-    to stay under what the compiler allows.
+    to stay under what the compiler allows: ``limit``, the scoped VMEM
+    the call asks Mosaic for, or its default where the call asks for
+    nothing (None).
     tests/test_chip_compile.py compiles the shapes that decide."""
     tile = score_tile_bytes(block_q, block_k) * itemsize // 2
-    return 2 * resident + 2 * tile <= SCOPED_VMEM_BYTES
+    return 2 * resident + 2 * tile <= (limit or SCOPED_VMEM_BYTES)
 
 
 def block_sizes(t, block_q, block_k, d=64, itemsize=2, dv=None, tk=None):
@@ -206,7 +218,62 @@ def scoped_vmem(t, d, block_q, block_k, itemsize, dv=None):
     elif 2 * resident_row_bytes(t, d, itemsize, dv) < VMEM_BUDGET_BYTES:
         return None
     estimate = vmem_estimate(t, d, block_q, block_k, itemsize, dv)
-    return min(2 * estimate + (16 << 20), 100 << 20)
+    return min(2 * estimate + VMEM_HEADROOM_BYTES, VMEM_LIMIT_CAP_BYTES)
+
+
+def _lanes(width):
+    """The lanes a row ``width`` elements wide lies in: VMEM tiles are
+    128 lanes wide, so 64 lies in 128 and 192 in 256."""
+    return -(-width // 128) * 128
+
+
+def one_pass_backward_vmem(t, tk, d, dv, block_q, block_k, itemsize,
+                           group=1, q_vectors=2, k_vectors=0):
+    """Bytes ONE instance of the one-pass flash backward
+    (flash_attention._flash_bwd_fused_kernel, grid over heads) holds
+    in VMEM, counted as Mosaic lays them out:
+
+    - the q, dO (``t`` long) and k, v (``tk`` long) row inputs and the
+      dq, dk, dv row outputs, each in the pipeline's TWO buffers, a
+      row as wide as the lanes it lies in (_lanes);
+    - the f32 dq scratch and, where ``group`` query heads share a K/V
+      head, the f32 dk and dv scratch (one buffer each);
+    - the [1, n] float32 vectors, 8 sublanes each, two buffers:
+      ``q_vectors`` of length t (lse, delta, and the lse cotangent
+      where there is one), ``k_vectors`` of length tk (the key bias
+      and its gradient);
+    - two chains' [block_q, block_k] score tiles (s -> p and dO v^T ->
+      ds), each as score_tile_bytes() counts one, and twice that for
+      float32 operands, whose full-precision products split every
+      operand into bfloat16 parts that lie beside it
+      (room_for_second_tile counts them so too).
+
+    The gate between the one-pass and the two-pass backward, and what
+    the one-pass call asks of Mosaic, both read this count
+    (one_pass_backward_limit); tests/test_chip_compile.py compiles the
+    cells' shapes against it."""
+    wide = _lanes(d) + _lanes(dv)
+    rows_in = (t + tk) * wide * itemsize
+    rows_out = (t * _lanes(d) + tk * wide) * itemsize
+    vectors = 8 * 4 * (q_vectors * t + k_vectors * tk)
+    scratch = t * _lanes(d) * 4 + (tk * wide * 4 if group > 1 else 0)
+    tiles = 2 * score_tile_bytes(block_q, block_k) * max(itemsize // 2, 1)
+    return 2 * (rows_in + rows_out + vectors) + scratch + tiles
+
+
+def one_pass_backward_limit(count):
+    """(admitted, ``vmem_limit_bytes``) of a one-pass backward call
+    whose instance holds ``count`` bytes (one_pass_backward_vmem).
+    Under Mosaic's default the call asks for nothing (None) and lowers
+    as it always has; over it, for the count and the headroom
+    scoped_vmem adds; and where that passes the cap every call here
+    keeps to, the one-pass kernel is not admitted and the two-pass
+    kernels (whose instances hold K and V, or Q and dO, not all four
+    and three outputs) run."""
+    if count <= SCOPED_VMEM_BYTES:
+        return True, None
+    limit = count + VMEM_HEADROOM_BYTES
+    return limit <= VMEM_LIMIT_CAP_BYTES, limit
 
 
 def record_dispatch(kernel, fused, reason, interpret=False):
@@ -281,7 +348,9 @@ def dispatch(kernel, enabled, checks=(), force=None,
 
 def report():
     """/statusz section: per-kernel registration + last decision +
-    dispatch/fallback counter values, and under 'dropout' the dropout
+    dispatch/fallback counter values (and, for flash attention, the
+    backward lowerings by kind and the largest ``vmem_limit_bytes``
+    a call asked for), and under 'dropout' the dropout
     op's draws from the kernels' counter hash (ops/keep_hash.py):
     lowerings counted and the elements the last traced program draws
     a step.  Empty dict when nothing has dispatched or drawn yet
@@ -309,6 +378,16 @@ def report():
             # of the fused ones: lowered inside a shard_map over the
             # GSPMD runner's batch axes
             ent['dispatch_sharded'] = sharded
+        # which backward a kernel with two of them lowered (flash
+        # attention: one pass over a head's rows, or dq then dkv), and
+        # the most scoped VMEM any of its calls asked Mosaic for
+        for key in ('backward_one_pass', 'backward_two_pass'):
+            n = counter('pallas/%s/%s' % (name, key)) or 0
+            if n:
+                ent[key] = n
+        asked = gauge('pallas/%s/vmem_asked_max' % name) or 0
+        if asked:
+            ent['vmem_asked_max'] = int(asked)
         if info.get('op_types'):
             ent['op_types'] = list(info['op_types'])
         if last:

@@ -10,10 +10,13 @@ Backward is wired through custom_vjp: the forward additionally emits
 the per-row log-sum-exp (lse); backward precomputes delta =
 rowsum(dO * O), then either ONE kernel per head walks k-blocks x
 q-blocks and accumulates dQ, dK, dV (+ the key-bias gradient) while
-the head's rows fit VMEM (_flash_bwd_fused_kernel: every BERT shape),
-or the standard two-pass scheme: one kernel recomputes p blocks to
-accumulate dQ (grid over Q blocks) and a second accumulates dK/dV with
-a grid over K blocks (d128, long sequences).
+the head's rows fit the VMEM the call can ask Mosaic for
+(_flash_bwd_fused_kernel; common.one_pass_backward_vmem counts them:
+every bfloat16 shape a cell runs, up to 8192 positions at 192 over
+128), or the standard two-pass scheme: one kernel recomputes p blocks
+to accumulate dQ (grid over Q blocks) and a second accumulates dK/dV
+with a grid over K blocks (float32 rows of an 8k sequence, and
+FUSED_BWD = False).
 
 The four kernel bodies share the per-tile chain (_score_tile: scores,
 scale, bias, causal mask, dropout multiplier) and the tile loop
@@ -656,9 +659,11 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
                      causal, block_q, block_k, interpret, rate,
-                     window=0, coarse=None):
+                     window=0, coarse=None, limit=None):
     """pallas_call plumbing for the one-pass backward: grid (BH,), or
-    (B*Hkv, group) where ``group`` query heads share a K/V head."""
+    (B*Hkv, group) where ``group`` query heads share a K/V head.
+    ``limit``: the scoped VMEM the call asks Mosaic for (_flash_bwd;
+    None: its default); a second tile has to fit under THAT."""
     bh, t, d = q.shape
     tk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
@@ -669,7 +674,7 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
         None if causal or coarse else t // block_q,
         _fused_bwd_resident(t, d, block_k, q.dtype.itemsize, group, dv,
                             tk),
-        block_q, block_k, q.dtype.itemsize)
+        block_q, block_k, q.dtype.itemsize, limit)
     kernel = functools.partial(
         _flash_bwd_fused_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, tiles=tiles,
@@ -720,9 +725,7 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
-        **_backward_params(
-            _fused_bwd_vmem(t, d, block_q, block_k, q.dtype.itemsize,
-                            group, dv, tk), q.dtype.itemsize),
+        **_vmem_limit(limit),
     )(*operands)
     if has_bias:
         dq, dk, dv, dbias_bh = res
@@ -734,12 +737,14 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
     return dq, dk, dv, dbias
 
 
-# The fused one-pass backward engages when the per-head VMEM residency
-# fits; False forces the two-pass scheme (sweeps / A-B measurement).
+# The one-pass backward runs where its instance fits the VMEM the call
+# may ask Mosaic for (_flash_bwd); False forces the two-pass scheme
+# (sweeps / A-B measurement: tools/bench_flash.py --two-pass).
 FUSED_BWD = True
-# Fused-backward tile shape (chip-swept round 5: 512/512 best at d=64
-# within the VMEM budget; larger k-tiles push the f32 score blocks
-# over it and fall back to two-pass).
+# One-pass tile shape (chip-swept round 5 at d=64: 512/512 best).  A
+# wider key tile is no longer a question of room (the call asks for
+# the VMEM it counts, of the core's 128 MiB, not Mosaic's 16 MiB
+# default) but of time: PERF.md section 6, PR 42, has the sweep.
 FUSED_BLOCK_Q = 512
 FUSED_BLOCK_K = 512
 # The widest a coarse call's key block is narrowed to (_window_blocks).
@@ -748,12 +753,14 @@ COARSE_BLOCK_K = 512
 
 def _fused_bwd_resident(t, d, block_k, itemsize, group=1, dv=None,
                         tk=None):
-    """What a fused-backward instance holds beside its score tiles:
-    q/k (``d`` wide) and v/do (``dv`` wide) full rows, q and do ``t``
-    long, k and v ``tk`` (t unless the keys are of another length),
-    the f32 dq accumulator (and, where ``group`` query heads share a
-    K/V head, the dk and dv ones) and the dk/dv f32 blocks (x2 slack
-    for compiler temporaries)."""
+    """What a fused-backward instance holds beside its score tiles,
+    ONE buffer of each, as common.room_for_second_tile() wants it (it
+    doubles the sum for the pipeline's second buffers): q/k (``d``
+    wide) and v/do (``dv`` wide) full rows, q and do ``t`` long, k
+    and v ``tk`` (t unless the keys are of another length), the f32 dq
+    accumulator (and, where ``group`` query heads share a K/V head,
+    the dk and dv ones) and the dk/dv f32 blocks (x2 slack for
+    compiler temporaries)."""
     dv = d if dv is None else dv
     tk = t if tk is None else tk
     rows = (t + tk) * (d + dv) * itemsize
@@ -761,27 +768,42 @@ def _fused_bwd_resident(t, d, block_k, itemsize, group=1, dv=None,
     return rows + accs + 2 * block_k * (d + dv) * 4 + (1 << 19)
 
 
-def _fused_bwd_vmem(t, d, block_q, block_k, itemsize, group=1, dv=None,
-                    tk=None):
-    """Resident bytes for the fused backward, one tile a trip: each
-    tile has two chains (s -> p and dp -> ds), so two of
-    common.score_tile_bytes()."""
-    return _fused_bwd_resident(t, d, block_k, itemsize, group, dv, tk) \
-        + 2 * _common.score_tile_bytes(block_q, block_k)
+def _one_pass_blocks(t, tk, block_q, block_k):
+    """The one-pass backward's tile: the two-pass blocks (clamped, and
+    narrowed by a band or a coarse mask) no larger than FUSED_BLOCK_*,
+    dividing the two lengths."""
+    fq, fk = min(block_q, FUSED_BLOCK_Q), min(block_k, FUSED_BLOCK_K)
+    while t % fq:
+        fq //= 2
+    while tk % fk:
+        fk //= 2
+    return fq, fk
 
 
-def _second_tile(trips, resident, block_q, block_k, itemsize):
+def _one_pass_vmem(t, tk, d, dv, block_q, block_k, itemsize, group,
+                   has_bias, has_glse):
+    """common.one_pass_backward_vmem() of a call as _flash_bwd_fused
+    makes it: lse and delta, the lse cotangent where there is one, the
+    key bias and its gradient where there is one."""
+    return _common.one_pass_backward_vmem(
+        t, tk, d, dv, block_q, block_k, itemsize, group,
+        q_vectors=3 if has_glse else 2, k_vectors=2 if has_bias else 0)
+
+
+def _second_tile(trips, resident, block_q, block_k, itemsize,
+                 limit=None):
     """(tiles a loop trip, dp_early): how a kernel instance uses the
     room for a second score tile, where the VMEM model finds it
-    (common.room_for_second_tile).  The tile loop takes two tiles a
-    trip where its trip count is even and known at trace time
-    (``trips``; None for causal calls, which bound their loops by the
-    diagonal).  Where it is not, the backward bodies issue their
+    (common.room_for_second_tile) under ``limit``, the scoped VMEM
+    the call asks for (None: Mosaic's default).  The tile loop takes
+    two tiles a trip where its trip count is even and known at trace
+    time (``trips``; None for causal calls, which bound their loops
+    by the diagonal).  Where it is not, the backward bodies issue their
     second independent product (dO V^T) before the first tile's
     chain is through, which keeps a second tile alive just the
     same."""
     room = _common.room_for_second_tile(resident, block_q, block_k,
-                                        itemsize)
+                                        itemsize, limit)
     tiles = 2 if room and trips is not None and trips % 2 == 0 else 1
     return tiles, room and tiles == 1
 
@@ -796,28 +818,36 @@ def _mosaic_params(t, d, block_q, block_k, itemsize, dv):
 
 
 def _vmem_limit(limit):
+    """``compiler_params`` that ask Mosaic for ``limit`` bytes of
+    scoped VMEM; nothing at all for None, so a call that fits the
+    default lowers to the bytes it always has.  The largest limit any
+    flash call of the process asked for is the gauge
+    ``pallas/flash_attention/vmem_asked_max`` (common.report())."""
     if limit is None:
         return {}
     from jax.experimental.pallas import tpu as pltpu
+    from ...fluid import monitor
+    monitor.set_gauge('pallas/flash_attention/vmem_asked_max', max(
+        limit, monitor.gauge_value('pallas/flash_attention/vmem_asked_max')))
     return {'compiler_params': pltpu.CompilerParams(
         vmem_limit_bytes=limit)}
 
 
-def _backward_params(estimate, itemsize, rows=None):
-    """``compiler_params`` of a backward call whose own VMEM model
-    gives ``estimate``.  bfloat16 calls ask what a forward call asks:
-    a dq or dkv call _mosaic_params(*``rows``, itemsize, dv), ``rows``
-    = (resident length, d, block_q, block_k, dv); the fused one
-    nothing.  float32 ones always ask for twice their
-    estimate and 16 MB: their full-precision products split every
-    operand into bfloat16 parts that lie beside it, which no estimate
-    here counts, and from a grid of some size on the compiler refused
-    them at its default (2048 keys x 128: 16.96 of 16 MB for a dkv
-    call, 17.99 for a fused one over 4096 queries and 256 keys;
-    ROADMAP S3 (6))."""
+def _backward_params(t, d, block_q, block_k, itemsize, dv):
+    """``compiler_params`` of a dq or dkv call whose resident rows are
+    ``t`` long.  bfloat16 calls ask what a forward call asks
+    (_mosaic_params).  float32 ones always ask for twice their
+    estimate and the headroom: their full-precision products split
+    every operand into bfloat16 parts that lie beside it, which
+    vmem_estimate() does not count, and from a grid of some size on
+    the compiler refused them at its default (2048 keys x 128: 16.96
+    of 16 MB for a dkv call; ROADMAP S3 (6)).  The one-pass call has a
+    count of its own that knows both (common.one_pass_backward_vmem)."""
     if itemsize >= 4:
-        return _vmem_limit(min(2 * estimate + (16 << 20), 100 << 20))
-    return _mosaic_params(*rows[:4], itemsize, rows[4]) if rows else {}
+        return _vmem_limit(min(
+            2 * _vmem_estimate(t, d, block_q, block_k, itemsize, dv) +
+            _common.VMEM_HEADROOM_BYTES, _common.VMEM_LIMIT_CAP_BYTES))
+    return _mosaic_params(t, d, block_q, block_k, itemsize, dv)
 
 
 def _rows_resident(t, d, block_q, block_k, itemsize, dv=None):
@@ -930,25 +960,30 @@ def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
     block_q, block_k = _window_blocks(
         _block_sizes(t, block_q, block_k, d, q.dtype.itemsize, dv, tk),
         window, coarse, tk)
-    fq, fk = min(block_q, FUSED_BLOCK_Q), min(block_k, FUSED_BLOCK_K)
-    while t % fq:
-        fq //= 2
-    while tk % fk:
-        fk //= 2
-    fused = FUSED_BWD and _fused_bwd_vmem(
-        t, d, fq, fk, q.dtype.itemsize,
-        bh // k.shape[0], dv, tk) <= VMEM_BUDGET_BYTES
+    fq, fk = _one_pass_blocks(t, tk, block_q, block_k)
+    # one pass where an instance's rows, outputs, scratch and tiles
+    # fit the VMEM the call may ask for: the shape decides, through
+    # the count, and nothing else does
+    admitted, limit = _common.one_pass_backward_limit(_one_pass_vmem(
+        t, tk, d, dv, fq, fk, q.dtype.itemsize, bh // k.shape[0],
+        bias is not None, g_lse is not None))
+    fused = FUSED_BWD and admitted
+    from ...fluid import monitor
+    monitor.add('pallas/flash_attention/backward_%s'
+                % ('one_pass' if fused else 'two_pass'), 1)
     return _bwd_call(
         q, k, v, bias, seed, o, lse, do, g_lse, h=h, causal=causal,
         blocks=(fq, fk) if fused else (block_q, block_k), fused=fused,
-        interpret=interpret, rate=rate, window=window, coarse=coarse)
+        limit=limit if fused else None, interpret=interpret, rate=rate,
+        window=window, coarse=coarse)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    'h', 'causal', 'blocks', 'fused', 'interpret', 'rate', 'window',
-    'coarse'))
+    'h', 'causal', 'blocks', 'fused', 'limit', 'interpret', 'rate',
+    'window', 'coarse'))
 def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
-              blocks, fused, interpret, rate, window=0, coarse=None):
+              blocks, fused, interpret, rate, window=0, coarse=None,
+              limit=None):
     bh, t, d = q.shape
     tk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
@@ -968,7 +1003,7 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
     if fused:
         return _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3,
                                 glse3, h, causal, block_q, block_k,
-                                interpret, rate, window, coarse)
+                                interpret, rate, window, coarse, limit)
 
     # the dq call keeps a head's K and V rows resident (tk long), the
     # dkv call its Q and dO rows (t long)
@@ -1010,10 +1045,8 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        **_backward_params(
-            _vmem_estimate(tk, d, block_q, block_k, q.dtype.itemsize,
+        **_backward_params(tk, d, block_q, block_k, q.dtype.itemsize,
                            dv),
-            q.dtype.itemsize, (tk, d, block_q, block_k, dv)),
     )(*dq_operands)
 
     if t != tk:
@@ -1079,10 +1112,8 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
-        **_backward_params(
-            _vmem_estimate(t, d, block_q, block_k, q.dtype.itemsize,
+        **_backward_params(t, d, block_q, block_k, q.dtype.itemsize,
                            dv),
-            q.dtype.itemsize, (t, d, block_q, block_k, dv)),
     )(*dkv_operands)
     if has_bias:
         dk, dv, dbias_bh = res

@@ -96,12 +96,41 @@ def _compiled_on_chip(kernel):
         common._LAST[kernel]
 
 
+def _one_pass(t, d, dtype, group=1, dv=None, tk=None, lse=False,
+              block_k=None):
+    """What the one-pass backward's count (common.one_pass_backward_vmem
+    at the blocks _flash_bwd hands it) says of a call with no key
+    bias: (admitted, the ``vmem_limit_bytes`` it asks Mosaic for)."""
+    return common.one_pass_backward_limit(flash_attention._one_pass_vmem(
+        t, tk or t, d, dv or d, flash_attention.FUSED_BLOCK_Q,
+        block_k or flash_attention.FUSED_BLOCK_K,
+        jnp.dtype(dtype).itemsize, group, False, lse))
+
+
+def _scoped(text):
+    """The scoped VMEM of each Mosaic call of an executable, in bytes:
+    what the call asked for, or Mosaic's default."""
+    import re
+    return [int(n) for n in re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?'
+        r'scoped_memory_configs[^\n]*?"size":"(\d+)"', text)]
+
+
+def _forward_and_one_pass(text, limit):
+    """The executable holds two Mosaic calls, the forward and the
+    one-pass backward, and one of them runs under ``limit`` (None:
+    Mosaic's default)."""
+    scoped = _scoped(text)
+    assert len(scoped) == 2 and \
+        (limit or common.SCOPED_VMEM_BYTES) in scoped, (scoped, limit)
+
+
 @pytest.mark.parametrize('b,t,h,d,rate', [
     (4, 2048, 12, 64, 0.0),     # chip_smoke.py / bench_bert_long
     (4, 2048, 12, 64, 0.1),     # in-kernel dropout mask
     (16, 512, 12, 64, 0.0),     # the dispatch floor (FLASH_MIN_SEQ)
     (48, 512, 12, 64, 0.1),     # bert_base_s512_b48's calls
-    (4, 2048, 16, 128, 0.0),    # d128: the two-pass backward
+    (4, 2048, 16, 128, 0.0),    # d128: one pass, under Mosaic's default
 ])
 def test_flash_attention_fwd_bwd(one_chip, as_on_tpu, b, t, h, d, rate):
     def step(q, k, v, bias):
@@ -115,7 +144,7 @@ def test_flash_attention_fwd_bwd(one_chip, as_on_tpu, b, t, h, d, rate):
     qkv = _spec((b, t, h, d), jnp.bfloat16)
     n = _compile(step, one_chip, qkv, qkv, qkv, _spec((b, t)))
     _compiled_on_chip('flash_attention')
-    assert n >= 2, n    # forward + fused (or dq, dkv) backward
+    assert n == 2, n    # forward + one-pass backward
 
 
 @pytest.mark.parametrize('b,t', [(2, 512), (1, 2048)])
@@ -133,15 +162,18 @@ def test_flash_forward_in_float32_at_the_reference_check_shapes(
 @pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
 def test_causal_flash_at_the_olmoe_cell_shape(one_chip, as_on_tpu, dtype):
     """olmoe_1b7b_s4096: b3 t4096 h16 d128, causal, no key bias, no
-    dropout.  At d128 the one-pass backward's residency is over the
-    VMEM budget, so _flash_bwd picks the two-pass kernels: forward,
-    dq, dkv.  bfloat16 is the timed train step; float32 (products at
-    full precision) is the cell's reference check and
-    ``chip_smoke.py --phase olmoe``."""
+    dropout.  The one-pass backward's instance counts 22.5 MB in
+    bfloat16 and 42.5 in float32: over Mosaic's default and far under
+    the core's 128 MiB, so _flash_bwd picks it and the call asks for
+    its count and the headroom: forward + one backward call.
+    bfloat16 is the timed train step; float32 (products at full
+    precision) is the cell's reference check and ``chip_smoke.py
+    --phase olmoe``."""
     b, t, h, d = 3, 4096, 16, 128
-    assert flash_attention._fused_bwd_vmem(
-        t, d, flash_attention.FUSED_BLOCK_Q, flash_attention.FUSED_BLOCK_K,
-        2) > flash_attention.VMEM_BUDGET_BYTES
+    admitted, limit = _one_pass(t, d, dtype)
+    assert admitted and limit == int(
+        (22.5 if dtype == jnp.bfloat16 else 42.5) * 2 ** 20) + \
+        common.VMEM_HEADROOM_BYTES
 
     def step(q, k, v):
         def loss(q, k, v):
@@ -150,9 +182,9 @@ def test_causal_flash_at_the_olmoe_cell_shape(one_chip, as_on_tpu, dtype):
         return jax.grad(loss, (0, 1, 2))(q, k, v)
 
     qkv = _spec((b, t, h, d), dtype)
-    n = _compile(step, one_chip, qkv, qkv, qkv)
+    text = _compiled(step, one_chip, qkv, qkv, qkv).as_text()
     _compiled_on_chip('flash_attention')
-    assert n == 3, n
+    _forward_and_one_pass(text, limit)
 
 
 @pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
@@ -161,12 +193,14 @@ def test_causal_flash_at_the_olmoe_cell_shape(one_chip, as_on_tpu, dtype):
 def test_banded_grouped_flash_at_the_laguna_cell_shapes(
         one_chip, as_on_tpu, heads, window, dtype):
     """laguna_s21_s4096: b1 t4096 d128, 72 (banded, window 512) or 48
-    (full causal) query heads over 8 K/V heads.  The two-pass backward
-    at d128: the dK/dV kernel walks a K/V head's group of query heads
-    on a third grid axis and sums into two f32 VMEM blocks; the banded
-    calls take 512-wide key blocks.  bfloat16 is the timed step,
-    float32 the reference check and ``chip_smoke.py --phase laguna``.
-    The windowed calls carry the scope the op lowers them in."""
+    (full causal) query heads over 8 K/V heads.  The one-pass backward
+    at d128 (26.5 MB an instance in bfloat16, 46.5 in float32, asked
+    of Mosaic with the headroom): its grid's second axis walks a K/V
+    head's group of query heads over the resident K/V rows and sums dK
+    and dV into two f32 VMEM scratch buffers; the banded calls take
+    512-wide key blocks.  bfloat16 is the timed step, float32 the
+    reference check and ``chip_smoke.py --phase laguna``.  The
+    windowed calls carry the scope the op lowers them in."""
     import contextlib
     import re
 
@@ -188,7 +222,9 @@ def test_banded_grouped_flash_at_the_laguna_cell_shapes(
     _compiled_on_chip('flash_attention')
     names = re.findall(
         r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
-    assert len(names) == 3, names
+    admitted, limit = _one_pass(4096, 128, dtype, group=heads // 8)
+    assert admitted and limit > common.SCOPED_VMEM_BYTES
+    _forward_and_one_pass(text, limit)
     assert all(n.startswith('window512') == bool(window)
                for n in names), names
 
@@ -197,22 +233,23 @@ def test_banded_grouped_flash_at_the_laguna_cell_shapes(
 def test_latent_flash_at_the_moonlight_cell_shape(one_chip, as_on_tpu,
                                                   dtype):
     """moonlight_16b_s8192: b1 t8192 h16, queries and keys 192 wide
-    over values 128 wide, causal.  The fused backward's residency is
-    over the budget (two-pass: forward, dq, dkv), and the resident
-    rows of an 8k sequence at 192 + 128, which the pipeline keeps
-    twice, are the block clamp's whole budget in bfloat16 and twice it
-    in float32: these calls ask Mosaic for more scoped VMEM than its
-    default (common.scoped_vmem; inside the cell's train step the
-    bfloat16 dkv call was refused at 16.56M of 16M without).  bfloat16
-    is the timed step, float32 ``chip_smoke.py --phase moonlight`` and
-    the cell's reference check.  The calls carry the scope the op
-    lowers them in."""
+    over values 128 wide, causal.  The resident rows of an 8k
+    sequence at 192 + 128, which the pipeline keeps twice, are the
+    block clamp's whole budget in bfloat16 and twice it in float32:
+    the forward asks Mosaic for more scoped VMEM than its default
+    (common.scoped_vmem).  In bfloat16, the timed step, the backward
+    is the one-pass kernel: 59 MB an instance (rows 192 wide lie in
+    256 lanes), 75 asked, of the core's 128.  In float32
+    (``chip_smoke.py --phase moonlight``, the cell's reference check)
+    the same instance counts 109 MB, over the 100 every call here
+    keeps to: dq and dkv, which ask too.  The calls carry the scope
+    the op lowers them in."""
     import re
     b, t, h, d, dv = 1, 8192, 16, 192, 128
     item = jnp.dtype(dtype).itemsize
-    assert flash_attention._fused_bwd_vmem(
-        t, d, flash_attention.FUSED_BLOCK_Q, flash_attention.FUSED_BLOCK_K,
-        item, 1, dv) > flash_attention.VMEM_BUDGET_BYTES
+    admitted, limit = _one_pass(t, d, dtype, dv=dv)
+    assert (admitted, limit) == (
+        (True, 75 << 20) if dtype == jnp.bfloat16 else (False, 125 << 20))
     blocks = common.block_sizes(t, 512, 1024, d, item, dv)
     assert common.scoped_vmem(t, d, *blocks, item, dv) > \
         common.SCOPED_VMEM_BYTES
@@ -232,7 +269,10 @@ def test_latent_flash_at_the_moonlight_cell_shape(one_chip, as_on_tpu,
     _compiled_on_chip('flash_attention')
     names = re.findall(
         r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
-    assert len(names) == 3, names
+    if admitted:
+        _forward_and_one_pass(text, limit)
+    else:
+        assert len(names) == 3, names
     assert all(n.startswith('qk192v128') for n in names), names
 
 
@@ -244,8 +284,10 @@ def test_grouped_causal_flash_d64_at_the_lfm2_cell_shape(one_chip, as_on_tpu,
     width 64 only with as many K/V heads as query heads, not causal,
     at 2048 and under (BERT).  bfloat16 is the timed step, float32
     ``chip_smoke.py --phase lfm2`` and the cell's reference check.
-    Every call is named after the op's own scope: no window, one
-    width."""
+    The backward is the one-pass kernel in both: rows 64 wide lie in
+    128 lanes, so an instance counts 47 MB in bfloat16 and 81 in
+    float32 (63 and 97 asked).  Every call is named after the op's
+    own scope: no window, one width."""
     import re
     b, t, h, hkv, d = 2, 8192, 32, 8, 64
 
@@ -262,12 +304,16 @@ def test_grouped_causal_flash_d64_at_the_lfm2_cell_shape(one_chip, as_on_tpu,
     _compiled_on_chip('flash_attention')
     names = re.findall(
         r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
-    assert len(names) == 3 and all(
-        'fused_multihead_attention' in n for n in names), names
+    admitted, limit = _one_pass(t, d, dtype, group=h // hkv)
+    assert (admitted, limit) == (
+        True, (63 << 20) if dtype == jnp.bfloat16 else (97 << 20))
+    _forward_and_one_pass(text, limit)
+    assert all('fused_multihead_attention' in n for n in names), names
     # rows 64 wide lie in 128 lanes: two buffers of an 8k sequence's
-    # take half of Mosaic's default and the calls ask for more (inside
-    # the cell's train step the bfloat16 dkv call was refused at 16.07
-    # of 16 MB without); BERT's, at 2048 keys and under, do not
+    # take half of Mosaic's default and the forward asks for more
+    # (inside the cell's train step the bfloat16 dkv call was refused
+    # at 16.07 of 16 MB without); BERT's, at 2048 keys and under, do
+    # not
     item = jnp.dtype(dtype).itemsize
     assert common.scoped_vmem(t, d, 512, 512, item) > \
         common.SCOPED_VMEM_BYTES
@@ -287,25 +333,31 @@ def test_both_eva_streams_at_the_evabyte_shapes(one_chip, as_on_tpu,
     coarse mask, named ``remote``.  bfloat16 is the timed step;
     float32 is ``chip_smoke.py --phase evabyte`` and the cell's
     reference check, whose backward calls ask Mosaic for more scoped
-    VMEM than its default (flash_attention._backward_params: refused
-    at 17.99 of 16 MB without).  T = 32768 is the published context:
-    16 windows, 2048 summaries, dq + dkv for both streams, and no
-    [T, T / 16] tensor in the program."""
+    VMEM than its default (refused at 17.99 of 16 MB without).  Both
+    streams' backward is the one-pass kernel wherever its instance
+    fits what a call may ask for: the local stream's at 2048 keys
+    counts 14.4 MB in bfloat16 (under Mosaic's default: the call asks
+    for nothing) and 27.4 in float32; the remote stream's holds q and
+    dO rows T long over T / 16 summaries.  T = 32768 is the published
+    context: 16 windows, 2048 summaries, the remote stream still one
+    pass in bfloat16 (80 MB an instance, 96 asked; float32 would count
+    138 and run dq + dkv), and no [T, T / 16] tensor in the program."""
     import re
     from paddle_tpu.ops import fused_ops
     b, h, d, window, chunk = 1, 32, 128, 2048, 16
     item = jnp.dtype(dtype).itemsize
     # the blocks: a coarse call's key block is narrowed towards a
-    # window's 128 summaries, but not under a quarter of the keys; at
-    # 4096 the backward is the one-pass kernel over rows of two
-    # lengths, at 32768 its q and dO rows are too long
+    # window's 128 summaries, but not under a quarter of the keys; the
+    # backward is the one-pass kernel over rows of two lengths
     block_k = 128 if t == 4096 else 512
     assert flash_attention._window_blocks(
         common.block_sizes(t, 512, 1024, d, item, None, t // chunk), 0,
         (window, window // chunk), t // chunk) == (512, block_k)
-    assert (flash_attention._fused_bwd_vmem(t, d, 512, block_k, item, 1,
-                                            d, t // chunk) <=
-            flash_attention.VMEM_BUDGET_BYTES) == (t == 4096)
+    local = _one_pass(window, d, dtype, lse=True)
+    assert local[0] and (local[1] is None) == (dtype == jnp.bfloat16), \
+        local
+    assert _one_pass(t, d, dtype, tk=t // chunk, lse=True,
+                     block_k=block_k)[0]
 
     def run(ins, attrs):
         return fused_ops.fused_multihead_attention(
@@ -338,9 +390,8 @@ def test_both_eva_streams_at_the_evabyte_shapes(one_chip, as_on_tpu,
     remote = [n for n in names if 'remote' in n]
     local = [n for n in names if 'remote' not in n]
     assert all('fused_multihead_attention' in n for n in local), names
-    # forward + one-pass backward, or forward + dq + dkv
-    assert len(remote) == (2 if t == 4096 else 3), names
-    assert len(local) in (2, 3), names
+    # forward + one-pass backward, both streams
+    assert len(remote) == 2 and len(local) == 2, names
     assert not re.search(r'\[(\d+,)*%d,%d\]' % (t, t // chunk), text)
     assert not re.search(r'\[(\d+,)*%d,%d\]' % (window, window), text)
     assert monitor.gauge_value('eva/remote_pairs') == sum(
@@ -365,11 +416,22 @@ def test_both_eva_streams_at_the_evabyte_shapes(one_chip, as_on_tpu,
     ('float32', 2, 1024, 4, 128, False),
     # one tile an instance, dO V^T issued early: f32 at the floor
     ('float32', 48, 512, 12, 64, True),
-    # f32 at t2048 compiles on a small grid only: at [12, 2048, 12, 64]
-    # and [6, 2048, 16, 128] the backward is refused, before PR 29 and
-    # since (ROADMAP S3 (6))
     ('float32', 2, 2048, 4, 128, True),
     ('float32', 2, 2048, 4, 64, True),
+    # the shapes ROADMAP S3 (6) listed as refused at Mosaic's default,
+    # one pass since the call asks for what its instance counts
+    # (common.one_pass_backward_vmem): bf16 at 4096 (23 MB counted, 39
+    # asked; the compiler had said 18.2 of 16) and at 8192 (40, 56),
+    # two tiles a trip under the raised limit; f32 at the cells' grids
+    # (27.5 MB counted, 43.5 asked)
+    ('bfloat16', 6, 4096, 12, 64, True),
+    ('bfloat16', 3, 8192, 12, 64, True),
+    ('float32', 12, 2048, 12, 64, True),
+    ('float32', 6, 2048, 16, 128, True),
+    # their dq + dkv twins (FUSED_BWD = False; 17.7 and 17.2 of 16 MB
+    # when S3 (6) was written) have asked since PR 38
+    ('float32', 12, 2048, 12, 64, False),
+    ('float32', 6, 2048, 16, 128, False),
 ])
 def test_flash_backward_fits_the_scoped_vmem(one_chip, as_on_tpu,
                                              monkeypatch, dtype, b, t, h,
@@ -377,7 +439,9 @@ def test_flash_backward_fits_the_scoped_vmem(one_chip, as_on_tpu,
     """The backward kernels with a key bias and the in-kernel draw at
     the shapes that decide whether an instance may hold a second
     score tile alive (common.room_for_second_tile; flash_attention.
-    _second_tile says what it is used for)."""
+    _second_tile says what it is used for), and at those that decide
+    whether the one-pass call has to ask Mosaic for more than its
+    default."""
     monkeypatch.setattr(flash_attention, 'FUSED_BWD', fused)
 
     def step(q, k, v, bias):
@@ -475,8 +539,7 @@ def test_the_wrapped_flash_op_over_a_dp_mesh_of_four(dp_mesh, as_on_tpu,
         'pallas/flash_attention/dispatch_sharded') == before + 2
     names = re.findall(
         r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
-    fused = attrs.get('window') is None and d == 64
-    assert len(names) == (2 if fused else 3), names   # fwd + bwd (dq, dkv)
+    assert len(names) == 2, names   # forward + one-pass backward
     assert {re.sub(r'\.\d+$', '', n) for n in names} == {named}, names
     assert not re.search(r'\[\d+,%d,%d,%d\]' % (h, t, t), text)
     assert not re.search(r'all-reduce|all-gather|all-to-all|'

@@ -554,13 +554,14 @@ def test_loop_runs_every_tile_once_and_in_order(lo, hi, tiles):
     (512, 64, 'bfloat16', False, (1, True), (1, True), (1, True)),
     (1024, 64, 'bfloat16', False, (1, True), (2, False), (2, False)),
     # olmoe: a dynamic bound, and no room beside 4096 rows of 128
-    (4096, 128, 'bfloat16', True, (1, False), (1, False), (1, False)),
+    # under Mosaic's default; the one-pass call asks for 38.5 MB
+    (4096, 128, 'bfloat16', True, (1, False), (1, False), (1, True)),
     (2048, 128, 'bfloat16', True, (1, True), (1, True), (1, True)),
     (2048, 128, 'bfloat16', False, (2, False), (2, False), (2, False)),
-    (8192, 64, 'bfloat16', False, (1, False), (1, False), (1, False)),
-    # f32 tiles count twice
-    (2048, 64, 'float32', False, (1, False), (1, False), (1, False)),
-    (1024, 128, 'float32', False, (1, False), (1, False), (1, False)),
+    (8192, 64, 'bfloat16', False, (1, False), (1, False), (2, False)),
+    # f32 tiles count twice; the one-pass calls ask for 43.5 and 30 MB
+    (2048, 64, 'float32', False, (1, False), (1, False), (2, False)),
+    (1024, 128, 'float32', False, (1, False), (1, False), (2, False)),
     (512, 64, 'float32', False, (1, True), (1, True), (1, True)),
 ])
 def test_the_second_tile_follows_the_vmem_model(t, d, dtype, causal, fwd,
@@ -570,7 +571,8 @@ def test_the_second_tile_follows_the_vmem_model(t, d, dtype, causal, fwd,
     common.room_for_second_tile): two tiles a loop trip where the
     trip count is even and known at trace time, else the backward's
     dO V^T issued early, and neither where two tiles do not fit
-    beside the instance's rows."""
+    beside the instance's rows under the scoped VMEM the call runs
+    with: Mosaic's default, or what the one-pass call asks for."""
     from paddle_tpu.ops.pallas import flash_attention as fa
     isz = jnp.dtype(dtype).itemsize
     bq, bk = fa._block_sizes(t, fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K,
@@ -580,10 +582,14 @@ def test_the_second_tile_follows_the_vmem_model(t, d, dtype, causal, fwd,
                            isz) == fwd
     assert fa._second_tile(None if causal else t // bq, rows, bq, bk,
                            isz) == dkv
+    from paddle_tpu.ops.pallas import common
     fq, fk = min(bq, fa.FUSED_BLOCK_Q), min(bk, fa.FUSED_BLOCK_K)
+    _, limit = common.one_pass_backward_limit(fa._one_pass_vmem(
+        t, t, d, d, fq, fk, isz, 1, False, False))
     assert fa._second_tile(
         None if causal else t // fq,
-        fa._fused_bwd_resident(t, d, fk, isz), fq, fk, isz) == fused
+        fa._fused_bwd_resident(t, d, fk, isz), fq, fk, isz,
+        limit) == fused
 
 
 @pytest.fixture
@@ -597,6 +603,193 @@ def fresh_calls():
     yield
     fa._fwd_call.clear_cache()
     fa._bwd_call.clear_cache()
+
+
+MB = 1 << 20
+
+
+@pytest.mark.parametrize('name,shape,dtype,count,asked', [
+    # (t, tk, d, dv, group, key bias, lse cotangent) -> MB an instance
+    # of the one-pass backward counts, and MB the call asks Mosaic for
+    # (None: nothing, the default; False: over the cap, two-pass)
+    ('bert_s2048', (2048, 2048, 64, 64, 1, True, False), 'bfloat16',
+     14.5, None),
+    ('bert_s512', (512, 512, 64, 64, 1, True, False), 'bfloat16',
+     8.125, None),
+    ('eva_local', (2048, 2048, 128, 128, 1, False, True), 'bfloat16',
+     14.375, None),
+    ('olmoe', (4096, 4096, 128, 128, 1, False, False), 'bfloat16',
+     22.5, 38.5),
+    ('laguna_window', (4096, 4096, 128, 128, 9, False, False),
+     'bfloat16', 26.5, 42.5),
+    ('laguna_full', (4096, 4096, 128, 128, 6, False, False), 'bfloat16',
+     26.5, 42.5),
+    ('lfm2', (8192, 8192, 64, 64, 4, False, False), 'bfloat16', 47.0,
+     63.0),
+    ('moonlight', (8192, 8192, 192, 128, 1, False, False), 'bfloat16',
+     59.0, 75.0),
+    # float32: the 8k rows of Moonlight pass the cap, LFM2's do not;
+    # BERT's s2048 b12, which ROADMAP S3 (6) lists as refused, asks
+    ('moonlight_f32', (8192, 8192, 192, 128, 1, False, False),
+     'float32', 109.0, False),
+    ('lfm2_f32', (8192, 8192, 64, 64, 4, False, False), 'float32', 81.0,
+     97.0),
+    ('bert_s2048_f32', (2048, 2048, 64, 64, 1, True, False), 'float32',
+     27.5, 43.5),
+    ('bert_s512_f32', (512, 512, 64, 64, 1, True, False), 'float32',
+     15.875, None),
+])
+def test_the_one_pass_backward_is_admitted_by_its_vmem_count(
+        name, shape, dtype, count, asked):
+    """common.one_pass_backward_vmem at the cells' shapes and
+    [512, 512] tiles: rows in two buffers and in the lanes they lie
+    in, f32 scratch, vectors, two chains' tiles.  Under Mosaic's 16
+    MiB default the call asks for nothing; over it, for the count and
+    16 MB; past 100 MB the two-pass kernels run."""
+    from paddle_tpu.ops.pallas import common
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    t, tk, d, dv, group, bias, glse = shape
+    got = fa._one_pass_vmem(t, tk, d, dv, min(512, t), min(512, tk),
+                            jnp.dtype(dtype).itemsize, group, bias, glse)
+    assert got == count * MB
+    admitted, limit = common.one_pass_backward_limit(got)
+    assert admitted == (asked is not False)
+    if admitted:
+        assert limit == (None if asked is None else asked * MB)
+        assert (limit is None) == (got <= common.SCOPED_VMEM_BYTES)
+    else:
+        assert limit > common.VMEM_LIMIT_CAP_BYTES
+
+
+def _mosaic_limits(fn, *specs):
+    """``vmem_limit_bytes`` of each pallas_call fn traces for a chip
+    (None where the call passes no compiler_params at all)."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == 'pallas_call':
+                params = dict(eqn.params['compiler_params'])
+                found.append(params['mosaic_tpu'].vmem_limit_bytes
+                             if params else None)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(fn)(*specs).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize('shape,kwargs,limits', [
+    # bert_base_s2048's and s512_b48's calls: no compiler_params, on
+    # the forward or on the one-pass backward, as before PR 42
+    ((2, 2048, 12, 12, 64, 64), dict(bias=True, rate=0.1), [None, None]),
+    ((2, 512, 12, 12, 64, 64), dict(bias=True, rate=0.1), [None, None]),
+    # OLMoE's: the backward asks, the forward does not
+    ((1, 4096, 2, 2, 128, 128), dict(causal=True), [None, 38.5 * MB]),
+    # Moonlight's: both ask, the forward by common.scoped_vmem
+    ((1, 8192, 2, 2, 192, 128), dict(causal=True),
+     [33.375 * MB, 75 * MB]),
+])
+def test_a_call_under_the_default_asks_mosaic_for_nothing(
+        monkeypatch, fresh_calls, shape, kwargs, limits):
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.ops.pallas import common
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.setattr(common, 'on_tpu', lambda: True)
+    b, t, h, hkv, d, dv = shape
+
+    def step(q, k, v, bias):
+        def loss(q, k, v):
+            o = fa.flash_attention(
+                q, k, v, causal=kwargs.get('causal', False),
+                key_bias=bias if kwargs.get('bias') else None,
+                dropout_rate=kwargs.get('rate', 0.0),
+                dropout_seed=jnp.uint32(7))
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    monitor.remove_gauge('pallas/flash_attention/vmem_asked_max')
+    got = _mosaic_limits(
+        step, *(jax.ShapeDtypeStruct((b, t, n, w), jnp.bfloat16)
+                for n, w in ((h, d), (hkv, d), (hkv, dv))),
+        jax.ShapeDtypeStruct((b, t), jnp.float32))
+    assert got == limits
+    assert monitor.gauge_value('pallas/flash_attention/vmem_asked_max') \
+        == max(x or 0 for x in limits)
+
+
+@pytest.mark.parametrize('shape,window', [
+    ((1, 1024, 2, 2, 192, 128), 0),     # two widths, as Moonlight's
+    ((1, 1024, 4, 2, 128, 128), 512),   # grouped and banded (Laguna)
+    ((1, 1024, 2, 2, 128, 128), 0),     # causal d128 (OLMoE, EvaByte)
+], ids=['qk192v128', 'grouped_banded', 'causal_d128'])
+def test_one_pass_backward_parity_at_the_cells_blocks(shape, window):
+    """The one-pass backward with [512, 512] tiles, two key blocks by
+    two query blocks under the diagonal, against the dense chain on
+    the same mask: the widths, grouping and band of the calls that
+    ran the dq + dkv kernels before PR 42."""
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    b, t, h, hkv, d, dv = shape
+    rng = np.random.RandomState(11)
+    q = jnp.asarray(rng.randn(b, t, h, d), jnp.float32)
+    k = jnp.asarray(rng.randn(b, t, hkv, d), jnp.float32)
+    v = jnp.asarray(rng.randn(b, t, hkv, dv), jnp.float32)
+    cot = jnp.asarray(rng.randn(b, t, h, dv), jnp.float32)
+
+    def f(q, k, v):
+        return jnp.vdot(fa.flash_attention(q, k, v, causal=True,
+                                           window=window), cot)
+
+    def r(q, k, v):
+        return jnp.vdot(fa._dense_path(q, k, v, True, None,
+                                       window=window), cot)
+
+    before = monitor.counter_value(
+        'pallas/flash_attention/backward_one_pass')
+    got = jax.grad(f, (0, 1, 2))(q, k, v)
+    assert monitor.counter_value(
+        'pallas/flash_attention/backward_one_pass') == before + 1
+    for a, w in zip(got, jax.grad(r, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_the_backward_kind_is_counted_and_shown_in_statusz(monkeypatch):
+    """pallas/flash_attention/backward_one_pass and backward_two_pass
+    count the lowerings where _flash_bwd decides, and common.report()
+    (the /statusz section) shows them with the largest VMEM asked."""
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.ops.pallas import common
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    names = ['pallas/flash_attention/backward_%s' % kind
+             for kind in ('one_pass', 'two_pass')]
+    q = jnp.asarray(np.random.RandomState(3).randn(1, 32, 2, 8),
+                    jnp.float32)
+
+    def grad():
+        return jax.grad(lambda x: jnp.sum(
+            fa.flash_attention(x, q, q, causal=True, min_seq=0)))(q)
+
+    before = [monitor.counter_value(n) for n in names]
+    one = grad()
+    assert [monitor.counter_value(n) for n in names] == \
+        [before[0] + 1, before[1]]
+    # what the count refuses runs the dq + dkv kernels, same numbers
+    monkeypatch.setattr(common, 'VMEM_LIMIT_CAP_BYTES', 0)
+    monkeypatch.setattr(common, 'SCOPED_VMEM_BYTES', 0)
+    two = grad()
+    assert [monitor.counter_value(n) for n in names] == \
+        [before[0] + 1, before[1] + 1]
+    np.testing.assert_allclose(np.asarray(one), np.asarray(two),
+                               rtol=1e-5, atol=1e-6)
+    monitor.set_gauge('pallas/flash_attention/vmem_asked_max', 75 * MB)
+    entry = common.report()['kernels']['flash_attention']
+    assert entry['backward_one_pass'] == before[0] + 1
+    assert entry['backward_two_pass'] == before[1] + 1
+    assert entry['vmem_asked_max'] == 75 * MB
+    from paddle_tpu.fluid import health
+    assert health.statusz()['pallas']['kernels']['flash_attention'][
+        'backward_one_pass'] == before[0] + 1
 
 
 @pytest.mark.parametrize('tokens,room', [
@@ -615,8 +808,9 @@ def test_flash_parity_with_and_without_a_second_tile(
     for name in ('DEFAULT_BLOCK_Q', 'DEFAULT_BLOCK_K', 'FUSED_BLOCK_Q',
                  'FUSED_BLOCK_K'):
         monkeypatch.setattr(fa, name, 16)
-    if not room:
+    if not room:    # neither by default nor by asking (the one-pass call)
         monkeypatch.setattr(common, 'SCOPED_VMEM_BYTES', 0)
+        monkeypatch.setattr(common, 'VMEM_HEADROOM_BYTES', 0)
     assert fa._second_tile(tokens // 16, 0, 16, 16, 2) == {
         (128, True): (2, False), (48, True): (1, True),
         (48, False): (1, False)}[tokens, room]

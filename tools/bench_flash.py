@@ -19,7 +19,14 @@ the module's _flash_fwd / _flash_bwd on [B*H, T, D] operands: the
 Mosaic kernels plus, in the backward, the one XLA reduce that makes
 delta; the [B, T, H, D] transposes around them are not in the times.
 A shape the chip's compiler refuses is a row with ``error`` and no
-time.  --dense-parity adds, per row, how far each output lies from
+time.  --kv-heads, --v-dim and --window reach the grouped, latent and
+banded calls (Laguna: --heads 48 --kv-heads 8 --dims 128 --causal
+[--window 512]; Moonlight: --heads 16 --dims 192 --v-dim 128
+--causal), and every row says what the one-pass backward's instance
+counts in VMEM and what the call asks Mosaic for (``bwd_vmem_mb``,
+``bwd_asked_mb``: 0 where it asks for nothing; the dq + dkv calls of
+--two-pass ask by rules of their own).  --dense-parity adds, per
+row, how far each output lies from
 the module's own dense chain (_dense_path: same operands, same mask)
 as a share of that output's largest entry.
 
@@ -94,12 +101,13 @@ def measure(fa, args, h, operands, bias, rate):
     (min, median) a call, and the outputs of one forward + backward as
     float32 numpy arrays, in OUTPUTS' order)."""
     q, k, v, do = operands
-    fa.FUSED_BWD = not args.two_pass
     seed = fa._pack_seed(jnp.uint32(args.seed + 1), (3, 5), 7) \
         if rate else None
     static = dict(
         h=h, causal=args.causal, block_q=fa.DEFAULT_BLOCK_Q,
         block_k=fa.DEFAULT_BLOCK_K, rate=rate, interpret=False)
+    if args.window:     # a parent from before the banded calls has none
+        static['window'] = args.window
     fwd = jax.jit(functools.partial(fa._flash_fwd, **static))
     bwd = jax.jit(functools.partial(fa._flash_bwd, g_lse=None, **static))
     o, lse = fwd(q, k, v, bias, seed)
@@ -112,8 +120,13 @@ def measure(fa, args, h, operands, bias, rate):
     n = args.inner
 
     def fwd_n(q, k, v, bias, seed):
-        return jax.lax.fori_loop(
-            0, n, lambda _, q: fwd(q, k, v, bias, seed)[0], q)
+        def chain(_, q):
+            o = fwd(q, k, v, bias, seed)[0]
+            # values of another width than q: the next q hangs on a
+            # column of o (one more pass over q in the time)
+            return o if o.shape == q.shape else \
+                q + (o[..., :1] * 0).astype(q.dtype)
+        return jax.lax.fori_loop(0, n, chain, q)
 
     def bwd_n(q, k, v, bias, seed, o, lse, do):
         return jax.lax.fori_loop(
@@ -132,7 +145,7 @@ def dense_outputs(fa, args, b, h, operands, bias, rate):
     """o, dq, dk, dv (and dbias) of the module's dense chain on the
     same operands and the same mask, as [B*H, T, D] float32 numpy
     arrays; two samples at a time, so the [2, H, T, T] scores fit."""
-    q, k, v, do = ([x.reshape(b, h, *x.shape[1:]).transpose(0, 2, 1, 3)
+    q, k, v, do = ([x.reshape(b, -1, *x.shape[1:]).transpose(0, 2, 1, 3)
                     for x in operands])
 
     @jax.jit
@@ -140,7 +153,8 @@ def dense_outputs(fa, args, b, h, operands, bias, rate):
         def f(q, k, v, bias):
             return fa._dense_path(
                 q, k, v, args.causal, bias, rate,
-                jnp.uint32(args.seed + 1), (3, 5), g_off)
+                jnp.uint32(args.seed + 1), (3, 5), g_off,
+                **({'window': args.window} if args.window else {}))
         o, vjp = jax.vjp(f, q, k, v, bias)
         return (o,) + vjp(do)
 
@@ -158,31 +172,61 @@ def dense_outputs(fa, args, b, h, operands, bias, rate):
         x = np.concatenate([np.asarray(x.astype(jnp.float32))
                             for x in xs])
         outs.append(x if i == 4 else
-                    x.transpose(0, 2, 1, 3).reshape(b * h, *x.shape[1:2],
+                    x.transpose(0, 2, 1, 3).reshape(-1, x.shape[1],
                                                     x.shape[3]))
     return outs
+
+
+def one_pass_vmem(fa, args, t, d, dv, group, has_bias):
+    """What an instance of the one-pass backward counts and what its
+    call asks Mosaic for, in MB, by the implementation's own rule
+    (none in a copy from before PR 42); 0 asked: nothing, the
+    compiler's default."""
+    if not hasattr(fa, '_one_pass_vmem'):
+        return {}
+    item = jnp.dtype(args.dtype).itemsize
+    blocks = fa._one_pass_blocks(t, t, *fa._window_blocks(
+        fa._block_sizes(t, fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K, d,
+                        item, dv), args.window))
+    count = fa._one_pass_vmem(t, t, d, dv, *blocks, item, group,
+                              has_bias, False)
+    admitted, limit = fa._common.one_pass_backward_limit(count)
+    return dict(bwd_vmem_mb=round(count / 2 ** 20, 2),
+                bwd_asked_mb=round((limit or 0) / 2 ** 20, 2),
+                one_pass_admitted=admitted)
 
 
 def bench_calls(args):
     impls = [load_impl(s) for s in (args.impl or ['tree'])]
     h, d = args.heads or 12, args.dims[0]
+    hkv, dv = args.kv_heads or h, args.v_dim or d
     device = jax.devices()[0].device_kind
     rows = []
     for shape in args.shapes:
         b, t = (int(x) for x in shape.split('x'))
         rng = np.random.RandomState(args.seed)
-        operands = [jnp.asarray(rng.randn(b * h, t, d),
-                                jnp.dtype(args.dtype)) for _ in range(4)]
+        # q, k, v, dO as the kernels take them: [B*H | B*Hkv, T, D | Dv]
+        operands = [jnp.asarray(rng.randn(b * n, t, w),
+                                jnp.dtype(args.dtype))
+                    for n, w in ((h, d), (hkv, d), (hkv, dv), (h, dv))]
         # a padding mask as models/bert.py builds it: 0 / -10000
         bias_full = jnp.asarray(
             np.where(rng.rand(b, t) < 0.1, -10000.0, 0.0), jnp.float32)
         for has_bias, rate in itertools.product(args.key_bias, args.rate):
             first = None
             for name, fa in impls:
+                fa.FUSED_BWD = not args.two_pass
+                if args.fused_blocks:
+                    fa.FUSED_BLOCK_Q, fa.FUSED_BLOCK_K = args.fused_blocks
                 row = dict(device=device, impl=name, b=b, t=t, h=h, d=d,
+                           hkv=hkv, dv=dv, window=args.window,
                            dtype=args.dtype, bias=int(has_bias),
                            rate=rate, causal=int(args.causal),
-                           fused_bwd=int(not args.two_pass))
+                           fused_bwd=int(not args.two_pass),
+                           fused_blocks=[fa.FUSED_BLOCK_Q,
+                                         fa.FUSED_BLOCK_K])
+                row.update(one_pass_vmem(fa, args, t, d, dv, h // hkv,
+                                         has_bias))
                 bias = bias_full if has_bias else None
                 try:
                     f_ms, b_ms, outs = measure(fa, args, h, operands,
@@ -333,6 +377,15 @@ def main():
     ap.add_argument('--dims', type=int, nargs='+', default=[64, 128],
                     help='head dims (the call bench takes the first)')
     ap.add_argument('--causal', action='store_true')
+    ap.add_argument('--kv-heads', type=int, default=None,
+                    help='K/V heads (default: as many as --heads)')
+    ap.add_argument('--v-dim', type=int, default=None,
+                    help="the values' width (default: the first --dims)")
+    ap.add_argument('--window', type=int, default=0,
+                    help='band of the causal mask (with --causal)')
+    ap.add_argument('--fused-blocks', type=int, nargs=2, default=None,
+                    metavar=('Q', 'K'),
+                    help='sweep: FUSED_BLOCK_Q / FUSED_BLOCK_K')
     # the call bench
     ap.add_argument('--shapes', nargs='+', default=['12x2048', '48x512'],
                     help='BATCHxSEQ of each call')
