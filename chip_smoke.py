@@ -103,9 +103,9 @@ def _ints32(batch):
 
 
 def build_bert(cfg, seq):
-    """bench_bert_long's program: BERT pretrain, bf16 AMP with dynamic
-    loss scaling around Adam.  Also returns the for_test clone taken
-    before minimize (forward only, dropout off)."""
+    """BERT pretrain, bf16 AMP with dynamic loss scaling around Adam.
+    Also returns the for_test clone taken before minimize (forward
+    only, dropout off)."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu import models
     main, startup = fluid.Program(), fluid.Program()
@@ -129,7 +129,7 @@ def host_batch(cfg, batch, seq):
 def train_fresh(cfg, batch, seq, steps):
     """Build the program, run startup in a fresh scope and train
     `steps` steps on the device-resident fixed batch (uncommitted, as
-    bench._timed_steps feeds).  Returns train_steps()'s triple."""
+    the benchmark feeds).  Returns train_steps()'s triple."""
     import jax
     import paddle_tpu.fluid as fluid
     main, startup, _, loss = build_bert(cfg, seq)

@@ -139,7 +139,7 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def place_jax_cache():
     """The one rule for where JAX's persistent compilation cache lives
-    (chip_smoke.py, bench.py and the segment store all call this and
+    (chip_smoke.py and the segment store both call this and
     nothing else sets the directory): where JAX_COMPILATION_CACHE_DIR
     is set, JAX has already read it and no code moves it; otherwise
     ``<checkout>/.jax_cache``, computed from this file's location — the

@@ -31,7 +31,6 @@ from . import faultinject as _finject
 from . import framework
 from . import memviz as _memviz
 from . import monitor
-from . import opprof as _opprof
 from . import supervisor as _sup
 from . import timeseries as _tseries
 from . import trace as _trace
@@ -353,8 +352,8 @@ def _survivable_copy(v):
 
 
 def _segment_label(seg, comms_key=None):
-    """A segment's name in watchdog dumps, op-cost snapshots and
-    estimated memory rows."""
+    """A segment's name in watchdog dumps and estimated memory
+    rows."""
     if comms_key is not None:
         return '%dops@%s' % (len(seg.ops), str(comms_key)[:8])
     return '%dops:%s' % (len(seg.ops),
@@ -411,17 +410,10 @@ def _lower_ops(ops, env, step, prefer_test):
                     'conditional_block': _lower_conditional_block,
                     'while_grad': _lower_while_grad,
                     'conditional_block_grad': _lower_conditional_block_grad}
-    # instance-suffixed scope names (FLAGS_opprof): read once per
-    # lowering walk — lowerings run at trace time, never per step.
-    # Scope names do not enter compile_cache.fingerprint (it hashes
-    # op descs + specs + lowering flags), so this flag is
-    # fingerprint-neutral: flipping it causes zero retraces.
-    inst = _opprof.instancing()
     for op in ops:
         cf = CF_LOWERINGS.get(op.type)
         if cf is not None:
-            with jax.named_scope(_opprof.op_scope(op) if inst
-                                 else op.type):
+            with jax.named_scope(op.type):
                 cf(op, env, step, prefer_test)
             continue
         opdef = registry.get(op.type)
@@ -442,11 +434,8 @@ def _lower_ops(ops, env, step, prefer_test):
             # per-op trace attribution: the reference wraps every op run
             # in a profiler RecordEvent (framework/operator.cc:170); here
             # the scope name flows into XLA op metadata so Perfetto
-            # traces and HLO dumps read as fluid op names — with the
-            # '#<block-index>' instance suffix under FLAGS_opprof, so
-            # two fc layers stay distinguishable in a capture
-            with jax.named_scope(_opprof.op_scope(op) if inst
-                                 else op.type):
+            # traces and HLO dumps read as fluid op names
+            with jax.named_scope(op.type):
                 outs = opdef.run(ctx, ins, op.attrs)
         except Exception as e:
             # enforce-style error context (reference: PADDLE_ENFORCE +
@@ -1257,7 +1246,6 @@ class Executor(object):
     def __init__(self, place=None):
         self.place = place or core.XLAPlace(0)
         self._step = 0
-        self._opprof_step = False
         self._posture = (False, False, 0.0)
         # FLAGS_status_port: the status/metrics HTTP plane starts with
         # the first executor (no-op when the flag is 0 or a server is
@@ -1710,10 +1698,8 @@ class Executor(object):
             _finject.check('executor.step', step=self._step)
         t0 = _time_mod.perf_counter()
         # the step's debugging posture, read once here and not per
-        # segment: the op-cost snapshot decision (fluid.opprof), the
-        # NaN sweep, the tensor-health summaries, the hung-step
-        # watchdog's deadline
-        self._opprof_step = _opprof.want_snapshot(self._step)
+        # segment: the NaN sweep, the tensor-health summaries, the
+        # hung-step watchdog's deadline
         self._posture = (
             bool(get_flag('FLAGS_check_nan_inf')),
             bool(get_flag('FLAGS_health_summaries')),
@@ -2405,8 +2391,8 @@ class Executor(object):
         one chip: no collective to account, and the compile plane
         recorded the exact row when it built the executable.
         `replayable`: the segment's ops can be run again one by one,
-        eagerly, on copies of these arguments (NaN provenance, op-cost
-        attribution).  Not under a mesh: c_* lowerings need shard_map's
+        eagerly, on copies of these arguments (NaN provenance).  Not
+        under a mesh: c_* lowerings need shard_map's
         bound axis names, mesh-aware lowerings need the trace mesh.
         `aot_fallback`: builds the call to retry with when an AOT
         executable refuses an argument's kind (the compile plane's
@@ -2416,7 +2402,7 @@ class Executor(object):
         of spec leaves, not variables."""
         step = self._step
         check_nan, health_on, step_timeout = self._posture
-        replay = opprof_snap = opprof_wall = prev_params = hp = None
+        replay = prev_params = hp = None
         if check_nan and replayable and get_flag('FLAGS_nan_replay',
                                                  True):
             # the op-by-op provenance replay needs the segment inputs
@@ -2426,16 +2412,6 @@ class Executor(object):
             with _trace.span('nan_snapshot'):
                 replay = ({n: _survivable_copy(v)
                            for n, v in state.items()}, dict(data))
-        if self._opprof_step and replayable:
-            # op-cost replay snapshot (fluid.opprof): same survivable-
-            # copy rule as the nan path — the donated state buffers
-            # are gone after the step; reuse a live nan snapshot
-            # instead of copying twice
-            if replay is not None:
-                opprof_snap = (dict(replay[0]), dict(data))
-            else:
-                opprof_snap = ({n: _survivable_copy(v)
-                                for n, v in state.items()}, dict(data))
         if health_on:
             hp = seg.health_params
             if hp is None:
@@ -2501,21 +2477,6 @@ class Executor(object):
                         out = _sup.guard_dispatch(
                             watched, _segment_label(seg, comms_key),
                             step_timeout, step=step)
-                elif opprof_snap is not None:
-                    # opprof snapshot step: park the sync INSIDE the
-                    # dispatch span so the measured wall — the eager-
-                    # replay normalization target — is this segment's
-                    # synchronous device time, and step_report's
-                    # dispatch phase carries the same number the
-                    # attribution sums are checked against.  Costs the
-                    # dispatch/compute overlap on snapshot steps only
-                    # (an opt-in profiling posture).
-                    with _dispatch_span(comms_key, recs):
-                        t_sync0 = _time_mod.perf_counter()
-                        out = run()
-                        jax.block_until_ready(out)
-                        opprof_wall = (_time_mod.perf_counter() -
-                                       t_sync0)
                 else:
                     with _dispatch_span(comms_key, recs):
                         out = run()
@@ -2576,11 +2537,6 @@ class Executor(object):
                 if note:
                     _add_note(e, note)
             raise
-        if opprof_wall is not None:
-            _opprof.note_segment(
-                _memviz.current_program(), _segment_label(seg),
-                seg.ops, opprof_snap[0], opprof_snap[1], step,
-                seg.prefer_test, opprof_wall)
         if check_nan:
             self._check_nan_inf(out, seg=seg, replay=replay)
         if health_on and hp[0]:

@@ -169,7 +169,7 @@ _DEFAULTS = {
     # jax.live_arrays() classified param/state/feed/exec/other into
     # memviz/live_bytes/* gauges and a Perfetto counter track merged
     # into the step timeline.  Off (the default) the executor pays one
-    # flag read per step (bench.py --smoke memviz_overhead proves it);
+    # flag read per step (tools/check_memviz.py holds it to that);
     # peak ATTRIBUTION (per-(program, segment) decomposition of each
     # AOT executable's memory_analysis()) and OOM forensics are always
     # on — they run at compile/incident time, never per step.
@@ -190,21 +190,6 @@ _DEFAULTS = {
     # rate limits for the detector and OOM-incident flight dumps
     'FLAGS_memviz_dump_interval_s': 60.0,
     'FLAGS_memviz_oom_interval_s': 30.0,
-    # op-level cost attribution plane (fluid/opprof.py): FLAGS_opprof
-    # turns on (a) instance-suffixed per-op scope names
-    # ('<type>#<block-index>', trace-time only, fingerprint-neutral —
-    # flipping it retraces nothing) so device captures resolve to a
-    # specific op desc, and (b) the per-step replay-snapshot sampler:
-    # on snapshot steps the executor stashes each warmed segment's
-    # bound inputs + measured synchronous wall for the on-demand
-    # eager replay profiler (/opprof, tools/op_costs.py).  Off (the
-    # default) the executor pays one flag read per step (bench.py
-    # --smoke opprof_overhead proves it).
-    'FLAGS_opprof': False,
-    # snapshot cadence: stash replay inputs every N'th step (snapshot
-    # steps sync the dispatch to measure the segment wall, losing
-    # overlap, so they are thinned by default)
-    'FLAGS_opprof_snapshot_steps': 16,
     # auto-sharding planner (parallel/plan.py): with the flag on, an
     # UNANNOTATED CompiledProgram (no with_mesh / with_param_shardings)
     # is planned automatically — regex rule -> PartitionSpec matching
@@ -389,9 +374,8 @@ _DEFAULTS = {
     # XLA reference runs instead, and the decision + reason land in
     # pallas/<kernel>/dispatch_* counters surfaced at /statusz.
     # FLAGS_pallas_force promotes the fused path even off-TPU
-    # (interpret mode) — the knob parity tests and bench A/Bs use to
-    # exercise the kernels on the CPU mesh; never set it in
-    # production.
+    # (interpret mode) — the knob parity tests use to exercise the
+    # kernels on the CPU mesh; never set it in production.
     'FLAGS_pallas_force': False,
     # fused block-scaled quantize->reduce-scatter for the quantized
     # collective arm: the int8 copy + fp32 dequant temporaries of the

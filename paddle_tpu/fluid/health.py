@@ -354,19 +354,6 @@ def statusz():
             pallas_section = rep
     except Exception:
         pass
-    # op-level cost attribution (fluid.opprof): top-K instances by
-    # attributable ms/step with type/layer rollups — 'which op desc
-    # costs this step its milliseconds' in one scrape; rendered once
-    # the plane is on or has attributed anything (the on-demand
-    # replay lives at /opprof, this section only reads the registry)
-    op_costs_section = None
-    try:
-        from . import opprof
-        rep = opprof.report()
-        if rep.get('enabled') or rep.get('top') or rep.get('snapshots'):
-            op_costs_section = rep
-    except Exception:
-        pass
     # aggregator rank: per-rank liveness + last-heartbeat skew, so one
     # /statusz answers 'is the job healthy and who is the straggler'
     job_section = None
@@ -392,7 +379,6 @@ def statusz():
         'autopilot': autopilot_section,
         'fleet': fleet_section,
         'pallas': pallas_section,
-        'op_costs': op_costs_section,
         'job': job_section,
         'flags': _all_flags(),
         'versions': versions,
@@ -999,21 +985,13 @@ def _make_handler(aggregator):
                 elif path == '/alertz':
                     from . import slo
                     self._send_json(200, slo.alertz())
-                elif path == '/opprof':
-                    # on-demand eager replay over the stashed warmed
-                    # segments + the ranked kernel worklist; bounded
-                    # by the snapshot registry, runs on this handler
-                    # thread (eager jax is thread-safe alongside the
-                    # training loop)
-                    from . import opprof
-                    self._send_json(200, opprof.http_report())
                 else:
                     self._send_json(404, {
                         'error': 'unknown path %s' % path,
                         'paths': ['/metrics', '/metrics.json',
                                   '/metrics/local', '/healthz',
                                   '/healthz/local', '/statusz',
-                                  '/timeseries', '/alertz', '/opprof',
+                                  '/timeseries', '/alertz',
                                   '/trace/dump', '/trace/collect']})
             except Exception as e:  # a broken handler must not kill
                 monitor.add('health/http_errors')

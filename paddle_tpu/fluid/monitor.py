@@ -25,8 +25,7 @@ Key convention: '/'-separated paths ('executor/segment_cache_hit');
 snapshot() nests on '/'.  Three export surfaces:
 
 - snapshot(): nested dict for tests/tools;
-- dump_jsonl(path, step=...): append ONE json line (trajectory files,
-  BENCH_*.json style);
+- dump_jsonl(path, step=...): append ONE json line (trajectory files);
 - prometheus_text(): text exposition format for scraping.
 """
 
@@ -133,7 +132,7 @@ def histogram_value(name):
 
 def reset():
     """Drop every stat (platform::StatRegistry has STAT_RESET per stat;
-    tests and per-entry bench subprocesses want the whole registry)."""
+    tests want the whole registry)."""
     _counters.clear()
     _gauges.clear()
     _hists.clear()
@@ -192,7 +191,7 @@ def raw_state():
 
 def dump_jsonl(path, step=None, extra=None):
     """Append ONE json line holding the full registry — call once per
-    step (or per bench entry) to build a trajectory file that
+    step to build a trajectory file that
     tools/stat_summary.py renders or diffs."""
     rec = {'ts': time.time()}
     if step is not None:

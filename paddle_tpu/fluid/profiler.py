@@ -136,26 +136,20 @@ def _registered_op_types():
     return set(registry._REGISTRY)
 
 
-def _resolve_component(comp, op_types, per_instance):
+def _resolve_component(comp, op_types):
     """One scope-path component -> attribution name or None.  Strips
-    transform wrappers (transpose(jvp(relu))) and, in per-instance
-    mode, resolves '<type>#<idx>' instance suffixes (the FLAGS_opprof
-    scope names) to the full instance name."""
+    transform wrappers (transpose(jvp(relu)))."""
     base = comp
     while '(' in base and base.endswith(')'):
         base = base[base.index('(') + 1:-1]
     for cand in (comp, base):
         if _is_op_type(cand, op_types):
             return cand
-        if per_instance and '#' in cand:
-            typ = cand.rsplit('#', 1)[0]
-            if typ in op_types:
-                return cand
     return None
 
 
-def attribute_trace_events(events, op_types=None, per_instance=False,
-                           with_stats=False, costs=None):
+def attribute_trace_events(events, op_types=None, with_stats=False,
+                           costs=None):
     """Map device-trace kernel events back to fluid op types.
 
     `events` are chrome-trace events (``load_trace_events``, or a
@@ -170,15 +164,6 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
     'unattributed/<hlo name>'.  Returns {name: [calls, total_s, max_s,
     min_s]}.
 
-    `per_instance=True` (the fluid.opprof mode) resolves the
-    '<type>#<block-index>' instance scopes FLAGS_opprof emits, and
-    splits FUSED kernel time across constituent ops: a fusion event
-    whose tf_op carries multiple ';'/','-separated source paths has
-    its duration divided equally among them, with the shares of
-    unresolvable constituents filed under the honest
-    'unattributed/<hlo name>' bucket rather than inflating the ops
-    that did match.
-
     Tolerant by contract: real captures contain malformed rows (counter
     events without dur, instant events, non-string tf_op metadata,
     null fields) — those are skipped or zero-timed, never raised on,
@@ -188,7 +173,7 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
 
     `costs`, a dict, receives {name: [GFLOP or None, MB]} summed from
     the events that carry a cost (``load_trace_events`` gives
-    args['mb'] and, where known, args['gflop']), split as the time is;
+    args['mb'] and, where known, args['gflop']);
     None once one event of the name has bytes and no FLOPs.
 
     Both positive and negative lookups are cached per tf_op string
@@ -196,7 +181,7 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
     the negative cache every repeat re-splits the path)."""
     op_types = op_types or _registered_op_types()
     recs = {}
-    cache = {}   # tf_op -> tuple(resolved names) | () for negative
+    cache = {}   # tf_op -> resolved name | False for negative
     n_events = n_attr = dropped = 0
 
     def _fold(name, sec, cost=None):
@@ -232,47 +217,29 @@ def attribute_trace_events(events, op_types=None, per_instance=False,
             sec = float(e.get('self_dur', e.get('dur')) or 0) * 1e-6
         except (TypeError, ValueError):
             sec = 0.0
-        hit = cache.get(tf_op)
-        if hit is None:
-            if per_instance:
-                # fusion events carry multiple source paths; each path
-                # resolves (or not) independently
-                paths = [p for p in re.split('[;,]', tf_op) if p]
-            else:
-                paths = [tf_op]
-            resolved = []
-            for p in paths:
-                name = None
-                for comp in p.split('/'):
-                    name = _resolve_component(comp, op_types,
-                                              per_instance)
-                    if name is not None:
-                        break
-                resolved.append(name)
-            hit = tuple(resolved)
-            cache[tf_op] = hit   # negative ((None,)*n) cached too
-        matched = [n for n in hit if n is not None]
-        cost = part = None
+        name = cache.get(tf_op)
+        if name is None:
+            name = False
+            for comp in tf_op.split('/'):
+                hit = _resolve_component(comp, op_types)
+                if hit is not None:
+                    name = hit
+                    break
+            cache[tf_op] = name
+        cost = None
         if isinstance(args.get('mb'), (int, float)):
             gflop = args.get('gflop')
             if not isinstance(gflop, (int, float)):
                 gflop = None
             cost = (gflop, args['mb'])
-            part = (gflop and gflop / len(hit), args['mb'] / len(hit))
-        if not matched:
+        if not name:
             # per-HLO-name bucket: distinct kernels share a scope
             # path, so the bucket keys on the event name instead
             _fold('unattributed/' +
                   str(e.get('name', '?')).split('.')[0], sec, cost)
             continue
         n_attr += 1
-        share = sec / len(hit)
-        leftover = share * (len(hit) - len(matched))
-        for name in matched:
-            _fold(name, share, part)
-        if leftover > 0:
-            _fold('unattributed/' +
-                  str(e.get('name', '?')).split('.')[0], leftover)
+        _fold(name, sec, cost)
     if with_stats:
         return recs, {'events': n_events, 'attributed': n_attr,
                       'dropped': dropped}
@@ -408,7 +375,6 @@ def fluid_scope(op_name, op_types=None):
             backward = backward or m.group(1) == 'transpose'
             comp = m.group(2)
             m = _TRANSFORMS.match(comp)
-        comp = comp.split('#', 1)[0]    # FLAGS_opprof's instance suffix
         if not _is_op_type(comp, op_types):
             continue
         if backward and not comp.endswith('_grad'):

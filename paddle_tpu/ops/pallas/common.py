@@ -64,9 +64,7 @@ def register_kernel(name, dense_fallback, has_vjp=False, doc='',
     path string — documentation + check_kernels assertion, not a
     callable, so registration never imports lowering code).
     ``op_types`` names the fluid op types the kernel's fused launch
-    subsumes — the coverage metadata ``fluid.opprof.kernel_worklist``
-    cross-references to mark candidate op runs already served by an
-    existing kernel."""
+    subsumes: documentation, nothing reads it."""
     if not dense_fallback:
         raise ValueError('pallas kernel %r must declare its dense '
                          'fallback' % (name,))
@@ -78,21 +76,6 @@ def register_kernel(name, dense_fallback, has_vjp=False, doc='',
 
 def kernels():
     return dict(KERNELS)
-
-
-def covering_kernel(op_types):
-    """Name of the registered kernel whose declared ``op_types``
-    coverage subsumes every type in `op_types`, or None — the
-    worklist's 'already fused' cross-reference.  Deterministic: first
-    match in sorted registry order."""
-    ts = set(op_types)
-    if not ts:
-        return None
-    for name in sorted(KERNELS):
-        cover = set(KERNELS[name].get('op_types') or ())
-        if cover and ts <= cover:
-            return name
-    return None
 
 
 def on_tpu():

@@ -1,5 +1,5 @@
 """Subprocess worker for the job-wide observability tests/gates
-(tools/check_comms.py, tests/test_comms.py, bench.py --parallel):
+(tools/check_comms.py, tests/test_comms.py):
 boots a REAL executor on a GradAllReduce-transpiled program (the
 collective runner path — c_allreduce_sum per grad over the 'dp' mesh
 of this process's devices), enables the fluid.trace flight recorder,

@@ -62,49 +62,6 @@ def test_place_jax_cache_set_is_left_alone(monkeypatch, tmp_path,
         compile_cache.reset_plane()
 
 
-def test_chip_peak_unknown_kind_raises(monkeypatch):
-    import bench
-
-    class Dev(object):
-        platform = 'tpu'
-        device_kind = 'TPU v99'
-
-    monkeypatch.setattr(jax, 'devices', lambda *a: [Dev()])
-    with pytest.raises(KeyError, match='TPU v99'):
-        bench._chip_peak()
-    with pytest.raises(KeyError):       # not swallowed into {} either
-        bench._perf_fields(0.1, {'flops': 1e12, 'bytes': 1e9})
-    Dev.device_kind = 'TPU v5 lite'
-    assert bench._chip_peak() == (197.0, 819.0)
-    Dev.platform, Dev.device_kind = 'cpu', 'cpu'
-    assert bench._perf_fields(0.1, {'flops': 1e12}) == {}
-
-
-def test_bench_takes_no_result_from_a_failed_child(monkeypatch, capsys,
-                                                   jax_cache_config):
-    import bench
-
-    class Child(object):
-        returncode = 1
-        stdout = '{"metric": "m", "value": 1.0}\n'
-        stderr = 'teardown crash'
-
-    monkeypatch.setattr(subprocess, 'run', lambda *a, **k: Child())
-    assert bench._run_entry('lenet', {}) is False
-    assert '{' not in capsys.readouterr().out
-    Child.returncode = 0
-    assert bench._run_entry('lenet', {}) is True
-    assert '"metric": "m"' in capsys.readouterr().out
-    # and a sweep with no result at all is a failure, not a 0.0 line
-    Child.returncode = 1
-    for argv in (['bench.py'], ['bench.py', '--all']):
-        monkeypatch.setattr(sys, 'argv', argv)
-        with pytest.raises(SystemExit) as e:
-            bench.main()
-        assert e.value.code == 1
-        assert '{' not in capsys.readouterr().out
-
-
 def test_dryrun_multichip_too_few_devices_raises_and_spawns_nothing(
         monkeypatch):
     import __graft_entry__ as graft

@@ -210,6 +210,59 @@ def test_collective_runner_records_comms():
     assert args['participants'] == ndev and args['axes'] == 'dp'
 
 
+_PLAN_ARMS = {
+    'dense_flat': {'FLAGS_comms_plan': False,
+                   'FLAGS_comms_quantize': False},
+    'fused_dense': {'FLAGS_comms_plan': True,
+                    'FLAGS_comms_quantize': False},
+    'quant': {'FLAGS_comms_plan': True, 'FLAGS_comms_quantize': True,
+              'FLAGS_comms_quantize_min_bytes': 4096},
+}
+
+
+@pytest.mark.parametrize('width', [64, 96])
+@pytest.mark.parametrize('arm', sorted(_PLAN_ARMS))
+def test_bytes_on_wire_per_step_is_the_arms_formula(arm, width):
+    """What the planner A/B's record was worth, as counts: every step
+    of a GradAllReduce program, the first included, puts on the wire
+    exactly the ring formula over its gradients' bytes: 2(n-1)/n of
+    them dense, whether reduced one by one or fused into a bucket,
+    and the int8 payload plus its block scales where the bucket is
+    quantized."""
+    import jax
+    from paddle_tpu.fluid import comms_plan
+    ndev = len(jax.devices())
+    flags = dict(_PLAN_ARMS[arm], FLAGS_comms_model_path=os.devnull)
+    prev = fluid.get_flags(sorted(set(flags) |
+                                  {'FLAGS_comms_quantize_min_bytes'}))
+    fluid.set_flags(flags)
+    comms_plan.reset()
+    try:
+        main_p, startup, loss = _allreduce_program(width)
+        payload = sum(4 * int(np.prod(p.shape))
+                      for p in main_p.all_parameters())
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        feed = {'x': np.ones((16, width), 'float32')}
+        per_step = []
+        with fluid.scope_guard(fluid.Scope()):
+            exe.run(startup)
+            for _ in range(4):
+                w0 = monitor.counter_value('comms/bytes_on_wire')
+                exe.run(main_p, feed=feed, fetch_list=[loss])
+                per_step.append(
+                    monitor.counter_value('comms/bytes_on_wire') - w0)
+    finally:
+        fluid.set_flags(prev)
+        comms_plan.reset()
+    want = comms_plan.quant_wire_bytes(payload, 4, ndev) \
+        if arm == 'quant' else comms.wire_bytes('allreduce', payload,
+                                                ndev)
+    assert per_step == [want] * 4
+    assert monitor.counter_value('comms/plan_arm/' + (
+        'quant' if arm == 'quant' else 'dense')) == \
+        (0 if arm == 'dense_flat' else 4)
+
+
 def test_ring_attention_op_records_ppermute():
     import jax
     from paddle_tpu.parallel import mesh as pmesh

@@ -93,6 +93,80 @@ def test_disk_roundtrip_second_process_zero_retraces(plane_dir):
         np.testing.assert_array_equal(r, g)
 
 
+_DISK_COUNTERS = ('aot_compiles', 'compile_cache_disk_hit',
+                  'compile_cache_disk_miss', 'compile_cache_disk_writes',
+                  'segments_lowered')
+
+
+def _two_segment_prog(seed, width):
+    """_prog with a host op in the middle: two device segments."""
+    with unique_name.guard():
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = seed
+        with fluid.program_guard(main, startup):
+            x = layers.data('x', shape=[8], dtype='float32')
+            h = layers.fc(x, width, act='relu')
+            mid = main.current_block().create_var(
+                name='cc_mid', shape=[-1, width], dtype='float32')
+            layers.py_func(lambda a: a, h, mid)
+            loss = layers.reduce_mean(layers.fc(mid, 4))
+    return main, startup, loss
+
+
+# a width a case: a program some earlier case of this process compiled
+# is handed over in memory, and its executable cannot be stored again
+@pytest.mark.parametrize('build, width, warmup', [
+    (_prog, 5, False), (_prog, 6, True), (_two_segment_prog, 7, False)],
+    ids=['lazy', 'warmup', 'two_segments_lazy'])
+def test_cold_and_warm_process_counts_equal_the_entries(
+        plane_dir, build, width, warmup):
+    """What the cold / warm / warm+warmup start-up records were worth:
+    a cold process compiles, misses and writes once per store entry;
+    a warm one (fresh plane, same store) hits once per entry and
+    compiles, misses, writes and lowers nothing, whether its
+    executables are asked for by the first step or by warmup()."""
+    xs = _xs()
+
+    def process():
+        before = {k: monitor.counter_value('executor/' + k)
+                  for k in _DISK_COUNTERS}
+        main, startup, loss = build(131, width)
+        with fluid.scope_guard(fluid.Scope()):
+            exe = fluid.Executor(fluid.XLAPlace(0))
+            exe.run(startup)
+            if warmup:
+                exe.warmup(main, feed_shapes={'x': xs},
+                           fetch_list=[loss], wait=True)
+            out = [np.asarray(exe.run(main, feed={'x': xs},
+                                      fetch_list=[loss])[0])
+                   for _ in range(3)]
+        # a process that ends joins its compile pool: a warmed
+        # executable is stored after its future resolves
+        ended = compile_cache.reset_plane()
+        if ended is not None and ended._pool is not None:
+            ended._pool.shutdown(wait=True)
+        return out, {k: monitor.counter_value('executor/' + k) - v
+                     for k, v in before.items()}
+
+    ref, cold = process()
+    n = len(_seg_entries(plane_dir))
+    assert n >= 2       # the startup program's and the step's
+    assert cold['aot_compiles'] == n
+    assert cold['compile_cache_disk_miss'] == n
+    assert cold['compile_cache_disk_writes'] == n
+    assert cold['compile_cache_disk_hit'] == 0
+
+    got, warm = process()
+    assert len(_seg_entries(plane_dir)) == n
+    assert warm['compile_cache_disk_hit'] == n
+    assert warm['aot_compiles'] == 0
+    assert warm['compile_cache_disk_miss'] == 0
+    assert warm['compile_cache_disk_writes'] == 0
+    assert warm['segments_lowered'] == 0
+    for r, g in zip(ref, got):
+        np.testing.assert_array_equal(r, g)
+
+
 def test_identical_program_shares_executable_in_memory(plane_dir):
     """Two content-identical programs in ONE process share the
     executable through the fingerprint map — no second compile."""

@@ -519,6 +519,78 @@ def test_resume_warms_compile_cache_zero_retraces():
         compile_cache.reset_plane()
 
 
+@pytest.mark.parametrize('layout, hidden', [((2, 1, 1), 40),
+                                            ((1, 2, 1), 48)],
+                         ids=['from_dp2', 'from_fsdp2'])
+def test_resume_on_another_topology_cold_then_warm_counts(
+        layout, hidden, tmp_path, monkeypatch):
+    """What the elastic start-up record was worth, as counts: a
+    generation saved on two devices resumes on one; the first such
+    process compiles what it warms and hits nothing on disk, the second
+    hits once per stored executable and compiles nothing, and neither
+    lowers anything after its warm-up.  Both continue bit for bit
+    alike."""
+    import jax
+    from paddle_tpu.fluid import compile_cache
+    fluid.set_flags({'FLAGS_auto_shard': True})
+    main, startup, loss = _build(hidden=hidden)
+    feed = _feed(n=8)
+    store = str(tmp_path / 'store')
+    _run_layout(main, startup, loss, feed, layout, 2, 2, save_at=2,
+                save_dir=store)
+    fluid.set_flags({'FLAGS_auto_shard': False})
+    # JAX's own cache beside the segment store, as in
+    # test_compile_cache.py: "cold" must not depend on earlier runs
+    xla = str(tmp_path / 'xla')
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', xla)
+    jax.config.update('jax_compilation_cache_dir', xla)
+    compile_cache.reset_plane()
+    fluid.set_flags({'FLAGS_compile_cache_dir': str(tmp_path / 'cc')})
+    keys = ('aot_compiles', 'compile_cache_disk_hit',
+            'compile_cache_disk_writes')
+
+    def process():
+        before = {k: monitor.counter_value('executor/' + k)
+                  for k in keys}
+        with fluid.scope_guard(fluid.Scope()):
+            exe = fluid.Executor(fluid.XLAPlace(0))
+            info = elastic.resume(exe, store, main,
+                                  feed_shapes={'x': feed['x']},
+                                  fetch_list=[loss])
+            assert info.get('warmed') and info['src_layout']
+            lowered = monitor.counter_value('executor/segments_lowered')
+            losses = [_f(exe.run(main, feed=feed,
+                                 fetch_list=[loss])[0])
+                      for _ in range(3)]
+            after_warmup = monitor.counter_value(
+                'executor/segments_lowered') - lowered
+        # a process that ends joins its compile pool: a warmed
+        # executable is stored after its future resolves
+        ended = compile_cache.reset_plane()
+        if ended is not None and ended._pool is not None:
+            ended._pool.shutdown(wait=True)
+        return losses, after_warmup, {
+            k: monitor.counter_value('executor/' + k) - v
+            for k, v in before.items()}
+
+    try:
+        cold_losses, cold_after, cold = process()
+        assert cold['aot_compiles'] >= 1
+        assert cold['compile_cache_disk_writes'] == cold['aot_compiles']
+        assert cold['compile_cache_disk_hit'] == 0
+        assert cold_after == 0
+        warm_losses, warm_after, warm = process()
+        assert warm['compile_cache_disk_hit'] == cold['aot_compiles']
+        assert warm['aot_compiles'] == 0
+        assert warm['compile_cache_disk_writes'] == 0
+        assert warm_after == 0
+        assert warm_losses == cold_losses
+    finally:
+        fluid.set_flags({'FLAGS_compile_cache_dir': ''})
+        compile_cache.reset_plane()
+        jax.config.update('jax_compilation_cache_dir', None)
+
+
 # ------------------------------------------------------- retry/backoff
 def test_retry_backoff_and_deadline():
     from paddle_tpu.distributed.rpc_ps import PsClient, \

@@ -54,6 +54,63 @@ def test_steady_state_binder_hits_and_staged_h2d():
         f0['executor/bind_seconds/count']
 
 
+def _two_segment_train_program(seed=11):
+    """Device segment -> py_func host op -> device segment."""
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = seed
+    with fluid.program_guard(main, startup):
+        x = layers.data('x', shape=[8], dtype='float32')
+        h = layers.fc(x, 8, act='relu')
+        mid = main.current_block().create_var(
+            name='fp_mid', shape=[-1, 8], dtype='float32')
+        layers.py_func(lambda a: a, h, mid)
+        loss = layers.reduce_mean(layers.fc(mid, 4, act='relu'))
+        fluid.optimizer.SGD(0.05).minimize(loss)
+    return main, startup, loss
+
+
+@pytest.mark.parametrize('fetch', ['no_fetch', 'async_fetch'])
+@pytest.mark.parametrize('feed', ['device_feed', 'host_feed'])
+@pytest.mark.parametrize('build, segments', [
+    (_tiny_train_program, 1), (_two_segment_train_program, 2)],
+    ids=['one_segment', 'two_segments'])
+def test_post_warmup_step_counts(build, segments, feed, fetch):
+    """The per-step budgets of the steady state, as counts: after the
+    warm-up of the SAME call signature no bind walks the scope, every
+    segment of every step binds through its cached table, a
+    device-resident feed crosses H2D never and a host feed exactly
+    once a step, and an unresolved async fetch blocks nothing."""
+    import jax
+    main, startup, loss = build()
+    xs = _xs()
+    fed = {'x': jax.device_put(xs) if feed == 'device_feed' else xs}
+    kw = dict(fetch_list=[loss], return_numpy='async') \
+        if fetch == 'async_fetch' else dict(fetch_list=[])
+    steps = 6
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        for _ in range(3):
+            for h in exe.run(main, feed=fed, **kw) or ():
+                h.as_numpy()
+        f0 = monitor.flat()
+        handles = [exe.run(main, feed=fed, **kw) for _ in range(steps)]
+        f1 = monitor.flat()
+        for hs in handles:
+            for h in hs or ():
+                assert np.isfinite(h.as_numpy()).all()
+
+    def delta(key):
+        return f1.get(key, 0.0) - f0.get(key, 0.0)
+
+    assert delta('executor/scope_lookups') == 0
+    assert delta('executor/fastpath_hits') == steps * segments
+    assert delta('executor/h2d_bytes_async') == \
+        (0 if feed == 'device_feed' else steps * xs.nbytes)
+    assert delta('executor/fetch_blocked_seconds/count') == 0
+    assert delta('executor/segment_cache_miss') == 0
+
+
 def test_donation_safety_caller_fed_state():
     """A caller-fed jax.Array bound to a DONATED state slot must
     survive the step (the executor copies caller-owned buffers; only

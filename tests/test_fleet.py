@@ -253,6 +253,47 @@ class TestMigration:
         assert d['info']['from'] == src and d['info']['to'] == tgt
         assert monitor.counter_value('fleet/migrations') == 1
 
+    @pytest.mark.parametrize('hops', [2, 3])
+    def test_migrations_count_and_stay_bitwise_retrace_free(self, exe,
+                                                            hops):
+        """A tenant moved `hops` times between two replicas (there and
+        back again): every hop is counted once, acted on and lands on
+        the other replica, and the traffic after each hop answers bit
+        for bit as before the first, lowering nothing."""
+        fl, _ = _make_fleet(exe, replicas=2,
+                            tenants=(('a', 16, 'interactive'),))
+        fl.warmup(wait=True)
+        rng = np.random.RandomState(2)
+        feeds = [rng.randn(r, 8).astype('float32') for r in (2, 1, 4)]
+
+        def answers():
+            return [np.asarray(fl.submit('a', {'x': xv}).result(120)[0])
+                    for xv in feeds]
+
+        before = answers()
+        lowered0 = None
+        for hop in range(hops):
+            src = fl.placement('a')
+            tgt = fl.migrate('a', why='hop %d' % hop)
+            assert tgt is not None and tgt != src
+            if lowered0 is None:
+                # the first hop warmed the other replica's ladder
+                lowered0 = monitor.counter_value(
+                    'executor/segments_lowered')
+            for b, a in zip(before, answers()):
+                assert np.array_equal(b, a)
+        assert monitor.counter_value(
+            'executor/segments_lowered') == lowered0
+        assert monitor.counter_value('fleet/migrations') == hops
+        acted = [d for d in fleet.decisions()
+                 if d['kind'] == 'migrate' and d['acted']]
+        assert len(acted) == hops
+        for name in ('r0', 'r1'):
+            held = [t for t in fl.replica(name).resident_report()
+                    ['tenants'] if t['tenant'] == 'a']
+            assert len(held) == (name == fl.placement('a'))
+            assert all(t['retraces'] == 0 for t in held)
+
     def test_frozen_migrate_is_intent_only(self, exe):
         fl, _ = _make_fleet(exe, replicas=2,
                             tenants=(('a', 16, 'interactive'),))

@@ -322,11 +322,11 @@ def _mlp_with(kind, width=256):
 
 
 @pytest.mark.parametrize('kind', sorted(_ADAM_FAMILY))
-def test_adam_family_program_lowers_one_scope_per_parameter(kind):
+def test_adam_family_program_lowers_under_the_op_scope(kind):
     """What Executor.run traces for a six-parameter program: six ops of
-    the type, each under its own scope (FLAGS_opprof's instance suffix
-    tells them apart), no fused_* op, no Mosaic call, and no
-    concatenate (the packed path built parameter-sized ones)."""
+    the type, lowered under the scope of that name, no fused_* op, no
+    Mosaic call, and no concatenate (the packed path built
+    parameter-sized ones)."""
     import re
     import jax
     main, startup, loss = _mlp_with(kind)
@@ -348,14 +348,9 @@ def test_adam_family_program_lowers_one_scope_per_parameter(kind):
         state = {n: spec(n) for n in step.state_names}
         data = {n: spec(n) for n in step.input_names if n != 'x'}
     data['x'] = jax.ShapeDtypeStruct((4, 64), np.float32)
-    fluid.set_flags({'FLAGS_opprof': True})
-    try:
-        text = jax.jit(step.fn, donate_argnums=(1,)).lower(
-            np.int32(0), state, data).as_text(debug_info=True)
-    finally:
-        fluid.set_flags({'FLAGS_opprof': False})
-    scopes = set(re.findall(r'/(%s#\d+)/' % kind, text))
-    assert scopes == {'%s#%d' % (kind, i) for i in updates}, scopes
+    text = jax.jit(step.fn, donate_argnums=(1,)).lower(
+        np.int32(0), state, data).as_text(debug_info=True)
+    assert re.search(r'/%s/' % kind, text)
     assert 'fused_' + kind not in text
     assert 'tpu_custom_call' not in text
     assert 'concatenate' not in text

@@ -1,5 +1,5 @@
-"""fluid.timeseries + fluid.slo — windowed history, SLO burn-rate
-alerting, and the regression-gate comparer.
+"""fluid.timeseries + fluid.slo — windowed history and SLO burn-rate
+alerting.
 
 The acceptance contract: window math survives the ugly inputs real
 jobs produce — counter resets from a restarted worker (the post-reset
@@ -9,23 +9,13 @@ empty windows (None, not a crash, and no-data neither fires nor
 resolves an SLO); the alert state machine holds its hysteresis
 against a flapping series and scales its slow window honestly on
 short histories; the exposition linter rejects the per-bucket-count
-histogram rendering; rate_limited_dump claims atomically; and the
-run-to-run comparer passes honest reruns while failing seeded
-slowdowns by name."""
-
-import json
-import os
-import sys
+histogram rendering; rate_limited_dump claims atomically."""
 
 import pytest
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import (health, monitor, slo, supervisor,
                               timeseries, trace)
-
-sys.path.insert(0, os.path.join(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))), 'tools'))
-import check_regress  # noqa: E402
 
 
 @pytest.fixture(autouse=True)
@@ -417,71 +407,3 @@ class TestRateLimitedDump:
         trace.reset_rate_limits('m/')
         assert trace.rate_limited_dump('m/key', 3600.0,
                                        tag='y') is not None
-
-
-# -------------------------------------------------------- check_regress
-def _hist_lines(entry, vals, metric='step_s'):
-    return [{'ts': float(i), 'entry': entry, 'run_id': None,
-             'metrics': {metric: v}} for i, v in enumerate(vals)]
-
-
-class TestCheckRegress:
-    def test_honest_run_passes(self):
-        lines = _hist_lines('bench', [0.10, 0.11, 0.09, 0.105])
-        v = [x for x in check_regress.compare(lines)
-             if x['metric'] == 'step_s'][0]
-        assert v['status'] == 'PASS'
-
-    def test_slowdown_regresses_by_name(self):
-        lines = _hist_lines('bench', [0.10, 0.11, 0.09, 0.50])
-        v = [x for x in check_regress.compare(lines)
-             if x['metric'] == 'step_s'][0]
-        assert v['status'] == 'REGRESS' and v['direction'] == 'lower'
-
-    def test_throughput_drop_regresses(self):
-        lines = _hist_lines('bench', [1000.0, 980.0, 1020.0, 300.0],
-                            metric='examples_per_sec')
-        v = [x for x in check_regress.compare(lines)
-             if x['metric'] == 'examples_per_sec'][0]
-        assert v['status'] == 'REGRESS' and v['direction'] == 'higher'
-        # a throughput INCREASE is not a regression
-        lines = _hist_lines('bench', [1000.0, 980.0, 1020.0, 2500.0],
-                            metric='examples_per_sec')
-        v = [x for x in check_regress.compare(lines)
-             if x['metric'] == 'examples_per_sec'][0]
-        assert v['status'] == 'PASS'
-
-    def test_median_of_n_absorbs_one_outlier(self):
-        lines = _hist_lines('bench', [0.10, 0.11, 0.09,
-                                      0.50, 0.10, 0.105])
-        v = [x for x in check_regress.compare(lines, current_n=3)
-             if x['metric'] == 'step_s'][0]
-        assert v['status'] == 'PASS'
-
-    def test_thin_baseline_and_unknown_direction_are_info(self):
-        lines = _hist_lines('bench', [0.10, 0.50])
-        v = [x for x in check_regress.compare(lines)
-             if x['metric'] == 'step_s'][0]
-        assert v['status'] == 'INFO'
-        lines = _hist_lines('bench', [1.0, 2.0, 3.0, 99.0],
-                            metric='monitor.executor.retraces')
-        assert all(x['status'] == 'INFO'
-                   for x in check_regress.compare(lines))
-
-    def test_bench_history_append_and_load(self, tmp_path):
-        sys.path.insert(0, os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))))
-        import bench
-        path = str(tmp_path / 'h.jsonl')
-        rec = {'step_s': 0.1, 'note': 'text-skipped',
-               'nested': {'p99': 0.2, 'flag': True}}
-        bench.append_history('demo', rec, path=path)
-        lines = check_regress.load_history(path)
-        assert len(lines) == 1
-        m = lines[0]['metrics']
-        assert m['step_s'] == 0.1 and m['nested.p99'] == 0.2
-        assert 'note' not in m and 'nested.flag' not in m
-        # a torn tail line is skipped, not fatal
-        with open(path, 'a') as f:
-            f.write('{"entry": "demo", "metr')
-        assert len(check_regress.load_history(path)) == 1

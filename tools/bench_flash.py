@@ -278,8 +278,7 @@ def naive_attention(q, k, v, causal=False):
 
 def timed(fn, args, steps):
     """Chained steps (each consumes the previous grads) + one host
-    readback: serialize on-device and sync via np.asarray (bench.py's
-    convention)."""
+    readback: serialize on-device and sync via np.asarray."""
     q, k, v = args
 
     def step(q, k, v):

@@ -37,9 +37,7 @@ check_supervisor (cross-process jax collectives are unavailable on the
 CPU backend) — every kill, scrape, RPC frame and restart crosses a
 real OS process boundary, which is what the controller gates.
 
-Run from `make check` (CPU: JAX_PLATFORMS=cpu).  ``bench.py --chaos``
-drives this same soak and records the stats line (CHAOS_STATS) into
-BENCH_chaos.json.
+Run from `make check` (CPU: JAX_PLATFORMS=cpu).
 """
 
 import json
@@ -306,8 +304,7 @@ def _child_json(stdout, tag=''):
 
 
 def run_soak():
-    """The whole soak; returns (failures, stats) so bench.py --chaos
-    can record the stats without re-implementing the harness."""
+    """The whole soak; returns (failures, stats)."""
     work = tempfile.mkdtemp(prefix='pt_chaos_')
     store = os.path.join(work, 'store')
     p0, p1, p2 = _free_port(), _free_port(), _free_port()

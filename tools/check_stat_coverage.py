@@ -82,9 +82,8 @@ REQUIRED = [
     ('paddle_tpu/fluid/transpiler/collective.py',
      'collective/%s_ops_inserted'),
     ('paddle_tpu/ops/collective_ops.py', 'collective/traced_bytes'),
-    # profiler fold-in + bench export
+    # profiler fold-in
     ('paddle_tpu/fluid/profiler.py', "profiler/%s/calls"),
-    ('bench.py', '_monitor_fields'),
     # span tracer / flight recorder (fluid/trace.py): its own counters
     # keep the trace plane observable through the monitor plane, and
     # the phase-span instrument sites across the hot path feed the
@@ -101,7 +100,6 @@ REQUIRED = [
     ('paddle_tpu/fluid/reader.py', "_trace.record('reader_wait'"),
     ('paddle_tpu/fluid/parallel_executor.py', "_step_scope("),
     ('paddle_tpu/fluid/compile_cache.py', "'cache_deserialize'"),
-    ('bench.py', '_step_phase_fields'),
     # health plane (fluid/health.py): the HTTP status surface, the
     # aggregator's worker probes, the tensor-health summaries and the
     # NaN/divergence detectors — tools/check_health.py exercises the
@@ -119,7 +117,6 @@ REQUIRED = [
     ('paddle_tpu/fluid/executor.py', 'executor/last_step_unix_ts'),
     ('paddle_tpu/fluid/monitor.py', '# HELP'),
     ('paddle_tpu/distributed/launch.py', 'PADDLE_TPU_STATUS_WORKERS'),
-    ('bench.py', 'health_overhead'),
     # serving plane (fluid/serving.py): continuous-batching SLO
     # surface — per-tenant queue depth, batch occupancy,
     # admission-to-completion latency, pad waste, and the
@@ -135,7 +132,6 @@ REQUIRED = [
     ('paddle_tpu/fluid/serving.py', 'serving/warmup_buckets'),
     ('paddle_tpu/fluid/serving.py', "_trace.step_tags"),
     ('paddle_tpu/fluid/trace.py', 'step_tags'),
-    ('bench.py', 'serving_requests_per_sec'),
     # job-wide observability (fluid/comms.py + trace.collect_job +
     # the aggregator's skew detector): collective telemetry with
     # bytes-on-wire and per-(collective, size-bucket) bandwidth,
@@ -174,7 +170,6 @@ REQUIRED = [
     ('paddle_tpu/ops/collective_ops.py', '_planned_allreduce'),
     ('paddle_tpu/fluid/parallel_executor.py', 'comms_plan.digest'),
     ('paddle_tpu/fluid/health.py', 'comms_plan.program_plans'),
-    ('bench.py', '_plan_ab_fields'),
     ('paddle_tpu/fluid/executor.py', '_comms.record_memory'),
     # a restarted (disk-hit) process must keep memory accounting
     ('paddle_tpu/fluid/compile_cache.py', 'comms.record_memory'),
@@ -187,7 +182,6 @@ REQUIRED = [
     ('paddle_tpu/distributed/launch.py', 'PADDLE_TPU_STATUS_WORKERS'),
     ('tools/comms_calibrate.py', 'inv_bw_s_per_byte'),
     ('tools/timeline.py', 'collect_job'),
-    ('bench.py', 'bytes_on_wire'),
     # device-memory observability plane (fluid/memviz.py): per-
     # (program, segment) peak attribution, the live-HBM census sampler
     # + Perfetto counter track, OOM forensics and budget watermarks —
@@ -212,7 +206,6 @@ REQUIRED = [
     ('paddle_tpu/fluid/health.py', 'memviz.memory_pressure'),
     ('paddle_tpu/fluid/serving.py', 'register_scope_provider'),
     ('tools/stat_summary.py', 'memviz/live_bytes_total'),
-    ('bench.py', 'memviz_overhead'),
     # auto-sharding planner (parallel/plan.py): plan build volume, the
     # priced-candidate table, the memviz HBM-gate rejections, the
     # unpriced-term honesty counter, the chosen-layout gauges, and the
@@ -232,7 +225,6 @@ REQUIRED = [
      'auto_shard_plan.transpile_plan'),
     ('paddle_tpu/fluid/health.py', 'auto_shard_plan.report'),
     ('tools/stat_summary.py', 'parallel/plan_hbm_rejected'),
-    ('bench.py', '_autoshard_fields'),
     # elastic resilience plane (fluid/elastic.py + fluid/faultinject.py
     # + the rpc/heartbeat retry satellites): crash-consistent store
     # volume, refusal accounting, the reshard schedule's predicted-vs-
@@ -273,7 +265,6 @@ REQUIRED = [
     ('paddle_tpu/fluid/executor.py', '_finject.check'),
     ('paddle_tpu/fluid/executor.py', "'collective.dispatch'"),
     ('paddle_tpu/fluid/health.py', 'elastic.report'),
-    ('bench.py', '_elastic_fields'),
     # self-healing supervisor (fluid/supervisor.py + the hung-step
     # watchdog + serving shedding satellites): decision volume, the
     # checkpoint plane's backpressure/stretch/torn-resave accounting,
@@ -306,7 +297,6 @@ REQUIRED = [
     ('paddle_tpu/fluid/elastic.py', 'elastic/rejoin_retries'),
     ('paddle_tpu/fluid/health.py', 'supervisor.report'),
     ('paddle_tpu/fluid/health.py', 'peer_health'),
-    ('bench.py', '_chaos_fields'),
     # static Program verifier (fluid/progcheck.py): programs checked,
     # per-class diagnostic counters, seeded mutations, wall time —
     # tools/check_progcheck.py proves every class fires by name and
@@ -357,7 +347,6 @@ REQUIRED = [
     ('paddle_tpu/fluid/trace.py', 'trace/dumps_suppressed'),
     ('paddle_tpu/fluid/serving.py', 'FLAGS_serving_slo_p99_s'),
     ('tools/stat_summary.py', 'ts.counter_deltas'),
-    ('bench.py', 'append_history'),
     # closed-loop autopilot (fluid/autopilot.py): the bounded decision
     # log, the online comms refits and their freeze/interlock/revert
     # accounting, the degenerate-refit guard in the fitter, and the
@@ -400,26 +389,8 @@ REQUIRED = [
     ('paddle_tpu/fluid/serving.py', 'serving/tenant_evicted'),
     ('paddle_tpu/fluid/serving.py', 'serving/warmup_buckets'),
     ('paddle_tpu/fluid/health.py', "'fleet':"),
-    # op-cost attribution plane (fluid/opprof.py): segment snapshots +
-    # eager replays, the attributed-vs-unattributed ms honesty split,
-    # capture event consumption with the dropped-row counter, and the
-    # ranked kernel-worklist gauge — tools/check_opprof.py closes the
-    # loop against a warmed LeNet with the 10% step-report agreement
-    # band
-    ('paddle_tpu/fluid/opprof.py', 'opprof/snapshots'),
-    ('paddle_tpu/fluid/opprof.py', 'opprof/replays'),
-    ('paddle_tpu/fluid/opprof.py', 'opprof/instances'),
-    ('paddle_tpu/fluid/opprof.py', 'opprof/attributed_ms_total'),
-    ('paddle_tpu/fluid/opprof.py', 'opprof/unattributed_ms_total'),
-    ('paddle_tpu/fluid/opprof.py', 'opprof/capture_events'),
-    ('paddle_tpu/fluid/opprof.py', 'opprof/dropped_events'),
-    ('paddle_tpu/fluid/opprof.py', 'opprof/worklist_candidates'),
-    ('paddle_tpu/fluid/executor.py', '_opprof.want_snapshot'),
-    ('paddle_tpu/fluid/executor.py', '_opprof.note_segment'),
+    # rows a device capture could not attribute are counted
     ('paddle_tpu/fluid/profiler.py', 'profiler/dropped_events'),
-    ('paddle_tpu/fluid/health.py', "'op_costs':"),
-    ('tools/stat_summary.py', 'opprof/worklist_candidates'),
-    ('bench.py', 'opprof_overhead'),
 ]
 
 

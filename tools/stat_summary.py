@@ -28,12 +28,6 @@ Usage:
                                   # builds/reuse, candidates priced,
                                   # HBM-gate rejections, unpriced
                                   # terms (parallel/plan.py)
-  python tools/stat_summary.py --ops run.jsonl     # op-cost plane
-                                  # rollup: snapshots taken, eager
-                                  # replays, attributed vs honest
-                                  # unattributed ms, capture events
-                                  # consumed/dropped, worklist size
-                                  # (fluid.opprof)
   python tools/stat_summary.py --verify run.jsonl
                                   # static-verifier rollup: programs
                                   # checked/clean, diagnostics by
@@ -290,51 +284,6 @@ def memory_report(rec, out=None):
     return 0
 
 
-def ops_report(rec, out=None):
-    """Op-cost attribution rollup from one monitor record: snapshot /
-    replay volume, the attributed-vs-unattributed ms split, capture
-    event consumption (and the dropped-row honesty counter), and the
-    ranked-worklist size gauge — the offline form of /statusz's
-    op_costs section (fluid.opprof)."""
-    out = out if out is not None else sys.stdout
-    c = rec.get('counters', {})
-    g = rec.get('gauges', {})
-    if not any(n.startswith('opprof/') for n in list(c) + list(g)):
-        out.write('no opprof/* stats in this record: enable '
-                  'FLAGS_opprof for the op-cost attribution plane\n')
-        return 1
-    out.write('op-cost attribution rollup (fluid.opprof)\n')
-    for name, label in (('opprof/snapshots', 'segment snapshots'),
-                        ('opprof/replays', 'eager replays'),
-                        ('opprof/capture_events',
-                         'capture events consumed'),
-                        ('opprof/dropped_events',
-                         'malformed events dropped')):
-        v = c.get(name)
-        if v:
-            out.write('  %-26s %10d\n' % (label, v))
-    att = g.get('opprof/attributed_ms_total')
-    unatt = g.get('opprof/unattributed_ms_total')
-    if att is not None:
-        total = att + (unatt or 0.0)
-        out.write('  attributed ms/step         %10.4f (%.1f%% of '
-                  'observed)\n'
-                  % (att, 100.0 * att / total if total else 100.0))
-    if unatt:
-        out.write('  unattributed ms/step       %10.4f\n' % unatt)
-    inst = g.get('opprof/instances')
-    if inst is not None:
-        out.write('  op instances tracked       %10d\n' % inst)
-    wl = g.get('opprof/worklist_candidates')
-    if wl is not None:
-        out.write('  kernel-worklist candidates %10d\n' % wl)
-    prof_drop = c.get('profiler/dropped_events')
-    if prof_drop:
-        out.write('  profiler rows dropped      %10d (malformed '
-                  'device events)\n' % prof_drop)
-    return 0
-
-
 def verify_report(rec, out=None):
     """Static-verifier rollup from one monitor record: programs
     checked vs clean, error/warning volume, the per-diagnostic-class
@@ -495,11 +444,6 @@ def main(argv=None):
             sys.stderr.write(__doc__)
             return 2
         return watch(float(argv[1]), argv[2], iterations=iters)
-    if argv and argv[0] == '--ops':
-        if len(argv) != 2:
-            sys.stderr.write(__doc__)
-            return 2
-        return ops_report(load_last(argv[1]))
     if argv and argv[0] == '--verify':
         if len(argv) != 2:
             sys.stderr.write(__doc__)

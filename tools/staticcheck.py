@@ -50,7 +50,6 @@ LOCK_MODULES = [
     'paddle_tpu/fluid/slo.py',
     'paddle_tpu/fluid/autopilot.py',
     'paddle_tpu/fluid/fleet.py',
-    'paddle_tpu/fluid/opprof.py',
 ]
 # documented GIL-discipline exemption: registries with NO lock at all
 # (the lint fails if a lock ever appears there half-wired)
@@ -139,7 +138,7 @@ def check_flags(errors):
                 'in fluid/flags.py _DEFAULTS (a typo here silently '
                 'reads the fallback default forever)' % (name, f, ln))
     # reads anywhere in the repo count against dead-declaration
-    # (bench.py / tools / tests legitimately read runtime flags)
+    # (tools / tests legitimately read runtime flags)
     all_reads = dict(pkg_reads)
     extra = [p for p in _py_files(ROOT)
              if not p.startswith(PKG + os.sep)]
@@ -153,7 +152,7 @@ def check_flags(errors):
                 'site; v1.6 compat-only knobs belong in '
                 'V16_COMPAT_ONLY)' % name)
     # pallas kernel knobs must gate dispatch inside the package — a
-    # FLAGS_pallas_* read only by tests/bench would pass the generic
+    # FLAGS_pallas_* read only by tests would pass the generic
     # dead-knob check above while the kernel library silently never
     # consults it (a dense fallback masquerading as a fused win)
     for name in sorted(declared):
@@ -162,7 +161,7 @@ def check_flags(errors):
                 'FLAG PALLAS UNWIRED  %s is declared but no '
                 'paddle_tpu/ code reads it — pallas dispatch knobs '
                 'must be consulted by the kernel library itself, not '
-                'only by tests or bench harnesses' % name)
+                'only by tests or tools' % name)
     for name in sorted(compat):
         if name in pkg_reads:
             f, ln = pkg_reads[name][0]

@@ -28,17 +28,6 @@ Usage: python tools/timeline.py --job --workers 0=h:9184,1=h:9185 \
            --timeline_path /tmp/job_timeline.json
        python tools/timeline.py --job --dumps w0.json w1.json \
            --timeline_path /tmp/job_timeline.json
-
-Op mode (`--ops`) attributes a capture's device-kernel time back to
-per-INSTANCE fluid op descs through fluid.opprof (capture taken with
-FLAGS_opprof on, so scope names carry the '#<block-index>' suffix):
-it feeds the capture — or an already-merged timeline (--timeline as
-input) — through opprof.record_capture, prints the ranked table with
-type/layer rollups and the honest unattributed remainder, and can
-emit the kernel worklist (--worklist op_worklist.json).
-
-Usage: python tools/timeline.py --ops --profile_path /tmp/profile \
-           [--steps N] [--worklist op_worklist.json]
 """
 
 import argparse
@@ -142,52 +131,6 @@ def collect_job_cli(args):
     return 0
 
 
-def ops_cli(args):
-    """--ops: per-instance op attribution of a capture or merged
-    timeline via fluid.opprof (no device needed — pure event math)."""
-    os.environ.setdefault('JAX_PLATFORMS', 'cpu')
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from paddle_tpu.fluid import opprof
-    if os.path.isfile(args.timeline_path):
-        with open(args.timeline_path) as f:
-            events = json.load(f).get('traceEvents', [])
-        src_label = args.timeline_path
-    else:
-        src = find_trace(args.profile_path)
-        opener = gzip.open if src.endswith('.gz') else open
-        with opener(src, 'rt') as f:
-            events = json.load(f).get('traceEvents', [])
-        src_label = src
-    res = opprof.record_capture(events, program='capture',
-                                steps=max(args.steps, 1))
-    rep = opprof.report()
-    print('op attribution of %s (%d segment groups, %d malformed '
-          'rows dropped):' % (src_label, res['segments'],
-                              res['dropped']))
-    print('%-34s %-22s %10s %8s %7s' %
-          ('instance', 'segment', 'ms/step', 'calls', 'share'))
-    for row in rep['top']:
-        print('%-34s %-22s %10.4f %8d %6.2f%%'
-              % (row['instance'], row['segment'][:22],
-                 row['ms_per_step'], row['calls'], row['share_pct']))
-    if rep['unattributed_ms']:
-        print('unattributed: %.4f ms/step' % rep['unattributed_ms'])
-    print('by type: ' + ', '.join(
-        '%s=%.3fms' % (t, v['ms_per_step']) for t, v in sorted(
-            rep['by_type'].items(),
-            key=lambda kv: -kv[1]['ms_per_step'])[:8]))
-    by_layer = rep['by_layer']
-    if by_layer:
-        print('by layer: ' + ', '.join(
-            '%s=%.3fms' % (l, v) for l, v in sorted(
-                by_layer.items(), key=lambda kv: -kv[1])[:8]))
-    if args.worklist:
-        path = opprof.write_worklist(args.worklist)
-        print('kernel worklist written to %s' % path)
-    return 0
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument('--profile_path', default='/tmp/profile')
@@ -207,21 +150,7 @@ def main():
                     help='merge saved /trace/dump files instead of '
                          'scraping (each dump\'s own ptRank labels '
                          'it; argument order is the fallback)')
-    ap.add_argument('--ops', action='store_true',
-                    help='attribute device-kernel time to per-'
-                         'instance fluid op descs (fluid.opprof) '
-                         'from the capture under --profile_path, or '
-                         'from an existing merged timeline when '
-                         '--timeline_path names a file')
-    ap.add_argument('--steps', type=int, default=1,
-                    help='--ops: steps the capture spans (totals '
-                         'divide by this for per-step costs)')
-    ap.add_argument('--worklist', default=None,
-                    help='--ops: also write the ranked kernel '
-                         'worklist JSON here')
     args = ap.parse_args()
-    if args.ops:
-        return ops_cli(args)
     if args.job:
         return collect_job_cli(args)
     src = find_trace(args.profile_path)
