@@ -20,6 +20,10 @@ the chip: BERT-base pretraining through the normal entry points
                                     # call at the published 32768
                                     # positions, then every gradient
                                     # of one layer at 4096
+    python chip_smoke.py --phase solar   # Solar-Open2-250B's: the gated
+                                    # delta rule alone at 32768
+                                    # positions, then gradients of
+                                    # the cell's four layers
 
 One process, no children.  It fails (non-zero, no result line) unless
 jax.devices()[0].platform == 'tpu'; nothing here falls back to the CPU.
@@ -1924,15 +1928,335 @@ def phase_evabyte(seed=0):
     _evabyte_cell_losses(EVA_LAYER_SEQ, seed)
 
 
+# --- Solar-Open2-250B ---------------------------------------------------
+# Published widths (models.solar_open2.BASE) as the benchmark cuts the
+# rest: the model's layers 0 to 3 (softmax, then three delta-rule
+# layers), 8 of 64 heads of each kind with one K/V head, experts 0-7 of
+# 320 held, 24576 vocabulary rows, one 4096-token sequence.  First the
+# ``kda_attention`` op ALONE at the 32768 positions the cell cannot
+# hold, forward and backward, against the token scan stepped in
+# checkpointed blocks; then sampled gradients of the f32 TRAIN program
+# against jax.grad of the reference, and the f32 for_test loss over
+# SOLAR_LOSS_BATCHES batches against the reference in f32 and in
+# bfloat16 throughout: the two readings the family's REFERENCE_RTOL
+# lies between.
+SOLAR_LAYERS = 4
+SOLAR_OP_TOKENS = 32768
+SOLAR_OP_TOL = 2e-4         # op alone, f32, relative L2 of a tensor
+SOLAR_LOSS_RTOL = 2e-6
+SOLAR_L2_RTOL = 2e-3
+SOLAR_CELL_RTOL = 5e-6      # = benchmark/families/solar_open2.py's
+SOLAR_LOSS_BATCHES = 12
+SOLAR_LOADS_OFF = 24
+# creation order, trainable parameters only: embedding 0; layer 0
+# (softmax) g_op 1 Wq 2 Wk 3 Wv 4 Wgate 5 Wo 6 g_ffn 7 router 8 gate 9
+# up 10 down 11 shared gate 12 up 13 down 14; layer 1 (delta rule) g_op
+# 15 Wq 16 fq 17 Wk 18 fk 19 Wv 20 fv 21 Wf_down 22 Wf_up 23 A_log 24
+# dt_bias 25 Wb 26 g_o 27 Wg_down 28 Wg_up 29 Wo 30 g_ffn 31 router 32
+# gate 33 up 34 down 35 shared 36 37 38; layer 2 from 39
+# (the embedding's and the head's [24576, 4096] gradients and a second
+# expert tensor are left out: fetched beside 12 GB of f32 temporaries
+# they do not fit)
+SOLAR_SAMPLED = {'Wq (layer 0)': 2, 'Wk (layer 0)': 3,
+                 'Wgate (layer 0)': 5, 'router (layer 0)': 8,
+                 'gate': 9, 'shared gate (layer 0)': 12,
+                 'Wq (layer 1)': 16, 'filter q (layer 1)': 17,
+                 'Wk (layer 1)': 18, 'filter v (layer 1)': 21,
+                 'Wf_down (layer 1)': 22, 'Wf_up (layer 1)': 23,
+                 'A_log (layer 1)': 24, 'dt_bias (layer 1)': 25,
+                 'Wb (layer 1)': 26, 'o gain (layer 1)': 27,
+                 'Wg_up (layer 1)': 29, 'Wo (layer 1)': 30,
+                 'A_log (layer 2)': 48, 'Wb (layer 2)': 50}
+
+
+def _solar_cut(layers=SOLAR_LAYERS):
+    from paddle_tpu.models import solar_open2
+    base = solar_open2.BASE
+    return solar_open2.SolarOpen2Config(
+        vocab_size=24576, layers=layers, heads=base.heads // 8,
+        kv_heads=base.kv_heads // 8, kda_heads=base.kda_heads // 8,
+        experts_held=(0, 8), bias_init_std=0.005)
+
+
+def _solar_single_op(t=SOLAR_OP_TOKENS, heads=8, d=128, seed=0):
+    """The ``kda_attention`` op alone, float32, at ``t`` positions:
+    forward and all five gradients against the recurrence stepped a
+    token at a time (in checkpointed blocks of 128, or its gradient
+    would keep t states of [128, 128] a head), with the device's peak
+    memory and each direction's time beside the hand count's
+    roofline."""
+    import jax
+    import jax.numpy as jnp
+    from benchmark.lib import flops, peaks, solar_flops
+    from paddle_tpu.models.reference import solar_open2 as reference
+    from paddle_tpu.ops import kda_ops
+    rng = np.random.RandomState(seed)
+    q, k = (rng.randn(1, t, heads, d) for _ in range(2))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * d ** 0.5
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.randn(1, t, heads, d)
+    # the startup draws' range: A in (1, 16), dt in (0.001, 0.1)
+    a = -rng.uniform(1, 16, (1, 1, heads, 1)) * np.exp(
+        rng.uniform(np.log(1e-3), np.log(1e-1), (1, t, heads, d)))
+    beta = 2 / (1 + np.exp(-rng.randn(1, t, heads)))
+    args = [jnp.asarray(x, jnp.float32) for x in (q, k, v, a, beta)]
+    probe = jnp.asarray(rng.randn(1, t, heads, d), jnp.float32)
+
+    def both(f):
+        def run(*x):
+            out, pull = jax.vjp(f, *x)
+            return (out,) + pull(probe)
+        return jax.jit(run)
+
+    op = both(kda_ops.gated_delta_rule)
+    with jax.default_matmul_precision('highest'):
+        scan = both(lambda *x: reference.kda_recurrence(*x, block=128))
+        want = [np.asarray(x) for x in scan(*args)]
+    got = [np.asarray(x) for x in op(*args)]
+    worst = 0.0
+    for name, x, y in zip(('o', 'dq', 'dk', 'dv', 'da', 'dbeta'), got,
+                          want):
+        l2 = float(np.linalg.norm(x - y) / np.linalg.norm(y))
+        worst = max(worst, l2)
+        say('kda_attention alone, f32, 1 x %d x %d x %d: %s relative L2 '
+            'distance from the token scan %.3e (largest entry '
+            'difference %.3e of %.3e)'
+            % (t, heads, d, name, l2, np.abs(x - y).max(),
+               np.abs(y).max()))
+    check(np.isfinite(got[0]).all() and worst <= SOLAR_OP_TOL,
+          'the op at %d positions, forward and five gradients, within '
+          '%g of the token scan (worst %.3e)' % (t, SOLAR_OP_TOL, worst))
+    forward = jax.jit(kda_ops.gated_delta_rule)
+    fwd_s, both_s = _timed(forward, *args), _timed(op, *args)
+    cost = solar_flops.kda_train_cost(1, t, heads, d, itemsize=4)
+    least, side = flops.roofline_seconds(
+        *cost, *peaks.chip_peak(jax.devices()[0].device_kind))
+    say('kda_attention alone at %d positions: forward %.2f ms, forward '
+        '+ backward %.2f ms; the hand count (%.1f GFLOP, %.1f MB at 4 '
+        'bytes an element) is %s-bound at %.2f ms: %.1f%% of its '
+        'roofline; peak memory %.2f GB'
+        % (t, fwd_s * 1e3, both_s * 1e3, cost[0] / 1e9, cost[1] / 1e6,
+           side, least * 1e3, 100 * least / both_s,
+           _peak_bytes(jax.devices()[:1])[0] / 1e9))
+
+
+def _solar_cell_losses(seq, seed):
+    """The cell's own cut, forward only: the f32 for_test program's
+    loss on SOLAR_LOSS_BATCHES batches beside the reference's in
+    float32 and in bfloat16 throughout."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import solar_open2
+    from paddle_tpu.models.reference import solar_open2 as reference
+    cfg = _solar_cut()
+    sizes = reference.sizes_of(cfg)
+    feeds = [_ints32(solar_open2.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(s)))
+        for s in range(seed, seed + SOLAR_LOSS_BATCHES)]
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.scope_guard(fluid.Scope()):
+        with fluid.program_guard(main, startup), \
+                fluid.unique_name.guard():
+            _, _, loss = solar_open2.build_pretrain(cfg, seq)
+            test = main.clone(for_test=True)
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights, biases = ([np.asarray(fluid.core.as_array(
+            scope.find_var(p.name))) for p in main.all_parameters()
+            if p.trainable == kind] for kind in (True, False))
+        program = [_scalar(exe.run(test, feed=f, fetch_list=[loss]))
+                   for f in feeds]
+        for name in scope.local_var_names():
+            scope.erase(name)
+    weights, biases = ([jnp.asarray(x) for x in part]
+                       for part in (weights, biases))
+    both = jax.jit(lambda w, b, i, l: [reference.loss(
+        w, b, i, l, sizes=sizes, dtype=dt)
+        for dt in (jnp.float32, jnp.bfloat16)])
+    off, low = [], []
+    for n, (feed, got) in enumerate(zip(feeds, program)):
+        full, half = (float(x) for x in both(weights, biases, *(
+            jnp.asarray(feed[k]) for k in ('ids', 'labels'))))
+        off.append(abs(got - full) / full)
+        low.append(abs(half - full) / full)
+        say('%d layers, batch seed %d: program %.6f, reference %.6f '
+            '(relative difference %.2e), reference in bfloat16 '
+            'throughout %.6f (%.2e)'
+            % (cfg.layers, seed + n, got, full, off[-1], half, low[-1]))
+    say('over %d batches at %d layers: f32 for_test program against the '
+        'reference, relative: median %.2e, largest %.2e; reference in '
+        'bfloat16 throughout: smallest %.2e, quartiles %.2e %.2e %.2e, '
+        'largest %.2e, %d within %g'
+        % ((len(off), cfg.layers, np.median(off), max(off), min(low)) +
+           tuple(np.percentile(low, (25, 50, 75))) +
+           (max(low), sum(x <= SOLAR_CELL_RTOL for x in low),
+            SOLAR_CELL_RTOL)))
+    check(max(off) <= SOLAR_CELL_RTOL, 'solar f32 for_test loss at the '
+          'cell\'s cut within %g of the reference on every batch'
+          % SOLAR_CELL_RTOL)
+    check(min(low) > SOLAR_CELL_RTOL, 'the reference in bfloat16 '
+          'throughout misses %g on every batch' % SOLAR_CELL_RTOL)
+
+
+def phase_solar(seq=4096, seed=0):
+    """The op alone at 32768 positions; then models.solar_open2.BASE
+    cut as above: loss and sampled gradients of the f32 TRAIN program
+    (the chunked delta rule and its reverse walk, the three ungated
+    filters, the float32 decay chain, the grouped causal flash kernels
+    at a group of 8 without rotary, the held experts' grouped matmuls)
+    against the reference's on one seeded sequence; then the cell's
+    for_test losses."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.models import solar_open2
+    from paddle_tpu.models.reference import solar_open2 as reference
+    from paddle_tpu.ops.pallas import common
+    _solar_single_op()
+    cfg = _solar_cut()
+    sizes = reference.sizes_of(cfg)
+    feed = _ints32(solar_open2.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(seed)))
+    ids, labels = (jnp.asarray(feed[k]) for k in ('ids', 'labels'))
+    experts = {}
+
+    def sample(name, array):
+        if name == 'gate':
+            return {'%s, %s loaded held expert (layer 0)' % (name, which):
+                    array[e] for which, e in experts.items()}
+        return {name: array}
+
+    def ref_loss(some, full, biases, chosen=None, dtype=jnp.float32):
+        full = list(full)
+        for name, w in some.items():
+            full[SOLAR_SAMPLED[name]] = w
+        return reference.loss(full, biases, ids, labels, sizes=sizes,
+                              dtype=dtype, remat=True, chosen=chosen)
+
+    ref_grads = jax.jit(jax.value_and_grad(ref_loss))
+    ref_free = jax.jit(lambda full, biases: [
+        ref_loss({}, full, biases, dtype=dt)
+        for dt in (jnp.float32, jnp.bfloat16)])
+    ref_loads = jax.jit(lambda full, biases: reference.forward(
+        full, biases, ids, sizes=sizes)[1])
+
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        _, _, loss = solar_open2.build_pretrain(cfg, seq)
+        every = main.all_parameters()
+        params = [p.name for p in every if p.trainable]
+        biases = [p.name for p in every if not p.trainable]
+        pairs = dict((p.name, g.name) for p, g in
+                     fluid.optimizer.SGD(0.0).minimize(loss)[1])
+    fetches = [loss] + [pairs[params[i]] for i in SOLAR_SAMPLED.values()]
+    routers = [op for op in main.global_block().ops
+               if op.type == 'moe_route']
+    load_names = [op.output('Load')[0] for op in routers]
+    choice_names = [op.output('TopKIdx')[0] for op in routers]
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights, bias_values = ([np.asarray(fluid.core.as_array(
+            scope.find_var(n))) for n in names]
+            for names in (params, biases))
+        t0 = time.time()
+        got = exe.run(main, feed=feed,
+                      fetch_list=fetches + load_names + choice_names)
+        got_loss = _scalar(got[:1])
+        say('solar f32 train program, 1 x %d tokens, %d layers: loss '
+            '%.6f in %.1f s; moe/held_share %.4f, moe/held_rows_max %d, '
+            'moe/dropped_tokens %d; kda/calls %d, kda/chunks %d a step; '
+            'short_conv/calls %d; flash_attention last dispatch %s; '
+            'peak memory %.2f GB'
+            % (seq, cfg.layers, got_loss, time.time() - t0,
+               monitor.gauge_value('moe/held_share'),
+               monitor.gauge_value('moe/held_rows_max'),
+               monitor.counter_value('moe/dropped_tokens'),
+               monitor.counter_value('kda/calls'),
+               monitor.gauge_value('kda/chunks'),
+               monitor.counter_value('short_conv/calls'),
+               common._LAST.get('flash_attention'),
+               _peak_bytes(jax.devices()[:1])[0] / 1e9))
+        check(common._LAST.get('flash_attention', {}).get('path') ==
+              'fused', 'the grouped causal calls (8 query heads on one '
+              'K/V head, no rotary) ran the flash kernels')
+        check(monitor.gauge_value('kda/chunks') ==
+              3 * 2 * -(-seq // 64), 'kda/chunks counted a forward and '
+              'a reverse scan of %d chunks a delta-rule layer'
+              % -(-seq // 64))
+        chosen = [jnp.asarray(x) for x in got[-len(routers):]]
+        program_loads = [np.asarray(x) for x in got[
+            len(fetches):len(fetches) + len(routers)]]
+        grads_raw = [np.asarray(x) for x in got[1:len(fetches)]]
+        del got
+        for name in scope.local_var_names():
+            scope.erase(name)
+    weights, bias_values = ([jnp.asarray(x) for x in part]
+                            for part in (weights, bias_values))
+    loads = [np.asarray(x) for x in ref_loads(weights, bias_values)]
+    loads_off = [int(np.sum(a != b))
+                 for a, b in zip(program_loads, loads)]
+    say('experts a layer whose load differs between program and '
+        'reference (a near-tie token changes two by one): %s; held rows '
+        'a layer %s' % (loads_off, [int(x[:8].sum()) for x in loads]))
+    check(max(loads_off) <= SOLAR_LOADS_OFF,
+          'the program\'s choice is the reference\'s but for near-ties')
+    load = loads[0][:8]
+    experts.update(most=int(load.argmax()), least=int(load.argmin()))
+    grads = {what: x for name, g in zip(SOLAR_SAMPLED, grads_raw)
+             for what, x in sample(name, g).items()}
+    some = {name: weights[i] for name, i in SOLAR_SAMPLED.items()}
+    pinned, want_grads = ref_grads(some, weights, bias_values, chosen)
+    want_loss, low = (float(x) for x in ref_free(weights, bias_values))
+    rel = abs(got_loss - want_loss) / want_loss
+    low_rel = abs(low - want_loss) / want_loss
+    say('reference loss %.6f, program %.6f (relative difference %.2e; '
+        '%.2e from the reference routed by the program\'s choice); '
+        'reference in bfloat16 throughout %.6f (%.2e)'
+        % (want_loss, got_loss, rel,
+           abs(got_loss - float(pinned)) / float(pinned), low, low_rel))
+    check(abs(got_loss - float(pinned)) <= SOLAR_LOSS_RTOL * float(pinned),
+          'solar f32 train loss within %g of the reference routed by '
+          'the program\'s choice' % SOLAR_LOSS_RTOL)
+    check(rel <= SOLAR_CELL_RTOL, 'solar f32 train loss within %g of '
+          'the reference by its own choice' % SOLAR_CELL_RTOL)
+    check(low_rel > SOLAR_CELL_RTOL, 'the reference in bfloat16 '
+          'throughout misses %g' % SOLAR_CELL_RTOL)
+    far = 0.0
+    for name in SOLAR_SAMPLED:
+        for what, y in sample(name, np.asarray(want_grads[name])).items():
+            x = grads[what]
+            e = float(np.abs(x - y).max() / np.abs(y).max())
+            d = float(np.linalg.norm(x - y) / np.linalg.norm(y))
+            far = max(far, d)
+            say('gradient of %s %s: largest entry difference %.3e of '
+                'the largest entry (%.3e), relative L2 distance %.3e'
+                % (what, x.shape, e, np.abs(y).max(), d))
+    del weights, bias_values, some, want_grads
+    _solar_cell_losses(seq, seed)
+    check(far <= SOLAR_L2_RTOL,
+          'solar gradients: every sampled tensor within %g of the '
+          'reference\'s routed by the program\'s choice, relative L2 '
+          'distance (worst %.3e)' % (SOLAR_L2_RTOL, far))
+
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
     ap.add_argument('--chips', type=int, choices=(1, 4), default=1)
     ap.add_argument('--phase',
                     choices=('bert', 'olmoe', 'laguna', 'moonlight',
-                             'lfm2', 'evabyte'),
+                             'lfm2', 'evabyte', 'solar'),
                     default='bert',
                     help="'olmoe' / 'laguna' / 'moonlight' / 'lfm2' / "
-                    "'evabyte': only that model's gradient check")
+                    "'evabyte' / 'solar': only that model's gradient "
+                    "check")
     args = ap.parse_args()
 
     import jax
@@ -1969,6 +2293,8 @@ def main():
             phase_lfm2_gradients()
         elif args.phase == 'evabyte':
             phase_evabyte()
+        elif args.phase == 'solar':
+            phase_solar()
         elif args.chips == 4:
             phase_four_chips(
                 models.bert.BertConfig(dropout=0.0, attn_dropout=0.0),

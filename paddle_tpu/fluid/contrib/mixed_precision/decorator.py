@@ -55,6 +55,9 @@ def _mark_amp_ops(program, amp_lists):
                     'group_norm', 'sync_batch_norm',
                     'rms_norm', 'rotary_embedding', 'short_conv',
                     'eva_chunk_summary',
+                    # float32 log decays beside bf16 q, k, v: cast
+                    # neither way
+                    'kda_attention',
                     # f32 gates beside bf16 rows: cast neither way
                     'moe_route', 'moe_dispatch', 'moe_combine',
                     # compute in f32 internally; black-casting their

@@ -29,6 +29,9 @@ gray_list = {
     # f32 inside, output in the input's dtype, like layer_norm
     'rms_norm', 'rotary_embedding', 'moe_dispatch', 'moe_combine',
     'short_conv', 'eva_chunk_summary',
+    # bf16 q, k, v beside float32 log decays; the solve and the state
+    # float32 inside, the output in v's dtype
+    'kda_attention',
 }
 
 

@@ -204,6 +204,24 @@ def short_conv(input, filter_size, gate_in=None, gate_out=None,
     return _simple('short_conv', ins, name=name)
 
 
+def kda_attention(q, k, v, a, beta, name=None):
+    """The gated delta rule with a per-channel decay (the linear
+    attention of Solar Open 2's ``linear_attn_config`` layers; the op
+    ``kda_attention``, ``ops/kda_ops.py``, has the equations): q, k [B,
+    T, H, dk], v [B, T, H, dv], ``a`` [B, T, H, dk] the LOG of each key
+    channel's decay (<= 0; float32 under AMP), ``beta`` [B, T, H] the
+    write strength -> o [B, T, H, dv] in v's dtype, ``o_t = S_t^T q_t``
+    of a state ``S_t = (I - beta_t k_t k_t^T) Diag(exp(a_t)) S_(t-1) +
+    beta_t k_t v_t^T`` that starts at zero in every sequence.  What
+    comes before (projections, filters, the normalisation of q and k)
+    and after (the gated norm) is the model's.  Computed in chunks of
+    64 tokens (``ops.kda_ops.CHUNK``); T need be no whole number of
+    them."""
+    return _simple('kda_attention',
+                   {'Q': q, 'K': k, 'V': v, 'A': a, 'Beta': beta},
+                   dtype=v.dtype, name=name)
+
+
 def flash_attention(q, k, v, causal=False, window=0, coarse=None,
                     with_lse=False, name=None):
     """The ``fused_multihead_attention`` op on heads already split: q

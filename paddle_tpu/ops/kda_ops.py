@@ -1,0 +1,249 @@
+"""The gated delta rule with a per-CHANNEL decay (Kimi Delta Attention,
+"KDA": the linear-attention layers of Solar Open 2), forward and
+backward, in chunked matmul form.
+
+Per sequence and head, with a state ``S`` [dk, dv] that is zero at the
+sequence's start:
+
+    S_t = (I - beta_t k_t k_t^T) Diag(exp(a_t)) S_(t-1) + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+``a`` is the LOG of the decay (<= 0, one number a key channel and
+token), ``beta`` the write strength (in (0, 2) where negative
+eigenvalues are allowed).  A loop over tokens is the reference
+(``paddle_tpu/models/reference/solar_open2.py`` ``kda_recurrence``);
+here the sequence is cut into chunks of ``chunk`` tokens and only the
+state crosses a chunk's boundary.  With ``G_t`` the running sum of ``a``
+inside a chunk, ``S_0`` the state the chunk starts from and ``u_t =
+beta_t (v_t - (Diag(exp(a_t)) S_(t-1))^T k_t)`` (so that ``S_t =
+Diag(exp(a_t)) S_(t-1) + k_t u_t^T``):
+
+    A_tj = sum_c k_t[c] k_j[c] exp(G_t[c] - G_j[c])        j <  t
+    B_tj = sum_c q_t[c] k_j[c] exp(G_t[c] - G_j[c])        j <= t
+    (I + Diag(beta) A) U = Diag(beta) (V - Kbar S_0)       Kbar_t = k_t exp(G_t)
+    O    = Qbar S_0 + B U                                  Qbar_t = q_t exp(G_t)
+    S_C  = Diag(exp(G_C)) S_0 + Khat^T U                   Khat_t = k_t exp(G_C - G_t)
+
+``A``, ``B``, the solve of the unit lower-triangular system (``W = (I +
+Diag(beta) A)^-1 Diag(beta) [Kbar | V]``, so ``U = W_v - W_k S_0``),
+``Qbar`` and ``Khat`` are computed for ALL chunks, heads and sequences
+at once (``_prepare``); a ``lax.scan`` over the T / chunk chunks carries
+the state through three small matmuls (``_step``).
+
+THE DECAY IS PER CHANNEL, so ``exp(G_t - G_j)`` does not factor out of
+the sum over channels as a scalar, and the factored form ``(k_t
+exp(G_t)) . (k_j exp(-G_j))`` overflows float32: at ``-a`` of 1.6 a
+token ``exp(-G_j)`` passes 3e38 inside 64 tokens.  Every exponent taken
+here is a DIFFERENCE that is <= 0: a chunk is cut into sub-chunks of
+``SUB`` tokens; between a row of sub-chunk I and a column of an earlier
+sub-chunk J the weight is ``exp(G_t - r_I) exp(r_I - G_j)`` with ``r_I``
+the running sum at I's start, which lies between the two (both factors
+<= 1, the product matmul-shaped); inside one sub-chunk the difference
+``G_t - G_j`` is taken before the exponential, on a [SUB, SUB, dk]
+block.  Positions outside the causal triangle are masked in the
+EXPONENT (to -inf), never after it.  No clamp, no floor, no dropped
+term: a factor that underflows belongs to a product under 1e-38.
+
+The backward is a ``custom_vjp`` of the whole op: it keeps what the op
+was handed (q, k, v, a, beta as they arrived) and the state at each
+chunk's START (T / chunk x [dk, dv] a head), computes ``_prepare``
+again under ``jax.vjp``, walks the chunks in reverse carrying the
+state's cotangent with each chunk's ``_step`` under a ``jax.vjp`` of
+its own, and hands the operands' cotangents back through ``_prepare``.
+Nothing saved grows with T x dk x dv, nor with what ``_prepare`` holds
+inside a chunk.
+
+float32 inside whatever arrives (float64 under x64): the decays, the
+triangular solve, the state and every product (``Precision.HIGHEST``:
+the op's FLOPs are a few percent of a layer's projections); the output
+in V's dtype (the ``rms_norm`` / ``short_conv`` policy).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from . import registry
+from .registry import register
+
+CHUNK = 64
+SUB = 16
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm(spec, a, b):
+    return jnp.einsum(spec, a, b, precision=_HIGHEST)
+
+
+def _exp_masked(exponent, keep):
+    """exp where ``keep``, 0 elsewhere, masked BEFORE the exponential:
+    what is not kept may be a large positive difference."""
+    return jnp.exp(jnp.where(keep, exponent, -jnp.inf))
+
+
+def _prepare(q, k, v, a, beta):
+    """q, k, a [..., C, dk], v [..., C, dv], beta [..., C] of whole
+    chunks (leading axes: chunk, sequence, head) -> the scan's operands
+    (W_k [..., C, dk], W_v [..., C, dv], Qbar, B [..., C, C], Khat,
+    exp(G_C) [..., dk])."""
+    lead, (c, dk) = k.shape[:-2], k.shape[-2:]
+    sub = SUB if c % SUB == 0 else c
+    n_sub = c // sub
+    g = jnp.cumsum(a, axis=-2)                          # G, inclusive
+    g_end = g[..., -1:, :]
+    q_bar, k_bar = q * jnp.exp(g), k * jnp.exp(g)
+    k_hat = k * jnp.exp(g_end - g)
+
+    def blocks(x):                      # [..., C, d] -> [..., S, s, d]
+        return x.reshape(lead + (n_sub, sub, x.shape[-1]))
+
+    gs, qs, ks = blocks(g), blocks(q), blocks(k)
+    # r_I: the running sum at sub-chunk I's start (0 at the chunk's)
+    start = jnp.concatenate(
+        [jnp.zeros_like(gs[..., :1, -1, :]), gs[..., :-1, -1, :]], -2)
+    row = jnp.exp(gs - start[..., None, :])             # [..., I, i, dk]
+    earlier = jnp.arange(n_sub)[:, None] > jnp.arange(n_sub)[None, :]
+    col = _exp_masked(                                  # [..., I, J, j, dk]
+        start[..., :, None, None, :] - gs[..., None, :, :, :],
+        earlier[:, :, None, None])
+    k_col = ks[..., None, :, :, :] * col
+    a_off = _mm('...Iic,...IJjc->...IiJj', ks * row, k_col)
+    b_off = _mm('...Iic,...IJjc->...IiJj', qs * row, k_col)
+    # inside a sub-chunk: the difference first, on [s, s, dk]
+    upto = jnp.arange(sub)[:, None] >= jnp.arange(sub)[None, :]
+    within = _exp_masked(gs[..., :, None, :] - gs[..., None, :, :],
+                         upto[:, :, None])              # [..., I, i, j, dk]
+    k_within = ks[..., None, :, :] * within
+    a_in = jnp.sum(ks[..., :, None, :] * k_within, -1)
+    b_in = jnp.sum(qs[..., :, None, :] * k_within, -1)
+    strictly = jnp.arange(sub)[:, None] > jnp.arange(sub)[None, :]
+    same = jnp.eye(n_sub, dtype=k.dtype)[:, None, :, None]
+
+    def whole(off, inside):             # -> [..., C, C]
+        return (off + inside[..., :, :, None, :] * same).reshape(
+            lead + (c, c))
+
+    a_mat = whole(a_off, a_in * strictly)
+    b_mat = whole(b_off, b_in)
+    system = jnp.eye(c, dtype=k.dtype) + beta[..., None] * a_mat
+    w = jax.lax.linalg.triangular_solve(
+        system, beta[..., None] * jnp.concatenate([k_bar, v], -1),
+        left_side=True, lower=True, unit_diagonal=True)
+    return (w[..., :dk], w[..., dk:], q_bar, b_mat, k_hat,
+            jnp.exp(g_end[..., 0, :]))
+
+
+def _step(state, operands):
+    """One chunk: the state [B, H, dk, dv] it starts from -> (the state
+    it ends with, its outputs [B, H, C, dv])."""
+    w_k, w_v, q_bar, b_mat, k_hat, decay = operands
+    u = w_v - _mm('bhck,bhkv->bhcv', w_k, state)
+    out = _mm('bhck,bhkv->bhcv', q_bar, state) + \
+        _mm('bhcj,bhjv->bhcv', b_mat, u)
+    state = decay[..., None] * state + _mm('bhck,bhcv->bhkv', k_hat, u)
+    return state, out
+
+
+def _count_chunks(operands):
+    """``kda/chunks``: the chunk steps the scans of the traced program
+    take, the forward's and the reverse walk's alike."""
+    registry.trace_sum('kda/chunks', operands[0].shape[0])
+
+
+def _layout(t, chunk):
+    """-> (chunk size as run, chunks): a sequence shorter than a chunk
+    is one chunk of whole sub-chunks."""
+    chunk = min(int(chunk), -(-t // SUB) * SUB)
+    return chunk, -(-t // chunk)
+
+
+def _chunked(x, chunk, n, dtype):
+    """[B, T, H, ...] -> [N, B, H, C, ...] in ``dtype``, the tail
+    padded with zeros: tokens that neither decay nor write (a = 0,
+    beta = 0, k = 0)."""
+    b, t = x.shape[:2]
+    x = jnp.pad(x.astype(dtype), ((0, 0), (0, n * chunk - t)) +
+                ((0, 0),) * (x.ndim - 2))
+    x = x.reshape((b, n, chunk) + x.shape[2:])
+    return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
+
+
+def _operands(q, k, v, a, beta, chunk):
+    f32 = jnp.float64 if v.dtype == jnp.float64 else jnp.float32
+    chunk, n = _layout(k.shape[1], chunk)
+    return _prepare(*(_chunked(x, chunk, n, f32)
+                      for x in (q, k, v, a, beta)))
+
+
+def _forward(q, k, v, a, beta, chunk):
+    """-> (o [B, T, H, dv] in v's dtype, the state at each chunk's
+    START [N, B, H, dk, dv])."""
+    operands = _operands(q, k, v, a, beta, chunk)
+    _count_chunks(operands)
+    w_k, w_v = operands[0], operands[1]
+
+    def step(state, x):
+        after, out = _step(state, x)
+        return after, (out, state)
+
+    zero = jnp.zeros(w_k.shape[1:3] + (w_k.shape[-1], w_v.shape[-1]),
+                     w_k.dtype)
+    _, (out, starts) = jax.lax.scan(step, zero, operands)
+    b, t, h = k.shape[:3]
+    out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 3)   # [B, N, C, H, dv]
+    out = out.reshape(b, -1, h, v.shape[-1])[:, :t]
+    return out.astype(v.dtype), starts
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def gated_delta_rule(q, k, v, a, beta, chunk=CHUNK):
+    """q, k, a [B, T, H, dk], v [B, T, H, dv], beta [B, T, H] -> o [B,
+    T, H, dv] in v's dtype.  T need be no whole number of chunks."""
+    return _forward(q, k, v, a, beta, chunk)[0]
+
+
+def _rule_fwd(q, k, v, a, beta, chunk):
+    out, starts = _forward(q, k, v, a, beta, chunk)
+    return out, ((q, k, v, a, beta), starts)
+
+
+def _rule_bwd(chunk, saved, d_out):
+    """What the forward kept is what it was handed and the state at
+    each chunk's start: the per-chunk operands are computed again
+    (under ``jax.vjp``, which then carries their cotangents back to q,
+    k, v, a and beta), and the chunks walked in reverse with the
+    state's cotangent, each chunk's ``_step`` under a ``jax.vjp`` of
+    its own."""
+    inputs, starts = saved
+    operands, pull = jax.vjp(
+        lambda *x: _operands(*x, chunk), *inputs)
+    _count_chunks(operands)
+    size, n = _layout(inputs[1].shape[1], chunk)
+
+    def step(d_state, x):
+        chunk_operands, start, d_chunk_out = x
+        _, pull_step = jax.vjp(_step, start, chunk_operands)
+        return pull_step((d_state, d_chunk_out))
+
+    _, d_operands = jax.lax.scan(
+        step, jnp.zeros_like(starts[0]),
+        (operands, starts, _chunked(d_out, size, n, starts.dtype)),
+        reverse=True)
+    return pull(d_operands)
+
+
+gated_delta_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+@register('kda_attention')
+def kda_attention(ctx, ins, attrs):
+    """Q, K, A [B, T, H, dk], V [B, T, H, dv], Beta [B, T, H] -> Out
+    [B, T, H, dv] in chunks of ``CHUNK`` tokens: the module's docstring
+    has the equations."""
+    from ..fluid import monitor
+    monitor.add('kda/calls', 1)
+    out = gated_delta_rule(
+        ins['Q'][0], ins['K'][0], ins['V'][0], ins['A'][0],
+        ins['Beta'][0])
+    return {'Out': [out]}

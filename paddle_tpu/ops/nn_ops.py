@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from . import keep_hash
+from . import registry
 from .registry import register
 
 
@@ -622,8 +623,7 @@ def dropout(ctx, ins, attrs):
     # draws the same bits and XLA merges the two draws
     from ..fluid import monitor
     monitor.add('dropout/counter_draws', 1)
-    monitor.set_gauge('dropout/elements',
-                      monitor.gauge_value('dropout/elements') + x.size)
+    registry.trace_sum('dropout/elements', x.size)
     keep = keep_hash.keep_nd(ctx.draw_seed(), x.shape, p)
     mask = keep.astype(x.dtype)
     if impl == 'upscale_in_train':

@@ -68,13 +68,27 @@ class LowerCtx(object):
         return self.draw_seed()
 
 
-def begin_trace():
-    """The executor calls this as a trace of a segment begins:
-    `dropout/elements` is a sum the dropout lowerings add to over ONE
-    traced program, so what shape inference lowered at build time, or
-    an earlier program, is not in the reading."""
+# gauges that are SUMS over one traced program: lowerings add to them
+# (trace_sum) and a trace's beginning takes them back to zero
+_TRACE_SUMS = {'dropout/elements'}
+
+
+def trace_sum(name, amount):
+    """Add ``amount`` to the gauge ``name``, a sum over ONE traced
+    program (``dropout/elements``, ``kda/chunks``)."""
     from ..fluid import monitor
-    monitor.set_gauge('dropout/elements', 0.0)
+    _TRACE_SUMS.add(name)
+    monitor.set_gauge(name, monitor.gauge_value(name) + amount)
+
+
+def begin_trace():
+    """The executor calls this as a trace of a segment begins: a gauge
+    the lowerings sum into over one traced program starts at zero, so
+    what shape inference lowered at build time, or an earlier program,
+    is not in the reading."""
+    from ..fluid import monitor
+    for name in _TRACE_SUMS:
+        monitor.set_gauge(name, 0.0)
 
 
 class OpDef(object):
