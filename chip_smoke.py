@@ -1990,6 +1990,7 @@ def _solar_single_op(t=SOLAR_OP_TOKENS, heads=8, d=128, seed=0):
     from benchmark.lib import flops, peaks, solar_flops
     from paddle_tpu.models.reference import solar_open2 as reference
     from paddle_tpu.ops import kda_ops
+    from paddle_tpu.ops.pallas import common
     rng = np.random.RandomState(seed)
     q, k = (rng.randn(1, t, heads, d) for _ in range(2))
     q /= np.linalg.norm(q, axis=-1, keepdims=True) * d ** 0.5
@@ -2025,16 +2026,22 @@ def _solar_single_op(t=SOLAR_OP_TOKENS, heads=8, d=128, seed=0):
                np.abs(y).max()))
     check(np.isfinite(got[0]).all() and worst <= SOLAR_OP_TOL,
           'the op at %d positions, forward and five gradients, within '
-          '%g of the token scan (worst %.3e)' % (t, SOLAR_OP_TOL, worst))
+          '%g of the token scan (worst %.3e; PR 46, the scores dense: '
+          '1.1e-5)' % (t, SOLAR_OP_TOL, worst))
+    last = common._LAST.get('kda_chunk', {})
+    check(last.get('path') == 'fused' and not last.get('interpret'),
+          'the op\'s in-chunk scores ran the kda_chunk kernels (%s)' % last)
     forward = jax.jit(kda_ops.gated_delta_rule)
     fwd_s, both_s = _timed(forward, *args), _timed(op, *args)
     cost = solar_flops.kda_train_cost(1, t, heads, d, itemsize=4)
     least, side = flops.roofline_seconds(
         *cost, *peaks.chip_peak(jax.devices()[0].device_kind))
-    say('kda_attention alone at %d positions: forward %.2f ms, forward '
-        '+ backward %.2f ms; the hand count (%.1f GFLOP, %.1f MB at 4 '
-        'bytes an element) is %s-bound at %.2f ms: %.1f%% of its '
-        'roofline; peak memory %.2f GB'
+    say('kda_attention alone at %d positions, the scores by the '
+        'kda_chunk kernels: forward %.2f ms, forward + backward %.2f ms '
+        '(PR 46, the scores dense: 23.87 / 77.63 ms); the hand count '
+        '(%.1f GFLOP, %.1f MB at 4 bytes an element) is %s-bound at '
+        '%.2f ms: %.1f%% of its roofline (PR 46: 3.8%%); peak memory '
+        '%.2f GB'
         % (t, fwd_s * 1e3, both_s * 1e3, cost[0] / 1e9, cost[1] / 1e6,
            side, least * 1e3, 100 * least / both_s,
            _peak_bytes(jax.devices()[:1])[0] / 1e9))
@@ -2190,6 +2197,10 @@ def phase_solar(seq=4096, seed=0):
               3 * 2 * -(-seq // 64), 'kda/chunks counted a forward and '
               'a reverse scan of %d chunks a delta-rule layer'
               % -(-seq // 64))
+        check(common._LAST.get('kda_chunk', {}).get('path') == 'fused',
+              'the three delta-rule layers\' in-chunk scores ran the '
+              'kda_chunk kernels (%d fused dispatches)'
+              % monitor.counter_value('pallas/kda_chunk/dispatch_fused'))
         chosen = [jnp.asarray(x) for x in got[-len(routers):]]
         program_loads = [np.asarray(x) for x in got[
             len(fetches):len(fetches) + len(routers)]]
@@ -2243,7 +2254,8 @@ def phase_solar(seq=4096, seed=0):
     check(far <= SOLAR_L2_RTOL,
           'solar gradients: every sampled tensor within %g of the '
           'reference\'s routed by the program\'s choice, relative L2 '
-          'distance (worst %.3e)' % (SOLAR_L2_RTOL, far))
+          'distance (worst %.3e; PR 46, the scores dense: 9.1e-5)'
+          % (SOLAR_L2_RTOL, far))
 
 
 

@@ -30,6 +30,21 @@ Diag(beta) A)^-1 Diag(beta) [Kbar | V]``, so ``U = W_v - W_k S_0``),
 at once (``_prepare``); a ``lax.scan`` over the T / chunk chunks carries
 the state through three small matmuls (``_step``).
 
+``A`` and ``B`` (``_scores``) are a Pallas kernel on a TPU
+(``ops/pallas/kda_chunk.py``, ``kda_chunk`` in the kernel library: one
+chunk-head a grid step, the [SUB, SUB, dk] decay blocks of the next
+paragraph built and dropped in VMEM), with a backward kernel of its own
+under its ``custom_vjp`` (dq, dk, dG from dA, dB; the blocks built
+again on the chip).  ``gated_delta_rule`` asks ``common.dispatch`` once
+a call: the kernels where the working dtype is float32, dk a whole
+number of 128-lane tiles and the chunk as run whole sub-chunks; the
+dense form below (``_scores``, XLA's: its blocks are HBM buffers,
+sixteen times the operands) for float64, other widths, under the GSPMD
+runner (``auto_partitioned``) and off a TPU (the kernels' bodies under
+the Pallas interpreter where ``FLAGS_pallas_force`` asks).  Everything
+else is XLA's on either path: the cumulative sum, ``Qbar``, ``Kbar``,
+``Khat``, the solve, ``_step`` and both scans.
+
 THE DECAY IS PER CHANNEL, so ``exp(G_t - G_j)`` does not factor out of
 the sum over channels as a scalar, and the factored form ``(k_t
 exp(G_t)) . (k_j exp(-G_j))`` overflows float32: at ``-a`` of 1.6 a
@@ -46,12 +61,15 @@ term: a factor that underflows belongs to a product under 1e-38.
 
 The backward is a ``custom_vjp`` of the whole op: it keeps what the op
 was handed (q, k, v, a, beta as they arrived) and the state at each
-chunk's START (T / chunk x [dk, dv] a head), computes ``_prepare``
-again under ``jax.vjp``, walks the chunks in reverse carrying the
-state's cotangent with each chunk's ``_step`` under a ``jax.vjp`` of
-its own, and hands the operands' cotangents back through ``_prepare``.
-Nothing saved grows with T x dk x dv, nor with what ``_prepare`` holds
-inside a chunk.
+chunk's START (T / chunk x [dk, dv] a head), and not one byte for the
+kernels (their ``custom_vjp`` keeps q, k and G of the RECOMPUTED
+preparation, transients of the backward).  It computes ``_prepare``
+again under ``jax.vjp`` (the scores by the forward kernel once more),
+walks the chunks in reverse carrying the state's cotangent with each
+chunk's ``_step`` under a ``jax.vjp`` of its own, and hands the
+operands' cotangents back through ``_prepare`` (through the scores by
+the backward kernel).  Nothing saved grows with T x dk x dv, nor with
+what ``_prepare`` holds inside a chunk.
 
 float32 inside whatever arrives (float64 under x64): the decays, the
 triangular solve, the state and every product (``Precision.HIGHEST``:
@@ -82,18 +100,14 @@ def _exp_masked(exponent, keep):
     return jnp.exp(jnp.where(keep, exponent, -jnp.inf))
 
 
-def _prepare(q, k, v, a, beta):
-    """q, k, a [..., C, dk], v [..., C, dv], beta [..., C] of whole
-    chunks (leading axes: chunk, sequence, head) -> the scan's operands
-    (W_k [..., C, dk], W_v [..., C, dv], Qbar, B [..., C, C], Khat,
-    exp(G_C) [..., dk])."""
+def _scores(q, k, g):
+    """q, k and the running log decay g [..., C, dk] of whole chunks
+    -> (A strictly lower, B lower) [..., C, C]: the dense form, and the
+    ``kda_chunk`` kernel's fallback.  Its [.., SUB, SUB, dk] blocks are
+    XLA buffers in HBM."""
     lead, (c, dk) = k.shape[:-2], k.shape[-2:]
     sub = SUB if c % SUB == 0 else c
     n_sub = c // sub
-    g = jnp.cumsum(a, axis=-2)                          # G, inclusive
-    g_end = g[..., -1:, :]
-    q_bar, k_bar = q * jnp.exp(g), k * jnp.exp(g)
-    k_hat = k * jnp.exp(g_end - g)
 
     def blocks(x):                      # [..., C, d] -> [..., S, s, d]
         return x.reshape(lead + (n_sub, sub, x.shape[-1]))
@@ -124,8 +138,20 @@ def _prepare(q, k, v, a, beta):
         return (off + inside[..., :, :, None, :] * same).reshape(
             lead + (c, c))
 
-    a_mat = whole(a_off, a_in * strictly)
-    b_mat = whole(b_off, b_in)
+    return whole(a_off, a_in * strictly), whole(b_off, b_in)
+
+
+def _prepare(q, k, v, a, beta, scores=_scores):
+    """q, k, a [..., C, dk], v [..., C, dv], beta [..., C] of whole
+    chunks (leading axes: chunk, sequence, head) -> the scan's operands
+    (W_k [..., C, dk], W_v [..., C, dv], Qbar, B [..., C, C], Khat,
+    exp(G_C) [..., dk]).  ``scores``: ``_scores`` or the kernel."""
+    c, dk = k.shape[-2:]
+    g = jnp.cumsum(a, axis=-2)                          # G, inclusive
+    g_end = g[..., -1:, :]
+    q_bar, k_bar = q * jnp.exp(g), k * jnp.exp(g)
+    k_hat = k * jnp.exp(g_end - g)
+    a_mat, b_mat = scores(q, k, g)
     system = jnp.eye(c, dtype=k.dtype) + beta[..., None] * a_mat
     w = jax.lax.linalg.triangular_solve(
         system, beta[..., None] * jnp.concatenate([k_bar, v], -1),
@@ -169,17 +195,41 @@ def _chunked(x, chunk, n, dtype):
     return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
 
 
-def _operands(q, k, v, a, beta, chunk):
-    f32 = jnp.float64 if v.dtype == jnp.float64 else jnp.float32
+def _working_dtype(v):
+    return jnp.float64 if v.dtype == jnp.float64 else jnp.float32
+
+
+def _scores_path(k, v, chunk, auto_partitioned):
+    """How this call's in-chunk scores are computed: 'dense'
+    (``_scores``), 'fused' (the ``kda_chunk`` kernel) or 'interpret'
+    (its body under the Pallas interpreter: FLAGS_pallas_force off a
+    TPU).  One ``common.dispatch`` decision a call, which its forward
+    and its backward both follow; the kernel's layout decides it from
+    what the operands show (``kda_chunk.checks``)."""
+    from .pallas import common, kda_chunk
+    fused, interpret = common.dispatch(
+        'kda_chunk', True,
+        checks=kda_chunk.checks(_layout(k.shape[1], chunk)[0],
+                                k.shape[-1], _working_dtype(v)),
+        auto_partitioned=auto_partitioned)
+    return ('interpret' if interpret else 'fused') if fused else 'dense'
+
+
+def _operands(q, k, v, a, beta, chunk, path):
+    scores = _scores
+    if path != 'dense':
+        from .pallas import kda_chunk
+        scores = functools.partial(kda_chunk.chunk_scores,
+                                   interpret=path == 'interpret')
     chunk, n = _layout(k.shape[1], chunk)
-    return _prepare(*(_chunked(x, chunk, n, f32)
-                      for x in (q, k, v, a, beta)))
+    return _prepare(*(_chunked(x, chunk, n, _working_dtype(v))
+                      for x in (q, k, v, a, beta)), scores=scores)
 
 
-def _forward(q, k, v, a, beta, chunk):
+def _forward(q, k, v, a, beta, chunk, path):
     """-> (o [B, T, H, dv] in v's dtype, the state at each chunk's
     START [N, B, H, dk, dv])."""
-    operands = _operands(q, k, v, a, beta, chunk)
+    operands = _operands(q, k, v, a, beta, chunk, path)
     _count_chunks(operands)
     w_k, w_v = operands[0], operands[1]
 
@@ -196,28 +246,36 @@ def _forward(q, k, v, a, beta, chunk):
     return out.astype(v.dtype), starts
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def gated_delta_rule(q, k, v, a, beta, chunk=CHUNK):
+def gated_delta_rule(q, k, v, a, beta, chunk=CHUNK, auto_partitioned=False):
     """q, k, a [B, T, H, dk], v [B, T, H, dv], beta [B, T, H] -> o [B,
-    T, H, dv] in v's dtype.  T need be no whole number of chunks."""
-    return _forward(q, k, v, a, beta, chunk)[0]
+    T, H, dv] in v's dtype.  T need be no whole number of chunks.
+    ``auto_partitioned``: ``common.dispatch``'s (the caller's word that
+    XLA will partition this program over a mesh)."""
+    return _rule(q, k, v, a, beta, chunk,
+                 _scores_path(k, v, chunk, auto_partitioned))
 
 
-def _rule_fwd(q, k, v, a, beta, chunk):
-    out, starts = _forward(q, k, v, a, beta, chunk)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _rule(q, k, v, a, beta, chunk, path):
+    return _forward(q, k, v, a, beta, chunk, path)[0]
+
+
+def _rule_fwd(q, k, v, a, beta, chunk, path):
+    out, starts = _forward(q, k, v, a, beta, chunk, path)
     return out, ((q, k, v, a, beta), starts)
 
 
-def _rule_bwd(chunk, saved, d_out):
+def _rule_bwd(chunk, path, saved, d_out):
     """What the forward kept is what it was handed and the state at
     each chunk's start: the per-chunk operands are computed again
     (under ``jax.vjp``, which then carries their cotangents back to q,
-    k, v, a and beta), and the chunks walked in reverse with the
-    state's cotangent, each chunk's ``_step`` under a ``jax.vjp`` of
-    its own."""
+    k, v, a and beta: through the scores' kernel by ITS backward
+    kernel), and the chunks walked in reverse with the state's
+    cotangent, each chunk's ``_step`` under a ``jax.vjp`` of its
+    own."""
     inputs, starts = saved
     operands, pull = jax.vjp(
-        lambda *x: _operands(*x, chunk), *inputs)
+        lambda *x: _operands(*x, chunk, path), *inputs)
     _count_chunks(operands)
     size, n = _layout(inputs[1].shape[1], chunk)
 
@@ -233,7 +291,7 @@ def _rule_bwd(chunk, saved, d_out):
     return pull(d_operands)
 
 
-gated_delta_rule.defvjp(_rule_fwd, _rule_bwd)
+_rule.defvjp(_rule_fwd, _rule_bwd)
 
 
 @register('kda_attention')
@@ -245,5 +303,6 @@ def kda_attention(ctx, ins, attrs):
     monitor.add('kda/calls', 1)
     out = gated_delta_rule(
         ins['Q'][0], ins['K'][0], ins['V'][0], ins['A'][0],
-        ins['Beta'][0])
+        ins['Beta'][0],
+        auto_partitioned=ctx.auto_partitioned)
     return {'Out': [out]}

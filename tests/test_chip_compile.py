@@ -734,7 +734,36 @@ def test_quant_collective_tiles(one_chip):
     assert n == 1, n
 
 
+@pytest.mark.parametrize('t,heads,dk', [
+    (4096, 8, 128),     # solar_open2_250b_s4096: 64 chunks x 8 heads
+    (24, 3, 128),       # less than a chunk: one chunk of two sub-chunks
+    (100, 2, 256),      # a padded tail, two lane tiles of channels
+])
+def test_the_delta_rule_compiles_its_two_score_kernels(one_chip, as_on_tpu,
+                                                       t, heads, dk):
+    """``kda_attention``'s forward + backward at the Solar cell's layer
+    shape (and at a short and a ragged length): the dispatch answers
+    fused, the executable holds THREE Mosaic calls (the scores' forward,
+    its recompute in the backward, the scores' backward), and no buffer
+    of the step is a [.., SUB, SUB, dk] or [.., n_sub, n_sub, SUB, dk]
+    decay block any more."""
+    import re
+    from paddle_tpu.ops import kda_ops
+
+    def step(q, k, v, a, beta, probe):
+        out, pull = jax.vjp(kda_ops.gated_delta_rule, q, k, v, a, beta)
+        return (out,) + pull(probe)
+
+    wide, rows = _spec((1, t, heads, dk)), _spec((1, t, heads))
+    text = _compiled(step, one_chip, wide, wide, wide, wide, rows,
+                     wide).as_text()
+    _compiled_on_chip('kda_chunk')
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    blocks = re.findall(r'f32\[[\d,]*(?:16,16|\d,\d,16),%d\]' % dk, text)
+    assert not blocks, sorted(set(blocks))
+
+
 def test_every_dispatchable_kernel_is_compiled_here():
     """A kernel registered later must bring its compile with it."""
     assert set(common.kernels()) == {
-        'flash_attention', 'quant_collective'}
+        'flash_attention', 'kda_chunk', 'quant_collective'}

@@ -1,0 +1,174 @@
+"""The ``kda_chunk`` kernels (``paddle_tpu/ops/pallas/kda_chunk.py``: the
+gated delta rule's in-chunk scores, forward and backward) under the
+Pallas interpreter against the dense form they replace
+(``ops/kda_ops.py`` ``_scores``) and, through the whole op, against the
+token-by-token recurrence; and what ``common.dispatch`` answers for
+shapes the kernels' layout does not hold.  CPU; what the chip's compiler
+says of them is ``tests/test_chip_compile.py``'s."""
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.fluid import monitor
+from paddle_tpu.models.reference import solar_open2 as reference
+from paddle_tpu.ops import kda_ops
+from paddle_tpu.ops.pallas import common, kda_chunk
+
+
+def _chunks(seed, lead=(2, 3), c=64, dk=128, rate=1.0):
+    """Unit q and k and the running log decay of whole chunks, down to
+    -rate x softplus(.) a token and channel."""
+    rng = np.random.RandomState(seed)
+    q, k = (rng.randn(*(lead + (c, dk))) for _ in range(2))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    a = -rate * np.log1p(np.exp(rng.randn(*(lead + (c, dk))))) * \
+        rng.uniform(0, 1, lead + (c, dk))
+    return [jnp.asarray(x, jnp.float32)
+            for x in (q, k, np.cumsum(a, -2))]
+
+
+def _sequence(seed, t, b=2, h=3, dk=128, dv=8, rate=16.0,
+              dtype=jnp.float32):
+    """``tests/test_solar_open2.py``'s inputs at a head width the
+    kernels take; the log decays stay float32."""
+    rng = np.random.RandomState(seed)
+    q, k = (rng.randn(b, t, h, dk) for _ in range(2))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True)
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    v = rng.randn(b, t, h, dv)
+    a = -rate * np.log1p(np.exp(rng.randn(b, t, h, dk))) * \
+        rng.uniform(0, 1, (b, t, h, dk))
+    beta = 2 / (1 + np.exp(-1 - rng.randn(b, t, h)))
+    return [jnp.asarray(x, jnp.float32 if x is a else dtype)
+            for x in (q, k, v, a, beta)]
+
+
+def _close(got, want, rtol):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() <= rtol * np.abs(want).max()
+
+
+def _fused():
+    return monitor.counter_value('pallas/kda_chunk/dispatch_fused') or 0
+
+
+@pytest.mark.parametrize('c,dk,rate', [(64, 128, 1.0), (64, 128, 16.0),
+                                       (32, 128, 4.0), (16, 256, 1.0)])
+def test_the_kernels_scores_and_gradients_are_the_dense_forms(c, dk, rate):
+    """A, B and dq, dk, dG of the two kernels against ``_scores`` and
+    its ``jax.vjp``, float32, at a whole chunk, at the shorter chunks a
+    short sequence runs as, at two lane tiles of channels, and at
+    decays whose running sum passes -88 inside the chunk."""
+    q, k, g = _chunks(c, c=c, dk=dk, rate=rate)
+    if rate == 16.0:
+        assert float(g.min()) < -200
+    want, pull = jax.vjp(kda_ops._scores, q, k, g)
+    got, pull_kernel = jax.vjp(
+        lambda *x: kda_chunk.chunk_scores(*x, True), q, k, g)
+    for x, y in zip(got, want):
+        _close(x, y, 2e-6)
+    upper = np.triu(np.ones((c, c), bool))
+    assert (np.asarray(got[0])[..., upper] == 0).all()
+    assert (np.asarray(got[1])[..., np.triu(upper, 1)] == 0).all()
+    rng = np.random.RandomState(1)
+    cotangents = tuple(jnp.asarray(rng.randn(*x.shape), jnp.float32)
+                       for x in want)
+    for x, y in zip(pull_kernel(cotangents), pull(cotangents)):
+        _close(x, y, 5e-6)
+
+
+@pytest.mark.parametrize('t', [100, 64, 24])
+def test_the_fused_op_is_the_recurrence_at_whole_and_ragged_lengths(
+        pallas_interpret, t):
+    """The whole op through the kernels (dispatch counted fused),
+    forward and all five gradients, float32 against the token loop at
+    rate 16: one chunk, less than one (a chunk of two sub-chunks) and
+    no whole number of them (a padded tail)."""
+    args = _sequence(t, t)
+    before = _fused()
+    with jax.default_matmul_precision('highest'):
+        got, pull = jax.vjp(kda_ops.gated_delta_rule, *args)
+        probe = jnp.asarray(
+            np.random.RandomState(1).randn(*got.shape), jnp.float32)
+        got_grads = pull(probe)
+        want, pull_want = jax.vjp(reference.kda_recurrence, *args)
+        want_grads = pull_want(probe)
+    assert _fused() == before + 1
+    assert common._LAST['kda_chunk']['reason'] == 'forced_interpret'
+    _close(got, want, 2e-5)
+    for got_grad, want_grad in zip(got_grads, want_grads):
+        _close(got_grad, want_grad, 5e-5)
+
+
+def test_the_fused_op_and_the_dense_op_agree_on_bf16_inputs(
+        pallas_interpret):
+    """bf16 q, k, v, beta beside float32 log decays: the kernels see
+    the float32 working copies the dense form sees, and the two paths'
+    outputs and gradients round to the same bf16 but for an ulp."""
+    args = _sequence(5, 100, dtype=jnp.bfloat16)
+    fused, pull = jax.vjp(kda_ops.gated_delta_rule, *args)
+    probe = jnp.asarray(np.random.RandomState(2).randn(*fused.shape),
+                        jnp.bfloat16)
+    dense, pull_dense = jax.vjp(
+        lambda *x: kda_ops._rule(*x, kda_ops.CHUNK, 'dense'), *args)
+    assert fused.dtype == jnp.bfloat16
+    assert common._LAST['kda_chunk']['path'] == 'fused'
+    _close(fused, dense, 2 ** -7)
+    for x, y in zip(pull(probe), pull_dense(probe)):
+        assert x.dtype == y.dtype
+        _close(x, y, 2 ** -6)
+
+
+@pytest.mark.parametrize('what,kwargs', [
+    ('layout', dict(dk=16)),                    # dk is no lane tile
+    ('layout', dict(dk=128, chunk=40)),         # no whole sub-chunks
+    ('auto_partitioned', dict(dk=128, auto_partitioned=True)),
+])
+def test_the_dispatch_answers_dense_with_its_reason_counted(
+        pallas_interpret, what, kwargs):
+    """Where the kernels' layout does not hold (dk off the 128 lanes, a
+    chunk of no whole sub-chunks) and where XLA partitions the program,
+    the op runs the dense form, says why, and is the recurrence."""
+    kwargs = dict(kwargs)
+    args = _sequence(7, 50, b=1, h=2, dk=kwargs.pop('dk'), rate=4.0)
+    name = 'pallas/kda_chunk/fallback/' + what
+    before = monitor.counter_value(name) or 0
+    fused = _fused()
+    got = kda_ops.gated_delta_rule(*args, **kwargs)
+    assert (monitor.counter_value(name) or 0) == before + 1
+    assert _fused() == fused
+    assert common._LAST['kda_chunk'] == {
+        'path': 'dense', 'reason': what, 'interpret': False}
+    with jax.default_matmul_precision('highest'):
+        _close(got, reference.kda_recurrence(*args), 2e-5)
+
+
+def test_float64_runs_the_dense_form(pallas_interpret):
+    """Under x64 the working dtype is float64, which the kernels do
+    not take: reason 'dtype'."""
+    before = monitor.counter_value('pallas/kda_chunk/fallback/dtype') or 0
+    with jax.enable_x64():
+        args = _sequence(8, 40, b=1, h=1, dtype=jnp.float64)
+        args[3] = args[3].astype(jnp.float64)
+        got = kda_ops.gated_delta_rule(*args)
+        _close(got, reference.kda_recurrence(*args), 1e-12)
+    assert monitor.counter_value(
+        'pallas/kda_chunk/fallback/dtype') == before + 1
+
+
+def test_off_a_tpu_and_unforced_the_op_is_dense():
+    before = monitor.counter_value('pallas/kda_chunk/fallback/off_tpu') or 0
+    kda_ops.gated_delta_rule(*_sequence(9, 20, b=1, h=1))
+    assert monitor.counter_value(
+        'pallas/kda_chunk/fallback/off_tpu') == before + 1
+
+
+def test_the_kernel_is_registered_with_its_dense_fallback():
+    entry = common.kernels()['kda_chunk']
+    assert entry['has_vjp'] and entry['op_types'] == ('kda_attention',)
+    module, name = entry['dense_fallback'].rsplit('.', 1)
+    assert module == 'ops.kda_ops' and callable(getattr(kda_ops, name))

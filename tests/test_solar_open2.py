@@ -114,20 +114,30 @@ def test_the_op_is_the_recurrence_forward_and_in_all_five_gradients(
         _close(got_grad, want_grad, 5e-5)
 
 
-def test_the_decays_overflow_the_factored_form_and_not_the_op():
+@pytest.mark.parametrize('path,dk', [('dense', 16), ('fused', 128)])
+def test_the_decays_overflow_the_factored_form_and_not_the_op(
+        path, dk, request):
     """At rate 16 the running sum of the log decays falls under -88
     inside a chunk, so ``exp(-G)`` alone is inf in float32 and the
     factored scores ``(k exp(G)) . (k exp(-G))`` are inf or NaN; the op
-    is finite, and in float64 it is the recurrence to rounding: no
-    clamp, floor or dropped term stands behind the float32 agreement."""
-    args = _inputs(3, t=128)
+    is finite, on the dense path and through the ``kda_chunk`` kernels
+    (their bodies under the interpreter, at a head width they take)
+    alike, and in float64 it is the recurrence to rounding: no clamp,
+    floor or dropped term stands behind the float32 agreement."""
+    if path == 'fused':
+        request.getfixturevalue('pallas_interpret')
+    args = _inputs(3, t=128, dk=dk)
     running = np.cumsum(np.asarray(args[3])[:, :64], 1)
     assert running.min() < -200
     with np.errstate(over='ignore'):
         assert np.isinf(np.exp(-running.astype('float32'))).any()
-    assert np.isfinite(np.asarray(_op(*args))).all()
+    fused = monitor.counter_value('pallas/kda_chunk/dispatch_fused') or 0
+    out = jax.jit(_op.__wrapped__)(*args)
+    assert np.isfinite(np.asarray(out)).all()
+    assert (monitor.counter_value('pallas/kda_chunk/dispatch_fused') or
+            0) == fused + (path == 'fused')
     with jax.enable_x64():
-        exact = _inputs(3, t=128, dtype=jnp.float64)
+        exact = _inputs(3, t=128, dk=dk, dtype=jnp.float64)
         probe = jnp.asarray(np.random.RandomState(2).randn(2, 128, 3, 8))
         got = jax.jit(jax.grad(
             lambda *x: jnp.sum(kda_ops.gated_delta_rule(*x) * probe),
@@ -135,10 +145,12 @@ def test_the_decays_overflow_the_factored_form_and_not_the_op():
         want = jax.jit(jax.grad(
             lambda *x: jnp.sum(reference.kda_recurrence(*x) * probe),
             argnums=(0, 1, 2, 3, 4)))(*exact)
-        _close(jax.jit(kda_ops.gated_delta_rule)(*exact),
-               jax.jit(reference.kda_recurrence)(*exact), 1e-12)
+        recurrence = jax.jit(reference.kda_recurrence)(*exact)
+        _close(jax.jit(kda_ops.gated_delta_rule)(*exact), recurrence,
+               1e-12)
         for g, w in zip(got, want):
             _close(g, w, 1e-11)
+        _close(out, recurrence, 2e-5)
 
 
 def test_no_state_crosses_from_one_sequence_of_a_batch_into_the_next():

@@ -10,7 +10,8 @@ auto-dispatch + dense-fallback contract:
   2. parity: each kernel's forced-fused (interpret) path against its
      dense reference on CPU — bitwise where the reference is exact
      (blockwise quantize), tolerance-bounded where the kernel body
-     sums in another order (flash attention's online softmax);
+     sums in another order (flash attention's online softmax, the
+     delta rule's in-chunk scores);
   3. observability: every dispatch lands a pallas/<kernel>/dispatch_*
      counter and a last-decision record with a reason, and the
      /statusz pallas section renders them — a silent dense fallback
@@ -25,7 +26,7 @@ Run from `make check` (CPU: JAX_PLATFORMS=cpu).
 import os
 import sys
 
-EXPECTED = ('flash_attention', 'quant_collective')
+EXPECTED = ('flash_attention', 'kda_chunk', 'quant_collective')
 
 
 def main():
@@ -38,6 +39,7 @@ def main():
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid import health, monitor
     from paddle_tpu.fluid.flags import _DEFAULTS
+    from paddle_tpu.ops import kda_ops
     from paddle_tpu.ops.pallas import (common, flash_attention,
                                        quant_collective)
 
@@ -73,6 +75,27 @@ def main():
             failures.append('flash_attention forward/grad parity')
             break
 
+    # the delta rule through the kda_chunk kernels against the dense
+    # scores (dk 128: the kernels' layout; 40 tokens: a padded tail)
+    delta = [jnp.asarray(x.astype('float32')) for x in (
+        rng.randn(1, 40, 1, 128) / 11, rng.randn(1, 40, 1, 128) / 11,
+        rng.randn(1, 40, 1, 8), -rng.uniform(0, 2, (1, 40, 1, 128)),
+        rng.uniform(0, 2, (1, 40, 1)))]
+
+    def recur(*x):
+        return jnp.sum(kda_ops.gated_delta_rule(*x) ** 2)
+
+    fluid.set_flags({'FLAGS_pallas_force': True})
+    fused = jax.value_and_grad(recur, (0, 1, 2, 3, 4))(*delta)
+    fluid.set_flags({'FLAGS_pallas_force': False})
+    dense = jax.value_and_grad(recur, (0, 1, 2, 3, 4))(*delta)
+    for a, b in zip(jax.tree_util.tree_leaves(fused),
+                    jax.tree_util.tree_leaves(dense)):
+        if not np.allclose(np.asarray(a), np.asarray(b),
+                           rtol=5e-5, atol=5e-6):
+            failures.append('kda_chunk forward/grad parity')
+            break
+
     flat = jnp.asarray(rng.randn(16, 256).astype('float32'))
     qv, s = quant_collective.quantize_blocks(flat, True)
 
@@ -88,7 +111,8 @@ def main():
     if not (np.array_equal(np.asarray(qv), np.asarray(qref)) and
             np.array_equal(np.asarray(s), np.asarray(sref))):
         failures.append('quantize_blocks not bitwise vs dense q()')
-    print('parity: flash_attention fwd/grad, quantize_blocks ok')
+    print('parity: flash_attention fwd/grad, kda_chunk fwd/grad, '
+          'quantize_blocks ok')
 
     # -- 3. dispatch observability -----------------------------------
     quant_collective.dispatch()
