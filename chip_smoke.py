@@ -24,6 +24,9 @@ the chip: BERT-base pretraining through the normal entry points
                                     # delta rule alone at 32768
                                     # positions, then gradients of
                                     # the cell's four layers
+    python chip_smoke.py --phase grouped # the experts' grouped-matmul
+                                    # kernels against ragged_dot at
+                                    # the five routed cells' shapes
 
 One process, no children.  It fails (non-zero, no result line) unless
 jax.devices()[0].platform == 'tpu'; nothing here falls back to the CPU.
@@ -2259,16 +2262,94 @@ def phase_solar(seq=4096, seed=0):
 
 
 
+# (buffer rows, groups, K, N, live rows) of the routed cells' expert
+# products (tools/bench_grouped_matmul.py times the same)
+GROUPED_SHAPES = {
+    'olmoe': (98304, 64, 2048, 1024, 98304),
+    'laguna': (32768, 8, 3072, 1024, 1280),
+    'moonlight': (49152, 8, 2048, 1408, 7440),
+    'lfm2': (32768, 8, 2048, 1792, 8400),
+    'solar': (32768, 8, 4096, 1280, 819),
+}
+
+
+def bf16_units(got, want):
+    """How far ``got`` lies from ``want`` at most, in units of the last
+    bfloat16 place of ``want``'s largest entry."""
+    unit = 2.0 ** (np.floor(np.log2(np.abs(want).max())) - 7)
+    return float(np.abs(got - want).max() / unit)
+
+
+def phase_grouped_matmul(seed=0, units=2.0):
+    """The three forms of ops/pallas/grouped_matmul.py in bfloat16 at
+    the five routed cells' shapes against ``jax.lax.ragged_dot`` and
+    its two transposes on the same operands: uneven groups with an
+    empty one, the rows past the last group NaN in both inputs.  Every
+    row inside a group and every weight gradient has to lie within
+    ``units`` of the last bfloat16 place of the result's largest entry
+    (both sides round a float32 sum of the same bfloat16 products)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+
+    rng = np.random.RandomState(seed)
+    for name, (m, e, k, n, live) in GROUPED_SHAPES.items():
+        share = rng.uniform(0.3, 1.7, e)
+        share[rng.randint(e)] = 0
+        sizes = np.floor(live * share / share.sum()).astype(np.int32)
+        sizes[-1] += live - sizes.sum()
+        rows = jnp.asarray(rng.randn(m, k), jnp.bfloat16)
+        cot = jnp.asarray(rng.randn(m, n), jnp.bfloat16)
+        w = jnp.asarray(rng.randn(e, k, n) / 32, jnp.bfloat16)
+        sizes = jnp.asarray(sizes)
+
+        def dense(rows, w, cot):
+            out, pull = jax.vjp(
+                lambda r, w: jax.lax.ragged_dot(r, w, sizes), rows, w)
+            return (out,) + pull(cot)
+
+        def ours(rows, w, cot):
+            walk = gm.visits(sizes, m)
+            return (gm.forward(rows, w, walk), gm.transposed(cot, w, walk),
+                    gm.weight_gradient(rows, cot, walk))
+
+        want = jax.jit(dense)(rows.at[live:].set(0), w,
+                              cot.at[live:].set(0))
+        got = jax.jit(ours)(rows.at[live:].set(jnp.nan), w,
+                            cot.at[live:].set(jnp.nan))
+        off = []
+        for form, a, b in zip(('forward', 'transposed',
+                               'weight_gradient'), got, want):
+            if form != 'weight_gradient':
+                a, b = a[:live], b[:live]
+            a = np.asarray(a.astype(jnp.float32))
+            b = np.asarray(b.astype(jnp.float32))
+            check(np.isfinite(a).all(),
+                  'grouped %s %s: no NaN row reaches a result'
+                  % (name, form))
+            off.append(bf16_units(a, b))
+            check(off[-1] <= units,
+                  'grouped %s %s: %.2f <= %.0f bf16 units from ragged_dot'
+                  % (name, form, off[-1], units))
+        say('grouped %s [%d, %d, %d, %d] groups %s: forward / transposed '
+            '/ weight_gradient %.2f / %.2f / %.2f bf16 units from '
+            'ragged_dot' % ((name, m, e, k, n,
+                             np.asarray(sizes).tolist() if e <= 8 else
+                             '%d..%d' % (int(sizes.min()),
+                                         int(sizes.max()))) + tuple(off)))
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
     ap.add_argument('--chips', type=int, choices=(1, 4), default=1)
     ap.add_argument('--phase',
                     choices=('bert', 'olmoe', 'laguna', 'moonlight',
-                             'lfm2', 'evabyte', 'solar'),
+                             'lfm2', 'evabyte', 'solar', 'grouped'),
                     default='bert',
                     help="'olmoe' / 'laguna' / 'moonlight' / 'lfm2' / "
                     "'evabyte' / 'solar': only that model's gradient "
-                    "check")
+                    "check; 'grouped': only the grouped-matmul "
+                    "kernels against ragged_dot")
     args = ap.parse_args()
 
     import jax
@@ -2307,6 +2388,8 @@ def main():
             phase_evabyte()
         elif args.phase == 'solar':
             phase_solar()
+        elif args.phase == 'grouped':
+            phase_grouped_matmul()
         elif args.chips == 4:
             phase_four_chips(
                 models.bert.BertConfig(dropout=0.0, attn_dropout=0.0),

@@ -8,6 +8,7 @@ XLA's automatic fusion isn't enough.
 
 from . import common
 from . import flash_attention
+from . import grouped_matmul
 from . import kda_chunk
 from . import quant_collective
 from .flash_attention import flash_attention as flash_attention_fn

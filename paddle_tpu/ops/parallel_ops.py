@@ -346,10 +346,13 @@ def moe_experts_op(ctx, ins, attrs):
     """Rows [M, D] grouped by expert, GroupSizes [E] int32, WGate /
     WUp [E, D, H], WDown [E, H, D] -> Out [M, D]:
     down(silu(gate x) * up x), one grouped matmul per weight set; in
-    bfloat16 under AMP (white-listed).  The TPU compiler turns each
-    ``lax.ragged_dot`` into Mosaic calls whose whole op_name is its own
-    (``ragged-dot-none``, ``ragged-dot-metadata``), forward and
-    backward alike.  In a layer that holds a range of the experts
+    bfloat16 under AMP (white-listed).  bfloat16 products on a TPU run
+    the kernels of ops/pallas/grouped_matmul.py (parallel.moe._operands
+    has the gates); float32 ones, and every program under the GSPMD
+    runner, ``lax.ragged_dot``, which the TPU compiler turns into
+    Mosaic calls whose whole op_name is its own (``ragged-dot-none``,
+    ``ragged-dot-metadata``), forward and backward alike.  In a layer
+    that holds a range of the experts
     (attrs['experts_held']) most of the buffer lies past the last
     group, and the grouped matmuls skip those rows: there the op is
     parallel.moe.held_gated_mlp, whose SiLU product, its backward and
@@ -364,7 +367,8 @@ def moe_experts_op(ctx, ins, attrs):
         rows.dtype in (jnp.float32, jnp.bfloat16)
     mlp = grouped_gated_mlp if _held(attrs) is None else held_gated_mlp
     return {'Out': [mlp(rows, ins['GroupSizes'][0], ins['WGate'][0],
-                        ins['WUp'][0], ins['WDown'][0], low)]}
+                        ins['WUp'][0], ins['WDown'][0], low,
+                        bool(ctx is not None and ctx.auto_partitioned))]}
 
 
 @register('moe_combine')

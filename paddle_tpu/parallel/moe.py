@@ -488,10 +488,101 @@ def _gated(gate, up, dtype):
             up.astype(jnp.float32)).astype(dtype)
 
 
-def _operands(rows, group_sizes, w_gate, w_up, w_down, low_precision):
+class _RaggedDot:
+    """The grouped products as the compiler computes them:
+    ``jax.lax.ragged_dot`` and its two transposes (a grouped matmul is
+    linear in either operand)."""
+
+    def __init__(self, group_sizes, precision, reason):
+        self.reason = reason
+        self.groups = group_sizes.shape[0]
+        self.dot = functools.partial(
+            jax.lax.ragged_dot, group_sizes=group_sizes,
+            precision=precision)
+
+    def _counted(self):
+        from ..ops.pallas import common
+        common.record_dispatch('grouped_matmul', False, self.reason)
+
+    def __call__(self, rows, w):
+        """rows [M, K] x w [E, K, N] -> [M, N]."""
+        self._counted()
+        return self.dot(rows, w)
+
+    # ``ragged_dot`` differentiates as it stands
+    with_gradient = __call__
+
+    def transposed(self, cot, w):
+        """cot [M, N] x w [E, K, N]^T -> [M, K]."""
+        self._counted()
+        at = jax.ShapeDtypeStruct((cot.shape[0], w.shape[1]), cot.dtype)
+        return jax.linear_transpose(lambda r: self.dot(r, w), at)(cot)[0]
+
+    def weight_gradient(self, rows, cot):
+        """rows [M, K]^T x cot [M, N] per group -> [E, K, N]."""
+        self._counted()
+        at = jax.ShapeDtypeStruct(
+            (self.groups, rows.shape[1], cot.shape[1]), rows.dtype)
+        return jax.linear_transpose(
+            lambda w: self.dot(rows, w), at)(cot)[0]
+
+
+class _GroupedMatmul:
+    """The same three products by the kernels of
+    ops/pallas/grouped_matmul.py, over one walk of the groups' row
+    tiles (``visits``) that every product of a pass shares."""
+
+    def __init__(self, group_sizes, m, reason, interpret):
+        from ..ops.pallas import grouped_matmul
+        self.kernels = grouped_matmul
+        self.walk = grouped_matmul.visits(group_sizes, m)
+        self.reason = reason
+        self.interpret = interpret
+
+    def _run(self, form, a, b):
+        from ..ops.pallas import common
+        common.record_dispatch('grouped_matmul', True, self.reason,
+                               self.interpret)
+        return getattr(self.kernels, form)(a, b, self.walk,
+                                           self.interpret)
+
+    def __call__(self, rows, w):
+        return self._run('forward', rows, w)
+
+    def with_gradient(self, rows, w):
+        """The product for ``jax.vjp`` to differentiate (a kernel has
+        no rule of its own): form 1's two transposes are forms 2 and
+        3."""
+        @jax.custom_vjp
+        def product(rows, w):
+            return self(rows, w)
+
+        product.defvjp(
+            lambda rows, w: (self(rows, w), (rows, w)),
+            lambda kept, cot: (self.transposed(cot, kept[1]),
+                               self.weight_gradient(kept[0], cot)))
+        return product(rows, w)
+
+    def transposed(self, cot, w):
+        return self._run('transposed', cot, w)
+
+    def weight_gradient(self, rows, cot):
+        return self._run('weight_gradient', rows, cot)
+
+
+def _operands(rows, group_sizes, w_gate, w_up, w_down, low_precision,
+              auto_partitioned=False):
     """What the grouped matmuls multiply and how -> (dot, rows, w_gate,
     w_up, w_down): ``low_precision`` (AMP) casts everything to
-    bfloat16; otherwise float32 operands multiply at full precision."""
+    bfloat16; otherwise float32 operands multiply at full precision.
+    ``dot(rows, w)`` is the grouped product (``dot.with_gradient`` where
+    ``jax.vjp`` is to differentiate it), ``dot.transposed(cot, w)`` and
+    ``dot.weight_gradient(rows, cot)`` its two transposes: the
+    kernels of ops/pallas/grouped_matmul.py where the operands are
+    bfloat16 in whole tiles on a TPU (``common.dispatch``'s decision,
+    counted a product: ``pallas/grouped_matmul/dispatch_*``), the
+    compiler's ``ragged_dot`` otherwise."""
+    from ..ops.pallas import common, grouped_matmul
     if low_precision:
         rows = rows.astype(jnp.bfloat16)
         w_gate, w_up, w_down = (w.astype(jnp.bfloat16)
@@ -500,26 +591,36 @@ def _operands(rows, group_sizes, w_gate, w_up, w_down, low_precision):
     else:
         precision = jax.lax.Precision.HIGHEST \
             if rows.dtype == jnp.float32 else None
-    dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes,
-                            precision=precision)
+    fused, reason, interpret = common.decide(
+        True, grouped_matmul.checks(
+            rows.shape[0], w_gate.shape[1:],
+            [x.dtype for x in (rows, w_gate, w_up, w_down)]),
+        auto_partitioned=auto_partitioned)
+    if fused:
+        dot = _GroupedMatmul(group_sizes, rows.shape[0], reason,
+                             interpret)
+    else:
+        dot = _RaggedDot(group_sizes, precision, reason)
     return dot, rows, w_gate, w_up, w_down
 
 
 def grouped_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
-                      low_precision=False):
+                      low_precision=False, auto_partitioned=False):
     """down(silu(gate x) * up x) for rows grouped by expert.
 
     rows [M, D] (group e is the next group_sizes[e] rows), w_gate and
-    w_up [E, D, H], w_down [E, H, D] -> [M, D].  One ragged matmul per
-    weight set (``jax.lax.ragged_dot``: the TPU compiler's own grouped
-    matmul, 2*M*D*H FLOPs whatever the grouping).  ``low_precision``
-    (AMP) multiplies in bfloat16 and keeps the [M, H] intermediates in
-    bfloat16; otherwise float32 operands multiply at full precision."""
+    w_up [E, D, H], w_down [E, H, D] -> [M, D].  One grouped matmul per
+    weight set (_operands: 2*M*D*H FLOPs whatever the grouping).
+    ``low_precision`` (AMP) multiplies in bfloat16 and keeps the [M, H]
+    intermediates in bfloat16; otherwise float32 operands multiply at
+    full precision.  ``auto_partitioned``: ``common.dispatch``'s (the
+    caller's word that XLA will partition this program over a mesh)."""
     dot, rows, w_gate, w_up, w_down = _operands(
-        rows, group_sizes, w_gate, w_up, w_down, low_precision)
-    gate = dot(rows, w_gate)
-    up = dot(rows, w_up)
-    return dot(_gated(gate, up, rows.dtype), w_down)
+        rows, group_sizes, w_gate, w_up, w_down, low_precision,
+        auto_partitioned)
+    gate = dot.with_gradient(rows, w_gate)
+    up = dot.with_gradient(rows, w_up)
+    return dot.with_gradient(_gated(gate, up, rows.dtype), w_down)
 
 
 def _rewrite_held(held_rows, per_chunk, buffers, *read):
@@ -541,9 +642,9 @@ def _rewrite_held(held_rows, per_chunk, buffers, *read):
     return _walk_held(buffers[0].shape[0], held_rows, trip, buffers)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def held_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
-                   low_precision=False):
+                   low_precision=False, auto_partitioned=False):
     """grouped_gated_mlp for a layer that holds a range of its
     experts, whose groups fill the first ``sum(group_sizes)`` rows of a
     worst-case buffer (held_rows_bound): the same products, and
@@ -559,7 +660,8 @@ def held_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
     hidden), each over the buffer it came from, and a second adds the
     up branch's cotangent of ``rows`` to the gate branch's."""
     dot, rows, w_gate, w_up, w_down = _operands(
-        rows, group_sizes, w_gate, w_up, w_down, low_precision)
+        rows, group_sizes, w_gate, w_up, w_down, low_precision,
+        auto_partitioned)
     hidden, = _rewrite_held(
         jnp.sum(group_sizes),
         lambda gate, up: (_gated(gate, up, rows.dtype),),
@@ -567,13 +669,14 @@ def held_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
     return dot(hidden, w_down)
 
 
-def _held_fwd(rows, group_sizes, w_gate, w_up, w_down, low_precision):
+def _held_fwd(rows, group_sizes, w_gate, w_up, w_down, low_precision,
+              auto_partitioned):
     return held_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
-                          low_precision), \
+                          low_precision, auto_partitioned), \
         (rows, group_sizes, w_gate, w_up, w_down)
 
 
-def _held_bwd(low_precision, res, dout):
+def _held_bwd(low_precision, auto_partitioned, res, dout):
     # the barrier (jax.checkpoint's own) keeps the compiler from
     # sharing the forward pass's casts and products with the ones
     # computed again here, which would keep them alive in between, and
@@ -582,12 +685,9 @@ def _held_bwd(low_precision, res, dout):
     rows, w_gate, w_up, w_down, dout = jax.lax.optimization_barrier(
         (res[0],) + res[2:] + (dout,))
     dot, rows_c, w_gate_c, w_up_c, w_down_c = _operands(
-        rows, group_sizes, w_gate, w_up, w_down, low_precision)
+        rows, group_sizes, w_gate, w_up, w_down, low_precision,
+        auto_partitioned)
     held_rows = jnp.sum(group_sizes)
-
-    def grad(product, at, cotangent):
-        # a grouped matmul is linear in either operand
-        return jax.linear_transpose(product, at)(cotangent)[0]
 
     def gated_grad(gate, up, dhidden):
         hidden, back = jax.vjp(
@@ -596,21 +696,16 @@ def _held_bwd(low_precision, res, dout):
 
     gate, up = dot(rows_c, w_gate_c), dot(rows_c, w_up_c)
     dgate, dup, hidden = _rewrite_held(
-        held_rows, gated_grad,
-        (gate, up, grad(lambda h: dot(h, w_down_c), gate, dout)))
+        held_rows, gated_grad, (gate, up, dot.transposed(dout, w_down_c)))
     drows, = _rewrite_held(
         held_rows,
         lambda a, b: ((a.astype(jnp.float32) +
                        b.astype(jnp.float32)).astype(a.dtype),),
-        (grad(lambda r: dot(r, w_gate_c), rows_c, dgate),),
-        grad(lambda r: dot(r, w_up_c), rows_c, dup))
+        (dot.transposed(dgate, w_gate_c),), dot.transposed(dup, w_up_c))
     return (drows.astype(rows.dtype), None,
-            grad(lambda w: dot(rows_c, w), w_gate_c, dgate).astype(
-                w_gate.dtype),
-            grad(lambda w: dot(rows_c, w), w_up_c, dup).astype(
-                w_up.dtype),
-            grad(lambda w: dot(hidden, w), w_down_c, dout).astype(
-                w_down.dtype))
+            dot.weight_gradient(rows_c, dgate).astype(w_gate.dtype),
+            dot.weight_gradient(rows_c, dup).astype(w_up.dtype),
+            dot.weight_gradient(hidden, dout).astype(w_down.dtype))
 
 
 held_gated_mlp.defvjp(_held_fwd, _held_bwd)

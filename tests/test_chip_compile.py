@@ -763,7 +763,50 @@ def test_the_delta_rule_compiles_its_two_score_kernels(one_chip, as_on_tpu,
     assert not blocks, sorted(set(blocks))
 
 
+@pytest.mark.parametrize('rows,experts,d,hidden,held', [
+    (98304, 64, 2048, 1024, None),      # olmoe_1b7b_s4096
+    (32768, 8, 3072, 1024, (0, 8)),     # laguna_s21_s4096
+    (49152, 8, 2048, 1408, (0, 8)),     # moonlight_16b_s8192
+    (32768, 8, 2048, 1792, (0, 8)),     # lfm2_8b_a1b_s8192
+    (32768, 8, 4096, 1280, (0, 8)),     # solar_open2_250b_s4096
+], ids=['olmoe', 'laguna', 'moonlight', 'lfm2', 'solar'])
+def test_the_experts_products_compile_as_our_kernels(
+        one_chip, as_on_tpu, rows, experts, d, hidden, held):
+    """``moe_experts`` and its gradient op at the five routed cells'
+    shapes under AMP (bf16 rows, f32 weights cast inside): the
+    dispatch answers fused, every grouped product is a Mosaic call of
+    ours (3 forward; 6 backward, or 8 with the held MLP's recompute)
+    and none is left to the compiler (``ragged-dot``); each call asks
+    Mosaic for the VMEM a whole expert's matrix takes, under the
+    cap."""
+    lower = registry.get('moe_experts').fn
+    grad = registry.grad_op_def(registry.get('moe_experts')).fn
+    attrs = {'__amp__': True}
+    if held:
+        attrs['experts_held'] = held
+    monitor.set_gauge('pallas/grouped_matmul/vmem_asked_max', 0)
+
+    def step(x, sizes, gate, up, down, dout):
+        ins = {'Rows': [x], 'GroupSizes': [sizes], 'WGate': [gate],
+               'WUp': [up], 'WDown': [down]}
+        out = lower(None, ins, attrs)['Out'][0]
+        return out, grad(None, dict(ins, **{'GRAD::Out': [dout]}), attrs)
+
+    buffer = _spec((rows, d), jnp.bfloat16)
+    wide = _spec((experts, d, hidden))
+    text = _compiled(step, one_chip, buffer, _spec((experts,), jnp.int32),
+                     wide, wide, _spec((experts, hidden, d)),
+                     buffer).as_text()
+    _compiled_on_chip('grouped_matmul')
+    assert 'ragged-dot' not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (11 if held else 9)
+    asked = monitor.gauge_value('pallas/grouped_matmul/vmem_asked_max')
+    assert common.SCOPED_VMEM_BYTES < asked <= common.VMEM_LIMIT_CAP_BYTES
+
+
 def test_every_dispatchable_kernel_is_compiled_here():
     """A kernel registered later must bring its compile with it."""
     assert set(common.kernels()) == {
-        'flash_attention', 'kda_chunk', 'quant_collective'}
+        'flash_attention', 'grouped_matmul', 'kda_chunk',
+        'quant_collective'}

@@ -11,7 +11,7 @@ auto-dispatch + dense-fallback contract:
      dense reference on CPU — bitwise where the reference is exact
      (blockwise quantize), tolerance-bounded where the kernel body
      sums in another order (flash attention's online softmax, the
-     delta rule's in-chunk scores);
+     delta rule's in-chunk scores, the experts' grouped products);
   3. observability: every dispatch lands a pallas/<kernel>/dispatch_*
      counter and a last-decision record with a reason, and the
      /statusz pallas section renders them — a silent dense fallback
@@ -26,7 +26,8 @@ Run from `make check` (CPU: JAX_PLATFORMS=cpu).
 import os
 import sys
 
-EXPECTED = ('flash_attention', 'kda_chunk', 'quant_collective')
+EXPECTED = ('flash_attention', 'grouped_matmul', 'kda_chunk',
+            'quant_collective')
 
 
 def main():
@@ -96,6 +97,32 @@ def main():
             failures.append('kda_chunk forward/grad parity')
             break
 
+    # a held layer's expert MLP through the grouped_matmul kernels
+    # against ragged_dot, both in bfloat16: all four gradients
+    # within a few last places of their largest entry
+    from paddle_tpu.parallel import moe
+    sizes = jnp.asarray([130, 0, 70, 100], jnp.int32)
+    experts = [jnp.asarray(x.astype('float32')) for x in (
+        rng.randn(512, 256), rng.randn(4, 256, 128) / 16,
+        rng.randn(4, 256, 128) / 16, rng.randn(4, 128, 256) / 11)]
+
+    def mlp(*x):
+        out = moe.held_gated_mlp(x[0], sizes, *x[1:], True)
+        return jnp.sum(out[:300].astype(jnp.float32) ** 2)
+
+    fluid.set_flags({'FLAGS_pallas_force': True})
+    fused = jax.grad(mlp, (0, 1, 2, 3))(*experts)
+    fluid.set_flags({'FLAGS_pallas_force': False})
+    dense = jax.grad(mlp, (0, 1, 2, 3))(*experts)
+    for a, b in zip(fused, dense):
+        # rows past the groups are nobody's: the rows' gradient there
+        # is whatever the buffer held
+        a = np.asarray(a.astype(jnp.float32))[..., :300, :]
+        b = np.asarray(b.astype(jnp.float32))[..., :300, :]
+        if not np.abs(a - b).max() <= 2.0 ** -5 * np.abs(b).max():
+            failures.append('grouped_matmul forward/grad parity')
+            break
+
     flat = jnp.asarray(rng.randn(16, 256).astype('float32'))
     qv, s = quant_collective.quantize_blocks(flat, True)
 
@@ -112,7 +139,7 @@ def main():
             np.array_equal(np.asarray(s), np.asarray(sref))):
         failures.append('quantize_blocks not bitwise vs dense q()')
     print('parity: flash_attention fwd/grad, kda_chunk fwd/grad, '
-          'quantize_blocks ok')
+          'grouped_matmul fwd/grad, quantize_blocks ok')
 
     # -- 3. dispatch observability -----------------------------------
     quant_collective.dispatch()
