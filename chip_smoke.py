@@ -24,6 +24,9 @@ the chip: BERT-base pretraining through the normal entry points
                                     # delta rule alone at 32768
                                     # positions, then gradients of
                                     # the cell's four layers
+    python chip_smoke.py --phase ouro    # Ouro-2.6B's: the looped stack
+                                    # as ONE While, gradients of the
+                                    # shared layers summed over trips
     python chip_smoke.py --phase grouped # the experts' grouped-matmul
                                     # kernels against ragged_dot at
                                     # the five routed cells' shapes
@@ -2280,6 +2283,172 @@ def bf16_units(got, want):
     return float(np.abs(got - want).max() / unit)
 
 
+# --- Ouro-2.6B ----------------------------------------------------------
+# Published widths (models.ouro.BASE), one 4096-token sequence, the
+# stack applied total_ut_steps = 4 times through ONE While.  Sampled
+# gradients of the f32 TRAIN program (the masked scan under the
+# whole-program vjp, the flash kernels in float32) against jax.grad of
+# the reference: a shared layer's Wq and Wd (each the sum over four
+# trips), the last norm, the gate and rows of the head.  At TWO layers:
+# the described-chip compile puts the f32 step of the cell's four at
+# 18.9 GB and of two at 11.3 GB (PERF.md section 4).  Then the f32
+# for_test loss (the lax.while_loop lowering) at the cell's own four
+# layers over OURO_LOSS_BATCHES batches against the reference in f32
+# and in bfloat16 throughout: the two readings the family's
+# REFERENCE_RTOL lies between.
+OURO_GRAD_LAYERS = 2
+OURO_CELL_LAYERS = 4
+OURO_SEQ = 4096
+OURO_LOSS_RTOL = 1e-6       # = benchmark/families/ouro.py's
+OURO_L2_RTOL = 2e-3         # a gradient tensor's relative L2 distance
+OURO_LOSS_BATCHES = 8
+OURO_SAMPLED = ('ouro_l0_wq', 'ouro_l0_wd', 'ouro_l0_g2', 'ouro_l1_wq',
+                'ouro_l1_wd', 'ouro_g_f', 'ouro_w_head', 'ouro_w_gate',
+                'ouro_b_gate')
+
+
+def _ouro_reference_kw(cfg):
+    return dict(layers=cfg.layers, heads=cfg.heads, steps=cfg.steps,
+                eps=cfg.rms_eps, theta=cfg.rope_theta,
+                beta=cfg.entropy_weight, block=512)
+
+
+def _ouro_gradients(seq, seed):
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.models import ouro
+    from paddle_tpu.models.reference import ouro as reference
+    cfg = ouro.OuroConfig(layers=OURO_GRAD_LAYERS)
+    feed = _ints32(ouro.synthetic_batch(cfg, 1, seq,
+                                        np.random.RandomState(seed)))
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.scope_guard(fluid.Scope()):
+        with fluid.program_guard(main, startup), \
+                fluid.unique_name.guard():
+            _, _, loss = ouro.build_pretrain(cfg, seq)
+            params = [p.name for p in main.all_parameters()]
+            pairs = dict((p.name, g.name) for p, g in
+                         fluid.optimizer.SGD(0.0).minimize(loss)[1])
+        check([op.type for op in main.global_block().ops].count('while')
+              == 1 and len(params) == 1 + 11 * cfg.layers + 4,
+              'one while op over the stack, each layer\'s parameters once')
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        # the gate's startup weights leave p at (1/2, 1/4, 1/8, 1/8) on
+        # every token: spread them, so that its gradient is no rounding
+        rng = np.random.RandomState(seed)
+        scope.set_var('ouro_w_gate', jnp.asarray(
+            rng.randn(cfg.hidden, 1).astype('float32') / 16))
+        # host copies first: a run donates the state it may write
+        weights = [np.asarray(fluid.core.as_array(scope.find_var(p)))
+                   for p in params]
+        fused0 = _fused_dispatches()
+        t0 = time.time()
+        got = exe.run(main, feed=feed, fetch_list=[loss] + [
+            pairs[p] for p in OURO_SAMPLED])
+        got_loss = _scalar(got[:1])
+        grads = [np.asarray(g) for g in got[1:]]
+        say('ouro f32 train program, %d layers x %d passes, 1 x %d '
+            'tokens: loss %.6f in %.1f s (with compile); %d flash '
+            'dispatches fused; loop/trips %s; exit entropy %s'
+            % (cfg.layers, cfg.steps, seq, got_loss, time.time() - t0,
+               _fused_dispatches() - fused0,
+               monitor.gauge_value('loop/trips', None),
+               monitor.gauge_value('ouro/exit_entropy', None)))
+        check(monitor.gauge_value('loop/trips', None) == cfg.steps,
+              'the loop\'s body ran %d times in the step' % cfg.steps)
+        del got
+        for n in scope.local_var_names():
+            scope.erase(n)
+    jfeed = {k: jnp.asarray(v) for k, v in feed.items()}
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda w: reference.loss_fn(
+            w, jfeed['ids'], jfeed['pos_ids'], jfeed['labels'],
+            remat=True, **_ouro_reference_kw(cfg))))(
+        [jnp.asarray(w) for w in weights])
+    want_loss = float(want_loss)
+    rel = abs(got_loss - want_loss) / want_loss
+    say('reference: loss %.6f; relative difference %.2e'
+        % (want_loss, rel))
+    check(rel <= OURO_LOSS_RTOL, 'ouro f32 train loss within %g of the '
+          'reference' % OURO_LOSS_RTOL)
+    worst = 0.0
+    for name, x in zip(OURO_SAMPLED, grads):
+        y = np.asarray(want[params.index(name)])
+        e = float(np.abs(x - y).max() / np.abs(y).max())
+        l2 = float(np.linalg.norm(x - y) / np.linalg.norm(y))
+        worst = max(worst, l2)
+        say('gradient of %s %s: largest entry difference %.3e of the '
+            'largest entry (%.3e), relative L2 distance %.3e'
+            % (name, x.shape, e, np.abs(y).max(), l2))
+    check(worst <= OURO_L2_RTOL, 'ouro gradients: every sampled '
+          'parameter within %g relative L2 of the reference\'s (worst '
+          '%.3e)' % (OURO_L2_RTOL, worst))
+
+
+def _ouro_cell_losses(seq, seed):
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import ouro
+    from paddle_tpu.models.reference import ouro as reference
+    cfg = ouro.OuroConfig(layers=OURO_CELL_LAYERS)
+    feeds = [_ints32(ouro.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(s)))
+        for s in range(seed, seed + OURO_LOSS_BATCHES)]
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.scope_guard(fluid.Scope()):
+        with fluid.program_guard(main, startup), \
+                fluid.unique_name.guard():
+            _, _, loss = ouro.build_pretrain(cfg, seq)
+            test = main.clone(for_test=True)
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights = [np.asarray(fluid.core.as_array(scope.find_var(p.name)))
+                   for p in main.all_parameters()]
+        program = [_scalar(exe.run(test, feed=f, fetch_list=[loss]))
+                   for f in feeds]
+        for n in scope.local_var_names():
+            scope.erase(n)
+    weights = [jnp.asarray(w) for w in weights]
+    both = jax.jit(lambda w, f: [
+        reference.loss_fn(w, f['ids'], f['pos_ids'], f['labels'], dtype=dt,
+                          **_ouro_reference_kw(cfg))
+        for dt in (jnp.float32, jnp.bfloat16)])
+    off, low = [], []
+    for n, (feed, got) in enumerate(zip(feeds, program)):
+        full, half = (float(x) for x in both(
+            weights, {k: jnp.asarray(v) for k, v in feed.items()}))
+        off.append(abs(got - full) / full)
+        low.append(abs(half - full) / full)
+        say('%d layers x %d passes, batch seed %d: program %.6f, '
+            'reference %.6f (relative difference %.2e), reference in '
+            'bfloat16 throughout %.6f (%.2e)'
+            % (cfg.layers, cfg.steps, seed + n, got, full, off[-1], half,
+               low[-1]))
+    say('over %d batches: f32 for_test program against the reference, '
+        'relative: median %.2e, largest %.2e; reference in bfloat16 '
+        'throughout: smallest %.2e, median %.2e, largest %.2e'
+        % (len(off), np.median(off), max(off), min(low), np.median(low),
+           max(low)))
+    check(max(off) <= OURO_LOSS_RTOL, 'ouro f32 for_test loss at the '
+          'cell\'s cut within %g of the reference on every batch'
+          % OURO_LOSS_RTOL)
+    check(min(low) > OURO_LOSS_RTOL, 'the reference in bfloat16 '
+          'throughout misses %g on every batch' % OURO_LOSS_RTOL)
+
+
+def phase_ouro(seed=0):
+    _ouro_gradients(OURO_SEQ, seed)
+    _ouro_cell_losses(OURO_SEQ, seed)
+
+
 def phase_grouped_matmul(seed=0, units=2.0):
     """The three forms of ops/pallas/grouped_matmul.py in bfloat16 at
     the five routed cells' shapes against ``jax.lax.ragged_dot`` and
@@ -2344,11 +2513,12 @@ def main():
     ap.add_argument('--chips', type=int, choices=(1, 4), default=1)
     ap.add_argument('--phase',
                     choices=('bert', 'olmoe', 'laguna', 'moonlight',
-                             'lfm2', 'evabyte', 'solar', 'grouped'),
+                             'lfm2', 'evabyte', 'solar', 'ouro',
+                             'grouped'),
                     default='bert',
                     help="'olmoe' / 'laguna' / 'moonlight' / 'lfm2' / "
-                    "'evabyte' / 'solar': only that model's gradient "
-                    "check; 'grouped': only the grouped-matmul "
+                    "'evabyte' / 'solar' / 'ouro': only that model's "
+                    "gradient check; 'grouped': only the grouped-matmul "
                     "kernels against ragged_dot")
     args = ap.parse_args()
 
@@ -2388,6 +2558,8 @@ def main():
             phase_evabyte()
         elif args.phase == 'solar':
             phase_solar()
+        elif args.phase == 'ouro':
+            phase_ouro()
         elif args.phase == 'grouped':
             phase_grouped_matmul()
         elif args.chips == 4:

@@ -344,12 +344,18 @@ def _control_flow_backward(block, op, contribs, resolve_grad, no_grad_set):
     TPU-native analog of the reference's WhileGradOp
     (/root/reference/paddle/fluid/operators/controlflow/while_op.cc) and
     ConditionalBlockGradOp (conditional_block_op.cc).  Instead of
-    replaying saved step scopes, the forward op saves the ENTRY values of
-    its loop state (carry), and the grad op re-runs the sub-block
-    functionally from those entries under jax.vjp — loops re-run as a
-    bounded, masked lax.scan (reverse-differentiable, hence the
-    max_trip_count requirement), branches as lax.cond.  See
-    executor._lower_while_grad / _lower_conditional_block_grad.
+    replaying saved step scopes, a loop runs as a bounded, masked
+    lax.scan (reverse-differentiable, hence the max_trip_count
+    requirement) whose residuals are the scan's own, and its forward
+    runs ONCE a step: the whole-program vjp differentiates the scan in
+    place and lowers no grad op; on the per-op path the forward op
+    takes its scan under jax.vjp and keeps the vjp for the grad op of
+    the same trace.  Only a grad op cut into another segment than its
+    forward op re-runs the sub-block from the ENTRY values of the loop
+    state, which the forward op saves; a branch's grad op always does
+    (lax.cond, one pass of the body at most).  See
+    executor._lower_while / _lower_while_grad /
+    _lower_conditional_block_grad.
     """
     is_while = op.type == 'while'
     if is_while and int(op.attrs.get('max_trip_count') or 0) <= 0:
@@ -439,6 +445,10 @@ def _control_flow_backward(block, op, contribs, resolve_grad, no_grad_set):
         closure_grad_row.append(gname)
         contribs[n].append(gname)
 
+    # the forward op can then take its scan under jax.vjp itself
+    # (executor._lower_while keep_vjp)
+    op.attrs['__float_carries__'] = list(float_carries)
+    op.attrs['__closure_names__'] = list(closure)
     grad_inputs = {'X': list(op.input('X')), cond_slot: [cond_name],
                    'Entry': list(entry_row), 'GRAD::Out': cot_row}
     attrs = {'sub_block': op.attrs['sub_block'],
@@ -458,6 +468,22 @@ def _control_flow_backward(block, op, contribs, resolve_grad, no_grad_set):
                              'GRAD::X': closure_grad_row},
                     attrs=attrs, infer_shape=False)
     return True
+
+
+def recompute_guard(program=None):
+    """Context manager: the ops appended inside are ONE group whose
+    intermediate values the backward pass computes again from the
+    group's inputs instead of keeping them (``jax.checkpoint`` around
+    the group's lowering).  It takes effect where jax differentiates
+    the lowering itself: the whole-program vjp of a train step and the
+    scan of a differentiable ``While``, whose residuals are otherwise
+    fixed per trip as the body is traced, out of the compiler's reach.
+    A recomputed product the gradient does not read (a matmul's output
+    is no input of its own gradient) is dead code and costs nothing.
+    ``RecomputeOptimizer`` is the other mechanism: it re-emits forward
+    spans as ops of the backward block for the per-op gradient path and
+    cannot reach into a sub-block."""
+    return (program or framework.default_main_program())._recompute_guard()
 
 
 def calc_gradient(targets, inputs, target_gradients=None, no_grad_set=None):

@@ -1,2 +1,2 @@
-from .decorator import decorate, keep_float32
+from .decorator import decorate, float32_output, keep_float32
 from .fp16_lists import AutoMixedPrecisionLists

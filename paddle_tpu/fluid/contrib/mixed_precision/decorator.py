@@ -37,6 +37,22 @@ def keep_float32(*variables):
     return variables[0] if len(variables) == 1 else variables
 
 
+# A white `mul` that carries this attribute multiplies bfloat16
+# operands as every other and WRITES float32: the MXU's own
+# accumulator, not rounded on its way out.
+FLOAT32_OUTPUT = '__amp_float32_out__'
+
+
+def float32_output(var):
+    """Have the `mul` that produced ``var`` keep its float32
+    accumulator as its output under AMP: a vocabulary head whose
+    logits meet a float32 softmax (a float32 product of float32
+    operands, which ``keep_float32`` would give, costs six bfloat16
+    passes on the MXU).  Without AMP the op is as it was.  -> ``var``."""
+    var.op.attrs[FLOAT32_OUTPUT] = True
+    return var
+
+
 def _mark_amp_ops(program, amp_lists):
     """White ops run their MXU dots in bf16 ('__amp__'); gray ops FOLLOW
     a low-precision input by casting their f32 inputs down

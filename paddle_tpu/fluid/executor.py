@@ -404,48 +404,102 @@ def _op_dep_reads(op):
     return names
 
 
+def _recompute_runs(ops):
+    """``ops`` cut into runs: a maximal run of neighbours that carry
+    one ``__recompute__`` group (backward.recompute_guard), every
+    other op alone."""
+    runs = []
+    for op in ops:
+        group = op.attrs.get('__recompute__')
+        if group is not None and runs and \
+                runs[-1][0].attrs.get('__recompute__') == group:
+            runs[-1].append(op)
+        else:
+            runs.append([op])
+    return runs
+
+
+def _lower_recomputed(run, env, step, prefer_test):
+    """One recompute group under ``jax.checkpoint``: what it reads of
+    the env goes in, what it writes comes out, and a differentiation
+    of the trace keeps the former and computes the rest again."""
+    written, reads = set(), []
+    for op in run:
+        for n in _op_reads(op):
+            if n not in written and n in env and n not in reads:
+                reads.append(n)
+        written.update(_op_writes(op))
+
+    def group(values):
+        local = dict(values)
+        for op in run:
+            _lower_op(op, local, step, prefer_test)
+        return {n: local[n] for n in written if n in local}
+
+    env.update(jax.checkpoint(group)({n: env[n] for n in reads}))
+
+
 def _lower_ops(ops, env, step, prefer_test):
     """Run a list of ops' lowering rules over a functional env."""
+    # a loop whose gradient op is lowered in this same trace hands it
+    # the vjp of its one forward scan (the per-op path; under the
+    # whole-program vjp the grad ops are never lowered)
+    differentiated_here = set(op.attrs['sub_block'] for op in ops
+                              if op.type == 'while_grad')
+    for run in _recompute_runs(ops):
+        if '__recompute__' in run[0].attrs:
+            _lower_recomputed(run, env, step, prefer_test)
+        else:
+            (op,) = run
+            _lower_op(op, env, step, prefer_test,
+                      op.attrs.get('sub_block') in differentiated_here)
+
+
+def _lower_op(op, env, step, prefer_test, keep_vjp=False):
+    """One op's lowering rule over a functional env (``keep_vjp``: a
+    `while` whose gradient op follows in the same trace)."""
     CF_LOWERINGS = {'while': _lower_while,
                     'conditional_block': _lower_conditional_block,
                     'while_grad': _lower_while_grad,
                     'conditional_block_grad': _lower_conditional_block_grad}
-    for op in ops:
-        cf = CF_LOWERINGS.get(op.type)
-        if cf is not None:
-            with jax.named_scope(op.type):
+    cf = CF_LOWERINGS.get(op.type)
+    if cf is not None:
+        with jax.named_scope(op.type):
+            if op.type == 'while':
+                cf(op, env, step, prefer_test, keep_vjp=keep_vjp)
+            else:
                 cf(op, env, step, prefer_test)
+        return
+    opdef = registry.get(op.type)
+    ins = {}
+    for slot, names in op.inputs.items():
+        if not names:
             continue
-        opdef = registry.get(op.type)
-        ins = {}
-        for slot, names in op.inputs.items():
-            if not names:
-                continue
-            try:
-                ins[slot] = [env[n] for n in names]
-            except KeyError as e:
-                err = RuntimeError(
-                    'op %s reads undefined var %s' % (op.type, e))
-                _add_note(err, _op_error_context(op, {}))
-                raise err from e
-        ctx = registry.LowerCtx(step, op.attrs.get('__op_seed__', 0),
-                                prefer_test)
         try:
-            # per-op trace attribution: the reference wraps every op run
-            # in a profiler RecordEvent (framework/operator.cc:170); here
-            # the scope name flows into XLA op metadata so Perfetto
-            # traces and HLO dumps read as fluid op names
-            with jax.named_scope(op.type):
-                outs = opdef.run(ctx, ins, op.attrs)
-        except Exception as e:
-            # enforce-style error context (reference: PADDLE_ENFORCE +
-            # op_callstack, platform/enforce.h, framework/op_call_stack.h)
-            _add_note(e, _op_error_context(op, ins))
-            raise
-        for slot, names in op.outputs.items():
-            vals = outs.get(slot, [])
-            for n, v in zip(names, vals):
-                env[n] = v
+            ins[slot] = [env[n] for n in names]
+        except KeyError as e:
+            err = RuntimeError(
+                'op %s reads undefined var %s' % (op.type, e))
+            _add_note(err, _op_error_context(op, {}))
+            raise err from e
+    ctx = registry.LowerCtx(step, op.attrs.get('__op_seed__', 0),
+                            prefer_test)
+    try:
+        # per-op trace attribution: the reference wraps every op run
+        # in a profiler RecordEvent (framework/operator.cc:170); here
+        # the scope name flows into XLA op metadata so Perfetto
+        # traces and HLO dumps read as fluid op names
+        with jax.named_scope(op.type):
+            outs = opdef.run(ctx, ins, op.attrs)
+    except Exception as e:
+        # enforce-style error context (reference: PADDLE_ENFORCE +
+        # op_callstack, platform/enforce.h, framework/op_call_stack.h)
+        _add_note(e, _op_error_context(op, ins))
+        raise
+    for slot, names in op.outputs.items():
+        vals = outs.get(slot, [])
+        for n, v in zip(names, vals):
+            env[n] = v
 
 
 def _subblock_carry(sub_ops, env):
@@ -462,17 +516,29 @@ def _subblock_carry(sub_ops, env):
     return writes
 
 
-def _lower_while(op, env, step, prefer_test):
+def _loop_vjp_name(op):
+    """Where a loop's forward op leaves the vjp of its scan for its
+    gradient op, in the env of one trace (never a segment output)."""
+    return 'while@VJP@%d' % op.attrs['sub_block']
+
+
+def _lower_while(op, env, step, prefer_test, keep_vjp=False):
     """while op -> lax.while_loop.  Static shapes; parent vars the
     sub-block only reads are captured as closure constants.
 
     When the loop carries gradients (__needs_grad__, set by
     backward._control_flow_backward) it lowers instead to a bounded,
     masked lax.scan — semantically `for i in range(max_trip_count):
-    carry = cond ? body(carry) : carry` — which is what the grad op
-    re-runs under jax.vjp, and it stashes the carry ENTRY values for
-    the grad op (the reference keeps them in step scopes:
-    operators/controlflow/while_op.cc)."""
+    carry = cond ? body(carry) : carry` — which reverse mode can
+    differentiate.  The scan runs ONCE a step either way: under the
+    whole-program vjp (_make_segment_fn) it is part of the one
+    differentiated forward, and its residuals are the scan's own; on
+    the per-op path (`keep_vjp`: the while_grad op is lowered in the
+    same trace) the scan is taken under jax.vjp HERE and the vjp kept
+    in the env for the grad op, which then replays nothing.  The carry
+    ENTRY values are stashed for a grad op that does have to replay
+    (one cut into another segment; the reference keeps them in step
+    scopes: operators/controlflow/while_op.cc)."""
     import jax
     import jax.numpy as jnp
     program = op.block.program
@@ -487,10 +553,29 @@ def _lower_while(op, env, step, prefer_test):
                     'loop' % n)
             env[en] = env[n]
         init = {n: env[n] for n in carry_names}
-        final = _while_scan(sub.ops, carry_names, cond_name, init, env,
-                            int(op.attrs['max_trip_count']), step,
-                            prefer_test)
-        env.update(final)
+        max_t = int(op.attrs['max_trip_count'])
+        if not keep_vjp:
+            env.update(_while_scan(sub.ops, carry_names, cond_name, init,
+                                   env, max_t, step, prefer_test))
+            return
+        float_carries = list(op.attrs['__float_carries__'])
+        closure = {n: jnp.asarray(env[n])
+                   for n in op.attrs['__closure_names__']}
+        outer = {n: v for n, v in env.items() if n not in closure}
+
+        def fwd(entry_carry, closure):
+            final = _while_scan(sub.ops, carry_names, cond_name,
+                                entry_carry, dict(outer, **closure),
+                                max_t, step, prefer_test)
+            return ({n: final[n] for n in float_carries},
+                    {n: v for n, v in final.items()
+                     if n not in float_carries})
+
+        floats, env[_loop_vjp_name(op)], rest = jax.vjp(
+            fwd, {n: jnp.asarray(v) for n, v in init.items()}, closure,
+            has_aux=True)
+        env.update(floats)
+        env.update(rest)
         return
     carry_names = _subblock_carry(sub.ops, env)
     if cond_name not in carry_names:
@@ -538,13 +623,19 @@ def _while_scan(sub_ops, carry_names, cond_name, init, outer_env, max_t,
         pred = jnp.asarray(carry[cond_name]).reshape(()).astype(bool)
         local = dict(outer_env)
         local.update(carry)
-        _lower_ops(sub_ops, local, step, prefer_test)
+        # the loop's body in the optimised HLO, forward and (under
+        # transpose(...)) backward: fluid.profiler.loop_tables
+        with jax.named_scope(registry.LOOP_BODY_SCOPE):
+            _lower_ops(sub_ops, local, step, prefer_test)
         merged = {}
         for n in carry_names:
             new = jnp.asarray(local[n]).astype(carry[n].dtype)
             merged[n] = jnp.where(pred, new, carry[n])
         return merged, None
 
+    # gauge `loop/trips`: the body executions of the traced program's
+    # differentiable loops, a step (every trip of a masked scan runs)
+    registry.trace_sum('loop/trips', max_t)
     final, _ = jax.lax.scan(body, init, None, length=max_t)
     truncated = jnp.asarray(final[cond_name]).reshape(()).astype(bool)
     poison = jnp.where(truncated, jnp.float32(jnp.nan), jnp.float32(0))
@@ -557,10 +648,13 @@ def _while_scan(sub_ops, carry_names, cond_name, init, outer_env, max_t,
     return out
 
 
-def _control_flow_grad(op, env, make_fwd):
+def _control_flow_grad(op, env, make_fwd, kept_vjp=None):
     """Shared plumbing for while_grad / conditional_block_grad: collect
-    entries + closure values from env, jax.vjp over the re-run forward
-    (make_fwd builds it from the collected pieces), write grads back.
+    the cotangents, pull them back and write the grads.  With
+    ``kept_vjp`` (a loop whose forward op ran in this trace:
+    _lower_while) nothing is run again; else entries + closure values
+    come from the env and the forward (make_fwd builds it from the
+    collected pieces) is re-run under jax.vjp.
     The op wiring comes from backward._control_flow_backward."""
     import jax
     import jax.numpy as jnp
@@ -568,20 +662,22 @@ def _control_flow_grad(op, env, make_fwd):
     float_carries = list(op.attrs['__float_carries__'])
     closure_names = list(op.attrs['__closure_names__'])
 
-    entries = {n: jnp.asarray(env[en])
-               for n, en in zip(carry_names, op.input('Entry'))}
-    base_env = {n: env[n] for n in op.input('X')
-                if n in env and n not in carry_names
-                and n not in closure_names}
-    closure_vals = {n: jnp.asarray(env[n]) for n in closure_names}
-
-    fwd = make_fwd(carry_names, float_carries, base_env)
-    out, vjp_fn = jax.vjp(fwd, entries, closure_vals)
+    if kept_vjp is None:
+        entries = {n: jnp.asarray(env[en])
+                   for n, en in zip(carry_names, op.input('Entry'))}
+        base_env = {n: env[n] for n in op.input('X')
+                    if n in env and n not in carry_names
+                    and n not in closure_names}
+        closure_vals = {n: jnp.asarray(env[n]) for n in closure_names}
+        fwd = make_fwd(carry_names, float_carries, base_env)
+        out, kept_vjp = jax.vjp(fwd, entries, closure_vals)
+    else:
+        out = {n: env[n] for n in float_carries}
     cots = {}
     for n, g in zip(float_carries, op.input('GRAD::Out')):
         cots[n] = jnp.asarray(env[g]).astype(out[n].dtype).reshape(
             out[n].shape)
-    d_entry, d_closure = vjp_fn(cots)
+    d_entry, d_closure = kept_vjp(cots)
     for n, gname in zip(float_carries, op.output('GRAD::Entry')):
         env[gname] = d_entry[n]
     for n, gname in zip(closure_names, op.output('GRAD::X')):
@@ -589,11 +685,15 @@ def _control_flow_grad(op, env, make_fwd):
 
 
 def _lower_while_grad(op, env, step, prefer_test):
-    """Gradient of a while op: re-run the bounded masked scan from the
-    saved carry entries under jax.vjp.  Gradients flow to the entry
-    values of the loop state and to closure reads (e.g. weights used
-    inside the body).  Reference analog: WhileGradOp replaying step
-    scopes (operators/controlflow/while_op.cc)."""
+    """Gradient of a while op.  Gradients flow to the entry values of
+    the loop state and to closure reads (e.g. weights used inside the
+    body: one gradient, the sum over the trips).  Where the forward op
+    was lowered in this trace it left the vjp of its scan
+    (_lower_while `keep_vjp`) and the loop's forward runs once a step;
+    a grad op alone in its segment re-runs the bounded masked scan
+    from the saved carry entries under jax.vjp.  Reference analog:
+    WhileGradOp replaying step scopes
+    (operators/controlflow/while_op.cc)."""
     program = op.block.program
     sub = program.blocks[op.attrs['sub_block']]
     cond_name = op.input('Condition')[0]
@@ -609,7 +709,8 @@ def _lower_while_grad(op, env, step, prefer_test):
             return {n: final[n] for n in float_carries}
         return fwd
 
-    _control_flow_grad(op, env, make_fwd)
+    _control_flow_grad(op, env, make_fwd,
+                       env.pop(_loop_vjp_name(op), None))
 
 
 def _lower_conditional_block_grad(op, env, step, prefer_test):
@@ -937,37 +1038,48 @@ def _make_segment_fn(segment, prefer_test=False, whole_program_grad=False):
             def fwd(wrt_vals):
                 env = dict(others)
                 env.update(wrt_vals)
-                for op in pre:
-                    if op.type in CF_FWD and \
-                            not op.attrs.get('__needs_grad__'):
-                        # the backward pass gave this loop/branch no
-                        # gradient (no cotangent reaches its outputs),
-                        # but a raw lax.while_loop cannot sit on a
-                        # differentiated path under jax.vjp — lower it
-                        # against a shadow env whose reads are
-                        # gradient-stopped, exactly the per-op
-                        # semantics (no grads flow through it)
-                        shadow = dict(env)
-                        wrapped = {}
-                        for n in set(_op_dep_reads(op)):
-                            if n in shadow:
-                                v = jax.lax.stop_gradient(shadow[n])
-                                shadow[n] = wrapped[n] = v
-                        _lower_ops([op], shadow, step, prefer_test)
-                        for n, v in shadow.items():
-                            if n in wrapped and v is wrapped[n]:
-                                continue  # an unmodified pinned read
-                            if n not in env or env[n] is not v:
-                                env[n] = v
-                        continue
-                    _lower_ops([op], env, step, prefer_test)
-                    # stop_gradient / no_grad_set vars are constants
-                    # to the pruning pass — pin them for the vjp at
-                    # write time, before any consumer reads them
-                    for n in _op_writes(op):
-                        if n in stop_names and n in env:
-                            env[n] = jax.lax.stop_gradient(env[n])
+                for run in _recompute_runs(pre):
+                    if '__recompute__' in run[0].attrs and \
+                            not stop_names.intersection(
+                                n for op in run for n in _op_writes(op)):
+                        # a recompute group (one that pins nothing
+                        # halfway): one checkpointed lowering
+                        _lower_recomputed(run, env, step, prefer_test)
+                    else:
+                        for op in run:
+                            lower_one(op, env)
                 return {p: env[p] for p, _, _ in seeds}, env
+
+            def lower_one(op, env):
+                if op.type in CF_FWD and \
+                        not op.attrs.get('__needs_grad__'):
+                    # the backward pass gave this loop/branch no
+                    # gradient (no cotangent reaches its outputs),
+                    # but a raw lax.while_loop cannot sit on a
+                    # differentiated path under jax.vjp — lower it
+                    # against a shadow env whose reads are
+                    # gradient-stopped, exactly the per-op
+                    # semantics (no grads flow through it)
+                    shadow = dict(env)
+                    wrapped = {}
+                    for n in set(_op_dep_reads(op)):
+                        if n in shadow:
+                            v = jax.lax.stop_gradient(shadow[n])
+                            shadow[n] = wrapped[n] = v
+                    _lower_ops([op], shadow, step, prefer_test)
+                    for n, v in shadow.items():
+                        if n in wrapped and v is wrapped[n]:
+                            continue  # an unmodified pinned read
+                        if n not in env or env[n] is not v:
+                            env[n] = v
+                    return
+                _lower_ops([op], env, step, prefer_test)
+                # stop_gradient / no_grad_set vars are constants
+                # to the pruning pass — pin them for the vjp at
+                # write time, before any consumer reads them
+                for n in _op_writes(op):
+                    if n in stop_names and n in env:
+                        env[n] = jax.lax.stop_gradient(env[n])
 
             roots, vjp_fn, env = jax.vjp(fwd, wrt, has_aux=True)
             # one backward pass per loss (usually one): cotangent only

@@ -13,6 +13,7 @@ the IR never drifts from the kernels.
 """
 
 import contextlib
+import itertools
 import os
 import sys
 import weakref
@@ -311,6 +312,9 @@ class Block(object):
         if '__op_role__' not in attrs:
             attrs['__op_role__'] = getattr(self.program, '_current_role',
                                            'forward')
+        group = getattr(self.program, '_recompute_group', None)
+        if group is not None and attrs['__op_role__'] == 'forward':
+            attrs.setdefault('__recompute__', group)
         op = Operator(self, type, inputs=inputs, outputs=outputs, attrs=attrs)
         self.ops.append(op)
         if infer_shape and registry.is_registered(type) \
@@ -393,6 +397,8 @@ def _normalize_io(io):
 # (tools/progcheck.py) execs a model file and verifies whatever
 # Programs it built, without the file having to hand them over
 _all_programs = weakref.WeakSet()
+# ids of backward.recompute_guard's groups
+_recompute_groups = itertools.count(1)
 
 
 def all_live_programs():
@@ -438,6 +444,16 @@ class Program(object):
             yield
         finally:
             self._current_role = prev
+
+    @contextlib.contextmanager
+    def _recompute_guard(self):
+        """See ``fluid.backward.recompute_guard``."""
+        prev = getattr(self, '_recompute_group', None)
+        self._recompute_group = next(_recompute_groups)
+        try:
+            yield
+        finally:
+            self._recompute_group = prev
 
     def _bump_version(self):
         self._version += 1

@@ -260,6 +260,14 @@ def attribute_trace_events(events, op_types=None, with_stats=False,
 #   fluid op type; jax's transform wrappers are looked through, and a
 #   ``transpose`` among them makes it that type's backward
 #   (``mul_grad``, the name the explicit grad op lowers under);
+# - a control-flow op (``while``, ``conditional_block``) is looked
+#   INTO: an instruction of its sub-block counts to the first fluid op
+#   type further down the path
+#   (``jvp(while)/while/body/loop_body/mul/dot_general`` is ``mul``),
+#   backward where the control-flow component is (jax names the
+#   transposed body's instructions by their forward ops), and only
+#   what the loop itself adds (the carries' selects, the residuals'
+#   stacking) counts to ``while`` / ``while_grad``;
 # - a plain named scope the lowering itself opened right under the op's
 #   is kept as ``<type>/<scope>``;
 # - a fusion counts to the ``dot`` / ``convolution`` / custom call it
@@ -360,6 +368,24 @@ def _is_op_type(name, op_types):
                                 name[:-5] in op_types)
 
 
+# ops the executor lowers itself and whose sub-block's ops lower
+# inside their named scope
+_CONTROL_FLOW = frozenset(['while', 'conditional_block', 'while_grad',
+                           'conditional_block_grad'])
+
+
+def _unwrapped(comp):
+    """One ``op_name`` component without jax's transform wrappers ->
+    (name, whether a ``transpose`` was among them)."""
+    backward = False
+    m = _TRANSFORMS.match(comp)
+    while m:
+        backward = backward or m.group(1) == 'transpose'
+        comp = m.group(2)
+        m = _TRANSFORMS.match(comp)
+    return comp, backward
+
+
 def fluid_scope(op_name, op_types=None):
     """The fluid op an HLO instruction was lowered from, by the rule
     above, from the ``op_name`` of its metadata: ``'mul'``,
@@ -368,23 +394,55 @@ def fluid_scope(op_name, op_types=None):
         return None
     op_types = op_types or _registered_op_types()
     parts = op_name.split('/')
+    around = None           # the control-flow op the walk is inside
+    inherited = False       # ... and whether that one is transposed
     for i, comp in enumerate(parts[:-1]):
-        backward = False
-        m = _TRANSFORMS.match(comp)
-        while m:
-            backward = backward or m.group(1) == 'transpose'
-            comp = m.group(2)
-            m = _TRANSFORMS.match(comp)
+        comp, backward = _unwrapped(comp)
         if not _is_op_type(comp, op_types):
             continue
+        backward = backward or inherited
         if backward and not comp.endswith('_grad'):
             comp += '_grad'
         inner = parts[i + 1] if i + 2 < len(parts) else ''
-        return comp + '/' + inner if inner and '(' not in inner else comp
+        found = comp + '/' + inner if inner and '(' not in inner else comp
+        if comp in _CONTROL_FLOW:
+            around = around or found
+            inherited = inherited or comp.endswith('_grad')
+            continue
+        return found
+    if around is not None:
+        return around
     from ..ops import registry
     for prefix, op_type in registry.COMPILER_NAMED.items():
         if op_name.startswith(prefix):
             return op_type
+    return None
+
+
+def loop_side(op_name):
+    """Which side of a differentiable fluid loop an instruction belongs
+    to, from its ``op_name``: 'forward' for the scan's body as the
+    forward pass runs it, 'backward' for the body of its transpose (or
+    anything of an explicit ``while_grad`` op), None outside such a
+    body."""
+    from ..ops import registry
+    backward = False
+    for comp in (op_name or '').split('/'):
+        comp, transposed = _unwrapped(comp)
+        backward = backward or transposed or comp == 'while_grad'
+        if comp == registry.LOOP_BODY_SCOPE:
+            return 'backward' if backward else 'forward'
+    return None
+
+
+def _fusion_loop_side(fusion, body):
+    """A fusion's side is that of the dot or call it holds, else its
+    own, else the first of its instructions that has one."""
+    held = [ins for ins in body if ins.opcode in _HELD_BY_FUSION]
+    for ins in held + [fusion] + body:
+        side = loop_side(ins.op_name)
+        if side:
+            return side
     return None
 
 
@@ -721,15 +779,16 @@ def _instruction_cost(ins, shapes, called):
 
 def _tables(hlo_text, op_types=None):
     """One compiled module's optimised HLO text -> (module name, scope
-    table, cost table) from ONE parse; both tables hold every
-    instruction a trace can name (those of fused computations are left
-    out, their fusion stands for them), so ``pick_table`` picks the
-    same program in both."""
+    table, cost table, loop table) from ONE parse; the scope and cost
+    tables hold every instruction a trace can name (those of fused
+    computations are left out, their fusion stands for them), so
+    ``pick_table`` picks the same program in both; the loop table
+    holds those inside a differentiable loop's body (``loop_side``)."""
     op_types = op_types or _registered_op_types()
     module, computations = _parse_hlo(hlo_text)
     fused = {ins.calls for body in computations.values() for ins in body
              if ins.opcode == 'fusion'}
-    scopes, costs = {}, {}
+    scopes, costs, loops = {}, {}, {}
     for name, body in computations.items():
         if name in fused:
             continue
@@ -738,10 +797,14 @@ def _tables(hlo_text, op_types=None):
             called = computations.get(ins.calls)
             if ins.opcode == 'fusion' and called is not None:
                 scopes[ins.name] = _fusion_scope(ins, called, op_types)
+                side = _fusion_loop_side(ins, called)
             else:
                 scopes[ins.name] = fluid_scope(ins.op_name, op_types)
+                side = loop_side(ins.op_name)
+            if side:
+                loops[ins.name] = side
             costs[ins.name] = _instruction_cost(ins, shapes, called)
-    return module, scopes, costs
+    return module, scopes, costs, loops
 
 
 def hlo_scopes(hlo_text, op_types=None):
@@ -755,15 +818,16 @@ def hlo_scopes(hlo_text, op_types=None):
 def hlo_costs(hlo_text):
     """The same text -> (module name, {instruction name: Cost or None})
     for the same instructions, by the cost rule above."""
-    module, _scopes, costs = _tables(hlo_text)
+    module, _scopes, costs, _loops = _tables(hlo_text)
     return module, costs
 
 
 def _held_tables():
-    """[(module name, scope table, cost table)] of every executable
-    this process holds.  The compile plane keeps what ``_tables`` made
-    of an executable while it holds it: each is printed and parsed
-    once, whichever table is asked for first and however often."""
+    """[(module name, scope table, cost table, loop table)] of every
+    executable this process holds.  The compile plane keeps what
+    ``_tables`` made of an executable while it holds it: each is
+    printed and parsed once, whichever table is asked for first and
+    however often."""
     from . import compile_cache
     return [built for _key, built in
             compile_cache.plane().held_tables(_tables)]
@@ -777,7 +841,7 @@ def scope_tables():
     BERT-base.  Two programs of one name (a segment planned for two
     fetch lists) keep a table each; ``pick_table`` tells them apart."""
     tables = {}
-    for module, scopes, _costs in _held_tables():
+    for module, scopes, _costs, _loops in _held_tables():
         tables.setdefault(module, []).append(scopes)
     return tables
 
@@ -786,8 +850,20 @@ def cost_tables():
     """{HLO module name: [table, ...]} (tables as ``hlo_costs`` gives
     them), beside ``scope_tables()`` and from the same parse."""
     tables = {}
-    for module, _scopes, costs in _held_tables():
+    for module, _scopes, costs, _loops in _held_tables():
         tables.setdefault(module, []).append(costs)
+    return tables
+
+
+def loop_tables():
+    """{HLO module name: [table, ...]} beside ``scope_tables()`` and
+    from the same parse: {instruction name: 'forward' | 'backward'} for
+    the instructions inside the bodies of the program's differentiable
+    loops (``loop_side``); a module without one has an empty
+    table."""
+    tables = {}
+    for module, _scopes, _costs, loops in _held_tables():
+        tables.setdefault(module, []).append(loops)
     return tables
 
 

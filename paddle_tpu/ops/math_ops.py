@@ -8,6 +8,8 @@ All matmuls flow to the MXU through jnp.matmul/lax.dot_general with
 float32 accumulation; gradients via jax.vjp (registry.grad_op_def).
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -54,6 +56,29 @@ def matmul_v2(ctx, ins, attrs):
     return matmul(ctx, ins, a)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _dot_float32_out(x, y, dims):
+    """bfloat16 operands, the MXU's float32 accumulator as the result
+    (``mixed_precision.float32_output``).  Backward as a bfloat16
+    product's: the cotangent rounded to bfloat16 first, so both
+    gradient products have bfloat16 operands like every AMP `mul`'s."""
+    return jax.lax.dot_general(x, y, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_float32_out_fwd(x, y, dims):
+    return _dot_float32_out(x, y, dims), (x, y)
+
+
+def _dot_float32_out_bwd(dims, operands, cotangent):
+    _, vjp = jax.vjp(lambda a, b: jax.lax.dot_general(a, b, dims),
+                     *operands)
+    return vjp(cotangent.astype(jnp.bfloat16))
+
+
+_dot_float32_out.defvjp(_dot_float32_out_fwd, _dot_float32_out_bwd)
+
+
 @register('mul')
 def mul(ctx, ins, attrs):
     """Reference operators/mul_op.cc: x flattened to 2-D by
@@ -76,8 +101,9 @@ def mul(ctx, ins, attrs):
     dims = ((tuple(range(xn, len(xs))), tuple(range(len(tail)))),
             ((), ()))
     if attrs.get('__amp__') and x.dtype in (jnp.float32, jnp.bfloat16):
-        out = jax.lax.dot_general(x.astype(jnp.bfloat16),
-                                  y3.astype(jnp.bfloat16), dims)
+        dot = _dot_float32_out if attrs.get('__amp_float32_out__') \
+            else jax.lax.dot_general
+        out = dot(x.astype(jnp.bfloat16), y3.astype(jnp.bfloat16), dims)
     else:
         if x.dtype != y3.dtype:
             # dot_general rejects mixed operand dtypes; preserve jnp

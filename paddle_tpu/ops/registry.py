@@ -164,6 +164,10 @@ HOST_OPS = set()
 # lowering that emits them says so (register(compiler_named=...)) and
 # fluid.profiler's scope table reads it here
 COMPILER_NAMED = {}
+# the named scope the executor opens around the body of a
+# differentiable loop (a `while` lowered as a masked scan):
+# fluid.profiler's loop table tells the body's instructions by it
+LOOP_BODY_SCOPE = 'loop_body'
 
 
 def register(type, in_slots=None, out_slots=None, no_grad_out_slots=(),
