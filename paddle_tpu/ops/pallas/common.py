@@ -114,8 +114,11 @@ def vmem_estimate(t, d, block_q, block_k, itemsize, dv=None):
 
 
 def room_for_second_tile(resident, block_q, block_k, itemsize,
-                         limit=None):
-    """May a kernel instance hold two score tiles alive at once?
+                         limit=None, heads=1):
+    """May a kernel instance hold two score tiles alive at once (of
+    each of the ``heads`` it holds: a d64 pair's instance runs one
+    chain a head, so two of its tiles are alive anyway and the
+    question is about four)?
     Inside one tile the products and the vector chain depend on each
     other, so an instance that holds one tile runs MXU and VPU in
     turn; with a second tile alive the scheduler puts one's chain
@@ -130,8 +133,18 @@ def room_for_second_tile(resident, block_q, block_k, itemsize,
     the call asks Mosaic for, or its default where the call asks for
     nothing (None).
     tests/test_chip_compile.py compiles the shapes that decide."""
+    return two_tiles_vmem(resident, block_q, block_k, itemsize, heads) \
+        <= (limit or SCOPED_VMEM_BYTES)
+
+
+def two_tiles_vmem(resident, block_q, block_k, itemsize, heads=1):
+    """What room_for_second_tile() weighs against the limit: the bytes
+    an instance holds with two score tiles of each of its ``heads``
+    alive.  The forward of a d64 pair asks Mosaic for this much
+    (one_pass_backward_limit), as its one-pass backward asks for its
+    count; every other forward asks by scoped_vmem's older rule."""
     tile = score_tile_bytes(block_q, block_k) * itemsize // 2
-    return 2 * resident + 2 * tile <= (limit or SCOPED_VMEM_BYTES)
+    return 2 * resident + 2 * heads * tile
 
 
 def block_sizes(t, block_q, block_k, d=64, itemsize=2, dv=None, tk=None):
@@ -211,7 +224,7 @@ def _lanes(width):
 
 
 def one_pass_backward_vmem(t, tk, d, dv, block_q, block_k, itemsize,
-                           group=1, q_vectors=2, k_vectors=0):
+                           group=1, q_vectors=2, k_vectors=0, heads=1):
     """Bytes ONE instance of the one-pass flash backward
     (flash_attention._flash_bwd_fused_kernel, grid over heads) holds
     in VMEM, counted as Mosaic lays them out:
@@ -231,6 +244,12 @@ def one_pass_backward_vmem(t, tk, d, dv, block_q, block_k, itemsize,
       operand into bfloat16 parts that lie beside it
       (room_for_second_tile counts them so too).
 
+    An instance that holds a PAIR of 64-wide heads (``heads`` = 2; its
+    rows are the pair's 128 lanes, so ``d`` = ``dv`` = 128 and the
+    rows cost what one head's cost in the lanes they lay in) runs the
+    two chains once a head: four tiles.  Its [2, n] vectors lie in the
+    8 sublanes a [1, n] one takes.
+
     The gate between the one-pass and the two-pass backward, and what
     the one-pass call asks of Mosaic, both read this count
     (one_pass_backward_limit); tests/test_chip_compile.py compiles the
@@ -240,7 +259,8 @@ def one_pass_backward_vmem(t, tk, d, dv, block_q, block_k, itemsize,
     rows_out = (t * _lanes(d) + tk * wide) * itemsize
     vectors = 8 * 4 * (q_vectors * t + k_vectors * tk)
     scratch = t * _lanes(d) * 4 + (tk * wide * 4 if group > 1 else 0)
-    tiles = 2 * score_tile_bytes(block_q, block_k) * max(itemsize // 2, 1)
+    tiles = 2 * heads * score_tile_bytes(block_q, block_k) * \
+        max(itemsize // 2, 1)
     return 2 * (rows_in + rows_out + vectors) + scratch + tiles
 
 
@@ -332,8 +352,9 @@ def dispatch(kernel, enabled, checks=(), force=None,
 def report():
     """/statusz section: per-kernel registration + last decision +
     dispatch/fallback counter values (and, for flash attention, the
-    backward lowerings by kind and the largest ``vmem_limit_bytes``
-    a call asked for), and under 'dropout' the dropout
+    backward lowerings by kind, the fused lowerings by the layout
+    their kernels address and the largest ``vmem_limit_bytes`` a call
+    asked for), and under 'dropout' the dropout
     op's draws from the kernels' counter hash (ops/keep_hash.py):
     lowerings counted and the elements the last traced program draws
     a step.  Empty dict when nothing has dispatched or drawn yet
@@ -362,9 +383,13 @@ def report():
             # GSPMD runner's batch axes
             ent['dispatch_sharded'] = sharded
         # which backward a kernel with two of them lowered (flash
-        # attention: one pass over a head's rows, or dq then dkv), and
-        # the most scoped VMEM any of its calls asked Mosaic for
-        for key in ('backward_one_pass', 'backward_two_pass'):
+        # attention: one pass over a head's rows, or dq then dkv), which
+        # layout its kernels address (flash attention: a pair of
+        # 64-wide heads in the op's own [B, T, H x 64], or one head of
+        # a transposed [B x H, T, D] copy), and the most scoped VMEM
+        # any of its calls asked Mosaic for
+        for key in ('backward_one_pass', 'backward_two_pass',
+                    'layout_paired', 'layout_transposed'):
             n = counter('pallas/%s/%s' % (name, key)) or 0
             if n:
                 ent[key] = n

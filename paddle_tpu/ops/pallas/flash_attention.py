@@ -50,6 +50,15 @@ _score_tile, with the tiles wholly outside them never visited
   fused backward), and every estimate of common.py takes the length
   that is resident.
 
+Two layouts, and the shape picks (_heads_a_step): the forward and the
+one-pass backward address the op's own [B, T, H*64] operands, the pair
+of heads 2p, 2p+1 a grid step (128 lanes dense, nothing transposed in
+or out, the residuals the op's inputs and output), where the heads are
+64 wide, not grouped, even in number and under no band or coarse mask
+(BERT's calls); every other call hands the same bodies one head a step
+of [B*H, T, D] copies.  Counters ``pallas/flash_attention/layout_paired``
+/ ``layout_transposed``, one a lowering.
+
 flash_attention() is the one public entry; ``with_lse`` makes the
 rows' log-sum-exp a second, differentiable output (its cotangent
 folds into dS inside the backward kernels), by which partial results
@@ -305,17 +314,58 @@ def _flush_at_last(member, group, accs, outs):
             out[0] = acc[...].astype(out.dtype)
 
 
+# A grid step of the forward and of the one-pass backward holds ONE
+# head of [B*H, T, D] operands (``heads`` = 1), or, where the heads are
+# 64 wide and not grouped (_heads_a_step), the PAIR of heads 2p, 2p+1
+# as the 128 lanes 128p .. 128p+127 of the op's own [B, T, H*64]
+# operands (``heads`` = 2): nothing is transposed around such a call,
+# and every load and store is 128 lanes dense.  The per-head chain is
+# the same and runs once a head of the step.  At width 64 every product
+# half-fills the 128 x 128 array, so a head's products over the pair's
+# lanes cost the MXU what they cost over its own: q k^T and dO v^T
+# contract over 128 lanes with the other head's zeroed in ONE operand
+# (_head_lanes), p v, dv, dk write 128 columns of which the head's
+# half is kept (_join_heads), and dq, from the zeroed k, is the sum of
+# the two.  No lane moves.  (A non-finite entry in one head of a pair
+# reaches the other's scores as 0 x inf.)
+PAIRED_HEAD_DIM = 64
+
+
+def _head_lanes(x, r, heads):
+    """Head ``r`` of a pair's [n, 128] rows, the other head's lanes
+    zeroed; ``x`` itself where the step holds one head."""
+    if heads == 1:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane // PAIRED_HEAD_DIM == r, x, jnp.zeros_like(x))
+
+
+def _join_heads(parts):
+    """[n, 128] rows whose lanes 64r .. 64r+63 are ``parts[r]``'s: each
+    head's half of what its products wrote over the pair's lanes."""
+    if len(parts) == 1:
+        return parts[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, parts[0].shape, 1)
+    return jnp.where(lane < PAIRED_HEAD_DIM, *parts)
+
+
+def _head_id(step, r, heads):
+    """The head index b * H + h (the dropout draw's) of head ``r`` of
+    grid step ``step``: pair p of batch b holds heads 2p and 2p + 1."""
+    return step if heads == 1 else heads * step + r
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                       block_k, tiles, has_bias, rate, window=0,
-                      coarse=None):
+                      coarse=None, heads=1):
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
     seed_ref = rest.pop(0) if rate else None
     o_ref, lse_ref = rest
     # q_ref: [1, bq, d]; k_ref: [1, T, d]; v_ref: [1, T, dv]; bias_ref:
-    # [1, 1, T]; o_ref: [1, bq, dv]; lse_ref: [1, 1, bq]  (the singleton
+    # [1, 1, T]; o_ref: [1, bq, dv]; lse_ref: [1, heads, bq]  (the
     # middle dim satisfies the TPU block-shape rule for 1-D-per-row
-    # operands)
+    # operands; d = dv = 128, a pair's lanes, where heads = 2)
     # dots consume the native (usually bf16) dtype and accumulate in
     # f32 (_dot): the MXU runs bf16 at 2x f32 throughput and VMEM
     # traffic halves — the pre-cast-to-f32 variant measured ~25%
@@ -327,35 +377,42 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     exact = _scale_is_exact(scale)
     if exact:
         q = q * scale
-    rows = _draw_rows(seed_ref, pl.program_id(0), q_off, bq)
+    qs = [_head_lanes(q, r, heads) for r in range(heads)]
+    rows = [_draw_rows(seed_ref, _head_id(pl.program_id(0), r, heads),
+                       q_off, bq) for r in range(heads)]
 
     nk = t // block_k
 
     def body(i, carry):
-        m, l, acc = carry
         k = k_ref[0, pl.dslice(i * block_k, block_k), :]
         v = v_ref[0, pl.dslice(i * block_k, block_k), :]
         bias = bias_ref[0, 0, pl.dslice(i * block_k, block_k)].astype(
             jnp.float32) if has_bias else None
-        s, u = _score_tile(
-            q, k, bias, rows,
-            _draw_cols(seed_ref, i * block_k, block_k),
-            scale=None if exact else scale, causal=causal, q0=q_off,
-            k0=i * block_k, rate=rate, window=window, coarse=coarse)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        # a row with every key masked so far: m_new = -inf, p = 0
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe[:, None])
-        corr = jnp.exp(m - m_safe)
-        # dropout applies AFTER softmax (reference: dropout around the
-        # probs, python/paddle/fluid/layers/nn.py): the normalizer l
-        # accumulates the UNDROPPED p, only the V-weighting is masked
-        l_new = l * corr + jnp.sum(p, axis=1)
-        if rate:
-            p = p * u
-        acc_new = acc * corr[:, None] + _dot(p.astype(v.dtype), v,
-                                             (1, 0))
-        return m_new, l_new, acc_new
+        cols = _draw_cols(seed_ref, i * block_k, block_k)
+
+        def chain(q, rows, m, l, acc):
+            s, u = _score_tile(
+                q, k, bias, rows, cols,
+                scale=None if exact else scale, causal=causal, q0=q_off,
+                k0=i * block_k, rate=rate, window=window, coarse=coarse)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1))
+            # a row with every key masked so far: m_new = -inf, p = 0
+            m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+            p = jnp.exp(s - m_safe[:, None])
+            corr = jnp.exp(m - m_safe)
+            # dropout applies AFTER softmax (reference: dropout around
+            # the probs, python/paddle/fluid/layers/nn.py): the
+            # normalizer l accumulates the UNDROPPED p, only the
+            # V-weighting is masked
+            l_new = l * corr + jnp.sum(p, axis=1)
+            if rate:
+                p = p * u
+            acc_new = acc * corr[:, None] + _dot(p.astype(v.dtype), v,
+                                                 (1, 0))
+            return m_new, l_new, acc_new
+
+        return tuple(chain(qs[r], rows[r], *carry[r])
+                     for r in range(heads))
 
     m0 = jnp.full((bq,), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((bq,), jnp.float32)
@@ -363,12 +420,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     # skip the K blocks no query of this block sees
     lo, hi = _key_blocks(q_off, bq, block_k, nk, causal, window,
                          coarse)
-    m, l, acc = _loop(lo, hi, body, (m0, l0, acc0), tiles)
-    l_safe = jnp.maximum(l, 1e-20)
-    out = acc / l_safe[:, None]
-    o_ref[0] = out.astype(o_ref.dtype)
-    m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
-    lse_ref[0, 0] = (m_safe + jnp.log(l_safe)).astype(jnp.float32)
+    state = _loop(lo, hi, body, ((m0, l0, acc0),) * heads, tiles)
+    l_safe = [jnp.maximum(l, 1e-20) for _, l, _ in state]
+    o_ref[0] = _join_heads([
+        acc / ls[:, None] for (_, _, acc), ls in zip(state, l_safe)
+    ]).astype(o_ref.dtype)
+    for r, ((m, _, _), ls) in enumerate(zip(state, l_safe)):
+        m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
+        lse_ref[0, r] = (m_safe + jnp.log(ls)).astype(jnp.float32)
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
@@ -533,7 +592,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                             block_q, block_k, tiles, dp_early, has_bias,
                             has_glse, rate, window=0, group=1,
-                            coarse=None):
+                            coarse=None, heads=1):
     """Single-pass backward: grid (BH,) only.  The two-pass scheme
     (dq grid over Q blocks, dk/dv grid over K blocks) recomputes the
     score block s AND the prob-cotangent dp = dO V^T in BOTH kernels —
@@ -547,7 +606,12 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     With ``group`` > 1 query heads to a K/V head the grid is
     (B*Hkv, group): the second axis walks the group's query heads over
     one resident K/V head, and dk, dv add up over it in two more f32
-    [T, d] scratch buffers, written out at the group's last head."""
+    [T, d] scratch buffers, written out at the group's last head.
+
+    With ``heads`` = 2 the grid is (B*H/2,) and a step holds a pair of
+    64-wide heads in 128 lanes (_head_lanes): the chain runs once a
+    head on the step's q and dO tiles, lse and delta are [2, T], and
+    the key-bias gradient is the pair's sum."""
     rest = list(rest)
     dk_acc, dv_acc = (rest.pop(-2), rest.pop(-1)) if group > 1 \
         else (None, None)
@@ -566,6 +630,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     g_id = pl.program_id(0)
     if group > 1:       # the query head this step holds
         g_id = g_id * group + pl.program_id(1)
+    g_ids = [_head_id(g_id, r, heads) for r in range(heads)]
     exact = _scale_is_exact(scale)
     nq, nk = t // block_q, k_ref.shape[1] // block_k
 
@@ -577,62 +642,74 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         # with an exact scale k carries it into s AND into dq
         k_s = k * scale if exact else k
         cols = _draw_cols(seed_ref, i * block_k, block_k)
+        # a head's keys and values: s, dO v^T and dq see no other's
+        ks = [_head_lanes(k_s, r, heads) for r in range(heads)]
+        vs = [_head_lanes(v, r, heads) for r in range(heads)]
 
         def q_step(j, carry):
-            dk, dv, dbias = carry
             q = q_ref[0, pl.dslice(j * block_q, block_q), :]
             do = do_ref[0, pl.dslice(j * block_q, block_q), :]
-            lse = lse_ref[0, 0, pl.dslice(j * block_q,
-                                          block_q)].astype(jnp.float32)
-            delta = delta_ref[0, 0, pl.dslice(j * block_q,
-                                              block_q)].astype(
-                jnp.float32)
-            s, u = _score_tile(
-                q, k_s, bias,
-                _draw_rows(seed_ref, g_id, j * block_q, block_q), cols,
-                scale=None if exact else scale, causal=causal,
-                q0=j * block_q, k0=i * block_k, rate=rate,
-                window=window, coarse=coarse)
-            p = jnp.exp(s - lse[:, None])
 
-            def dp_tile():
-                dp = _dot(do, v, (1, 1))
-                return dp * u if rate else dp
-
-            # dO V^T before or after p^T dO: _flash_bwd_dkv_kernel
-            if dp_early:
-                dp = dp_tile()
-            dv = dv + _dot((p * u if rate else p).astype(do.dtype), do,
-                           (0, 0))
-            if not dp_early:
-                dp = dp_tile()
-            dd = dp - delta[:, None]
-            if has_glse:
-                glse = glse_ref[0, 0, pl.dslice(j * block_q,
-                                                block_q)].astype(
+            def chain(r, dk, dv, dbias):
+                lse = lse_ref[0, r, pl.dslice(j * block_q,
+                                              block_q)].astype(jnp.float32)
+                delta = delta_ref[0, r, pl.dslice(j * block_q,
+                                                  block_q)].astype(
                     jnp.float32)
-                dd = dd + glse[:, None]
-            ds_raw = p * dd
-            dk_blk = _dot(ds_raw.astype(q.dtype), q, (0, 0))
-            dq_blk = _dot(ds_raw.astype(k.dtype), k_s, (1, 0))
-            if not exact:
-                dk_blk, dq_blk = dk_blk * scale, dq_blk * scale
-            dk = dk + dk_blk
-            if has_bias:
-                dbias = dbias + jnp.sum(ds_raw, axis=0)
+                s, u = _score_tile(
+                    q, ks[r], bias,
+                    _draw_rows(seed_ref, g_ids[r], j * block_q, block_q),
+                    cols, scale=None if exact else scale, causal=causal,
+                    q0=j * block_q, k0=i * block_k, rate=rate,
+                    window=window, coarse=coarse)
+                p = jnp.exp(s - lse[:, None])
+
+                def dp_tile():
+                    dp = _dot(do, vs[r], (1, 1))
+                    return dp * u if rate else dp
+
+                # dO V^T before or after p^T dO: _flash_bwd_dkv_kernel
+                if dp_early:
+                    dp = dp_tile()
+                dv = dv + _dot((p * u if rate else p).astype(do.dtype),
+                               do, (0, 0))
+                if not dp_early:
+                    dp = dp_tile()
+                dd = dp - delta[:, None]
+                if has_glse:
+                    glse = glse_ref[0, r, pl.dslice(j * block_q,
+                                                    block_q)].astype(
+                        jnp.float32)
+                    dd = dd + glse[:, None]
+                ds_raw = p * dd
+                dk_blk = _dot(ds_raw.astype(q.dtype), q, (0, 0))
+                dq_blk = _dot(ds_raw.astype(k.dtype), ks[r], (1, 0))
+                if not exact:
+                    dk_blk, dq_blk = dk_blk * scale, dq_blk * scale
+                dk = dk + dk_blk
+                if has_bias:
+                    dbias = dbias + jnp.sum(ds_raw, axis=0)
+                return (dk, dv, dbias), dq_blk
+
+            new, dq_blks = zip(*(chain(r, *carry[r])
+                                 for r in range(heads)))
             # dq accumulates across k-blocks in the f32 VMEM scratch
             # (read-modify-write through the ref: Mosaic supports
-            # dynamic slicing on refs, not on carried values)
+            # dynamic slicing on refs, not on carried values); a pair's
+            # two blocks are zero in each other's lanes
             cur = acc_ref[pl.dslice(j * block_q, block_q), :]
-            acc_ref[pl.dslice(j * block_q, block_q), :] = cur + dq_blk
-            return dk, dv, dbias
+            acc_ref[pl.dslice(j * block_q, block_q), :] = \
+                cur + functools.reduce(jnp.add, dq_blks)
+            return new
 
         j0, j1 = _query_blocks(i * block_k, block_k, block_q, nq,
                                causal, window, coarse)
         dk0 = jnp.zeros((block_k, d), jnp.float32)
         dv0 = jnp.zeros((block_k, v_ref.shape[2]), jnp.float32)
         db0 = jnp.zeros((block_k,), jnp.float32)
-        dk, dv, dbias = _loop(j0, j1, q_step, (dk0, dv0, db0), tiles)
+        state = _loop(j0, j1, q_step, ((dk0, dv0, db0),) * heads, tiles)
+        dk, dv = (_join_heads([c[n] for c in state]) for n in (0, 1))
+        dbias = functools.reduce(jnp.add, [c[2] for c in state])
         if exact:
             dk = dk * scale
         here = pl.dslice(i * block_k, block_k)
@@ -657,44 +734,76 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                        (dk_ref, dv_ref))
 
 
+def _step_shape(q, v, h, heads):
+    """(grid steps over heads, T, the width of the q / k rows a step
+    holds, that of its v / o / dO rows) of a forward or one-pass
+    backward call: a head's of [B*H, T, D] operands, or a pair's 128
+    lanes of [B, T, H*64] ones."""
+    n, t, width = q.shape
+    if heads == 1:
+        return n, t, width, v.shape[2]
+    return n * h // heads, t, heads * width // h, heads * v.shape[2] // h
+
+
+def _softmax_scale(q, h, heads):
+    """1/sqrt(d) of the heads' own width d, whichever way q lies."""
+    d = q.shape[2] if heads == 1 else q.shape[2] // h
+    return 1.0 / (d ** 0.5)
+
+
+def _pair_rows(n, pairs, tiled=False):
+    """A pair's rows in a [B, T, H*64] operand: at grid step b * pairs
+    + p the lanes 128p .. 128p+127 of batch b, all ``n`` = T rows of
+    them, or (``tiled``) the ``n`` rows of the grid's second index."""
+    return pl.BlockSpec(
+        (1, n, 2 * PAIRED_HEAD_DIM),
+        (lambda i, j: (i // pairs, j, i % pairs)) if tiled else
+        (lambda i, *_: (i // pairs, 0, i % pairs)))
+
+
 def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
                      causal, block_q, block_k, interpret, rate,
-                     window=0, coarse=None, limit=None):
+                     window=0, coarse=None, limit=None, heads=1):
     """pallas_call plumbing for the one-pass backward: grid (BH,), or
-    (B*Hkv, group) where ``group`` query heads share a K/V head.
+    (B*Hkv, group) where ``group`` query heads share a K/V head, or
+    (B*H/2,) over pairs of heads (``heads`` = 2: q, k, v, do and the
+    three outputs are [B, T, H*64], the vectors [B*H/2, 2, T]).
     ``limit``: the scoped VMEM the call asks Mosaic for (_flash_bwd;
     None: its default); a second tile has to fit under THAT."""
-    bh, t, d = q.shape
-    tk, dv = k.shape[1], v.shape[2]
-    group = bh // k.shape[0]
-    scale = 1.0 / (d ** 0.5)
+    steps, t, d, dv = _step_shape(q, v, h, heads)
+    tk = k.shape[1]
+    group = 1 if heads > 1 else steps // k.shape[0]
+    scale = _softmax_scale(q, h, heads)
     has_bias = bias is not None
     has_glse = glse3 is not None
     tiles, dp_early = _second_tile(
         None if causal or coarse else t // block_q,
         _fused_bwd_resident(t, d, block_k, q.dtype.itemsize, group, dv,
                             tk),
-        block_q, block_k, q.dtype.itemsize, limit)
+        block_q, block_k, q.dtype.itemsize, limit, heads)
     kernel = functools.partial(
         _flash_bwd_fused_kernel, scale=scale, causal=causal,
         block_q=block_q, block_k=block_k, tiles=tiles,
         dp_early=dp_early, has_bias=has_bias, has_glse=has_glse,
-        rate=rate, window=window, group=group, coarse=coarse)
+        rate=rate, window=window, group=group, coarse=coarse,
+        heads=heads)
 
     def head(*ids):     # the query head of a grid step
         return ids[0] if group == 1 else ids[0] * group + ids[1]
 
     def rows(width, kv=False):      # one head's [t | tk, width] rows
+        if heads > 1:               # or a pair's, of its batch
+            return _pair_rows(t, h // heads)
         return pl.BlockSpec(
             (1, tk if kv else t, width),
             lambda *ids: (ids[0] if kv else head(*ids), 0, 0))
 
-    vec = pl.BlockSpec((1, 1, t), lambda *ids: (head(*ids), 0, 0))
+    vec = pl.BlockSpec((1, heads, t), lambda *ids: (head(*ids), 0, 0))
     in_specs = [rows(d), rows(d, True), rows(dv, True)]
     operands = [q, k, v]
     if has_bias:
         in_specs.append(pl.BlockSpec(
-            (1, 1, tk), lambda *ids: (head(*ids) // h, 0, 0)))
+            (1, 1, tk), lambda *ids: (head(*ids) // (h // heads), 0, 0)))
         operands.append(bias[:, None, :])
     if rate:
         in_specs.append(pl.BlockSpec((1, 4), lambda *ids: (0, 0)))
@@ -711,7 +820,8 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
     if has_bias:
         out_specs.append(pl.BlockSpec(
             (1, 1, tk), lambda *ids: (head(*ids), 0, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((bh, 1, tk), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((steps, 1, tk),
+                                              jnp.float32))
     from jax.experimental.pallas import tpu as pltpu
     scratch = [pltpu.VMEM((t, d), jnp.float32)]         # dq
     if group > 1:                                       # dk, dv
@@ -719,7 +829,7 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
                     pltpu.VMEM((tk, dv), jnp.float32)]
     res = pl.pallas_call(
         kernel,
-        grid=(bh,) if group == 1 else (bh // group, group),
+        grid=(steps,) if group == 1 else (steps // group, group),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -729,8 +839,10 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
     )(*operands)
     if has_bias:
         dq, dk, dv, dbias_bh = res
-        b = bh // h
-        dbias = dbias_bh[:, 0, :].reshape(b, h, tk).sum(axis=1)
+        # bias is per (batch, key): sum the steps of a batch
+        per_b = h // heads
+        dbias = dbias_bh[:, 0, :].reshape(steps // per_b, per_b,
+                                          tk).sum(axis=1)
     else:
         dq, dk, dv = res
         dbias = None
@@ -781,17 +893,18 @@ def _one_pass_blocks(t, tk, block_q, block_k):
 
 
 def _one_pass_vmem(t, tk, d, dv, block_q, block_k, itemsize, group,
-                   has_bias, has_glse):
+                   has_bias, has_glse, heads=1):
     """common.one_pass_backward_vmem() of a call as _flash_bwd_fused
     makes it: lse and delta, the lse cotangent where there is one, the
     key bias and its gradient where there is one."""
     return _common.one_pass_backward_vmem(
         t, tk, d, dv, block_q, block_k, itemsize, group,
-        q_vectors=3 if has_glse else 2, k_vectors=2 if has_bias else 0)
+        q_vectors=3 if has_glse else 2, k_vectors=2 if has_bias else 0,
+        heads=heads)
 
 
 def _second_tile(trips, resident, block_q, block_k, itemsize,
-                 limit=None):
+                 limit=None, heads=1):
     """(tiles a loop trip, dp_early): how a kernel instance uses the
     room for a second score tile, where the VMEM model finds it
     (common.room_for_second_tile) under ``limit``, the scoped VMEM
@@ -801,9 +914,11 @@ def _second_tile(trips, resident, block_q, block_k, itemsize,
     by the diagonal).  Where it is not, the backward bodies issue their
     second independent product (dO V^T) before the first tile's
     chain is through, which keeps a second tile alive just the
-    same."""
+    same.  An instance that holds a pair of heads runs a chain a
+    head, so two tiles are alive in it however this answers; the room
+    it asks about is for two of EACH head."""
     room = _common.room_for_second_tile(resident, block_q, block_k,
-                                        itemsize, limit)
+                                        itemsize, limit, heads)
     tiles = 2 if room and trips is not None and trips % 2 == 0 else 1
     return tiles, room and tiles == 1
 
@@ -815,6 +930,15 @@ def _mosaic_params(t, d, block_q, block_k, itemsize, dv):
     scoped VMEM than the compiler's default (common.scoped_vmem)."""
     return _vmem_limit(
         _common.scoped_vmem(t, d, block_q, block_k, itemsize, dv))
+
+
+def _pair_forward_limit(resident, block_q, block_k, itemsize):
+    """The scoped VMEM the forward of a pair of heads asks Mosaic for
+    (None: its default): what two tiles of each head's chain hold
+    beside the rows, by the one-pass backward's rule."""
+    admitted, limit = _common.one_pass_backward_limit(
+        _common.two_tiles_vmem(resident, block_q, block_k, itemsize, 2))
+    return limit if admitted else None
 
 
 def _vmem_limit(limit):
@@ -878,20 +1002,22 @@ def _window_blocks(blocks, window, coarse=None, tk=0):
 
 
 def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
-               interpret, rate=0.0, window=0, coarse=None):
+               interpret, rate=0.0, window=0, coarse=None, heads=1):
     """q: [BH, T, D], k: [B*Hkv, Tk, D], v: [B*Hkv, Tk, Dv] (query
     head i reads K/V head i // (H / Hkv); Tk = T but under a coarse
     mask), bias: [B, Tk] or None, seed: packed (1,4) uint32 [seed,
     q_off, k_off, g_off] (_pack_seed, required when rate>0) ->
-    (o [BH,T,Dv], lse [BH,T])."""
-    _, t, d = q.shape
+    (o [BH,T,Dv], lse [BH,T]).  With ``heads`` = 2 (_heads_a_step)
+    q, k, v and o are [B, T, H*64] instead, as the op holds them."""
+    _, t, d, dv = _step_shape(q, v, h, heads)
     return _fwd_call(
         q, k, v, bias, seed, h=h, causal=causal,
         blocks=_window_blocks(
             _block_sizes(t, block_q, block_k, d, q.dtype.itemsize,
-                         v.shape[2], k.shape[1]),
+                         dv, k.shape[1]),
             window, coarse, k.shape[1]),
-        interpret=interpret, rate=rate, window=window, coarse=coarse)
+        interpret=interpret, rate=rate, window=window, coarse=coarse,
+        heads=heads)
 
 
 # The calls are jitted on their static arguments: the layers of a model
@@ -903,33 +1029,47 @@ def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
 # name of the scope the caller lowered it in (the executor's, the
 # fluid op's type), which is how a device trace is read.
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    'h', 'causal', 'blocks', 'interpret', 'rate', 'window', 'coarse'))
+    'h', 'causal', 'blocks', 'interpret', 'rate', 'window', 'coarse',
+    'heads'))
 def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
-              rate, window=0, coarse=None):
-    bh, t, d = q.shape
-    tk, dv = k.shape[1], v.shape[2]
-    group = bh // k.shape[0]
+              rate, window=0, coarse=None, heads=1):
+    steps, t, d, dv = _step_shape(q, v, h, heads)
+    tk = k.shape[1]
+    group = 1 if heads > 1 else steps // k.shape[0]
     block_q, block_k = blocks
-    scale = 1.0 / (d ** 0.5)
+    scale = _softmax_scale(q, h, heads)
     has_bias = bias is not None
+    resident = _rows_resident(tk, d, block_q, block_k, q.dtype.itemsize,
+                              dv)
+    # a pair's call asks for what its two chains' tiles hold, and its
+    # loop takes two tiles a trip under THAT; the others ask by
+    # common.scoped_vmem and loop as Mosaic's default allows
+    limit = _pair_forward_limit(resident, block_q, block_k,
+                                q.dtype.itemsize) if heads > 1 else None
+    params = _vmem_limit(limit) if heads > 1 else _mosaic_params(
+        tk, d, block_q, block_k, q.dtype.itemsize, dv)
     tiles, _ = _second_tile(
-        None if causal or coarse else tk // block_k,
-        _rows_resident(tk, d, block_q, block_k, q.dtype.itemsize, dv),
-        block_q, block_k, q.dtype.itemsize)
+        None if causal or coarse else tk // block_k, resident, block_q,
+        block_k, q.dtype.itemsize, limit, heads)
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal, block_k=block_k,
         tiles=tiles, has_bias=has_bias, rate=rate, window=window,
-        coarse=coarse)
-    grid = (bh, t // block_q)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-        pl.BlockSpec((1, tk, d), lambda i, j: (i // group, 0, 0)),
-        pl.BlockSpec((1, tk, dv), lambda i, j: (i // group, 0, 0)),
-    ]
+        coarse=coarse, heads=heads)
+    grid = (steps, t // block_q)
+    if heads > 1:       # a pair's lanes of the op's own layout
+        q_rows = o_rows = _pair_rows(block_q, h // heads, tiled=True)
+        k_rows = v_rows = _pair_rows(tk, h // heads)
+    else:
+        q_rows = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0))
+        k_rows = pl.BlockSpec((1, tk, d), lambda i, j: (i // group, 0, 0))
+        v_rows = pl.BlockSpec((1, tk, dv),
+                              lambda i, j: (i // group, 0, 0))
+        o_rows = pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0))
+    in_specs = [q_rows, k_rows, v_rows]
     operands = [q, k, v]
     if has_bias:
-        in_specs.append(pl.BlockSpec((1, 1, tk),
-                                     lambda i, j: (i // h, 0, 0)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, tk), lambda i, j: (i // (h // heads), 0, 0)))
         operands.append(bias[:, None, :])
     if rate:
         in_specs.append(pl.BlockSpec((1, 4), lambda i, j: (0, 0)))
@@ -939,61 +1079,95 @@ def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
+            o_rows,
+            pl.BlockSpec((1, heads, block_q), lambda i, j: (i, 0, j)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
-            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape[:2] + v.shape[2:], q.dtype),
+            jax.ShapeDtypeStruct((steps, heads, t), jnp.float32),
         ],
         interpret=interpret,
-        **_mosaic_params(tk, d, block_q, block_k, q.dtype.itemsize, dv),
+        **params,
     )(*operands)
-    return o, lse3[:, 0, :]
+    # [BH, T]: a pair's two rows are heads 2p and 2p + 1
+    return o, lse3[:, 0, :] if heads == 1 else lse3.reshape(-1, t)
+
+
+def _backward_plan(t, tk, d, dv, itemsize, group, has_bias, has_glse,
+                   block_q, block_k, window=0, coarse=None, heads=1):
+    """(one pass?, its scoped VMEM to ask for, the blocks) of the
+    backward of a call whose grid steps hold rows ``d`` / ``dv`` wide:
+    one pass where an instance's rows, outputs, scratch and tiles fit
+    the VMEM the call may ask for: the shape decides, through the
+    count, and nothing else does.  Else the dq + dkv kernels at the
+    forward's blocks."""
+    block_q, block_k = _window_blocks(
+        _block_sizes(t, block_q, block_k, d, itemsize, dv, tk),
+        window, coarse, tk)
+    fq, fk = _one_pass_blocks(t, tk, block_q, block_k)
+    admitted, limit = _common.one_pass_backward_limit(_one_pass_vmem(
+        t, tk, d, dv, fq, fk, itemsize, group, has_bias, has_glse,
+        heads))
+    if FUSED_BWD and admitted:
+        return True, limit, (fq, fk)
+    return False, None, (block_q, block_k)
 
 
 def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
                block_q, block_k, interpret, rate=0.0, window=0,
-               coarse=None):
-    bh, t, d = q.shape
-    tk, dv = k.shape[1], v.shape[2]
-    block_q, block_k = _window_blocks(
-        _block_sizes(t, block_q, block_k, d, q.dtype.itemsize, dv, tk),
-        window, coarse, tk)
-    fq, fk = _one_pass_blocks(t, tk, block_q, block_k)
-    # one pass where an instance's rows, outputs, scratch and tiles
-    # fit the VMEM the call may ask for: the shape decides, through
-    # the count, and nothing else does
-    admitted, limit = _common.one_pass_backward_limit(_one_pass_vmem(
-        t, tk, d, dv, fq, fk, q.dtype.itemsize, bh // k.shape[0],
-        bias is not None, g_lse is not None))
-    fused = FUSED_BWD and admitted
+               coarse=None, heads=1):
+    steps, t, d, dv = _step_shape(q, v, h, heads)
+    fused, limit, blocks = _backward_plan(
+        t, k.shape[1], d, dv, q.dtype.itemsize,
+        1 if heads > 1 else steps // k.shape[0], bias is not None,
+        g_lse is not None, block_q, block_k, window, coarse, heads)
     from ...fluid import monitor
     monitor.add('pallas/flash_attention/backward_%s'
                 % ('one_pass' if fused else 'two_pass'), 1)
     return _bwd_call(
         q, k, v, bias, seed, o, lse, do, g_lse, h=h, causal=causal,
-        blocks=(fq, fk) if fused else (block_q, block_k), fused=fused,
-        limit=limit if fused else None, interpret=interpret, rate=rate,
-        window=window, coarse=coarse)
+        blocks=blocks, fused=fused, limit=limit, interpret=interpret,
+        rate=rate, window=window, coarse=coarse, heads=heads)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
     'h', 'causal', 'blocks', 'fused', 'limit', 'interpret', 'rate',
-    'window', 'coarse'))
+    'window', 'coarse', 'heads'))
 def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
               blocks, fused, interpret, rate, window=0, coarse=None,
-              limit=None):
+              limit=None, heads=1):
+    block_q, block_k = blocks
+    has_bias = bias is not None
+    has_glse = g_lse is not None
+    if heads > 1:
+        # delta over each head's 64 lanes of [B, T, H*64], into the
+        # [B*H/2, 2, T] the pairs' steps read (lse and its cotangent
+        # come [B*H, T]); always one pass (_heads_a_step).  A product
+        # with the heads' 0/1 lane selector, not a reduce over a
+        # [B, T, H, 64] view: that view half-fills its lanes, and XLA
+        # wrote the f32 product out and transposed it to sum it
+        b, t, width = q.shape
+        lanes = jnp.arange(width)[:, None] // (width // h) == \
+            jnp.arange(h)[None, :]
+        delta = jnp.einsum(
+            'btc,ch->bht',
+            do.astype(jnp.float32) * o.astype(jnp.float32),
+            lanes.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST)
+        return _flash_bwd_fused(
+            q, k, v, bias,
+            jnp.asarray(seed, jnp.uint32).reshape(1, 4) if rate else None,
+            do, lse.reshape(-1, heads, t), delta.reshape(-1, heads, t),
+            g_lse.astype(jnp.float32).reshape(-1, heads, t)
+            if has_glse else None, h, causal, block_q, block_k,
+            interpret, rate, window, coarse, limit, heads)
     bh, t, d = q.shape
     tk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
-    block_q, block_k = blocks
     scale = 1.0 / (d ** 0.5)
     # delta = rowsum(dO * O): one fused elementwise+reduce in XLA
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)
-    has_bias = bias is not None
-    has_glse = g_lse is not None
     lse3 = lse[:, None, :]
     delta3 = delta[:, None, :]
     glse3 = g_lse.astype(jnp.float32)[:, None, :] if has_glse else None
@@ -1149,35 +1323,39 @@ def _flash_primitive(with_lse):
     results can be merged in log-sum-exp space (ring attention's
     blocks, EVA's two key sets).  ``interpret`` is the one
     common.dispatch() decision the public entry made; forward and
-    backward kernels all read it."""
+    backward kernels all read it.  q, k, v (and o, dq, dk, dv) are
+    [B*H, T, D] copies, or with ``heads`` = 2 the op's own operands
+    seen as [B, T, H*64] (_heads_a_step): the residuals are then the
+    op's inputs and its output, no copy of them."""
     def outputs(o, lse):
         return (o, lse) if with_lse else o
 
     def primitive(q, k, v, bias, seed, h, causal, rate, interpret,
-                  window=0, coarse=None):
+                  window=0, coarse=None, heads=1):
         return outputs(*_flash_fwd(
             q, k, v, bias, seed, h, causal, DEFAULT_BLOCK_Q,
-            DEFAULT_BLOCK_K, interpret, rate, window, coarse))
+            DEFAULT_BLOCK_K, interpret, rate, window, coarse, heads))
 
     # the name a jaxpr (and an instruction's metadata) shows
     primitive.__name__ = '_flash_lse' if with_lse else '_flash'
     primitive = jax.custom_vjp(primitive,
-                               nondiff_argnums=(5, 6, 7, 8, 9, 10))
+                               nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 
     def fwd_rule(q, k, v, bias, seed, h, causal, rate, interpret,
-                 window, coarse):
+                 window, coarse, heads):
         o, lse = _flash_fwd(q, k, v, bias, seed, h, causal,
                             DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret,
-                            rate, window, coarse)
+                            rate, window, coarse, heads)
         return outputs(o, lse), (q, k, v, bias, seed, o, lse)
 
-    def bwd_rule(h, causal, rate, interpret, window, coarse, res, g):
+    def bwd_rule(h, causal, rate, interpret, window, coarse, heads, res,
+                 g):
         q, k, v, bias, seed, o, lse = res
         g, g_lse = g if with_lse else (g, None)
         dq, dk, dv, dbias = _flash_bwd(
             q, k, v, bias, seed, o, lse, g, g_lse, h, causal,
             DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret, rate, window,
-            coarse)
+            coarse, heads)
         return dq, dk, dv, (None if bias is None
                             else dbias.astype(bias.dtype)), None
 
@@ -1288,6 +1466,24 @@ def _check_mask_and_heads(q, k, v, causal, window, coarse=None):
     return 0, (span, span // chunk)
 
 
+def _heads_a_step(q, k, v, has_bias, with_lse, window, coarse):
+    """2 where the kernels address the op's own [B, T, H*64] operands,
+    a pair of heads a grid step (no transposed copy goes in or comes
+    out): heads 64 wide, values too, as many K/V heads as query heads,
+    an even number of them, no band and no coarse mask, and a backward
+    that is one pass by its VMEM count (a call the count refuses, or
+    FUSED_BWD off, takes the [B*H, T, D] path whole).  1 everywhere
+    else.  The shape decides; there is nothing to set."""
+    _, t, h, d = q.shape
+    if not (d == v.shape[3] == PAIRED_HEAD_DIM and k.shape[2] == h
+            and h % 2 == 0 and not window and not coarse):
+        return 1
+    one_pass, _, _ = _backward_plan(
+        t, t, 2 * d, 2 * d, q.dtype.itemsize, 1, has_bias, with_lse,
+        DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, heads=2)
+    return 2 if one_pass else 1
+
+
 def _checks(t, min_seq=None):
     """flash_attention()'s own gates for common.dispatch()."""
     return (('below_floor',
@@ -1362,12 +1558,26 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
                            dropout_g_offset, with_lse=with_lse,
                            window=window, coarse=coarse)
 
-    def to_bh(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(
-            -1, x.shape[1], x.shape[3])
+    heads = _heads_a_step(q, k, v, key_bias is not None, with_lse,
+                          window, coarse)
+    from ...fluid import monitor
+    monitor.add('pallas/flash_attention/layout_%s'
+                % ('paired' if heads > 1 else 'transposed'), 1)
 
-    def to_bthd(x):
-        return jnp.transpose(x.reshape(b, h, t, x.shape[2]), (0, 2, 1, 3))
+    if heads > 1:       # views of the op's own layout: no copy
+        def to_bh(x):
+            return x.reshape(b, x.shape[1], -1)
+
+        def to_bthd(x):
+            return x.reshape(b, t, h, -1)
+    else:
+        def to_bh(x):
+            return jnp.transpose(x, (0, 2, 1, 3)).reshape(
+                -1, x.shape[1], x.shape[3])
+
+        def to_bthd(x):
+            return jnp.transpose(x.reshape(b, h, t, x.shape[2]),
+                                 (0, 2, 1, 3))
 
     if key_bias is not None:
         key_bias = key_bias.astype(jnp.float32)
@@ -1376,9 +1586,9 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     if not with_lse:
         return to_bthd(_flash(to_bh(q), to_bh(k), to_bh(v), key_bias,
                               seed, h, causal, rate, interpret, window,
-                              coarse))
+                              coarse, heads))
     o, lse = _flash_lse(to_bh(q), to_bh(k), to_bh(v), key_bias, seed, h,
-                        causal, rate, interpret, window, coarse)
+                        causal, rate, interpret, window, coarse, heads)
     lse = lse.reshape(b, h, t)
     if coarse:      # the rows that saw no key: the dense arm's -inf
         lse = jnp.where(jnp.arange(t) >= coarse[0], lse, -jnp.inf)

@@ -458,6 +458,69 @@ def test_flash_backward_fits_the_scoped_vmem(one_chip, as_on_tpu,
     assert n >= 2, n
 
 
+@pytest.mark.parametrize('b,t,forward_mb,backward_mb', [
+    (12, 2048, 27.25, 20.5),    # bert_base_s2048: both calls ask
+    (48, 512, 13.75, 14.125),   # bert_base_s512_b48: Mosaic's default
+])
+def test_paired_d64_calls_lower_at_berts_shapes_and_ask_their_count(
+        one_chip, as_on_tpu, b, t, forward_mb, backward_mb):
+    """BERT's calls (12 heads of 64, key bias, rate 0.1) on [B, T, 768]
+    operands as the projections write them: the forward and the
+    one-pass backward hold a pair of heads a grid step
+    (flash_attention._heads_a_step), compile for the described v5e
+    under the scoped VMEM their counts ask for
+    (common.two_tiles_vmem, common.one_pass_backward_vmem with
+    heads=2; the headroom where the count passes Mosaic's default),
+    which gauge vmem_asked_max reports, and no [B, H, T, 64] copy of
+    an operand is in the executable."""
+    h, d = 12, 64
+    item = jnp.dtype(jnp.bfloat16).itemsize
+    blocks = flash_attention._block_sizes(
+        t, flash_attention.DEFAULT_BLOCK_Q,
+        flash_attention.DEFAULT_BLOCK_K, 2 * d, item, 2 * d)
+    forward = common.two_tiles_vmem(flash_attention._rows_resident(
+        t, 2 * d, *blocks, item, 2 * d), *blocks, item, 2)
+    backward = flash_attention._one_pass_vmem(
+        t, t, 2 * d, 2 * d, min(t, flash_attention.FUSED_BLOCK_Q),
+        min(t, flash_attention.FUSED_BLOCK_K), item, 1, True, False, 2)
+    assert (forward, backward) == (forward_mb * 2 ** 20,
+                                   backward_mb * 2 ** 20)
+    asked = [common.one_pass_backward_limit(n)[1]
+             for n in (forward, backward)]
+
+    def step(q, k, v, bias):
+        def loss(q, k, v, bias):
+            o = flash_attention.flash_attention(
+                *(x.reshape(b, t, h, d) for x in (q, k, v)),
+                key_bias=bias, dropout_rate=0.1,
+                dropout_seed=jnp.uint32(7))
+            return jnp.sum(o.reshape(b, t, h * d).astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2, 3))(q, k, v, bias)
+
+    # the jitted calls keep their trace, and with it the gauge's setter
+    flash_attention._fwd_call.clear_cache()
+    flash_attention._bwd_call.clear_cache()
+    monitor.remove_gauge('pallas/flash_attention/vmem_asked_max')
+    before = monitor.counter_value(
+        'pallas/flash_attention/layout_paired') or 0
+    qkv = _spec((b, t, h * d), jnp.bfloat16)
+    text = _compiled(step, one_chip, qkv, qkv, qkv,
+                     _spec((b, t))).as_text()
+    _compiled_on_chip('flash_attention')
+    assert monitor.counter_value(
+        'pallas/flash_attention/layout_paired') == before + 1
+    # a call that asks runs under its limit, one that does not under
+    # what it takes of Mosaic's default
+    scoped = _scoped(text)
+    assert len(scoped) == 2 and all(
+        (n in scoped) if n else min(scoped) <= common.SCOPED_VMEM_BYTES
+        for n in asked), (scoped, asked)
+    assert monitor.gauge_value('pallas/flash_attention/vmem_asked_max') \
+        == max(n or 0 for n in asked)
+    assert 'bf16[%d,%d,%d,%d]' % (b, h, t, d) not in text
+    assert 'bf16[%d,%d,%d]' % (b * h, t, d) not in text
+
+
 def test_flash_kernels_are_named_after_the_scope_they_are_lowered_in(
         one_chip, as_on_tpu):
     """A device trace is read by instruction names (the benchmark's

@@ -679,10 +679,17 @@ def _mosaic_limits(fn, *specs):
 
 
 @pytest.mark.parametrize('shape,kwargs,limits', [
-    # bert_base_s2048's and s512_b48's calls: no compiler_params, on
-    # the forward or on the one-pass backward, as before PR 42
-    ((2, 2048, 12, 12, 64, 64), dict(bias=True, rate=0.1), [None, None]),
+    # bert_base_s2048's and s512_b48's calls hold a PAIR of heads a
+    # step since PR 51, four tiles alive: at 2048 keys the forward
+    # asks for its 27.25 MB and the one-pass backward for its 20.5,
+    # each with the headroom; at 512 both stay under the default and
+    # pass no compiler_params
+    ((2, 2048, 12, 12, 64, 64), dict(bias=True, rate=0.1),
+     [43.25 * MB, 36.5 * MB]),
     ((2, 512, 12, 12, 64, 64), dict(bias=True, rate=0.1), [None, None]),
+    # an odd head count at the same width: a head a step on [B x H, T,
+    # D], and no compiler_params, as before PR 42
+    ((2, 2048, 3, 3, 64, 64), dict(bias=True, rate=0.1), [None, None]),
     # OLMoE's: the backward asks, the forward does not
     ((1, 4096, 2, 2, 128, 128), dict(causal=True), [None, 38.5 * MB]),
     # Moonlight's: both ask, the forward by common.scoped_vmem
@@ -1112,3 +1119,224 @@ def test_a_one_device_lowering_holds_no_shard_map():
     assert str(jax.make_jaxpr(one)(*qkv)) == want
     two, _ = _lowered(_mesh({'dp': 2}), ('dp',), attrs)
     assert 'shard_map' in str(jax.make_jaxpr(two)(*qkv))
+
+
+# ---- d64 calls in the op's own [B, T, H x 64] layout, a pair a step ----
+
+def _flash_and_grads(impl, operands, with_lse):
+    """(o, [lse,] dq, dk, dv[, dbias]) of ``impl`` under fixed
+    cotangents on o and lse."""
+    q, k, v, bias, cot, lse_cot = operands
+
+    def loss(q, k, v, bias):
+        out = impl(q, k, v, bias)
+        o, lse = out if with_lse else (out, None)
+        total = jnp.vdot(o.astype(jnp.float32), cot.astype(jnp.float32))
+        if with_lse:
+            total = total + jnp.vdot(lse, lse_cot)
+        return total, out
+
+    args = (0, 1, 2) + ((3,) if bias is not None else ())
+    (_, out), grads = jax.value_and_grad(loss, args, has_aux=True)(
+        q, k, v, bias)
+    return [np.asarray(x, np.float32)
+            for x in jax.tree_util.tree_leaves((out, grads))]
+
+
+@pytest.mark.parametrize('bias,rate,with_lse,offsets,causal,dtype', [
+    (True, 0.1, False, None, False, 'float32'),     # BERT's call
+    (False, 0.0, False, None, False, 'float32'),
+    (True, 0.0, True, None, False, 'float32'),
+    (False, 0.1, True, (7, 11), False, 'float32'),  # a ring's block
+    (True, 0.1, True, (64, 0), True, 'float32'),
+    (True, 0.1, False, None, False, 'bfloat16'),
+], ids=['bias_drop', 'plain', 'bias_lse', 'drop_lse_offsets',
+        'causal_all', 'bf16_bias_drop'])
+def test_paired_layout_matches_dense_and_the_transposed_path(
+        monkeypatch, bias, rate, with_lse, offsets, causal, dtype):
+    """A d64 call with an even number of ungrouped heads reads and
+    writes [B, T, H x 64], two heads a grid step: o, lse, dq, dk, dv
+    and dbias against the dense chain on the same mask AND against the
+    [B x H, T, D] kernels on the same inputs: o and lse keep their
+    bits; the gradients lie within float32 rounding of theirs (delta
+    is a product with the heads' lane selector there, a reduce here;
+    dbias sums pairs first)."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    b, t, h, d = 2, 64, 4, 64
+    rng = np.random.RandomState(17)
+    q, k, v, cot = (jnp.asarray(rng.randn(b, t, h, d), dtype)
+                    for _ in range(4))
+    operands = (q, k, v,
+                jnp.asarray(rng.randn(b, t), jnp.float32) if bias
+                else None, cot,
+                jnp.asarray(rng.randn(b, h, t), jnp.float32))
+    seed = jnp.uint32(5)
+
+    def flash(q, k, v, bias):
+        return fa.flash_attention(
+            q, k, v, causal=causal, key_bias=bias, min_seq=0,
+            dropout_rate=rate, dropout_seed=seed if rate else None,
+            with_lse=with_lse, dropout_offsets=offsets,
+            dropout_g_offset=3 if offsets else 0)
+
+    def dense(q, k, v, bias):
+        return fa._dense_path(q, k, v, causal, bias, rate, seed, offsets,
+                              3 if offsets else 0, with_lse=with_lse)
+
+    assert fa._heads_a_step(q, k, v, bias, with_lse, 0, None) == 2
+    paired = _flash_and_grads(flash, operands, with_lse)
+    monkeypatch.setattr(fa, '_heads_a_step', lambda *a: 1)
+    transposed = _flash_and_grads(flash, operands, with_lse)
+    wanted = _flash_and_grads(dense, operands, with_lse)
+    assert len(paired) == 4 + with_lse + bias
+    tol = 2e-5 if dtype == 'float32' else 3e-2
+    for i, (got, same, want) in enumerate(zip(paired, transposed, wanted)):
+        np.testing.assert_allclose(got, want, rtol=tol,
+                                   atol=tol * np.abs(want).max())
+        if i < 1 + with_lse:
+            np.testing.assert_array_equal(got, same)
+        else:       # f32 rounding, or the last bfloat16 place
+            near = 1e-5 if dtype == 'float32' else 2.0 ** -7
+            np.testing.assert_allclose(got, same, rtol=near,
+                                       atol=near * np.abs(same).max())
+
+
+@pytest.mark.parametrize('rate,offsets,g_off', [
+    (0.1, None, 0), (0.5, (128, 64), 0), (0.25, (3, 5), 24)])
+def test_a_pair_draws_keep_hashs_bits_for_both_of_its_heads(
+        rate, offsets, g_off):
+    """Uniform scores over one-hot values make o[i, :] = keep[i, :] /
+    (T (1 - rate)): the mask each head of a pair drew, read off the
+    kernel's output, is ops/keep_hash.py's for head index b H + h, bit
+    for bit, offsets and all."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    b, t, h, d = 2, 64, 4, 64
+    zeros = jnp.zeros((b, t, h, d), jnp.float32)
+    v = jnp.broadcast_to(jnp.eye(t, d)[None, :, None, :], (b, t, h, d))
+    seed = jnp.uint32(99)
+    assert fa._heads_a_step(zeros, zeros, v, False, False, 0, None) == 2
+    o = fa.flash_attention(zeros, zeros, v, min_seq=0, dropout_rate=rate,
+                           dropout_seed=seed, dropout_offsets=offsets,
+                           dropout_g_offset=g_off)
+    qo, ko = offsets or (0, 0)
+    want = fa.dropout_keep_dense(seed, b, h, t, t, qo, ko, g_off, rate)
+    got = np.asarray(o).transpose(0, 2, 1, 3) > 0      # [b, h, q, key]
+    np.testing.assert_array_equal(got, np.asarray(want))
+    assert 0 < got.mean() < 1
+
+
+def _pallas_operand_shapes(jaxpr):
+    """The operand shapes of every pallas_call in a jaxpr, and whether
+    a transpose of a 4-D tensor is in it."""
+    shapes, transposes = [], []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == 'pallas_call':
+                shapes.append([v.aval.shape for v in eqn.invars])
+            if eqn.primitive.name == 'transpose' and \
+                    len(eqn.invars[0].aval.shape) == 4:
+                transposes.append(eqn.invars[0].aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jaxpr.jaxpr)
+    return shapes, transposes
+
+
+_OTHER_SIDE = {
+    # (q heads, kv heads, d, dv), mask
+    'odd_heads': ((3, 3, 64, 64), {}),
+    'd128': ((2, 2, 128, 128), {}),
+    'grouped_d64': ((4, 2, 64, 64), {}),
+    'wide_values': ((2, 2, 64, 128), {}),
+    'window': ((2, 2, 64, 64), dict(causal=True, window=32)),
+    'coarse': ((2, 2, 64, 64), dict(coarse=(32, 16))),
+}
+
+
+@pytest.mark.parametrize('case', ['paired'] + sorted(_OTHER_SIDE))
+def test_only_the_paired_shape_leaves_the_transposed_jaxpr(
+        monkeypatch, case):
+    """The shape decides and nothing else does: an odd head count, a
+    width other than 64 (of q / k or of v), grouped K/V heads, a band
+    or a coarse mask trace to the jaxpr they traced to before there
+    was a second layout (what _heads_a_step = 1 gives: [B x H, T, D]
+    operands behind 4-D transposes), counted under layout_transposed;
+    the paired call's jaxpr holds no 4-D transpose and hands the
+    kernels [B, T, H x 64]."""
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    (h, hkv, d, dv), mask = _OTHER_SIDE.get(case, ((4, 4, 64, 64), {}))
+    b, t = 2, 64
+    tk = t // 2 if 'coarse' in mask else t
+    q = jnp.zeros((b, t, h, d), jnp.float32)
+    k = jnp.zeros((b, tk, hkv, d), jnp.float32)
+    v = jnp.zeros((b, tk, hkv, dv), jnp.float32)
+
+    def trace():        # a function of its own: make_jaxpr keeps one's
+        return jax.make_jaxpr(lambda q, k, v: jax.grad(
+            lambda q, k, v: jnp.sum(fa.flash_attention(
+                q, k, v, min_seq=0, **mask)), (0, 1, 2))(q, k, v))(q, k, v)
+
+    names = ['pallas/flash_attention/layout_' + n
+             for n in ('paired', 'transposed')]
+    before = [monitor.counter_value(n) or 0 for n in names]
+    traced = trace()
+    counted = [(monitor.counter_value(n) or 0) - x
+               for n, x in zip(names, before)]
+    shapes, transposes = _pallas_operand_shapes(traced)
+    monkeypatch.setattr(fa, '_heads_a_step', lambda *a: 1)
+    forced = trace()
+    if case == 'paired':
+        assert counted == [1, 0]
+        assert not transposes and str(traced) != str(forced)
+        assert all(s[0] == (b, t, h * d) for s in shapes) and \
+            len(shapes) == 2
+    else:
+        assert counted == [0, 1]
+        assert str(traced) == str(forced)
+        assert transposes and all(s[0] == (b * h, t, d) for s in shapes)
+
+
+def test_a_backward_the_count_refuses_takes_the_transposed_path_whole(
+        monkeypatch):
+    """Forward and backward go one way: where the one-pass count does
+    not admit the pair's instance (or FUSED_BWD is off), the forward
+    reads [B x H, T, D] too."""
+    from paddle_tpu.ops.pallas import common
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    q = jnp.zeros((1, 64, 2, 64), jnp.float32)
+    assert fa._heads_a_step(q, q, q, True, False, 0, None) == 2
+    monkeypatch.setattr(fa, 'FUSED_BWD', False)
+    assert fa._heads_a_step(q, q, q, True, False, 0, None) == 1
+    monkeypatch.setattr(fa, 'FUSED_BWD', True)
+    monkeypatch.setattr(common, 'VMEM_LIMIT_CAP_BYTES', 0)
+    monkeypatch.setattr(common, 'SCOPED_VMEM_BYTES', 0)
+    assert fa._heads_a_step(q, q, q, True, False, 0, None) == 1
+    shapes, _ = _pallas_operand_shapes(jax.make_jaxpr(
+        lambda q: fa.flash_attention(q, q, q, min_seq=0))(q))
+    assert shapes[0][0] == (2, 64, 64)
+
+
+def test_the_layout_is_counted_and_shown_in_statusz():
+    """pallas/flash_attention/layout_paired and layout_transposed: one
+    a fused lowering, under the kernel's entry of common.report() and
+    /statusz beside the backward's kind."""
+    from paddle_tpu.fluid import health, monitor
+    from paddle_tpu.ops.pallas import common
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    names = ['pallas/flash_attention/layout_' + n
+             for n in ('paired', 'transposed')]
+    before = [monitor.counter_value(n) or 0 for n in names]
+    pair = jnp.zeros((1, 64, 2, 64), jnp.float32)
+    fa.flash_attention(pair, pair, pair, min_seq=0)
+    fa.flash_attention(pair[:, :, :1], pair[:, :, :1], pair[:, :, :1],
+                       min_seq=0)
+    fa.flash_attention(pair, pair, pair)    # below the floor: dense
+    assert [monitor.counter_value(n) for n in names] == \
+        [before[0] + 1, before[1] + 1]
+    for entry in (common.report()['kernels']['flash_attention'],
+                  health.statusz()['pallas']['kernels'][
+                      'flash_attention']):
+        assert entry['layout_paired'] == before[0] + 1
+        assert entry['layout_transposed'] == before[1] + 1

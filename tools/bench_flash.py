@@ -14,10 +14,18 @@ in-kernel dropout draw and the key bias cost:
 --impl NAME=PATH (repeatable) times another copy of
 ops/pallas/flash_attention.py beside the tree's own (the parent's file
 beside the change's, in one process on one chip) and says whether its
-outputs are bit-equal to the first implementation's.  The calls are
-the module's _flash_fwd / _flash_bwd on [B*H, T, D] operands: the
-Mosaic kernels plus, in the backward, the one XLA reduce that makes
-delta; the [B, T, H, D] transposes around them are not in the times.
+outputs are bit-equal to the first implementation's.  Two readings a
+row.  ``fwd_ms`` / ``bwd_ms``: the module's _flash_fwd / _flash_bwd on
+[B*H, T, D] operands, the Mosaic kernels plus, in the backward, the
+one XLA reduce that makes delta; the [B, T, H, D] transposes around
+them are not in the times.  ``entry_fwd_ms`` / ``entry_fwd_bwd_ms``:
+the public flash_attention() and its vjp on [B, T, H, D] views of
+[B, T, H*D] arrays, as a model's projections leave them: the calls
+AND what the entry puts around them (the transposes of a [B*H, T, D]
+call; nothing but delta around a d64 pair's, whose kernels only this
+reading runs), so ``entry_fwd_bwd_ms - fwd_ms - bwd_ms`` is what
+surrounds a layer's two calls (``around_ms``; ``entry_differ`` holds
+the entry's outputs to the first implementation's).
 A shape the chip's compiler refuses is a row with ``error`` and no
 time.  --kv-heads, --v-dim and --window reach the grouped, latent and
 banded calls (Laguna: --heads 48 --kv-heads 8 --dims 128 --causal
@@ -141,6 +149,55 @@ def measure(fa, args, h, operands, bias, rate):
     return f_ms, b_ms, outs
 
 
+def to_model_layout(x, b):
+    """[B*H, T, D] -> the [B, T, H*D] a projection writes."""
+    n, t, d = x.shape
+    return x.reshape(b, n // b, t, d).transpose(0, 2, 1, 3).reshape(
+        b, t, -1)
+
+
+def measure_entry(fa, args, b, heads, operands, bias, rate):
+    """flash_attention() on [B, T, H, D] views of [B, T, H*D] arrays:
+    ((min, median) ms of a forward, of a forward + backward, and the
+    outputs o, dq, dk, dv (and dbias) as float32 numpy arrays)."""
+    q, k, v, do = (to_model_layout(x, b) for x in operands)
+
+    def entry(q, k, v, bias):
+        split = [x.reshape(b, x.shape[1], n, -1)
+                 for x, n in zip((q, k, v), heads)]
+        o = fa.flash_attention(
+            *split, causal=args.causal, key_bias=bias, dropout_rate=rate,
+            dropout_seed=jnp.uint32(args.seed + 1) if rate else None,
+            dropout_offsets=(3, 5), dropout_g_offset=7,
+            **({'window': args.window} if args.window else {}))
+        return o.reshape(b, o.shape[1], -1)
+
+    def both(q, k, v, bias, do):
+        o, vjp = jax.vjp(entry, q, k, v, bias)
+        return (o,) + vjp(do)
+
+    outs = [np.asarray(x.astype(jnp.float32))
+            for x in jax.jit(both)(q, k, v, bias, do) if x is not None]
+    n = args.inner
+
+    def fwd_n(q, k, v, bias):
+        def chain(_, q):
+            o = entry(q, k, v, bias)
+            return o if o.shape == q.shape else \
+                q + (o[..., :1] * 0).astype(q.dtype)
+        return jax.lax.fori_loop(0, n, chain, q)
+
+    def both_n(q, k, v, bias, do):
+        return jax.lax.fori_loop(
+            0, n, lambda _, c: both(*c, bias, do)[1:4], (q, k, v))
+
+    f_ms = [x / n for x in time_call(
+        jax.jit(fwd_n), (q, k, v, bias), args.steps, args.repeats)]
+    fb_ms = [x / n for x in time_call(
+        jax.jit(both_n), (q, k, v, bias, do), args.steps, args.repeats)]
+    return f_ms, fb_ms, outs
+
+
 def dense_outputs(fa, args, b, h, operands, bias, rate):
     """o, dq, dk, dv (and dbias) of the module's dense chain on the
     same operands and the same mask, as [B*H, T, D] float32 numpy
@@ -197,11 +254,13 @@ def one_pass_vmem(fa, args, t, d, dv, group, has_bias):
 
 
 def bench_calls(args):
+    from paddle_tpu.fluid import monitor
     impls = [load_impl(s) for s in (args.impl or ['tree'])]
     h, d = args.heads or 12, args.dims[0]
     hkv, dv = args.kv_heads or h, args.v_dim or d
     device = jax.devices()[0].device_kind
     rows = []
+    first_entry = {}
     for shape in args.shapes:
         b, t = (int(x) for x in shape.split('x'))
         rng = np.random.RandomState(args.seed)
@@ -249,6 +308,28 @@ def bench_calls(args):
                            bwd_ms=round(b_ms[0], 4),
                            bwd_ms_median=round(b_ms[1], 4),
                            bit_equal_to_first=not differ, differ=differ)
+                if not args.two_pass:   # FUSED_BWD off is no entry's
+                    paired = monitor.counter_value(
+                        'pallas/flash_attention/layout_paired') or 0
+                    ef_ms, efb_ms, e_outs = measure_entry(
+                        fa, args, b, (h, hkv, hkv), operands, bias, rate)
+                    row['entry_layout'] = 'paired' if (
+                        monitor.counter_value(
+                            'pallas/flash_attention/layout_paired') or 0
+                    ) > paired else 'transposed'
+                    mine = first_entry.setdefault(
+                        (shape, has_bias, rate), e_outs)
+                    row.update(
+                        entry_fwd_ms=round(ef_ms[0], 4),
+                        entry_fwd_bwd_ms=round(efb_ms[0], 4),
+                        entry_fwd_bwd_ms_median=round(efb_ms[1], 4),
+                        around_ms=round(efb_ms[0] - f_ms[0] - b_ms[0], 4),
+                        entry_differ={
+                            n: float(np.nanmax(np.abs(x - y)))
+                            for n, x, y in zip(
+                                ('o', 'dq', 'dk', 'dv', 'dbias'),
+                                e_outs, mine)
+                            if not np.array_equal(x, y, equal_nan=True)})
                 if args.dense_parity:
                     # lse is not an output of the dense chain
                     mine = [x for n, x in zip(OUTPUTS, outs) if n != 'lse']

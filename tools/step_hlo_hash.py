@@ -4,11 +4,16 @@ loss and the quiet one) for a DESCRIBED v5e, without a chip, and print
 a hash of the lowered text:
 
     JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 \
-        python tools/step_hlo_hash.py <tree> <out.json> [cell ...]
+        python tools/step_hlo_hash.py <tree> <out.json> [--memory] [cell ...]
 
 Run it on a copy of the parent (``git archive``) and on the tree and
 compare the two files: a PR that says an accepted cell's step is
-untouched shows equal hashes.
+untouched shows equal hashes.  ``--memory`` also COMPILES each quiet
+step for the described chip (minutes a cell) and records what the
+compiler says it holds (``fluid.memviz.analysis_fields``: arguments,
+outputs, temporaries, peak, and the executable's own size, which moves
+with the kernels' bodies and not with the batch): which part of a
+``peak_hbm`` that moved is whose, before any chip time.
 
 A Mosaic kernel's serialized module carries the SOURCE LINES of its
 body and of every caller in the file, so one comment line above a
@@ -27,7 +32,7 @@ import os
 import sys
 
 
-def main(root, out_path, only=()):
+def main(root, out_path, only=(), memory=False):
     root = os.path.abspath(root)
     sys.path.insert(0, root)
     os.chdir(root)
@@ -39,6 +44,7 @@ def main(root, out_path, only=()):
     from jaxlib.mlir.passmanager import PassManager
     import paddle_tpu.fluid as fluid
     from benchmark import run
+    from paddle_tpu.fluid import memviz
     from paddle_tpu.ops.pallas import common
     from paddle_tpu.parallel import mesh as pmesh
 
@@ -98,15 +104,22 @@ def main(root, out_path, only=()):
                 with pmesh.use_trace_mesh(mesh, mesh.axis_names[:1]):
                     return step.fn(count, state, data)
 
-            text = jax.jit(fn, donate_argnums=(1,)).lower(
-                jax.ShapeDtypeStruct((), np.int32), state, data).as_text()
+            lowered = jax.jit(fn, donate_argnums=(1,)).lower(
+                jax.ShapeDtypeStruct((), np.int32), state, data)
+            text = lowered.as_text()
             key = '%s/%s' % (entry['name'], kind)
             hashes[key] = [hashlib.sha256(text.encode()).hexdigest()[:16],
                            len(text), text.count('tpu_custom_call')]
             print(key, *hashes[key], flush=True)
+            if memory and kind == 'quiet':
+                hashes[key + '/memory'] = memviz.analysis_fields(
+                    lowered.compile())
+                print(key + '/memory', hashes[key + '/memory'], flush=True)
     with open(out_path, 'w') as f:
         json.dump(hashes, f, indent=1)
 
 
 if __name__ == '__main__':
-    main(sys.argv[1], sys.argv[2], sys.argv[3:])
+    main(sys.argv[1], sys.argv[2],
+         [a for a in sys.argv[3:] if a != '--memory'],
+         memory='--memory' in sys.argv[3:])
