@@ -385,8 +385,9 @@ class CompilePlane(object):
                 or 256))
         # lazily jitted callables the runners hold, with what their
         # first call was given: key -> (weak reference to the callable,
-        # lowering args).  Weak, so a dead segment's program is not
-        # kept alive from here; read by held_hlo() only
+        # lowering args, the (program, segment) its memory row is filed
+        # under).  Weak, so a dead segment's program is not kept alive
+        # from here; read by _held() only
         self._lazy = LRUCache(
             int(get_flag('FLAGS_compile_cache_memory_capacity', 256)
                 or 256))
@@ -686,33 +687,44 @@ class CompilePlane(object):
                 'executor/aot_compiles'),
         }
 
-    def note_lazy(self, key, jitted, lowering_args):
+    def note_lazy(self, key, jitted, lowering_args, label=None):
         """A runner's word, at the first call of a lazily jitted
         segment, on how to find the compiled program again without a
         second trace: ``jitted.lower(*lowering_args)`` repeats the
         call's signature, so jit's own caches serve the lowering and
-        the compile."""
+        the compile.  ``label``: the (program, segment) fluid.memviz
+        files the executable's memory row under."""
         import weakref
         with self._lock:
-            self._lazy[key] = (weakref.ref(jitted), lowering_args)
+            self._lazy[key] = (weakref.ref(jitted), lowering_args, label)
 
     def _held(self):
         """[(key, the object that is the program while it lives, () ->
-        its executable)]: the AOT executables of the map and the lazily
-        jitted callables the runners noted."""
+        its executable, what a lazy jit was noted with or None)]: the
+        AOT executables of the map and the lazily jitted callables the
+        runners noted (``(lowering args, label)``)."""
         from concurrent.futures import Future
         with self._lock:
-            held = [(fp, ex, lambda ex=ex: ex)
+            held = [(fp, ex, lambda ex=ex: ex, None)
                     for fp, ex in self._mem.items()
                     if not isinstance(ex, Future) and
                     hasattr(ex, 'as_text')]
-            lazy = [(key, ref(), args)
-                    for key, (ref, args) in self._lazy.items()]
-        for key, jitted, args in lazy:
+            lazy = [(key, ref(), args, label)
+                    for key, (ref, args, label) in self._lazy.items()]
+        for key, jitted, args, label in lazy:
             if jitted is not None:      # else its segment is gone
                 held.append((key, jitted, lambda jitted=jitted, args=args:
-                             jitted.lower(*args).compile()))
+                             jitted.lower(*args).compile(), (args, label)))
         return held
+
+    def held_executables(self):
+        """[(key, executable, noted)] of every executable this process
+        holds; ``noted`` is None for an AOT executable of the map and
+        ``(lowering args, label)`` for a lazily jitted one, whose
+        executable jit's own caches answer (``_held``).  On demand only
+        (fluid.memviz's tables), as ``held_hlo``."""
+        return [(key, executable(), noted)
+                for key, _program, executable, noted in self._held()]
 
     @staticmethod
     def _hlo_text(key, executable):
@@ -735,7 +747,7 @@ class CompilePlane(object):
         that silently lacked a program would move all of its device
         time to 'unattributed'."""
         return [(key, self._hlo_text(key, executable()))
-                for key, _program, executable in self._held()
+                for key, _program, executable, _noted in self._held()
                 if key not in skip]
 
     def held_tables(self, build):
@@ -750,7 +762,7 @@ class CompilePlane(object):
         import weakref
         kept = {}
         out = []
-        for key, program, executable in self._held():
+        for key, program, executable, _noted in self._held():
             hit = self._built.get(key)
             if hit is None or hit[0]() is not program:
                 try:

@@ -352,12 +352,15 @@ def _survivable_copy(v):
 
 
 def _segment_label(seg, comms_key=None):
-    """A segment's name in watchdog dumps and estimated memory
-    rows."""
+    """A segment's name in watchdog dumps, memory rows and the
+    first-run list.  One chip: the same ops planned for another fetch
+    list are another executable (the step that fetches the loss and
+    the quiet one), so the count of outputs is part of the name."""
     if comms_key is not None:
         return '%dops@%s' % (len(seg.ops), str(comms_key)[:8])
-    return '%dops:%s' % (len(seg.ops),
-                         ','.join(sorted(seg.output_names)[:3]))
+    names = sorted(seg.output_names)
+    return '%dops:%s+%d' % (len(seg.ops), ','.join(names[:3]),
+                            len(names))
 
 
 def _dispatch_span(comms_key, records):
@@ -2421,13 +2424,15 @@ class Executor(object):
                 # entry — compile, memory hit or disk hit all land
                 # here, so a zero-retrace restarted process keeps its
                 # per-(program, segment) peak decomposition
+                row_label = '%dops:%s@%s' % (
+                    len(seg.ops), ','.join(sorted(seg.output_names)[:3]),
+                    fp[:8])
                 _memviz.record_segment(
-                    None,
-                    '%dops:%s@%s' % (
-                        len(seg.ops),
-                        ','.join(sorted(seg.output_names)[:3]),
-                        fp[:8]),
-                    compiled, state_specs, data_specs, seg=seg)
+                    None, row_label, compiled, state_specs, data_specs,
+                    seg=seg, held_key=fp)
+                # this call only (seg.compiled holds the executable):
+                # the allocator's marks around the entry's first run
+                compiled = _memviz.watch_first_run(compiled, row_label)
             else:
                 monitor.add('executor/segment_cache_hit')
         else:
@@ -2482,7 +2487,9 @@ class Executor(object):
             # only a program that ran: one whose first call raised (a
             # feed of the wrong shape) cannot be lowered again, and
             # would fail every later scope table of the process
-            plane.note_lazy((id(seg), key), compiled, noted)
+            plane.note_lazy((id(seg), key), compiled, noted,
+                            label=(_memviz.current_program() or
+                                   'unlabeled', _segment_label(seg)))
 
     def _dispatch_segment(self, seg, call, state, data, feed, scope,
                           fetched, first_run, comms_key=None,
@@ -2498,8 +2505,8 @@ class Executor(object):
         legitimate cold compile can exceed any step deadline).
         `comms_key`: the fingerprint a mesh runner's shared jit is
         registered under; its lowerings file collective records there
-        at trace time, and the executable exposes no memory analysis,
-        so its memory row is estimated from the arguments.  None on
+        at trace time, and jit holds the executable, so its first
+        memory row is estimated from the arguments.  None on
         one chip: no collective to account, and the compile plane
         recorded the exact row when it built the executable.
         `replayable`: the segment's ops can be run again one by one,
@@ -2573,6 +2580,7 @@ class Executor(object):
                     # compile-latency histogram — and the step's
                     # 'compile' phase span; steady-state calls are the
                     # async 'dispatch' phase
+                    marks = _memviz.device_marks()
                     with comms.collecting(comms_key) if mesh \
                             else contextlib.nullcontext(), \
                             _trace.span('compile'):
@@ -2603,9 +2611,14 @@ class Executor(object):
                     'parallel/segment_compile_seconds' if mesh
                     else 'executor/segment_compile_seconds',
                     _time_mod.perf_counter() - t0)
+                # whose first run raised the allocator's marks; waits
+                # for the outputs, as only a first run may
+                _memviz.first_run_end(
+                    marks, None, _segment_label(seg, comms_key), out)
                 if mesh:
                     # keeps the per-program HBM headroom gate live for
-                    # programs a mesh runner compiled
+                    # programs a mesh runner compiled, until
+                    # memviz.build_tables() has the real row
                     _memviz.record_segment_estimate(
                         None, _segment_label(seg, comms_key), state,
                         data, outputs=out, seg=seg)

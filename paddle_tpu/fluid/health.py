@@ -200,7 +200,9 @@ def statusz():
         pass
     # device-memory plane (fluid.memviz + fluid.comms.record_memory):
     # per-(program, segment) peak ATTRIBUTION (named contributors, not
-    # four scalars), the latest live-HBM census by class, and the
+    # four scalars; once memviz.build_tables() ran, each row's
+    # ``temp_peak``: the temporaries at their peak by class, fluid op
+    # and buffer), the latest live-HBM census by class, and the
     # budget watermarks — the HBM view the placement planner, the
     # collective planner's headroom gate, and an OOM post-mortem read
     memory_section = None
@@ -214,6 +216,9 @@ def statusz():
         if rows or attribution or memviz.last_census() is not None:
             memory_section = {
                 'attribution': attribution,
+                # every new executable's first run in run order with
+                # the allocator's marks around it, and who raised each
+                'high_water': memviz.high_water(),
                 'top_buffers': memviz.top_contributors(),
                 'live': memviz.last_census(),
                 'budget': memviz.memory_pressure(),

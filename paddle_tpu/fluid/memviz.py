@@ -24,6 +24,30 @@ section renders the top-K table, and ``peak_bytes(program)`` is the
 per-program HBM input ``comms_plan.hbm_headroom_bytes`` reads instead
 of the global max.
 
+**The temp arena has names.**  ``build_tables()``, on demand (a traced
+run, ``/statusz`` after it, ``tools/step_hlo_hash.py --memory``; never
+at compile time: a module's text costs seconds), gives every row
+``temp_peak``: where, by ``fluid.profiler``'s walk of the scheduled HLO
+text (``live_tables()``, the same ONE parse the scope and cost tables
+come from), the sum of the executable's live temporaries is largest,
+that sum, the bytes alive there by class (residual / gradient /
+optimizer / working / unscoped) and by fluid op, and the ten largest
+buffers.  A program a runner jitted lazily (every mesh program, and
+the one-chip programs while the AOT plane is off) has no executable
+in hand at its first run; jit's own caches answer for it by then
+(``CompilePlane.held_executables``), so the same call files its REAL
+``memory_analysis()`` row and the first-run estimate goes.
+
+**Who set the high-water mark.**  ``device_marks()`` /
+``first_run_end()`` bracket the FIRST run of every new executable
+(the AOT plane's new entry, the runners' ``first_run`` branch; a first
+run may wait for its outputs, no steady step reads anything) with the
+allocator's ``peak_bytes_in_use`` / ``peak_bytes_reserved``:
+``high_water()`` holds the list in run order and, per mark, the
+executable whose first run last raised it; gauges
+``memviz/hwm_in_use_bytes`` / ``memviz/hwm_reserved_bytes`` are the
+marks as last read.
+
 **Live-HBM accounting.**  ``live_census()`` walks ``jax.live_arrays()``
 and classifies every resident device buffer: ``param`` (registered
 parameter names), ``state`` (other scope-resident values — optimizer
@@ -69,6 +93,8 @@ from .flags import get_flag
 
 __all__ = [
     'record_segment', 'record_segment_estimate', 'report',
+    'build_tables', 'device_marks', 'first_run_end',
+    'watch_first_run', 'high_water', 'temp_peak',
     'peak_bytes', 'top_contributors',
     'program_label', 'program_scope', 'current_program',
     'note_params', 'live_census', 'last_census', 'maybe_sample',
@@ -84,6 +110,12 @@ _tls = threading.local()
 # the compile caches, but a retrace loop must not leak)
 _SEGMENTS = {}
 _SEGMENTS_CAP = 512
+# the compile plane's key of an AOT executable -> its row's key
+_HELD_ROWS = {}
+# first runs of new executables, in run order: the allocator's marks
+# before and after each (bounded like the rows)
+_FIRST_RUNS = []
+TOP_BUFFERS = 10
 # program-object labeling: monotonic sequence, stamped on the Program
 _prog_seq = [0]
 # registered parameter names (census param-vs-state classification)
@@ -105,6 +137,8 @@ def reset():
     module lifetime, not run lifetime."""
     with _lock:
         _SEGMENTS.clear()
+        _HELD_ROWS.clear()
+        del _FIRST_RUNS[:]
         _PARAM_NAMES.clear()
         _state.update({'ema': None, 'hwm': 0.0, 'last_census': None,
                        'budget_detected': None})
@@ -214,12 +248,14 @@ def analysis_fields(compiled):
            'temp_bytes': _field('temp_size_in_bytes'),
            'peak_bytes': _field('peak_memory_in_bytes'),
            'generated_code_bytes': _field(
-               'generated_code_size_in_bytes')}
+               'generated_code_size_in_bytes'),
+           # of the outputs, what is a donated argument's memory
+           'alias_bytes': _field('alias_size_in_bytes')}
     if all(v is None for v in out.values()):
         monitor.add('memviz/analysis_unavailable')
         return None
     for k in ('argument_bytes', 'output_bytes', 'temp_bytes',
-              'generated_code_bytes'):
+              'generated_code_bytes', 'alias_bytes'):
         out[k] = out[k] or 0.0
     if out['peak_bytes'] is None:
         # a backend that reports no peak: arg+out+temp is the
@@ -272,6 +308,12 @@ def _classify_args(state_specs, data_specs, param_names=None):
     return contributors, classes
 
 
+def _largest(contributors):
+    """A row's ``top_buffers``: the TOP_K named contributors by bytes."""
+    return sorted((c for c in contributors if c['bytes']),
+                  key=lambda c: -c['bytes'])[:TOP_K]
+
+
 def _file_row(prog, row):
     key = (prog, row['segment'])
     evicted_prog = None
@@ -279,6 +321,9 @@ def _file_row(prog, row):
         if key not in _SEGMENTS and len(_SEGMENTS) >= _SEGMENTS_CAP:
             (ep, _es) = next(iter(_SEGMENTS))
             _SEGMENTS.pop((ep, _es))
+            for held in [h for h, k in _HELD_ROWS.items()
+                         if k == (ep, _es)]:
+                del _HELD_ROWS[held]
             # keep the per-program gauge label set bounded: when a
             # program's LAST row rotates out, its gauge goes too — a
             # frozen peak for a long-gone program misleads scrapes
@@ -297,12 +342,13 @@ def _file_row(prog, row):
 
 
 def record_segment(program, segment_label, compiled, state_specs,
-                   data_specs, seg=None, param_names=None):
+                   data_specs, seg=None, param_names=None, held_key=None):
     """Decompose one AOT executable's peak into named contributors and
     file the row under (program, segment).  Runs once per new
     executable entry — compile, memory hit or disk hit — NEVER per
-    step.  Returns the row or None when the backend has no analysis
-    (counted, not silent)."""
+    step.  ``held_key``: the compile plane's key of the executable, by
+    which ``build_tables()`` finds the row again.  Returns the row or
+    None when the backend has no analysis (counted, not silent)."""
     fields = analysis_fields(compiled)
     if fields is None:
         return None
@@ -325,17 +371,19 @@ def record_segment(program, segment_label, compiled, state_specs,
         'output_bytes': fields['output_bytes'],
         'temp_bytes': fields['temp_bytes'],
         'generated_code_bytes': fields['generated_code_bytes'],
+        'alias_bytes': fields['alias_bytes'],
         'classes': classes,
         # alignment/padding XLA adds over the raw boundary specs: the
         # residual that keeps sum(classes) + overhead == argument_bytes
         'arg_overhead_bytes': fields['argument_bytes'] - named_args,
-        'top_buffers': sorted(
-            (c for c in contributors if c['bytes']),
-            key=lambda c: -c['bytes'])[:TOP_K],
+        'top_buffers': _largest(contributors),
         'outputs': [c for c in contributors
                     if c['class'] == 'output'][:TOP_K],
         'ts': time.time(),
     }
+    if held_key is not None:
+        with _lock:
+            _HELD_ROWS[held_key] = (prog, row['segment'])
     return _file_row(prog, row)
 
 
@@ -343,12 +391,15 @@ def record_segment_estimate(program, segment_label, state, data,
                             outputs=None, seg=None):
     """ESTIMATED attribution for segments compiled through the
     shape-polymorphic shared jits (the parallel/collective runners):
-    those executables expose no ``memory_analysis()`` without paying a
-    second compile, so the row is built from the bound argument and
-    output arrays themselves — peak = arguments + outputs, temps
-    unknown (a LOWER bound, flagged ``estimated``).  Keeps the
-    per-program headroom gate live on exactly the multi-program
-    collective path it was built for.  Runs at first_run only."""
+    at its first run such an executable is not in hand (jit holds it),
+    so the row is built from the bound argument and output arrays
+    themselves — peak = arguments + outputs, temps unknown (a LOWER
+    bound, flagged ``estimated``).  It is the FIRST-RUN bound the
+    per-program headroom gate reads on exactly the multi-program
+    collective path it was built for; ``build_tables()`` replaces it
+    with the executable's real ``memory_analysis()`` row (jit's caches
+    answer ``lower().compile()`` by then) and its ``temp_peak``.  Runs
+    at first_run only."""
     prog = _resolve_program(program)
     contributors, classes = _classify_args(state, data)
     out_total = 0.0
@@ -371,12 +422,11 @@ def record_segment_estimate(program, segment_label, state, data,
         'output_bytes': out_total,
         'temp_bytes': 0.0,
         'generated_code_bytes': 0.0,
+        'alias_bytes': 0.0,
         'classes': classes,
         'arg_overhead_bytes': 0.0,
         'estimated': True,
-        'top_buffers': sorted(
-            (c for c in contributors if c['bytes']),
-            key=lambda c: -c['bytes'])[:TOP_K],
+        'top_buffers': _largest(contributors),
         'outputs': [c for c in contributors
                     if c['class'] == 'output'][:TOP_K],
         'ts': time.time(),
@@ -384,11 +434,192 @@ def record_segment_estimate(program, segment_label, state, data,
     return _file_row(prog, row)
 
 
+def temp_peak(live):
+    """A row's ``temp_peak`` from fluid.profiler's live table
+    (``hlo_live`` / ``live_tables``): the sum, the point, the bytes by
+    class and by fluid op, the count of buffers alive there, the ten
+    largest, and the two figures the walk keeps apart: the bytes alive
+    there in another memory space and what the loops' bodies compute
+    anew for their next trip."""
+    return {'bytes': float(live['bytes']), 'point': live['point'],
+            'op': live['op'], 'by_class': dict(live['by_class']),
+            'by_op': {str(op): b for op, b in sorted(
+                live['by_op'].items(), key=lambda kv: -kv[1])},
+            'buffers': len(live['buffers']),
+            'top_buffers': live['buffers'][:TOP_BUFFERS],
+            'elsewhere_bytes': float(live['elsewhere_bytes']),
+            'carried_anew_bytes': float(live['carried_anew_bytes'])}
+
+
+def _one_device(specs):
+    """{name: spec} with every spec that says how it is sharded cut to
+    one device's shard: ``memory_analysis()`` counts one device."""
+    import jax
+    out = {}
+    for n, spec in (specs or {}).items():
+        sharding = getattr(spec, 'sharding', None)
+        try:
+            if sharding is not None:
+                spec = jax.ShapeDtypeStruct(
+                    sharding.shard_shape(spec.shape), spec.dtype)
+        except Exception:
+            pass
+        out[n] = spec
+    return out
+
+
+def build_tables():
+    """Give every executable this process holds its real
+    ``memory_analysis()`` row and the row's ``temp_peak`` (the module
+    docstring).  ON DEMAND ONLY: it prints and parses each held
+    module once (``CompilePlane.held_tables``: seconds at BERT-base,
+    shared with fluid.profiler's scope and cost tables) and asks jit's
+    caches for the lazily jitted ones.  Returns the rows it touched."""
+    from . import compile_cache, profiler
+    live = profiler.live_tables()
+    touched = []
+    for key, executable, noted in \
+            compile_cache.plane().held_executables():
+        label = noted[1] if noted else None
+        with _lock:     # an AOT row by its key, a lazy one by its label
+            at = _HELD_ROWS.get(key) or (tuple(label) if label else None)
+            row = _SEGMENTS.get(at)
+        if row is None or row.get('estimated'):
+            # a lazily jitted program: the executable is in hand now
+            fields = analysis_fields(executable)
+            if fields is None:
+                continue
+            prog, segment = label or ('unlabeled', 'held:%s' % (key,))
+            args = noted[0] if noted else (None, {}, {})
+            contributors, classes = _classify_args(
+                _one_device(args[1]), _one_device(args[2]))
+            named = sum(classes.values())
+            fresh = dict(row or {}, program=prog, segment=str(segment),
+                         classes=classes,
+                         arg_overhead_bytes=fields['argument_bytes'] -
+                         named, ts=time.time(), **fields)
+            fresh.pop('estimated', None)
+            fresh['top_buffers'] = _largest(contributors)
+            fresh.setdefault('outputs', [])
+            row = _file_row(prog, fresh)
+        table = live.get(key)
+        if table is not None and table[1] is not None:
+            row['module'] = table[0]
+            row['temp_peak'] = temp_peak(table[1])
+        touched.append(row)
+    return touched
+
+
+# ---------------------------------------------------- high-water marks
+_MARKS = ('peak_bytes_in_use', 'peak_bytes_reserved')
+
+
+def device_marks():
+    """The allocator's two high-water marks, the largest over this
+    process's devices; None where the backend reports none (CPU).
+    Read before a new executable's first dispatch and handed to
+    ``first_run_end``."""
+    try:
+        import jax
+        stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    except Exception:
+        return None
+    if not any(stats):
+        return None
+    return {m: float(max(s.get(m, 0) for s in stats)) for m in _MARKS}
+
+
+def watch_first_run(executable, segment_label):
+    """``executable`` for ONE call, its first, with the marks read
+    around it (the AOT plane's new entry, whose dispatch is not a
+    ``first_run`` of the runner's)."""
+    def first(*args):
+        before = device_marks()
+        out = executable(*args)
+        first_run_end(before, None, segment_label, out)
+        return out
+    return first
+
+
+def first_run_end(before, program, segment_label, outputs=None):
+    """File a new executable's first run: wait for its outputs (a
+    first run may; no other step does), read the marks again, and keep
+    both under (program, segment) at the end of the run-ordered
+    list.  Never raises: a first run that could not be filed is a
+    missing entry of ``high_water()``'s list, and what its outputs
+    raised surfaces where they are next read."""
+    try:
+        if outputs is not None:
+            import jax
+            jax.block_until_ready(outputs)
+        after = device_marks()
+        entry = {'program': _resolve_program(program),
+                 'segment': str(segment_label),
+                 'before': before, 'after': after, 'ts': time.time()}
+        with _lock:
+            if len(_FIRST_RUNS) >= _SEGMENTS_CAP:
+                del _FIRST_RUNS[0]
+            entry['order'] = (_FIRST_RUNS[-1]['order'] + 1
+                              if _FIRST_RUNS else 0)
+            _FIRST_RUNS.append(entry)
+        if after:
+            monitor.set_gauge('memviz/hwm_in_use_bytes',
+                              after['peak_bytes_in_use'])
+            monitor.set_gauge('memviz/hwm_reserved_bytes',
+                              after['peak_bytes_reserved'])
+        return entry
+    except Exception:
+        return None
+
+
+def high_water():
+    """{'first_runs': every new executable's first run in run order
+    (program, segment, the allocator's marks before and after),
+    'raised_by': per mark, the (program, segment) whose first run
+    last raised it and the bytes it left it at}: which executable set
+    ``peak_bytes_in_use`` and which ``peak_bytes_reserved``.  A mark
+    is raised while one of the process's own programs runs for the
+    first time, or by what runs outside them (a caller's own jit, a
+    batch put on the device): a ``before`` above the previous entry's
+    ``after``, filed with ``program`` None.  What a steady step raises
+    (nothing, where every program ran once) is not seen."""
+    with _lock:
+        runs = [dict(r) for r in _FIRST_RUNS]
+    raised = {}
+    last = None
+    for r in runs:
+        if not (r['before'] and r['after']):
+            continue
+        for mark in _MARKS:
+            if last is not None and r['before'][mark] > last[mark]:
+                # between two first runs: none of the program's own
+                # executables is new there, so what the caller ran
+                raised[mark] = {
+                    'program': None, 'order': r['order'],
+                    'segment': 'outside the program\'s executables, '
+                    'before %s/%s' % (r['program'], r['segment']),
+                    'bytes': r['before'][mark]}
+            if r['after'][mark] > r['before'][mark]:
+                raised[mark] = {'program': r['program'],
+                                'segment': r['segment'],
+                                'order': r['order'],
+                                'bytes': r['after'][mark]}
+        last = r['after']
+    return {'first_runs': runs, 'raised_by': raised}
+
+
 def report(limit=32):
     """Attribution rows for /statusz, largest peak first: the top-K
     table that replaces the four scalars."""
     with _lock:
         rows = [dict(r) for r in _SEGMENTS.values()]
+        runs = {(r['program'], r['segment']): r for r in _FIRST_RUNS}
+    for r in rows:
+        # the allocator's marks around the executable's first run and
+        # its place in the run order (``high_water()`` has the list)
+        first = runs.get((r['program'], r['segment']))
+        if first is not None:
+            r['first_run'] = dict(first)
     rows.sort(key=lambda r: -r['peak_bytes'])
     return rows[:limit]
 

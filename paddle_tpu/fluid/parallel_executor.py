@@ -20,7 +20,8 @@ from . import memviz as _memviz
 from . import monitor
 from . import trace as _trace
 from .executor import (_SegmentBinder, FetchHandle, _make_segment_fn,
-                       _lowering_args, _lowering_flag_items)
+                       _lowering_args, _lowering_flag_items,
+                       _segment_label)
 from .flags import get_flag
 
 
@@ -539,7 +540,10 @@ def _dispatch_noting(executor, seg, compiled, step, state, data, feed,
         scope, fetched, first_run, comms_key=seg.comms_key,
         describe_args=describe_args)
     if first_run:
-        compile_cache.plane().note_lazy(seg.comms_key, compiled, noted)
+        compile_cache.plane().note_lazy(
+            seg.comms_key, compiled, noted,
+            label=(_memviz.current_program() or 'unlabeled',
+                   _segment_label(seg, seg.comms_key)))
 
 
 def run_collective(executor, program, feed, fetch_names, scope,

@@ -742,13 +742,21 @@ def _collective_cost(ins, shapes, attrs):
                 ', '.join(_plain(shapes.get(n, '')) for n in names))
 
 
+def _base_opcode(opcode):
+    """An opcode without the ``-start`` / ``-done`` of its
+    asynchronous forms."""
+    for suffix in ('-start', '-done'):
+        if opcode.endswith(suffix):
+            return opcode[:-len(suffix)]
+    return opcode
+
+
 def _instruction_cost(ins, shapes, called):
     """The Cost of one instruction a trace can name, by the rule above,
     or None; ``shapes`` are those of its computation's instructions,
     ``called`` the body of the computation it calls, if any."""
     opcode = ins.opcode
-    base = opcode[:-6] if opcode.endswith('-start') else \
-        opcode[:-5] if opcode.endswith('-done') else opcode
+    base = _base_opcode(opcode)
     if base in _COLLECTIVES:
         return None if opcode.endswith('-done') else \
             _collective_cost(ins, shapes, ins.attrs)
@@ -777,13 +785,458 @@ def _instruction_cost(ins, shapes, called):
                 nbytes, dtype, None, text)
 
 
-def _tables(hlo_text, op_types=None):
+# ------------------------------------------------- HLO live temporaries
+# The compiler says how much an executable holds beside its arguments
+# (``memory_analysis().temp_size_in_bytes``) and not what: the TPU
+# executable keeps no buffer assignment.  Its text is SCHEDULED
+# (``is_scheduled=true``: the order of a computation's instructions is
+# the order they run in) and its header carries ``input_output_alias``,
+# so the same parse walks the entry computation once more and says
+# which buffers are alive where their sum is largest.  The rule:
+#
+# - a buffer is born at the instruction that defines it, at
+#   ``_nbytes(shape)``: main memory only, an ``S(1)`` result counts
+#   nothing, as in the cost table; it dies after the last instruction
+#   that reads it or an alias of it.  A tuple's leaves are buffers of
+#   their own.
+# - these define nothing and ALIAS an operand: ``bitcast``,
+#   ``get-tuple-element``, ``tuple``, ``opt-barrier``, ``copy-done`` and
+#   the other ``-done``s (the part of their ``-start``'s tuple that is
+#   the result), the operand part of a ``copy-start`` / ``slice-start`` /
+#   ``all-gather-start`` / ``async-start`` tuple, a
+#   ``dynamic-update-slice``, a fusion whose root (or a leaf of its root
+#   tuple) is a ``dynamic-update-slice`` of one of its parameters, a
+#   fusion or custom call that says so (``output_to_operand_aliasing``),
+#   and a ``while`` (its carried tuple).  An alias reads nothing: only
+#   what reads IT keeps the buffer alive.
+# - entry parameters are arguments and constants are the executable's:
+#   neither is a temporary.  An operand of the root is live-out: an
+#   output (a donated parameter's memory by the header's
+#   ``input_output_alias``, else counted in ``output_size_in_bytes``),
+#   no temporary either way; so the header need not be read.  Nothing
+#   but an output is written in place into an argument: any other
+#   in-place update of one comes out as a buffer of its own.
+# - ``while`` / ``conditional`` / ``call`` are walked INTO: the peak of
+#   the body (the larger of body and condition, the largest branch)
+#   stands at the call, over what is alive around it.  Inside, the
+#   parameter is the caller's memory, and so is an operand of the root:
+#   a ``while`` writes its carried tuple in place, and a branch writes
+#   the call's result.
+# - a buffer's fluid op is its defining instruction's
+#   (``instruction_scopes``' rule); a copy the compiler put in
+#   (``copy``, ``copy-start``, ``slice-start`` with no ``op_name``)
+#   takes that of what it copies, and a buffer it allocates for a loop
+#   to fill (``AllocateBuffer``: a scan's stacked residuals) that of
+#   its nearest reader; a reader that is such a copy is looked
+#   through the same way.  Its class:
+#   ``residual``: defined under a forward op, last read under a
+#   ``_grad`` one (kept for the backward pass); ``gradient``: defined
+#   under a ``_grad`` op, last read by an optimizer op or a collective;
+#   ``optimizer``: defined under an optimizer op; ``working``: born
+#   and dead on one side; ``unscoped``: no fluid op.
+#
+# What the walk cannot see: the compiler's packing (alignment, a buffer
+# reused by an elementwise result of the instruction that frees it),
+# what a custom call allocates for itself, and how the chip's compiler
+# holds a loop's carried state.  ``temp_bytes`` less the walk's sum
+# says how far off it is: 0.4 to 1.5% in the BERT, ResNet and OLMoE
+# steps compiled for a described v5e (two thirds of it the ``S(1)``
+# buffers alive at the peak, which the compiler's figure counts and
+# the table gives apart as ``elsewhere_bytes``), 3 to 25% where the
+# step holds loops (PR 52; a scan whose carry a fusion rewrites read
+# 23% short, one whose trips only stack read 0.1%:
+# ``carried_anew_bytes`` is what the bodies compute anew for their
+# next trip).
+_HLO_ENTRY = re.compile(r'^ENTRY\s+%?([^\s(]+)', re.M)
+_HLO_CALLED = re.compile(
+    r'\b(condition|body|to_apply|true_computation|false_computation)'
+    r'=%?([^\s,}]+)')
+_HLO_BRANCHES = re.compile(r'\bbranch_computations=\{([^}]*)\}')
+_HLO_OUTPUT_ALIASING = re.compile(r'\boutput_to_operand_aliasing=\{(.*?)\)\}')
+_HLO_ALIAS_PAIR = re.compile(r'\{([\d, ]*)\}:\s*\((\d+),\s*\{([\d, ]*)\}')
+_HLO_TUPLE_INDEX = re.compile(r'\bindex=(\d+)')
+_HLO_COMMENT = re.compile(r'/\*.*?\*/')
+_PASS_THROUGH = frozenset(['bitcast', 'opt-barrier', 'add-dependency',
+                           'while', 'async-update', _UPDATED])
+# the compiler's own copies: they carry no op_name of the program's
+_COPIES = frozenset(['copy', 'copy-start', 'slice-start'])
+# the compiler's allocation of what a loop fills trip by trip (the
+# stacked residuals of a scan): it takes the op of its nearest reader
+_ALLOCATE = 'custom_call_target="AllocateBuffer"'
+_CALLER = object()      # a value that is the caller's (or an argument)
+_ARGUMENT = object()    # an entry parameter: never written in place
+
+
+@functools.lru_cache(maxsize=8192)
+def _shape_tree(shape):
+    """A shape's text -> a leaf's text, or a list of trees for a
+    tuple."""
+    shape = _HLO_COMMENT.sub('', shape).strip()
+    if not shape.startswith('('):
+        return shape
+    parts, depth, layout, start = [], 0, 0, 1
+    for i, c in enumerate(shape):
+        if c in '{[':
+            layout += 1
+        elif c in '}]':
+            layout -= 1
+        elif layout:
+            continue
+        elif c == '(':
+            depth += 1
+        elif c == ')':
+            depth -= 1
+            if depth == 0:
+                parts.append(shape[start:i])
+        elif c == ',' and depth == 1:
+            parts.append(shape[start:i])
+            start = i + 1
+    return [_shape_tree(p.strip()) for p in parts if p.strip()]
+
+
+def _leaves(value):
+    """The buffers of a value (a buffer, None, ``_CALLER`` or a nest of
+    lists of them)."""
+    if isinstance(value, list):
+        for v in value:
+            for leaf in _leaves(v):
+                yield leaf
+    elif isinstance(value, _Buffer):
+        yield value
+
+
+def _element(value, index):
+    if isinstance(value, list):
+        return value[index] if index < len(value) else None
+    return value            # the caller's, or nothing: so are its parts
+
+
+class _Buffer(object):
+    __slots__ = ('bytes', 'elsewhere', 'shape', 'ins', 'scope', 'born',
+                 'last', 'reader', 'out')
+
+    def __init__(self, nbytes, elsewhere, shape, ins, scope, born):
+        self.bytes, self.elsewhere = nbytes, elsewhere
+        self.shape, self.ins, self.scope, self.born = shape, ins, scope, born
+        self.last, self.reader, self.out = born, None, False
+
+
+def _in_place_outputs(called):
+    """{leaf index of a fusion's result (None: the whole of it):
+    parameter number} for the leaves its computation writes in place: a
+    ``dynamic-update-slice`` of a parameter, looked at through
+    bitcasts."""
+    by_name = {ins.name: ins for ins in called}
+    roots = [ins for ins in called if ins.root] or called[-1:]
+    if not roots:
+        return {}
+
+    def source(name, want):
+        ins = by_name.get(name)
+        while ins is not None and ins.opcode == 'bitcast':
+            ins = by_name.get((_operand_names(ins) or [None])[0])
+        return ins if ins is not None and ins.opcode == want else None
+
+    def parameter(name):
+        updated = source(name, _UPDATED)
+        if updated is None:
+            return None
+        param = source((_operand_names(updated) or [None])[0], 'parameter')
+        return int(param.operands) if param is not None and \
+            param.operands.strip().isdigit() else None
+
+    root = roots[0]
+    outputs = dict(enumerate(_operand_names(root))) \
+        if root.opcode == 'tuple' else {None: root.name}
+    found = {leaf: parameter(name) for leaf, name in outputs.items()}
+    return {leaf: n for leaf, n in found.items() if n is not None}
+
+
+class _LiveWalk(object):
+    """The walk of one module by the rule above."""
+
+    def __init__(self, computations, scopes, entry):
+        self.computations = computations
+        self.entry = entry
+        self.scopes = scopes
+        self.optimizers = _optimizer_types()
+        self.walking = None     # the computation value_of is asked in
+        self.walked = {}        # computation -> (peak, point, [buffers])
+        self.every = []         # every buffer the walk defined
+        self.carried_anew = 0   # bytes the loops' bodies compute anew
+        self.users = collections.defaultdict(list)  # name -> [readers]
+
+    def define(self, tree, ins, index):
+        if isinstance(tree, list):
+            return [self.define(t, ins, index) for t in tree]
+        everywhere = _nbytes(tree, True)
+        if not everywhere:
+            return None
+        nbytes = _nbytes(tree)
+        buf = _Buffer(nbytes, everywhere - nbytes, _plain(tree), ins.name,
+                      self.scopes.get(ins.name), index)
+        self.every.append(buf)
+        return buf
+
+    def called(self, ins):
+        """[(the computation a control-flow instruction runs, whether
+        as a loop's body)]."""
+        names = [(m.group(2), m.group(1) == 'body')
+                 for m in _HLO_CALLED.finditer(ins.attrs)]
+        branches = _HLO_BRANCHES.search(ins.attrs)
+        if branches:
+            names += [(n.strip().lstrip('%'), False)
+                      for n in branches.group(1).split(',') if n.strip()]
+        return [(n, loop) for n, loop in names if n in self.computations]
+
+    def value_of(self, ins, index, values):
+        """What instruction ``ins`` gives: buffers it defines, or the
+        operands' it passes on."""
+        opcode = ins.opcode
+        names = _operand_names(ins)
+        operands = [values.get(n) for n in names]
+        first = operands[0] if operands else None
+        tree = _shape_tree(ins.shape)
+        if opcode == 'parameter':
+            return _ARGUMENT if self.walking == self.entry else _CALLER
+        if opcode == 'constant':
+            return None
+        if opcode == 'tuple':
+            return operands
+        if opcode == 'get-tuple-element':
+            m = _HLO_TUPLE_INDEX.search(ins.attrs)
+            return _element(first, int(m.group(1))) if m else first
+        if opcode in _PASS_THROUGH and not (
+                opcode == _UPDATED and first is _ARGUMENT):
+            return first
+        if opcode.endswith('-done'):
+            if opcode == 'copy-done':
+                return _element(first, 0)
+            if opcode == 'all-reduce-done':
+                return first
+            return _element(first, 1)
+        if opcode.endswith('-start') and isinstance(tree, list) and \
+                opcode != 'all-reduce-start':
+            if opcode == 'copy-start':          # (copy, operand, context)
+                return [self.define(tree[0], ins, index), first] + \
+                    [None] * (len(tree) - 2)
+            return [operands if isinstance(tree[0], list) else first] + \
+                self.define(tree[1:], ins, index)   # (operands, result, ..)
+        made = self.define(tree, ins, index)
+        aliased = {}
+        if opcode == 'fusion':
+            aliased = _in_place_outputs(
+                self.computations.get(ins.calls) or [])
+        m = _HLO_OUTPUT_ALIASING.search(ins.attrs)
+        if m:
+            for out, operand, _inner in _HLO_ALIAS_PAIR.findall(m.group(0)):
+                path = [int(i) for i in out.replace(' ', '').split(',') if i]
+                aliased[path[0] if path else None] = int(operand)
+        for leaf, n in aliased.items():
+            if n >= len(operands) or operands[n] is _ARGUMENT:
+                continue
+            if leaf is None:
+                self.forget(made)
+                made = operands[n]
+            elif isinstance(made, list) and leaf < len(made):
+                self.forget(made[leaf])
+                made[leaf] = operands[n]
+        return made
+
+    def copied_scope(self, ins, by_name):
+        """The fluid op of what a compiler's copy copies: that of the
+        nearest instruction up its first operands that has one."""
+        for _ in range(16):
+            ins = by_name.get((_operand_names(ins) or [None])[0])
+            if ins is None:
+                return None
+            if self.scopes.get(ins.name):
+                return self.scopes[ins.name]
+        return None
+
+    def forget(self, value):
+        for buf in _leaves(value):
+            buf.bytes = buf.elsewhere = 0
+
+    def walk(self, name, loop=False):
+        """(peak bytes, the instruction it stands at, the buffers alive
+        there, the bytes alive there in another memory space) of one
+        computation, what it calls included; ``loop``: it is a
+        ``while``'s body."""
+        if name in self.walked:
+            return self.walked[name]
+        self.walked[name] = (0, None, [], 0)    # a cycle is not walked
+        body = self.computations[name]
+        self.walking = name
+        by_name = {ins.name: ins for ins in body}
+        values, local, inner = {}, [], {}
+        for index, ins in enumerate(body):
+            start = len(self.every)
+            values[ins.name] = self.value_of(ins, index, values)
+            for operand in _operand_names(ins):
+                self.users[operand].append(ins)
+            for buf in self.every[start:]:
+                local.append(buf)
+                if buf.scope is None and ins.opcode in _COPIES:
+                    buf.scope = self.copied_scope(ins, by_name)
+            if ins.opcode in ('tuple', 'get-tuple-element', 'bitcast',
+                              'parameter', 'constant'):
+                if not ins.root:
+                    continue                    # an alias reads nothing
+            live_out = ins.root and ins.opcode == 'tuple'
+            for operand in _operand_names(ins):
+                for buf in _leaves(values.get(operand)):
+                    if live_out:
+                        buf.out = True
+                    else:
+                        buf.last, buf.reader = index, ins
+            if ins.root:
+                for buf in _leaves(values[ins.name]):
+                    buf.out = True
+            callees = self.called(ins) if ins.opcode in (
+                'while', 'conditional', 'call') else ()
+            if callees:
+                inner[index] = max((self.walk(c, is_body) for c, is_body
+                                    in callees), key=lambda got: got[0])
+                self.walking = name
+        for buf in local:
+            made_by = by_name[buf.ins]
+            if buf.scope is None and made_by.opcode == 'custom-call' and \
+                    _ALLOCATE in made_by.attrs:
+                buf.scope = self.read_scope(made_by)
+        if loop:
+            # what the body computes anew for the next trip: by the
+            # rule the carried buffer's memory, so no temporary; kept
+            # apart because it is the first suspect where a program
+            # with loops holds more than the walk finds
+            self.carried_anew += sum(
+                b.bytes for b in local if b.out and
+                not by_name[b.ins].opcode.endswith('-start'))
+        born, dying = {}, {}
+        for buf in local:
+            if buf.out or not (buf.bytes or buf.elsewhere):
+                continue
+            born.setdefault(buf.born, []).append(buf)
+            dying.setdefault(buf.last, []).append(buf)
+        live = peak = 0
+        at = None
+        for index in range(len(body)):
+            live += sum(b.bytes for b in born.get(index, ()))
+            here = live + (inner[index][0] if index in inner else 0)
+            if here > peak:
+                peak, at = here, index
+            live -= sum(b.bytes for b in dying.get(index, ()))
+        if at is None:
+            return self.walked[name]
+        there = [b for b in local if not b.out and b.born <= at <= b.last]
+        alive = [b for b in there if b.bytes]
+        elsewhere = sum(b.elsewhere for b in there)
+        point = body[at].name
+        if at in inner and inner[at][1]:
+            alive += inner[at][2]
+            elsewhere += inner[at][3]
+            point = '%s > %s' % (point, inner[at][1])
+        self.walked[name] = (peak, point, alive, elsewhere)
+        return self.walked[name]
+
+    def read_scope(self, reader):
+        """The fluid op that reads through ``reader``: its own, or
+        where it has none (a copy the compiler put in, an alias) that
+        of the nearest reader of its result."""
+        queue, seen = collections.deque([reader]), {reader.name}
+        while queue and len(seen) < 64:
+            ins = queue.popleft()
+            if self.scopes.get(ins.name):
+                return self.scopes[ins.name]
+            for user in self.users.get(ins.name, ()):
+                if user.name not in seen:
+                    seen.add(user.name)
+                    queue.append(user)
+        return None
+
+    def kind(self, buf):
+        """A buffer's class, by the rule above."""
+        if not buf.scope:
+            return 'unscoped'
+        op = buf.scope.split('/')[0]
+        if op in self.optimizers:
+            return 'optimizer'
+        backward = op.endswith('_grad')
+        reader = buf.reader
+        read_by = self.read_scope(reader) if reader else None
+        read_op = read_by.split('/')[0] if read_by else ''
+        if not backward and read_op.endswith('_grad'):
+            return 'residual'
+        if backward and reader is not None:
+            if read_op in self.optimizers or read_op.startswith('c_') or \
+                    _base_opcode(reader.opcode) in _COLLECTIVES:
+                return 'gradient'
+        return 'working'
+
+
+def _optimizer_types():
+    """The registered op types that update a parameter: those of
+    ``ops/optimizer_ops.py``."""
+    from ..ops import registry
+    return frozenset(
+        t for t, d in registry._REGISTRY.items()
+        if getattr(getattr(d, 'fn', None), '__module__', '').endswith(
+            'optimizer_ops'))
+
+
+def _live_table(hlo_text, computations, scopes, every=False):
+    """The live table of one parsed module: where the sum of its live
+    temporaries is largest and what is alive there.  ``every``: also
+    every buffer the walk defined, under 'every' (tests)."""
+    m = _HLO_ENTRY.search(hlo_text)
+    if m is None or m.group(1) not in computations or \
+            'is_scheduled=true' not in hlo_text[:4096]:
+        return None     # no entry, or its order is not the schedule
+    walk = _LiveWalk(computations, scopes, m.group(1))
+    peak, point, alive, elsewhere = walk.walk(m.group(1))
+    where = (point or '').split(' > ')[-1]
+
+    def row(buf):
+        return {'bytes': buf.bytes, 'shape': buf.shape,
+                'instruction': buf.ins, 'op': buf.scope,
+                'class': walk.kind(buf)}
+
+    buffers = sorted((row(b) for b in alive),
+                     key=lambda r: (-r['bytes'], r['instruction']))
+    by_class, by_op = collections.Counter(), collections.Counter()
+    for r in buffers:
+        by_class[r['class']] += r['bytes']
+        by_op[r['op']] += r['bytes']
+    table = {'bytes': peak, 'point': point, 'op': scopes.get(where),
+             'by_class': dict(by_class), 'by_op': dict(by_op),
+             'buffers': buffers,
+             # alive at that point in another memory space (``S(1)``),
+             # and what the loops' bodies compute anew for their next
+             # trip: neither is in ``bytes``, both are where to look
+             # when the compiler's figure is larger
+             'elsewhere_bytes': elsewhere,
+             'carried_anew_bytes': walk.carried_anew}
+    if every:
+        names = {name: [i.name for i in body]
+                 for name, body in computations.items()}
+        owner = {ins.name: comp for comp, body in computations.items()
+                 for ins in body}
+        table['every'] = [
+            dict(row(b), born=b.ins, out=b.out,
+                 dies=names[owner[b.ins]][b.last]) for b in walk.every
+            if b.bytes]
+    return table
+
+
+def _tables(hlo_text, op_types=None, every_buffer=False):
     """One compiled module's optimised HLO text -> (module name, scope
-    table, cost table, loop table) from ONE parse; the scope and cost
-    tables hold every instruction a trace can name (those of fused
-    computations are left out, their fusion stands for them), so
-    ``pick_table`` picks the same program in both; the loop table
-    holds those inside a differentiable loop's body (``loop_side``)."""
+    table, cost table, loop table, live table) from ONE parse; the
+    scope and cost tables hold every instruction a trace can name
+    (those of fused computations are left out, their fusion stands for
+    them), so ``pick_table`` picks the same program in both; the loop
+    table holds those inside a differentiable loop's body
+    (``loop_side``); the live table (``_live_table``) is the module's
+    temporaries at their peak."""
     op_types = op_types or _registered_op_types()
     module, computations = _parse_hlo(hlo_text)
     fused = {ins.calls for body in computations.values() for ins in body
@@ -804,7 +1257,8 @@ def _tables(hlo_text, op_types=None):
             if side:
                 loops[ins.name] = side
             costs[ins.name] = _instruction_cost(ins, shapes, called)
-    return module, scopes, costs, loops
+    return module, scopes, costs, loops, _live_table(
+        hlo_text, computations, scopes, every_buffer)
 
 
 def hlo_scopes(hlo_text, op_types=None):
@@ -818,19 +1272,31 @@ def hlo_scopes(hlo_text, op_types=None):
 def hlo_costs(hlo_text):
     """The same text -> (module name, {instruction name: Cost or None})
     for the same instructions, by the cost rule above."""
-    module, _scopes, costs, _loops = _tables(hlo_text)
+    module, _scopes, costs = _tables(hlo_text)[:3]
     return module, costs
 
 
+def hlo_live(hlo_text, every=False):
+    """The same text -> (module name, the live table: the module's
+    temporaries where their sum is largest, by the rule above;
+    ``every``: with every buffer the walk defined, where it is born
+    and where it dies)."""
+    built = _tables(hlo_text, every_buffer=every)
+    return built[0], built[4]
+
+
 def _held_tables():
-    """[(module name, scope table, cost table, loop table)] of every
-    executable this process holds.  The compile plane keeps what
+    """[(module name, scope table, cost table, loop table, live
+    table)] of every executable this process holds.  The compile plane keeps what
     ``_tables`` made of an executable while it holds it: each is
     printed and parsed once, whichever table is asked for first and
     however often."""
+    return [built for _key, built in _keyed_tables()]
+
+
+def _keyed_tables():
     from . import compile_cache
-    return [built for _key, built in
-            compile_cache.plane().held_tables(_tables)]
+    return compile_cache.plane().held_tables(_tables)
 
 
 def scope_tables():
@@ -841,7 +1307,7 @@ def scope_tables():
     BERT-base.  Two programs of one name (a segment planned for two
     fetch lists) keep a table each; ``pick_table`` tells them apart."""
     tables = {}
-    for module, scopes, _costs, _loops in _held_tables():
+    for module, scopes, _costs, _loops, _live in _held_tables():
         tables.setdefault(module, []).append(scopes)
     return tables
 
@@ -850,7 +1316,7 @@ def cost_tables():
     """{HLO module name: [table, ...]} (tables as ``hlo_costs`` gives
     them), beside ``scope_tables()`` and from the same parse."""
     tables = {}
-    for module, _scopes, costs, _loops in _held_tables():
+    for module, _scopes, costs, _loops, _live in _held_tables():
         tables.setdefault(module, []).append(costs)
     return tables
 
@@ -862,9 +1328,21 @@ def loop_tables():
     loops (``loop_side``); a module without one has an empty
     table."""
     tables = {}
-    for module, _scopes, _costs, loops in _held_tables():
+    for module, _scopes, _costs, loops, _live in _held_tables():
         tables.setdefault(module, []).append(loops)
     return tables
+
+
+def live_tables():
+    """{the compile plane's key of the executable: (HLO module name,
+    table)} beside ``scope_tables()`` and from the same parse: each
+    program's temporaries where their sum is largest (``hlo_live``:
+    the point, the sum, and every buffer alive there with its bytes,
+    shape, defining instruction, fluid op and class); the table is
+    None for a module whose text names no entry computation or is not
+    scheduled.  By executable and not by module name, because
+    fluid.memviz's rows, which carry it, are filed by executable."""
+    return {key: (built[0], built[4]) for key, built in _keyed_tables()}
 
 
 def pick_table(candidates, instruction_names):

@@ -405,7 +405,10 @@ def test_statusz_memory_table_names_contributors():
     assert mem['live'] is not None and 'classes' in mem['live']
 
 
-def test_stat_summary_memory_rollup(tmp_path, capsys):
+def test_stat_summary_memory_rollup(tmp_path, capsys, monkeypatch):
+    monkeypatch.setattr(memviz, 'device_marks', lambda: {
+        'peak_bytes_in_use': 3.0 * (1 << 30),
+        'peak_bytes_reserved': 5.0 * (1 << 20)})
     main_p, startup, loss = _build_mlp()
     fluid.set_flags({'FLAGS_memviz': True})
     _run_steps(main_p, startup, loss, fluid.Scope())
@@ -422,3 +425,192 @@ def test_stat_summary_memory_rollup(tmp_path, capsys):
     out = capsys.readouterr().out
     assert 'live HBM' in out
     assert 'param' in out
+    # the allocator's marks as a new executable's first run left them
+    assert 'allocator marks      3.00GiB in use, 5.0MiB reserved' in out
+
+
+# ------------------------------------------- temp_peak / high-water (PR 52)
+def _fake_marks(monkeypatch, readings):
+    """Stand in for the allocator's marks (the CPU backend reports
+    none): each read takes the next of ``readings``."""
+    it = iter(readings)
+    monkeypatch.setattr(
+        memviz, 'device_marks',
+        lambda: dict(zip(memviz._MARKS, next(it))))
+
+
+@pytest.mark.parametrize('path', ['aot', 'lazy'])
+def test_build_tables_gives_the_row_its_temp_peak(path):
+    main_p, startup, loss = _build_mlp(width=64)
+    _run_steps(main_p, startup, loss, fluid.Scope(), warm=path == 'aot',
+               width=64, batch=32)
+    label = main_p._memviz_label
+    before = [r for r in memviz.report() if r['program'] == label]
+    # the AOT plane files its row at compile time, without the table;
+    # a lazily jitted program has no row until the tables are built
+    assert all('temp_peak' not in r for r in before)
+    assert bool(before) == (path == 'aot')
+    memviz.build_tables()
+    rows = [r for r in memviz.report() if r['program'] == label]
+    assert len(rows) == 1 and 'estimated' not in rows[0]
+    r = rows[0]
+    peak = r['temp_peak']
+    assert set(peak) == {'bytes', 'point', 'op', 'by_class', 'by_op',
+                         'buffers', 'top_buffers', 'elsewhere_bytes',
+                         'carried_anew_bytes'}
+    assert 0 < peak['bytes'] <= 2 * r['temp_bytes'] + 4096
+    assert sum(peak['by_class'].values()) == peak['bytes']
+    assert sum(peak['by_op'].values()) == peak['bytes']
+    assert 0 < len(peak['top_buffers']) <= memviz.TOP_BUFFERS
+    assert peak['top_buffers'][0]['bytes'] == max(
+        b['bytes'] for b in peak['top_buffers'])
+    assert {'shape', 'instruction', 'op', 'class'} <= set(
+        peak['top_buffers'][0])
+    # what the row had, it keeps: the classes still sum to the arena
+    assert sum(r['classes'].values()) + r['arg_overhead_bytes'] == \
+        pytest.approx(r['argument_bytes'])
+    assert r['classes']['param'] > 0 and r['classes']['feed'] > 0
+    assert r['alias_bytes'] <= r['output_bytes']
+    # /statusz shows it
+    shown = [a for a in health.statusz()['memory']['attribution']
+             if a['program'] == label]
+    assert shown and shown[0]['temp_peak']['point'] == peak['point']
+    # built once: a second call parses nothing again and changes nothing
+    memviz.build_tables()
+    again = [r for r in memviz.report() if r['program'] == label]
+    assert again[0]['temp_peak'] == peak
+
+
+def test_the_mesh_row_loses_estimated_once_tables_are_built():
+    from paddle_tpu.fluid.compiler import CompiledProgram
+    main_p, startup, loss = _build_mlp(width=64)
+    feed = {'x': np.ones((32, 64), 'float32')}
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        cp = CompiledProgram(main_p).with_data_parallel(
+            loss_name=loss.name)
+        exe.run(cp, feed=feed, fetch_list=[loss])
+        label = main_p._memviz_label
+        first = [r for r in memviz.report() if r['program'] == label]
+        # the first-run bound the headroom gate reads
+        assert len(first) == 1 and first[0]['estimated'] is True
+        assert first[0]['temp_bytes'] == 0.0
+        memviz.build_tables()
+    rows = [r for r in memviz.report() if r['program'] == label]
+    assert len(rows) == 1 and rows[0]['segment'] == first[0]['segment']
+    r = rows[0]
+    assert 'estimated' not in r
+    assert r['temp_bytes'] > 0 and r['generated_code_bytes'] >= 0
+    assert r['temp_peak']['bytes'] > 0
+    # one device's shard of the batch, as memory_analysis() counts
+    import jax
+    # (and the learning rate's four bytes)
+    assert r['classes']['feed'] == pytest.approx(
+        32 * 64 * 4 / jax.device_count(), abs=16)
+    assert r['classes']['param'] > 0
+    shown = [a for a in health.statusz()['memory']['attribution']
+             if a['program'] == label]
+    assert 'estimated' not in shown[0] and 'temp_peak' in shown[0]
+    with memviz.program_scope(label):
+        assert memviz.peak_bytes(label) == r['peak_bytes']
+
+
+def test_the_high_water_list_is_in_run_order(monkeypatch):
+    """Start-up program, the for_test clone, the step that fetches, the
+    quiet step: each new executable's first run is filed once, with
+    the marks before and after it, and a steady step reads nothing."""
+    gb = 1e9
+    _fake_marks(monkeypatch, [
+        (0.0, 0.0), (1 * gb, 0.0),              # start-up
+        (1 * gb, 0.0), (3 * gb, 2 * gb),        # the clone
+        (3 * gb, 2 * gb), (3 * gb, 6 * gb),     # the step that fetches
+        (3 * gb, 6 * gb), (3 * gb, 6 * gb)])    # the quiet step
+    main_p, startup, loss = _build_mlp()
+    test_p = main_p.clone(for_test=True)
+    feed = {'x': np.ones((8, 16), 'float32')}
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        exe.run(test_p, feed=feed, fetch_list=[loss])
+        exe.run(main_p, feed=feed, fetch_list=[loss])
+        exe.run(main_p, feed=feed, fetch_list=[])
+        for _ in range(3):          # steady: the marks are not read
+            exe.run(main_p, feed=feed, fetch_list=[loss])
+            exe.run(main_p, feed=feed, fetch_list=[])
+    water = memviz.high_water()
+    runs = water['first_runs']
+    assert [r['order'] for r in runs] == [0, 1, 2, 3]
+    assert [r['program'] for r in runs] == [
+        startup._memviz_label, test_p._memviz_label,
+        main_p._memviz_label, main_p._memviz_label]
+    # the same ops planned for another fetch list are another executable
+    assert runs[2]['segment'] != runs[3]['segment']
+    assert runs[1]['before'] == runs[0]['after']
+    assert runs[3]['after'] == {'peak_bytes_in_use': 3 * gb,
+                                'peak_bytes_reserved': 6 * gb}
+    raised = water['raised_by']
+    assert (raised['peak_bytes_in_use']['program'],
+            raised['peak_bytes_in_use']['order'],
+            raised['peak_bytes_in_use']['bytes']) == (
+                test_p._memviz_label, 1, 3 * gb)
+    assert (raised['peak_bytes_reserved']['program'],
+            raised['peak_bytes_reserved']['segment']) == (
+                main_p._memviz_label, runs[2]['segment'])
+    assert monitor.gauge_value('memviz/hwm_in_use_bytes') == 3 * gb
+    assert monitor.gauge_value('memviz/hwm_reserved_bytes') == 6 * gb
+    # the rows carry their place in the list, and /statusz the list
+    memviz.build_tables()
+    orders = {r['segment']: r['first_run']['order']
+              for r in memviz.report()
+              if r['program'] == main_p._memviz_label}
+    assert sorted(orders.values()) == [2, 3]
+    shown = health.statusz()['memory']['high_water']
+    assert [r['order'] for r in shown['first_runs']] == [0, 1, 2, 3]
+    assert shown['raised_by']['peak_bytes_reserved']['order'] == 2
+
+
+def test_a_rise_between_first_runs_is_the_callers(monkeypatch):
+    """What raises a mark between two first runs is none of the
+    program's executables (a caller's own jit, a batch put on the
+    device): it is filed with no program, before the run it precedes."""
+    _fake_marks(monkeypatch, [(0.0, 0.0), (1.0, 0.0),
+                              (4.0, 0.0), (4.0, 7.0)])
+    main_p, startup, loss = _build_mlp()
+    _run_steps(main_p, startup, loss, fluid.Scope(), steps=1, warm=False)
+    raised = memviz.high_water()['raised_by']
+    assert raised['peak_bytes_in_use']['program'] is None
+    assert raised['peak_bytes_in_use']['bytes'] == 4.0
+    assert raised['peak_bytes_in_use']['segment'].startswith(
+        "outside the program's executables, before %s/"
+        % main_p._memviz_label)
+    assert raised['peak_bytes_reserved']['program'] == main_p._memviz_label
+
+
+def test_the_aot_planes_new_entry_files_its_first_run(monkeypatch):
+    _fake_marks(monkeypatch, [(0.0, 0.0), (1.0, 0.0), (1.0, 0.0),
+                              (2.0, 5.0)] + [(9.0, 9.0)] * 8)
+    main_p, startup, loss = _build_mlp()
+    _run_steps(main_p, startup, loss, fluid.Scope(), steps=3)
+    runs = memviz.high_water()['first_runs']
+    # start-up (a lazy first run), then the warmed step's ONE new entry
+    assert [r['program'] for r in runs] == [
+        startup._memviz_label, main_p._memviz_label]
+    rows = [r for r in memviz.report()
+            if r['program'] == main_p._memviz_label]
+    assert rows[0]['segment'] == runs[1]['segment']
+    assert rows[0]['first_run']['after'] == {
+        'peak_bytes_in_use': 2.0, 'peak_bytes_reserved': 5.0}
+
+
+def test_no_marks_where_the_backend_reports_none():
+    """The CPU backend's ``memory_stats()`` is None: the list still
+    holds the run order, with no marks and nobody raising any."""
+    main_p, startup, loss = _build_mlp()
+    _run_steps(main_p, startup, loss, fluid.Scope(), warm=False)
+    water = memviz.high_water()
+    assert len(water['first_runs']) == 2
+    assert all(r['before'] is None and r['after'] is None
+               for r in water['first_runs'])
+    assert water['raised_by'] == {}
+    assert monitor.gauge_value('memviz/hwm_in_use_bytes', None) is None

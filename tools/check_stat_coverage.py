@@ -198,6 +198,12 @@ REQUIRED = [
     ('paddle_tpu/fluid/memviz.py', 'memviz/oom_dumps'),
     ('paddle_tpu/fluid/memviz.py', 'memviz/analysis_unavailable'),
     ('paddle_tpu/fluid/memviz.py', 'memviz/samples'),
+    # the allocator's high-water marks as last read around a new
+    # executable's first run (memviz.high_water() names who raised
+    # each), shown by `stat_summary.py --memory`
+    ('paddle_tpu/fluid/memviz.py', 'memviz/hwm_in_use_bytes'),
+    ('paddle_tpu/fluid/memviz.py', 'memviz/hwm_reserved_bytes'),
+    ('paddle_tpu/fluid/executor.py', '_memviz.first_run_end'),
     ('paddle_tpu/fluid/executor.py', '_memviz.record_segment'),
     ('paddle_tpu/fluid/executor.py', '_memviz.maybe_sample'),
     ('paddle_tpu/fluid/executor.py', '_memviz.oom_incident'),
@@ -206,6 +212,8 @@ REQUIRED = [
     ('paddle_tpu/fluid/health.py', 'memviz.memory_pressure'),
     ('paddle_tpu/fluid/serving.py', 'register_scope_provider'),
     ('tools/stat_summary.py', 'memviz/live_bytes_total'),
+    ('tools/stat_summary.py', 'memviz/hwm_in_use_bytes'),
+    ('tools/stat_summary.py', 'memviz/hwm_reserved_bytes'),
     # auto-sharding planner (parallel/plan.py): plan build volume, the
     # priced-candidate table, the memviz HBM-gate rejections, the
     # unpriced-term honesty counter, the chosen-layout gauges, and the

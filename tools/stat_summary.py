@@ -269,6 +269,14 @@ def memory_report(rec, out=None):
     for prog, peak in peaks[:8]:
         out.write('  program %-12s peak %12s\n'
                   % (prog, _fmt_bytes(peak)))
+    in_use = g.get('memviz/hwm_in_use_bytes')
+    if in_use is not None:
+        # the allocator's own marks as last read around a new
+        # executable's first run (memviz.high_water() names who
+        # raised each); no FLAGS_memviz needed
+        out.write('  allocator marks %12s in use, %s reserved\n'
+                  % (_fmt_bytes(in_use), _fmt_bytes(
+                      g.get('memviz/hwm_reserved_bytes', 0.0))))
     for name, label in (('memviz/samples', 'census samples'),
                         ('memviz/segments_attributed',
                          'segments attributed'),

@@ -8,12 +8,24 @@ a hash of the lowered text:
 
 Run it on a copy of the parent (``git archive``) and on the tree and
 compare the two files: a PR that says an accepted cell's step is
-untouched shows equal hashes.  ``--memory`` also COMPILES each quiet
-step for the described chip (minutes a cell) and records what the
-compiler says it holds (``fluid.memviz.analysis_fields``: arguments,
-outputs, temporaries, peak, and the executable's own size, which moves
-with the kernels' bodies and not with the batch): which part of a
-``peak_hbm`` that moved is whose, before any chip time.
+untouched shows equal hashes.  ``--memory`` also COMPILES both steps
+for the described chip (under a minute each; the step that fetches
+the loss can hold gigabytes more than the quiet one: BERT's keeps a
+float32 copy of its logits) and records what
+the compiler says it holds (``fluid.memviz.analysis_fields``:
+arguments, outputs, temporaries, peak, and the executable's own size,
+which moves with the kernels' bodies and not with the batch) and,
+under ``temp_peak``, WHOSE the temporaries are where their sum is
+largest (``fluid.profiler.hlo_live``: the point, the bytes by class and
+fluid op, the ten largest buffers; a tree from before PR 52 has no such
+walk and records the fields alone).  Given two such files,
+
+    python tools/step_hlo_hash.py --compare <parent.json> <tree.json>
+
+prints per cell whether the hashes are equal and what moved: every
+field, every class and fluid op, and the buffers that are among the
+ten largest of one side only.  That is the check a kernel PR runs
+before any chip time: which part of a ``peak_hbm`` that moved is whose.
 
 A Mosaic kernel's serialized module carries the SOURCE LINES of its
 body and of every caller in the file, so one comment line above a
@@ -44,7 +56,7 @@ def main(root, out_path, only=(), memory=False):
     from jaxlib.mlir.passmanager import PassManager
     import paddle_tpu.fluid as fluid
     from benchmark import run
-    from paddle_tpu.fluid import memviz
+    from paddle_tpu.fluid import memviz, profiler
     from paddle_tpu.ops.pallas import common
     from paddle_tpu.parallel import mesh as pmesh
 
@@ -111,15 +123,96 @@ def main(root, out_path, only=(), memory=False):
             hashes[key] = [hashlib.sha256(text.encode()).hexdigest()[:16],
                            len(text), text.count('tpu_custom_call')]
             print(key, *hashes[key], flush=True)
-            if memory and kind == 'quiet':
-                hashes[key + '/memory'] = memviz.analysis_fields(
-                    lowered.compile())
-                print(key + '/memory', hashes[key + '/memory'], flush=True)
+            if memory:
+                compiled = lowered.compile()
+                fields = hashes[key + '/memory'] = \
+                    memviz.analysis_fields(compiled)
+                live = profiler.hlo_live(compiled.as_text())[1] \
+                    if hasattr(profiler, 'hlo_live') else None
+                if live is not None:
+                    fields['temp_peak'] = memviz.temp_peak(live)
+                print(key + '/memory', {
+                    k: v for k, v in fields.items() if k != 'temp_peak'},
+                    flush=True)
+                if 'temp_peak' in fields:
+                    peak = fields['temp_peak']
+                    print(key + '/memory/temp_peak', peak['bytes'],
+                          peak['point'], peak['op'], peak['by_class'],
+                          flush=True)
     with open(out_path, 'w') as f:
         json.dump(hashes, f, indent=1)
 
 
+def _moved(what, before, after, unit=1e6, out=print):
+    """Print the keys of two {name: bytes} whose values differ."""
+    for name in sorted(set(before) | set(after), key=str):
+        a, b = before.get(name, 0.0), after.get(name, 0.0)
+        if a != b:
+            out('    %s %s: %.3f -> %.3f MB (%+.3f)'
+                % (what, name, a / unit, b / unit, (b - a) / unit))
+
+
+def compare(parent_path, tree_path, out=print):
+    """Per cell of two files this tool wrote: are the programs equal,
+    and if ``--memory`` was on, what moved and whose it is.  Returns
+    the number of keys that differ."""
+    with open(parent_path) as f:
+        parent = json.load(f)
+    with open(tree_path) as f:
+        tree = json.load(f)
+    differing = 0
+    for key in sorted(set(parent) | set(tree)):
+        a, b = parent.get(key), tree.get(key)
+        if a is None or b is None:
+            out('%s: only in %s' % (key, 'the tree' if a is None
+                                     else 'the parent'))
+            differing += 1
+            continue
+        if not key.endswith('/memory'):
+            same = a[0] == b[0]
+            differing += not same
+            out('%s: %s' % (key, 'equal' if same else
+                            'DIFFERENT (%s -> %s, %d -> %d characters, '
+                            '%d -> %d kernels)' % (a[0], b[0], a[1], b[1],
+                                                   a[2], b[2])))
+            continue
+        fields = {k: v for k, v in a.items() if k != 'temp_peak'}, \
+            {k: v for k, v in b.items() if k != 'temp_peak'}
+        if a == b:
+            out('%s: equal' % key)
+            continue
+        differing += 1
+        out('%s: moved' % key)
+        _moved('field', fields[0], fields[1], out=out)
+        pa, pb = a.get('temp_peak'), b.get('temp_peak')
+        if not (pa and pb):
+            out('    no temp_peak on %s: whose bytes cannot be said'
+                % ('either side' if not (pa or pb) else
+                   'the parent\'s side' if not pa else 'the tree\'s side'))
+            continue
+        out('    temporaries at their peak: %.3f MB at %s (%s) -> %.3f MB '
+            'at %s (%s)' % (pa['bytes'] / 1e6, pa['point'], pa['op'],
+                            pb['bytes'] / 1e6, pb['point'], pb['op']))
+        _moved('class', pa['by_class'], pb['by_class'], out=out)
+        _moved('fluid op', pa['by_op'], pb['by_op'], out=out)
+
+        def bag(peak):
+            held = {}
+            for buf in peak['top_buffers']:
+                k = (buf['shape'], str(buf['op']), buf['class'])
+                held[k] = held.get(k, 0) + 1
+            return held
+        ba, bb = bag(pa), bag(pb)
+        for k in sorted(set(ba) | set(bb)):
+            if ba.get(k, 0) != bb.get(k, 0):
+                out('    among the ten largest: %s under %s (%s): %d -> %d'
+                    % (k[0], k[1], k[2], ba.get(k, 0), bb.get(k, 0)))
+    return differing
+
+
 if __name__ == '__main__':
+    if sys.argv[1] == '--compare':
+        sys.exit(1 if compare(sys.argv[2], sys.argv[3]) else 0)
     main(sys.argv[1], sys.argv[2],
          [a for a in sys.argv[3:] if a != '--memory'],
          memory='--memory' in sys.argv[3:])
