@@ -642,7 +642,7 @@ def dropout(ctx, ins, attrs):
 def _swce_core(logits, lab, ax, ignore_index, loss_f32=False):
     """Hard-label softmax-CE along axis `ax` with an ANALYTIC backward.
     The jax.vjp-synthesized gradient keeps the full f32 log-prob tensor
-    as a residual — at BERT's MLM head that is a ~1 GB [B, T, V] f32
+    as a residual — at BERT's MLM head that is a 3.0 GB [B, T, V] f32
     buffer written+read per step.  The lean saved set is (logits as
     they arrived — usually bf16 under AMP, a buffer that is ALIVE
     anyway as the fc output — plus the per-row f32 logsumexp), and
@@ -654,6 +654,14 @@ def _swce_core(logits, lab, ax, ignore_index, loss_f32=False):
     buy a full layout-change copy.  Mirrors the reference's fused
     softmax_with_cross_entropy_grad kernel
     (operators/softmax_with_cross_entropy_op.cu).
+
+    The forward keeps no f32 [.., classes] tensor either: the cast
+    feeds only logsumexp's reductions (the compiler fuses it into
+    them) and the dead-unless-read Softmax.  The label's logit is
+    PICKED from the logits as they arrived and cast after (the same
+    number bit for bit): a gather's operand is never fused into, so
+    picking from the cast writes all of it out, 3.0 GB in the step
+    that fetches the loss.
 
     `lab` has the logits rank with a size-1 dim at `ax`.
 
@@ -669,7 +677,9 @@ def _swce_fwd_math(logits, lab, ax, ignore_index, loss_f32=False):
     lf = logits.astype(jnp.float32)
     lse = jax.nn.logsumexp(lf, axis=ax, keepdims=True)
     lab_safe = jnp.where(lab == ignore_index, 0, lab).astype(jnp.int32)
-    picked = jnp.take_along_axis(lf, lab_safe, axis=ax) - lse
+    # picked BEFORE the cast (_swce_core's docstring says why)
+    picked = jnp.take_along_axis(logits, lab_safe, axis=ax).astype(
+        jnp.float32) - lse
     valid = lab != ignore_index
     loss = jnp.where(valid, -picked, 0.0)
     softmax = jnp.exp(lf - lse)
@@ -736,17 +746,18 @@ def cross_entropy(ctx, ins, attrs):
     label = ins['Label'][0]
     soft_label = attrs.get('soft_label', False)
     ignore_index = attrs.get('ignore_index', -100)
-    logx = jnp.log(jnp.clip(x, 1e-20, None))
     if soft_label:
+        logx = jnp.log(jnp.clip(x, 1e-20, None))
         loss = -jnp.sum(label * logx, axis=-1, keepdims=True)
     else:
         lab = label
         if lab.ndim == x.ndim and lab.shape[-1] == 1:
             lab = jnp.squeeze(lab, -1)
         lab_safe = jnp.where(lab == ignore_index, 0, lab)
+        # pick, THEN the logarithm: one value a row, not every class's
         picked = jnp.take_along_axis(
-            logx, jnp.expand_dims(lab_safe, -1).astype(jnp.int32), axis=-1)
-        loss = -picked
+            x, jnp.expand_dims(lab_safe, -1).astype(jnp.int32), axis=-1)
+        loss = -jnp.log(jnp.clip(picked, 1e-20, None))
         loss = jnp.where(jnp.expand_dims(lab, -1) == ignore_index,
                          jnp.zeros_like(loss), loss)
     return {'Y': [loss]}

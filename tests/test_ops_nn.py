@@ -4,6 +4,8 @@ Mirrors reference tests test_conv2d_op.py, test_pool2d_op.py,
 test_batch_norm_op.py, test_softmax_with_cross_entropy_op.py, etc.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -407,6 +409,164 @@ class TestCrossEntropy(OpTest):
                           {'X': probs, 'Label': label},
                           expect={'Y': loss}, out_slots=['Y'],
                           atol=1e-5)
+
+
+def _outs_and_grad(f, x):
+    """(f(x), d x) under cotangents drawn from a fixed seed, one an
+    output of the tuple f returns."""
+    import jax
+    import jax.numpy as jnp
+    outs, vjp = jax.vjp(f, jnp.asarray(x))
+    r = np.random.RandomState(11)
+    cots = tuple(jnp.asarray(r.randn(*o.shape), o.dtype) for o in outs)
+    return outs + vjp(cots)
+
+
+def _lowering(op, ins, attrs, wrt, out_slots):
+    """x -> the `out_slots` of a registered lowering fed x as `wrt`."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import registry
+
+    def f(x):
+        held = {k: [jnp.asarray(v)] for k, v in ins.items()}
+        held[wrt] = [x]
+        out = registry.get(op).fn(registry.LowerCtx(0), held, attrs)
+        return tuple(out[s][0] for s in out_slots)
+    return f
+
+
+def _assert_bit_equal(got, want):
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g, 'float32'),
+                                      np.asarray(w, 'float32'))
+
+
+def _swce_forward_as_it_was(logits, lab, ax, ignore_index, loss_f32):
+    """The plain reference: `_swce_fwd_math` as it stood while the
+    label's logit was picked from the float32 cast."""
+    import jax
+    import jax.numpy as jnp
+    lf = logits.astype(jnp.float32)
+    lse = jax.nn.logsumexp(lf, axis=ax, keepdims=True)
+    lab_safe = jnp.where(lab == ignore_index, 0, lab).astype(jnp.int32)
+    picked = jnp.take_along_axis(lf, lab_safe, axis=ax) - lse
+    loss = jnp.where(lab != ignore_index, -picked, 0.0)
+    softmax = jnp.exp(lf - lse)
+    return ((softmax.astype(logits.dtype),
+             loss if loss_f32 else loss.astype(logits.dtype)), lse)
+
+
+def _swce_inputs(dtype, shape, axis):
+    import jax.numpy as jnp
+    r = np.random.RandomState(len(shape) * 7 + axis % len(shape))
+    logits = jnp.asarray(r.randn(*shape) * 4, dtype)
+    lab_shape = list(shape)
+    lab_shape[axis] = 1
+    label = r.randint(0, shape[axis], lab_shape).astype('int64')
+    label.flat[1] = -100            # a row that counts for nothing
+    return logits, label
+
+
+@pytest.mark.parametrize('amp_black_out', [False, True])
+@pytest.mark.parametrize('shape,axis', [((6, 37), -1), ((3, 5, 37), -1),
+                                        ((3, 37, 5), 1)])
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_swce_hard_label_is_bit_equal_to_the_pick_after_the_cast(
+        dtype, shape, axis, amp_black_out):
+    """Picking the label's logit BEFORE the float32 cast moves no value:
+    Loss, Softmax and the logits' gradient (the op's own backward rule,
+    fed by what each forward saves) are the old forward's bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import nn_ops
+    logits, label = _swce_inputs(dtype, shape, axis)
+    attrs = {'axis': axis, 'ignore_index': -100,
+             '__amp_black_out__': amp_black_out}
+    got = _outs_and_grad(_lowering(
+        'softmax_with_cross_entropy', {'Logits': logits, 'Label': label},
+        attrs, 'Logits', ('Softmax', 'Loss')), logits)
+    softmax, loss, _ = got
+    assert loss.dtype == (jnp.float32 if amp_black_out else logits.dtype)
+    assert softmax.dtype == logits.dtype
+    assert not np.asarray(loss, 'float32').flat[1]
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+    def was(x, lab, ax, ignore_index, loss_f32):
+        return _swce_forward_as_it_was(x, lab, ax, ignore_index,
+                                       loss_f32)[0]
+
+    def was_fwd(x, lab, ax, ignore_index, loss_f32):
+        y, lse = _swce_forward_as_it_was(x, lab, ax, ignore_index, loss_f32)
+        return y, (x, lse, lab)
+
+    was.defvjp(was_fwd, nn_ops._swce_bwd_rule)
+    _assert_bit_equal(got, _outs_and_grad(
+        lambda x: was(x, jnp.asarray(label), axis % len(shape), -100,
+                      amp_black_out and dtype == 'bfloat16'), logits))
+
+
+def _gathers(jaxpr):
+    """Every `gather` equation of a jaxpr and of the jaxprs it holds."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == 'gather':
+            yield eqn
+        for held in eqn.params.values():
+            for sub in held if isinstance(held, (list, tuple)) else [held]:
+                sub = getattr(sub, 'jaxpr', sub)
+                if hasattr(sub, 'eqns'):
+                    yield from _gathers(sub)
+
+
+def test_swce_asks_for_no_float32_copy_of_the_logits():
+    """The guard on what the lowering ASKS for: a gather's operand is
+    written out whole (the TPU compiler fuses no producer into it), so
+    a pick from the float32 cast of bf16 [B, T, V] logits costs the
+    step that fetches the loss a float32 [B, T, V] buffer: 3.0 GB in
+    the BERT cells.  No gather of loss + gradient may read one."""
+    import jax
+    import jax.numpy as jnp
+    logits, label = _swce_inputs('bfloat16', (8, 16, 256), -1)
+
+    def wide(forward):
+        traced = jax.make_jaxpr(jax.value_and_grad(
+            lambda x: jnp.sum(forward(x).astype(jnp.float32))))(logits)
+        return [e for e in _gathers(traced.jaxpr)
+                if e.invars[0].aval.shape == logits.shape and
+                e.invars[0].aval.dtype == jnp.float32]
+
+    lowered = _lowering(
+        'softmax_with_cross_entropy', {'Logits': logits, 'Label': label},
+        {'__amp_black_out__': True}, 'Logits', ('Loss',))
+    assert not wide(lambda x: lowered(x)[0])
+    # the walk does see one where it is asked for
+    assert wide(lambda x: _swce_forward_as_it_was(
+        x, jnp.asarray(label), 2, -100, True)[0][1])
+
+
+@pytest.mark.parametrize('op', ['cross_entropy', 'cross_entropy2'])
+@pytest.mark.parametrize('dtype', ['bfloat16', 'float32'])
+def test_cross_entropy_hard_label_picks_before_the_logarithm(dtype, op):
+    """One logarithm a row, of the picked probability, is the picked
+    logarithm of every probability bit for bit, value and gradient."""
+    import jax.numpy as jnp
+    r = np.random.RandomState(5)
+    probs = jnp.asarray(r.dirichlet(np.ones(9), (4, 3)), dtype)
+    probs = probs.at[0, 0, 2].set(0.)       # under the clip
+    label = r.randint(0, 9, (4, 3, 1)).astype('int64')
+    label[0, 0, 0], label[1, 1, 0] = 2, -100
+    got = _outs_and_grad(_lowering(
+        op, {'X': probs, 'Label': label}, {'ignore_index': -100}, 'X',
+        ('Y',)), probs)
+    assert not np.asarray(got[0], 'float32')[1, 1, 0]
+
+    def was(x):
+        logx = jnp.log(jnp.clip(x, 1e-20, None))
+        lab_safe = jnp.where(label == -100, 0, label).astype(jnp.int32)
+        picked = jnp.take_along_axis(logx, lab_safe, axis=-1)
+        return jnp.where(label == -100, jnp.zeros_like(picked), -picked),
+
+    _assert_bit_equal(got, _outs_and_grad(was, probs))
 
 
 class TestLookupTable(OpTest):
