@@ -27,6 +27,9 @@ the chip: BERT-base pretraining through the normal entry points
     python chip_smoke.py --phase ouro    # Ouro-2.6B's: the looped stack
                                     # as ONE While, gradients of the
                                     # shared layers summed over trips
+    python chip_smoke.py --phase xing4   # Xing4.0-29B-A4B's: the hyper-
+                                         # connection ops alone, the train
+                                         # step's gradients, the cell's loss
     python chip_smoke.py --phase grouped # the experts' grouped-matmul
                                     # kernels against ragged_dot at
                                     # the five routed cells' shapes
@@ -45,6 +48,7 @@ stdout line is the contract's:
 
 import argparse
 import json
+import os
 import shutil
 import sys
 import tempfile
@@ -2449,6 +2453,399 @@ def phase_ouro(seed=0):
     _ouro_cell_losses(OURO_SEQ, seed)
 
 
+# --- Xing4.0-29B-A4B ----------------------------------------------------
+# Published widths (models.xing4.BASE), one 4096-token sequence.  (1)
+# The hyper-connection ops ALONE at [4096, 4, 3584]: float32 forward
+# and all five gradients against jax.grad of the reference's equations
+# on 512 tokens, then a bfloat16 stream's forward and forward +
+# backward timed against the hand count
+# (benchmark/lib/xing_flops.py mhc_train_cost) and the largest distance
+# of H_res from the doubly stochastic matrices.  (2) Sampled gradients
+# of the f32 TRAIN program (the executor's one vjp through the recompute
+# groups; the flash kernels in float32) at the dense layer, ONE sparse
+# layer and the module against jax.grad of the reference (the cell's
+# check sees the for_test forward only).  (3) The harness's own
+# reference check at the cell's cut over XING4_LOSS_BATCHES batches,
+# each under the tolerance the family's rule gives it, the reference in
+# bfloat16 throughout put through the same comparison (the two readings
+# the tolerance lies between), what zeroing one of phi's three blocks or
+# cutting the Sinkhorn loop short moves that loss by, and
+# mhc/stochastic_err over as many train steps as a window makes.  (4)
+# What the compiler says
+# the cell's bf16 AMP train step holds with and without the recompute
+# groups.
+XING4_SEQ = 4096
+XING4_GRAD_LAYERS = 2
+XING4_LOSS_RTOL = 1e-5      # the f32 train loss: a router's one undecided
+                            # choice moves it by up to 4e-6
+XING4_WINDOW_BLOCKS = 12    # 132 train steps: a window run makes ~105
+XING4_L2_RTOL = 2e-3        # a gradient tensor's relative L2 distance
+XING4_LOSS_BATCHES = 6
+XING4_SAMPLED = ('xing4_embedding', 'hyper_connection_pre_0.w_0',
+                 'hyper_connection_pre_0.w_1', 'hyper_connection_pre_0.w_2',
+                 'hyper_connection_pre_3.w_0', 'hyper_connection_pre_3.w_1',
+                 'hyper_connection_pre_3.w_2', 'fc_0.w_0', 'rms_norm_1.w_0',
+                 'fc_1.w_0', 'xing4_g_final', 'xing4_w_head')
+
+
+def _xing4_cell():
+    """The benchmark's cell, as its harness finds it."""
+    from benchmark import run as harness
+    return harness.Cell(harness.load_json(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), 'BENCHMARK.json')),
+        'xing4_29b_s4096')
+
+
+def _xing4_cut(layers=None):
+    cell = _xing4_cell()
+    cfg = cell.family._zoo_config(cell.config, cell.traffic)
+    if layers:
+        cfg.layers = layers
+    return cfg
+
+
+def _xing4_single_op(seed=0, tokens=XING4_SEQ, n=4, c=3584):
+    import jax
+    import jax.numpy as jnp
+    from benchmark.lib import xing_flops
+    from paddle_tpu.models.reference import xing4 as reference
+    from paddle_tpu.ops import registry
+    sizes = reference.sizes_of(_xing4_cut())
+    rng = np.random.RandomState(seed)
+    m = n * n + 2 * n
+    ctx = registry.LowerCtx(0)
+    attrs = {'sinkhorn_iters': 20, 'epsilon': 1e-6, 'hc_eps': 1e-6,
+             'clamp_min': -30.0, 'clamp_max': 30.0}
+
+    def ops(x, y, phi, alpha, b):
+        pre = registry.get('hyper_connection_pre').fn(
+            ctx, {'X': [x], 'Phi': [phi], 'Alpha': [alpha], 'Bias': [b]},
+            attrs)
+        out = registry.get('hyper_connection_post').fn(
+            ctx, {'X': [x], 'Y': [y], 'HPost': pre['HPost'],
+                  'HRes': pre['HRes']}, {})
+        return pre['U'][0], out['XOut'][0], pre['Err'][0]
+
+    def want(x, y, phi, alpha, b):
+        h_pre, h_post, h_res = reference.hyper_maps(x, phi, alpha, b,
+                                                    sizes)
+        return (jnp.einsum('btn,btnc->btc', h_pre, x),
+                jnp.einsum('btij,btjc->btic', h_res, x) +
+                h_post[..., None] * y[:, :, None, :])
+
+    def inputs(t, dtype):
+        bias = np.zeros((m,), 'float32')
+        bias[2 * n:] = np.eye(n).ravel()
+        return (jnp.asarray(rng.randn(1, t, n, c), dtype),
+                jnp.asarray(rng.randn(1, t, c), dtype),
+                jnp.asarray(rng.randn(n * c, m) / np.sqrt(n * c),
+                            jnp.float32),
+                jnp.full((3,), 0.5, jnp.float32), jnp.asarray(bias))
+
+    small = inputs(512, jnp.float32)
+    w_u = jnp.asarray(rng.randn(1, 512, c), jnp.float32)
+    w_x = jnp.asarray(rng.randn(1, 512, n, c), jnp.float32)
+
+    def scalar(fn):
+        def f(*args):
+            u, out = fn(*args)[:2]
+            return jnp.sum(u * w_u) + jnp.sum(out * w_x)
+        return f
+
+    with jax.default_matmul_precision('highest'):
+        got = jax.jit(jax.grad(scalar(ops), (0, 1, 2, 3, 4)))(*small)
+        ref = jax.jit(jax.grad(scalar(want), (0, 1, 2, 3, 4)))(*small)
+        u, out, _ = jax.jit(ops)(*small)
+        want_u, want_out = jax.jit(want)(*small)
+    off = {'U': float(jnp.abs(u - want_u).max() / jnp.abs(want_u).max()),
+           'XOut': float(jnp.abs(out - want_out).max() /
+                         jnp.abs(want_out).max())}
+    for name, a, b in zip(('dX', 'dY', 'dPhi', 'dAlpha', 'dBias'), got,
+                          ref):
+        off[name] = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+    say('hyper-connection ops alone, float32, 512 tokens x %d x %d, '
+        'against the reference: %s'
+        % (n, c, ', '.join('%s %.2e' % kv for kv in off.items())))
+    check(max(off.values()) <= 1e-4, 'the ops and all five gradients '
+          'within 1e-4 of the reference at the published width')
+
+    big = inputs(tokens, jnp.bfloat16)
+    forward = jax.jit(ops)
+    both = jax.jit(lambda *a: jax.grad(
+        lambda *a: sum(jnp.sum(o.astype(jnp.float32))
+                       for o in ops(*a)[:2]), (0, 1, 2, 3, 4))(*a))
+    err = float(forward(*big)[2][0])
+    fwd_s, both_s = _timed(forward, *big), _timed(both, *big)
+    flop, byte = xing_flops.mhc_train_cost(tokens, n, c)
+    fwd_bytes = tokens * c * 2 * (3 * n + 2)
+    say('hyper-connection ops alone, bfloat16 stream [%d, %d, %d]: '
+        'forward %.3f ms (%.0f GB/s of the hand count\'s %.1f MB), '
+        'forward + backward %.3f ms (%.0f GB/s of %.1f MB); '
+        'mhc/stochastic_err %.2e'
+        % (tokens, n, c, fwd_s * 1e3, fwd_bytes / fwd_s / 1e9,
+           fwd_bytes / 1e6, both_s * 1e3, byte / both_s / 1e9,
+           byte / 1e6, err))
+    check(err < 1e-3, 'H_res within 1e-3 of doubly stochastic on every '
+          'token')
+
+
+def _xing4_gradients(seq, seed):
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.models import xing4
+    from paddle_tpu.models.reference import xing4 as reference
+    cfg = _xing4_cut(XING4_GRAD_LAYERS)
+    sizes = reference.sizes_of(cfg)
+    rng = np.random.RandomState(seed)
+    feed = _ints32(xing4.mtp_batch(
+        rng.randint(0, cfg.vocab_size, (1, seq))))
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        _, _, loss = xing4.build_pretrain(cfg, seq)
+        every = main.all_parameters()
+        params = [p.name for p in every if p.trainable]
+        biases = [p.name for p in every if not p.trainable]
+        pairs = dict((p.name, g.name) for p, g in
+                     fluid.optimizer.SGD(0.0).minimize(loss)[1])
+    missing = [n for n in XING4_SAMPLED if n not in pairs]
+    check(not missing, 'the sampled parameters exist: %s' % missing)
+    rows = np.unique(feed['ids'])[:64]
+    before = _fused_dispatches()
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights, bias_values = (
+            [np.asarray(fluid.core.as_array(scope.find_var(n)))
+             for n in names] for names in (params, biases))
+        t0 = time.time()
+        got = exe.run(main, feed=feed, fetch_list=[loss] + [
+            pairs[n] for n in XING4_SAMPLED])
+        say('xing4 f32 train program, %d main layers + the module, 1 x '
+            '%d tokens: loss %.6f in %.1f s; mhc/stochastic_err %.2e, '
+            'mtp/loss %.4f, mtp/loss_share %.4f; %d fused dispatches'
+            % (cfg.layers, seq, _scalar(got[:1]), time.time() - t0,
+               monitor.gauge_value('mhc/stochastic_err'),
+               monitor.gauge_value('mtp/loss'),
+               monitor.gauge_value('mtp/loss_share'),
+               _fused_dispatches() - before))
+        got = [np.asarray(g) for g in got]
+        for n in scope.local_var_names():
+            scope.erase(n)
+    index = {n: params.index(n) for n in XING4_SAMPLED}
+    ids, pos, labels, labels_mtp = (jnp.asarray(feed[k]) for k in (
+        'ids', 'pos_ids', 'labels', 'labels_mtp'))
+
+    def ref_loss(some, full, biases):
+        full = list(full)
+        for name, w in some.items():
+            full[index[name]] = w
+        return reference.loss(full, biases, ids, pos, labels, labels_mtp,
+                              sizes=sizes, remat=True)
+
+    weights = [jnp.asarray(w) for w in weights]
+    want_loss, want = jax.jit(jax.value_and_grad(ref_loss))(
+        {n: weights[index[n]] for n in XING4_SAMPLED}, weights,
+        [jnp.asarray(b) for b in bias_values])
+    off = abs(got[0].ravel()[0] - float(want_loss)) / float(want_loss)
+    say('xing4 f32 train program against the reference: loss relative '
+        'difference %.2e' % off)
+    check(off <= XING4_LOSS_RTOL, 'xing4 train loss within %g'
+          % XING4_LOSS_RTOL)
+    worst = 0.0
+    for name, g in zip(XING4_SAMPLED, got[1:]):
+        w = np.asarray(want[name])
+        if name == 'xing4_embedding':
+            g, w = g[rows], w[rows]
+        rel = float(np.linalg.norm(g - w) / np.linalg.norm(w))
+        worst = max(worst, rel)
+        say('  d loss / d %-28s |g| %.3e, relative L2 distance %.2e'
+            % (name, np.linalg.norm(w), rel))
+    check(worst <= XING4_L2_RTOL, 'every sampled gradient within %g '
+          '(relative L2) of jax.grad of the reference' % XING4_L2_RTOL)
+
+
+def _xing4_cell_losses(seq, seed):
+    """The cell's programs as the harness builds them, on the startup
+    state: (a) the harness's OWN reference check over
+    XING4_LOSS_BATCHES batches, each with the tolerance the family's
+    rule gives that batch; (b) the control, the reference in bfloat16
+    throughout, put through the same comparison; (c) what the loss
+    moves by when one block of phi is zero and when the Sinkhorn loop
+    is cut short; (d) ``mhc/stochastic_err`` and the module's share of
+    the loss over XING4_WINDOW_BLOCKS blocks of train steps on the one
+    fixed sequence, as many as a window run makes."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from benchmark import run as harness
+    from paddle_tpu.fluid import monitor
+    cell = _xing4_cell()
+    config, traffic, family = cell.config, cell.traffic, cell.family
+    cfg = _xing4_cut()
+    n, m = cfg.hc_mult, cfg.hc_mult ** 2 + 2 * cfg.hc_mult
+    hosts = [family.batch(config, traffic, 1, s)
+             for s in range(seed, seed + XING4_LOSS_BATCHES)]
+
+    def readings(dtype=None, **changed):
+        c = dict(config, **changed)
+        return jax.jit(lambda w, f: family.reference_readings(
+            c, traffic, w, f, dtype=dtype))
+
+    def within(got, want, rtol):        # benchmark/run.py's comparison
+        return abs(got - want) <= rtol * abs(want)
+
+    with fluid.scope_guard(fluid.Scope()):
+        main, startup, test, loss, params = harness.build_programs(
+            cell, seed)
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+
+        def current():      # a run hands the scope new arrays
+            return [fluid.core.as_array(scope.find_var(p))
+                    for p in params]
+
+        plain, low = readings(), readings(jnp.bfloat16)
+        sound, control, limits, refused = [], [], [], 0
+        for i, host in enumerate(hosts):
+            check(harness.reference_check(cell, exe, test, loss, params,
+                                          host),
+                  'the harness\'s reference check, batch seed %d'
+                  % (seed + i))
+            feed = {k: jnp.asarray(v) for k, v in host.items()}
+            want, moved, undecided = (float(x) for x in
+                                      plain(current(), feed))
+            got = _scalar(exe.run(test, feed=host, fetch_list=[loss]))
+            half = float(low(current(), feed)[0])
+            limits.append(family.allowed(want, moved))
+            sound.append(abs(got - want) / want)
+            control.append(abs(half - want) / want)
+            refused += not within(half, want, limits[-1])
+            say('batch seed %d: %d undecided choices move the '
+                'reference\'s loss by %.2e: tolerance %.2e; program '
+                '%.2e; reference in bfloat16 throughout %.2e (%s)'
+                % (seed + i, undecided, moved / want, limits[-1],
+                   sound[-1], control[-1],
+                   'correct' if within(half, want, limits[-1])
+                   else 'NOT correct'))
+        say('over %d batches: tolerance %.2e to %.2e; program against '
+            'the reference largest %.2e; bfloat16 control smallest '
+            '%.2e, median %.2e, largest %.2e, refused on %d'
+            % (len(hosts), min(limits), max(limits), max(sound),
+               min(control), np.median(control), max(control), refused))
+        # (c) on the first batch, against the largest tolerance seen
+        feed = {k: jnp.asarray(v) for k, v in hosts[0].items()}
+        base = float(plain(current(), feed)[0])
+        cut = {k: abs(float(readings(hc_sinkhorn_iters=k)(
+            current(), feed)[0]) - base) / base for k in (19, 8, 5, 3)}
+        say('a Sinkhorn loop of k normalisations instead of 20 moves '
+            'the reference\'s loss by: %s' % ', '.join(
+                'k=%d %.2e' % kv for kv in cut.items()))
+        got = _scalar(exe.run(test, feed=hosts[0], fetch_list=[loss]))
+        phis = [(p, np.asarray(w)) for p, w in zip(params, current())
+                if w.shape == (n * cfg.hidden, m)]
+        moved = {}
+        for tag, columns in (('H_pre\'s block', slice(0, n)),
+                             ('H_post\'s block', slice(n, 2 * n)),
+                             ('H_res\'s block', slice(2 * n, None))):
+            for p, w in phis:
+                w = w.copy()
+                w[:, columns] = 0
+                scope.set_var(p, jnp.asarray(w))
+            moved[tag] = abs(_scalar(exe.run(
+                test, feed=hosts[0], fetch_list=[loss])) - got) / got
+        for p, w in phis:
+            scope.set_var(p, jnp.asarray(w))
+        say('zeroing one block of phi moves the for_test loss by: %s '
+            '(largest tolerance %.2e)' % (', '.join(
+                '%s %.2e' % kv for kv in moved.items()), max(limits)))
+        # (d) the window's steps
+        target, placed = cell.layout.place(main, loss, jax.devices()[:1],
+                                           hosts[0])
+        runner = harness.Runner(cell, exe, target, placed, loss)
+        worst = 0.0
+        for block in range(XING4_WINDOW_BLOCKS):
+            value = runner.block()
+            flat = monitor.flat()
+            worst = max(worst, flat['mhc/stochastic_err'])
+            say('after %3d train steps: loss %.4f, mhc/stochastic_err '
+                '%.2e, mtp/loss_share %.4f'
+                % ((block + 1) * traffic['steps_per_block'], value,
+                   flat['mhc/stochastic_err'], flat['mtp/loss_share']))
+        # every reading is out before a check can stop the phase
+        check(refused == len(hosts), 'the comparison refuses the '
+              'bfloat16 control on every batch')
+        check(min(moved.values()) > family.BASE_RTOL,
+              'zeroing any of phi\'s three blocks moves the loss by more '
+              'than BASE_RTOL (against this batch\'s own tolerance, '
+              '%.2e: %s)' % (limits[0], ', '.join(
+                  '%s %s' % (tag, 'over' if v > limits[0] else 'UNDER')
+                  for tag, v in moved.items())))
+        check(cut[3] > family.BASE_RTOL, 'a loop of three normalisations '
+              'misses the tolerance')
+        check(worst < 1e-3, 'mhc/stochastic_err under 1e-3 over the '
+              'window\'s steps (largest %.2e)' % worst)
+        for n_ in scope.local_var_names():
+            scope.erase(n_)
+
+
+def _xing4_step_memory(seed=0):
+    """What the compiler says the cell's bf16 AMP train step holds, as
+    the model groups it and with the ``__recompute__`` marks taken
+    off."""
+    import jax
+    import paddle_tpu.fluid as fluid
+    from benchmark import run as harness
+    from paddle_tpu.fluid import memviz
+    cell = _xing4_cell()
+    host = cell.family.batch(cell.config, cell.traffic, 1, seed)
+    for grouped in (True, False):
+        main, startup, _, loss, _ = harness.build_programs(cell, seed)
+        if not grouped:
+            for op in main.global_block().ops:
+                op.attrs.pop('__recompute__', None)
+        try:
+            with fluid.scope_guard(fluid.Scope()):
+                exe = fluid.Executor(fluid.XLAPlace(0))
+                exe.run(startup)
+                step = exe.compile(main, feed_names=sorted(host),
+                                   fetch_names=[loss.name])
+                scope = fluid.global_scope()
+
+                def spec(a):
+                    return jax.ShapeDtypeStruct(a.shape, a.dtype)
+
+                state = {n: spec(fluid.core.as_array(scope.find_var(n)))
+                         for n in step.state_names}
+                data = {n: spec(host[n]) if n in host else spec(
+                    fluid.core.as_array(scope.find_var(n)))
+                    for n in step.input_names}
+                for n in scope.local_var_names():
+                    scope.erase(n)
+            compiled = jax.jit(step.fn, donate_argnums=(1,)).lower(
+                jax.ShapeDtypeStruct((), np.int32), state, data).compile()
+            fields = memviz.analysis_fields(compiled)
+            say('the cell\'s train step %s recompute groups, by the '
+                'compiler: %s' % ('WITH' if grouped else 'WITHOUT',
+                                  json.dumps(fields, sort_keys=True)))
+        except Exception as e:      # the chip's compiler refuses it
+            say('the cell\'s train step %s recompute groups does not '
+                'compile: %s: %s' % ('WITH' if grouped else 'WITHOUT',
+                                     type(e).__name__, str(e)[:400]))
+            check(not grouped, 'the grouped step compiles')
+
+
+def phase_xing4(seed=0):
+    _xing4_single_op(seed)
+    _xing4_gradients(XING4_SEQ, seed)
+    _xing4_cell_losses(XING4_SEQ, seed)
+    _xing4_step_memory(seed)
+
+
 def phase_grouped_matmul(seed=0, units=2.0):
     """The three forms of ops/pallas/grouped_matmul.py in bfloat16 at
     the five routed cells' shapes against ``jax.lax.ragged_dot`` and
@@ -2513,11 +2910,11 @@ def main():
     ap.add_argument('--chips', type=int, choices=(1, 4), default=1)
     ap.add_argument('--phase',
                     choices=('bert', 'olmoe', 'laguna', 'moonlight',
-                             'lfm2', 'evabyte', 'solar', 'ouro',
+                             'lfm2', 'evabyte', 'solar', 'ouro', 'xing4',
                              'grouped'),
                     default='bert',
                     help="'olmoe' / 'laguna' / 'moonlight' / 'lfm2' / "
-                    "'evabyte' / 'solar' / 'ouro': only that model's "
+                    "'evabyte' / 'solar' / 'ouro' / 'xing4': only that model's "
                     "gradient check; 'grouped': only the grouped-matmul "
                     "kernels against ragged_dot")
     args = ap.parse_args()
@@ -2560,6 +2957,8 @@ def main():
             phase_solar()
         elif args.phase == 'ouro':
             phase_ouro()
+        elif args.phase == 'xing4':
+            phase_xing4()
         elif args.phase == 'grouped':
             phase_grouped_matmul()
         elif args.chips == 4:

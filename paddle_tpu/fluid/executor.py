@@ -422,10 +422,15 @@ def _recompute_runs(ops):
     return runs
 
 
-def _lower_recomputed(run, env, step, prefer_test):
+def _lower_recomputed(run, env, step, prefer_test, stop_names=()):
     """One recompute group under ``jax.checkpoint``: what it reads of
     the env goes in, what it writes comes out, and a differentiation
-    of the trace keeps the former and computes the rest again."""
+    of the trace keeps the former and computes the rest again.
+    ``stop_names``: what the group writes that the backward pass
+    treats as constant (a router's indices and loads inside a decoder
+    block), pinned as it is written, before a consumer reads it.
+    Counter ``executor/recompute_groups``: one a lowering."""
+    monitor.add('executor/recompute_groups', 1)
     written, reads = set(), []
     for op in run:
         for n in _op_reads(op):
@@ -437,6 +442,9 @@ def _lower_recomputed(run, env, step, prefer_test):
         local = dict(values)
         for op in run:
             _lower_op(op, local, step, prefer_test)
+            for n in _op_writes(op):
+                if n in stop_names and n in local:
+                    local[n] = jax.lax.stop_gradient(local[n])
         return {n: local[n] for n in written if n in local}
 
     env.update(jax.checkpoint(group)({n: env[n] for n in reads}))
@@ -1042,12 +1050,10 @@ def _make_segment_fn(segment, prefer_test=False, whole_program_grad=False):
                 env = dict(others)
                 env.update(wrt_vals)
                 for run in _recompute_runs(pre):
-                    if '__recompute__' in run[0].attrs and \
-                            not stop_names.intersection(
-                                n for op in run for n in _op_writes(op)):
-                        # a recompute group (one that pins nothing
-                        # halfway): one checkpointed lowering
-                        _lower_recomputed(run, env, step, prefer_test)
+                    if '__recompute__' in run[0].attrs:
+                        # a recompute group: one checkpointed lowering
+                        _lower_recomputed(run, env, step, prefer_test,
+                                          stop_names)
                     else:
                         for op in run:
                             lower_one(op, env)

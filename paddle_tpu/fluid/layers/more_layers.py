@@ -222,13 +222,69 @@ def kda_attention(q, k, v, a, beta, name=None):
                    dtype=v.dtype, name=name)
 
 
+def hyper_connection_pre(x, sinkhorn_iters=20, epsilon=1e-6, hc_eps=1e-6,
+                         clamp=(-30.0, 30.0), param_attr=None,
+                         alpha_attr=None, bias_attr=None, name=None):
+    """The read side of a manifold-constrained hyper-connection (the
+    op ``hyper_connection_pre``, ``ops/hyper_connection_ops.py``, has
+    the equations) around ONE operator: ``x`` [B, T, n, C] is the
+    residual stream, n rows a token.  Creates the operator's own float32
+    parameters, phi [n C, n^2 + 2 n] (``param_attr``; stored at UNIT
+    size, default Normal(0, 1): the op divides by sqrt(n C)), the three scalars
+    alpha [3] (``alpha_attr``; default 0.01) and the bias [n^2 + 2 n]
+    (``bias_attr``; default zeros), and -> (u [B, T, C], the mix of the
+    rows the operator reads, in x's dtype; ``carry``, what
+    ``hyper_connection_post`` needs: the token's write weights H_post
+    [B, n, T] and its doubly stochastic H_res [B, n, n, T], float32,
+    tokens last; err [1], the largest distance of a row or column sum
+    of H_res from 1, no gradient).  The maps, and the ``sinkhorn_iters``
+    normalisations that make H_res, are float32 under AMP too."""
+    n, c = int(x.shape[2]), int(x.shape[3])
+    m = n * n + 2 * n
+    helper = LayerHelper('hyper_connection_pre', name=name)
+    phi = helper.create_parameter(
+        param_attr, [n * c, m], 'float32',
+        default_initializer=init.Normal(0., 1.))
+    alpha = helper.create_parameter(
+        alpha_attr, [3], 'float32',
+        default_initializer=init.Constant(0.01))
+    bias = helper.create_parameter(bias_attr, [m], 'float32', is_bias=True)
+    u = helper.create_variable_for_type_inference(x.dtype)
+    h_post = helper.create_variable_for_type_inference('float32')
+    h_res = helper.create_variable_for_type_inference('float32')
+    err = helper.create_variable_for_type_inference('float32',
+                                                    stop_gradient=True)
+    helper.append_op(
+        'hyper_connection_pre',
+        inputs={'X': x, 'Phi': phi, 'Alpha': alpha, 'Bias': bias},
+        outputs={'U': u, 'HPost': h_post, 'HRes': h_res, 'Err': err},
+        attrs={'sinkhorn_iters': int(sinkhorn_iters),
+               'epsilon': float(epsilon), 'hc_eps': float(hc_eps),
+               'clamp_min': float(clamp[0]), 'clamp_max': float(clamp[1])})
+    return u, (h_post, h_res), err
+
+
+def hyper_connection_post(x, y, carry, name=None):
+    """The write side: ``x`` [B, T, n, C] the stream the operator read
+    from, ``y`` [B, T, C] what it produced, ``carry`` from
+    ``hyper_connection_pre`` -> the new stream H_res x + H_post^T y
+    [B, T, n, C] in y's dtype."""
+    h_post, h_res = carry
+    return _simple('hyper_connection_post',
+                   {'X': x, 'Y': y, 'HPost': h_post, 'HRes': h_res},
+                   dtype=y.dtype, out_slot='XOut', name=name)
+
+
 def flash_attention(q, k, v, causal=False, window=0, coarse=None,
                     with_lse=False, name=None):
     """The ``fused_multihead_attention`` op on heads already split: q
     [B, T, H, D], k [B, Tk, Hkv, D], v [B, Tk, Hkv, Dv] -> [B, T, H,
     Dv] (the flash kernels on a chip from
     ``flash_attention.FLASH_MIN_SEQ`` queries up, the op's dense chain
-    under it and off a chip).  ``causal``, ``window`` and ``coarse`` =
+    under it and off a chip).  Scores are scaled by 1 / sqrt(D); a
+    model whose softmax scale is another (YaRN's ``mscale`` squared,
+    ``models/moonlight.py`` ``softmax_scale``) multiplies q by the
+    ratio before the call.  ``causal``, ``window`` and ``coarse`` =
     (window, chunk) are the op's three masks (``ops/pallas/
     flash_attention.py`` lists them in one place); ``with_lse`` also
     returns every row's log-sum-exp [B, T, H], differentiable, for

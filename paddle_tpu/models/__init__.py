@@ -1,7 +1,10 @@
 """Model zoo matching BASELINE.json configs:
 LeNet (MNIST), ResNet-50 (ImageNet), BERT-base, Transformer NMT,
 Wide&Deep CTR, word2vec, plus GPT-2 and OLMoE decoders — all built on the fluid layers API so they run
-unchanged on the reference framework.
+unchanged on the reference framework.  The decoders added since
+(``laguna``, ``moonlight``, ``lfm2``, ``evabyte``, ``solar_open2``,
+``ouro``, ``xing4``) are imported by name where they are used, each
+with its plain reference in ``models/reference/``.
 """
 
 from . import lenet

@@ -27,7 +27,20 @@ gradient is the sum over the heads that ``expand``'s gradient takes.
 Reading it through the kernels' index maps instead would save that
 copy and sum; what they cost on the chip is in PERF.md (section 6,
 PR 32).
+
+``attention`` is the zoo's ONE latent-attention helper
+(``models/xing4.py`` calls it too): a config with ``q_rank`` gives the
+queries a normed latent of their own (``q = rms_norm(u Wqa) Wqb``),
+one with ``yarn`` (a ``rope_scaling`` dict) rotates by YaRN's inverse
+frequencies and scales the scores by ``softmax_scale(cfg)``, which is
+where the softmax scale comes from: 1 / sqrt(qk width) times
+(0.1 mscale_all_dim ln factor + 1)^2.  ``layers.flash_attention``
+scales by 1 / sqrt(qk width) itself, so q is multiplied by the rest
+before the call.  Moonlight publishes neither key and builds the ops
+it always built.
 """
+
+import math
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import layers
@@ -44,7 +57,7 @@ class MoonlightConfig(object):
                  top_k=6, routed_scale=2.446, renormalize=True,
                  experts_held=None, rms_eps=1e-5, rope_theta=50000.0,
                  bias_update_rate=0.001, bias_init_std=0.0,
-                 init_std=0.02):
+                 init_std=0.02, q_rank=None, yarn=None):
         self.vocab_size = vocab_size        # the rows held here
         self.hidden = hidden
         self.layers = layers
@@ -53,6 +66,11 @@ class MoonlightConfig(object):
         self.qk_rope = qk_rope              # qk_rope_head_dim
         self.v_dim = v_dim                  # v_head_dim
         self.kv_rank = kv_rank              # kv_lora_rank
+        self.q_rank = q_rank                # q_lora_rank; None: no latent
+        # rope_scaling of type yarn (factor,
+        # original_max_position_embeddings, beta_fast, beta_slow,
+        # mscale, mscale_all_dim); None: theta's own frequencies
+        self.yarn = yarn
         self.dense_layers = dense_layers    # first_k_dense_replace
         self.dense_hidden = dense_hidden    # intermediate_size
         self.expert_hidden = expert_hidden  # moe_intermediate_size
@@ -95,10 +113,51 @@ def _attend(q, k, v):
     return layers.flash_attention(q, k, v, causal=True)
 
 
+def yarn_mscale(factor, mscale):
+    """HF ``yarn_get_mscale``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def softmax_correction(cfg):
+    """``yarn_mscale(factor, mscale_all_dim)`` squared under YaRN (HF
+    ``DeepseekV3Attention.scaling``), else exactly 1."""
+    yarn = cfg.yarn
+    if yarn and yarn.get('mscale_all_dim'):
+        return yarn_mscale(yarn['factor'], yarn['mscale_all_dim']) ** 2
+    return 1.0
+
+
+def softmax_scale(cfg):
+    """What the scores are multiplied by before the softmax."""
+    return softmax_correction(cfg) * (cfg.qk_nope + cfg.qk_rope) ** -0.5
+
+
+def _rotate(q_rope, k_rope, pos_ids, cfg):
+    yarn = cfg.yarn
+    if not yarn:
+        return layers.rotary_embedding(q_rope, k_rope, pos_ids,
+                                       theta=cfg.rope_theta,
+                                       interleaved=True)
+    from .laguna import yarn_inv_freq
+    table = layers.assign(yarn_inv_freq(
+        cfg.qk_rope, rope_theta=cfg.rope_theta, **yarn))
+    # cos and sin times mscale / mscale_all_dim (HF
+    # ``_compute_yarn_parameters``'s attention factor)
+    factor = yarn_mscale(yarn['factor'], yarn.get('mscale', 1.0)) / \
+        yarn_mscale(yarn['factor'], yarn.get('mscale_all_dim') or 0.0)
+    return layers.rotary_embedding(
+        q_rope, k_rope, pos_ids, theta=cfg.rope_theta, inv_freq=table,
+        attention_factor=factor, interleaved=True)
+
+
 def attention(u, pos_ids, cfg):
     """One layer's latent attention on the normed block input ``u``."""
     h, nope, rope, dv = cfg.heads, cfg.qk_nope, cfg.qk_rope, cfg.v_dim
-    q = layers.reshape(_linear(u, h * (nope + rope), cfg),
+    q_in = u
+    if cfg.q_rank:
+        q_in = layers.rms_norm(_linear(u, cfg.q_rank, cfg),
+                               epsilon=cfg.rms_eps)
+    q = layers.reshape(_linear(q_in, h * (nope + rope), cfg),
                        [0, 0, h, nope + rope])
     q_nope, q_rope = layers.split(q, [nope, rope], dim=3)
     # the latent and the rotary key, one projection
@@ -108,10 +167,12 @@ def attention(u, pos_ids, cfg):
     kv = layers.reshape(_linear(latent, h * (nope + dv), cfg),
                         [0, 0, h, nope + dv])
     k_nope, v = layers.split(kv, [nope, dv], dim=3)
-    q_rope, k_rope = layers.rotary_embedding(
-        q_rope, layers.reshape(k_rope, [0, 0, 1, rope]), pos_ids,
-        theta=cfg.rope_theta, interleaved=True)
+    q_rope, k_rope = _rotate(
+        q_rope, layers.reshape(k_rope, [0, 0, 1, rope]), pos_ids, cfg)
     q = layers.concat([q_nope, q_rope], axis=3)
+    if softmax_correction(cfg) != 1.0:
+        # the op scales by 1 / sqrt(qk width) itself
+        q = layers.scale(q, scale=softmax_correction(cfg))
     k = layers.concat([k_nope, layers.expand(k_rope, [1, 1, h, 1])],
                       axis=3)
     ctx = _attend(q, k, v)

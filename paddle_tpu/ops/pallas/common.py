@@ -357,8 +357,13 @@ def report():
     asked for), and under 'dropout' the dropout
     op's draws from the kernels' counter hash (ops/keep_hash.py):
     lowerings counted and the elements the last traced program draws
-    a step.  Empty dict when nothing has dispatched or drawn yet
-    (health.py hides the section)."""
+    a step, under 'hyper_connections' the mHC ops' lowerings, streams,
+    Sinkhorn iterations and H_res's last distance from the doubly
+    stochastic matrices, and under 'mtp' the prediction module's last
+    loss and its share of the training loss
+    (ops/hyper_connection_ops.py, models/xing4.py).  Empty dict when
+    nothing has dispatched or drawn yet (health.py hides the
+    section)."""
     try:
         from ...fluid import monitor
         counter = monitor.counter_value
@@ -413,4 +418,13 @@ def report():
     if draws:
         rep['dropout'] = {'counter_draws': draws,
                           'elements': gauge('dropout/elements') or 0}
+    calls = counter('mhc/calls') or 0
+    if calls:
+        rep['hyper_connections'] = {
+            'calls': calls, 'streams': gauge('mhc/streams') or 0,
+            'sinkhorn_iters': gauge('mhc/sinkhorn_iters') or 0,
+            'stochastic_err': gauge('mhc/stochastic_err') or 0}
+        if gauge('mtp/loss'):
+            rep['mtp'] = {'loss': gauge('mtp/loss'),
+                          'loss_share': gauge('mtp/loss_share') or 0}
     return rep

@@ -399,6 +399,19 @@ REQUIRED = [
     ('paddle_tpu/fluid/health.py', "'fleet':"),
     # rows a device capture could not attribute are counted
     ('paddle_tpu/fluid/profiler.py', 'profiler/dropped_events'),
+    # manifold-constrained hyper-connections and the multi-token-
+    # prediction module (ops/hyper_connection_ops.py, models/xing4.py):
+    # lowerings, static gauges, and what the runs that fetch read
+    # (benchmark/layer_metrics/mhc_stochastic_err.py, mtp_loss_share.py)
+    ('paddle_tpu/ops/hyper_connection_ops.py', 'mhc/calls'),
+    ('paddle_tpu/ops/hyper_connection_ops.py', 'mhc/streams'),
+    ('paddle_tpu/ops/hyper_connection_ops.py', 'mhc/sinkhorn_iters'),
+    ('paddle_tpu/models/xing4.py', 'mhc/stochastic_err'),
+    ('paddle_tpu/models/xing4.py', 'mtp/loss'),
+    ('paddle_tpu/models/xing4.py', 'mtp/loss_share'),
+    ('paddle_tpu/ops/pallas/common.py', 'mhc/stochastic_err'),
+    # recompute groups lowered (benchmark/layer_metrics/mhc_ms.py's note)
+    ('paddle_tpu/fluid/executor.py', 'executor/recompute_groups'),
 ]
 
 
