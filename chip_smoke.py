@@ -2457,7 +2457,8 @@ def phase_ouro(seed=0):
 # Published widths (models.xing4.BASE), one 4096-token sequence.  (1)
 # The hyper-connection ops ALONE at [4096, 4, 3584]: float32 forward
 # and all five gradients against jax.grad of the reference's equations
-# on 512 tokens, then a bfloat16 stream's forward and forward +
+# on 512 tokens (H_res's trips through the sinkhorn kernels, dispatch
+# checked), then a bfloat16 stream's forward and forward +
 # backward timed against the hand count
 # (benchmark/lib/xing_flops.py mhc_train_cost) and the largest distance
 # of H_res from the doubly stochastic matrices.  (2) Sampled gradients
@@ -2568,6 +2569,7 @@ def _xing4_single_op(seed=0, tokens=XING4_SEQ, n=4, c=3584):
         % (n, c, ', '.join('%s %.2e' % kv for kv in off.items())))
     check(max(off.values()) <= 1e-4, 'the ops and all five gradients '
           'within 1e-4 of the reference at the published width')
+    check_dispatch(['sinkhorn'])    # H_res's trips ran the kernels
 
     big = inputs(tokens, jnp.bfloat16)
     forward = jax.jit(ops)
@@ -2614,6 +2616,12 @@ def _xing4_gradients(seq, seed):
     check(not missing, 'the sampled parameters exist: %s' % missing)
     rows = np.unique(feed['ids'])[:64]
     before = _fused_dispatches()
+
+    def mixes():    # (hyper_connection_pre lowerings, of them fused)
+        return [monitor.counter_value(name) for name in (
+            'mhc/calls', 'pallas/sinkhorn/dispatch_fused')]
+
+    mixes_before = mixes()
     with fluid.scope_guard(fluid.Scope()):
         exe = fluid.Executor(fluid.XLAPlace(0))
         exe.run(startup)
@@ -2624,6 +2632,11 @@ def _xing4_gradients(seq, seed):
         t0 = time.time()
         got = exe.run(main, feed=feed, fetch_list=[loss] + [
             pairs[n] for n in XING4_SAMPLED])
+        lowered, fused = (now - was for now, was in
+                          zip(mixes(), mixes_before))
+        check(lowered == fused > 0, 'every hyper_connection_pre lowering '
+              'ran the sinkhorn kernels (%d lowerings, %d fused dispatches)'
+              % (lowered, fused))
         say('xing4 f32 train program, %d main layers + the module, 1 x '
             '%d tokens: loss %.6f in %.1f s; mhc/stochastic_err %.2e, '
             'mtp/loss %.4f, mtp/loss_share %.4f; %d fused dispatches'

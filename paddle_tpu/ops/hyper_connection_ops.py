@@ -58,9 +58,23 @@ tile of the chip sixteen-fold and more.  The stream is addressed as
 [B T, n C] with the rows as column ranges.
 
 GRADIENT.  jax.vjp of these lowerings: exact through all ``iters``
-normalisations (no fixed-point shortcut).  The loop is one
-``jax.checkpoint``: a forward pass keeps M_0 alone, and the gradient
-runs the normalisations again with every trip's M kept.
+normalisations, trip by trip (no fixed-point shortcut).  THE LOOP runs
+one of two ways, chosen once a call from what M_0 shows (``project``,
+``common.dispatch``; counters ``pallas/sinkhorn/dispatch_*``):
+
+- on a TPU, for a float32 M_0 over whole 128-token rows, INSIDE ONE
+  KERNEL CALL a side (``ops/pallas/sinkhorn.py``): the forward call runs
+  all the trips on a tile of 1024 tokens held in registers and keeps M_0
+  alone; the backward call runs them again with every half-trip's input
+  in a VMEM scratch and walks them in reverse (``y = m / (s + hc_eps)``,
+  ``dm = (dy - sum(dy * y)) / (s + hc_eps)`` over the summed axis);
+- otherwise (off a TPU, the tokens no multiple of 128, float64, under
+  the GSPMD runner, a scratch over the kernels' VMEM budget) as ONE
+  ``lax.scan`` under a ``jax.checkpoint``: a forward pass keeps M_0
+  alone, and the gradient runs the scan again with every trip's M kept
+  in HBM.  On the chip a trip of it is seven small fusions, 4.7 us:
+  960 trips a step of the Xing4 cell were 4.5 ms where the kernels'
+  36 calls are 0.2 (my chip runs, PR 55; PERF.md section 6).
 
 It is bound by BYTES beside operators bound by the MXU: a fused
 implementation moves (3 n + 2) C elements of the stream's type a token
@@ -79,12 +93,14 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 
 def sinkhorn(m, iters, hc_eps):
     """m [n, n, S] > 0 (rows, columns, tokens) -> the same after
-    ``iters`` x (rows, then columns) normalisations: ONE loop of a
-    fixed trip count in the program (``lax.scan``; reverse mode keeps
-    every trip's M and differentiates each exactly), not ``iters``
-    copies of its body: unrolled, 12 operators x (forward, recomputed
-    forward, backward) x 40 reductions made each step program 0.44 GB
-    of code and two minutes of compiling (my chip runs, PR 54)."""
+    ``iters`` x (rows, then columns) normalisations, the DENSE form
+    (``project`` runs the ``pallas.sinkhorn`` kernels instead where M_0
+    fits them): ONE loop of a fixed trip count in the program
+    (``lax.scan``; reverse mode keeps every trip's M and differentiates
+    each exactly), not ``iters`` copies of its body: unrolled, 12
+    operators x (forward, recomputed forward, backward) x 40 reductions
+    made each step program 0.44 GB of code and two minutes of compiling
+    (my chip runs, PR 54)."""
     def normalise(m, _):
         m = m / (jnp.sum(m, 1, keepdims=True) + hc_eps)
         return m / (jnp.sum(m, 0, keepdims=True) + hc_eps), None
@@ -107,12 +123,33 @@ def _project(x2, phi):
     return parts[:, :m] + parts[:, m:2 * m] + parts[:, 2 * m:]
 
 
-def maps(x2, phi, alpha, bias, n, epsilon, iters, hc_eps, clamp):
+def project(m0, iters, hc_eps, auto_partitioned=False):
+    """M_0 [n, n, S] > 0 -> H_res, by the ``sinkhorn`` kernels or by
+    the scan: ONE ``common.dispatch`` decision a call, from what the
+    operand shows (``pallas.sinkhorn.checks``), which the forward and
+    the backward both follow.  Either way a forward pass keeps M_0
+    alone: the kernel's ``custom_vjp`` by its rule, the scan under a
+    ``jax.checkpoint``."""
+    from .pallas import common, sinkhorn as kernel
+    fused, interpret = common.dispatch(
+        'sinkhorn', True, checks=kernel.checks(m0.shape, m0.dtype, iters),
+        auto_partitioned=auto_partitioned)
+    if fused:
+        return kernel.sinkhorn(m0, iters, hc_eps, interpret)
+    return jax.checkpoint(lambda m: sinkhorn(m, iters, hc_eps))(m0)
+
+
+def maps(x2, phi, alpha, bias, n, epsilon, iters, hc_eps, clamp,
+         auto_partitioned=False):
     """x2 [S, n C] -> (H_pre [n, S], H_post [n, S], H_res [n, n, S]),
     float32."""
-    xf = x2.astype(jnp.float32)
-    inv = jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) +
-                        epsilon)
+    # the squares summed ROW BY ROW: a reduction over the whole [S, n C]
+    # takes the write-back's concatenate into its fusion as n pads to
+    # full width and their maximums (0.34 ms a call where X's bytes need
+    # 0.16; my chip runs, PR 55), the rows' slices undo it
+    squares = sum(jnp.sum(jnp.square(row), -1, keepdims=True)
+                  for row in _rows(x2, n))
+    inv = jax.lax.rsqrt(squares / x2.shape[-1] + epsilon)
     proj = (_project(x2, phi.astype(jnp.float32)) *
             (inv * x2.shape[-1] ** -0.5)).T                 # [m, S]
     bias = bias.astype(jnp.float32)[:, None]
@@ -122,8 +159,7 @@ def maps(x2, phi, alpha, bias, n, epsilon, iters, hc_eps, clamp):
     res = alpha[2] * proj[2 * n:] + bias[2 * n:]
     with jax.named_scope('sinkhorn'):
         m0 = jnp.exp(jnp.clip(res, clamp[0], clamp[1])).reshape(n, n, -1)
-        h_res = jax.checkpoint(
-            lambda m: sinkhorn(m, iters, hc_eps))(m0)
+        h_res = project(m0, iters, hc_eps, auto_partitioned)
     return jax.nn.sigmoid(pre), 2.0 * jax.nn.sigmoid(post), h_res
 
 
@@ -153,7 +189,8 @@ def hyper_connection_pre(ctx, ins, attrs):
             x2, ins['Phi'][0], ins['Alpha'][0], ins['Bias'][0], n,
             attrs.get('epsilon', 1e-6), int(attrs['sinkhorn_iters']),
             attrs.get('hc_eps', 1e-6),
-            (attrs.get('clamp_min', -30.0), attrs.get('clamp_max', 30.0)))
+            (attrs.get('clamp_min', -30.0), attrs.get('clamp_max', 30.0)),
+            getattr(ctx, 'auto_partitioned', False))
         err = jax.lax.stop_gradient(jnp.maximum(
             jnp.max(jnp.abs(jnp.sum(h_res, 1) - 1.0)),
             jnp.max(jnp.abs(jnp.sum(h_res, 0) - 1.0))))

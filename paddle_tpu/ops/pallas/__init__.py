@@ -11,4 +11,5 @@ from . import flash_attention
 from . import grouped_matmul
 from . import kda_chunk
 from . import quant_collective
+from . import sinkhorn
 from .flash_attention import flash_attention as flash_attention_fn

@@ -868,8 +868,49 @@ def test_the_experts_products_compile_as_our_kernels(
     assert common.SCOPED_VMEM_BYTES < asked <= common.VMEM_LIMIT_CAP_BYTES
 
 
+@pytest.mark.parametrize('tokens', [
+    4096,           # xing4_29b_s4096: four tiles of 1024 tokens
+    128 * 23,       # no whole tiles: ONE block of 23 rows, the largest
+                    # the gate admits (tests/test_sinkhorn_kernel.py)
+    128,            # one row of lanes
+])
+def test_the_sinkhorn_projection_compiles_its_two_calls(one_chip, as_on_tpu,
+                                                        tokens):
+    """``hyper_connection_ops.project`` forward + backward on a [4, 4,
+    tokens] float32 matrix, 20 trips: the dispatch answers fused, the
+    executable holds TWO Mosaic calls (the forward; the backward, which
+    runs the trips again over its VMEM scratch), neither asks Mosaic for
+    more than its default scoped VMEM, what the compiler says the
+    backward call uses is the kernel module's count (or less: a tile of
+    one row lies in no 8 sublanes), and no loop of the program is
+    left."""
+    import re
+    from paddle_tpu.ops import hyper_connection_ops as hc_ops
+    from paddle_tpu.ops.pallas import sinkhorn
+
+    def step(m, d_out):
+        out, pull = jax.vjp(lambda m: hc_ops.project(m, 20, 1e-6), m)
+        return (out,) + pull(d_out)
+
+    rows = tokens // sinkhorn.LANES
+    assert sinkhorn.backward_vmem(4, 20, rows) <= common.VMEM_BUDGET_BYTES
+    m = _spec((4, 4, tokens))
+    text = _compiled(step, one_chip, m, m).as_text()
+    _compiled_on_chip('sinkhorn')
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert ' while(' not in text
+    call = r'custom_call_target="tpu_custom_call"[^\n]*?'
+    asked = re.findall(call + r'"scoped_memory_configs":\[([^\]]*)\]', text)
+    assert asked == ['', ''], asked
+    used = [int(n) for n in re.findall(
+        call + r'"used_scoped_memory_configs":\[\{[^}]*?"size":"(\d+)"',
+        text)]
+    assert len(used) == 2 and \
+        max(used) <= 1.02 * sinkhorn.backward_vmem(4, 20, rows), used
+
+
 def test_every_dispatchable_kernel_is_compiled_here():
     """A kernel registered later must bring its compile with it."""
     assert set(common.kernels()) == {
         'flash_attention', 'grouped_matmul', 'kda_chunk',
-        'quant_collective'}
+        'quant_collective', 'sinkhorn'}

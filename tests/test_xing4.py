@@ -125,12 +125,11 @@ def test_the_ops_are_the_reference_forward():
                                rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize('wrt', ['x', 'y', 'phi', 'alpha', 'b'])
-def test_the_ops_gradient_is_the_reference(wrt):
+def _gradient_against_the_reference(wrt, t):
     """d sum(w_u U) + sum(w_x X') by the program's gradient ops
     against jax.grad of the reference, through all 20
-    normalisations."""
-    ins = _op_inputs(1)
+    normalisations, on 2 x ``t`` tokens."""
+    ins = _op_inputs(1, t=t)
     rng = np.random.RandomState(7)
     w_u = rng.randn(*ins['y'].shape).astype('float32')
     w_x = rng.randn(*ins['x'].shape).astype('float32')
@@ -165,6 +164,33 @@ def test_the_ops_gradient_is_the_reference(wrt):
         other = np.asarray(jax.grad(short)(jnp.asarray(ins[wrt])))
     if wrt in ('x', 'phi', 'alpha', 'b'):
         assert np.abs(other - want).max() > 1e-2 * np.abs(want).max()
+
+
+WRT = ['x', 'y', 'phi', 'alpha', 'b']
+
+
+@pytest.mark.parametrize('wrt', WRT)
+def test_the_ops_gradient_is_the_reference(wrt):
+    """12 tokens: no whole 128-lane row, so the Sinkhorn loop is the
+    scan (the dense path's test)."""
+    before = monitor.counter_value('pallas/sinkhorn/fallback/layout') or 0
+    _gradient_against_the_reference(wrt, 6)
+    assert monitor.counter_value(
+        'pallas/sinkhorn/fallback/layout') > before
+
+
+@pytest.mark.parametrize('wrt', WRT)
+def test_the_ops_gradient_through_the_kernels_is_the_reference(
+        pallas_interpret, wrt):
+    """128 tokens with the ``sinkhorn`` kernels forced (their bodies
+    under the interpreter): the same five gradients, through the
+    kernel's own backward call."""
+    from paddle_tpu.ops.pallas import common
+    before = monitor.counter_value('pallas/sinkhorn/dispatch_fused') or 0
+    _gradient_against_the_reference(wrt, 64)
+    assert monitor.counter_value('pallas/sinkhorn/dispatch_fused') > before
+    assert common._LAST['sinkhorn'] == {
+        'path': 'fused', 'reason': 'forced_interpret', 'interpret': True}
 
 
 class TestFiniteDifferences(OpTest):
@@ -777,6 +803,30 @@ def test_the_sinkhorn_loop_is_one_loop_of_the_program():
     got, want = (jax.grad(lambda m: jnp.sum(weight * f(m)))(m0)
                  for f in (lambda m: hc_ops.sinkhorn(m, 20, 1e-6), plain))
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-7)
+
+
+def test_the_mean_square_is_summed_row_by_row():
+    """No reduction of the lowering reads the whole [S, n C] stream: on
+    the chip one that does takes the write-back's ``concatenate`` into
+    its fusion as n pads to full width (PERF.md section 6, PR 55).
+    The values are ``test_the_ops_are_the_reference_forward``'s."""
+    ins = _op_inputs(6, t=8)
+    x2 = jnp.asarray(ins['x'].reshape(-1, 64))
+
+    def fn(x):
+        return hc_ops.maps(x, ins['phi'], ins['alpha'], ins['b'], 4, 1e-6,
+                           20, 1e-6, (-30.0, 30.0))
+
+    def reductions(jaxpr):
+        from jax._src import core
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name.startswith('reduce_'):
+                yield eqn.invars[0].aval.shape
+            for sub in core.jaxprs_in_params(eqn.params):
+                yield from reductions(sub)
+
+    shapes = list(reductions(jax.make_jaxpr(fn)(x2).jaxpr))
+    assert (16, 16) in shapes and x2.shape not in shapes, shapes
 
 
 def test_phi_is_stored_at_unit_size():

@@ -11,7 +11,8 @@ auto-dispatch + dense-fallback contract:
      dense reference on CPU — bitwise where the reference is exact
      (blockwise quantize), tolerance-bounded where the kernel body
      sums in another order (flash attention's online softmax, the
-     delta rule's in-chunk scores, the experts' grouped products);
+     delta rule's in-chunk scores, the experts' grouped products, the
+     hyper-connections' Sinkhorn trips);
   3. observability: every dispatch lands a pallas/<kernel>/dispatch_*
      counter and a last-decision record with a reason, and the
      /statusz pallas section renders them — a silent dense fallback
@@ -27,7 +28,7 @@ import os
 import sys
 
 EXPECTED = ('flash_attention', 'grouped_matmul', 'kda_chunk',
-            'quant_collective')
+            'quant_collective', 'sinkhorn')
 
 
 def main():
@@ -40,7 +41,7 @@ def main():
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid import health, monitor
     from paddle_tpu.fluid.flags import _DEFAULTS
-    from paddle_tpu.ops import kda_ops
+    from paddle_tpu.ops import hyper_connection_ops, kda_ops
     from paddle_tpu.ops.pallas import (common, flash_attention,
                                        quant_collective)
 
@@ -123,6 +124,24 @@ def main():
             failures.append('grouped_matmul forward/grad parity')
             break
 
+    # the hyper-connections' projection through the sinkhorn kernels
+    # against the scan: 20 trips of a 4 x 4 matrix over 256 tokens
+    m0 = jnp.asarray(np.exp(3 * rng.randn(4, 4, 256)).astype('float32'))
+    weight = jnp.asarray(rng.randn(4, 4, 256).astype('float32'))
+
+    def projected(m):
+        return jnp.sum(weight * hyper_connection_ops.project(m, 20, 1e-6))
+
+    fluid.set_flags({'FLAGS_pallas_force': True})
+    fused = jax.value_and_grad(projected)(m0)
+    fluid.set_flags({'FLAGS_pallas_force': False})
+    dense = jax.value_and_grad(projected)(m0)
+    for a, b in zip(fused, dense):
+        if not np.abs(np.asarray(a) - np.asarray(b)).max() <= \
+                1e-5 * np.abs(np.asarray(b)).max():
+            failures.append('sinkhorn forward/grad parity')
+            break
+
     flat = jnp.asarray(rng.randn(16, 256).astype('float32'))
     qv, s = quant_collective.quantize_blocks(flat, True)
 
@@ -139,7 +158,7 @@ def main():
             np.array_equal(np.asarray(s), np.asarray(sref))):
         failures.append('quantize_blocks not bitwise vs dense q()')
     print('parity: flash_attention fwd/grad, kda_chunk fwd/grad, '
-          'grouped_matmul fwd/grad, quantize_blocks ok')
+          'grouped_matmul fwd/grad, sinkhorn fwd/grad, quantize_blocks ok')
 
     # -- 3. dispatch observability -----------------------------------
     quant_collective.dispatch()
