@@ -30,6 +30,9 @@ the chip: BERT-base pretraining through the normal entry points
     python chip_smoke.py --phase xing4   # Xing4.0-29B-A4B's: the hyper-
                                          # connection ops alone, the train
                                          # step's gradients, the cell's loss
+    python chip_smoke.py --phase phi4flash   # Phi-4-mini-flash's: the
+                                    # selective scan, differential
+                                    # attention, the cross-decoder
     python chip_smoke.py --phase grouped # the experts' grouped-matmul
                                     # kernels against ragged_dot at
                                     # the five routed cells' shapes
@@ -2859,6 +2862,239 @@ def phase_xing4(seed=0):
     _xing4_step_memory(seed)
 
 
+# --- Phi-4-mini-flash ---------------------------------------------------
+# Published widths (models.phi4flash.BASE but for the cell's cut: 8
+# layers, 25008 rows), one 8192-token sequence.  (1) The f32 TRAIN
+# program's loss and sampled gradients (the executor's one vjp through
+# the recompute groups, the scan's custom_vjp, the two-width grouped
+# flash kernels in float32, banded and full) against jax.grad of the
+# reference with every layer recomputed and its attention 512 queries
+# at a time: a parameter of every kind of layer, among them the
+# memory's Mamba (layer 4: what reaches it through the gated memory
+# unit) and layer 5's Wqkv (its K and V columns: through the cross
+# layer).  (2) The same program under bf16 AMP against the same float32
+# reference.  (3) The harness's OWN reference check at the cell's cut
+# over PHI4_LOSS_BATCHES batches: the reference in f32, in bfloat16
+# throughout, with a bfloat16 scan state alone, and with a part left
+# out: the readings the family's REFERENCE_RTOL lies between.
+PHI4_SEQ = 8192
+PHI4_LOSS_RTOL = 1e-6       # = benchmark/families/phi4flash.py's
+# a gradient tensor's relative L2 distance, float32: the order of sums
+# through eight layers; the first chip run read 1.0e-6 to 6.7e-5 over
+# the eighteen tensors (my chip run, PR 56), the limit has 7 times of
+# room over the worst
+PHI4_L2_RTOL = 5e-4
+# under bf16 AMP against the float32 reference: bfloat16 operands in
+# every matmul and flash call, eight layers deep (a bf16 place is
+# 4e-3).  The loss read 1.25e-5; the tensors 2.3e-3 to 5.0e-2, median
+# 3.8e-2 (the same run): about twice and three times of room
+PHI4_AMP_LOSS_RTOL = 1e-4
+PHI4_AMP_L2_MEDIAN = 0.08
+PHI4_AMP_L2_RTOL = 0.15
+# the controls that have to miss the family's limit on EVERY batch; the
+# others move the loss by a signed sum that can land near zero (a
+# window of 511 drops one key of 512 a query: 2.7e-7 on one batch of
+# six, 1.7e-5 to 5.1e-5 on the others): they have to miss it in the
+# median, and the phase says on how many batches they did
+PHI4_ALWAYS = ('bfloat16 throughout', 'no D * x')
+PHI4_LOSS_BATCHES = 6
+PHI4_SAMPLED = (
+    'phi4flash.embed_tokens',
+    'phi4flash.0.mamba.conv_b', 'phi4flash.0.mamba.w_x',
+    'phi4flash.0.mamba.b_dt', 'phi4flash.0.mamba.a_log',
+    'phi4flash.0.mamba.d',
+    'phi4flash.1.sliding_attention.wqkv',
+    'phi4flash.1.sliding_attention.lq1',
+    'phi4flash.1.sliding_attention.subln_g',
+    'phi4flash.3.mlp.w2',
+    'phi4flash.4.mamba.w_x', 'phi4flash.4.mamba.w_dt',
+    'phi4flash.5.full_attention.wqkv', 'phi4flash.5.full_attention.lk2',
+    'phi4flash.6.gmu.w_in', 'phi4flash.7.cross_attention.wq',
+    'phi4flash.7.cross_attention.bo', 'phi4flash.ln_f.b')
+
+
+def _phi4_cell():
+    """The benchmark's cell, as its harness finds it."""
+    from benchmark import run as harness
+    return harness.Cell(harness.load_json(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), 'BENCHMARK.json')),
+        'phi4_mini_flash_s8192')
+
+
+def _phi4_train(cfg, seq, seed, feed, amp):
+    """One step of the train program (SGD at lr 0) -> (loss, sampled
+    gradients, weights by name)."""
+    import jax
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.models import phi4flash
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.scope_guard(fluid.Scope()):
+        with fluid.program_guard(main, startup), \
+                fluid.unique_name.guard():
+            _, _, loss = phi4flash.build_pretrain(cfg, seq)
+            params = [p.name for p in main.all_parameters()]
+            optimizer = fluid.optimizer.SGD(0.0)
+            if amp:
+                optimizer = fluid.contrib.mixed_precision.decorate(
+                    optimizer, use_dynamic_loss_scaling=False,
+                    init_loss_scaling=1.0)
+            pairs = dict((p.name, g.name)
+                         for p, g in optimizer.minimize(loss)[1])
+        check(params == phi4flash.parameter_names(cfg),
+              'the program creates the parameters its specs list, in '
+              'their order')
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        # host copies first: a run donates the state it may write
+        weights = {p: np.asarray(fluid.core.as_array(scope.find_var(p)))
+                   for p in params}
+        fused0, scans0 = _fused_dispatches(), \
+            monitor.flat().get('ssm/calls', 0)
+        t0 = time.time()
+        got = exe.run(main, feed=feed, fetch_list=[loss] + [
+            pairs[p] for p in PHI4_SAMPLED])
+        got_loss = _scalar(got[:1])
+        grads = [np.asarray(g, np.float32) for g in got[1:]]
+        fused = _fused_dispatches() - fused0
+        say('phi4flash %s train program, %d layers, 1 x %d tokens: loss '
+            '%.6f in %.1f s (with compile); %d kernel dispatches fused; '
+            'ssm/calls +%d, ssm/chunks %s, ssm/boundary_state_mb %s; '
+            'peak HBM %.2f GB'
+            % ('bf16 AMP' if amp else 'f32', cfg.layers, seq, got_loss,
+               time.time() - t0, fused,
+               monitor.flat().get('ssm/calls', 0) - scans0,
+               monitor.gauge_value('ssm/chunks', None),
+               monitor.gauge_value('ssm/boundary_state_mb', None),
+               _peak_bytes(jax.devices()[:1])[0] / 1e9))
+        check(fused >= 4, 'the four differential calls (two windowed, '
+              'the full one, the cross one) ran the flash kernels (%d '
+              'dispatches fused)' % fused)
+        chunks = -(-seq // 256)
+        check(monitor.gauge_value('ssm/chunks', None) == 3 * 3 * chunks,
+              'three Mamba layers: a forward, a recomputed forward and '
+              'a reverse walk of %d chunks each' % chunks)
+        del got
+        for n in scope.local_var_names():
+            scope.erase(n)
+    return got_loss, grads, weights
+
+
+def _phi4_gradients(seq, seed):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import phi4flash
+    from paddle_tpu.models.reference import phi4flash as reference
+    cell = _phi4_cell()
+    cfg = cell.family._zoo_config(cell.config, cell.traffic)
+    feed = _ints32(phi4flash.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(seed)))
+    f32_loss, f32_grads, weights = _phi4_train(cfg, seq, seed, feed, False)
+    amp_loss, amp_grads, _ = _phi4_train(cfg, seq, seed, feed, True)
+    sizes = reference.sizes_of(cfg)
+    rest = {k: jnp.asarray(v) for k, v in weights.items()
+            if k not in PHI4_SAMPLED}
+    ids, labels = jnp.asarray(feed['ids']), jnp.asarray(feed['labels'])
+    # the other weights go in as an ARGUMENT: closed over they would be
+    # 3.6 GB of constants in the program (the first chip run's compile
+    # met the machine's 40 GiB)
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda sampled, rest: reference.loss(
+            dict(rest, **sampled), ids, labels, sizes=sizes, remat=True,
+            block=512)))({k: jnp.asarray(weights[k]) for k in PHI4_SAMPLED},
+                         rest)
+    want_loss = float(want_loss)
+    for tag, got_loss, grads, loss_rtol, l2_rtol in (
+            ('f32', f32_loss, f32_grads, PHI4_LOSS_RTOL, PHI4_L2_RTOL),
+            ('bf16 AMP', amp_loss, amp_grads, PHI4_AMP_LOSS_RTOL,
+             PHI4_AMP_L2_RTOL)):
+        rel = abs(got_loss - want_loss) / want_loss
+        say('%s: program loss %.6f, reference %.6f, relative difference '
+            '%.2e' % (tag, got_loss, want_loss, rel))
+        distances = []
+        for name, x in zip(PHI4_SAMPLED, grads):
+            y = np.asarray(want[name])
+            e = float(np.abs(x - y).max() / np.abs(y).max())
+            l2 = float(np.linalg.norm(x - y) / np.linalg.norm(y))
+            distances.append(l2)
+            say('%s gradient of %s %s: largest entry difference %.3e of '
+                'the largest entry (%.3e), relative L2 distance %.3e'
+                % (tag, name, x.shape, e, np.abs(y).max(), l2))
+        check(rel <= loss_rtol, 'phi4flash %s train loss within %g of '
+              'the reference' % (tag, loss_rtol))
+        check(max(distances) <= l2_rtol, 'phi4flash %s gradients: every '
+              'sampled parameter within %g relative L2 of the '
+              'reference\'s (worst %.3e, median %.3e)'
+              % (tag, l2_rtol, max(distances), np.median(distances)))
+        if tag != 'f32':
+            check(np.median(distances) <= PHI4_AMP_L2_MEDIAN,
+                  'their median within %g' % PHI4_AMP_L2_MEDIAN)
+
+
+def _phi4_cell_losses(seed):
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from benchmark import run as harness
+    cell = _phi4_cell()
+    family = cell.family
+    controls = (('bfloat16 throughout', dict(dtype=jnp.bfloat16)),
+                ('bfloat16 scan state', dict(state_dtype=jnp.bfloat16)),
+                ('no D * x', dict(without=('skip',))),
+                ('lambdas at lam0', dict(without=('lambda',))),
+                ('window of 511', dict(without=('window_511',))))
+    with fluid.scope_guard(fluid.Scope()):
+        _, startup, test, loss, params = harness.build_programs(cell, seed)
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights = [fluid.core.as_array(scope.find_var(p)) for p in params]
+        reference = jax.jit(lambda w, f: [
+            family.reference_loss(cell.config, cell.traffic, w, f, **kw)
+            for _, kw in (('float32', {}),) + controls])
+        off, low = [], {name: [] for name, _ in controls}
+        for batch_seed in range(seed, seed + PHI4_LOSS_BATCHES):
+            feed = {k: jax.device_put(v) for k, v in family.batch(
+                cell.config, cell.traffic, 1, batch_seed).items()}
+            got = _scalar(exe.run(test, feed=feed, fetch_list=[loss]))
+            full, *others = (float(x) for x in reference(weights, feed))
+            off.append(abs(got - full) / full)
+            for (name, _), other in zip(controls, others):
+                low[name].append(abs(other - full) / full)
+            say('cell cut, batch seed %d: program %.6f, reference %.6f '
+                '(relative difference %.2e); %s'
+                % (batch_seed, got, full, off[-1], '; '.join(
+                    '%s %.2e' % (name, low[name][-1])
+                    for name, _ in controls)))
+        for n in scope.local_var_names():
+            scope.erase(n)
+    rtol = family.REFERENCE_RTOL
+    say('over %d batches: f32 for_test program against the reference, '
+        'relative: median %.2e, largest %.2e; %s'
+        % (len(off), np.median(off), max(off), '; '.join(
+            '%s: smallest %.2e, median %.2e, largest %.2e'
+            % (name, min(v), np.median(v), max(v))
+            for name, v in low.items())))
+    check(max(off) <= rtol, 'phi4flash f32 for_test loss at the cell\'s '
+          'cut within %g of the reference on every batch' % rtol)
+    for name, v in low.items():
+        missed = sum(x > rtol for x in v)
+        if name in PHI4_ALWAYS:
+            check(missed == len(v), 'the reference with %s misses %g on '
+                  'every batch' % (name, rtol))
+        else:
+            check(np.median(v) > rtol, 'the reference with %s misses %g '
+                  'in the median (on %d of %d batches)'
+                  % (name, rtol, missed, len(v)))
+
+
+def phase_phi4flash(seed=0):
+    _phi4_gradients(PHI4_SEQ, seed)
+    _phi4_cell_losses(seed)
+
+
 def phase_grouped_matmul(seed=0, units=2.0):
     """The three forms of ops/pallas/grouped_matmul.py in bfloat16 at
     the five routed cells' shapes against ``jax.lax.ragged_dot`` and
@@ -2924,10 +3160,10 @@ def main():
     ap.add_argument('--phase',
                     choices=('bert', 'olmoe', 'laguna', 'moonlight',
                              'lfm2', 'evabyte', 'solar', 'ouro', 'xing4',
-                             'grouped'),
+                             'phi4flash', 'grouped'),
                     default='bert',
                     help="'olmoe' / 'laguna' / 'moonlight' / 'lfm2' / "
-                    "'evabyte' / 'solar' / 'ouro' / 'xing4': only that model's "
+                    "'evabyte' / 'solar' / 'ouro' / 'xing4' / 'phi4flash': only that model's "
                     "gradient check; 'grouped': only the grouped-matmul "
                     "kernels against ragged_dot")
     args = ap.parse_args()
@@ -2972,6 +3208,8 @@ def main():
             phase_ouro()
         elif args.phase == 'xing4':
             phase_xing4()
+        elif args.phase == 'phi4flash':
+            phase_phi4flash()
         elif args.phase == 'grouped':
             phase_grouped_matmul()
         elif args.chips == 4:

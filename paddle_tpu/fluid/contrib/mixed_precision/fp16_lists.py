@@ -32,6 +32,9 @@ gray_list = {
     # bf16 q, k, v beside float32 log decays; the solve and the state
     # float32 inside, the output in v's dtype
     'kda_attention',
+    # bf16 x, B, C beside float32 steps, decays and skip; the state
+    # and every sum float32 inside, the output in x's dtype
+    'selective_scan',
     # a bf16 stream beside float32 maps: r, the projection, the three
     # maps and the Sinkhorn loop float32 inside, U and XOut in the
     # stream's and the operator's dtype

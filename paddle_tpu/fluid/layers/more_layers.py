@@ -183,20 +183,25 @@ def row_conv(input, future_context_size, param_attr=None, act=None,
 
 
 def short_conv(input, filter_size, gate_in=None, gate_out=None,
-               param_attr=None, name=None):
+               param_attr=None, bias_attr=False, name=None):
     """A causal depthwise convolution over time with one
-    ``filter_size``-tap filter a channel and no bias (``row_conv``
+    ``filter_size``-tap filter a channel (``row_conv``
     looks ahead; this looks back): input [B, T, C] -> out[b, t] =
     sum_j w[:, j] * z[b, t - (filter_size - 1) + j], z zero before the
     sequence starts, so the LAST tap weighs the token itself.  The
     filter is a parameter [C, filter_size].  ``gate_in`` and
     ``gate_out`` ([B, T, C] each) fuse the two multiplicative gates of
     a gated short convolution in: z = input * gate_in, out = gate_out *
-    (the filter of z); without them z = input."""
+    (the filter of z); without them z = input.  ``bias_attr`` (default
+    False: none) adds a bias [C] to the filter's output inside the op
+    (a Mamba layer's filter)."""
     helper = LayerHelper('short_conv', name=name)
     w = helper.create_parameter(
         param_attr, [int(input.shape[-1]), int(filter_size)], input.dtype)
     ins = {'X': input, 'Filter': w}
+    if bias_attr is not False:
+        ins['Bias'] = helper.create_parameter(
+            bias_attr, [int(input.shape[-1])], input.dtype, is_bias=True)
     if gate_in is not None:
         ins['GateIn'] = gate_in
     if gate_out is not None:
@@ -220,6 +225,23 @@ def kda_attention(q, k, v, a, beta, name=None):
     return _simple('kda_attention',
                    {'Q': q, 'K': k, 'V': v, 'A': a, 'Beta': beta},
                    dtype=v.dtype, name=name)
+
+
+def selective_scan(x, delta, a, b, c, d, name=None):
+    """The selective state-space scan of a Mamba layer (the op
+    ``selective_scan``, ``ops/ssm_ops.py``, has the equations): x [B, T,
+    D], ``delta`` [B, T, D] the steps (> 0; float32 under AMP), ``a``
+    [D, N] (< 0), ``b`` and ``c`` [B, T, N] the token's write and read
+    vectors, ``d`` [D] the skip -> m [B, T, D] in x's dtype, ``m_t = h_t
+    c_t + d * x_t`` of a state ``h_t = exp(delta_t a) * h_(t-1) +
+    (delta_t x_t) b_t^T`` [D, N] that starts at zero in every sequence.
+    What comes before (the projections, the filter, the softplus) and
+    after (the gate, W_out) is the model's.  Computed in chunks of 256
+    tokens (``ops.ssm_ops.CHUNK``); T need be no whole number of
+    them."""
+    return _simple('selective_scan',
+                   {'X': x, 'Delta': delta, 'A': a, 'B': b, 'C': c, 'D': d},
+                   dtype=x.dtype, name=name)
 
 
 def hyper_connection_pre(x, sinkhorn_iters=20, epsilon=1e-6, hc_eps=1e-6,

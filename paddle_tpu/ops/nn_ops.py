@@ -1203,16 +1203,18 @@ def short_conv(ctx, ins, attrs):
     Filter[:, L - 1] weighs the token itself, nothing later enters and
     nothing crosses from one sequence of the batch into the next (a
     ``Conv1d(C, C, L, groups=C, padding=L - 1)`` cut to its first T
-    outputs, no bias).  The two multiplicative gates of a gated short
+    outputs), plus Bias [C] where given (a Mamba layer's filter).  The
+    two multiplicative gates of a gated short
     convolution (Liquid's LFM2 ``conv`` operator) fuse in where given:
-    z = X * GateIn before the filter, Out = GateOut * (filter of z)
-    after it, each [B, T, C]; without them z = X.
+    z = X * GateIn before the filter, Out = GateOut * (filter of z +
+    Bias) after it, each [B, T, C]; without them z = X.
 
     L shifted multiply-adds in float32 whatever X is, Out in X's dtype
     (the rms_norm policy: a bf16 stream stays bf16 past the f32
     filter).  Bytes-bound: every operand is read once and Out written
     once in one fusion; the gradient (jax.vjp of this) is the same
-    pattern shifted the other way plus the filter's sum over B and T."""
+    pattern shifted the other way plus the filter's and the bias's sums
+    over B and T."""
     from ..fluid import monitor
     monitor.add('short_conv/calls', 1)
     x = ins['X'][0]
@@ -1225,6 +1227,8 @@ def short_conv(ctx, ins, attrs):
         z = z * ins['GateIn'][0].astype(f32)
     zp = jnp.pad(z, ((0, 0), (taps - 1, 0), (0, 0)))
     out = sum(zp[:, j:j + t] * w[:, j].astype(f32) for j in range(taps))
+    if ins.get('Bias'):
+        out = out + ins['Bias'][0].astype(f32)
     if ins.get('GateOut'):
         out = out * ins['GateOut'][0].astype(f32)
     return {'Out': [out.astype(x.dtype)]}

@@ -277,6 +277,44 @@ def test_latent_flash_at_the_moonlight_cell_shape(one_chip, as_on_tpu,
 
 
 @pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize('window', [512, 0], ids=['window512', 'full'])
+def test_differential_flash_at_the_phi4flash_cell_shapes(
+        one_chip, as_on_tpu, window, dtype):
+    """phi4_mini_flash_s8192: b1 t8192, 40 query heads 64 wide over 20
+    K/V heads (keys 64 wide, values 128 wide: a differential pair's
+    [v1 | v2]), causal, banded at 512 in the self-decoder and full in
+    the full and the cross layer: the first calls at which the
+    two-width path (Moonlight's, Xing4's: 192 / 128, one K/V head a
+    query head, no window) runs UNDER a window, WITH grouped K/V heads
+    and at width 64.  bfloat16 is the timed step, float32
+    ``chip_smoke.py --phase phi4flash`` and the cell's reference check.
+    Every call is named after the innermost scope, the widths', banded
+    or not."""
+    import re
+    b, t, h, hkv, d, dv = 1, 8192, 40, 20, 64, 128
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            with jax.named_scope('fused_multihead_attention'):
+                with jax.named_scope('window%d' % window if window
+                                     else 'full'):
+                    with jax.named_scope('qk%dv%d' % (d, dv)):
+                        o = flash_attention.flash_attention(
+                            q, k, v, causal=True, window=window)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    text = _compiled(step, one_chip, _spec((b, t, h, d), dtype),
+                     _spec((b, t, hkv, d), dtype),
+                     _spec((b, t, hkv, dv), dtype)).as_text()
+    _compiled_on_chip('flash_attention')
+    names = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert len(names) in (2, 3), names      # forward + one pass, or dq + dkv
+    assert all(n.startswith('qk64v128') for n in names), names
+
+
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
 def test_grouped_causal_flash_d64_at_the_lfm2_cell_shape(one_chip, as_on_tpu,
                                                          dtype):
     """lfm2_8b_a1b_s8192: b2 t8192, 32 query heads over 8 K/V heads of

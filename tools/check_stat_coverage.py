@@ -412,6 +412,13 @@ REQUIRED = [
     ('paddle_tpu/ops/pallas/common.py', 'mhc/stochastic_err'),
     # recompute groups lowered (benchmark/layer_metrics/mhc_ms.py's note)
     ('paddle_tpu/fluid/executor.py', 'executor/recompute_groups'),
+    # the selective state-space scan (ops/ssm_ops.py): lowerings, the
+    # trips of its scans over chunks and the boundary states it keeps,
+    # sums over one traced program
+    # (benchmark/layer_metrics/ssm_chunks.py, ssm_state_mb.py)
+    ('paddle_tpu/ops/ssm_ops.py', 'ssm/calls'),
+    ('paddle_tpu/ops/ssm_ops.py', 'ssm/chunks'),
+    ('paddle_tpu/ops/ssm_ops.py', 'ssm/boundary_state_mb'),
 ]
 
 
