@@ -2953,6 +2953,12 @@ def _phi4_train(cfg, seq, seed, feed, amp):
                    for p in params}
         fused0, scans0 = _fused_dispatches(), \
             monitor.flat().get('ssm/calls', 0)
+
+        def scan_dispatches():
+            return [monitor.counter_value('pallas/ssm_scan/dispatch_' + k)
+                    or 0 for k in ('fused', 'dense')]
+
+        walks0 = scan_dispatches()
         t0 = time.time()
         got = exe.run(main, feed=feed, fetch_list=[loss] + [
             pairs[p] for p in PHI4_SAMPLED])
@@ -2972,6 +2978,10 @@ def _phi4_train(cfg, seq, seed, feed, amp):
         check(fused >= 4, 'the four differential calls (two windowed, '
               'the full one, the cross one) ran the flash kernels (%d '
               'dispatches fused)' % fused)
+        walks = [now - was for now, was in zip(scan_dispatches(), walks0)]
+        check(walks[0] >= 3 and walks[1] == 0, 'every lowering of the '
+              'selective scan took the ssm_scan kernels (%d fused, %d '
+              'dense)' % tuple(walks))
         chunks = -(-seq // 256)
         check(monitor.gauge_value('ssm/chunks', None) == 3 * 3 * chunks,
               'three Mamba layers: a forward, a recomputed forward and '

@@ -12,4 +12,5 @@ from . import grouped_matmul
 from . import kda_chunk
 from . import quant_collective
 from . import sinkhorn
+from . import ssm_scan
 from .flash_attention import flash_attention as flash_attention_fn

@@ -106,26 +106,73 @@ def _forward(x, delta, a, bm, cm, dskip, chunk):
     return _unchunked(m, x.shape[1]), starts
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def selective_scan(x, delta, a, bm, cm, dskip, chunk=CHUNK):
+def _scan_path(x, a, chunk, auto_partitioned):
+    """How this call walks its tokens: 'dense' (the two ``lax.scan``s
+    below), 'fused' (the ``ssm_scan`` kernels) or 'interpret' (their
+    bodies under the Pallas interpreter: FLAGS_pallas_force off a
+    TPU).  One ``common.dispatch`` decision a call, which its forward
+    and its backward both follow, from what the operands show
+    (``ssm_scan.checks``)."""
+    from .pallas import common, ssm_scan
+    fused, interpret = common.dispatch(
+        'ssm_scan', True,
+        checks=ssm_scan.checks(x.shape, a.shape[-1], _working_dtype(x),
+                               chunk, x.dtype.itemsize),
+        auto_partitioned=auto_partitioned)
+    return ('interpret' if interpret else 'fused') if fused else 'dense'
+
+
+def _fused(x, chunk, path):
+    """-> (the kernels' module, the keywords of its calls: the chunk
+    as they run it, and whether under the interpreter); a walk of
+    theirs counts its trips over chunks as a dense one does."""
+    from .pallas import ssm_scan
+    size, n = ssm_scan.layout(x.shape[1], chunk)
+    registry.trace_sum('ssm/chunks', n)
+    return ssm_scan, dict(size=size, interpret=path == 'interpret')
+
+
+def selective_scan(x, delta, a, bm, cm, dskip, chunk=CHUNK,
+                   auto_partitioned=False):
     """x, delta [B, T, D], a [D, N], bm, cm [B, T, N], dskip [D] -> m
-    [B, T, D] in x's dtype.  T need be no whole number of chunks."""
-    return _forward(x, delta, a, bm, cm, dskip, chunk)[0]
+    [B, T, D] in x's dtype.  T need be no whole number of chunks.
+    ``auto_partitioned``: ``common.dispatch``'s (the caller's word that
+    XLA will partition this program over a mesh)."""
+    return _scan(x, delta, a, bm, cm, dskip, chunk,
+                 _scan_path(x, a, chunk, auto_partitioned))
 
 
-def _scan_fwd(x, delta, a, bm, cm, dskip, chunk):
-    m, starts = _forward(x, delta, a, bm, cm, dskip, chunk)
+def _walk(x, delta, a, bm, cm, dskip, chunk, path):
+    """-> (m, the state at each chunk's START [n, B, N, D]) by the
+    path's forward."""
+    if path == 'dense':
+        return _forward(x, delta, a, bm, cm, dskip, chunk)
+    kernels, how = _fused(x, chunk, path)
+    return kernels.forward(x, delta, a, bm, cm, dskip, **how)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _scan(x, delta, a, bm, cm, dskip, chunk, path):
+    return _walk(x, delta, a, bm, cm, dskip, chunk, path)[0]
+
+
+def _scan_fwd(x, delta, a, bm, cm, dskip, chunk, path):
+    m, starts = _walk(x, delta, a, bm, cm, dskip, chunk, path)
     registry.trace_sum('ssm/boundary_state_mb',
                        starts.size * starts.dtype.itemsize / 1e6)
     return m, ((x, delta, a, bm, cm, dskip), starts)
 
 
-def _scan_bwd(chunk, saved, d_m):
+def _scan_bwd(chunk, path, saved, d_m):
     """The chunks in reverse.  A trip runs its chunk's forward again
     from the kept start (``before``: the state before each token), then
     its tokens in reverse, carrying the cotangents of the state, of A
-    and of Dskip."""
+    and of Dskip: the dense path in the scans below, the fused one
+    inside one kernel call."""
     inputs, starts = saved
+    if path != 'dense':
+        kernels, how = _fused(inputs[0], chunk, path)
+        return kernels.backward(*inputs, starts, d_m, **how)
     x, a = inputs[0], inputs[2]
     chunked, tokens, a_t, skip = _operands(*inputs, chunk)
 
@@ -156,7 +203,7 @@ def _scan_bwd(chunk, saved, d_m):
             d_skip.astype(inputs[5].dtype))
 
 
-selective_scan.defvjp(_scan_fwd, _scan_bwd)
+_scan.defvjp(_scan_fwd, _scan_bwd)
 
 
 @register('selective_scan')
@@ -168,4 +215,5 @@ def selective_scan_op(ctx, ins, attrs):
     monitor.add('ssm/calls', 1)
     return {'Out': [selective_scan(
         ins['X'][0], ins['Delta'][0], ins['A'][0], ins['B'][0],
-        ins['C'][0], ins['D'][0])]}
+        ins['C'][0], ins['D'][0],
+        auto_partitioned=ctx.auto_partitioned)]}

@@ -947,8 +947,56 @@ def test_the_sinkhorn_projection_compiles_its_two_calls(one_chip, as_on_tpu,
         max(used) <= 1.02 * sinkhorn.backward_vmem(4, 20, rows), used
 
 
+@pytest.mark.parametrize('b,t,d,dtype', [
+    (1, 8192, 5120, jnp.bfloat16),  # phi4_mini_flash_s8192 under AMP
+    (1, 8192, 5120, jnp.float32),   # its for_test reference check
+    (2, 300, 1024, jnp.bfloat16),   # a batch, a tail that fills no chunk
+])
+def test_the_selective_scan_compiles_its_two_calls(one_chip, as_on_tpu,
+                                                   b, t, d, dtype):
+    """``selective_scan`` forward + backward at the Phi-4-mini-flash
+    cell's layer shape ([1, 8192, 5120] x 16 states, bfloat16 x, B, C
+    beside float32 steps) and at a short ragged batch: the dispatch
+    answers fused, the executable holds TWO Mosaic calls (the forward
+    walk; the reverse walk, which runs each chunk forward again over
+    its VMEM scratch) and no loop; the reverse call asks Mosaic for the
+    kernel module's count and the headroom, and what the compiler says
+    it uses stays under what it asked; the float32 steps and their
+    gradient reach the calls as they lie (no copy of a [T, D] float32
+    array is made for them)."""
+    import re
+    from paddle_tpu.ops import ssm_ops
+    from paddle_tpu.ops.pallas import ssm_scan
+
+    def step(*args):
+        out, pull = jax.vjp(ssm_ops.selective_scan, *args[:6])
+        return (out,) + pull(args[6])
+
+    wide, steps = _spec((b, t, d), dtype), _spec((b, t, d))
+    narrow = _spec((b, t, 16), dtype)
+    text = _compiled(step, one_chip, wide, steps, _spec((d, 16)), narrow,
+                     narrow, _spec((d,)), wide).as_text()
+    _compiled_on_chip('ssm_scan')
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert ' while(' not in text
+    size = ssm_scan.layout(t, ssm_ops.CHUNK)[0]
+    count = ssm_scan.backward_vmem(size, 16, jnp.dtype(dtype).itemsize)
+    assert max(_scoped(text)) == count + common.VMEM_HEADROOM_BYTES \
+        <= common.VMEM_LIMIT_CAP_BYTES
+    used = [int(n) for n in re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*?'
+        r'"used_scoped_memory_configs":\[\{[^}]*?"size":"(\d+)"', text)]
+    assert len(used) == 2 and max(used) <= 1.02 * count, (used, count)
+    if t % 8 == 0:
+        copies = re.findall(r'= f32\[[\d,]*\]\S* copy\(', text)
+        big = [c for c in copies if
+               np.prod([int(n) for n in re.findall(r'\d+', c)[1:]]) >=
+               b * t * d]
+        assert not big, big
+
+
 def test_every_dispatchable_kernel_is_compiled_here():
     """A kernel registered later must bring its compile with it."""
     assert set(common.kernels()) == {
         'flash_attention', 'grouped_matmul', 'kda_chunk',
-        'quant_collective', 'sinkhorn'}
+        'quant_collective', 'sinkhorn', 'ssm_scan'}

@@ -28,7 +28,7 @@ import os
 import sys
 
 EXPECTED = ('flash_attention', 'grouped_matmul', 'kda_chunk',
-            'quant_collective', 'sinkhorn')
+            'quant_collective', 'sinkhorn', 'ssm_scan')
 
 
 def main():
@@ -142,6 +142,30 @@ def main():
             failures.append('sinkhorn forward/grad parity')
             break
 
+    # the selective scan through the ssm_scan kernels against the two
+    # lax.scans: 40 tokens in chunks of 16, 1024 channels x 4 states
+    from paddle_tpu.ops import ssm_ops
+    scan = [jnp.asarray(v.astype('float32')) for v in (
+        rng.randn(1, 40, 1024), np.exp(rng.uniform(-7, 1, (1, 40, 1024))),
+        -np.exp(rng.randn(1024, 4)), rng.randn(1, 40, 4),
+        rng.randn(1, 40, 4), rng.randn(1024))]
+    weight = jnp.asarray(rng.randn(1, 40, 1024).astype('float32'))
+
+    def scanned(*x):
+        out, pull = jax.vjp(
+            lambda *x: ssm_ops.selective_scan(*x, chunk=16), *x)
+        return (out,) + pull(weight)
+
+    fluid.set_flags({'FLAGS_pallas_force': True})
+    fused = scanned(*scan)
+    fluid.set_flags({'FLAGS_pallas_force': False})
+    dense = scanned(*scan)
+    for a, b in zip(fused, dense):
+        if not np.abs(np.asarray(a) - np.asarray(b)).max() <= \
+                1e-5 * np.abs(np.asarray(b)).max():
+            failures.append('ssm_scan forward/grad parity')
+            break
+
     flat = jnp.asarray(rng.randn(16, 256).astype('float32'))
     qv, s = quant_collective.quantize_blocks(flat, True)
 
@@ -158,7 +182,8 @@ def main():
             np.array_equal(np.asarray(s), np.asarray(sref))):
         failures.append('quantize_blocks not bitwise vs dense q()')
     print('parity: flash_attention fwd/grad, kda_chunk fwd/grad, '
-          'grouped_matmul fwd/grad, sinkhorn fwd/grad, quantize_blocks ok')
+          'grouped_matmul fwd/grad, sinkhorn fwd/grad, ssm_scan fwd/grad, '
+          'quantize_blocks ok')
 
     # -- 3. dispatch observability -----------------------------------
     quant_collective.dispatch()
