@@ -56,7 +56,6 @@ from . import monitor
 __all__ = [
     'collecting', 'record_trace', 'records_for', 'wire_bytes',
     'size_bucket', 'account_dispatch', 'bw_samples',
-    'dispatch_points', 'clear_dispatch_points',
     'record_memory', 'memory_report', 'fit_linear',
     'model_predict', 'reset', 'BW_BUCKETS', 'MEM_BUCKETS',
     'RATIO_BUCKETS',
@@ -92,14 +91,6 @@ _BY_KEY_CAP = 512
 # medians from here); bounded per series
 _BW_SAMPLES = {}
 _BW_SAMPLES_CAP = 256
-# rolling (wire_bytes, wall_s) measured dispatch points per (kind,
-# bucket) — the autopilot's refit input: a bandwidth alone cannot
-# recover the latency term alpha, so the raw fit points are retained
-# alongside the GB/s samples.  For segments where several series
-# share one wall, each point's wall is ATTRIBUTED by wire share so a
-# refit over them reprices the segment total honestly.
-_DISPATCH_POINTS = {}
-_DISPATCH_POINTS_CAP = 256
 # label -> memory row; bounded like _BY_KEY
 _MEMORY = {}
 _MEMORY_CAP = 256
@@ -114,7 +105,6 @@ def reset():
     with _lock:
         _BY_KEY.clear()
         _BW_SAMPLES.clear()
-        _DISPATCH_POINTS.clear()
         _MEMORY.clear()
         _SUMMARY.clear()
 
@@ -287,56 +277,21 @@ def account_dispatch(records, wall_s, compile_run=False):
     total_wire = payload = 0.0
     kinds = {}
     series_wire = {}
-    refit_wire = {}
     plan_arms = {}
     plan_wire = plan_dense = plan_pred = 0.0
     plan_fused = plan_unpriced = 0
-    repricer = None
     for r in records:
         total_wire += r['wire_bytes']
         payload += r['payload_bytes']
         kinds[r['kind']] = kinds.get(r['kind'], 0) + 1
         key = (r['kind'], r['bucket'])
         series_wire[key] = series_wire.get(key, 0.0) + r['wire_bytes']
-        # refit-pool keying: the model ENTRY a record's wall should
-        # recalibrate.  An rs_ag-armed record executes reducescatter +
-        # allgather, so its wall decomposes into those two phase
-        # points (the same split reprice_record prices with) — filing
-        # it under 'allreduce' would both starve the phase entries of
-        # refit points AND pollute the dense-allreduce fit with walls
-        # the dense path never produced.  The quant arm's records
-        # already carry their own kind ('allreduce_quant'), the entry
-        # that prices them, so they pass through keyed as-is.
-        if r.get('arm') == 'rs_ag':
-            n = max(1, int(r.get('participants') or 1))
-            pl = float(r['payload_bytes'])
-            rs_w = wire_bytes('reducescatter', pl, n)
-            ag_w = wire_bytes('allgather', pl / n, n)
-            rs_key = ('reducescatter', size_bucket(pl))
-            ag_key = ('allgather', size_bucket(pl / n))
-            refit_wire[rs_key] = refit_wire.get(rs_key, 0.0) + rs_w
-            refit_wire[ag_key] = refit_wire.get(ag_key, 0.0) + ag_w
-        else:
-            refit_wire[key] = refit_wire.get(key, 0.0) + r['wire_bytes']
         arm = r.get('arm')
         if arm is not None:
             plan_arms[arm] = plan_arms.get(arm, 0) + 1
             plan_wire += r['wire_bytes']
             plan_dense += r.get('dense_wire_bytes', r['wire_bytes'])
             pred = r.get('predicted_s')
-            if repricer is None:
-                # the record froze predicted_s at TRACE time; when the
-                # autopilot installed an in-memory refit, reprice it
-                # live so the honesty ratio tracks the CURRENT model
-                # without retracing.  One module check per segment;
-                # False short-circuits the remaining records.
-                from . import comms_plan
-                repricer = comms_plan.reprice_record \
-                    if comms_plan.refit_active() else False
-            if repricer:
-                live = repricer(r)
-                if live is not None:
-                    pred = live
             if pred is None:
                 plan_unpriced += 1
             else:
@@ -384,24 +339,6 @@ def account_dispatch(records, wall_s, compile_run=False):
             if len(samples) >= _BW_SAMPLES_CAP:
                 del samples[:_BW_SAMPLES_CAP // 2]
             samples.append(bw_gbps)
-    # refit points: each MODEL-ENTRY series' wire over its wire-share
-    # of the wall, so summing repriced predictions over a multi-series
-    # segment reproduces the segment wall instead of K times it.  The
-    # refit keying decomposed rs_ag arms into their reducescatter /
-    # allgather phases above, so those entries — and the quant kind —
-    # recalibrate from live traffic the same way dense allreduce does.
-    refit_total = sum(refit_wire.values())
-    if refit_total <= 0:
-        return
-    for (kind, bucket), wire in refit_wire.items():
-        if wire <= 0:
-            continue
-        attributed_wall = wall_s * (wire / refit_total)
-        with _lock:
-            pts = _DISPATCH_POINTS.setdefault((kind, bucket), [])
-            if len(pts) >= _DISPATCH_POINTS_CAP:
-                del pts[:_DISPATCH_POINTS_CAP // 2]
-            pts.append((wire, attributed_wall))
 
 
 def bw_samples():
@@ -409,31 +346,6 @@ def bw_samples():
     bench/calibrate (the monitor histograms keep the scrape form)."""
     with _lock:
         return {k: list(v) for k, v in _BW_SAMPLES.items()}
-
-
-def dispatch_points(kind=None):
-    """{(kind, bucket): [(wire_bytes, wall_s), ...]} measured dispatch
-    fit points — the autopilot refit's input (fit_linear needs the
-    raw (bytes, seconds) pairs, not the bandwidths).  Walls are the
-    wire-share-attributed segment walls account_dispatch recorded;
-    `kind` filters to one collective's points as a flat list."""
-    with _lock:
-        if kind is not None:
-            out = []
-            for (k, _bucket), pts in _DISPATCH_POINTS.items():
-                if k == kind:
-                    out.extend(pts)
-            return out
-        return {k: list(v) for k, v in _DISPATCH_POINTS.items()}
-
-
-def clear_dispatch_points():
-    """Consume the refit fit-point pool (the autopilot calls this
-    after installing a refit, so the NEXT refit fits only points
-    measured after this one — mixing pre- and post-drift walls would
-    fit an in-between model)."""
-    with _lock:
-        _DISPATCH_POINTS.clear()
 
 
 # ------------------------------------------------------ memory accounting
@@ -483,7 +395,7 @@ def memory_report():
 
 
 # ------------------------------------------------------------ cost model
-def fit_linear(points, prior=None):
+def fit_linear(points):
     """Weighted least-squares fit of T(b) = alpha + beta*b over
     (bytes, seconds) points — the latency + inverse-bandwidth
     collective cost model.  Weights are 1/t^2, i.e. the fit minimizes
@@ -492,20 +404,8 @@ def fit_linear(points, prior=None):
     more than the 2x envelope the planner needs.  alpha is clamped
     non-negative (a negative launch latency is noise), beta to a tiny
     positive floor so predicted bandwidth stays finite.  Returns
-    (alpha_s, beta_s_per_byte).
-
-    `prior` is the autopilot-refit contract: a (alpha, beta) pair
-    returned VERBATIM when the points cannot support a two-parameter
-    fit — empty, a single size bucket (every wire size identical: the
-    intercept/slope split is unidentifiable), or a zero/negative
-    normal-equation determinant — counted ``autopilot/refit_degenerate``
-    instead of extrapolating a singular system into the planner.
-    Without a prior (the calibrator's sweeps) the legacy single-point
-    / degenerate fallbacks apply unchanged."""
+    (alpha_s, beta_s_per_byte)."""
     pts = [(float(b), float(t)) for b, t in points if t > 0]
-    if prior is not None and len({b for b, _t in pts}) < 2:
-        monitor.add('autopilot/refit_degenerate')
-        return float(prior[0]), float(prior[1])
     if not pts:
         return 0.0, 1e-12
     if len(pts) == 1:
@@ -521,9 +421,6 @@ def fit_linear(points, prior=None):
         swbt += w * b * t
     denom = sw * swbb - swb * swb
     if denom <= 0:
-        if prior is not None:
-            monitor.add('autopilot/refit_degenerate')
-            return float(prior[0]), float(prior[1])
         return 0.0, max(swt / max(swb, 1e-30), 1e-15)
     beta = (sw * swbt - swb * swt) / denom
     alpha = (swt - beta * swb) / sw

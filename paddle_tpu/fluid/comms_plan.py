@@ -76,8 +76,7 @@ __all__ = [
     'predict_seconds', 'load_model', 'model_entry', 'digest',
     'order_axes',
     'hbm_headroom_bytes', 'bucket_cap_bytes', 'quant_block',
-    'install_refit', 'adopt_refit', 'clear_refit', 'refit_active',
-    'refit_state', 'current_model', 'reprice_record',
+    'current_model',
     'record_program_plan', 'program_plans', 'reset',
 ]
 
@@ -91,18 +90,6 @@ _MODEL_MISS = object()
 _PLANS = {}
 _PLANS_CAP = 64
 _plan_seq = [0]
-
-# in-memory refit slot (the autopilot's online recalibration).  Two
-# generations deliberately: a freshly-INSTALLED (pending) refit prices
-# telemetry immediately (reprice_record, so the honesty ratio tracks
-# the new model without retracing anything), while planning —
-# decide()/predict_seconds()/digest() — keeps the ADOPTED model until
-# adopt_refit() promotes pending at an explicit re-plan point
-# (Executor.warmup, autopilot engage).  Live executables therefore
-# never retrace on a refit install; a (re)build after adoption
-# retraces exactly once onto the new coefficients.
-_refit = {'pending': None, 'pending_gen': 0,
-          'adopted': None, 'adopted_gen': 0, 'adopted_digest': None}
 
 # quantized-arm temporaries: int8 copy + fp32 dequant buffers alongside
 # the payload — the factor the HBM-headroom gate prices
@@ -137,13 +124,11 @@ def quant_hbm_temp(payload_bytes, fused=None):
 
 
 def reset():
-    """Drop the model cache + plan registry + refit slot (tests)."""
+    """Drop the model cache + plan registry (tests)."""
     with _lock:
         _model_cache.clear()
         _PLANS.clear()
         _plan_seq[0] = 0
-        _refit.update(pending=None, pending_gen=0, adopted=None,
-                      adopted_gen=0, adopted_digest=None)
 
 
 # ------------------------------------------------------------- cost model
@@ -190,135 +175,12 @@ def load_model(path=None):
     return model
 
 
-# ---------------------------------------------------- in-memory refit
-def _refit_digest_of(model):
-    """Stable short hash of a refit model's coefficients: the digest()
-    component adoption folds into segment fingerprints.  Coefficient-
-    content-addressed (not install-time-addressed) so the same refit
-    persisted and re-loaded across a restart yields the same segment
-    fingerprints — a restart onto an unchanged refit never retraces."""
-    ents = []
-    for kind in sorted(model.get('collectives', {})):
-        e = model['collectives'][kind]
-        try:
-            ents.append('%s:%.9g:%.9g' % (
-                kind, float(e['latency_s']),
-                float(e['inv_bw_s_per_byte'])))
-        except (KeyError, TypeError, ValueError):
-            ents.append('%s:partial' % kind)
-    return hashlib.sha256(';'.join(ents).encode()).hexdigest()[:12]
-
-
-def install_refit(model):
-    """Install an in-memory refit model (comms_model.json schema:
-    ``{'collectives': {kind: {'latency_s', 'inv_bw_s_per_byte'}}}``).
-    Takes effect immediately for TELEMETRY (reprice_record — the
-    honesty ratio re-converges without a retrace) but not for
-    PLANNING: decide()/digest() keep the previously-adopted model
-    until adopt_refit() promotes this one at an explicit re-plan
-    point.  Returns the pending generation number."""
-    if not isinstance(model, dict) or \
-            not isinstance(model.get('collectives'), dict):
-        raise ValueError('refit model must carry a collectives dict')
-    with _lock:
-        _refit['pending'] = model
-        _refit['pending_gen'] += 1
-        return _refit['pending_gen']
-
-
-def adopt_refit():
-    """Promote the pending refit into the ADOPTED planning model — the
-    explicit re-plan point (Executor.warmup and autopilot engage call
-    this).  After adoption, decide()/predict_seconds() price from the
-    refit and digest() folds its coefficient hash, so program
-    (re)builds retrace exactly once onto the new plan while live
-    executables keep the plan they were traced with.  No-op (None)
-    when nothing newer than the adopted generation is pending;
-    otherwise returns the adopted generation."""
-    with _lock:
-        if _refit['pending'] is None or \
-                _refit['pending_gen'] == _refit['adopted_gen']:
-            return None
-        _refit['adopted'] = _refit['pending']
-        _refit['adopted_gen'] = _refit['pending_gen']
-        _refit['adopted_digest'] = _refit_digest_of(_refit['adopted'])
-        return _refit['adopted_gen']
-
-
-def clear_refit():
-    """Drop both refit generations (the autopilot's one-call revert
-    leg): planning and telemetry pricing fall back to the on-disk
-    model.  A previously-adopted refit leaving the digest means the
-    next (re)build retraces once back onto the static plan.  Returns
-    True when anything was installed."""
-    with _lock:
-        had = _refit['pending'] is not None or \
-            _refit['adopted'] is not None
-        _refit.update(pending=None, adopted=None, adopted_digest=None)
-        return had
-
-
-def refit_active():
-    """One-dict-read hot-path predicate: is any refit installed?  The
-    account_dispatch repricing gate — False keeps the frozen
-    trace-time predictions (zero extra work per record)."""
-    return _refit['pending'] is not None or \
-        _refit['adopted'] is not None
-
-
-def refit_state():
-    """The /statusz-able refit slot summary."""
-    with _lock:
-        return {'pending': _refit['pending'] is not None,
-                'pending_gen': _refit['pending_gen'],
-                'adopted': _refit['adopted'] is not None,
-                'adopted_gen': _refit['adopted_gen'],
-                'adopted_digest': _refit['adopted_digest']}
-
-
 def current_model(model=None):
     """The model PLANNING prices from: an explicit argument wins, then
-    the adopted in-memory refit (no disk stat per call — the
-    predict_seconds fast path the autopilot satellite requires), then
     the cached on-disk comms_model.json."""
     if model is not None:
         return model
-    adopted = _refit['adopted']
-    if adopted is not None:
-        return adopted
     return load_model()
-
-
-def reprice_record(rec):
-    """Live predicted seconds for one frozen trace-time collective
-    record under the FRESHEST refit (pending first — telemetry tracks
-    an installed refit before adoption).  The record froze predicted_s
-    at trace time, so without this the windowed honesty ratio could
-    never move after a refit short of a retrace.  rs_ag records carry
-    the dense wire bytes; their phases re-price from payload and
-    participants the way decide() priced them.  None when no refit is
-    installed or it cannot price the record (the caller then keeps the
-    frozen prediction)."""
-    model = _refit['pending'] or _refit['adopted']
-    if model is None:
-        return None
-    try:
-        if rec.get('arm') == 'rs_ag':
-            from . import comms
-            payload = float(rec['payload_bytes'])
-            n = max(1, int(rec['participants']))
-            t_rs = predict_seconds(
-                'reducescatter',
-                comms.wire_bytes('reducescatter', payload, n), model)
-            t_ag = predict_seconds(
-                'allgather',
-                comms.wire_bytes('allgather', payload / n, n), model)
-            if t_rs is None or t_ag is None:
-                return None
-            return t_rs + t_ag
-        return predict_seconds(rec['kind'], rec['wire_bytes'], model)
-    except (KeyError, TypeError, ValueError):
-        return None
 
 
 def model_entry(kind, model=None):
@@ -386,13 +248,7 @@ def digest():
              'hbm=%d' % int(get_flag('FLAGS_comms_hbm_budget_bytes',
                                      0)),
              'model=%s' % hashlib.sha256(
-                 mid.encode()).hexdigest()[:12],
-             # ADOPTED refit only: an installed-but-unadopted refit
-             # reprices telemetry, never decisions, so it must not —
-             # and does not — move fingerprints (the zero-retrace-
-             # churn contract); adoption changes plans and retraces
-             # exactly once
-             'refit=%s' % (_refit['adopted_digest'] or 'none'))
+                 mid.encode()).hexdigest()[:12])
     return 'comms_plan(%s)' % ','.join(parts)
 
 
@@ -682,7 +538,6 @@ def program_plans():
         'digest': digest(),
         'model_path': _model_path() or None,
         'model_loaded': load_model() is not None,
-        'refit': refit_state(),
         'programs': plans,
         'arm_counters': {
             k.rsplit('/', 1)[1]: monitor.counter_value(k)

@@ -1519,13 +1519,6 @@ class Executor(object):
         even without a cache dir (memory-only)."""
         import threading as _threading
         import numpy as _np
-        # warmup is an explicit re-plan point: promote any pending
-        # autopilot comms refit BEFORE fingerprinting, so this rebuild
-        # traces exactly once onto the refit coefficients and the plan
-        # digest never moves between re-plan points (zero retrace
-        # churn post-warmup)
-        from . import comms_plan as _comms_plan
-        _comms_plan.adopt_refit()
         program = program or framework.default_main_program()
         scope = scope or core.global_scope()
         plane = compile_cache.plane()

@@ -78,8 +78,8 @@ _DEFAULTS = {
     # construction, exposing /metrics (Prometheus), /healthz
     # (liveness+readiness), /statusz (JSON runtime report) and
     # /trace/dump (on-demand flight-recorder dump).  0 (the default)
-    # leaves the plane off; monitor.serve(port)/health.serve(port)
-    # start it explicitly (port=0 there picks an ephemeral port).
+    # leaves the plane off; health.serve(port) starts it explicitly
+    # (port=0 there picks an ephemeral port).
     'FLAGS_status_port': 0,
     # readiness staleness bound: with steps recorded, /healthz reports
     # not-ready when the last step is older than this many seconds
@@ -300,74 +300,11 @@ _DEFAULTS = {
     'FLAGS_timeseries': False,
     'FLAGS_timeseries_window': 512,
     'FLAGS_timeseries_sample_steps': 1,
-    # declarative SLOs (fluid/slo.py): ';'-separated clauses like
-    # 'serving/admit_to_done_seconds p99 < 20ms;
-    #  executor/step_timeouts rate == 0', evaluated on the sampling
-    # cadence over a fast/slow window pair (the 5m/1h burn-rate
-    # analogs, scaled to the recorded step count) with
-    # FLAGS_slo_hysteresis consecutive evaluations required to fire
-    # or resolve; firing alerts surface at /alertz, land in the
-    # supervisor decision log, and leave one flight dump per
-    # FLAGS_slo_dump_interval_s.
-    'FLAGS_slo': '',
-    # nonzero: every ServingExecutor declares the standing
-    # 'serving/admit_to_done_seconds p99 < X' objective at
-    # construction (seconds)
-    'FLAGS_serving_slo_p99_s': 0.0,
-    'FLAGS_slo_fast_points': 12,
-    'FLAGS_slo_slow_points': 96,
-    'FLAGS_slo_hysteresis': 3,
-    'FLAGS_slo_dump_interval_s': 60.0,
     # supervisor state-transition flight dumps go through
     # trace.rate_limited_dump under this interval; 0 (the default)
     # keeps the one-dump-per-transition behavior, a positive value
     # bounds a transition storm to one dump per interval
     'FLAGS_supervisor_dump_interval_s': 0.0,
-    # closed-loop autopilot (fluid/autopilot.py): the act/freeze
-    # switch for an ENGAGED adaptation plane — 0 keeps every loop
-    # watching and LOGGING intents (autopilot/frozen_intents,
-    # acted=False in the decision log) while executing nothing: no
-    # refit installs/persists, no flag or ladder changes — every knob
-    # stays bit-identical to static behavior.  The plane only exists
-    # once autopilot.engage() ran; it rides the FLAGS_timeseries
-    # sampling cadence (no thread of its own).
-    'FLAGS_autopilot': True,
-    # minimum seconds between adaptation passes (each pass reads the
-    # windowed series once); 0 = every timeseries sample
-    'FLAGS_autopilot_interval_s': 2.0,
-    # comms-refit honesty guard: only recalibrate when the windowed
-    # comms/plan_pred_over_measured median drifts outside
-    # [1/band, band] — an honest model is left alone
-    'FLAGS_autopilot_honesty_band': 1.5,
-    # minimum measured (wire, wall) dispatch points per collective
-    # before a refit is attempted (fewer cannot support the 2-param
-    # fit; see comms.fit_linear's prior contract)
-    'FLAGS_autopilot_min_points': 4,
-    # where the refit model persists (atomic tmp+rename) so a restart
-    # re-engages onto the recalibrated coefficients; empty = the
-    # comms model path + '.refit.json'.  Deliberately NOT
-    # comms_model.json itself: comms_plan.digest() keys on that
-    # file's identity, and rewriting it in place would move segment
-    # fingerprints outside the adopt_refit() re-plan points.
-    'FLAGS_autopilot_refit_path': '',
-    # skew-aware bucket adaptation: windowed comms/skew_ratio mean
-    # above this is latency-dominated straggling — shrink the fused
-    # buckets; below half of it with honest pricing, widen back
-    'FLAGS_autopilot_skew_high': 1.5,
-    # bounds the bucket loop may move FLAGS_comms_bucket_bytes within
-    'FLAGS_autopilot_bucket_min_bytes': 256 << 10,
-    'FLAGS_autopilot_bucket_max_bytes': 32 << 20,
-    # serving ladder adaptation: drop a never-hit bucket only after
-    # the tenant served this many batches; pre-warm a natural (pow2)
-    # row bucket missing from the ladder once it padded up this often
-    'FLAGS_autopilot_ladder_min_batches': 16,
-    'FLAGS_autopilot_ladder_hits': 8,
-    # serving batch-close deadline bounds (seconds): windowed
-    # occupancy below the low-water mark widens a tenant's close wait
-    # toward the max (fuller batches), admit-to-done p99 pressure
-    # against the declared SLO target shrinks it back toward zero
-    'FLAGS_autopilot_close_wait_max_s': 0.02,
-    'FLAGS_autopilot_occupancy_low': 0.5,
     # Pallas kernel library (ops/pallas/): every fused kernel sits
     # behind the auto-dispatch + dense-fallback contract (see
     # ops/pallas/common.py) — off-TPU or when a gate fails, the dense
@@ -383,28 +320,6 @@ _DEFAULTS = {
     # quant arm with the reduced quant_hbm_temp term when this is
     # available (see _QUANT_MEM_FACTOR_FUSED)
     'FLAGS_pallas_quant_collective': True,
-
-    # --- serving fleet (fleet.py): cross-replica router, SLO-class
-    # policy and priced tenant migration.  0 freezes the plane: the
-    # router falls back to static first-replica placement and every
-    # migration/eviction/class move is logged as an intent
-    # (fleet/frozen_intents) without acting; revert() still works.
-    'FLAGS_fleet': True,
-    # control-loop throttle on the timeseries.sample cadence; a
-    # migration must settle 4x this before the balance loop moves again
-    'FLAGS_fleet_interval_s': 1.0,
-    # queue-depth gap (deepest - shallowest replica) that triggers a
-    # balancing migration
-    'FLAGS_fleet_imbalance_depth': 8,
-    # class policy when a protecting objective fires: 'shed' fails the
-    # non-protected classes fast, 'defer' widens their batch-close
-    # waits instead (they still serve, late)
-    'FLAGS_fleet_shed_mode': 'shed',
-    # close-wait applied to deferred classes under 'defer' mode
-    'FLAGS_fleet_defer_close_wait_s': 0.02,
-    # eviction-pricing fallback for the re-warmup wall before any
-    # serving/warmup_seconds observation exists
-    'FLAGS_fleet_rewarmup_default_s': 1.0,
 }
 
 # v1.6 scripts set these; the TPU runtime ACCEPTS them for script

@@ -27,9 +27,7 @@ thread exposing:
 - ``/timeseries`` — windowed history queries over fluid.timeseries
   (``?name=&window=&points=&resolution=&rank=``: per-series points
   plus derived rates/deltas/percentiles; job history per rank on the
-  aggregator);
-- ``/alertz`` — fluid.slo objective states (firing/pending/resolved
-  with burn rates), freshly evaluated per read.
+  aggregator).
 
 ``distributed/launch.py`` assigns each worker a port and marks rank 0
 the **aggregator**: a background prober scrapes every worker each
@@ -314,38 +312,6 @@ def statusz():
             timeseries_section = timeseries.statusz_rollup()
     except Exception:
         pass
-    # SLO plane (fluid.slo): objective states without forcing an
-    # evaluation — /alertz is the evaluating surface
-    slo_section = None
-    try:
-        from . import slo
-        rep = slo.report()
-        if rep.get('objectives'):
-            slo_section = rep
-    except Exception:
-        pass
-    # autopilot (fluid.autopilot): engagement, refit slot and the
-    # decision trail — rendered once the plane has engaged or decided
-    # anything (a plain static trainer pays nothing)
-    autopilot_section = None
-    try:
-        from . import autopilot
-        rep = autopilot.report()
-        if rep.get('engaged') or rep.get('decisions_total'):
-            autopilot_section = rep
-    except Exception:
-        pass
-    # serving fleet (fluid.fleet): per-replica router signals, the
-    # route table, class policy and the priced decision trail —
-    # rendered once a fleet exists or has decided anything
-    fleet_section = None
-    try:
-        from . import fleet
-        rep = fleet.report()
-        if rep.get('fleets') or rep.get('decisions_total'):
-            fleet_section = rep
-    except Exception:
-        pass
     # Pallas kernel library (ops/pallas/common.py): per-kernel fused
     # vs dense dispatch tallies, the LAST decision with its reason
     # (flag_off / off_tpu / below_floor / ...) and the documented
@@ -380,9 +346,6 @@ def statusz():
         'verify': verify_section,
         'supervisor': supervisor_section,
         'timeseries': timeseries_section,
-        'slo': slo_section,
-        'autopilot': autopilot_section,
-        'fleet': fleet_section,
         'pallas': pallas_section,
         'job': job_section,
         'flags': _all_flags(),
@@ -987,17 +950,14 @@ def _make_handler(aggregator):
                     params = {k: v[-1] for k, v in qs.items()}
                     code, doc = timeseries.http_query(params)
                     self._send_json(code, doc)
-                elif path == '/alertz':
-                    from . import slo
-                    self._send_json(200, slo.alertz())
                 else:
                     self._send_json(404, {
                         'error': 'unknown path %s' % path,
                         'paths': ['/metrics', '/metrics.json',
                                   '/metrics/local', '/healthz',
                                   '/healthz/local', '/statusz',
-                                  '/timeseries', '/alertz',
-                                  '/trace/dump', '/trace/collect']})
+                                  '/timeseries', '/trace/dump',
+                                  '/trace/collect']})
             except Exception as e:  # a broken handler must not kill
                 monitor.add('health/http_errors')
                 try:

@@ -38,7 +38,7 @@ __all__ = [
     'add', 'set_gauge', 'remove_gauge', 'observe', 'counter_value',
     'gauge_value',
     'histogram_value', 'reset', 'set_enabled', 'snapshot', 'flat',
-    'dump_jsonl', 'prometheus_text', 'raw_state', 'serve',
+    'dump_jsonl', 'prometheus_text', 'raw_state',
     'prom_escape_help', 'prom_escape_label', 'prom_sample',
     'prom_histogram_lines',
     'TIME_BUCKETS', 'SIZE_BUCKETS', 'NORM_BUCKETS',
@@ -303,12 +303,3 @@ def prometheus_text(prefix='paddle_tpu'):
                     'paddle_tpu runtime histogram %s' % n, seen)
         prom_histogram_lines(lines, m, edges, counts, total, cnt)
     return '\n'.join(lines) + '\n'
-
-
-def serve(port=None, host=None):
-    """Start the HTTP status plane serving this registry (plus
-    /healthz, /statusz, /trace/dump) on a background thread; returns
-    the fluid.health server handle (`.port` holds the bound port —
-    pass port=0 for an ephemeral one).  Idempotent per process."""
-    from . import health
-    return health.serve(port=port, host=host)

@@ -64,7 +64,6 @@ from . import core
 from . import monitor
 from . import trace as _trace
 from .executor import Executor
-from .flags import get_flag
 from .reader import bucket_for, mask_name, pow2_bucket_ladder
 
 __all__ = [
@@ -216,13 +215,10 @@ class _Tenant(object):
     __slots__ = ('name', 'program', 'scope', 'feed_names', 'fetch_names',
                  'feed_specs', 'mask_specs', 'ladder', 'fingerprint',
                  'pending', 'warmed', 'requests', 'batches', 'rows',
-                 'retraces', 'cache_hit_batches', 'pad_rows', 'errors',
-                 'base_ladder', 'bucket_hits', 'natural_miss_hits',
-                 'close_wait_s', 'slo_class')
+                 'retraces', 'cache_hit_batches', 'pad_rows', 'errors')
 
     def __init__(self, name, program, scope, feed_names, fetch_names,
-                 feed_specs, mask_specs, ladder, fingerprint,
-                 slo_class='interactive'):
+                 feed_specs, mask_specs, ladder, fingerprint):
         self.name = name
         self.program = program
         self.scope = scope
@@ -241,26 +237,12 @@ class _Tenant(object):
         self.cache_hit_batches = 0
         self.pad_rows = 0
         self.errors = 0
-        # ladder-adaptation inputs/state (fluid.autopilot): the ladder
-        # as registered (the one-call revert target), per-ladder-bucket
-        # dispatch hits, hits on the NATURAL pow2 bucket of a batch's
-        # rows when the ladder lacked it (the pre-warm signal), and
-        # the adapted batch-close deadline (None = close immediately,
-        # the static behavior)
-        self.base_ladder = tuple(ladder)
-        self.bucket_hits = {}
-        self.natural_miss_hits = {}
-        self.close_wait_s = None
-        # priority/SLO class (fluid.fleet): requests of a shed class
-        # fail fast while the protected class keeps its latency
-        self.slo_class = str(slo_class)
 
     def report(self):
         return {
             'tenant': self.name,
             'fingerprint': self.fingerprint,
             'bucket_ladder': list(self.ladder),
-            'base_ladder': list(self.base_ladder),
             'warmed': self.warmed,
             'requests_served': self.requests,
             'batches': self.batches,
@@ -270,13 +252,6 @@ class _Tenant(object):
             'pad_rows': self.pad_rows,
             'errors': self.errors,
             'queue_depth': len(self.pending),
-            'bucket_hits': {str(k): v
-                            for k, v in sorted(self.bucket_hits.items())},
-            'natural_miss_hits': {
-                str(k): v
-                for k, v in sorted(self.natural_miss_hits.items())},
-            'close_wait_s': self.close_wait_s,
-            'slo_class': self.slo_class,
         }
 
 
@@ -308,35 +283,15 @@ class ServingExecutor(object):
         self._tenants = {}
         self._rr = []        # tenant round-robin order
         self._rr_next = 0
-        # per-SLO-class shed latch (fluid.fleet's class policy leg):
-        # {slo_class: reason}.  While a class is latched, submit() for
-        # its tenants fails fast (``serving/shed_class``) — a firing
-        # objective on one class sheds the OTHER instead of both.
-        self._class_shed = {}
         self._cond = threading.Condition()
         self._thread = None
         self._stopping = False
         self._closed = False
-        # standing latency objective (fluid.slo): a nonzero
-        # FLAGS_serving_slo_p99_s declares
-        # 'serving/admit_to_done_seconds p99 < X' the moment a
-        # serving plane exists — evaluated on the timeseries sampling
-        # cadence, surfaced at /alertz, cited in the supervisor
-        # decision log on breach
-        p99 = float(get_flag('FLAGS_serving_slo_p99_s', 0.0) or 0.0)
-        if p99 > 0:
-            try:
-                from . import slo
-                slo.declare('serving/admit_to_done_seconds p99 < %g'
-                            % p99, name='serving_latency_p99')
-            except Exception:
-                monitor.add('slo/bad_clauses')
         _live.add(self)
 
     # -- registration --------------------------------------------------
     def add_program(self, name, program, feed_names, fetch_list,
-                    scope=None, feed_specs=None, bucket_ladder=None,
-                    slo_class='interactive'):
+                    scope=None, feed_specs=None, bucket_ladder=None):
         """Make `program` resident as tenant `name`.
 
         `scope` must already hold the program's parameters (run the
@@ -345,10 +300,7 @@ class ServingExecutor(object):
         (per-row shape, dtype) for feeds whose declared var shape has
         dynamic non-batch dims; everything else is derived from the
         program's var declarations.  `bucket_ladder` overrides the
-        power-of-two row ladder (default: up to ``max_batch``).
-        `slo_class` tags the tenant's priority class (e.g.
-        ``'interactive'`` vs ``'batch'``) — the fleet's class policy
-        sheds/defers by this tag when an objective fires."""
+        power-of-two row ladder (default: up to ``max_batch``)."""
         from . import framework as _fw
         if name in self._tenants:
             raise ValueError('tenant %r already registered' % name)
@@ -417,7 +369,7 @@ class ServingExecutor(object):
             block.ops, (), (), donate=False, purpose='serving-id')[:16]
         tenant = _Tenant(name, program, scope or core.Scope(),
                          feed_names, fetch_names, specs, mask_specs,
-                         ladder, fp, slo_class=slo_class)
+                         ladder, fp)
         with self._cond:
             self._tenants[name] = tenant
             self._rr.append(name)
@@ -472,37 +424,6 @@ class ServingExecutor(object):
                              name='pt_serving_warmup').start()
         return self
 
-    def warmup_tenant(self, name, wait=True, timeout=None):
-        """Pre-compile ONE tenant's whole bucket ladder (the fleet
-        migration's pre-warm leg: the target replica warms just the
-        arriving tenant through the persistent compile cache before
-        the route flips, so migrated traffic keeps the zero-retrace
-        contract).  Returns the measured warmup wall in seconds
-        (wait=True) or 0.0 (wait=False)."""
-        t = self._tenants[name]
-        t0 = _time.perf_counter()
-        results = []
-        for bucket in t.ladder:
-            results.append(self._exe.warmup(
-                t.program,
-                feed_shapes=self._bucket_feed_shapes(t, bucket),
-                fetch_list=t.fetch_names, scope=t.scope))
-            monitor.add('serving/warmup_buckets')
-
-        def finish():
-            for res in results:
-                res.wait(timeout)
-            t.warmed = True
-            wall = _time.perf_counter() - t0
-            monitor.observe('serving/warmup_seconds', wall)
-            return wall
-
-        if wait:
-            return finish()
-        threading.Thread(target=finish, daemon=True,
-                         name='pt_serving_warmup_tenant').start()
-        return 0.0
-
     @property
     def ready(self):
         """True when every registered tenant finished warmup."""
@@ -522,9 +443,7 @@ class ServingExecutor(object):
         dispatched; an ALREADY-expired deadline (``deadline_s <= 0``)
         is shed at admission, before it can queue.  While the replica
         is degraded (supervisor recovery), every submit completes
-        exceptionally with ``ServingDegraded`` immediately — and so
-        do requests of a shed SLO class (``serving/shed_class``, the
-        fleet's class policy)."""
+        exceptionally with ``ServingDegraded`` immediately."""
         from concurrent.futures import Future
         if _degraded_reason is not None:
             # shed, don't queue: a mid-recovery backend answering
@@ -539,16 +458,6 @@ class ServingExecutor(object):
         if t is None:
             raise KeyError('unknown tenant %r (resident: %r)'
                            % (tenant, sorted(self._tenants)))
-        shed_reason = self._class_shed.get(t.slo_class)
-        if shed_reason is not None:
-            # class-based shedding (the fleet's priority leg): a
-            # firing objective on the protected class sheds THIS class
-            # while the protected one keeps serving
-            monitor.add('serving/shed_class')
-            fut = Future()
-            fut.set_exception(ServingDegraded(
-                'class %r shed: %s' % (t.slo_class, shed_reason)))
-            return fut
         if deadline_s is not None and float(deadline_s) <= 0:
             # admission-time expiry: a deadline that has already
             # passed must fail fast HERE, not queue behind live work
@@ -605,29 +514,6 @@ class ServingExecutor(object):
                 target=self._loop, daemon=True, name='pt_serving')
             self._thread.start()
 
-    def _close_hold_s(self, t):
-        """Seconds tenant `t`'s batch-close deadline still holds its
-        admission window open (caller holds ``_cond``): with an
-        adapted ``close_wait_s`` a sub-capacity batch keeps queueing
-        while its oldest request is younger than the wait.  0 closes
-        the window now — the static (no deadline) behavior, a batch
-        already at bucket capacity, an aged-out oldest request, or a
-        queued request whose submit deadline would pass inside the
-        hold (deadline-AWARE closing: coalescing for occupancy must
-        never turn a meetable deadline into a shed)."""
-        wait = t.close_wait_s
-        if not wait or not t.pending:
-            return 0.0
-        rows = sum(req.rows for req in t.pending)
-        if rows >= t.ladder[-1]:
-            return 0.0
-        now = _time.perf_counter()
-        remaining = wait - (now - t.pending[0].t_admit)
-        for req in t.pending:
-            if req.deadline is not None:
-                remaining = min(remaining, req.deadline - now)
-        return remaining if remaining > 0 else 0.0
-
     def _take_batch(self, wait_s):
         """Coalesce the next batch: pick the next tenant (round-robin)
         with pending work and drain its queue up to the largest
@@ -637,20 +523,10 @@ class ServingExecutor(object):
                 if wait_s:
                     self._cond.wait(wait_s)
             n = len(self._rr)
-            defer_wait = None
             for i in range(n):
                 name = self._rr[(self._rr_next + i) % n]
                 t = self._tenants[name]
                 if not t.pending:
-                    continue
-                hold = self._close_hold_s(t)
-                if hold > 0 and not self._stopping:
-                    # adapted batch-close deadline: the window stays
-                    # open for more rows while the oldest request is
-                    # younger than the tenant's close wait — bounded
-                    # latency traded for occupancy
-                    defer_wait = hold if defer_wait is None \
-                        else min(defer_wait, hold)
                     continue
                 self._rr_next = (self._rr_next + i + 1) % n
                 reqs = []
@@ -685,13 +561,6 @@ class ServingExecutor(object):
                 if not reqs:
                     continue   # whole window was cancelled
                 return _Batch(t, reqs, rows)
-            if defer_wait is not None:
-                # every pending tenant is inside its close window:
-                # sleep out the shortest remaining hold (bounded, and
-                # a submit() notify wakes the wait early) instead of
-                # spinning on the lock
-                monitor.add('serving/close_wait_holds')
-                self._cond.wait(min(defer_wait, 0.005))
         return None
 
     def _dispatch(self, batch):
@@ -719,15 +588,6 @@ class ServingExecutor(object):
             if waste:
                 monitor.add('serving/bucket_pad_waste_bytes', waste)
             t.pad_rows += bucket - batch.rows
-            # ladder-adaptation signals: which rung served, and — when
-            # the rows' NATURAL pow2 bucket is missing from the ladder
-            # — the rung traffic keeps padding up past (the autopilot's
-            # pre-warm candidate)
-            t.bucket_hits[bucket] = t.bucket_hits.get(bucket, 0) + 1
-            nat = 1 << max(0, int(batch.rows - 1).bit_length())
-            if nat < bucket:
-                t.natural_miss_hits[nat] = \
-                    t.natural_miss_hits.get(nat, 0) + 1
             # server-wide pad-waste ratio, derived from the same
             # per-tenant pad/row tallies the occupancy counters feed
             # (t.rows lands below, so this batch's live rows count in)
@@ -825,131 +685,6 @@ class ServingExecutor(object):
                             _deliver(req.future, exc=e)
                 inflight = None
 
-    # -- ladder / deadline adaptation (fluid.autopilot) ----------------
-    def adapt_ladder(self, tenant, drop=(), add=(), warm=True):
-        """Apply one bucket-ladder adaptation to a resident tenant:
-        `drop` rungs leave the ladder (traffic that would have landed
-        there pads up to the next rung; the LARGEST rung can never
-        drop — it bounds admissible request sizes), `add` rungs join
-        it, pre-compiled through ``Executor.warmup`` + the persistent
-        compile cache BEFORE they become admissible so an adapted
-        ladder keeps the zero-serving-path-retrace contract.  Counted
-        ``serving/bucket_dropped`` / ``serving/bucket_prewarmed``.
-        Returns the new ladder."""
-        t = self._tenants[tenant]
-        drop = {int(b) for b in drop}
-        add = sorted({int(b) for b in add})
-        ladder = [b for b in t.ladder
-                  if b not in drop or b == t.ladder[-1]]
-        dropped = len(t.ladder) - len(ladder)
-        prewarmed = 0
-        for b in add:
-            if b in ladder or b <= 0 or b > t.ladder[-1]:
-                continue
-            if warm:
-                self._exe.warmup(
-                    t.program,
-                    feed_shapes=self._bucket_feed_shapes(t, b),
-                    fetch_list=t.fetch_names, scope=t.scope).wait()
-            ladder.append(b)
-            prewarmed += 1
-        ladder.sort()
-        with self._cond:
-            t.ladder = tuple(ladder)
-            t.bucket_hits = {b: n for b, n in t.bucket_hits.items()
-                             if b in t.ladder}
-            t.natural_miss_hits = {
-                b: n for b, n in t.natural_miss_hits.items()
-                if b not in t.ladder}
-        if dropped:
-            monitor.add('serving/bucket_dropped', float(dropped))
-        if prewarmed:
-            monitor.add('serving/bucket_prewarmed', float(prewarmed))
-        return t.ladder
-
-    def set_close_wait(self, tenant, wait_s):
-        """Set (or clear, with None/0) a tenant's batch-close
-        deadline: how long a sub-capacity batch may wait for more
-        rows before dispatching.  None/0 restores the static
-        close-immediately behavior."""
-        t = self._tenants[tenant]
-        t.close_wait_s = float(wait_s) if wait_s else None
-        return t.close_wait_s
-
-    # -- SLO-class policy (fluid.fleet) --------------------------------
-    def set_class_shed(self, slo_class, reason):
-        """Latch one SLO class into shed: every submit() for a tenant
-        of this class fails fast with ``ServingDegraded``
-        (``serving/shed_class``) until ``clear_class_shed`` — the
-        fleet's 'shed the batch class, protect the interactive one'
-        move.  Already-queued requests of the class still serve (they
-        were admitted under the old policy)."""
-        with self._cond:
-            self._class_shed[str(slo_class)] = str(reason)
-        monitor.set_gauge('serving/class_shed', len(self._class_shed))
-
-    def clear_class_shed(self, slo_class=None):
-        """Clear one class's shed latch (or all with None)."""
-        with self._cond:
-            if slo_class is None:
-                self._class_shed.clear()
-            else:
-                self._class_shed.pop(str(slo_class), None)
-        monitor.set_gauge('serving/class_shed', len(self._class_shed))
-
-    def class_shed(self):
-        """{slo_class: reason} snapshot of the shed latches."""
-        with self._cond:
-            return dict(self._class_shed)
-
-    def tenants_of_class(self, slo_class):
-        """Resident tenant names carrying `slo_class` (the fleet's
-        defer leg iterates these to widen close waits)."""
-        return [t.name for t in self._tenant_list()
-                if t.slo_class == str(slo_class)]
-
-    # -- eviction (fluid.fleet churn policy) ---------------------------
-    def remove_program(self, name, drain=True, timeout=30.0):
-        """Evict tenant `name`: stop admitting (unknown-tenant errors
-        from now on), optionally drain its queued requests through the
-        dispatcher, then drop it from the registry so its scope's
-        device residency is releasable (memviz stops attributing it
-        once the caller drops its own references).  The fleet prices
-        this against the re-warmup wall a return would cost through
-        the persistent compile cache.  Counted
-        ``serving/tenant_evicted``."""
-        with self._cond:
-            t = self._tenants.get(name)
-            if t is None:
-                raise KeyError('unknown tenant %r' % name)
-            if not drain:
-                while t.pending:
-                    _deliver(t.pending.popleft().future,
-                             exc=RuntimeError(
-                                 'tenant %r evicted' % name))
-        if drain:
-            deadline = _time.perf_counter() + float(timeout)
-            while True:
-                with self._cond:
-                    if not t.pending:
-                        break
-                    self._cond.notify()
-                if _time.perf_counter() > deadline:
-                    raise RuntimeError(
-                        'tenant %r drain timed out with %d queued'
-                        % (name, len(t.pending)))
-                _time.sleep(0.002)
-        with self._cond:
-            self._tenants.pop(name, None)
-            if name in self._rr:
-                self._rr.remove(name)
-                self._rr_next = self._rr_next % max(1, len(self._rr))
-        monitor.add('serving/tenant_evicted')
-        monitor.set_gauge('serving/resident_programs',
-                          len(self._tenants))
-        monitor.set_gauge('serving/queue_depth/%s' % name, 0.0)
-        return t
-
     # -- lifecycle / status --------------------------------------------
     def stop(self, drain=True):
         """Stop the dispatcher.  `drain=True` serves queued requests
@@ -988,7 +723,6 @@ class ServingExecutor(object):
             'ready': all(t.warmed for t in tenants),
             'max_batch': self.max_batch,
             'tenants': [t.report() for t in tenants],
-            'class_shed': self.class_shed(),
             'compile_plane': compile_cache.plane().stats(),
         }
 
@@ -1021,12 +755,6 @@ def resident_report():
     /statusz section body)."""
     return [s.resident_report() for s in list(_live)
             if not s._closed]
-
-
-def live_executors():
-    """Live (non-closed) ServingExecutors — the autopilot's serving
-    adaptation walks these the way memviz walks tenant_scopes()."""
-    return [s for s in list(_live) if not s._closed]
 
 
 def tenant_scopes():

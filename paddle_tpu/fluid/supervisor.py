@@ -5,9 +5,8 @@ PR 11 built every recovery PRIMITIVE — crash-consistent checkpoint
 generations, priced cross-topology reshard, ``rejoin_trainer``,
 deterministic fault injection — but a human still had to notice a dead
 worker and drive the recovery by hand.  This module is the CONTROLLER
-(the ROADMAP item-4 follow-on, and the controller-shaped half of the
-item-2 autopilot arc): the first plane where the telemetry *acts*
-instead of being read.
+(the ROADMAP item-4 follow-on): the first plane where the telemetry
+*acts* instead of being read.
 
 **Periodic async checkpoints with backpressure.**  An attached
 supervisor snapshots the training program's persistables at a step
@@ -97,7 +96,6 @@ from .flags import get_flag
 __all__ = [
     'Supervisor', 'Recovered', 'StepTimeoutError', 'guard_dispatch',
     'attach', 'detach', 'current', 'active', 'report', 'reset',
-    'record_slo_breach',
 ]
 
 # decision log: module-level (like elastic._refusals) so /statusz keeps
@@ -835,41 +833,6 @@ def decisions():
     """A copy of the bounded decision log (newest last)."""
     with _lock:
         return [dict(d) for d in _decisions]
-
-
-def record_slo_breach(alert):
-    """fluid.slo's feed: a firing objective lands in THE decision log
-    (kind='slo_breach', the breaching series/window in info) so a
-    later recovery's post-mortem can cite the objective that was
-    already burning when the controller acted.  Works with or without
-    an attached controller — the trail is module-level state."""
-    info = {
-        'series': alert.get('series'),
-        'clause': alert.get('clause'),
-        'measured_fast': alert.get('measured_fast'),
-        'measured_slow': alert.get('measured_slow'),
-        'burn_fast': alert.get('burn_fast'),
-        'burn_slow': alert.get('burn_slow'),
-        'window': alert.get('window'),
-    }
-    sup = _active
-    if sup is not None:
-        return sup._decide('slo_breach', alert.get('name'),
-                           acted=False, **info)
-    rec = {
-        'seq': None, 'wall_unix': time.time(), 'step': None,
-        'kind': 'slo_breach', 'choice': alert.get('name'),
-        'acted': False, 'frozen': False, 'fault': None,
-        'state': None, 'info': info,
-    }
-    with _lock:
-        _seq[0] += 1
-        rec['seq'] = _seq[0]
-        _decisions.append(rec)
-        del _decisions[:-_DECISIONS_CAP]
-    monitor.add('supervisor/decisions')
-    monitor.add('supervisor/decision/slo_breach')
-    return rec
 
 
 def report():

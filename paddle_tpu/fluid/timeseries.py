@@ -1,11 +1,9 @@
 """fluid.timeseries — bounded windowed history over the monitor
 registry.
 
-Every signal fluid.monitor holds is a point-in-time snapshot; the
-supervisor, the autopilot (ROADMAP item 2) and the serving-fleet
-router (item 3) need *windowed* history — rates, trends,
-percentiles-over-time — to price adaptations honestly.  This module
-is that substrate:
+Every signal fluid.monitor holds is a point-in-time snapshot; a
+reader of rates, trends and percentiles-over-time needs *windowed*
+history.  This module is that substrate:
 
 **Local history.**  ``maybe_sample(step)`` (called from the executor's
 step boundary and the aggregator heartbeat) appends ONE point per
@@ -117,28 +115,6 @@ def sample(step=None, now=None):
         n_series = len(_local)
     monitor.add('timeseries/samples')
     monitor.set_gauge('timeseries/series', float(n_series))
-    # SLO objectives ride the same cadence: evaluated here (worker
-    # step boundary) and on the aggregator heartbeat, never off a
-    # thread of their own
-    try:
-        from . import slo
-        slo.maybe_evaluate(now=now)
-    except Exception:
-        monitor.add('slo/eval_errors')
-    # the autopilot's adaptation loops ride the same cadence (one dict
-    # read when not engaged, interval-throttled when engaged)
-    try:
-        from . import autopilot
-        autopilot.maybe_tick(now=now)
-    except Exception:
-        monitor.add('autopilot/tick_errors')
-    # the serving fleet's class/balance/pressure loops ride here too
-    # (one weak-set read when no fleet exists)
-    try:
-        from . import fleet
-        fleet.maybe_tick(now=now)
-    except Exception:
-        monitor.add('fleet/tick_errors')
 
 
 def job_sample(rank, state, now=None):

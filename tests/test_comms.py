@@ -95,47 +95,6 @@ def test_account_dispatch_points_and_histograms():
     assert monitor.counter_value('comms/collective_calls') == 2.0
 
 
-def test_phase_arms_feed_refit_pool():
-    """rs_ag-armed records decompose into reducescatter + allgather
-    phase refit points (the entries that price them), and quant
-    records refit their own 'allreduce_quant' entry — neither pollutes
-    the dense-allreduce fit."""
-    from paddle_tpu.fluid import comms_plan
-    comms.clear_dispatch_points()
-    n, pl = 8, float(4 << 20)
-    with comms.collecting('fp_phase'):
-        comms.record_trace(
-            'allreduce', pl, dtype='float32', axis='dp',
-            participants=n, arm='rs_ag',
-            wire=comms.wire_bytes('reducescatter', pl, n)
-            + comms.wire_bytes('allgather', pl / n, n),
-            dense_wire=comms.wire_bytes('allreduce', pl, n))
-        comms.record_trace(
-            'allreduce_quant', pl, dtype='float32', axis='dp',
-            participants=n, arm='quant',
-            wire=comms_plan.quant_wire_bytes(pl, 4, n),
-            dense_wire=comms.wire_bytes('allreduce', pl, n))
-        comms.record_trace('allreduce', pl, dtype='float32',
-                           axis='dp', participants=n, arm='dense')
-    comms.account_dispatch(comms.records_for('fp_phase'), 0.01)
-    rs = comms.dispatch_points('reducescatter')
-    ag = comms.dispatch_points('allgather')
-    qt = comms.dispatch_points('allreduce_quant')
-    dense = comms.dispatch_points('allreduce')
-    assert len(rs) == len(ag) == len(qt) == len(dense) == 1
-    # phase points carry the PHASE wire, not the composite
-    assert rs[0][0] == pytest.approx(
-        comms.wire_bytes('reducescatter', pl, n))
-    assert ag[0][0] == pytest.approx(
-        comms.wire_bytes('allgather', pl / n, n))
-    assert dense[0][0] == pytest.approx(
-        comms.wire_bytes('allreduce', pl, n))
-    # wire-share attribution still reproduces the segment wall
-    walls = sum(p[1] for p in rs + ag + qt + dense)
-    assert walls == pytest.approx(0.01)
-    comms.clear_dispatch_points()
-
-
 def test_summarize_for_span_annotation():
     with comms.collecting('fp3'):
         comms.record_trace('allreduce', 100, axis='dp', participants=8)
@@ -164,6 +123,21 @@ def test_cost_model_fit_and_predict():
     assert bta > 0
     a, bta = comms.fit_linear([(1e6, 0.001)])
     assert bta > 0 and a == 0.0
+
+
+@pytest.mark.parametrize('points,want', [
+    ([], (0.0, 1e-12)),
+    ([(1e6, 1e-3)], (0.0, 1e-9)),       # one point: through the origin
+    ([(1e6, 0.0), (1e6, -1.0)], (0.0, 1e-12)),   # no positive wall
+    ([(float(b), 2e-4 + 2e-9 * b)
+      for b in (1 << 20, 4 << 20, 16 << 20)], (2e-4, 2e-9)),
+], ids=['empty', 'one_point', 'no_positive_wall', 'exact_line'])
+def test_fit_linear_cases(points, want):
+    """What tools/comms_calibrate.py's sweeps rest on: degenerate
+    inputs give a finite model, an exact line gives itself."""
+    a, b = comms.fit_linear(points)
+    assert a == pytest.approx(want[0], rel=1e-6)
+    assert b == pytest.approx(want[1], rel=1e-6)
 
 
 # --------------------------------------------- real collective telemetry

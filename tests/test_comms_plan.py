@@ -446,6 +446,23 @@ def test_zero_retrace_post_warmup():
         assert monitor.counter_value('parallel/segment_cache_hit') >= 5
 
 
+def test_digest_steady_across_warmups():
+    # the digest is flags + the model file's identity and nothing a
+    # warm-up moves: the fingerprints that fold it stay put
+    fluid.set_flags({'FLAGS_comms_plan': True})
+    main_p, startup, loss = _build_mlp()
+    exe = fluid.Executor(fluid.XLAPlace(0))
+    shapes = {'x': ((16, 64), 'float32')}
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        d0 = comms_plan.digest()
+        assert 'refit' not in d0
+        for _ in range(2):
+            exe.warmup(main_p, feed_shapes=shapes,
+                       fetch_list=[loss]).wait()
+            assert comms_plan.digest() == d0
+
+
 def test_stat_summary_plan_rollup(tmp_path, capsys):
     import importlib
     import os

@@ -135,7 +135,7 @@ def test_status_endpoints_serve_and_validate():
         exe.run(startup)
         exe.run(main, feed={'x': np.ones((4, 8), 'float32')},
                 fetch_list=[loss])
-    srv = monitor.serve(port=0)   # monitor.serve delegates to health
+    srv = health.serve(port=0)
     assert srv.port > 0
     try:
         code, text = _get(srv.url + '/metrics')
@@ -176,6 +176,29 @@ def test_status_endpoints_serve_and_validate():
     finally:
         srv.stop()
     assert health.server() is None
+
+
+# README "Health & status endpoints": every section is always present
+# (None until its plane has something to say)
+STATUSZ_KEYS = {
+    'status', 'step_report', 'caches', 'serving', 'memory',
+    'comms_plan', 'auto_shard', 'elastic', 'verify', 'supervisor',
+    'timeseries', 'pallas', 'job', 'flags', 'versions', 'trace_active',
+    'monitor',
+}
+
+
+def test_statusz_keys_are_the_documented_list_and_alertz_is_gone():
+    srv = health.serve(port=0)
+    try:
+        code, body = _get(srv.url + '/statusz')
+        assert code == 200 and set(json.loads(body)) == STATUSZ_KEYS
+        code, body = _get(srv.url + '/alertz')
+        doc = json.loads(body)
+        assert code == 404 and '/alertz' not in doc['paths']
+        assert '/timeseries' in doc['paths']
+    finally:
+        srv.stop()
 
 
 def test_healthz_not_ready_before_first_step():

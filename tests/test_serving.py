@@ -359,6 +359,40 @@ def test_health_readiness_and_statusz(exe):
     assert ready in (None, True)
 
 
+def test_sub_capacity_batch_closes_without_waiting(exe):
+    """One queued row of a ladder that reaches 8 is a batch NOW: the
+    dispatcher holds no window open for more rows."""
+    from concurrent.futures import Future
+    main_p, _startup, y = _build_mlp(width=16, seed=14)
+    srv = serving.ServingExecutor(max_batch=8, executor=exe)
+    t = srv.add_program('m', main_p, ['x'], [y])
+    try:
+        req = serving._Request('m', {'x': np.zeros((1, 8), 'float32')},
+                               1, Future())
+        t.pending.append(req)      # queued, no dispatcher thread
+        batch = srv._take_batch(0.0)
+        assert batch is not None and batch.requests == [req]
+        assert batch.rows == 1 and not t.pending
+        assert srv._take_batch(0.0) is None
+    finally:
+        srv.close()
+
+
+def test_reports_carry_the_static_keys_only(exe):
+    main_p, _startup, y = _build_mlp(width=16, seed=15)
+    srv = serving.ServingExecutor(max_batch=2, executor=exe)
+    t = srv.add_program('m', main_p, ['x'], [y])
+    try:
+        assert set(t.report()) == {
+            'tenant', 'fingerprint', 'bucket_ladder', 'warmed',
+            'requests_served', 'batches', 'rows', 'cache_hit_batches',
+            'retraces', 'pad_rows', 'errors', 'queue_depth'}
+        assert set(srv.resident_report()) == {
+            'ready', 'max_batch', 'tenants', 'compile_plane'}
+    finally:
+        srv.close()
+
+
 def test_predictor_bucket_parity(exe, tmp_path):
     """Single-shot predictor run() routes through the same
     pad/mask/slice helper: padded and unpadded results bitwise-equal

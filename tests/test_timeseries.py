@@ -1,20 +1,18 @@
-"""fluid.timeseries + fluid.slo — windowed history and SLO burn-rate
-alerting.
+"""fluid.timeseries — windowed history.
 
 The acceptance contract: window math survives the ugly inputs real
 jobs produce — counter resets from a restarted worker (the post-reset
 value IS the delta, prometheus rate() semantics), gauge gaps from a
 dead worker's missed heartbeats (reported as holes, never bridged),
-empty windows (None, not a crash, and no-data neither fires nor
-resolves an SLO); the alert state machine holds its hysteresis
-against a flapping series and scales its slow window honestly on
-short histories; the exposition linter rejects the per-bucket-count
-histogram rendering; rate_limited_dump claims atomically."""
+empty windows (None, not a crash); the sampler appends one point a
+series and pulls in no plane above it; the exposition linter rejects
+the per-bucket-count histogram rendering; rate_limited_dump claims
+atomically."""
 
 import pytest
 
 import paddle_tpu.fluid as fluid
-from paddle_tpu.fluid import (health, monitor, slo, supervisor,
+from paddle_tpu.fluid import (health, monitor, supervisor,
                               timeseries, trace)
 
 
@@ -23,12 +21,7 @@ def _clean():
     yield
     fluid.set_flags({'FLAGS_timeseries': False,
                      'FLAGS_timeseries_window': 512,
-                     'FLAGS_timeseries_sample_steps': 1,
-                     'FLAGS_slo': '',
-                     'FLAGS_slo_fast_points': 12,
-                     'FLAGS_slo_slow_points': 96,
-                     'FLAGS_slo_hysteresis': 3})
-    slo.reset()
+                     'FLAGS_timeseries_sample_steps': 1})
     timeseries.reset()
     supervisor.reset()
     trace.reset()
@@ -138,6 +131,23 @@ class TestSampling:
         # points carry (ts, step, value)
         assert doc['points'][0][1] == 1 and doc['points'][1][1] == 2
 
+    def test_sample_is_one_point_a_series_and_imports_nothing(self):
+        # the sampler is the base of the telemetry: a sample touches
+        # the registry and its own rings, never a plane above it
+        import sys
+        fluid.set_flags({'FLAGS_timeseries': True})
+        monitor.add('demo/c')
+        monitor.set_gauge('demo/g', 1.0)
+        monitor.observe('demo/h', 0.01)
+        before = set(sys.modules)
+        assert timeseries.maybe_sample(step=1) is True
+        grown = {m for m in set(sys.modules) - before
+                 if m.startswith('paddle_tpu.fluid')}
+        assert grown == set()
+        lens = {n: timeseries.window(n)['n'] for n in timeseries.names()}
+        assert {'demo/c', 'demo/g', 'demo/h'} <= set(lens)
+        assert set(lens.values()) == {1}
+
     def test_sample_stride(self):
         fluid.set_flags({'FLAGS_timeseries': True,
                          'FLAGS_timeseries_sample_steps': 4})
@@ -209,127 +219,6 @@ class TestSampling:
         # preferred ordering puts executor series first
         assert names[0] == 'executor/run_calls'
         assert all(r['spark'] for r in roll['series'])
-
-
-# --------------------------------------------------------------- slo
-def _gauge_run(values, start=100.0):
-    """Feed a synthetic gauge level per sample tick and evaluate."""
-    for i, v in enumerate(values):
-        monitor.set_gauge('demo/level', float(v))
-        timeseries.sample(step=i, now=start + i)
-
-
-class TestSLO:
-    def test_parse_units_and_forms(self):
-        assert slo.parse('a/b p99 < 20ms') == ('a/b', 'p99', '<',
-                                               pytest.approx(0.02))
-        assert slo.parse('a/b rate == 0') == ('a/b', 'rate', '==', 0.0)
-        assert slo.parse('a/b < 90%') == ('a/b', 'value', '<',
-                                          pytest.approx(0.9))
-        assert slo.parse('a/b value <= 5us')[3] == pytest.approx(5e-6)
-        for bad in ('a/b', 'a/b frobnicate < 1', 'a/b ~ 1',
-                    'a/b < 1parsec', 'a/b p99 < 1 extra'):
-            with pytest.raises(ValueError):
-                slo.parse(bad)
-
-    def test_bad_flag_clause_counts_not_crashes(self):
-        fluid.set_flags({'FLAGS_timeseries': True,
-                         'FLAGS_slo': 'broken clause here extra;'
-                                      'demo/level < 10'})
-        monitor.set_gauge('demo/level', 1.0)
-        timeseries.sample(step=0)
-        assert monitor.counter_value('slo/bad_clauses') == 1
-        assert len(slo.objectives()) == 1
-
-    def test_fires_after_hysteresis_and_cites_supervisor(self):
-        fluid.set_flags({'FLAGS_timeseries': True,
-                         'FLAGS_slo_fast_points': 3,
-                         'FLAGS_slo_slow_points': 6,
-                         'FLAGS_slo_hysteresis': 2})
-        slo.declare('demo/level < 10', name='level_cap')
-        _gauge_run([1, 1, 1])                    # healthy
-        assert slo.objectives()[0]['state'] == 'ok'
-        _gauge_run([50], start=103.0)            # first breach
-        assert slo.objectives()[0]['state'] == 'pending'
-        assert monitor.counter_value('slo/alerts_fired') == 0
-        _gauge_run([50, 50], start=104.0)        # hold the breach
-        doc = slo.objectives()[0]
-        assert doc['state'] == 'firing'
-        assert doc['burn_fast'] == pytest.approx(5.0)
-        assert monitor.counter_value('slo/alerts_fired') == 1
-        recs = [d for d in supervisor.decisions()
-                if d.get('kind') == 'slo_breach']
-        assert recs and recs[-1]['info']['series'] == 'demo/level'
-        assert recs[-1]['info']['window']['fast_points'] == 3
-        az = slo.alertz()
-        assert [a['name'] for a in az['firing']] == ['level_cap']
-
-    def test_flapping_series_neither_fires_nor_resolves(self):
-        fluid.set_flags({'FLAGS_timeseries': True,
-                         'FLAGS_slo_fast_points': 2,
-                         'FLAGS_slo_slow_points': 4,
-                         'FLAGS_slo_hysteresis': 3})
-        slo.declare('demo/level < 10', name='level_cap')
-        # oscillate across the threshold every sample: the bad streak
-        # never reaches 3 (both-window breaches), the good streak is
-        # zeroed by every breach -> pending forever, zero alerts
-        _gauge_run([50, 1] * 12)
-        assert monitor.counter_value('slo/alerts_fired') == 0
-        assert monitor.counter_value('slo/alerts_resolved') == 0
-        assert slo.objectives()[0]['state'] == 'pending'
-
-    def test_resolve_path_and_trail(self):
-        fluid.set_flags({'FLAGS_timeseries': True,
-                         'FLAGS_slo_fast_points': 2,
-                         'FLAGS_slo_slow_points': 4,
-                         'FLAGS_slo_hysteresis': 2})
-        slo.declare('demo/level < 10', name='level_cap')
-        _gauge_run([50, 50, 50, 50])
-        assert slo.objectives()[0]['state'] == 'firing'
-        _gauge_run([1, 1], start=110.0)     # clean run >= hysteresis
-        doc = slo.objectives()[0]
-        assert doc['state'] == 'resolved'
-        assert monitor.counter_value('slo/alerts_resolved') == 1
-        az = slo.alertz()
-        assert az['resolved_trail']
-        _gauge_run([1, 1, 1, 1], start=115.0)   # 2h clean -> ok
-        assert slo.objectives()[0]['state'] == 'ok'
-
-    def test_short_history_scales_slow_window(self):
-        fluid.set_flags({'FLAGS_timeseries': True,
-                         'FLAGS_slo_fast_points': 2,
-                         'FLAGS_slo_slow_points': 96,
-                         'FLAGS_slo_hysteresis': 1})
-        slo.declare('demo/level < 10', name='level_cap')
-        _gauge_run([50, 50, 50])
-        doc = slo.objectives()[0]
-        w = doc['window']
-        assert w['scaled'] is True
-        assert w['available_points'] == 3 < w['slow_points'] == 96
-        # the scaled slow window still measured (and breached): a
-        # short job is not blind for an hour of steps
-        assert doc['measured_slow'] == 50.0 and doc['state'] == 'firing'
-
-    def test_empty_window_neither_fires_nor_resolves(self):
-        fluid.set_flags({'FLAGS_timeseries': True,
-                         'FLAGS_slo_hysteresis': 1})
-        slo.declare('demo/never_recorded < 1', name='ghost')
-        for _ in range(5):
-            slo.evaluate_all(now=100.0)
-        doc = slo.objectives()[0]
-        assert doc['state'] == 'ok' and doc.get('no_data') is True
-        assert monitor.counter_value('slo/alerts_fired') == 0
-
-    def test_zero_budget_burn_reports_raw_measure(self):
-        fluid.set_flags({'FLAGS_timeseries': True,
-                         'FLAGS_slo_fast_points': 2,
-                         'FLAGS_slo_slow_points': 4,
-                         'FLAGS_slo_hysteresis': 1})
-        slo.declare('demo/level == 0', name='zero_budget')
-        _gauge_run([3, 3, 3])
-        doc = slo.objectives()[0]
-        assert doc['state'] == 'firing'
-        assert doc['burn_fast'] == pytest.approx(3.0)
 
 
 # ----------------------------------------------------- exposition lint

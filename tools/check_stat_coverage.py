@@ -326,10 +326,10 @@ REQUIRED = [
     ('paddle_tpu/fluid/comms_plan.py', 'verify_buckets'),
     ('paddle_tpu/parallel/plan.py', 'progcheck.check_sharding'),
     ('paddle_tpu/fluid/health.py', 'progcheck.report'),
-    # time-series telemetry plane (fluid/timeseries.py + fluid/slo.py):
-    # the windowed-history sampler's own accounting, the job-history
-    # retention at the aggregator, the SLO evaluator/alert counters,
-    # and the step-boundary/heartbeat wiring that feeds them —
+    # time-series telemetry plane (fluid/timeseries.py): the
+    # windowed-history sampler's own accounting, the job-history
+    # retention at the aggregator, and the step-boundary/heartbeat
+    # wiring that feeds them —
     # tools/check_timeseries.py exercises the plane against a live
     # two-process job
     ('paddle_tpu/fluid/timeseries.py', 'timeseries/samples'),
@@ -337,66 +337,15 @@ REQUIRED = [
     ('paddle_tpu/fluid/timeseries.py', 'timeseries/job_samples'),
     ('paddle_tpu/fluid/timeseries.py', 'timeseries/gap_points'),
     ('paddle_tpu/fluid/timeseries.py', 'timeseries/series'),
-    ('paddle_tpu/fluid/slo.py', 'slo/objectives'),
-    ('paddle_tpu/fluid/slo.py', 'slo/evals'),
-    ('paddle_tpu/fluid/slo.py', 'slo/eval_errors'),
-    ('paddle_tpu/fluid/slo.py', 'slo/alerts_fired'),
-    ('paddle_tpu/fluid/slo.py', 'slo/alerts_resolved'),
-    ('paddle_tpu/fluid/slo.py', 'slo/alerts_pending'),
-    ('paddle_tpu/fluid/slo.py', 'slo/bad_clauses'),
-    ('paddle_tpu/fluid/slo.py', 'slo/firing'),
-    ('paddle_tpu/fluid/slo.py', 'supervisor.record_slo_breach'),
     ('paddle_tpu/fluid/executor.py', '_tseries.maybe_sample'),
     ('paddle_tpu/fluid/health.py', 'timeseries.job_sample'),
     ('paddle_tpu/fluid/health.py', 'timeseries.job_gap'),
     ('paddle_tpu/fluid/health.py', 'timeseries.http_query'),
-    ('paddle_tpu/fluid/health.py', 'slo.alertz'),
-    ('paddle_tpu/fluid/supervisor.py', 'supervisor/decision/slo_breach'),
     ('paddle_tpu/fluid/trace.py', 'trace/dumps_suppressed'),
-    ('paddle_tpu/fluid/serving.py', 'FLAGS_serving_slo_p99_s'),
     ('tools/stat_summary.py', 'ts.counter_deltas'),
-    # closed-loop autopilot (fluid/autopilot.py): the bounded decision
-    # log, the online comms refits and their freeze/interlock/revert
-    # accounting, the degenerate-refit guard in the fitter, and the
-    # serving-side ladder adaptation counters —
-    # tools/check_autopilot.py closes the loop against a live
-    # faultinjected drift
-    ('paddle_tpu/fluid/autopilot.py', 'autopilot/decisions'),
-    ('paddle_tpu/fluid/autopilot.py', 'autopilot/decision/'),
-    ('paddle_tpu/fluid/autopilot.py', 'autopilot/refits'),
-    ('paddle_tpu/fluid/autopilot.py', 'autopilot/frozen_intents'),
-    ('paddle_tpu/fluid/autopilot.py', 'autopilot/slo_frozen'),
-    ('paddle_tpu/fluid/autopilot.py', 'autopilot/reverts'),
-    ('paddle_tpu/fluid/autopilot.py', 'autopilot/engaged'),
-    ('paddle_tpu/fluid/autopilot.py', 'autopilot/persist_errors'),
-    ('paddle_tpu/fluid/timeseries.py', 'autopilot/tick_errors'),
-    ('paddle_tpu/fluid/comms.py', 'autopilot/refit_degenerate'),
     ('paddle_tpu/fluid/comms.py', 'comms/plan_pred_over_measured'),
-    ('paddle_tpu/fluid/serving.py', 'serving/bucket_dropped'),
-    ('paddle_tpu/fluid/serving.py', 'serving/bucket_prewarmed'),
     ('paddle_tpu/fluid/serving.py', 'serving/pad_waste_ratio'),
-    ('paddle_tpu/fluid/serving.py', 'serving/close_wait_holds'),
-    # serving fleet (fluid/fleet.py): the cross-replica router's
-    # decision log, sticky routing, class policy and priced
-    # eviction/migration accounting — tools/check_fleet.py closes the
-    # loop against a live two-replica skewed soak
-    ('paddle_tpu/fluid/fleet.py', 'fleet/decisions'),
-    ('paddle_tpu/fluid/fleet.py', 'fleet/decision/'),
-    ('paddle_tpu/fluid/fleet.py', 'fleet/frozen_intents'),
-    ('paddle_tpu/fluid/fleet.py', 'fleet/routed_requests'),
-    ('paddle_tpu/fluid/fleet.py', 'fleet/placements'),
-    ('paddle_tpu/fluid/fleet.py', 'fleet/migrations'),
-    ('paddle_tpu/fluid/fleet.py', 'fleet/evictions'),
-    ('paddle_tpu/fluid/fleet.py', 'fleet/reverts'),
-    ('paddle_tpu/fluid/fleet.py', 'fleet/ticks'),
-    ('paddle_tpu/fluid/fleet.py', 'fleet/class_shed'),
-    ('paddle_tpu/fluid/fleet.py', 'fleet/class_restored'),
-    ('paddle_tpu/fluid/fleet.py', 'fleet/replicas'),
-    ('paddle_tpu/fluid/timeseries.py', 'fleet/tick_errors'),
-    ('paddle_tpu/fluid/serving.py', 'serving/shed_class'),
-    ('paddle_tpu/fluid/serving.py', 'serving/tenant_evicted'),
     ('paddle_tpu/fluid/serving.py', 'serving/warmup_buckets'),
-    ('paddle_tpu/fluid/health.py', "'fleet':"),
     # rows a device capture could not attribute are counted
     ('paddle_tpu/fluid/profiler.py', 'profiler/dropped_events'),
     # manifold-constrained hyper-connections and the multi-token-

@@ -1,6 +1,6 @@
-"""Telemetry-plane gate: the windowed time-series history, the SLO
-burn-rate alerting and their HTTP surfaces must work against REAL
-executors and REAL processes — and cost nothing when off.
+"""Telemetry-plane gate: the windowed time-series history and its
+HTTP surfaces must work against REAL executors and REAL processes —
+and cost nothing when off.
 
 Three postures:
 
@@ -11,12 +11,7 @@ Three postures:
      rate), a histogram window (executor/run_seconds with windowed
      p50/p95/p99), a `point` query, a 404-with-directory on an
      unknown name and a 400 on a malformed number; /statusz must
-     carry the sparkline rollup section.  Then a deliberately-
-     impossible SLO (`executor/run_seconds p99 < 1us`) is declared:
-     it must walk ok -> pending -> firing through the hysteresis on
-     the step cadence, show up under `firing` at /alertz with both
-     burn-rate windows populated, and land a `slo_breach` decision in
-     the supervisor decision log citing the breaching series;
+     carry the sparkline rollup section;
   2. two-process job (tests/comms_worker.py x2, rank 0 aggregating
      with FLAGS_timeseries on): the aggregator's /timeseries must
      list both ranks in the job history, serve a per-worker
@@ -83,18 +78,12 @@ def check_local_plane(failures):
     """Posture 1: live in-process run against the real status plane."""
     import numpy as np
     import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import layers, slo, supervisor, timeseries
+    from paddle_tpu.fluid import layers, timeseries
 
     port = _free_port()
-    # aggressive windows so the hysteresis walk fits in a short run
     fluid.set_flags({'FLAGS_timeseries': True,
-                     'FLAGS_status_port': port,
-                     'FLAGS_slo_fast_points': 4,
-                     'FLAGS_slo_slow_points': 8,
-                     'FLAGS_slo_hysteresis': 2})
+                     'FLAGS_status_port': port})
     timeseries.reset()
-    slo.reset()
-    supervisor.reset()
 
     prog, startup = fluid.Program(), fluid.Program()
     prog.random_seed = startup.random_seed = 11
@@ -186,49 +175,8 @@ def check_local_plane(failures):
             elif not any(r.get('spark') for r in ts_sec['series']):
                 failures.append('/statusz timeseries rows carry no '
                                 'sparklines: %r' % ts_sec['series'][:2])
-
-            # seeded SLO breach: impossible latency target must walk
-            # the hysteresis to firing on the step cadence
-            slo.declare('executor/run_seconds p99 < 1us',
-                        name='seeded_latency')
-            for _ in range(12):
-                exe.run(prog, feed=feed, fetch_list=[loss])
-            code, doc = _get_json(base + '/alertz')
-            firing = {a['name']: a for a in doc.get('firing', [])}
-            if 'seeded_latency' not in firing:
-                failures.append(
-                    '/alertz: seeded SLO never fired (firing=%r '
-                    'pending=%r)' % (sorted(firing),
-                                     [a['name'] for a in
-                                      doc.get('pending', [])]))
-            else:
-                a = firing['seeded_latency']
-                if not (a.get('burn_fast') and a.get('burn_fast') > 1
-                        and a.get('burn_slow') and
-                        a.get('measured_fast') is not None and
-                        a.get('window', {}).get('fast_points') == 4):
-                    failures.append('/alertz firing doc missing burn '
-                                    'windows: %r' % a)
-
-            # the supervisor decision log must cite the breach
-            recs = [d for d in supervisor.decisions()
-                    if d.get('kind') == 'slo_breach']
-            if not recs:
-                failures.append('no slo_breach decision recorded in '
-                                'the supervisor log')
-            else:
-                info = recs[-1].get('info', {})
-                if info.get('series') != 'executor/run_seconds' or \
-                        not info.get('window'):
-                    failures.append('slo_breach decision does not '
-                                    'cite series+window: %r' % info)
     finally:
-        fluid.set_flags({'FLAGS_timeseries': False,
-                         'FLAGS_slo_fast_points': 12,
-                         'FLAGS_slo_slow_points': 96,
-                         'FLAGS_slo_hysteresis': 3})
-        slo.reset()
-        supervisor.reset()
+        fluid.set_flags({'FLAGS_timeseries': False})
         timeseries.reset()
 
 
@@ -337,8 +285,7 @@ def main():
         return 1
     print('check_timeseries: /timeseries windows schema-valid '
           '(counter rate, hist percentiles, point/404/400), /statusz '
-          'sparklines render, seeded SLO fired at /alertz + cited in '
-          'the supervisor decision log, 2-rank job history serves '
+          'sparklines render, 2-rank job history serves '
           'per-worker and aggregated series, hot-path budgets hold')
     return 0
 

@@ -47,9 +47,6 @@ LOCK_MODULES = [
     'paddle_tpu/fluid/supervisor.py',
     'paddle_tpu/parallel/plan.py',
     'paddle_tpu/fluid/timeseries.py',
-    'paddle_tpu/fluid/slo.py',
-    'paddle_tpu/fluid/autopilot.py',
-    'paddle_tpu/fluid/fleet.py',
 ]
 # documented GIL-discipline exemption: registries with NO lock at all
 # (the lint fails if a lock ever appears there half-wired)
