@@ -24,11 +24,30 @@ Diag(exp(a_t)) S_(t-1) + k_t u_t^T``):
     O    = Qbar S_0 + B U                                  Qbar_t = q_t exp(G_t)
     S_C  = Diag(exp(G_C)) S_0 + Khat^T U                   Khat_t = k_t exp(G_C - G_t)
 
-``A``, ``B``, the solve of the unit lower-triangular system (``W = (I +
+``A``, ``B``, the unit lower-triangular system's solution (``W = (I +
 Diag(beta) A)^-1 Diag(beta) [Kbar | V]``, so ``U = W_v - W_k S_0``),
 ``Qbar`` and ``Khat`` are computed for ALL chunks, heads and sequences
 at once (``_prepare``); a ``lax.scan`` over the T / chunk chunks carries
 the state through three small matmuls (``_step``).
+
+``_prepare`` HOLDS NO LOOP: only the two scans walk anything.  The
+system is C x C with C at most 64, under the block size at which the
+compiler's ``triangular_solve`` multiplies, so that call inverted each
+system row by row, 64 dependent steps a call (0.63 to 0.69 ms on a
+v5e at 512 chunk-heads).  Here the inverse ``T = (I + Diag(beta)
+A)^-1`` is FORMED, by substitution in blocks of ``SUB``
+(``_unit_lower_inverse``: the diagonal blocks row by row, ``ROWS``
+rows a pass, every chunk, head and block in each step; the blocks
+under the diagonal by block substitution, products), and ``W = T
+(Diag(beta) [Kbar | V])`` is one product; its backward is the closed
+form ``dM = -strictly_lower(T^T dW W^T)`` (``_solve``).  Substitution
+and NOT a series: ``(I + M)^-1 = (I - M)(I + M^2)(I + M^4)..`` is exact
+on paper (``M`` is nilpotent) but with ``beta`` up to 2 and keys that
+nearly repeat the entries of ``M`` are near 2, its powers pass 1e15 and
+the float32 sum has to cancel them; substitution only ever forms
+entries of ``T`` itself.  The running decay ``G`` stays a ``cumsum``:
+as a product with a triangle of ones it read FASTER alone and SLOWER
+in the step it runs in (``PERF.md`` section 6, PR 59).
 
 ``A`` and ``B`` (``_scores``) are a Pallas kernel on a TPU
 (``ops/pallas/kda_chunk.py``, ``kda_chunk`` in the kernel library: one
@@ -42,8 +61,9 @@ dense form below (``_scores``, XLA's: its blocks are HBM buffers,
 sixteen times the operands) for float64, other widths, under the GSPMD
 runner (``auto_partitioned``) and off a TPU (the kernels' bodies under
 the Pallas interpreter where ``FLAGS_pallas_force`` asks).  Everything
-else is XLA's on either path: the cumulative sum, ``Qbar``, ``Kbar``,
-``Khat``, the solve, ``_step`` and both scans.
+else is XLA's on either path, ONE ``_prepare`` for both: the
+cumulative sum, ``Qbar``, ``Kbar``, ``Khat``, the inverse, ``_step``
+and both scans.
 
 THE DECAY IS PER CHANNEL, so ``exp(G_t - G_j)`` does not factor out of
 the sum over channels as a scalar, and the factored form ``(k_t
@@ -71,10 +91,12 @@ operands' cotangents back through ``_prepare`` (through the scores by
 the backward kernel).  Nothing saved grows with T x dk x dv, nor with
 what ``_prepare`` holds inside a chunk.
 
-float32 inside whatever arrives (float64 under x64): the decays, the
-triangular solve, the state and every product (``Precision.HIGHEST``:
-the op's FLOPs are a few percent of a layer's projections); the output
-in V's dtype (the ``rms_norm`` / ``short_conv`` policy).
+float32 inside whatever arrives (float64 under x64): the decays and
+their sums, the system's inverse, the state and every product
+(``Precision.HIGHEST``: the op's FLOPs are a few percent of a layer's
+projections; the row substitution multiplies and adds float32 on the
+vector unit); the output in V's dtype (the ``rms_norm`` /
+``short_conv`` policy).
 """
 
 import functools
@@ -87,6 +109,7 @@ from .registry import register
 
 CHUNK = 64
 SUB = 16
+ROWS = 4    # rows of a diagonal block's inverse taken in one pass
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -141,21 +164,90 @@ def _scores(q, k, g):
     return whole(a_off, a_in * strictly), whole(b_off, b_in)
 
 
+@jax.jit
+def _unit_lower_inverse(m):
+    """m [..., C, C], strictly lower -> (I + m)^-1 (unit lower) by
+    substitution, every chunk and head at once, in two levels.  The
+    diagonal blocks of ``SUB`` rows by ROW substitution (row i of a
+    block's inverse is ``e_i - m[i, :i] T[:i]``), ``ROWS`` rows a
+    pass: what the rows above the pass give is one product-and-sum
+    over those rows, what the pass's own rows give each other its
+    ``ROWS - 1`` dependent steps.  Then the block rows under the
+    diagonal one after the other, ``T[I, :I] = -T_II (m[I, :I] T[:I,
+    :I])``: products.  The module's docstring says why no series.
+    Jitted so that its hundred-odd equations are traced once a shape
+    and process, not at each of a step's calls (``setup_s``)."""
+    c = m.shape[-1]
+    sub = SUB if c % SUB == 0 else c
+    diag = jnp.stack([m[..., i:i + sub, i:i + sub]
+                      for i in range(0, c, sub)], -3)   # [..., I, s, s]
+    eye = jnp.eye(sub, dtype=m.dtype)
+    inv = None                                  # [..., I, rows so far, s]
+    for lo in range(0, sub, ROWS):
+        hi = min(lo + ROWS, sub)
+        among = jnp.broadcast_to(eye[lo:hi],
+                                 diag.shape[:-2] + (hi - lo, sub))
+        if lo:
+            among = among - jnp.sum(diag[..., lo:hi, :lo, None] *
+                                    inv[..., None, :, :], -2)
+        at_row = jnp.arange(hi - lo)[:, None]
+        # m[i, j >= i] is 0: the rows of ``among`` from r on add nothing
+        for r in range(1, hi - lo):
+            row = among[..., r, :] - jnp.sum(
+                diag[..., lo + r, lo:hi, None] * among, -2)
+            among = jnp.where(at_row == r, row[..., None, :], among)
+        inv = among if inv is None else jnp.concatenate([inv, among], -2)
+    t = inv[..., 0, :, :]
+    for i in range(1, c // sub):
+        lo, t_ii = i * sub, inv[..., i, :, :]
+        under = -_mm('...ij,...jk->...ik', t_ii, _mm(
+            '...ij,...jk->...ik', m[..., lo:lo + sub, :lo], t))
+        t = jnp.concatenate([
+            jnp.pad(t, ((0, 0),) * (t.ndim - 1) + ((0, sub),)),
+            jnp.concatenate([under, t_ii], -1)], -2)
+    return t
+
+
+@jax.custom_vjp
+def _solve(m, rhs):
+    """(I + m)^-1 rhs for m [..., C, C] strictly lower, rhs [..., C,
+    d]: the inverse formed, then ONE product."""
+    return _solve_fwd(m, rhs)[0]
+
+
+def _solve_fwd(m, rhs):
+    t = _unit_lower_inverse(m)
+    w = _mm('...ij,...jd->...id', t, rhs)
+    return w, (t, w)
+
+
+def _solve_bwd(saved, d_w):
+    """The closed form (d_rhs = T^T d_w, d_m = -strictly_lower(d_rhs
+    w^T)): two products, nothing differentiated through the
+    substitution."""
+    t, w = saved
+    d_rhs = _mm('...ji,...jd->...id', t, d_w)
+    return -jnp.tril(_mm('...id,...jd->...ij', d_rhs, w), -1), d_rhs
+
+
+_solve.defvjp(_solve_fwd, _solve_bwd)
+
+
 def _prepare(q, k, v, a, beta, scores=_scores):
     """q, k, a [..., C, dk], v [..., C, dv], beta [..., C] of whole
     chunks (leading axes: chunk, sequence, head) -> the scan's operands
     (W_k [..., C, dk], W_v [..., C, dv], Qbar, B [..., C, C], Khat,
     exp(G_C) [..., dk]).  ``scores``: ``_scores`` or the kernel."""
-    c, dk = k.shape[-2:]
+    dk = k.shape[-1]
     g = jnp.cumsum(a, axis=-2)                          # G, inclusive
     g_end = g[..., -1:, :]
     q_bar, k_bar = q * jnp.exp(g), k * jnp.exp(g)
     k_hat = k * jnp.exp(g_end - g)
     a_mat, b_mat = scores(q, k, g)
-    system = jnp.eye(c, dtype=k.dtype) + beta[..., None] * a_mat
-    w = jax.lax.linalg.triangular_solve(
-        system, beta[..., None] * jnp.concatenate([k_bar, v], -1),
-        left_side=True, lower=True, unit_diagonal=True)
+    system = beta[..., None] * a_mat
+    written = beta[..., None] * jnp.concatenate([k_bar, v], -1)
+    with jax.named_scope('inverse'):
+        w = _solve(system, written)
     return (w[..., :dk], w[..., dk:], q_bar, b_mat, k_hat,
             jnp.exp(g_end[..., 0, :]))
 

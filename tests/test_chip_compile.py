@@ -845,9 +845,11 @@ def test_the_delta_rule_compiles_its_two_score_kernels(one_chip, as_on_tpu,
     """``kda_attention``'s forward + backward at the Solar cell's layer
     shape (and at a short and a ragged length): the dispatch answers
     fused, the executable holds THREE Mosaic calls (the scores' forward,
-    its recompute in the backward, the scores' backward), and no buffer
-    of the step is a [.., SUB, SUB, dk] or [.., n_sub, n_sub, SUB, dk]
-    decay block any more."""
+    its recompute in the backward, the scores' backward), no buffer of
+    the step is a [.., SUB, SUB, dk] or [.., n_sub, n_sub, SUB, dk]
+    decay block any more, and its only loops are the two scans over the
+    chunks (none where the sequence is one chunk): ``_prepare`` holds
+    no loop and no solve."""
     import re
     from paddle_tpu.ops import kda_ops
 
@@ -860,8 +862,14 @@ def test_the_delta_rule_compiles_its_two_score_kernels(one_chip, as_on_tpu,
                      wide).as_text()
     _compiled_on_chip('kda_chunk')
     assert text.count('custom_call_target="tpu_custom_call"') == 3
-    blocks = re.findall(r'f32\[[\d,]*(?:16,16|\d,\d,16),%d\]' % dk, text)
+    # (whole axes only: the compiler moves a [64, 8, 64, dk] operand in
+    # four [64, 8, 16, dk] slices, which are no blocks)
+    blocks = re.findall(
+        r'f32\[[\d,]*(?:16,16|(?<!\d)\d,\d,16),%d\]' % dk, text)
     assert not blocks, sorted(set(blocks))
+    assert len(re.findall(r' while\(', text)) == (2 if t > 64 else 0)
+    for opcode in ('triangular-solve', 'InvertDiagBlocksLowerTriangular'):
+        assert opcode not in text, opcode
 
 
 @pytest.mark.parametrize('rows,experts,d,hidden,held', [

@@ -2,9 +2,11 @@
 gated delta rule's in-chunk scores, forward and backward) under the
 Pallas interpreter against the dense form they replace
 (``ops/kda_ops.py`` ``_scores``) and, through the whole op, against the
-token-by-token recurrence; and what ``common.dispatch`` answers for
-shapes the kernels' layout does not hold.  CPU; what the chip's compiler
-says of them is ``tests/test_chip_compile.py``'s."""
+token-by-token recurrence; what ``common.dispatch`` answers for shapes
+the kernels' layout does not hold; and ``_prepare``'s unit-triangular
+system, inverted by blocks, against float64 NumPy and
+``triangular_solve``.  CPU; what the chip's compiler says of them is
+``tests/test_chip_compile.py``'s."""
 
 import numpy as np
 import pytest
@@ -172,3 +174,95 @@ def test_the_kernel_is_registered_with_its_dense_fallback():
     assert entry['has_vjp'] and entry['op_types'] == ('kda_attention',)
     module, name = entry['dense_fallback'].rsplit('.', 1)
     assert module == 'ops.kda_ops' and callable(getattr(kda_ops, name))
+
+
+def _system(seed, c, dtype, lead=(3, 2), d=24):
+    """A chunk's system at its hardest: keys that nearly repeat (every
+    entry of ``A`` within a few percent of 1), no decay between them
+    and write strengths up to 2, so ``m = beta A`` holds entries near 2
+    and the substitution's terms cancel instead of dying out."""
+    rng = np.random.RandomState(seed)
+    k = rng.randn(*(lead + (1, 32))) + 0.1 * rng.randn(*(lead + (c, 32)))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    beta = rng.uniform(0, 2, lead + (c, 1))
+    beta[..., ::5, :] = 2.0
+    m = beta * np.tril(k @ np.swapaxes(k, -1, -2), -1)
+    assert np.tril(m / beta, -1).max() > 0.98
+    rhs = rng.randn(*(lead + (c, d)))
+    return [jnp.asarray(x, dtype) for x in (m, rhs)]
+
+
+_SYSTEMS = [(c, dtype) for c in (16, 32, 64)
+            for dtype in ('float32', 'float64')]
+
+
+@pytest.mark.parametrize('c,dtype', _SYSTEMS)
+def test_the_block_inverse_is_numpys_float64_solve(c, dtype):
+    """``_unit_lower_inverse`` and ``_solve`` against
+    ``numpy.linalg.solve`` in float64, at one, two and four sub-chunks:
+    float32 within what ``triangular_solve`` itself reads on these
+    inputs (a few 1e-7 of the largest entry), float64 to rounding."""
+    with jax.enable_x64(dtype == 'float64'):
+        m, rhs = _system(c, c, dtype)
+        inverse = kda_ops._unit_lower_inverse(m)
+        got = kda_ops._solve(m, rhs)
+        assert inverse.dtype == got.dtype == jnp.dtype(dtype)
+    system = np.eye(c) + np.asarray(m, np.float64)
+    rtol = 2e-6 if dtype == 'float32' else 1e-14
+    _close(inverse, np.linalg.solve(
+        system, np.broadcast_to(np.eye(c), system.shape)), rtol)
+    _close(got, np.linalg.solve(system, np.asarray(rhs, np.float64)), rtol)
+    assert (np.asarray(inverse)[..., np.triu(np.ones((c, c), bool), 1)]
+            == 0).all()
+
+
+@pytest.mark.parametrize('c,dtype', _SYSTEMS)
+def test_the_block_inverses_gradients_are_triangular_solves(c, dtype):
+    """The closed-form backward of ``_solve`` against autodiff through
+    ``jax.lax.linalg.triangular_solve`` (what ``_prepare`` called until
+    PR 59) on the same system and cotangent."""
+    def reference(m, rhs):
+        return jax.lax.linalg.triangular_solve(
+            jnp.eye(c, dtype=m.dtype) + m, rhs, left_side=True,
+            lower=True, unit_diagonal=True)
+
+    with jax.enable_x64(dtype == 'float64'):
+        m, rhs = _system(100 + c, c, dtype)
+        probe = jnp.asarray(
+            np.random.RandomState(3).randn(*rhs.shape), dtype)
+        got = jax.vjp(kda_ops._solve, m, rhs)[1](probe)
+        want = jax.vjp(reference, m, rhs)[1](probe)
+    for x, y in zip(got, want):
+        assert x.dtype == jnp.dtype(dtype)
+        _close(x, y, 5e-6 if dtype == 'float32' else 1e-13)
+
+
+def _primitives(jaxpr):
+    """Every primitive's name in a jaxpr and in the jaxprs its
+    equations hold (scans, custom_vjp calls, pjit)."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                sub = getattr(sub, 'jaxpr', sub)
+                if hasattr(sub, 'eqns'):
+                    yield from _primitives(sub)
+
+
+@pytest.mark.parametrize('path', ['dense', 'interpret'])
+def test_the_op_holds_no_solve_and_no_loop_but_its_scans(path):
+    """Forward and backward of the whole op, traced: no
+    ``triangular_solve`` and no ``while`` on either path; the products
+    are there and, outside the kernels' bodies, the two scans alone."""
+    args = _sequence(11, 100, b=1, h=2)
+
+    def both(*x):
+        out, pull = jax.vjp(
+            lambda *y: kda_ops._rule(*y, kda_ops.CHUNK, path), *x)
+        return pull(out)
+
+    found = list(_primitives(jax.make_jaxpr(both)(*args).jaxpr))
+    assert 'dot_general' in found
+    assert found.count('scan') == 2 or path == 'interpret'
+    assert not {'triangular_solve', 'while'} & set(found)
