@@ -33,6 +33,10 @@ the chip: BERT-base pretraining through the normal entry points
     python chip_smoke.py --phase phi4flash   # Phi-4-mini-flash's: the
                                     # selective scan, differential
                                     # attention, the cross-decoder
+    python chip_smoke.py --phase kimi    # Kimi-Linear-48B-A3B's: the
+                                    # delta rule at all 32 heads in
+                                    # recompute groups, NoPE latent
+                                    # attention, the cell's loss
     python chip_smoke.py --phase grouped # the experts' grouped-matmul
                                     # kernels against ragged_dot at
                                     # the five routed cells' shapes
@@ -2272,6 +2276,311 @@ def phase_solar(seq=4096, seed=0):
 
 
 
+# --- Kimi-Linear-48B-A3B ------------------------------------------------
+# Published widths (models.kimi_linear.BASE) as the cell cuts them: the
+# model's layers 1 to 5 (the dense delta-rule layer, two routed
+# delta-rule layers, the routed latent layer, one more delta-rule
+# layer), ALL 32 heads of both kinds, experts 0 to 7 of 256, 20480
+# vocabulary rows, one 8192-token sequence.  Loss and sampled gradients
+# of the f32 TRAIN program (the chunked delta rule inside its recompute
+# groups, the latent flash kernels without rotary, the held experts'
+# grouped matmuls) against jax.grad of the reference, then the f32
+# for_test loss over KIMI_LOSS_BATCHES batches against the reference in
+# f32 and in bfloat16 throughout: the two readings the family's
+# REFERENCE_RTOL lies between.
+KIMI_LAYERS = 5
+KIMI_LOSS_RTOL = 2e-6
+KIMI_L2_RTOL = 2e-3
+KIMI_CELL_RTOL = 5e-6       # = benchmark/families/kimi_linear.py's
+KIMI_LOSS_BATCHES = 12
+# experts a routed layer whose load differs between program and
+# reference: a token whose 8th and 9th biased scores tie to float32
+# rounding changes two loads by one, and a token that changed expert
+# in one layer is routed on another input in the next, so the count
+# grows with depth: 10, 22, 35, 42 of 256 over the four routed layers
+# on one 8192-token sequence (my chip run, PR 60; Solar's limit is 24
+# at 4096 tokens); a bias that does not pick, or another top-k, moves
+# thousands
+KIMI_LOADS_OFF = 128
+# the delta-rule heads of the step whose reference the host CPU computes
+# (8 x 4096 tokens: 36 s and about 21 GB there; 4 GB more a 1024 tokens)
+KIMI_HOST_HEADS = 8
+# creation order, trainable parameters only: embedding 0; layer 1
+# (delta rule, dense) g_op 1 Wq 2 fq 3 Wk 4 fk 5 Wv 6 fv 7 Wf_down 8
+# Wf_up 9 A_log 10 dt_bias 11 Wb 12 g_o 13 Wg_down 14 Wg_up 15 Wo 16
+# g_ffn 17 gate 18 up 19 down 20; layer 2 (delta rule, routed) from 21:
+# the operator's fifteen, g_ffn 37 router 38 gate 39 up 40 down 41
+# shared 42 43 44; layer 3 from 45; layer 4 (latent) g_op 69 Wq 70 Wkva
+# 71 g_latent 72 Wkvb 73 Wo 74 g_ffn 75 router 76 ...; layer 5 from 83;
+# final gain 107, head 108
+KIMI_SAMPLED = {'Wq (layer 1)': 2, 'filter q (layer 1)': 3,
+                'filter k (layer 1)': 5, 'filter v (layer 1)': 7,
+                'filter q (layer 2)': 23, 'filter q (layer 5)': 85,
+                'Wf_up (layer 1)': 9, 'A_log (layer 1)': 10,
+                'dt_bias (layer 1)': 11, 'Wb (layer 1)': 12,
+                'o gain (layer 1)': 13, 'Wo (layer 1)': 16,
+                'dense gate (layer 1)': 18, 'router (layer 2)': 38,
+                'gate': 39, 'shared gate (layer 2)': 42,
+                'A_log (layer 3)': 54, 'Wb (layer 3)': 56,
+                'Wq (layer 4)': 70, 'Wkva (layer 4)': 71,
+                'latent gain (layer 4)': 72, 'Wkvb (layer 4)': 73,
+                'Wo (layer 4)': 74, 'Wb (layer 5)': 94}
+
+
+def _kimi_cut(layers=KIMI_LAYERS):
+    import copy
+    from paddle_tpu.models import kimi_linear
+    cfg = copy.copy(kimi_linear.BASE)
+    cfg.vocab_size, cfg.layers, cfg.experts_held = 20480, layers, (0, 8)
+    cfg.bias_init_std = 0.005
+    return cfg
+
+
+def _kimi_cell_losses(seq, seed):
+    """The cell's own cut, forward only: the f32 for_test program's
+    loss on KIMI_LOSS_BATCHES batches beside the reference's in float32
+    and in bfloat16 throughout."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import kimi_linear
+    from paddle_tpu.models.reference import kimi_linear as reference
+    cfg = _kimi_cut()
+    sizes = reference.sizes_of(cfg)
+    feeds = [_ints32(kimi_linear.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(s)))
+        for s in range(seed, seed + KIMI_LOSS_BATCHES)]
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.scope_guard(fluid.Scope()):
+        with fluid.program_guard(main, startup), \
+                fluid.unique_name.guard():
+            _, _, loss = kimi_linear.build_pretrain(cfg, seq)
+            test = main.clone(for_test=True)
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights, biases = ([np.asarray(fluid.core.as_array(
+            scope.find_var(p.name))) for p in main.all_parameters()
+            if p.trainable == kind] for kind in (True, False))
+        program = [_scalar(exe.run(test, feed=f, fetch_list=[loss]))
+                   for f in feeds]
+        for name in scope.local_var_names():
+            scope.erase(name)
+    weights, biases = ([jnp.asarray(x) for x in part]
+                       for part in (weights, biases))
+    both = jax.jit(lambda w, b, i, l: [reference.loss(
+        w, b, i, l, sizes=sizes, dtype=dt)
+        for dt in (jnp.float32, jnp.bfloat16)])
+    off, low = [], []
+    for n, (feed, got) in enumerate(zip(feeds, program)):
+        full, half = (float(x) for x in both(weights, biases, *(
+            jnp.asarray(feed[k]) for k in ('ids', 'labels'))))
+        off.append(abs(got - full) / full)
+        low.append(abs(half - full) / full)
+        say('%d layers, batch seed %d: program %.6f, reference %.6f '
+            '(relative difference %.2e), reference in bfloat16 '
+            'throughout %.6f (%.2e)'
+            % (cfg.layers, seed + n, got, full, off[-1], half, low[-1]))
+    say('over %d batches at %d layers: f32 for_test program against the '
+        'reference, relative: median %.2e, largest %.2e; reference in '
+        'bfloat16 throughout: smallest %.2e, quartiles %.2e %.2e %.2e, '
+        'largest %.2e, %d within %g'
+        % ((len(off), cfg.layers, np.median(off), max(off), min(low)) +
+           tuple(np.percentile(low, (25, 50, 75))) +
+           (max(low), sum(x <= KIMI_CELL_RTOL for x in low),
+            KIMI_CELL_RTOL)))
+    check(max(off) <= KIMI_CELL_RTOL, 'kimi f32 for_test loss at the '
+          'cell\'s cut within %g of the reference on every batch'
+          % KIMI_CELL_RTOL)
+    check(2 * sum(x > KIMI_CELL_RTOL for x in low) > len(low),
+          'the reference in bfloat16 throughout misses %g on most '
+          'batches' % KIMI_CELL_RTOL)
+
+
+def phase_kimi(seq=8192, seed=0):
+    """models.kimi_linear.BASE cut as above: loss and sampled gradients
+    of the f32 TRAIN program against the reference's on one seeded
+    sequence, twice; then the cell's for_test losses.  At published
+    widths the reference is compiled for the TPU and the FILTERS are
+    left out of the sample: ``jax.grad`` of the reference as the v5e
+    compiler builds it put one tap of a q filter's gradient 2.2e-2 off
+    what the host CPU's compile of the same function and the program
+    give (which filter moved with the set of gradients asked for;
+    PERF.md section 6, PR 60).  At KIMI_HOST_HEADS delta-rule heads x
+    half the tokens, the largest size whose reference gradient the
+    host's 40 GiB hold, the reference is compiled for the host CPU and
+    every sampled tensor, the filters by tap, is held to it."""
+    _kimi_train_step(None, seq, seed)
+    _kimi_train_step(KIMI_HOST_HEADS, seq // 2, seed)
+    _kimi_cell_losses(seq, seed)
+
+
+def _kimi_train_step(host_heads, seq, seed):
+    """One f32 train step against the reference: at published widths
+    and the TPU's reference (``host_heads`` None), or at ``host_heads``
+    delta-rule heads and the host CPU's."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.models import kimi_linear
+    from paddle_tpu.models.reference import kimi_linear as reference
+    from paddle_tpu.ops.pallas import common
+    cfg = _kimi_cut()
+    if host_heads:
+        cfg.kda_heads = host_heads
+    sampled = {name: i for name, i in KIMI_SAMPLED.items()
+               if host_heads or not name.startswith('filter')}
+    device = jax.devices('cpu' if host_heads else None)[0]
+    sizes, held = reference.sizes_of(cfg), cfg.experts_held[1]
+    deltas = sum(i not in cfg.full_attn_layers
+                 for i in cfg.layer_indices())
+    feed = _ints32(kimi_linear.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(seed)))
+    ids, labels = (np.asarray(feed[k]) for k in ('ids', 'labels'))
+    experts = {}
+
+    def sample(name, array):
+        if name == 'gate':
+            return {'%s, %s loaded held expert (layer 2)' % (name, which):
+                    array[e] for which, e in experts.items()}
+        return {name: array}
+
+    def ref_loss(some, full, biases, chosen=None, dtype=jnp.float32):
+        full = list(full)
+        for name, w in some.items():
+            full[sampled[name]] = w
+        return reference.loss(full, biases, ids, labels, sizes=sizes,
+                              dtype=dtype, remat=True, chosen=chosen)
+
+    ref_grads = jax.jit(jax.value_and_grad(ref_loss))
+    ref_free = jax.jit(lambda full, biases: [
+        ref_loss({}, full, biases, dtype=dt)
+        for dt in (jnp.float32, jnp.bfloat16)])
+    ref_loads = jax.jit(lambda full, biases: reference.forward(
+        full, biases, ids, sizes=sizes)[1])
+
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        _, _, loss = kimi_linear.build_pretrain(cfg, seq)
+        every = main.all_parameters()
+        params = [p.name for p in every if p.trainable]
+        biases = [p.name for p in every if not p.trainable]
+        pairs = dict((p.name, g.name) for p, g in
+                     fluid.optimizer.SGD(0.0).minimize(loss)[1])
+    count = sum(int(np.prod(p.shape)) for p in every if p.trainable)
+    say('kimi: %d trainable parameters in %d tensors, %d choice biases'
+        % (count, len(params), len(biases)))
+    fetches = [loss] + [pairs[params[i]] for i in sampled.values()]
+    routers = [op for op in main.global_block().ops
+               if op.type == 'moe_route']
+    load_names = [op.output('Load')[0] for op in routers]
+    choice_names = [op.output('TopKIdx')[0] for op in routers]
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights, bias_values = ([np.asarray(fluid.core.as_array(
+            scope.find_var(n))) for n in names]
+            for names in (params, biases))
+        t0 = time.time()
+        got = exe.run(main, feed=feed,
+                      fetch_list=fetches + load_names + choice_names)
+        got_loss = _scalar(got[:1])
+        say('kimi f32 train program, 1 x %d tokens, %d layers: loss '
+            '%.6f in %.1f s; moe/held_share %.4f, moe/held_rows_max %d, '
+            'moe/dropped_tokens %d; kda/calls %d, kda/chunks %d a step; '
+            'short_conv/calls %d; executor/recompute_groups %d; '
+            'flash_attention last dispatch %s; peak memory %.2f GB'
+            % (seq, cfg.layers, got_loss, time.time() - t0,
+               monitor.gauge_value('moe/held_share'),
+               monitor.gauge_value('moe/held_rows_max'),
+               monitor.counter_value('moe/dropped_tokens'),
+               monitor.counter_value('kda/calls'),
+               monitor.gauge_value('kda/chunks'),
+               monitor.counter_value('short_conv/calls'),
+               monitor.counter_value('executor/recompute_groups'),
+               common._LAST.get('flash_attention'),
+               _peak_bytes(jax.devices()[:1])[0] / 1e9))
+        check(common._LAST.get('flash_attention', {}).get('path') ==
+              'fused', 'the latent layer\'s calls (32 heads, 192 over '
+              '128, no rotary) ran the flash kernels')
+        check(monitor.gauge_value('kda/chunks') ==
+              (deltas * 3 - 1) * -(-seq // 64), 'kda/chunks counted a '
+              'forward, a recompute group\'s second forward (the last '
+              'block is no group) and a reverse scan of %d chunks a '
+              'delta-rule layer' % -(-seq // 64))
+        check(common._LAST.get('kda_chunk', {}).get('path') == 'fused',
+              'the delta-rule layers\' in-chunk scores ran the '
+              'kda_chunk kernels (%d fused dispatches)'
+              % monitor.counter_value('pallas/kda_chunk/dispatch_fused'))
+        chosen = [jnp.asarray(x) for x in got[-len(routers):]]
+        program_loads = [np.asarray(x) for x in got[
+            len(fetches):len(fetches) + len(routers)]]
+        grads_raw = [np.asarray(x) for x in got[1:len(fetches)]]
+        del got
+        for name in scope.local_var_names():
+            scope.erase(name)
+    weights, bias_values = ([jnp.asarray(x) for x in part]
+                            for part in (weights, bias_values))
+    loads = [np.asarray(x) for x in ref_loads(weights, bias_values)]
+    loads_off = [int(np.sum(a != b))
+                 for a, b in zip(program_loads, loads)]
+    say('experts a layer whose load differs between program and '
+        'reference (a near-tie token changes two by one): %s; held rows '
+        'a layer %s' % (loads_off, [int(x[:held].sum()) for x in loads]))
+    check(max(loads_off) <= KIMI_LOADS_OFF,
+          'the program\'s choice is the reference\'s but for near-ties')
+    load = loads[0][:held]
+    experts.update(most=int(load.argmax()), least=int(load.argmin()))
+    grads = {what: x for name, g in zip(sampled, grads_raw)
+             for what, x in sample(name, g).items()}
+    some = {name: weights[i] for name, i in sampled.items()}
+    t0 = time.time()
+    pinned, want_grads = ref_grads(*jax.device_put(
+        (some, weights, bias_values, chosen), device))
+    want_grads = {name: np.asarray(g) for name, g in want_grads.items()}
+    say('the reference\'s loss and %d gradients on %s in %.1f s'
+        % (len(want_grads), device, time.time() - t0))
+    want_loss, low = (float(x) for x in ref_free(weights, bias_values))
+    rel = abs(got_loss - want_loss) / want_loss
+    low_rel = abs(low - want_loss) / want_loss
+    say('reference loss %.6f, program %.6f (relative difference %.2e; '
+        '%.2e from the reference routed by the program\'s choice); '
+        'reference in bfloat16 throughout %.6f (%.2e)'
+        % (want_loss, got_loss, rel,
+           abs(got_loss - float(pinned)) / float(pinned), low, low_rel))
+    check(abs(got_loss - float(pinned)) <= KIMI_LOSS_RTOL * float(pinned),
+          'kimi f32 train loss within %g of the reference routed by '
+          'the program\'s choice' % KIMI_LOSS_RTOL)
+    check(rel <= KIMI_CELL_RTOL, 'kimi f32 train loss within %g of '
+          'the reference by its own choice' % KIMI_CELL_RTOL)
+    far = 0.0
+    for name in sampled:
+        for what, y in sample(name, np.asarray(want_grads[name])).items():
+            x = grads[what]
+            e = float(np.abs(x - y).max() / np.abs(y).max())
+            d = float(np.linalg.norm(x - y) / np.linalg.norm(y))
+            far = max(far, d)
+            say('gradient of %s %s: largest entry difference %.3e of '
+                'the largest entry (%.3e), relative L2 distance %.3e'
+                % (what, x.shape, e, np.abs(y).max(), d))
+            if what.startswith('filter'):           # by tap
+                say('  by tap: relative L2 %s, norms %s'
+                    % (' '.join('%.2e' % (np.linalg.norm(x[:, j] - y[:, j])
+                                          / np.linalg.norm(y[:, j]))
+                                for j in range(x.shape[1])),
+                       ' '.join('%.2e' % np.linalg.norm(y[:, j])
+                                for j in range(x.shape[1]))))
+    check(far <= KIMI_L2_RTOL,
+          'kimi gradients, %d delta-rule heads x %d tokens: all %d '
+          'sampled tensors within %g of the reference\'s on %s, routed '
+          'by the program\'s choice, relative L2 distance (worst %.3e)'
+          % (cfg.kda_heads, seq, len(sampled), KIMI_L2_RTOL, device, far))
+
+
 # (buffer rows, groups, K, N, live rows) of the routed cells' expert
 # products (tools/bench_grouped_matmul.py times the same)
 GROUPED_SHAPES = {
@@ -3170,10 +3479,10 @@ def main():
     ap.add_argument('--phase',
                     choices=('bert', 'olmoe', 'laguna', 'moonlight',
                              'lfm2', 'evabyte', 'solar', 'ouro', 'xing4',
-                             'phi4flash', 'grouped'),
+                             'phi4flash', 'kimi', 'grouped'),
                     default='bert',
                     help="'olmoe' / 'laguna' / 'moonlight' / 'lfm2' / "
-                    "'evabyte' / 'solar' / 'ouro' / 'xing4' / 'phi4flash': only that model's "
+                    "'evabyte' / 'solar' / 'ouro' / 'xing4' / 'phi4flash' / 'kimi': only that model's "
                     "gradient check; 'grouped': only the grouped-matmul "
                     "kernels against ragged_dot")
     args = ap.parse_args()
@@ -3220,6 +3529,8 @@ def main():
             phase_xing4()
         elif args.phase == 'phi4flash':
             phase_phi4flash()
+        elif args.phase == 'kimi':
+            phase_kimi()
         elif args.phase == 'grouped':
             phase_grouped_matmul()
         elif args.chips == 4:

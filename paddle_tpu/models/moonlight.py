@@ -37,7 +37,9 @@ where the softmax scale comes from: 1 / sqrt(qk width) times
 (0.1 mscale_all_dim ln factor + 1)^2.  ``layers.flash_attention``
 scales by 1 / sqrt(qk width) itself, so q is multiplied by the rest
 before the call.  Moonlight publishes neither key and builds the ops
-it always built.
+it always built.  Handed no positions (``models/kimi_linear.py``:
+``mla_use_nope``) it rotates nothing and the shared key slice enters
+the product as projected.
 """
 
 import math
@@ -151,7 +153,10 @@ def _rotate(q_rope, k_rope, pos_ids, cfg):
 
 
 def attention(u, pos_ids, cfg):
-    """One layer's latent attention on the normed block input ``u``."""
+    """One layer's latent attention on the normed block input ``u``.
+    ``pos_ids`` None: NO position encoding (``mla_use_nope``): the
+    queries stay as projected and the 64-wide shared key slice stays
+    in the 192-wide product unrotated."""
     h, nope, rope, dv = cfg.heads, cfg.qk_nope, cfg.qk_rope, cfg.v_dim
     q_in = u
     if cfg.q_rank:
@@ -159,17 +164,19 @@ def attention(u, pos_ids, cfg):
                                epsilon=cfg.rms_eps)
     q = layers.reshape(_linear(q_in, h * (nope + rope), cfg),
                        [0, 0, h, nope + rope])
-    q_nope, q_rope = layers.split(q, [nope, rope], dim=3)
-    # the latent and the rotary key, one projection
+    if pos_ids is not None:
+        q_nope, q_rope = layers.split(q, [nope, rope], dim=3)
+    # the latent and the shared (rotary) key, one projection
     latent, k_rope = layers.split(_linear(u, cfg.kv_rank + rope, cfg),
                                   [cfg.kv_rank, rope], dim=2)
     latent = layers.rms_norm(latent, epsilon=cfg.rms_eps)
     kv = layers.reshape(_linear(latent, h * (nope + dv), cfg),
                         [0, 0, h, nope + dv])
     k_nope, v = layers.split(kv, [nope, dv], dim=3)
-    q_rope, k_rope = _rotate(
-        q_rope, layers.reshape(k_rope, [0, 0, 1, rope]), pos_ids, cfg)
-    q = layers.concat([q_nope, q_rope], axis=3)
+    k_rope = layers.reshape(k_rope, [0, 0, 1, rope])
+    if pos_ids is not None:
+        q_rope, k_rope = _rotate(q_rope, k_rope, pos_ids, cfg)
+        q = layers.concat([q_nope, q_rope], axis=3)
     if softmax_correction(cfg) != 1.0:
         # the op scales by 1 / sqrt(qk width) itself
         q = layers.scale(q, scale=softmax_correction(cfg))
@@ -186,13 +193,10 @@ def gated_mlp(w, width, cfg):
                    cfg.hidden, cfg)
 
 
-def decoder_block(x, pos_ids, i, cfg):
-    u = layers.rms_norm(x, epsilon=cfg.rms_eps)
-    x = layers.elementwise_add(x, attention(u, pos_ids, cfg))
-    w = layers.rms_norm(x, epsilon=cfg.rms_eps)
-    if i < cfg.dense_layers:
-        return layers.elementwise_add(
-            x, gated_mlp(w, cfg.dense_hidden, cfg))
+def sparse_mlp(x, w, cfg):
+    """x + shared(w) + routed(w) of a sparse layer on the normed ``w``:
+    ONE CHIP'S SHARE of the routed experts under the bias-picked
+    sigmoid router, the shared experts one gated MLP."""
     routed, _ = layers.moe(
         w, num_experts=cfg.experts, hidden_size=cfg.expert_hidden,
         capacity_factor=None, top_k=cfg.top_k,
@@ -205,6 +209,16 @@ def decoder_block(x, pos_ids, i, cfg):
     x = layers.elementwise_add(
         x, gated_mlp(w, cfg.shared_experts * cfg.expert_hidden, cfg))
     return layers.elementwise_add(x, routed)
+
+
+def decoder_block(x, pos_ids, i, cfg):
+    u = layers.rms_norm(x, epsilon=cfg.rms_eps)
+    x = layers.elementwise_add(x, attention(u, pos_ids, cfg))
+    w = layers.rms_norm(x, epsilon=cfg.rms_eps)
+    if i < cfg.dense_layers:
+        return layers.elementwise_add(
+            x, gated_mlp(w, cfg.dense_hidden, cfg))
+    return sparse_mlp(x, w, cfg)
 
 
 def moonlight_decoder(ids, pos_ids, cfg):

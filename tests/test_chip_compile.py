@@ -230,10 +230,13 @@ def test_banded_grouped_flash_at_the_laguna_cell_shapes(
 
 
 @pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize('h', [16, 32], ids=['moonlight_h16', 'kimi_h32'])
 def test_latent_flash_at_the_moonlight_cell_shape(one_chip, as_on_tpu,
-                                                  dtype):
+                                                  dtype, h):
     """moonlight_16b_s8192: b1 t8192 h16, queries and keys 192 wide
-    over values 128 wide, causal.  The resident rows of an 8k
+    over values 128 wide, causal; kimi_linear_48b_s8192's one latent
+    layer is the same call at 32 heads (a longer grid, an instance's
+    VMEM as at 16).  The resident rows of an 8k
     sequence at 192 + 128, which the pipeline keeps twice, are the
     block clamp's whole budget in bfloat16 and twice it in float32:
     the forward asks Mosaic for more scoped VMEM than its default
@@ -245,7 +248,7 @@ def test_latent_flash_at_the_moonlight_cell_shape(one_chip, as_on_tpu,
     keeps to: dq and dkv, which ask too.  The calls carry the scope
     the op lowers them in."""
     import re
-    b, t, h, d, dv = 1, 8192, 16, 192, 128
+    b, t, d, dv = 1, 8192, 192, 128
     item = jnp.dtype(dtype).itemsize
     admitted, limit = _one_pass(t, d, dtype, dv=dv)
     assert (admitted, limit) == (
@@ -837,6 +840,7 @@ def test_quant_collective_tiles(one_chip):
 
 @pytest.mark.parametrize('t,heads,dk', [
     (4096, 8, 128),     # solar_open2_250b_s4096: 64 chunks x 8 heads
+    (8192, 32, 128),    # kimi_linear_48b_s8192: 128 chunks x 32 heads
     (24, 3, 128),       # less than a chunk: one chunk of two sub-chunks
     (100, 2, 256),      # a padded tail, two lane tiles of channels
 ])
