@@ -2048,6 +2048,9 @@ def _solar_single_op(t=SOLAR_OP_TOKENS, heads=8, d=128, seed=0):
     last = common._LAST.get('kda_chunk', {})
     check(last.get('path') == 'fused' and not last.get('interpret'),
           'the op\'s in-chunk scores ran the kda_chunk kernels (%s)' % last)
+    last = common._LAST.get('kda_walk', {})
+    check(last.get('path') == 'fused' and not last.get('interpret'),
+          'the op\'s chunks were walked by the kda_walk kernels (%s)' % last)
     forward = jax.jit(kda_ops.gated_delta_rule)
     fwd_s, both_s = _timed(forward, *args), _timed(op, *args)
     cost = solar_flops.kda_train_cost(1, t, heads, d, itemsize=4)
@@ -2218,6 +2221,10 @@ def phase_solar(seq=4096, seed=0):
               'the three delta-rule layers\' in-chunk scores ran the '
               'kda_chunk kernels (%d fused dispatches)'
               % monitor.counter_value('pallas/kda_chunk/dispatch_fused'))
+        check(common._LAST.get('kda_walk', {}).get('path') == 'fused',
+              'and their chunks were walked by the kda_walk kernels (%d '
+              'fused dispatches)'
+              % monitor.counter_value('pallas/kda_walk/dispatch_fused'))
         chosen = [jnp.asarray(x) for x in got[-len(routers):]]
         program_loads = [np.asarray(x) for x in got[
             len(fetches):len(fetches) + len(routers)]]
@@ -2516,6 +2523,10 @@ def _kimi_train_step(host_heads, seq, seed):
               'the delta-rule layers\' in-chunk scores ran the '
               'kda_chunk kernels (%d fused dispatches)'
               % monitor.counter_value('pallas/kda_chunk/dispatch_fused'))
+        check(common._LAST.get('kda_walk', {}).get('path') == 'fused',
+              'and their chunks were walked by the kda_walk kernels (%d '
+              'fused dispatches)'
+              % monitor.counter_value('pallas/kda_walk/dispatch_fused'))
         chosen = [jnp.asarray(x) for x in got[-len(routers):]]
         program_loads = [np.asarray(x) for x in got[
             len(fetches):len(fetches) + len(routers)]]

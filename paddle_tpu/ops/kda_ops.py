@@ -27,10 +27,23 @@ Diag(exp(a_t)) S_(t-1) + k_t u_t^T``):
 ``A``, ``B``, the unit lower-triangular system's solution (``W = (I +
 Diag(beta) A)^-1 Diag(beta) [Kbar | V]``, so ``U = W_v - W_k S_0``),
 ``Qbar`` and ``Khat`` are computed for ALL chunks, heads and sequences
-at once (``_prepare``); a ``lax.scan`` over the T / chunk chunks carries
-the state through three small matmuls (``_step``).
+at once (``_prepare``); a walk over the T / chunk chunks carries the
+state through three small matmuls a chunk (``_step``).
 
-``_prepare`` HOLDS NO LOOP: only the two scans walk anything.  The
+THE WALK is a Pallas kernel on a TPU (``ops/pallas/kda_walk.py``,
+``kda_walk`` in the kernel library): ONE call forward and one in
+reverse, the chunk axis the grid's last and sequential, the float32
+state (in reverse its cotangent) in VMEM from a sequence's first chunk
+to its last; it takes what the scan takes and gives what it gives.  Its
+dense form is a ``lax.scan`` over ``_step``
+(in reverse over ``jax.vjp(_step)``): a ``while`` of T / chunk trips,
+the state through HBM at every fusion's boundary.  ``gated_delta_rule``
+asks ``common.dispatch`` once a call for it too (``_paths``): the
+kernels where the working dtype is float32, dk and dv whole 128-lane
+tiles and the call's VMEM count fits; the scans for float64, other
+widths, under the GSPMD runner and off a TPU.
+
+``_prepare`` HOLDS NO LOOP: only the walks walk anything.  The
 system is C x C with C at most 64, under the block size at which the
 compiler's ``triangular_solve`` multiplies, so that call inverted each
 system row by row, 64 dependent steps a call (0.63 to 0.69 ms on a
@@ -61,9 +74,8 @@ dense form below (``_scores``, XLA's: its blocks are HBM buffers,
 sixteen times the operands) for float64, other widths, under the GSPMD
 runner (``auto_partitioned``) and off a TPU (the kernels' bodies under
 the Pallas interpreter where ``FLAGS_pallas_force`` asks).  Everything
-else is XLA's on either path, ONE ``_prepare`` for both: the
-cumulative sum, ``Qbar``, ``Kbar``, ``Khat``, the inverse, ``_step``
-and both scans.
+else of the preparation is XLA's on either path, ONE ``_prepare`` for
+both: the cumulative sum, ``Qbar``, ``Kbar``, ``Khat`` and the inverse.
 
 THE DECAY IS PER CHANNEL, so ``exp(G_t - G_j)`` does not factor out of
 the sum over channels as a scalar, and the factored form ``(k_t
@@ -85,11 +97,13 @@ chunk's START (T / chunk x [dk, dv] a head), and not one byte for the
 kernels (their ``custom_vjp`` keeps q, k and G of the RECOMPUTED
 preparation, transients of the backward).  It computes ``_prepare``
 again under ``jax.vjp`` (the scores by the forward kernel once more),
-walks the chunks in reverse carrying the state's cotangent with each
-chunk's ``_step`` under a ``jax.vjp`` of its own, and hands the
-operands' cotangents back through ``_prepare`` (through the scores by
-the backward kernel).  Nothing saved grows with T x dk x dv, nor with
-what ``_prepare`` holds inside a chunk.
+walks the chunks in reverse carrying the state's cotangent (the
+reverse kernel, which recomputes ``u`` from the kept start and writes
+the six operands' cotangents; densely each chunk's ``_step`` under a
+``jax.vjp`` of its own), and hands the operands' cotangents back
+through ``_prepare`` (through the scores by the backward kernel).
+Nothing saved grows with T x dk x dv, nor with what ``_prepare`` holds
+inside a chunk.
 
 float32 inside whatever arrives (float64 under x64): the decays and
 their sums, the system's inverse, the state and every product
@@ -264,8 +278,9 @@ def _step(state, operands):
 
 
 def _count_chunks(operands):
-    """``kda/chunks``: the chunk steps the scans of the traced program
-    take, the forward's and the reverse walk's alike."""
+    """``kda/chunks``: the chunk steps the walks of the traced program
+    take, the forward's and the reverse walk's alike: a scan's trips or
+    a kernel's sequential grid steps a head block."""
     registry.trace_sum('kda/chunks', operands[0].shape[0])
 
 
@@ -287,32 +302,47 @@ def _chunked(x, chunk, n, dtype):
     return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 2, 3)
 
 
+def _unchunked(out, t, dtype):
+    """``_chunked``'s reverse for the kernels' o: [N, B, H, C, dv] ->
+    [B, t, H, dv] in ``dtype``, the padded tail cut off."""
+    out = jnp.moveaxis(jnp.moveaxis(out, 0, 1), 2, 3)   # [B, N, C, H, dv]
+    b, _, _, h, dv = out.shape
+    return out.reshape(b, -1, h, dv)[:, :t].astype(dtype)
+
+
 def _working_dtype(v):
     return jnp.float64 if v.dtype == jnp.float64 else jnp.float32
 
 
-def _scores_path(k, v, chunk, auto_partitioned):
-    """How this call's in-chunk scores are computed: 'dense'
-    (``_scores``), 'fused' (the ``kda_chunk`` kernel) or 'interpret'
-    (its body under the Pallas interpreter: FLAGS_pallas_force off a
-    TPU).  One ``common.dispatch`` decision a call, which its forward
-    and its backward both follow; the kernel's layout decides it from
-    what the operands show (``kda_chunk.checks``)."""
-    from .pallas import common, kda_chunk
-    fused, interpret = common.dispatch(
-        'kda_chunk', True,
-        checks=kda_chunk.checks(_layout(k.shape[1], chunk)[0],
-                                k.shape[-1], _working_dtype(v)),
-        auto_partitioned=auto_partitioned)
-    return ('interpret' if interpret else 'fused') if fused else 'dense'
+def _paths(k, v, chunk, auto_partitioned):
+    """How this call's in-chunk scores are computed and how its chunks
+    are walked, (scores, walk), each 'dense' (``_scores``; the
+    ``lax.scan`` over ``_step``), 'fused' (the ``kda_chunk`` kernels;
+    the ``kda_walk`` kernels) or 'interpret' (the kernels' bodies under
+    the Pallas interpreter: FLAGS_pallas_force off a TPU).  One
+    ``common.dispatch`` decision a kernel and call, which the call's
+    forward and its backward both follow; each kernel's layout decides
+    it from what the operands show (``kda_chunk.checks``,
+    ``kda_walk.checks``)."""
+    from .pallas import common, kda_chunk, kda_walk
+    size, dtype = _layout(k.shape[1], chunk)[0], _working_dtype(v)
+
+    def path(kernel, checks):
+        fused, interpret = common.dispatch(
+            kernel, True, checks=checks, auto_partitioned=auto_partitioned)
+        return ('interpret' if interpret else 'fused') if fused else 'dense'
+
+    return (path('kda_chunk', kda_chunk.checks(size, k.shape[-1], dtype)),
+            path('kda_walk', kda_walk.checks(
+                k.shape[2], size, k.shape[-1], v.shape[-1], dtype)))
 
 
 def _operands(q, k, v, a, beta, chunk, path):
     scores = _scores
-    if path != 'dense':
+    if path[0] != 'dense':
         from .pallas import kda_chunk
         scores = functools.partial(kda_chunk.chunk_scores,
-                                   interpret=path == 'interpret')
+                                   interpret=path[0] == 'interpret')
     chunk, n = _layout(k.shape[1], chunk)
     return _prepare(*(_chunked(x, chunk, n, _working_dtype(v))
                       for x in (q, k, v, a, beta)), scores=scores)
@@ -320,9 +350,14 @@ def _operands(q, k, v, a, beta, chunk, path):
 
 def _forward(q, k, v, a, beta, chunk, path):
     """-> (o [B, T, H, dv] in v's dtype, the state at each chunk's
-    START [N, B, H, dk, dv])."""
+    START [N, B, H, dk, dv]; the walk's kernels keep it transposed)."""
     operands = _operands(q, k, v, a, beta, chunk, path)
     _count_chunks(operands)
+    if path[1] != 'dense':
+        from .pallas import kda_walk
+        out, starts = kda_walk.forward(operands,
+                                       interpret=path[1] == 'interpret')
+        return _unchunked(out, k.shape[1], v.dtype), starts
     w_k, w_v = operands[0], operands[1]
 
     def step(state, x):
@@ -344,7 +379,7 @@ def gated_delta_rule(q, k, v, a, beta, chunk=CHUNK, auto_partitioned=False):
     ``auto_partitioned``: ``common.dispatch``'s (the caller's word that
     XLA will partition this program over a mesh)."""
     return _rule(q, k, v, a, beta, chunk,
-                 _scores_path(k, v, chunk, auto_partitioned))
+                 _paths(k, v, chunk, auto_partitioned))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -363,12 +398,18 @@ def _rule_bwd(chunk, path, saved, d_out):
     (under ``jax.vjp``, which then carries their cotangents back to q,
     k, v, a and beta: through the scores' kernel by ITS backward
     kernel), and the chunks walked in reverse with the state's
-    cotangent, each chunk's ``_step`` under a ``jax.vjp`` of its
-    own."""
+    cotangent: by the walk's reverse kernel, or each chunk's ``_step``
+    under a ``jax.vjp`` of its own."""
     inputs, starts = saved
     operands, pull = jax.vjp(
         lambda *x: _operands(*x, chunk, path), *inputs)
     _count_chunks(operands)
+    if path[1] != 'dense':
+        from .pallas import kda_walk
+        d_chunks = _chunked(d_out, *_layout(inputs[1].shape[1], chunk),
+                            starts.dtype)
+        return pull(kda_walk.reverse(operands, starts, d_chunks,
+                                     interpret=path[1] == 'interpret')[0])
     size, n = _layout(inputs[1].shape[1], chunk)
 
     def step(d_state, x):

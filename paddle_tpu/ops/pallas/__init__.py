@@ -10,6 +10,7 @@ from . import common
 from . import flash_attention
 from . import grouped_matmul
 from . import kda_chunk
+from . import kda_walk
 from . import quant_collective
 from . import sinkhorn
 from . import ssm_scan

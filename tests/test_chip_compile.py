@@ -838,42 +838,69 @@ def test_quant_collective_tiles(one_chip):
     assert n == 1, n
 
 
-@pytest.mark.parametrize('t,heads,dk', [
-    (4096, 8, 128),     # solar_open2_250b_s4096: 64 chunks x 8 heads
-    (8192, 32, 128),    # kimi_linear_48b_s8192: 128 chunks x 32 heads
-    (24, 3, 128),       # less than a chunk: one chunk of two sub-chunks
-    (100, 2, 256),      # a padded tail, two lane tiles of channels
+@pytest.mark.parametrize('t,heads,dk,dv', [
+    (4096, 8, 128, 128),    # solar_open2_250b_s4096: 64 chunks x 8 heads
+    (8192, 32, 128, 128),   # kimi_linear_48b_s8192: 128 chunks x 32 heads
+    (24, 3, 128, 128),      # less than a chunk: one of two sub-chunks
+    (100, 2, 256, 256),     # a padded tail, two lane tiles of channels
+    (4096, 8, 128, 64),     # dv no lane tile: the chunks walked by scans
 ])
-def test_the_delta_rule_compiles_its_two_score_kernels(one_chip, as_on_tpu,
-                                                       t, heads, dk):
-    """``kda_attention``'s forward + backward at the Solar cell's layer
-    shape (and at a short and a ragged length): the dispatch answers
-    fused, the executable holds THREE Mosaic calls (the scores' forward,
-    its recompute in the backward, the scores' backward), no buffer of
-    the step is a [.., SUB, SUB, dk] or [.., n_sub, n_sub, SUB, dk]
-    decay block any more, and its only loops are the two scans over the
-    chunks (none where the sequence is one chunk): ``_prepare`` holds
-    no loop and no solve."""
+def test_the_delta_rule_compiles_its_score_and_walk_kernels(
+        one_chip, as_on_tpu, t, heads, dk, dv):
+    """``kda_attention``'s forward + backward at the two cells' layer
+    shapes (and at a short and a ragged length): both dispatches answer
+    fused, the executable holds FIVE Mosaic calls (the scores' forward
+    and the forward walk; the scores' forward again, the reverse walk
+    and the scores' backward) and NO ``while``: the chunks are walked
+    inside two calls, which hold no more VMEM than ``kda_walk``'s count
+    says and ask Mosaic for none.  Where the walk's layout does not
+    hold (dv off the lanes) the scores' three calls and the two scans'
+    ``while``s are what is there.  On either path no buffer of the step
+    is a [.., SUB, SUB, dk] or [.., n_sub, n_sub, SUB, dk] decay block
+    and ``_prepare`` holds no loop and no solve."""
     import re
     from paddle_tpu.ops import kda_ops
+    from paddle_tpu.ops.pallas import kda_walk
 
     def step(q, k, v, a, beta, probe):
         out, pull = jax.vjp(kda_ops.gated_delta_rule, q, k, v, a, beta)
         return (out,) + pull(probe)
 
     wide, rows = _spec((1, t, heads, dk)), _spec((1, t, heads))
-    text = _compiled(step, one_chip, wide, wide, wide, wide, rows,
-                     wide).as_text()
+    values = _spec((1, t, heads, dv))
+    text = _compiled(step, one_chip, wide, wide, values, wide, rows,
+                     values).as_text()
     _compiled_on_chip('kda_chunk')
-    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    walked = dv % 128 == 0
+    if walked:
+        _compiled_on_chip('kda_walk')
+    else:
+        assert common._LAST['kda_walk'] == {
+            'path': 'dense', 'reason': 'layout', 'interpret': False}
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        (5 if walked else 3)
     # (whole axes only: the compiler moves a [64, 8, 64, dk] operand in
     # four [64, 8, 16, dk] slices, which are no blocks)
     blocks = re.findall(
         r'f32\[[\d,]*(?:16,16|(?<!\d)\d,\d,16),%d\]' % dk, text)
     assert not blocks, sorted(set(blocks))
-    assert len(re.findall(r' while\(', text)) == (2 if t > 64 else 0)
+    assert len(re.findall(r' while\(', text)) == \
+        (0 if walked or t <= 64 else 2)
     for opcode in ('triangular-solve', 'InvertDiagBlocksLowerTriangular'):
         assert opcode not in text, opcode
+    if walked:
+        names = re.findall(r'op_name="[^"]*(kda_walk_\w+)\)*/pallas_call"',
+                           text)
+        assert sorted(set(names)) == ['kda_walk_forward',
+                                      'kda_walk_reverse'], names
+        used = [int(n) for n in re.findall(
+            r'custom_call_target="tpu_custom_call"[^\n]*?'
+            r'"used_scoped_memory_configs":\[\{[^}]*?"size":"(\d+)"', text)]
+        count = kda_walk.reverse_vmem(
+            kda_walk.heads_a_step(heads),
+            kda_ops._layout(t, kda_ops.CHUNK)[0], dk, dv)
+        assert len(used) == 5 and max(used) <= 1.1 * count <= \
+            common.SCOPED_VMEM_BYTES, (used, count)
 
 
 @pytest.mark.parametrize('rows,experts,d,hidden,held', [
@@ -1010,5 +1037,5 @@ def test_the_selective_scan_compiles_its_two_calls(one_chip, as_on_tpu,
 def test_every_dispatchable_kernel_is_compiled_here():
     """A kernel registered later must bring its compile with it."""
     assert set(common.kernels()) == {
-        'flash_attention', 'grouped_matmul', 'kda_chunk',
+        'flash_attention', 'grouped_matmul', 'kda_chunk', 'kda_walk',
         'quant_collective', 'sinkhorn', 'ssm_scan'}

@@ -1,10 +1,12 @@
 """The ``kda_chunk`` kernels (``paddle_tpu/ops/pallas/kda_chunk.py``: the
-gated delta rule's in-chunk scores, forward and backward) under the
-Pallas interpreter against the dense form they replace
-(``ops/kda_ops.py`` ``_scores``) and, through the whole op, against the
-token-by-token recurrence; what ``common.dispatch`` answers for shapes
-the kernels' layout does not hold; and ``_prepare``'s unit-triangular
-system, inverted by blocks, against float64 NumPy and
+gated delta rule's in-chunk scores, forward and backward) and the
+``kda_walk`` kernels (``ops/pallas/kda_walk.py``: its walk over the
+chunks, forward and reverse) under the Pallas interpreter against the
+dense forms they replace (``ops/kda_ops.py`` ``_scores``; the
+``lax.scan`` over ``_step`` and its ``jax.vjp``) and, through the whole
+op, against the token-by-token recurrence; what ``common.dispatch``
+answers for shapes the kernels' layouts do not hold; and ``_prepare``'s
+unit-triangular system, inverted by blocks, against float64 NumPy and
 ``triangular_solve``.  CPU; what the chip's compiler says of them is
 ``tests/test_chip_compile.py``'s."""
 
@@ -16,7 +18,7 @@ import jax.numpy as jnp
 from paddle_tpu.fluid import monitor
 from paddle_tpu.models.reference import solar_open2 as reference
 from paddle_tpu.ops import kda_ops
-from paddle_tpu.ops.pallas import common, kda_chunk
+from paddle_tpu.ops.pallas import common, kda_chunk, kda_walk
 
 
 def _chunks(seed, lead=(2, 3), c=64, dk=128, rate=1.0):
@@ -54,8 +56,8 @@ def _close(got, want, rtol):
     assert np.abs(got - want).max() <= rtol * np.abs(want).max()
 
 
-def _fused():
-    return monitor.counter_value('pallas/kda_chunk/dispatch_fused') or 0
+def _fused(kernel='kda_chunk'):
+    return monitor.counter_value('pallas/%s/dispatch_fused' % kernel) or 0
 
 
 @pytest.mark.parametrize('c,dk,rate', [(64, 128, 1.0), (64, 128, 16.0),
@@ -83,15 +85,18 @@ def test_the_kernels_scores_and_gradients_are_the_dense_forms(c, dk, rate):
         _close(x, y, 5e-6)
 
 
-@pytest.mark.parametrize('t', [100, 64, 24])
+@pytest.mark.parametrize('t,dv', [(100, 8), (64, 8), (24, 8),
+                                  (100, 128), (128, 128), (24, 128)])
 def test_the_fused_op_is_the_recurrence_at_whole_and_ragged_lengths(
-        pallas_interpret, t):
+        pallas_interpret, t, dv):
     """The whole op through the kernels (dispatch counted fused),
     forward and all five gradients, float32 against the token loop at
     rate 16: one chunk, less than one (a chunk of two sub-chunks) and
-    no whole number of them (a padded tail)."""
-    args = _sequence(t, t)
-    before = _fused()
+    no whole number of them (a padded tail).  At a value width of 8 the
+    scores' kernels run and the chunks are walked by the scan (reason
+    'layout'); at 128 the walk's kernels run too."""
+    args = _sequence(t, t, dv=dv)
+    before, walks = _fused(), _fused('kda_walk')
     with jax.default_matmul_precision('highest'):
         got, pull = jax.vjp(kda_ops.gated_delta_rule, *args)
         probe = jnp.asarray(
@@ -101,76 +106,97 @@ def test_the_fused_op_is_the_recurrence_at_whole_and_ragged_lengths(
         want_grads = pull_want(probe)
     assert _fused() == before + 1
     assert common._LAST['kda_chunk']['reason'] == 'forced_interpret'
+    assert _fused('kda_walk') == walks + (dv == 128)
+    assert common._LAST['kda_walk']['reason'] == (
+        'forced_interpret' if dv == 128 else 'layout')
     _close(got, want, 2e-5)
     for got_grad, want_grad in zip(got_grads, want_grads):
         _close(got_grad, want_grad, 5e-5)
 
 
+@pytest.mark.parametrize('dv', [8, 128])
 def test_the_fused_op_and_the_dense_op_agree_on_bf16_inputs(
-        pallas_interpret):
+        pallas_interpret, dv):
     """bf16 q, k, v, beta beside float32 log decays: the kernels see
-    the float32 working copies the dense form sees, and the two paths'
-    outputs and gradients round to the same bf16 but for an ulp."""
-    args = _sequence(5, 100, dtype=jnp.bfloat16)
+    the float32 working copies the dense form sees (the walk's give o
+    and take its cotangent in float32, cast as the dense form's), and
+    the two paths' outputs and gradients round to the same bf16 but for
+    an ulp."""
+    args = _sequence(5, 100, dv=dv, dtype=jnp.bfloat16)
     fused, pull = jax.vjp(kda_ops.gated_delta_rule, *args)
     probe = jnp.asarray(np.random.RandomState(2).randn(*fused.shape),
                         jnp.bfloat16)
     dense, pull_dense = jax.vjp(
-        lambda *x: kda_ops._rule(*x, kda_ops.CHUNK, 'dense'), *args)
+        lambda *x: kda_ops._rule(*x, kda_ops.CHUNK, ('dense', 'dense')),
+        *args)
     assert fused.dtype == jnp.bfloat16
     assert common._LAST['kda_chunk']['path'] == 'fused'
+    assert common._LAST['kda_walk']['path'] == (
+        'fused' if dv == 128 else 'dense')
     _close(fused, dense, 2 ** -7)
     for x, y in zip(pull(probe), pull_dense(probe)):
         assert x.dtype == y.dtype
         _close(x, y, 2 ** -6)
 
 
-@pytest.mark.parametrize('what,kwargs', [
-    ('layout', dict(dk=16)),                    # dk is no lane tile
-    ('layout', dict(dk=128, chunk=40)),         # no whole sub-chunks
-    ('auto_partitioned', dict(dk=128, auto_partitioned=True)),
+@pytest.mark.parametrize('kernel,what,kwargs', [
+    ('kda_chunk', 'layout', dict(dk=16)),           # dk is no lane tile
+    ('kda_chunk', 'layout', dict(dk=128, chunk=40)),    # no sub-chunks
+    ('kda_chunk', 'auto_partitioned', dict(dk=128, auto_partitioned=True)),
+    ('kda_walk', 'layout', dict(dk=16, dv=128)),
+    ('kda_walk', 'layout', dict(dk=128, dv=192)),   # dv is no lane tile
+    ('kda_walk', 'layout', dict(dk=128, dv=128, chunk=12)),   # no tiles
+    ('kda_walk', 'vmem_over_budget', dict(dk=128, dv=128, h=12)),
+    ('kda_walk', 'auto_partitioned',
+     dict(dk=128, dv=128, auto_partitioned=True)),
 ])
 def test_the_dispatch_answers_dense_with_its_reason_counted(
-        pallas_interpret, what, kwargs):
-    """Where the kernels' layout does not hold (dk off the 128 lanes, a
-    chunk of no whole sub-chunks) and where XLA partitions the program,
-    the op runs the dense form, says why, and is the recurrence."""
+        pallas_interpret, kernel, what, kwargs):
+    """Where a kernel's layout does not hold (dk or dv off the 128
+    lanes, a chunk of no whole sub-chunks; for the walk, more heads a
+    grid step than its VMEM count admits) and where XLA partitions the
+    program, the op runs that kernel's dense form, says why, and is the
+    recurrence."""
     kwargs = dict(kwargs)
-    args = _sequence(7, 50, b=1, h=2, dk=kwargs.pop('dk'), rate=4.0)
-    name = 'pallas/kda_chunk/fallback/' + what
+    args = _sequence(7, 50, b=1, h=kwargs.pop('h', 2), dk=kwargs.pop('dk'),
+                     dv=kwargs.pop('dv', 8), rate=4.0)
+    name = 'pallas/%s/fallback/%s' % (kernel, what)
     before = monitor.counter_value(name) or 0
-    fused = _fused()
+    fused = _fused(kernel)
     got = kda_ops.gated_delta_rule(*args, **kwargs)
     assert (monitor.counter_value(name) or 0) == before + 1
-    assert _fused() == fused
-    assert common._LAST['kda_chunk'] == {
+    assert _fused(kernel) == fused
+    assert common._LAST[kernel] == {
         'path': 'dense', 'reason': what, 'interpret': False}
     with jax.default_matmul_precision('highest'):
         _close(got, reference.kda_recurrence(*args), 2e-5)
 
 
-def test_float64_runs_the_dense_form(pallas_interpret):
+@pytest.mark.parametrize('kernel', ['kda_chunk', 'kda_walk'])
+def test_float64_runs_the_dense_form(pallas_interpret, kernel):
     """Under x64 the working dtype is float64, which the kernels do
     not take: reason 'dtype'."""
-    before = monitor.counter_value('pallas/kda_chunk/fallback/dtype') or 0
+    name = 'pallas/%s/fallback/dtype' % kernel
+    before = monitor.counter_value(name) or 0
     with jax.enable_x64():
-        args = _sequence(8, 40, b=1, h=1, dtype=jnp.float64)
+        args = _sequence(8, 40, b=1, h=1, dv=128, dtype=jnp.float64)
         args[3] = args[3].astype(jnp.float64)
         got = kda_ops.gated_delta_rule(*args)
         _close(got, reference.kda_recurrence(*args), 1e-12)
-    assert monitor.counter_value(
-        'pallas/kda_chunk/fallback/dtype') == before + 1
+    assert monitor.counter_value(name) == before + 1
 
 
-def test_off_a_tpu_and_unforced_the_op_is_dense():
-    before = monitor.counter_value('pallas/kda_chunk/fallback/off_tpu') or 0
-    kda_ops.gated_delta_rule(*_sequence(9, 20, b=1, h=1))
-    assert monitor.counter_value(
-        'pallas/kda_chunk/fallback/off_tpu') == before + 1
+@pytest.mark.parametrize('kernel', ['kda_chunk', 'kda_walk'])
+def test_off_a_tpu_and_unforced_the_op_is_dense(kernel):
+    name = 'pallas/%s/fallback/off_tpu' % kernel
+    before = monitor.counter_value(name) or 0
+    kda_ops.gated_delta_rule(*_sequence(9, 20, b=1, h=1, dv=128))
+    assert monitor.counter_value(name) == before + 1
 
 
-def test_the_kernel_is_registered_with_its_dense_fallback():
-    entry = common.kernels()['kda_chunk']
+@pytest.mark.parametrize('kernel', ['kda_chunk', 'kda_walk'])
+def test_the_kernel_is_registered_with_its_dense_fallback(kernel):
+    entry = common.kernels()[kernel]
     assert entry['has_vjp'] and entry['op_types'] == ('kda_attention',)
     module, name = entry['dense_fallback'].rsplit('.', 1)
     assert module == 'ops.kda_ops' and callable(getattr(kda_ops, name))
@@ -239,9 +265,12 @@ def test_the_block_inverses_gradients_are_triangular_solves(c, dtype):
 
 def _primitives(jaxpr):
     """Every primitive's name in a jaxpr and in the jaxprs its
-    equations hold (scans, custom_vjp calls, pjit)."""
+    equations hold (scans, custom_vjp calls, pjit), a kernel's body
+    left out: a ``pallas_call`` is one equation."""
     for eqn in jaxpr.eqns:
         yield eqn.primitive.name
+        if eqn.primitive.name == 'pallas_call':
+            continue
         for value in eqn.params.values():
             for sub in value if isinstance(value, (tuple, list)) \
                     else (value,):
@@ -250,12 +279,17 @@ def _primitives(jaxpr):
                     yield from _primitives(sub)
 
 
-@pytest.mark.parametrize('path', ['dense', 'interpret'])
+@pytest.mark.parametrize('path', [('dense', 'dense'), ('interpret', 'dense'),
+                                  ('interpret', 'interpret')])
 def test_the_op_holds_no_solve_and_no_loop_but_its_scans(path):
     """Forward and backward of the whole op, traced: no
-    ``triangular_solve`` and no ``while`` on either path; the products
-    are there and, outside the kernels' bodies, the two scans alone."""
-    args = _sequence(11, 100, b=1, h=2)
+    ``triangular_solve`` and no ``while`` on any path; the products are
+    there and, outside the kernels' bodies, the two scans alone where
+    the chunks are walked densely and NO loop where the walk's kernels
+    run: five kernel calls then (the scores' forward and the forward
+    walk; the scores' forward again, the reverse walk, the scores'
+    backward), three with the scores' kernels alone."""
+    args = _sequence(11, 100, b=1, h=2, dv=128)
 
     def both(*x):
         out, pull = jax.vjp(
@@ -264,5 +298,93 @@ def test_the_op_holds_no_solve_and_no_loop_but_its_scans(path):
 
     found = list(_primitives(jax.make_jaxpr(both)(*args).jaxpr))
     assert 'dot_general' in found
-    assert found.count('scan') == 2 or path == 'interpret'
+    assert found.count('scan') == (2 if path[1] == 'dense' else 0)
+    assert found.count('pallas_call') == \
+        3 * (path[0] != 'dense') + 2 * (path[1] != 'dense')
     assert not {'triangular_solve', 'while'} & set(found)
+
+
+def _walked(operands):
+    """The dense walk: the ``lax.scan`` over ``_step`` as ``_forward``
+    runs it -> (o [N, B, H, C, dv], the state at each chunk's start)."""
+    def step(state, x):
+        after, out = kda_ops._step(state, x)
+        return after, (out, state)
+
+    w_k, w_v = operands[0], operands[1]
+    zero = jnp.zeros(w_k.shape[1:3] + (w_k.shape[-1], w_v.shape[-1]),
+                     w_k.dtype)
+    return jax.lax.scan(step, zero, operands)[1]
+
+
+def _walked_back(operands, starts, d_out):
+    """The dense reverse walk, as ``_rule_bwd`` runs it -> (the start
+    state's cotangent, the six operands' cotangents)."""
+    def step(d_state, x):
+        chunk_operands, start, d_chunk_out = x
+        _, pull_step = jax.vjp(kda_ops._step, start, chunk_operands)
+        return pull_step((d_state, d_chunk_out))
+
+    return jax.lax.scan(step, jnp.zeros_like(starts[0]),
+                        (operands, starts, d_out), reverse=True)
+
+
+@pytest.mark.parametrize('t,h,heads', [
+    (128, 4, 2),        # whole chunks, two head blocks a sequence
+    (128, 4, None),     # the same in one block (heads_a_step(4) == 4)
+    (100, 4, 1),        # a padded tail, a head a grid step
+    (64, 3, None),      # one chunk
+    (24, 2, 1),         # less than one: a chunk of two sub-chunks
+    (200, 16, None),    # eight of sixteen heads a grid step
+])
+def test_the_walks_are_the_scans_over_the_step(t, h, heads):
+    """The forward walk's o and starts and the reverse walk's six
+    cotangents and final dS against the ``lax.scan`` over ``_step`` and
+    over its ``jax.vjp``, on operands ``_prepare`` made from two
+    sequences (the state is zeroed at each sequence's first chunk: the
+    scratch still holds the one before's last state)."""
+    args = _sequence(t + h, t, b=2, h=h, dv=128)
+    operands = kda_ops._operands(*args, kda_ops.CHUNK, ('dense', 'dense'))
+    size, n = kda_ops._layout(t, kda_ops.CHUNK)
+    assert operands[0].shape[:4] == (n, 2, h, size)
+    if heads is None:
+        assert kda_walk.heads_a_step(h) == min(h, kda_walk.HEADS)
+    out, starts = _walked(operands)
+    got, got_starts = kda_walk.forward(operands, heads=heads,
+                                       interpret=True)
+    assert got.shape == out.shape and got.dtype == jnp.float32
+    _close(got, out, 1e-6)
+    # the kernels keep the state transposed, S^T [dv, dk]
+    _close(jnp.swapaxes(got_starts, -1, -2), starts, 1e-6)
+    assert (np.asarray(got_starts[0]) == 0).all()
+    # a cotangent of o as the op hands it over: zero on the padded tail
+    probe = kda_ops._chunked(jnp.asarray(np.random.RandomState(2).randn(
+        2, t, h, 128), jnp.float32), size, n, jnp.float32)
+    d_start, grads = _walked_back(operands, starts, probe)
+    got_grads, got_d_start = kda_walk.reverse(
+        operands, got_starts, probe, heads=heads, interpret=True)
+    assert len(got_grads) == 6
+    for x, y in zip(got_grads, grads):
+        assert x.shape == y.shape and x.dtype == y.dtype
+        _close(x, y, 2e-6)
+    _close(jnp.swapaxes(got_d_start, -1, -2), d_start, 2e-6)
+
+
+def test_the_walks_gates_read_the_shapes():
+    """``kda_walk.checks`` at the two cells' layer shapes and off them:
+    float32, both widths whole lane tiles, whole sublane tiles a chunk, and
+    the reverse call's VMEM count (eight heads a step at both cells'
+    head counts) under the budget of a call that asks for nothing."""
+    for heads in (8, 32):
+        assert kda_walk.heads_a_step(heads) == 8
+        assert all(ok for _, ok in kda_walk.checks(
+            heads, 64, 128, 128, jnp.float32))
+    assert kda_walk.reverse_vmem(8, 64, 128, 128) < \
+        common.VMEM_BUDGET_BYTES < kda_walk.reverse_vmem(16, 64, 128, 128)
+    failing = {
+        'dtype': kda_walk.checks(8, 64, 128, 128, jnp.float64),
+        'layout': kda_walk.checks(8, 64, 128, 64, jnp.float32),
+        'vmem_over_budget': kda_walk.checks(8, 64, 512, 512, jnp.float32),
+    }
+    for reason, gates in failing.items():
+        assert [name for name, ok in gates if not ok][0] == reason

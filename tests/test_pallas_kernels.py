@@ -184,7 +184,8 @@ def test_comms_plan_fused_quant_admissibility():
 def test_kernel_registry_contract():
     ks = common.kernels()
     assert set(ks) == {'flash_attention', 'grouped_matmul', 'kda_chunk',
-                       'quant_collective', 'sinkhorn', 'ssm_scan'}
+                       'kda_walk', 'quant_collective', 'sinkhorn',
+                       'ssm_scan'}
     for name in ks:
         assert ks[name]['dense_fallback'], name
 

@@ -14,17 +14,19 @@ gradients lie from the first's, as a share of the largest entry:
   git show HEAD~1:paddle_tpu/ops/kda_ops.py > .bench_archive/kda_parent.py
   python tools/bench_kda.py --impl parent=.bench_archive/kda_parent.py \\
       --impl tree --ops chiprun_out/kda_trace
+  python tools/bench_kda.py --tokens 8192 --heads 32 ...   # Kimi Linear's
 
 ``--ops`` traces one forward + backward call a row and gives the row
 its device time BY PART, ms (``parts_ms``): the scores' kernels (every
 Mosaic call), the inverse of the unit-triangular system (what lowers
 under the ``inverse`` scope, or a ``triangular_solve``), the running
-decay's sums (the ``cumsum``), the forward scan and the reverse scan
-(the program's two ``while`` loops in the order they run, with
-``trip_us``: a loop's time over its chunks), and everything else of the
-op (exponentials, layout, the chunks' padding); and the five longest
-operations outside the loops by name, whose instructions are in
-``program.txt`` beside the trace.
+decay's sums (the ``cumsum``), the forward walk and the reverse walk
+over the chunks (the ``kda_walk`` kernels' two calls by their name, or
+where the copy holds none the program's two ``while`` loops in the
+order they run, with ``trip_us``: a walk's time over its chunks), and
+everything else of the op (exponentials, layout, the chunks' padding);
+and the five longest operations outside the walks by name, whose
+instructions are in ``program.txt`` beside the trace.
 
 Rows go to stdout and to ``--out`` (a .jsonl under chiprun_out/).
 """
@@ -56,7 +58,9 @@ _OP_NAME = re.compile(r'^\s*(?:ROOT )?%?(\S+) = .*op_name="([^"]*)"')
 # copy of the module from before PR 59 solves by ``triangular_solve``)
 PARTS = (('inverse', ('inverse', 'triangular_solve')),
          ('sums', ('cumsum',)))
-SCANS = ('forward_scan', 'reverse_scan')
+WALKS = ('forward_walk', 'reverse_walk')
+# the names ``ops/pallas/kda_walk.py`` gives its two calls
+WALK_CALLS = ('kda_walk_forward', 'kda_walk_reverse')
 
 
 def load_impl(spec):
@@ -94,17 +98,21 @@ def part_of(op_name):
 
 def by_part(ops, op_names, trips):
     """One traced call's device ops (``trace_reduce.plane_ops``) ->
-    ({part: ms}, {scan: us a trip}, the five longest operations outside
-    the loops).  Every instant goes to the innermost op running then;
+    ({part: ms}, {walk: us a trip}, the five longest operations outside
+    the walks).  Every instant goes to the innermost op running then;
     inside a ``while`` it goes to that loop, whatever the body's
-    instruction is called."""
+    instruction is called, and a Mosaic call that ``kda_walk`` named
+    is that walk."""
     loops = sorted((op for op in ops
                     if op.name.split('.')[0] == 'while'),
                    key=lambda op: op.start)
     ms, outside = {}, {}
     for a, b, op in trace_reduce.innermost_segments(ops):
-        part = next((name for name, loop in zip(SCANS, loops)
+        part = next((name for name, loop in zip(WALKS, loops)
                      if loop.start <= a and b <= loop.end), None)
+        if part is None and op.kind == trace_reduce.MOSAIC:
+            part = next((name for name, call in zip(WALKS, WALK_CALLS)
+                         if call in op_names.get(op.name, '')), None)
         if part is None:
             part = 'scores_kernels' if op.kind == trace_reduce.MOSAIC \
                 else part_of(op_names.get(op.name, ''))
@@ -113,7 +121,7 @@ def by_part(ops, op_names, trips):
     longest = sorted(outside.items(), key=lambda kv: -kv[1])[:5]
     return ({part: round(v, 3) for part, v in sorted(ms.items())},
             {name: round(1e3 * ms[name] / trips, 2)
-             for name in SCANS if name in ms},
+             for name in WALKS if name in ms},
             {name: round(v, 3) for name, v in longest})
 
 

@@ -27,7 +27,7 @@ Run from `make check` (CPU: JAX_PLATFORMS=cpu).
 import os
 import sys
 
-EXPECTED = ('flash_attention', 'grouped_matmul', 'kda_chunk',
+EXPECTED = ('flash_attention', 'grouped_matmul', 'kda_chunk', 'kda_walk',
             'quant_collective', 'sinkhorn', 'ssm_scan')
 
 
@@ -96,6 +96,27 @@ def main():
         if not np.allclose(np.asarray(a), np.asarray(b),
                            rtol=5e-5, atol=5e-6):
             failures.append('kda_chunk forward/grad parity')
+            break
+
+    # the same with values a lane tile wide, so that the chunks are
+    # walked by the kda_walk kernels (two sequences of three heads: the
+    # state is zeroed between them) against the scan over ``_step``
+    walk = [jnp.asarray(x.astype('float32')) for x in (
+        rng.randn(2, 150, 3, 128) / 11, rng.randn(2, 150, 3, 128) / 11,
+        rng.randn(2, 150, 3, 128), -rng.uniform(0, 2, (2, 150, 3, 128)),
+        rng.uniform(0, 2, (2, 150, 3)))]
+    fluid.set_flags({'FLAGS_pallas_force': True})
+    walks = monitor.counter_value('pallas/kda_walk/dispatch_fused') or 0
+    fused = jax.value_and_grad(recur, (0, 1, 2, 3, 4))(*walk)
+    if monitor.counter_value('pallas/kda_walk/dispatch_fused') != walks + 1:
+        failures.append('kda_walk did not dispatch fused at dv 128')
+    fluid.set_flags({'FLAGS_pallas_force': False})
+    dense = jax.value_and_grad(recur, (0, 1, 2, 3, 4))(*walk)
+    for a, b in zip(jax.tree_util.tree_leaves(fused),
+                    jax.tree_util.tree_leaves(dense)):
+        if not np.allclose(np.asarray(a), np.asarray(b),
+                           rtol=5e-5, atol=5e-6):
+            failures.append('kda_walk forward/grad parity')
             break
 
     # a held layer's expert MLP through the grouped_matmul kernels
@@ -182,8 +203,8 @@ def main():
             np.array_equal(np.asarray(s), np.asarray(sref))):
         failures.append('quantize_blocks not bitwise vs dense q()')
     print('parity: flash_attention fwd/grad, kda_chunk fwd/grad, '
-          'grouped_matmul fwd/grad, sinkhorn fwd/grad, ssm_scan fwd/grad, '
-          'quantize_blocks ok')
+          'kda_walk fwd/grad, grouped_matmul fwd/grad, sinkhorn fwd/grad, '
+          'ssm_scan fwd/grad, quantize_blocks ok')
 
     # -- 3. dispatch observability -----------------------------------
     quant_collective.dispatch()
