@@ -27,8 +27,31 @@ Diag(exp(a_t)) S_(t-1) + k_t u_t^T``):
 ``A``, ``B``, the unit lower-triangular system's solution (``W = (I +
 Diag(beta) A)^-1 Diag(beta) [Kbar | V]``, so ``U = W_v - W_k S_0``),
 ``Qbar`` and ``Khat`` are computed for ALL chunks, heads and sequences
-at once (``_prepare``); a walk over the T / chunk chunks carries the
+at once (the preparation); a walk over the T / chunk chunks carries the
 state through three small matmuls a chunk (``_step``).
+
+THE PREPARATION is a Pallas kernel on a TPU (``ops/pallas/kda_chunk.py``,
+``kda_chunk`` in the kernel library), all of it but the system's
+inverse: the call reads q, k, v, a and beta where the projections
+wrote them ([B, T, H x d], bfloat16 under AMP, a chunk's rows of one
+head's lanes a block) and writes the system ``beta A``, ``B``,
+``Qbar``, ``Khat``, the right-hand side ``beta [Kbar | V]`` as ONE
+array and ``exp(G_C)``, float32 and chunk-major as the solve's product
+and the walk's kernels read them; the running sum, the exponentials,
+the casts, the chunking and the ragged tail's mask happen in VMEM, and
+its backward kernel (under ``kda_chunk.prepare``'s ``custom_vjp``)
+writes dq, dk, dv, da, dbeta in the op's own order and dtypes.  XLA
+keeps ``_unit_lower_inverse`` and the products that apply it
+(``_solve`` and its closed-form backward) and nothing else: ``W = T
+rhs`` is one product on [.., C, dk + dv], whose two halves the walk's
+calls read as lanes of one block.  Until PR 62 the kernel made ``A``
+and ``B`` alone and the rest was XLA's elementwise and layout fusions
+around it: 9.7 ms of a forward preparation's 16.5 and 7 of a
+pull-back's at Kimi Linear's 32 heads x 8192 tokens, the same arrays
+across HBM 3.5 GB a preparation where the walk and the solve need
+about 1 (PERF.md section 6, PR 62).  The dense form is ``_prepare``
+(over ``_chunked`` copies, with ``_scores``): float64, widths off the
+128 lanes, under the GSPMD runner and off a TPU.
 
 THE WALK is a Pallas kernel on a TPU (``ops/pallas/kda_walk.py``,
 ``kda_walk`` in the kernel library): ONE call forward and one in
@@ -43,39 +66,35 @@ kernels where the working dtype is float32, dk and dv whole 128-lane
 tiles and the call's VMEM count fits; the scans for float64, other
 widths, under the GSPMD runner and off a TPU.
 
-``_prepare`` HOLDS NO LOOP: only the walks walk anything.  The
-system is C x C with C at most 64, under the block size at which the
-compiler's ``triangular_solve`` multiplies, so that call inverted each
-system row by row, 64 dependent steps a call (0.63 to 0.69 ms on a
-v5e at 512 chunk-heads).  Here the inverse ``T = (I + Diag(beta)
+THE PREPARATION HOLDS NO LOOP on either path: only the walks walk
+anything. The system is C x C with C at most 64, under the block size at
+which the compiler's ``triangular_solve`` multiplies, so that call
+inverted each system row by row, 64 dependent steps a call (0.63 to 0.69
+ms on a v5e at 512 chunk-heads). Here the inverse ``T = (I + Diag(beta)
 A)^-1`` is FORMED, by substitution in blocks of ``SUB``
-(``_unit_lower_inverse``: the diagonal blocks row by row, ``ROWS``
-rows a pass, every chunk, head and block in each step; the blocks
-under the diagonal by block substitution, products), and ``W = T
-(Diag(beta) [Kbar | V])`` is one product; its backward is the closed
-form ``dM = -strictly_lower(T^T dW W^T)`` (``_solve``).  Substitution
-and NOT a series: ``(I + M)^-1 = (I - M)(I + M^2)(I + M^4)..`` is exact
-on paper (``M`` is nilpotent) but with ``beta`` up to 2 and keys that
-nearly repeat the entries of ``M`` are near 2, its powers pass 1e15 and
-the float32 sum has to cancel them; substitution only ever forms
-entries of ``T`` itself.  The running decay ``G`` stays a ``cumsum``:
-as a product with a triangle of ones it read FASTER alone and SLOWER
-in the step it runs in (``PERF.md`` section 6, PR 59).
+(``_unit_lower_inverse``: the diagonal blocks row by row, ``ROWS`` rows
+a pass, every chunk, head and block in each step; the blocks under the
+diagonal by block substitution, products), and ``W = T (Diag(beta) [Kbar
+| V])`` is one product; its backward is the closed form ``dM =
+-strictly_lower(T^T dW W^T)`` (``_solve``). Substitution and NOT a
+series: ``(I + M)^-1 = (I - M)(I + M^2)(I + M^4)..`` is exact on paper
+(``M`` is nilpotent) but with ``beta`` up to 2 and keys that nearly
+repeat the entries of ``M`` are near 2, its powers pass 1e15 and the
+float32 sum has to cancel them; substitution only ever forms entries of
+``T`` itself. In ``_prepare`` the running decay ``G`` is a ``cumsum``:
+as an XLA product with a triangle of ones it read FASTER alone and
+SLOWER in the step it runs in (``PERF.md`` section 6, PR 59); the kernel
+takes that product in VMEM, where it costs no pass over HBM.
 
-``A`` and ``B`` (``_scores``) are a Pallas kernel on a TPU
-(``ops/pallas/kda_chunk.py``, ``kda_chunk`` in the kernel library: one
-chunk-head a grid step, the [SUB, SUB, dk] decay blocks of the next
-paragraph built and dropped in VMEM), with a backward kernel of its own
-under its ``custom_vjp`` (dq, dk, dG from dA, dB; the blocks built
-again on the chip).  ``gated_delta_rule`` asks ``common.dispatch`` once
-a call: the kernels where the working dtype is float32, dk a whole
-number of 128-lane tiles and the chunk as run whole sub-chunks; the
-dense form below (``_scores``, XLA's: its blocks are HBM buffers,
-sixteen times the operands) for float64, other widths, under the GSPMD
-runner (``auto_partitioned``) and off a TPU (the kernels' bodies under
-the Pallas interpreter where ``FLAGS_pallas_force`` asks).  Everything
-else of the preparation is XLA's on either path, ONE ``_prepare`` for
-both: the cumulative sum, ``Qbar``, ``Kbar``, ``Khat`` and the inverse.
+``gated_delta_rule`` asks ``common.dispatch`` once a call for the
+preparation: the kernels where the working dtype is float32, dk and dv
+whole numbers of 128-lane tiles and the chunk as run whole sub-chunks;
+``_prepare`` for float64, other widths, under the GSPMD runner
+(``auto_partitioned``) and off a TPU (the kernels' bodies under the
+Pallas interpreter where ``FLAGS_pallas_force`` asks).  ``_scores`` is
+the dense form of the in-chunk scores: its [.., SUB, SUB, dk] blocks of
+the next paragraph are HBM buffers, sixteen times the operands, where
+the kernel builds and drops them in VMEM.
 
 THE DECAY IS PER CHANNEL, so ``exp(G_t - G_j)`` does not factor out of
 the sum over channels as a scalar, and the factored form ``(k_t
@@ -94,16 +113,16 @@ term: a factor that underflows belongs to a product under 1e-38.
 The backward is a ``custom_vjp`` of the whole op: it keeps what the op
 was handed (q, k, v, a, beta as they arrived) and the state at each
 chunk's START (T / chunk x [dk, dv] a head), and not one byte for the
-kernels (their ``custom_vjp`` keeps q, k and G of the RECOMPUTED
-preparation, transients of the backward).  It computes ``_prepare``
-again under ``jax.vjp`` (the scores by the forward kernel once more),
-walks the chunks in reverse carrying the state's cotangent (the
-reverse kernel, which recomputes ``u`` from the kept start and writes
-the six operands' cotangents; densely each chunk's ``_step`` under a
+kernels (the preparation's ``custom_vjp`` keeps its five inputs and A
+of the RECOMPUTED preparation, transients of the backward).  It
+computes the operands again under ``jax.vjp`` (by the forward kernel
+once more), walks the chunks in reverse carrying the state's cotangent
+(the reverse kernel, which recomputes ``u`` from the kept start and
+writes the operands' cotangents; densely each chunk's ``_step`` under a
 ``jax.vjp`` of its own), and hands the operands' cotangents back
-through ``_prepare`` (through the scores by the backward kernel).
-Nothing saved grows with T x dk x dv, nor with what ``_prepare`` holds
-inside a chunk.
+through the solve's closed form and the preparation's backward kernel.
+Nothing saved grows with T x dk x dv, nor with what the preparation
+holds inside a chunk.
 
 float32 inside whatever arrives (float64 under x64): the decays and
 their sums, the system's inverse, the state and every product
@@ -315,12 +334,12 @@ def _working_dtype(v):
 
 
 def _paths(k, v, chunk, auto_partitioned):
-    """How this call's in-chunk scores are computed and how its chunks
-    are walked, (scores, walk), each 'dense' (``_scores``; the
-    ``lax.scan`` over ``_step``), 'fused' (the ``kda_chunk`` kernels;
-    the ``kda_walk`` kernels) or 'interpret' (the kernels' bodies under
-    the Pallas interpreter: FLAGS_pallas_force off a TPU).  One
-    ``common.dispatch`` decision a kernel and call, which the call's
+    """How this call's chunks are prepared and how they are walked,
+    (preparation, walk), each 'dense' (``_prepare`` with ``_scores``;
+    the ``lax.scan`` over ``_step``), 'fused' (the ``kda_chunk``
+    kernels; the ``kda_walk`` kernels) or 'interpret' (the kernels'
+    bodies under the Pallas interpreter: FLAGS_pallas_force off a TPU).
+    One ``common.dispatch`` decision a kernel and call, which the call's
     forward and its backward both follow; each kernel's layout decides
     it from what the operands show (``kda_chunk.checks``,
     ``kda_walk.checks``)."""
@@ -332,20 +351,36 @@ def _paths(k, v, chunk, auto_partitioned):
             kernel, True, checks=checks, auto_partitioned=auto_partitioned)
         return ('interpret' if interpret else 'fused') if fused else 'dense'
 
-    return (path('kda_chunk', kda_chunk.checks(size, k.shape[-1], dtype)),
+    return (path('kda_chunk', kda_chunk.checks(
+                size, k.shape[-1], v.shape[-1], dtype)),
             path('kda_walk', kda_walk.checks(
                 k.shape[2], size, k.shape[-1], v.shape[-1], dtype)))
 
 
 def _operands(q, k, v, a, beta, chunk, path):
-    scores = _scores
-    if path[0] != 'dense':
-        from .pallas import kda_chunk
-        scores = functools.partial(kda_chunk.chunk_scores,
-                                   interpret=path[0] == 'interpret')
+    """The walk's operands of every chunk.  Where the chunks are walked
+    densely ``_step``'s six, (W_k, W_v, Qbar, B, Khat, exp(G_C)); for
+    the walk's kernels five, W = [W_k | W_v] one array as the product
+    leaves it (they read its two halves as lanes of one block).  On the
+    fused path the ``kda_chunk`` kernel makes everything but W from the
+    op's inputs as they arrived, and XLA holds the inverse and its one
+    product."""
+    dk = k.shape[-1]
     chunk, n = _layout(k.shape[1], chunk)
-    return _prepare(*(_chunked(x, chunk, n, _working_dtype(v))
-                      for x in (q, k, v, a, beta)), scores=scores)
+    if path[0] == 'dense':
+        operands = _prepare(*(_chunked(x, chunk, n, _working_dtype(v))
+                              for x in (q, k, v, a, beta)))
+        if path[1] != 'dense':
+            operands = (jnp.concatenate(operands[:2], -1),) + operands[2:]
+        return operands
+    from .pallas import kda_chunk
+    system, b_mat, q_bar, k_hat, written, decay = kda_chunk.prepare(
+        q, k, v, a, beta, chunk, path[0] == 'interpret')
+    with jax.named_scope('inverse'):
+        w = _solve(system, written)
+    if path[1] == 'dense':
+        return w[..., :dk], w[..., dk:], q_bar, b_mat, k_hat, decay
+    return w, q_bar, b_mat, k_hat, decay
 
 
 def _forward(q, k, v, a, beta, chunk, path):
@@ -396,21 +431,39 @@ def _rule_bwd(chunk, path, saved, d_out):
     """What the forward kept is what it was handed and the state at
     each chunk's start: the per-chunk operands are computed again
     (under ``jax.vjp``, which then carries their cotangents back to q,
-    k, v, a and beta: through the scores' kernel by ITS backward
+    k, v, a and beta: through the preparation's kernel by ITS backward
     kernel), and the chunks walked in reverse with the state's
     cotangent: by the walk's reverse kernel, or each chunk's ``_step``
-    under a ``jax.vjp`` of its own."""
+    under a ``jax.vjp`` of its own.
+
+    The barrier ties the saved inputs to the cotangent, so the operands
+    are computed again WHEN the backward runs.  Without it the
+    recomputation depends on the forward's inputs alone and the
+    compiler runs it in the forward, beside the forward's own, and
+    holds its results across the step: in ``solar_open2_250b_s4096``
+    (no recompute group around the op: a group places this barrier
+    itself) seven float32 arrays a layer from the forward to the
+    backward, 128 MB of the step's peak and, some of them resident in
+    the core's fast memory all that time, the room 24 casts of the
+    experts' weights had there (0 of 24 fit where 21 had: the step 4 ms
+    slower, PERF.md section 6, PR 62)."""
     inputs, starts = saved
+    size, n = _layout(inputs[1].shape[1], chunk)
+    # through the barrier in the orders their readers take, the inputs
+    # [B, T, H x d] and the cotangent in chunks: a barrier fixes its
+    # operands' layouts, and the op's own four-dimensional ones cost
+    # Kimi Linear's cell a copy an operand, 13 ms a step
+    flat = tuple(x.reshape(x.shape[:2] + (-1,)) for x in inputs)
+    flat, d_chunks = jax.lax.optimization_barrier(
+        (flat, _chunked(d_out, size, n, starts.dtype)))
+    inputs = tuple(x.reshape(y.shape) for x, y in zip(flat, inputs))
     operands, pull = jax.vjp(
         lambda *x: _operands(*x, chunk, path), *inputs)
     _count_chunks(operands)
     if path[1] != 'dense':
         from .pallas import kda_walk
-        d_chunks = _chunked(d_out, *_layout(inputs[1].shape[1], chunk),
-                            starts.dtype)
         return pull(kda_walk.reverse(operands, starts, d_chunks,
                                      interpret=path[1] == 'interpret')[0])
-    size, n = _layout(inputs[1].shape[1], chunk)
 
     def step(d_state, x):
         chunk_operands, start, d_chunk_out = x
@@ -418,8 +471,7 @@ def _rule_bwd(chunk, path, saved, d_out):
         return pull_step((d_state, d_chunk_out))
 
     _, d_operands = jax.lax.scan(
-        step, jnp.zeros_like(starts[0]),
-        (operands, starts, _chunked(d_out, size, n, starts.dtype)),
+        step, jnp.zeros_like(starts[0]), (operands, starts, d_chunks),
         reverse=True)
     return pull(d_operands)
 

@@ -1,7 +1,7 @@
 """The gated delta rule's walk over the chunks on the chip
 (``ops/kda_ops.py`` has the equations): per sequence and head, with a
 float32 state ``S`` [dk, dv] that is zero at the sequence's start and
-the operands ``_prepare`` made for every chunk at once,
+the operands the preparation made for every chunk at once,
 
     u = W_v - W_k S        o = Qbar S + B u        S <- exp(G_C) S + Khat^T u
 
@@ -12,9 +12,10 @@ the step) in fusions of their own around a state that crosses HBM at
 every fusion's boundary, and a stack of per-chunk results the loop
 zeroes first.  Here the state never leaves the core between a
 sequence's first chunk and its last.  A call takes what the scan takes
-and gives what it gives: the six operands [N, B, H, C, .], ``o`` [N, B,
-H, C, dv] float32 and the state at each chunk's start; in reverse the
-starts and ``o``'s cotangent in, the six operands' cotangents out.
+and gives what it gives: the operands [N, B, H, C, .] (``W_k`` and
+``W_v`` as one array), ``o`` [N, B, H, C, dv] float32 and the state at
+each chunk's start; in reverse the starts and ``o``'s cotangent in, the
+operands' cotangents out.
 
 THE GRID is (sequence, head block, chunk), the chunks LAST and
 sequential; where the chunk index is 0 the state is zeroed, so nothing
@@ -25,24 +26,27 @@ heads' 1.4 MB of operands); the count divides H and is a whole number
 of sublane tiles or H itself, what a block of ``exp(G_C)`` [.., H, dk]
 asks.
 
-LAYOUT.  ``W_k``, ``W_v``, ``B`` and their cotangents are read and
-written chunk-major as the products and the ``kda_chunk`` kernels leave
-them, [N, B, H, C, .].  ``Qbar`` and ``Khat`` are elementwise results
-of q, k and the running decay, which the compiler lays rows first, [B,
-C, N, H, dk] (the cumulative sum wants the chunk's rows outermost): the
-calls take them in THAT order (``_rows_first``: a transpose the
-compiler answers with the layout it had chosen anyway, no copy), a
-block is a chunk's C rows of ``heads`` x dk and a head's [C, dk] one
-load with a sublane stride; their cotangents go back the same way.
-Handed chunk-major, each cost a 134 MB transposing copy a call at Kimi
-Linear's shape (14.7 ms a step in its cell, and 0.18 GB of ``peak_hbm``:
-PERF.md section 6, PR 61).  ``o`` leaves float32 and chunk-major like
-the scan's, and ``d_o`` arrives so: what reads ``o`` next (a norm over
-each head's dv) wants the tokens innermost, so the one copy on the way
-out is the compiler's to place.  Written through a block map into the
-op's own [B, T, H x dv] in v's dtype it cost that copy AFTER the call,
-6 ms a step and two float32 copies of ``o`` kept for the backward
-(PERF.md, the same entry).
+LAYOUT.  Every operand and every cotangent is chunk-major, [N, B, H,
+C, .], the order its producer writes it in and its consumer reads it
+in: ``W = [W_k | W_v]`` ONE array [.., C, dk + dv] as the solve's
+product leaves it (the body reads its two halves as lanes of one
+block, and writes ``dW`` the same way for the solve's pull-back: no
+slice and no ``concatenate`` is materialised around a call); ``Qbar``,
+``B``, ``Khat`` as the ``kda_chunk`` kernel writes them, and their
+cotangents as its backward reads them.  (Until PR 62 ``Qbar`` and
+``Khat`` were XLA's elementwise results, which the compiler lays rows
+first, [B, C, N, H, dk], and the calls followed THAT order through a
+strided block; handed chunk-major each had cost a 134 MB transposing
+copy a call at Kimi Linear's shape.  A Mosaic call fixes its operands'
+layouts: the order to take is the producer's, PERF.md section 6, PRs
+61 and 62.)  ``o`` leaves float32 and chunk-major like the scan's,
+and ``d_o`` arrives so: what reads ``o`` next (a norm over each head's
+dv) wants the tokens innermost, so the one copy on the way out is the
+compiler's to place (it fuses ``d_o``'s way in into the norm's
+gradient).  Written through a block map into the op's own [B, T, H x
+dv] in v's dtype it cost that copy AFTER the call, 6 ms a step and two
+float32 copies of ``o`` kept for the backward (PERF.md section 6, PR
+61).
 
 THE STATE LIES TRANSPOSED, ``S^T`` [dv, dk], in the scratch, in
 ``starts`` [N, B, H, dv, dk] (the residual the dense form keeps as [N,
@@ -141,132 +145,114 @@ def checks(h, c, dk, dv, dtype):
              _common.VMEM_BUDGET_BYTES))
 
 
-def _rows_first(x):
-    """[N, B, H, C, d] -> [B, C, N, H, d]: the order the compiler lays
-    the preparation's elementwise results in."""
-    return x.transpose(1, 3, 0, 2, 4)
-
-
-def _chunks_first(x):
-    """``_rows_first``'s reverse."""
-    return x.transpose(2, 0, 3, 1, 4)
-
-
-def _forward_kernel(w_k_ref, w_v_ref, q_bar_ref, b_ref, k_hat_ref,
-                    decay_ref, o_ref, starts_ref, state_ref):
-    """One chunk of ``heads`` heads: the operands [heads, C, .] (Qbar,
-    Khat [C, heads, dk]), the decay [heads, dk] -> o [heads, C, dv] and
-    the state the chunk starts from, S^T [heads, dv, dk]."""
+def _forward_kernel(w_ref, q_bar_ref, b_ref, k_hat_ref, decay_ref,
+                    o_ref, starts_ref, state_ref):
+    """One chunk of ``heads`` heads: W = [W_k | W_v] [heads, C, dk +
+    dv] and the other operands [heads, C, .], the decay [heads, dk] ->
+    o [heads, C, dv] and the state the chunk starts from, S^T [heads,
+    dv, dk]."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         state_ref[...] = jnp.zeros_like(state_ref)
 
     starts_ref[...] = state_ref[...]
-    c = w_k_ref.shape[1]
-    for h in range(w_k_ref.shape[0]):
+    c, dk = q_bar_ref.shape[1:]
+    for h in range(w_ref.shape[0]):
         state = state_ref[h]
         # W_k S and Qbar S in one product: the state is the MXU's
         # stationary side, loaded once for 2 C rows
-        both = _dot(jnp.concatenate([w_k_ref[h], q_bar_ref[:, h, :]], 0),
+        both = _dot(jnp.concatenate([w_ref[h, :, :dk], q_bar_ref[h]], 0),
                     state, (1, 1))
-        u = w_v_ref[h] - both[:c]
+        u = w_ref[h, :, dk:] - both[:c]
         o_ref[h] = both[c:] + _dot(b_ref[h], u, (1, 0))
         state_ref[h] = decay_ref[h:h + 1, :] * state + \
-            _dot(u, k_hat_ref[:, h, :], (0, 0))
+            _dot(u, k_hat_ref[h], (0, 0))
 
 
-def _reverse_kernel(w_k_ref, w_v_ref, q_bar_ref, b_ref, k_hat_ref,
-                    decay_ref, starts_ref, d_o_ref, d_w_k_ref, d_w_v_ref,
-                    d_q_bar_ref, d_b_ref, d_k_hat_ref, d_decay_ref,
-                    d_state_ref):
+def _reverse_kernel(w_ref, q_bar_ref, b_ref, k_hat_ref, decay_ref,
+                    starts_ref, d_o_ref, d_w_ref, d_q_bar_ref, d_b_ref,
+                    d_k_hat_ref, d_decay_ref, d_state_ref):
     """One chunk of ``heads`` heads, the chunks counted down: what the
-    forward kernel saw, the start it wrote and o's cotangent -> the six
+    forward kernel saw, the start it wrote and o's cotangent -> the five
     operands' cotangents; ``d_state_ref`` [heads, dv, dk] carries the
     state's."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         d_state_ref[...] = jnp.zeros_like(d_state_ref)
 
-    c = w_k_ref.shape[1]
-    for h in range(w_k_ref.shape[0]):
+    c, dk = q_bar_ref.shape[1:]
+    for h in range(w_ref.shape[0]):
         state, d_state = starts_ref[h], d_state_ref[h]
-        w_k, decay, d_o = w_k_ref[h], decay_ref[h:h + 1, :], d_o_ref[h]
-        u = w_v_ref[h] - _dot(w_k, state, (1, 1))
+        w_k, decay, d_o = w_ref[h, :, :dk], decay_ref[h:h + 1, :], d_o_ref[h]
+        u = w_ref[h, :, dk:] - _dot(w_k, state, (1, 1))
         d_u = _dot(b_ref[h], d_o, (0, 0)) + \
-            _dot(k_hat_ref[:, h, :], d_state, (1, 1))
-        d_w_v_ref[h] = d_u
+            _dot(k_hat_ref[h], d_state, (1, 1))
+        d_w_ref[h, :, dk:] = d_u
         # d_u S^T and d_o S^T in one product, and below Qbar^T d_o -
         # W_k^T d_u in one of 2 C rows' depth
         through = _dot(jnp.concatenate([d_u, d_o], 0), state, (1, 0))
-        d_w_k_ref[h] = -through[:c]
-        d_q_bar_ref[:, h, :] = through[c:]
+        d_w_ref[h, :, :dk] = -through[:c]
+        d_q_bar_ref[h] = through[c:]
         d_b_ref[h] = _dot(d_o, u, (1, 1))
-        d_k_hat_ref[:, h, :] = _dot(u, d_state, (1, 0))
+        d_k_hat_ref[h] = _dot(u, d_state, (1, 0))
         d_decay_ref[h:h + 1, :] = jnp.sum(d_state * state, 0, keepdims=True)
         d_state_ref[h] = decay * d_state + _dot(
             jnp.concatenate([d_o, -d_u], 0),
-            jnp.concatenate([q_bar_ref[:, h, :], w_k], 0), (0, 0))
+            jnp.concatenate([q_bar_ref[h], w_k], 0), (0, 0))
 
 
 @functools.partial(jax.jit, inline=True,
                    static_argnames=('heads', 'interpret'))
-def _call(w_k, w_v, q_bar, b_mat, k_hat, decay, *rest, heads, interpret):
-    """The forward kernel over ``_prepare``'s operands -> (o [N, B, H,
-    C, dv], S^T at each chunk's start [N, B, H, dv, dk]), or, given
-    those starts and o's cotangent, the reverse one -> the six
-    cotangents and the start state's [B, H, dv, dk].  Under a jit cache
-    of its own, ``inline`` (as kda_chunk._call): a body is traced once
-    a process and shape and its instruction keeps the name of the scope
+def _call(w, q_bar, b_mat, k_hat, decay, *rest, heads, interpret):
+    """The forward kernel over the walk's operands -> (o [N, B, H, C,
+    dv], S^T at each chunk's start [N, B, H, dv, dk]), or, given those
+    starts and o's cotangent, the reverse one -> the five cotangents
+    and the start state's [B, H, dv, dk].  Under a jit cache of its
+    own, ``inline`` (as kda_chunk._call): a body is traced once a
+    process and shape and its instruction keeps the name of the scope
     the caller lowered it in."""
-    n, b, h, c, dk = w_k.shape
-    dv = w_v.shape[-1]
+    n, b, h, c, dk = q_bar.shape
+    dv = w.shape[-1] - dk
     heads = heads or heads_a_step(h)
     last = n - 1 if rest else 0         # the walk's first chunk
-
-    def chunk(s):
-        return last - s if rest else s
 
     def per_chunk(x):
         """``heads`` heads of one chunk of a [N, B, H, ..] array."""
         tail = x.shape[3:]
         return pl.BlockSpec(
             (None, None, heads) + tail,
-            lambda i, j, s: (chunk(s), i, j) + (0,) * len(tail))
+            lambda i, j, s: (last - s if rest else s, i, j) +
+            (0,) * len(tail))
 
-    # a chunk's rows of ``heads`` heads of a [B, C, N, H, dk] array
-    by_row = pl.BlockSpec((None, c, None, heads, dk),
-                          lambda i, j, s: (i, 0, chunk(s), j, 0))
-    operands = (w_k, w_v, _rows_first(q_bar), b_mat, _rows_first(k_hat),
-                decay)
-    specs = [per_chunk(w_k), per_chunk(w_v), by_row, per_chunk(b_mat),
-             by_row, per_chunk(decay)]
+    operands = (w, q_bar, b_mat, k_hat, decay)
+    specs = [per_chunk(x) for x in operands]
     starts = jax.ShapeDtypeStruct((n, b, h, dv, dk), _F32)
     kwargs = dict(
         grid=(b, h // heads, n), interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel', 'parallel', 'arbitrary')))
     if not rest:
+        out = jax.ShapeDtypeStruct((n, b, h, c, dv), _F32)
         return pl.pallas_call(
             _forward_kernel, in_specs=specs,
-            out_specs=[per_chunk(w_v), per_chunk(starts)],
-            out_shape=[jax.ShapeDtypeStruct(w_v.shape, _F32), starts],
+            out_specs=[per_chunk(out), per_chunk(starts)],
+            out_shape=[out, starts],
             scratch_shapes=[pltpu.VMEM((heads, dv, dk), _F32)],
             name='kda_walk_forward', **kwargs)(*operands)
-    grads = list(pl.pallas_call(
+    return pl.pallas_call(
         _reverse_kernel, in_specs=specs + [per_chunk(x) for x in rest],
         out_specs=specs + [pl.BlockSpec((None, heads, dv, dk),
                                         lambda i, j, s: (i, j, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct(x.shape, _F32) for x in operands] +
         [jax.ShapeDtypeStruct((b, h, dv, dk), _F32)],
-        name='kda_walk_reverse', **kwargs)(*operands, *rest))
-    grads[2], grads[4] = _chunks_first(grads[2]), _chunks_first(grads[4])
-    return grads
+        name='kda_walk_reverse', **kwargs)(*operands, *rest)
 
 
 def forward(operands, heads=None, interpret=False):
-    """``_prepare``'s six operands of whole chunks ([N, B, H, C, .];
-    ``checks`` holds) -> (o [N, B, H, C, dv], S^T at each chunk's START
-    [N, B, H, dv, dk]), both float32: what the scan over ``_step``
+    """The walk's five operands of whole chunks (W = [W_k | W_v] [N, B,
+    H, C, dk + dv], Qbar, B, Khat [N, B, H, C, .], exp(G_C) [N, B, H,
+    dk]; ``checks`` holds) -> (o [N, B, H, C, dv], S^T at each chunk's
+    START [N, B, H, dv, dk]), both float32: what the scan over ``_step``
     stacks, the state transposed.  ``heads``: heads a grid step
     (``heads_a_step`` of H where None)."""
     return _call(*operands, heads=heads, interpret=interpret)
@@ -274,7 +260,7 @@ def forward(operands, heads=None, interpret=False):
 
 def reverse(operands, starts, d_out, heads=None, interpret=False):
     """``forward``'s operands, the starts it kept and o's cotangent [N,
-    B, H, C, dv] float32 -> (the six operands' cotangents, the start
+    B, H, C, dv] float32 -> (the five operands' cotangents, the start
     state's [B, H, dv, dk])."""
     grads = _call(*operands, starts, d_out, heads=heads, interpret=interpret)
-    return tuple(grads[:6]), grads[6]
+    return tuple(grads[:5]), grads[5]

@@ -838,26 +838,32 @@ def test_quant_collective_tiles(one_chip):
     assert n == 1, n
 
 
-@pytest.mark.parametrize('t,heads,dk,dv', [
-    (4096, 8, 128, 128),    # solar_open2_250b_s4096: 64 chunks x 8 heads
-    (8192, 32, 128, 128),   # kimi_linear_48b_s8192: 128 chunks x 32 heads
-    (24, 3, 128, 128),      # less than a chunk: one of two sub-chunks
-    (100, 2, 256, 256),     # a padded tail, two lane tiles of channels
-    (4096, 8, 128, 64),     # dv no lane tile: the chunks walked by scans
+@pytest.mark.parametrize('t,heads,dk,dv,dtype', [
+    (4096, 8, 128, 128, 'float32'),     # solar_open2_250b_s4096: 64 x 8
+    (8192, 32, 128, 128, 'float32'),    # kimi_linear_48b_s8192: 128 x 32
+    (8192, 32, 128, 128, 'bfloat16'),   # the same as AMP hands it over
+    (24, 3, 128, 128, 'float32'),   # less than a chunk: one of two sub-chunks
+    (100, 2, 256, 256, 'bfloat16'),     # a masked tail, two lane tiles
+    (4096, 8, 128, 64, 'float32'),      # dv no lane tile: all of it XLA's
 ])
 def test_the_delta_rule_compiles_its_score_and_walk_kernels(
-        one_chip, as_on_tpu, t, heads, dk, dv):
+        one_chip, as_on_tpu, t, heads, dk, dv, dtype):
     """``kda_attention``'s forward + backward at the two cells' layer
-    shapes (and at a short and a ragged length): both dispatches answer
-    fused, the executable holds FIVE Mosaic calls (the scores' forward
-    and the forward walk; the scores' forward again, the reverse walk
-    and the scores' backward) and NO ``while``: the chunks are walked
-    inside two calls, which hold no more VMEM than ``kda_walk``'s count
-    says and ask Mosaic for none.  Where the walk's layout does not
-    hold (dv off the lanes) the scores' three calls and the two scans'
-    ``while``s are what is there.  On either path no buffer of the step
-    is a [.., SUB, SUB, dk] or [.., n_sub, n_sub, SUB, dk] decay block
-    and ``_prepare`` holds no loop and no solve."""
+    shapes (and at a short and a ragged length; q, k, v, beta float32
+    and bfloat16, the log decays float32): both dispatches answer fused,
+    the executable holds FIVE Mosaic calls (the preparation's forward
+    and the forward walk; the preparation's forward again, the reverse
+    walk and the preparation's backward), each under the name its module
+    gives it, and NO ``while``: the chunks are walked inside two calls,
+    and no call holds more VMEM than ``kda_walk``'s count says or asks
+    Mosaic for any.  What the preparation's calls read of q, k, v and a
+    is the [B, T, H x d] view of what the op was handed (a parameter
+    re-laid here, the producer's own result in a cell's step), never a
+    chunked, transposed or padded copy.  Where a value's width is off
+    the lanes neither kernel's layout holds: no Mosaic call and the two
+    scans' ``while``s.  On the fused path no buffer of the step is a
+    [.., SUB, SUB, dk] or [.., n_sub, n_sub, SUB, dk] decay block; on
+    either the preparation holds no loop and no solve."""
     import re
     from paddle_tpu.ops import kda_ops
     from paddle_tpu.ops.pallas import kda_walk
@@ -866,33 +872,45 @@ def test_the_delta_rule_compiles_its_score_and_walk_kernels(
         out, pull = jax.vjp(kda_ops.gated_delta_rule, q, k, v, a, beta)
         return (out,) + pull(probe)
 
-    wide, rows = _spec((1, t, heads, dk)), _spec((1, t, heads))
-    values = _spec((1, t, heads, dv))
-    text = _compiled(step, one_chip, wide, wide, values, wide, rows,
-                     values).as_text()
-    _compiled_on_chip('kda_chunk')
-    walked = dv % 128 == 0
-    if walked:
-        _compiled_on_chip('kda_walk')
-    else:
-        assert common._LAST['kda_walk'] == {
-            'path': 'dense', 'reason': 'layout', 'interpret': False}
+    dtype = jnp.dtype(dtype)
+    wide, rows = _spec((1, t, heads, dk), dtype), _spec((1, t, heads), dtype)
+    values = _spec((1, t, heads, dv), dtype)
+    text = _compiled(step, one_chip, wide, wide, values,
+                     _spec((1, t, heads, dk)), rows, values).as_text()
+    fused = dv % 128 == 0
+    for kernel in ('kda_chunk', 'kda_walk'):
+        if fused:
+            _compiled_on_chip(kernel)
+        else:
+            assert common._LAST[kernel] == {
+                'path': 'dense', 'reason': 'layout', 'interpret': False}
     assert text.count('custom_call_target="tpu_custom_call"') == \
-        (5 if walked else 3)
+        (5 if fused else 0)
     # (whole axes only: the compiler moves a [64, 8, 64, dk] operand in
     # four [64, 8, 16, dk] slices, which are no blocks)
     blocks = re.findall(
         r'f32\[[\d,]*(?:16,16|(?<!\d)\d,\d,16),%d\]' % dk, text)
-    assert not blocks, sorted(set(blocks))
+    assert not blocks or not fused, sorted(set(blocks))
     assert len(re.findall(r' while\(', text)) == \
-        (0 if walked or t <= 64 else 2)
+        (0 if fused or t <= 64 else 2)
     for opcode in ('triangular-solve', 'InvertDiagBlocksLowerTriangular'):
         assert opcode not in text, opcode
-    if walked:
-        names = re.findall(r'op_name="[^"]*(kda_walk_\w+)\)*/pallas_call"',
-                           text)
-        assert sorted(set(names)) == ['kda_walk_forward',
-                                      'kda_walk_reverse'], names
+    if fused:
+        names = re.findall(
+            r'op_name="[^"]*(kda_(?:walk|chunk)_\w+?)\)*/pallas_call"', text)
+        assert sorted(set(names)) == [
+            'kda_chunk_backward', 'kda_chunk_forward',
+            'kda_walk_forward', 'kda_walk_reverse'], names
+        read = re.findall(
+            r'custom-call\(([^)]*)\), custom_call_target="tpu_custom_call"'
+            r'[^\n]*kda_chunk_', text)
+        assert len(read) == 3
+        for operands in read:
+            names = [x.strip().lstrip('%') for x in operands.split(',')][:4]
+            shapes = [re.search(r'%%%s = \w+\[([\d,]*)\]' % re.escape(x),
+                                text).group(1) for x in names]
+            assert shapes == ['1,%d,%d' % (t, heads * d)
+                              for d in (dk, dk, dv, dk)], (names, shapes)
         used = [int(n) for n in re.findall(
             r'custom_call_target="tpu_custom_call"[^\n]*?'
             r'"used_scoped_memory_configs":\[\{[^}]*?"size":"(\d+)"', text)]

@@ -1,8 +1,9 @@
 """The ``kda_chunk`` kernels (``paddle_tpu/ops/pallas/kda_chunk.py``: the
-gated delta rule's in-chunk scores, forward and backward) and the
-``kda_walk`` kernels (``ops/pallas/kda_walk.py``: its walk over the
-chunks, forward and reverse) under the Pallas interpreter against the
-dense forms they replace (``ops/kda_ops.py`` ``_scores``; the
+gated delta rule's preparation of its chunks from the op's inputs as
+they arrive, forward and backward) and the ``kda_walk`` kernels
+(``ops/pallas/kda_walk.py``: its walk over the chunks, forward and
+reverse) under the Pallas interpreter against the dense forms they
+replace (``ops/kda_ops.py`` ``_prepare`` with ``_scores``; the
 ``lax.scan`` over ``_step`` and its ``jax.vjp``) and, through the whole
 op, against the token-by-token recurrence; what ``common.dispatch``
 answers for shapes the kernels' layouts do not hold; and ``_prepare``'s
@@ -14,24 +15,12 @@ import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal
 
 from paddle_tpu.fluid import monitor
 from paddle_tpu.models.reference import solar_open2 as reference
 from paddle_tpu.ops import kda_ops
 from paddle_tpu.ops.pallas import common, kda_chunk, kda_walk
-
-
-def _chunks(seed, lead=(2, 3), c=64, dk=128, rate=1.0):
-    """Unit q and k and the running log decay of whole chunks, down to
-    -rate x softplus(.) a token and channel."""
-    rng = np.random.RandomState(seed)
-    q, k = (rng.randn(*(lead + (c, dk))) for _ in range(2))
-    q /= np.linalg.norm(q, axis=-1, keepdims=True)
-    k /= np.linalg.norm(k, axis=-1, keepdims=True)
-    a = -rate * np.log1p(np.exp(rng.randn(*(lead + (c, dk))))) * \
-        rng.uniform(0, 1, lead + (c, dk))
-    return [jnp.asarray(x, jnp.float32)
-            for x in (q, k, np.cumsum(a, -2))]
 
 
 def _sequence(seed, t, b=2, h=3, dk=128, dv=8, rate=16.0,
@@ -60,21 +49,68 @@ def _fused(kernel='kda_chunk'):
     return monitor.counter_value('pallas/%s/dispatch_fused' % kernel) or 0
 
 
-@pytest.mark.parametrize('c,dk,rate', [(64, 128, 1.0), (64, 128, 16.0),
-                                       (32, 128, 4.0), (16, 256, 1.0)])
-def test_the_kernels_scores_and_gradients_are_the_dense_forms(c, dk, rate):
-    """A, B and dq, dk, dG of the two kernels against ``_scores`` and
-    its ``jax.vjp``, float32, at a whole chunk, at the shorter chunks a
-    short sequence runs as, at two lane tiles of channels, and at
-    decays whose running sum passes -88 inside the chunk."""
-    q, k, g = _chunks(c, c=c, dk=dk, rate=rate)
+RESULTS = ('beta A', 'B', 'Qbar', 'Khat', 'beta Kbar', 'beta V', 'exp(G_C)')
+
+
+def _unsolved(system, written):
+    """In ``_solve``'s place, so that ``_prepare`` hands over what it
+    made BEFORE the solve: the system beside its right-hand side."""
+    return jnp.concatenate([system, written], -1)
+
+
+@pytest.mark.parametrize('t,h,dk,rate,dtype', [
+    (128, 3, 128, 1.0, 'float32'),      # whole chunks of C 64
+    (128, 3, 128, 16.0, 'float32'),     # G passes -88 inside a chunk
+    (32, 3, 128, 4.0, 'float32'),       # a chunk of two sub-chunks
+    (16, 3, 256, 1.0, 'float32'),       # one sub-chunk, two lane tiles
+    (100, 3, 128, 16.0, 'float32'),     # a ragged tail, masked on the chip
+    (24, 8, 128, 1.0, 'float32'),       # less than a chunk, ragged
+    (100, 8, 128, 1.0, 'bfloat16'),     # AMP's inputs, eight heads
+    (128, 8, 128, 16.0, 'bfloat16'),
+])
+def test_the_kernels_scores_and_gradients_are_the_dense_forms(
+        monkeypatch, t, h, dk, rate, dtype):
+    """The fused preparation (``kda_chunk.prepare``: the op's inputs as
+    they arrive -> the system, B, Qbar, Khat, the right-hand side's two
+    halves, exp(G_C)) and its five pull-backs against ``_prepare`` over
+    ``_chunked`` and ``_scores`` under ``jax.vjp``, at whole chunks, at
+    the shorter chunks a short sequence runs as, at two lane tiles of
+    channels, at lengths that are no whole number of chunks (the tail
+    read past the array and masked in the kernel), at 3 heads and 8, on
+    float32 and bfloat16 inputs, and at decays whose running sum passes
+    -88 inside the chunk.  G is a product with a triangle of ones in the
+    kernel and a ``cumsum`` densely: two float32 sums in different
+    orders, each an ulp of |G| from the other (6e-5 at |G| of 1000),
+    which every exponent inherits."""
+    args = _sequence(t + h, t, h=h, dk=dk, dv=128, rate=rate,
+                     dtype=jnp.dtype(dtype))
     if rate == 16.0:
-        assert float(g.min()) < -200
-    want, pull = jax.vjp(kda_ops._scores, q, k, g)
-    got, pull_kernel = jax.vjp(
-        lambda *x: kda_chunk.chunk_scores(*x, True), q, k, g)
-    for x, y in zip(got, want):
-        _close(x, y, 2e-6)
+        assert float(jnp.cumsum(args[3][:, :64], 1).min()) < -200
+    c, n = kda_ops._layout(t, kda_ops.CHUNK)
+    monkeypatch.setattr(kda_ops, '_solve', _unsolved)
+
+    def dense(*x):
+        w_k, w_v, q_bar, b_mat, k_hat, decay = kda_ops._prepare(*(
+            kda_ops._chunked(y, c, n, jnp.float32) for y in x))
+        both = jnp.concatenate([w_k, w_v], -1)  # [system | its right side]
+        return (both[..., :c], b_mat, q_bar, k_hat, both[..., c:c + dk],
+                both[..., c + dk:], decay)
+
+    def fused(*x):
+        system, b_mat, q_bar, k_hat, written, decay = kda_chunk.prepare(
+            *x, c, True)
+        return (system, b_mat, q_bar, k_hat, written[..., :dk],
+                written[..., dk:], decay)
+
+    want, pull = jax.vjp(dense, *args)
+    got, pull_kernel = jax.vjp(fused, *args)
+    rtol = 4e-5 if rate == 16.0 else 5e-6
+    for name, x, y in zip(RESULTS, got, want):
+        assert x.shape == y.shape and x.dtype == jnp.float32, name
+        if float(jnp.abs(y).max()):
+            _close(x, y, rtol)
+        else:                           # exp(G_C) under 1e-38
+            assert not np.asarray(x).any(), name
     upper = np.triu(np.ones((c, c), bool))
     assert (np.asarray(got[0])[..., upper] == 0).all()
     assert (np.asarray(got[1])[..., np.triu(upper, 1)] == 0).all()
@@ -82,20 +118,24 @@ def test_the_kernels_scores_and_gradients_are_the_dense_forms(c, dk, rate):
     cotangents = tuple(jnp.asarray(rng.randn(*x.shape), jnp.float32)
                        for x in want)
     for x, y in zip(pull_kernel(cotangents), pull(cotangents)):
-        _close(x, y, 5e-6)
+        assert x.shape == y.shape and x.dtype == y.dtype
+        _close(x, y, 2 ** -7 if y.dtype == jnp.bfloat16 else rtol)
 
 
-@pytest.mark.parametrize('t,dv', [(100, 8), (64, 8), (24, 8),
-                                  (100, 128), (128, 128), (24, 128)])
+@pytest.mark.parametrize('t,h', [(100, 12), (64, 12), (24, 12),
+                                 (100, 3), (128, 3), (24, 3)])
 def test_the_fused_op_is_the_recurrence_at_whole_and_ragged_lengths(
-        pallas_interpret, t, dv):
+        pallas_interpret, t, h):
     """The whole op through the kernels (dispatch counted fused),
     forward and all five gradients, float32 against the token loop at
     rate 16: one chunk, less than one (a chunk of two sub-chunks) and
-    no whole number of them (a padded tail).  At a value width of 8 the
-    scores' kernels run and the chunks are walked by the scan (reason
-    'layout'); at 128 the walk's kernels run too."""
-    args = _sequence(t, t, dv=dv)
+    no whole number of them (a masked tail).  At 12 heads the
+    preparation's kernels run and the chunks are walked by the scan
+    (twelve heads a grid step of whole chunks are over the walk's VMEM
+    count); at 3, and at 12 heads of a 32-token chunk, the walk's
+    kernels run too."""
+    args = _sequence(t, t, h=h, dv=128)
+    walked = h == 3 or t < 64
     before, walks = _fused(), _fused('kda_walk')
     with jax.default_matmul_precision('highest'):
         got, pull = jax.vjp(kda_ops.gated_delta_rule, *args)
@@ -106,23 +146,24 @@ def test_the_fused_op_is_the_recurrence_at_whole_and_ragged_lengths(
         want_grads = pull_want(probe)
     assert _fused() == before + 1
     assert common._LAST['kda_chunk']['reason'] == 'forced_interpret'
-    assert _fused('kda_walk') == walks + (dv == 128)
+    assert _fused('kda_walk') == walks + walked
     assert common._LAST['kda_walk']['reason'] == (
-        'forced_interpret' if dv == 128 else 'layout')
+        'forced_interpret' if walked else 'vmem_over_budget')
     _close(got, want, 2e-5)
     for got_grad, want_grad in zip(got_grads, want_grads):
         _close(got_grad, want_grad, 5e-5)
 
 
-@pytest.mark.parametrize('dv', [8, 128])
+@pytest.mark.parametrize('h', [12, 3])
 def test_the_fused_op_and_the_dense_op_agree_on_bf16_inputs(
-        pallas_interpret, dv):
-    """bf16 q, k, v, beta beside float32 log decays: the kernels see
-    the float32 working copies the dense form sees (the walk's give o
-    and take its cotangent in float32, cast as the dense form's), and
-    the two paths' outputs and gradients round to the same bf16 but for
-    an ulp."""
-    args = _sequence(5, 100, dv=dv, dtype=jnp.bfloat16)
+        pallas_interpret, h):
+    """bf16 q, k, v, beta beside float32 log decays: the preparation's
+    kernels read them as they are and widen them on the chip to what
+    the dense form's float32 working copies hold (the walk's give o and
+    take its cotangent in float32, cast as the dense form's), and the
+    two paths' outputs and gradients round to the same bf16 but for an
+    ulp."""
+    args = _sequence(5, 100, h=h, dv=128, dtype=jnp.bfloat16)
     fused, pull = jax.vjp(kda_ops.gated_delta_rule, *args)
     probe = jnp.asarray(np.random.RandomState(2).randn(*fused.shape),
                         jnp.bfloat16)
@@ -132,7 +173,7 @@ def test_the_fused_op_and_the_dense_op_agree_on_bf16_inputs(
     assert fused.dtype == jnp.bfloat16
     assert common._LAST['kda_chunk']['path'] == 'fused'
     assert common._LAST['kda_walk']['path'] == (
-        'fused' if dv == 128 else 'dense')
+        'fused' if h == 3 else 'dense')
     _close(fused, dense, 2 ** -7)
     for x, y in zip(pull(probe), pull_dense(probe)):
         assert x.dtype == y.dtype
@@ -142,6 +183,7 @@ def test_the_fused_op_and_the_dense_op_agree_on_bf16_inputs(
 @pytest.mark.parametrize('kernel,what,kwargs', [
     ('kda_chunk', 'layout', dict(dk=16)),           # dk is no lane tile
     ('kda_chunk', 'layout', dict(dk=128, chunk=40)),    # no sub-chunks
+    ('kda_chunk', 'layout', dict(dk=128, dv=8)),    # dv is no lane tile
     ('kda_chunk', 'auto_partitioned', dict(dk=128, auto_partitioned=True)),
     ('kda_walk', 'layout', dict(dk=16, dv=128)),
     ('kda_walk', 'layout', dict(dk=128, dv=192)),   # dv is no lane tile
@@ -159,7 +201,7 @@ def test_the_dispatch_answers_dense_with_its_reason_counted(
     recurrence."""
     kwargs = dict(kwargs)
     args = _sequence(7, 50, b=1, h=kwargs.pop('h', 2), dk=kwargs.pop('dk'),
-                     dv=kwargs.pop('dv', 8), rate=4.0)
+                     dv=kwargs.pop('dv', 128), rate=4.0)
     name = 'pallas/%s/fallback/%s' % (kernel, what)
     before = monitor.counter_value(name) or 0
     fused = _fused(kernel)
@@ -263,20 +305,59 @@ def test_the_block_inverses_gradients_are_triangular_solves(c, dtype):
         _close(x, y, 5e-6 if dtype == 'float32' else 1e-13)
 
 
+def _held(eqn):
+    """The jaxprs an equation holds (a scan's, a custom_vjp call's, a
+    pjit's)."""
+    for value in eqn.params.values():
+        for sub in value if isinstance(value, (tuple, list)) else (value,):
+            sub = getattr(sub, 'jaxpr', sub)
+            if hasattr(sub, 'eqns'):
+                yield sub
+
+
 def _primitives(jaxpr):
     """Every primitive's name in a jaxpr and in the jaxprs its
     equations hold (scans, custom_vjp calls, pjit), a kernel's body
     left out: a ``pallas_call`` is one equation."""
     for eqn in jaxpr.eqns:
         yield eqn.primitive.name
+        if eqn.primitive.name != 'pallas_call':
+            for sub in _held(eqn):
+                yield from _primitives(sub)
+
+
+def _preparations(jaxpr):
+    """(the jaxpr it stands in, the equation, the operands that are
+    the op's inputs) of every ``kda_chunk`` call under ``jaxpr`` and of
+    every equation that holds one."""
+    for eqn in jaxpr.eqns:
         if eqn.primitive.name == 'pallas_call':
+            if eqn.params['name'].startswith('kda_chunk'):
+                yield jaxpr, eqn, 5
             continue
-        for value in eqn.params.values():
-            for sub in value if isinstance(value, (tuple, list)) \
-                    else (value,):
-                sub = getattr(sub, 'jaxpr', sub)
-                if hasattr(sub, 'eqns'):
-                    yield from _primitives(sub)
+        inside = [x for sub in _held(eqn) for x in _preparations(sub)]
+        if inside:
+            yield jaxpr, eqn, len(eqn.invars)
+            yield from inside
+
+
+def _made_from(jaxpr, call, operands):
+    """The primitives of ``jaxpr``'s own equations that ``call``'s
+    first ``operands`` operands are made by, back to its inputs."""
+    made_by = {out: eqn for eqn in jaxpr.eqns for out in eqn.outvars}
+    found, todo = set(), list(call.invars[:operands])
+    while todo:
+        var = todo.pop()
+        eqn = None if isinstance(var, Literal) else made_by.get(var)
+        if eqn is None:
+            continue
+        found.add(eqn.primitive.name)
+        if eqn.primitive.name == 'optimization_barrier':
+            # a barrier hands each operand through as it is
+            todo.append(eqn.invars[eqn.outvars.index(var)])
+        else:
+            todo.extend(eqn.invars)
+    return found
 
 
 @pytest.mark.parametrize('path', [('dense', 'dense'), ('interpret', 'dense'),
@@ -286,9 +367,14 @@ def test_the_op_holds_no_solve_and_no_loop_but_its_scans(path):
     ``triangular_solve`` and no ``while`` on any path; the products are
     there and, outside the kernels' bodies, the two scans alone where
     the chunks are walked densely and NO loop where the walk's kernels
-    run: five kernel calls then (the scores' forward and the forward
-    walk; the scores' forward again, the reverse walk, the scores'
-    backward), three with the scores' kernels alone."""
+    run: five kernel calls then (the preparation's forward and the
+    forward walk; the preparation's forward again, the reverse walk, the
+    preparation's backward), three with the preparation's kernels
+    alone.  On the fused path no running sum is XLA's, and what the
+    preparation's calls read of the op's five inputs is the inputs
+    themselves, reshaped (in the backward behind the barrier that
+    keeps the recomputation there): no ``cumsum``, ``concatenate``,
+    ``transpose``, ``pad`` or cast stands between."""
     args = _sequence(11, 100, b=1, h=2, dv=128)
 
     def both(*x):
@@ -296,12 +382,20 @@ def test_the_op_holds_no_solve_and_no_loop_but_its_scans(path):
             lambda *y: kda_ops._rule(*y, kda_ops.CHUNK, path), *x)
         return pull(out)
 
-    found = list(_primitives(jax.make_jaxpr(both)(*args).jaxpr))
+    jaxpr = jax.make_jaxpr(both)(*args).jaxpr
+    found = list(_primitives(jaxpr))
     assert 'dot_general' in found
     assert found.count('scan') == (2 if path[1] == 'dense' else 0)
     assert found.count('pallas_call') == \
         3 * (path[0] != 'dense') + 2 * (path[1] != 'dense')
     assert not {'triangular_solve', 'while'} & set(found)
+    assert ('cumsum' in found) == (path[0] == 'dense')
+    prepared = list(_preparations(jaxpr))
+    assert sum(eqn.primitive.name == 'pallas_call'
+               for _, eqn, _ in prepared) == 3 * (path[0] != 'dense')
+    for inside, eqn, operands in prepared:
+        assert _made_from(inside, eqn, operands) <= {
+            'reshape', 'optimization_barrier'}, eqn
 
 
 def _walked(operands):
@@ -338,20 +432,23 @@ def _walked_back(operands, starts, d_out):
     (200, 16, None),    # eight of sixteen heads a grid step
 ])
 def test_the_walks_are_the_scans_over_the_step(t, h, heads):
-    """The forward walk's o and starts and the reverse walk's six
+    """The forward walk's o and starts and the reverse walk's
     cotangents and final dS against the ``lax.scan`` over ``_step`` and
     over its ``jax.vjp``, on operands ``_prepare`` made from two
     sequences (the state is zeroed at each sequence's first chunk: the
-    scratch still holds the one before's last state)."""
+    scratch still holds the one before's last state).  The kernels
+    read W_k and W_v out of the ONE array the solve's product leaves,
+    [W_k | W_v], and write their cotangents into one."""
     args = _sequence(t + h, t, b=2, h=h, dv=128)
     operands = kda_ops._operands(*args, kda_ops.CHUNK, ('dense', 'dense'))
+    one = kda_ops._operands(*args, kda_ops.CHUNK, ('dense', 'interpret'))
     size, n = kda_ops._layout(t, kda_ops.CHUNK)
     assert operands[0].shape[:4] == (n, 2, h, size)
+    assert len(one) == 5 and one[0].shape == (n, 2, h, size, 128 + 128)
     if heads is None:
         assert kda_walk.heads_a_step(h) == min(h, kda_walk.HEADS)
     out, starts = _walked(operands)
-    got, got_starts = kda_walk.forward(operands, heads=heads,
-                                       interpret=True)
+    got, got_starts = kda_walk.forward(one, heads=heads, interpret=True)
     assert got.shape == out.shape and got.dtype == jnp.float32
     _close(got, out, 1e-6)
     # the kernels keep the state transposed, S^T [dv, dk]
@@ -362,8 +459,10 @@ def test_the_walks_are_the_scans_over_the_step(t, h, heads):
         2, t, h, 128), jnp.float32), size, n, jnp.float32)
     d_start, grads = _walked_back(operands, starts, probe)
     got_grads, got_d_start = kda_walk.reverse(
-        operands, got_starts, probe, heads=heads, interpret=True)
-    assert len(got_grads) == 6
+        one, got_starts, probe, heads=heads, interpret=True)
+    assert len(got_grads) == 5
+    got_grads = (got_grads[0][..., :128], got_grads[0][..., 128:]) + \
+        got_grads[1:]
     for x, y in zip(got_grads, grads):
         assert x.shape == y.shape and x.dtype == y.dtype
         _close(x, y, 2e-6)

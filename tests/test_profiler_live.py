@@ -304,3 +304,59 @@ def test_step_hlo_hash_compare_says_whose_bytes_moved(tmp_path):
     assert ('among the ten largest: f32[2,12,2048,64] under '
             'fused_multihead_attention_grad (working): 0 -> 1') in text
     assert 'field argument_bytes' not in text       # what stood still
+
+
+_CALLS = """HloModule step
+%fused_computation.1 (p: f32[8,4,128]) -> f32[4,8,128] {
+  %p = f32[8,4,128]{2,1,0} parameter(0)
+  %t = f32[4,8,128]{2,1,0} transpose(%p), dimensions={1,0,2}
+  ROOT %m = f32[4,8,128]{2,1,0} multiply(%t, %t)
+}
+%fused_computation.2 (p: f32[8,128]) -> f32[8,128] {
+  %p.1 = f32[8,128]{1,0} parameter(0)
+  ROOT %e = f32[8,128]{1,0} exponential(%p.1)
+}
+ENTRY %main (a: f32[8,4,128], b: f32[8,128]) -> (f32[8,128], f32[8,128]) {
+  %a = f32[8,4,128]{2,1,0} parameter(0)
+  %b = f32[8,128]{1,0} parameter(1)
+  %fusion.1 = f32[4,8,128]{2,1,0} fusion(%a), kind=kLoop, calls=%fused_computation.1
+  %fusion.2 = f32[8,128]{1,0} fusion(%b), kind=kLoop, calls=%fused_computation.2
+  %copy.7 = f32[8,128]{0,1} copy(%b)
+  %copy-done.3 = f32[8,128]{1,0:S(1)} copy-done(%b)
+  %walk.1 = (f32[8,128]{1,0}, f32[8,128]{1,0}) custom-call(%fusion.1, %copy.7), custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/walk"}
+  %chunk.1 = f32[8,128]{1,0} custom-call(%fusion.2, %copy-done.3, %b), custom_call_target="tpu_custom_call"
+  %sort.1 = f32[8,128]{1,0} custom-call(%copy.7), custom_call_target="TopK"
+  ROOT %out = (f32[8,128]{1,0}, f32[8,128]{1,0}) tuple(%chunk.1, %sort.1)
+}
+"""
+
+
+def test_step_hlo_hash_names_the_operands_re_laid_for_a_kernel(tmp_path):
+    """``relaid_operands``: of a compiled step's Mosaic calls, those
+    that read what a ``copy`` or ``transpose`` made, alone or inside a
+    fusion; a fusion without one, the compiler's prefetch pair, a
+    parameter and another library's custom call are not named.
+    ``--compare`` prints the lines one side has alone."""
+    import importlib.util
+    import json
+    spec = importlib.util.spec_from_file_location(
+        'step_hlo_hash', os.path.join(os.path.dirname(HERE), 'tools',
+                                      'step_hlo_hash.py'))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    relaid = tool.relaid_operands(_CALLS)
+    assert relaid == ['walk.1 <- fusion.1 (fusion)',
+                      'walk.1 <- copy.7 (copy)']
+    fields = {'peak_bytes': 1.0, 'relaid': relaid}
+    parent = {'c/quiet/memory': fields}
+    tree = {'c/quiet/memory': dict(fields, relaid=relaid[:1])}
+    a, b = str(tmp_path / 'a.json'), str(tmp_path / 'b.json')
+    json.dump(parent, open(a, 'w'))
+    json.dump(tree, open(b, 'w'))
+    lines = []
+    assert tool.compare(a, b, out=lines.append) == 1
+    assert lines == [
+        'c/quiet/memory: moved',
+        "    a call's operand re-laid on the parent only: "
+        'walk.1 <- copy.7 (copy)',
+        '    no temp_peak on either side: whose bytes cannot be said']

@@ -126,7 +126,8 @@ def test_the_decays_overflow_the_factored_form_and_not_the_op(
     floor or dropped term stands behind the float32 agreement."""
     if path == 'fused':
         request.getfixturevalue('pallas_interpret')
-    args = _inputs(3, t=128, dk=dk)
+    dv = dk if path == 'fused' else 8   # the kernels take whole lane tiles
+    args = _inputs(3, t=128, dk=dk, dv=dv)
     running = np.cumsum(np.asarray(args[3])[:, :64], 1)
     assert running.min() < -200
     with np.errstate(over='ignore'):
@@ -137,8 +138,8 @@ def test_the_decays_overflow_the_factored_form_and_not_the_op(
     assert (monitor.counter_value('pallas/kda_chunk/dispatch_fused') or
             0) == fused + (path == 'fused')
     with jax.enable_x64():
-        exact = _inputs(3, t=128, dk=dk, dtype=jnp.float64)
-        probe = jnp.asarray(np.random.RandomState(2).randn(2, 128, 3, 8))
+        exact = _inputs(3, t=128, dk=dk, dv=dv, dtype=jnp.float64)
+        probe = jnp.asarray(np.random.RandomState(2).randn(2, 128, 3, dv))
         got = jax.jit(jax.grad(
             lambda *x: jnp.sum(kda_ops.gated_delta_rule(*x) * probe),
             argnums=(0, 1, 2, 3, 4)))(*exact)

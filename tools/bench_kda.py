@@ -16,17 +16,27 @@ gradients lie from the first's, as a share of the largest entry:
       --impl tree --ops chiprun_out/kda_trace
   python tools/bench_kda.py --tokens 8192 --heads 32 ...   # Kimi Linear's
 
+(A copy loads beside the TREE's ``ops/pallas``: one from before a PR
+that changed the kernels' entry points, as PR 62 did, is timed from
+its own checkout with this file copied in, ``--impl tree`` there.)
+
 ``--ops`` traces one forward + backward call a row and gives the row
-its device time BY PART, ms (``parts_ms``): the scores' kernels (every
-Mosaic call), the inverse of the unit-triangular system (what lowers
+its device time BY PART, ms (``parts_ms``): the preparation's two
+kernels by the names ``kda_chunk`` gives its calls (``chunk_forward``,
+twice a call of the op, and ``chunk_backward``; a copy whose Mosaic
+calls carry no name reads ``scores_kernels``, all of them together),
+the inverse of the unit-triangular system and its products (what lowers
 under the ``inverse`` scope, or a ``triangular_solve``), the running
-decay's sums (the ``cumsum``), the forward walk and the reverse walk
-over the chunks (the ``kda_walk`` kernels' two calls by their name, or
-where the copy holds none the program's two ``while`` loops in the
-order they run, with ``trip_us``: a walk's time over its chunks), and
-everything else of the op (exponentials, layout, the chunks' padding);
-and the five longest operations outside the walks by name, whose
-instructions are in ``program.txt`` beside the trace.
+decay's sums (a ``cumsum`` of XLA's), the forward walk and the reverse
+walk over the chunks (the ``kda_walk`` kernels' two calls by their
+name, or where the copy holds none the program's two ``while`` loops in
+the order they run, with ``trip_us``: a walk's time over its chunks),
+``layout`` (every ``copy`` and ``transpose`` instruction and every
+fusion the compiler named for one: an operand re-laid for a call shows
+here, without an ``--xla_dump_to``), and everything else of the op
+(exponentials, casts, the chunks' padding); and the five longest
+operations outside the walks by name, whose instructions are in
+``program.txt`` beside the trace.
 
 Rows go to stdout and to ``--out`` (a .jsonl under chiprun_out/).
 """
@@ -61,6 +71,10 @@ PARTS = (('inverse', ('inverse', 'triangular_solve')),
 WALKS = ('forward_walk', 'reverse_walk')
 # the names ``ops/pallas/kda_walk.py`` gives its two calls
 WALK_CALLS = ('kda_walk_forward', 'kda_walk_reverse')
+# and ``ops/pallas/kda_chunk.py`` its two, by part
+CHUNK_CALLS = (('kda_chunk_forward', 'chunk_forward'),
+               ('kda_chunk_backward', 'chunk_backward'))
+_LAYOUT = re.compile(r'(?:^|[_\-.])(?:copy|transpose)(?:$|[_\-.])')
 
 
 def load_impl(spec):
@@ -89,11 +103,19 @@ def timed(fn, *args, runs=10):
     return statistics.median(times)
 
 
-def part_of(op_name):
+def part_of(name, op_name):
+    """The part of an instruction of XLA's, by the scope or primitive
+    in its ``op_name`` and else by what the compiler called it."""
     for part, marks in PARTS:
         if any(mark in op_name for mark in marks):
             return part
-    return 'other'
+    return 'layout' if _LAYOUT.search(name) else 'other'
+
+
+def kernel_part(op_name):
+    """The part of a Mosaic call that is no walk."""
+    return next((part for call, part in CHUNK_CALLS if call in op_name),
+                'scores_kernels')
 
 
 def by_part(ops, op_names, trips):
@@ -114,8 +136,9 @@ def by_part(ops, op_names, trips):
             part = next((name for name, call in zip(WALKS, WALK_CALLS)
                          if call in op_names.get(op.name, '')), None)
         if part is None:
-            part = 'scores_kernels' if op.kind == trace_reduce.MOSAIC \
-                else part_of(op_names.get(op.name, ''))
+            scope = op_names.get(op.name, '')
+            part = kernel_part(scope) if op.kind == trace_reduce.MOSAIC \
+                else part_of(op.name, scope)
             outside[op.name] = outside.get(op.name, 0.0) + (b - a) / 1e6
         ms[part] = ms.get(part, 0.0) + (b - a) / 1e6
     longest = sorted(outside.items(), key=lambda kv: -kv[1])[:5]

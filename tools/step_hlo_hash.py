@@ -26,6 +26,11 @@ prints per cell whether the hashes are equal and what moved: every
 field, every class and fluid op, and the buffers that are among the
 ten largest of one side only.  That is the check a kernel PR runs
 before any chip time: which part of a ``peak_hbm`` that moved is whose.
+``--memory`` also names, under ``relaid``, every Mosaic call of the
+compiled step that reads an operand the compiler RE-LAID for it: one a
+``copy`` or ``transpose`` instruction made, or a fusion that holds one
+(``relaid_operands``).  A call fixes its operands' layouts; such a line
+is the price (PRs 61, 62), and it shows in no memory field.
 
 A Mosaic kernel's serialized module carries the SOURCE LINES of its
 body and of every caller in the file, so one comment line above a
@@ -41,7 +46,34 @@ flash cell's step anew once.
 import hashlib
 import json
 import os
+import re
 import sys
+
+_LAYOUT_OPS = {'copy', 'transpose'}
+
+
+def relaid_operands(text):
+    """A compiled module's text -> ['call <- producer (opcode)', ..]:
+    the Mosaic calls' operands that a ``copy`` or ``transpose``
+    instruction made, or a fusion whose body holds one.  (A
+    ``copy-start`` / ``copy-done`` pair is the compiler's prefetch into
+    its fast memory and moves no layout.)"""
+    from paddle_tpu.fluid import profiler
+    computations = profiler._parse_hlo(text)[1]
+    made = {ins.name: ins for body in computations.values() for ins in body}
+    found = []
+    for call in made.values():
+        if 'custom_call_target="tpu_custom_call"' not in call.attrs:
+            continue
+        for operand in re.findall(r'%([\w.\-]+)', call.operands):
+            by = made.get(operand)
+            if by is None:
+                continue
+            inside = {ins.opcode for ins in computations.get(by.calls, ())}
+            if by.opcode in _LAYOUT_OPS or inside & _LAYOUT_OPS:
+                found.append('%s <- %s (%s)' % (call.name, operand,
+                                               by.opcode))
+    return found
 
 
 def main(root, out_path, only=(), memory=False):
@@ -131,9 +163,12 @@ def main(root, out_path, only=(), memory=False):
                     if hasattr(profiler, 'hlo_live') else None
                 if live is not None:
                     fields['temp_peak'] = memviz.temp_peak(live)
+                fields['relaid'] = relaid_operands(compiled.as_text())
+                for line in fields['relaid']:
+                    print(key + '/memory/relaid', line, flush=True)
                 print(key + '/memory', {
-                    k: v for k, v in fields.items() if k != 'temp_peak'},
-                    flush=True)
+                    k: v for k, v in fields.items()
+                    if k not in ('temp_peak', 'relaid')}, flush=True)
                 if 'temp_peak' in fields:
                     peak = fields['temp_peak']
                     print(key + '/memory/temp_peak', peak['bytes'],
@@ -176,14 +211,19 @@ def compare(parent_path, tree_path, out=print):
                             '%d -> %d kernels)' % (a[0], b[0], a[1], b[1],
                                                    a[2], b[2])))
             continue
-        fields = {k: v for k, v in a.items() if k != 'temp_peak'}, \
-            {k: v for k, v in b.items() if k != 'temp_peak'}
+        fields = [{k: v for k, v in side.items()
+                   if k not in ('temp_peak', 'relaid')} for side in (a, b)]
         if a == b:
             out('%s: equal' % key)
             continue
         differing += 1
         out('%s: moved' % key)
         _moved('field', fields[0], fields[1], out=out)
+        for line in sorted(set(a.get('relaid', ())) ^
+                           set(b.get('relaid', ()))):
+            out('    a call\'s operand re-laid on %s only: %s'
+                % ('the tree' if line in b.get('relaid', ())
+                   else 'the parent', line))
         pa, pb = a.get('temp_peak'), b.get('temp_peak')
         if not (pa and pb):
             out('    no temp_peak on %s: whose bytes cannot be said'
