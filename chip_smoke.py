@@ -37,6 +37,10 @@ the chip: BERT-base pretraining through the normal entry points
                                     # delta rule at all 32 heads in
                                     # recompute groups, NoPE latent
                                     # attention, the cell's loss
+    python chip_smoke.py --phase sdar    # SDAR-30B-A3B-Chat's: block
+                                    # diffusion's two copies under the
+                                    # block-relation flash mask,
+                                    # gradients, the cell's loss
     python chip_smoke.py --phase grouped # the experts' grouped-matmul
                                     # kernels against ragged_dot at
                                     # the five routed cells' shapes
@@ -2610,6 +2614,247 @@ def bf16_units(got, want):
     return float(np.abs(got - want).max() / unit)
 
 
+# --- SDAR-30B-A3B-Chat --------------------------------------------------
+# Published widths (models.sdar.BASE) as the benchmark cuts them
+# (experts 0-15 of 128 held, 18992 vocabulary rows), one sequence of
+# 4096 data tokens = 8192 positions.  Sampled gradients of the f32
+# TRAIN program at TWO layers (a whole one in a recompute group and the
+# last, which computes of its clean rows only keys and values; six
+# layers' f32 activations do not fit) against jax.grad of the reference
+# with a head's scores computed again for its gradient; then the f32
+# for_test loss at the cell's own six layers over SDAR_LOSS_BATCHES
+# corruptions against the reference in f32 and in bfloat16 throughout:
+# the two readings the family's REFERENCE_RTOL lies between.
+SDAR_GRAD_LAYERS = 2
+SDAR_CELL_LAYERS = 6
+SDAR_SEQ = 4096
+SDAR_CELL_RTOL = 4e-5       # = benchmark/families/sdar.py's
+SDAR_L2_RTOL = 2e-3         # a gradient tensor's relative L2 distance
+SDAR_LOSS_BATCHES = 12
+# creation order: embedding 0; layer 0 g1 1 Wq 2 gq 3 Wk 4 gk 5 Wv 6 Wo
+# 7 g2 8 router 9 gate 10 up 11 down 12; layer 1 from 13; final gain
+# 25, head 26
+SDAR_SAMPLED = {'embedding': 0, 'Wq (layer 0)': 2, 'q gain (layer 0)': 3,
+                'Wk (layer 0)': 4, 'k gain (layer 0)': 5,
+                'Wv (layer 0)': 6, 'Wo (layer 0)': 7,
+                'router (layer 0)': 9, 'gate (layer 0)': 10,
+                'Wq (layer 1)': 14, 'Wk (layer 1)': 16,
+                'Wv (layer 1)': 18, 'Wo (layer 1)': 19,
+                'router (layer 1)': 21, 'down (layer 1)': 24,
+                'final gain': 25}
+
+
+def _sdar_cut(layers):
+    """The cell's cut at ``layers`` layers, the assumed numbers (block
+    length, least mask probability, the startup values) as the cell's
+    configuration file has them."""
+    import copy
+    from paddle_tpu.models import sdar
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           'benchmark', 'configs',
+                           'sdar-30b-a3b-chat.json')) as f:
+        assumed = json.load(f)['assumed']
+    cfg = copy.copy(sdar.BASE)
+    cfg.vocab_size, cfg.layers, cfg.experts_held = 18992, layers, (0, 16)
+    cfg.block_length = assumed['block_length']['value']
+    cfg.t_min = assumed['t_min']['value']
+    cfg.embed_std = assumed['embed_std']['value']
+    cfg.qk_gain = assumed['qk_gain']['value']
+    return cfg
+
+
+def _sdar_sizes(cfg):
+    return dict(layers=cfg.layers, head_dim=cfg.head_dim,
+                top_k=cfg.top_k, block=cfg.block_length,
+                first=cfg.experts_held[0], eps=cfg.rms_eps,
+                theta=cfg.rope_theta, renormalize=cfg.renormalize)
+
+
+def _sdar_program(cfg, seq, seed, train):
+    """-> (main or its for_test clone, startup, loss, parameter names,
+    {param: grad name} or None)."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import sdar
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        _, _, loss = sdar.build_pretrain(cfg, seq)
+        params = [p.name for p in main.all_parameters()]
+        if not train:
+            return main.clone(for_test=True), startup, loss, params, None
+        pairs = dict((p.name, g.name) for p, g in
+                     fluid.optimizer.SGD(0.0).minimize(loss)[1])
+    return main, startup, loss, params, pairs
+
+
+def _sdar_cell_losses(seq, seed):
+    """The cell's own cut, forward only: the f32 for_test program's
+    loss on SDAR_LOSS_BATCHES corruptions beside the reference's in
+    float32 and in bfloat16 throughout."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.models import sdar
+    from paddle_tpu.models.reference import sdar as reference
+    cfg = _sdar_cut(SDAR_CELL_LAYERS)
+    feeds = [sdar.synthetic_batch(cfg, 1, seq, s)
+             for s in range(seed, seed + SDAR_LOSS_BATCHES)]
+    test, startup, loss, params, _ = _sdar_program(cfg, seq, seed, False)
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights = [np.asarray(fluid.core.as_array(scope.find_var(p)))
+                   for p in params]
+        program = [_scalar(exe.run(test, feed=f, fetch_list=[loss]))
+                   for f in feeds]
+        say('sdar f32 for_test program, %d layers: sdar/visible_pairs %d '
+            'a head, sdar/tiles_visited %d, sdar/masked_share %.4f, '
+            'pallas/flash_attention/mask_block %d lowerings'
+            % (cfg.layers, monitor.gauge_value('sdar/visible_pairs'),
+               monitor.gauge_value('sdar/tiles_visited'),
+               monitor.gauge_value('sdar/masked_share'),
+               monitor.counter_value('pallas/flash_attention/mask_block')))
+        check(monitor.gauge_value('sdar/visible_pairs') ==
+              (cfg.layers - 1) * seq * seq +
+              seq * (seq - cfg.block_length) // 2,
+              'sdar/visible_pairs is L^2 a layer that runs both copies '
+              'and L (L - B) / 2 in the last')
+        for name in scope.local_var_names():
+            scope.erase(name)
+    weights = [jnp.asarray(x) for x in weights]
+    sizes = _sdar_sizes(cfg)
+    both = jax.jit(lambda w, f: [reference.loss(w, f, dtype=dt, **sizes)
+                                 for dt in (jnp.float32, jnp.bfloat16)])
+    off, low = [], []
+    for n, (feed, got) in enumerate(zip(feeds, program)):
+        full, half = (float(x) for x in both(
+            weights, {k: jnp.asarray(v) for k, v in feed.items()}))
+        off.append(abs(got - full) / full)
+        low.append(abs(half - full) / full)
+        say('%d layers, batch seed %d (masked share %.3f): program %.6f, '
+            'reference %.6f (relative difference %.2e), reference in '
+            'bfloat16 throughout %.6f (%.2e)'
+            % (cfg.layers, seed + n, (feed['weights'] > 0).mean(), got,
+               full, off[-1], half, low[-1]))
+    say('over %d batches at %d layers: f32 for_test program against the '
+        'reference, relative: median %.2e, largest %.2e; reference in '
+        'bfloat16 throughout: smallest %.2e, quartiles %.2e %.2e %.2e, '
+        'largest %.2e, %d within %g'
+        % ((len(off), cfg.layers, np.median(off), max(off), min(low)) +
+           tuple(np.percentile(low, (25, 50, 75))) +
+           (max(low), sum(x <= SDAR_CELL_RTOL for x in low),
+            SDAR_CELL_RTOL)))
+    check(max(off) <= SDAR_CELL_RTOL, 'sdar f32 for_test loss at the '
+          'cell\'s cut within %g of the reference on every batch'
+          % SDAR_CELL_RTOL)
+    check(2 * sum(x > SDAR_CELL_RTOL for x in low) > len(low),
+          'the reference in bfloat16 throughout misses %g on most '
+          'batches' % SDAR_CELL_RTOL)
+
+
+def _sdar_train_step(seq, seed):
+    """One f32 train step at SDAR_GRAD_LAYERS layers against jax.grad
+    of the reference on sampled tensors."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.models import sdar
+    from paddle_tpu.models.reference import sdar as reference
+    from paddle_tpu.ops.pallas import common
+    cfg = _sdar_cut(SDAR_GRAD_LAYERS)
+    sizes = _sdar_sizes(cfg)
+    feed = sdar.synthetic_batch(cfg, 1, seq, seed)
+    main, startup, loss, params, pairs = _sdar_program(cfg, seq, seed,
+                                                       True)
+    count = sum(int(np.prod(main.global_block().var(p).shape))
+                for p in params)
+    say('sdar: %d parameters in %d tensors at %d layers'
+        % (count, len(params), cfg.layers))
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights = [np.asarray(fluid.core.as_array(scope.find_var(p)))
+                   for p in params]
+        t0 = time.time()
+        got = exe.run(main, feed=feed, fetch_list=[loss] + [
+            pairs[params[i]] for i in SDAR_SAMPLED.values()])
+        got_loss = _scalar(got[:1])
+        say('sdar f32 train program, 1 x %d data tokens, %d layers: loss '
+            '%.6f in %.1f s; moe/held_share %.4f, moe/held_rows_max %d, '
+            'moe/dropped_tokens %d; sdar/tiles_visited %d; '
+            'executor/recompute_groups %d; flash_attention last '
+            'dispatch %s; backward one_pass %d two_pass %d; peak memory '
+            '%.2f GB'
+            % (seq, cfg.layers, got_loss, time.time() - t0,
+               monitor.gauge_value('moe/held_share'),
+               monitor.gauge_value('moe/held_rows_max'),
+               monitor.counter_value('moe/dropped_tokens'),
+               monitor.gauge_value('sdar/tiles_visited'),
+               monitor.counter_value('executor/recompute_groups'),
+               common._LAST.get('flash_attention'),
+               monitor.counter_value(
+                   'pallas/flash_attention/backward_one_pass'),
+               monitor.counter_value(
+                   'pallas/flash_attention/backward_two_pass'),
+               _peak_bytes(jax.devices()[:1])[0] / 1e9))
+        check(monitor.counter_value(
+            'pallas/flash_attention/mask_block') >= 3,
+            'the block-mask calls (32 query heads over 4 K/V heads of '
+            '128, float32) ran the flash kernels')
+        grads = [np.asarray(x) for x in got[1:]]
+        del got
+        for name in scope.local_var_names():
+            scope.erase(name)
+    weights = [jnp.asarray(x) for x in weights]
+    fed = {k: jnp.asarray(v) for k, v in feed.items()}
+
+    def ref_loss(some, full, dtype=jnp.float32):
+        full = list(full)
+        for name, w in some.items():
+            full[SDAR_SAMPLED[name]] = w
+        return reference.loss(full, fed, dtype=dtype, remat=True, **sizes)
+
+    t0 = time.time()
+    want_loss, want_grads = jax.jit(jax.value_and_grad(ref_loss))(
+        {name: weights[i] for name, i in SDAR_SAMPLED.items()}, weights)
+    want_loss = float(want_loss)
+    low = float(jax.jit(lambda w: ref_loss({}, w, jnp.bfloat16))(weights))
+    say('the reference\'s loss and %d gradients in %.1f s'
+        % (len(want_grads), time.time() - t0))
+    rel = abs(got_loss - want_loss) / want_loss
+    say('reference loss %.6f, program %.6f (relative difference %.2e); '
+        'reference in bfloat16 throughout %.6f (%.2e)'
+        % (want_loss, got_loss, rel, low,
+           abs(low - want_loss) / want_loss))
+    check(rel <= SDAR_CELL_RTOL, 'sdar f32 train loss within %g of the '
+          'reference' % SDAR_CELL_RTOL)
+    far = 0.0
+    for (name, _), x in zip(SDAR_SAMPLED.items(), grads):
+        y = np.asarray(want_grads[name])
+        d = float(np.linalg.norm(x - y) / np.linalg.norm(y))
+        far = max(far, d)
+        say('gradient of %s %s: largest entry difference %.3e of the '
+            'largest entry (%.3e), relative L2 distance %.3e'
+            % (name, x.shape, np.abs(x - y).max() / np.abs(y).max(),
+               np.abs(y).max(), d))
+    check(far <= SDAR_L2_RTOL,
+          'sdar gradients: all %d sampled tensors within %g of the '
+          'reference\'s, relative L2 distance (worst %.3e)'
+          % (len(SDAR_SAMPLED), SDAR_L2_RTOL, far))
+
+
+def phase_sdar(seq=SDAR_SEQ, seed=0):
+    """models.sdar.BASE cut as above: loss and sampled gradients of the
+    f32 TRAIN program against the reference's on one seeded corruption;
+    then the cell's for_test losses."""
+    _sdar_train_step(seq, seed)
+    _sdar_cell_losses(seq, seed)
+
+
 # --- Ouro-2.6B ----------------------------------------------------------
 # Published widths (models.ouro.BASE), one 4096-token sequence, the
 # stack applied total_ut_steps = 4 times through ONE While.  Sampled
@@ -3490,10 +3735,10 @@ def main():
     ap.add_argument('--phase',
                     choices=('bert', 'olmoe', 'laguna', 'moonlight',
                              'lfm2', 'evabyte', 'solar', 'ouro', 'xing4',
-                             'phi4flash', 'kimi', 'grouped'),
+                             'phi4flash', 'kimi', 'sdar', 'grouped'),
                     default='bert',
                     help="'olmoe' / 'laguna' / 'moonlight' / 'lfm2' / "
-                    "'evabyte' / 'solar' / 'ouro' / 'xing4' / 'phi4flash' / 'kimi': only that model's "
+                    "'evabyte' / 'solar' / 'ouro' / 'xing4' / 'phi4flash' / 'kimi' / 'sdar': only that model's "
                     "gradient check; 'grouped': only the grouped-matmul "
                     "kernels against ragged_dot")
     args = ap.parse_args()
@@ -3542,6 +3787,8 @@ def main():
             phase_phi4flash()
         elif args.phase == 'kimi':
             phase_kimi()
+        elif args.phase == 'sdar':
+            phase_sdar()
         elif args.phase == 'grouped':
             phase_grouped_matmul()
         elif args.chips == 4:

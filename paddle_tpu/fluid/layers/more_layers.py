@@ -298,7 +298,7 @@ def hyper_connection_post(x, y, carry, name=None):
 
 
 def flash_attention(q, k, v, causal=False, window=0, coarse=None,
-                    with_lse=False, name=None):
+                    with_lse=False, block_mask=None, name=None):
     """The ``fused_multihead_attention`` op on heads already split: q
     [B, T, H, D], k [B, Tk, Hkv, D], v [B, Tk, Hkv, Dv] -> [B, T, H,
     Dv] (the flash kernels on a chip from
@@ -306,9 +306,10 @@ def flash_attention(q, k, v, causal=False, window=0, coarse=None,
     under it and off a chip).  Scores are scaled by 1 / sqrt(D); a
     model whose softmax scale is another (YaRN's ``mscale`` squared,
     ``models/moonlight.py`` ``softmax_scale``) multiplies q by the
-    ratio before the call.  ``causal``, ``window`` and ``coarse`` =
-    (window, chunk) are the op's three masks (``ops/pallas/
-    flash_attention.py`` lists them in one place); ``with_lse`` also
+    ratio before the call.  ``causal``, ``window``, ``coarse`` =
+    (window, chunk) and ``block_mask`` = (block, 'causal' | 'strict')
+    are the op's four masks (``ops/pallas/flash_attention.py`` lists
+    them in one place); ``with_lse`` also
     returns every row's log-sum-exp [B, T, H], differentiable, for
     ``attention_merge``."""
     helper = LayerHelper('fused_multihead_attention', name=name)
@@ -320,6 +321,9 @@ def flash_attention(q, k, v, causal=False, window=0, coarse=None,
     if coarse:
         attrs['coarse_window'], attrs['coarse_chunk'] = \
             (int(n) for n in coarse)
+    if block_mask:
+        attrs['block_mask'] = int(block_mask[0])
+        attrs['block_relation'] = str(block_mask[1])
     if with_lse:
         attrs['with_lse'] = True
         lse = helper.create_variable_for_type_inference('float32')
@@ -435,6 +439,69 @@ def eva_attention(q, k, v, window_size, chunk_size, phi, mu, name=None):
                                   remote, remote_lse, name=name)
     out.block.program.watch([weight.name], _record_remote_weight)
     return out
+
+
+def block_diffusion_attention(q, k, v, block, name=None):
+    """Attention of block-diffusion TRAINING (BD3-LMs, Arriola et al.
+    2025; SDAR): q, k, v hold TWO copies of every sequence, one over
+    the other along time, [B, 2L, H | Hkv, D]: rows 0 .. L-1 the
+    corrupted copy, rows L .. 2L-1 the clean one, both at positions
+    0 .. L-1 (rotated already) -> [B, 2L, H, Dv].  q may hold the
+    corrupted rows alone, [B, L, H, D], and so does the result then (a
+    last layer, whose clean rows nothing reads once their keys and
+    values exist).  In blocks of ``block`` positions, in ONE softmax a
+    query:
+
+    - corrupted i sees the corrupted keys of its OWN block (both
+      directions) and the clean keys of every EARLIER block;
+    - clean i sees the clean keys of its own and every earlier block,
+      and no corrupted key.
+
+    Lowered as three attention calls and a merge, each with its
+    gradient: the clean rows over the clean keys under the block mask
+    'causal'; the corrupted rows over the clean keys under 'strict'
+    (the first block sees none: out 0, lse -inf); the corrupted rows
+    over their own block's corrupted keys with the blocks folded into
+    the batch ([B, L, ..] -> [B L / block, block, ..], a reshape; no
+    mask); ``attention_merge`` of the last two by their log-sum-exps.
+    On a chip the first two run the flash kernels from
+    ``flash_attention.FLASH_MIN_SEQ`` queries up and no [L, L] tensor
+    reaches HBM, forward or backward.  L has to be a whole number of
+    blocks.  As they are lowered the block-mask calls add to
+    ``sdar/visible_pairs`` and ``sdar/tiles_visited``."""
+    t, block = int(k.shape[1]) // 2, int(block)
+    if int(k.shape[1]) != 2 * t or t % block or \
+            int(q.shape[1]) not in (t, 2 * t):
+        raise ValueError(
+            'block_diffusion_attention: %d query and %d key rows in '
+            'blocks of %d: the keys are two copies of a whole number of '
+            'blocks, the queries both copies or the first'
+            % (q.shape[1], k.shape[1], block))
+    from .nn import reshape, split
+    from .tensor import concat
+
+    def fold(x):        # blocks into the batch
+        return reshape(x, [-1, block, int(x.shape[2]), int(x.shape[3])])
+
+    def unfold(x):
+        return reshape(x, [-1, t] + [int(n) for n in x.shape[2:]])
+
+    (k_noisy, k_clean), (v_noisy, v_clean) = (
+        split(x, 2, dim=1) for x in (k, v))
+    q_noisy, q_clean = split(q, 2, dim=1) if int(q.shape[1]) == 2 * t \
+        else (q, None)
+    own, own_lse = flash_attention(fold(q_noisy), fold(k_noisy),
+                                   fold(v_noisy), with_lse=True)
+    earlier, earlier_lse = flash_attention(
+        q_noisy, k_clean, v_clean, block_mask=(block, 'strict'),
+        with_lse=True)
+    noisy, _ = attention_merge(unfold(own), unfold(own_lse), earlier,
+                               earlier_lse, name=name)
+    if q_clean is None:
+        return noisy
+    clean = flash_attention(q_clean, k_clean, v_clean,
+                            block_mask=(block, 'causal'))
+    return concat([noisy, clean], axis=1)
 
 
 def grid_sampler(x, grid, name=None):

@@ -63,7 +63,17 @@ def fused_multihead_attention(ctx, ins, attrs):
     window that completes it).  attrs['with_lse'] adds the output Lse
     [B, T, H] (float32), every row's log-sum-exp with a gradient of
     its own, -inf where a row sees no key: what ``attention_merge``
-    joins two calls by."""
+    joins two calls by.
+
+    attrs['block_mask'] (a block length) with attrs['block_relation']
+    ('causal' or 'strict') is the fourth mask
+    (flash_attention()'s ``block_mask``): a relation between blocks of
+    positions, block diffusion's (``layers.block_diffusion_attention``).
+    Such a call is lowered inside the scope ``block<n>_<relation>``
+    and adds to the traced program's sums ``sdar/visible_pairs`` (the
+    (query, key) pairs the mask lets through, a head) and, where the
+    kernels run, ``sdar/tiles_visited`` (the score tiles their loops
+    walk, forward and backward: flash_attention._count_tiles)."""
     from .pallas.flash_attention import mesh_flash_attention
     q = ins['Q'][0]
     k = ins['K'][0]
@@ -84,6 +94,12 @@ def fused_multihead_attention(ctx, ins, attrs):
         scopes.append('remote')
         _coarse_gauges(q.shape[0], q.shape[1], k.shape[1],
                        *more['coarse'])
+    if attrs.get('block_mask'):
+        more['block_mask'] = (int(attrs['block_mask']),
+                              attrs['block_relation'])
+        scopes.append('block%d_%s' % more['block_mask'])
+        _block_pairs(q.shape[0], q.shape[1], k.shape[1],
+                     *more['block_mask'])
     if attrs.get('with_lse'):
         more['with_lse'] = True
     with contextlib.ExitStack() as stack:
@@ -97,6 +113,15 @@ def fused_multihead_attention(ctx, ins, attrs):
     if not attrs.get('with_lse'):
         return {'Out': [out]}
     return {'Out': [out[0]], 'Lse': [jnp.transpose(out[1], (0, 2, 1))]}
+
+
+def _block_pairs(batch, t, tk, block, kind):
+    """``sdar/visible_pairs``: what a block-mask call adds to the
+    traced program's sum, a head."""
+    from . import registry
+    from .pallas import flash_attention as fa
+    seen = fa.relation_keys_seen(t, tk, fa.block_relation(block, kind))
+    registry.trace_sum('sdar/visible_pairs', float(batch * seen.sum()))
 
 
 def _coarse_gauges(batch, t, summaries, window, chunk):

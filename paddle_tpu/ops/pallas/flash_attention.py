@@ -48,7 +48,14 @@ _score_tile, with the tiles wholly outside them never visited
   differ in length: the resident rows are then of two lengths (K and
   V in a forward or dq call, Q and dO in a dkv call, both in the
   fused backward), and every estimate of common.py takes the length
-  that is resident.
+  that is resident;
+- ``relation`` = (block, inclusive), a RELATION BETWEEN BLOCKS of
+  ``block`` positions (block diffusion's two copies of a sequence):
+  query i sees key j where j // block <= i // block (``inclusive``:
+  block-causal, the clean copy over itself) or j // block <
+  i // block (strictly: the corrupted copy over the clean one, whose
+  first block sees nothing: out 0, lse -inf, as under the coarse
+  mask).
 
 Two layouts, and the shape picks (_heads_a_step): the forward and the
 one-pass backward address the op's own [B, T, H*64] operands, the pair
@@ -197,13 +204,14 @@ def _scale_is_exact(scale):
 
 
 def _score_tile(q, k, bias, rows, cols, *, scale, causal, q0, k0,
-                rate, window=0, coarse=None):
+                rate, window=0, coarse=None, relation=None):
     """What the four kernel bodies share for one [bq, bk] tile:
     s = q k^T (* scale, unless an operand already carries it:
     scale=None) (+ bias[None, :]) (-inf above the diagonal and, with
     a ``window``, ``window`` or more keys below it), and the
     a ``coarse`` (window, keys a window) mask instead: -inf from key
-    (q // window) * keys on, _coarse_visible), and the
+    (q // window) * keys on, _coarse_visible; or the block
+    ``relation``: _block_visible), and the
     dropout multiplier u = 1/(1-rate) where the element is kept, 0
     where it is dropped (None at rate 0), drawn from the tile's
     [bq, 1] ``rows`` and [1, bk] ``cols`` terms.  Callers turn s into
@@ -229,6 +237,12 @@ def _score_tile(q, k, bias, rows, cols, *, scale, causal, q0, k0,
             q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0),
             k0 + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1),
             coarse), s, -jnp.inf)
+    elif relation:
+        bq, bk = s.shape
+        s = jnp.where(_block_visible(
+            q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0),
+            k0 + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1),
+            relation), s, -jnp.inf)
     u = None
     if rate:
         u = jnp.where(_dropout_keep(rows, cols, _keep_threshold(rate)),
@@ -247,6 +261,43 @@ def _coarse_visible(qpos, kpos, coarse):
     return kpos < (qpos // window) * keys
 
 
+def _block_visible(qpos, kpos, relation):
+    """The fourth mask, a relation between blocks: ``relation`` =
+    (block, inclusive).  Query i sees key j where
+    j // block < i // block + inclusive."""
+    block, inclusive = relation
+    return kpos // block < qpos // block + inclusive
+
+
+def _relation_keys(q0, bq, relation):
+    """The keys 0 .. n-1 are the ones some query of the rows q0 ..
+    q0+bq-1 sees under a block relation: those of the last row."""
+    block, inclusive = relation
+    return ((q0 + bq - 1) // block + inclusive) * block
+
+
+def relation_keys_seen(t, tk, relation):
+    """[t] ints: how many of the ``tk`` keys each query row sees under
+    a block relation (its keys are 0 .. n-1); numpy, for the counts a
+    lowering publishes."""
+    import numpy as np
+    block, inclusive = relation
+    return np.minimum((np.arange(t) // block + inclusive) * block, tk)
+
+
+def _count_tiles(calls, t, tk, relation, blocks, passes=1):
+    """Add to ``sdar/tiles_visited``, a sum over ONE traced program,
+    the [block_q, block_k] score tiles that ``calls`` kernel instances
+    (one a head) walk under a block relation, ``passes`` times: the
+    tiles that hold a visible pair, as _key_blocks and _query_blocks
+    bound the loops."""
+    from .. import registry
+    block_q, block_k = blocks
+    seen = relation_keys_seen(t, tk, relation).reshape(-1, block_q).max(1)
+    registry.trace_sum('sdar/tiles_visited', float(
+        calls * passes * (-(-seen // block_k)).sum()))
+
+
 def _loop(lo, hi, step, init, tiles):
     """fori_loop over the tiles lo .. hi-1 of one kernel instance,
     ``tiles`` of them a trip (_second_tile() says how many and why;
@@ -262,14 +313,19 @@ def _loop(lo, hi, step, init, tiles):
     return jax.lax.fori_loop(0, (hi - lo) // tiles, trip, init)
 
 
-def _key_blocks(q0, bq, block_k, nk, causal, window, coarse=None):
+def _key_blocks(q0, bq, block_k, nk, causal, window, coarse=None,
+                relation=None):
     """[lo, hi) of the key blocks that hold a key some query of the
     block q0 .. q0+bq-1 sees: all of them without a mask, up to the
     diagonal's under a causal one, and from the block of key
     q0 - window + 1 on under a banded one.  The blocks outside are
     not visited; the ones on the band's two edges are masked per
     element (_score_tile).  Under a coarse mask: up to the last
-    summary the block's last query sees."""
+    summary the block's last query sees; under a block relation: up to
+    the last key any of its queries sees (_relation_keys)."""
+    if relation:
+        seen = _relation_keys(q0, bq, relation)
+        return 0, jnp.minimum(nk, (seen + block_k - 1) // block_k)
     if coarse:
         seen = ((q0 + bq - 1) // coarse[0]) * coarse[1]
         return 0, jnp.minimum(nk, (seen + block_k - 1) // block_k)
@@ -280,12 +336,18 @@ def _key_blocks(q0, bq, block_k, nk, causal, window, coarse=None):
     return lo, hi
 
 
-def _query_blocks(k0, bk, block_q, nq, causal, window, coarse=None):
+def _query_blocks(k0, bk, block_q, nq, causal, window, coarse=None,
+                  relation=None):
     """[lo, hi) of the query blocks that hold a query which sees some
     key of the block k0 .. k0+bk-1: _key_blocks() from the other
     side (the last such query is k0 + bk - 1 + window - 1).  Under a
     coarse mask: from the first query of the window after key k0's
-    on (possibly none: lo = nq)."""
+    on (possibly none: lo = nq).  Under a block relation: from the
+    first query that sees key k0 on."""
+    if relation:
+        block, inclusive = relation
+        first = (k0 // block + 1 - inclusive) * block
+        return jnp.minimum(nq, first // block_q), nq
     if coarse:
         first = (k0 // coarse[1] + 1) * coarse[0]
         return jnp.minimum(nq, first // block_q), nq
@@ -357,7 +419,7 @@ def _head_id(step, r, heads):
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                       block_k, tiles, has_bias, rate, window=0,
-                      coarse=None, heads=1):
+                      coarse=None, heads=1, relation=None):
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
     seed_ref = rest.pop(0) if rate else None
@@ -394,7 +456,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             s, u = _score_tile(
                 q, k, bias, rows, cols,
                 scale=None if exact else scale, causal=causal, q0=q_off,
-                k0=i * block_k, rate=rate, window=window, coarse=coarse)
+                k0=i * block_k, rate=rate, window=window, coarse=coarse,
+                relation=relation)
             m_new = jnp.maximum(m, jnp.max(s, axis=1))
             # a row with every key masked so far: m_new = -inf, p = 0
             m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -419,7 +482,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     acc0 = jnp.zeros((bq, v_ref.shape[2]), jnp.float32)
     # skip the K blocks no query of this block sees
     lo, hi = _key_blocks(q_off, bq, block_k, nk, causal, window,
-                         coarse)
+                         coarse, relation)
     state = _loop(lo, hi, body, ((m0, l0, acc0),) * heads, tiles)
     l_safe = [jnp.maximum(l, 1e-20) for _, l, _ in state]
     o_ref[0] = _join_heads([
@@ -432,7 +495,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                          block_k, tiles, has_bias, has_glse, rate,
-                         window=0, coarse=None):
+                         window=0, coarse=None, relation=None):
     """Grid (BH, T/bq): recompute p row-blocks from q and lse, then
     dq = sum_k (p * (dO V^T - delta)) K * scale."""
     rest = list(rest)
@@ -465,7 +528,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             q_s, k, bias, rows,
             _draw_cols(seed_ref, i * block_k, block_k),
             scale=None if exact else scale, causal=causal, q0=q_off,
-            k0=i * block_k, rate=rate, window=window, coarse=coarse)
+            k0=i * block_k, rate=rate, window=window, coarse=coarse,
+            relation=relation)
         p = jnp.exp(s - lse[:, None])
         dp = _dot(do, v, (1, 1))
         if rate:
@@ -482,7 +546,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
         return dq + _dot(ds.astype(k.dtype), k, (1, 0))
 
     lo, hi = _key_blocks(q_off, bq, block_k, nk, causal, window,
-                         coarse)
+                         coarse, relation)
     dq = _loop(lo, hi, body, jnp.zeros((bq, d), jnp.float32), tiles)
     if exact:
         dq = dq * scale
@@ -491,7 +555,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                           block_q, tiles, dp_early, has_bias, has_glse,
-                          rate, window=0, group=1, coarse=None):
+                          rate, window=0, group=1, coarse=None,
+                          relation=None):
     """Grid (BH, T/bk): for one K/V block, stream Q row-blocks:
     dv = sum_q p^T dO;  ds_raw = p * (dO V^T - delta);
     dk = sum_q ds_raw^T Q * scale;  dbias = sum_q ds_raw (per key).
@@ -538,7 +603,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             _draw_rows(seed_ref, g_id, j * block_q, block_q), cols,
             scale=None if exact else scale, causal=causal,
             q0=j * block_q, k0=k_off, rate=rate, window=window,
-            coarse=coarse)
+            coarse=coarse, relation=relation)
         p = jnp.exp(s - lse[:, None])
 
         def dp_tile():
@@ -569,7 +634,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
     # q blocks whose queries see no key of this block contribute
     # nothing
     j0, j1 = _query_blocks(k_off, bk, block_q, nq, causal, window,
-                           coarse)
+                           coarse, relation)
     dk0 = jnp.zeros((bk, d), jnp.float32)
     dv0 = jnp.zeros(v.shape, jnp.float32)
     db0 = jnp.zeros((bk,), jnp.float32)
@@ -592,7 +657,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                             block_q, block_k, tiles, dp_early, has_bias,
                             has_glse, rate, window=0, group=1,
-                            coarse=None, heads=1):
+                            coarse=None, heads=1, relation=None):
     """Single-pass backward: grid (BH,) only.  The two-pass scheme
     (dq grid over Q blocks, dk/dv grid over K blocks) recomputes the
     score block s AND the prob-cotangent dp = dO V^T in BOTH kernels —
@@ -661,7 +726,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
                     _draw_rows(seed_ref, g_ids[r], j * block_q, block_q),
                     cols, scale=None if exact else scale, causal=causal,
                     q0=j * block_q, k0=i * block_k, rate=rate,
-                    window=window, coarse=coarse)
+                    window=window, coarse=coarse, relation=relation)
                 p = jnp.exp(s - lse[:, None])
 
                 def dp_tile():
@@ -703,7 +768,7 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, *rest, scale, causal,
             return new
 
         j0, j1 = _query_blocks(i * block_k, block_k, block_q, nq,
-                               causal, window, coarse)
+                               causal, window, coarse, relation)
         dk0 = jnp.zeros((block_k, d), jnp.float32)
         dv0 = jnp.zeros((block_k, v_ref.shape[2]), jnp.float32)
         db0 = jnp.zeros((block_k,), jnp.float32)
@@ -763,7 +828,8 @@ def _pair_rows(n, pairs, tiled=False):
 
 def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
                      causal, block_q, block_k, interpret, rate,
-                     window=0, coarse=None, limit=None, heads=1):
+                     window=0, coarse=None, limit=None, heads=1,
+                     relation=None):
     """pallas_call plumbing for the one-pass backward: grid (BH,), or
     (B*Hkv, group) where ``group`` query heads share a K/V head, or
     (B*H/2,) over pairs of heads (``heads`` = 2: q, k, v, do and the
@@ -777,7 +843,7 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
     has_bias = bias is not None
     has_glse = glse3 is not None
     tiles, dp_early = _second_tile(
-        None if causal or coarse else t // block_q,
+        None if causal or coarse or relation else t // block_q,
         _fused_bwd_resident(t, d, block_k, q.dtype.itemsize, group, dv,
                             tk),
         block_q, block_k, q.dtype.itemsize, limit, heads)
@@ -786,7 +852,7 @@ def _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3, glse3, h,
         block_q=block_q, block_k=block_k, tiles=tiles,
         dp_early=dp_early, has_bias=has_bias, has_glse=has_glse,
         rate=rate, window=window, group=group, coarse=coarse,
-        heads=heads)
+        heads=heads, relation=relation)
 
     def head(*ids):     # the query head of a grid step
         return ids[0] if group == 1 else ids[0] * group + ids[1]
@@ -1002,22 +1068,25 @@ def _window_blocks(blocks, window, coarse=None, tk=0):
 
 
 def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
-               interpret, rate=0.0, window=0, coarse=None, heads=1):
+               interpret, rate=0.0, window=0, coarse=None, heads=1,
+               relation=None):
     """q: [BH, T, D], k: [B*Hkv, Tk, D], v: [B*Hkv, Tk, Dv] (query
     head i reads K/V head i // (H / Hkv); Tk = T but under a coarse
     mask), bias: [B, Tk] or None, seed: packed (1,4) uint32 [seed,
     q_off, k_off, g_off] (_pack_seed, required when rate>0) ->
     (o [BH,T,Dv], lse [BH,T]).  With ``heads`` = 2 (_heads_a_step)
     q, k, v and o are [B, T, H*64] instead, as the op holds them."""
-    _, t, d, dv = _step_shape(q, v, h, heads)
+    steps, t, d, dv = _step_shape(q, v, h, heads)
+    blocks = _window_blocks(
+        _block_sizes(t, block_q, block_k, d, q.dtype.itemsize, dv,
+                     k.shape[1]),
+        window, coarse, k.shape[1])
+    if relation:
+        _count_tiles(steps, t, k.shape[1], relation, blocks)
     return _fwd_call(
-        q, k, v, bias, seed, h=h, causal=causal,
-        blocks=_window_blocks(
-            _block_sizes(t, block_q, block_k, d, q.dtype.itemsize,
-                         dv, k.shape[1]),
-            window, coarse, k.shape[1]),
+        q, k, v, bias, seed, h=h, causal=causal, blocks=blocks,
         interpret=interpret, rate=rate, window=window, coarse=coarse,
-        heads=heads)
+        heads=heads, relation=relation)
 
 
 # The calls are jitted on their static arguments: the layers of a model
@@ -1030,9 +1099,9 @@ def _flash_fwd(q, k, v, bias, seed, h, causal, block_q, block_k,
 # fluid op's type), which is how a device trace is read.
 @functools.partial(jax.jit, inline=True, static_argnames=(
     'h', 'causal', 'blocks', 'interpret', 'rate', 'window', 'coarse',
-    'heads'))
+    'heads', 'relation'))
 def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
-              rate, window=0, coarse=None, heads=1):
+              rate, window=0, coarse=None, heads=1, relation=None):
     steps, t, d, dv = _step_shape(q, v, h, heads)
     tk = k.shape[1]
     group = 1 if heads > 1 else steps // k.shape[0]
@@ -1049,12 +1118,12 @@ def _fwd_call(q, k, v, bias, seed, *, h, causal, blocks, interpret,
     params = _vmem_limit(limit) if heads > 1 else _mosaic_params(
         tk, d, block_q, block_k, q.dtype.itemsize, dv)
     tiles, _ = _second_tile(
-        None if causal or coarse else tk // block_k, resident, block_q,
-        block_k, q.dtype.itemsize, limit, heads)
+        None if causal or coarse or relation else tk // block_k,
+        resident, block_q, block_k, q.dtype.itemsize, limit, heads)
     kernel = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal, block_k=block_k,
         tiles=tiles, has_bias=has_bias, rate=rate, window=window,
-        coarse=coarse, heads=heads)
+        coarse=coarse, heads=heads, relation=relation)
     grid = (steps, t // block_q)
     if heads > 1:       # a pair's lanes of the op's own layout
         q_rows = o_rows = _pair_rows(block_q, h // heads, tiled=True)
@@ -1115,7 +1184,7 @@ def _backward_plan(t, tk, d, dv, itemsize, group, has_bias, has_glse,
 
 def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
                block_q, block_k, interpret, rate=0.0, window=0,
-               coarse=None, heads=1):
+               coarse=None, heads=1, relation=None):
     steps, t, d, dv = _step_shape(q, v, h, heads)
     fused, limit, blocks = _backward_plan(
         t, k.shape[1], d, dv, q.dtype.itemsize,
@@ -1124,18 +1193,22 @@ def _flash_bwd(q, k, v, bias, seed, o, lse, do, g_lse, h, causal,
     from ...fluid import monitor
     monitor.add('pallas/flash_attention/backward_%s'
                 % ('one_pass' if fused else 'two_pass'), 1)
+    if relation:    # one pass over the tiles, or the dq and the dkv call's
+        _count_tiles(steps, t, k.shape[1], relation, blocks,
+                     passes=1 if fused else 2)
     return _bwd_call(
         q, k, v, bias, seed, o, lse, do, g_lse, h=h, causal=causal,
         blocks=blocks, fused=fused, limit=limit, interpret=interpret,
-        rate=rate, window=window, coarse=coarse, heads=heads)
+        rate=rate, window=window, coarse=coarse, heads=heads,
+        relation=relation)
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
     'h', 'causal', 'blocks', 'fused', 'limit', 'interpret', 'rate',
-    'window', 'coarse', 'heads'))
+    'window', 'coarse', 'heads', 'relation'))
 def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
               blocks, fused, interpret, rate, window=0, coarse=None,
-              limit=None, heads=1):
+              limit=None, heads=1, relation=None):
     block_q, block_k = blocks
     has_bias = bias is not None
     has_glse = g_lse is not None
@@ -1160,7 +1233,7 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
             do, lse.reshape(-1, heads, t), delta.reshape(-1, heads, t),
             g_lse.astype(jnp.float32).reshape(-1, heads, t)
             if has_glse else None, h, causal, block_q, block_k,
-            interpret, rate, window, coarse, limit, heads)
+            interpret, rate, window, coarse, limit, heads, relation)
     bh, t, d = q.shape
     tk, dv = k.shape[1], v.shape[2]
     group = bh // k.shape[0]
@@ -1177,18 +1250,21 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
     if fused:
         return _flash_bwd_fused(q, k, v, bias, seed2, do, lse3, delta3,
                                 glse3, h, causal, block_q, block_k,
-                                interpret, rate, window, coarse, limit)
+                                interpret, rate, window, coarse, limit,
+                                relation=relation)
 
     # the dq call keeps a head's K and V rows resident (tk long), the
     # dkv call its Q and dO rows (t long)
     resident = _rows_resident(tk, d, block_q, block_k, q.dtype.itemsize,
                               dv)
-    tiles, _ = _second_tile(None if causal or coarse else tk // block_k,
-                            resident, block_q, block_k, q.dtype.itemsize)
+    tiles, _ = _second_tile(
+        None if causal or coarse or relation else tk // block_k,
+        resident, block_q, block_k, q.dtype.itemsize)
     dq_kernel = functools.partial(
         _flash_bwd_dq_kernel, scale=scale, causal=causal,
         block_k=block_k, tiles=tiles, has_bias=has_bias,
-        has_glse=has_glse, rate=rate, window=window, coarse=coarse)
+        has_glse=has_glse, rate=rate, window=window, coarse=coarse,
+        relation=relation)
     dq_specs = [
         pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         pl.BlockSpec((1, tk, d), lambda i, j: (i // group, 0, 0)),
@@ -1227,13 +1303,13 @@ def _bwd_call(q, k, v, bias, seed, o, lse, do, g_lse, *, h, causal,
         resident = _rows_resident(t, d, block_q, block_k,
                                   q.dtype.itemsize, dv)
     tiles, dp_early = _second_tile(
-        None if causal or coarse else t // block_q, resident, block_q,
-        block_k, q.dtype.itemsize)
+        None if causal or coarse or relation else t // block_q,
+        resident, block_q, block_k, q.dtype.itemsize)
     dkv_kernel = functools.partial(
         _flash_bwd_dkv_kernel, scale=scale, causal=causal,
         block_q=block_q, tiles=tiles, dp_early=dp_early,
         has_bias=has_bias, has_glse=has_glse, rate=rate, window=window,
-        group=group, coarse=coarse)
+        group=group, coarse=coarse, relation=relation)
 
     # grid (BH, T/bk), or (B*Hkv, T/bk, group): ids[0] is the K/V head
     def head(*ids):     # the query head of a grid step
@@ -1331,31 +1407,33 @@ def _flash_primitive(with_lse):
         return (o, lse) if with_lse else o
 
     def primitive(q, k, v, bias, seed, h, causal, rate, interpret,
-                  window=0, coarse=None, heads=1):
+                  window=0, coarse=None, heads=1, relation=None):
         return outputs(*_flash_fwd(
             q, k, v, bias, seed, h, causal, DEFAULT_BLOCK_Q,
-            DEFAULT_BLOCK_K, interpret, rate, window, coarse, heads))
+            DEFAULT_BLOCK_K, interpret, rate, window, coarse, heads,
+            relation))
 
     # the name a jaxpr (and an instruction's metadata) shows
     primitive.__name__ = '_flash_lse' if with_lse else '_flash'
     primitive = jax.custom_vjp(primitive,
-                               nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+                               nondiff_argnums=(5, 6, 7, 8, 9, 10, 11,
+                                                12))
 
     def fwd_rule(q, k, v, bias, seed, h, causal, rate, interpret,
-                 window, coarse, heads):
+                 window, coarse, heads, relation):
         o, lse = _flash_fwd(q, k, v, bias, seed, h, causal,
                             DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret,
-                            rate, window, coarse, heads)
+                            rate, window, coarse, heads, relation)
         return outputs(o, lse), (q, k, v, bias, seed, o, lse)
 
-    def bwd_rule(h, causal, rate, interpret, window, coarse, heads, res,
-                 g):
+    def bwd_rule(h, causal, rate, interpret, window, coarse, heads,
+                 relation, res, g):
         q, k, v, bias, seed, o, lse = res
         g, g_lse = g if with_lse else (g, None)
         dq, dk, dv, dbias = _flash_bwd(
             q, k, v, bias, seed, o, lse, g, g_lse, h, causal,
             DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, interpret, rate, window,
-            coarse, heads)
+            coarse, heads, relation)
         return dq, dk, dv, (None if bias is None
                             else dbias.astype(bias.dtype)), None
 
@@ -1369,7 +1447,7 @@ _flash, _flash_lse = _flash_primitive(False), _flash_primitive(True)
 def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
                 dropout_seed=None, dropout_offsets=None,
                 dropout_g_offset=0, with_lse=False, window=0,
-                coarse=None):
+                coarse=None, relation=None):
     """Fused-by-XLA dense chain on [B, T, H, D] (bf16 dots, f32
     softmax) — the measured winner below FLASH_MIN_SEQ, where the
     whole chain fits VMEM outright.  Differentiable via XLA autodiff.
@@ -1381,8 +1459,8 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
     over their group here (the kernels read them through their index
     maps instead); ``window`` bands the causal mask, ``coarse`` is the
     third mask (_coarse_visible) over keys of another length than the
-    queries, and this arm, unlike the kernels, builds its [T, Tk]
-    scores.  v may be of another width than q and k: the scale is
+    queries, ``relation`` the fourth (_block_visible), and this arm,
+    unlike the kernels, builds its [T, Tk] scores.  v may be of another width than q and k: the scale is
     q's."""
     b, t, h, d = q.shape
     tk = k.shape[1]
@@ -1399,10 +1477,11 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
             mask = mask & ~jnp.tril(jnp.ones((t, t), bool), -window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
     lse = None
-    if coarse:
-        s = jnp.where(_coarse_visible(
-            jnp.arange(t)[:, None], jnp.arange(tk)[None, :], coarse),
-            s, -jnp.inf)
+    if coarse or relation:
+        rows, cols = jnp.arange(t)[:, None], jnp.arange(tk)[None, :]
+        s = jnp.where(_coarse_visible(rows, cols, coarse) if coarse
+                      else _block_visible(rows, cols, relation),
+                      s, -jnp.inf)
         # a query of the first window sees no key: p = 0 and lse =
         # -inf there, not softmax's 0 / 0 (the row's max moves
         # neither, so it carries no gradient)
@@ -1430,9 +1509,19 @@ def _dense_path(q, k, v, causal, key_bias, dropout_rate=0.0,
     return o
 
 
-def _check_mask_and_heads(q, k, v, causal, window, coarse=None):
-    """The argument checks every entry shares -> (window, coarse) as
-    the kernels' static arguments."""
+BLOCK_RELATIONS = ('causal', 'strict')
+
+
+def block_relation(block, kind):
+    """``block_mask`` = (block, kind) -> the kernels' static
+    ``relation`` = (block, inclusive)."""
+    return int(block), int(kind == 'causal')
+
+
+def _check_mask_and_heads(q, k, v, causal, window, coarse=None,
+                          block_mask=None):
+    """The argument checks every entry shares -> (window, coarse,
+    relation) as the kernels' static arguments."""
     h, hkv = q.shape[2], k.shape[2]
     if k.shape[:3] != v.shape[:3] or k.shape[3] != q.shape[3] or \
             k.shape[0] != q.shape[0] or hkv < 1 or h % hkv:
@@ -1445,12 +1534,28 @@ def _check_mask_and_heads(q, k, v, causal, window, coarse=None):
         raise ValueError('attention: a window (%r) bands the causal '
                          'mask; causal is False' % (window,))
     t, tk = q.shape[1], k.shape[1]
+    if block_mask:
+        block, kind = int(block_mask[0]), block_mask[1]
+        if causal or window or coarse:
+            raise ValueError('attention: the block mask is a mask of '
+                             'its own; causal, window and coarse must '
+                             'be off')
+        if block < 1 or kind not in BLOCK_RELATIONS:
+            raise ValueError(
+                'attention: block_mask=(block, kind) wants a block of '
+                '1 or more positions and a kind of %r; got %r'
+                % (BLOCK_RELATIONS, tuple(block_mask)))
+        if t != tk:
+            raise ValueError(
+                'attention: %d queries over %d keys under the %r block '
+                'relation, which wants as many of each' % (t, tk, kind))
+        return 0, None, block_relation(block, kind)
     if not coarse:
         if tk != t:
             raise ValueError(
                 'attention: %d keys for %d queries; keys of another '
                 'length than the queries need the coarse mask' % (tk, t))
-        return int(window or 0), None
+        return int(window or 0), None, None
     span, chunk = (int(n) for n in coarse)
     if causal or window:
         raise ValueError('attention: the coarse mask is a mask of its '
@@ -1463,20 +1568,22 @@ def _check_mask_and_heads(q, k, v, causal, window, coarse=None):
         raise ValueError(
             'attention: %d summaries of %d-key chunks do not cover %d '
             'queries' % (tk, chunk, t))
-    return 0, (span, span // chunk)
+    return 0, (span, span // chunk), None
 
 
-def _heads_a_step(q, k, v, has_bias, with_lse, window, coarse):
+def _heads_a_step(q, k, v, has_bias, with_lse, window, coarse,
+                  relation=None):
     """2 where the kernels address the op's own [B, T, H*64] operands,
     a pair of heads a grid step (no transposed copy goes in or comes
     out): heads 64 wide, values too, as many K/V heads as query heads,
-    an even number of them, no band and no coarse mask, and a backward
+    an even number of them, no band, coarse or block mask, and a backward
     that is one pass by its VMEM count (a call the count refuses, or
     FUSED_BWD off, takes the [B*H, T, D] path whole).  1 everywhere
     else.  The shape decides; there is nothing to set."""
     _, t, h, d = q.shape
     if not (d == v.shape[3] == PAIRED_HEAD_DIM and k.shape[2] == h
-            and h % 2 == 0 and not window and not coarse):
+            and h % 2 == 0 and not window and not coarse
+            and not relation):
         return 1
     one_pass, _, _ = _backward_plan(
         t, t, 2 * d, 2 * d, q.dtype.itemsize, 1, has_bias, with_lse,
@@ -1494,7 +1601,7 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
                     min_seq=None, dropout_rate=0.0, dropout_seed=None,
                     dropout_offsets=None, dropout_g_offset=0,
                     auto_partitioned=False, window=0, with_lse=False,
-                    coarse=None):
+                    coarse=None, block_mask=None):
     """q: [B, T, H, D]; k: [B, T, Hkv, D], v: [B, T, Hkv, Dv] with Hkv
     a divisor of H (grouped K/V: query head i attends K/V head
     i // (H / Hkv)) and Dv = D unless the values are narrower or wider
@@ -1502,7 +1609,7 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     key_bias: optional [B, T] additive score bias (e.g. padding mask
     as 0 / -10000) -> [B, T, H, Dv].
 
-    The three masks, and none by default (every query sees every key):
+    The four masks, and none by default (every query sees every key):
 
     - ``causal``: query i sees the keys j <= i; the kernels skip the
       blocks above the diagonal;
@@ -1515,7 +1622,13 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
       positions before its own, keys 0 .. (i // window) * (window /
       chunk) - 1.  A query of the first window sees none: its output
       is 0 and its lse -inf.  The kernels walk only the key blocks a
-      query block sees; no [T, Tk] tensor reaches HBM.
+      query block sees; no [T, Tk] tensor reaches HBM;
+    - ``block_mask`` = (block, kind), without ``causal``: a relation
+      between blocks of ``block`` positions.  Kind ``'causal'``: query
+      i sees the keys j with j // block <= i // block; ``'strict'``:
+      those with j // block < i // block, none in the first block
+      (output 0, lse -inf).  The kernels walk only the tiles that
+      hold a visible pair.
 
     ``with_lse`` also returns the per-row log-sum-exp [B, H, T]
     (float32), the merge state for blockwise composition:
@@ -1544,8 +1657,8 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     opens one).  Pass min_seq=0 to drop the floor (benchmark
     sweeps, a ring's blocks)."""
     b, t, h, d = q.shape
-    window, coarse = _check_mask_and_heads(q, k, v, causal, window,
-                                           coarse)
+    window, coarse, relation = _check_mask_and_heads(
+        q, k, v, causal, window, coarse, block_mask)
     rate = float(dropout_rate or 0.0)
     if rate and dropout_seed is None:
         raise ValueError('dropout_rate > 0 needs a dropout_seed')
@@ -1556,13 +1669,16 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
         return _dense_path(q, k, v, causal, key_bias, rate,
                            dropout_seed, dropout_offsets,
                            dropout_g_offset, with_lse=with_lse,
-                           window=window, coarse=coarse)
+                           window=window, coarse=coarse,
+                           relation=relation)
 
     heads = _heads_a_step(q, k, v, key_bias is not None, with_lse,
-                          window, coarse)
+                          window, coarse, relation)
     from ...fluid import monitor
     monitor.add('pallas/flash_attention/layout_%s'
                 % ('paired' if heads > 1 else 'transposed'), 1)
+    if relation:
+        monitor.add('pallas/flash_attention/mask_block', 1)
 
     if heads > 1:       # views of the op's own layout: no copy
         def to_bh(x):
@@ -1586,19 +1702,23 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     if not with_lse:
         return to_bthd(_flash(to_bh(q), to_bh(k), to_bh(v), key_bias,
                               seed, h, causal, rate, interpret, window,
-                              coarse, heads))
+                              coarse, heads, relation))
     o, lse = _flash_lse(to_bh(q), to_bh(k), to_bh(v), key_bias, seed, h,
-                        causal, rate, interpret, window, coarse, heads)
+                        causal, rate, interpret, window, coarse, heads,
+                        relation)
     lse = lse.reshape(b, h, t)
-    if coarse:      # the rows that saw no key: the dense arm's -inf
+    # the rows that saw no key: the dense arm's -inf
+    if coarse:
         lse = jnp.where(jnp.arange(t) >= coarse[0], lse, -jnp.inf)
+    elif relation and not relation[1]:      # the strict rows' first block
+        lse = jnp.where(jnp.arange(t) >= relation[0], lse, -jnp.inf)
     return to_bthd(o), lse
 
 
 def mesh_flash_attention(q, k, v, auto_partitioned, scope, causal=False,
                          key_bias=None, dropout_rate=0.0,
                          dropout_seed=None, window=0, with_lse=False,
-                         coarse=None):
+                         coarse=None, block_mask=None):
     """flash_attention() as an op lowering calls it, with
     ``ctx.auto_partitioned``: the one place a flash call is wrapped
     for the GSPMD runner (fused_multihead_attention, ring_attention's
@@ -1641,7 +1761,7 @@ def mesh_flash_attention(q, k, v, auto_partitioned, scope, causal=False,
             q, k, v, causal=causal, key_bias=key_bias,
             dropout_rate=dropout_rate, dropout_seed=dropout_seed,
             auto_partitioned=auto_partitioned, window=window,
-            with_lse=with_lse, coarse=coarse)
+            with_lse=with_lse, coarse=coarse, block_mask=block_mask)
     from jax.sharding import PartitionSpec as P
     from ...compat import shard_map
     from ...fluid import monitor, trace
@@ -1651,14 +1771,14 @@ def mesh_flash_attention(q, k, v, auto_partitioned, scope, causal=False,
                  if mesh.shape[a] > 1)
     shards = math.prod(mesh.shape[a] for a in axes)
     if not axes or q.shape[0] % shards:
-        window, coarse = _check_mask_and_heads(q, k, v, causal, window,
-                                               coarse)
+        window, coarse, relation = _check_mask_and_heads(
+            q, k, v, causal, window, coarse, block_mask)
         _common.record_dispatch('flash_attention', False,
                                 'batch_not_split')
         return _dense_path(q, k, v, causal, key_bias,
                            float(dropout_rate or 0.0), dropout_seed,
                            with_lse=with_lse, window=window,
-                           coarse=coarse)
+                           coarse=coarse, relation=relation)
     split = P(axes if len(axes) > 1 else axes[0])
     operands = [(x, spec) for x, spec in (
         (q, split), (k, split), (v, split), (key_bias, split),
@@ -1674,7 +1794,7 @@ def mesh_flash_attention(q, k, v, auto_partitioned, scope, causal=False,
                 key_bias=rest.pop() if rest else None,
                 dropout_rate=dropout_rate, dropout_seed=seed_,
                 dropout_g_offset=first, window=window,
-                with_lse=with_lse, coarse=coarse)
+                with_lse=with_lse, coarse=coarse, block_mask=block_mask)
 
     with trace.span('pallas/flash_attention/shard_map',
                     axes=','.join(axes), shards=shards):

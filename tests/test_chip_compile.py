@@ -361,6 +361,57 @@ def test_grouped_causal_flash_d64_at_the_lfm2_cell_shape(one_chip, as_on_tpu,
     assert common.scoped_vmem(2048, d, 512, 1024, item) is None
 
 
+@pytest.mark.parametrize('kind', flash_attention.BLOCK_RELATIONS)
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32],
+                         ids=['bf16', 'f32'])
+def test_block_mask_flash_at_the_sdar_cell_shape(one_chip, as_on_tpu,
+                                                 dtype, kind):
+    """sdar_30b_a3b_s4096: b1, 4096 keys, 32 query heads over 4 K/V
+    heads of 128, blocks of 4, through the op (so the calls carry its
+    ``block4_<relation>`` scope), WITH the log-sum-exp and a cotangent
+    on it as ``layers.block_diffusion_attention`` merges the strict
+    part: 'causal' (clean over clean) and 'strict' (corrupted over
+    clean) at 4096 queries, the two the cell runs.
+    bfloat16 is the timed step, float32 ``chip_smoke.py --phase sdar``
+    and the cell's reference check.  The backward is the one-pass
+    kernel in both, its dk and dv summed over a group of 8 in VMEM; no
+    [T, Tk] tensor is in the program."""
+    import re
+    from paddle_tpu.ops import fused_ops
+    b, t, h, hkv, d, block = 1, 4096, 32, 4, 128, 4
+    tk = t
+    assert _one_pass(t, d, dtype, tk=tk, lse=True, group=h // hkv)[0]
+    registry.begin_trace()      # the sums below start at this program
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            with jax.named_scope('fused_multihead_attention'):
+                out = fused_ops.fused_multihead_attention(
+                    registry.LowerCtx(0),
+                    {'Q': [q], 'K': [k], 'V': [v]},
+                    {'block_mask': block, 'block_relation': kind,
+                     'with_lse': True})
+            lse = out['Lse'][0]
+            return jnp.sum(out['Out'][0].astype(jnp.float32)) + \
+                jnp.sum(jnp.where(jnp.isfinite(lse), lse, 0.0))
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    text = _compiled(step, one_chip, _spec((b, t, h, d), dtype),
+                     _spec((b, tk, hkv, d), dtype),
+                     _spec((b, tk, hkv, d), dtype)).as_text()
+    _compiled_on_chip('flash_attention')
+    names = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert len(names) == 2, names       # forward + one-pass backward
+    assert all(n.startswith('block4_' + kind) for n in names), names
+    assert not re.search(r'\[(\d+,)*%d,%d\]' % (t, tk), text)
+    # the pairs the mask lets through, a head: the strict or the
+    # block-causal half of the square
+    assert monitor.gauge_value('sdar/visible_pairs') == {
+        'causal': tk * (tk + block) // 2,
+        'strict': tk * (tk - block) // 2}[kind]
+
+
 @pytest.mark.parametrize('dtype,t', [
     (jnp.bfloat16, 4096), (jnp.float32, 4096), (jnp.bfloat16, 32768)],
     ids=['bf16-cell', 'f32-cell', 'bf16-32768'])
