@@ -2805,6 +2805,10 @@ def _sdar_train_step(seq, seed):
             'pallas/flash_attention/mask_block') >= 3,
             'the block-mask calls (32 query heads over 4 K/V heads of '
             '128, float32) ran the flash kernels')
+        check(monitor.counter_value(
+            'pallas/flash_attention/dispatch_small_keys') >= cfg.layers,
+            'the corrupted copy\'s own blocks (4 x 4, folded into the '
+            'batch, float32) ran the small-keys kernels')
         grads = [np.asarray(x) for x in got[1:]]
         del got
         for name in scope.local_var_names():

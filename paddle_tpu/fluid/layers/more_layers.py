@@ -302,8 +302,10 @@ def flash_attention(q, k, v, causal=False, window=0, coarse=None,
     """The ``fused_multihead_attention`` op on heads already split: q
     [B, T, H, D], k [B, Tk, Hkv, D], v [B, Tk, Hkv, Dv] -> [B, T, H,
     Dv] (the flash kernels on a chip from
-    ``flash_attention.FLASH_MIN_SEQ`` queries up, the op's dense chain
-    under it and off a chip).  Scores are scaled by 1 / sqrt(D); a
+    ``flash_attention.FLASH_MIN_SEQ`` queries up, the small-keys
+    kernels for an unmasked call of ``flash_attention.SMALL_KEYS``
+    keys or fewer, the op's dense chain between the two and off a
+    chip).  Scores are scaled by 1 / sqrt(D); a
     model whose softmax scale is another (YaRN's ``mscale`` squared,
     ``models/moonlight.py`` ``softmax_scale``) multiplies q by the
     ratio before the call.  ``causal``, ``window``, ``coarse`` =
@@ -466,8 +468,11 @@ def block_diffusion_attention(q, k, v, block, name=None):
     mask); ``attention_merge`` of the last two by their log-sum-exps.
     On a chip the first two run the flash kernels from
     ``flash_attention.FLASH_MIN_SEQ`` queries up and no [L, L] tensor
-    reaches HBM, forward or backward.  L has to be a whole number of
-    blocks.  As they are lowered the block-mask calls add to
+    reaches HBM, forward or backward; the third runs the small-keys
+    kernels (``ops/pallas/small_keys.py``: a ``block`` of
+    ``flash_attention.SMALL_KEYS`` or less that divides 128, heads in
+    whole lanes, L a multiple of 128), the dense chain where its shape
+    fails their gates.  L has to be a whole number of blocks.  As they are lowered the block-mask calls add to
     ``sdar/visible_pairs`` and ``sdar/tiles_visited``."""
     t, block = int(k.shape[1]) // 2, int(block)
     if int(k.shape[1]) != 2 * t or t % block or \

@@ -22,7 +22,10 @@ def fused_multihead_attention(ctx, ins, attrs):
     -> Out [B, T, H, Dv] via
     mesh_flash_attention(): the Pallas kernels forward and backward on
     a TPU, the dense chain elsewhere (ops/pallas/common.py
-    dispatch()).  Under the GSPMD runner's mesh (with_data_parallel /
+    dispatch()); a call of ``flash_attention.SMALL_KEYS`` keys or
+    fewer with no mask, bias or dropout runs the small-keys kernels
+    there instead (ops/pallas/small_keys.py; counter
+    ``pallas/flash_attention/dispatch_small_keys``).  Under the GSPMD runner's mesh (with_data_parallel /
     with_mesh) the kernels run inside a shard_map on each device's
     share of the batch, split over the axes the runner split the
     batch over; heads are not split, a mesh's further axes see the

@@ -387,14 +387,17 @@ def report():
             # of the fused ones: lowered inside a shard_map over the
             # GSPMD runner's batch axes
             ent['dispatch_sharded'] = sharded
-        # which backward a kernel with two of them lowered (flash
+        # of the fused ones, those a second set of kernels took (flash
+        # attention: a handful of keys, small_keys.py); which backward
+        # a kernel with two of them lowered (flash
         # attention: one pass over a head's rows, or dq then dkv), which
         # layout its kernels address (flash attention: a pair of
         # 64-wide heads in the op's own [B, T, H x 64], or one head of
         # a transposed [B x H, T, D] copy), and the most scoped VMEM
         # any of its calls asked Mosaic for
-        for key in ('backward_one_pass', 'backward_two_pass',
-                    'layout_paired', 'layout_transposed'):
+        for key in ('dispatch_small_keys', 'backward_one_pass',
+                    'backward_two_pass', 'layout_paired',
+                    'layout_transposed'):
             n = counter('pallas/%s/%s' % (name, key)) or 0
             if n:
                 ent[key] = n

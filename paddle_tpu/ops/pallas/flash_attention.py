@@ -66,6 +66,11 @@ or out, the residuals the op's inputs and output), where the heads are
 of [B*H, T, D] copies.  Counters ``pallas/flash_attention/layout_paired``
 / ``layout_transposed``, one a lowering.
 
+A call of a handful of keys (SMALL_KEYS or fewer, no mask, bias or
+dropout: block diffusion's own blocks folded into the batch) is
+neither these kernels' nor the dense chain's shape: small_keys.py's
+two calls take it, by the shape alone.
+
 flash_attention() is the one public entry; ``with_lse`` makes the
 rows' log-sum-exp a second, differentiable output (its cotangent
 folds into dS inside the backward kernels), by which partial results
@@ -97,6 +102,17 @@ DEFAULT_BLOCK_K = 1024
 # chain (a pre-round reading, not re-measured on this code: ROADMAP
 # S3 / S4 ask for the crossover at s128-s256 again).
 FLASH_MIN_SEQ = 512
+
+# A call whose WHOLE key length is this or less, with no mask, key bias
+# or dropout, goes to small_keys.py's two calls (block diffusion's own
+# blocks: 4 queries x 4 keys, the blocks folded into the batch).  Their
+# cost does not move with the length (a grid step is 128 key rows
+# whatever their blocks; forward + backward 0.46 ms at 4096 rows of 32
+# heads over 4 of 128, at 4 keys and at 16) while the dense chain's
+# falls as the blocks grow, 1.68 ms at 4 keys and 0.99 at 16 (my chip
+# run, PR 64; PERF.md section 6); 16 covers block diffusion's published
+# block lengths.  Not measured beyond.
+SMALL_KEYS = 16
 
 # platform probe / VMEM model / block clamp live in common.py now
 # (shared by the whole kernel library); the module-level aliases keep
@@ -1647,7 +1663,12 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     whole-program vjp see the same network.  dropout_seed must be a
     uint32 scalar (fold the op seed with the step).
 
-    Auto-dispatch through common.dispatch(): sequences shorter than
+    Auto-dispatch through common.dispatch(): a call of SMALL_KEYS
+    keys or fewer under no mask, key bias or dropout runs the
+    small-keys kernels (small_keys.py; one call forward, one backward,
+    no [T, T] scores in HBM) where its shape passes their gates and
+    the platform's, counted in ``dispatch_small_keys`` beside
+    ``dispatch_fused``; other sequences shorter than
     `min_seq` (default FLASH_MIN_SEQ, the measured crossover) run the
     dense XLA chain, and so does every call off a TPU unless
     FLAGS_pallas_force asks for the interpreter (tests), and every
@@ -1662,6 +1683,19 @@ def flash_attention(q, k, v, causal=False, key_bias=None,
     rate = float(dropout_rate or 0.0)
     if rate and dropout_seed is None:
         raise ValueError('dropout_rate > 0 needs a dropout_seed')
+    if k.shape[1] <= SMALL_KEYS and key_bias is None and not (
+            causal or coarse or relation or rate):
+        from . import small_keys
+        fused, reason, interpret = _common.decide(
+            True, small_keys.checks(q, k, v),
+            auto_partitioned=auto_partitioned)
+        if fused:       # else: the dispatch as it stands
+            from ...fluid import monitor
+            _common.record_dispatch('flash_attention', True, reason,
+                                    interpret)
+            _common._LAST['flash_attention']['arm'] = 'small_keys'
+            monitor.add('pallas/flash_attention/dispatch_small_keys', 1)
+            return small_keys.attention(q, k, v, with_lse, interpret)
     fused, interpret = _common.dispatch(
         'flash_attention', True, checks=_checks(t, min_seq),
         auto_partitioned=auto_partitioned)

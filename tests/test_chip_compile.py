@@ -412,6 +412,72 @@ def test_block_mask_flash_at_the_sdar_cell_shape(one_chip, as_on_tpu,
         'strict': tk * (tk - block) // 2}[kind]
 
 
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32],
+                         ids=['bf16', 'f32'])
+def test_own_blocks_at_the_sdar_cell_shape(one_chip, as_on_tpu, dtype):
+    """sdar_30b_a3b_s4096's third attention part: the corrupted copy's
+    4096 positions over their OWN blocks of 4, folded into the batch
+    as ``layers.block_diffusion_attention`` folds them ([1024, 4, 32 |
+    4, 128]), through the op under its bare scope, WITH the log-sum-exp
+    and a cotangent on it.  The call takes the small-keys arm
+    (ops/pallas/small_keys.py), counted once a lowering: one Mosaic
+    call forward and one backward under the op's scope and under no
+    ``block<n>_`` one (``bd_flash_roofline`` times those), and no
+    ``dot`` or ``convolution`` of the dense chain beside them.  The
+    calls read HEADS-FIRST operands, [32 | 4, 4096, 128]: from this
+    test's [1, 4096, 32, 128] arguments the compiler transposes each
+    one; in the cell's step the producers write that layout and none
+    is re-laid (``tools/step_hlo_hash.py --memory``'s ``relaid``
+    lines, PERF.md section 6, PR 64).  bfloat16 is the timed step,
+    float32 the cell's reference check and ``chip_smoke.py --phase
+    sdar`` (a float32 backward is refused at Mosaic's default scoped
+    VMEM: the calls ask for what ``_vmem_count`` says)."""
+    import re
+    from paddle_tpu.ops import fused_ops
+    n, block, h, hkv, d = 1024, 4, 32, 4, 128
+    taken = monitor.counter_value(
+        'pallas/flash_attention/dispatch_small_keys') or 0
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            def fold(x):
+                return x.reshape(n, block, x.shape[2], d)
+            with jax.named_scope('fused_multihead_attention'):
+                out = fused_ops.fused_multihead_attention(
+                    registry.LowerCtx(0),
+                    {'Q': [fold(q)], 'K': [fold(k)], 'V': [fold(v)]},
+                    {'with_lse': True})
+            return jnp.sum(out['Out'][0].astype(jnp.float32)) + \
+                jnp.sum(jnp.square(out['Lse'][0]))
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    text = _compiled(step, one_chip, _spec((1, n * block, h, d), dtype),
+                     _spec((1, n * block, hkv, d), dtype),
+                     _spec((1, n * block, hkv, d), dtype)).as_text()
+    assert common._LAST['flash_attention'] == {
+        'path': 'fused', 'reason': 'tpu', 'interpret': False,
+        'arm': 'small_keys'}
+    assert monitor.counter_value(
+        'pallas/flash_attention/dispatch_small_keys') == taken + 1
+    calls = re.findall(
+        r'%(\S+) = \(([^\n]*?)\) custom-call\([^\n]*'
+        r'custom_call_target="tpu_custom_call"[^\n]*op_name="([^"]*)"',
+        text)
+    assert sorted(re.sub(r'\.\d+$', '', c[0]) for c in calls) == [
+        'small_keys_backward', 'small_keys_forward'], calls
+    # a device trace's scope table files both under the op
+    assert all(re.search(r'fused_multihead_attention\)+/small_keys_',
+                         scope) and not re.search(r'block\d+_', scope)
+               for _, _, scope in calls), calls
+    # heads first: o, dq [32, 4096, 128]; dk, dv [4, 4096, 128]
+    name = {jnp.bfloat16: 'bf16', jnp.float32: 'f32'}[dtype]
+    assert all(outs.count('%s[%d,%d,%d]' % (name, h, n * block, d)) == 1
+               for _, outs, _ in calls), calls
+    assert not re.search(r' (dot|convolution)\(', text)
+    assert not re.search(r'\[(\d+,)*%d,%d\]' % (block, block), text)
+    assert max(_scoped(text)) <= common.VMEM_LIMIT_CAP_BYTES
+
+
 @pytest.mark.parametrize('dtype,t', [
     (jnp.bfloat16, 4096), (jnp.float32, 4096), (jnp.bfloat16, 32768)],
     ids=['bf16-cell', 'f32-cell', 'bf16-32768'])

@@ -173,6 +173,177 @@ def test_block_mask_refuses_what_it_cannot_mean():
                            block_mask=(4, 'strict'))
 
 
+# --- a handful of keys -------------------------------------------------
+
+
+def _small(n, t, h, g, d, dv, dtype, seed=0):
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(n, t, h, d), dtype),
+            jnp.asarray(rng.randn(n, t, g, d), dtype),
+            jnp.asarray(rng.randn(n, t, g, dv), dtype),
+            jnp.asarray(rng.randn(n, t, h, dv), jnp.float32))
+
+
+def _taken(name):
+    return monitor.counter_value('pallas/flash_attention/' + name) or 0
+
+
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16],
+                         ids=['f32', 'bf16'])
+@pytest.mark.parametrize('t,h,g,d,dv', [
+    (1, 8, 1, 128, 128), (4, 32, 4, 128, 128), (16, 16, 2, 128, 128),
+    (4, 16, 2, 128, 256), (4, 16, 2, 256, 128), (8, 3, 3, 128, 128)])
+def test_small_keys_kernels_match_the_dense_chain(t, h, g, d, dv, dtype,
+                                                  pallas_interpret):
+    """The own-block call's arm (ops/pallas/small_keys.py, the bodies
+    under the interpreter) against the dense chain at the cell's
+    grouping (H query heads over H / 8 K/V heads): values, log-sum-exps
+    and the gradients of q, k, v through BOTH outputs; 1, 4 and 16 keys,
+    values wider and narrower than the keys once each, and three heads
+    ungrouped once."""
+    n = 2 * 128 // t
+    q, k, v, w = _small(n, t, h, g, d, dv, dtype, seed=t)
+
+    def value(f, q, k, v):
+        o, lse = f(q, k, v)
+        return jnp.sum(o.astype(jnp.float32) * w) + \
+            0.1 * jnp.sum(jnp.square(lse))
+
+    def ours(q, k, v):
+        return fa.flash_attention(q, k, v, with_lse=True)
+
+    def dense(q, k, v):
+        return fa._dense_path(q, k, v, False, None, with_lse=True)
+
+    before = _taken('dispatch_small_keys')
+    o, lse = ours(q, k, v)
+    assert _taken('dispatch_small_keys') == before + 1
+    assert fa._common._LAST['flash_attention'] == {
+        'path': 'fused', 'reason': 'forced_interpret', 'interpret': True,
+        'arm': 'small_keys'}
+    want_o, want_lse = dense(q, k, v)
+    assert o.dtype == dtype and lse.dtype == jnp.float32
+    assert lse.shape == (n, h, t)
+    # bfloat16: one last place of an output of a few units
+    tol = 2e-5 if dtype == jnp.float32 else 3.2e-2
+    assert np.abs(np.asarray(o, np.float32) -
+                  np.asarray(want_o, np.float32)).max() <= tol
+    assert np.abs(np.asarray(lse) - want_lse).max() <= 2e-5
+    got = jax.grad(functools.partial(value, ours), (0, 1, 2))(q, k, v)
+    want = jax.grad(functools.partial(value, dense), (0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, 'qkv'):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= \
+            (1e-4 if dtype == jnp.float32 else 2e-2) * np.abs(b).max(), name
+
+
+def test_small_keys_without_the_log_sum_exp(pallas_interpret):
+    """``with_lse`` off: the same output, and the gradient with no
+    cotangent on the statistics."""
+    q, k, v, w = _small(64, 4, 8, 1, 128, 128, jnp.float32)
+    o = fa.flash_attention(q, k, v)
+    want = fa._dense_path(q, k, v, False, None)
+    assert np.abs(np.asarray(o) - want).max() <= 2e-5
+    got = jax.grad(lambda *a: jnp.sum(fa.flash_attention(*a) * w),
+                   (0, 1, 2))(q, k, v)
+    dense = jax.grad(lambda *a: jnp.sum(
+        fa._dense_path(*a, False, None) * w), (0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, dense, 'qkv'):
+        assert np.abs(np.asarray(a) - b).max() <= \
+            1e-4 * np.abs(np.asarray(b)).max(), name
+
+
+@pytest.mark.parametrize('case', [
+    'keys_17', 'causal', 'key_bias', 'dropout', 'block_mask'])
+def test_what_the_small_keys_arm_leaves_alone(case, pallas_interpret):
+    """The shape and the masks decide: 16 unmasked keys take the arm;
+    one key more, a causal mask, a key bias, a dropout rate and a block
+    mask each go the way they went."""
+    t = 17 if case == 'keys_17' else 16
+    q, k, v, _ = _small(8, t, 8, 1, 128, 128, jnp.float32)
+    more = {'keys_17': {}, 'causal': dict(causal=True),
+            'key_bias': dict(key_bias=jnp.zeros((8, t))),
+            'dropout': dict(dropout_rate=0.1,
+                            dropout_seed=jnp.uint32(7)),
+            'block_mask': dict(block_mask=(4, 'causal'))}[case]
+    before = _taken('dispatch_small_keys')
+    fa.flash_attention(q, k, v, **more)
+    assert _taken('dispatch_small_keys') == before
+    assert 'arm' not in fa._common._LAST['flash_attention']
+    assert fa._common._LAST['flash_attention']['reason'] == 'below_floor'
+    fa.flash_attention(q[:, :16], k[:, :16], v[:, :16])
+    assert _taken('dispatch_small_keys') == before + 1
+
+
+@pytest.mark.parametrize('case', [
+    'off_tpu', 'narrow_heads', 'ragged_batch', 'three_keys', 'float16',
+    'auto_partitioned'])
+def test_small_keys_gates_leave_the_call_where_it_went(case, request):
+    """Off a TPU, heads off the 128 lanes, a batch that is no whole
+    grid step, a length that does not divide the lanes, float16 and
+    the GSPMD runner's trace: the dispatch as it stood, which answers
+    the dense chain below ``FLASH_MIN_SEQ``."""
+    if case != 'off_tpu':
+        request.getfixturevalue('pallas_interpret')
+    n, t, h, g, d, dtype = {
+        'narrow_heads': (32, 4, 8, 1, 64, jnp.float32),
+        'ragged_batch': (33, 4, 8, 1, 128, jnp.float32),
+        'three_keys': (128, 3, 8, 1, 128, jnp.float32),
+        'float16': (32, 4, 8, 1, 128, jnp.float16),
+    }.get(case, (32, 4, 8, 1, 128, jnp.float32))
+    q, k, v, _ = _small(n, t, h, g, d, d, dtype)
+    before = (_taken('dispatch_small_keys'),
+              _taken('fallback/below_floor'))
+    o, lse = fa.flash_attention(
+        q, k, v, with_lse=True,
+        auto_partitioned=case == 'auto_partitioned')
+    assert (_taken('dispatch_small_keys'),
+            _taken('fallback/below_floor')) == (before[0], before[1] + 1)
+    assert fa._common._LAST['flash_attention'] == {
+        'path': 'dense', 'reason': 'below_floor', 'interpret': False}
+    want_o, want_lse = fa._dense_path(q, k, v, False, None, with_lse=True)
+    assert np.array_equal(np.asarray(o), np.asarray(want_o))
+    assert np.array_equal(np.asarray(lse), np.asarray(want_lse))
+
+
+def test_block_diffusion_attention_runs_its_own_blocks_in_the_arm(
+        pallas_interpret, monkeypatch):
+    """The layer at the cell's grouping in small (8 query heads a K/V
+    head, heads of 128): the own-block call takes the small-keys arm
+    (one a layer's lowering, beside the two block-mask calls' kernels)
+    and the layer is still ONE softmax under the reference's mask,
+    values and all three gradients."""
+    monkeypatch.setattr(fa, 'FLASH_MIN_SEQ', 128)
+    length, block, heads, kv_heads, d = 128, 4, 8, 1, 128
+    rng = np.random.RandomState(3)
+    feed = {n: rng.randn(1, r, h, d).astype('float32')
+            for n, r, h in (('q', 2 * length, heads),
+                            ('w', 2 * length, heads),
+                            ('k', 2 * length, kv_heads),
+                            ('v', 2 * length, kv_heads))}
+    with fluid.scope_guard(fluid.Scope()):
+        main, startup, out, grads = _layer_program(
+            length, block, heads, kv_heads, d, 2 * length)
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        before = _taken('dispatch_small_keys'), _taken('dispatch_fused')
+        got = exe.run(main, feed=feed, fetch_list=[out] + grads)
+    # the arm's call beside the two block-mask calls' kernels
+    assert _taken('dispatch_small_keys') - before[0] == 1
+    assert _taken('dispatch_fused') - before[1] == 3
+    mask = reference.visible(length, block)
+
+    def dense(q, k, v):
+        return jnp.sum(_dense_masked(q, k, v, mask)[0] * feed['w'])
+
+    want = _dense_masked(feed['q'], feed['k'], feed['v'], mask)[0]
+    assert np.abs(got[0] - want).max() <= 2e-5
+    want_grads = jax.grad(dense, (0, 1, 2))(feed['q'], feed['k'],
+                                            feed['v'])
+    for g, wg, name in zip(got[1:], want_grads, 'qkv'):
+        assert np.abs(g - wg).max() <= 1e-4 * np.abs(wg).max(), name
+
+
 # --- the layer --------------------------------------------------------
 
 
