@@ -41,6 +41,10 @@ the chip: BERT-base pretraining through the normal entry points
                                     # diffusion's two copies under the
                                     # block-relation flash mask,
                                     # gradients, the cell's loss
+    python chip_smoke.py --phase nemotron_h  # Nemotron-3-Nano-30B-A3B's:
+                                    # the chunked ssd_scan in recompute
+                                    # groups, relu2 experts, GQA 32/2,
+                                    # gradients, the cell's loss
     python chip_smoke.py --phase grouped # the experts' grouped-matmul
                                     # kernels against ragged_dot at
                                     # the five routed cells' shapes
@@ -2859,6 +2863,271 @@ def phase_sdar(seq=SDAR_SEQ, seed=0):
     _sdar_cell_losses(seq, seed)
 
 
+# --- NVIDIA-Nemotron-3-Nano-30B-A3B -------------------------------------
+# Published widths (models.nemotron_h.BASE) as the benchmark cuts them
+# (experts 0-7 of 128 held, 16384 vocabulary rows).  Sampled gradients
+# of the f32 TRAIN program on ONE short sequence of a pattern with every
+# kind of layer (the chunked ``ssd_scan`` and its backward inside
+# recompute groups, the held relu2 experts' grouped products, GQA
+# 32-over-2 flash in float32) against jax.grad of the reference, whose
+# recurrence steps a token at a time in checkpointed blocks; then the
+# cell itself as the harness builds it (nine layers, 8192 tokens), its
+# f32 for_test loss over NEMOTRON_LOSS_BATCHES batches through the
+# family's OWN comparison (a number and a tolerance for each batch from
+# the reference's undecided choices: benchmark/families/nemotron_h.py),
+# with the reference in bfloat16 throughout put through the same: the
+# program inside every batch's limit, the control outside every one.
+# The filters are left out of the sample: jax.grad of a
+# reference's shifted sums as the v5e compiler builds it is not what
+# the host's compile gives (PERF.md section 6, PR 60); the CPU tests
+# hold them.
+NEMOTRON_GRAD_PATTERN = 'MEM*E'
+NEMOTRON_GRAD_SEQ = 1024
+NEMOTRON_TRAIN_LOSS_RTOL = 1e-6     # 1024 tokens, two routed layers:
+# 0 to 1.9e-7 read, the reference in bfloat16 throughout 4.3e-5
+NEMOTRON_MARGINS = (1e-7, 3e-7, 1e-6, 3e-6, 1e-5, 3e-5)
+NEMOTRON_L2_RTOL = 2e-3     # a gradient tensor's relative L2 distance
+NEMOTRON_LOSS_BATCHES = 12
+NEMOTRON_SAMPLED = (
+    'embedding', '0.norm_g', '0.mamba.w_in', '0.mamba.dt_bias',
+    '0.mamba.a_log', '0.mamba.d', '0.mamba.norm_g', '0.mamba.w_out',
+    '1.moe.router', '1.moe.up', '1.moe.down', '1.moe.shared_up',
+    '2.mamba.w_in', '2.mamba.a_log', '3.attention.wq', '3.attention.wk',
+    '3.attention.wv', '3.attention.wo', '4.moe.down', 'norm_f', 'head')
+
+
+def _nemotron_cut(pattern):
+    """The cell's cut at ``pattern``'s layers, the assumed numbers as
+    the cell's configuration file has them."""
+    import copy
+    from paddle_tpu.models import nemotron_h
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           'benchmark', 'configs',
+                           'nemotron-3-nano-30b-a3b.json')) as f:
+        assumed = json.load(f)['assumed']
+    cfg = copy.copy(nemotron_h.BASE)
+    cfg.pattern, cfg.kinds = pattern, nemotron_h.layer_kinds(pattern)
+    cfg.vocab_size, cfg.experts_held = 16384, (0, 8)
+    cfg.residual_layers = len(nemotron_h.PATTERN)
+    cfg.bias_init_std = assumed['bias_init_std']['value']
+    cfg.bias_update_rate = assumed['bias_update_rate']['value']
+    cfg.embed_std = assumed['embed_std']['value']
+    return cfg
+
+
+def _nemotron_sizes(cfg):
+    return dict(pattern=cfg.pattern, head_dim=cfg.head_dim,
+                top_k=cfg.top_k, first=cfg.experts_held[0],
+                routed_scale=cfg.routed_scale, eps=cfg.rms_eps,
+                renormalize=cfg.renormalize)
+
+
+def _nemotron_program(cfg, seq, seed, train):
+    """-> (main or its for_test clone, startup, loss, parameter names,
+    {param: grad name} or None)."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.models import nemotron_h
+    main, startup = fluid.Program(), fluid.Program()
+    startup.random_seed = 1 + seed
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        _, _, loss = nemotron_h.build_pretrain(cfg, seq)
+        params = [p.name for p in main.all_parameters()]
+        if not train:
+            return main.clone(for_test=True), startup, loss, params, None
+        pairs = dict((p.name, g.name) for p, g in
+                     fluid.optimizer.SGD(0.0).minimize(loss)[1])
+    return main, startup, loss, params, pairs
+
+
+def _nemotron_train_step(seq, seed):
+    """One f32 train step at NEMOTRON_GRAD_PATTERN against jax.grad of
+    the reference on sampled tensors."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import monitor
+    from paddle_tpu.models import nemotron_h
+    from paddle_tpu.models.reference import nemotron_h as reference
+    from paddle_tpu.ops.pallas import common
+    cfg = _nemotron_cut(NEMOTRON_GRAD_PATTERN)
+    sizes = _nemotron_sizes(cfg)
+    labels = [label for label, _ in nemotron_h.parameter_specs(cfg)]
+    sampled = {label: labels.index(label) for label in NEMOTRON_SAMPLED}
+    feed = _ints32(nemotron_h.synthetic_batch(
+        cfg, 1, seq, np.random.RandomState(seed)))
+    main, startup, loss, params, pairs = _nemotron_program(cfg, seq, seed,
+                                                           True)
+    count = sum(int(np.prod(main.global_block().var(p).shape))
+                for p in params)
+    say('nemotron_h: %d parameters in %d tensors at %s'
+        % (count, len(params), cfg.pattern))
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+        weights = [np.asarray(fluid.core.as_array(scope.find_var(p)))
+                   for p in params]
+        t0 = time.time()
+        got = exe.run(main, feed=feed, fetch_list=[loss] + [
+            pairs[params[i]] for i in sampled.values()])
+        got_loss = _scalar(got[:1])
+        say('nemotron_h f32 train program, 1 x %d tokens, %s: loss %.6f '
+            'in %.1f s; ssd/chunks %d, ssd/boundary_state_mb %.1f; '
+            'moe/held_share %.4f, moe/load_max_over_mean %.3f, '
+            'moe/dropped_tokens %d; executor/recompute_groups %d; '
+            'flash_attention last dispatch %s; peak memory %.2f GB'
+            % (seq, cfg.pattern, got_loss, time.time() - t0,
+               monitor.gauge_value('ssd/chunks'),
+               monitor.gauge_value('ssd/boundary_state_mb'),
+               monitor.gauge_value('moe/held_share'),
+               monitor.gauge_value('moe/load_max_over_mean'),
+               monitor.counter_value('moe/dropped_tokens'),
+               monitor.counter_value('executor/recompute_groups'),
+               common._LAST.get('flash_attention'),
+               _peak_bytes(jax.devices()[:1])[0] / 1e9))
+        check(common._LAST.get('flash_attention', {}).get('path') ==
+              'fused', '32 query heads over 2 K/V heads of 128, float32, '
+              'ran the flash kernels')
+        grads = [np.asarray(x) for x in got[1:]]
+        del got
+        for name in scope.local_var_names():
+            scope.erase(name)
+    weights = [jnp.asarray(x) for x in weights]
+    fed = {k: jnp.asarray(v) for k, v in feed.items()}
+
+    def ref_loss(some, full, dtype=jnp.float32):
+        full = list(full)
+        for label, w in some.items():
+            full[sampled[label]] = w
+        return reference.loss(full, fed, dtype=dtype, remat=True,
+                              block=64, **sizes)
+
+    t0 = time.time()
+    want_loss, want_grads = jax.jit(jax.value_and_grad(ref_loss))(
+        {label: weights[i] for label, i in sampled.items()}, weights)
+    want_loss = float(want_loss)
+    low = float(jax.jit(lambda w: ref_loss({}, w, jnp.bfloat16))(weights))
+    say('the reference\'s loss and %d gradients in %.1f s'
+        % (len(want_grads), time.time() - t0))
+    rel = abs(got_loss - want_loss) / want_loss
+    say('reference loss %.6f, program %.6f (relative difference %.2e); '
+        'reference in bfloat16 throughout %.6f (%.2e)'
+        % (want_loss, got_loss, rel, low,
+           abs(low - want_loss) / want_loss))
+    check(rel <= NEMOTRON_TRAIN_LOSS_RTOL, 'nemotron_h f32 train loss '
+          'within %g of the reference' % NEMOTRON_TRAIN_LOSS_RTOL)
+    check(abs(low - want_loss) / want_loss > NEMOTRON_TRAIN_LOSS_RTOL,
+          'the reference in bfloat16 throughout misses it')
+    far = 0.0
+    for label, x in zip(sampled, grads):
+        y = np.asarray(want_grads[label])
+        d = float(np.linalg.norm(x - y) / np.linalg.norm(y))
+        far = max(far, d)
+        say('gradient of %s %s: largest entry difference %.3e of the '
+            'largest entry (%.3e), relative L2 distance %.3e'
+            % (label, x.shape, np.abs(x - y).max() / np.abs(y).max(),
+               np.abs(y).max(), d))
+    check(far <= NEMOTRON_L2_RTOL,
+          'nemotron_h gradients: all %d sampled tensors within %g of the '
+          'reference\'s, relative L2 distance (worst %.3e)'
+          % (len(sampled), NEMOTRON_L2_RTOL, far))
+
+
+def _nemotron_cell():
+    """The benchmark's cell, as its harness finds it."""
+    from benchmark import run as harness
+    return harness.Cell(harness.load_json(os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), 'BENCHMARK.json')),
+        'nemotron3_nano_30b_s8192')
+
+
+def _nemotron_cell_losses(seed):
+    """The cell's programs as the harness builds them, on the startup
+    state, over NEMOTRON_LOSS_BATCHES batches: (a) the harness's OWN
+    reference check, each batch under the number and tolerance the
+    family's rule gives it; (b) what the rule reads at each of
+    NEMOTRON_MARGINS, beside where the program lies: the margin from
+    which the span holds the program's loss says how near a tie the
+    choice was that it took the other way; (c) the control, the
+    reference in bfloat16 throughout, put through the same comparison:
+    it has to be refused on EVERY batch."""
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu.fluid as fluid
+    from benchmark import run as harness
+    cell = _nemotron_cell()
+    config, traffic, family = cell.config, cell.traffic, cell.family
+    hosts = [family.batch(config, traffic, 1, s)
+             for s in range(seed, seed + NEMOTRON_LOSS_BATCHES)]
+    plain = jax.jit(lambda w, f, margin: family.reference_readings(
+        config, traffic, w, f, tie_margin=margin))
+    low_precision = jax.jit(lambda w, f: family.reference_readings(
+        config, traffic, w, f, dtype=jnp.bfloat16)[0])
+    with fluid.scope_guard(fluid.Scope()):
+        _, startup, test, loss, params = harness.build_programs(cell, seed)
+        exe = fluid.Executor(fluid.XLAPlace(0))
+        exe.run(startup)
+        scope = fluid.global_scope()
+
+        def current():      # a run hands the scope new arrays
+            return [fluid.core.as_array(scope.find_var(p))
+                    for p in params]
+
+        sound, control, limits, refused = [], [], [], 0
+        for i, host in enumerate(hosts):
+            check(harness.reference_check(cell, exe, test, loss, params,
+                                          host),
+                  'the harness\'s reference check, batch seed %d'
+                  % (seed + i))
+            feed = {k: jnp.asarray(v) for k, v in host.items()}
+            got = _scalar(exe.run(test, feed=host, fetch_list=[loss]))
+            half = float(low_precision(current(), feed))
+            spans = []
+            for margin in sorted({family.TIE_MARGIN, *NEMOTRON_MARGINS}):
+                want, low, high, undecided = (
+                    float(x) for x in plain(current(), feed,
+                                            jnp.float32(margin)))
+                spans.append('%g: %d, %+.2e %+.2e' % (
+                    margin, undecided, low / want, high / want))
+                if margin == family.TIE_MARGIN:
+                    middle, rtol = family.allowed(want, low, high)
+            middle = float(middle)
+            limits.append(rtol)
+            sound.append(abs(got - middle) / middle / rtol)
+            control.append(abs(half - middle) / middle / rtol)
+            refused += control[-1] > 1
+            say('batch seed %d: reference %.6f; program %+.2e of it, '
+                'reference in bfloat16 throughout %+.2e; the number '
+                'compared %+.2e, tolerance %.2e: program at %.2f of the '
+                'tolerance, bfloat16 at %.2f (%s); undecided choices and '
+                'the span by margin: %s'
+                % (seed + i, want, (got - want) / want,
+                   (half - want) / want, (middle - want) / want, rtol,
+                   sound[-1], control[-1],
+                   'NOT correct' if control[-1] > 1 else 'correct',
+                   '; '.join(spans)))
+        say('over %d batches: tolerance %.2e to %.2e; program at most '
+            '%.2f of its batch\'s tolerance; bfloat16 control at least '
+            '%.2f of its batch\'s, median %.1f, refused on %d'
+            % (len(hosts), min(limits), max(limits), max(sound),
+               min(control), np.median(control), refused))
+        check(max(sound) <= 1, 'nemotron_h f32 for_test loss at the '
+              'cell\'s cut within its batch\'s tolerance on every batch')
+        check(refused == len(hosts), 'the reference in bfloat16 '
+              'throughout is refused on EVERY batch, each under its own '
+              'batch\'s tolerance')
+        for name in scope.local_var_names():
+            scope.erase(name)
+
+
+def phase_nemotron_h(seed=0):
+    """models.nemotron_h.BASE cut as above: loss and sampled gradients
+    of the f32 TRAIN program against the reference's on one short
+    seeded sequence; then the cell's for_test losses at 8192 tokens."""
+    _nemotron_train_step(NEMOTRON_GRAD_SEQ, seed)
+    _nemotron_cell_losses(seed)
+
+
 # --- Ouro-2.6B ----------------------------------------------------------
 # Published widths (models.ouro.BASE), one 4096-token sequence, the
 # stack applied total_ut_steps = 4 times through ONE While.  Sampled
@@ -3739,10 +4008,11 @@ def main():
     ap.add_argument('--phase',
                     choices=('bert', 'olmoe', 'laguna', 'moonlight',
                              'lfm2', 'evabyte', 'solar', 'ouro', 'xing4',
-                             'phi4flash', 'kimi', 'sdar', 'grouped'),
+                             'phi4flash', 'kimi', 'sdar', 'nemotron_h',
+                             'grouped'),
                     default='bert',
                     help="'olmoe' / 'laguna' / 'moonlight' / 'lfm2' / "
-                    "'evabyte' / 'solar' / 'ouro' / 'xing4' / 'phi4flash' / 'kimi' / 'sdar': only that model's "
+                    "'evabyte' / 'solar' / 'ouro' / 'xing4' / 'phi4flash' / 'kimi' / 'sdar' / 'nemotron_h': only that model's "
                     "gradient check; 'grouped': only the grouped-matmul "
                     "kernels against ragged_dot")
     args = ap.parse_args()
@@ -3793,6 +4063,8 @@ def main():
             phase_kimi()
         elif args.phase == 'sdar':
             phase_sdar()
+        elif args.phase == 'nemotron_h':
+            phase_nemotron_h()
         elif args.phase == 'grouped':
             phase_grouped_matmul()
         elif args.chips == 4:

@@ -76,7 +76,7 @@ def _mark_amp_ops(program, amp_lists):
                     'kda_attention',
                     # float32 steps and decays beside bf16 x, B, C:
                     # cast neither way
-                    'selective_scan',
+                    'selective_scan', 'ssd_scan',
                     # float32 phi / alpha / bias and maps beside a
                     # bf16 stream: cast neither way
                     'hyper_connection_pre', 'hyper_connection_post',

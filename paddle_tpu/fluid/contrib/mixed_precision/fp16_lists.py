@@ -35,6 +35,10 @@ gray_list = {
     # bf16 x, B, C beside float32 steps, decays and skip; the state
     # and every sum float32 inside, the output in x's dtype
     'selective_scan',
+    # the same for Mamba-2's chunked form: bf16 x, B, C beside float32
+    # steps, decays and skip; the products' operands in x's dtype, the
+    # state and every sum float32
+    'ssd_scan',
     # a bf16 stream beside float32 maps: r, the projection, the three
     # maps and the Sinkhorn loop float32 inside, U and XOut in the
     # stream's and the operator's dtype

@@ -238,10 +238,33 @@ def selective_scan(x, delta, a, b, c, d, name=None):
     What comes before (the projections, the filter, the softplus) and
     after (the gate, W_out) is the model's.  Computed in chunks of 256
     tokens (``ops.ssm_ops.CHUNK``); T need be no whole number of
-    them."""
+    them.  Mamba-1's: a decay per channel AND state and 16 states a
+    channel, stepped a token at a time; ``ssd_scan`` is Mamba-2's, a
+    SCALAR decay a head and a [64, 128] state, in matrix products over
+    chunks."""
     return _simple('selective_scan',
                    {'X': x, 'Delta': delta, 'A': a, 'B': b, 'C': c, 'D': d},
                    dtype=x.dtype, name=name)
+
+
+def ssd_scan(x, delta, a, b, c, d, chunk=128, name=None):
+    """Mamba-2's recurrence in its state-space-dual form (the op
+    ``ssd_scan``, ``ops/ssd_ops.py``, has the equations): x [B, T, H,
+    P] (H heads of P channels), ``delta`` [B, T, H] the steps (> 0;
+    float32 under AMP), ``a`` [H] (< 0: ONE decay rate a head), ``b``
+    and ``c`` [B, T, G, N] the token's write and read vectors, one pair
+    a GROUP of H / G heads, ``d`` [H] the skip -> y [B, T, H, P] in x's
+    dtype, ``y_t = S_t c_t + d x_t`` of a state ``S_t = exp(a delta_t)
+    S_(t-1) + delta_t x_t b_t^T`` [P, N] a head that starts at zero in
+    every sequence.  What comes before (the projection, the filter, the
+    softplus) and after (the gated norm, W_out) is the model's.
+    Computed as matrix products over chunks of ``chunk`` tokens (the
+    model's ``chunk_size``) and one elementwise walk over the chunks'
+    states; T need be no whole number of them.  ``selective_scan`` is
+    Mamba-1's (a decay per channel and state, no matrix form)."""
+    return _simple('ssd_scan',
+                   {'X': x, 'Delta': delta, 'A': a, 'B': b, 'C': c, 'D': d},
+                   attrs={'chunk': int(chunk)}, dtype=x.dtype, name=name)
 
 
 def hyper_connection_pre(x, sinkhorn_iters=20, epsilon=1e-6, hc_eps=1e-6,

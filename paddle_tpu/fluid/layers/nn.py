@@ -234,17 +234,21 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
 
 
 def rms_norm(input, epsilon=1e-5, param_attr=None, name=None,
-             unit_offset=False):
+             unit_offset=False, gain_axes=1):
     """Root-mean-square norm over the last axis with a learned gain
     (initialised to 1) and no bias: the norm of the 2023+ decoder
     blocks.  Statistics in float32 whatever the input's dtype.
     ``unit_offset`` stores the gain as its offset from one (the
     parameter starts at 0 and the norm multiplies by 1 + it: Gemma's
     and EvaByte's ``norm_add_unit_offset``), so weight decay pulls
-    the gain to 1 and not to 0."""
+    the gain to 1 and not to 0.  ``gain_axes`` 2 makes the gain as wide
+    as the LAST TWO axes while the statistics stay over the last: a
+    GROUPED norm of an input reshaped to [..., groups, width], one gain
+    a channel (Mamba-2's gated norm)."""
     helper = LayerHelper('rms_norm', name=name)
     gain = helper.create_parameter(
-        param_attr, shape=[int(input.shape[-1])], dtype=input.dtype,
+        param_attr, shape=[int(n) for n in input.shape[-gain_axes:]],
+        dtype=input.dtype,
         default_initializer=Constant(0.0 if unit_offset else 1.0))
     out = helper.create_variable_for_type_inference(input.dtype)
     attrs = {'epsilon': epsilon}

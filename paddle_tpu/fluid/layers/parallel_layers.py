@@ -71,7 +71,8 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
         aux_weight=0.01, axis='ep', top_k=1, param_attr=None,
         name=None, renormalize=True, z_loss_weight=0.0,
         experts_held=None, gate_scale=1.0, score_func='softmax',
-        score_bias=None, bias_update_rate=0.0, renorm_eps=1e-20):
+        score_bias=None, bias_update_rate=0.0, renorm_eps=1e-20,
+        expert_form='gated'):
     """Mixture-of-Experts feed-forward layer, in two forms.
 
     **Capacity-based** (``capacity_factor`` a number, the default):
@@ -85,10 +86,14 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
     **Dropless** (``capacity_factor=None``): any ``top_k`` up to
     ``num_experts``, no token dropped, gates taken from the softmax
     over all experts and divided by their sum only under
-    ``renormalize`` (OLMoE: False); experts are gated,
+    ``renormalize`` (OLMoE: False).  The experts come in two forms
+    (``expert_form``): ``'gated'`` (the default),
     ``down(silu(gate x) * up x)`` with gate and up [E, D, hidden_size],
-    down [E, hidden_size, D].  Routing sorts the (token, expert) pairs
-    by expert and runs one grouped matmul per weight set
+    down [E, hidden_size, D]; ``'relu2'`` (Nemotron-H's), ``down(relu(up
+    x)^2)`` with up and down ONLY: no gate parameter is created and a
+    pass runs two grouped matmuls, not three.  Routing sorts the
+    (token, expert) pairs by expert and runs one grouped matmul per
+    weight set
     (``moe_route`` / ``moe_dispatch`` / ``moe_experts`` /
     ``moe_combine`` ops); it raises NotImplementedError under an
     ``axis`` mesh dimension.  ``gate_scale`` multiplies the gates (a
@@ -165,6 +170,14 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
     if bias_update_rate and not score_bias:
         raise ValueError('moe: bias_update_rate=%r moves a score_bias, '
                          'and there is none' % (bias_update_rate,))
+    from ...parallel.moe import expert_slots
+    expert_slots(expert_form)           # raises on a form it does not know
+    if expert_form != 'gated' and not dropless:
+        raise ValueError(
+            'moe: expert_form=%r needs the dropless path '
+            '(capacity_factor=None): the capacity-based experts are '
+            'relu(x W1) W2; got capacity_factor=%r'
+            % (expert_form, capacity_factor))
     if dropless and not 1 <= top_k <= e:
         raise ValueError('moe: dropless top_k must be in 1..num_experts '
                          '(%d), got %r' % (e, top_k))
@@ -196,7 +209,8 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
                              axis, renormalize, aux_weight,
                              z_loss_weight, experts_held,
                              float(gate_scale), score_func, score_bias,
-                             float(bias_update_rate), float(renorm_eps))
+                             float(bias_update_rate), float(renorm_eps),
+                             expert_form)
     w1, w2 = weight([e, d, h]), weight([e, h, d])
     out = helper.create_variable_for_type_inference(x.dtype)
     aux = helper.create_variable_for_type_inference('float32')
@@ -217,11 +231,15 @@ def moe(x, num_experts, hidden_size, capacity_factor=2.0,
 def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
                   renormalize, aux_weight, z_loss_weight, held=None,
                   gate_scale=1.0, score_func='softmax', score_bias=None,
-                  bias_update_rate=0.0, renorm_eps=1e-20):
+                  bias_update_rate=0.0, renorm_eps=1e-20,
+                  expert_form='gated'):
+    from ...parallel.moe import expert_slots
     d = int(x.shape[-1])
     here = e if held is None else held[1]      # experts with weights
-    w_gate, w_up, w_down = weight([here, d, h]), weight([here, d, h]), \
-        weight([here, h, d])
+    # 'gated': gate, up, down; 'relu2': up, down
+    slots = expert_slots(expert_form)
+    w_in = [weight([here, d, h]) for _ in slots]
+    w_down = weight([here, h, d])
 
     def var(dtype, stop_gradient=False):
         return helper.create_variable_for_type_inference(
@@ -279,11 +297,14 @@ def _dropless_moe(helper, x, wg, weight, scaled, e, h, top_k, axis,
                               'Inverse': inverse, 'Dropped': dropped},
                      attrs=held_attrs, infer_shape=False)
     expert_out = var(x.dtype)
-    helper.append_op('moe_experts',
-                     inputs={'Rows': rows, 'GroupSizes': sizes,
-                             'WGate': w_gate, 'WUp': w_up,
-                             'WDown': w_down},
-                     outputs={'Out': expert_out}, attrs=held_attrs,
+    expert_ins = {'Rows': rows, 'GroupSizes': sizes}
+    expert_ins.update(zip(slots, w_in))
+    expert_ins['WDown'] = w_down
+    expert_attrs = dict(held_attrs)
+    if expert_form != 'gated':      # the default leaves the op as it was
+        expert_attrs['expert_form'] = expert_form
+    helper.append_op('moe_experts', inputs=expert_ins,
+                     outputs={'Out': expert_out}, attrs=expert_attrs,
                      infer_shape=False)
     flat = var(x.dtype)
     combine_ins = {'Rows': expert_out, 'TopKWeight': gates,

@@ -31,6 +31,7 @@ from . import parallel_ops  # noqa: F401
 from . import kda_ops  # noqa: F401
 from . import hyper_connection_ops  # noqa: F401
 from . import ssm_ops  # noqa: F401
+from . import ssd_ops  # noqa: F401
 
 # host-sharded embedding (PS analog) host ops: registration lives with
 # the table implementation; import so distributed_lookup_table /

@@ -346,7 +346,10 @@ def moe_experts_op(ctx, ins, attrs):
     """Rows [M, D] grouped by expert, GroupSizes [E] int32, WGate /
     WUp [E, D, H], WDown [E, H, D] -> Out [M, D]:
     down(silu(gate x) * up x), one grouped matmul per weight set; in
-    bfloat16 under AMP (white-listed).  bfloat16 products on a TPU run
+    bfloat16 under AMP (white-listed).  With attrs['expert_form']
+    'relu2' there is no WGate and the expert is down(relu(up x)^2): two
+    grouped matmuls a pass (parallel.moe.EXPERT_FORMS).  bfloat16
+    products on a TPU run
     the kernels of ops/pallas/grouped_matmul.py (parallel.moe._operands
     has the gates); float32 ones, and every program under the GSPMD
     runner, ``lax.ragged_dot``, which the TPU compiler turns into
@@ -355,19 +358,23 @@ def moe_experts_op(ctx, ins, attrs):
     that holds a range of the experts
     (attrs['experts_held']) most of the buffer lies past the last
     group, and the grouped matmuls skip those rows: there the op is
-    parallel.moe.held_gated_mlp, whose SiLU product, its backward and
+    parallel.moe.held_expert_mlp, whose activation, its backward and
     the sum of Rows' two cotangents walk the chunks of the buffer that
     hold a held row, in place, so that what lies between the products
     follows sum(GroupSizes) as the products do (``moe/walked_share``),
-    and whose backward computes gate and up again instead of keeping
-    the [M, H] intermediates, whose cost is M, not the rows held."""
-    from ..parallel.moe import grouped_gated_mlp, held_gated_mlp
+    and whose backward computes the input products again instead of
+    keeping the [M, H] intermediates, whose cost is M, not the rows
+    held."""
+    from ..parallel.moe import (expert_slots, grouped_expert_mlp,
+                                held_expert_mlp)
     rows = ins['Rows'][0]
     low = bool(attrs.get('__amp__')) and \
         rows.dtype in (jnp.float32, jnp.bfloat16)
-    mlp = grouped_gated_mlp if _held(attrs) is None else held_gated_mlp
-    return {'Out': [mlp(rows, ins['GroupSizes'][0], ins['WGate'][0],
-                        ins['WUp'][0], ins['WDown'][0], low,
+    form = attrs.get('expert_form', 'gated')
+    w_in = tuple(ins[slot][0] for slot in expert_slots(form))
+    mlp = grouped_expert_mlp if _held(attrs) is None else held_expert_mlp
+    return {'Out': [mlp(rows, ins['GroupSizes'][0], w_in,
+                        ins['WDown'][0], form, low,
                         bool(ctx is not None and ctx.auto_partitioned))]}
 
 

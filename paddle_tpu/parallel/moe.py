@@ -570,14 +570,42 @@ class _GroupedMatmul:
         return self._run('weight_gradient', rows, cot)
 
 
-def _operands(rows, group_sizes, w_gate, w_up, w_down, low_precision,
+def _squared_relu(up, dtype):
+    """relu(up)^2, multiplied in float32, in ``dtype``."""
+    kept = jax.nn.relu(up.astype(jnp.float32))
+    return (kept * kept).astype(dtype)
+
+
+# an expert's FORM: what lies between its input products (one a weight
+# set, each [E, D, H]) and its down product, and the ``moe_experts``
+# op's slots of those sets.  'gated': down(silu(gate x) * up x);
+# 'relu2': down(relu(up x)^2), no gate
+EXPERT_FORMS = {'gated': (_gated, ('WGate', 'WUp')),
+                'relu2': (_squared_relu, ('WUp',))}
+
+
+def expert_slots(form):
+    """The ``moe_experts`` slots of the form's [E, D, H] weight sets."""
+    if form not in EXPERT_FORMS:
+        raise ValueError('moe: expert_form is one of %s, got %r'
+                         % (sorted(EXPERT_FORMS), form))
+    return EXPERT_FORMS[form][1]
+
+
+def _activation(form, dtype):
+    """(the input products) -> the hidden rows, in ``dtype``."""
+    return functools.partial(EXPERT_FORMS[form][0], dtype=dtype)
+
+
+def _operands(rows, group_sizes, weights, low_precision,
               auto_partitioned=False):
-    """What the grouped matmuls multiply and how -> (dot, rows, w_gate,
-    w_up, w_down): ``low_precision`` (AMP) casts everything to
-    bfloat16; otherwise float32 operands multiply at full precision.
-    ``dot(rows, w)`` is the grouped product (``dot.with_gradient`` where
-    ``jax.vjp`` is to differentiate it), ``dot.transposed(cot, w)`` and
-    ``dot.weight_gradient(rows, cot)`` its two transposes: the
+    """What the grouped matmuls multiply and how -> (dot, rows,
+    weights): ``low_precision`` (AMP) casts everything to bfloat16;
+    otherwise float32 operands multiply at full precision.
+    ``weights``: the expert's input sets [E, D, H], then down [E, H,
+    D].  ``dot(rows, w)`` is the grouped product (``dot.with_gradient``
+    where ``jax.vjp`` is to differentiate it), ``dot.transposed(cot,
+    w)`` and ``dot.weight_gradient(rows, cot)`` its two transposes: the
     kernels of ops/pallas/grouped_matmul.py where the operands are
     bfloat16 in whole tiles on a TPU (``common.dispatch``'s decision,
     counted a product: ``pallas/grouped_matmul/dispatch_*``), the
@@ -585,42 +613,64 @@ def _operands(rows, group_sizes, w_gate, w_up, w_down, low_precision,
     from ..ops.pallas import common, grouped_matmul
     if low_precision:
         rows = rows.astype(jnp.bfloat16)
-        w_gate, w_up, w_down = (w.astype(jnp.bfloat16)
-                                for w in (w_gate, w_up, w_down))
+        weights = tuple(w.astype(jnp.bfloat16) for w in weights)
         precision = None
     else:
         precision = jax.lax.Precision.HIGHEST \
             if rows.dtype == jnp.float32 else None
+    stream, width = weights[0].shape[1:]
+    lanes = -(-width // 128) * 128
     fused, reason, interpret = common.decide(
         True, grouped_matmul.checks(
-            rows.shape[0], w_gate.shape[1:],
-            [x.dtype for x in (rows, w_gate, w_up, w_down)]),
+            rows.shape[0], (stream, lanes),
+            [x.dtype for x in (rows,) + tuple(weights)]),
         auto_partitioned=auto_partitioned)
     if fused:
         dot = _GroupedMatmul(group_sizes, rows.shape[0], reason,
                              interpret)
+        if lanes != width:
+            # an expert width that fills no whole 128-lane tiles
+            # (Nemotron-H's 1856 = 14.5 of them): zero columns of the
+            # input sets and zero rows of down up to the next tile,
+            # which the activation keeps zero (``_unpadded`` for the
+            # gradients a custom backward forms)
+            none, fill = (0, 0), (0, lanes - width)
+            weights = tuple(jnp.pad(w, (none, none, fill))
+                            for w in weights[:-1]) + \
+                (jnp.pad(weights[-1], (none, fill, none)),)
     else:
         dot = _RaggedDot(group_sizes, precision, reason)
-    return dot, rows, w_gate, w_up, w_down
+    return dot, rows, tuple(weights)
 
 
-def grouped_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
-                      low_precision=False, auto_partitioned=False):
-    """down(silu(gate x) * up x) for rows grouped by expert.
+def _unpadded(grad, like):
+    """A weight's gradient as ``_operands``'s kernels gave it, cut back
+    to the weight's own width and cast to its dtype."""
+    if grad.shape != like.shape:
+        grad = grad[tuple(slice(0, n) for n in like.shape)]
+    return grad.astype(like.dtype)
 
-    rows [M, D] (group e is the next group_sizes[e] rows), w_gate and
-    w_up [E, D, H], w_down [E, H, D] -> [M, D].  One grouped matmul per
-    weight set (_operands: 2*M*D*H FLOPs whatever the grouping).
-    ``low_precision`` (AMP) multiplies in bfloat16 and keeps the [M, H]
-    intermediates in bfloat16; otherwise float32 operands multiply at
-    full precision.  ``auto_partitioned``: ``common.dispatch``'s (the
-    caller's word that XLA will partition this program over a mesh)."""
-    dot, rows, w_gate, w_up, w_down = _operands(
-        rows, group_sizes, w_gate, w_up, w_down, low_precision,
+
+def grouped_expert_mlp(rows, group_sizes, w_in, w_down, form='gated',
+                       low_precision=False, auto_partitioned=False):
+    """The experts' MLP for rows grouped by expert: 'gated'
+    down(silu(gate x) * up x), 'relu2' down(relu(up x)^2)
+    (``EXPERT_FORMS``).
+
+    rows [M, D] (group e is the next group_sizes[e] rows), ``w_in`` the
+    form's input sets, each [E, D, H], w_down [E, H, D] -> [M, D].  One
+    grouped matmul per weight set (_operands: 2*M*D*H FLOPs whatever
+    the grouping).  ``low_precision`` (AMP) multiplies in bfloat16 and
+    keeps the [M, H] intermediates in bfloat16; otherwise float32
+    operands multiply at full precision.  ``auto_partitioned``:
+    ``common.dispatch``'s (the caller's word that XLA will partition
+    this program over a mesh)."""
+    dot, rows, weights = _operands(
+        rows, group_sizes, tuple(w_in) + (w_down,), low_precision,
         auto_partitioned)
-    gate = dot.with_gradient(rows, w_gate)
-    up = dot.with_gradient(rows, w_up)
-    return dot.with_gradient(_gated(gate, up, rows.dtype), w_down)
+    projected = [dot.with_gradient(rows, w) for w in weights[:-1]]
+    return dot.with_gradient(
+        _activation(form, rows.dtype)(*projected), weights[-1])
 
 
 def _rewrite_held(held_rows, per_chunk, buffers, *read):
@@ -642,70 +692,75 @@ def _rewrite_held(held_rows, per_chunk, buffers, *read):
     return _walk_held(buffers[0].shape[0], held_rows, trip, buffers)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def held_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
-                   low_precision=False, auto_partitioned=False):
-    """grouped_gated_mlp for a layer that holds a range of its
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def held_expert_mlp(rows, group_sizes, w_in, w_down, form='gated',
+                    low_precision=False, auto_partitioned=False):
+    """grouped_expert_mlp for a layer that holds a range of its
     experts, whose groups fill the first ``sum(group_sizes)`` rows of a
     worst-case buffer (held_rows_bound): the same products, and
     everything between them walks the chunks of the buffer that hold a
-    held row (_rewrite_held): silu(gate) * up is written over
-    ``gate``.  Rows past those chunks stay as the grouped matmuls
-    leave them, unwritten on the chip: every consumer is a grouped
-    matmul that skips them again.
+    held row (_rewrite_held): the hidden rows are written over the
+    first input product's.  Rows past those chunks stay as the grouped
+    matmuls leave them, unwritten on the chip: every consumer is a
+    grouped matmul that skips them again.
 
-    Its own backward computes gate and up again and keeps no [M, H]
-    intermediate between the passes (the buffer's cost is its static
-    length): one loop turns (gate, up, dhidden) into (dgate, dup,
-    hidden), each over the buffer it came from, and a second adds the
-    up branch's cotangent of ``rows`` to the gate branch's."""
-    dot, rows, w_gate, w_up, w_down = _operands(
-        rows, group_sizes, w_gate, w_up, w_down, low_precision,
+    Its own backward computes the input products again and keeps no
+    [M, H] intermediate between the passes (the buffer's cost is its
+    static length): one loop turns (the input products, dhidden) into
+    (their cotangents, hidden), each over the buffer it came from, and
+    where the form has two input sets a second adds the up branch's
+    cotangent of ``rows`` to the gate branch's."""
+    dot, rows, weights = _operands(
+        rows, group_sizes, tuple(w_in) + (w_down,), low_precision,
         auto_partitioned)
+    held_rows = jnp.sum(group_sizes)
+    projected = tuple(dot(rows, w) for w in weights[:-1])
     hidden, = _rewrite_held(
-        jnp.sum(group_sizes),
-        lambda gate, up: (_gated(gate, up, rows.dtype),),
-        (dot(rows, w_gate),), dot(rows, w_up))
-    return dot(hidden, w_down)
+        held_rows,
+        lambda *chunks: (_activation(form, rows.dtype)(*chunks),),
+        projected[:1], *projected[1:])
+    return dot(hidden, weights[-1])
 
 
-def _held_fwd(rows, group_sizes, w_gate, w_up, w_down, low_precision,
+def _held_fwd(rows, group_sizes, w_in, w_down, form, low_precision,
               auto_partitioned):
-    return held_gated_mlp(rows, group_sizes, w_gate, w_up, w_down,
-                          low_precision, auto_partitioned), \
-        (rows, group_sizes, w_gate, w_up, w_down)
+    return held_expert_mlp(rows, group_sizes, w_in, w_down, form,
+                           low_precision, auto_partitioned), \
+        (rows, group_sizes, tuple(w_in), w_down)
 
 
-def _held_bwd(low_precision, auto_partitioned, res, dout):
+def _held_bwd(form, low_precision, auto_partitioned, res, dout):
     # the barrier (jax.checkpoint's own) keeps the compiler from
     # sharing the forward pass's casts and products with the ones
     # computed again here, which would keep them alive in between, and
     # from computing them before ``dout`` is there
-    group_sizes = res[1]
-    rows, w_gate, w_up, w_down, dout = jax.lax.optimization_barrier(
-        (res[0],) + res[2:] + (dout,))
-    dot, rows_c, w_gate_c, w_up_c, w_down_c = _operands(
-        rows, group_sizes, w_gate, w_up, w_down, low_precision,
-        auto_partitioned)
+    rows, group_sizes, w_in, w_down = res
+    rows, *weights, dout = jax.lax.optimization_barrier(
+        (rows,) + w_in + (w_down, dout))
+    dot, rows_c, weights_c = _operands(
+        rows, group_sizes, weights, low_precision, auto_partitioned)
     held_rows = jnp.sum(group_sizes)
 
-    def gated_grad(gate, up, dhidden):
-        hidden, back = jax.vjp(
-            lambda g, u: _gated(g, u, rows_c.dtype), gate, up)
-        return back(dhidden) + (hidden,)
+    def form_grad(*chunks):         # the input products, then dhidden
+        hidden, back = jax.vjp(_activation(form, rows_c.dtype),
+                               *chunks[:-1])
+        return back(chunks[-1]) + (hidden,)
 
-    gate, up = dot(rows_c, w_gate_c), dot(rows_c, w_up_c)
-    dgate, dup, hidden = _rewrite_held(
-        held_rows, gated_grad, (gate, up, dot.transposed(dout, w_down_c)))
-    drows, = _rewrite_held(
-        held_rows,
-        lambda a, b: ((a.astype(jnp.float32) +
-                       b.astype(jnp.float32)).astype(a.dtype),),
-        (dot.transposed(dgate, w_gate_c),), dot.transposed(dup, w_up_c))
+    projected = tuple(dot(rows_c, w) for w in weights_c[:-1])
+    *dprojected, hidden = _rewrite_held(
+        held_rows, form_grad,
+        projected + (dot.transposed(dout, weights_c[-1]),))
+    drows = dot.transposed(dprojected[0], weights_c[0])
+    for dp, w in zip(dprojected[1:], weights_c[1:]):
+        drows, = _rewrite_held(
+            held_rows,
+            lambda a, b: ((a.astype(jnp.float32) +
+                           b.astype(jnp.float32)).astype(a.dtype),),
+            (drows,), dot.transposed(dp, w))
     return (drows.astype(rows.dtype), None,
-            dot.weight_gradient(rows_c, dgate).astype(w_gate.dtype),
-            dot.weight_gradient(rows_c, dup).astype(w_up.dtype),
-            dot.weight_gradient(hidden, dout).astype(w_down.dtype))
+            tuple(_unpadded(dot.weight_gradient(rows_c, dp), w)
+                  for dp, w in zip(dprojected, w_in)),
+            _unpadded(dot.weight_gradient(hidden, dout), w_down))
 
 
-held_gated_mlp.defvjp(_held_fwd, _held_bwd)
+held_expert_mlp.defvjp(_held_fwd, _held_bwd)

@@ -779,8 +779,8 @@ def test_grouped_expert_matmuls_at_the_olmoe_cell_shape(one_chip):
 
     def step(x, sizes, gate, up, down):
         def loss(x, gate, up, down):
-            return jnp.sum(moe.grouped_gated_mlp(
-                x, sizes, gate, up, down,
+            return jnp.sum(moe.grouped_expert_mlp(
+                x, sizes, (gate, up), down,
                 low_precision=True).astype(jnp.float32))
         return jax.grad(loss, (0, 1, 2, 3))(x, gate, up, down)
 
@@ -1167,6 +1167,59 @@ def test_the_selective_scan_compiles_its_two_calls(one_chip, as_on_tpu,
                np.prod([int(n) for n in re.findall(r'\d+', c)[1:]]) >=
                b * t * d]
         assert not big, big
+
+
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32],
+                         ids=['bf16', 'f32'])
+def test_the_chunked_scan_and_gqa_flash_at_the_nemotron_cell_shapes(
+        one_chip, as_on_tpu, dtype):
+    """nemotron3_nano_30b_s8192's two new shapes, forward + backward for
+    the described chip (bfloat16 is the timed step, float32 ``chip_smoke.py
+    --phase nemotron_h`` and the cell's reference check).  ``ssd_scan`` at
+    one Mamba-2 layer's [1, 8192, 64, 64] over 8 groups of 128 states in
+    64 chunks of 128: XLA's lowering, no Mosaic call, ONE loop each way
+    (the walks over the chunks' states; the chunks' products run
+    outside them), and the temporaries of the pair stay under 4 GB
+    (the [64, 64, 128, 128] float32 decay weights of every chunk at
+    once are 268 MB; a lowering that kept a [T, T] or a [T, H, P, N]
+    array would not fit).  Causal flash at 32 query heads over 2 K/V
+    heads of 128, sixteen a group, the widest grouping asked of the
+    kernels: the dispatch answers fused and every call is named after
+    the op's own scope."""
+    import re
+    from paddle_tpu.ops import ssd_ops
+    b, t, h, p, g, n = 1, 8192, 64, 64, 8, 128
+
+    def scan(*args):
+        out, pull = jax.vjp(
+            lambda *x: ssd_ops.ssd_scan(*x, 128), *args[:6])
+        return (out,) + pull(args[6])
+
+    wide, narrow = _spec((b, t, h, p), dtype), _spec((b, t, g, n), dtype)
+    compiled = _compiled(scan, one_chip, wide, _spec((b, t, h)),
+                         _spec((h,)), narrow, narrow, _spec((h,)), wide)
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' not in text
+    assert text.count(' while(') == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+    heads, kv, d = 32, 2, 128
+
+    def attend(q, k, v):
+        def loss(q, k, v):
+            with jax.named_scope('fused_multihead_attention'):
+                o = flash_attention.flash_attention(q, k, v, causal=True)
+            return jnp.sum(o.astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    text = _compiled(attend, one_chip, _spec((b, t, heads, d), dtype),
+                     _spec((b, t, kv, d), dtype),
+                     _spec((b, t, kv, d), dtype)).as_text()
+    _compiled_on_chip('flash_attention')
+    names = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert len(names) >= 2
+    assert all('fused_multihead_attention' in name for name in names), names
 
 
 def test_every_dispatchable_kernel_is_compiled_here():

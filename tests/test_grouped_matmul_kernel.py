@@ -145,7 +145,7 @@ def _mlp_operands(seed, m, e, d, h, dtype=jnp.float32):
 
 def _mlp_grads(mlp, sizes, rows, w_gate, w_up, w_down, probe):
     def loss(rows, w_gate, w_up, w_down):
-        out = mlp(rows, sizes, w_gate, w_up, w_down, True)
+        out = mlp(rows, sizes, (w_gate, w_up), w_down, 'gated', True)
         live = jnp.arange(rows.shape[0])[:, None] < jnp.sum(sizes)
         return jnp.sum(jnp.where(live, out.astype(jnp.float32) * probe,
                                  0)), out
@@ -166,8 +166,8 @@ def _dense_count():
 
 
 @pytest.mark.parametrize('mlp,products,dense_products,sizes', [
-    ('grouped_gated_mlp', 9, 3, [130, 0, 254, 128]),
-    ('held_gated_mlp', 11, 11, [130, 0, 70, 100]),
+    ('grouped_expert_mlp', 9, 3, [130, 0, 254, 128]),
+    ('held_expert_mlp', 11, 11, [130, 0, 70, 100]),
 ], ids=['all_held', 'a_range_held'])
 def test_expert_mlp_forced_fused_against_dense(
         mlp, products, dense_products, sizes, pallas_interpret):
@@ -197,7 +197,7 @@ def test_expert_mlp_forced_fused_against_dense(
         _close(got, want, units=4.0)
 
 
-@pytest.mark.parametrize('mlp', ['grouped_gated_mlp', 'held_gated_mlp'])
+@pytest.mark.parametrize('mlp', ['grouped_expert_mlp', 'held_expert_mlp'])
 def test_float32_programs_hold_the_ragged_dots_they_held(
         mlp, pallas_interpret):
     """What decides ``correct`` is untouched: with float32 operands the
@@ -210,13 +210,13 @@ def test_float32_programs_hold_the_ragged_dots_they_held(
     def step(*operands):
         def loss(rows, w_gate, w_up, w_down):
             return jnp.sum(getattr(moe, mlp)(
-                rows, sizes, w_gate, w_up, w_down, False))
+                rows, sizes, (w_gate, w_up), w_down, 'gated', False))
         return jax.grad(loss, (0, 1, 2, 3))(*operands)
 
     text = str(jax.make_jaxpr(step)(*operands[:4]))
     assert 'pallas_call' not in text
     assert text.count('= ragged_dot') == (
-        9 if mlp == 'grouped_gated_mlp' else 11)
+        9 if mlp == 'grouped_expert_mlp' else 11)
     assert 'precision=HIGHEST' in text or 'Precision.HIGHEST' in text
 
 
@@ -227,9 +227,9 @@ def test_low_precision_jaxpr_holds_kernels_and_no_ragged_dot(
 
     def step(*operands):
         def loss(rows, w_gate, w_up, w_down):
-            return jnp.sum(moe.held_gated_mlp(
-                rows, sizes, w_gate, w_up, w_down, True).astype(
-                    jnp.float32))
+            return jnp.sum(moe.held_expert_mlp(
+                rows, sizes, (w_gate, w_up), w_down, 'gated',
+                True).astype(jnp.float32))
         return jax.grad(loss, (0, 1, 2, 3))(*operands)
 
     text = str(jax.make_jaxpr(step)(*operands[:4]))
@@ -239,12 +239,11 @@ def test_low_precision_jaxpr_holds_kernels_and_no_ragged_dot(
 @pytest.mark.parametrize('what,m,d,h,low,partitioned,forced', [
     ('dtype', 4 * TILE, 256, 128, False, False, True),
     ('layout', 4 * TILE, 192, 128, True, False, True),      # K off
-    ('layout', 4 * TILE, 256, 64, True, False, True),       # N off
     ('layout', 4 * TILE - 8, 256, 128, True, False, True),  # M off
     ('auto_partitioned', 4 * TILE, 256, 128, True, True, True),
     ('off_tpu', 4 * TILE, 256, 128, True, False, False),
-], ids=['float32', 'k_off_the_lanes', 'n_off_the_lanes',
-        'm_off_the_row_tile', 'auto_partitioned', 'off_tpu'])
+], ids=['float32', 'k_off_the_lanes', 'm_off_the_row_tile',
+        'auto_partitioned', 'off_tpu'])
 def test_the_dispatch_answers_dense_with_its_reason_counted(
         what, m, d, h, low, partitioned, forced):
     from paddle_tpu.fluid.flags import get_flag, set_flags
@@ -256,8 +255,8 @@ def test_the_dispatch_answers_dense_with_its_reason_counted(
         name = 'pallas/grouped_matmul/fallback/' + what
         before = monitor.counter_value(name) or 0
         dense, fused = _dense_count(), _fused()
-        out = moe.grouped_gated_mlp(rows, sizes, w_gate, w_up, w_down,
-                                    low, partitioned)
+        out = moe.grouped_expert_mlp(rows, sizes, (w_gate, w_up), w_down,
+                                     'gated', low, partitioned)
     finally:
         set_flags({'FLAGS_pallas_force': was})
     assert out.shape == rows.shape
@@ -265,6 +264,51 @@ def test_the_dispatch_answers_dense_with_its_reason_counted(
     assert _dense_count() == dense + 3 and _fused() == fused
     assert common._LAST['grouped_matmul'] == {
         'path': 'dense', 'reason': what, 'interpret': False}
+
+
+@pytest.mark.parametrize('mlp,form,products', [
+    ('grouped_expert_mlp', 'gated', 9), ('held_expert_mlp', 'gated', 11),
+    ('held_expert_mlp', 'relu2', 7),
+], ids=['all_held', 'a_range_held', 'a_range_held_relu2'])
+def test_an_expert_width_off_the_lanes_is_padded_not_refused(
+        mlp, form, products, pallas_interpret):
+    """An expert width off the lanes (Nemotron-H's 1856; 192 here):
+    ``_operands`` pads the width with zeros to the next 128-lane tile
+    and the kernels run, forward and backward; the stream's width (K of
+    the input products) off the lanes still answers dense.  Output, drows and every weight's
+    gradient, CUT BACK to the weight's own width (``_unpadded``),
+    against ``ragged_dot`` on the unpadded operands."""
+    from paddle_tpu.fluid.flags import set_flags
+    rows, w_gate, w_up, w_down, probe = _mlp_operands(7, 4 * TILE, 4, 256,
+                                                      192)
+    sizes = jnp.asarray([100, 50, 0, 60], jnp.int32)
+    live = int(sizes.sum())
+    w_in = (w_gate, w_up) if form == 'gated' else (w_up,)
+
+    def grads():
+        def loss(rows, w_in, w_down):
+            out = getattr(moe, mlp)(rows, sizes, w_in, w_down, form, True)
+            kept = jnp.arange(rows.shape[0])[:, None] < live
+            return jnp.sum(jnp.where(
+                kept, out.astype(jnp.float32) * probe, 0)), out
+        (_, out), (drows, din, ddown) = jax.value_and_grad(
+            loss, (0, 1, 2), has_aux=True)(rows, w_in, w_down)
+        return (out, drows) + tuple(din) + (ddown,)
+
+    dense, fused = _dense_count(), _fused()
+    padded = grads()
+    assert (_dense_count(), _fused()) == (dense, fused + products)
+    assert common._LAST['grouped_matmul']['path'] == 'fused'
+    set_flags({'FLAGS_pallas_force': False})
+    plain = grads()
+    assert padded[0].dtype == jnp.bfloat16
+    for got, want in zip(padded[:2], plain[:2]):    # out, drows
+        assert got.dtype == want.dtype
+        _close(got[:live], want[:live], units=4.0)
+    for got, want, w in zip(padded[2:], plain[2:], w_in + (w_down,)):
+        assert got.shape == want.shape == w.shape
+        assert got.dtype == want.dtype == jnp.float32
+        _close(got, want, units=4.0)
 
 
 def test_the_kernel_is_registered_with_its_dense_fallback():

@@ -359,10 +359,10 @@ def _through_experts(body, sizes, low, rows, dout, *weights):
 @pytest.mark.parametrize('n_held', [0, 5, 8, 9, 24])
 def test_the_held_experts_body_is_the_plain_one_on_the_rows_held(
         n_held, chunk, dtype, monkeypatch):
-    """held_gated_mlp (the SiLU product, its backward and the sum of
-    the rows' two cotangents walked in chunks, in place; its own
-    backward that computes gate and up again) against
-    ``jax.checkpoint(grouped_gated_mlp)``, which it replaced in the
+    """held_expert_mlp's gated form (the SiLU product, its backward and
+    the sum of the rows' two cotangents walked in chunks, in place; its
+    own backward that computes gate and up again) against
+    ``jax.checkpoint(grouped_expert_mlp)``, which it replaced in the
     layers that hold a range of their experts: with the rows past the
     held ones NaN in ``rows`` and in the output's cotangent, the
     output and the rows' gradient on the held rows and the three
@@ -377,12 +377,15 @@ def test_the_held_experts_body_is_the_plain_one_on_the_rows_held(
 
     def plain(rows, sizes, w_gate, w_up, w_down, low):
         return jax.checkpoint(functools.partial(
-            pmoe.grouped_gated_mlp, low_precision=low))(
-                rows, sizes, w_gate, w_up, w_down)
+            pmoe.grouped_expert_mlp, low_precision=low))(
+                rows, sizes, (w_gate, w_up), w_down)
 
-    got = jax.jit(functools.partial(
-        _through_experts, pmoe.held_gated_mlp, sizes, low))(
-            rows, dout, *weights)
+    def held(rows, sizes, w_gate, w_up, w_down, low):
+        return pmoe.held_expert_mlp(rows, sizes, (w_gate, w_up), w_down,
+                                    'gated', low)
+
+    got = jax.jit(functools.partial(_through_experts, held, sizes, low))(
+        rows, dout, *weights)
     want = jax.jit(functools.partial(_through_experts, plain, sizes, low))(
         jnp.nan_to_num(rows), jnp.nan_to_num(dout), *weights)
     unit = 2 * 2.0 ** -8 if low else 1e-6
@@ -396,15 +399,14 @@ def test_the_held_experts_body_is_the_plain_one_on_the_rows_held(
         assert np.abs(a - b).max(initial=0) <= unit * max(
             np.abs(b).max(initial=0), 1), name
     text = str(jax.make_jaxpr(functools.partial(
-        _through_experts, pmoe.held_gated_mlp, sizes, low))(
-            rows, dout, *weights))
+        _through_experts, held, sizes, low))(rows, dout, *weights))
     assert text.count('while[') == 3 and 'cond[' not in text
 
 
 @pytest.mark.parametrize('held', [None, (0, 3)], ids=['all', 'a_range'])
 def test_only_a_held_layers_experts_loop(held):
     """``moe_experts`` without ``experts_held`` (OLMoE: every row of
-    the buffer is some expert's) traces to grouped_gated_mlp as it
+    the buffer is some expert's) traces to grouped_expert_mlp as it
     was: three products forward, no loop, no body with a backward of
     its own, nothing computed twice.  With a held range: the same
     three products forward, the loops, no conditional."""
@@ -423,8 +425,8 @@ def test_only_a_held_layers_experts_loop(held):
     assert 'cond[' not in both and 'checkpoint' not in both
     if held is None:
         plain = str(jax.make_jaxpr(
-            lambda rows, *w: pmoe.grouped_gated_mlp(rows, sizes, *w))(
-                rows, *weights))
+            lambda rows, *w: pmoe.grouped_expert_mlp(
+                rows, sizes, w[:2], w[2]))(rows, *weights))
         assert forward == plain and 'custom_vjp' not in forward
         assert 'while[' not in both
     else:

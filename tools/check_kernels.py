@@ -144,7 +144,7 @@ def main():
         rng.randn(4, 256, 128) / 16, rng.randn(4, 128, 256) / 11)]
 
     def mlp(*x):
-        out = moe.held_gated_mlp(x[0], sizes, *x[1:], True)
+        out = moe.held_expert_mlp(x[0], sizes, x[1:3], x[3], 'gated', True)
         return jnp.sum(out[:300].astype(jnp.float32) ** 2)
 
     fluid.set_flags({'FLAGS_pallas_force': True})

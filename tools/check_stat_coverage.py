@@ -368,6 +368,11 @@ REQUIRED = [
     ('paddle_tpu/ops/ssm_ops.py', 'ssm/calls'),
     ('paddle_tpu/ops/ssm_ops.py', 'ssm/chunks'),
     ('paddle_tpu/ops/ssm_ops.py', 'ssm/boundary_state_mb'),
+    # Mamba-2's chunked scan (ops/ssd_ops.py): the same three
+    # (benchmark/layer_metrics/ssd_chunks.py, ssd_state_mb.py)
+    ('paddle_tpu/ops/ssd_ops.py', 'ssd/calls'),
+    ('paddle_tpu/ops/ssd_ops.py', 'ssd/chunks'),
+    ('paddle_tpu/ops/ssd_ops.py', 'ssd/boundary_state_mb'),
 ]
 
 
