@@ -2968,8 +2968,17 @@ def _nemotron_train_step(seq, seed):
         weights = [np.asarray(fluid.core.as_array(scope.find_var(p)))
                    for p in params]
         t0 = time.time()
+        scans = monitor.counter_value('pallas/ssd_scan/dispatch_fused')
         got = exe.run(main, feed=feed, fetch_list=[loss] + [
             pairs[params[i]] for i in sampled.values()])
+        scans = monitor.counter_value('pallas/ssd_scan/dispatch_fused') - \
+            scans
+        say('ssd_scan: %d lowerings took the kernels in the f32 train '
+            'step, last dispatch %s' % (scans, common._LAST.get('ssd_scan')))
+        check(scans >= cfg.pattern.count('M') and
+              common._LAST.get('ssd_scan', {}).get('path') == 'fused',
+              'the f32 train step\'s chunked scans ran the ssd_scan '
+              'kernels (the gradients below are theirs)')
         got_loss = _scalar(got[:1])
         say('nemotron_h f32 train program, 1 x %d tokens, %s: loss %.6f '
             'in %.1f s; ssd/chunks %d, ssd/boundary_state_mb %.1f; '
@@ -3120,10 +3129,73 @@ def _nemotron_cell_losses(seed):
             scope.erase(name)
 
 
+# Both paths round both operands of every product to bfloat16 (2^-9
+# each) but not at the same place of the algebra, a gradient passes
+# through two products and a product sums 128 terms: 4 x 2^-8 of the
+# largest entry between the two paths.  Read on the chip (PR 66,
+# tools/bench_ssd_scan.py at this shape): the gradients 2.2e-3 to
+# 7.5e-3 apart, D's 2e-7.
+NEMOTRON_SCAN_BF16_RTOL = 2 ** -6
+
+
+def _nemotron_scan_arm(seed):
+    """The ``ssd_scan`` op ALONE at the cell's own shape ([1, 8192, 64,
+    64] over 8 groups of 128 states in chunks of 128) in bfloat16: the
+    kernels against the dense path, y and the six gradients, each
+    beside its distance from the dense path in float32."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import nemotron_h
+    from paddle_tpu.ops import ssd_ops
+    cfg = nemotron_h.BASE
+    b, t = 1, 8192
+    h, p, g, n = cfg.mamba_heads, cfg.mamba_head_dim, cfg.groups, cfg.states
+    rng = np.random.RandomState(seed)
+    wide = [rng.randn(b, t, h, p), rng.randn(b, t, g, n),
+            rng.randn(b, t, g, n), rng.randn(b, t, h, p)]
+    # steps 0.001 to 0.1 and rates 1 to 16: Mamba-2's initialisers
+    narrow = [jnp.asarray(v, jnp.float32) for v in (
+        np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (b, t, h))),
+        -rng.uniform(1, 16, (h,)), rng.randn(h))]
+
+    def both(path, dtype):
+        x, bm, cm, probe = (jnp.asarray(v, jnp.bfloat16).astype(dtype)
+                            for v in wide)
+        delta, a, skip = narrow
+
+        def run(*ins):
+            out, pull = jax.vjp(
+                lambda *v: ssd_ops._scan(*v, cfg.chunk, path), *ins)
+            return (out,) + pull(probe)
+        return [np.asarray(v, np.float64) for v in jax.jit(run)(
+            x, delta, a, bm, cm, skip)]
+
+    exact = both('dense', jnp.float32)
+    dense, fused = both('dense', jnp.bfloat16), both('fused', jnp.bfloat16)
+    worst = 0.0
+    for name, e, d, f in zip(('y', 'dX', 'dDelta', 'dA', 'dB', 'dC', 'dD'),
+                             exact, dense, fused):
+        top = np.abs(e).max()
+        apart = np.abs(f - d).max() / top
+        worst = max(worst, apart)
+        say('ssd_scan bf16 at the cell\'s shape, %s: fused from dense '
+            '%.3e of the largest entry (%.3e); from the float32 dense '
+            'path: fused %.3e, dense %.3e'
+            % (name, apart, top, np.abs(f - e).max() / top,
+               np.abs(d - e).max() / top))
+    check(worst <= NEMOTRON_SCAN_BF16_RTOL,
+          'ssd_scan in bfloat16 at [1, 8192, 64, 64]: the kernels\' y and '
+          'six gradients within %g of the dense path\'s (worst %.3e)'
+          % (NEMOTRON_SCAN_BF16_RTOL, worst))
+
+
 def phase_nemotron_h(seed=0):
-    """models.nemotron_h.BASE cut as above: loss and sampled gradients
-    of the f32 TRAIN program against the reference's on one short
-    seeded sequence; then the cell's for_test losses at 8192 tokens."""
+    """models.nemotron_h.BASE cut as above: the chunked scan's kernels
+    against its dense path in bfloat16 at the cell's shape; loss and
+    sampled gradients of the f32 TRAIN program against the reference's
+    on one short seeded sequence; then the cell's for_test losses at
+    8192 tokens."""
+    _nemotron_scan_arm(seed)
     _nemotron_train_step(NEMOTRON_GRAD_SEQ, seed)
     _nemotron_cell_losses(seed)
 

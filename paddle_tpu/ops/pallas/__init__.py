@@ -13,5 +13,6 @@ from . import kda_chunk
 from . import kda_walk
 from . import quant_collective
 from . import sinkhorn
+from . import ssd_scan
 from . import ssm_scan
 from .flash_attention import flash_attention as flash_attention_fn

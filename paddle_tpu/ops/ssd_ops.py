@@ -39,6 +39,25 @@ sequence), computes the inside of every chunk again under ``jax.vjp``
 chunks hands it), and no [T, H, P, N] array exists on either pass.  T
 need be no whole number of chunks: the tail is padded with tokens of
 step 0, which neither decay nor write.
+
+WHICH PATH RUNS WHERE.  The functions of this module are the DENSE
+path and the definition: every chunk's [Q, Q] scores and decay weights
+at once, as XLA lowers them.  It is what runs off a TPU (every tier-1
+test but the forced ones), in float64, under the GSPMD runner (XLA
+partitions no Mosaic call), and for operands the kernels' layout does
+not hold: a chunk or N off whole 128-lane tiles, a head's P off
+whole 16-row tiles, a tail that fills no chunk.  On a
+TPU everything else (the model's bfloat16 step, its float32 ``for_test``
+program and gradient checks) runs the ``ssd_scan`` kernels
+(``ops/pallas/ssd_scan.py``): one Mosaic call forward and one backward,
+a chunk's scores and weights in VMEM and a group's state on the core
+from a sequence's first chunk to its last.  ``_scan_path`` asks
+``common.dispatch`` ONCE a call (counters
+``pallas/ssd_scan/dispatch_{fused,dense}``, ``fallback/<reason>``) and
+the forward and the backward of that call follow the one answer; the
+state at each chunk's start is the residual on both paths (the same
+bytes, in the path's own order), and ``ssd/chunks`` /
+``ssd/boundary_state_mb`` read the same on both.
 """
 
 import functools
@@ -173,12 +192,41 @@ def _unchunked(v, like):
     return v.reshape((b, -1) + v.shape[3:])[:, :t].reshape(like.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def ssd_scan(x, delta, a, bm, cm, dskip, chunk=CHUNK):
+def _scan_path(x, bm, chunk, auto_partitioned):
+    """How this call runs its chunks: 'dense' (the functions above, the
+    definition), 'fused' (the ``ssd_scan`` kernels) or 'interpret'
+    (their bodies under the Pallas interpreter: FLAGS_pallas_force off
+    a TPU).  One ``common.dispatch`` decision a call, which its forward
+    and its backward both follow, from what the operands show
+    (``ssd_scan.checks``)."""
+    from .pallas import common, ssd_scan as kernels
+    fused, interpret = common.dispatch(
+        'ssd_scan', True,
+        checks=kernels.checks(x.shape, bm.shape[2], bm.shape[3], chunk,
+                              _working_dtype(x), x.dtype.itemsize),
+        auto_partitioned=auto_partitioned)
+    return ('interpret' if interpret else 'fused') if fused else 'dense'
+
+
+def _fused(x, chunk, path):
+    """-> (the kernels' module, the keywords of its calls: the chunk as
+    they run it, and whether under the interpreter); a pass of theirs
+    counts its trips over chunks as a dense walk does."""
+    from .pallas import ssd_scan as kernels
+    size, n = _layout(x.shape[1], chunk)
+    registry.trace_sum('ssd/chunks', n)
+    return kernels, dict(size=size, interpret=path == 'interpret')
+
+
+def ssd_scan(x, delta, a, bm, cm, dskip, chunk=CHUNK,
+             auto_partitioned=False):
     """x [B, T, H, P], delta [B, T, H] (> 0), a [H] (< 0), bm, cm [B, T,
     G, N] (H a whole number of G), dskip [H] -> y [B, T, H, P] in x's
-    dtype.  T need be no whole number of chunks."""
-    return _forward(x, delta, a, bm, cm, dskip, chunk)[0]
+    dtype.  T need be no whole number of chunks.  ``auto_partitioned``:
+    ``common.dispatch``'s (the caller's word that XLA will partition
+    this program over a mesh)."""
+    return _scan(x, delta, a, bm, cm, dskip, chunk,
+                 _scan_path(x, bm, chunk, auto_partitioned))
 
 
 def _forward(x, delta, a, bm, cm, dskip, chunk):
@@ -189,19 +237,37 @@ def _forward(x, delta, a, bm, cm, dskip, chunk):
     return _unchunked(y, x).astype(x.dtype), starts
 
 
-def _scan_fwd(x, delta, a, bm, cm, dskip, chunk):
-    y, starts = _forward(x, delta, a, bm, cm, dskip, chunk)
+def _pass(x, delta, a, bm, cm, dskip, chunk, path):
+    """-> (y, the state at each chunk's START, in the path's own
+    order) by the path's forward."""
+    if path == 'dense':
+        return _forward(x, delta, a, bm, cm, dskip, chunk)
+    kernels, how = _fused(x, chunk, path)
+    return kernels.forward(x, delta, a, bm, cm, dskip, **how)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _scan(x, delta, a, bm, cm, dskip, chunk, path):
+    return _pass(x, delta, a, bm, cm, dskip, chunk, path)[0]
+
+
+def _scan_fwd(x, delta, a, bm, cm, dskip, chunk, path):
+    y, starts = _pass(x, delta, a, bm, cm, dskip, chunk, path)
     registry.trace_sum('ssd/boundary_state_mb',
                        starts.size * starts.dtype.itemsize / 1e6)
     return y, ((x, delta, a, bm, cm, dskip), starts)
 
 
-def _scan_bwd(chunk, saved, d_y):
-    """Every chunk's inside again, twice under ``jax.vjp``: the read
-    with y's cotangent, which also gives each start state's own; the
-    chunks in reverse for the states' full cotangents; the writes with
-    those."""
+def _scan_bwd(chunk, path, saved, d_y):
+    """Every chunk's inside again.  Dense: twice under ``jax.vjp``, the
+    read with y's cotangent, which also gives each start state's own;
+    the chunks in reverse for the states' full cotangents; the writes
+    with those.  Fused: inside one kernel call, the chunks counted
+    down."""
     inputs, starts = saved
+    if path != 'dense':
+        kernels, how = _fused(inputs[0], chunk, path)
+        return kernels.backward(*inputs, starts, d_y, **how)
     operands = _operands(*inputs, chunk)
     xc, dc, ac, bc = operands[:4]
     f = _working_dtype(inputs[0])
@@ -222,7 +288,7 @@ def _scan_bwd(chunk, saved, d_y):
             d_skip.reshape(dskip.shape).astype(dskip.dtype))
 
 
-ssd_scan.defvjp(_scan_fwd, _scan_bwd)
+_scan.defvjp(_scan_fwd, _scan_bwd)
 
 
 @register('ssd_scan')
@@ -234,4 +300,5 @@ def ssd_scan_op(ctx, ins, attrs):
     monitor.add('ssd/calls', 1)
     return {'Out': [ssd_scan(
         ins['X'][0], ins['Delta'][0], ins['A'][0], ins['B'][0],
-        ins['C'][0], ins['D'][0], int(attrs.get('chunk', CHUNK)))]}
+        ins['C'][0], ins['D'][0], int(attrs.get('chunk', CHUNK)),
+        auto_partitioned=ctx.auto_partitioned)]}

@@ -1177,31 +1177,37 @@ def test_the_chunked_scan_and_gqa_flash_at_the_nemotron_cell_shapes(
     the described chip (bfloat16 is the timed step, float32 ``chip_smoke.py
     --phase nemotron_h`` and the cell's reference check).  ``ssd_scan`` at
     one Mamba-2 layer's [1, 8192, 64, 64] over 8 groups of 128 states in
-    64 chunks of 128: XLA's lowering, no Mosaic call, ONE loop each way
-    (the walks over the chunks' states; the chunks' products run
-    outside them), and the temporaries of the pair stay under 4 GB
-    (the [64, 64, 128, 128] float32 decay weights of every chunk at
-    once are 268 MB; a lowering that kept a [T, T] or a [T, H, P, N]
-    array would not fit).  Causal flash at 32 query heads over 2 K/V
-    heads of 128, sixteen a group, the widest grouping asked of the
-    kernels: the dispatch answers fused and every call is named after
-    the op's own scope."""
+    64 chunks of 128: the dispatch answers fused, the forward and the
+    backward are ONE Mosaic call each, named after the op's own scope,
+    no loop walks the chunks through HBM, no float32 array of all 64 x
+    64 chunk-heads' [128, 128] scores or weights is among the program's
+    arrays (268 MB each where XLA lowered the chunks), and the
+    temporaries of the pair stay under 1.5 GB (they were 4).  Causal
+    flash at 32 query heads over 2 K/V heads of 128, sixteen a group,
+    the widest grouping asked of the kernels: the dispatch answers fused
+    and every call is named after the op's own scope."""
     import re
     from paddle_tpu.ops import ssd_ops
     b, t, h, p, g, n = 1, 8192, 64, 64, 8, 128
 
     def scan(*args):
-        out, pull = jax.vjp(
-            lambda *x: ssd_ops.ssd_scan(*x, 128), *args[:6])
-        return (out,) + pull(args[6])
+        with jax.named_scope('ssd_scan'):
+            out, pull = jax.vjp(
+                lambda *x: ssd_ops.ssd_scan(*x, 128), *args[:6])
+            return (out,) + pull(args[6])
 
     wide, narrow = _spec((b, t, h, p), dtype), _spec((b, t, g, n), dtype)
     compiled = _compiled(scan, one_chip, wide, _spec((b, t, h)),
                          _spec((h,)), narrow, narrow, _spec((h,)), wide)
+    _compiled_on_chip('ssd_scan')
     text = compiled.as_text()
-    assert 'custom_call_target="tpu_custom_call"' not in text
-    assert text.count(' while(') == 2
-    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+    names = re.findall(
+        r'%(\S+) = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert len(names) == 2, names
+    assert all('ssd_scan' in name for name in names), names
+    assert ' while(' not in text
+    assert not re.search(r'f32\[(1,)?64,(64|8,8),128,128\]', text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
     heads, kv, d = 32, 2, 128
 
@@ -1226,4 +1232,4 @@ def test_every_dispatchable_kernel_is_compiled_here():
     """A kernel registered later must bring its compile with it."""
     assert set(common.kernels()) == {
         'flash_attention', 'grouped_matmul', 'kda_chunk', 'kda_walk',
-        'quant_collective', 'sinkhorn', 'ssm_scan'}
+        'quant_collective', 'sinkhorn', 'ssd_scan', 'ssm_scan'}

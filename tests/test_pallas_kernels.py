@@ -185,7 +185,7 @@ def test_kernel_registry_contract():
     ks = common.kernels()
     assert set(ks) == {'flash_attention', 'grouped_matmul', 'kda_chunk',
                        'kda_walk', 'quant_collective', 'sinkhorn',
-                       'ssm_scan'}
+                       'ssd_scan', 'ssm_scan'}
     for name in ks:
         assert ks[name]['dense_fallback'], name
 

@@ -28,7 +28,7 @@ import os
 import sys
 
 EXPECTED = ('flash_attention', 'grouped_matmul', 'kda_chunk', 'kda_walk',
-            'quant_collective', 'sinkhorn', 'ssm_scan')
+            'quant_collective', 'sinkhorn', 'ssd_scan', 'ssm_scan')
 
 
 def _near(got, want, rtol=2e-5):
@@ -202,6 +202,29 @@ def main():
             failures.append('ssm_scan forward/grad parity')
             break
 
+    # Mamba-2's chunked scan through the ssd_scan kernels against XLA's
+    # lowering of every chunk at once: two chunks of 128 tokens, 8 heads
+    # of 64 in one group, 128 states
+    from paddle_tpu.ops import ssd_ops
+    chunked = [jnp.asarray(v.astype('float32')) for v in (
+        rng.randn(1, 256, 8, 64), np.exp(rng.uniform(-5, 0.5, (1, 256, 8))),
+        -np.exp(rng.uniform(-3, 2, 8)), rng.randn(1, 256, 1, 128) / 4,
+        rng.randn(1, 256, 1, 128) / 4, rng.randn(8))]
+    weight = jnp.asarray(rng.randn(1, 256, 8, 64).astype('float32'))
+
+    def chunk_scanned(*x):
+        out, pull = jax.vjp(lambda *x: ssd_ops.ssd_scan(*x, 128), *x)
+        return (out,) + pull(weight)
+
+    fluid.set_flags({'FLAGS_pallas_force': True})
+    fused = chunk_scanned(*chunked)
+    fluid.set_flags({'FLAGS_pallas_force': False})
+    dense = chunk_scanned(*chunked)
+    for a, b in zip(fused, dense):
+        if not _near(a, b, 1e-5):
+            failures.append('ssd_scan forward/grad parity')
+            break
+
     flat = jnp.asarray(rng.randn(16, 256).astype('float32'))
     qv, s = quant_collective.quantize_blocks(flat, True)
 
@@ -219,7 +242,7 @@ def main():
         failures.append('quantize_blocks not bitwise vs dense q()')
     print('parity: flash_attention fwd/grad, kda_chunk fwd/grad, '
           'kda_walk fwd/grad, grouped_matmul fwd/grad, sinkhorn fwd/grad, '
-          'ssm_scan fwd/grad, quantize_blocks ok')
+          'ssm_scan fwd/grad, ssd_scan fwd/grad, quantize_blocks ok')
 
     # -- 3. dispatch observability -----------------------------------
     quant_collective.dispatch()
