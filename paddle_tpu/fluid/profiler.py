@@ -53,6 +53,7 @@ _enabled = False
 _mode = 'Serial'         # 'Serial' | 'Default' (trace-derived)
 _records = {}  # op type -> [calls, total, max, min]
 _costs = {}    # op type -> [GFLOP or None (unknown), MB], trace-derived
+_passes = {}   # pass -> seconds, trace-derived
 _folded = False          # records already added to fluid.monitor
 _trace_path = None
 _prof_trace_dir = None   # capture dir while a 'Default' profile runs
@@ -82,6 +83,7 @@ def reset_profiler():
     global _folded
     _records.clear()
     _costs.clear()
+    _passes.clear()
     _folded = False
 
 
@@ -110,7 +112,9 @@ def summary_records():
 def summary_string(sorted_key='total'):
     """The reference's profiler table (profiler.h:166 prints Event
     rows sorted by sorted_key); after a device trace with four columns
-    more, a row's cost and what it achieved ('-': not known)."""
+    more, a row's cost and what it achieved ('-': not known), and,
+    where the capture held a recompute group, a last line with the
+    device time of each pass (``fluid_pass``)."""
     if sorted_key not in (None,) + _SORT_KEYS:
         raise ValueError('sorted_key must be one of %s, got %r'
                          % (_SORT_KEYS, sorted_key))
@@ -128,6 +132,9 @@ def summary_string(sorted_key='total'):
                         r['ave'] * 1e3) + ''.join(
                             ' %10.2f' % r[k] if k in r else ' %10s' % '-'
                             for k, _ in extra))
+    if _passes.get('recomputed'):
+        lines.append('by pass (ms): ' + ', '.join(
+            '%s %.4f' % (p, _passes.get(p, 0.0) * 1e3) for p in PASSES))
     return '\n'.join(lines)
 
 
@@ -136,20 +143,21 @@ def _registered_op_types():
     return set(registry._REGISTRY)
 
 
-def _resolve_component(comp, op_types):
-    """One scope-path component -> attribution name or None.  Strips
-    transform wrappers (transpose(jvp(relu)))."""
-    base = comp
-    while '(' in base and base.endswith(')'):
-        base = base[base.index('(') + 1:-1]
-    for cand in (comp, base):
-        if _is_op_type(cand, op_types):
-            return cand
-    return None
+def attributed_type(tf_op, op_types=None):
+    """An event's ``tf_op`` -> the op type its time is filed under, or
+    None: a scope of the scope table as it stands (``'mul_grad'``,
+    ``'fused_adam/pack'``), else a raw ``op_name`` path, read by the
+    one rule there is (``fluid_scope``)."""
+    op_types = op_types or _registered_op_types()
+    head = tf_op.split('/', 1)[0]
+    if _is_op_type(head, op_types):
+        return head
+    scope = fluid_scope(tf_op, op_types)
+    return scope.split('/', 1)[0] if scope else None
 
 
 def attribute_trace_events(events, op_types=None, with_stats=False,
-                           costs=None):
+                           costs=None, passes=None):
     """Map device-trace kernel events back to fluid op types.
 
     `events` are chrome-trace events (``load_trace_events``, or a
@@ -158,11 +166,15 @@ def attribute_trace_events(events, op_types=None, with_stats=False,
     XLA op_metadata op_name, i.e. the jax.named_scope path the
     executor wrapped the lowering in ('jit_segment_x/relu/max' or,
     under whole-program autodiff,
-    'jit_.../transpose(jvp(...))/relu/...').  Attribution:
-    the first path component that names a registered op type; kernels
-    with no such component (copies, infeed, grad-only glue) land under
-    'unattributed/<hlo name>'.  Returns {name: [calls, total_s, max_s,
-    min_s]}.
+    'jit_.../transpose(jvp(relu))/...').  Attribution: the op type of
+    the scope, the backward's as ``<type>_grad``; a raw path is read by
+    the scope table's own rule (``fluid_scope``), so this table and
+    that one cannot disagree; kernels with no fluid op (copies, infeed,
+    glue) land under 'unattributed/<hlo name>'.  Returns {name: [calls,
+    total_s, max_s, min_s]}.
+
+    ``passes``, a dict, receives {pass: seconds} summed from the events
+    that carry one (``load_trace_events`` gives args['pass']).
 
     Tolerant by contract: real captures contain malformed rows (counter
     events without dur, instant events, non-string tf_op metadata,
@@ -219,13 +231,10 @@ def attribute_trace_events(events, op_types=None, with_stats=False,
             sec = 0.0
         name = cache.get(tf_op)
         if name is None:
-            name = False
-            for comp in tf_op.split('/'):
-                hit = _resolve_component(comp, op_types)
-                if hit is not None:
-                    name = hit
-                    break
-            cache[tf_op] = name
+            name = cache[tf_op] = attributed_type(tf_op, op_types) \
+                or False
+        if passes is not None and isinstance(args.get('pass'), str):
+            passes[args['pass']] = passes.get(args['pass'], 0.0) + sec
         cost = None
         if isinstance(args.get('mb'), (int, float)):
             gflop = args.get('gflop')
@@ -246,6 +255,54 @@ def attribute_trace_events(events, op_types=None, with_stats=False,
     return recs
 
 
+UNSCOPED = '(unscoped)'
+
+
+def instructions_under(events, scopes, op_types=None):
+    """The instructions a capture ran under the given fluid scopes
+    (``'moe_dispatch'`` holds ``'moe_dispatch/<scope>'`` too;
+    ``UNSCOPED``: those the scope table gives no fluid op, whatever
+    their module), from the events ``load_trace_events`` gives or a
+    ``device.trace.json`` holds, of the first process that ran any:
+    [{'name', 'tf_op', 'pass', 'kind', 'shapes', 'calls', 'ms', 'mb',
+    'gbps'}], longest first; ``mb`` is one call's, ``gbps`` over all
+    calls' own time.  Two instructions of one name, kind and shapes (a
+    quiet step's and a fetching step's) are one row."""
+    op_types = op_types or _registered_op_types()
+    wanted = set(scopes)
+    rows, pid = {}, None
+    for e in events:
+        if not isinstance(e, dict) or e.get('ph') != 'X':
+            continue
+        args = e.get('args')
+        if not isinstance(args, dict) or \
+                not isinstance(args.get('tf_op'), str):
+            continue
+        if pid is None:
+            pid = e.get('pid')
+        if e.get('pid') != pid:
+            continue
+        tf_op = args['tf_op']
+        if attributed_type(tf_op, op_types) is None:
+            if UNSCOPED not in wanted:
+                continue
+        elif not (tf_op in wanted or tf_op.split('/', 1)[0] in wanted):
+            continue
+        key = (e.get('name'), tf_op, args.get('kind'), args.get('shapes'))
+        row = rows.get(key)
+        if row is None:
+            row = rows[key] = {
+                'name': key[0], 'tf_op': tf_op, 'pass': args.get('pass'),
+                'kind': key[2], 'shapes': key[3], 'calls': 0, 'ms': 0.0,
+                'mb': args.get('mb')}
+        row['calls'] += 1
+        row['ms'] += float(e.get('self_dur', e.get('dur')) or 0) / 1e3
+    for row in rows.values():
+        row['gbps'] = row['mb'] * row['calls'] / row['ms'] \
+            if row['mb'] is not None and row['ms'] > 0 else None
+    return sorted(rows.values(), key=lambda r: -r['ms'])
+
+
 # ------------------------------------------------ HLO instruction scopes
 # A device trace names HLO instructions (``fusion.933``), and XLA renames
 # them whenever a lowering changes.  The executor lowers every fluid op
@@ -260,6 +317,32 @@ def attribute_trace_events(events, op_types=None, with_stats=False,
 #   fluid op type; jax's transform wrappers are looked through, and a
 #   ``transpose`` among them makes it that type's backward
 #   (``mul_grad``, the name the explicit grad op lowers under);
+# - the PASS an instruction runs in (``fluid_pass``, ``pass_tables()``)
+#   is read off the same components, and the scope's ``_grad`` follows
+#   it, so the two cannot disagree.  A recompute group
+#   (``executor._lower_recomputed``: ``jax.checkpoint``) puts the
+#   ``transpose`` on an EARLIER, nameless component and runs the
+#   group's forward a second time inside the backward pass:
+#   ``transpose(jvp(jvp()))/checkpoint/mul/dot_general`` is the group's
+#   backward, ``.../checkpoint/rematted_computation/mul/dot_general``
+#   its second forward.  So:
+#     ``recomputed``: a component ``rematted_computation`` anywhere
+#       before the op's, whatever else wraps it; the scope stays the
+#       forward's (``mul``);
+#     ``backward``: else a ``transpose`` among the wrappers of the op's
+#       component or of ANY component before it, an explicit ``_grad``
+#       op type, or a transposed control-flow op around it (below);
+#       the scope is ``mul_grad``;
+#     ``forward``: else.
+#   An optimizer's op belongs to no pass, nor does an instruction with
+#   no fluid scope or one the compiler named itself.  What the pass is
+#   NOT: an op whose own ``custom_vjp`` backward runs a chunk's forward
+#   again (``ssd_scan``, ``selective_scan``, ``kda_attention``, the
+#   ``sinkhorn`` backward) does so under its ``_grad`` scope and stays
+#   ``backward``; only ``jax.checkpoint``'s second forward is
+#   ``recomputed``.  What the program runs between the gradient and the
+#   optimizer under plain scopes (loss-scale checks, clipping) reads
+#   ``forward``.
 # - a control-flow op (``while``, ``conditional_block``) is looked
 #   INTO: an instruction of its sub-block counts to the first fluid op
 #   type further down the path
@@ -274,7 +357,8 @@ def attribute_trace_events(events, op_types=None, with_stats=False,
 #   holds, else to its root; a root that carries no scope (the tuple of
 #   a multi-output fusion, a bitcast or copy XLA put there) stands for
 #   the nearest of its operands inside the fusion that does, and only
-#   if none does, the fusion's own ``op_name`` decides;
+#   if none does, the fusion's own ``op_name`` decides; scope AND pass
+#   are those of that one instruction (``_fusion_decider``);
 # - an instruction the TPU compiler expands and renames itself keeps no
 #   ``op_name`` of the program's (a grouped matmul comes out as Mosaic
 #   calls named ``ragged-dot-none``): it counts to the op whose
@@ -386,37 +470,64 @@ def _unwrapped(comp):
     return comp, backward
 
 
-def fluid_scope(op_name, op_types=None):
-    """The fluid op an HLO instruction was lowered from, by the rule
-    above, from the ``op_name`` of its metadata: ``'mul'``,
-    ``'mul_grad'``, ``'<type>/<scope>'``, or None."""
+PASSES = ('forward', 'recomputed', 'backward')
+_REMATTED = 'rematted_computation'  # jax.checkpoint's second forward
+
+
+def _scope_and_pass(op_name, op_types, optimizers):
+    """(fluid scope, pass) of an ``op_name`` by the rule above, each
+    None for none."""
     if not op_name:
-        return None
-    op_types = op_types or _registered_op_types()
+        return None, None
     parts = op_name.split('/')
     around = None           # the control-flow op the walk is inside
-    inherited = False       # ... and whether that one is transposed
+    # a ``transpose`` among the wrappers of a component so far, or an
+    # explicit ``while_grad`` / ``conditional_block_grad`` around
+    behind = False
+    rematted = False
     for i, comp in enumerate(parts[:-1]):
-        comp, backward = _unwrapped(comp)
+        if comp == _REMATTED:
+            rematted = True
+            continue
+        comp, transposed = _unwrapped(comp)
+        behind = behind or transposed
         if not _is_op_type(comp, op_types):
             continue
-        backward = backward or inherited
+        backward = not rematted and (behind or comp.endswith('_grad'))
         if backward and not comp.endswith('_grad'):
             comp += '_grad'
         inner = parts[i + 1] if i + 2 < len(parts) else ''
         found = comp + '/' + inner if inner and '(' not in inner else comp
+        phase = None if comp in optimizers else \
+            'recomputed' if rematted else \
+            'backward' if backward else 'forward'
         if comp in _CONTROL_FLOW:
-            around = around or found
-            inherited = inherited or comp.endswith('_grad')
+            around = around or (found, phase)
+            behind = behind or comp.endswith('_grad')
             continue
-        return found
+        return found, phase
     if around is not None:
         return around
     from ..ops import registry
     for prefix, op_type in registry.COMPILER_NAMED.items():
         if op_name.startswith(prefix):
-            return op_type
-    return None
+            return op_type, None
+    return None, None
+
+
+def fluid_scope(op_name, op_types=None):
+    """The fluid op an HLO instruction was lowered from, by the rule
+    above, from the ``op_name`` of its metadata: ``'mul'``,
+    ``'mul_grad'``, ``'<type>/<scope>'``, or None."""
+    return _scope_and_pass(op_name, op_types or _registered_op_types(),
+                           ())[0]
+
+
+def fluid_pass(op_name, op_types=None):
+    """The pass an HLO instruction runs in, by the same rule:
+    ``'forward'``, ``'recomputed'``, ``'backward'``, or None."""
+    return _scope_and_pass(op_name, op_types or _registered_op_types(),
+                           _optimizer_types())[1]
 
 
 def loop_side(op_name):
@@ -496,24 +607,27 @@ def _parse_hlo(text):
     return module, computations
 
 
-def _fusion_scope(fusion, body, op_types):
-    scopes = {ins.name: fluid_scope(ins.op_name, op_types)
-              for ins in body}
+def _fusion_decider(fusion, body, op_types):
+    """The one instruction whose ``op_name`` decides a fusion's scope
+    and pass, by the rule above: of the computation it calls, or the
+    fusion itself."""
+    scoped = {ins.name for ins in body
+              if fluid_scope(ins.op_name, op_types)}
     for ins in body:
-        if ins.opcode in _HELD_BY_FUSION and scopes[ins.name]:
-            return scopes[ins.name]
+        if ins.opcode in _HELD_BY_FUSION and ins.name in scoped:
+            return ins
     by_name = {ins.name: ins for ins in body}
     roots = [ins for ins in body if ins.root] or body[-1:]
     queue, seen = collections.deque(roots), set()
     while queue:                # breadth first: the nearest operand
         ins = queue.popleft()
-        if scopes[ins.name]:
-            return scopes[ins.name]
+        if ins.name in scoped:
+            return ins
         for name in _HLO_OPERAND.findall(ins.operands):
             if name in by_name and name not in seen:
                 seen.add(name)
                 queue.append(by_name[name])
-    return fluid_scope(fusion.op_name, op_types)
+    return fusion
 
 
 # ------------------------------------------------- HLO instruction costs
@@ -828,12 +942,16 @@ def _instruction_cost(ins, shapes, called):
 #   takes that of what it copies, and a buffer it allocates for a loop
 #   to fill (``AllocateBuffer``: a scan's stacked residuals) that of
 #   its nearest reader; a reader that is such a copy is looked
-#   through the same way.  Its class:
-#   ``residual``: defined under a forward op, last read under a
-#   ``_grad`` one (kept for the backward pass); ``gradient``: defined
-#   under a ``_grad`` op, last read by an optimizer op or a collective;
-#   ``optimizer``: defined under an optimizer op; ``working``: born
-#   and dead on one side; ``unscoped``: no fluid op.
+#   through the same way.  Its pass goes with its op (that of the
+#   same instruction).  Its class:
+#   ``recomputed``: defined by an instruction of a recompute group's
+#   second forward (pass ``recomputed``: what the group did NOT keep);
+#   ``residual``: defined under a forward op of the first forward, last
+#   read under a ``_grad`` one or by a ``recomputed`` instruction (kept
+#   for the backward pass: of a group, its inputs); ``gradient``:
+#   defined under a ``_grad`` op, last read by an optimizer op or a
+#   collective; ``optimizer``: defined under an optimizer op;
+#   ``working``: born and dead on one side; ``unscoped``: no fluid op.
 #
 # What the walk cannot see: the compiler's packing (alignment, a buffer
 # reused by an elementwise result of the instruction that frees it),
@@ -912,12 +1030,13 @@ def _element(value, index):
 
 
 class _Buffer(object):
-    __slots__ = ('bytes', 'elsewhere', 'shape', 'ins', 'scope', 'born',
-                 'last', 'reader', 'out')
+    __slots__ = ('bytes', 'elsewhere', 'shape', 'ins', 'scope', 'phase',
+                 'born', 'last', 'reader', 'out')
 
-    def __init__(self, nbytes, elsewhere, shape, ins, scope, born):
+    def __init__(self, nbytes, elsewhere, shape, ins, scoped, born):
         self.bytes, self.elsewhere = nbytes, elsewhere
-        self.shape, self.ins, self.scope, self.born = shape, ins, scope, born
+        self.shape, self.ins, self.born = shape, ins, born
+        self.scope, self.phase = scoped     # the fluid op and its pass
         self.last, self.reader, self.out = born, None, False
 
 
@@ -955,10 +1074,11 @@ def _in_place_outputs(called):
 class _LiveWalk(object):
     """The walk of one module by the rule above."""
 
-    def __init__(self, computations, scopes, entry):
+    def __init__(self, computations, scopes, passes, entry):
         self.computations = computations
         self.entry = entry
         self.scopes = scopes
+        self.passes = passes
         self.optimizers = _optimizer_types()
         self.walking = None     # the computation value_of is asked in
         self.walked = {}        # computation -> (peak, point, [buffers])
@@ -974,9 +1094,13 @@ class _LiveWalk(object):
             return None
         nbytes = _nbytes(tree)
         buf = _Buffer(nbytes, everywhere - nbytes, _plain(tree), ins.name,
-                      self.scopes.get(ins.name), index)
+                      self.scoped(ins.name), index)
         self.every.append(buf)
         return buf
+
+    def scoped(self, name):
+        """(fluid op, pass) of an instruction."""
+        return self.scopes.get(name), self.passes.get(name)
 
     def called(self, ins):
         """[(the computation a control-flow instruction runs, whether
@@ -1044,15 +1168,16 @@ class _LiveWalk(object):
         return made
 
     def copied_scope(self, ins, by_name):
-        """The fluid op of what a compiler's copy copies: that of the
-        nearest instruction up its first operands that has one."""
+        """The (fluid op, pass) of what a compiler's copy copies: that
+        of the nearest instruction up its first operands that has an
+        op."""
         for _ in range(16):
             ins = by_name.get((_operand_names(ins) or [None])[0])
             if ins is None:
-                return None
+                break
             if self.scopes.get(ins.name):
-                return self.scopes[ins.name]
-        return None
+                return self.scoped(ins.name)
+        return None, None
 
     def forget(self, value):
         for buf in _leaves(value):
@@ -1078,7 +1203,7 @@ class _LiveWalk(object):
             for buf in self.every[start:]:
                 local.append(buf)
                 if buf.scope is None and ins.opcode in _COPIES:
-                    buf.scope = self.copied_scope(ins, by_name)
+                    buf.scope, buf.phase = self.copied_scope(ins, by_name)
             if ins.opcode in ('tuple', 'get-tuple-element', 'bitcast',
                               'parameter', 'constant'):
                 if not ins.root:
@@ -1103,7 +1228,7 @@ class _LiveWalk(object):
             made_by = by_name[buf.ins]
             if buf.scope is None and made_by.opcode == 'custom-call' and \
                     _ALLOCATE in made_by.attrs:
-                buf.scope = self.read_scope(made_by)
+                buf.scope, buf.phase = self.read_scope(made_by)
         if loop:
             # what the body computes anew for the next trip: by the
             # rule the carried buffer's memory, so no temporary; kept
@@ -1140,19 +1265,19 @@ class _LiveWalk(object):
         return self.walked[name]
 
     def read_scope(self, reader):
-        """The fluid op that reads through ``reader``: its own, or
-        where it has none (a copy the compiler put in, an alias) that
-        of the nearest reader of its result."""
+        """The (fluid op, pass) that reads through ``reader``: its own,
+        or where it has no op (a copy the compiler put in, an alias)
+        that of the nearest reader of its result."""
         queue, seen = collections.deque([reader]), {reader.name}
         while queue and len(seen) < 64:
             ins = queue.popleft()
             if self.scopes.get(ins.name):
-                return self.scopes[ins.name]
+                return self.scoped(ins.name)
             for user in self.users.get(ins.name, ()):
                 if user.name not in seen:
                     seen.add(user.name)
                     queue.append(user)
-        return None
+        return None, None
 
     def kind(self, buf):
         """A buffer's class, by the rule above."""
@@ -1161,11 +1286,15 @@ class _LiveWalk(object):
         op = buf.scope.split('/')[0]
         if op in self.optimizers:
             return 'optimizer'
+        if buf.phase == 'recomputed':
+            return 'recomputed'
         backward = op.endswith('_grad')
         reader = buf.reader
-        read_by = self.read_scope(reader) if reader else None
+        read_by, read_in = self.read_scope(reader) if reader \
+            else (None, None)
         read_op = read_by.split('/')[0] if read_by else ''
-        if not backward and read_op.endswith('_grad'):
+        if not backward and (read_op.endswith('_grad') or
+                             read_in == 'recomputed'):
             return 'residual'
         if backward and reader is not None:
             if read_op in self.optimizers or read_op.startswith('c_') or \
@@ -1184,7 +1313,7 @@ def _optimizer_types():
             'optimizer_ops'))
 
 
-def _live_table(hlo_text, computations, scopes, every=False):
+def _live_table(hlo_text, computations, scopes, passes, every=False):
     """The live table of one parsed module: where the sum of its live
     temporaries is largest and what is alive there.  ``every``: also
     every buffer the walk defined, under 'every' (tests)."""
@@ -1192,7 +1321,7 @@ def _live_table(hlo_text, computations, scopes, every=False):
     if m is None or m.group(1) not in computations or \
             'is_scheduled=true' not in hlo_text[:4096]:
         return None     # no entry, or its order is not the schedule
-    walk = _LiveWalk(computations, scopes, m.group(1))
+    walk = _LiveWalk(computations, scopes, passes, m.group(1))
     peak, point, alive, elsewhere = walk.walk(m.group(1))
     where = (point or '').split(' > ')[-1]
 
@@ -1228,20 +1357,26 @@ def _live_table(hlo_text, computations, scopes, every=False):
     return table
 
 
+# what one parse of a module gives
+_Built = collections.namedtuple(
+    '_Built', 'module scopes costs loops live passes')
+
+
 def _tables(hlo_text, op_types=None, every_buffer=False):
-    """One compiled module's optimised HLO text -> (module name, scope
-    table, cost table, loop table, live table) from ONE parse; the
-    scope and cost tables hold every instruction a trace can name
-    (those of fused computations are left out, their fusion stands for
-    them), so ``pick_table`` picks the same program in both; the loop
-    table holds those inside a differentiable loop's body
-    (``loop_side``); the live table (``_live_table``) is the module's
-    temporaries at their peak."""
+    """One compiled module's optimised HLO text -> a ``_Built`` (module
+    name, scope table, cost table, loop table, live table, pass table)
+    from ONE parse; the scope, cost and pass tables hold every
+    instruction a trace can name (those of fused computations are left
+    out, their fusion stands for them), so ``pick_table`` picks the
+    same program in each; the loop table holds those inside a
+    differentiable loop's body (``loop_side``); the live table
+    (``_live_table``) is the module's temporaries at their peak."""
     op_types = op_types or _registered_op_types()
+    optimizers = _optimizer_types()
     module, computations = _parse_hlo(hlo_text)
     fused = {ins.calls for body in computations.values() for ins in body
              if ins.opcode == 'fusion'}
-    scopes, costs, loops = {}, {}, {}
+    scopes, costs, loops, passes = {}, {}, {}, {}
     for name, body in computations.items():
         if name in fused:
             continue
@@ -1249,16 +1384,18 @@ def _tables(hlo_text, op_types=None, every_buffer=False):
         for ins in body:
             called = computations.get(ins.calls)
             if ins.opcode == 'fusion' and called is not None:
-                scopes[ins.name] = _fusion_scope(ins, called, op_types)
+                decider = _fusion_decider(ins, called, op_types)
                 side = _fusion_loop_side(ins, called)
             else:
-                scopes[ins.name] = fluid_scope(ins.op_name, op_types)
+                decider = ins
                 side = loop_side(ins.op_name)
+            scopes[ins.name], passes[ins.name] = _scope_and_pass(
+                decider.op_name, op_types, optimizers)
             if side:
                 loops[ins.name] = side
             costs[ins.name] = _instruction_cost(ins, shapes, called)
-    return module, scopes, costs, loops, _live_table(
-        hlo_text, computations, scopes, every_buffer)
+    return _Built(module, scopes, costs, loops, _live_table(
+        hlo_text, computations, scopes, passes, every_buffer), passes)
 
 
 def hlo_scopes(hlo_text, op_types=None):
@@ -1282,21 +1419,27 @@ def hlo_live(hlo_text, every=False):
     ``every``: with every buffer the walk defined, where it is born
     and where it dies)."""
     built = _tables(hlo_text, every_buffer=every)
-    return built[0], built[4]
+    return built.module, built.live
 
 
 def _held_tables():
-    """[(module name, scope table, cost table, loop table, live
-    table)] of every executable this process holds.  The compile plane keeps what
-    ``_tables`` made of an executable while it holds it: each is
-    printed and parsed once, whichever table is asked for first and
-    however often."""
+    """The ``_Built`` of every executable this process holds.  The
+    compile plane keeps what ``_tables`` made of an executable while it
+    holds it: each is printed and parsed once, whichever table is asked
+    for first and however often."""
     return [built for _key, built in _keyed_tables()]
 
 
 def _keyed_tables():
     from . import compile_cache
     return compile_cache.plane().held_tables(_tables)
+
+
+def _by_module(field):
+    tables = {}
+    for built in _held_tables():
+        tables.setdefault(built.module, []).append(getattr(built, field))
+    return tables
 
 
 def scope_tables():
@@ -1306,19 +1449,13 @@ def scope_tables():
     other time: it prints and parses whole modules, seconds at
     BERT-base.  Two programs of one name (a segment planned for two
     fetch lists) keep a table each; ``pick_table`` tells them apart."""
-    tables = {}
-    for module, scopes, _costs, _loops, _live in _held_tables():
-        tables.setdefault(module, []).append(scopes)
-    return tables
+    return _by_module('scopes')
 
 
 def cost_tables():
     """{HLO module name: [table, ...]} (tables as ``hlo_costs`` gives
     them), beside ``scope_tables()`` and from the same parse."""
-    tables = {}
-    for module, _scopes, costs, _loops, _live in _held_tables():
-        tables.setdefault(module, []).append(costs)
-    return tables
+    return _by_module('costs')
 
 
 def loop_tables():
@@ -1327,10 +1464,16 @@ def loop_tables():
     the instructions inside the bodies of the program's differentiable
     loops (``loop_side``); a module without one has an empty
     table."""
-    tables = {}
-    for module, _scopes, _costs, loops, _live in _held_tables():
-        tables.setdefault(module, []).append(loops)
-    return tables
+    return _by_module('loops')
+
+
+def pass_tables():
+    """{HLO module name: [table, ...]} beside ``scope_tables()`` and
+    from the same parse: {instruction name: 'forward' | 'recomputed' |
+    'backward' | None} for the same instructions, by the pass rule
+    above (``fluid_pass``).  Built when asked for and at no other time,
+    as the others."""
+    return _by_module('passes')
 
 
 def live_tables():
@@ -1342,7 +1485,8 @@ def live_tables():
     None for a module whose text names no entry computation or is not
     scheduled.  By executable and not by module name, because
     fluid.memviz's rows, which carry it, are filed by executable."""
-    return {key: (built[0], built[4]) for key, built in _keyed_tables()}
+    return {key: (built.module, built.live)
+            for key, built in _keyed_tables()}
 
 
 def pick_table(candidates, instruction_names):
@@ -1451,14 +1595,16 @@ def load_trace_events(logdir):
     an ``hlo_op`` stat) carries ``args['tf_op']``: the fluid scope
     ``scope_tables()`` gives it, else the module's name,
     ``self_dur`` where other events of its line nest inside it, and
-    what ``cost_tables()`` knows of it: ``args['kind']``, ``args['mb']``
-    and, unless it is a custom call, ``args['gflop']`` and the rates
-    over its ``dur``, ``args['tflops']`` and ``args['gbps']``."""
+    ``args['pass']`` where ``pass_tables()`` gives it one, and what
+    ``cost_tables()`` knows of it: ``args['kind']`` (the opcode held),
+    ``args['shapes']``, ``args['mb']`` and, unless it is a custom call,
+    ``args['gflop']`` and the rates over its ``dur``,
+    ``args['tflops']`` and ``args['gbps']``."""
     path = _newest_xplane(logdir)
     if path is None:
         return []
     from jax.profiler import ProfileData
-    tables = scope_tables(), cost_tables()
+    tables = scope_tables(), cost_tables(), pass_tables()
     out = []
     for pid, plane in enumerate(ProfileData.from_file(path).planes):
         out.append({'ph': 'M', 'pid': pid, 'name': 'process_name',
@@ -1490,21 +1636,24 @@ def load_trace_events(logdir):
 
 def _attach_scopes(rows, tables):
     """Give the instruction events of one line their ``tf_op``, their
-    cost and their ``self_dur``; ``tables`` are (scope tables, cost
-    tables)."""
+    pass, their cost and their ``self_dur``; ``tables`` are (scope
+    tables, cost tables, pass tables)."""
     if not rows:
         return
     programs = [row.pop('module') for row in rows]
     ops = [(p, row['name']) for p, row in zip(programs, rows)]
-    scopes = instruction_scopes(ops, tables[0])
-    costs = instruction_costs(ops, tables[1])
-    for row, program, scope, cost in zip(rows, programs, scopes, costs):
+    found = zip(rows, programs, *(instruction_scopes(ops, t)
+                                  for t in tables))
+    for row, program, scope, cost, phase in found:
         args = row['args'] = {
             'tf_op': scope or _PROGRAM_ID.sub('', program) or
             'unknown_module'}
+        if phase:
+            args['pass'] = phase
         if cost is None:
             continue
         args['kind'] = cost.kind
+        args['shapes'] = cost.shapes
         args['mb'] = cost.bytes / 1e6
         if cost.flops is not None:
             args['gflop'] = cost.flops / 1e9
@@ -1617,7 +1766,7 @@ def stop_profiler(sorted_key='total', profile_path=None):
         device_events = load_trace_events(_prof_trace_dir)
         recs, stats = attribute_trace_events(
             [e for e in device_events if 'args' in e], with_stats=True,
-            costs=_costs)
+            costs=_costs, passes=_passes)
         _records.update(recs)
         if stats['dropped']:
             # malformed capture rows are counted, not silently eaten
@@ -1722,7 +1871,8 @@ def stop_trace():
     device_events = load_trace_events(path)
     reset_profiler()
     _records.update(attribute_trace_events(
-        [e for e in device_events if 'args' in e], costs=_costs))
+        [e for e in device_events if 'args' in e], costs=_costs,
+        passes=_passes))
     try:
         trace_mod.write_chrome(os.path.join(path, 'device.trace.json'),
                                device_events)

@@ -323,7 +323,8 @@ def test_start_trace_double_start_raises(tmp_path):
 def test_attribute_trace_events_transform_wrapped_scopes():
     """Satellite: transform-wrapped scope components — the wpg backward
     wraps op scopes as transpose(jvp(op)), possibly nested — must
-    attribute to the base op; kernels with no registered component land
+    attribute to the op, the transposed one to its ``_grad`` as in the
+    scope table; kernels with no registered component land
     in per-HLO 'unattributed/…' buckets (folded keys stay one level)."""
     ev = [
         {'ph': 'X', 'name': 'fusion.9', 'dur': 50.0,
@@ -334,8 +335,9 @@ def test_attribute_trace_events_transform_wrapped_scopes():
          'args': {'tf_op': 'jit_seg/convert'}},
     ]
     recs = profiler.attribute_trace_events(ev, op_types={'relu'})
-    assert recs['relu'][0] == 2
-    assert abs(recs['relu'][1] - 80e-6) < 1e-12
+    assert recs['relu'][0] == 1 and recs['relu_grad'][0] == 1
+    assert abs(recs['relu'][1] - 30e-6) < 1e-12
+    assert abs(recs['relu_grad'][1] - 50e-6) < 1e-12
     assert recs['unattributed/convert'][0] == 1
     # fold-in keeps the unattributed bucket one level deep
     monitor.reset()
@@ -343,7 +345,8 @@ def test_attribute_trace_events_transform_wrapped_scopes():
     profiler._records.update(recs)
     profiler._fold_into_monitor()
     prof = monitor.snapshot()['profiler']
-    assert prof['relu']['calls'] == 2.0
+    assert prof['relu']['calls'] == 1.0
+    assert prof['relu_grad']['calls'] == 1.0
     assert prof['unattributed:convert']['calls'] == 1.0
     profiler.reset_profiler()
     monitor.reset()

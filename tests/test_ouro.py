@@ -402,7 +402,7 @@ def _three_times(x, w):
     ('jit(s)/jvp(while)/while/body/loop_body/checkpoint/rms_norm/mul',
      'rms_norm', 'forward'),
     ('jit(s)/transpose(jvp(while))/while/body/loop_body/checkpoint/'
-     'rematted_computation/rms_norm/mul', 'rms_norm_grad', 'backward'),
+     'rematted_computation/rms_norm/mul', 'rms_norm', 'backward'),
     ('jit(s)/while/while/body/closed_call/fused_multihead_attention/'
      'pallas_call', 'fused_multihead_attention', None),
     ('jit(s)/jvp(while)/while/body/dynamic_update_slice', 'while/while',
@@ -419,7 +419,9 @@ def _three_times(x, w):
 def test_the_scope_table_looks_into_a_loop_s_body(op_name, scope, side):
     """An instruction inside a ``while`` body counts to the fluid op it
     was lowered from, backward where the loop's component is
-    transposed; only what the loop adds counts to ``while``; the loop
-    table tells the forward body from the transposed one."""
+    transposed (a recompute group's second forward there keeps the
+    forward's name: its pass says ``recomputed``); only what the loop
+    adds counts to ``while``; the loop table tells the forward body
+    from the transposed one."""
     assert profiler.fluid_scope(op_name) == scope
     assert profiler.loop_side(op_name) == side

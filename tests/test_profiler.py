@@ -120,8 +120,11 @@ def test_attribute_trace_events_maps_kernels_to_ops():
     ]
     recs = profiler.attribute_trace_events(
         ev, op_types={'mul', 'relu', 'reduce_mean'})
-    assert recs['mul'][0] == 3  # two fwd calls + one transposed bwd
-    assert abs(recs['mul'][1] - (800 + 820 + 700) * 1e-6) < 1e-12
+    # two fwd calls; the transposed one is the scope table's mul_grad
+    # (one rule: fluid_scope reads a raw path too)
+    assert recs['mul'][0] == 2 and recs['mul_grad'][0] == 1
+    assert abs(recs['mul'][1] - (800 + 820) * 1e-6) < 1e-12
+    assert abs(recs['mul_grad'][1] - 700e-6) < 1e-12
     assert recs['relu'][0] == 1
     assert 'unattributed/copy-start' in recs
     # dominant op of the known program is mul

@@ -10,7 +10,8 @@ import pytest
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import compile_cache, profiler
 
-OPS = {'mul', 'relu', 'adam', 'fused_adam', 'lookup_table_v2', 'softmax'}
+OPS = {'mul', 'relu', 'adam', 'fused_adam', 'lookup_table_v2', 'softmax',
+       'tanh', 'while'}
 
 # two modules of one name (a segment planned for two fetch lists); the
 # second holds one instruction more and gives 'fusion.1' another scope
@@ -132,22 +133,119 @@ def test_trace_ops_go_to_the_program_whose_run_holds_them():
          ('', 'multiply.14')], tables)[0] == 'mul_grad'
 
 
-@pytest.mark.parametrize('op_name,scope', [
-    ('jit(segment_x)/mul/dot_general', 'mul'),
-    ('jit(segment_wpg_x)/jvp(mul)/dot_general', 'mul'),
-    ('jit(segment_wpg_x)/transpose(jvp(mul))/dot_general', 'mul_grad'),
-    ('jit(segment_x)/mul_grad/dot_general', 'mul_grad'),
-    ('jit(segment_x)/mul#7/dot_general', None),  # one rule: no suffixes
-    ('jit(segment_x)/fused_adam/unpack/slice', 'fused_adam/unpack'),
+_WPG = 'jit(segment_wpg_mul_x36)/transpose(jvp(jvp()))/checkpoint/'
+_LOOP = ('jit(s)/transpose(jvp(while))/while/body/closed_call/loop_body/'
+         'loop_body/checkpoint/')
+
+
+@pytest.mark.parametrize('op_name,scope,phase', [
+    ('jit(segment_x)/mul/dot_general', 'mul', 'forward'),
+    ('jit(segment_wpg_x)/jvp(mul)/dot_general', 'mul', 'forward'),
+    ('jit(segment_wpg_x)/transpose(jvp(mul))/dot_general', 'mul_grad',
+     'backward'),
+    ('jit(segment_x)/mul_grad/dot_general', 'mul_grad', 'backward'),
+    ('jit(segment_x)/mul#7/dot_general', None, None),  # no suffixes
+    ('jit(segment_x)/fused_adam/unpack/slice', 'fused_adam/unpack',
+     'forward'),
+    # a registered optimizer's op belongs to no pass
+    ('jit(segment_x)/adam/mul', 'adam', None),
     ('jit(segment_x)/lookup_table_v2/jit(_take)/gather',
-     'lookup_table_v2'),
-    ('jit(segment_x)/jit(relu)/max', None),     # jit's name is no scope
-    ('jit(segment_x)/mul', None),               # a primitive, not a scope
-    ('reduce_sum', None),
-    ('', None),
+     'lookup_table_v2', 'forward'),
+    ('jit(segment_x)/jit(relu)/max', None, None),  # jit's name: no scope
+    ('jit(segment_x)/mul', None, None),         # a primitive, not a scope
+    ('reduce_sum', None, None),
+    ('', None, None),
+    # a recompute group: the transpose sits on an earlier, nameless
+    # component; the group's backward ...
+    (_WPG + 'mul/dot_general', 'mul_grad', 'backward'),
+    (_WPG + 'tanh/mul', 'tanh_grad', 'backward'),
+    # ... and its second forward, which keeps the forward's name
+    (_WPG + 'rematted_computation/mul/dot_general', 'mul', 'recomputed'),
+    (_WPG + 'rematted_computation/tanh/tanh', 'tanh', 'recomputed'),
+    # a group in the body of a differentiable loop, under the
+    # transposed ``while``
+    (_LOOP + 'mul/dot_general', 'mul_grad', 'backward'),
+    (_LOOP + 'rematted_computation/mul/dot_general', 'mul', 'recomputed'),
+    ('jit(s)/jvp(while)/while/body/closed_call/loop_body/mul/dot_general',
+     'mul', 'forward'),
+    ('jit(s)/transpose(jvp(while))/while/body/dynamic_slice',
+     'while_grad/while', 'backward'),
+    # a Mosaic call lowered inside a group follows the same components
+    (_WPG + 'rematted_computation/softmax/pallas_call', 'softmax',
+     'recomputed'),
+    (_WPG + 'softmax/pallas_call', 'softmax_grad', 'backward'),
+    # an op's own backward rule that runs its forward again is backward
+    ('jit(s)/transpose(jvp(softmax))/pallas_call',
+     'softmax_grad', 'backward'),
+    # a loop a group runs again
+    (_WPG + 'rematted_computation/while/body/loop_body/mul/dot_general',
+     'mul', 'recomputed'),
 ])
-def test_fluid_scope_of_an_op_name(op_name, scope):
+def test_fluid_scope_of_an_op_name(op_name, scope, phase):
     assert profiler.fluid_scope(op_name, OPS) == scope
+    assert profiler.fluid_pass(op_name, OPS) == phase
+
+
+# a fusion that holds a recomputed dot under a backward root: the dot
+# decides scope AND pass; a fusion of elementwise backward code; a
+# multi-output fusion whose tuple root stands for a recomputed operand
+GROUP_HLO = '''HloModule jit_segment_wpg_mul_x36, is_scheduled=true
+
+%fused_dot (p0: f32[8,8], p1: f32[8,8]) -> f32[8,8] {
+  %p0 = f32[8,8]{1,0} parameter(0)
+  %p1 = f32[8,8]{1,0} parameter(1)
+  %dot.2 = f32[8,8]{1,0} dot(%p0, %p1), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="WPGrematted_computation/mul/dot_general"}
+  ROOT %mul.3 = f32[8,8]{1,0} multiply(%dot.2, %p0), metadata={op_name="WPGtanh/mul"}
+}
+
+%fused_back (p0.1: f32[8,8]) -> f32[8,8] {
+  %p0.1 = f32[8,8]{1,0} parameter(0)
+  ROOT %mul.4 = f32[8,8]{1,0} multiply(%p0.1, %p0.1), metadata={op_name="WPGtanh/mul"}
+}
+
+%fused_tuple (p0.2: f32[8,8]) -> (f32[8,8], f32[8,8]) {
+  %p0.2 = f32[8,8]{1,0} parameter(0)
+  %tanh.5 = f32[8,8]{1,0} tanh(%p0.2), metadata={op_name="WPGrematted_computation/tanh/tanh"}
+  %copy.6 = f32[8,8]{1,0} copy(%tanh.5)
+  ROOT %tuple.7 = (f32[8,8]{1,0}, f32[8,8]{1,0}) tuple(%copy.6, %p0.2)
+}
+
+ENTRY %main.9 (Arg_0.1: f32[8,8]) -> f32[8,8] {
+  %Arg_0.1 = f32[8,8]{1,0} parameter(0)
+  %first.1 = f32[8,8]{1,0} tanh(%Arg_0.1), metadata={op_name="jit(segment_wpg_mul_x36)/jvp(tanh)/tanh"}
+  %fusion.1 = f32[8,8]{1,0} fusion(%first.1, %Arg_0.1), kind=kOutput, calls=%fused_dot, metadata={op_name="WPGtanh/mul"}
+  %fusion.2 = f32[8,8]{1,0} fusion(%fusion.1), kind=kLoop, calls=%fused_back
+  %fusion.3 = (f32[8,8]{1,0}, f32[8,8]{1,0}) fusion(%first.1), kind=kLoop, calls=%fused_tuple
+  %get-tuple-element.8 = f32[8,8]{1,0} get-tuple-element(%fusion.3), index=0
+  %adam.1 = f32[8,8]{1,0} add(%fusion.2, %get-tuple-element.8), metadata={op_name="jit(segment_wpg_mul_x36)/adam/add"}
+  ROOT %copy.2 = f32[8,8]{1,0} copy(%adam.1)
+}
+'''.replace('WPG', _WPG)
+
+
+def test_a_fusion_s_pass_is_that_of_the_instruction_that_decides_its_scope():
+    built = profiler._tables(GROUP_HLO, op_types=OPS | {'tanh'})
+    assert built.module == 'jit_segment_wpg_mul_x36'
+    both = {name: (built.scopes[name], built.passes[name])
+            for name in built.scopes}
+    # the dot it holds, not its backward root nor its own op_name
+    assert both['fusion.1'] == ('mul', 'recomputed')
+    assert both['fusion.2'] == ('tanh_grad', 'backward')
+    # the tuple root's nearest scoped operand
+    assert both['fusion.3'] == ('tanh', 'recomputed')
+    assert both['first.1'] == ('tanh', 'forward')
+    assert both['adam.1'] == ('adam', None)
+    assert both['copy.2'] == (None, None)
+    assert set(built.passes) == set(built.scopes) == set(built.costs)
+    # the live walk: what the first forward defines and the second
+    # forward reads is kept; what the second forward defines is
+    # recomputed, a copy of it the compiler made too
+    rows = {r['instruction']: r['class']
+            for r in profiler.hlo_live(GROUP_HLO, every=True)[1]['every']}
+    assert rows['first.1'] == 'residual'
+    assert rows['fusion.1'] == 'recomputed'
+    assert rows['fusion.3'] == 'recomputed'
+    assert rows['fusion.2'] == 'gradient'
 
 
 def test_self_durations_of_a_nest():
@@ -164,6 +262,25 @@ def _scopes_of_held_programs():
     return types
 
 
+def _scope_and_pass_of_held_programs():
+    """Counter of (scope, pass) over every instruction of every program
+    held; the two tables hold the same instructions, and a pass goes
+    with its name: ``_grad`` where and only where it is backward."""
+    both = collections.Counter()
+    scopes, passes = profiler.scope_tables(), profiler.pass_tables()
+    assert sorted(scopes) == sorted(passes)
+    for module, tables in scopes.items():
+        for table, by_pass in zip(tables, passes[module]):
+            assert set(table) == set(by_pass)
+            both.update((table[name], by_pass[name]) for name in table)
+    for scope, phase in both:
+        assert phase in (None,) + profiler.PASSES
+        if phase is not None:
+            assert scope.split('/')[0].endswith('_grad') == \
+                (phase == 'backward'), (scope, phase)
+    return both
+
+
 def _train_once(build, feed):
     compile_cache.reset_plane()
     main, startup = fluid.Program(), fluid.Program()
@@ -175,6 +292,9 @@ def _train_once(build, feed):
         exe.run(main, feed=feed, fetch_list=[loss])
         program_types = {op.type for program in (main, startup)
                          for op in program.global_block().ops}
+        # a program without a recompute group runs nothing twice
+        both = _scope_and_pass_of_held_programs()
+        assert {phase for _, phase in both} == {None, 'forward', 'backward'}
         return program_types, _scopes_of_held_programs()
 
 
@@ -249,6 +369,176 @@ def test_scope_table_of_a_tiny_resnet_program():
         {'conv2d', 'conv2d_grad', 'batch_norm', 'batch_norm_grad',
          'pool2d', 'pool2d_grad', 'momentum'},
         absorbed={'elementwise_add', 'softmax'})
+
+
+def _group_program():
+    """Four fc layers, the middle two one recompute group."""
+    x = fluid.layers.data('x', shape=[16], dtype='float32')
+    h = fluid.layers.fc(x, 16, act='tanh')
+    with fluid.backward.recompute_guard():
+        h = fluid.layers.fc(h, 16, act='tanh')
+        h = fluid.layers.fc(h, 16, act='relu')
+    loss = fluid.layers.mean(fluid.layers.fc(h, 16))
+    fluid.optimizer.Adam(1e-3).minimize(loss)
+    return loss
+
+
+def _loop_program():
+    """A differentiable ``While`` whose body, tanh(exp(x) x w), is a
+    recompute group (as models/ouro.py's blocks are)."""
+    layers = fluid.layers
+    x = layers.data('x', shape=[16], dtype='float32')
+    w = layers.create_parameter([16, 16], 'float32', name='w')
+    i = layers.fill_constant([1], 'int64', 0)
+    n = layers.fill_constant([1], 'int64', 3)
+    going = layers.less_than(i, n)
+    state = layers.scale(x, scale=1.0)
+    loop = layers.While(going, max_trip_count=3)
+    with loop.block():
+        with fluid.backward.recompute_guard():
+            new = layers.tanh(layers.mul(layers.exp(state), w))
+        layers.assign(new, state)
+        layers.increment(i, 1.0)
+        layers.less_than(i, n, cond=going)
+    loss = layers.mean(state)
+    fluid.optimizer.SGD(0.1).minimize(loss)
+    return loss
+
+
+def _run_once(build):
+    """-> (executor, program, feed, loss) after one step, inside a
+    scope of its own, on an emptied compile plane."""
+    compile_cache.reset_plane()
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        loss = build()
+    feed = {'x': np.ones((4, 16), 'float32')}
+    exe = fluid.Executor(fluid.XLAPlace(0))
+    exe.run(startup)
+    exe.run(main, feed=feed, fetch_list=[loss])
+    return exe, main, feed, loss
+
+
+def _live_rows_of_the_step():
+    (text,) = [text for _, text in compile_cache.plane().held_hlo()
+               if 'rematted_computation' in text]
+    return profiler.hlo_live(text, every=True)[1]['every']
+
+
+def test_a_recompute_group_s_three_passes_through_the_executor(tmp_path,
+                                                              capsys):
+    """What jax names a group's instructions is read off a real
+    lowering: a jax that renames ``rematted_computation`` fails here
+    and not, silently, in a metric."""
+    import importlib.util
+    import json
+    import os
+    with fluid.scope_guard(fluid.Scope()):
+        exe, main, feed, loss = _run_once(_group_program)
+        both = _scope_and_pass_of_held_programs()
+        for wanted in (('mul', 'forward'), ('mul', 'recomputed'),
+                       ('mul_grad', 'backward'), ('tanh', 'recomputed'),
+                       ('tanh_grad', 'backward'), ('adam', None)):
+            assert both[wanted], (wanted, sorted(both, key=str))
+        # the group's two products are run a second time, the two
+        # outside it are not
+        assert both[('mul', 'recomputed')] == 2
+        # the live walk: a second forward's buffers are ``recomputed``;
+        # the group's input, which the first forward's tanh defined and
+        # the second forward reads, is what the group KEEPS
+        rows = _live_rows_of_the_step()
+        classes = collections.Counter(r['class'] for r in rows)
+        assert classes['recomputed'] and classes['residual']
+        assert all(not r['op'].split('/')[0].endswith('_grad')
+                   for r in rows if r['class'] in ('recomputed', 'residual'))
+        assert any(r['op'] == 'tanh' and r['class'] == 'residual'
+                   for r in rows)
+        # a capture: every instruction event carries its pass and its
+        # shapes, and the table ends with the three totals
+        logdir = str(tmp_path / 'cap')
+        profiler.start_trace(logdir)
+        for _ in range(2):
+            exe.run(main, feed=feed, fetch_list=[loss])
+        profiler.stop_trace()
+    last = profiler.summary_string().splitlines()[-1]
+    assert last.startswith('by pass (ms): forward ')
+    assert ', recomputed ' in last and ', backward ' in last
+    assert 'mul_grad' in profiler.summary_records()
+    events = json.load(open(os.path.join(
+        logdir, 'device.trace.json')))['traceEvents']
+    seen = collections.Counter(
+        (e['args']['tf_op'], e['args'].get('pass')) for e in events
+        if 'args' in e and 'tf_op' in e['args'])
+    assert seen[('mul', 'recomputed')] and seen[('mul_grad', 'backward')]
+    assert all('shapes' in e['args'] for e in events
+               if e.get('args', {}).get('kind'))
+    rows = profiler.instructions_under(events, ['mul'])
+    assert {r['pass'] for r in rows} == {'forward', 'recomputed'}
+    assert all(r['tf_op'] == 'mul' and r['kind'] == 'dot' and
+               r['calls'] >= 2 and r['ms'] > 0 and 'f32[' in r['shapes']
+               for r in rows)
+    unscoped = profiler.instructions_under(events, [profiler.UNSCOPED])
+    assert unscoped and all(r['pass'] is None for r in unscoped)
+    assert not {r['name'] for r in rows} & {r['name'] for r in unscoped}
+    # tools/timeline.py --scope prints the same rows
+    spec = importlib.util.spec_from_file_location(
+        'timeline_tool', os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), 'tools', 'timeline.py'))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    capsys.readouterr()
+    assert tool.print_scope(logdir, ['mul', 'mul_grad',
+                                     profiler.UNSCOPED], 50) == 0
+    printed = capsys.readouterr().out
+    assert ' recomputed ' in printed and ' backward ' in printed
+    assert ' copy ' in printed
+    profiler.reset_profiler()
+
+
+def test_a_recompute_group_in_a_loop_s_body():
+    """Under the transposed ``while`` the same components decide: the
+    body's second forward is ``recomputed`` under the forward's name,
+    on the loop's backward side."""
+    with fluid.scope_guard(fluid.Scope()):
+        _run_once(_loop_program)
+        both = _scope_and_pass_of_held_programs()
+        for wanted in (('mul', 'forward'), ('mul', 'recomputed'),
+                       ('exp', 'recomputed'), ('mul_grad', 'backward'),
+                       ('tanh_grad', 'backward'), ('sgd', None)):
+            assert both[wanted], (wanted, sorted(both, key=str))
+        sides = collections.Counter()
+        passes, loops = profiler.pass_tables(), profiler.loop_tables()
+        for module, tables in passes.items():
+            for by_pass, by_side in zip(tables, loops[module]):
+                sides.update((by_pass[name], side)
+                             for name, side in by_side.items())
+        assert sides[('recomputed', 'backward')]
+        assert not sides[('recomputed', 'forward')]
+        assert sides[('forward', 'forward')]
+        classes = collections.Counter(
+            r['class'] for r in _live_rows_of_the_step())
+        assert classes['recomputed'] and classes['residual']
+
+
+def test_a_program_without_a_group_has_no_recomputed_pass(tmp_path):
+    def build():
+        x = fluid.layers.data('x', shape=[16], dtype='float32')
+        loss = fluid.layers.mean(fluid.layers.fc(x, 16, act='tanh'))
+        fluid.optimizer.SGD(0.1).minimize(loss)
+        return loss
+
+    with fluid.scope_guard(fluid.Scope()):
+        exe, main, feed, loss = _run_once(build)
+        both = _scope_and_pass_of_held_programs()
+        assert both[('mul', 'forward')] and both[('mul_grad', 'backward')]
+        assert 'recomputed' not in {phase for _, phase in both}
+        for _module, live in profiler.live_tables().values():
+            assert 'recomputed' not in live['by_class']
+        profiler.start_trace(str(tmp_path / 'cap'))
+        exe.run(main, feed=feed, fetch_list=[loss])
+        profiler.stop_trace()
+    assert 'by pass' not in profiler.summary_string()
+    profiler.reset_profiler()
 
 
 def test_a_dead_segment_leaves_the_plane_and_a_live_one_needs_no_trace():

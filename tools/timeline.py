@@ -16,6 +16,18 @@ aligned on the pt_clock_sync annotation the capture emitted.
 Usage: python tools/timeline.py --profile_path /tmp/profile \
            --timeline_path /tmp/timeline.json [--host_trace host.json]
 
+`--scope NAME [NAME ...]` prints, instead, every instruction the capture
+ran under those fluid scopes (`moe_dispatch moe_dispatch_grad`; the
+first device's), longest first, with its pass, the opcode it holds, its
+shapes, calls, ms, one call's MB and GB/s: what `--xla_dump_to` was
+needed for.  `'(unscoped)'` is a name too: the instructions the scope
+table gives no fluid op (the compiler's own copies and broadcasts), by
+opcode and shape, no owner guessed.  It reads the
+`<logdir>/device.trace.json` that `stop_trace` wrote.
+
+Usage: python tools/timeline.py --profile_path /tmp/profile \
+           --scope moe_dispatch moe_dispatch_grad '(unscoped)'
+
 Job mode (`--job`) merges a whole MULTI-WORKER job instead: it pulls
 every worker's /trace/dump over HTTP (--workers 'rank=host:port,...',
 default $PADDLE_TPU_STATUS_WORKERS — the launcher's wire format) or
@@ -87,6 +99,39 @@ def merge(src, host_path, out_path):
     return n_host, n_counters
 
 
+def print_scope(profile_path, scopes, top):
+    sys.path.insert(0, os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))
+    from paddle_tpu.fluid import profiler
+    hits = glob.glob(os.path.join(profile_path, '**', 'device.trace.json'),
+                     recursive=True)
+    if not hits:
+        raise SystemExit(
+            'no device.trace.json under %s: capture with fluid.profiler.'
+            'start_trace(logdir)/stop_trace()' % profile_path)
+    rows = profiler.instructions_under(
+        load_device_events(max(hits, key=os.path.getmtime)), scopes)
+    print('%d instructions under %s, %.3f ms in all (ms: an '
+          "instruction's own time over all its calls in the capture)"
+          % (len(rows), ' + '.join(scopes), sum(r['ms'] for r in rows)))
+    print('%-28s %-24s %-10s %-12s %6s %10s %9s %8s  %s'
+          % ('instruction', 'scope', 'pass', 'holds', 'calls', 'ms', 'MB',
+             'GB/s', 'shapes'))
+
+    def cell(value, form):
+        return '-' if value is None else form % value
+
+    for r in rows[:top]:
+        print('%-28s %-24s %-10s %-12s %6d %10.3f %9s %8s  %s'
+              % (r['name'], r['tf_op'], r['pass'] or '-', r['kind'] or '-',
+                 r['calls'], r['ms'], cell(r['mb'], '%.2f'),
+                 cell(r['gbps'], '%.1f'), r['shapes'] or ''))
+    if len(rows) > top:
+        print('(%d more, %.3f ms)' % (len(rows) - top,
+                                     sum(r['ms'] for r in rows[top:])))
+    return 0
+
+
 def collect_job_cli(args):
     sys.path.insert(0, os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
@@ -150,9 +195,17 @@ def main():
                     help='merge saved /trace/dump files instead of '
                          'scraping (each dump\'s own ptRank labels '
                          'it; argument order is the fallback)')
+    ap.add_argument('--scope', nargs='+', default=None,
+                    help='print the instructions the capture ran under '
+                         "these fluid scopes ('(unscoped)': under "
+                         'none) instead of writing a timeline')
+    ap.add_argument('--top', type=int, default=60,
+                    help='rows --scope prints')
     args = ap.parse_args()
     if args.job:
         return collect_job_cli(args)
+    if args.scope:
+        return print_scope(args.profile_path, args.scope, args.top)
     src = find_trace(args.profile_path)
     host_path = args.host_trace or find_host_trace(args.profile_path)
     if host_path:
